@@ -16,14 +16,14 @@ nodes, one after the other:
   `preempt.announced` -> `autoscaler.replace` -> `node.dead` per victim,
   and the run's wall time fully attributed to goodput buckets.
 
-One JSON line reports the episode; it is also self-captured as the next
-BENCH_CLUSTER_r<NN>.json round file.
+One JSON line reports the episode. No process of this drill opens the
+chip: the driver runs with `detect_accelerators=False` and every agent it
+spawns is held to JAX_PLATFORMS=cpu.
 """
 
 from __future__ import annotations
 
 import argparse
-import glob
 import json
 import os
 import sys
@@ -32,33 +32,14 @@ import threading
 import time
 
 
-def _emit_result(payload: dict, rc: int = 0) -> None:
-    """Print the ONE result line and self-capture it as the next
-    BENCH_CLUSTER_r<NN>.json round file (same {n, cmd, rc, tail, parsed}
-    shape the driver writes for bench.py), anchored to the repo root so
-    the round history survives whatever cwd the bench ran from."""
-    line = json.dumps(payload)
-    print(line)
-    root = os.path.dirname(os.path.abspath(__file__))
-    rounds = [
-        int(os.path.basename(p)[len("BENCH_CLUSTER_r"):-len(".json")])
-        for p in glob.glob(os.path.join(root, "BENCH_CLUSTER_r*.json"))
-        if os.path.basename(p)[len("BENCH_CLUSTER_r"):-len(".json")].isdigit()
-    ]
-    n = max(rounds, default=0) + 1
-    path = os.path.join(root, f"BENCH_CLUSTER_r{n:02d}.json")
-    with open(path, "w") as f:
-        json.dump(
-            {
-                "n": n,
-                "cmd": "python " + " ".join(sys.argv),
-                "rc": rc,
-                "tail": line + "\n",
-                "parsed": payload,
-            },
-            f,
-        )
-        f.write("\n")
+def _emit_result(payload: dict) -> None:
+    """Print the ONE result line. These drills check cluster BEHAVIOUR on
+    CPU processes (`detect_accelerators=False`, agents under
+    JAX_PLATFORMS=cpu): the line says so, carries counts and wall-clock
+    readings of this host, and nothing is written into the checkout."""
+    print(json.dumps({**payload, "platform": "cpu",
+                      "note": "behaviour drill on CPU processes; no "
+                              "device metric"}))
 
 
 def _first_ts(evs, kind, **match):

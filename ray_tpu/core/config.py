@@ -350,9 +350,10 @@ define_flag("profile_cost_accounting", True,
 
 # kernels & data-parallel collectives (PERF_NOTES.md round 6)
 define_flag("attn_pipeline", True,
-            "Use the double-buffered emit_pipeline flash-attention kernel "
-            "on TPU backends (falls back to the classic kernel when the "
-            "shape leaves fewer than two kv tiles).")
+            "Use the double-buffered emit_pipeline flash-attention forward "
+            "on TPU backends where ops.attention.resolve_attention_impl's "
+            "static rule admits it (head_dim % 128 == 0, >= 2 kv tiles); "
+            "the classic kernel runs everywhere else.")
 define_flag("dp_allreduce_dtype", "f32",
             "Wire dtype of the data-parallel gradient sync: 'f32' (exact) "
             "or 'int8' (block-quantized all-reduce with error feedback).")

@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import ctypes
 import os
+import shutil
 import subprocess
 import threading
 from typing import Optional
@@ -31,16 +32,29 @@ _lib_lock = threading.Lock()
 _ABI_VERSION = 3
 
 
-def _try_build() -> bool:
+class NativeStoreUnavailable(RuntimeError):
+    """The native arena cannot be loaded; the message says why (no g++,
+    the build failed, or the built library has another ABI)."""
+
+
+def _build() -> None:
+    """Build libobjstore.so from native/objstore.cc — the .so is not in
+    git, so a fresh checkout builds it on first use."""
     if not os.path.exists(_BUILD_SCRIPT):
-        return False
+        raise NativeStoreUnavailable(f"build script missing: {_BUILD_SCRIPT}")
+    if shutil.which("g++") is None:
+        raise NativeStoreUnavailable("g++ is not installed on this machine")
     try:
         subprocess.run(
             ["sh", _BUILD_SCRIPT], capture_output=True, check=True, timeout=120
         )
-        return True
-    except Exception:
-        return False
+    except subprocess.CalledProcessError as exc:
+        raise NativeStoreUnavailable(
+            f"native/build.sh failed (rc={exc.returncode}): "
+            f"{exc.stderr.decode(errors='replace')[-400:]}"
+        ) from exc
+    except subprocess.TimeoutExpired as exc:
+        raise NativeStoreUnavailable("native/build.sh timed out") from exc
 
 
 def _abi_matches(path: str) -> bool:
@@ -56,7 +70,9 @@ def _abi_matches(path: str) -> bool:
         return False
 
 
-def _load_lib() -> Optional[ctypes.CDLL]:
+def _load_lib() -> ctypes.CDLL:
+    """The loaded library, building it first when the .so is missing or
+    stale. Raises NativeStoreUnavailable with the reason otherwise."""
     global _lib
     with _lib_lock:
         if _lib is not None:
@@ -64,10 +80,12 @@ def _load_lib() -> Optional[ctypes.CDLL]:
         if not os.path.exists(_LIB_PATH) or not _abi_matches(_LIB_PATH):
             # missing or stale: rebuild (writes a fresh inode, so the CDLL
             # below maps the new code even if a stale handle exists)
-            if not _try_build():
-                return None
+            _build()
         if not os.path.exists(_LIB_PATH) or not _abi_matches(_LIB_PATH):
-            return None
+            raise NativeStoreUnavailable(
+                f"{_LIB_PATH} does not report ABI {_ABI_VERSION} after a "
+                "rebuild (native/objstore.cc and native_store.py disagree)"
+            )
         lib = ctypes.CDLL(_LIB_PATH)
         lib.store_create_arena.restype = ctypes.c_void_p
         lib.store_create_arena.argtypes = [ctypes.c_uint64]
@@ -109,7 +127,14 @@ def _load_lib() -> Optional[ctypes.CDLL]:
 
 
 def native_available() -> bool:
-    return _load_lib() is not None
+    """True when the native arena can be used (builds it if needed). For
+    callers that may go without it; code that was told to use the arena
+    calls NativeArena and lets NativeStoreUnavailable say why it cannot."""
+    try:
+        _load_lib()
+    except NativeStoreUnavailable:
+        return False
+    return True
 
 
 class NativeArena:
@@ -123,12 +148,7 @@ class NativeArena:
         payloads zero-copy via (offset, size) descriptors (the plasma
         client protocol, plasma/store.h:55; descriptors ride the worker
         pipes instead of a unix socket)."""
-        lib = _load_lib()
-        if lib is None:
-            raise RuntimeError(
-                "native object store unavailable (build failed / no g++)"
-            )
-        self._lib = lib
+        self._lib = lib = _load_lib()
         self.path = path
         if path is None:
             self._arena = lib.store_create_arena(capacity)

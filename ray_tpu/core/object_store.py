@@ -200,31 +200,29 @@ class ObjectStore:
         # Opt-in native shared-memory tier (plasma-equivalent arena) for
         # large numpy payloads. In-process workers pass objects by reference
         # already, so this buys bounded accounting + native LRU eviction and
-        # is the substrate for multi-process CPU workers.
+        # is the substrate for multi-process CPU workers. Asked for and not
+        # loadable (no g++, failed build, stale ABI) is an error that says
+        # which: the store never drops to the Python tier on its own.
         self._arena = None
         if cfg.native_store:
-            try:
-                import tempfile
-                import uuid as _uuid
+            import tempfile
+            import uuid as _uuid
 
-                from .native_store import NativeArena, native_available
+            from .native_store import NativeArena
 
-                if native_available():
-                    # SHARED arena file (plasma-style): worker processes
-                    # mmap it and read sealed payloads zero-copy via
-                    # descriptors (resolve_process_args below)
-                    shm_dir = (
-                        "/dev/shm" if os.path.isdir("/dev/shm")
-                        else tempfile.gettempdir()
-                    )
-                    _reap_stale_arenas(shm_dir)
-                    path = os.path.join(
-                        shm_dir,
-                        f"ray_tpu_arena_{os.getpid()}_{_uuid.uuid4().hex[:8]}",
-                    )
-                    self._arena = NativeArena(capacity_bytes, path=path)
-            except Exception:
-                self._arena = None
+            # SHARED arena file (plasma-style): worker processes mmap it
+            # and read sealed payloads zero-copy via descriptors
+            # (resolve_process_args below)
+            shm_dir = (
+                "/dev/shm" if os.path.isdir("/dev/shm")
+                else tempfile.gettempdir()
+            )
+            _reap_stale_arenas(shm_dir)
+            path = os.path.join(
+                shm_dir,
+                f"ray_tpu_arena_{os.getpid()}_{_uuid.uuid4().hex[:8]}",
+            )
+            self._arena = NativeArena(capacity_bytes, path=path)
         self._shm_entries: Dict[int, ObjectID] = {}  # arena id -> object id  # guarded-by: _lock
         # Lineage resubmission hook (Runtime wires scheduler.submit here):
         # get() of a LOST entry with a recorded owner_task re-executes it
@@ -1070,6 +1068,12 @@ class ObjectStore:
         return resolved, release
 
     # ------------------------------------------------------------------ intro
+
+    @property
+    def large_object_tier(self) -> str:
+        """Where large numpy payloads live: "native_arena" (the C++
+        shared-memory arena, cfg.native_store) or "python" (host tier)."""
+        return "native_arena" if self._arena is not None else "python"
 
     def usage(self) -> Dict[str, int]:
         with self._lock:

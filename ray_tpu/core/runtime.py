@@ -1231,6 +1231,11 @@ class Runtime:
             actors = list(self._actors.values())
         for rt in actors:
             rt.kill(no_restart=True, reason="runtime shutdown")
+        # a dead runtime can outlive shutdown (a stray handle, a gauge
+        # callback): it must not pin its actors' constructor arguments,
+        # which for a model server are the weights on the device
+        with self._lock:
+            self._actors.clear()
         self.scheduler.shutdown()
         from .worker_pool import shutdown_worker_pool
 
@@ -1325,8 +1330,11 @@ def init_runtime(**kwargs) -> Runtime:
             # start, so spawned node agents can be armed with e.g.
             # kill_node injections before any task reaches them.
             from . import chaos
+            from .compile_cache import ensure_compile_cache
 
             chaos.load_from_env()
+            # before the first compile of this process or its children
+            ensure_compile_cache()
             _global_runtime = Runtime(**kwargs)
         return _global_runtime
 

@@ -92,7 +92,9 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Params:
     res_std = std / math.sqrt(2 * c.n_layers)
 
     def normal(k, shape, s=std):
-        return (s * jax.random.normal(k, shape)).astype(pd)
+        # drawn in the parameter dtype: an f32 draw of one stacked bf16
+        # weight at 8B widths is a multi-GiB transient on a 16 GB chip
+        return s * jax.random.normal(k, shape, pd)
 
     L = c.n_layers
     blocks: Params = {

@@ -4,10 +4,12 @@ The reference framework delegates attention/normalization kernels to vLLM /
 torch CUDA kernels (e.g. /root/reference/python/ray/llm/_internal/serve/
 deployments/llm/vllm/vllm_engine.py:254). Here the hot ops are implemented
 TPU-first: Pallas kernels tiled for the MXU/VPU, with pure-XLA reference
-implementations used for correctness testing and as the CPU fallback.
+implementations that tests compare them with and that run off TPU.
 
 Dispatch convention: every op takes `implementation=` ("pallas" | "xla" |
-None). None auto-selects pallas on TPU backends, xla elsewhere.
+None). None is a static rule on backend and shape (attention.
+resolve_attention_impl, ragged_paged_attention.resolve_ragged_impl) — a
+kernel is never tried and swapped for the reference when it fails.
 """
 
 from .attention import flash_attention, mha_reference  # noqa: F401

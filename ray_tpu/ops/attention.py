@@ -24,14 +24,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pltpu imports fail on non-TPU builds only for exotic setups; guard anyway
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 _NEG_INF = -1e30  # finite "minus infinity": keeps exp() at exactly 0.0 without NaNs
 _LOG2E = 1.4426950408889634  # kernels fold log2(e) into sm_scale and use
@@ -198,6 +192,7 @@ def _fwd_pallas(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret)
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return out, lse
 
@@ -354,6 +349,7 @@ def _bwd_pallas(q, k, v, out, lse, do, causal, sm_scale, block_q, block_kv, kv_l
             pltpu.VMEM((block_kv, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
 
     q_spec2 = pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, i, j: (b_, h_, i, 0))
@@ -372,6 +368,7 @@ def _bwd_pallas(q, k, v, out, lse, do, causal, sm_scale, block_q, block_kv, kv_l
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -414,15 +411,15 @@ def _flash_bwd(causal, sm_scale, block_q, block_kv, kv_len, interpret, res, do):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-# ---------------------------------------------------------- pipelined kernels
+# ------------------------------------------------- pipelined forward kernel
 #
-# The classic kernels above run, per (q, kv) tile: QK^T (MXU) -> online
+# The classic forward above runs, per (q, kv) tile: QK^T (MXU) -> online
 # softmax (VPU) -> PV (MXU) — a serial dependency chain that parks the MXU
-# through the whole softmax (PERF_NOTES.md: 5-6x off roofline at D=64).
-# The pipelined variants break the chain with a one-step software skew over
-# the kv-tile loop: inner step t issues tile t's QK^T while the online
-# softmax/rescale for tile t-1 runs, so the two stages have no data
-# dependency inside one step and Mosaic can overlap the MXU and VPU chains.
+# through the whole softmax. The pipelined forward breaks the chain with a
+# one-step software skew over the kv-tile loop: inner step t issues tile
+# t's QK^T while the online softmax/rescale for tile t-1 runs, so the two
+# stages have no data dependency inside one step and Mosaic can overlap
+# the MXU and VPU chains.
 #
 # On TPU the kv tiles stream HBM->VMEM through pltpu.emit_pipeline (explicit
 # double buffering; q and the accumulators stay VMEM-resident across the
@@ -432,6 +429,14 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 # both drivers are identical by construction, and bit-identical to the
 # classic kernel: tile math and accumulation order are unchanged, only the
 # schedule moves. tests/test_ops.py pins that equality at f32.
+#
+# What the v5e compiler accepts (tests/test_tpu_compile.py): the streamed
+# (1, 1, block_kv, D) tile must be lane-aligned, so D % 128 == 0 — at
+# D = 64 Mosaic refuses the slice and `resolve_attention_impl` picks the
+# classic kernel. There is no pipelined backward: its streamed
+# (block_q, 1) lse/delta tiles were refused at every width, so the
+# pipelined forward's residuals (same out/lse, bit for bit) feed the
+# classic dkv/dq kernels.
 
 
 def _fwd_stages(sm_scale, causal, block_q, block_kv, kv_len):
@@ -468,39 +473,6 @@ def _fwd_stages(sm_scale, causal, block_q, block_kv, kv_len):
         l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
     return scores, online_update
-
-
-def _bwd_stages(sm_scale, causal, block_q, block_kv, kv_len):
-    """Per-tile backward stages; expressions mirror _dkv_kernel/_dq_kernel."""
-    scores, _ = _fwd_stages(sm_scale, causal, block_q, block_kv, kv_len)
-
-    def dkv_update(s, q, do, v, lse, delta, dk_scr, dv_scr):
-        p = jnp.exp2(s - lse * _LOG2E)
-        dv_scr[...] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta) * sm_scale
-        dk_scr[...] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    def dq_update(s, k, v, do, lse, delta, dq_scr):
-        p = jnp.exp2(s - lse * _LOG2E)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta) * sm_scale
-        dq_scr[...] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    return scores, dkv_update, dq_update
 
 
 def _num_kv_tiles(i, causal, block_q, block_kv, nk):
@@ -587,6 +559,7 @@ def _fwd_pipe_interp(q, k, v, causal, sm_scale, block_q, block_kv, kv_len):
             pltpu.VMEM((2, block_q, block_kv), jnp.float32),
         ],
         interpret=True,
+        name="flash_fwd_pipelined",
     )(q, k, v)
 
 
@@ -648,8 +621,8 @@ def _fwd_pipe_tpu(q, k, v, causal, sm_scale, block_q, block_kv, kv_len):
         grid=(b, hq, nq),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda b_, h, i: (b_, h, i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda b_, h, i: (b_, h, i, 0)),
@@ -665,6 +638,7 @@ def _fwd_pipe_tpu(q, k, v, causal, sm_scale, block_q, block_kv, kv_len):
             pltpu.VMEM((block_q, d), jnp.float32),
             pltpu.VMEM((2, block_q, block_kv), jnp.float32),
         ],
+        name="flash_fwd_pipelined",
     )(q, k, v)
 
 
@@ -672,288 +646,6 @@ def _fwd_pipe(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret):
     if interpret:
         return _fwd_pipe_interp(q, k, v, causal, sm_scale, block_q, block_kv, kv_len)
     return _fwd_pipe_tpu(q, k, v, causal, sm_scale, block_q, block_kv, kv_len)
-
-
-def _dkv_kernel_pipe_interp(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-    dk_scr, dv_scr, s_scr,
-    *, sm_scale, causal, block_q, block_kv, kv_len, num_q_blocks,
-):
-    j = pl.program_id(2)
-    scores, dkv_update, _ = _bwd_stages(sm_scale, causal, block_q, block_kv, kv_len)
-    dk_scr[...] = jnp.zeros_like(dk_scr)
-    dv_scr[...] = jnp.zeros_like(dv_scr)
-    k_blk = k_ref[0, 0]
-    v_blk = v_ref[0, 0]
-    # causal: q blocks strictly above the diagonal band contribute nothing
-    t_start = (j * block_kv) // block_q if causal else 0
-    n_tiles = num_q_blocks - t_start
-
-    def body(u, carry):
-        t = t_start + u
-
-        @pl.when(u < n_tiles)
-        def _stage_a():
-            qt = q_ref[0, 0, pl.ds(t * block_q, block_q), :]
-            s_scr[u % 2] = scores(qt, k_blk, t, j)
-
-        @pl.when(u > 0)
-        def _stage_b():
-            tp = t - 1
-            sl = pl.ds(tp * block_q, block_q)
-            dkv_update(
-                s_scr[(u - 1) % 2], q_ref[0, 0, sl, :], do_ref[0, 0, sl, :],
-                v_blk, lse_ref[0, 0, sl, :], delta_ref[0, 0, sl, :],
-                dk_scr, dv_scr,
-            )
-
-        return carry
-
-    jax.lax.fori_loop(0, n_tiles + 1, body, 0)
-    dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
-    dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
-
-
-def _dq_kernel_pipe_interp(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr, s_scr,
-    *, sm_scale, causal, block_q, block_kv, kv_len, num_kv_blocks,
-):
-    i = pl.program_id(2)
-    scores, _, dq_update = _bwd_stages(sm_scale, causal, block_q, block_kv, kv_len)
-    dq_scr[...] = jnp.zeros_like(dq_scr)
-    q_blk = q_ref[0, 0]
-    do_blk = do_ref[0, 0]
-    lse_blk = lse_ref[0, 0]
-    delta_blk = delta_ref[0, 0]
-    tiles = _num_kv_tiles(i, causal, block_q, block_kv, num_kv_blocks)
-
-    def body(t, carry):
-        @pl.when(t < tiles)
-        def _stage_a():
-            kt = k_ref[0, 0, pl.ds(t * block_kv, block_kv), :]
-            s_scr[t % 2] = scores(q_blk, kt, i, t)
-
-        @pl.when(t > 0)
-        def _stage_b():
-            sl = pl.ds((t - 1) * block_kv, block_kv)
-            dq_update(
-                s_scr[(t - 1) % 2], k_ref[0, 0, sl, :], v_ref[0, 0, sl, :],
-                do_blk, lse_blk, delta_blk, dq_scr,
-            )
-
-        return carry
-
-    jax.lax.fori_loop(0, tiles + 1, body, 0)
-    dq_ref[0, 0] = dq_scr[...].astype(dq_ref.dtype)
-
-
-def _bwd_pipe_interp(q, k, v, out, lse, do, causal, sm_scale, block_q, block_kv, kv_len):
-    b, h, sq, d = q.shape
-    skv = k.shape[2]
-    nq = sq // block_q
-    nk = skv // block_kv
-    delta = jnp.sum(
-        do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1, keepdims=True
-    )
-
-    full_q = pl.BlockSpec((1, 1, sq, d), lambda b_, h_, g: (b_, h_, 0, 0))
-    full_row = pl.BlockSpec((1, 1, sq, 1), lambda b_, h_, g: (b_, h_, 0, 0))
-    kv_blk = pl.BlockSpec((1, 1, block_kv, d), lambda b_, h_, j: (b_, h_, j, 0))
-
-    dkv_kernel = functools.partial(
-        _dkv_kernel_pipe_interp, sm_scale=sm_scale, causal=causal,
-        block_q=block_q, block_kv=block_kv, kv_len=kv_len, num_q_blocks=nq,
-    )
-    dk, dv = pl.pallas_call(
-        dkv_kernel,
-        grid=(b, h, nk),
-        in_specs=[full_q, kv_blk, kv_blk, full_q, full_row, full_row],
-        out_specs=[kv_blk, kv_blk],
-        out_shape=[
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_kv, d), jnp.float32),
-            pltpu.VMEM((block_kv, d), jnp.float32),
-            pltpu.VMEM((2, block_q, block_kv), jnp.float32),
-        ],
-        interpret=True,
-    )(q, k, v, do, lse, delta)
-
-    q_blk = pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, i: (b_, h_, i, 0))
-    row_blk = pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, i: (b_, h_, i, 0))
-    full_kv = pl.BlockSpec((1, 1, skv, d), lambda b_, h_, i: (b_, h_, 0, 0))
-
-    dq_kernel = functools.partial(
-        _dq_kernel_pipe_interp, sm_scale=sm_scale, causal=causal,
-        block_q=block_q, block_kv=block_kv, kv_len=kv_len, num_kv_blocks=nk,
-    )
-    dq = pl.pallas_call(
-        dq_kernel,
-        grid=(b, h, nq),
-        in_specs=[q_blk, full_kv, full_kv, q_blk, row_blk, row_blk],
-        out_specs=q_blk,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((2, block_q, block_kv), jnp.float32),
-        ],
-        interpret=True,
-    )(q, k, v, do, lse, delta)
-    return dq, dk, dv
-
-
-def _bwd_pipe_tpu(q, k, v, out, lse, do, causal, sm_scale, block_q, block_kv, kv_len):
-    b, h, sq, d = q.shape
-    skv = k.shape[2]
-    nq = sq // block_q
-    nk = skv // block_kv
-    delta = jnp.sum(
-        do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1, keepdims=True
-    )
-
-    def dkv_outer(q_hbm, k_ref, v_ref, do_hbm, lse_hbm, delta_hbm,
-                  dk_ref, dv_ref, dk_scr, dv_scr, s_scr):
-        bi = pl.program_id(0)
-        hi = pl.program_id(1)
-        j = pl.program_id(2)
-        scores, dkv_update, _ = _bwd_stages(sm_scale, causal, block_q, block_kv, kv_len)
-        dk_scr[...] = jnp.zeros_like(dk_scr)
-        dv_scr[...] = jnp.zeros_like(dv_scr)
-        k_blk = k_ref[0, 0]
-        v_blk = v_ref[0, 0]
-        t_start = (j * block_kv) // block_q if causal else 0
-        n_tiles = nq - t_start
-
-        def inner(qa_ref, qb_ref, do_ref, lse_ref, delta_ref):
-            u = pl.program_id(0)
-            t = t_start + u
-
-            @pl.when(u < n_tiles)
-            def _stage_a():
-                s_scr[u % 2] = scores(qa_ref[0, 0], k_blk, t, j)
-
-            @pl.when(u > 0)
-            def _stage_b():
-                dkv_update(
-                    s_scr[(u - 1) % 2], qb_ref[0, 0], do_ref[0, 0], v_blk,
-                    lse_ref[0, 0], delta_ref[0, 0], dk_scr, dv_scr,
-                )
-
-        # q streams twice at different offsets: once for the t-tile QK^T,
-        # once (a step behind) for the t-1 dk accumulation
-        idx_a = lambda u: (bi, hi, jnp.minimum(t_start + u, nq - 1), 0)
-        idx_b = lambda u: (bi, hi, jnp.minimum(t_start + jnp.maximum(u - 1, 0), nq - 1), 0)
-        pipeline = pltpu.emit_pipeline(
-            inner,
-            grid=(n_tiles + 1,),
-            in_specs=[
-                pl.BlockSpec((1, 1, block_q, d), idx_a),
-                pl.BlockSpec((1, 1, block_q, d), idx_b),
-                pl.BlockSpec((1, 1, block_q, d), idx_b),
-                pl.BlockSpec((1, 1, block_q, 1), idx_b),
-                pl.BlockSpec((1, 1, block_q, 1), idx_b),
-            ],
-            out_specs=[],
-        )
-        pipeline(q_hbm, q_hbm, do_hbm, lse_hbm, delta_hbm)
-        dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
-
-    kv_blk = pl.BlockSpec((1, 1, block_kv, d), lambda b_, h_, j: (b_, h_, j, 0))
-    dk, dv = pl.pallas_call(
-        dkv_outer,
-        grid=(b, h, nk),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY), kv_blk, kv_blk,
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
-        out_specs=[kv_blk, kv_blk],
-        out_shape=[
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_kv, d), jnp.float32),
-            pltpu.VMEM((block_kv, d), jnp.float32),
-            pltpu.VMEM((2, block_q, block_kv), jnp.float32),
-        ],
-    )(q, k, v, do, lse, delta)
-
-    def dq_outer(q_ref, k_hbm, v_hbm, do_ref, lse_ref, delta_ref,
-                 dq_ref, dq_scr, s_scr):
-        bi = pl.program_id(0)
-        hi = pl.program_id(1)
-        i = pl.program_id(2)
-        scores, _, dq_update = _bwd_stages(sm_scale, causal, block_q, block_kv, kv_len)
-        dq_scr[...] = jnp.zeros_like(dq_scr)
-        q_blk = q_ref[0, 0]
-        do_blk = do_ref[0, 0]
-        lse_blk = lse_ref[0, 0]
-        delta_blk = delta_ref[0, 0]
-        tiles = _num_kv_tiles(i, causal, block_q, block_kv, nk)
-
-        def inner(ka_ref, kb_ref, vb_ref):
-            t = pl.program_id(0)
-
-            @pl.when(t < tiles)
-            def _stage_a():
-                s_scr[t % 2] = scores(q_blk, ka_ref[0, 0], i, t)
-
-            @pl.when(t > 0)
-            def _stage_b():
-                dq_update(
-                    s_scr[(t - 1) % 2], kb_ref[0, 0], vb_ref[0, 0],
-                    do_blk, lse_blk, delta_blk, dq_scr,
-                )
-
-        idx_a = lambda t: (bi, hi, jnp.minimum(t, nk - 1), 0)
-        idx_b = lambda t: (bi, hi, jnp.maximum(t - 1, 0), 0)
-        pipeline = pltpu.emit_pipeline(
-            inner,
-            grid=(tiles + 1,),
-            in_specs=[
-                pl.BlockSpec((1, 1, block_kv, d), idx_a),
-                pl.BlockSpec((1, 1, block_kv, d), idx_b),
-                pl.BlockSpec((1, 1, block_kv, d), idx_b),
-            ],
-            out_specs=[],
-        )
-        pipeline(k_hbm, k_hbm, v_hbm)
-        dq_ref[0, 0] = dq_scr[...].astype(dq_ref.dtype)
-
-    q_blk2 = pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, i: (b_, h_, i, 0))
-    row_blk2 = pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, i: (b_, h_, i, 0))
-    dq = pl.pallas_call(
-        dq_outer,
-        grid=(b, h, nq),
-        in_specs=[
-            q_blk2,
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            q_blk2, row_blk2, row_blk2,
-        ],
-        out_specs=q_blk2,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((2, block_q, block_kv), jnp.float32),
-        ],
-    )(q, k, v, do, lse, delta)
-    return dq, dk, dv
-
-
-def _bwd_pipe(q, k, v, out, lse, do, causal, sm_scale, block_q, block_kv, kv_len, interpret):
-    if interpret:
-        return _bwd_pipe_interp(
-            q, k, v, out, lse, do, causal, sm_scale, block_q, block_kv, kv_len
-        )
-    return _bwd_pipe_tpu(
-        q, k, v, out, lse, do, causal, sm_scale, block_q, block_kv, kv_len
-    )
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
@@ -967,48 +659,14 @@ def _flash_pipelined_fwd(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, i
     return out, (q, k, v, out, lse)
 
 
-def _flash_pipelined_bwd(causal, sm_scale, block_q, block_kv, kv_len, interpret, res, do):
-    q, k, v, out, lse = res
-    hq, hkv = q.shape[1], k.shape[1]
-    if hq != hkv:
-        groups = hq // hkv
-        k_full = jnp.repeat(k, groups, axis=1)
-        v_full = jnp.repeat(v, groups, axis=1)
-    else:
-        groups = 1
-        k_full, v_full = k, v
-    dq, dk, dv = _bwd_pipe(
-        q, k_full, v_full, out, lse, do, causal, sm_scale, block_q, block_kv,
-        kv_len, interpret,
-    )
-    if groups > 1:
-        b, _, skv, d = dk.shape
-        dk = dk.reshape(b, hkv, groups, skv, d).sum(axis=2)
-        dv = dv.reshape(b, hkv, groups, skv, d).sum(axis=2)
-    return dq, dk, dv
-
-
-_flash_pipelined.defvjp(_flash_pipelined_fwd, _flash_pipelined_bwd)
+_flash_pipelined.defvjp(_flash_pipelined_fwd, _flash_bwd)
 
 
 # ------------------------------------------------------------------ public API
 
 
 _PIPE_BLOCK_KV = 256  # stream tile: >=2 tiles in flight is what buys overlap
-
-
-def _pipeline_enabled() -> bool:
-    from ..core.config import cfg
-
-    return bool(cfg.attn_pipeline)
-
-
-def _resolve_impl(implementation: Optional[str]) -> str:
-    if implementation is not None:
-        return implementation
-    if jax.default_backend() != "tpu":
-        return "xla"
-    return "pallas_pipelined" if _pipeline_enabled() else "pallas"
+_IMPLEMENTATIONS = ("xla", "pallas", "pallas_pipelined")
 
 
 def _pipe_blocks(sq: int, skv: int, block_q: Optional[int], block_kv: Optional[int]):
@@ -1021,6 +679,80 @@ def _pipe_blocks(sq: int, skv: int, block_q: Optional[int], block_kv: Optional[i
     if padded_skv // bkv < 2:
         return None
     return bq, bkv
+
+
+def _blocks(implementation: str, sq: int, skv: int,
+            block_q: Optional[int], block_kv: Optional[int]):
+    """(block_q, block_kv) of a resolved Pallas implementation."""
+    if implementation == "pallas_pipelined":
+        return _pipe_blocks(sq, skv, block_q, block_kv)
+    return min(block_q or 1024, max(sq, 1)), min(block_kv or 1024, max(skv, 1))
+
+
+def resolve_attention_impl(
+    head_dim: int,
+    sq: int,
+    skv: int,
+    *,
+    implementation: Optional[str] = None,
+    block_q: Optional[int] = None,
+    block_kv: Optional[int] = None,
+) -> str:
+    """The implementation `flash_attention` runs for a shape: "xla",
+    "pallas" (classic kernel) or "pallas_pipelined". Every choice is a
+    static rule on the backend and the shape — a kernel is never tried and
+    swapped for another when it fails:
+
+    - nothing requested, backend not "tpu": "xla". Off-TPU the Pallas
+      kernels only run through the interpreter, for callers that ask.
+    - nothing requested, backend "tpu": "pallas_pipelined" when
+      `cfg.attn_pipeline` is set, else "pallas".
+    - "pallas_pipelined" (requested or chosen) becomes "pallas" when the
+      shape leaves < 2 kv tiles (nothing to overlap), and, compiled for a
+      TPU, when head_dim % 128 != 0: emit_pipeline's HBM->VMEM kv tile
+      must be lane-aligned and Mosaic refuses a 64-wide slice.
+
+    Callers that report what ran (chip_smoke.py, bench.py) print this.
+    """
+    if implementation is not None and implementation not in _IMPLEMENTATIONS:
+        raise ValueError(f"unknown attention implementation: {implementation!r}")
+    on_tpu = jax.default_backend() == "tpu"
+    if implementation is None:
+        if not on_tpu:
+            return "xla"
+        from ..core.config import cfg
+
+        implementation = "pallas_pipelined" if cfg.attn_pipeline else "pallas"
+    if implementation == "pallas_pipelined" and (
+        (on_tpu and head_dim % 128)
+        or _pipe_blocks(sq, skv, block_q, block_kv) is None
+    ):
+        return "pallas"
+    return implementation
+
+
+def _per_shard(kernel_fn):
+    """GSPMD cannot partition a Mosaic call ("wrap the call in a
+    shard_map"), so a Pallas kernel traced under a context mesh
+    (make_train_step traces its step inside `use_abstract_mesh`) runs
+    once per shard: batch over the data axes, heads over tp — every
+    shard is a whole (S, D) attention problem, no collective needed.
+    With no context mesh, one device, or inside somebody else's
+    shard_map (ring, Ulysses, pipeline, explicit-dp: the axes are already
+    manual there) the kernel is called as it is. Axis names are
+    parallel.mesh's (DATA_AXES, "tp")."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.manual_axes:
+        return kernel_fn
+    batch = tuple(a for a in ("dp", "fsdp") if mesh.shape.get(a, 1) > 1)
+    heads = "tp" if mesh.shape.get("tp", 1) > 1 else None
+    if not batch and heads is None:
+        return kernel_fn
+    spec = P(batch or None, heads, None, None)
+    return jax.shard_map(
+        kernel_fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False,
+    )
 
 
 def _pad_seq(x: jax.Array, axis: int, multiple: int) -> jax.Array:
@@ -1047,10 +779,9 @@ def flash_attention(
     """Blockwise flash attention. q (B,Hq,Sq,D); k,v (B,Hkv,Skv,D).
 
     implementation: "pallas_pipelined" (double-buffered emit_pipeline
-    kernel; skewed-schedule interpret driver off-TPU), "pallas" (classic
-    kernel; interpreted off-TPU), "xla" (reference), or None = auto: on TPU
-    backends the pipelined kernel when `cfg.attn_pipeline` is set and the
-    shape gives >=2 kv tiles, else the classic kernel; xla otherwise.
+    forward + classic backward; skewed-schedule interpret driver off-TPU),
+    "pallas" (classic kernel; interpreted off-TPU), "xla" (reference), or
+    None = the static rule of `resolve_attention_impl`.
 
     Block defaults: classic kernel 1024x1024 (clamped to the sequence) —
     at head_dim 64-128 it is grid-overhead-bound and big tiles measured
@@ -1059,42 +790,25 @@ def flash_attention(
     kv tiles cost no revisit overhead, and >=4 tiles in flight is what
     lets the next tile's QK^T overlap the current tile's softmax.
     """
-    implementation = _resolve_impl(implementation)
+    sq, skv = q.shape[2], k.shape[2]
+    implementation = resolve_attention_impl(
+        q.shape[-1], sq, skv, implementation=implementation,
+        block_q=block_q, block_kv=block_kv,
+    )
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     if implementation == "xla":
         return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale)
-    if implementation not in ("pallas", "pallas_pipelined"):
-        raise ValueError(f"unknown attention implementation: {implementation!r}")
-    if not _HAS_PLTPU:  # pragma: no cover
-        return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale)
-
-    sq, skv = q.shape[2], k.shape[2]
     if causal and sq != skv:
         raise NotImplementedError("causal flash kernel requires Sq == Skv")
     interpret = jax.default_backend() != "tpu"
-
-    if implementation == "pallas_pipelined":
-        blocks = _pipe_blocks(sq, skv, block_q, block_kv)
-        if blocks is not None:
-            bq, bkv = blocks
-            qp = _pad_seq(q, 2, bq)
-            kp = _pad_seq(k, 2, bkv)
-            vp = _pad_seq(v, 2, bkv)
-            out = _flash_pipelined(
-                qp, kp, vp, causal, sm_scale, bq, bkv, skv, interpret
-            )
-            if out.shape[2] != sq:
-                out = out[:, :, :sq]
-            return out
-        # single kv tile: fall through to the classic kernel
-
-    block_q = min(block_q or 1024, max(sq, 1))
-    block_kv = min(block_kv or 1024, max(skv, 1))
-    qp = _pad_seq(q, 2, block_q)
-    kp = _pad_seq(k, 2, block_kv)
-    vp = _pad_seq(v, 2, block_kv)
-    out = _flash(qp, kp, vp, causal, sm_scale, block_q, block_kv, skv, interpret)
+    kernel = _flash_pipelined if implementation == "pallas_pipelined" else _flash
+    bq, bkv = _blocks(implementation, sq, skv, block_q, block_kv)
+    out = _per_shard(
+        lambda q_, k_, v_: kernel(
+            q_, k_, v_, causal, sm_scale, bq, bkv, skv, interpret
+        )
+    )(_pad_seq(q, 2, bq), _pad_seq(k, 2, bkv), _pad_seq(v, 2, bkv))
     if out.shape[2] != sq:
         out = out[:, :, :sq]
     return out
@@ -1118,12 +832,18 @@ def flash_attention_with_lse(
     FORWARD ONLY: no VJP is registered through the lse output; callers
     that need gradients wrap their own (ring_attention's custom_vjp
     recomputes through the einsum reference)."""
-    implementation = _resolve_impl(implementation)
+    sq, skv = q.shape[2], k.shape[2]
+    implementation = resolve_attention_impl(
+        q.shape[-1], sq, skv, implementation=implementation,
+        block_q=block_q, block_kv=block_kv,
+    )
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
-    if implementation == "xla" or not _HAS_PLTPU:
-        _, hq, sq, _ = q.shape
-        _, hkv, skv, _ = k.shape
+    if causal and sq != skv:
+        raise NotImplementedError("causal requires Sq == Skv")
+    if implementation == "xla":
+        _, hq, _, _ = q.shape
+        hkv = k.shape[1]
         if hq != hkv:
             groups = hq // hkv
             k = jnp.repeat(k, groups, axis=1)
@@ -1132,8 +852,6 @@ def flash_attention_with_lse(
             "bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32
         ) * sm_scale
         if causal:
-            if sq != skv:
-                raise NotImplementedError("causal requires Sq == Skv")
             row = jnp.arange(sq)[:, None]
             col = jnp.arange(skv)[None, :]
             s = jnp.where(col <= row, s, _NEG_INF)
@@ -1142,32 +860,12 @@ def flash_attention_with_lse(
         l = jnp.sum(p, axis=-1, keepdims=True)
         out = jnp.einsum("bhqk,bhkd->bhqd", p / l, v.astype(p.dtype))
         return out.astype(q.dtype), m + jnp.log(l)
-    sq, skv = q.shape[2], k.shape[2]
-    if causal and sq != skv:
-        raise NotImplementedError("causal flash kernel requires Sq == Skv")
     interpret = jax.default_backend() != "tpu"
-    if implementation == "pallas_pipelined":
-        blocks = _pipe_blocks(sq, skv, block_q, block_kv)
-        if blocks is not None:
-            bq, bkv = blocks
-            qp = _pad_seq(q, 2, bq)
-            kp = _pad_seq(k, 2, bkv)
-            vp = _pad_seq(v, 2, bkv)
-            out, lse = _fwd_pipe(
-                qp, kp, vp, causal, sm_scale, bq, bkv, skv, interpret
-            )
-            if out.shape[2] != sq:
-                out = out[:, :, :sq]
-                lse = lse[:, :, :sq]
-            return out, lse
-        # single kv tile: fall through to the classic kernel
-    block_q = min(block_q or 1024, max(sq, 1))
-    block_kv = min(block_kv or 1024, max(skv, 1))
-    qp = _pad_seq(q, 2, block_q)
-    kp = _pad_seq(k, 2, block_kv)
-    vp = _pad_seq(v, 2, block_kv)
-    out, lse = _fwd_pallas(
-        qp, kp, vp, causal, sm_scale, block_q, block_kv, skv, interpret
+    fwd = _fwd_pipe if implementation == "pallas_pipelined" else _fwd_pallas
+    bq, bkv = _blocks(implementation, sq, skv, block_q, block_kv)
+    out, lse = fwd(
+        _pad_seq(q, 2, bq), _pad_seq(k, 2, bkv), _pad_seq(v, 2, bkv),
+        causal, sm_scale, bq, bkv, skv, interpret,
     )
     if out.shape[2] != sq:
         out = out[:, :, :sq]

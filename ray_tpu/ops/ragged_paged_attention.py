@@ -26,11 +26,13 @@ Layout:
   position + 1) are the same descriptor.
 
 Numerics contract: the kernel uses plain exp (NOT the exp2 trick the dense
-flash kernel uses) and the caller pre-scales q, so the XLA fallback
+flash kernel uses) and the caller pre-scales q, so the XLA reference
 `ragged_reference_attention` — a gather over block tables that replays the
 kernel's block schedule op for op — is bit-exact vs the kernel at f32.
-Off-TPU the engine runs the reference; the interpret driver exists so CI
-can replay the exact kernel schedule without hardware.
+Which of the two runs is a static rule (`resolve_ragged_impl`): the kernel
+on a TPU backend at Mosaic-tileable shapes, the reference elsewhere; the
+interpret driver exists so CI can replay the exact kernel schedule without
+hardware.
 """
 
 from __future__ import annotations
@@ -41,15 +43,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.experimental import pallas as pl
-
-try:  # pltpu imports can fail on exotic non-TPU builds; guard anyway
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 _NEG_INF = -1e30  # finite "minus infinity": exp() lands at exactly 0.0
 
@@ -186,6 +183,7 @@ def _ragged_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((hq, t, d), q.dtype),
         interpret=interpret,
+        name="ragged_paged_attention",
     )(starts, counts, q_lens, kv_lens, tables, q, k_pages, v_pages)
 
 
@@ -196,7 +194,7 @@ def ragged_reference_attention(
     q, k_pages, v_pages, starts, counts, q_lens, kv_lens, tables,
     *, block_q: int, max_q_blocks: int,
 ):
-    """Gather-based XLA fallback that REPLAYS the kernel's block schedule.
+    """Gather-based XLA reference that REPLAYS the kernel's block schedule.
 
     Pages are gathered through the block tables exactly as the kernel's
     index maps fetch them, and the online-softmax update runs per kv block
@@ -291,6 +289,51 @@ def ragged_reference_attention(
 
 # ----------------------------------------------------------------- dispatch
 
+RAGGED_KERNEL = "ragged_pallas"
+RAGGED_REFERENCE = "gather_reference"
+
+
+def resolve_ragged_impl(
+    head_dim: int,
+    page_size: int,
+    block_q: int,
+    *,
+    use_kernel: Optional[bool] = None,
+    interpret: bool = False,
+) -> str:
+    """Which implementation `ragged_paged_attention` runs — RAGGED_KERNEL
+    or RAGGED_REFERENCE — as a static rule, so callers (the engine's
+    `stats()`, chip_smoke.py) can name it before anything is compiled:
+
+    - `interpret`: the kernel, through the Pallas interpreter (tests);
+    - `use_kernel=False`: the reference (the oracle the kernel is
+      compared with, and GSPMD-partitionable without shard_map);
+    - otherwise the kernel iff the backend is "tpu" and the shape obeys
+      Mosaic's (8, 128) rule on the kernel's (block_q, D) and
+      (page_size, D) tiles (GPT-2's 64-wide heads and tiny test configs
+      do not).
+      `use_kernel=True` at a shape or backend that cannot run it is an
+      error, not a quiet switch to the reference.
+    """
+    if interpret:
+        return RAGGED_KERNEL
+    if use_kernel is False:
+        return RAGGED_REFERENCE
+    can_run = (
+        jax.default_backend() == "tpu"
+        and head_dim % 128 == 0
+        and page_size % 8 == 0
+        and block_q % 8 == 0
+    )
+    if use_kernel and not can_run:
+        raise ValueError(
+            f"ragged Pallas kernel requested but cannot run here: backend "
+            f"{jax.default_backend()!r}, head_dim {head_dim}, page_size "
+            f"{page_size}, block_q {block_q} (needs a tpu backend, "
+            "head_dim % 128 == 0, page_size % 8 == 0, block_q % 8 == 0)"
+        )
+    return RAGGED_KERNEL if can_run else RAGGED_REFERENCE
+
 
 def ragged_paged_attention(
     q: jax.Array,           # (Hq, T, D) token-major, per-seq block regions
@@ -321,10 +364,8 @@ def ragged_paged_attention(
     paged history). Nothing kernel-side distinguishes a verify region
     from a short prefill chunk — speculation rides the existing grid.
 
-    Dispatch: Pallas kernel on TPU when the Mosaic tiling rules hold
-    (D % 128 == 0, page_size % 8 == 0, block_q % 8 == 0); the
-    schedule-replaying gather reference otherwise. `interpret=True` forces
-    the kernel through the Pallas interpreter (CI parity drills).
+    Dispatch: the static rule of `resolve_ragged_impl`. `interpret=True`
+    forces the kernel through the Pallas interpreter (CI parity drills).
     Under a tensor-parallel mesh the kernel path is wrapped in `shard_map`
     over the head axes — GSPMD cannot partition a pallas_call, but both
     Hq and Hkv divide by tp, so each shard runs the kernel on its local
@@ -345,45 +386,35 @@ def ragged_paged_attention(
         # tighter bound (the engine: chunk blocks) pass it to shrink the grid
         max_q_blocks = t // block_q
     q = (q.astype(jnp.float32) * sm_scale).astype(q.dtype)
-    if use_kernel is None:
-        use_kernel = (
-            _HAS_PLTPU
-            and jax.default_backend() == "tpu"
-            and d % 128 == 0
-            and ps % 8 == 0
-            and block_q % 8 == 0
-        )
-    if interpret and _HAS_PLTPU:
-        use_kernel = True
-    args = (starts, counts, q_lens, kv_lens, tables)
-    if use_kernel:
-        # nb: keep this local's name distinct from any method name in the
-        # repo — raylint's name-level reachability treats shard_map args
-        # as hot roots project-wide
-        ragged_kernel_fn = functools.partial(
-            _ragged_pallas,
-            block_q=block_q,
-            max_q_blocks=max_q_blocks,
-            interpret=interpret or jax.default_backend() != "tpu",
-        )
-        if mesh is not None and mesh.shape.get(tp_axis, 1) > 1:
-            from .._jax_compat import shard_map
-            from jax.sharding import PartitionSpec as P
-
-            ragged_kernel_fn = shard_map(
-                ragged_kernel_fn,
-                mesh=mesh,
-                in_specs=(
-                    P(tp_axis, None, None),        # q: shard heads
-                    P(tp_axis, None, None, None),  # k pages: shard kv heads
-                    P(tp_axis, None, None, None),  # v pages
-                    P(), P(), P(), P(), P(),       # descriptor: replicated
-                ),
-                out_specs=P(tp_axis, None, None),
-                check_rep=False,
-            )
-        return ragged_kernel_fn(q, k_pages, v_pages, *args)
-    return ragged_reference_attention(
-        q, k_pages, v_pages, *args,
-        block_q=block_q, max_q_blocks=max_q_blocks,
+    impl = resolve_ragged_impl(
+        d, ps, block_q, use_kernel=use_kernel, interpret=interpret
     )
+    args = (starts, counts, q_lens, kv_lens, tables)
+    if impl == RAGGED_REFERENCE:
+        return ragged_reference_attention(
+            q, k_pages, v_pages, *args,
+            block_q=block_q, max_q_blocks=max_q_blocks,
+        )
+    # nb: keep this local's name distinct from any method name in the
+    # repo — raylint's name-level reachability treats shard_map args
+    # as hot roots project-wide
+    ragged_kernel_fn = functools.partial(
+        _ragged_pallas,
+        block_q=block_q,
+        max_q_blocks=max_q_blocks,
+        interpret=interpret,
+    )
+    if mesh is not None and mesh.shape.get(tp_axis, 1) > 1:
+        ragged_kernel_fn = shard_map(
+            ragged_kernel_fn,
+            mesh=mesh,
+            in_specs=(
+                P(tp_axis, None, None),        # q: shard heads
+                P(tp_axis, None, None, None),  # k pages: shard kv heads
+                P(tp_axis, None, None, None),  # v pages
+                P(), P(), P(), P(), P(),       # descriptor: replicated
+            ),
+            out_specs=P(tp_axis, None, None),
+            check_vma=False,
+        )
+    return ragged_kernel_fn(q, k_pages, v_pages, *args)

@@ -26,10 +26,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
-
-from .._jax_compat import shard_map
 
 P = PartitionSpec
 
@@ -134,10 +132,8 @@ def _ring_fused_local(
     (m, l, acc). The diagonal block is the causal kernel; past blocks the
     full kernel; future blocks skip (Liu et al. causal skipping).
 
-    The kernel choice rides flash_attention_with_lse's auto-resolution:
-    with cfg.attn_pipeline set (default) each ring block runs the
-    double-buffered emit_pipeline kernel on TPU, so `ring_fused_speedup`
-    inherits the pipelined inner block without a separate code path."""
+    The kernel choice is flash_attention_with_lse's, i.e. the static
+    rule ops.attention.resolve_attention_impl: no separate code path."""
     from .attention import flash_attention_with_lse
 
     n = lax.psum(1, axis_name)
@@ -241,8 +237,8 @@ def ring_attention(
     impl: "fused" (default — per-block Pallas flash kernel on TPU, fused
     XLA reference elsewhere) or "einsum" (the original blockwise einsum
     body; also the backward path of "fused"). block_impl picks the flash
-    kernel inside each fused ring block (None = flash_attention's auto
-    resolution, i.e. the pipelined kernel when cfg.attn_pipeline is on)."""
+    kernel inside each fused ring block (None = the static rule
+    ops.attention.resolve_attention_impl)."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     hq, hkv = q.shape[1], k.shape[1]

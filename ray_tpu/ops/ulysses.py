@@ -23,10 +23,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
-
-from .._jax_compat import shard_map
 
 from .attention import flash_attention
 

@@ -26,10 +26,8 @@ from typing import Dict
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
-
-from .._jax_compat import shard_map
 
 P = PartitionSpec
 

@@ -41,7 +41,11 @@ import numpy as np
 
 from ...models.transformer import TransformerConfig, _norm
 from ...ops import apply_rope, rope_frequencies
-from ...ops.ragged_paged_attention import ragged_paged_attention
+from ...ops.ragged_paged_attention import (
+    RAGGED_KERNEL,
+    ragged_paged_attention,
+    resolve_ragged_impl,
+)
 
 Params = Dict[str, Any]
 
@@ -321,30 +325,24 @@ def paged_attention(q, k_cache, v_cache, block_tables, lengths, *, page_size: in
                     use_kernel: Optional[bool] = None, mesh=None,
                     interpret: bool = False):
     """Decode-step paged attention: the q_len == 1 case of the ragged
-    kernel. Dispatch: Pallas ragged kernel on TPU, gather reference
-    elsewhere.
-
-    The Mosaic lowering requires the trailing block dims be (8, 128)-
-    divisible, so the kernel is only eligible for head_dim % 128 == 0 and
-    page_size % 8 == 0 (e.g. Llama-class models); smaller shapes (tiny
-    test configs, GPT-2's 64-dim heads) take the gather reference, which
-    XLA fuses well at those sizes anyway.
+    kernel. Dispatch is `resolve_ragged_impl`'s static rule: the Pallas
+    ragged kernel on a TPU backend at Mosaic-tileable shapes
+    (head_dim % 128 == 0, page_size % 8 == 0 — Llama-class models), the
+    gather reference elsewhere (CPU, tiny test configs, GPT-2's 64-wide
+    heads).
 
     Tensor parallelism: the kernel path is `shard_map`-wrapped over the
     tp mesh axis inside `ragged_paged_attention` (GSPMD cannot partition
     a pallas_call, but both head axes divide by tp, so each shard runs
-    the kernel on its local head group) — use_kernel=False is no longer
-    forced under a mesh; pass `mesh` instead. The gather reference still
-    partitions cleanly on the kv-head axis under plain GSPMD."""
+    the kernel on its local head group); pass `mesh`. The gather
+    reference partitions cleanly on the kv-head axis under plain GSPMD."""
     b, hq, head_dim = q.shape
-    if use_kernel is None:
-        use_kernel = (
-            jax.default_backend() == "tpu"
-            and head_dim % 128 == 0
-            and page_size % 8 == 0
-        )
-    if use_kernel or interpret:
-        block_q = 8
+    block_q = 8
+    impl = resolve_ragged_impl(
+        head_dim, page_size, block_q, use_kernel=use_kernel,
+        interpret=interpret,
+    )
+    if impl == RAGGED_KERNEL:
         # adapt (B, Hq, D) single-token lanes to the ragged layout: one
         # block_q-row region per lane, real row 0, q_len 1
         q_r = jnp.swapaxes(q, 0, 1)[:, :, None, :]  # (Hq, B, 1, D)

@@ -23,10 +23,9 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import optax
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from .._jax_compat import shard_map
 from ..models.transformer import (
     TransformerConfig,
     forward,
@@ -415,8 +414,15 @@ def make_train_step(
         }
         return new_state, metrics
 
+    def step_under_mesh(state: TrainState, batch: Dict[str, jax.Array]):
+        # traced with the mesh as context: Pallas kernels inside the model
+        # (ops/attention._per_shard) shard_map themselves over it, which
+        # GSPMD cannot do for them
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return step_fn(state, batch)
+
     return jax.jit(
-        step_fn,
+        step_under_mesh,
         in_shardings=(state_shardings, {"tokens": batch_sharding}),
         out_shardings=(state_shardings, {k: metric_sharding for k in ("loss", "grad_norm", "num_tokens")}),
         donate_argnums=(0,),
@@ -582,7 +588,8 @@ def make_eval_step(config: TransformerConfig, mesh: Mesh, state_shardings: Any):
 
     def eval_fn(state: TrainState, batch):
         tokens = batch["tokens"]
-        logits = forward(state.params, tokens[:, :-1], config)
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):  # see make_train_step
+            logits = forward(state.params, tokens[:, :-1], config)
         loss, ntok = cross_entropy_loss(logits, tokens[:, 1:])
         return {"eval_loss": loss.astype(jnp.float32), "num_tokens": ntok}
 

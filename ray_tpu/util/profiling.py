@@ -493,50 +493,56 @@ class ProfileStore:
 
 # ----------------------------------------------------- cost model / roofline
 
-# Peak dense bf16 FLOPs/s and HBM bandwidth per chip generation. This is
-# the ONE table every MFU/roofline number in the repo prices against
-# (bench.py used to carry its own copy).
-_PEAK_FLOPS: Dict[str, float] = {
-    "TPU v4": 275e12,
-    "TPU v5 lite": 197e12,   # v5e
-    "TPU v5e": 197e12,
-    "TPU v5p": 459e12,
-    "TPU v6 lite": 918e12,   # v6e
-    "TPU v6e": 918e12,
+# Published peak dense bf16 FLOP/s and HBM bytes/s of one chip, keyed by the
+# `device_kind` JAX reports (Google Cloud TPU documentation, the "System
+# architecture" page of each generation; v5e: 197 TFLOP/s, 819 GB/s). This
+# is the ONE table every MFU/roofline number in the repo prices against. A
+# device that is not in it is an error, not a default: a utilization priced
+# against an invented peak is not a measurement. CPU tests that want the
+# arithmetic exercised add a nominal "cpu" row themselves (monkeypatch).
+DEVICE_PEAKS: Dict[str, Tuple[float, float]] = {
+    "TPU v4": (275e12, 1228e9),
+    "TPU v5 lite": (197e12, 819e9),   # v5e
+    "TPU v5e": (197e12, 819e9),
+    "TPU v5p": (459e12, 2765e9),
+    "TPU v6 lite": (918e12, 1640e9),  # v6e
+    "TPU v6e": (918e12, 1640e9),
 }
-_PEAK_HBM_BPS: Dict[str, float] = {
-    "TPU v4": 1228e9,
-    "TPU v5 lite": 819e9,
-    "TPU v5e": 819e9,
-    "TPU v5p": 2765e9,
-    "TPU v6 lite": 1640e9,
-    "TPU v6e": 1640e9,
-}
-# Unknown chips (and the CPU test backend) get nominal peaks so the
-# fractions stay defined; `estimated` flags them as not a hardware claim.
-_FALLBACK_PEAK_FLOPS = 1e12
-_FALLBACK_HBM_BPS = 100e9
+
+
+def device_labels() -> Dict[str, Any]:
+    """What every benchmark result line carries: the device as JAX
+    reports it. A number without these is not attributable to a chip."""
+    import jax
+
+    devices = jax.devices()
+    return {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
+    }
 
 
 def device_peaks(device: Any = None) -> Dict[str, Any]:
     """Peak FLOPs/s and HBM bandwidth of the attached (or given) device.
-    `estimated=True` marks the fallback used for unknown kinds/CPU."""
-    kind = "unknown"
-    if device is not None:
-        kind = getattr(device, "device_kind", "unknown")
-    else:
+    Raises ProfilingError for a device kind without published peaks."""
+    if device is None:
         jax = sys.modules.get("jax")
-        if jax is not None:
-            try:
-                kind = getattr(jax.devices()[0], "device_kind", "unknown")
-            except Exception:  # noqa: BLE001 - no backend: fall back
-                kind = "unknown"
-    known = kind in _PEAK_FLOPS
+        if jax is None:
+            raise ProfilingError("device_peaks needs jax imported or a device")
+        device = jax.devices()[0]
+    kind = getattr(device, "device_kind", "unknown")
+    if kind not in DEVICE_PEAKS:
+        raise ProfilingError(
+            f"no published peaks for device kind {kind!r} (known: "
+            f"{sorted(DEVICE_PEAKS)}); MFU and roofline shares are device "
+            "metrics and are not estimated for other backends"
+        )
+    peak_flops, peak_hbm_bps = DEVICE_PEAKS[kind]
     return {
         "device_kind": kind,
-        "peak_flops": _PEAK_FLOPS.get(kind, _FALLBACK_PEAK_FLOPS),
-        "peak_hbm_bps": _PEAK_HBM_BPS.get(kind, _FALLBACK_HBM_BPS),
-        "estimated": not known,
+        "peak_flops": peak_flops,
+        "peak_hbm_bps": peak_hbm_bps,
     }
 
 
@@ -555,7 +561,6 @@ class StepCost:
     n_devices: int
     peak_flops: float           # per device
     peak_hbm_bps: float         # per device
-    estimated_peaks: bool
 
     @property
     def total_flops(self) -> float:
@@ -571,15 +576,12 @@ class StepCost:
 
 
 def compiled_cost(compiled: Any) -> Tuple[float, float, Dict[str, float]]:
-    """Normalize `compiled.cost_analysis()` (a dict on new jax, a
-    one-element list of dicts on the pinned 0.4.x) into
+    """Normalize `compiled.cost_analysis()` (a dict) into
     (flops, bytes_accessed, raw_numeric_buckets)."""
     try:
         analysis = compiled.cost_analysis()
     except Exception as exc:  # noqa: BLE001 - typed boundary
         raise ProfilingError(f"cost_analysis failed: {exc!r}") from exc
-    if isinstance(analysis, (list, tuple)):
-        analysis = analysis[0] if analysis else {}
     if not isinstance(analysis, dict):
         raise ProfilingError(
             f"cost_analysis returned {type(analysis).__name__}, not a dict"
@@ -601,10 +603,10 @@ def step_cost(fn: Any, *args: Any, **kwargs: Any) -> StepCost:
     jitted callable (lowered+compiled here via the AOT path — one extra
     XLA compile, so callers cache the result) or an already-compiled
     object exposing `cost_analysis()`."""
-    jax = sys.modules.get("jax")
     if hasattr(fn, "cost_analysis"):
         compiled = fn
     elif hasattr(fn, "lower"):
+        device_peaks()  # no peaks for this backend: fail before the compile
         try:
             compiled = fn.lower(*args, **kwargs).compile()
         except Exception as exc:  # noqa: BLE001 - typed boundary
@@ -619,22 +621,17 @@ def step_cost(fn: Any, *args: Any, **kwargs: Any) -> StepCost:
         raise ProfilingError(
             "cost_analysis reported no flops/bytes for this program"
         )
-    # devices the program actually spans (pjit over a mesh): read the
-    # first input sharding's device set, falling back to single-device
+    # devices the program actually spans (pjit over a mesh): the first
+    # input sharding's device set, else the default device
+    import jax
+
     device = None
     n_devices = 1
-    if jax is not None:
-        try:
-            leaves = jax.tree_util.tree_leaves(compiled.input_shardings)
-            device_set = getattr(leaves[0], "device_set", None) if leaves else None
-            if device_set:
-                n_devices = len(device_set)
-                device = next(iter(device_set))
-            else:
-                device = jax.devices()[0]
-        except Exception:  # noqa: BLE001 - peaks fall back below
-            device = None
-            n_devices = 1
+    leaves = jax.tree_util.tree_leaves(compiled.input_shardings)
+    device_set = getattr(leaves[0], "device_set", None) if leaves else None
+    if device_set:
+        n_devices = len(device_set)
+        device = next(iter(device_set))
     peaks = device_peaks(device)
     return StepCost(
         flops=flops,
@@ -644,7 +641,6 @@ def step_cost(fn: Any, *args: Any, **kwargs: Any) -> StepCost:
         n_devices=n_devices,
         peak_flops=peaks["peak_flops"],
         peak_hbm_bps=peaks["peak_hbm_bps"],
-        estimated_peaks=peaks["estimated"],
     )
 
 
@@ -669,5 +665,4 @@ def roofline(cost: StepCost, step_time_s: float) -> Dict[str, Any]:
         "step_time_s": step_time_s,
         "n_devices": cost.n_devices,
         "device_kind": cost.device_kind,
-        "estimated_peaks": cost.estimated_peaks,
     }

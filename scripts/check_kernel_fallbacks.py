@@ -35,7 +35,8 @@ def main() -> int:
     flags = defined_flags(config.tree) if config else set()
     print(
         f"check_kernel_fallbacks: ok ({len(flags)} registered flags, "
-        f"all cfg reads resolve, pltpu kernels keep fallbacks)"
+        f"all cfg reads resolve, Pallas kernels keep a reference oracle "
+        f"and no run-time fallback to it)"
     )
     return 0
 

@@ -360,33 +360,37 @@ def _uses_pltpu(tree: ast.AST) -> bool:
     return False
 
 
-def _pltpu_import_guarded(tree: ast.AST) -> bool:
-    """The `from jax.experimental.pallas import tpu as pltpu` import must
-    sit inside a try/except ImportError (or be function-local)."""
+def _pltpu_import_guards(tree: ast.AST) -> List[int]:
+    """Lines of try blocks that wrap the `pltpu` import in an except
+    ImportError/Exception: the `_HAS_PLTPU = False` idiom that lets a
+    module carry on, on the reference, without its kernels."""
+    lines = []
     for node in ast.walk(tree):
-        if isinstance(node, ast.Try):
-            handled = any(
-                isinstance(h.type, ast.Name)
-                and h.type.id in ("ImportError", "Exception")
-                or isinstance(h.type, ast.Tuple)
-                for h in node.handlers
-            )
-            if not handled:
-                continue
-            for child in ast.walk(node):
-                if isinstance(child, ast.ImportFrom):
-                    mod = child.module or ""
-                    if mod.startswith("jax.experimental.pallas") and any(
-                        a.asname == "pltpu" or a.name == "tpu"
-                        for a in child.names
-                    ):
-                        return True
-    return False
+        if not isinstance(node, ast.Try):
+            continue
+        handled = any(
+            isinstance(h.type, ast.Name)
+            and h.type.id in ("ImportError", "Exception")
+            or isinstance(h.type, ast.Tuple)
+            for h in node.handlers
+        )
+        if not handled:
+            continue
+        for child in ast.walk(node):
+            if isinstance(child, ast.ImportFrom):
+                mod = child.module or ""
+                if mod.startswith("jax.experimental.pallas") and any(
+                    a.asname == "pltpu" or a.name == "tpu"
+                    for a in child.names
+                ):
+                    lines.append(node.lineno)
+    return lines
 
 
-def _has_fallback_path(tree: ast.AST) -> bool:
+def _has_reference_oracle(tree: ast.AST) -> bool:
     """A `*reference*` function (pure-XLA ground truth) or an
-    `interpret=` kwarg on some call (interpret-mode driver)."""
+    `interpret=` kwarg on some call (interpret-mode driver): what the
+    tests compare the kernel with."""
     for node in ast.walk(tree):
         if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
             if "reference" in node.name:
@@ -398,6 +402,24 @@ def _has_fallback_path(tree: ast.AST) -> bool:
         if isinstance(node, ast.arg) and node.arg == "interpret":
             return True
     return False
+
+
+def _reference_in_except(tree: ast.AST) -> List[int]:
+    """Lines of except handlers that call a `*reference*` function: the
+    `try: kernel / except: reference` swap that hides a kernel the chip's
+    compiler refused behind a run that still exits 0."""
+    lines = []
+    for node in ast.walk(tree):
+        if not isinstance(node, ast.ExceptHandler):
+            continue
+        for child in ast.walk(node):
+            if isinstance(child, ast.Call):
+                fn = child.func
+                name = getattr(fn, "id", None) or getattr(fn, "attr", "")
+                if "reference" in name:
+                    lines.append(node.lineno)
+                    break
+    return lines
 
 
 def defined_flags(config_tree: ast.AST) -> set:
@@ -446,9 +468,12 @@ def cfg_reads(tree: ast.AST) -> List[Tuple[int, str]]:
 @register
 class KernelFallbacksRule(Rule):
     name = "kernel-fallbacks"
-    doc = ("pltpu-gated kernels keep a guarded import plus a non-TPU "
-           "fallback path; every cfg.<flag> read resolves to a "
-           "define_flag registration in core/config.py.")
+    doc = ("Pallas TPU kernels keep a reference ORACLE for tests (a "
+           "*reference* function or an interpret= driver) and never fall "
+           "back to it at run time: no guarded pltpu import, no "
+           "except-handler that calls the reference; every cfg.<flag> "
+           "read resolves to a define_flag registration in "
+           "core/config.py.")
 
     def check(self, project: Project) -> Iterable[Finding]:
         config = project.file("ray_tpu/core/config.py")
@@ -464,19 +489,28 @@ class KernelFallbacksRule(Rule):
         for sf in project.files:
             tree = sf.tree
             if _uses_pltpu(tree):
-                if not _pltpu_import_guarded(tree):
+                for lineno in _pltpu_import_guards(tree):
                     yield Finding(
-                        self.name, sf.rel, 1,
-                        "pltpu import is not guarded by try/except "
-                        "ImportError — non-TPU builds must still import "
-                        "this",
+                        self.name, sf.rel, lineno,
+                        "pltpu import is guarded by try/except — one JAX "
+                        "is installed; a missing Pallas TPU backend must "
+                        "fail at import, not leave the module running on "
+                        "its reference",
                     )
-                if not _has_fallback_path(tree):
+                if not _has_reference_oracle(tree):
                     yield Finding(
                         self.name, sf.rel, 1,
-                        "pltpu-gated kernels but no registered non-TPU "
-                        "fallback (need a *reference* function or an "
-                        "interpret= driver)",
+                        "Pallas TPU kernels but no reference oracle for "
+                        "tests to compare with (need a *reference* "
+                        "function or an interpret= driver)",
+                    )
+                for lineno in _reference_in_except(tree):
+                    yield Finding(
+                        self.name, sf.rel, lineno,
+                        "except-handler calls a *reference* function — a "
+                        "kernel that fails must fail the run; selection "
+                        "between kernel and reference is a static rule "
+                        "on backend and shape",
                     )
             if flags:
                 for lineno, attr in cfg_reads(tree):

@@ -14,19 +14,6 @@ import dataclasses
 import jax
 import pytest
 
-
-@pytest.fixture(autouse=True)
-def _shardy_partitioner():
-    """These tests assert axis-name sharding markers (sdy.mesh, {"tp"},
-    {"fsdp"}) in the lowered HLO. The pinned jax defaults to the GSPMD
-    partitioner whose text form carries device-id shardings instead;
-    Shardy is available behind a flag — enable it for this module and
-    restore the default after (lowering-only: nothing executes here)."""
-    old = jax.config.jax_use_shardy_partitioner
-    jax.config.update("jax_use_shardy_partitioner", True)
-    yield
-    jax.config.update("jax_use_shardy_partitioner", old)
-
 from ray_tpu.models import get_config
 from ray_tpu.models.transformer import logical_axes
 from ray_tpu.parallel import MeshSpec, build_mesh, default_rules

@@ -72,3 +72,30 @@ def test_store_reads_flags(monkeypatch, tmp_path):
     oid = ObjectID.for_put(JobID.next())
     store.put(oid, b"x" * 100)  # > 10 bytes -> host tier, not inline
     assert store.entry(oid).tier == Tier.HOST
+
+
+def test_compile_cache_is_placed_from_outside_or_at_one_fixed_path(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: nothing is set in code. Unset: the
+    one fixed path inside the checkout, exported for jax and children."""
+    import os
+
+    import jax
+
+    from ray_tpu.core import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv(compile_cache.ENV_VAR, "/somewhere/outside")
+    assert compile_cache.ensure_compile_cache() == "/somewhere/outside"
+    assert jax.config.jax_compilation_cache_dir == before  # untouched
+    assert os.environ[compile_cache.ENV_VAR] == "/somewhere/outside"
+
+    monkeypatch.delenv(compile_cache.ENV_VAR)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    fixed = os.path.join(repo, ".jax_cache")
+    try:
+        assert compile_cache.ensure_compile_cache() == fixed
+        assert jax.config.jax_compilation_cache_dir == fixed
+        assert os.environ[compile_cache.ENV_VAR] == fixed
+        assert compile_cache.ensure_compile_cache() == fixed  # and stays there
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -1,7 +1,6 @@
 """Model tests: GPT-2/Llama forward, decode-cache equivalence, sharded run."""
 
 import jax
-from ray_tpu._jax_compat import set_mesh as compat_set_mesh
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -129,7 +128,7 @@ def test_sharded_forward_on_mesh():
     mesh = build_mesh(MeshSpec(dp=2, fsdp=2, tp=2))
     sharded = shard_tree(params, logical_axes(config), default_rules(), mesh)
     fwd = jax.jit(lambda p, t: forward(p, t, config))
-    with compat_set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = fwd(sharded, tokens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expected), atol=1e-4, rtol=1e-4)
 

@@ -1,7 +1,6 @@
 """MoE: routing invariants, forward/backward, expert-parallel sharded run."""
 
 import jax
-from ray_tpu._jax_compat import set_mesh as compat_set_mesh
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -93,7 +92,7 @@ def test_expert_parallel_sharded_matches_replicated(model):
     sharded = shard_tree(params, logical_axes(config), default_rules(), mesh)
     assert sharded["blocks"]["we_up"].sharding.spec[1] == "ep"
     fwd = jax.jit(lambda p, t: forward(p, t, config))
-    with compat_set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out, aux = fwd(sharded, tokens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expected), atol=1e-4, rtol=1e-4)
     np.testing.assert_allclose(float(aux), float(aux_e), rtol=1e-5)

@@ -303,6 +303,42 @@ def test_pipelined_auto_fallback_single_tile():
                                atol=2e-5, rtol=2e-5)
 
 
+def test_flash_kernel_runs_per_shard_under_a_context_mesh():
+    """Traced under a context mesh (as make_train_step does) the Pallas
+    kernel is shard_mapped — batch over dp x fsdp, heads over tp — because
+    GSPMD cannot partition a Mosaic call on a real chip. Same values and
+    gradients as the unsharded reference, GQA included."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from ray_tpu.parallel import MeshSpec, build_mesh
+
+    mesh = build_mesh(MeshSpec(dp=2, fsdp=2, tp=2))
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(26), 3)
+    q = _rand(kq, (4, 4, 64, 32))
+    k = _rand(kk, (4, 2, 64, 32))
+    v = _rand(kv, (4, 2, 64, 32))
+    spec = NamedSharding(mesh, PartitionSpec(("dp", "fsdp"), "tp", None, None))
+
+    def loss_sharded(q, k, v):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            out = flash_attention(q, k, v, causal=True, implementation="pallas",
+                                  block_q=32, block_kv=32)
+        return jnp.sum(out * out)
+
+    def loss_ref(q, k, v):
+        return jnp.sum(mha_reference(q, k, v, causal=True) ** 2)
+
+    step = jax.jit(jax.value_and_grad(loss_sharded, argnums=(0, 1, 2)),
+                   in_shardings=(spec, spec, spec))
+    assert "manual_computation" in step.lower(q, k, v).as_text()
+    val, grads = step(q, k, v)
+    ref_val, ref_grads = jax.value_and_grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(float(val), float(ref_val), rtol=1e-4)
+    for a, b_ in zip(grads, ref_grads):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   atol=1e-3, rtol=1e-3)
+
+
 def test_auto_loss_chunk_crossover():
     """Pins the dense->fused crossover at the measured v5e numbers: batch
     24 stays dense on a 16G chip, batch 32 (the measured regression) flips

@@ -1,7 +1,6 @@
 """Mesh / sharding-rule / collective tests on the virtual 8-device CPU mesh."""
 
 import jax
-from ray_tpu._jax_compat import shard_map as compat_shard_map
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -132,7 +131,7 @@ def test_in_graph_collectives_under_shard_map(mesh8):
     from functools import partial
 
     @jax.jit
-    @partial(compat_shard_map, mesh=mesh8, in_specs=P("dp"), out_specs=P("dp"))
+    @partial(jax.shard_map, mesh=mesh8, in_specs=P("dp"), out_specs=P("dp"))
     def normalize(x):
         total = col.psum(jnp.sum(x), "dp")
         return x / total
@@ -254,7 +253,7 @@ def test_quantized_psum_rows_consistent_and_close():
     exact = x.sum(axis=0)
 
     @jax.jit
-    @partial(compat_shard_map, mesh=mesh, in_specs=P("dp"),
+    @partial(jax.shard_map, mesh=mesh, in_specs=P("dp"),
              out_specs=(P("dp"), P("dp")), check_vma=False)
     def qar(rows):
         red, err = col.quantized_psum_rows(rows[0], "dp", block=128)
@@ -282,7 +281,7 @@ def test_quantized_psum_scatter_rows_close_to_exact():
     exact = x.sum(axis=0)
 
     @jax.jit
-    @partial(compat_shard_map, mesh=mesh, in_specs=P("dp"),
+    @partial(jax.shard_map, mesh=mesh, in_specs=P("dp"),
              out_specs=(P("dp"), P("dp")), check_vma=False)
     def qrs(rows):
         own, err = col.quantized_psum_scatter_rows(rows[0], "dp", block=128)
@@ -352,7 +351,7 @@ def test_sharded_update_matches_replicated_exactly():
 
     @jax.jit
     @partial(
-        compat_shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(), P(), opt_specs),
         out_specs=(P(), opt_specs),
         check_vma=False,

@@ -123,7 +123,25 @@ def test_device_trace_events_align_to_wall_clock():
 # -------------------------------------------------------- cost model / MFU
 
 
-def test_step_cost_and_roofline():
+@pytest.fixture
+def nominal_cpu_peaks(monkeypatch):
+    """The peaks table holds published chips only; a CPU test that wants
+    the MFU/roofline ARITHMETIC exercised asks for a nominal row here."""
+    monkeypatch.setitem(profiling.DEVICE_PEAKS, "cpu", (1e12, 100e9))
+
+
+def test_unknown_device_kind_is_an_error_not_a_default():
+    f = jax.jit(lambda a, b: a @ b)
+    with pytest.raises(ProfilingError, match="no published peaks"):
+        profiling.step_cost(f, jnp.ones((8, 8)), jnp.ones((8, 8)))
+    with pytest.raises(ProfilingError, match="no published peaks"):
+        profiling.device_peaks()
+    assert profiling.device_peaks(
+        type("Dev", (), {"device_kind": "TPU v5 lite"})()
+    )["peak_flops"] == 197e12
+
+
+def test_step_cost_and_roofline(nominal_cpu_peaks):
     f = jax.jit(lambda a, b: a @ b)
     x = jnp.ones((256, 128))
     w = jnp.ones((128, 64))
@@ -133,8 +151,7 @@ def test_step_cost_and_roofline():
     roof = profiling.roofline(cost, 0.001)
     assert roof["mfu"] > 0 and roof["hbm_fraction"] > 0
     assert roof["bound"] in ("compute", "memory")
-    # CPU backend: unknown chip prices against the documented fallback
-    assert roof["estimated_peaks"] is True
+    assert roof["device_kind"] == "cpu"
     with pytest.raises(ProfilingError):
         profiling.roofline(cost, 0.0)
 
@@ -144,7 +161,7 @@ def test_step_cost_rejects_plain_callable():
         profiling.step_cost(lambda: 1)
 
 
-def test_sharded_step_cost_counts_devices():
+def test_sharded_step_cost_counts_devices(nominal_cpu_peaks):
     from jax.sharding import NamedSharding, PartitionSpec
     import numpy as np
 
@@ -271,7 +288,7 @@ def test_check_lazy_jax_wired():
 # --------------------------------------------------------- train MFU gauges
 
 
-def test_train_run_publishes_mfu_from_cost_analysis(rt):
+def test_train_run_publishes_mfu_from_cost_analysis(rt, nominal_cpu_peaks):
     """A short CPU-backend train run publishes a nonzero raytpu_train_mfu
     gauge derived from the compiled step's cost_analysis(), and the
     accounting lands in the Result."""
@@ -317,7 +334,7 @@ def test_train_run_publishes_mfu_from_cost_analysis(rt):
 # ----------------------------------------------------- engine tick gauges
 
 
-def test_engine_batch_occupancy_accounting(rt):
+def test_engine_batch_occupancy_accounting(rt, nominal_cpu_peaks):
     from ray_tpu.models import get_config, init_params
     from ray_tpu.serve.llm.engine import EngineConfig, LLMEngine
 

@@ -233,6 +233,52 @@ def test_hybrid_top_k_randomizes_over_idle_nodes():
         ray_tpu.shutdown()
 
 
+def test_tpu_env_claim_is_clamped_to_the_chips_jax_holds(monkeypatch):
+    """The v5e builder machine (PR 22's chip run): the environment
+    advertises the whole host — v5litepod-4, TPU_TOPOLOGY=2x2 — and one
+    chip is attached. On one host JAX is the truth: TPU 1.0, a sub-slice
+    (no slice head). Where JAX holds no TPU at all (this harness) or the
+    slice spans hosts, the environment stands."""
+    import types
+
+    import jax
+
+    from ray_tpu.core.resources import detect_tpu_resources
+
+    monkeypatch.setenv("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+    monkeypatch.setenv("TPU_TOPOLOGY", "2x2")
+    monkeypatch.setenv("TPU_WORKER_HOSTNAMES", "localhost")
+    monkeypatch.setenv("TPU_WORKER_ID", "0")
+    monkeypatch.delenv("TPU_VISIBLE_CHIPS", raising=False)
+    assert detect_tpu_resources() == {"TPU": 4.0, "TPU-v5litepod-4-head": 1.0}
+
+    chip = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    monkeypatch.setattr(jax, "local_devices", lambda: [chip])
+    assert detect_tpu_resources() == {"TPU": 1.0}
+    monkeypatch.setattr(jax, "local_devices", lambda: [chip] * 4)
+    assert detect_tpu_resources() == {"TPU": 4.0, "TPU-v5litepod-4-head": 1.0}
+
+    # another host's chips cannot be counted from here
+    monkeypatch.setattr(jax, "local_devices", lambda: [chip])
+    monkeypatch.setenv("TPU_ACCELERATOR_TYPE", "v5litepod-8")
+    monkeypatch.setenv("TPU_TOPOLOGY", "2x4")
+    monkeypatch.setenv("TPU_WORKER_HOSTNAMES", "h0,h1")
+    assert detect_tpu_resources()["TPU"] == 4.0
+
+    # no environment contract: what JAX holds, and a backend that fails
+    # to come up is raised, not read as "no chip"
+    for name in ("TPU_ACCELERATOR_TYPE", "TPU_TOPOLOGY", "TPU_WORKER_HOSTNAMES"):
+        monkeypatch.delenv(name)
+    assert detect_tpu_resources() == {"TPU": 1.0, "TPU-v5-lite-1-head": 1.0}
+
+    def broken():
+        raise RuntimeError("TPU is held by another process")
+
+    monkeypatch.setattr(jax, "local_devices", broken)
+    with pytest.raises(RuntimeError, match="held by another process"):
+        detect_tpu_resources()
+
+
 def test_tpu_pod_env_resources(monkeypatch):
     """TPU pod env vars drive resource synthesis: visible chips count,
     and the slice head resource appears only on worker 0 (reference
@@ -270,7 +316,7 @@ def test_tpu_pod_env_resources(monkeypatch):
 
     # a SMALLER attached topology clamps the type-derived count: a
     # v5litepod-4 slice type with a 1x1 topology is ONE real chip
-    # (tunneled dev chips / GKE subslicing) — over-reporting would let
+    # (GKE subslicing, a one-chip cut of a host) — over-reporting would let
     # 4 num_tpus=1 tasks contend for it. A clamped node is a SUB-slice:
     # it must NOT advertise the full-slice head resource, or a gang
     # demanding the slice lands on fewer chips than it asked for.

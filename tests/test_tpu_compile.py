@@ -1,0 +1,156 @@
+"""The main-path kernels at real widths, compiled for a DESCRIBED v5e chip.
+
+The TPU compiler is installed here and compiles for a topology that is
+described, not attached (on-chip-measurement guide, section 2.3). Interpret
+mode cannot see what it refuses: the pipelined flash kernel passed every
+interpret test and was refused at D=64 (64-wide stream tile) and in its
+backward (1-wide lse/delta tiles); a Mosaic call under GSPMD is refused
+outright. Nothing runs here — a compile that passes is not a chip run.
+
+Steering is done in this file: code that asks `jax.default_backend()` sees
+"tpu" through a monkeypatch, and emit_pipeline reads the described chip's
+kind instead of the attached device's.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
+
+from ray_tpu.ops import attention as flash
+from ray_tpu.ops.ragged_paged_attention import _ragged_pallas
+from ray_tpu.parallel import MeshSpec, build_mesh
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as exc:  # noqa: BLE001 - no TPU compiler in this install
+        pytest.skip(f"cannot describe a v5e topology here: {exc}")
+
+
+@pytest.fixture
+def as_tpu(v5e, monkeypatch):
+    """Take the TPU branches of the static rules, for the described chip."""
+    import jax._src.pallas.mosaic.core as mosaic_core
+
+    chip = v5e.devices[0]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(mosaic_core, "get_device_kind", lambda: chip.device_kind)
+    monkeypatch.setattr(mosaic_core, "get_num_device_cores", lambda: chip.num_cores)
+    return chip
+
+
+def _on(chip, shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=SingleDeviceSharding(chip))
+
+
+def _kernel_calls(compiled) -> int:
+    return compiled.as_text().count("tpu_custom_call")
+
+
+GPT2 = ((24, 12, 1024, 64), (24, 12, 1024, 64))       # bench.py's shape
+LLAMA = ((4, 32, 2048, 128), (4, 8, 2048, 128))       # GQA 32 -> 8
+RING = ((4, 8, 2048, 128), (4, 8, 2048, 128))         # bench.py's ring block
+
+
+@pytest.mark.parametrize(
+    "shapes,impl",
+    [(GPT2, "pallas"), (LLAMA, "pallas"), (LLAMA, "pallas_pipelined")],
+    ids=["classic-gpt2-d64", "classic-llama-d128", "pipelined-llama-d128"],
+)
+def test_flash_attention_fwd_and_grad_compile(as_tpu, shapes, impl):
+    q_shape, kv_shape = shapes
+    q, k, v = _on(as_tpu, q_shape), _on(as_tpu, kv_shape), _on(as_tpu, kv_shape)
+    assert flash.resolve_attention_impl(
+        q_shape[-1], q_shape[2], kv_shape[2], implementation=impl) == impl
+
+    def attend(q, k, v):
+        return flash.flash_attention(q, k, v, causal=True, implementation=impl)
+
+    def loss(q, k, v):
+        return attend(q, k, v).astype(jnp.float32).sum()
+
+    assert _kernel_calls(jax.jit(attend).lower(q, k, v).compile()) == 1
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+    assert _kernel_calls(grad.lower(q, k, v).compile()) == 3  # fwd, dkv, dq
+
+
+def test_default_rule_never_picks_a_kernel_the_compiler_refuses(as_tpu):
+    """What the chip's compiler said, as the static rule states it: the
+    pipelined forward needs D % 128 == 0; everything else is classic."""
+    assert flash.resolve_attention_impl(64, 1024, 1024) == "pallas"
+    assert flash.resolve_attention_impl(128, 2048, 2048) == "pallas_pipelined"
+    assert flash.resolve_attention_impl(128, 256, 256) == "pallas"  # < 2 kv tiles
+    assert flash.resolve_attention_impl(
+        64, 1024, 1024, implementation="pallas_pipelined") == "pallas"
+    q = _on(as_tpu, GPT2[0])
+    refused = jax.jit(lambda q, k, v: flash._flash_pipelined(
+        q, k, v, True, 0.125, 1024, 256, 1024, False))
+    with pytest.raises(Exception, match="aligned to tiling"):
+        refused.lower(q, q, q).compile()
+
+
+@pytest.mark.parametrize("fwd", [flash._fwd_pallas, flash._fwd_pipe],
+                         ids=["classic", "pipelined"])
+def test_ring_attention_fused_block_compiles(as_tpu, fwd):
+    """ops/ring_attention's fused local block: the forward with its lse."""
+    q = _on(as_tpu, RING[0])
+    block_kv = 1024 if fwd is flash._fwd_pallas else 256
+    block = jax.jit(lambda q, k, v: fwd(
+        q, k, v, True, 128 ** -0.5, 1024, block_kv, 2048, False))
+    assert _kernel_calls(block.lower(q, q, q).compile()) == 1
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize(
+    "region",
+    [dict(lanes=4 + 8, rows=4 * 256 + 8 * 8, max_q_blocks=32),  # mixed tick
+     dict(lanes=8, rows=8 * 8, max_q_blocks=1)],               # decode block
+    ids=["prefill-chunks+decode", "decode-only"],
+)
+def test_ragged_paged_kernel_compiles(as_tpu, region, dtype):
+    """Hq 32 / Hkv 8 / D 128 / page 64, block_q 8: the engine's shapes."""
+    pages, max_pages = 4096, 16
+    i32 = lambda *shape: _on(as_tpu, shape, jnp.int32)  # noqa: E731
+    kernel = jax.jit(lambda *a: _ragged_pallas(
+        *a, block_q=8, max_q_blocks=region["max_q_blocks"], interpret=False))
+    compiled = kernel.lower(
+        _on(as_tpu, (32, region["rows"], 128), dtype),
+        _on(as_tpu, (8, pages, 64, 128), dtype),
+        _on(as_tpu, (8, pages, 64, 128), dtype),
+        *(i32(region["lanes"]) for _ in range(4)),
+        i32(region["lanes"], max_pages),
+    ).compile()
+    assert _kernel_calls(compiled) == 1
+
+
+def test_flash_attention_under_a_mesh_is_partitioned_per_shard(v5e, as_tpu):
+    """GSPMD refuses a Mosaic call ("wrap the call in a shard_map"): traced
+    under a context mesh, as make_train_step traces its step, the kernel
+    runs per shard — batch over fsdp, heads over tp — on four chips."""
+    mesh = build_mesh(MeshSpec(fsdp=2, tp=2), devices=v5e.devices)
+    spec = NamedSharding(mesh, PartitionSpec(("dp", "fsdp"), "tp", None, None))
+    q = jax.ShapeDtypeStruct(LLAMA[0], jnp.bfloat16, sharding=spec)
+    kv = jax.ShapeDtypeStruct(LLAMA[1], jnp.bfloat16, sharding=spec)
+
+    def loss(q, k, v):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            out = flash.flash_attention(q, k, v, causal=True)
+        return out.astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv).compile()
+    assert _kernel_calls(compiled) == 3
+    # each chip holds a quarter of q: two of four sequences, half the heads
+    per_chip = 2 * 16 * 2048 * 128 * 2
+    assert compiled.memory_analysis().argument_size_in_bytes < 3 * per_chip
+    no_context = jax.jit(lambda q, k, v: flash.flash_attention(q, k, v, causal=True))
+    with pytest.raises(NotImplementedError, match="cannot be automatically partitioned"):
+        no_context.lower(q, kv, kv).compile()
