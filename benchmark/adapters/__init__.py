@@ -1,0 +1,5 @@
+"""One module per `model_type` of a configuration file, found by that name:
+what the program needs to build the model (`program_config`), the sizes
+the cost functions take (`shapes`), and the plain reference's forward and
+loss (`reference_logits`, `reference_loss`). A configuration of a new
+family brings its own adapter (and reference) file."""
