@@ -303,11 +303,6 @@ define_flag("steplog_federate_batch", 256,
 define_flag("steplog_table_cap", 2000,
             "Per-node cap on step marks retained in the GCS _steps "
             "table (the cluster-wide queryable tail).")
-define_flag("steplog_dp_bandwidth_gbs", 100.0,
-            "Assumed interconnect bandwidth (GB/s) used to ESTIMATE "
-            "the dp_sync share of device step time on sampled steps "
-            "(the gradient sync is fused into the XLA step program and "
-            "cannot be host-timed separately).")
 
 # flight recorder (durable events + federation + goodput accounting)
 define_flag("events_dir", "",

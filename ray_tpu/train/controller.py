@@ -153,9 +153,7 @@ class TrainController:
 
     def run(self) -> Result:
         # The whole run is one trace: gang attempts, restarts and
-        # checkpoint restores nest as phase spans; device_annotate labels
-        # each attempt in the XLA device trace (util/profiling) so host
-        # phases line up with HLO activity.
+        # checkpoint restores nest as phase spans.
         from ..util import tracing
         from ..util.goodput import GoodputAccountant
 
@@ -276,9 +274,7 @@ class TrainController:
                        "resume_from_step": self.latest_checkpoint_step},
             )
             try:
-                with tracing.use_context(attempt_span.context), \
-                        tracing.device_annotate(
-                            f"train.attempt:{self.run_config.name}"):
+                with tracing.use_context(attempt_span.context):
                     group.start()
                     self.status = RunStatus.RUNNING
                     emit("INFO", "train",

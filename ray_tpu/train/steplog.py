@@ -5,10 +5,13 @@ watchdog's EWMA step gap) answers "is this gang slow" but not *where
 inside a step* the time went or *which rank's which bucket* lags. The
 StepLog is the train-side mirror of serve/reqlog.py: typed per-phase
 STEP MARKS with both clocks, recorded on SAMPLED steps only (every
-``cfg.step_log_sample_every``-th step pays one ``block_until_ready``;
-every other step stays fully async), each sampled step sealed by an
-``other`` mark whose duration is the remainder — so the buckets sum
-EXACTLY to the measured step wall time, by construction.
+``cfg.step_log_sample_every``-th step the trainer has dispatched, over
+all its ``train()`` calls, pays the ``block_until_ready``; every other
+step stays fully async), each sampled step sealed by an ``other`` mark
+whose duration is the remainder — so the buckets sum EXACTLY to the
+measured step wall time, by construction. The durations are those of the
+trainer's ``train.step.*`` spans (util/tracing): the log reads the span
+record's stamps and takes none of its own.
 
 Marks live in a bounded per-process ring plus a bounded per-(run, rank,
 step) summary index; per-step records also ride the gang report plane
@@ -41,13 +44,10 @@ from typing import Any, Dict, List, Optional, Tuple
 STEP_PHASES: Dict[str, str] = {
     "data_wait": "host blocked in next(batch_iter) — the input pipeline",
     "h2d": "host->device batch materialization (np->jnp + ready)",
-    "fwd_bwd_compute": "forward+backward device compute (device time "
-                       "minus the dp_sync estimate)",
-    "dp_sync": "data-parallel gradient sync share of device time "
-               "(wire-byte estimate; the sync is fused into the XLA "
-               "program and cannot be host-timed)",
-    "optimizer_update": "optimizer update (fused into the step program; "
-                        "0 unless a backend splits it out)",
+    "device": "the fused step program on the device: dispatch to the "
+              "state being ready (forward, backward, gradient sync and "
+              "optimizer update are one XLA program; their split is the "
+              "device trace's, under the steplog.* named scopes)",
     "ckpt_save": "checkpoint save blocking the step loop",
     "report": "metrics conversion + session.report",
     "other": "remainder: step wall time minus every measured bucket "
@@ -365,9 +365,7 @@ def skew_matrix(summaries: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
 _BUCKET_GLYPHS = {
     "data_wait": "d",
     "h2d": "h",
-    "fwd_bwd_compute": "f",
-    "dp_sync": "s",
-    "optimizer_update": "u",
+    "device": "f",
     "ckpt_save": "c",
     "report": "r",
     "other": ".",

@@ -80,61 +80,69 @@ class LMTrainer:
     ):
         from ..core.config import cfg
         from ..parallel.collectives import dp_sync_bytes
+        from ..util import tracing
 
         self.config = config
-        n_dev = len(jax.devices())
-        self.mesh = build_mesh(mesh_spec or MeshSpec().with_devices(n_dev))
-        self.rules = rules or default_rules()
-        # dp sync knobs: explicit args win, cfg flags are the default
-        if dp_allreduce_dtype is None:
-            dp_allreduce_dtype = cfg.dp_allreduce_dtype
-        if dp_shard_update is None:
-            dp_shard_update = cfg.dp_shard_update
-        n_dp = self.mesh.shape.get("dp", 1)
-        explicit_dp = (
-            dp_shard_update or dp_allreduce_dtype == "int8"
-        ) and n_dp > 1
-        self.dp_sync_mode = (
-            f"{dp_allreduce_dtype}"
-            + ("+shard_update" if dp_shard_update else "")
-            if explicit_dp else "xla_psum"
-        )
-        self.optimizer = optimizer or default_optimizer(
-            learning_rate, total_steps=total_steps,
-            shard_axis="dp" if (explicit_dp and dp_shard_update) else None,
-        )
-        self.total_steps = total_steps
-        self.state, self.state_shardings = create_train_state(
-            self.config, self.optimizer, jax.random.PRNGKey(seed), self.mesh,
-            self.rules,
-            dp_shard_update=explicit_dp and dp_shard_update,
-            dp_error_feedback=explicit_dp and dp_allreduce_dtype == "int8",
-        )
-        self.step_fn = make_train_step(
-            self.config,
-            self.optimizer,
-            self.mesh,
-            state_shardings=self.state_shardings,
-            z_loss_coeff=z_loss_coeff,
-            grad_accum=grad_accum,
-            loss_chunk=loss_chunk,
-            dp_allreduce_dtype=dp_allreduce_dtype,
-            dp_shard_update=dp_shard_update,
-        )
-        self.dp_sync_bytes = dp_sync_bytes(
-            count_params(self.state.params), n_dp,
-            mode=dp_allreduce_dtype, shard_update=dp_shard_update,
-            block=cfg.dp_quant_block,
-        ) if explicit_dp else (
-            dp_sync_bytes(count_params(self.state.params), n_dp)
-        )
-        # per-step dp_sync ESTIMATE for the step-phase decomposition
-        # (train/steplog): wire bytes over the assumed interconnect
-        # bandwidth — 0 on a single replica, where nothing syncs
-        self._dp_sync_est_s = (
-            self.dp_sync_bytes / (cfg.steplog_dp_bandwidth_gbs * 1e9)
-            if n_dp > 1 else 0.0
-        )
+        # sampled steps are chosen on this count of dispatched steps, not
+        # on a train() call's own: one step in step_log_sample_every
+        # syncs whatever the length of the calls
+        self._steps_dispatched = 0
+        # tracing.compile_seconds() as of the last report: the first
+        # report's compile_s then holds what building the trainer compiled
+        self._compile_s_reported = tracing.compile_seconds()
+        with tracing.span("train.init"):
+            with tracing.span("train.init.mesh"):
+                n_dev = len(jax.devices())
+                self.mesh = build_mesh(
+                    mesh_spec or MeshSpec().with_devices(n_dev))
+            self.rules = rules or default_rules()
+            # dp sync knobs: explicit args win, cfg flags are the default
+            if dp_allreduce_dtype is None:
+                dp_allreduce_dtype = cfg.dp_allreduce_dtype
+            if dp_shard_update is None:
+                dp_shard_update = cfg.dp_shard_update
+            n_dp = self.mesh.shape.get("dp", 1)
+            explicit_dp = (
+                dp_shard_update or dp_allreduce_dtype == "int8"
+            ) and n_dp > 1
+            self.dp_sync_mode = (
+                f"{dp_allreduce_dtype}"
+                + ("+shard_update" if dp_shard_update else "")
+                if explicit_dp else "xla_psum"
+            )
+            self.total_steps = total_steps
+            with tracing.span("train.init.state"):
+                self.optimizer = optimizer or default_optimizer(
+                    learning_rate, total_steps=total_steps,
+                    shard_axis="dp" if (explicit_dp and dp_shard_update)
+                    else None,
+                )
+                self.state, self.state_shardings = create_train_state(
+                    self.config, self.optimizer, jax.random.PRNGKey(seed),
+                    self.mesh, self.rules,
+                    dp_shard_update=explicit_dp and dp_shard_update,
+                    dp_error_feedback=explicit_dp
+                    and dp_allreduce_dtype == "int8",
+                )
+            with tracing.span("train.init.step_fn"):
+                self.step_fn = make_train_step(
+                    self.config,
+                    self.optimizer,
+                    self.mesh,
+                    state_shardings=self.state_shardings,
+                    z_loss_coeff=z_loss_coeff,
+                    grad_accum=grad_accum,
+                    loss_chunk=loss_chunk,
+                    dp_allreduce_dtype=dp_allreduce_dtype,
+                    dp_shard_update=dp_shard_update,
+                )
+                self.dp_sync_bytes = dp_sync_bytes(
+                    count_params(self.state.params), n_dp,
+                    mode=dp_allreduce_dtype, shard_update=dp_shard_update,
+                    block=cfg.dp_quant_block,
+                ) if explicit_dp else (
+                    dp_sync_bytes(count_params(self.state.params), n_dp)
+                )
         # cost_analysis() of the compiled step (util/profiling), computed
         # once the first time a report needs it (one extra AOT compile;
         # disable with profile_cost_accounting=False)
@@ -177,6 +185,7 @@ class LMTrainer:
         tokens/sec. `report_fn` defaults to session.report when inside a
         worker, else a no-op. `run_name` keys the step-forensics records
         (default: the session's run name, else "local")."""
+        from ..util import tracing
         from . import steplog
         from .session import _local
 
@@ -188,149 +197,173 @@ class LMTrainer:
         rank = session.context.world_rank if session is not None else 0
 
         ckpt_every = self.ckpt_config.checkpoint_every if self.ckpt_config else 0
-        # step forensics (train/steplog): every sample_every-th step is
-        # decomposed into typed phase buckets. ONLY sampled steps sync
-        # (block_until_ready); the rest keep jax async dispatch rolling.
+        # step forensics (train/steplog): every sample_every-th dispatched
+        # step is decomposed into typed phase buckets. ONLY sampled steps
+        # sync (block_until_ready); the rest keep jax async dispatch rolling.
         sample_every = steplog.sample_every() if steplog.enabled() else 0
         pending_steps: list = []
-        t0 = time.perf_counter()
         tokens_done = 0.0
         last_metrics: Dict[str, Any] = {}
         steps = 0
-        window_t0, window_steps = t0, 0
+        window_steps = 0
         # per-window phase seconds: the goodput accountant (util/goodput)
         # re-attributes these out of the step_compute bucket when the
         # report reaches the controller
         window_input_wait = 0.0
         window_ckpt_save = 0.0
-        window_dp_sync = 0.0
         batch_iter = iter(batches)
-        while True:
-            t_step0 = time.perf_counter()
-            try:
-                batch = next(batch_iter)  # input pipeline wait happens HERE
-            except StopIteration:
-                break
-            t_data = time.perf_counter()
-            window_input_wait += t_data - t_step0
-            if num_steps is not None and steps >= num_steps:
-                break
-            sampled = sample_every > 0 and steps % sample_every == 0
-            tokens = batch["tokens"]
-            if isinstance(tokens, np.ndarray):
-                batch = {"tokens": jax.numpy.asarray(tokens)}
-            if sampled:
-                # the ONE deliberate sync before dispatch: land the batch
-                # so h2d separates from device compute in the timeline
-                jax.block_until_ready(batch["tokens"])
-            t_h2d = time.perf_counter()
-            self.state, metrics = self.step_fn(self.state, batch)
-            if sampled:
-                jax.block_until_ready(self.state)
-            t_dev = time.perf_counter()
-            steps += 1
-            window_steps += 1
-            window_dp_sync += self._dp_sync_est_s
-            tokens_done += float(tokens.shape[0] * (tokens.shape[1] - 1))
-            t_rep0 = time.perf_counter()
-            if steps % report_every == 0 or (num_steps is not None and steps == num_steps):
-                metrics = {k: float(v) for k, v in metrics.items()}
-                now = time.perf_counter()
-                elapsed = now - t0
-                metrics["tokens_per_sec"] = tokens_done / max(elapsed, 1e-9)
-                metrics["step"] = int(self.state.step)
-                metrics["input_wait_s"] = round(window_input_wait, 6)
-                metrics["ckpt_save_s"] = round(window_ckpt_save, 6)
-                metrics["dp_sync_s"] = round(window_dp_sync, 6)
-                window_input_wait = window_ckpt_save = window_dp_sync = 0.0
-                # MFU/roofline from the compiled step's cost_analysis()
-                # over this window's measured step time (the first window
-                # absorbs the compile, so its MFU reads low)
-                metrics.update(self.profiling_metrics(
-                    batch, (now - window_t0) / max(window_steps, 1)
-                ))
-                window_t0, window_steps = now, 0
-                last_metrics = metrics
-                # sampled-step records + the worker's monotonic clock
-                # ride the report on RESERVED keys (popped controller-
-                # side before any metric publication)
-                payload = dict(metrics)
-                payload["_mono"] = time.perf_counter()
-                if pending_steps:
-                    payload["_steplog"] = pending_steps
-                    pending_steps = []
-                report_fn(payload)
-            t_rep1 = time.perf_counter()
-            ckpt_dur = 0.0
-            if ckpt_every and steps % ckpt_every == 0 and self.ckpt_mgr is not None:
-                t_ck = time.perf_counter()
-                self.save_checkpoint()
-                ckpt_dur = time.perf_counter() - t_ck
-                window_ckpt_save += ckpt_dur
-            if sampled:
-                pending_steps.append(self._mark_sampled_step(
-                    run_name, rank, int(self.state.step),
-                    data_wait=t_data - t_step0,
-                    h2d=t_h2d - t_data,
-                    device=t_dev - t_h2d,
-                    report=t_rep1 - t_rep0,
-                    ckpt=ckpt_dur,
-                    wall=time.perf_counter() - t_step0,
-                ))
-                del pending_steps[:-64]  # bounded if reports never drain
-        if pending_steps and session is not None:
-            # trailing sampled steps with no report behind them: ship a
-            # reserved-keys-only report (the controller drops it from
-            # metric publication after popping the steplog payload)
-            report_fn({"_steplog": pending_steps,
-                       "_mono": time.perf_counter()})
-        if self.ckpt_mgr is not None and self.ckpt_config.checkpoint_every:
-            self.save_checkpoint()
-            self.ckpt_mgr.wait_until_finished()
+        # The spans are the loop's only clock. `train.loop`, `train.step`
+        # and `train.report` are opened with start_span, which the profile
+        # does not mirror: the host lane of a profile then holds the leaves
+        # alone, and a device gap is named by the leaf the host was in,
+        # not by a span that covers the whole step or call. Each leaf
+        # starts on the stamp the one before it ended on.
+        tracer = tracing.tracer()
+        loop = tracer.start_span("train.loop", attrs={
+            "run": run_name, "rank": rank, "num_steps": num_steps})
+        loop_ctx = loop.context
+        t0 = window_t0 = loop.start_mono
+        try:
+            while num_steps is None or steps < num_steps:
+                sampled = (sample_every > 0
+                           and self._steps_dispatched % sample_every == 0)
+                if sampled:
+                    # a sampled step is timed alone: the steps still in
+                    # flight are waited out before it starts, or its
+                    # `device` bucket would hold their time too
+                    with tracing.span("train.drain", parent=loop_ctx):
+                        jax.block_until_ready(self.state)
+                step = tracer.start_span("train.step", parent=loop_ctx)
+                ctx = step.context
+                with tracing.span("train.step.data_wait", parent=ctx,
+                                  start=step.started) as wait:
+                    batch = next(batch_iter, None)  # input pipeline wait happens HERE
+                if batch is None:
+                    step.end(end_of_data=True)
+                    break
+                window_input_wait += wait.duration_s
+                tokens = batch["tokens"]
+                with tracing.span("train.step.h2d", parent=ctx,
+                                  start=wait.ended) as h2d:
+                    if isinstance(tokens, np.ndarray):
+                        batch = {"tokens": jax.numpy.asarray(tokens)}
+                    if sampled:
+                        # the ONE deliberate sync before dispatch: land the
+                        # batch so h2d separates from device compute
+                        jax.block_until_ready(batch["tokens"])
+                with tracing.span("train.step.dispatch", parent=ctx,
+                                  start=h2d.ended) as dispatch:
+                    self.state, metrics = self.step_fn(self.state, batch)
+                self._steps_dispatched += 1
+                device_s = dispatch.duration_s
+                if sampled:
+                    with tracing.span("train.step.sync", parent=ctx,
+                                      start=dispatch.ended) as sync:
+                        jax.block_until_ready(self.state)
+                    device_s += sync.duration_s
+                steps += 1
+                window_steps += 1
+                tokens_done += float(tokens.shape[0] * (tokens.shape[1] - 1))
+                report_s = ckpt_s = 0.0
+                if steps % report_every == 0 or steps == num_steps:
+                    report = tracer.start_span("train.report", parent=ctx)
+                    rctx = report.context
+                    with tracing.span("train.report.read", parent=rctx,
+                                      start=report.started) as read:
+                        # the host read that waits for the device
+                        metrics = {k: float(v) for k, v in metrics.items()}
+                        metrics["step"] = int(self.state.step)
+                    with tracing.span("train.report.cost", parent=rctx,
+                                      start=read.ended) as cost:
+                        now = read.end_mono
+                        metrics["tokens_per_sec"] = tokens_done / max(now - t0, 1e-9)
+                        metrics["input_wait_s"] = round(window_input_wait, 6)
+                        metrics["ckpt_save_s"] = round(window_ckpt_save, 6)
+                        window_input_wait = window_ckpt_save = 0.0
+                        # MFU/roofline from the compiled step's cost_analysis()
+                        # over this window's measured step time (the first
+                        # window absorbs the compile, so its MFU reads low)
+                        metrics.update(self.profiling_metrics(
+                            batch, (now - window_t0) / max(window_steps, 1)
+                        ))
+                        window_t0, window_steps = now, 0
+                    with tracing.span("train.report.publish", parent=rctx,
+                                      start=cost.ended):
+                        # after `cost`: what step_cost lowers or builds counts
+                        compiled_s = tracing.compile_seconds()
+                        metrics["compile_s"] = round(
+                            compiled_s - self._compile_s_reported, 6)
+                        self._compile_s_reported = compiled_s
+                        last_metrics = metrics
+                        # sampled-step records + the worker's monotonic clock
+                        # ride the report on RESERVED keys (popped controller-
+                        # side before any metric publication)
+                        payload = dict(metrics)
+                        payload["_mono"] = cost.end_mono
+                        if pending_steps:
+                            payload["_steplog"] = pending_steps
+                            pending_steps = []
+                        report_fn(payload)
+                    report.end()
+                    report_s = report.duration_s
+                if ckpt_every and steps % ckpt_every == 0 and self.ckpt_mgr is not None:
+                    with tracing.span("train.ckpt_save", parent=ctx) as ckpt:
+                        self.save_checkpoint()
+                    ckpt_s = ckpt.duration_s
+                    window_ckpt_save += ckpt_s
+                step.end()
+                if sampled:
+                    pending_steps.append(self._mark_sampled_step(
+                        run_name, rank, int(self.state.step),
+                        data_wait=wait.duration_s, h2d=h2d.duration_s,
+                        device=device_s, report=report_s, ckpt=ckpt_s,
+                        wall=step.duration_s,
+                    ))
+                    del pending_steps[:-64]  # bounded if reports never drain
+            if pending_steps and session is not None:
+                # trailing sampled steps with no report behind them: ship a
+                # reserved-keys-only report (the controller drops it from
+                # metric publication after popping the steplog payload)
+                report_fn({"_steplog": pending_steps,
+                           "_mono": time.perf_counter()})
+            if self.ckpt_mgr is not None and self.ckpt_config.checkpoint_every:
+                with tracing.span("train.ckpt_save", parent=loop_ctx):
+                    self.save_checkpoint()
+                    self.ckpt_mgr.wait_until_finished()
+        except BaseException as exc:
+            loop.end(status="ERROR", error=repr(exc), steps=steps)
+            raise
+        loop.end(steps=steps)
         return last_metrics
 
     def _mark_sampled_step(self, run: str, rank: int, step: int, *,
                            data_wait: float, h2d: float, device: float,
                            report: float, ckpt: float,
                            wall: float) -> Dict[str, Any]:
-        """Decompose one SAMPLED step into the typed steplog buckets.
-
-        The fused XLA program is one opaque device interval: dp_sync is
-        the wire-byte ESTIMATE (cfg.steplog_dp_bandwidth_gbs; exactly 0
-        on one replica), fwd_bwd_compute the device remainder, and
-        optimizer_update stays 0 (fused into the step program). `other`
-        is wall minus every measured bucket, so the recorded buckets sum
+        """Write one SAMPLED step's span durations as the typed steplog
+        buckets. The fused XLA program is one opaque interval, `device`
+        (dispatch and the wait for the state). `other` is the step
+        span's duration less every bucket, so the recorded buckets sum
         EXACTLY to wall_s — the invariant the tests enforce."""
         from . import steplog
 
-        dp_sync = min(self._dp_sync_est_s, device)
-        fwd_bwd = device - dp_sync
-        measured = data_wait + h2d + device + report + ckpt
-        other = wall - measured
-        if other < 0.0:  # clock jitter: wall is then the measured sum
-            other, wall = 0.0, measured
-        steplog.mark("data_wait", data_wait, run=run, rank=rank, step=step)
-        steplog.mark("h2d", h2d, run=run, rank=rank, step=step)
-        steplog.mark("fwd_bwd_compute", fwd_bwd, run=run, rank=rank,
-                     step=step)
-        steplog.mark("dp_sync", dp_sync, run=run, rank=rank, step=step,
-                     estimated=True)
-        steplog.mark("optimizer_update", 0.0, run=run, rank=rank, step=step)
-        steplog.mark("ckpt_save", ckpt, run=run, rank=rank, step=step)
-        steplog.mark("report", report, run=run, rank=rank, step=step)
-        steplog.mark("other", other, run=run, rank=rank, step=step,
-                     wall_s=wall)
-        return {
-            "run": run, "rank": rank, "step": step,
-            "node": steplog._default_node(), "ts": time.time(),
-            "wall_s": wall,
-            "buckets": {
-                "data_wait": data_wait, "h2d": h2d,
-                "fwd_bwd_compute": fwd_bwd, "dp_sync": dp_sync,
-                "optimizer_update": 0.0, "ckpt_save": ckpt,
-                "report": report, "other": other,
-            },
+        buckets = {
+            "data_wait": data_wait, "h2d": h2d, "device": device,
+            "ckpt_save": ckpt, "report": report,
         }
+        buckets["other"] = wall - sum(buckets.values())
+        if buckets["other"] < 0.0:  # float rounding: wall is then the sum
+            buckets["other"] = 0.0
+            wall = sum(buckets.values())
+        ids = {"run": run, "rank": rank, "step": step}
+        steplog.mark("data_wait", data_wait, **ids)
+        steplog.mark("h2d", h2d, **ids)
+        steplog.mark("device", device, **ids)
+        steplog.mark("ckpt_save", ckpt, **ids)
+        steplog.mark("report", report, **ids)
+        steplog.mark("other", buckets["other"], wall_s=wall, **ids)
+        return dict(ids, node=steplog._default_node(), ts=time.time(),
+                    wall_s=wall, buckets=buckets)
 
     def step_cost(self, batch: Dict[str, Any]):
         """cost_analysis() of the compiled train step at this batch's

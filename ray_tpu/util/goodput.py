@@ -8,12 +8,13 @@ reports, preemption notices, the stall watchdog — into a wall-time
 partition over named buckets:
 
 - ``init``          gang start, placement, process/compile bring-up
-- ``compile``       explicitly-reported XLA compile time (split out of
-                    init when the trainer reports ``compile_s``)
-- ``step_compute``  productive training steps — the GOODPUT
-- ``dp_sync``       data-parallel gradient sync share of the step
-                    windows (reported ``dp_sync_s``, the train/steplog
-                    wire-byte estimate)
+- ``compile``       jaxpr tracing, lowering, XLA compiles and persistent-
+                    cache fetches (split out of init by the ``compile_s``
+                    of a trainer's report: seconds of util/tracing's
+                    ``compile.*`` spans since its last report)
+- ``step_compute``  productive training steps — the GOODPUT (the
+                    gradient sync is part of the fused step program; its
+                    exposed share is a device-trace number, not a bucket)
 - ``input_wait``    host input pipeline stalls (reported ``input_wait_s``)
 - ``ckpt_save``     checkpoint saves, incl. the emergency-save window
                     after a preemption notice
@@ -43,7 +44,7 @@ import time
 from typing import Any, Dict, Optional
 
 BUCKETS = (
-    "init", "compile", "step_compute", "dp_sync", "input_wait",
+    "init", "compile", "step_compute", "input_wait",
     "ckpt_save", "ckpt_restore", "preempt_restart", "stall", "other",
 )
 
@@ -187,10 +188,8 @@ class GoodputAccountant:
     _REPORT_TRANSFERS = {
         "input_wait_s": ("step_compute", "input_wait"),
         "ckpt_save_s": ("step_compute", "ckpt_save"),
-        # the steplog-estimated gradient-sync share of the window: sync
-        # seconds stop being silently folded into step_compute (still
-        # summing to wall time — transfer only moves seconds)
-        "dp_sync_s": ("step_compute", "dp_sync"),
+        # the first report's compile_s is what bring-up compiled (the
+        # trainer's state and step), which the controller held in `init`
         "compile_s": ("init", "compile"),
     }
 

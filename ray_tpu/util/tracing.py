@@ -23,6 +23,29 @@ raytpu_transfer_seconds) so the /metrics scrape and the trace waterfall
 always agree. Export is chrome-trace/Perfetto JSON — spans nest, one
 process lane per node, one thread lane per actor/engine slot/thread —
 superseding the completed-task-only `chrome_tracing_dump`.
+
+Clocks. Every span carries two stamps of each end: wall time
+(``start_ts``/``end_ts``, ``time.time()``), which places spans of
+different nodes on one axis, and the monotonic clock
+(``start_mono``/``end_mono``, ``time.perf_counter()``), which is what
+durations, train/steplog buckets and the benchmark's window are
+measured on. A span that follows another may take that span's end as
+its start (``start=prev.ended``), so a boundary is read once.
+
+The profile. ``span()`` also enters ``jax.profiler.TraceAnnotation`` of
+the same name once JAX has been imported by someone else (this module
+never imports it): a TraceMe costs a flag check when no profile is being
+taken, and while one is, every program span sits on the host lane of the
+profile (the benchmark's, ``ray_tpu profile``'s, TensorBoard's) on the
+profiler's own clock, beside the device's operations. Spans opened with
+``start_span`` may end on another thread, which a TraceMe cannot, and are
+not mirrored.
+
+Compiles. The first span after JAX is imported registers one
+``jax.monitoring`` listener: every jaxpr trace, lowering, backend
+compile and persistent-cache fetch becomes a ``compile.*`` span, child of
+the span current on the compiling thread, and feeds
+``raytpu_compile_total{kind}`` / ``raytpu_compile_seconds_total{kind}``.
 """
 
 from __future__ import annotations
@@ -32,10 +55,11 @@ import contextvars
 import json
 import os
 import random
+import sys
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, Iterator, List, Optional
+from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 __all__ = [
     "Span",
@@ -48,13 +72,19 @@ __all__ = [
     "inject_context",
     "extract_context",
     "export_chrome_trace",
-    "device_annotate",
+    "compile_seconds",
 ]
 
 
 def _new_id() -> str:
     return os.urandom(8).hex()
 
+
+# ids of a span that is not recorded: nothing reads them (inject_context
+# passes no unsampled context on), so none are drawn
+_UNSAMPLED_ID = "0" * 16
+
+Stamp = Tuple[float, float]     # one instant on both clocks: (wall, mono)
 
 # The context-local current span context: {"trace_id", "span_id",
 # "sampled"}. contextvars follow the thread that set them; hops across
@@ -68,30 +98,40 @@ _current: "contextvars.ContextVar[Optional[Dict[str, Any]]]" = contextvars.Conte
 class Span:
     """One timed operation. Not thread-safe for concurrent mutation, but
     start/end may happen on different threads (engine submit thread vs.
-    loop thread) — `end()` is idempotent."""
+    loop thread) — `end()` is idempotent. An unsampled span still keeps
+    its stamps (callers read durations off it) and is never recorded."""
 
     __slots__ = (
         "trace_id", "span_id", "parent_id", "name", "start_ts", "end_ts",
-        "attrs", "status", "lane", "sampled", "_tracer", "_token", "_ended",
+        "start_mono", "end_mono", "attrs", "status", "lane", "sampled",
+        "_tracer", "_ended",
     )
 
     def __init__(self, trace_id: str, span_id: str, parent_id: Optional[str],
                  name: str, *, attrs: Optional[Dict[str, Any]] = None,
                  lane: str = "", sampled: bool = True,
                  start_ts: Optional[float] = None,
+                 start: Optional[Stamp] = None,
                  tracer_: "Optional[Tracer]" = None):
         self.trace_id = trace_id
         self.span_id = span_id
         self.parent_id = parent_id
         self.name = name
-        self.start_ts = time.time() if start_ts is None else start_ts
-        self.end_ts = 0.0
-        self.attrs = dict(attrs or {})
+        if start is not None:
+            self.start_ts, self.start_mono = start
+        else:
+            now, self.start_mono = time.time(), time.perf_counter()
+            if start_ts is None:
+                self.start_ts = now
+            else:   # a wall stamp taken earlier: the same instant on the mono clock
+                self.start_ts = start_ts
+                self.start_mono -= now - start_ts
+        self.end_ts = self.end_mono = 0.0
+        self.attrs = dict(attrs) if attrs else {}
         self.status = "OK"
         self.lane = lane
         self.sampled = sampled
         self._tracer = tracer_
-        self._token = None
         self._ended = False
 
     @property
@@ -102,6 +142,20 @@ class Span:
             "sampled": self.sampled,
         }
 
+    @property
+    def started(self) -> Stamp:
+        return self.start_ts, self.start_mono
+
+    @property
+    def ended(self) -> Stamp:
+        """The end's stamp, for the `start=` of the span that follows."""
+        return self.end_ts, self.end_mono
+
+    @property
+    def duration_s(self) -> float:
+        """Seconds on the monotonic clock (0 until the span has ended)."""
+        return max(0.0, self.end_mono - self.start_mono)
+
     def set_attribute(self, key: str, value: Any) -> None:
         self.attrs[key] = value
 
@@ -110,7 +164,12 @@ class Span:
         if self._ended:
             return
         self._ended = True
-        self.end_ts = time.time() if end_ts is None else end_ts
+        now, self.end_mono = time.time(), time.perf_counter()
+        if end_ts is None:
+            self.end_ts = now
+        else:
+            self.end_ts = end_ts
+            self.end_mono -= now - end_ts
         self.status = status
         if attrs:
             self.attrs.update(attrs)
@@ -125,7 +184,9 @@ class Span:
             "name": self.name,
             "start_ts": self.start_ts,
             "end_ts": self.end_ts,
-            "duration_s": max(0.0, self.end_ts - self.start_ts),
+            "start_mono": self.start_mono,
+            "end_mono": self.end_mono,
+            "duration_s": self.duration_s,
             "status": self.status,
             "lane": self.lane,
             "attrs": dict(self.attrs),
@@ -185,6 +246,9 @@ _SERVE_ATTR_METRICS: Dict[str, tuple] = {
 }
 
 
+_DERIVED_NAMES = frozenset(_DURATION_METRICS) | {"engine.request", "serve.request"}
+
+
 def _observe_derived(span_: Span) -> None:
     from .metrics import get_or_create_histogram
 
@@ -196,7 +260,7 @@ def _observe_derived(span_: Span) -> None:
             tags = {"direction": span_.name.split(".", 1)[1]}
         get_or_create_histogram(name, desc, boundaries=bounds,
                                 tag_keys=("direction",) if tags else ()).observe(
-            max(0.0, span_.end_ts - span_.start_ts), tags=tags
+            span_.duration_s, tags=tags
         )
     if span_.name in ("engine.request", "serve.request"):
         for attr, (name, desc, bounds) in _SERVE_ATTR_METRICS.items():
@@ -213,10 +277,11 @@ def _observe_derived(span_: Span) -> None:
 class Tracer:
     """Per-process span sink: a ring buffer plus the sampling decision.
 
-    Lock discipline: one mutex guards only the deque/index bookkeeping in
-    `_record`; span creation takes no lock at all (ids are os.urandom,
-    the sampling roll is thread-local random), so tracing stays off the
-    hot path's contention profile."""
+    Lock discipline: one mutex guards only the deque in `_record` and the
+    queries; span creation takes no lock at all (ids are os.urandom, the
+    sampling roll is thread-local random), so tracing stays off the hot
+    path's contention profile. The ring holds the ended Span objects;
+    the queries hand out dictionaries."""
 
     def __init__(self, capacity: Optional[int] = None,
                  sample_ratio: Optional[float] = None):
@@ -224,7 +289,7 @@ class Tracer:
 
         self._capacity = capacity or cfg.trace_buffer_spans
         self._sample_ratio = sample_ratio
-        self._buf: "deque[Dict[str, Any]]" = deque(maxlen=self._capacity)
+        self._buf: "deque[Span]" = deque(maxlen=self._capacity)
         self._lock = threading.Lock()
 
     # -------------------------------------------------------------- creation
@@ -243,21 +308,28 @@ class Tracer:
 
     def start_span(self, name: str, *, parent: Optional[Dict[str, Any]] = None,
                    attrs: Optional[Dict[str, Any]] = None, lane: str = "",
-                   start_ts: Optional[float] = None) -> Span:
+                   start_ts: Optional[float] = None,
+                   start: Optional[Stamp] = None) -> Span:
         """Open a span. `parent` is a context dict (wire-shaped); when
         None the context-local current span is the parent; when there is
         no current span either, this span roots a new trace and rolls
-        the sampling decision for the whole trace."""
+        the sampling decision for the whole trace. `start` is a stamp of
+        both clocks taken already (`prev.ended`, `parent.started`);
+        `start_ts` a wall time alone."""
         if parent is None:
             parent = _current.get()
         if parent is None:
-            return Span(_new_id(), _new_id(), None, name, attrs=attrs,
-                        lane=lane, sampled=self._sampled(),
-                        start_ts=start_ts, tracer_=self)
-        return Span(parent["trace_id"], _new_id(), parent["span_id"], name,
-                    attrs=attrs, lane=lane,
-                    sampled=bool(parent.get("sampled", True)),
-                    start_ts=start_ts, tracer_=self)
+            sampled, trace_id, parent_id = self._sampled(), None, None
+        else:
+            sampled = bool(parent.get("sampled", True))
+            trace_id, parent_id = parent["trace_id"], parent["span_id"]
+        if not sampled:
+            return Span(trace_id or _UNSAMPLED_ID, _UNSAMPLED_ID, parent_id,
+                        name, lane=lane, sampled=False, start_ts=start_ts,
+                        start=start)
+        return Span(trace_id or _new_id(), _new_id(), parent_id, name,
+                    attrs=attrs, lane=lane, start_ts=start_ts, start=start,
+                    tracer_=self)
 
     def record_span(self, name: str, start_ts: float, end_ts: float, *,
                     parent: Optional[Dict[str, Any]] = None,
@@ -271,13 +343,13 @@ class Tracer:
         return span_
 
     def _record(self, span_: Span) -> None:
-        rec = span_.to_dict()
         with self._lock:
-            self._buf.append(rec)
-        try:
-            _observe_derived(span_)
-        except Exception:  # noqa: BLE001 - metrics must not break tracing
-            pass
+            self._buf.append(span_)
+        if span_.name in _DERIVED_NAMES:
+            try:
+                _observe_derived(span_)
+            except Exception:  # noqa: BLE001 - metrics must not break tracing
+                pass
 
     # --------------------------------------------------------------- queries
 
@@ -286,9 +358,9 @@ class Tracer:
         with self._lock:
             out = [
                 s for s in self._buf
-                if trace_id is None or s["trace_id"] == trace_id
+                if trace_id is None or s.trace_id == trace_id
             ]
-        return out[-limit:]
+        return [s.to_dict() for s in out[-limit:]]
 
     def list_traces(self, limit: int = 100) -> List[Dict[str, Any]]:
         """Newest-last trace summaries: root name, span count, duration."""
@@ -296,21 +368,21 @@ class Tracer:
             snapshot = list(self._buf)
         traces: Dict[str, Dict[str, Any]] = {}
         for s in snapshot:
-            t = traces.setdefault(s["trace_id"], {
-                "trace_id": s["trace_id"],
-                "root": s["name"],
-                "start_ts": s["start_ts"],
-                "end_ts": s["end_ts"],
+            t = traces.setdefault(s.trace_id, {
+                "trace_id": s.trace_id,
+                "root": s.name,
+                "start_ts": s.start_ts,
+                "end_ts": s.end_ts,
                 "spans": 0,
                 "errors": 0,
             })
             t["spans"] += 1
-            t["start_ts"] = min(t["start_ts"], s["start_ts"])
-            t["end_ts"] = max(t["end_ts"], s["end_ts"])
-            if s["status"] != "OK":
+            t["start_ts"] = min(t["start_ts"], s.start_ts)
+            t["end_ts"] = max(t["end_ts"], s.end_ts)
+            if s.status != "OK":
                 t["errors"] += 1
-            if s["parent_id"] is None:
-                t["root"] = s["name"]
+            if s.parent_id is None:
+                t["root"] = s.name
         out = sorted(traces.values(), key=lambda t: t["start_ts"])
         for t in out:
             t["duration_s"] = max(0.0, t["end_ts"] - t["start_ts"])
@@ -363,21 +435,153 @@ def start_span(name: str, *, parent: Optional[Dict[str, Any]] = None,
     return tracer().start_span(name, parent=parent, attrs=attrs, lane=lane)
 
 
-@contextlib.contextmanager
+class _SpanScope:
+    """`with span(...)`: the span is current inside the block, mirrored
+    into the profile, and ended on the way out."""
+
+    __slots__ = ("span", "_token", "_annotation")
+
+    def __init__(self, span_: Span):
+        self.span = span_
+
+    def __enter__(self) -> Span:
+        sp = self.span
+        self._token = _current.set(sp.context)
+        # looked up for every span, recorded or not: the first look after
+        # JAX is imported is what registers the compile listener
+        annotation = _profile_annotation()
+        self._annotation = (
+            annotation(sp.name) if annotation is not None and sp.sampled else None)
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        return sp
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+        _current.reset(self._token)
+        if exc is None:
+            self.span.end()
+        else:
+            self.span.end(status="ERROR", error=repr(exc))
+
+
 def span(name: str, *, parent: Optional[Dict[str, Any]] = None,
-         lane: str = "", **attrs: Any) -> Iterator[Span]:
+         lane: str = "", start: Optional[Stamp] = None,
+         **attrs: Any) -> _SpanScope:
     """Open a span, make it the context-local current span, end it on
-    exit (status=ERROR with the exception repr on the error path)."""
-    sp = tracer().start_span(name, parent=parent, attrs=attrs, lane=lane)
-    token = _current.set(sp.context)
+    exit (status=ERROR with the exception repr on the error path). While
+    a JAX profile is being taken the block is also a host event of that
+    name in it."""
+    return _SpanScope(
+        tracer().start_span(name, parent=parent, attrs=attrs, lane=lane,
+                            start=start))
+
+
+# ------------------------------------------------------ the profile, compiles
+
+_trace_annotation = None    # jax.profiler.TraceAnnotation, once JAX is there
+
+
+def _profile_annotation():
+    """jax.profiler.TraceAnnotation if somebody has imported JAX, else
+    None: tracing never imports the accelerator stack. The first time it
+    is found, the compile listener is registered with it."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        jax = sys.modules.get("jax")
+        profiler = getattr(jax, "profiler", None)
+        if profiler is None:    # not imported, or still importing
+            return None
+        with _tracer_lock:
+            if _trace_annotation is None:
+                import jax.monitoring
+
+                jax.monitoring.register_event_duration_secs_listener(
+                    _on_jax_duration)
+                _trace_annotation = profiler.TraceAnnotation
+    return _trace_annotation
+
+
+# jax.monitoring duration event -> span kind
+_COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+# fires, before backend_compile_duration does, only when the executable
+# came from the persistent cache
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+
+class _CompileLog(threading.local):
+    """What one thread's compile events have added up to. Events of a
+    thread arrive by end time, an inner trace before the one that
+    contains it, so `open` keeps (start, seconds) of the spans no later
+    one has swallowed yet."""
+
+    def __init__(self) -> None:
+        self.cache_hit = False
+        self.open: List[Tuple[float, float]] = []
+
+
+_compile_log = _CompileLog()
+_compile_seconds = 0.0      # process-wide, nested spans counted once
+
+
+def compile_seconds() -> float:
+    """Seconds this process has spent tracing, lowering, compiling and
+    fetching programs so far; a trace inside a trace counts once."""
+    return _compile_seconds
+
+
+def _on_jax_duration(event: str, duration: float, **kwargs: Any) -> None:
+    """One compile-path event of JAX, as it ends on the compiling thread.
+    JAX calls this from inside the user's jit call: it must not raise."""
+    if event == _CACHE_HIT_EVENT:
+        _compile_log.cache_hit = True
+        return
+    kind = _COMPILE_EVENTS.get(event)
+    if kind is None:
+        return
+    if kind == "backend" and _compile_log.cache_hit:
+        kind, _compile_log.cache_hit = "cache_load", False
     try:
-        yield sp
-    except BaseException as exc:
-        sp.end(status="ERROR", error=repr(exc))
-        raise
-    finally:
-        _current.reset(token)
-        sp.end()
+        _record_compile(kind, duration, str(kwargs.get("fun_name", "")))
+    except Exception:  # noqa: BLE001 - tracing must not break a compile
+        pass
+
+
+def _record_compile(kind: str, duration: float, fun_name: str) -> None:
+    """A `compile.<kind>` span of that duration ending now, under the span
+    current here (so the parent says who compiled), and the counters."""
+    global _compile_seconds
+    wall, mono = time.time(), time.perf_counter()
+    sp = tracer().start_span(
+        "compile." + kind, attrs={"fun_name": fun_name},
+        start=(wall - duration, mono - duration))
+    sp.end()
+    inner, open_ = 0.0, _compile_log.open
+    while open_ and open_[-1][0] >= sp.start_mono:
+        inner += open_.pop()[1]
+    open_.append((sp.start_mono, duration))
+    del open_[:-4096]
+    with _tracer_lock:
+        _compile_seconds += duration - inner
+    from .metrics import get_or_create_counter
+
+    tags = {"kind": kind}
+    get_or_create_counter(
+        "raytpu_compile_total",
+        "Programs traced, lowered, built (backend) or fetched from the "
+        "persistent cache (cache_load), from jax.monitoring.",
+        tag_keys=("kind",)).inc(tags=tags)
+    get_or_create_counter(
+        "raytpu_compile_seconds_total",
+        "Seconds in jaxpr tracing, lowering, backend compiles and "
+        "persistent-cache fetches, by kind (a nested trace counts in its "
+        "own and in the enclosing one).",
+        tag_keys=("kind",)).inc(duration, tags=tags)
 
 
 # --------------------------------------------------------------- wire format
@@ -478,6 +682,8 @@ def export_chrome_trace(spans: List[Dict[str, Any]],
                 "span_id": s["span_id"],
                 "parent_id": s["parent_id"],
                 "status": s["status"],
+                "start_mono": s.get("start_mono"),
+                "end_mono": s.get("end_mono"),
                 **{k: v for k, v in s.get("attrs", {}).items()
                    if isinstance(v, (str, int, float, bool, type(None)))},
             },
@@ -487,19 +693,3 @@ def export_chrome_trace(spans: List[Dict[str, Any]],
         with open(path, "w") as f:
             f.write(payload)
     return payload
-
-
-# ------------------------------------------------- device-trace bridge
-
-
-def device_annotate(name: str):
-    """Label a host region in the XLA device trace (util/profiling
-    .annotate) so runtime spans line up with HLO activity — returns a
-    null context when jax isn't importable (tracing must never require
-    the accelerator stack)."""
-    try:
-        from .profiling import annotate
-
-        return annotate(name)
-    except Exception:  # noqa: BLE001 - tracing works without jax
-        return contextlib.nullcontext()
