@@ -89,7 +89,7 @@ def attn_kernel_bench() -> dict:
     n_fwd, n_bwd = 20, 8
     keys = jax.random.split(jax.random.PRNGKey(2), 3)
     q, k, v = (jax.random.normal(kk, (b, h, s, d), jnp.bfloat16) for kk in keys)
-    impl = resolve_attention_impl(d, s, s)
+    impl = resolve_attention_impl()
 
     def chain(n):
         def f(q, k, v):

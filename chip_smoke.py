@@ -49,7 +49,6 @@ _KERNEL_NAME = re.compile(r'kernel_name = "(\w+)"')
 TRAIN_KERNELS = {
     "xla": set(),
     "pallas": {"flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"},
-    "pallas_pipelined": {"flash_fwd_pipelined", "flash_bwd_dkv", "flash_bwd_dq"},
 }
 RAGGED_KERNEL_NAME = "ragged_paged_attention"
 GIB = float(1 << 30)
@@ -182,6 +181,19 @@ def phase_runtime(n_chips: int) -> dict:
 # -------------------------------------------------------------------- train
 
 
+def resolved_attention(config, seq: int, expect_impl: str) -> dict:
+    """The attention implementation a train step resolves to at `seq`,
+    which must be `expect_impl`, and how far its sub-tile walk engages."""
+    from ray_tpu.ops.attention import attention_plan
+
+    plan = attention_plan(seq, causal=config.causal,
+                          implementation=config.attn_impl)
+    check(plan["attention_impl"] == expect_impl,
+          f"attention resolved to {plan['attention_impl']!r}, the phase names "
+          f"{expect_impl!r}")
+    return plan
+
+
 def phase_train(config, *, batch: int, seq: int, steps: int, expect_impl: str,
                 cache: CompileCacheCounter) -> dict:
     """LMTrainer on a repeated seeded batch: the first step (compile), then
@@ -192,14 +204,10 @@ def phase_train(config, *, batch: int, seq: int, steps: int, expect_impl: str,
     import jax.numpy as jnp
     import numpy as np
 
-    from ray_tpu.ops.attention import resolve_attention_impl
     from ray_tpu.train.trainer import LMTrainer
 
-    impl = resolve_attention_impl(
-        config.head_dim, seq, seq, implementation=config.attn_impl
-    )
-    check(impl == expect_impl,
-          f"attention resolved to {impl!r}, the phase names {expect_impl!r}")
+    plan = resolved_attention(config, seq, expect_impl)
+    impl = plan["attention_impl"]
     trainer = LMTrainer(config, learning_rate=3e-4, total_steps=1000, seed=SEED)
     tokens = np.random.default_rng(SEED).integers(
         0, config.vocab_size, size=(batch, seq + 1), dtype=np.int32
@@ -232,7 +240,7 @@ def phase_train(config, *, batch: int, seq: int, steps: int, expect_impl: str,
         "model_params": trainer.num_params,
         "batch": batch,
         "seq": seq,
-        "attention_impl": impl,
+        **plan,  # attention_impl and how far its sub-tile walk engages
         "kernels_in_step_program": kernels,
         "loss_first": losses[0],
         "loss_last": losses[1],
@@ -536,6 +544,7 @@ def phase_train_sharded(config, mesh_specs, *, batch: int, seq: int,
     from ray_tpu.train.lm import default_optimizer
     from ray_tpu.train.trainer import LMTrainer
 
+    plan = resolved_attention(config, seq, expect_impl)
     tokens = np.random.default_rng(SEED).integers(
         0, config.vocab_size, size=(batch, seq + 1), dtype=np.int32
     )
@@ -616,7 +625,7 @@ def phase_train_sharded(config, mesh_specs, *, batch: int, seq: int,
         "n_layers": config.n_layers,
         "batch": batch,
         "seq": seq,
-        "attention_impl": expect_impl,
+        **plan,  # attention_impl and how far its sub-tile walk engages
         "total_param_bytes": total_param_bytes,
         "one_device_reference_loss": ref_loss,
         "tolerance": tol,
@@ -745,7 +754,7 @@ def four_chip_phases() -> list:
         run_phase(
             "train_sharded", phase_train_sharded, llama.replace(n_layers=2),
             [MeshSpec(fsdp=2, tp=2), MeshSpec(dp=2, fsdp=2)],
-            batch=8, seq=1024, expect_impl="pallas_pipelined",
+            batch=8, seq=1024, expect_impl="pallas",
         ),
         # float32 activations: the layouts compute the same sums, the
         # tokens must be equal

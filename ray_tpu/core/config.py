@@ -344,11 +344,6 @@ define_flag("profile_cost_accounting", True,
             "and engine ticks (pays one extra XLA compile per program).")
 
 # kernels & data-parallel collectives (PERF_NOTES.md round 6)
-define_flag("attn_pipeline", True,
-            "Use the double-buffered emit_pipeline flash-attention forward "
-            "on TPU backends where ops.attention.resolve_attention_impl's "
-            "static rule admits it (head_dim % 128 == 0, >= 2 kv tiles); "
-            "the classic kernel runs everywhere else.")
 define_flag("dp_allreduce_dtype", "f32",
             "Wire dtype of the data-parallel gradient sync: 'f32' (exact) "
             "or 'int8' (block-quantized all-reduce with error feedback).")

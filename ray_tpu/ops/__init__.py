@@ -7,9 +7,10 @@ TPU-first: Pallas kernels tiled for the MXU/VPU, with pure-XLA reference
 implementations that tests compare them with and that run off TPU.
 
 Dispatch convention: every op takes `implementation=` ("pallas" | "xla" |
-None). None is a static rule on backend and shape (attention.
-resolve_attention_impl, ragged_paged_attention.resolve_ragged_impl) — a
-kernel is never tried and swapped for the reference when it fails.
+None). None is a static rule (attention.resolve_attention_impl: the
+backend alone; ragged_paged_attention.resolve_ragged_impl: backend and
+shape) — a kernel is never tried and swapped for the reference when it
+fails.
 """
 
 from .attention import flash_attention, mha_reference  # noqa: F401

@@ -71,6 +71,205 @@ def mha_reference(
     return out.astype(q.dtype)
 
 
+# ------------------------------------------------- sub-tiles of a resident block
+#
+# The grid hands a kernel one (block_q, block_kv) tile of the score matrix
+# whose q, k, v (and do, lse, delta) rows are resident in VMEM: at S <= 1,024
+# that is the whole head, one grid step and one set of DMAs. The kernels do
+# not compute that tile in one piece. They walk it in strips of (sub_q,
+# sub_kv) sub-tiles read from the resident refs with `pl.ds`, and visit only
+# the sub-tiles that hold a live (query, key) pair: one wholly above the
+# causal diagonal or wholly beyond `kv_len` costs nothing, one wholly below
+# the diagonal and inside `kv_len` runs without the iota/compare/select
+# mask, and only the sub-tiles the diagonal or the `kv_len` edge crosses
+# build it. A grid step costs ~0.9 us of DMA issue and pipeline bookkeeping
+# (the reason small GRID blocks lost every sweep); a sub-tile costs its
+# compute and nothing else, so skipping one saves all of it.
+#
+# Along a strip the sub-tiles of one kind are adjacent (`_kv_range`,
+# `_q_range`: unmasked ones, then or before them the masked ones), so each
+# kind is one piece: one wide matmul and, in the forward, one softmax
+# update a strip, not one a sub-tile (a row-max and a row-sum are cross-lane
+# reductions, and an update per 256-wide sub-tile made the forward slower
+# than the unwalked block: PERF.md section 6, PR 26). `attention_subtiles`
+# counts with the same two functions.
+#
+# The walk needs the strip's place against the diagonal while tracing, so
+# it engages with one grid tile a head. With more (S > 1,024, explicit
+# small blocks) a grid tile is its own single sub-tile whose offsets are
+# traced scalars: live or not is then decided per grid step
+# (`_walk_strip`), which is the grid-level skip the kernels always had.
+
+# (sub_q, sub_kv) of the walk. One chip sweep over {128, 256, 512} a side
+# and kernel, at D = 64 and D = 128 (PERF.md section 6, PR 26): 256 x 256
+# won, or lost by under 1%, in all three kernels at both widths, so the
+# size depends on nothing a call can observe but its block.
+_SUB_TILE = (256, 256)
+
+
+def _sub_tiles(block_q: int, block_kv: int, grid_tiles: int):
+    """Sub-tile shape of the walk over a (block_q, block_kv) block, one of
+    `grid_tiles` a head. A block the sub-tile does not divide (it is
+    smaller, or an odd length) and a block of a larger grid are their own
+    single sub-tile."""
+    sub_q, sub_kv = _SUB_TILE
+    if grid_tiles > 1:
+        return block_q, block_kv
+    return (sub_q if block_q % sub_q == 0 else block_q,
+            sub_kv if block_kv % sub_kv == 0 else block_kv)
+
+
+def _kv_range(row0: int, col0: int, n: int, sub_q: int, sub_kv: int,
+              causal: bool, kv_len: int):
+    """For the queries [row0, row0 + sub_q) and the n kv sub-tiles
+    [col0 + c * sub_kv, + sub_kv): (full, live). Sub-tiles c < full hold
+    only live pairs, full <= c < live hold live and masked ones (the
+    diagonal or the kv_len edge crosses them), c >= live hold none."""
+    live = -((col0 - kv_len) // sub_kv)  # ceil
+    full = (kv_len - col0) // sub_kv
+    if causal:
+        live = min(live, (row0 + sub_q - 1 - col0) // sub_kv + 1)
+        full = min(full, (row0 + 1 - col0) // sub_kv)
+    live = max(0, min(live, n))
+    return max(0, min(full, live)), live
+
+
+def _q_range(row0: int, col0: int, n: int, sub_q: int, sub_kv: int,
+             causal: bool, kv_len: int):
+    """The same set seen from the keys [col0, col0 + sub_kv) over the n q
+    sub-blocks [row0 + a * sub_q, + sub_q): (first_live, first_full).
+    Sub-blocks a < first_live hold no live pair, first_live <= a <
+    first_full hold live and masked ones, a >= first_full only live."""
+    first_live = (col0 - row0) // sub_q if causal else 0
+    first_full = -((row0 - col0 - sub_kv + 1) // sub_q) if causal else 0
+    if col0 >= kv_len:  # beyond kv_len: never live
+        first_live = n
+    if col0 + sub_kv > kv_len:  # the edge crosses it: masked for every query
+        first_full = n
+    first_live = max(0, min(first_live, n))
+    return first_live, max(first_live, min(first_full, n))
+
+
+def attention_subtiles(sq: int, skv: int, causal: bool, kv_len: int,
+                       block_q: int, block_kv: int, sub_q: int, sub_kv: int):
+    """(visited, masked, total) sub-tiles of one head's (sq, skv) score
+    matrix as the kernels walk it: `visited` run their matmuls, `masked`
+    of those build the mask, `total` is what a dense walk would visit.
+    Counted with the kernels' own loop bounds."""
+    visited = masked = 0
+    for i in range(sq // block_q):
+        for j in range(skv // block_kv):
+            for a in range(block_q // sub_q):
+                full, live = _kv_range(
+                    i * block_q + a * sub_q, j * block_kv, block_kv // sub_kv,
+                    sub_q, sub_kv, causal, kv_len)
+                visited += live
+                masked += live - full
+    if sq // block_q * (skv // block_kv) > 1:
+        masked = visited  # traced offsets: `_walk_strip` masks every live tile
+    return visited, masked, (sq // sub_q) * (skv // sub_kv)
+
+
+def _static(*xs) -> bool:
+    return all(isinstance(x, int) for x in xs)
+
+
+def _walk_strip(row0, col0, n, sub_q, sub_kv, causal, kv_len, along, visit,
+                always):
+    """`visit(pieces)` for the live sub-tiles of one strip, as runs (lo, hi,
+    is_masked) of sub-tile indices along the strip: the n kv sub-tiles
+    from col0 against the queries [row0, row0 + sub_q) (`along` "kv"), or
+    the n q sub-blocks from row0 against the keys [col0, col0 + sub_kv)
+    (`along` "q"). No live sub-tile: no call, unless `always` (the
+    strip's output is then the visitor's to write).
+
+    Static offsets (one grid tile a head): one call with the non-empty
+    runs. Traced offsets (the strip is the one sub-tile of a grid tile):
+    live or not is a comparison per grid step, and a live tile is computed
+    masked, as every grid tile was before the walk."""
+    if _static(row0, col0):
+        if along == "kv":
+            full, live = _kv_range(row0, col0, n, sub_q, sub_kv, causal, kv_len)
+            runs = ((0, full, False), (full, live, True))
+        else:
+            first_live, first_full = _q_range(
+                row0, col0, n, sub_q, sub_kv, causal, kv_len)
+            runs = ((first_full, n, False), (first_live, first_full, True))
+        pieces = [run for run in runs if run[1] > run[0]]
+        if pieces or always:
+            visit(pieces)
+        return
+    assert n == 1, "a strip with traced offsets is a single sub-tile"
+    live = col0 < kv_len
+    if causal:
+        live = live & (col0 <= row0 + sub_q - 1)
+    pl.when(live)(lambda: visit([(0, 1, True)]))
+    if always:
+        pl.when(jnp.logical_not(live))(lambda: visit([]))
+
+
+def _grid_tile(num_q_blocks, num_kv_blocks, q_axis, kv_axis):
+    """(i, j) of this grid step; the int 0 on an axis of one block, which
+    is what makes the walk's bounds static."""
+    i = pl.program_id(q_axis) if num_q_blocks > 1 else 0
+    j = pl.program_id(kv_axis) if num_kv_blocks > 1 else 0
+    return i, j
+
+
+def _scores(q, k, scale, mask_at, causal, kv_len):
+    """QK^T of one piece in the base-2 log domain. `mask_at` is None for
+    a piece of live pairs only, else its (row0, col0)."""
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * scale
+    if mask_at is None:
+        return s
+    row0, col0 = mask_at
+    col = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    mask = col < kv_len
+    if causal:
+        row = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        mask = mask & (col <= row)
+    return jnp.where(mask, s, _NEG_INF)
+
+
+def _softmax_pieces(scores, values, carry=None):
+    """(m, l, acc) of one softmax step over the score pieces `scores` (f32,
+    base-2 domain; at least one) and their value rows `values`: from
+    nothing, or online on top of `carry` = (m, l, read_acc) of earlier kv
+    (`read_acc()` loads the accumulator, late: it then does not live
+    across the matmuls)."""
+    m = None if carry is None else carry[0]
+    for s in scores:
+        m_cur = jnp.max(s, axis=-1, keepdims=True)
+        m = m_cur if m is None else jnp.maximum(m, m_cur)
+    l = acc = None
+    for s, v in zip(scores, values):
+        p = jnp.exp2(s - m)
+        l_cur = jnp.sum(p, axis=-1, keepdims=True)
+        acc_cur = jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        l = l_cur if l is None else l + l_cur
+        acc = acc_cur if acc is None else acc + acc_cur
+    if carry is not None:
+        alpha = jnp.exp2(carry[0] - m)
+        l, acc = alpha * carry[1] + l, carry[2]() * alpha + acc
+    return m, l, acc
+
+
+def _write_out(o_ref, lse_ref, rows, m, l, acc):
+    """Rows `rows` of the output and of its natural-log lse residual."""
+    safe_l = jnp.where(l == 0.0, 1.0, l)
+    o_ref[0, 0, rows, :] = (acc / safe_l).astype(o_ref.dtype)
+    # rows no sub-tile reached (kv_len == 0) get -inf. Stored as (..., S, 1):
+    # a (block_q, 1) block satisfies the Mosaic last-two-dims tiling rule,
+    # a bare (block_q,) block does not.
+    lse_ref[0, 0, rows, :] = jnp.where(
+        l == 0.0, _NEG_INF, (m + jnp.log2(safe_l)) * (1.0 / _LOG2E))
+
+
 # -------------------------------------------------------------- pallas forward
 
 
@@ -80,74 +279,69 @@ def _fwd_kernel(
     v_ref,
     o_ref,
     lse_ref,
-    m_scr,
-    l_scr,
-    acc_scr,
-    *,
+    *scratch,
     sm_scale: float,
     causal: bool,
     block_q: int,
     block_kv: int,
+    sub_q: int,
+    sub_kv: int,
     kv_len: int,
+    num_q_blocks: int,
     num_kv_blocks: int,
 ):
-    i = pl.program_id(2)
-    j = pl.program_id(3)
+    i, j = _grid_tile(num_q_blocks, num_kv_blocks, 2, 3)
+    n = block_kv // sub_kv
+    # one kv block a row: a strip's softmax is whole and goes straight to
+    # the output. More: (m, l, acc) ride in scratch across the kv axis.
+    carried = num_kv_blocks > 1
+    if carried:
+        m_scr, l_scr, acc_scr = scratch
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        def _init():
+            m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+            l_scr[...] = jnp.zeros_like(l_scr)
+            acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # Causal: kv blocks strictly above the diagonal band contribute nothing.
-    needed = True
-    if causal:
-        needed = j * block_kv <= i * block_q + (block_q - 1)
+        pl.when(j == 0)(_init)
 
-    @pl.when(needed)
-    def _compute():
-        q = q_ref[0, 0]  # (block_q, d)
-        k = k_ref[0, 0]  # (block_kv, d)
-        v = v_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        s = s * (sm_scale * _LOG2E)  # base-2 log domain
+    # per strip of queries: one softmax step over its live kv sub-tiles.
+    # Causal: a strip (with a grid of tiles, the whole tile) above the
+    # diagonal has none.
+    for a in range(block_q // sub_q):
+        row0 = i * block_q + a * sub_q
+        rows = pl.ds(a * sub_q, sub_q)
 
-        col = j * block_kv + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = col < kv_len
-        if causal:
-            row = i * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            mask = mask & (col <= row)
-        s = jnp.where(mask, s, _NEG_INF)
+        def visit(pieces, row0=row0, rows=rows):
+            if not pieces:  # nothing live and nothing carried: kv_len == 0
+                o_ref[0, 0, rows, :] = jnp.zeros((sub_q, o_ref.shape[-1]), o_ref.dtype)
+                lse_ref[0, 0, rows, :] = jnp.full((sub_q, 1), _NEG_INF, jnp.float32)
+                return
+            q = q_ref[0, 0, rows, :]
+            scores, values = [], []
+            for lo, hi, masked in pieces:
+                cols = pl.ds(lo * sub_kv, (hi - lo) * sub_kv)
+                scores.append(_scores(
+                    q, k_ref[0, 0, cols, :], sm_scale * _LOG2E,
+                    (row0, j * block_kv + lo * sub_kv) if masked else None,
+                    causal, kv_len))
+                values.append(v_ref[0, 0, cols, :])
+            if not carried:
+                _write_out(o_ref, lse_ref, rows, *_softmax_pieces(scores, values))
+                return
+            m, l, acc = _softmax_pieces(
+                scores, values,
+                (m_scr[rows, :1], l_scr[rows, :1], lambda: acc_scr[rows, :]))
+            acc_scr[rows, :] = acc
+            m_scr[rows, :] = jnp.broadcast_to(m, (sub_q, m_scr.shape[1]))
+            l_scr[rows, :] = jnp.broadcast_to(l, (sub_q, l_scr.shape[1]))
 
-        m_prev = m_scr[:, :1]
-        l_prev = l_scr[:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp2(s - m_new)
-        alpha = jnp.exp2(m_prev - m_new)
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        _walk_strip(row0, j * block_kv, n, sub_q, sub_kv, causal, kv_len, "kv",
+                    visit, always=not carried)
 
-    @pl.when(j == num_kv_blocks - 1)
-    def _finalize():
-        l = l_scr[:, :1]
-        safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
-        # logsumexp residual for the backward pass; fully-masked rows get -inf.
-        # Stored as (..., S, 1) — a (block_q, 1) block satisfies the Mosaic
-        # last-two-dims tiling rule, a bare (block_q,) block does not.
-        lse_ref[0, 0] = jnp.where(
-            l == 0.0, _NEG_INF,
-            (m_scr[:, :1] + jnp.log2(safe_l)) * (1.0 / _LOG2E),
-        )
+    if carried:
+        pl.when(j == num_kv_blocks - 1)(lambda: _write_out(
+            o_ref, lse_ref, slice(None), m_scr[:, :1], l_scr[:, :1], acc_scr[...]))
 
 
 def _fwd_pallas(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret):
@@ -156,6 +350,7 @@ def _fwd_pallas(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret)
     groups = hq // hkv
     nq = sq // block_q
     nk = skv // block_kv
+    sub_q, sub_kv = _sub_tiles(block_q, block_kv, nq * nk)
 
     kernel = functools.partial(
         _fwd_kernel,
@@ -163,7 +358,10 @@ def _fwd_pallas(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret)
         causal=causal,
         block_q=block_q,
         block_kv=block_kv,
+        sub_q=sub_q,
+        sub_kv=sub_kv,
         kv_len=kv_len,
+        num_q_blocks=nq,
         num_kv_blocks=nk,
     )
     out, lse = pl.pallas_call(
@@ -190,7 +388,7 @@ def _fwd_pallas(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret)
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
-        ],
+        ] if nk > 1 else [],
         interpret=interpret,
         name="flash_fwd",
     )(q, k, v)
@@ -204,116 +402,150 @@ def _fwd_pallas(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret)
 #   dq  kernel: grid q-outer / kv-inner, accumulates dQ_i across kv blocks
 # P is recomputed from (q, k, lse); delta = rowsum(dO * O) is cheap in XLA.
 # GQA is handled in the wrapper (repeat kv, then segment-sum dk/dv) — the
-# kernels always see Hq == Hkv.
+# kernels always see Hq == Hkv. Inside a grid tile both walk sub-tiles as
+# the forward does: dkv per kv sub-tile over the q sub-blocks from the
+# diagonal down, dq per q sub-block over the kv sub-tiles up to it.
+
+
+def _probs(q, k, lse, sm_scale, mask_at, causal, kv_len):
+    """One piece's probabilities, rebuilt from the forward's lse."""
+    s = _scores(q, k, sm_scale * _LOG2E, mask_at, causal, kv_len)
+    return jnp.exp2(s - lse * _LOG2E)
+
+
+def _ds(p, do, v, delta, sm_scale):
+    """dP = dO V^T ; dS = P * (dP - delta)."""
+    dp = jax.lax.dot_general(
+        do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    return p * (dp - delta) * sm_scale
+
+
+def _accumulate(out_ref, scr, carried, where, value):
+    """A strip's gradient rows `where` (a pl.ds): straight to the output
+    when this grid step is the only one that adds to them, else added in
+    scratch. `value` is None for a strip with nothing live."""
+    if carried:
+        if value is not None:
+            scr[where, :] += value
+    elif value is None:
+        out_ref[0, 0, where, :] = jnp.zeros(
+            (where.size, out_ref.shape[-1]), out_ref.dtype)
+    else:
+        out_ref[0, 0, where, :] = value.astype(out_ref.dtype)
 
 
 def _dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-    dk_ref, dv_ref, dk_scr, dv_scr,
-    *, sm_scale, causal, block_q, block_kv, kv_len, num_q_blocks,
+    dk_ref, dv_ref, *scratch,
+    sm_scale, causal, block_q, block_kv, sub_q, sub_kv, kv_len,
+    num_q_blocks, num_kv_blocks,
 ):
-    j = pl.program_id(2)  # kv block (outer)
-    i = pl.program_id(3)  # q block (inner)
+    # grid: kv block outer (axis 2), q block inner (axis 3)
+    i, j = _grid_tile(num_q_blocks, num_kv_blocks, 3, 2)
+    n = block_q // sub_q
+    carried = num_q_blocks > 1  # dK_j, dV_j summed over q blocks in scratch
+    dk_scr, dv_scr = scratch if carried else (None, None)
+    if carried:
+        def _init():
+            dk_scr[...] = jnp.zeros_like(dk_scr)
+            dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    @pl.when(i == 0)
-    def _init():
-        dk_scr[:] = jnp.zeros_like(dk_scr)
-        dv_scr[:] = jnp.zeros_like(dv_scr)
+        pl.when(i == 0)(_init)
 
-    needed = True
-    if causal:
-        needed = j * block_kv <= i * block_q + (block_q - 1)
+    for c in range(block_kv // sub_kv):
+        col0 = j * block_kv + c * sub_kv
+        cols = pl.ds(c * sub_kv, sub_kv)
 
-    @pl.when(needed)
-    def _compute():
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
-        do = do_ref[0, 0]
-        lse = lse_ref[0, 0]  # (block_q, 1)
-        delta = delta_ref[0, 0]
+        def visit(pieces, col0=col0, cols=cols):
+            k = k_ref[0, 0, cols, :]
+            v = v_ref[0, 0, cols, :]
+            dk = dv = None
+            for lo, hi, masked in pieces:
+                rows = pl.ds(lo * sub_q, (hi - lo) * sub_q)
+                q = q_ref[0, 0, rows, :]
+                do = do_ref[0, 0, rows, :]
+                p = _probs(
+                    q, k, lse_ref[0, 0, rows, :], sm_scale,
+                    (i * block_q + lo * sub_q, col0) if masked else None,
+                    causal, kv_len)
+                # dV_j += P^T dO
+                dv_cur = jax.lax.dot_general(
+                    p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                ds = _ds(p, do, v, delta_ref[0, 0, rows, :], sm_scale)
+                # dK_j += dS^T Q
+                dk_cur = jax.lax.dot_general(
+                    ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                dv = dv_cur if dv is None else dv + dv_cur
+                dk = dk_cur if dk is None else dk + dk_cur
+            _accumulate(dv_ref, dv_scr, carried, cols, dv)
+            _accumulate(dk_ref, dk_scr, carried, cols, dk)
 
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * (sm_scale * _LOG2E)
-        col = j * block_kv + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = col < kv_len
-        if causal:
-            row = i * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            mask = mask & (col <= row)
-        s = jnp.where(mask, s, _NEG_INF)
-        p = jnp.exp2(s - lse * _LOG2E)  # (block_q, block_kv)
+        _walk_strip(i * block_q, col0, n, sub_q, sub_kv, causal, kv_len, "q",
+                    visit, always=not carried)
 
-        # dV_j += P^T dO
-        dv_scr[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        # dP = dO V^T ; dS = P * (dP - delta)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta) * sm_scale
-        # dK_j += dS^T Q
-        dk_scr[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+    if carried:
+        def _finalize():
+            dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
+            dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
 
-    @pl.when(i == num_q_blocks - 1)
-    def _finalize():
-        dk_ref[0, 0] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
+        pl.when(i == num_q_blocks - 1)(_finalize)
 
 
 def _dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-    dq_ref, dq_scr,
-    *, sm_scale, causal, block_q, block_kv, kv_len, num_kv_blocks,
+    dq_ref, *scratch,
+    sm_scale, causal, block_q, block_kv, sub_q, sub_kv, kv_len,
+    num_q_blocks, num_kv_blocks,
 ):
-    i = pl.program_id(2)  # q block (outer)
-    j = pl.program_id(3)  # kv block (inner)
+    # grid: q block outer (axis 2), kv block inner (axis 3)
+    i, j = _grid_tile(num_q_blocks, num_kv_blocks, 2, 3)
+    n = block_kv // sub_kv
+    carried = num_kv_blocks > 1  # dQ_i summed over kv blocks in scratch
+    (dq_scr,) = scratch if carried else (None,)
+    if carried:
+        def _init():
+            dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    @pl.when(j == 0)
-    def _init():
-        dq_scr[:] = jnp.zeros_like(dq_scr)
+        pl.when(j == 0)(_init)
 
-    needed = True
-    if causal:
-        needed = j * block_kv <= i * block_q + (block_q - 1)
+    for a in range(block_q // sub_q):
+        row0 = i * block_q + a * sub_q
+        rows = pl.ds(a * sub_q, sub_q)
 
-    @pl.when(needed)
-    def _compute():
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
-        do = do_ref[0, 0]
-        lse = lse_ref[0, 0]  # (block_q, 1)
-        delta = delta_ref[0, 0]
+        def visit(pieces, row0=row0, rows=rows):
+            q = q_ref[0, 0, rows, :]
+            do = do_ref[0, 0, rows, :]
+            lse = lse_ref[0, 0, rows, :]  # (sub_q, 1)
+            delta = delta_ref[0, 0, rows, :]
+            dq = None
+            for lo, hi, masked in pieces:
+                cols = pl.ds(lo * sub_kv, (hi - lo) * sub_kv)
+                k = k_ref[0, 0, cols, :]
+                p = _probs(
+                    q, k, lse, sm_scale,
+                    (row0, j * block_kv + lo * sub_kv) if masked else None,
+                    causal, kv_len)
+                ds = _ds(p, do, v_ref[0, 0, cols, :], delta, sm_scale)
+                dq_cur = jax.lax.dot_general(
+                    ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                dq = dq_cur if dq is None else dq + dq_cur
+            _accumulate(dq_ref, dq_scr, carried, rows, dq)
 
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * (sm_scale * _LOG2E)
-        col = j * block_kv + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = col < kv_len
-        if causal:
-            row = i * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            mask = mask & (col <= row)
-        s = jnp.where(mask, s, _NEG_INF)
-        p = jnp.exp2(s - lse * _LOG2E)
+        _walk_strip(row0, j * block_kv, n, sub_q, sub_kv, causal, kv_len, "kv",
+                    visit, always=not carried)
 
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta) * sm_scale
-        dq_scr[:] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+    if carried:
+        def _finalize():
+            dq_ref[0, 0] = dq_scr[...].astype(dq_ref.dtype)
 
-    @pl.when(j == num_kv_blocks - 1)
-    def _finalize():
-        dq_ref[0, 0] = dq_scr[:].astype(dq_ref.dtype)
+        pl.when(j == num_kv_blocks - 1)(_finalize)
 
 
 def _bwd_pallas(q, k, v, out, lse, do, causal, sm_scale, block_q, block_kv, kv_len, interpret):
@@ -327,16 +559,21 @@ def _bwd_pallas(q, k, v, out, lse, do, causal, sm_scale, block_q, block_kv, kv_l
         do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1, keepdims=True
     )
 
+    sub_q, sub_kv = _sub_tiles(block_q, block_kv, nq * nk)
+
+    def kernel(fn):
+        return functools.partial(
+            fn, sm_scale=sm_scale, causal=causal, block_q=block_q,
+            block_kv=block_kv, sub_q=sub_q, sub_kv=sub_kv, kv_len=kv_len,
+            num_q_blocks=nq, num_kv_blocks=nk,
+        )
+
     q_spec = pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, j, i: (b_, h_, i, 0))
     kv_spec = pl.BlockSpec((1, 1, block_kv, d), lambda b_, h_, j, i: (b_, h_, j, 0))
     row_spec = pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, j, i: (b_, h_, i, 0))
 
-    dkv_kernel = functools.partial(
-        _dkv_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
-        block_kv=block_kv, kv_len=kv_len, num_q_blocks=nq,
-    )
     dk, dv = pl.pallas_call(
-        dkv_kernel,
+        kernel(_dkv_kernel),
         grid=(b, h, nk, nq),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
         out_specs=[kv_spec, kv_spec],
@@ -347,7 +584,7 @@ def _bwd_pallas(q, k, v, out, lse, do, causal, sm_scale, block_q, block_kv, kv_l
         scratch_shapes=[
             pltpu.VMEM((block_kv, d), jnp.float32),
             pltpu.VMEM((block_kv, d), jnp.float32),
-        ],
+        ] if nq > 1 else [],
         interpret=interpret,
         name="flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
@@ -356,17 +593,13 @@ def _bwd_pallas(q, k, v, out, lse, do, causal, sm_scale, block_q, block_kv, kv_l
     kv_spec2 = pl.BlockSpec((1, 1, block_kv, d), lambda b_, h_, i, j: (b_, h_, j, 0))
     row_spec2 = pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, i, j: (b_, h_, i, 0))
 
-    dq_kernel = functools.partial(
-        _dq_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
-        block_kv=block_kv, kv_len=kv_len, num_kv_blocks=nk,
-    )
     dq = pl.pallas_call(
-        dq_kernel,
+        kernel(_dq_kernel),
         grid=(b, h, nq, nk),
         in_specs=[q_spec2, kv_spec2, kv_spec2, q_spec2, row_spec2, row_spec2],
         out_specs=q_spec2,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)] if nk > 1 else [],
         interpret=interpret,
         name="flash_bwd_dq",
     )(q, k, v, do, lse, delta)
@@ -411,324 +644,57 @@ def _flash_bwd(causal, sm_scale, block_q, block_kv, kv_len, interpret, res, do):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-# ------------------------------------------------- pipelined forward kernel
-#
-# The classic forward above runs, per (q, kv) tile: QK^T (MXU) -> online
-# softmax (VPU) -> PV (MXU) — a serial dependency chain that parks the MXU
-# through the whole softmax. The pipelined forward breaks the chain with a
-# one-step software skew over the kv-tile loop: inner step t issues tile
-# t's QK^T while the online softmax/rescale for tile t-1 runs, so the two
-# stages have no data dependency inside one step and Mosaic can overlap
-# the MXU and VPU chains.
-#
-# On TPU the kv tiles stream HBM->VMEM through pltpu.emit_pipeline (explicit
-# double buffering; q and the accumulators stay VMEM-resident across the
-# whole row instead of being re-fetched per (i, j) grid step like the
-# classic 4D grid does). Off-TPU an interpret-mode driver executes the SAME
-# stage functions and slot arithmetic inside a fori_loop — the numerics of
-# both drivers are identical by construction, and bit-identical to the
-# classic kernel: tile math and accumulation order are unchanged, only the
-# schedule moves. tests/test_ops.py pins that equality at f32.
-#
-# What the v5e compiler accepts (tests/test_tpu_compile.py): the streamed
-# (1, 1, block_kv, D) tile must be lane-aligned, so D % 128 == 0 — at
-# D = 64 Mosaic refuses the slice and `resolve_attention_impl` picks the
-# classic kernel. There is no pipelined backward: its streamed
-# (block_q, 1) lse/delta tiles were refused at every width, so the
-# pipelined forward's residuals (same out/lse, bit for bit) feed the
-# classic dkv/dq kernels.
-
-
-def _fwd_stages(sm_scale, causal, block_q, block_kv, kv_len):
-    """Per-tile forward stages. `scores` is the MXU stage (QK^T + mask),
-    `online_update` the VPU-heavy stage (online softmax + PV rescale).
-    Expressions mirror _fwd_kernel exactly — bit-compatibility depends on
-    it."""
-
-    def scores(q, k, i, t):
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        s = s * (sm_scale * _LOG2E)
-        col = t * block_kv + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = col < kv_len
-        if causal:
-            row = i * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            mask = mask & (col <= row)
-        return jnp.where(mask, s, _NEG_INF)
-
-    def online_update(s, v, m_scr, l_scr, acc_scr):
-        m_prev = m_scr[:, :1]
-        l_prev = l_scr[:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp2(s - m_new)
-        alpha = jnp.exp2(m_prev - m_new)
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
-
-    return scores, online_update
-
-
-def _num_kv_tiles(i, causal, block_q, block_kv, nk):
-    """kv tiles query block i touches (causal block skipping, same set the
-    classic kernel's `needed` predicate admits)."""
-    if not causal:
-        return nk
-    last = (i * block_q + block_q - 1) // block_kv
-    return jnp.minimum(last + 1, nk)
-
-
-def _fwd_finalize(o_ref, lse_ref, m_scr, l_scr, acc_scr):
-    l = l_scr[:, :1]
-    safe_l = jnp.where(l == 0.0, 1.0, l)
-    o_ref[0, 0] = (acc_scr[...] / safe_l).astype(o_ref.dtype)
-    lse_ref[0, 0] = jnp.where(
-        l == 0.0, _NEG_INF,
-        (m_scr[:, :1] + jnp.log2(safe_l)) * (1.0 / _LOG2E),
-    )
-
-
-def _fwd_kernel_pipe_interp(
-    q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, s_scr,
-    *, sm_scale, causal, block_q, block_kv, kv_len, num_kv_blocks,
-):
-    """Interpret-mode driver: the emit_pipeline schedule (skewed stages,
-    double-buffered score slots) replayed in a fori_loop with whole-row k/v
-    resident."""
-    i = pl.program_id(2)
-    scores, online_update = _fwd_stages(sm_scale, causal, block_q, block_kv, kv_len)
-    m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
-    l_scr[...] = jnp.zeros_like(l_scr)
-    acc_scr[...] = jnp.zeros_like(acc_scr)
-    q = q_ref[0, 0]
-    tiles = _num_kv_tiles(i, causal, block_q, block_kv, num_kv_blocks)
-
-    def body(t, carry):
-        @pl.when(t < tiles)
-        def _stage_a():  # QK^T for tile t
-            kt = k_ref[0, 0, pl.ds(t * block_kv, block_kv), :]
-            s_scr[t % 2] = scores(q, kt, i, t)
-
-        @pl.when(t > 0)
-        def _stage_b():  # online softmax + PV for tile t-1
-            vt = v_ref[0, 0, pl.ds((t - 1) * block_kv, block_kv), :]
-            online_update(s_scr[(t - 1) % 2], vt, m_scr, l_scr, acc_scr)
-
-        return carry
-
-    jax.lax.fori_loop(0, tiles + 1, body, 0)
-    _fwd_finalize(o_ref, lse_ref, m_scr, l_scr, acc_scr)
-
-
-def _fwd_pipe_interp(q, k, v, causal, sm_scale, block_q, block_kv, kv_len):
-    b, hq, sq, d = q.shape
-    _, hkv, skv, _ = k.shape
-    groups = hq // hkv
-    nq = sq // block_q
-    nk = skv // block_kv
-    kernel = functools.partial(
-        _fwd_kernel_pipe_interp, sm_scale=sm_scale, causal=causal,
-        block_q=block_q, block_kv=block_kv, kv_len=kv_len, num_kv_blocks=nk,
-    )
-    return pl.pallas_call(
-        kernel,
-        grid=(b, hq, nq),
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, d), lambda b_, h, i: (b_, h, i, 0)),
-            pl.BlockSpec((1, 1, skv, d), lambda b_, h, i, g=groups: (b_, h // g, 0, 0)),
-            pl.BlockSpec((1, 1, skv, d), lambda b_, h, i, g=groups: (b_, h // g, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_q, d), lambda b_, h, i: (b_, h, i, 0)),
-            pl.BlockSpec((1, 1, block_q, 1), lambda b_, h, i: (b_, h, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((b, hq, sq, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((2, block_q, block_kv), jnp.float32),
-        ],
-        interpret=True,
-        name="flash_fwd_pipelined",
-    )(q, k, v)
-
-
-def _fwd_pipe_tpu(q, k, v, causal, sm_scale, block_q, block_kv, kv_len):
-    """emit_pipeline driver: q/accumulators VMEM-resident per (b, h, i) row;
-    kv tiles stream HBM->VMEM double-buffered, v delivered one step behind k
-    so stage B always has the tile stage A scored on the previous step."""
-    b, hq, sq, d = q.shape
-    _, hkv, skv, _ = k.shape
-    groups = hq // hkv
-    nq = sq // block_q
-    nk = skv // block_kv
-
-    def outer(q_ref, k_hbm, v_hbm, o_ref, lse_ref, m_scr, l_scr, acc_scr, s_scr):
-        bi = pl.program_id(0)
-        hi = pl.program_id(1)
-        i = pl.program_id(2)
-        hk = hi // groups
-        scores, online_update = _fwd_stages(
-            sm_scale, causal, block_q, block_kv, kv_len
-        )
-        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-        q_blk = q_ref[0, 0]
-        tiles = _num_kv_tiles(i, causal, block_q, block_kv, nk)
-
-        def inner(k_ref, v_ref):
-            t = pl.program_id(0)
-
-            @pl.when(t < tiles)
-            def _stage_a():
-                s_scr[t % 2] = scores(q_blk, k_ref[0, 0], i, t)
-
-            @pl.when(t > 0)
-            def _stage_b():
-                online_update(s_scr[(t - 1) % 2], v_ref[0, 0], m_scr, l_scr, acc_scr)
-
-        pipeline = pltpu.emit_pipeline(
-            inner,
-            grid=(tiles + 1,),
-            in_specs=[
-                pl.BlockSpec(
-                    (1, 1, block_kv, d),
-                    lambda t: (bi, hk, jnp.minimum(t, nk - 1), 0),
-                ),
-                pl.BlockSpec(
-                    (1, 1, block_kv, d),
-                    lambda t: (bi, hk, jnp.maximum(t - 1, 0), 0),
-                ),
-            ],
-            out_specs=[],
-        )
-        pipeline(k_hbm, v_hbm)
-        _fwd_finalize(o_ref, lse_ref, m_scr, l_scr, acc_scr)
-
-    return pl.pallas_call(
-        outer,
-        grid=(b, hq, nq),
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, d), lambda b_, h, i: (b_, h, i, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_q, d), lambda b_, h, i: (b_, h, i, 0)),
-            pl.BlockSpec((1, 1, block_q, 1), lambda b_, h, i: (b_, h, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((b, hq, sq, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((2, block_q, block_kv), jnp.float32),
-        ],
-        name="flash_fwd_pipelined",
-    )(q, k, v)
-
-
-def _fwd_pipe(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret):
-    if interpret:
-        return _fwd_pipe_interp(q, k, v, causal, sm_scale, block_q, block_kv, kv_len)
-    return _fwd_pipe_tpu(q, k, v, causal, sm_scale, block_q, block_kv, kv_len)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash_pipelined(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret):
-    out, _ = _fwd_pipe(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret)
-    return out
-
-
-def _flash_pipelined_fwd(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret):
-    out, lse = _fwd_pipe(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret)
-    return out, (q, k, v, out, lse)
-
-
-_flash_pipelined.defvjp(_flash_pipelined_fwd, _flash_bwd)
-
-
 # ------------------------------------------------------------------ public API
 
 
-_PIPE_BLOCK_KV = 256  # stream tile: >=2 tiles in flight is what buys overlap
-_IMPLEMENTATIONS = ("xla", "pallas", "pallas_pipelined")
+_IMPLEMENTATIONS = ("xla", "pallas")
 
 
-def _pipe_blocks(sq: int, skv: int, block_q: Optional[int], block_kv: Optional[int]):
-    """Pipelined defaults: whole-row q tiles (q stays VMEM-resident), small
-    streaming kv tiles. Returns None if the shape leaves <2 kv tiles —
-    nothing to overlap, the classic single-block kernel is the right tool."""
-    bq = min(block_q or 1024, max(sq, 1))
-    bkv = min(block_kv or _PIPE_BLOCK_KV, max(skv, 1))
-    padded_skv = skv + ((-skv) % bkv)
-    if padded_skv // bkv < 2:
-        return None
-    return bq, bkv
-
-
-def _blocks(implementation: str, sq: int, skv: int,
-            block_q: Optional[int], block_kv: Optional[int]):
-    """(block_q, block_kv) of a resolved Pallas implementation."""
-    if implementation == "pallas_pipelined":
-        return _pipe_blocks(sq, skv, block_q, block_kv)
+def _blocks(sq: int, skv: int, block_q: Optional[int], block_kv: Optional[int]):
+    """(block_q, block_kv) of the Pallas kernels: the whole sequence up to
+    1,024 a side, resident in VMEM for the sub-tile walk."""
     return min(block_q or 1024, max(sq, 1)), min(block_kv or 1024, max(skv, 1))
 
 
-def resolve_attention_impl(
-    head_dim: int,
-    sq: int,
-    skv: int,
-    *,
-    implementation: Optional[str] = None,
-    block_q: Optional[int] = None,
-    block_kv: Optional[int] = None,
-) -> str:
-    """The implementation `flash_attention` runs for a shape: "xla",
-    "pallas" (classic kernel) or "pallas_pipelined". Every choice is a
-    static rule on the backend and the shape — a kernel is never tried and
-    swapped for another when it fails:
+def resolve_attention_impl(implementation: Optional[str] = None) -> str:
+    """The implementation `flash_attention` runs: "xla" or "pallas". A
+    static rule on the backend: with nothing requested, "pallas" on a TPU
+    and "xla" elsewhere (off-TPU the Pallas kernels only run through the
+    interpreter, for callers that ask). The shape does not enter: the one
+    kernel family compiles for the v5e at D = 64 and D = 128
+    (tests/test_tpu_compile.py), and a kernel is never tried and swapped
+    for another when it fails.
 
-    - nothing requested, backend not "tpu": "xla". Off-TPU the Pallas
-      kernels only run through the interpreter, for callers that ask.
-    - nothing requested, backend "tpu": "pallas_pipelined" when
-      `cfg.attn_pipeline` is set, else "pallas".
-    - "pallas_pipelined" (requested or chosen) becomes "pallas" when the
-      shape leaves < 2 kv tiles (nothing to overlap), and, compiled for a
-      TPU, when head_dim % 128 != 0: emit_pipeline's HBM->VMEM kv tile
-      must be lane-aligned and Mosaic refuses a 64-wide slice.
-
-    Callers that report what ran (chip_smoke.py, bench.py) print this.
+    Callers that report what ran (chip_smoke.py, LMTrainer) print this.
     """
     if implementation is not None and implementation not in _IMPLEMENTATIONS:
         raise ValueError(f"unknown attention implementation: {implementation!r}")
-    on_tpu = jax.default_backend() == "tpu"
     if implementation is None:
-        if not on_tpu:
-            return "xla"
-        from ..core.config import cfg
-
-        implementation = "pallas_pipelined" if cfg.attn_pipeline else "pallas"
-    if implementation == "pallas_pipelined" and (
-        (on_tpu and head_dim % 128)
-        or _pipe_blocks(sq, skv, block_q, block_kv) is None
-    ):
-        return "pallas"
+        return "pallas" if jax.default_backend() == "tpu" else "xla"
     return implementation
+
+
+def attention_plan(seq: int, *, causal: bool = True,
+                   implementation: Optional[str] = None) -> dict:
+    """What `flash_attention` runs for one head of a (seq, seq)
+    self-attention: the resolved implementation and how far the kernels'
+    sub-tile walk engages (no sub-tiles for "xla").
+    For callers that report it: LMTrainer's `train.init.step_fn` span,
+    chip_smoke.py."""
+    impl = resolve_attention_impl(implementation)
+    visited = masked = total = 0
+    if impl != "xla":
+        bq, bkv = _blocks(seq, seq, None, None)
+        padded_q, padded_kv = seq + (-seq) % bq, seq + (-seq) % bkv
+        visited, masked, total = attention_subtiles(
+            padded_q, padded_kv, causal, seq, bq, bkv,
+            *_sub_tiles(bq, bkv, (padded_q // bq) * (padded_kv // bkv)))
+    return {
+        "attention_impl": impl,
+        "attn_subtiles_visited": visited,
+        "attn_subtiles_masked": masked,
+        "attn_subtiles_total": total,
+    }
 
 
 def _per_shard(kernel_fn):
@@ -778,23 +744,20 @@ def flash_attention(
 ) -> jax.Array:
     """Blockwise flash attention. q (B,Hq,Sq,D); k,v (B,Hkv,Skv,D).
 
-    implementation: "pallas_pipelined" (double-buffered emit_pipeline
-    forward + classic backward; skewed-schedule interpret driver off-TPU),
-    "pallas" (classic kernel; interpreted off-TPU), "xla" (reference), or
-    None = the static rule of `resolve_attention_impl`.
+    implementation: "pallas" (the kernels; interpreted off-TPU), "xla"
+    (reference), or None = the static rule of `resolve_attention_impl`.
 
-    Block defaults: classic kernel 1024x1024 (clamped to the sequence) —
-    at head_dim 64-128 it is grid-overhead-bound and big tiles measured
-    3.1x faster than 128x128 on v5e while the f32 score tile (4 MB) still
-    fits VMEM. Pipelined kernel 1024x256: q stays VMEM-resident so small
-    kv tiles cost no revisit overhead, and >=4 tiles in flight is what
-    lets the next tile's QK^T overlap the current tile's softmax.
+    Block defaults: 1024 x 1024, clamped to the sequence, so that up to
+    S = 1,024 a head is ONE grid step whose q, k, v stay resident in VMEM
+    (128 KB each at D = 64 in bf16, 256 KB at D = 128): a grid step costs
+    ~0.9 us of DMA issue and bookkeeping, which is why smaller grid blocks
+    lost every sweep. Inside the resident block the kernels walk 256 x 256
+    sub-tiles and skip the dead ones (the comment above `_SUB_TILE`; the
+    chip sweep is in PERF.md section 6, PR 26). Explicit `block_q` /
+    `block_kv` make a grid of smaller blocks, each its own sub-tile.
     """
     sq, skv = q.shape[2], k.shape[2]
-    implementation = resolve_attention_impl(
-        q.shape[-1], sq, skv, implementation=implementation,
-        block_q=block_q, block_kv=block_kv,
-    )
+    implementation = resolve_attention_impl(implementation)
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     if implementation == "xla":
@@ -802,10 +765,9 @@ def flash_attention(
     if causal and sq != skv:
         raise NotImplementedError("causal flash kernel requires Sq == Skv")
     interpret = jax.default_backend() != "tpu"
-    kernel = _flash_pipelined if implementation == "pallas_pipelined" else _flash
-    bq, bkv = _blocks(implementation, sq, skv, block_q, block_kv)
+    bq, bkv = _blocks(sq, skv, block_q, block_kv)
     out = _per_shard(
-        lambda q_, k_, v_: kernel(
+        lambda q_, k_, v_: _flash(
             q_, k_, v_, causal, sm_scale, bq, bkv, skv, interpret
         )
     )(_pad_seq(q, 2, bq), _pad_seq(k, 2, bkv), _pad_seq(v, 2, bkv))
@@ -833,10 +795,7 @@ def flash_attention_with_lse(
     that need gradients wrap their own (ring_attention's custom_vjp
     recomputes through the einsum reference)."""
     sq, skv = q.shape[2], k.shape[2]
-    implementation = resolve_attention_impl(
-        q.shape[-1], sq, skv, implementation=implementation,
-        block_q=block_q, block_kv=block_kv,
-    )
+    implementation = resolve_attention_impl(implementation)
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     if causal and sq != skv:
@@ -861,9 +820,8 @@ def flash_attention_with_lse(
         out = jnp.einsum("bhqk,bhkd->bhqd", p / l, v.astype(p.dtype))
         return out.astype(q.dtype), m + jnp.log(l)
     interpret = jax.default_backend() != "tpu"
-    fwd = _fwd_pipe if implementation == "pallas_pipelined" else _fwd_pallas
-    bq, bkv = _blocks(implementation, sq, skv, block_q, block_kv)
-    out, lse = fwd(
+    bq, bkv = _blocks(sq, skv, block_q, block_kv)
+    out, lse = _fwd_pallas(
         _pad_seq(q, 2, bq), _pad_seq(k, 2, bkv), _pad_seq(v, 2, bkv),
         causal, sm_scale, bq, bkv, skv, interpret,
     )

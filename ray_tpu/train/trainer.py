@@ -124,7 +124,7 @@ class LMTrainer:
                     dp_error_feedback=explicit_dp
                     and dp_allreduce_dtype == "int8",
                 )
-            with tracing.span("train.init.step_fn"):
+            with tracing.span("train.init.step_fn") as self._step_fn_span:
                 self.step_fn = make_train_step(
                     self.config,
                     self.optimizer,
@@ -147,6 +147,10 @@ class LMTrainer:
         # once the first time a report needs it (one extra AOT compile;
         # disable with profile_cost_accounting=False)
         self._step_cost = None
+        # sequence length the step was last traced for: the attention
+        # implementation and its sub-tile walk are known with it, and are
+        # then written on the train.init.step_fn span
+        self._attn_seq: Optional[int] = None
         self.ckpt_config = checkpoint_config
         self.ckpt_mgr: Optional[CheckpointManager] = None
         if checkpoint_config and checkpoint_config.checkpoint_dir:
@@ -159,6 +163,18 @@ class LMTrainer:
     @property
     def num_params(self) -> int:
         return count_params(self.state.params)
+
+    def _note_attention(self, seq: int) -> None:
+        """Attributes of `train.init.step_fn`: what the step's attention
+        resolves to at this sequence length (the jitted step is traced per
+        shape, so only the first batch says)."""
+        from ..ops.attention import attention_plan
+
+        self._attn_seq = seq
+        for key, value in attention_plan(
+                seq, causal=self.config.causal,
+                implementation=self.config.attn_impl).items():
+            self._step_fn_span.set_attribute(key, value)
 
     def restore(self, step: Optional[int] = None) -> int:
         """Resume from a checkpoint; returns the restored step."""
@@ -243,6 +259,8 @@ class LMTrainer:
                     break
                 window_input_wait += wait.duration_s
                 tokens = batch["tokens"]
+                if tokens.shape[-1] - 1 != self._attn_seq:
+                    self._note_attention(tokens.shape[-1] - 1)
                 with tracing.span("train.step.h2d", parent=ctx,
                                   start=wait.ended) as h2d:
                     if isinstance(tokens, np.ndarray):

@@ -339,7 +339,6 @@ class LazyJaxRule(Rule):
 # ----------------------------------------------------------- kernel-fallbacks
 
 REQUIRED_FLAGS = (
-    "attn_pipeline",
     "dp_allreduce_dtype",
     "dp_shard_update",
     "dp_quant_block",

@@ -66,6 +66,10 @@ def test_train_phase_tiny(cache_counter):
         expect_impl="xla", cache=cache_counter,
     )
     assert info["attention_impl"] == "xla"
+    # printed next to it: how far the kernels' sub-tile walk engages (the
+    # reference has no sub-tiles)
+    assert (info["attn_subtiles_visited"], info["attn_subtiles_masked"],
+            info["attn_subtiles_total"]) == (0, 0, 0)
     assert info["kernels_in_step_program"] == {}
     assert info["loss_last"] < info["loss_first"]
     assert info["steps"] == 4

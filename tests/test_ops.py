@@ -93,6 +93,187 @@ def test_flash_backward_gqa():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=1e-3, rtol=1e-3)
 
 
+# ------------------------------------------- sub-tile walk of a resident block
+#
+# At the default blocks the kernels hold one (min(S, 1024))^2 grid tile a
+# head and walk it in sub-tiles, visiting only those with a live pair.
+
+
+_WALK_CASES = {
+    # name: (S, D, Hq, Hkv, causal)
+    "s1024-d64-causal": (1024, 64, 1, 1, True),
+    "s1024-d64-full": (1024, 64, 1, 1, False),
+    "s1024-d128-causal": (1024, 128, 1, 1, True),
+    "s1024-d128-full": (1024, 128, 1, 1, False),
+    # padded to 2 x 2 grid tiles of 1024: the kv_len edge on a sub-tile border
+    "s1536-causal-padded": (1536, 64, 1, 1, True),
+    # ... and inside a sub-tile, every q sub-block reaching it
+    "s1400-full-padded": (1400, 64, 1, 1, False),
+    # a grid tile above, on and below the diagonal
+    "s2048-causal-grid2x2": (2048, 64, 1, 1, True),
+    "s1024-gqa4to1-causal": (1024, 64, 4, 1, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_WALK_CASES))
+def test_flash_subtile_walk_matches_reference(case):
+    s, d, hq, hkv, causal = _WALK_CASES[case]
+    kq, kk, kv, ko = jax.random.split(jax.random.PRNGKey(30), 4)
+    q = _rand(kq, (1, hq, s, d))
+    k = _rand(kk, (1, hkv, s, d))
+    v = _rand(kv, (1, hkv, s, d))
+    w = _rand(ko, (1, hq, s, d))
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v) * w)
+
+    kernel = lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, causal=causal, implementation="pallas")
+    ref = lambda q, k, v: mha_reference(q, k, v, causal=causal)  # noqa: E731
+    np.testing.assert_allclose(np.asarray(kernel(q, k, v)),
+                               np.asarray(ref(q, k, v)), atol=2e-5, rtol=2e-5)
+    gk = jax.grad(loss(kernel), argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+    for a, b_ in zip(gk, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   atol=1e-3, rtol=1e-3)
+
+
+def test_flash_rows_no_subtile_reaches():
+    """kv_len = 0 visits nothing: o = 0 and lse = -inf, the contract ring
+    attention's merge relies on. Padded query rows (beyond kv_len) see
+    every real key and stay finite."""
+    from ray_tpu.ops.attention import _NEG_INF, _bwd_pallas, _fwd_pallas
+
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(31), 3)
+    q = _rand(kq, (1, 1, 1024, 64))
+    k = _rand(kk, (1, 1, 1024, 64))
+    v = _rand(kv, (1, 1, 1024, 64))
+    out, lse = _fwd_pallas(q, k, v, False, 0.125, 1024, 1024, 0, True)
+    assert not np.asarray(out).any()
+    np.testing.assert_array_equal(np.asarray(lse), np.float32(_NEG_INF))
+    grads = _bwd_pallas(q, k, v, out, lse, q, False, 0.125, 1024, 1024, 0, True)
+    assert not any(np.asarray(g).any() for g in grads)
+    # causal, kv_len 700 of 1024: the edge inside the third 256-wide sub-tile
+    out, lse = _fwd_pallas(q, k, v, True, 0.125, 1024, 1024, 700, True)
+    assert np.isfinite(np.asarray(out)).all()
+    assert np.isfinite(np.asarray(lse)).all() and (np.asarray(lse) > -1e29).all()
+    ref = mha_reference(q, k, v, causal=True, sm_scale=0.125, kv_len=700)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize(
+    "args,expect",
+    [((1024, 1024, True, 1024, 1024, 1024, 256, 256), (10, 4, 16)),
+     ((1024, 1024, True, 1024, 1024, 1024, 128, 128), (36, 8, 64)),
+     ((1024, 1024, True, 1024, 1024, 1024, 512, 512), (3, 2, 4)),
+     ((1024, 1024, False, 1024, 1024, 1024, 256, 256), (16, 0, 16)),
+     # a grid of tiles a head: each its own sub-tile, masked when live
+     ((2048, 2048, True, 2048, 1024, 1024, 1024, 1024), (3, 3, 4)),
+     # one tile, kv_len 700: 3 of 4 kv sub-tiles a strip, the third crossed
+     ((1024, 1024, False, 700, 1024, 1024, 256, 256), (12, 4, 16)),
+     # rectangular sub-tiles
+     ((1024, 1024, True, 1024, 1024, 1024, 512, 256), (6, 4, 8)),
+     # explicit small blocks: a grid of single sub-tiles
+     ((256, 256, True, 256, 128, 64, 128, 64), (6, 6, 8))],
+    ids=["causal-256", "causal-128", "causal-512", "full-256", "grid2x2",
+         "kv-edge", "rectangular", "small-blocks"],
+)
+def test_attention_subtiles_counts(args, expect):
+    from ray_tpu.ops.attention import attention_subtiles
+
+    assert attention_subtiles(*args) == expect
+
+
+def test_attention_plan_names_what_runs(monkeypatch):
+    """What LMTrainer writes on `train.init.step_fn` and chip_smoke.py
+    prints: both cells train causal at S = 1,024."""
+    from ray_tpu.ops.attention import attention_plan
+
+    assert attention_plan(1024) == {
+        "attention_impl": "xla", "attn_subtiles_visited": 0,
+        "attn_subtiles_masked": 0, "attn_subtiles_total": 0}
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert attention_plan(1024) == {
+        "attention_impl": "pallas", "attn_subtiles_visited": 10,
+        "attn_subtiles_masked": 4, "attn_subtiles_total": 16}
+    assert attention_plan(1024, causal=False)["attn_subtiles_visited"] == 16
+    assert attention_plan(1024, implementation="xla")["attention_impl"] == "xla"
+    # a grid of tiles: each its own sub-tile, the one above the diagonal skipped
+    assert attention_plan(2048)["attn_subtiles_visited"] == 3
+
+
+@pytest.mark.parametrize("s,causal", [(1024, True), (1024, False), (700, True),
+                                      (2048, True)],
+                         ids=["causal", "full", "odd-block", "grid2x2"])
+def test_kernels_walk_the_counted_subtiles(monkeypatch, s, causal):
+    """What the three kernels compute while they are traced is what
+    `attention_subtiles` counts: every QK^T piece is recorded with its
+    size in sub-tiles and whether it builds the mask."""
+    from ray_tpu.ops import attention as A
+
+    pieces = []
+    scores = A._scores
+
+    def recording(q, k, scale, mask_at, causal_, kv_len):
+        pieces.append((q.shape[0] * k.shape[0], mask_at is not None))
+        return scores(q, k, scale, mask_at, causal_, kv_len)
+
+    monkeypatch.setattr(A, "_scores", recording)
+    q = jnp.zeros((1, 1, s, 64), jnp.float32)
+    jax.grad(lambda q: flash_attention(
+        q, q, q, causal=causal, implementation="pallas").sum())(q)
+    block = min(1024, s)
+    padded = s + (-s) % block
+    tiles = (padded // block) ** 2
+    sub_q, sub_kv = A._sub_tiles(block, block, tiles)
+    visited, masked, total = A.attention_subtiles(
+        padded, padded, causal, s, block, block, sub_q, sub_kv)
+    if tiles == 1:
+        # static walk: each kernel's pieces add up to the count
+        area = sub_q * sub_kv
+        assert sum(n for n, _ in pieces) == 3 * visited * area
+        assert sum(n for n, m in pieces if m) == 3 * masked * area
+        assert total == (padded // sub_q) * (padded // sub_kv)
+    else:
+        # a grid of tiles: one masked whole-tile body a kernel, run or not
+        # per grid step
+        assert pieces == [(block * block, True)] * 3
+        assert (visited, masked, total) == (3, 3, 4)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("kv_len", [1024, 700, 0])
+@pytest.mark.parametrize("sub", [(256, 256), (128, 256), (512, 128)])
+def test_subtile_ranges_agree(causal, kv_len, sub):
+    """`_q_range` (the dkv kernel's bounds) reaches exactly the set
+    `_kv_range` (forward, dq, the counter) reaches, with the same masked
+    sub-tiles, and that set is the live pairs'."""
+    from ray_tpu.ops.attention import _kv_range, _q_range
+
+    sub_q, sub_kv = sub
+    nq, nk = 1024 // sub_q, 1024 // sub_kv
+    by_rows, by_cols = set(), set()
+    for a in range(nq):
+        full, live = _kv_range(a * sub_q, 0, nk, sub_q, sub_kv, causal, kv_len)
+        by_rows |= {(a, c, c >= full) for c in range(live)}
+    for c in range(nk):
+        first_live, first_full = _q_range(0, c * sub_kv, nq, sub_q, sub_kv, causal, kv_len)
+        by_cols |= {(a, c, a < first_full) for a in range(first_live, nq)}
+    assert by_rows == by_cols
+    # every live pair is in a visited sub-tile, every masked pair in a masked one
+    rows, cols = np.arange(1024)[:, None], np.arange(1024)[None, :]
+    live_pair = (cols < kv_len) & ((cols <= rows) | (not causal))
+    for a in range(nq):
+        for c in range(nk):
+            tile = live_pair[a * sub_q:(a + 1) * sub_q, c * sub_kv:(c + 1) * sub_kv]
+            kind = {m for (a_, c_, m) in by_rows if (a_, c_) == (a, c)}
+            assert bool(tile.any()) == bool(kind)
+            if kind:
+                assert kind == {not tile.all()}
+
+
 def test_rmsnorm_and_layernorm():
     x = _rand(jax.random.PRNGKey(4), (2, 8, 64))
     scale = jnp.ones((64,))
@@ -153,154 +334,6 @@ def test_cross_entropy_z_loss_increases_loss():
     base, _ = cross_entropy_loss(logits, targets)
     with_z, _ = cross_entropy_loss(logits, targets, z_loss_coeff=1e-2)
     assert float(with_z) > float(base)
-
-
-# ----------------------------------------------- pipelined kernel numerics
-#
-# The emit_pipeline kernel's interpret driver executes the same stage
-# functions and slot arithmetic as the TPU driver, so these tests pin the
-# pipelined dataflow (skewed stages, double-buffered score slots, causal
-# trip counts) against the classic kernel BIT-FOR-BIT at f32 — the
-# acceptance bar for swapping the default kernel.
-
-
-@pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("gqa", [False, True])
-def test_pipelined_forward_bitwise_vs_classic(causal, gqa):
-    key = jax.random.PRNGKey(20)
-    kq, kk, kv = jax.random.split(key, 3)
-    b, hq, s, d = 2, 4, 256, 64
-    hkv = 2 if gqa else hq
-    q = _rand(kq, (b, hq, s, d))
-    k = _rand(kk, (b, hkv, s, d))
-    v = _rand(kv, (b, hkv, s, d))
-    classic = flash_attention(q, k, v, causal=causal, implementation="pallas",
-                              block_q=128, block_kv=64)
-    pipe = flash_attention(q, k, v, causal=causal,
-                           implementation="pallas_pipelined",
-                           block_q=128, block_kv=64)
-    np.testing.assert_array_equal(np.asarray(classic), np.asarray(pipe))
-    ref = mha_reference(q, k, v, causal=causal)
-    np.testing.assert_allclose(np.asarray(pipe), np.asarray(ref),
-                               atol=2e-5, rtol=2e-5)
-
-
-@pytest.mark.parametrize("causal", [False, True])
-def test_pipelined_backward_bitwise_vs_classic(causal):
-    key = jax.random.PRNGKey(21)
-    kq, kk, kv = jax.random.split(key, 3)
-    b, h, s, d = 1, 2, 256, 64
-    q = _rand(kq, (b, h, s, d))
-    k = _rand(kk, (b, h, s, d))
-    v = _rand(kv, (b, h, s, d))
-
-    def loss(impl):
-        def f(q, k, v):
-            o = flash_attention(q, k, v, causal=causal, implementation=impl,
-                                block_q=64, block_kv=64)
-            return jnp.sum(o * o)
-        return f
-
-    gp = jax.grad(loss("pallas_pipelined"), argnums=(0, 1, 2))(q, k, v)
-    gc = jax.grad(loss("pallas"), argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(loss("xla"), argnums=(0, 1, 2))(q, k, v)
-    for a, b_ in zip(gp, gc):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b_))
-    for a, b_ in zip(gp, gr):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
-                                   atol=1e-3, rtol=1e-3)
-
-
-def test_pipelined_backward_gqa_matches_reference():
-    key = jax.random.PRNGKey(22)
-    kq, kk, kv = jax.random.split(key, 3)
-    b, hq, hkv, s, d = 1, 4, 2, 128, 32
-    q = _rand(kq, (b, hq, s, d))
-    k = _rand(kk, (b, hkv, s, d))
-    v = _rand(kv, (b, hkv, s, d))
-
-    def loss_pipe(q, k, v):
-        return jnp.sum(flash_attention(
-            q, k, v, causal=True, implementation="pallas_pipelined",
-            block_q=64, block_kv=32) ** 2)
-
-    def loss_ref(q, k, v):
-        return jnp.sum(mha_reference(q, k, v, causal=True) ** 2)
-
-    gp = jax.grad(loss_pipe, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    for a, b_ in zip(gp, gr):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
-                                   atol=1e-3, rtol=1e-3)
-
-
-def test_pipelined_odd_sequence_tail():
-    """Seq not a multiple of either block: wrapper pads, kernel masks; same
-    tiles -> bitwise equal to the classic kernel, close to XLA."""
-    key = jax.random.PRNGKey(23)
-    kq, kk, kv = jax.random.split(key, 3)
-    b, h, s, d = 1, 2, 192, 64
-    q = _rand(kq, (b, h, s, d))
-    k = _rand(kk, (b, h, s, d))
-    v = _rand(kv, (b, h, s, d))
-    classic = flash_attention(q, k, v, implementation="pallas",
-                              block_q=128, block_kv=64)
-    pipe = flash_attention(q, k, v, implementation="pallas_pipelined",
-                           block_q=128, block_kv=64)
-    np.testing.assert_array_equal(np.asarray(classic), np.asarray(pipe))
-    ref = mha_reference(q, k, v)
-    np.testing.assert_allclose(np.asarray(pipe), np.asarray(ref),
-                               atol=2e-5, rtol=2e-5)
-
-
-def test_pipelined_lse_matches_classic_and_boundary():
-    """flash_attention_with_lse parity incl. the fully-masked boundary
-    (kv_len=0): both kernels share the finalize contract bit-for-bit."""
-    from ray_tpu.ops.attention import (
-        _fwd_pallas, _fwd_pipe, flash_attention_with_lse,
-    )
-
-    key = jax.random.PRNGKey(24)
-    kq, kk, kv = jax.random.split(key, 3)
-    q = _rand(kq, (1, 2, 256, 64))
-    k = _rand(kk, (1, 2, 256, 64))
-    v = _rand(kv, (1, 2, 256, 64))
-    o1, l1 = flash_attention_with_lse(q, k, v, causal=True,
-                                      implementation="pallas",
-                                      block_q=128, block_kv=64)
-    o2, l2 = flash_attention_with_lse(q, k, v, causal=True,
-                                      implementation="pallas_pipelined",
-                                      block_q=128, block_kv=64)
-    np.testing.assert_array_equal(np.asarray(o1), np.asarray(o2))
-    np.testing.assert_array_equal(np.asarray(l1), np.asarray(l2))
-    # lse agrees with the dense logsumexp of the scaled causal scores
-    s = np.einsum("bhqd,bhkd->bhqk", np.asarray(q, np.float32),
-                  np.asarray(k, np.float32)) / np.sqrt(64.0)
-    mask = np.tril(np.ones((256, 256), bool))
-    s = np.where(mask[None, None], s, -np.inf)
-    dense_lse = np.log(np.exp(s - s.max(-1, keepdims=True)).sum(-1)) + s.max(-1)
-    np.testing.assert_allclose(np.asarray(l2)[..., 0], dense_lse,
-                               atol=1e-4, rtol=1e-4)
-    # boundary: kv_len=0 masks everything; pipelined == classic on the
-    # degenerate rows too (shared finalize semantics)
-    ob1, lb1 = _fwd_pallas(q, k, v, False, 0.125, 64, 64, 0, True)
-    ob2, lb2 = _fwd_pipe(q, k, v, False, 0.125, 64, 64, 0, True)
-    np.testing.assert_array_equal(np.asarray(ob1), np.asarray(ob2))
-    np.testing.assert_array_equal(np.asarray(lb1), np.asarray(lb2))
-
-
-def test_pipelined_auto_fallback_single_tile():
-    """Shapes with <2 kv tiles fall back to the classic kernel instead of
-    degenerate pipelining."""
-    key = jax.random.PRNGKey(25)
-    kq, kk, kv = jax.random.split(key, 3)
-    q = _rand(kq, (1, 2, 64, 32))
-    k = _rand(kk, (1, 2, 64, 32))
-    v = _rand(kv, (1, 2, 64, 32))
-    out = flash_attention(q, k, v, implementation="pallas_pipelined")
-    ref = mha_reference(q, k, v)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=2e-5, rtol=2e-5)
 
 
 def test_flash_kernel_runs_per_shard_under_a_context_mesh():
@@ -374,9 +407,9 @@ def test_check_kernel_fallbacks_wired():
     flags = mod.defined_flags(config_tree)
     assert set(mod.REQUIRED_FLAGS) <= flags
     reads = mod.cfg_reads(ast.parse(
-        "from .config import cfg\nx = cfg.attn_pipeline\n"
+        "from .config import cfg\nx = cfg.dp_quant_block\n"
     ))
-    assert reads == [(2, "attn_pipeline")]
+    assert reads == [(2, "dp_quant_block")]
 
 
 def test_fused_linear_cross_entropy_matches_dense():

@@ -2,14 +2,15 @@
 
 The TPU compiler is installed here and compiles for a topology that is
 described, not attached (on-chip-measurement guide, section 2.3). Interpret
-mode cannot see what it refuses: the pipelined flash kernel passed every
-interpret test and was refused at D=64 (64-wide stream tile) and in its
-backward (1-wide lse/delta tiles); a Mosaic call under GSPMD is refused
-outright. Nothing runs here — a compile that passes is not a chip run.
+mode cannot see what it refuses: a pipelined flash kernel (deleted in PR 26)
+passed every interpret test and was refused at D=64 (64-wide stream tile)
+and in its backward (1-wide lse/delta tiles); a Mosaic call under GSPMD is
+refused outright. Nothing runs here — a compile that passes is not a chip
+run.
 
 Steering is done in this file: code that asks `jax.default_backend()` sees
-"tpu" through a monkeypatch, and emit_pipeline reads the described chip's
-kind instead of the attached device's.
+"tpu" through a monkeypatch, and Mosaic reads the described chip's kind
+instead of the attached device's.
 """
 
 import os
@@ -56,24 +57,25 @@ def _kernel_calls(compiled) -> int:
     return compiled.as_text().count("tpu_custom_call")
 
 
-GPT2 = ((24, 12, 1024, 64), (24, 12, 1024, 64))       # bench.py's shape
-LLAMA = ((4, 32, 2048, 128), (4, 8, 2048, 128))       # GQA 32 -> 8
+GPT2 = ((24, 12, 1024, 64), (24, 12, 1024, 64))       # train-gpt2s
+MISTRAL = ((12, 16, 1024, 128), (12, 4, 1024, 128))   # a train-mistral7b shard
+LLAMA = ((4, 32, 2048, 128), (4, 8, 2048, 128))       # GQA 32 -> 8, 2 x 2 tiles
 RING = ((4, 8, 2048, 128), (4, 8, 2048, 128))         # bench.py's ring block
 
 
 @pytest.mark.parametrize(
-    "shapes,impl",
-    [(GPT2, "pallas"), (LLAMA, "pallas"), (LLAMA, "pallas_pipelined")],
-    ids=["classic-gpt2-d64", "classic-llama-d128", "pipelined-llama-d128"],
+    "shapes", [GPT2, MISTRAL, LLAMA],
+    ids=["gpt2-d64-s1024", "mistral-d128-s1024-gqa", "llama-d128-s2048-grid"],
 )
-def test_flash_attention_fwd_and_grad_compile(as_tpu, shapes, impl):
+def test_flash_attention_fwd_and_grad_compile(as_tpu, shapes):
+    """One grid tile a head (the sub-tile walk, both cells' shapes) and a
+    grid of tiles, at the default blocks and the default rule."""
     q_shape, kv_shape = shapes
     q, k, v = _on(as_tpu, q_shape), _on(as_tpu, kv_shape), _on(as_tpu, kv_shape)
-    assert flash.resolve_attention_impl(
-        q_shape[-1], q_shape[2], kv_shape[2], implementation=impl) == impl
+    assert flash.attention_plan(q_shape[2])["attention_impl"] == "pallas"
 
     def attend(q, k, v):
-        return flash.flash_attention(q, k, v, causal=True, implementation=impl)
+        return flash.flash_attention(q, k, v, causal=True)
 
     def loss(q, k, v):
         return attend(q, k, v).astype(jnp.float32).sum()
@@ -83,29 +85,27 @@ def test_flash_attention_fwd_and_grad_compile(as_tpu, shapes, impl):
     assert _kernel_calls(grad.lower(q, k, v).compile()) == 3  # fwd, dkv, dq
 
 
-def test_default_rule_never_picks_a_kernel_the_compiler_refuses(as_tpu):
-    """What the chip's compiler said, as the static rule states it: the
-    pipelined forward needs D % 128 == 0; everything else is classic."""
-    assert flash.resolve_attention_impl(64, 1024, 1024) == "pallas"
-    assert flash.resolve_attention_impl(128, 2048, 2048) == "pallas_pipelined"
-    assert flash.resolve_attention_impl(128, 256, 256) == "pallas"  # < 2 kv tiles
-    assert flash.resolve_attention_impl(
-        64, 1024, 1024, implementation="pallas_pipelined") == "pallas"
-    q = _on(as_tpu, GPT2[0])
-    refused = jax.jit(lambda q, k, v: flash._flash_pipelined(
-        q, k, v, True, 0.125, 1024, 256, 1024, False))
-    with pytest.raises(Exception, match="aligned to tiling"):
-        refused.lower(q, q, q).compile()
+def test_default_rule_is_the_backend_alone(as_tpu, monkeypatch):
+    """One kernel family since PR 26: the rule looks at the backend, not
+    at the shape, and the walk engages at the cells' sequence length."""
+    assert flash.resolve_attention_impl() == "pallas"
+    assert flash.resolve_attention_impl("xla") == "xla"
+    plan = flash.attention_plan(1024)
+    assert (plan["attention_impl"], plan["attn_subtiles_visited"],
+            plan["attn_subtiles_total"]) == ("pallas", 10, 16)
+    with pytest.raises(ValueError, match="unknown attention implementation"):
+        flash.resolve_attention_impl("pallas_pipelined")
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert flash.resolve_attention_impl() == "xla"
 
 
-@pytest.mark.parametrize("fwd", [flash._fwd_pallas, flash._fwd_pipe],
-                         ids=["classic", "pipelined"])
-def test_ring_attention_fused_block_compiles(as_tpu, fwd):
-    """ops/ring_attention's fused local block: the forward with its lse."""
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_ring_attention_fused_block_compiles(as_tpu, causal):
+    """ops/ring_attention's fused local block, the forward with its lse:
+    the diagonal block is causal, the blocks below it are not."""
     q = _on(as_tpu, RING[0])
-    block_kv = 1024 if fwd is flash._fwd_pallas else 256
-    block = jax.jit(lambda q, k, v: fwd(
-        q, k, v, True, 128 ** -0.5, 1024, block_kv, 2048, False))
+    block = jax.jit(lambda q, k, v: flash._fwd_pallas(
+        q, k, v, causal, 128 ** -0.5, 1024, 1024, 2048, False))
     assert _kernel_calls(block.lower(q, q, q).compile()) == 1
 
 

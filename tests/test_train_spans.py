@@ -69,6 +69,25 @@ def _one(spans, name):
     return found
 
 
+def test_step_fn_span_names_the_attention_the_step_runs(trainer, monkeypatch):
+    """`train.init.step_fn` carries the resolved attention implementation
+    and how far the kernels' sub-tile walk engages, written when the first
+    batch gives the sequence length (the step is traced per shape)."""
+    trainer, _, init_spans, _ = trainer
+    assert "attention_impl" not in _one(init_spans, "train.init.step_fn")["attrs"]
+    attrs = trainer._step_fn_span.to_dict()["attrs"]
+    assert attrs == {"attention_impl": "xla", "attn_subtiles_visited": 0,
+                     "attn_subtiles_masked": 0, "attn_subtiles_total": 0}
+    # on the chip, at the cells' sequence length
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    trainer._note_attention(1024)
+    attrs = trainer._step_fn_span.to_dict()["attrs"]
+    assert attrs["attention_impl"] == "pallas"
+    assert (attrs["attn_subtiles_visited"], attrs["attn_subtiles_masked"],
+            attrs["attn_subtiles_total"]) == (10, 4, 16)
+    trainer._note_attention(16)
+
+
 def test_span_tree_of_a_three_step_train_call(trainer):
     trainer, config, _, _ = trainer
     trainer.train(_batches(1, 3, config.vocab_size), num_steps=3, report_every=3)
