@@ -1,15 +1,21 @@
 """ray_tpu.models — flagship model families, TPU-shaped.
 
-Decoder-only LMs (GPT-2, Llama) now; MoE (Mixtral) and ViT/CLIP follow the
-same pattern: pytree params + logical-axis tree + scan-stacked layers.
+Decoder-only LMs (GPT-2, Llama), MoE (Mixtral, OLMoE) and ViT/CLIP follow
+one pattern: pytree params + logical-axis tree + scan-stacked layers.
+`model_family(config)` is the one place that maps a configuration's type to
+the functions that build and run its model (train/lm.py reads it).
 """
+
+from typing import Callable, NamedTuple
+
+from . import moe as _moe, transformer as _dense
 
 from .configs import PRESETS, get_config  # noqa: F401
 from .moe import (  # noqa: F401
     MoEConfig,
     mixtral_8x7b,
-    moe_loss,
     moe_tiny,
+    olmoe_1b_7b,
 )
 from .transformer import (  # noqa: F401
     TransformerConfig,
@@ -32,3 +38,35 @@ from .vit import (  # noqa: F401
     vit_l16,
     vit_tiny,
 )
+
+
+class ModelFamily(NamedTuple):
+    init_params: Callable     # (config, key) -> params
+    logical_axes: Callable    # (config) -> logical-axis tree of the params
+    # (params, tokens, config) -> (hidden (B, S, E) before the LM head, the
+    # routers' scalars: `router_aux_loss`, `moe_load_max_over_mean`; {} for
+    # a dense model)
+    forward_hidden: Callable
+    # (config, tokens a step) -> what the family's own layers resolve to, for
+    # callers that report it (LMTrainer's `train.init.step_fn` span)
+    plan: Callable
+
+
+def _dense_hidden(params, tokens, config):
+    return _dense.forward_hidden(params, tokens, config), {}
+
+
+# most derived first: a MoEConfig is a TransformerConfig
+_FAMILIES = (
+    (MoEConfig, ModelFamily(_moe.init_params, _moe.logical_axes, _moe.forward_hidden,
+                            _moe.moe_plan)),
+    (TransformerConfig, ModelFamily(init_params, logical_axes, _dense_hidden,
+                                    lambda config, tokens_per_step: {})),
+)
+
+
+def model_family(config) -> ModelFamily:
+    for config_type, family in _FAMILIES:
+        if isinstance(config, config_type):
+            return family
+    raise TypeError(f"no model family for a {type(config).__name__}")

@@ -8,6 +8,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from .moe import mixtral_8x7b, moe_tiny, olmoe_1b_7b
 from .transformer import TransformerConfig
 
 
@@ -107,6 +108,9 @@ PRESETS = {
     "llama3-70b": llama3_70b,
     "gpt2-tiny": gpt2_tiny,
     "llama-tiny": llama_tiny,
+    "mixtral-8x7b": mixtral_8x7b,
+    "olmoe-1b-7b": olmoe_1b_7b,
+    "moe-tiny": moe_tiny,
 }
 
 

@@ -1,25 +1,46 @@
-"""Mixture-of-Experts transformer (Mixtral family) with expert parallelism.
+"""Mixture-of-Experts transformer (Mixtral and OLMoE families).
 
 The reference only reaches MoE through vLLM engine internals (SURVEY.md
-§2.4: expert parallel "absent as a framework feature"). Here experts are a
-first-class mesh axis: expert-stacked weights carry the "expert" logical
-axis → `ep` on the mesh, and the GShard-style dense dispatch/combine
-einsums give XLA the contraction structure it needs to insert the
-all-to-alls over ICI on its own. Routing is top-k with capacity: dropped
-tokens (over capacity) fall through on the residual path, the standard
-Switch/GShard behavior; a load-balancing aux loss keeps experts busy.
+§2.4: expert parallel "absent as a framework feature"). Here the expert
+layer has two forms, and the mesh alone chooses between them (the context
+mesh, or the one the expert weights are sharded over: `_mesh_of`):
+
+- dropless (every mesh without an `ep` axis, one chip included): the
+  T x k (token, choice) rows are sorted by expert, gathered into one
+  buffer in which every expert's rows are contiguous, pushed through
+  three grouped matmuls over the ragged groups (ops/grouped_matmul:
+  the `moe_gmm_*` Pallas kernels on a TPU, `ragged_dot` elsewhere), and
+  gathered back and gated. Shapes are static whatever the routing, no
+  token is ever dropped, and nothing has a capacity. Under a mesh every
+  device does this for the tokens it holds (`shard_map` over the data,
+  sequence and tp axes), so one path serves 1..N chips.
+- GShard dense dispatch (`ep > 1`): experts are a mesh axis,
+  expert-stacked weights carry the "expert" logical axis, and the
+  dispatch/combine einsums give XLA the contraction structure it needs
+  to insert the all-to-alls over ICI on its own. Tokens over
+  `capacity_factor` are dropped. A dropless expert-parallel exchange
+  needs a four-chip cell to be judged on (PERF.md, Open questions).
+
+The router is float32; the gates are the top-k probabilities as they are
+(OLMoE, `norm_topk_prob=False`) or renormalised over the chosen k
+(Mixtral). The load-balancing loss is E * sum_e f_e P_e with f_e the
+share of (token, choice) pairs routed to e and P_e the mean router
+probability (1 at perfect balance).
 """
 
 from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from ..ops import rope_frequencies, swiglu
+from ..ops.grouped_matmul import gmm_tile_rows, grouped_matmul, resolve_gmm_impl
+from ..parallel.mesh import DATA_AXES
 from .transformer import (
     Params,
     TransformerConfig,
@@ -34,8 +55,9 @@ from .transformer import (
 class MoEConfig(TransformerConfig):
     n_experts: int = 8
     top_k: int = 2
-    capacity_factor: float = 2.0
+    capacity_factor: float = 2.0  # read by the GShard path (ep > 1) alone
     router_aux_coeff: float = 0.01
+    norm_topk_prob: bool = True  # gates renormalised over the chosen k (Mixtral)
 
 
 def mixtral_8x7b() -> MoEConfig:
@@ -57,6 +79,31 @@ def mixtral_8x7b() -> MoEConfig:
         remat=True,
         n_experts=8,
         top_k=2,
+    )
+
+
+def olmoe_1b_7b() -> MoEConfig:
+    """OLMoE-1B-7B (allenai/OLMoE-1B-7B-0125-Instruct config.json): 64
+    experts of width 1024, top-8 gates not renormalised, QK-norm, MHA."""
+    return MoEConfig(
+        vocab_size=50304,
+        d_model=2048,
+        n_layers=16,
+        n_heads=16,
+        n_kv_heads=16,
+        d_ff=1024,
+        max_seq=4096,
+        pos_emb="rope",
+        norm="rmsnorm",
+        act="swiglu",
+        use_bias=False,
+        tie_embeddings=False,
+        rope_theta=10000.0,
+        qk_norm=True,
+        norm_eps=1e-5,
+        n_experts=64,
+        top_k=8,
+        norm_topk_prob=False,
     )
 
 
@@ -108,7 +155,9 @@ def logical_axes(config: MoEConfig) -> Params:
     blocks = axes["blocks"]
     for name in ("w_up", "w_down", "w_gate", "b_up", "b_down"):
         blocks.pop(name, None)
-    blocks["router"] = ("layers", "embed", None)
+    # replicated: 0.13 M weights a layer, and an embed-sharded router makes
+    # GSPMD reshard the float32 activations for its gradient
+    blocks["router"] = ("layers", None, None)
     blocks["we_gate"] = ("layers", "expert", "embed", "mlp")
     blocks["we_up"] = ("layers", "expert", "embed", "mlp")
     blocks["we_down"] = ("layers", "expert", "mlp", "embed")
@@ -119,13 +168,14 @@ def logical_axes(config: MoEConfig) -> Params:
 
 
 def topk_dispatch(
-    probs: jax.Array, top_k: int, capacity: int
+    probs: jax.Array, top_k: int, capacity: int, normalize: bool = True
 ) -> Tuple[jax.Array, jax.Array]:
     """GShard dense dispatch. probs (B, S, E) → dispatch (B,S,E,C) {0,1},
     combine (B,S,E,C) gate-weighted; tokens over capacity are dropped."""
     num_experts = probs.shape[-1]
     weights, idx = jax.lax.top_k(probs, top_k)  # (B,S,k)
-    weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-9)
+    if normalize:
+        weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-9)
     onehot = jax.nn.one_hot(idx, num_experts, dtype=probs.dtype)  # (B,S,k,E)
     b, s, k, e = onehot.shape
     # queue position of each (token, choice) within its expert, in (S·k) order
@@ -142,53 +192,232 @@ def topk_dispatch(
     return dispatch, combine
 
 
-def load_balancing_loss(probs: jax.Array, dispatch: jax.Array) -> jax.Array:
-    """Switch aux loss: E · Σ_e (token frac to e · mean router prob of e)."""
-    num_experts = probs.shape[-1]
-    token_frac = jnp.mean(jnp.sum(dispatch, axis=-1), axis=(0, 1))  # (E,)
-    prob_mean = jnp.mean(probs, axis=(0, 1))
-    return num_experts * jnp.sum(token_frac * prob_mean)
+class DroplessLayout(NamedTuple):
+    """Where each (token, choice) row sits in the expert-sorted buffer.
+    Row r = t * k + j is token t's j-th choice; the buffer has P slots,
+    expert e owning `padded_sizes[e]` of them in a row, of which the first
+    `sizes[e]` hold rows and the rest are zero rows."""
+
+    sizes: jax.Array         # (E,) rows routed to each expert
+    padded_sizes: jax.Array  # (E,) the same, as positive multiples of the tile
+    slot_row: jax.Array      # (P,) the row a slot holds; T * k where none
+    row_slot: jax.Array      # (T * k,) the slot of each row
+
+
+def dropless_layout(expert_idx: jax.Array, n_experts: int, tile_rows: int) -> DroplessLayout:
+    """expert_idx (T, k) int → the layout. A stable sort of the rows by
+    expert, group sizes from the sorted ids, every group padded to a
+    positive multiple of `tile_rows` (ops/grouped_matmul's contract); P =
+    T·k rounded up + E·tile_rows bounds every routing, so shapes are static."""
+    flat = expert_idx.reshape(-1).astype(jnp.int32)
+    rows = flat.shape[0]
+    slots = -(-rows // tile_rows) * tile_rows + n_experts * tile_rows
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)  # sorted position → row
+    sorted_expert = flat[order]
+    ends = jnp.searchsorted(
+        sorted_expert, jnp.arange(n_experts, dtype=jnp.int32), side="right").astype(jnp.int32)
+    sizes = jnp.diff(ends, prepend=0)
+    starts = ends - sizes
+    padded = -(-jnp.maximum(sizes, 1) // tile_rows) * tile_rows
+    padded_ends = jnp.cumsum(padded)
+    padded_starts = padded_ends - padded
+    # slot → row: both maps are built from gathers and a sort, no scatter
+    slot = jnp.arange(slots, dtype=jnp.int32)
+    slot_expert = jnp.minimum(
+        jnp.searchsorted(padded_ends, slot, side="right"), n_experts - 1)
+    rank = slot - padded_starts[slot_expert]
+    held = rank < sizes[slot_expert]
+    slot_row = jnp.where(
+        held, order[jnp.clip(starts[slot_expert] + rank, 0, rows - 1)], rows)
+    slot_sorted = padded_starts[sorted_expert] + jnp.arange(rows, dtype=jnp.int32) - starts[sorted_expert]
+    _, row_slot = jax.lax.sort((order, slot_sorted), num_keys=1)
+    return DroplessLayout(sizes, padded.astype(jnp.int32), slot_row.astype(jnp.int32),
+                          row_slot.astype(jnp.int32))
+
+
+@jax.custom_vjp
+def _take_rows(x: jax.Array, idx: jax.Array, readers: jax.Array) -> jax.Array:
+    """y[b] = x[idx[b]], zero where idx[b] is out of range. `readers`
+    (A, m) names for each row of x the m rows of y that read it (out of
+    range: none), so that the transpose is a gather as well: a scatter-add
+    of 10^5 wide rows is what a TPU does worst."""
+    del readers
+    return jnp.take(x, idx, axis=0, mode="fill", fill_value=0)
+
+
+def _take_rows_fwd(x, idx, readers):
+    return _take_rows(x, idx, readers), readers
+
+
+def _take_rows_bwd(readers, dy):
+    a, m = readers.shape
+    dx = jnp.take(dy, readers.reshape(-1), axis=0, mode="fill", fill_value=0)
+    return dx.reshape(a, m, dy.shape[-1]).sum(axis=1).astype(dy.dtype), None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+def _mesh_of(weights: Optional[jax.Array] = None):
+    """The mesh the expert layer is traced for: the context mesh
+    (make_train_step traces its step inside `use_abstract_mesh`), or, with
+    no context, the mesh the expert weights are sharded over, which their
+    type carries through scan, remat and grad. So parameters sharded over
+    `ep` take the expert-parallel form whether or not the caller set a
+    mesh. (A jit that names shardings only in `in_shardings`, with no
+    context mesh, shows the trace neither: set the mesh there.)"""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty and weights is not None:
+        mesh = jax.typeof(weights).sharding.mesh
+    return mesh
+
+
+def expert_parallel(mesh) -> bool:
+    """Whether `mesh` has an `ep` axis: the one thing that chooses the
+    GShard form over the dropless one."""
+    return (not mesh.empty) and mesh.shape.get("ep", 1) > 1
+
+
+def moe_plan(config: "MoEConfig", tokens_per_step: int) -> Dict[str, Any]:
+    """What the expert layer runs for a step of `tokens_per_step` tokens
+    under the context mesh, for callers that report it (LMTrainer's
+    `train.init.step_fn` span)."""
+    if expert_parallel(_mesh_of()):
+        impl, tile = "gshard_dense", 0
+    else:
+        gmm = resolve_gmm_impl()
+        impl, tile = {"pallas": "gmm_pallas", "xla": "ragged_dot"}[gmm], gmm_tile_rows(gmm)
+    return {
+        "moe_impl": impl, "moe_experts": config.n_experts, "moe_top_k": config.top_k,
+        "moe_rows_per_step": tokens_per_step * config.top_k, "moe_gmm_tile_rows": tile,
+    }
+
+
+def _gshard_experts(h, probs, weights, config):
+    """(B, S, M) -> expert output (B, S, M), rows per expert (E,)."""
+    c, dt = config, config.dtype
+    we_gate, we_up, we_down = weights
+    s = h.shape[1]
+    capacity = max(1, int(c.capacity_factor * c.top_k * s / c.n_experts))
+    dispatch, combine = topk_dispatch(probs, c.top_k, capacity, c.norm_topk_prob)
+    # dispatch: (B,S,E,C) x (B,S,M) -> (E,B,C,M); XLA turns the e-sharded
+    # contraction into the all-to-all over the ep axis
+    expert_in = jnp.einsum("bsec,bsm->ebcm", dispatch.astype(dt), h)
+    gate = jnp.einsum("ebcm,emf->ebcf", expert_in, we_gate)
+    up = jnp.einsum("ebcm,emf->ebcf", expert_in, we_up)
+    act = swiglu(gate, up)
+    expert_out = jnp.einsum("ebcf,efm->ebcm", act, we_down)
+    out = jnp.einsum("ebcm,bsec->bsm", expert_out, combine.astype(dt))
+    return out, jnp.sum(dispatch, axis=(0, 1, 3))
+
+
+def _dropless_shard(h, probs, weights, config):
+    """The same contract on the tokens one device holds, no capacity:
+    every (token, choice) row is computed."""
+    c = config
+    we_gate, we_up, we_down = weights
+    b, s, m = h.shape
+    tokens = b * s
+    impl = resolve_gmm_impl()
+    tile = gmm_tile_rows(impl)
+    gates, experts = jax.lax.top_k(probs.reshape(tokens, c.n_experts), c.top_k)  # (T, k)
+    if c.norm_topk_prob:
+        gates = gates / (jnp.sum(gates, -1, keepdims=True) + 1e-9)
+    with jax.named_scope("moe.dispatch"):
+        layout = dropless_layout(experts, c.n_experts, tile)
+        # slot -> token: T (out of range, a zero row) where the slot holds none
+        expert_in = _take_rows(
+            h.reshape(tokens, m), layout.slot_row // c.top_k,
+            layout.row_slot.reshape(tokens, c.top_k))
+    with jax.named_scope("moe.experts"):
+        def gmm(lhs, w):
+            return grouped_matmul(lhs, w, layout.padded_sizes,
+                                  tile_rows=tile, implementation=impl)
+
+        act = swiglu(gmm(expert_in, we_gate), gmm(expert_in, we_up))
+        expert_out = gmm(act, we_down)
+    with jax.named_scope("moe.combine"):
+        chosen = _take_rows(expert_out, layout.row_slot, layout.slot_row[:, None])
+        # gated in float32; one fused pass over the gathered rows
+        chosen = chosen.reshape(tokens, c.top_k, m).astype(jnp.float32)
+        out = jnp.sum(gates[..., None] * chosen, axis=1)
+    return out.astype(c.dtype).reshape(b, s, m), layout.sizes.astype(jnp.float32)
+
+
+def _dropless_experts(h, probs, weights, config, mesh):
+    """`_dropless_shard` once a device. GSPMD cannot partition a Mosaic
+    call, and a sort over every shard's rows is nothing it should be
+    given: under a mesh each device sorts and computes the tokens it
+    holds (batch over the data axes, sequence over sp) with its tp slice
+    of every expert, as ops/attention._per_shard does for the flash
+    kernels. The partial outputs add up over tp, the group sizes over the
+    token axes. With no mesh, one device, or inside somebody else's
+    shard_map the layer is called as it is."""
+    if mesh.empty or mesh.manual_axes or mesh.size == 1:
+        return _dropless_shard(h, probs, weights, config)
+    batch = tuple(a for a in DATA_AXES if mesh.shape[a] > 1)
+    seq = "sp" if mesh.shape.get("sp", 1) > 1 else None
+    tp = "tp" if mesh.shape.get("tp", 1) > 1 else None
+    token_axes = batch + ((seq,) if seq else ())
+
+    def shard(h, probs, weights):
+        out, sizes = _dropless_shard(h, probs, weights, config)
+        if tp:
+            out = jax.lax.psum(out, tp)
+        if token_axes:
+            sizes = jax.lax.psum(sizes, token_axes)
+        return out, sizes
+
+    tok = P(batch or None, seq, None)
+    return jax.shard_map(
+        shard, mesh=mesh,
+        in_specs=(tok, tok, (P(None, None, tp), P(None, None, tp), P(None, tp, None))),
+        out_specs=(tok, P()), check_vma=False,
+    )(h, probs, weights)
 
 
 def moe_mlp_sublayer(
     x: jax.Array, lp: Params, config: MoEConfig
-) -> Tuple[jax.Array, jax.Array]:
-    """Pre-norm MoE FFN + residual; returns (out, aux_loss)."""
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Pre-norm MoE FFN + residual; returns (out, aux_loss, the largest
+    expert's rows over the mean)."""
     c = config
-    dt = c.dtype
-    h = _norm(x, lp["ln2_scale"], lp.get("ln2_bias"), c.norm)
-    b, s, _ = h.shape
-    capacity = max(1, int(c.capacity_factor * c.top_k * s / c.n_experts))
-
-    router_logits = jnp.einsum(
-        "bsm,me->bse", h.astype(jnp.float32), lp["router"].astype(jnp.float32)
-    )
-    probs = jax.nn.softmax(router_logits, axis=-1)
-    dispatch, combine = topk_dispatch(probs, c.top_k, capacity)
-    aux = load_balancing_loss(probs, dispatch)
-
-    # dispatch: (B,S,E,C) × (B,S,M) → (E,B,C,M); XLA turns the e-sharded
-    # contraction into the all-to-all over the ep axis
-    expert_in = jnp.einsum("bsec,bsm->ebcm", dispatch.astype(dt), h)
-    gate = jnp.einsum("ebcm,emf->ebcf", expert_in, lp["we_gate"].astype(dt))
-    up = jnp.einsum("ebcm,emf->ebcf", expert_in, lp["we_up"].astype(dt))
-    act = swiglu(gate, up)
-    expert_out = jnp.einsum("ebcf,efm->ebcm", act, lp["we_down"].astype(dt))
-    out = jnp.einsum("ebcm,bsec->bsm", expert_out, combine.astype(dt))
-    return x + out, aux
+    h = _norm(x, lp["ln2_scale"], lp.get("ln2_bias"), c.norm, c.norm_eps)
+    with jax.named_scope("moe.route"):
+        # float32 in earnest: a TPU's default float32 matmul is one bfloat16
+        # pass, which rounds the router's weights and flips near-ties
+        router_logits = jnp.einsum(
+            "bsm,me->bse", h.astype(jnp.float32), lp["router"].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
+        )
+        probs = jax.nn.softmax(router_logits, axis=-1)
+    weights = tuple(lp[name].astype(c.dtype) for name in ("we_gate", "we_up", "we_down"))
+    mesh = _mesh_of(lp["we_gate"])
+    if expert_parallel(mesh):
+        out, load = _gshard_experts(h, probs, weights, c)
+    else:
+        out, load = _dropless_experts(h, probs, weights, c, mesh)
+    # E * sum_e f_e P_e: f_e the share of the (token, choice) pairs routed to
+    # e (it carries no gradient; under GShard, of those kept), P_e the mean
+    # router probability
+    share = load / (probs.shape[0] * probs.shape[1] * c.top_k)
+    aux = c.n_experts * jnp.sum(share * jnp.mean(probs, axis=(0, 1)))
+    return x + out, aux, jnp.max(load) / jnp.maximum(jnp.mean(load), 1e-9)
 
 
 # -------------------------------------------------------------------- forward
 
 
-def forward(
+def forward_hidden(
     params: Params,
     tokens: jax.Array,
     config: MoEConfig,
     *,
     positions: Optional[jax.Array] = None,
-) -> Tuple[jax.Array, jax.Array]:
-    """(B, S) → (logits (B,S,V), total aux loss)."""
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """Forward up to the LM head: (B, S) → ((B, S, E), what the routers
+    report: `router_aux_loss` summed over the layers and
+    `moe_load_max_over_mean` of the worst layer)."""
     c = config
     dt = c.dtype
     _, s = tokens.shape
@@ -201,27 +430,15 @@ def forward(
 
     def block_fn(carry, lp):
         x = attention_sublayer(carry, lp, c, rope_tables, positions)
-        x, aux = moe_mlp_sublayer(x, lp, c)
-        return x, aux
+        x, aux, load = moe_mlp_sublayer(x, lp, c)
+        return x, (aux, load)
 
     if c.remat:
         block_fn = jax.checkpoint(block_fn)
-    x, aux_per_layer = jax.lax.scan(block_fn, x, params["blocks"])
-
-    x = _norm(x, params["lnf_scale"], params.get("lnf_bias"), c.norm)
-    head = params.get("lm_head", None)
-    if head is None:
-        head = params["wte"].T
-    logits = jnp.einsum("bse,ev->bsv", x, head.astype(dt))
-    return logits, jnp.sum(aux_per_layer)
-
-
-def moe_loss(
-    params: Params, tokens: jax.Array, config: MoEConfig
-) -> Tuple[jax.Array, Any]:
-    """Next-token CE + router aux (for make_train_step-style factories)."""
-    from ..ops import cross_entropy_loss
-
-    logits, aux = forward(params, tokens[:, :-1], config)
-    ce, ntok = cross_entropy_loss(logits, tokens[:, 1:])
-    return ce + config.router_aux_coeff * aux, (ce, aux, ntok)
+    x, (aux_per_layer, load_per_layer) = jax.lax.scan(
+        block_fn, x, params["blocks"], unroll=c.scan_unroll)
+    x = _norm(x, params["lnf_scale"], params.get("lnf_bias"), c.norm, c.norm_eps)
+    return x, {
+        "router_aux_loss": jnp.sum(aux_per_layer),
+        "moe_load_max_over_mean": jnp.max(load_per_layer),
+    }

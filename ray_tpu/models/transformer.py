@@ -65,6 +65,8 @@ class TransformerConfig:
     causal: bool = True  # False → bidirectional encoder (ViT, CLIP text off)
     fused_qkv: bool = False  # single [E, (Hq+2Hkv)·Dh] projection matmul
     scan_unroll: int = 1  # lax.scan unroll for the layer stack
+    qk_norm: bool = False  # RMSNorm over the whole q and k projections (OLMoE)
+    norm_eps: Optional[float] = None  # None → 1e-6 (rmsnorm) / 1e-5 (layernorm)
 
     @property
     def kv_heads(self) -> int:
@@ -109,6 +111,9 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Params:
     }
     if c.act == "swiglu":
         blocks["w_gate"] = normal(keys[6], (L, c.d_model, c.d_ff))
+    if c.qk_norm:
+        blocks["q_norm_scale"] = jnp.ones((L, c.n_heads * dh), pd)
+        blocks["k_norm_scale"] = jnp.ones((L, c.kv_heads * dh), pd)
     if c.norm == "layernorm":
         blocks["ln1_bias"] = jnp.zeros((L, c.d_model), pd)
         blocks["ln2_bias"] = jnp.zeros((L, c.d_model), pd)
@@ -149,6 +154,9 @@ def logical_axes(config: TransformerConfig) -> Params:
     }
     if c.act == "swiglu":
         blocks["w_gate"] = ("layers", "embed", "mlp")
+    if c.qk_norm:
+        blocks["q_norm_scale"] = ("layers", None)
+        blocks["k_norm_scale"] = ("layers", None)
     if c.norm == "layernorm":
         blocks["ln1_bias"] = ("layers", None)
         blocks["ln2_bias"] = ("layers", None)
@@ -180,10 +188,22 @@ def count_params(params: Params) -> int:
 # -------------------------------------------------------------------- forward
 
 
-def _norm(x, scale, bias, kind):
+def _norm(x, scale, bias, kind, eps=None):
+    """`eps` None keeps each norm's own default (ops/layers)."""
+    kw = {} if eps is None else {"eps": eps}
     if kind == "rmsnorm":
-        return rmsnorm(x, scale)
-    return layernorm(x, scale, bias)
+        return rmsnorm(x, scale, **kw)
+    return layernorm(x, scale, bias, **kw)
+
+
+def _qk_norm(x: jax.Array, scale: jax.Array, eps: Optional[float]) -> jax.Array:
+    """RMSNorm of a (B, H, S, D) projection over all its H x D features (the
+    norm sits before the split into heads, as in OLMoE), scale (H x D,).
+    Reduced in place over the two axes: a transpose to (B, S, H x D) and
+    back costs 1.6% of the OLMoE cell's step (chip run, PR 27)."""
+    _, h, _, d = x.shape
+    kw = {} if eps is None else {"eps": eps}
+    return rmsnorm(x, scale.reshape(1, h, 1, d), axis=(1, 3), **kw)
 
 
 def attention_sublayer(
@@ -196,7 +216,7 @@ def attention_sublayer(
     """Pre-norm causal self-attention + residual on (B, S, E)."""
     c = config
     dt = c.dtype
-    h = _norm(x, lp["ln1_scale"], lp.get("ln1_bias"), c.norm)
+    h = _norm(x, lp["ln1_scale"], lp.get("ln1_bias"), c.norm, c.norm_eps)
     if c.fused_qkv:
         # one wide matmul beats three narrow ones on the MXU; the concat of
         # the (static) weights folds into the kernel at compile time
@@ -223,6 +243,9 @@ def attention_sublayer(
         q = q + lp["bq"].astype(dt)[None, :, None, :]
         k = k + lp["bk"].astype(dt)[None, :, None, :]
         v = v + lp["bv"].astype(dt)[None, :, None, :]
+    if c.qk_norm:
+        q = _qk_norm(q, lp["q_norm_scale"], c.norm_eps)
+        k = _qk_norm(k, lp["k_norm_scale"], c.norm_eps)
     if rope_tables is not None:
         cos, sin = rope_tables
         q = apply_rope(q, cos, sin, positions)
@@ -238,7 +261,7 @@ def mlp_sublayer(x: jax.Array, lp: Params, config: TransformerConfig) -> jax.Arr
     """Pre-norm dense MLP + residual on (B, S, E)."""
     c = config
     dt = c.dtype
-    h = _norm(x, lp["ln2_scale"], lp.get("ln2_bias"), c.norm)
+    h = _norm(x, lp["ln2_scale"], lp.get("ln2_bias"), c.norm, c.norm_eps)
     up = jnp.einsum("bse,ef->bsf", h, lp["w_up"].astype(dt))
     if c.use_bias:
         up = up + lp["b_up"].astype(dt)
@@ -296,7 +319,7 @@ def forward_hidden(
         block_fn = jax.checkpoint(block_fn)
     x, _ = jax.lax.scan(block_fn, x, params["blocks"], unroll=c.scan_unroll)
 
-    return _norm(x, params["lnf_scale"], params.get("lnf_bias"), c.norm)
+    return _norm(x, params["lnf_scale"], params.get("lnf_bias"), c.norm, c.norm_eps)
 
 
 def lm_head_weights(params: Params, config: TransformerConfig) -> jax.Array:
@@ -321,6 +344,13 @@ def forward(
 
 
 # --------------------------------------------------------------------- decode
+
+
+def _no_qk_norm(config: TransformerConfig) -> None:
+    if config.qk_norm:
+        raise NotImplementedError(
+            "qk_norm is applied by attention_sublayer (training, full-sequence "
+            "forward) only: the cached decode paths do not carry it yet")
 
 
 def init_cache(
@@ -365,6 +395,7 @@ def decode_step(
     cache row at its own position.
     """
     c = config
+    _no_qk_norm(c)
     dt = c.dtype
     b = tokens.shape[0]
     x = params["wte"].astype(dt)[tokens][:, None, :]  # (B, 1, E)
@@ -384,7 +415,7 @@ def decode_step(
 
     def block_fn(x, scanned):
         lp, k_cache, v_cache = scanned
-        h = _norm(x, lp["ln1_scale"], lp.get("ln1_bias"), c.norm)
+        h = _norm(x, lp["ln1_scale"], lp.get("ln1_bias"), c.norm, c.norm_eps)
         q = jnp.einsum("bse,ehd->bhsd", h, lp["wq"].astype(dt))
         k = jnp.einsum("bse,ehd->bhsd", h, lp["wk"].astype(dt))
         v = jnp.einsum("bse,ehd->bhsd", h, lp["wv"].astype(dt))
@@ -404,7 +435,7 @@ def decode_step(
         if c.use_bias:
             out = out + lp["bo"].astype(dt)
         x = x + out
-        h = _norm(x, lp["ln2_scale"], lp.get("ln2_bias"), c.norm)
+        h = _norm(x, lp["ln2_scale"], lp.get("ln2_bias"), c.norm, c.norm_eps)
         up = jnp.einsum("bse,ef->bsf", h, lp["w_up"].astype(dt))
         if c.use_bias:
             up = up + lp["b_up"].astype(dt)
@@ -418,7 +449,7 @@ def decode_step(
         return x + down, (k_cache, v_cache)
 
     x, (new_k, new_v) = jax.lax.scan(block_fn, x, (params["blocks"], cache["k"], cache["v"]))
-    x = _norm(x, params["lnf_scale"], params.get("lnf_bias"), c.norm)
+    x = _norm(x, params["lnf_scale"], params.get("lnf_bias"), c.norm, c.norm_eps)
     head = params.get("lm_head", None)
     if head is None:
         head = params["wte"].T
@@ -437,6 +468,7 @@ def prefill(
     cache, return last-valid-token logits. tokens (B, S) right-padded;
     lengths (B,) true prompt lengths."""
     c = config
+    _no_qk_norm(c)
     dt = c.dtype
     b, s = tokens.shape
     x = params["wte"].astype(dt)[tokens]
@@ -448,7 +480,7 @@ def prefill(
 
     def block_fn(x, scanned):
         lp, k_cache, v_cache = scanned
-        h = _norm(x, lp["ln1_scale"], lp.get("ln1_bias"), c.norm)
+        h = _norm(x, lp["ln1_scale"], lp.get("ln1_bias"), c.norm, c.norm_eps)
         q = jnp.einsum("bse,ehd->bhsd", h, lp["wq"].astype(dt))
         k = jnp.einsum("bse,ehd->bhsd", h, lp["wk"].astype(dt))
         v = jnp.einsum("bse,ehd->bhsd", h, lp["wv"].astype(dt))
@@ -473,7 +505,7 @@ def prefill(
         if c.use_bias:
             out = out + lp["bo"].astype(dt)
         x = x + out
-        h = _norm(x, lp["ln2_scale"], lp.get("ln2_bias"), c.norm)
+        h = _norm(x, lp["ln2_scale"], lp.get("ln2_bias"), c.norm, c.norm_eps)
         up = jnp.einsum("bse,ef->bsf", h, lp["w_up"].astype(dt))
         if c.use_bias:
             up = up + lp["b_up"].astype(dt)
@@ -489,7 +521,7 @@ def prefill(
     x, (new_k, new_v) = jax.lax.scan(
         block_fn, x, (params["blocks"], cache["k"], cache["v"])
     )
-    x = _norm(x, params["lnf_scale"], params.get("lnf_bias"), c.norm)
+    x = _norm(x, params["lnf_scale"], params.get("lnf_bias"), c.norm, c.norm_eps)
     head = params.get("lm_head", None)
     if head is None:
         head = params["wte"].T

@@ -246,7 +246,7 @@ def _text_features(
         return _block(carry, lp, c, None, None), None
 
     x, _ = jax.lax.scan(block_fn, x, params["blocks"])
-    x = _norm(x, params["lnf_scale"], params.get("lnf_bias"), c.norm)
+    x = _norm(x, params["lnf_scale"], params.get("lnf_bias"), c.norm, c.norm_eps)
     return jnp.take_along_axis(x, (lengths - 1)[:, None, None], axis=1)[:, 0]
 
 

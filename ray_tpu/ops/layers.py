@@ -15,11 +15,12 @@ import jax
 import jax.numpy as jnp
 
 
-def rmsnorm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
-    """RMSNorm (Llama-family). scale has shape (d,)."""
+def rmsnorm(x: jax.Array, scale: jax.Array, eps: float = 1e-6, axis=-1) -> jax.Array:
+    """RMSNorm (Llama-family) over `axis` (one or several); scale has
+    shape (d,), or any shape that broadcasts against x."""
     dtype = x.dtype
     x32 = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(x32), axis=axis, keepdims=True)
     normed = x32 * jax.lax.rsqrt(var + eps)
     return (normed * scale.astype(jnp.float32)).astype(dtype)
 

@@ -56,7 +56,7 @@ def cross_entropy_loss(
 # from the head matmul, their f32 upcast, and the f32 probs tensor the
 # backward softmax materializes (PERF_NOTES.md: the b24->b32 regression)
 _DENSE_LOSS_BYTES_PER_LOGIT = 2 + 4 + 4
-_AUTO_CHUNK_HBM_FRACTION = 0.8  # leave headroom for params/opt/activations
+_AUTO_CHUNK_HEADROOM_FRACTION = 0.2  # the least kept for params/opt/activations
 _CHUNK_CANDIDATES = (512, 256, 128)
 
 
@@ -65,24 +65,36 @@ def auto_loss_chunk(
     seq: int,
     vocab: int,
     hbm_bytes: Optional[int] = None,
+    *,
+    resident_bytes: int = 0,
+    step_bytes: int = 0,
 ) -> int:
     """Pick the fused-linear-CE chunk size (0 = dense) from the logits HBM
-    working-set estimate vs the device limit.
+    working-set estimate vs what the device has left.
 
     The dense path is ~8% faster when it fits (PERF_NOTES.md: its extra
     recomputed head matmul + scan overhead), so dense wins until the
     (B_local, S, V) logits working set crowds the HBM — measured on v5e
     16G: batch 24 dense 118.5k tok/s, batch 32 REGRESSES to 111k while
-    fused holds 110.3k flat. Crossover: estimate > 80% of HBM -> chunk.
+    fused holds 110.3k flat. The logits have the device less a headroom:
+    `resident_bytes` the caller knows a device holds all along (its share
+    of the train state, counted from the shardings) plus `step_bytes` it
+    will hold during the step (its gradients), and never less than 20% of
+    the device — so a small model crosses over where it always did
+    (estimate > 80% of HBM), and a model whose state fills most of the
+    chip (one OLMoE layer: 10 GB of 16.9) chunks though its logits alone
+    would fit. Nothing live is probed but the device's size, so the same
+    model and batch always get the same program.
 
     hbm_bytes None = probe the local device (memory_stats().bytes_limit);
-    unknown (CPU backends) means no HBM cliff to dodge -> dense."""
+    an unknown limit (CPU backends) means no HBM cliff to dodge -> dense."""
     if hbm_bytes is None:
         hbm_bytes = _device_hbm_bytes()
     if not hbm_bytes:
         return 0
+    headroom = max(resident_bytes + step_bytes, _AUTO_CHUNK_HEADROOM_FRACTION * hbm_bytes)
     est = batch_per_device * seq * vocab * _DENSE_LOSS_BYTES_PER_LOGIT
-    if est <= _AUTO_CHUNK_HBM_FRACTION * hbm_bytes:
+    if est <= hbm_bytes - headroom:
         return 0
     for chunk in _CHUNK_CANDIDATES:
         if seq % chunk == 0:

@@ -142,7 +142,7 @@ def make_pp_loss_fn(
         y = y_mb.reshape(b, seq, -1)
 
         def head_loss(y):
-            yn = _norm(y, rest["lnf_scale"], rest.get("lnf_bias"), c.norm)
+            yn = _norm(y, rest["lnf_scale"], rest.get("lnf_bias"), c.norm, c.norm_eps)
             head = rest.get("lm_head")
             if head is None:
                 head = rest["wte"].T
@@ -383,7 +383,7 @@ def make_pp_loss_and_grad_1f1b(
         inv_m = 1.0 / num_microbatches
 
         def head_loss(rest_p, y, t):
-            yn = _norm(y, rest_p["lnf_scale"], rest_p.get("lnf_bias"), c.norm)
+            yn = _norm(y, rest_p["lnf_scale"], rest_p.get("lnf_bias"), c.norm, c.norm_eps)
             head = rest_p.get("lm_head")
             if head is None:
                 head = rest_p["wte"].T

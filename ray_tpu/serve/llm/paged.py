@@ -407,7 +407,7 @@ def batched_chunk_prefill_step(
     zero = jnp.int32(0)
     for i in range(c.n_layers):
         lp = {name: w[i] for name, w in params["blocks"].items()}
-        h = _norm(x, lp["ln1_scale"], lp.get("ln1_bias"), c.norm)
+        h = _norm(x, lp["ln1_scale"], lp.get("ln1_bias"), c.norm, c.norm_eps)
         q = jnp.einsum("bse,ehd->bhsd", h, lp["wq"].astype(dt))
         k = jnp.einsum("bse,ehd->bhsd", h, lp["wk"].astype(dt))
         v = jnp.einsum("bse,ehd->bhsd", h, lp["wv"].astype(dt))
@@ -458,7 +458,7 @@ def batched_chunk_prefill_step(
         if c.use_bias:
             out = out + lp["bo"].astype(dt)
         x = x + out
-        h = _norm(x, lp["ln2_scale"], lp.get("ln2_bias"), c.norm)
+        h = _norm(x, lp["ln2_scale"], lp.get("ln2_bias"), c.norm, c.norm_eps)
         up = jnp.einsum("bse,ef->bsf", h, lp["w_up"].astype(dt))
         if c.use_bias:
             up = up + lp["b_up"].astype(dt)
@@ -474,7 +474,7 @@ def batched_chunk_prefill_step(
         if c.use_bias:
             down = down + lp["b_down"].astype(dt)
         x = x + down
-    x = _norm(x, params["lnf_scale"], params.get("lnf_bias"), c.norm)
+    x = _norm(x, params["lnf_scale"], params.get("lnf_bias"), c.norm, c.norm_eps)
     head = params.get("lm_head")
     if head is None:
         head = params["wte"].T
@@ -603,7 +603,7 @@ def ragged_mixed_step(
     zero = jnp.int32(0)
     for i in range(c.n_layers):
         lp = {name: w[i] for name, w in params["blocks"].items()}
-        h = _norm(x, lp["ln1_scale"], lp.get("ln1_bias"), c.norm)
+        h = _norm(x, lp["ln1_scale"], lp.get("ln1_bias"), c.norm, c.norm_eps)
         # heads-leading token-major projections: (T, E) @ (E, H, D) -> (H, T, D)
         q = jnp.einsum("te,ehd->htd", h, lp["wq"].astype(dt))
         k = jnp.einsum("te,ehd->htd", h, lp["wk"].astype(dt))
@@ -655,7 +655,7 @@ def ragged_mixed_step(
         if c.use_bias:
             out = out + lp["bo"].astype(dt)
         x = x + out
-        h = _norm(x, lp["ln2_scale"], lp.get("ln2_bias"), c.norm)
+        h = _norm(x, lp["ln2_scale"], lp.get("ln2_bias"), c.norm, c.norm_eps)
         up = jnp.einsum("te,ef->tf", h, lp["w_up"].astype(dt))
         if c.use_bias:
             up = up + lp["b_up"].astype(dt)
@@ -671,7 +671,7 @@ def ragged_mixed_step(
         if c.use_bias:
             down = down + lp["b_down"].astype(dt)
         x = x + down
-    x = _norm(x, params["lnf_scale"], params.get("lnf_bias"), c.norm)
+    x = _norm(x, params["lnf_scale"], params.get("lnf_bias"), c.norm, c.norm_eps)
     head = params.get("lm_head")
     if head is None:
         head = params["wte"].T
@@ -756,7 +756,7 @@ def paged_decode_step(
         lp = {name: w[i] for name, w in params["blocks"].items()}
         layer_tables = block_tables + i * num_pages
         layer_pages = page_ids + i * num_pages
-        h = _norm(x, lp["ln1_scale"], lp.get("ln1_bias"), c.norm)
+        h = _norm(x, lp["ln1_scale"], lp.get("ln1_bias"), c.norm, c.norm_eps)
         q = jnp.einsum("bse,ehd->bhsd", h, lp["wq"].astype(dt))
         k = jnp.einsum("bse,ehd->bhsd", h, lp["wk"].astype(dt))
         v = jnp.einsum("bse,ehd->bhsd", h, lp["wv"].astype(dt))
@@ -795,7 +795,7 @@ def paged_decode_step(
         if c.use_bias:
             out = out + lp["bo"].astype(dt)
         x = x + out
-        h = _norm(x, lp["ln2_scale"], lp.get("ln2_bias"), c.norm)
+        h = _norm(x, lp["ln2_scale"], lp.get("ln2_bias"), c.norm, c.norm_eps)
         up = jnp.einsum("bse,ef->bsf", h, lp["w_up"].astype(dt))
         if c.use_bias:
             up = up + lp["b_up"].astype(dt)
@@ -812,7 +812,7 @@ def paged_decode_step(
         if c.use_bias:
             down = down + lp["b_down"].astype(dt)
         x = x + down
-    x = _norm(x, params["lnf_scale"], params.get("lnf_bias"), c.norm)
+    x = _norm(x, params["lnf_scale"], params.get("lnf_bias"), c.norm, c.norm_eps)
     head = params.get("lm_head")
     if head is None:
         head = params["wte"].T

@@ -17,6 +17,7 @@ per-optimizer code.
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -26,14 +27,8 @@ import optax
 from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from ..models.transformer import (
-    TransformerConfig,
-    forward,
-    forward_hidden,
-    init_params,
-    lm_head_weights,
-    logical_axes,
-)
+from ..models import model_family
+from ..models.transformer import TransformerConfig, lm_head_weights
 from ..ops import cross_entropy_loss
 from ..ops.losses import auto_loss_chunk, fused_linear_cross_entropy
 from ..parallel.mesh import DATA_AXES
@@ -225,7 +220,8 @@ def create_train_state(
 
     Returns (state, state_shardings)."""
     rules = rules or default_rules()
-    param_specs = tree_specs(logical_axes(config), rules)
+    family = model_family(config)
+    param_specs = tree_specs(family.logical_axes(config), rules)
     n_dp = mesh.shape.get("dp", 1)
     if dp_quant_block is None:
         from ..core.config import cfg
@@ -235,7 +231,7 @@ def create_train_state(
         _check_pure_dp(param_specs)
 
     def build(k):
-        params = init_params(config, k)
+        params = family.init_params(config, k)
         if dp_shard_update:
             rows_template = jax.tree.map(
                 lambda p: jnp.zeros(
@@ -289,6 +285,36 @@ def create_train_state(
     return state, shardings
 
 
+# what every model's step reports; a family's own scalars come beside them
+CORE_STEP_METRICS = ("loss", "grad_norm", "num_tokens")
+
+
+def lm_loss(
+    params: Any, tokens: jax.Array, config: TransformerConfig, *,
+    chunk: int = 0, z_loss_coeff: float = 0.0,
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """The training objective on a (B, S + 1) batch, for every model
+    family: next-token cross entropy (the head chunked by `chunk` rows of
+    the sequence, 0 = dense) plus, for a MoE model, `router_aux_coeff`
+    times the routers' load-balancing loss. Returns (objective, the
+    step's scalars: `loss` = the cross entropy alone, so that a dense and
+    a sparse model's losses mean the same, `num_tokens`, and the
+    routers')."""
+    targets = tokens[:, 1:]
+    hidden, routers = model_family(config).forward_hidden(params, tokens[:, :-1], config)
+    head = lm_head_weights(params, config)
+    if chunk:
+        loss, ntok = fused_linear_cross_entropy(
+            hidden, head, targets, chunk=chunk, z_loss_coeff=z_loss_coeff)
+    else:
+        logits = jnp.einsum("bse,ev->bsv", hidden, head)
+        loss, ntok = cross_entropy_loss(logits, targets, z_loss_coeff=z_loss_coeff)
+    objective = loss
+    if routers:
+        objective = loss + config.router_aux_coeff * routers["router_aux_loss"]
+    return objective, {"loss": loss, "num_tokens": ntok, **routers}
+
+
 def make_train_step(
     config: TransformerConfig,
     optimizer: optax.GradientTransformation,
@@ -331,71 +357,86 @@ def make_train_step(
 
     batch_sharding = NamedSharding(mesh, PartitionSpec(DATA_AXES, None))
     metric_sharding = NamedSharding(mesh, PartitionSpec())
-    # batch rows per device, for the loss-chunk heuristic: the explicit
-    # path sees already-local shapes, the jit path logical/global ones
-    data_shards = 1 if explicit_dp else (
-        mesh.shape.get("dp", 1) * mesh.shape.get("fsdp", 1)
-    )
+    # batch rows per device, for the loss-chunk heuristic (asked with the
+    # whole step's shapes on both paths)
+    data_shards = mesh.shape.get("dp", 1) * mesh.shape.get("fsdp", 1)
 
-    def loss_fn(params, tokens):
-        targets = tokens[:, 1:]
-        chunk = loss_chunk
-        if chunk is None:
-            chunk = auto_loss_chunk(
-                max(tokens.shape[0] // max(data_shards, 1), 1),
-                tokens.shape[1] - 1,
+    chunks: Dict[Tuple[int, ...], int] = {}
+
+    def loss_chunk_for(tokens_shape, state: TrainState) -> int:
+        """The head's form for a (B, S + 1) batch of the whole step: decided
+        the first time the shape is asked for (by a caller or by the step's
+        trace) and kept, so that what is reported is what runs. What a
+        device holds beside the logits is counted from `state`'s shapes
+        and the shardings: its share of the state, and of the gradients."""
+        if loss_chunk is not None:
+            return loss_chunk
+        shape = tuple(tokens_shape)
+        if shape not in chunks:
+            def device_bytes(tree, shardings):
+                return sum(math.prod(sh.shard_shape(x.shape)) * x.dtype.itemsize
+                           for x, sh in zip(jax.tree.leaves(tree), jax.tree.leaves(shardings)))
+
+            chunks[shape] = auto_loss_chunk(
+                max(shape[0] // grad_accum // max(data_shards, 1), 1), shape[1] - 1,
                 config.vocab_size,
+                resident_bytes=device_bytes(state, state_shardings),
+                step_bytes=device_bytes(state.params, state_shardings.params),
             )
-        if chunk:
-            hidden = forward_hidden(params, tokens[:, :-1], config)
-            return fused_linear_cross_entropy(
-                hidden, lm_head_weights(params, config), targets,
-                chunk=chunk, z_loss_coeff=z_loss_coeff,
-            )
-        logits = forward(params, tokens[:, :-1], config)
-        loss, ntok = cross_entropy_loss(logits, targets, z_loss_coeff=z_loss_coeff)
-        return loss, ntok
+        return chunks[shape]
 
-    def microbatch_grads(params, tokens):
+    def loss_fn(params, tokens, chunk):
+        return lm_loss(params, tokens, config, chunk=chunk, z_loss_coeff=z_loss_coeff)
+
+    def microbatch_grads(params, tokens, chunk):
+        """(the step's scalars: `loss`, `num_tokens` and the routers', grads
+        of the objective)."""
         if grad_accum == 1:
-            (loss, ntok), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-                params, tokens
+            (_, scalars), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+                params, tokens, chunk
             )
-            return loss, ntok, grads
+            return scalars, grads
 
         mb_tokens = tokens.reshape(
             grad_accum, tokens.shape[0] // grad_accum, *tokens.shape[1:]
         )
 
         def body(carry, mb):
-            acc_loss, acc_ntok, acc_grads = carry
-            (loss, ntok), grads = jax.value_and_grad(loss_fn, has_aux=True)(params, mb)
-            acc_grads = jax.tree.map(jnp.add, acc_grads, grads)
-            return (acc_loss + loss, acc_ntok + ntok, acc_grads), None
+            acc_scalars, acc_grads = carry
+            (_, scalars), grads = jax.value_and_grad(loss_fn, has_aux=True)(params, mb, chunk)
+            return (jax.tree.map(jnp.add, acc_scalars, scalars),
+                    jax.tree.map(jnp.add, acc_grads, grads)), None
 
+        zero = jax.eval_shape(lambda: loss_fn(params, mb_tokens[0], chunk)[1])
+        zero_scalars = jax.tree.map(lambda x: jnp.zeros(x.shape, x.dtype), zero)
         zero_grads = jax.tree.map(jnp.zeros_like, params)
-        (total_loss, total_ntok, grads), _ = jax.lax.scan(
-            body, (jnp.zeros(()), jnp.zeros(()), zero_grads), mb_tokens
+        (scalars, grads), _ = jax.lax.scan(
+            body, (zero_scalars, zero_grads), mb_tokens
         )
         scale = 1.0 / grad_accum
-        return total_loss * scale, total_ntok, jax.tree.map(lambda g: g * scale, grads)
+        # tokens add up over the microbatches; every other scalar is a mean
+        scalars = {k: v if k == "num_tokens" else v * scale for k, v in scalars.items()}
+        return scalars, jax.tree.map(lambda g: g * scale, grads)
 
     if explicit_dp:
-        return _make_explicit_dp_step(
-            optimizer, mesh, state_shardings, microbatch_grads,
+        step = _make_explicit_dp_step(
+            optimizer, mesh, state_shardings, microbatch_grads, loss_chunk_for,
             dp_allreduce_dtype=dp_allreduce_dtype,
             dp_shard_update=dp_shard_update,
             dp_quant_block=cfg.dp_quant_block,
             batch_sharding=batch_sharding,
             metric_sharding=metric_sharding,
         )
+        step.loss_chunk_for = loss_chunk_for
+        return step
 
     # named_scope labels match the train/steplog STEP_PHASES so device
     # traces (`ray_tpu profile`) line up with the step-phase waterfall
     def step_fn(state: TrainState, batch: Dict[str, jax.Array]):
         tokens = batch["tokens"]
+        chunk = loss_chunk_for(tokens.shape, state)
         with jax.named_scope("steplog.fwd_bwd_compute"):
-            loss, ntok, grads = microbatch_grads(state.params, tokens)
+            scalars, grads = microbatch_grads(state.params, tokens, chunk)
         with jax.named_scope("steplog.optimizer_update"):
             updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
             new_params = optax.apply_updates(state.params, updates)
@@ -407,11 +448,8 @@ def make_train_step(
             rng=jax.random.fold_in(state.rng, state.step),
             ef=state.ef,
         )
-        metrics = {
-            "loss": loss.astype(jnp.float32),
-            "grad_norm": gnorm.astype(jnp.float32),
-            "num_tokens": ntok.astype(jnp.float32),
-        }
+        metrics = {k: v.astype(jnp.float32) for k, v in scalars.items()}
+        metrics["grad_norm"] = gnorm.astype(jnp.float32)
         return new_state, metrics
 
     def step_under_mesh(state: TrainState, batch: Dict[str, jax.Array]):
@@ -421,16 +459,18 @@ def make_train_step(
         with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
             return step_fn(state, batch)
 
-    return jax.jit(
+    step = jax.jit(
         step_under_mesh,
         in_shardings=(state_shardings, {"tokens": batch_sharding}),
-        out_shardings=(state_shardings, {k: metric_sharding for k in ("loss", "grad_norm", "num_tokens")}),
+        out_shardings=(state_shardings, metric_sharding),  # every metric, whatever the family's
         donate_argnums=(0,),
     )
+    step.loss_chunk_for = loss_chunk_for
+    return step
 
 
 def _make_explicit_dp_step(
-    optimizer, mesh, state_shardings, microbatch_grads, *,
+    optimizer, mesh, state_shardings, microbatch_grads, loss_chunk_for, *,
     dp_allreduce_dtype, dp_shard_update, dp_quant_block,
     batch_sharding, metric_sharding,
 ):
@@ -464,17 +504,14 @@ def _make_explicit_dp_step(
         is_leaf=lambda x: isinstance(x, NamedSharding),
     )
     batch_specs = {"tokens": batch_sharding.spec}
-    metric_specs = {
-        k: PartitionSpec() for k in ("loss", "grad_norm", "num_tokens")
-    }
 
     # named_scope labels match the train/steplog STEP_PHASES so device
     # traces line up with the step-phase waterfall (the host can only
     # ESTIMATE dp_sync; the trace scope is where the truth lives)
-    def local_step(state: TrainState, batch: Dict[str, jax.Array]):
+    def local_step(chunk: int, state: TrainState, batch: Dict[str, jax.Array]):
         tokens = batch["tokens"]
         with jax.named_scope("steplog.fwd_bwd_compute"):
-            loss, ntok, grads = microbatch_grads(state.params, tokens)
+            scalars, grads = microbatch_grads(state.params, tokens, chunk)
         grows = jax.tree.map(lambda g: _to_rows(g, n, dp_quant_block), grads)
         if quantized:
             if state.ef is None:
@@ -559,26 +596,28 @@ def _make_explicit_dp_step(
             rng=jax.random.fold_in(state.rng, state.step),
             ef=new_ef,
         )
+        # tokens add up over the replicas; every other scalar is a mean
         metrics = {
-            "loss": lax.pmean(loss, axis).astype(jnp.float32),
-            "grad_norm": gnorm.astype(jnp.float32),
-            "num_tokens": lax.psum(ntok, axis).astype(jnp.float32),
+            k: (lax.psum if k == "num_tokens" else lax.pmean)(v, axis).astype(jnp.float32)
+            for k, v in scalars.items()
         }
+        metrics["grad_norm"] = gnorm.astype(jnp.float32)
         return new_state, metrics
 
-    sharded = shard_map(
-        local_step, mesh=mesh,
-        in_specs=(state_specs, batch_specs),
-        out_specs=(state_specs, metric_specs),
-        check_vma=False,
-    )
+    def sharded(state: TrainState, batch: Dict[str, jax.Array]):
+        # the head's form is decided on the whole step's shapes, outside
+        chunk = loss_chunk_for(batch["tokens"].shape, state)
+        return shard_map(
+            partial(local_step, chunk), mesh=mesh,
+            in_specs=(state_specs, batch_specs),
+            out_specs=(state_specs, PartitionSpec()),
+            check_vma=False,
+        )(state, batch)
+
     return jax.jit(
         sharded,
         in_shardings=(state_shardings, {"tokens": batch_sharding}),
-        out_shardings=(
-            state_shardings,
-            {k: metric_sharding for k in ("loss", "grad_norm", "num_tokens")},
-        ),
+        out_shardings=(state_shardings, metric_sharding),
         donate_argnums=(0,),
     )
 
@@ -586,10 +625,13 @@ def _make_explicit_dp_step(
 def make_eval_step(config: TransformerConfig, mesh: Mesh, state_shardings: Any):
     batch_sharding = NamedSharding(mesh, PartitionSpec(DATA_AXES, None))
 
+    family = model_family(config)
+
     def eval_fn(state: TrainState, batch):
         tokens = batch["tokens"]
         with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):  # see make_train_step
-            logits = forward(state.params, tokens[:, :-1], config)
+            hidden, _ = family.forward_hidden(state.params, tokens[:, :-1], config)
+            logits = jnp.einsum("bse,ev->bsv", hidden, lm_head_weights(state.params, config))
         loss, ntok = cross_entropy_loss(logits, tokens[:, 1:])
         return {"eval_loss": loss.astype(jnp.float32), "num_tokens": ntok}
 

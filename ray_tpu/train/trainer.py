@@ -23,7 +23,7 @@ from ..parallel.sharding import default_rules
 from .checkpoint import CheckpointManager
 from .config import CheckpointConfig, RunConfig, ScalingConfig
 from .controller import Result, TrainController
-from .lm import create_train_state, default_optimizer, make_train_step
+from .lm import CORE_STEP_METRICS, create_train_state, default_optimizer, make_train_step
 
 
 class Trainer:
@@ -147,10 +147,11 @@ class LMTrainer:
         # once the first time a report needs it (one extra AOT compile;
         # disable with profile_cost_accounting=False)
         self._step_cost = None
-        # sequence length the step was last traced for: the attention
-        # implementation and its sub-tile walk are known with it, and are
-        # then written on the train.init.step_fn span
-        self._attn_seq: Optional[int] = None
+        # batch shape the step was last traced for: the attention
+        # implementation and its sub-tile walk, the expert layer's form and
+        # the head's chunk are known with it, and are then written on the
+        # train.init.step_fn span
+        self._plan_shape: Optional[tuple] = None
         self.ckpt_config = checkpoint_config
         self.ckpt_mgr: Optional[CheckpointManager] = None
         if checkpoint_config and checkpoint_config.checkpoint_dir:
@@ -164,16 +165,22 @@ class LMTrainer:
     def num_params(self) -> int:
         return count_params(self.state.params)
 
-    def _note_attention(self, seq: int) -> None:
-        """Attributes of `train.init.step_fn`: what the step's attention
-        resolves to at this sequence length (the jitted step is traced per
-        shape, so only the first batch says)."""
+    def _note_step_plan(self, tokens_shape: tuple) -> None:
+        """Attributes of `train.init.step_fn`: what the step's attention,
+        its expert layer (a MoE model's) and its head resolve to for a
+        (B, S + 1) batch (the jitted step is traced per shape, so only the
+        first batch says)."""
+        from ..models import model_family
         from ..ops.attention import attention_plan
 
-        self._attn_seq = seq
-        for key, value in attention_plan(
-                seq, causal=self.config.causal,
-                implementation=self.config.attn_impl).items():
+        self._plan_shape = tuple(tokens_shape)
+        batch, seq = tokens_shape[0], tokens_shape[1] - 1
+        plan = attention_plan(
+            seq, causal=self.config.causal, implementation=self.config.attn_impl)
+        with jax.sharding.use_abstract_mesh(self.mesh.abstract_mesh):  # as the step is traced
+            plan.update(model_family(self.config).plan(self.config, batch * seq))
+        plan["loss_chunk"] = self.step_fn.loss_chunk_for(tokens_shape, self.state)
+        for key, value in plan.items():
             self._step_fn_span.set_attribute(key, value)
 
     def restore(self, step: Optional[int] = None) -> int:
@@ -259,8 +266,8 @@ class LMTrainer:
                     break
                 window_input_wait += wait.duration_s
                 tokens = batch["tokens"]
-                if tokens.shape[-1] - 1 != self._attn_seq:
-                    self._note_attention(tokens.shape[-1] - 1)
+                if tuple(tokens.shape) != self._plan_shape:
+                    self._note_step_plan(tokens.shape)
                 with tracing.span("train.step.h2d", parent=ctx,
                                   start=wait.ended) as h2d:
                     if isinstance(tokens, np.ndarray):
@@ -288,9 +295,18 @@ class LMTrainer:
                     rctx = report.context
                     with tracing.span("train.report.read", parent=rctx,
                                       start=report.started) as read:
-                        # the host read that waits for the device
+                        # the host read that waits for the device: every
+                        # scalar and the step counter in ONE transfer, so the
+                        # idle device waits for one host round trip, not one
+                        # a scalar, before the next step is handed to it
+                        metrics, step_now = jax.device_get((metrics, self.state.step))
                         metrics = {k: float(v) for k, v in metrics.items()}
-                        metrics["step"] = int(self.state.step)
+                        # what the model's family adds to the step's scalars
+                        # (a MoE model's routers) also rides the span
+                        for key, value in metrics.items():
+                            if key not in CORE_STEP_METRICS:
+                                report.set_attribute(key, value)
+                        metrics["step"] = int(step_now)
                     with tracing.span("train.report.cost", parent=rctx,
                                       start=read.ended) as cost:
                         now = read.end_mono

@@ -19,7 +19,8 @@ from ray_tpu.models.transformer import logical_axes
 from ray_tpu.parallel import MeshSpec, build_mesh, default_rules
 from ray_tpu.parallel.sharding import tree_specs
 from ray_tpu.train import default_optimizer, make_train_step
-from ray_tpu.train.lm import TrainState, _sharding_tree, infer_state_specs, init_params
+from ray_tpu.models.transformer import init_params
+from ray_tpu.train.lm import TrainState, _sharding_tree, infer_state_specs
 
 
 def _abstract_state_and_shardings(config, opt, mesh):
@@ -168,10 +169,18 @@ def test_llama3_8b_tp_serving_lowers_sharded():
     assert any("'tp'" in s for s in flat)
 
 
-def test_mixtral_8x7b_moe_lowers_expert_parallel():
+@pytest.mark.parametrize("context_mesh", [False, True], ids=["weights-mesh", "context-mesh"])
+def test_mixtral_8x7b_moe_lowers_expert_parallel(context_mesh):
     """BASELINE config 3: the REAL Mixtral 8x7B shapes (8 experts, 32
     layers, d_ff 14336) lower through the partitioner on a dp2 x ep4
-    mesh with expert-stacked weights sharded on the ep axis."""
+    mesh with expert-stacked weights sharded on the ep axis, through the
+    trainer's objective. The mesh has an `ep` axis, so the GShard form is
+    what lowers, whether the mesh is the context's or only the one the
+    expert weights are sharded over (models/moe._mesh_of)."""
+    import contextlib
+
+    from ray_tpu.train.lm import lm_loss
+
     from ray_tpu.models import moe
 
     config = moe.mixtral_8x7b()
@@ -196,8 +205,12 @@ def test_mixtral_8x7b_moe_lowers_expert_parallel():
         (8, 1024 + 1), jax.numpy.int32, sharding=batch_sharding
     )
 
-    loss_fn = jax.jit(lambda p, t: moe.moe_loss(p, t, config)[0])
-    hlo = loss_fn.lower(abs_params, abs_tokens).as_text()
+    loss_fn = jax.jit(lambda p, t: lm_loss(p, t, config)[0])
+    with jax.set_mesh(mesh) if context_mesh else contextlib.nullcontext():
+        hlo = loss_fn.lower(abs_params, abs_tokens).as_text()
+    # the GShard dispatch: a (B, S, E, C) one-hot at capacity 2.0 * 2 * 1024 / 8
+    # contracted with the tokens, and no ragged group anywhere
+    assert "tensor<8x1024x8x512x" in hlo and "ragged_dot" not in hlo
     assert "mhlo.num_partitions = 8" in hlo
     assert '{"ep"}' in hlo, "no expert-stacked weight is ep-sharded"
     # the expert-parallel property: per-device expert bytes shrink by ep

@@ -7,15 +7,22 @@ import pytest
 
 from ray_tpu.models.moe import (
     MoEConfig,
-    forward,
+    forward_hidden,
     init_params,
-    load_balancing_loss,
     logical_axes,
-    moe_loss,
     moe_tiny,
     topk_dispatch,
 )
+from ray_tpu.models.transformer import lm_head_weights
 from ray_tpu.parallel import MeshSpec, build_mesh, default_rules, shard_tree
+from ray_tpu.train.lm import lm_loss
+
+
+def forward(params, tokens, config):
+    """(logits, the routers' summed auxiliary loss)."""
+    hidden, routers = forward_hidden(params, tokens, config)
+    logits = jnp.einsum("bse,ev->bsv", hidden, lm_head_weights(params, config))
+    return logits, routers["router_aux_loss"]
 
 
 @pytest.fixture
@@ -76,7 +83,7 @@ def test_param_axes_match(model):
 def test_grad_flows_including_router(model):
     config, params = model
     tokens = jax.random.randint(jax.random.PRNGKey(2), (2, 16), 0, config.vocab_size)
-    grads = jax.grad(lambda p: moe_loss(p, tokens, config)[0])(params)
+    grads = jax.grad(lambda p: lm_loss(p, tokens, config)[0])(params)
     router_norm = float(jnp.linalg.norm(grads["blocks"]["router"]))
     expert_norm = float(jnp.linalg.norm(grads["blocks"]["we_up"]))
     assert np.isfinite(router_norm) and router_norm > 0
@@ -98,6 +105,25 @@ def test_expert_parallel_sharded_matches_replicated(model):
     np.testing.assert_allclose(float(aux), float(aux_e), rtol=1e-5)
 
 
+@pytest.mark.parametrize("context_mesh", [False, True], ids=["weights-mesh", "context-mesh"])
+def test_ep_sharded_params_take_the_gshard_form(model, context_mesh):
+    """The form follows the mesh the step has: parameters sharded over an
+    `ep` axis trace the GShard einsums (no ragged group, no per-shard
+    shard_map) whether the mesh is the context's or only the one the
+    expert weights carry; the same parameters on one device trace the
+    dropless form."""
+    import contextlib
+
+    config, params = model
+    tokens = jax.random.randint(jax.random.PRNGKey(3), (4, 16), 0, config.vocab_size)
+    mesh = build_mesh(MeshSpec(dp=2, ep=2, tp=2))
+    sharded = shard_tree(params, logical_axes(config), default_rules(), mesh)
+    with jax.set_mesh(mesh) if context_mesh else contextlib.nullcontext():
+        traced = str(jax.make_jaxpr(lambda p: forward(p, tokens, config))(sharded))
+    assert "ragged_dot" not in traced and "shard_map" not in traced
+    assert "ragged_dot" in str(jax.make_jaxpr(lambda p: forward(p, tokens, config))(params))
+
+
 def test_moe_training_reduces_loss(model):
     config, params = model
     import optax
@@ -109,7 +135,7 @@ def test_moe_training_reduces_loss(model):
     @jax.jit
     def step(params, opt_state):
         (loss, _), grads = jax.value_and_grad(
-            lambda p: moe_loss(p, tokens, config), has_aux=True
+            lambda p: lm_loss(p, tokens, config), has_aux=True
         )(params)
         updates, opt_state = opt.update(grads, opt_state, params)
         return optax.apply_updates(params, updates), opt_state, loss

@@ -154,3 +154,61 @@ def test_flash_attention_under_a_mesh_is_partitioned_per_shard(v5e, as_tpu):
     no_context = jax.jit(lambda q, k, v: flash.flash_attention(q, k, v, causal=True))
     with pytest.raises(NotImplementedError, match="cannot be automatically partitioned"):
         no_context.lower(q, kv, kv).compile()
+
+
+@pytest.mark.parametrize("k,n", [(2048, 1024), (1024, 2048)], ids=["gate-up", "down"])
+def test_moe_grouped_matmul_kernels_compile_at_the_olmoe_cell_shapes(as_tpu, k, n):
+    """train-olmoe-64e-4k: 131,072 routed rows padded per expert to 256-row
+    tiles (147,456 slots), 64 experts, 2048 <-> 1024: the forward alone,
+    then it with both backward products (`moe_gmm_fwd`, `_dlhs`, `_drhs`)."""
+    from ray_tpu.ops import grouped_matmul as gmm
+
+    assert gmm.resolve_gmm_impl() == "pallas" and gmm.gmm_tile_rows() == gmm.PALLAS_TILE_ROWS
+    slots = 131072 + 64 * gmm.PALLAS_TILE_ROWS
+    lhs, rhs = _on(as_tpu, (slots, k)), _on(as_tpu, (64, k, n))
+    sizes = _on(as_tpu, (64,), jnp.int32)
+
+    def forward(lhs, rhs, sizes):
+        return gmm.grouped_matmul(lhs, rhs, sizes, tile_rows=gmm.PALLAS_TILE_ROWS)
+
+    def loss(lhs, rhs, sizes):
+        return forward(lhs, rhs, sizes).astype(jnp.float32).sum()
+
+    assert _kernel_calls(jax.jit(forward).lower(lhs, rhs, sizes).compile()) == 1
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1)))
+    # the forward is dead in this loss (a sum's gradient needs no output): dlhs, drhs
+    assert _kernel_calls(grad.lower(lhs, rhs, sizes).compile()) == 2
+
+
+def test_moe_layer_under_a_mesh_runs_the_kernels_per_shard(v5e, as_tpu):
+    """One OLMoE expert layer (64 experts of 1024, top-8) on fsdp=2 x tp=2:
+    the layer shard_maps itself over the mesh the weights carry (no context
+    mesh here), so each chip sorts its own two 4,096-token sequences and
+    runs the nine `moe_gmm_*` calls of a forward and backward on its tp
+    slice of every expert; nothing Mosaic is left to GSPMD."""
+    from ray_tpu.models import moe
+
+    config = moe.olmoe_1b_7b()
+    mesh = build_mesh(MeshSpec(fsdp=2, tp=2), devices=v5e.devices)
+
+    def on_mesh(shape, spec, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(mesh, spec))
+
+    m, f, e = config.d_model, config.d_ff, config.n_experts
+    lp = {
+        "ln2_scale": on_mesh((m,), PartitionSpec()),
+        "router": on_mesh((m, e), PartitionSpec("fsdp", None)),
+        "we_gate": on_mesh((e, m, f), PartitionSpec(None, "fsdp", "tp")),
+        "we_up": on_mesh((e, m, f), PartitionSpec(None, "fsdp", "tp")),
+        "we_down": on_mesh((e, f, m), PartitionSpec(None, "tp", "fsdp")),
+    }
+    x = on_mesh((4, 4096, m), PartitionSpec(("dp", "fsdp"), None, None), jnp.bfloat16)
+
+    def loss(lp, x):
+        out, aux, _ = moe.moe_mlp_sublayer(x, lp, config)
+        return out.astype(jnp.float32).sum() + aux
+
+    compiled = jax.jit(jax.grad(loss)).lower(lp, x).compile()
+    assert _kernel_calls(compiled) == 9
+    # a chip holds its quarter of the three expert stacks (float32), not the whole
+    assert compiled.memory_analysis().argument_size_in_bytes < 2 * (3 * e * m * f * 4) // 4

@@ -77,15 +77,16 @@ def test_step_fn_span_names_the_attention_the_step_runs(trainer, monkeypatch):
     assert "attention_impl" not in _one(init_spans, "train.init.step_fn")["attrs"]
     attrs = trainer._step_fn_span.to_dict()["attrs"]
     assert attrs == {"attention_impl": "xla", "attn_subtiles_visited": 0,
-                     "attn_subtiles_masked": 0, "attn_subtiles_total": 0}
+                     "attn_subtiles_masked": 0, "attn_subtiles_total": 0,
+                     "loss_chunk": 0}   # 0: the dense head; no MoE key on a dense model
     # on the chip, at the cells' sequence length
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    trainer._note_attention(1024)
+    trainer._note_step_plan((2, 1025))
     attrs = trainer._step_fn_span.to_dict()["attrs"]
     assert attrs["attention_impl"] == "pallas"
     assert (attrs["attn_subtiles_visited"], attrs["attn_subtiles_masked"],
             attrs["attn_subtiles_total"]) == (10, 4, 16)
-    trainer._note_attention(16)
+    trainer._note_step_plan((2, 17))
 
 
 def test_span_tree_of_a_three_step_train_call(trainer):
@@ -258,6 +259,27 @@ def test_successive_calls_take_exactly_their_batches(trainer):
     assert last["step"] > 0
     dry = [s for s in tracing.tracer().spans() if s["attrs"].get("end_of_data")]
     assert [s["name"] for s in dry] == ["train.step"]
+
+
+def test_a_report_reads_the_device_once(trainer, monkeypatch):
+    """The device idles from the last step's end to the next dispatch, so
+    the report fetches every scalar and the step counter in ONE host
+    transfer (under a busy host each further round trip cost the OLMoE
+    cell ~10 ms a segment, PERF.md PR 27): no scalar is read on its own."""
+    trainer, config, _, _ = trainer
+    cfg.train_step_log = False          # a sampled step syncs on its own
+    fetched = []
+    real = jax.device_get
+    monkeypatch.setattr(jax, "device_get", lambda x: fetched.append(x) or real(x))
+    monkeypatch.setattr(
+        type(jnp.zeros(())), "__float__",
+        lambda self: pytest.fail("a scalar read on its own"))
+    out = trainer.train(_batches(10, 4, config.vocab_size), num_steps=4,
+                        report_every=2)
+    assert len(fetched) == 2            # one a report
+    metrics, step = fetched[-1]
+    assert set(metrics) >= {"loss", "grad_norm", "num_tokens"}
+    assert out["step"] == int(step) and isinstance(out["loss"], float)
 
 
 def test_no_span_with_sample_ratio_zero_and_equal_metrics():
