@@ -333,8 +333,10 @@ def make_train_step(
 
     loss_chunk > 0 fuses the LM head with the loss over sequence chunks
     of that size (fused_linear_cross_entropy): the (B, S, V) logits —
-    the peak-memory hog at LM vocab sizes — never materializes, buying
-    batch headroom at ~+10%% recomputed head flops. None (default)
+    the peak-memory hog at LM vocab sizes — never materialize, and each
+    chunk's logits are built once (its dx and its share of the head's
+    gradient are computed in the same scan step: three head matmuls a
+    chunk, as the dense head runs over the whole batch). None (default)
     auto-selects via ops.losses.auto_loss_chunk (logits HBM estimate vs
     the device limit); 0 forces the dense path.
 

@@ -165,6 +165,32 @@ def test_lmtrainer_first_step_is_the_reference_loss_and_reports_the_routers():
     assert chunked._step_fn_span.to_dict()["attrs"]["loss_chunk"] == 16
 
 
+def test_chunked_head_step_of_the_tiny_olmoe_is_the_dense_heads_step():
+    """The head the cell runs (`loss_chunk` > 0: the head's gradients
+    computed in each chunk's forward pass) against the dense head through
+    the whole step, expert layer and routers' loss included: the same loss,
+    gradient norm and updated parameters (plain SGD, so that the update is
+    the gradient)."""
+    import optax
+
+    config = tiny_olmoe(top_k=2)
+    tokens = np.random.default_rng(4).integers(0, 256, size=(8, 33)).astype(np.int32)
+
+    def one_step(loss_chunk):
+        trainer = LMTrainer(config, mesh_spec=MeshSpec(dp=2, fsdp=2, tp=2), seed=3,
+                            optimizer=optax.sgd(0.1), loss_chunk=loss_chunk)
+        out = trainer.train(iter([{"tokens": tokens}]), num_steps=1, report_every=1)
+        return out, trainer.state.params
+
+    dense, dense_params = one_step(0)
+    chunked, chunked_params = one_step(16)
+    for key in ("loss", "grad_norm", "router_aux_loss"):
+        assert chunked[key] == pytest.approx(dense[key], rel=1e-5), key
+    for (path, got), want in zip(jax.tree_util.tree_flatten_with_path(chunked_params)[0],
+                                 jax.tree.leaves(dense_params)):
+        assert _rel_rms(got, want) <= LOGITS_REL_RMS, jax.tree_util.keystr(path)
+
+
 @pytest.mark.parametrize("axes,context_mesh", [
     (dict(dp=2, tp=2), True), (dict(fsdp=2, tp=2), False), (dict(dp=2, fsdp=2, sp=2), True)],
     ids=["dp2-tp2-context-mesh", "fsdp2-tp2-weights-mesh", "dp2-fsdp2-sp2-context-mesh"])
@@ -274,14 +300,19 @@ V5E_HBM = int(15.75 * 2 ** 30)
 @pytest.mark.parametrize("cell,batch,seq,vocab,state_gb,grad_gb,want", [
     ("train-gpt2s", 24, 1024, 50257, 1.5, 0.5, 0),
     ("train-mistral7b-fsdp2tp2", 12, 1024, 32768, 6.04, 2.01, 0),
-    ("train-olmoe-64e-4k", 4, 4096, 50304, 7.51, 2.50, 512),
+    ("train-olmoe-64e-4k", 4, 4096, 50304, 7.51, 2.50, 2048),
     ("gpt2s-at-batch-32", 32, 1024, 50257, 1.5, 0.5, 512),
+    # the largest chunk whose own logits fit half the room the dense ones did not
+    ("a-200k-vocabulary-at-4k", 8, 4096, 200192, 7.5, 2.5, 256),
+    ("no-chunk-fits-so-the-smallest", 64, 1024, 200192, 10.5, 3.5, 128),
 ])
 def test_head_is_chunked_where_the_logits_do_not_fit_beside_the_state(
         cell, batch, seq, vocab, state_gb, grad_gb, want):
     """The shipped cells keep the dense head they ran before; one OLMoE
     layer's state (10 GB of 16.9 with its gradients) leaves no room for
-    8.2 GB of logits. Nothing live is read: the same numbers, the same form."""
+    8.2 GB of logits. A chunked head takes the largest chunk that divides
+    S and whose own logits fit half of that room. Nothing live is read: the same
+    numbers, the same form."""
     from ray_tpu.ops.losses import auto_loss_chunk
 
     assert auto_loss_chunk(batch, seq, vocab, V5E_HBM, resident_bytes=int(state_gb * 1e9),

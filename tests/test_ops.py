@@ -1,5 +1,7 @@
 """Kernel correctness: Pallas flash attention (interpret mode) vs XLA reference."""
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -375,7 +377,9 @@ def test_flash_kernel_runs_per_shard_under_a_context_mesh():
 def test_auto_loss_chunk_crossover():
     """Pins the dense->fused crossover at the measured v5e numbers: batch
     24 stays dense on a 16G chip, batch 32 (the measured regression) flips
-    to the fused chunked path; unknown HBM (CPU) always dense."""
+    to the fused chunked path (1,024, the whole sequence, would take more
+    than half the room the activations share: measured 6% slower than 512
+    with the chip 95% full); unknown HBM (CPU) always dense."""
     from ray_tpu.ops.losses import auto_loss_chunk
 
     v5e = 16 * 1024**3
@@ -445,3 +449,134 @@ def test_fused_linear_cross_entropy_matches_dense():
 
     with pytest.raises(ValueError):
         fused_linear_cross_entropy(x, head, targets, chunk=5)
+
+
+def _head_case(dtype=jnp.float32):
+    b, s, e, v = 2, 64, 8, 50
+    x = _rand(jax.random.PRNGKey(21), (b, s, e), dtype)
+    head = (0.3 * _rand(jax.random.PRNGKey(22), (e, v))).astype(dtype)
+    targets = jax.random.randint(jax.random.PRNGKey(23), (b, s), 0, v)
+    mask = (jax.random.uniform(jax.random.PRNGKey(24), (b, s)) > 0.3).astype(jnp.int32)
+    return x, head, targets, mask
+
+
+@pytest.mark.parametrize(
+    "case", ["plain", "mask", "z_loss", "mask_z_loss", "cotangent", "tied", "jit", "bf16"])
+def test_chunked_head_value_and_gradients_match_the_dense_loss(case):
+    """`fused_linear_cross_entropy` computes dx and dW in the chunk's
+    forward pass (one custom_vjp); the dense `cross_entropy_loss` under
+    plain autodiff is the reference for the value and both gradients."""
+    from ray_tpu.ops.losses import fused_linear_cross_entropy
+
+    dtype = jnp.bfloat16 if case == "bf16" else jnp.float32
+    x, head, targets, mask = _head_case(dtype)
+    kw = {"mask": mask if "mask" in case else None,
+          "z_loss_coeff": 1e-2 if "z_loss" in case else 0.0}
+    scale = 3.0 if case == "cotangent" else 1.0
+    # a tied head reaches the loss as the transpose of the embedding
+    w = head.T if case == "tied" else head
+    weights = (lambda w: w.T) if case == "tied" else (lambda w: w)
+
+    def dense(x, w):
+        logits = jnp.einsum("bse,ev->bsv", x, weights(w))
+        return scale * cross_entropy_loss(logits, targets, **kw)[0]
+
+    def chunked(x, w):
+        loss, num = fused_linear_cross_entropy(x, weights(w), targets, chunk=16, **kw)
+        return scale * loss, num
+
+    want, (want_dx, want_dw) = jax.value_and_grad(dense, argnums=(0, 1))(x, w)
+    grad = jax.value_and_grad(chunked, argnums=(0, 1), has_aux=True)
+    (got, num), (got_dx, got_dw) = (jax.jit(grad) if case == "jit" else grad)(x, w)
+    assert got_dx.dtype == x.dtype and got_dw.dtype == w.dtype and got_dw.shape == w.shape
+    assert float(num) == (float(mask.sum()) if "mask" in case else targets.size)
+    # float32: the sums run in another order; bfloat16: dx and dW leave the
+    # dense path through the same casts, so one ulp (2^-8) of the largest entry
+    tol = {"rtol": 2e-2, "atol": 2e-3} if case == "bf16" else {"rtol": 1e-5, "atol": 1e-6}
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-2 if case == "bf16" else 1e-5)
+    np.testing.assert_allclose(np.asarray(got_dx, np.float32), np.asarray(want_dx, np.float32), **tol)
+    np.testing.assert_allclose(np.asarray(got_dw, np.float32), np.asarray(want_dw, np.float32), **tol)
+    # the undifferentiated call (evaluation) is the same loss
+    np.testing.assert_allclose(float(chunked(x, w)[0]), float(got), rtol=1e-6)
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _equations(sub)
+
+
+def _scan_bodies(jaxpr):
+    return [eqn.params["jaxpr"].jaxpr for eqn in _equations(jaxpr) if eqn.primitive.name == "scan"]
+
+
+def _count(jaxpr, primitive):
+    return sum(eqn.primitive.name == primitive for eqn in _equations(jaxpr))
+
+
+def test_chunked_head_builds_each_chunks_logits_once():
+    """Three matmuls a chunk under differentiation (logits, dx, dW), one
+    without; nothing is rematerialized, and nothing the backward keeps has
+    a vocabulary-sized axis beside more than a chunk of rows."""
+    from ray_tpu.ops import losses
+
+    x, head, targets, mask = _head_case()
+    chunk, vocab = 16, head.shape[1]
+
+    def loss(x, head):
+        return losses.fused_linear_cross_entropy(
+            x, head, targets, chunk=chunk, mask=mask, z_loss_coeff=1e-3)[0]
+
+    grad = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(x, head).jaxpr
+    (body,) = _scan_bodies(grad)
+    assert _count(body, "dot_general") == 3
+    assert _count(grad, "dot_general") == 3   # none outside the scan: the backward only scales
+    names = {eqn.primitive.name for eqn in _equations(grad)}
+    assert not names & {"checkpoint", "remat", "remat2"}, names
+
+    plain = jax.make_jaxpr(loss)(x, head).jaxpr
+    (body,) = _scan_bodies(plain)
+    assert _count(body, "dot_general") == 1 and _count(plain, "dot_general") == 1
+
+    _, residuals = jax.eval_shape(
+        lambda *a: losses._chunked_cross_entropy_fwd(*a, chunk, 1e-3),
+        x, head, targets, mask.astype(jnp.float32))
+    shapes = sorted(r.shape for r in jax.tree.leaves(residuals))
+    assert shapes == sorted([x.shape, head.shape])
+    for shape in shapes:
+        if vocab in shape:
+            assert math.prod(shape) // vocab <= chunk, shape
+
+
+def test_chunked_head_under_the_grad_accum_scan_of_the_train_step():
+    """`make_train_step(grad_accum=2)` differentiates the loss inside a
+    scan over microbatches: the chunked head there gives the dense head's
+    loss and update."""
+    import optax
+    from ray_tpu.models import get_config
+    from ray_tpu.parallel import single_device_mesh
+    from ray_tpu.train import create_train_state, make_train_step
+
+    config = get_config("gpt2-tiny")
+    mesh = single_device_mesh()
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 33), 0, config.vocab_size)
+
+    def run(loss_chunk):
+        opt = optax.sgd(0.1)   # the update is the gradient: no Adam sign step near zero
+        state, sh = create_train_state(config, opt, jax.random.PRNGKey(0), mesh)
+        step = make_train_step(config, opt, mesh, state_shardings=sh, grad_accum=2,
+                               loss_chunk=loss_chunk, z_loss_coeff=1e-3)
+        state, metrics = step(state, {"tokens": tokens})
+        return state, metrics
+
+    dense_state, dense = run(0)
+    chunked_state, chunked = run(16)
+    np.testing.assert_allclose(float(chunked["loss"]), float(dense["loss"]), rtol=1e-5)
+    np.testing.assert_allclose(float(chunked["grad_norm"]), float(dense["grad_norm"]), rtol=1e-4)
+    for a, b in zip(jax.tree.leaves(dense_state.params), jax.tree.leaves(chunked_state.params)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5, rtol=2e-4)

@@ -439,3 +439,32 @@ def test_path_specs_search_semantics(mesh8):
     specs = path_specs(tree, [(r"wq_norm", PartitionSpec()), (r"wq", PartitionSpec("tp"))])
     assert specs["decoder"]["wq"] == PartitionSpec("tp")
     assert specs["decoder"]["wq_norm"] == PartitionSpec()
+
+
+def test_chunked_head_on_an_fsdp_x_tp_mesh_matches_the_dense_head():
+    """The chunked head (dx and dW computed in the chunk's forward pass,
+    ops/losses.fused_linear_cross_entropy) is plain jnp code that GSPMD
+    partitions: on fsdp=2 x tp=2, with the untied head sharded over both,
+    one step gives the dense head's loss and updated parameters."""
+    import optax
+    from ray_tpu.models import get_config
+    from ray_tpu.train import create_train_state, make_train_step
+
+    config = get_config("llama-tiny")
+    mesh = build_mesh(MeshSpec(fsdp=2, tp=2), devices=jax.devices()[:4])
+    tokens = jax.random.randint(jax.random.PRNGKey(5), (4, 33), 0, config.vocab_size)
+
+    def one_step(loss_chunk):
+        opt = optax.sgd(0.1)   # the update is the gradient
+        state, sh = create_train_state(config, opt, jax.random.PRNGKey(2), mesh)
+        assert state.params["lm_head"].sharding.spec == P("fsdp", "tp")
+        step = make_train_step(config, opt, mesh, state_shardings=sh, loss_chunk=loss_chunk)
+        state, metrics = step(state, {"tokens": tokens})
+        return metrics, state.params
+
+    dense, dense_params = one_step(0)
+    chunked, chunked_params = one_step(16)
+    np.testing.assert_allclose(float(chunked["loss"]), float(dense["loss"]), rtol=1e-5)
+    np.testing.assert_allclose(float(chunked["grad_norm"]), float(dense["grad_norm"]), rtol=1e-4)
+    for a, b in zip(jax.tree.leaves(dense_params), jax.tree.leaves(chunked_params)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5, rtol=2e-4)
