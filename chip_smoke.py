@@ -8,7 +8,7 @@ replicas and engine loops are threads of it), so it owns the chip.
 Default (one chip), one JSON line per phase:
 
 1. runtime  `ray_tpu.init()` sees the chip; a `num_tpus=1` task reduces on it.
-2. train    `LMTrainer` on gpt2-small as bench.py configures it (bf16,
+2. train    `LMTrainer` on gpt2-small as the `train-gpt2s` cell configures it (bf16,
             batch 24 x seq 1024, scan_unroll=12): finite, falling loss;
             the attention kernels named in the step's program.
 3. serve    `serve.run(build_llm_app(llama3-8b widths, paged=True))` at the
@@ -717,7 +717,7 @@ def one_chip_phases(cache: CompileCacheCounter) -> list:
     from ray_tpu.serve.llm.paged_engine import PagedEngineConfig
 
     results = [run_phase("runtime", phase_runtime, 1)]
-    # the flagship exactly as bench.py configures it
+    # gpt2-small as benchmark/configs/gpt2-small-train-1chip.json configures it
     results.append(run_phase(
         "train", phase_train, get_config("gpt2-small").replace(scan_unroll=12),
         batch=24, seq=1024, steps=7, expect_impl="pallas", cache=cache,

@@ -343,18 +343,7 @@ define_flag("profile_cost_accounting", True,
             "Compute cost_analysis() MFU/roofline gauges for train steps "
             "and engine ticks (pays one extra XLA compile per program).")
 
-# kernels & data-parallel collectives (PERF_NOTES.md round 6)
-define_flag("dp_allreduce_dtype", "f32",
-            "Wire dtype of the data-parallel gradient sync: 'f32' (exact) "
-            "or 'int8' (block-quantized all-reduce with error feedback).")
-define_flag("dp_shard_update", False,
-            "Shard the weight update + optimizer state across the dp axis "
-            "(reduce-scatter grads, shard-local Adam, all-gather params).")
-define_flag("dp_quant_block", 512,
-            "Block size of the int8 gradient quantizer (one f32 scale per "
-            "block of this many elements).")
-
-# serve throughput (PERF_NOTES.md round 7)
+# serve throughput
 define_flag("serve_ragged_kernel", True,
             "Dispatch paged attention through the ragged Pallas kernel on "
             "TPU backends (one launch for mixed prefill+decode batches, "

@@ -203,99 +203,6 @@ class CollectiveGroup:
         self._get_jitted(("barrier",), build)(token).block_until_ready()
 
 
-# --------------------------------------------- quantized (int8) collectives
-#
-# EQuARX-style block-quantized all-reduce (PAPERS.md, arxiv 2506.17615) for
-# the data-parallel gradient sync: the wire carries int8 values plus one f32
-# scale per `block` elements instead of full-precision tensors — a ~3.7x
-# byte reduction at block 512 — while the reduction itself runs in f32.
-# Layout convention: the operand is a (n, k) "rows" matrix where n is the
-# group size and row r is the chunk destined to member r; the all-reduce is
-#     quantize -> all_to_all (int8 wire) -> dequant+sum   (reduce-scatter)
-#     -> requantize own row -> all_gather (int8 wire) -> dequant
-# Both quantization stages return their error so callers can keep an
-# error-feedback buffer (the residual re-enters next step's gradient, which
-# is what makes deterministic-rounding int8 training converge).
-# These are IN-GRAPH primitives: call under shard_map with a manual axis.
-
-
-def quantize_int8_block(x: jax.Array, block: int = 512):
-    """Blockwise int8 quantization along the last axis. Returns (values
-    int8, scales f32 with last dim x.shape[-1]//block). Last axis must be a
-    multiple of `block`; zero blocks get scale 1 (values are all 0)."""
-    if x.shape[-1] % block:
-        raise ValueError(f"last axis {x.shape[-1]} not divisible by block {block}")
-    shaped = x.astype(jnp.float32).reshape(*x.shape[:-1], x.shape[-1] // block, block)
-    amax = jnp.max(jnp.abs(shaped), axis=-1)
-    scales = jnp.where(amax == 0.0, 1.0, amax / 127.0)
-    q = jnp.clip(jnp.round(shaped / scales[..., None]), -127, 127).astype(jnp.int8)
-    return q.reshape(x.shape), scales
-
-
-def dequantize_int8_block(values: jax.Array, scales: jax.Array) -> jax.Array:
-    block = values.shape[-1] // scales.shape[-1]
-    shaped = values.astype(jnp.float32).reshape(
-        *values.shape[:-1], scales.shape[-1], block
-    )
-    return (shaped * scales[..., None]).reshape(values.shape)
-
-
-def quantized_psum_scatter_rows(x: jax.Array, axis_name: str, *, block: int = 512):
-    """Reduce-scatter of a (n, k) rows matrix with int8 wire traffic.
-    Returns (own_row (k,) f32 — the summed row this member owns — and the
-    local quantization error (n, k) for error feedback)."""
-    q, s = quantize_int8_block(x, block)
-    err = x.astype(jnp.float32) - dequantize_int8_block(q, s)
-    qx = lax.all_to_all(q, axis_name, split_axis=0, concat_axis=0, tiled=True)
-    sx = lax.all_to_all(s, axis_name, split_axis=0, concat_axis=0, tiled=True)
-    own = jnp.sum(dequantize_int8_block(qx, sx), axis=0)
-    return own, err
-
-
-def quantized_psum_rows(x: jax.Array, axis_name: str, *, block: int = 512):
-    """Full all-reduce of a (n, k) rows matrix with int8 wire traffic.
-    Returns (reduced (n, k) f32 — bit-identical on every member — and the
-    combined local quantization error (n, k) for error feedback: stage-1
-    errors everywhere plus this member's stage-2 error on its own row)."""
-    own, err = quantized_psum_scatter_rows(x, axis_name, block=block)
-    q2, s2 = quantize_int8_block(own[None], block)
-    err2 = own - dequantize_int8_block(q2, s2)[0]
-    qg = lax.all_gather(q2[0], axis_name, axis=0, tiled=False)
-    sg = lax.all_gather(s2[0], axis_name, axis=0, tiled=False)
-    reduced = dequantize_int8_block(qg, sg)
-    my = lax.axis_index(axis_name)
-    err = err.at[my].add(err2)
-    return reduced, err
-
-
-def dp_sync_bytes(
-    n_params: int,
-    n_replicas: int,
-    *,
-    mode: str = "f32",
-    shard_update: bool = False,
-    block: int = 512,
-    param_bytes: int = 4,
-) -> int:
-    """Per-replica wire bytes one data-parallel sync moves per step (ring
-    collective accounting: each stage ships (n-1)/n of the payload). The
-    number bench.py publishes as `dp_sync_bytes`."""
-    if n_replicas <= 1:
-        return 0
-    f = (n_replicas - 1) / n_replicas
-    scales = 4 * -(-n_params // block)
-    if mode == "int8":
-        grad_stage = f * (n_params + scales)          # int8 values + f32 scales
-        gather_stage = f * (n_params + scales)
-    else:
-        grad_stage = f * n_params * param_bytes       # reduce-scatter half
-        gather_stage = f * n_params * param_bytes     # all-gather half
-    if shard_update:
-        # grads only reduce-scatter; the gather ships updated params f32
-        return int(grad_stage + f * n_params * param_bytes)
-    return int(grad_stage + gather_stage)
-
-
 # -------------------------------------------------------------- group manager
 
 

@@ -75,11 +75,7 @@ class LMTrainer:
         rules=None,
         seed: int = 0,
         loss_chunk: Optional[int] = None,
-        dp_allreduce_dtype: Optional[str] = None,
-        dp_shard_update: Optional[bool] = None,
     ):
-        from ..core.config import cfg
-        from ..parallel.collectives import dp_sync_bytes
         from ..util import tracing
 
         self.config = config
@@ -96,34 +92,13 @@ class LMTrainer:
                 self.mesh = build_mesh(
                     mesh_spec or MeshSpec().with_devices(n_dev))
             self.rules = rules or default_rules()
-            # dp sync knobs: explicit args win, cfg flags are the default
-            if dp_allreduce_dtype is None:
-                dp_allreduce_dtype = cfg.dp_allreduce_dtype
-            if dp_shard_update is None:
-                dp_shard_update = cfg.dp_shard_update
-            n_dp = self.mesh.shape.get("dp", 1)
-            explicit_dp = (
-                dp_shard_update or dp_allreduce_dtype == "int8"
-            ) and n_dp > 1
-            self.dp_sync_mode = (
-                f"{dp_allreduce_dtype}"
-                + ("+shard_update" if dp_shard_update else "")
-                if explicit_dp else "xla_psum"
-            )
             self.total_steps = total_steps
             with tracing.span("train.init.state"):
                 self.optimizer = optimizer or default_optimizer(
-                    learning_rate, total_steps=total_steps,
-                    shard_axis="dp" if (explicit_dp and dp_shard_update)
-                    else None,
-                )
+                    learning_rate, total_steps=total_steps)
                 self.state, self.state_shardings = create_train_state(
                     self.config, self.optimizer, jax.random.PRNGKey(seed),
-                    self.mesh, self.rules,
-                    dp_shard_update=explicit_dp and dp_shard_update,
-                    dp_error_feedback=explicit_dp
-                    and dp_allreduce_dtype == "int8",
-                )
+                    self.mesh, self.rules)
             with tracing.span("train.init.step_fn") as self._step_fn_span:
                 self.step_fn = make_train_step(
                     self.config,
@@ -133,15 +108,6 @@ class LMTrainer:
                     z_loss_coeff=z_loss_coeff,
                     grad_accum=grad_accum,
                     loss_chunk=loss_chunk,
-                    dp_allreduce_dtype=dp_allreduce_dtype,
-                    dp_shard_update=dp_shard_update,
-                )
-                self.dp_sync_bytes = dp_sync_bytes(
-                    count_params(self.state.params), n_dp,
-                    mode=dp_allreduce_dtype, shard_update=dp_shard_update,
-                    block=cfg.dp_quant_block,
-                ) if explicit_dp else (
-                    dp_sync_bytes(count_params(self.state.params), n_dp)
                 )
         # cost_analysis() of the compiled step (util/profiling), computed
         # once the first time a report needs it (one extra AOT compile;
@@ -429,8 +395,6 @@ class LMTrainer:
                 "step_bytes": cost.total_bytes,
                 "roofline_hbm": roof["hbm_fraction"],
                 "roofline_bound": roof["bound"],
-                "dp_sync_mode": self.dp_sync_mode,
-                "dp_sync_bytes": self.dp_sync_bytes,
             }
         except Exception:  # noqa: BLE001 - accounting must not kill training
             return {}

@@ -20,7 +20,6 @@ class ActorPool:
         self._future_to_actor = {}
         self._index_to_future = {}
         self._next_task_index = 0
-        self._next_return_index = 0
 
     def submit(self, fn: Callable[[Any, Any], Any], value: Any) -> None:
         """fn(actor, value) -> ObjectRef; blocks if no actor is idle."""
@@ -33,14 +32,16 @@ class ActorPool:
         self._next_task_index += 1
 
     def has_next(self) -> bool:
-        return self._next_return_index < self._next_task_index
+        """A submitted result has not been returned yet."""
+        return bool(self._future_to_actor)
 
     def get_next(self, timeout: float = None) -> Any:
         """Next result IN SUBMISSION ORDER."""
         if not self.has_next():
             raise StopIteration("no pending results")
-        ref = self._index_to_future.pop(self._next_return_index)
-        self._next_return_index += 1
+        # the oldest index still pending: what an unordered get has
+        # returned already is no longer here
+        ref = self._index_to_future.pop(min(self._index_to_future))
         value = api.get(ref, timeout=timeout)
         _, actor = self._future_to_actor.pop(ref)
         if actor is not None:  # None = already freed by a blocking submit
@@ -60,11 +61,7 @@ class ActorPool:
             raise GetTimeoutError(f"no result within {timeout}s")
         ref = ready[0]
         index, actor = self._future_to_actor.pop(ref)
-        self._index_to_future.pop(index, None)
-        # keep ordered bookkeeping consistent: skip this index when the
-        # ordered cursor reaches it
-        if index == self._next_return_index:
-            self._next_return_index += 1
+        del self._index_to_future[index]
         if actor is not None:
             self._idle.append(actor)
         return api.get(ref, timeout=timeout)

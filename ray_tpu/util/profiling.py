@@ -17,7 +17,7 @@ first-class cost model — so this module is three things:
    step, `device_peaks` prices them against the detected chip's peak
    FLOPs/HBM bandwidth, and `roofline` turns (cost, step time) into
    MFU + roofline fractions — the currency every TPU perf claim is
-   quoted in. bench.py and the train/serve MFU gauges all go through
+   quoted in. The train/serve MFU gauges all go through
    here instead of hand-maintained constants.
 3. The **ProfileStore**: captured artifacts registered on the driver so
    `state.list_profiles()/get_profile()`, `ray_tpu profile`, and the

@@ -95,20 +95,14 @@ class SourceFile:
 
 
 class Project:
-    """The tree under analysis: repo root, the package, and the extra
-    top-level entry points the kernel-fallback rule also covers."""
+    """The tree under analysis: repo root and the package."""
 
-    def __init__(self, root, package: str = "ray_tpu",
-                 extra_files: Sequence[str] = ("bench.py", "bench_serve.py")):
+    def __init__(self, root, package: str = "ray_tpu"):
         self.root = Path(root).resolve()
         self.package_root = self.root / package
         paths: List[Path] = []
         if self.package_root.exists():
             paths.extend(sorted(self.package_root.rglob("*.py")))
-        for name in extra_files:
-            p = self.root / name
-            if p.exists():
-                paths.append(p)
         self.files: List[SourceFile] = [SourceFile(p, self.root) for p in paths]
         self._by_rel = {sf.rel: sf for sf in self.files}
 

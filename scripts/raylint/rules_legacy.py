@@ -339,9 +339,6 @@ class LazyJaxRule(Rule):
 # ----------------------------------------------------------- kernel-fallbacks
 
 REQUIRED_FLAGS = (
-    "dp_allreduce_dtype",
-    "dp_shard_update",
-    "dp_quant_block",
     # serve throughput round (ragged kernel + SLO autoscaler)
     "serve_ragged_kernel",
     "autoscale_burn_windows",

@@ -543,8 +543,6 @@ def test_head_restart_reconciles_lost_state():
 @pytest.mark.slow
 def test_kill_head_chaos_drill():
     """Capstone: chaos SIGKILLs the head from its own snapshot loop while
-    (the same episode is bench-captured with metrics by
-    `python bench_cluster.py --drill head_outage` -> BENCH_CLUSTER_r02);
     a writer keeps committing KV state and an agent keeps heartbeating.
     After --restore on the same port: every ACKNOWLEDGED write is still
     readable (zero acknowledged-write loss), the writer saw zero errors

@@ -411,13 +411,13 @@ def test_check_kernel_fallbacks_wired():
     flags = mod.defined_flags(config_tree)
     assert set(mod.REQUIRED_FLAGS) <= flags
     reads = mod.cfg_reads(ast.parse(
-        "from .config import cfg\nx = cfg.dp_quant_block\n"
+        "from .config import cfg\nx = cfg.serve_ragged_kernel\n"
     ))
-    assert reads == [(2, "dp_quant_block")]
+    assert reads == [(2, "serve_ragged_kernel")]
 
 
 def test_fused_linear_cross_entropy_matches_dense():
-    """The chunked fused head+CE (PERF_NOTES.md) must agree with the
+    """The chunked fused head+CE must agree with the
     dense path — values AND gradients — including mask and z-loss."""
     from ray_tpu.ops.losses import fused_linear_cross_entropy
 

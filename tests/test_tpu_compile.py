@@ -60,7 +60,7 @@ def _kernel_calls(compiled) -> int:
 GPT2 = ((24, 12, 1024, 64), (24, 12, 1024, 64))       # train-gpt2s
 MISTRAL = ((12, 16, 1024, 128), (12, 4, 1024, 128))   # a train-mistral7b shard
 LLAMA = ((4, 32, 2048, 128), (4, 8, 2048, 128))       # GQA 32 -> 8, 2 x 2 tiles
-RING = ((4, 8, 2048, 128), (4, 8, 2048, 128))         # bench.py's ring block
+RING = ((4, 8, 2048, 128), (4, 8, 2048, 128))         # a ring-attention block
 
 
 @pytest.mark.parametrize(

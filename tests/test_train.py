@@ -120,6 +120,59 @@ def test_lm_trainer_with_checkpoint_resume(tmp_path, mesh8):
     np.testing.assert_allclose(np.asarray(p1), np.asarray(p2))
 
 
+def test_checkpoint_of_a_state_with_the_empty_ef_field_restores(tmp_path, mesh8):
+    """Up to PR 28 `TrainState.ef` held the int8 gradient sync's error
+    buffer, or None. A checkpoint written from such a state (the field
+    empty) restores through CheckpointManager into today's TrainState with
+    equal leaves. The field is kept for this alone: orbax records the
+    empty entry, and refuses a target that has no such field."""
+    import dataclasses
+    from typing import Any
+
+    from ray_tpu.train.checkpoint import CheckpointManager
+    from ray_tpu.train.lm import TrainState
+
+    @jax.tree_util.register_dataclass
+    @dataclasses.dataclass
+    class OldTrainState:
+        step: jax.Array
+        params: Any
+        opt_state: Any
+        rng: jax.Array
+        ef: Any = None
+
+    @jax.tree_util.register_dataclass
+    @dataclasses.dataclass
+    class StateWithoutEf:
+        step: jax.Array
+        params: Any
+        opt_state: Any
+        rng: jax.Array
+
+    config = get_config("gpt2-tiny")
+    opt = default_optimizer(1e-3, total_steps=10)
+    state, sh = create_train_state(config, opt, jax.random.PRNGKey(3), mesh8)
+    step = make_train_step(config, opt, mesh8, state_shardings=sh)
+    batch = next(_batches(jax.random.PRNGKey(0), 1, 8, 16, config.vocab_size))
+    state, _ = step(state, batch)   # step 1, moments no longer zero
+    old = OldTrainState(state.step, state.params, state.opt_state, state.rng)
+    mgr = CheckpointManager(str(tmp_path / "ckpt"), async_save=False)
+    assert mgr.save(1, old)
+    mgr.wait_until_finished()
+
+    target, _ = create_train_state(config, opt, jax.random.PRNGKey(4), mesh8)
+    restored = CheckpointManager(str(tmp_path / "ckpt")).restore(target)
+    assert isinstance(restored, TrainState) and int(restored.step) == 1
+    want, got = jax.tree.leaves(state), jax.tree.leaves(restored)
+    assert len(want) == len(got)
+    for a, b in zip(want, got):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert a.sharding == b.sharding
+    without = StateWithoutEf(target.step, target.params, target.opt_state, target.rng)
+    with pytest.raises(ValueError, match="ef"):
+        CheckpointManager(str(tmp_path / "ckpt")).restore(without)
+
+
 def test_gang_trainer_reports_and_finishes(runtime):
     def loop(config):
         from ray_tpu import train

@@ -1,6 +1,7 @@
 """ActorPool + distributed Queue (reference ray.util.actor_pool/queue)."""
 
 import threading
+import time
 
 import pytest
 
@@ -32,6 +33,39 @@ def test_actor_pool_map_unordered():
     pool = ActorPool([Doubler.remote() for _ in range(2)])
     out = sorted(pool.map_unordered(lambda a, v: a.work.remote(v), range(8)))
     assert out == [x * 2 for x in range(8)]
+
+
+@ray_tpu.remote
+class SlowOnZero:
+    def work(self, x):
+        if x == 0:
+            time.sleep(1.0)
+        return x * 2
+
+
+def test_actor_pool_map_unordered_when_the_second_submission_finishes_first():
+    """Results that finish out of submission order: map_unordered yields
+    each once and stops (has_next is false once nothing is pending), and
+    an ordered get after an unordered one skips what was returned."""
+    pool = ActorPool([SlowOnZero.remote() for _ in range(2)])
+    out = []
+    worker = threading.Thread(
+        target=lambda: out.extend(
+            pool.map_unordered(lambda a, v: a.work.remote(v), [0, 1])),
+        daemon=True)
+    worker.start()
+    worker.join(30)
+    assert not worker.is_alive(), "map_unordered did not stop after the last result"
+    assert out == [2, 0]
+    assert not pool.has_next() and pool.num_idle == 2
+
+    for v in (0, 1):
+        pool.submit(lambda a, v: a.work.remote(v), v)
+    assert pool.get_next_unordered(timeout=30) == 2     # the later submission's index...
+    pool.submit(lambda a, v: a.work.remote(v), 2)
+    assert pool.get_next(timeout=30) == 0
+    assert pool.get_next(timeout=30) == 4               # ...is skipped here
+    assert not pool.has_next()
 
 
 def test_actor_pool_submit_get_next():
