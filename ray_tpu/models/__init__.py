@@ -6,7 +6,7 @@ one pattern: pytree params + logical-axis tree + scan-stacked layers.
 the functions that build and run its model (train/lm.py reads it).
 """
 
-from typing import Callable, NamedTuple
+from typing import Callable, NamedTuple, Optional
 
 from . import moe as _moe, transformer as _dense
 
@@ -43,25 +43,30 @@ from .vit import (  # noqa: F401
 class ModelFamily(NamedTuple):
     init_params: Callable     # (config, key) -> params
     logical_axes: Callable    # (config) -> logical-axis tree of the params
-    # (params, tokens, config) -> (hidden (B, S, E) before the LM head, the
-    # routers' scalars: `router_aux_loss`, `moe_load_max_over_mean`; {} for
-    # a dense model)
+    # (params, tokens, config, remat_saved=()) -> (hidden (B, S, E) before the
+    # LM head, the routers' scalars: `router_aux_loss`,
+    # `moe_load_max_over_mean`; {} for a dense model). `remat_saved` names what
+    # a model with `config.remat` keeps of each block across the forward pass
     forward_hidden: Callable
+    # (config, S, split) -> what one block costs a token and which of its values
+    # a checkpoint may keep (transformer.block_costs); None: a family whose
+    # blocks name none, and are recomputed whole
+    block_costs: Optional[Callable]
     # (config, tokens a step) -> what the family's own layers resolve to, for
     # callers that report it (LMTrainer's `train.init.step_fn` span)
     plan: Callable
 
 
-def _dense_hidden(params, tokens, config):
-    return _dense.forward_hidden(params, tokens, config), {}
+def _dense_hidden(params, tokens, config, remat_saved=()):
+    return _dense.forward_hidden(params, tokens, config, remat_saved=remat_saved), {}
 
 
 # most derived first: a MoEConfig is a TransformerConfig
 _FAMILIES = (
     (MoEConfig, ModelFamily(_moe.init_params, _moe.logical_axes, _moe.forward_hidden,
-                            _moe.moe_plan)),
+                            None, _moe.moe_plan)),
     (TransformerConfig, ModelFamily(init_params, logical_axes, _dense_hidden,
-                                    lambda config, tokens_per_step: {})),
+                                    _dense.block_costs, lambda config, tokens_per_step: {})),
 )
 
 

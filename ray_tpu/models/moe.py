@@ -46,6 +46,7 @@ from .transformer import (
     TransformerConfig,
     _norm,
     attention_sublayer,
+    checkpoint_block,
     init_params as _dense_init,
     logical_axes as _dense_axes,
 )
@@ -414,10 +415,13 @@ def forward_hidden(
     config: MoEConfig,
     *,
     positions: Optional[jax.Array] = None,
+    remat_saved: Tuple[str, ...] = (),
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Forward up to the LM head: (B, S) → ((B, S, E), what the routers
     report: `router_aux_loss` summed over the layers and
-    `moe_load_max_over_mean` of the worst layer)."""
+    `moe_load_max_over_mean` of the worst layer). `remat_saved` as in
+    transformer.forward_hidden (this family names no candidates of its own,
+    so train/lm.py asks for the whole block)."""
     c = config
     dt = c.dtype
     _, s = tokens.shape
@@ -434,7 +438,7 @@ def forward_hidden(
         return x, (aux, load)
 
     if c.remat:
-        block_fn = jax.checkpoint(block_fn)
+        block_fn = checkpoint_block(block_fn, remat_saved)
     x, (aux_per_layer, load_per_layer) = jax.lax.scan(
         block_fn, x, params["blocks"], unroll=c.scan_unroll)
     x = _norm(x, params["lnf_scale"], params.get("lnf_bias"), c.norm, c.norm_eps)

@@ -25,10 +25,11 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import (
     apply_rope,
@@ -60,6 +61,9 @@ class TransformerConfig:
     rope_theta: float = 10000.0
     dtype: Any = jnp.bfloat16  # activation/compute dtype
     param_dtype: Any = jnp.float32
+    # recompute each block in the backward pass: all of it, or, under
+    # train/lm.make_train_step, all but the candidates of `block_costs` that
+    # the device has room for
     remat: bool = False
     attn_impl: Optional[str] = None  # None → pallas on TPU, xla elsewhere
     causal: bool = True  # False → bidirectional encoder (ViT, CLIP text off)
@@ -254,7 +258,7 @@ def attention_sublayer(
     out = jnp.einsum("bhsd,hde->bse", attn, lp["wo"].astype(dt))
     if c.use_bias:
         out = out + lp["bo"].astype(dt)
-    return x + out
+    return checkpoint_name(x + out, "attn_residual")
 
 
 def mlp_sublayer(x: jax.Array, lp: Params, config: TransformerConfig) -> jax.Array:
@@ -265,9 +269,10 @@ def mlp_sublayer(x: jax.Array, lp: Params, config: TransformerConfig) -> jax.Arr
     up = jnp.einsum("bse,ef->bsf", h, lp["w_up"].astype(dt))
     if c.use_bias:
         up = up + lp["b_up"].astype(dt)
+    up = checkpoint_name(up, "mlp_up")
     if c.act == "swiglu":
         gate = jnp.einsum("bse,ef->bsf", h, lp["w_gate"].astype(dt))
-        act = swiglu(gate, up)
+        act = swiglu(checkpoint_name(gate, "mlp_gate"), up)
     else:
         act = gelu(up)
     down = jnp.einsum("bsf,fe->bse", act, lp["w_down"].astype(dt))
@@ -288,17 +293,76 @@ def _block(
     return mlp_sublayer(x, lp, config)
 
 
+class RematCandidate(NamedTuple):
+    """A value of the block, under its `checkpoint_name`, that a checkpoint
+    policy may keep across the forward pass, and what keeping it is worth,
+    a row (a token) and device."""
+
+    name: str
+    width: int        # features held
+    flops: int        # forward FLOPs that the backward need not repeat
+    all_reduce: bool  # and a tensor-parallel all-reduce of such a row with them
+
+
+def block_costs(
+    config: TransformerConfig, seq: int, split: Callable[[str], int] = lambda weight: 1,
+) -> Dict[str, Any]:
+    """What one `_block` costs a device for a row (a token) of an S-long
+    sequence, for the rule that decides what a recomputing step keeps
+    (train/lm.py): `flops` of its forward pass; `recomputed_flops`, the part
+    a whole-block checkpoint runs again in the backward pass (all but the
+    down projection, whose output only feeds the next block's input, which
+    is kept); `width`, the features of every activation the block writes;
+    `candidates`, the values named in the sublayers that a policy may keep:
+    the MLP's up (and gate) projection, each on its own, and the residual
+    stream after the attention output projection (with it the backward needs
+    neither that matmul again nor, under tensor parallelism, the all-reduce
+    of its partial sums). The q, k, v projections and the attention output
+    are not named: beside the others they do not fit the one cell that
+    recomputes (PERF.md section 6, PR 30). `split(weight)` is the number of
+    devices that share the output features of that block parameter's matmul
+    (tensor parallelism)."""
+    c = config
+    q_width, kv_width = c.n_heads * c.head_dim // split("wq"), c.kv_heads * c.head_dim // split("wk")
+    wide = ("w_up", "w_gate") if c.act == "swiglu" else ("w_up",)
+    d_ff = c.d_ff // split("w_up")
+    matmul = 2 * c.d_model * d_ff  # each of up, (gate,) down
+    out_proj = 2 * q_width * c.d_model
+    scores = 4 * seq * q_width // (2 if c.causal else 1)
+    flops = 2 * c.d_model * (q_width + 2 * kv_width) + scores + out_proj + (len(wide) + 1) * matmul
+    return {
+        "flops": flops,
+        "recomputed_flops": flops - matmul,
+        # both norms' outputs, the attention's and the MLP's, the two residuals;
+        # q and the attention output; k, v; up, (gate,) and the activation
+        "width": 6 * c.d_model + 2 * q_width + 2 * kv_width + (len(wide) + 1) * d_ff,
+        "candidates": (
+            *(RematCandidate(w.replace("w_", "mlp_"), d_ff, matmul, False) for w in wide),
+            RematCandidate("attn_residual", c.d_model, out_proj, split("wq") > 1),
+        ),
+    }
+
+
+def checkpoint_block(block_fn, saved: Tuple[str, ...] = ()):
+    """`block_fn` recomputed in the backward pass but for the values whose
+    `checkpoint_name` is in `saved` (none: the whole block)."""
+    policy = jax.checkpoint_policies.save_only_these_names(*saved) if saved else None
+    return jax.checkpoint(block_fn, policy=policy)
+
+
 def forward_hidden(
     params: Params,
     tokens: jax.Array,
     config: TransformerConfig,
     *,
     positions: Optional[jax.Array] = None,
+    remat_saved: Tuple[str, ...] = (),
 ) -> jax.Array:
     """Forward up to (but excluding) the LM head: (B, S) → (B, S, E).
     The chunked fused-loss path (ops/losses.py
     fused_linear_cross_entropy) consumes this so the full logits tensor
-    never materializes."""
+    never materializes. Where `config.remat`, `remat_saved` names the
+    candidates of `block_costs` that are kept across the forward pass."""
     c = config
     dt = c.dtype
     _, s = tokens.shape
@@ -316,7 +380,7 @@ def forward_hidden(
         return _block(carry, lp, c, rope_tables, positions), None
 
     if c.remat:
-        block_fn = jax.checkpoint(block_fn)
+        block_fn = checkpoint_block(block_fn, remat_saved)
     x, _ = jax.lax.scan(block_fn, x, params["blocks"], unroll=c.scan_unroll)
 
     return _norm(x, params["lnf_scale"], params.get("lnf_bias"), c.norm, c.norm_eps)

@@ -108,23 +108,29 @@ def auto_loss_chunk(
     hbm_bytes None = probe the local device (memory_stats().bytes_limit);
     an unknown limit (CPU backends) means no HBM cliff to dodge -> dense."""
     if hbm_bytes is None:
-        hbm_bytes = _device_hbm_bytes()
+        hbm_bytes = device_hbm_bytes()
     if not hbm_bytes:
         return 0
     headroom = max(resident_bytes + step_bytes, _AUTO_CHUNK_HEADROOM_FRACTION * hbm_bytes)
     room = hbm_bytes - headroom
-    logits = batch_per_device * seq * vocab
-    if logits * _DENSE_LOSS_BYTES_PER_LOGIT <= room:
+    if loss_logits_bytes(batch_per_device, seq, vocab) <= room:
         return 0
     dividing = [chunk for chunk in _CHUNK_CANDIDATES if seq % chunk == 0]
     for chunk in dividing:
-        chunk_bytes = batch_per_device * chunk * vocab * _CHUNKED_LOSS_BYTES_PER_LOGIT
-        if chunk_bytes <= _CHUNK_ROOM_FRACTION * room:
+        if loss_logits_bytes(batch_per_device, seq, vocab, chunk) <= _CHUNK_ROOM_FRACTION * room:
             return chunk
     return dividing[-1] if dividing else 0
 
 
-def _device_hbm_bytes() -> int:
+def loss_logits_bytes(batch_per_device: int, seq: int, vocab: int, chunk: int = 0) -> int:
+    """The estimate of what the head's logits hold on a device: all of them
+    under the dense loss (chunk 0), one chunk's under the chunked one."""
+    if chunk:
+        return batch_per_device * chunk * vocab * _CHUNKED_LOSS_BYTES_PER_LOGIT
+    return batch_per_device * seq * vocab * _DENSE_LOSS_BYTES_PER_LOGIT
+
+
+def device_hbm_bytes() -> int:
     try:
         device = jax.local_devices()[0]
         if getattr(device, "platform", "cpu") == "cpu":

@@ -17,6 +17,7 @@ per-optimizer code.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -27,7 +28,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ..models import model_family
 from ..models.transformer import TransformerConfig, lm_head_weights
-from ..ops import cross_entropy_loss
+from ..ops import cross_entropy_loss, losses
 from ..ops.losses import auto_loss_chunk, fused_linear_cross_entropy
 from ..parallel.mesh import DATA_AXES
 from ..parallel.sharding import LogicalRules, default_rules, tree_specs
@@ -112,6 +113,39 @@ def default_optimizer(
     )
 
 
+def _state_builder(config: TransformerConfig, optimizer: optax.GradientTransformation):
+    family = model_family(config)
+
+    def build(k):
+        params = family.init_params(config, k)
+        opt_state = optimizer.init(params)
+        return TrainState(
+            step=jnp.zeros((), jnp.int32),
+            params=params,
+            opt_state=opt_state,
+            rng=jax.random.fold_in(k, 1),
+        )
+
+    return build
+
+
+def abstract_train_state(
+    config: TransformerConfig,
+    optimizer: optax.GradientTransformation,
+    mesh: Mesh,
+    rules: Optional[LogicalRules] = None,
+) -> Tuple[TrainState, Any]:
+    """(the shapes of the TrainState that `create_train_state` builds, its
+    shardings), with nothing allocated: enough to lower a step, or to ask
+    one what it would trace."""
+    param_specs = tree_specs(model_family(config).logical_axes(config), rules or default_rules())
+    abstract = jax.eval_shape(_state_builder(config, optimizer), jax.random.PRNGKey(0))
+    spec_tree = infer_state_specs(abstract, param_specs)
+    # the params subtree must carry the full rule-derived specs
+    spec_tree = dataclasses.replace(spec_tree, params=param_specs)
+    return abstract, _sharding_tree(spec_tree, mesh)
+
+
 def create_train_state(
     config: TransformerConfig,
     optimizer: optax.GradientTransformation,
@@ -124,27 +158,76 @@ def create_train_state(
     shard — an 8B model initializes without ever forming a host copy.
 
     Returns (state, state_shardings)."""
-    rules = rules or default_rules()
-    family = model_family(config)
-    param_specs = tree_specs(family.logical_axes(config), rules)
-
-    def build(k):
-        params = family.init_params(config, k)
-        opt_state = optimizer.init(params)
-        return TrainState(
-            step=jnp.zeros((), jnp.int32),
-            params=params,
-            opt_state=opt_state,
-            rng=jax.random.fold_in(k, 1),
-        )
-
-    abstract = jax.eval_shape(build, key)
-    spec_tree = infer_state_specs(abstract, param_specs)
-    # the params subtree must carry the full rule-derived specs
-    spec_tree = dataclasses.replace(spec_tree, params=param_specs)
-    shardings = _sharding_tree(spec_tree, mesh)
-    state = jax.jit(build, out_shardings=shardings)(key)
+    _, shardings = abstract_train_state(config, optimizer, mesh, rules)
+    state = jax.jit(_state_builder(config, optimizer), out_shardings=shardings)(key)
     return state, shardings
+
+
+# ------------------------------------------------ what a remat step keeps
+# Constants of `auto_remat_saved` and of the estimate `make_train_step` gives
+# it, set from runs of Mistral-7B's widths on four v5e chips, fsdp=2 x tp=2,
+# 8 layers, 12 x 1,024 rows a device (PERF.md section 6, PR 30):
+# the share of the device that whatever is kept leaves free. The estimate read
+# 2% over the whole-block step's peak (11.38 GB against 11.145) and 2.4% over
+# with everything kept (15.00 against 14.66, 86.7% of the chip).
+_REMAT_FREE_FRACTION = 0.10
+# the backward pass of one block, in copies of the activations it writes:
+# the peak less state, gradients and the scan's carries was 2.29 GB, the
+# block's activations 1.26 GB (and chunking the head did not lower it, PR 29)
+_REMAT_BLOCK_COPIES = 2.0
+# FLOPs that take as long as moving one byte through a tensor-parallel
+# all-reduce, which is how a spared all-reduce counts beside spared matmuls:
+# keeping the residual took 23.2 ms off the step beside gate and up (32.0 on
+# its own), 8.8 of them the output projection's: 14.4 ms for 805 MB
+_ALL_REDUCE_FLOPS_PER_BYTE = 3600
+
+
+def auto_remat_saved(
+    candidates: Tuple[Any, ...],
+    *,
+    rows: int,
+    layers: int,
+    itemsize: int,
+    whole_block_bytes: float,
+    hbm_bytes: Optional[int] = None,
+) -> Tuple[Tuple[Any, ...], int]:
+    """Which of a block's `candidates` (models/transformer.RematCandidate)
+    a step that recomputes its blocks keeps across the forward pass, and the
+    bytes a device holds for them (their shapes': on the chip a kept value
+    cost 0.96 to 1.00 of that): one by one in order of the recomputation
+    spared per byte (a spared all-reduce counts as the FLOPs of its time),
+    each one that still fits beside `whole_block_bytes`, the estimate of the
+    step that keeps nothing, with `_REMAT_FREE_FRACTION` of the device left
+    free. `rows` are a device's tokens a step. Nothing live is probed but the
+    device's size, so the same model, mesh and batch always get the same
+    program; an unknown size (CPU) keeps nothing: the whole-block step is the
+    one that fits wherever anything does."""
+    if hbm_bytes is None:
+        hbm_bytes = losses.device_hbm_bytes()
+    if not hbm_bytes:
+        return (), 0
+    room = (1 - _REMAT_FREE_FRACTION) * hbm_bytes - whole_block_bytes
+
+    def spared_per_byte(c) -> float:
+        return c.flops / (c.width * itemsize) + c.all_reduce * _ALL_REDUCE_FLOPS_PER_BYTE
+
+    kept, kept_bytes = [], 0
+    for c in sorted(candidates, key=spared_per_byte, reverse=True):
+        c_bytes = layers * rows * c.width * itemsize
+        if kept_bytes + c_bytes <= room:
+            kept.append(c)
+            kept_bytes += c_bytes
+    return tuple(kept), kept_bytes
+
+
+def _model_split(sharding: NamedSharding, dims: slice) -> int:
+    """The number of devices that share these dimensions of a parameter over
+    the mesh's model axes, which is how the features its matmul writes are
+    split. (Over a data axis a parameter is only stored, ZeRO-style: its
+    matmul runs on the gathered whole.)"""
+    axes = [a for entry in tuple(sharding.spec)[dims] if entry
+            for a in ((entry,) if isinstance(entry, str) else entry)]
+    return math.prod(sharding.mesh.shape[a] for a in axes if a not in DATA_AXES)
 
 
 # what every model's step reports; a family's own scalars come beside them
@@ -153,17 +236,19 @@ CORE_STEP_METRICS = ("loss", "grad_norm", "num_tokens")
 
 def lm_loss(
     params: Any, tokens: jax.Array, config: TransformerConfig, *,
-    chunk: int = 0, z_loss_coeff: float = 0.0,
+    chunk: int = 0, z_loss_coeff: float = 0.0, remat_saved: Tuple[str, ...] = (),
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """The training objective on a (B, S + 1) batch, for every model
     family: next-token cross entropy (the head chunked by `chunk` rows of
     the sequence, 0 = dense) plus, for a MoE model, `router_aux_coeff`
-    times the routers' load-balancing loss. Returns (objective, the
+    times the routers' load-balancing loss. `remat_saved`: what a model
+    with `config.remat` keeps of each block. Returns (objective, the
     step's scalars: `loss` = the cross entropy alone, so that a dense and
     a sparse model's losses mean the same, `num_tokens`, and the
     routers')."""
     targets = tokens[:, 1:]
-    hidden, routers = model_family(config).forward_hidden(params, tokens[:, :-1], config)
+    hidden, routers = model_family(config).forward_hidden(
+        params, tokens[:, :-1], config, remat_saved=remat_saved)
     head = lm_head_weights(params, config)
     if chunk:
         loss, ntok = fused_linear_cross_entropy(
@@ -204,7 +289,16 @@ def make_train_step(
     # batch rows per device, for the loss-chunk heuristic
     data_shards = mesh.shape.get("dp", 1) * mesh.shape.get("fsdp", 1)
 
+    family = model_family(config)
     chunks: Dict[Tuple[int, ...], int] = {}
+    remat_plans: Dict[Tuple[int, ...], Dict[str, Any]] = {}
+
+    def device_bytes(tree, shardings) -> int:
+        return sum(math.prod(sh.shard_shape(x.shape)) * x.dtype.itemsize
+                   for x, sh in zip(jax.tree.leaves(tree), jax.tree.leaves(shardings)))
+
+    def device_batch(tokens_shape) -> int:
+        return max(tokens_shape[0] // grad_accum // max(data_shards, 1), 1)
 
     def loss_chunk_for(tokens_shape, state: TrainState) -> int:
         """The head's form for a (B, S + 1) batch of the whole step: decided
@@ -216,28 +310,64 @@ def make_train_step(
             return loss_chunk
         shape = tuple(tokens_shape)
         if shape not in chunks:
-            def device_bytes(tree, shardings):
-                return sum(math.prod(sh.shard_shape(x.shape)) * x.dtype.itemsize
-                           for x, sh in zip(jax.tree.leaves(tree), jax.tree.leaves(shardings)))
-
             chunks[shape] = auto_loss_chunk(
-                max(shape[0] // grad_accum // max(data_shards, 1), 1), shape[1] - 1,
-                config.vocab_size,
+                device_batch(shape), shape[1] - 1, config.vocab_size,
                 resident_bytes=device_bytes(state, state_shardings),
                 step_bytes=device_bytes(state.params, state_shardings.params),
             )
         return chunks[shape]
 
-    def loss_fn(params, tokens, chunk):
-        return lm_loss(params, tokens, config, chunk=chunk, z_loss_coeff=z_loss_coeff)
+    def remat_plan_for(tokens_shape, state: TrainState) -> Dict[str, Any]:
+        """What the step's blocks keep across the forward pass for a
+        (B, S + 1) batch, decided once a shape and kept, as the head's form
+        is: `remat` (`off`: nothing is recomputed; `whole_block`; `selective`),
+        `remat_saved` (the `checkpoint_name`s kept), `remat_saved_bytes` (a
+        device) and `remat_recomputed_flops_share` (of a block's forward pass,
+        run again in the backward; None for a family that names no
+        candidates). The estimate of the whole-block step counts, a device:
+        its share of the state and of the gradients (from `state`'s shapes
+        and the shardings), the blocks' inputs that the scan carries, and the
+        larger of the head's logits and one block's backward pass."""
+        shape = tuple(tokens_shape)
+        if shape in remat_plans:
+            return remat_plans[shape]
+        plan = remat_plans[shape] = {
+            "remat": "whole_block" if config.remat else "off", "remat_saved": (),
+            "remat_saved_bytes": 0, "remat_recomputed_flops_share": None if config.remat else 0.0}
+        if not config.remat or family.block_costs is None:
+            return plan
+        seq, chunk = shape[1] - 1, loss_chunk_for(shape, state)
+        rows = device_batch(shape) * seq
+        itemsize = jnp.dtype(config.dtype).itemsize
+        blocks = state_shardings.params["blocks"]
+        # a block parameter is (layers, input, *output)
+        costs = family.block_costs(
+            config, seq, lambda weight: _model_split(blocks[weight], slice(2, None)))
+        head = state_shardings.params.get("lm_head")
+        vocab = config.vocab_size // (
+            _model_split(state_shardings.params["wte"], slice(0, 1)) if head is None
+            else _model_split(head, slice(1, None)))
+        logits = losses.loss_logits_bytes(device_batch(shape), seq, vocab, chunk)
+        gradients = device_bytes(state.params, state_shardings.params)
+        kept, kept_bytes = auto_remat_saved(
+            costs["candidates"], rows=rows, layers=config.n_layers, itemsize=itemsize,
+            whole_block_bytes=(
+                device_bytes(state, state_shardings)
+                + gradients * (2 if grad_accum > 1 else 1)   # and their accumulator
+                + config.n_layers * rows * config.d_model * itemsize
+                + max(logits, _REMAT_BLOCK_COPIES * rows * costs["width"] * itemsize)))
+        plan.update(
+            remat="selective" if kept else "whole_block",
+            remat_saved=tuple(c.name for c in kept), remat_saved_bytes=kept_bytes,
+            remat_recomputed_flops_share=(
+                costs["recomputed_flops"] - sum(c.flops for c in kept)) / costs["flops"])
+        return plan
 
-    def microbatch_grads(params, tokens, chunk):
+    def microbatch_grads(loss_fn, params, tokens):
         """(the step's scalars: `loss`, `num_tokens` and the routers', grads
-        of the objective)."""
+        of the objective `loss_fn(params, tokens)`)."""
         if grad_accum == 1:
-            (_, scalars), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-                params, tokens, chunk
-            )
+            (_, scalars), grads = jax.value_and_grad(loss_fn, has_aux=True)(params, tokens)
             return scalars, grads
 
         mb_tokens = tokens.reshape(
@@ -246,11 +376,11 @@ def make_train_step(
 
         def body(carry, mb):
             acc_scalars, acc_grads = carry
-            (_, scalars), grads = jax.value_and_grad(loss_fn, has_aux=True)(params, mb, chunk)
+            (_, scalars), grads = jax.value_and_grad(loss_fn, has_aux=True)(params, mb)
             return (jax.tree.map(jnp.add, acc_scalars, scalars),
                     jax.tree.map(jnp.add, acc_grads, grads)), None
 
-        zero = jax.eval_shape(lambda: loss_fn(params, mb_tokens[0], chunk)[1])
+        zero = jax.eval_shape(lambda: loss_fn(params, mb_tokens[0])[1])
         zero_scalars = jax.tree.map(lambda x: jnp.zeros(x.shape, x.dtype), zero)
         zero_grads = jax.tree.map(jnp.zeros_like, params)
         (scalars, grads), _ = jax.lax.scan(
@@ -265,9 +395,13 @@ def make_train_step(
     # traces (`ray_tpu profile`) line up with the step-phase waterfall
     def step_fn(state: TrainState, batch: Dict[str, jax.Array]):
         tokens = batch["tokens"]
-        chunk = loss_chunk_for(tokens.shape, state)
+        # the head's form and what the blocks keep, decided for this shape
+        loss_fn = functools.partial(
+            lm_loss, config=config, z_loss_coeff=z_loss_coeff,
+            chunk=loss_chunk_for(tokens.shape, state),
+            remat_saved=remat_plan_for(tokens.shape, state)["remat_saved"])
         with jax.named_scope("steplog.fwd_bwd_compute"):
-            scalars, grads = microbatch_grads(state.params, tokens, chunk)
+            scalars, grads = microbatch_grads(loss_fn, state.params, tokens)
         with jax.named_scope("steplog.optimizer_update"):
             updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
             new_params = optax.apply_updates(state.params, updates)
@@ -296,6 +430,7 @@ def make_train_step(
         donate_argnums=(0,),
     )
     step.loss_chunk_for = loss_chunk_for
+    step.remat_plan_for = remat_plan_for
     return step
 
 

@@ -133,7 +133,8 @@ class LMTrainer:
 
     def _note_step_plan(self, tokens_shape: tuple) -> None:
         """Attributes of `train.init.step_fn`: what the step's attention,
-        its expert layer (a MoE model's) and its head resolve to for a
+        its expert layer (a MoE model's), its head and what its blocks keep
+        across the forward pass (`remat*`) resolve to for a
         (B, S + 1) batch (the jitted step is traced per shape, so only the
         first batch says)."""
         from ..models import model_family
@@ -146,6 +147,7 @@ class LMTrainer:
         with jax.sharding.use_abstract_mesh(self.mesh.abstract_mesh):  # as the step is traced
             plan.update(model_family(self.config).plan(self.config, batch * seq))
         plan["loss_chunk"] = self.step_fn.loss_chunk_for(tokens_shape, self.state)
+        plan.update(self.step_fn.remat_plan_for(tokens_shape, self.state))
         for key, value in plan.items():
             self._step_fn_span.set_attribute(key, value)
 

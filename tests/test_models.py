@@ -167,3 +167,80 @@ def test_fused_qkv_and_unroll_match_baseline():
     unrolled = forward(params, tokens, config.replace(scan_unroll=4))
     np.testing.assert_allclose(np.asarray(base), np.asarray(fused), atol=1e-5, rtol=1e-5)
     np.testing.assert_allclose(np.asarray(base), np.asarray(unrolled), atol=1e-6)
+
+
+# ------------------------------------------------- what a recomputing block keeps
+
+REMAT_NAMES = ("mlp_up", "mlp_gate", "attn_residual")
+
+
+def _remat_loss_and_grads(preset, remat, saved=()):
+    from ray_tpu.train.lm import lm_loss
+
+    config = get_config(preset).replace(remat=remat)
+    params = init_params(config, jax.random.PRNGKey(0))
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 17), 0, config.vocab_size)
+    loss_fn = jax.jit(jax.value_and_grad(
+        lambda p: lm_loss(p, tokens, config, remat_saved=saved)[0]))
+    return loss_fn(params)
+
+
+def _kept_sets(names):
+    return [tuple(n for i, n in enumerate(names) if mask >> i & 1)
+            for mask in range(1, 2 ** len(names))]
+
+
+@pytest.mark.parametrize("preset,saved", [
+    *(("llama-tiny", kept) for kept in [None, *_kept_sets(REMAT_NAMES)]),
+    # a GELU block has no gate: what the rule can choose there
+    *(("gpt2-tiny", kept) for kept in [None, *_kept_sets(("mlp_up", "attn_residual"))]),
+], ids=lambda v: v if isinstance(v, str) else "+".join(v) if v else "whole_block")
+def test_a_recomputing_block_gives_the_loss_and_gradients_of_one_that_keeps_everything(
+        preset, saved):
+    """`remat` off, the whole block recomputed, and every set of names a
+    policy can keep: the kept values are the ones the forward computed, so
+    the loss and every gradient leaf are those of the step that keeps all."""
+    want_loss, want_grads = _remat_loss_and_grads(preset, False)
+    loss, grads = _remat_loss_and_grads(preset, True, saved or ())
+    assert float(loss) == float(want_loss)
+    for (path, want), got in zip(jax.tree_util.tree_flatten_with_path(want_grads)[0],
+                                 jax.tree.leaves(grads)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6, atol=1e-7,
+                                   err_msg=jax.tree_util.keystr(path))
+
+
+def _dots_as_wide_as(jaxpr, width) -> int:
+    """`dot_general`s that write a (B, S, width) activation, in a jaxpr and
+    every jaxpr inside it (scan bodies, checkpoints, custom rules)."""
+    count = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            shape = eqn.outvars[0].aval.shape
+            count += len(shape) == 3 and shape[-1] == width
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            count += _dots_as_wide_as(sub, width)
+    return count
+
+
+def test_gate_and_up_are_not_multiplied_again_in_the_backward_pass_when_kept():
+    """Matmuls that write a d_ff-wide value, in the scanned block of a step
+    and its gradient: the gate and up projections, and in the backward the
+    gradient into the activation. A whole-block checkpoint runs gate and up
+    once more; the policy that keeps both runs neither, nor one of a pair."""
+    from ray_tpu.train.lm import lm_loss
+
+    config = get_config("llama-tiny").replace(remat=True)
+    params = init_params(config, jax.random.PRNGKey(0))
+    tokens = jnp.zeros((2, 17), jnp.int32)
+
+    def wide_dots(config, saved=()):
+        grad = jax.grad(lambda p: lm_loss(p, tokens, config, remat_saved=saved)[0])
+        return _dots_as_wide_as(jax.make_jaxpr(grad)(params).jaxpr, config.d_ff)
+
+    kept_all = wide_dots(config.replace(remat=False))
+    assert kept_all == 3
+    assert wide_dots(config) == kept_all + 2
+    assert wide_dots(config, ("mlp_up",)) == kept_all + 1
+    assert wide_dots(config, ("mlp_up", "mlp_gate")) == kept_all
+    # the residual spares the output projection, not these
+    assert wide_dots(config, ("attn_residual",)) == kept_all + 2

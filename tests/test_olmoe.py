@@ -339,7 +339,7 @@ def test_loss_chunk_for_counts_a_devices_share_of_the_state_from_the_shardings(m
         whole = sum(x.nbytes for x in jax.tree.leaves((state, state.params)))
         rows = shape[0] // (spec.dp * spec.fsdp)
         logits = rows * 128 * config.vocab_size * 10
-        monkeypatch.setattr(losses, "_device_hbm_bytes",
+        monkeypatch.setattr(losses, "device_hbm_bytes",
                             lambda: logits + int(room_for_state * whole))
         return step.loss_chunk_for(shape, state)
 

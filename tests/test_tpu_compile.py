@@ -212,3 +212,69 @@ def test_moe_layer_under_a_mesh_runs_the_kernels_per_shard(v5e, as_tpu):
     assert _kernel_calls(compiled) == 9
     # a chip holds its quarter of the three expert stacks (float32), not the whole
     assert compiled.memory_analysis().argument_size_in_bytes < 2 * (3 * e * m * f * 4) // 4
+
+
+@pytest.fixture(scope="module")
+def mistral_cell_step(v5e):
+    """What `train-mistral7b-fsdp2tp2` runs, as shapes on the described 2x2:
+    the state as `create_train_state` builds it, 24 x 1,025 tokens over the
+    data axes."""
+    from benchmark import model_config
+    from ray_tpu.train.lm import abstract_train_state, default_optimizer
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    config = model_config.transformer_config(model_config.load_config(
+        os.path.join(root, "benchmark/configs/mistral-7b-v0.3-train-4chip.json")))
+    mesh = build_mesh(MeshSpec(fsdp=2, tp=2), devices=v5e.devices)
+    opt = default_optimizer(3e-4, total_steps=1000)
+    state, shardings = abstract_train_state(config, opt, mesh)
+    state = jax.tree.map(
+        lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh), state, shardings)
+    tokens = jax.ShapeDtypeStruct(
+        (24, 1025), jnp.int32, sharding=NamedSharding(mesh, PartitionSpec(("dp", "fsdp"), None)))
+    return config, opt, mesh, shardings, state, tokens
+
+
+GIB = 2 ** 30
+WHOLE_BLOCK_TEMP_GIB, WHOLE_BLOCK_TFLOP = 6.84, 14.98   # this step since PR 24's rehearsal
+
+
+@pytest.mark.parametrize("hbm_gib,want", [
+    (0, ("whole_block", ())),
+    (15.75, ("selective", ("attn_residual", "mlp_up", "mlp_gate"))),
+], ids=["unknown-device-size", "v5e-15.75GiB"])
+def test_mistral_cell_step_keeps_what_fits_and_compiles(
+        as_tpu, monkeypatch, mistral_cell_step, hbm_gib, want):
+    """The cell's whole step for the described v5e:2x2, dense head. A device
+    of unknown size gets the whole-block program (5.63 GiB of arguments and
+    6.84 of temporaries a chip, 14.98 TFLOP with the scanned block counted
+    once, 21 all-reduces). At the chip's 15.75 GiB the rule keeps gate, up
+    and the residual after the output projection: two matmuls of 0.72 TFLOP
+    and the output projection's 0.2 less in the scanned block, one
+    all-reduce less in the text, and no more than 4.2 GiB of temporaries
+    over the whole-block program's (3.95 by this compiler's count, which
+    read 2.1 GiB over the chip's peak for the whole-block step and 25% over
+    the kept values' own bytes: PERF.md section 6, PR 30)."""
+    from ray_tpu.ops import losses
+    from ray_tpu.train.lm import make_train_step
+
+    config, opt, mesh, shardings, state, tokens = mistral_cell_step
+    monkeypatch.setattr(losses, "device_hbm_bytes", lambda: int(hbm_gib * GIB))
+    step = make_train_step(config, opt, mesh, state_shardings=shardings, loss_chunk=0)
+    plan = step.remat_plan_for(tokens.shape, state)
+    assert (plan["remat"], plan["remat_saved"]) == want
+    compiled = step.lower(state, {"tokens": tokens}).compile()
+    memory, tflop = compiled.memory_analysis(), compiled.cost_analysis()["flops"] / 1e12
+    all_reduces = compiled.as_text().count(" all-reduce(")
+    assert _kernel_calls(compiled) == 4   # flash_fwd twice (the forward, its recomputation), dkv, dq
+    assert memory.argument_size_in_bytes / GIB == pytest.approx(5.63, abs=0.02)
+    temp_gib = memory.temp_size_in_bytes / GIB
+    if not want[1]:
+        assert temp_gib == pytest.approx(WHOLE_BLOCK_TEMP_GIB, abs=0.15)
+        assert tflop == pytest.approx(WHOLE_BLOCK_TFLOP, abs=0.05)
+        assert all_reduces == 21
+    else:
+        assert WHOLE_BLOCK_TEMP_GIB + 3.0 < temp_gib <= WHOLE_BLOCK_TEMP_GIB + 4.2
+        assert plan["remat_saved_bytes"] / GIB == pytest.approx(3.375, abs=0.001)
+        assert tflop <= WHOLE_BLOCK_TFLOP - 1.6
+        assert all_reduces == 20

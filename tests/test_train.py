@@ -275,3 +275,141 @@ def test_elastic_gang_resizes_on_capacity(runtime):
     assert controller.world_sizes[-1] == 4  # grew back after restart
     assert result.checkpoint_step == 10
     assert result.num_restarts >= 1
+
+
+# ------------------------------------------- what a recomputing step keeps
+
+V5E_HBM = int(15.75 * 2 ** 30)
+
+
+def _cell_step(monkeypatch, cell_config, mesh_spec, hbm_bytes, **changed):
+    """(the step of a cell's configuration file, the shapes of its state):
+    nothing is allocated, so the published widths cost nothing here."""
+    import os
+
+    from benchmark import model_config
+    from ray_tpu.ops import losses
+    from ray_tpu.train.lm import abstract_train_state
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    config = model_config.transformer_config(model_config.load_config(
+        os.path.join(root, "benchmark", "configs", cell_config + ".json"))).replace(**changed)
+    monkeypatch.setattr(losses, "device_hbm_bytes", lambda: hbm_bytes)
+    mesh = build_mesh(mesh_spec, devices=jax.devices()[:mesh_spec.num_devices])
+    opt = default_optimizer(3e-4, total_steps=1000)
+    state, shardings = abstract_train_state(config, opt, mesh)
+    return make_train_step(config, opt, mesh, state_shardings=shardings), state
+
+
+MISTRAL = ("mistral-7b-v0.3-train-4chip", MeshSpec(fsdp=2, tp=2))
+
+
+EVERY_NAME = ("attn_residual", "mlp_up", "mlp_gate")
+
+
+@pytest.mark.parametrize("cell,mesh_spec,hbm,batch,seq,changed,want", [
+    (*MISTRAL, V5E_HBM, 24, 1024, {}, ("selective", EVERY_NAME)),
+    # nothing as wide as gate or up fits beside two more layers of state
+    (*MISTRAL, V5E_HBM, 24, 1024, {"n_layers": 10}, ("selective", ("attn_residual",))),
+    # no room at all: today's program, not an out-of-memory error
+    (*MISTRAL, V5E_HBM, 24, 1024, {"n_layers": 12}, ("whole_block", ())),
+    (*MISTRAL, V5E_HBM, 48, 1024, {}, ("whole_block", ())),
+    # a device of unknown size (the CPU): the step that fits wherever anything does
+    (*MISTRAL, 0, 24, 1024, {}, ("whole_block", ())),
+    ("gpt2-small-train-1chip", MeshSpec(), V5E_HBM, 24, 1024, {}, ("off", ())),
+    ("olmoe-1b-7b-train-1chip", MeshSpec(), V5E_HBM, 4, 4096, {}, ("off", ())),
+    # a family that names no candidates is recomputed whole wherever it recomputes
+    ("olmoe-1b-7b-train-1chip", MeshSpec(), 4 * V5E_HBM, 4, 4096, {"remat": True},
+     ("whole_block", ())),
+], ids=["mistral-2x2", "mistral-2x2-10-layers", "mistral-2x2-12-layers", "mistral-2x2-batch-48",
+        "mistral-2x2-unknown-size", "gpt2s", "olmoe", "olmoe-with-remat"])
+def test_remat_rule_on_the_cells_numbers(monkeypatch, cell, mesh_spec, hbm, batch, seq, changed, want):
+    """`make_train_step(...).remat_plan_for(shape, state)` at the shipped
+    cells' widths, meshes and batches, on a v5e's 15.75 GiB: the one cell
+    that recomputes keeps every named value and leaves the stated share of
+    the chip free; with ten layers only the narrow one fits, with twelve or
+    twice the batch nothing does and the whole block is recomputed, as on a
+    device whose size is unknown; the cells without `remat` recompute
+    nothing. The same numbers, the same plan: nothing live is read but the
+    device's size."""
+    from ray_tpu.train import lm
+
+    step, state = _cell_step(monkeypatch, cell, mesh_spec, hbm, **changed)
+    plan = step.remat_plan_for((batch, seq + 1), state)
+    assert (plan["remat"], plan["remat_saved"]) == want
+    assert step.remat_plan_for((batch, seq + 1), state) is plan
+    if want[0] == "off":
+        assert plan["remat_recomputed_flops_share"] == 0.0 and plan["remat_saved_bytes"] == 0
+    if cell == MISTRAL[0] and want[0] == "whole_block":
+        # all of the block but the down projection: 319 + 8 of 436 + 8 MFLOP a token
+        assert plan["remat_recomputed_flops_share"] == pytest.approx(0.736, abs=0.001)
+    if cell != MISTRAL[0] and want[0] == "whole_block":
+        assert plan["remat_recomputed_flops_share"] is None
+    if want == ("selective", EVERY_NAME):
+        # 12,288 rows a device x (its half of gate and of up + the residual) x 2 B x 8 layers
+        assert plan["remat_saved_bytes"] == 12288 * (2 * 7168 + 4096) * 2 * 8
+        # what is left to recompute: q, k, v and the attention kernel
+        assert plan["remat_recomputed_flops_share"] == pytest.approx(0.132, abs=0.001)
+        # beside the whole-block step as the chip measured it (11.145 GB; the
+        # estimate reads a little over), the stated share stays free
+        assert 11.145e9 + plan["remat_saved_bytes"] < (1 - lm._REMAT_FREE_FRACTION) * hbm
+
+
+def test_remat_rule_keeps_in_order_of_recomputation_spared_a_byte():
+    """The candidates one by one, not as a pair: with room for one d_ff-wide
+    value, one is kept and the residual beside it; a spared all-reduce
+    counts, so under tensor parallelism the residual can come first."""
+    from ray_tpu.models.transformer import RematCandidate
+    from ray_tpu.train.lm import _ALL_REDUCE_FLOPS_PER_BYTE, auto_remat_saved
+
+    def kept(candidates, room_rows):
+        size = dict(rows=100, layers=2, itemsize=2)
+        got, held = auto_remat_saved(candidates, **size, whole_block_bytes=0,
+                                     hbm_bytes=int(room_rows * 100 * 2 * 2 / 0.9) + 1)
+        assert held == sum(100 * 2 * 2 * c.width for c in got)
+        return tuple(c.name for c in got)
+
+    up, gate = RematCandidate("mlp_up", 64, 8192, False), RematCandidate("mlp_gate", 64, 8192, False)
+    residual = RematCandidate("attn_residual", 16, 512, False)
+    assert kept((up, gate, residual), 64 + 64 + 16) == ("mlp_up", "mlp_gate", "attn_residual")
+    assert kept((up, gate, residual), 64 + 16) == ("mlp_up", "attn_residual")
+    assert kept((up, gate, residual), 63) == ("attn_residual",)
+    assert kept((up, gate, residual), 15) == ()
+    assert auto_remat_saved((up, gate), rows=100, layers=2, itemsize=2, whole_block_bytes=0,
+                            hbm_bytes=0) == ((), 0)
+    # 64 FLOPs a byte against 16 and an all-reduce
+    exposed = residual._replace(all_reduce=True)
+    assert _ALL_REDUCE_FLOPS_PER_BYTE > 48
+    assert kept((up, gate, exposed), 64 + 16) == ("attn_residual", "mlp_up")
+
+
+def test_selective_step_on_a_mesh_gives_one_devices_loss(monkeypatch):
+    """fsdp=2 x tp=2 on virtual devices, the rule given room for every
+    candidate: the step that keeps them reports what one device's
+    whole-block step reports, and its plan counts a device's share (half
+    the rows, half of gate and up, the residual whole)."""
+    from ray_tpu.ops import losses
+
+    config = get_config("llama-tiny").replace(remat=True)
+    opt = default_optimizer(1e-3, total_steps=10)
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (8, 17), 0, config.vocab_size)}
+
+    def first_step(spec, hbm_bytes):
+        monkeypatch.setattr(losses, "device_hbm_bytes", lambda: hbm_bytes)
+        mesh = build_mesh(spec, devices=jax.devices()[:spec.num_devices])
+        state, shardings = create_train_state(config, opt, jax.random.PRNGKey(0), mesh)
+        step = make_train_step(config, opt, mesh, state_shardings=shardings)
+        plan = step.remat_plan_for(batch["tokens"].shape, state)
+        _, metrics = step(state, batch)
+        return plan, {k: float(v) for k, v in metrics.items()}
+
+    whole_plan, whole = first_step(MeshSpec(), 0)
+    plan, sharded = first_step(MeshSpec(fsdp=2, tp=2), 10 ** 9)
+    assert whole_plan["remat"] == "whole_block"
+    assert plan["remat"] == "selective" and set(plan["remat_saved"]) == {
+        "mlp_up", "mlp_gate", "attn_residual"}
+    rows, layers, itemsize = 4 * 16, config.n_layers, jnp.dtype(config.dtype).itemsize
+    assert plan["remat_saved_bytes"] == layers * rows * itemsize * (
+        2 * config.d_ff // 2 + config.d_model)
+    assert sharded["loss"] == pytest.approx(whole["loss"], rel=2e-3)
+    assert sharded["grad_norm"] == pytest.approx(whole["grad_norm"], rel=2e-2)

@@ -78,7 +78,10 @@ def test_step_fn_span_names_the_attention_the_step_runs(trainer, monkeypatch):
     attrs = trainer._step_fn_span.to_dict()["attrs"]
     assert attrs == {"attention_impl": "xla", "attn_subtiles_visited": 0,
                      "attn_subtiles_masked": 0, "attn_subtiles_total": 0,
-                     "loss_chunk": 0}   # 0: the dense head; no MoE key on a dense model
+                     "loss_chunk": 0,   # 0: the dense head; no MoE key on a dense model
+                     # gpt2-tiny keeps every activation: nothing is recomputed
+                     "remat": "off", "remat_saved": (), "remat_saved_bytes": 0,
+                     "remat_recomputed_flops_share": 0.0}
     # on the chip, at the cells' sequence length
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     trainer._note_step_plan((2, 1025))
@@ -87,6 +90,35 @@ def test_step_fn_span_names_the_attention_the_step_runs(trainer, monkeypatch):
     assert (attrs["attn_subtiles_visited"], attrs["attn_subtiles_masked"],
             attrs["attn_subtiles_total"]) == (10, 4, 16)
     trainer._note_step_plan((2, 17))
+
+
+@pytest.mark.parametrize("hbm_bytes,want", [
+    (0, ("whole_block", ())),                       # the CPU: a device of unknown size
+    (10 ** 9, ("selective", ("attn_residual", "mlp_up", "mlp_gate"))),
+], ids=["unknown-device-size", "room-for-every-name"])
+def test_step_fn_span_names_what_a_recomputing_step_keeps(monkeypatch, hbm_bytes, want):
+    """`train.init.step_fn` of a model with `remat`: the four `remat*` keys,
+    and what they report is what the step's trace gave the blocks'
+    checkpoint."""
+    from ray_tpu.models import transformer
+    from ray_tpu.ops import losses
+
+    traced = []
+    checkpoint_block = transformer.checkpoint_block
+    monkeypatch.setattr(transformer, "checkpoint_block", lambda block_fn, saved=(): (
+        traced.append(tuple(saved)), checkpoint_block(block_fn, saved))[1])
+    monkeypatch.setattr(losses, "device_hbm_bytes", lambda: hbm_bytes)
+    config = get_config("llama-tiny").replace(remat=True)
+    trainer = LMTrainer(config, mesh_spec=MeshSpec(dp=2, fsdp=2, tp=2), total_steps=10)
+    trainer.train(_batches(0, 1, config.vocab_size, batch=16), num_steps=1, report_every=1)
+    attrs = trainer._step_fn_span.to_dict()["attrs"]
+    assert (attrs["remat"], attrs["remat_saved"]) == want
+    assert traced and set(traced) == {want[1]}
+    assert attrs == {**attrs, **trainer.step_fn.remat_plan_for((16, 17), trainer.state)}
+    rows, itemsize = 4 * 16, jnp.dtype(config.dtype).itemsize
+    kept_width = (2 * config.d_ff // 2 + config.d_model) if want[1] else 0
+    assert attrs["remat_saved_bytes"] == config.n_layers * rows * itemsize * kept_width
+    assert 0 < attrs["remat_recomputed_flops_share"] < (0.3 if want[1] else 0.9)
 
 
 def test_span_tree_of_a_three_step_train_call(trainer):
