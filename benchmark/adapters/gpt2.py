@@ -1,6 +1,8 @@
 """GPT-2 family (pre-norm, LayerNorm, learned positions, MHA with biases,
 tanh GELU, tied head)."""
 
+import functools
+
 from ..reference import transformer_ref
 
 
@@ -32,5 +34,10 @@ def reference_logits(params, tokens, conf):
     return transformer_ref.forward_logits(params, tokens, **_arch(conf))
 
 
-def reference_loss(params, tokens, conf):
-    return transformer_ref.loss(params, tokens, **_arch(conf))
+def reference_steps(conf, total_tokens):
+    """What `reference/train_ref.follow` needs of this family: a block of
+    rows' share of the objective, nothing of the whole batch beforehand,
+    and 4 rows of float32 activations at a time."""
+    return {"part": functools.partial(
+                transformer_ref.objective_part, total_tokens=total_tokens, **_arch(conf)),
+            "stats": None, "rows_at_a_time": 4}

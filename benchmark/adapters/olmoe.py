@@ -1,6 +1,8 @@
 """OLMoE family (pre-norm, RMSNorm, QK-norm, rope, MHA, a router over
 SwiGLU experts with top-k gates, untied head)."""
 
+import functools
+
 from ..reference import olmoe_ref
 
 
@@ -47,6 +49,14 @@ def reference_logits(params, tokens, conf):
     return olmoe_ref.forward_logits(params, tokens, **_arch(conf))
 
 
-def reference_loss(params, tokens, conf):
-    # one 4,096-token row at a time: its float32 attention scores are 1 GiB
-    return olmoe_ref.loss(params, tokens, rows_at_a_time=1, **_arch(conf))
+def reference_steps(conf, total_tokens):
+    """What `reference/train_ref.follow` needs of this family: a block of
+    rows' share of the objective (cross entropy plus the published 0.01
+    times the load-balancing loss, whose load shares are the whole batch's:
+    `stats`), one 4,096-token row at a time."""
+    arch = _arch(conf)
+    return {"part": functools.partial(
+                olmoe_ref.objective_part, total_tokens=total_tokens,
+                router_aux_loss_coef=float(conf["assumed"]["router_aux_loss_coef"]), **arch),
+            "stats": lambda params, tokens: olmoe_ref.expert_shares(params, tokens[:, :-1], **arch),
+            "rows_at_a_time": 1}

@@ -59,12 +59,21 @@ def _rope(x, theta):
 
 
 def _attention(q, k, v):
-    """q, k, v: (B, S, H, D). Causal softmax attention."""
+    """q, k, v: (B, S, H, D). Causal softmax attention, one head at a time:
+    a 4,096-token row's float32 scores are 64 MiB a head, and the backward
+    pass computes a head's again instead of keeping all sixteen."""
     s, d = q.shape[1], q.shape[-1]
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(F32(d))
     causal = jnp.tril(jnp.ones((s, s), bool))
-    scores = jnp.where(causal[None, None], scores, -jnp.inf)
-    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, axis=-1), v)
+
+    @jax.checkpoint
+    def head(qkv):
+        qh, kh, vh = qkv                                            # (B, S, D)
+        scores = jnp.einsum("bqd,bkd->bqk", qh, kh) / jnp.sqrt(F32(d))
+        scores = jnp.where(causal[None], scores, -jnp.inf)
+        return jnp.einsum("bqk,bkd->bqd", jax.nn.softmax(scores, axis=-1), vh)
+
+    heads = jax.lax.map(head, tuple(jnp.moveaxis(t, 2, 0) for t in (q, k, v)))
+    return jnp.moveaxis(heads, 0, 2)
 
 
 def _gates(p, top_k: int, norm_topk_prob: bool):
@@ -79,7 +88,8 @@ def _gates(p, top_k: int, norm_topk_prob: bool):
 
 @functools.partial(jax.jit, static_argnames=("theta", "eps", "top_k", "norm_topk_prob"))
 def _layer(x, lp, *, theta: float, eps: float, top_k: int, norm_topk_prob: bool):
-    """-> (x after the layer, its load-balancing loss, the chosen experts)."""
+    """-> (x after the layer, its load-balancing loss, the chosen experts,
+    the router's probabilities summed over the tokens (E,))."""
     with jax.default_matmul_precision("highest"):
         lp = jax.tree.map(lambda w: w.astype(F32), lp)
         h = _rmsnorm(x, lp["ln1_scale"], eps)
@@ -96,10 +106,12 @@ def _layer(x, lp, *, theta: float, eps: float, top_k: int, norm_topk_prob: bool)
         p = jax.nn.softmax(h2 @ lp["router"], axis=-1)                # (B, S, E)
         gates, chosen = _gates(p, top_k, norm_topk_prob)
 
+        @jax.checkpoint      # the backward pass computes an expert again: nothing is kept of the 64
+        def gated(w_gate, w_up, w_down, gate):
+            return gate[..., None] * ((jax.nn.silu(h2 @ w_gate) * (h2 @ w_up)) @ w_down)
+
         def one_expert(total, expert):
-            w_gate, w_up, w_down, gate = expert
-            y = (jax.nn.silu(h2 @ w_gate) * (h2 @ w_up)) @ w_down
-            return total + gate[..., None] * y, None
+            return total + gated(*expert), None
 
         out, _ = jax.lax.scan(
             one_expert, jnp.zeros_like(x),
@@ -108,7 +120,7 @@ def _layer(x, lp, *, theta: float, eps: float, top_k: int, norm_topk_prob: bool)
         n_experts = p.shape[-1]
         share = jnp.mean(jax.nn.one_hot(chosen, n_experts, dtype=F32), axis=(0, 1, 2))
         aux = n_experts * jnp.sum(jax.lax.stop_gradient(share) * jnp.mean(p, axis=(0, 1)))
-        return x + out, aux, chosen
+        return x + out, aux, chosen, jnp.sum(p, axis=(0, 1))
 
 
 @functools.partial(jax.jit, static_argnames=("eps",))
@@ -129,7 +141,7 @@ def forward(params: Dict[str, Any], tokens: jax.Array, *, top_k: int,
     aux, chosen = jnp.zeros((), F32), []
     for layer in range(depth):
         lp = {name: w[layer] for name, w in blocks.items()}
-        x, layer_aux, layer_chosen = _layer(
+        x, layer_aux, layer_chosen, _ = _layer(
             x, lp, theta=float(rope_theta), eps=float(norm_eps), top_k=int(top_k),
             norm_topk_prob=bool(norm_topk_prob))
         aux = aux + layer_aux
@@ -156,6 +168,55 @@ def objective(params: Dict[str, Any], tokens: jax.Array, *, router_aux_loss_coef
     logp = jax.nn.log_softmax(logits, axis=-1)
     ce = -jnp.mean(jnp.take_along_axis(logp, tokens[:, 1:, None], axis=-1))
     return ce + router_aux_loss_coef * aux, {"cross_entropy": ce, "router_aux": aux}
+
+
+def expert_shares(params: Dict[str, Any], tokens: jax.Array, *, rows_at_a_time: int = 1,
+                  **arch) -> jax.Array:
+    """f of the load-balancing loss for the whole (B, S) batch: (L, E), the
+    share of its (token, choice) pairs routed to each expert of each layer,
+    a few rows at a time."""
+    n_experts = params["blocks"]["router"].shape[-1]
+    counts = 0
+    for i in range(0, tokens.shape[0], rows_at_a_time):
+        chosen = forward(params, tokens[i: i + rows_at_a_time], **arch)[2]      # (L, b, S, k)
+        counts = counts + jnp.sum(jax.nn.one_hot(chosen, n_experts, dtype=F32), axis=(1, 2, 3))
+    return counts / jnp.sum(counts, axis=-1, keepdims=True)
+
+
+def objective_part(params: Dict[str, Any], rows: jax.Array, shares: jax.Array, *,
+                   total_tokens: int, router_aux_loss_coef: float, head_rows: int = 1024,
+                   top_k: int, norm_topk_prob: bool = False, rope_theta: float = 10000.0,
+                   norm_eps: float = 1e-5):
+    """What the (b, S + 1) `rows` add to the objective of a batch of
+    `total_tokens` targets, of which `shares` (L, E) are the whole batch's
+    `expert_shares` (constants of the load-balancing loss: no gradient flows
+    through f), differentiable: (their share, their summed cross entropy).
+    Each layer is computed again in the backward pass, and the head runs
+    over `head_rows` positions at a time."""
+    tokens, targets = rows[:, :-1], rows[:, 1:]
+    blocks = params["blocks"]
+    layer = jax.checkpoint(functools.partial(
+        _layer, theta=float(rope_theta), eps=float(norm_eps), top_k=int(top_k),
+        norm_topk_prob=bool(norm_topk_prob)))
+    x = params["wte"][tokens].astype(F32)
+    aux = jnp.zeros((), F32)
+    for index in range(blocks["wq"].shape[0]):
+        x, _, _, p_sum = layer(x, {name: w[index] for name, w in blocks.items()})
+        aux = aux + shares.shape[-1] * jnp.sum(shares[index] * p_sum) / total_tokens
+
+    @jax.checkpoint
+    def chunk_ce(args):
+        xc, tc = args
+        logp = jax.nn.log_softmax(
+            _head(xc, params["lnf_scale"], params["lm_head"], eps=float(norm_eps)), axis=-1)
+        return -jnp.sum(jnp.take_along_axis(logp, tc[..., None], axis=-1))
+
+    b, s, e = x.shape
+    n = max(s // head_rows, 1) if s % head_rows == 0 else 1
+    ce_sum = jnp.sum(jax.lax.map(chunk_ce, (
+        jnp.moveaxis(x.reshape(b, n, s // n, e), 1, 0),
+        jnp.moveaxis(targets.reshape(b, n, s // n), 1, 0))))
+    return ce_sum / total_tokens + router_aux_loss_coef * aux, ce_sum
 
 
 def loss(params: Dict[str, Any], tokens: jax.Array, *, rows_at_a_time: int = 1,

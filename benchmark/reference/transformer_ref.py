@@ -120,6 +120,29 @@ def forward_logits(params: Dict[str, Any], tokens: jax.Array, *, family: str,
     return _head(x, final, head, family=family, eps=float(norm_eps))
 
 
+def objective_part(params: Dict[str, Any], rows: jax.Array, whole=None, *, total_tokens: int,
+                   family: str,
+                   rope_theta: float = 10000.0, norm_eps: float = 1e-5):
+    """What the (b, S + 1) `rows` add to the mean next-token cross entropy
+    of a batch of `total_tokens` targets, differentiable: (their share,
+    their summed cross entropy); nothing `whole` of the batch is needed
+    beforehand. The layers are scanned and each is
+    computed again in the backward pass, so one layer's float32
+    activations are all that is held."""
+    tokens, targets = rows[:, :-1], rows[:, 1:]
+    x = params["wte"][tokens].astype(F32)
+    if family == "gpt2":
+        x = x + params["wpe"][: tokens.shape[1]].astype(F32)[None]
+    layer = jax.checkpoint(functools.partial(
+        _layer, family=family, theta=float(rope_theta), eps=float(norm_eps)))
+    x, _ = jax.lax.scan(lambda x, lp: (layer(x, lp), None), x, params["blocks"])
+    final = {k: params[k] for k in ("lnf_scale", "lnf_bias") if k in params}
+    head = params["lm_head"] if "lm_head" in params else params["wte"].T
+    logp = jax.nn.log_softmax(_head(x, final, head, family=family, eps=float(norm_eps)), axis=-1)
+    ce_sum = -jnp.sum(jnp.take_along_axis(logp, targets[..., None], axis=-1))
+    return ce_sum / total_tokens, ce_sum
+
+
 def loss(params: Dict[str, Any], tokens: jax.Array, *, rows_at_a_time: int = 2,
          **arch) -> float:
     """Mean next-token cross entropy of (B, S + 1) tokens, a few rows at a

@@ -87,6 +87,7 @@ def run_cell(bench: Dict[str, Any], workload: str, seed: int, seconds: float,
             ctx["problems"].append(f"end-to-end metric {spec['name']} has no value")
     for problem in ctx["problems"]:
         log("not correct:", problem)
+    log("compared (each number beside its limit):", json.dumps(ctx.get("checks")))
     result: Dict[str, Any] = {
         "correct": not ctx["problems"], "attempted": ctx["attempted"],
         "failed": ctx["failed"], "metrics": metrics,

@@ -31,11 +31,23 @@ def test_traced_line_reports_what_a_cpu_can_count(benchmark_json):
     assert "busy_s" not in result["device"]
 
 
-def test_first_step_loss_equals_the_reference(benchmark_json):
+@pytest.mark.parametrize("cell", ["tiny-train", "tiny-train-4dev"])
+def test_first_steps_equal_the_reference(benchmark_json, cell):
+    """Float32 against float32: the step's first two losses, its first
+    gradient a leaf and the parameters' change a leaf against the plain
+    reference's gradient, clip and AdamW (also on the dp=2 x fsdp=2 x tp=2
+    mesh, where the reference's arrays lie as the program's do)."""
     from benchmark import run
 
-    result = rehearse(benchmark_json, "tiny-train", trace=False, seed=2**31 + 11)
+    result = rehearse(benchmark_json, cell, trace=False, seed=2**31 + 11)
     checks = result["info"]["checks"]
-    assert abs(checks["loss_first"] - checks["reference_loss_first"]) < 1e-4
+    assert result["correct"] is True
+    assert checks["first_loss_repeat_gap"]["value"] == 0
+    assert checks["loss_step1_gap"]["value"] < 1e-5 and checks["loss_step2_gap"]["value"] < 1e-5
+    assert checks["first_gradient_worst_leaf_difference"]["value"] < 1e-4
+    assert checks["first_gradient_worst_leaf_norm_gap"] < 1e-4
+    assert checks["change_worst_leaf_gap"]["value"] < 1e-4
+    assert all(0 < checks[name]["limit"] <= 1e-3 for name in (
+        "loss_step1_gap", "first_gradient_worst_leaf_difference", "change_worst_leaf_gap"))
     assert checks["loss_last"] < checks["loss_first"]
     assert run.CompileCounter.EVENT.endswith("backend_compile_duration")

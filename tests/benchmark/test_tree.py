@@ -9,6 +9,7 @@ import re
 import pytest
 
 from bench_helpers import ROOT, load, with_spare
+from benchmark import model_config
 
 BENCH = os.path.join(ROOT, "benchmark")
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -59,18 +60,37 @@ def test_every_cell_reports_setup_another_end_to_end_metric_and_a_layer(benchmar
         assert {m["moves"] for m in layers} <= e2e
 
 
-def test_cells_find_their_files(benchmark_json):
-    configs = {c["name"]: c for c in benchmark_json["configs"]}
-    assert {w["config"] for w in benchmark_json["workloads"]} == set(configs)
-    for w in benchmark_json["workloads"]:
-        conf = load(os.path.join(ROOT, configs[w["config"]]["file"]))
+def cells_find_their_files(bench):
+    """Every cell's configuration file and traffic file exist and agree with
+    their entries; what an entry's `reduced` names is held to the rule of
+    `model_config.check_reduced` (the depth or a count held as the chip's
+    share, never a width), whatever the file itself lists."""
+    configs = {c["name"]: c for c in bench["configs"]}
+    assert {w["config"] for w in bench["workloads"]} == set(configs)
+    for w in bench["workloads"]:
+        entry = configs[w["config"]]
+        conf = model_config.load_config(os.path.join(ROOT, entry["file"]))
+        model_config.check_reduced(dict(conf, reduced=entry["reduced"]), entry["name"])
         assert conf["chips"] == w["chips"]
-        assert conf["source"] == configs[w["config"]]["source"]
-        assert conf["reduced"] == configs[w["config"]]["reduced"]
-        for key in conf["reduced"]:
-            assert key in conf["published"] and conf["published"][key] != conf[key]
-            assert not key.endswith(("_dim", "_rank", "_size"))       # depth only, never a width
+        assert conf["source"] == entry["source"]
+        assert conf["reduced"] == entry["reduced"]
         assert os.path.exists(os.path.join(BENCH, "traffic", w["traffic"] + ".json"))
+
+
+def test_cells_find_their_files(benchmark_json):
+    cells_find_their_files(benchmark_json)
+
+
+@pytest.mark.parametrize("key", ["hidden_size", "intermediate_size", "num_experts_per_tok",
+                                 "head_dim", "kv_lora_rank", "moe_intermediate_size"])
+def test_an_entry_whose_reduced_names_a_width_is_refused(benchmark_json, key):
+    """Through BENCHMARK.json's own `reduced`: the shipped OLMoE file, with
+    an entry that claims one more key."""
+    bench = dict(benchmark_json, configs=[
+        dict(c, reduced=c["reduced"] + [key]) if c["name"] == "olmoe-1b-7b-train-1chip" else c
+        for c in benchmark_json["configs"]])
+    with pytest.raises(ValueError, match=repr(key)):
+        cells_find_their_files(bench)
 
 
 def test_every_data_file_is_reachable_or_spare(benchmark_json):
@@ -86,7 +106,8 @@ def test_every_data_file_is_reachable_or_spare(benchmark_json):
         assert callable(reader.read)
         for key in ("layer", "unit", "better", "source", "moves"):
             assert meta[key] == entry[key], (stem, key)
-        assert sorted(meta["workloads"]) == sorted(entry["workloads"])
+        # a metric's cells are listed in ONE place, its entry: a cell joins by one word there
+        assert "workloads" not in meta, stem
     for w in b["workloads"]:
         kind = load(os.path.join(BENCH, "traffic", w["traffic"] + ".json"))["kind"]
         assert callable(importlib.import_module("benchmark.kinds." + kind).run)
