@@ -8,9 +8,10 @@ the functions that build and run its model (train/lm.py reads it).
 
 from typing import Callable, NamedTuple, Optional
 
-from . import moe as _moe, transformer as _dense
+from . import mixed_stack as _mixed, moe as _moe, transformer as _dense
 
 from .configs import PRESETS, get_config  # noqa: F401
+from .mixed_stack import MixedStackConfig  # noqa: F401
 from .moe import (  # noqa: F401
     MoEConfig,
     mixtral_8x7b,
@@ -52,7 +53,7 @@ class ModelFamily(NamedTuple):
     # a checkpoint may keep (transformer.block_costs); None: a family whose
     # blocks name none, and are recomputed whole
     block_costs: Optional[Callable]
-    # (config, tokens a step) -> what the family's own layers resolve to, for
+    # (config, batch, seq of a step) -> what the family's own layers resolve to, for
     # callers that report it (LMTrainer's `train.init.step_fn` span)
     plan: Callable
 
@@ -61,12 +62,14 @@ def _dense_hidden(params, tokens, config, remat_saved=()):
     return _dense.forward_hidden(params, tokens, config, remat_saved=remat_saved), {}
 
 
-# most derived first: a MoEConfig is a TransformerConfig
+# most derived first: a MixedStackConfig is a MoEConfig is a TransformerConfig
 _FAMILIES = (
+    (MixedStackConfig, ModelFamily(_mixed.init_params, _mixed.logical_axes, _mixed.forward_hidden,
+                                   None, _mixed.plan)),
     (MoEConfig, ModelFamily(_moe.init_params, _moe.logical_axes, _moe.forward_hidden,
                             None, _moe.moe_plan)),
     (TransformerConfig, ModelFamily(init_params, logical_axes, _dense_hidden,
-                                    _dense.block_costs, lambda config, tokens_per_step: {})),
+                                    _dense.block_costs, lambda config, batch, seq: {})),
 )
 
 

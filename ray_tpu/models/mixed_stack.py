@@ -1,0 +1,308 @@
+"""A decoder whose layers are not all alike: a stack described as data.
+
+The dense and MoE families (transformer.py, moe.py) scan one block over a
+stack of identical layers. Here the configuration says, a layer, its
+attention kind ("sliding": a causal window with rotary positions; "full":
+every causal key and no positional encoding) and its MLP kind ("dense": one
+SwiGLU; "experts": a routed expert layer beside a shared expert), by two
+rules on the layer's index: every `global_attn_every`-th layer is full, the
+first `n_dense_layers` are dense. `stack_runs` groups the layers into runs
+of a repeated period of kinds; the forward scans each run over its repeats
+and unrolls the period inside the scan's body, so compile time grows with
+the period and not with the depth, and parameters are stacked a run: run r,
+position p of its period is one dict of leaves with a leading axis of the
+run's repeats.
+
+The block is written once, from the parts the two other families own:
+`transformer.attention_sublayer` (head size, QK-norm a head, rotary or none,
+window, output gate, post-norm: data of the configuration and arguments of
+the call), `transformer.mlp_sublayer`, `moe.moe_mlp`. Every block is
+recomputed whole in the backward pass where `config.remat`.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ..ops import rope_frequencies
+from ..ops.attention import attention_plan
+from .moe import MoEConfig, load_max_over_mean, moe_mlp, moe_plan
+from .transformer import Params, _norm, attention_sublayer, checkpoint_block, mlp_sublayer
+
+
+@dataclasses.dataclass(frozen=True)
+class MixedStackConfig(MoEConfig):
+    """`d_ff` is one routed expert's width (as in MoEConfig), `d_ff_dense`
+    the width of a dense layer's MLP."""
+
+    sliding_window: int = 2048
+    global_attn_every: int = 4     # layer i is full iff (i + 1) % this == 0
+    n_dense_layers: int = 0        # the leading layers whose MLP is dense
+    d_ff_dense: int = 0
+    # a layer's leaves that the step reads as constants: they get no gradient
+    # and so are not trained. What one chip's share of a layer names when it
+    # cannot form a leaf's whole gradient: the router's needs the outputs of
+    # all the chosen experts, and the absent ones' lie on other chips
+    frozen_leaves: Tuple[str, ...] = ()
+
+    def __post_init__(self):
+        known = {name for mlp in ("dense", "experts")
+                 for name in _layer_shapes(self, LayerKind("full", mlp))}
+        if not set(self.frozen_leaves) <= known:
+            raise ValueError(f"frozen_leaves {sorted(set(self.frozen_leaves) - known)}: "
+                             f"no layer has such a leaf")
+
+
+class LayerKind(NamedTuple):
+    attention: str  # "sliding" | "full"
+    mlp: str        # "dense" | "experts"
+
+    @property
+    def code(self) -> str:
+        return ("d" if self.mlp == "dense" else "e") + ("F" if self.attention == "full" else "S")
+
+
+class Run(NamedTuple):
+    """`repeats` times the period of `kinds`, layers in a row."""
+
+    kinds: Tuple[LayerKind, ...]
+    repeats: int
+
+
+def layer_kinds(config: MixedStackConfig) -> List[LayerKind]:
+    c = config
+    return [LayerKind("full" if (i + 1) % c.global_attn_every == 0 else "sliding",
+                      "dense" if i < c.n_dense_layers else "experts")
+            for i in range(c.n_layers)]
+
+
+def stack_runs(kinds: List[LayerKind]) -> List[Run]:
+    """The layers as runs: the stack is cut where the MLP kind changes (the
+    parameters' shapes do), and each piece is its shortest period repeated,
+    then what is left over as one more run of a single repeat."""
+    runs: List[Run] = []
+    start = 0
+    while start < len(kinds):
+        end = start
+        while end < len(kinds) and kinds[end].mlp == kinds[start].mlp:
+            end += 1
+        piece = kinds[start:end]
+        # the shortest period that repeats at least twice, else the piece itself
+        period = next((p for p in range(1, len(piece) // 2 + 1)
+                       if all(piece[i] == piece[i % p] for i in range(len(piece) // p * p))),
+                      len(piece))
+        repeats = len(piece) // period
+        runs.append(Run(tuple(piece[:period]), repeats))
+        if len(piece) % period:
+            runs.append(Run(tuple(piece[repeats * period:]), 1))
+        start = end
+    return runs
+
+
+# ----------------------------------------------------------------------- init
+
+
+# What the norm on a sublayer's OUTPUT starts at: the gain LayerScale puts on
+# a residual branch (the other families scale the projection into the
+# residual stream by 1/sqrt(2L); under a norm that scale is undone). At 1 the
+# norm takes an untrained attention layer's output, the mean of the values
+# over up to `sliding_window` keys and all but the same vector for every
+# query, up to the size of the token's own embedding, layer after layer: the
+# router's input is then half that common vector and most tokens choose the
+# same experts before a step is taken. Measured at the published widths
+# (PERF.md section 6, PR 33): the common vector's share of the router's input
+# 0.44-0.48 at 1, 0.16-0.23 at 0.29, 0.06-0.09 at 0.125; training on tokens
+# drawn independently brings it back the faster the larger the gain (largest
+# expert's rows over the mean after 46 steps: 7.6 at 0.05, 5.1 at 0.01),
+# while under 0.03 a token's copies route alike to the last bit, so that one
+# near-tie between bfloat16 and float32 moves all of them at once.
+POST_NORM_GAIN = 0.03
+
+
+def _layer_shapes(config: MixedStackConfig, kind: LayerKind) -> Dict[str, Tuple[Tuple[int, ...], Any, Any]]:
+    """name -> (shape, how it is initialised, logical axes) of one layer's
+    leaves. "post": the norm on a sublayer's output."""
+    c = config
+    dh, m = c.head_dim, c.d_model
+    shapes = {
+        "ln1_scale": ((m,), "ones", (None,)),
+        "ln1_post_scale": ((m,), "post", (None,)),
+        "ln2_scale": ((m,), "ones", (None,)),
+        "ln2_post_scale": ((m,), "post", (None,)),
+        "wq": ((m, c.n_heads, dh), "normal", ("embed", "heads", "head_dim")),
+        "wk": ((m, c.kv_heads, dh), "normal", ("embed", "kv_heads", "head_dim")),
+        "wv": ((m, c.kv_heads, dh), "normal", ("embed", "kv_heads", "head_dim")),
+        "wg": ((m, c.n_heads, dh), "normal", ("embed", "heads", "head_dim")),
+        "wo": ((c.n_heads, dh, m), "normal", ("heads", "head_dim", "embed")),
+        "q_norm_scale": ((dh,), "ones", (None,)),
+        "k_norm_scale": ((dh,), "ones", (None,)),
+    }
+    if kind.mlp == "dense":
+        shapes.update(
+            w_gate=((m, c.d_ff_dense), "normal", ("embed", "mlp")),
+            w_up=((m, c.d_ff_dense), "normal", ("embed", "mlp")),
+            w_down=((c.d_ff_dense, m), "normal", ("mlp", "embed")))
+        return shapes
+    held, shared = c.n_experts_held, c.shared_expert_width
+    shapes.update(
+        # replicated, as moe.logical_axes has it
+        router=((m, c.n_experts), "normal", (None, None)),
+        expert_bias=((c.n_experts,), "zeros", (None,)),
+        we_gate=((held, m, c.d_ff), "normal", ("expert", "embed", "mlp")),
+        we_up=((held, m, c.d_ff), "normal", ("expert", "embed", "mlp")),
+        we_down=((held, c.d_ff, m), "normal", ("expert", "mlp", "embed")),
+        ws_gate=((m, shared), "normal", ("embed", "mlp")),
+        ws_up=((m, shared), "normal", ("embed", "mlp")),
+        ws_down=((shared, m), "normal", ("mlp", "embed")))
+    return shapes
+
+
+def init_params(config: MixedStackConfig, key: jax.Array) -> Params:
+    """The repo's initialisation (N(0, 0.02), norms 1 but those on a
+    sublayer's output, which start at POST_NORM_GAIN, the selection bias 0),
+    stacked a run: params["runs"][r][p][leaf] has the run's repeats in
+    front."""
+    c = config
+    pd = c.param_dtype
+    std = 0.02
+    constant = {"ones": 1.0, "post": POST_NORM_GAIN, "zeros": 0.0}
+
+    def leaf(k, shape, how, repeats):
+        shape = (repeats, *shape)
+        if how == "normal":
+            return std * jax.random.normal(k, shape, pd)
+        return jnp.full(shape, constant[how], pd)
+
+    runs = []
+    for r, run in enumerate(stack_runs(layer_kinds(c))):
+        period = []
+        for p, kind in enumerate(run.kinds):
+            shapes = _layer_shapes(c, kind)
+            keys = jax.random.split(jax.random.fold_in(jax.random.fold_in(key, r), p), len(shapes))
+            period.append({name: leaf(k, shape, how, run.repeats)
+                           for k, (name, (shape, how, _)) in zip(keys, shapes.items())})
+        runs.append(period)
+    k_embed, k_head = jax.random.split(jax.random.fold_in(key, 10**6))
+    return {
+        "wte": std * jax.random.normal(k_embed, (c.vocab_size, c.d_model), pd),
+        "runs": runs,
+        "lnf_scale": jnp.ones((c.d_model,), pd),
+        "lm_head": std * jax.random.normal(k_head, (c.d_model, c.vocab_size), pd),
+    }
+
+
+def logical_axes(config: MixedStackConfig) -> Params:
+    return {
+        "wte": ("vocab", "embed"),
+        "runs": [[{name: ("layers", *axes) for name, (_, _, axes) in _layer_shapes(config, kind).items()}
+                  for kind in run.kinds] for run in stack_runs(layer_kinds(config))],
+        "lnf_scale": (None,),
+        "lm_head": ("embed", "vocab"),
+    }
+
+
+# -------------------------------------------------------------------- forward
+
+
+def _block(x, lp, config, kind: LayerKind, rope_tables, positions):
+    """One layer of either attention kind and either MLP kind on (B, S, E):
+    x + norm(attention(norm(x))), then the same around the MLP. -> (x, the
+    expert layer's scalars, {} for a dense layer)."""
+    c = config
+    if c.frozen_leaves:
+        lp = {name: jax.lax.stop_gradient(w) if name in c.frozen_leaves else w
+              for name, w in lp.items()}
+    sliding = kind.attention == "sliding"
+    with jax.named_scope("attn.window" if sliding else "attn.full"):
+        x = attention_sublayer(
+            x, lp, c, rope_tables if sliding else None, positions,
+            window=c.sliding_window if sliding else None)
+    if kind.mlp == "dense":
+        return mlp_sublayer(x, lp, c), {}
+    out, scalars = moe_mlp(_norm(x, lp["ln2_scale"], None, c.norm, c.norm_eps), lp, c)
+    out = _norm(out, lp["ln2_post_scale"], None, c.norm, c.norm_eps)
+    scalars.pop("aux")  # this family trains on the cross entropy alone
+    return x + out, dict(scalars, load=load_max_over_mean(scalars["load"]))
+
+
+def forward_hidden(
+    params: Params,
+    tokens: jax.Array,
+    config: MixedStackConfig,
+    *,
+    positions: Optional[jax.Array] = None,
+    remat_saved: Tuple[str, ...] = (),
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """Forward up to the LM head: (B, S) -> ((B, S, E), what the expert layers
+    report: `moe_load_max_over_mean` and `moe_passes` of the worst layer,
+    `moe_rows_held` and `moe_rows_held_share` (of the T k routed rows) as the
+    mean over the expert layers). The rotary table is built for the
+    sequence at hand, not for `max_seq`."""
+    c = config
+    dt = c.dtype
+    b, s = tokens.shape
+    x = params["wte"].astype(dt)[tokens]
+    if c.scale_embedding:
+        x = x * jnp.asarray(math.sqrt(c.d_model), dt)
+    rope_tables = rope_frequencies(c.head_dim, s, c.rope_theta)
+
+    reports: List[Dict[str, jax.Array]] = []
+    for run, period_params in zip(stack_runs(layer_kinds(c)), params["runs"]):
+        def period(x, period_lp, kinds=run.kinds):
+            scalars = []
+            for kind, lp in zip(kinds, period_lp):
+                def block_fn(x, lp, kind=kind):
+                    return _block(x, lp, c, kind, rope_tables, positions)
+
+                if c.remat:
+                    block_fn = checkpoint_block(block_fn, remat_saved)
+                x, layer_scalars = block_fn(x, lp)
+                if layer_scalars:
+                    scalars.append(layer_scalars)
+            return x, scalars
+
+        if run.repeats == 1:
+            x, scalars = period(x, jax.tree.map(lambda w: w[0], period_params))
+        else:
+            x, scalars = jax.lax.scan(period, x, period_params, unroll=c.scan_unroll)
+        reports.extend(scalars)      # under a scan each scalar is (repeats,)
+    x = _norm(x, params["lnf_scale"], None, c.norm, c.norm_eps)
+    if not reports:
+        return x, {}
+    every = {name: jnp.concatenate([jnp.ravel(r[name]) for r in reports]) for name in reports[0]}
+    out = {"moe_load_max_over_mean": jnp.max(every["load"])}
+    if "moe_rows_held" in every:
+        rows_held = jnp.mean(every["moe_rows_held"])
+        out.update(moe_rows_held=rows_held,
+                   moe_rows_held_share=100.0 * rows_held / (b * s * c.top_k),
+                   moe_passes=jnp.max(every["moe_passes"]))
+    return x, out
+
+
+def plan(config: MixedStackConfig, batch: int, seq: int) -> Dict[str, Any]:
+    """What the stack resolves to for a step of `batch` rows of `seq` tokens,
+    for callers that report it (LMTrainer's `train.init.step_fn` span): the
+    layers' kinds in order (`dS dS eS eF ...`), the window and the windowed
+    kernels' sub-tile walk (the full layers' is `attention_plan`'s, which the
+    trainer writes for every model), the router's form and the expert layer's
+    (`moe.moe_plan`)."""
+    c = config
+    windowed = attention_plan(seq, causal=c.causal, implementation=c.attn_impl,
+                              window=c.sliding_window)
+    out = {
+        "layer_kinds": " ".join(kind.code for kind in layer_kinds(c)),
+        "attn_window": c.sliding_window,
+        **{name.replace("attn_subtiles", "attn_window_subtiles"): value
+           for name, value in windowed.items() if name.startswith("attn_subtiles")},
+        "moe_router": c.router_score,
+        "moe_experts_routed": c.n_experts,
+        "moe_shared_width": c.shared_expert_width,
+    }
+    if c.n_dense_layers < c.n_layers:
+        out.update(moe_plan(c, batch, seq))
+    return out

@@ -59,6 +59,26 @@ class MoEConfig(TransformerConfig):
     capacity_factor: float = 2.0  # read by the GShard path (ep > 1) alone
     router_aux_coeff: float = 0.01
     norm_topk_prob: bool = True  # gates renormalised over the chosen k (Mixtral)
+    # the router's form, all of it data of the architecture: the scores that
+    # are ranked and gated with ("softmax" over the experts, or "sigmoid" of
+    # each logit), whether a bias `expert_bias` (a parameter with no gradient)
+    # is added to them for the SELECTION alone, and a scale on the gates
+    router_score: str = "softmax"
+    router_select_bias: bool = False
+    route_scale: float = 1.0
+    # a dense SwiGLU of this width on every token, added to the routed sum (0: none)
+    shared_expert_width: int = 0
+    # the range [first, last) of the published experts this device holds, the
+    # others lying on further chips (None: all `n_experts`). The router keeps
+    # its `n_experts` outputs and its top-k over all of them, the gates are
+    # normalised over all k chosen, and the layer computes the sum over the
+    # chosen experts that are held
+    held_experts: Optional[Tuple[int, int]] = None
+
+    @property
+    def n_experts_held(self) -> int:
+        first, last = self.held_experts or (0, self.n_experts)
+        return last - first
 
 
 def mixtral_8x7b() -> MoEConfig:
@@ -145,6 +165,7 @@ def init_params(config: MoEConfig, key: jax.Array) -> Params:
     keys = jax.random.split(jax.random.fold_in(key, 99), 4)
     L, E = c.n_layers, c.n_experts
     blocks["router"] = (std * jax.random.normal(keys[0], (L, c.d_model, E))).astype(pd)
+    E = c.n_experts_held
     blocks["we_gate"] = (std * jax.random.normal(keys[1], (L, E, c.d_model, c.d_ff))).astype(pd)
     blocks["we_up"] = (std * jax.random.normal(keys[2], (L, E, c.d_model, c.d_ff))).astype(pd)
     blocks["we_down"] = (res_std * jax.random.normal(keys[3], (L, E, c.d_ff, c.d_model))).astype(pd)
@@ -279,8 +300,8 @@ def expert_parallel(mesh) -> bool:
     return (not mesh.empty) and mesh.shape.get("ep", 1) > 1
 
 
-def moe_plan(config: "MoEConfig", tokens_per_step: int) -> Dict[str, Any]:
-    """What the expert layer runs for a step of `tokens_per_step` tokens
+def moe_plan(config: "MoEConfig", batch: int, seq: int) -> Dict[str, Any]:
+    """What the expert layer runs for a step of `batch` rows of `seq` tokens
     under the context mesh, for callers that report it (LMTrainer's
     `train.init.step_fn` span)."""
     if expert_parallel(_mesh_of()):
@@ -288,10 +309,38 @@ def moe_plan(config: "MoEConfig", tokens_per_step: int) -> Dict[str, Any]:
     else:
         gmm = resolve_gmm_impl()
         impl, tile = {"pallas": "gmm_pallas", "xla": "ragged_dot"}[gmm], gmm_tile_rows(gmm)
-    return {
+    plan = {
         "moe_impl": impl, "moe_experts": config.n_experts, "moe_top_k": config.top_k,
-        "moe_rows_per_step": tokens_per_step * config.top_k, "moe_gmm_tile_rows": tile,
+        "moe_rows_per_step": batch * seq * config.top_k, "moe_gmm_tile_rows": tile,
     }
+    if config.held_experts is not None:
+        mesh = _mesh_of()
+        # a device's tokens: the layer runs once a device on the tokens it holds
+        ways = 1 if mesh.empty else math.prod(
+            mesh.shape.get(a, 1) for a in (*DATA_AXES, "sp"))
+        mine = (config, batch * seq // ways, max(tile, 1))
+        plan.update(moe_experts_held=config.n_experts_held,
+                    moe_held_buffer_rows=held_buffer_rows(*mine),
+                    moe_held_passes_most=held_passes_most(*mine))
+    return plan
+
+
+def _route(scores, select, config):
+    """scores (T, E) -> (gates (T, k), the chosen experts (T, k)): the k
+    largest of `select` (the scores plus the selection bias; None: of the
+    scores themselves), gated by their scores, renormalised and scaled as the
+    configuration says."""
+    c = config
+    if select is None:
+        gates, experts = jax.lax.top_k(scores, c.top_k)
+    else:
+        _, experts = jax.lax.top_k(select, c.top_k)
+        gates = jnp.take_along_axis(scores, experts, axis=-1)
+    if c.norm_topk_prob:
+        gates = gates / (jnp.sum(gates, -1, keepdims=True) + 1e-9)
+    if c.route_scale != 1.0:
+        gates = gates * c.route_scale
+    return gates, experts
 
 
 def _gshard_experts(h, probs, weights, config):
@@ -312,18 +361,33 @@ def _gshard_experts(h, probs, weights, config):
     return out, jnp.sum(dispatch, axis=(0, 1, 3))
 
 
-def _dropless_shard(h, probs, weights, config):
-    """The same contract on the tokens one device holds, no capacity:
-    every (token, choice) row is computed."""
-    c = config
+def _swiglu_groups(expert_in, weights, group_sizes, tile, impl):
+    """The three grouped matmuls of the expert-sorted rows `expert_in`."""
     we_gate, we_up, we_down = weights
+
+    def gmm(lhs, w):
+        return grouped_matmul(lhs, w, group_sizes, tile_rows=tile, implementation=impl)
+
+    return gmm(swiglu(gmm(expert_in, we_gate), gmm(expert_in, we_up)), we_down)
+
+
+def _dropless_shard(h, probs, weights, config, select=None):
+    """The same contract on the tokens one device holds, no capacity:
+    every (token, choice) row is computed. -> (output (B, S, M), rows a
+    published expert (E,), what a layer that holds a part of the experts
+    reports of itself ({} otherwise))."""
+    c = config
     b, s, m = h.shape
     tokens = b * s
     impl = resolve_gmm_impl()
     tile = gmm_tile_rows(impl)
-    gates, experts = jax.lax.top_k(probs.reshape(tokens, c.n_experts), c.top_k)  # (T, k)
-    if c.norm_topk_prob:
-        gates = gates / (jnp.sum(gates, -1, keepdims=True) + 1e-9)
+    gates, experts = _route(
+        probs.reshape(tokens, c.n_experts),
+        None if select is None else select.reshape(tokens, c.n_experts), c)  # (T, k)
+    if c.held_experts is not None:
+        out, held = _held_experts(h.reshape(tokens, m), gates, experts, weights, c, tile, impl)
+        load = jnp.sum(jax.nn.one_hot(experts, c.n_experts, dtype=jnp.float32), axis=(0, 1))
+        return out.astype(c.dtype).reshape(b, s, m), load, held
     with jax.named_scope("moe.dispatch"):
         layout = dropless_layout(experts, c.n_experts, tile)
         # slot -> token: T (out of range, a zero row) where the slot holds none
@@ -331,21 +395,115 @@ def _dropless_shard(h, probs, weights, config):
             h.reshape(tokens, m), layout.slot_row // c.top_k,
             layout.row_slot.reshape(tokens, c.top_k))
     with jax.named_scope("moe.experts"):
-        def gmm(lhs, w):
-            return grouped_matmul(lhs, w, layout.padded_sizes,
-                                  tile_rows=tile, implementation=impl)
-
-        act = swiglu(gmm(expert_in, we_gate), gmm(expert_in, we_up))
-        expert_out = gmm(act, we_down)
+        expert_out = _swiglu_groups(expert_in, weights, layout.padded_sizes, tile, impl)
     with jax.named_scope("moe.combine"):
         chosen = _take_rows(expert_out, layout.row_slot, layout.slot_row[:, None])
         # gated in float32; one fused pass over the gathered rows
         chosen = chosen.reshape(tokens, c.top_k, m).astype(jnp.float32)
         out = jnp.sum(gates[..., None] * chosen, axis=1)
-    return out.astype(c.dtype).reshape(b, s, m), layout.sizes.astype(jnp.float32)
+    return out.astype(c.dtype).reshape(b, s, m), layout.sizes.astype(jnp.float32), {}
 
 
-def _dropless_experts(h, probs, weights, config, mesh):
+# ------------------------------------------------- a part of the experts held
+# The buffer of a layer that holds `held` of `published` experts, in shares of
+# the rows it is sent when routing is even (T k held / published): twice that
+# holds nearly every step of the cell that runs it (PERF.md section 6, PR 33:
+# 8.5-16.1% of the rows at the seeded weights against an even 12.5%; one layer
+# of one seed passed 25% once, and took a second pass), and a routing that
+# sends more is computed in further passes through the same buffer, never
+# dropped. At the cell's shapes the layer's forward and backward take 36.1 ms
+# with it and 58.1 ms with the buffer that holds every routing in one pass.
+_HELD_BUFFER_SHARES = 2.0
+
+
+def held_buffer_rows(config: MoEConfig, tokens: int, tile: int) -> int:
+    """Rows of (token, choice) pairs one pass of the held layer takes."""
+    rows = tokens * config.top_k
+    even = rows * config.n_experts_held / config.n_experts
+    return min(-(-int(_HELD_BUFFER_SHARES * even) // tile) * tile, -(-rows // tile) * tile)
+
+
+def held_passes_most(config: MoEConfig, tokens: int, tile: int) -> int:
+    """Passes that hold every routing: all T k rows sent here."""
+    return -(-tokens * config.top_k // held_buffer_rows(config, tokens, tile))
+
+
+def _held_experts(h, gates, experts, weights, config, tile, impl):
+    """h (T, M), gates and published expert ids (T, k) -> (sum over each
+    token's chosen experts THAT ARE HELD of gate x expert(h), float32 (T, M);
+    {"moe_rows_held", "moe_passes"}).
+
+    The (token, choice) rows are sorted by expert, those routed to an absent
+    expert last; they are never gathered, multiplied or combined. The held
+    rows go through the three grouped matmuls `held_buffer_rows` at a time:
+    pass p takes the sorted rows [p R, (p + 1) R), whose groups are what is
+    left of each expert's rows there, so every pass has the layout
+    `ops/grouped_matmul` asks for and the same static shapes. The first pass
+    always runs; a further pass runs (`lax.cond`) only if rows are left for
+    it, and is recomputed in the backward pass, so a pass not taken costs
+    neither time nor memory. No routing drops a row: `held_passes_most`
+    passes hold all T k."""
+    c = config
+    tokens, m = h.shape
+    k, n = c.top_k, c.n_experts_held
+    rows = tokens * k
+    first_held = c.held_experts[0]
+    buffer_rows = held_buffer_rows(c, tokens, tile)
+    slots = buffer_rows + n * tile
+    with jax.named_scope("moe.dispatch"):
+        local = experts.reshape(-1).astype(jnp.int32) - first_held
+        flat = jnp.where((local >= 0) & (local < n), local, n)      # absent: sorted last
+        order = jnp.argsort(flat, stable=True).astype(jnp.int32)     # sorted position -> row
+        sorted_expert = flat[order]
+        ends = jnp.searchsorted(
+            sorted_expert, jnp.arange(n, dtype=jnp.int32), side="right").astype(jnp.int32)
+        rows_held = ends[-1]
+        slot = jnp.arange(slots, dtype=jnp.int32)
+        flat_gates = gates.reshape(-1)
+
+    def one_pass(h, flat_gates, weights, start):
+        with jax.named_scope("moe.dispatch"):
+            pass_ends = jnp.clip(ends - start, 0, buffer_rows)
+            sizes = jnp.diff(pass_ends, prepend=0)
+            first_position = start + pass_ends - sizes     # of each expert's rows in this pass
+            padded = -(-jnp.maximum(sizes, 1) // tile) * tile
+            padded_ends = jnp.cumsum(padded)
+            padded_starts = padded_ends - padded
+            slot_expert = jnp.minimum(jnp.searchsorted(padded_ends, slot, side="right"), n - 1)
+            rank = slot - padded_starts[slot_expert]
+            slot_row = jnp.where(
+                rank < sizes[slot_expert],
+                order[jnp.clip(first_position[slot_expert] + rank, 0, rows - 1)], rows)
+            # slot -> token: T (out of range: read as a zero row, dropped when written)
+            # where the slot holds none
+            slot_token = slot_row // k
+            expert_in = jnp.take(h, slot_token, axis=0, mode="fill", fill_value=0)
+        with jax.named_scope("moe.experts"):
+            expert_out = _swiglu_groups(expert_in, weights, padded.astype(jnp.int32), tile, impl)
+        with jax.named_scope("moe.combine"):
+            slot_gate = jnp.take(flat_gates, slot_row, mode="fill", fill_value=0)
+            gated = slot_gate[:, None] * expert_out.astype(jnp.float32)
+            return jnp.zeros((tokens, m), jnp.float32).at[slot_token].add(gated, mode="drop")
+
+    out = one_pass(h, flat_gates, weights, jnp.int32(0))
+    most = held_passes_most(c, tokens, tile)
+    if most > 1:
+        later = jax.checkpoint(one_pass)
+
+        def further(out, start):
+            return out + jax.lax.cond(
+                start < rows_held, later,
+                lambda h, g, w, start: jnp.zeros((tokens, m), jnp.float32),
+                h, flat_gates, weights, start), None
+
+        out, _ = jax.lax.scan(
+            further, out, jnp.arange(1, most, dtype=jnp.int32) * buffer_rows)
+    passes = jnp.maximum(-(-rows_held // buffer_rows), 1)
+    return out, {"moe_rows_held": rows_held.astype(jnp.float32),
+                 "moe_passes": passes.astype(jnp.float32)}
+
+
+def _dropless_experts(h, probs, weights, config, mesh, select=None):
     """`_dropless_shard` once a device. GSPMD cannot partition a Mosaic
     call, and a sort over every shard's rows is nothing it should be
     given: under a mesh each device sorts and computes the tokens it
@@ -355,26 +513,84 @@ def _dropless_experts(h, probs, weights, config, mesh):
     token axes. With no mesh, one device, or inside somebody else's
     shard_map the layer is called as it is."""
     if mesh.empty or mesh.manual_axes or mesh.size == 1:
-        return _dropless_shard(h, probs, weights, config)
+        return _dropless_shard(h, probs, weights, config, select)
     batch = tuple(a for a in DATA_AXES if mesh.shape[a] > 1)
     seq = "sp" if mesh.shape.get("sp", 1) > 1 else None
     tp = "tp" if mesh.shape.get("tp", 1) > 1 else None
     token_axes = batch + ((seq,) if seq else ())
 
-    def shard(h, probs, weights):
-        out, sizes = _dropless_shard(h, probs, weights, config)
+    def shard(h, probs, weights, *select):
+        out, sizes, held = _dropless_shard(h, probs, weights, config, *select)
         if tp:
             out = jax.lax.psum(out, tp)
         if token_axes:
             sizes = jax.lax.psum(sizes, token_axes)
-        return out, sizes
+            # rows add up over the devices' tokens; the passes are the slowest device's
+            held = {name: (jax.lax.pmax if name == "moe_passes" else jax.lax.psum)(value, token_axes)
+                    for name, value in held.items()}
+        return out, sizes, held
 
     tok = P(batch or None, seq, None)
+    held_names = ("moe_rows_held", "moe_passes") if config.held_experts is not None else ()
     return jax.shard_map(
         shard, mesh=mesh,
-        in_specs=(tok, tok, (P(None, None, tp), P(None, None, tp), P(None, tp, None))),
-        out_specs=(tok, P()), check_vma=False,
-    )(h, probs, weights)
+        in_specs=(tok, tok, (P(None, None, tp), P(None, None, tp), P(None, tp, None)),
+                  *((tok,) if select is not None else ())),
+        out_specs=(tok, P(), {name: P() for name in held_names}), check_vma=False,
+    )(h, probs, weights, *((select,) if select is not None else ()))
+
+
+def moe_mlp(h: jax.Array, lp: Params, config: MoEConfig) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """The expert layer on normed activations (B, S, M): router, routed
+    experts (those held here) and the shared expert. -> (output, the layer's
+    scalars: `aux` (the load-balancing loss), `load` (rows a published expert;
+    `load_max_over_mean` of them is what is reported) and, where a part of the
+    experts is held, `moe_rows_held` and `moe_passes`)."""
+    c = config
+    with jax.named_scope("moe.route"):
+        # float32 in earnest: a TPU's default float32 matmul is one bfloat16
+        # pass, which rounds the router's weights and flips near-ties
+        router_logits = jnp.einsum(
+            "bsm,me->bse", h.astype(jnp.float32), lp["router"].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
+        )
+        if c.router_score == "softmax":
+            probs = jax.nn.softmax(router_logits, axis=-1)
+        elif c.router_score == "sigmoid":
+            probs = jax.nn.sigmoid(router_logits)
+        else:
+            raise ValueError(f"unknown router score: {c.router_score!r}")
+        select = None
+        if c.router_select_bias:
+            select = probs + jax.lax.stop_gradient(lp["expert_bias"].astype(jnp.float32))
+    weights = tuple(lp[name].astype(c.dtype) for name in ("we_gate", "we_up", "we_down"))
+    mesh = _mesh_of(lp["we_gate"])
+    if expert_parallel(mesh):
+        if select is not None or c.held_experts is not None or c.route_scale != 1.0:
+            raise NotImplementedError("the GShard form routes by the plain top-k of the scores")
+        out, load = _gshard_experts(h, probs, weights, c)
+        held = {}
+    else:
+        out, load, held = _dropless_experts(h, probs, weights, c, mesh, select)
+    if c.shared_expert_width:
+        with jax.named_scope("moe.shared"):
+            dt = c.dtype
+            out = out + jnp.einsum(
+                "bsf,fm->bsm",
+                swiglu(jnp.einsum("bsm,mf->bsf", h, lp["ws_gate"].astype(dt)),
+                       jnp.einsum("bsm,mf->bsf", h, lp["ws_up"].astype(dt))),
+                lp["ws_down"].astype(dt))
+    # E * sum_e f_e P_e: f_e the share of the (token, choice) pairs routed to
+    # e (it carries no gradient; under GShard, of those kept), P_e the mean
+    # router probability
+    share = load / (probs.shape[0] * probs.shape[1] * c.top_k)
+    aux = c.n_experts * jnp.sum(share * jnp.mean(probs, axis=(0, 1)))
+    return out, {"aux": aux, "load": load, **held}
+
+
+def load_max_over_mean(load: jax.Array) -> jax.Array:
+    """The largest expert's rows over the mean, of rows a published expert."""
+    return jnp.max(load) / jnp.maximum(jnp.mean(load), 1e-9)
 
 
 def moe_mlp_sublayer(
@@ -384,26 +600,8 @@ def moe_mlp_sublayer(
     expert's rows over the mean)."""
     c = config
     h = _norm(x, lp["ln2_scale"], lp.get("ln2_bias"), c.norm, c.norm_eps)
-    with jax.named_scope("moe.route"):
-        # float32 in earnest: a TPU's default float32 matmul is one bfloat16
-        # pass, which rounds the router's weights and flips near-ties
-        router_logits = jnp.einsum(
-            "bsm,me->bse", h.astype(jnp.float32), lp["router"].astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST,
-        )
-        probs = jax.nn.softmax(router_logits, axis=-1)
-    weights = tuple(lp[name].astype(c.dtype) for name in ("we_gate", "we_up", "we_down"))
-    mesh = _mesh_of(lp["we_gate"])
-    if expert_parallel(mesh):
-        out, load = _gshard_experts(h, probs, weights, c)
-    else:
-        out, load = _dropless_experts(h, probs, weights, c, mesh)
-    # E * sum_e f_e P_e: f_e the share of the (token, choice) pairs routed to
-    # e (it carries no gradient; under GShard, of those kept), P_e the mean
-    # router probability
-    share = load / (probs.shape[0] * probs.shape[1] * c.top_k)
-    aux = c.n_experts * jnp.sum(share * jnp.mean(probs, axis=(0, 1)))
-    return x + out, aux, jnp.max(load) / jnp.maximum(jnp.mean(load), 1e-9)
+    out, scalars = moe_mlp(h, lp, c)
+    return x + out, scalars["aux"], load_max_over_mean(scalars["load"])
 
 
 # -------------------------------------------------------------------- forward

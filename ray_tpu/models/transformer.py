@@ -71,6 +71,18 @@ class TransformerConfig:
     scan_unroll: int = 1  # lax.scan unroll for the layer stack
     qk_norm: bool = False  # RMSNorm over the whole q and k projections (OLMoE)
     norm_eps: Optional[float] = None  # None → 1e-6 (rmsnorm) / 1e-5 (layernorm)
+    # What follows is data of an architecture, each None / False for the
+    # families above: a head size that is not d_model / n_heads; the QK-norm
+    # taken over one head's features (one weight of head_dim for q, one for
+    # k) and not over the whole projection; a sigmoid gate on the attention
+    # output before its projection (`wg`, as wide as q); a norm on each
+    # sublayer's OUTPUT before it joins the residual stream, beside the one on
+    # its input ("sandwich"); the embedding scaled by sqrt(d_model).
+    d_head: Optional[int] = None
+    qk_norm_per_head: bool = False
+    attn_gate: bool = False
+    sandwich_norm: bool = False
+    scale_embedding: bool = False
 
     @property
     def kv_heads(self) -> int:
@@ -78,7 +90,7 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.d_head or self.d_model // self.n_heads
 
     def replace(self, **kw) -> "TransformerConfig":
         return dataclasses.replace(self, **kw)
@@ -216,8 +228,11 @@ def attention_sublayer(
     config: TransformerConfig,
     rope_tables: Optional[Tuple[jax.Array, jax.Array]],
     positions: Optional[jax.Array],
+    window: Optional[int] = None,
 ) -> jax.Array:
-    """Pre-norm causal self-attention + residual on (B, S, E)."""
+    """Pre-norm causal self-attention + residual on (B, S, E). What differs
+    between the layers of one stack comes as arguments: `rope_tables` (None:
+    this layer encodes no positions) and `window` (None: every causal key)."""
     c = config
     dt = c.dtype
     h = _norm(x, lp["ln1_scale"], lp.get("ln1_bias"), c.norm, c.norm_eps)
@@ -250,14 +265,23 @@ def attention_sublayer(
     if c.qk_norm:
         q = _qk_norm(q, lp["q_norm_scale"], c.norm_eps)
         k = _qk_norm(k, lp["k_norm_scale"], c.norm_eps)
+    if c.qk_norm_per_head:
+        kw = {} if c.norm_eps is None else {"eps": c.norm_eps}
+        q = rmsnorm(q, lp["q_norm_scale"], **kw)
+        k = rmsnorm(k, lp["k_norm_scale"], **kw)
     if rope_tables is not None:
         cos, sin = rope_tables
         q = apply_rope(q, cos, sin, positions)
         k = apply_rope(k, cos, sin, positions)
-    attn = flash_attention(q, k, v, causal=c.causal, implementation=c.attn_impl)
+    attn = flash_attention(q, k, v, causal=c.causal, window=window, implementation=c.attn_impl)
+    if c.attn_gate:
+        gate = jnp.einsum("bse,ehd->bhsd", h, lp["wg"].astype(dt))
+        attn = attn * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(dt)
     out = jnp.einsum("bhsd,hde->bse", attn, lp["wo"].astype(dt))
     if c.use_bias:
         out = out + lp["bo"].astype(dt)
+    if c.sandwich_norm:
+        out = _norm(out, lp["ln1_post_scale"], lp.get("ln1_post_bias"), c.norm, c.norm_eps)
     return checkpoint_name(x + out, "attn_residual")
 
 
@@ -278,6 +302,8 @@ def mlp_sublayer(x: jax.Array, lp: Params, config: TransformerConfig) -> jax.Arr
     down = jnp.einsum("bsf,fe->bse", act, lp["w_down"].astype(dt))
     if c.use_bias:
         down = down + lp["b_down"].astype(dt)
+    if c.sandwich_norm:
+        down = _norm(down, lp["ln2_post_scale"], lp.get("ln2_post_bias"), c.norm, c.norm_eps)
     return x + down
 
 

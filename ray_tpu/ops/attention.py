@@ -45,9 +45,11 @@ def mha_reference(
     causal: bool = False,
     sm_scale: Optional[float] = None,
     kv_len: Optional[int] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Pure-XLA multi-head attention. Ground truth for the Pallas kernels and
-    the CPU-backend fallback. Supports GQA and right-padding via `kv_len`."""
+    the CPU-backend fallback. Supports GQA and right-padding via `kv_len`.
+    `window` (causal only): key j is live for query i iff i - window < j <= i."""
     _, hq, sq, d = q.shape
     _, hkv, skv, _ = k.shape
     if sm_scale is None:
@@ -63,6 +65,8 @@ def mha_reference(
         mask = jnp.arange(skv)[None, :] < kv_len
     if causal:
         causal_mask = jnp.arange(skv)[None, :] <= jnp.arange(sq)[:, None] + (skv - sq)
+        if window is not None:
+            causal_mask &= jnp.arange(skv)[None, :] > jnp.arange(sq)[:, None] + (skv - sq) - window
         mask = causal_mask if mask is None else (mask & causal_mask)
     if mask is not None:
         logits = jnp.where(mask[None, None], logits, _NEG_INF)
@@ -99,6 +103,17 @@ def mha_reference(
 # small blocks) a grid tile is its own single sub-tile whose offsets are
 # traced scalars: live or not is then decided per grid step
 # (`_walk_strip`), which is the grid-level skip the kernels always had.
+#
+# A WINDOWED call (key j live for query i iff i - window < j <= i) has a
+# third edge, the window's lower one, and a grid of its own: its kv axis is
+# not the sequence's tiles but the BAND of square tiles one q tile's window
+# touches (`window_band`), the diagonal tile and those below it. A band tile
+# lies at a static offset from the diagonal whatever its place in the
+# sequence, and a pair is live by its row less its column alone, so the walk
+# engages inside every tile at any length (`_band_tiles`): a sub-tile
+# outside the window is skipped, one inside it and below the diagonal runs
+# unmasked, one that either edge crosses builds the mask; a tile outside the
+# band is never a grid step, so it is not copied either.
 
 # (sub_q, sub_kv) of the walk. One chip sweep over {128, 256, 512} a side
 # and kernel, at D = 64 and D = 128 (PERF.md section 6, PR 26): 256 x 256
@@ -109,9 +124,10 @@ _SUB_TILE = (256, 256)
 
 def _sub_tiles(block_q: int, block_kv: int, grid_tiles: int):
     """Sub-tile shape of the walk over a (block_q, block_kv) block, one of
-    `grid_tiles` a head. A block the sub-tile does not divide (it is
-    smaller, or an odd length) and a block of a larger grid are their own
-    single sub-tile."""
+    `grid_tiles` a head whose offsets are traced. A block the sub-tile does
+    not divide (it is smaller, or an odd length) and a block of a larger grid
+    are their own single sub-tile. (A windowed call's band of tiles has
+    static offsets against the diagonal, so it passes 1.)"""
     sub_q, sub_kv = _SUB_TILE
     if grid_tiles > 1:
         return block_q, block_kv
@@ -120,47 +136,110 @@ def _sub_tiles(block_q: int, block_kv: int, grid_tiles: int):
 
 
 def _kv_range(row0: int, col0: int, n: int, sub_q: int, sub_kv: int,
-              causal: bool, kv_len: int):
+              causal: bool, kv_len: Optional[int], window: Optional[int] = None):
     """For the queries [row0, row0 + sub_q) and the n kv sub-tiles
-    [col0 + c * sub_kv, + sub_kv): (full, live). Sub-tiles c < full hold
-    only live pairs, full <= c < live hold live and masked ones (the
-    diagonal or the kv_len edge crosses them), c >= live hold none."""
-    live = -((col0 - kv_len) // sub_kv)  # ceil
-    full = (kv_len - col0) // sub_kv
+    [col0 + c * sub_kv, + sub_kv): (first, full_lo, full_hi, live).
+    Sub-tiles full_lo <= c < full_hi hold only live pairs; first <= c <
+    full_lo (the window's lower edge crosses them) and full_hi <= c < live
+    (the diagonal or the kv_len edge does) hold live and masked ones; c <
+    first and c >= live hold none. `kv_len` None: no such edge (a windowed
+    call's offsets are relative, and causality implies it)."""
+    live = full = n
+    if kv_len is not None:
+        live = -((col0 - kv_len) // sub_kv)  # ceil
+        full = (kv_len - col0) // sub_kv
     if causal:
         live = min(live, (row0 + sub_q - 1 - col0) // sub_kv + 1)
         full = min(full, (row0 + 1 - col0) // sub_kv)
     live = max(0, min(live, n))
-    return max(0, min(full, live)), live
+    full = max(0, min(full, live))
+    first = full_lo = 0
+    if window is not None:
+        # key j is live iff j > i - window: some pair of the sub-tile, every pair
+        first = (row0 - window + 1 - col0) // sub_kv
+        full_lo = (row0 + sub_q - 1 - window - col0) // sub_kv + 1
+        first = max(0, min(first, live))
+        full_lo = max(first, min(full_lo, live))
+        full = max(full, full_lo)
+        if full_lo >= full:      # nothing whole: one masked run
+            full_lo = full = first
+    return first, full_lo, full, live
 
 
 def _q_range(row0: int, col0: int, n: int, sub_q: int, sub_kv: int,
-             causal: bool, kv_len: int):
+             causal: bool, kv_len: Optional[int], window: Optional[int] = None):
     """The same set seen from the keys [col0, col0 + sub_kv) over the n q
-    sub-blocks [row0 + a * sub_q, + sub_q): (first_live, first_full).
-    Sub-blocks a < first_live hold no live pair, first_live <= a <
-    first_full hold live and masked ones, a >= first_full only live."""
+    sub-blocks [row0 + a * sub_q, + sub_q): (first_live, first_full,
+    full_end, live_end). Sub-blocks first_full <= a < full_end hold only
+    live pairs; first_live <= a < first_full (the diagonal or the kv_len
+    edge) and full_end <= a < live_end (the window's edge) hold live and
+    masked ones; the others none."""
     first_live = (col0 - row0) // sub_q if causal else 0
     first_full = -((row0 - col0 - sub_kv + 1) // sub_q) if causal else 0
-    if col0 >= kv_len:  # beyond kv_len: never live
-        first_live = n
-    if col0 + sub_kv > kv_len:  # the edge crosses it: masked for every query
-        first_full = n
+    if kv_len is not None:
+        if col0 >= kv_len:  # beyond kv_len: never live
+            first_live = n
+        if col0 + sub_kv > kv_len:  # the edge crosses it: masked for every query
+            first_full = n
     first_live = max(0, min(first_live, n))
-    return first_live, max(first_live, min(first_full, n))
+    first_full = max(first_live, min(first_full, n))
+    full_end = live_end = n
+    if window is not None:
+        # query i sees key j iff i < j + window
+        live_end = -((row0 - (col0 + sub_kv - 1 + window)) // sub_q)
+        full_end = -((row0 - (col0 + window + 1)) // sub_q) - 1
+        live_end = max(first_live, min(live_end, n))
+        full_end = max(first_live, min(full_end, live_end))
+        first_full = min(first_full, live_end)
+        if first_full >= full_end:      # nothing whole: one masked run
+            first_full = full_end = live_end
+    return first_live, first_full, full_end, live_end
+
+
+def _kv_runs(*args):
+    """`_kv_range` as runs (lo, hi, is_masked) along the strip, the whole
+    sub-tiles first."""
+    first, full_lo, full_hi, live = _kv_range(*args)
+    return ((full_lo, full_hi, False), (first, full_lo, True), (full_hi, live, True))
+
+
+def _q_runs(*args):
+    first_live, first_full, full_end, live_end = _q_range(*args)
+    return ((first_full, full_end, False), (first_live, first_full, True),
+            (full_end, live_end, True))
+
+
+def window_band(window: int, block: int, tiles: int) -> int:
+    """Grid tiles of side `block` that one q tile's window touches (the
+    diagonal tile and those below it, back to the one the window's lower edge
+    crosses), at most `tiles`: the kv extent of a windowed call's grid."""
+    return min(tiles, -(-(window - 1) // block) + 1)
 
 
 def attention_subtiles(sq: int, skv: int, causal: bool, kv_len: int,
-                       block_q: int, block_kv: int, sub_q: int, sub_kv: int):
+                       block_q: int, block_kv: int, sub_q: int, sub_kv: int,
+                       window: Optional[int] = None):
     """(visited, masked, total) sub-tiles of one head's (sq, skv) score
     matrix as the kernels walk it: `visited` run their matmuls, `masked`
     of those build the mask, `total` is what a dense walk would visit.
     Counted with the kernels' own loop bounds."""
     visited = masked = 0
+    if window is not None:
+        # a band of tiles at static offsets from the diagonal (`_band_tiles`)
+        band = window_band(window, block_q, skv // block_kv)
+        for i in range(sq // block_q):
+            for d in range(min(band, i + 1)):
+                for a in range(block_q // sub_q):
+                    first, full_lo, full_hi, live = _kv_range(
+                        d * block_q + a * sub_q, 0, block_kv // sub_kv,
+                        sub_q, sub_kv, causal, None, window)
+                    visited += live - first
+                    masked += (live - first) - (full_hi - full_lo)
+        return visited, masked, (sq // sub_q) * (skv // sub_kv)
     for i in range(sq // block_q):
         for j in range(skv // block_kv):
             for a in range(block_q // sub_q):
-                full, live = _kv_range(
+                _, _, full, live = _kv_range(
                     i * block_q + a * sub_q, j * block_kv, block_kv // sub_kv,
                     sub_q, sub_kv, causal, kv_len)
                 visited += live
@@ -175,7 +254,7 @@ def _static(*xs) -> bool:
 
 
 def _walk_strip(row0, col0, n, sub_q, sub_kv, causal, kv_len, along, visit,
-                always):
+                always, window=None):
     """`visit(pieces)` for the live sub-tiles of one strip, as runs (lo, hi,
     is_masked) of sub-tile indices along the strip: the n kv sub-tiles
     from col0 against the queries [row0, row0 + sub_q) (`along` "kv"), or
@@ -183,23 +262,19 @@ def _walk_strip(row0, col0, n, sub_q, sub_kv, causal, kv_len, along, visit,
     (`along` "q"). No live sub-tile: no call, unless `always` (the
     strip's output is then the visitor's to write).
 
-    Static offsets (one grid tile a head): one call with the non-empty
-    runs. Traced offsets (the strip is the one sub-tile of a grid tile):
-    live or not is a comparison per grid step, and a live tile is computed
-    masked, as every grid tile was before the walk."""
+    Static offsets (one grid tile a head, or a tile of a windowed call's
+    band): one call with the non-empty runs. Traced offsets (the strip is
+    the one sub-tile of a grid tile): live or not is a comparison per grid
+    step, and a live tile is computed masked, as every grid tile was before
+    the walk."""
     if _static(row0, col0):
-        if along == "kv":
-            full, live = _kv_range(row0, col0, n, sub_q, sub_kv, causal, kv_len)
-            runs = ((0, full, False), (full, live, True))
-        else:
-            first_live, first_full = _q_range(
-                row0, col0, n, sub_q, sub_kv, causal, kv_len)
-            runs = ((first_full, n, False), (first_live, first_full, True))
+        runs = (_kv_runs if along == "kv" else _q_runs)(
+            row0, col0, n, sub_q, sub_kv, causal, kv_len, window)
         pieces = [run for run in runs if run[1] > run[0]]
         if pieces or always:
             visit(pieces)
         return
-    assert n == 1, "a strip with traced offsets is a single sub-tile"
+    assert n == 1 and window is None, "a strip with traced offsets is a single sub-tile"
     live = col0 < kv_len
     if causal:
         live = live & (col0 <= row0 + sub_q - 1)
@@ -216,7 +291,29 @@ def _grid_tile(num_q_blocks, num_kv_blocks, q_axis, kv_axis):
     return i, j
 
 
-def _scores(q, k, scale, mask_at, causal, kv_len):
+def _band_tiles(band, step, tile, tiles, toward_diagonal, strips):
+    """A windowed call's grid step: `strips(d, 0)` for the one tile of the
+    band this step holds, as the q tile d and kv tile 0 of a head, its STATIC
+    offset from the diagonal (a pair is live by its row less its column
+    alone), so that the sub-tile walk engages whatever the length. `tile` is the fixed
+    side's tile (a q tile for the forward and dQ, whose band runs
+    `toward_diagonal` over kv tiles tile - band + 1 .. tile; a kv tile for
+    dK/dV, whose band runs away from it over q tiles tile .. tile + band -
+    1), `step` the band's grid axis. A step whose tile falls off the
+    sequence (before its start, or after the last of `tiles`) does nothing;
+    its index map repeats a neighbour's block, so nothing is copied either."""
+    for t in range(band):
+        d = band - 1 - t if toward_diagonal else t    # tiles below the diagonal
+        inside = tile >= d if toward_diagonal else tile + d <= tiles - 1
+        here = inside if band == 1 else inside & (step == t)
+        run = functools.partial(strips, d, 0)
+        if here is True:
+            run()
+        elif here is not False:
+            pl.when(here)(run)
+
+
+def _scores(q, k, scale, mask_at, causal, kv_len, window=None):
     """QK^T of one piece in the base-2 log domain. `mask_at` is None for
     a piece of live pairs only, else its (row0, col0)."""
     s = jax.lax.dot_general(
@@ -226,10 +323,13 @@ def _scores(q, k, scale, mask_at, causal, kv_len):
         return s
     row0, col0 = mask_at
     col = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    mask = col < kv_len
+    mask = None if kv_len is None else col < kv_len
     if causal:
         row = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        mask = mask & (col <= row)
+        upper = col <= row
+        mask = upper if mask is None else mask & upper
+        if window is not None:
+            mask = mask & (col > row - window)
     return jnp.where(mask, s, _NEG_INF)
 
 
@@ -286,15 +386,20 @@ def _fwd_kernel(
     block_kv: int,
     sub_q: int,
     sub_kv: int,
-    kv_len: int,
+    kv_len: Optional[int],
     num_q_blocks: int,
     num_kv_blocks: int,
+    window: Optional[int] = None,
+    band: int = 0,
 ):
-    i, j = _grid_tile(num_q_blocks, num_kv_blocks, 2, 3)
+    # `band` > 0 (a windowed call): the kv grid axis is the band's, not the
+    # sequence's
+    kv_steps = band or num_kv_blocks
+    i, j = _grid_tile(num_q_blocks, kv_steps, 2, 3)
     n = block_kv // sub_kv
     # one kv block a row: a strip's softmax is whole and goes straight to
     # the output. More: (m, l, acc) ride in scratch across the kv axis.
-    carried = num_kv_blocks > 1
+    carried = kv_steps > 1
     if carried:
         m_scr, l_scr, acc_scr = scratch
 
@@ -308,49 +413,67 @@ def _fwd_kernel(
     # per strip of queries: one softmax step over its live kv sub-tiles.
     # Causal: a strip (with a grid of tiles, the whole tile) above the
     # diagonal has none.
-    for a in range(block_q // sub_q):
-        row0 = i * block_q + a * sub_q
-        rows = pl.ds(a * sub_q, sub_q)
+    def strips(i, j):
+        for a in range(block_q // sub_q):
+            row0 = i * block_q + a * sub_q
+            rows = pl.ds(a * sub_q, sub_q)
 
-        def visit(pieces, row0=row0, rows=rows):
-            if not pieces:  # nothing live and nothing carried: kv_len == 0
-                o_ref[0, 0, rows, :] = jnp.zeros((sub_q, o_ref.shape[-1]), o_ref.dtype)
-                lse_ref[0, 0, rows, :] = jnp.full((sub_q, 1), _NEG_INF, jnp.float32)
-                return
-            q = q_ref[0, 0, rows, :]
-            scores, values = [], []
-            for lo, hi, masked in pieces:
-                cols = pl.ds(lo * sub_kv, (hi - lo) * sub_kv)
-                scores.append(_scores(
-                    q, k_ref[0, 0, cols, :], sm_scale * _LOG2E,
-                    (row0, j * block_kv + lo * sub_kv) if masked else None,
-                    causal, kv_len))
-                values.append(v_ref[0, 0, cols, :])
-            if not carried:
-                _write_out(o_ref, lse_ref, rows, *_softmax_pieces(scores, values))
-                return
-            m, l, acc = _softmax_pieces(
-                scores, values,
-                (m_scr[rows, :1], l_scr[rows, :1], lambda: acc_scr[rows, :]))
-            acc_scr[rows, :] = acc
-            m_scr[rows, :] = jnp.broadcast_to(m, (sub_q, m_scr.shape[1]))
-            l_scr[rows, :] = jnp.broadcast_to(l, (sub_q, l_scr.shape[1]))
+            def visit(pieces, row0=row0, rows=rows):
+                if not pieces:  # nothing live and nothing carried: kv_len == 0
+                    o_ref[0, 0, rows, :] = jnp.zeros((sub_q, o_ref.shape[-1]), o_ref.dtype)
+                    lse_ref[0, 0, rows, :] = jnp.full((sub_q, 1), _NEG_INF, jnp.float32)
+                    return
+                q = q_ref[0, 0, rows, :]
+                scores, values = [], []
+                for lo, hi, masked in pieces:
+                    cols = pl.ds(lo * sub_kv, (hi - lo) * sub_kv)
+                    scores.append(_scores(
+                        q, k_ref[0, 0, cols, :], sm_scale * _LOG2E,
+                        (row0, j * block_kv + lo * sub_kv) if masked else None,
+                        causal, kv_len, window))
+                    values.append(v_ref[0, 0, cols, :])
+                if not carried:
+                    _write_out(o_ref, lse_ref, rows, *_softmax_pieces(scores, values))
+                    return
+                m, l, acc = _softmax_pieces(
+                    scores, values,
+                    (m_scr[rows, :1], l_scr[rows, :1], lambda: acc_scr[rows, :]))
+                acc_scr[rows, :] = acc
+                m_scr[rows, :] = jnp.broadcast_to(m, (sub_q, m_scr.shape[1]))
+                l_scr[rows, :] = jnp.broadcast_to(l, (sub_q, l_scr.shape[1]))
 
-        _walk_strip(row0, j * block_kv, n, sub_q, sub_kv, causal, kv_len, "kv",
-                    visit, always=not carried)
+            _walk_strip(row0, j * block_kv, n, sub_q, sub_kv, causal, kv_len, "kv",
+                        visit, always=not carried, window=window)
+
+    if band:
+        _band_tiles(band, j, i, num_q_blocks, True, strips)
+    else:
+        strips(i, j)
 
     if carried:
-        pl.when(j == num_kv_blocks - 1)(lambda: _write_out(
+        pl.when(j == kv_steps - 1)(lambda: _write_out(
             o_ref, lse_ref, slice(None), m_scr[:, :1], l_scr[:, :1], acc_scr[...]))
 
 
-def _fwd_pallas(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret):
+def _band_index(band: int, toward_diagonal: bool, tiles: int):
+    """The moving side's tile of a band step, as an index map computes it:
+    kv tile i - band + 1 + t of q tile i (never before the first), or q tile
+    j + t of kv tile j (never after the last). A step past the sequence
+    repeats its neighbour's block."""
+    if toward_diagonal:
+        return lambda i, t: jnp.maximum(i - (band - 1) + t, 0)
+    return lambda j, t: jnp.minimum(j + t, tiles - 1)
+
+
+def _fwd_pallas(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret, window=None):
     b, hq, sq, d = q.shape
     _, hkv, skv, _ = k.shape
     groups = hq // hkv
     nq = sq // block_q
     nk = skv // block_kv
-    sub_q, sub_kv = _sub_tiles(block_q, block_kv, nq * nk)
+    band = window_band(window, block_q, nk) if window is not None else 0
+    sub_q, sub_kv = _sub_tiles(block_q, block_kv, 1 if band else nq * nk)
+    kv_tile = _band_index(band, True, nk) if band else (lambda i, j: j)
 
     kernel = functools.partial(
         _fwd_kernel,
@@ -360,20 +483,24 @@ def _fwd_pallas(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret)
         block_kv=block_kv,
         sub_q=sub_q,
         sub_kv=sub_kv,
-        kv_len=kv_len,
+        kv_len=None if band else kv_len,
         num_q_blocks=nq,
         num_kv_blocks=nk,
+        **({"window": window, "band": band} if band else {}),
     )
+    kv_steps = band or nk
     out, lse = pl.pallas_call(
         kernel,
-        grid=(b, hq, nq, nk),
+        grid=(b, hq, nq, kv_steps),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda b_, h, i, j: (b_, h, i, 0)),
             pl.BlockSpec(
-                (1, 1, block_kv, d), lambda b_, h, i, j, g=groups: (b_, h // g, j, 0)
+                (1, 1, block_kv, d),
+                lambda b_, h, i, j, g=groups: (b_, h // g, kv_tile(i, j), 0)
             ),
             pl.BlockSpec(
-                (1, 1, block_kv, d), lambda b_, h, i, j, g=groups: (b_, h // g, j, 0)
+                (1, 1, block_kv, d),
+                lambda b_, h, i, j, g=groups: (b_, h // g, kv_tile(i, j), 0)
             ),
         ],
         out_specs=[
@@ -388,9 +515,9 @@ def _fwd_pallas(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret)
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
-        ] if nk > 1 else [],
+        ] if kv_steps > 1 else [],
         interpret=interpret,
-        name="flash_fwd",
+        name="flash_win_fwd" if band else "flash_fwd",
     )(q, k, v)
     return out, lse
 
@@ -407,9 +534,9 @@ def _fwd_pallas(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret)
 # diagonal down, dq per q sub-block over the kv sub-tiles up to it.
 
 
-def _probs(q, k, lse, sm_scale, mask_at, causal, kv_len):
+def _probs(q, k, lse, sm_scale, mask_at, causal, kv_len, window=None):
     """One piece's probabilities, rebuilt from the forward's lse."""
-    s = _scores(q, k, sm_scale * _LOG2E, mask_at, causal, kv_len)
+    s = _scores(q, k, sm_scale * _LOG2E, mask_at, causal, kv_len, window)
     return jnp.exp2(s - lse * _LOG2E)
 
 
@@ -439,12 +566,14 @@ def _dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dk_ref, dv_ref, *scratch,
     sm_scale, causal, block_q, block_kv, sub_q, sub_kv, kv_len,
-    num_q_blocks, num_kv_blocks,
+    num_q_blocks, num_kv_blocks, window=None, band=0,
 ):
-    # grid: kv block outer (axis 2), q block inner (axis 3)
-    i, j = _grid_tile(num_q_blocks, num_kv_blocks, 3, 2)
+    # grid: kv block outer (axis 2), q block inner (axis 3): the sequence's q
+    # blocks, or a windowed call's band of them
+    q_steps = band or num_q_blocks
+    i, j = _grid_tile(q_steps, num_kv_blocks, 3, 2)
     n = block_q // sub_q
-    carried = num_q_blocks > 1  # dK_j, dV_j summed over q blocks in scratch
+    carried = q_steps > 1  # dK_j, dV_j summed over q blocks in scratch
     dk_scr, dv_scr = scratch if carried else (None, None)
     if carried:
         def _init():
@@ -453,59 +582,67 @@ def _dkv_kernel(
 
         pl.when(i == 0)(_init)
 
-    for c in range(block_kv // sub_kv):
-        col0 = j * block_kv + c * sub_kv
-        cols = pl.ds(c * sub_kv, sub_kv)
+    def strips(i, j):
+        for c in range(block_kv // sub_kv):
+            col0 = j * block_kv + c * sub_kv
+            cols = pl.ds(c * sub_kv, sub_kv)
 
-        def visit(pieces, col0=col0, cols=cols):
-            k = k_ref[0, 0, cols, :]
-            v = v_ref[0, 0, cols, :]
-            dk = dv = None
-            for lo, hi, masked in pieces:
-                rows = pl.ds(lo * sub_q, (hi - lo) * sub_q)
-                q = q_ref[0, 0, rows, :]
-                do = do_ref[0, 0, rows, :]
-                p = _probs(
-                    q, k, lse_ref[0, 0, rows, :], sm_scale,
-                    (i * block_q + lo * sub_q, col0) if masked else None,
-                    causal, kv_len)
-                # dV_j += P^T dO
-                dv_cur = jax.lax.dot_general(
-                    p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-                ds = _ds(p, do, v, delta_ref[0, 0, rows, :], sm_scale)
-                # dK_j += dS^T Q
-                dk_cur = jax.lax.dot_general(
-                    ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-                dv = dv_cur if dv is None else dv + dv_cur
-                dk = dk_cur if dk is None else dk + dk_cur
-            _accumulate(dv_ref, dv_scr, carried, cols, dv)
-            _accumulate(dk_ref, dk_scr, carried, cols, dk)
+            def visit(pieces, col0=col0, cols=cols):
+                k = k_ref[0, 0, cols, :]
+                v = v_ref[0, 0, cols, :]
+                dk = dv = None
+                for lo, hi, masked in pieces:
+                    rows = pl.ds(lo * sub_q, (hi - lo) * sub_q)
+                    q = q_ref[0, 0, rows, :]
+                    do = do_ref[0, 0, rows, :]
+                    p = _probs(
+                        q, k, lse_ref[0, 0, rows, :], sm_scale,
+                        (i * block_q + lo * sub_q, col0) if masked else None,
+                        causal, kv_len, window)
+                    # dV_j += P^T dO
+                    dv_cur = jax.lax.dot_general(
+                        p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32,
+                    )
+                    ds = _ds(p, do, v, delta_ref[0, 0, rows, :], sm_scale)
+                    # dK_j += dS^T Q
+                    dk_cur = jax.lax.dot_general(
+                        ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32,
+                    )
+                    dv = dv_cur if dv is None else dv + dv_cur
+                    dk = dk_cur if dk is None else dk + dk_cur
+                _accumulate(dv_ref, dv_scr, carried, cols, dv)
+                _accumulate(dk_ref, dk_scr, carried, cols, dk)
 
-        _walk_strip(i * block_q, col0, n, sub_q, sub_kv, causal, kv_len, "q",
-                    visit, always=not carried)
+            _walk_strip(i * block_q, col0, n, sub_q, sub_kv, causal, kv_len, "q",
+                        visit, always=not carried, window=window)
+
+    if band:
+        # from the diagonal tile down: q tile j + t of kv tile j
+        _band_tiles(band, i, j, num_q_blocks, False, strips)
+    else:
+        strips(i, j)
 
     if carried:
         def _finalize():
             dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
             dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
 
-        pl.when(i == num_q_blocks - 1)(_finalize)
+        pl.when(i == q_steps - 1)(_finalize)
 
 
 def _dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dq_ref, *scratch,
     sm_scale, causal, block_q, block_kv, sub_q, sub_kv, kv_len,
-    num_q_blocks, num_kv_blocks,
+    num_q_blocks, num_kv_blocks, window=None, band=0,
 ):
     # grid: q block outer (axis 2), kv block inner (axis 3)
-    i, j = _grid_tile(num_q_blocks, num_kv_blocks, 2, 3)
+    kv_steps = band or num_kv_blocks
+    i, j = _grid_tile(num_q_blocks, kv_steps, 2, 3)
     n = block_kv // sub_kv
-    carried = num_kv_blocks > 1  # dQ_i summed over kv blocks in scratch
+    carried = kv_steps > 1  # dQ_i summed over kv blocks in scratch
     (dq_scr,) = scratch if carried else (None,)
     if carried:
         def _init():
@@ -513,42 +650,49 @@ def _dq_kernel(
 
         pl.when(j == 0)(_init)
 
-    for a in range(block_q // sub_q):
-        row0 = i * block_q + a * sub_q
-        rows = pl.ds(a * sub_q, sub_q)
+    def strips(i, j):
+        for a in range(block_q // sub_q):
+            row0 = i * block_q + a * sub_q
+            rows = pl.ds(a * sub_q, sub_q)
 
-        def visit(pieces, row0=row0, rows=rows):
-            q = q_ref[0, 0, rows, :]
-            do = do_ref[0, 0, rows, :]
-            lse = lse_ref[0, 0, rows, :]  # (sub_q, 1)
-            delta = delta_ref[0, 0, rows, :]
-            dq = None
-            for lo, hi, masked in pieces:
-                cols = pl.ds(lo * sub_kv, (hi - lo) * sub_kv)
-                k = k_ref[0, 0, cols, :]
-                p = _probs(
-                    q, k, lse, sm_scale,
-                    (row0, j * block_kv + lo * sub_kv) if masked else None,
-                    causal, kv_len)
-                ds = _ds(p, do, v_ref[0, 0, cols, :], delta, sm_scale)
-                dq_cur = jax.lax.dot_general(
-                    ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-                dq = dq_cur if dq is None else dq + dq_cur
-            _accumulate(dq_ref, dq_scr, carried, rows, dq)
+            def visit(pieces, row0=row0, rows=rows):
+                q = q_ref[0, 0, rows, :]
+                do = do_ref[0, 0, rows, :]
+                lse = lse_ref[0, 0, rows, :]  # (sub_q, 1)
+                delta = delta_ref[0, 0, rows, :]
+                dq = None
+                for lo, hi, masked in pieces:
+                    cols = pl.ds(lo * sub_kv, (hi - lo) * sub_kv)
+                    k = k_ref[0, 0, cols, :]
+                    p = _probs(
+                        q, k, lse, sm_scale,
+                        (row0, j * block_kv + lo * sub_kv) if masked else None,
+                        causal, kv_len, window)
+                    ds = _ds(p, do, v_ref[0, 0, cols, :], delta, sm_scale)
+                    dq_cur = jax.lax.dot_general(
+                        ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32,
+                    )
+                    dq = dq_cur if dq is None else dq + dq_cur
+                _accumulate(dq_ref, dq_scr, carried, rows, dq)
 
-        _walk_strip(row0, j * block_kv, n, sub_q, sub_kv, causal, kv_len, "kv",
-                    visit, always=not carried)
+            _walk_strip(row0, j * block_kv, n, sub_q, sub_kv, causal, kv_len, "kv",
+                        visit, always=not carried, window=window)
+
+    if band:
+        _band_tiles(band, j, i, num_q_blocks, True, strips)
+    else:
+        strips(i, j)
 
     if carried:
         def _finalize():
             dq_ref[0, 0] = dq_scr[...].astype(dq_ref.dtype)
 
-        pl.when(j == num_kv_blocks - 1)(_finalize)
+        pl.when(j == kv_steps - 1)(_finalize)
 
 
-def _bwd_pallas(q, k, v, out, lse, do, causal, sm_scale, block_q, block_kv, kv_len, interpret):
+def _bwd_pallas(q, k, v, out, lse, do, causal, sm_scale, block_q, block_kv, kv_len, interpret,
+                window=None):
     b, h, sq, d = q.shape
     skv = k.shape[2]
     nq = sq // block_q
@@ -559,22 +703,26 @@ def _bwd_pallas(q, k, v, out, lse, do, causal, sm_scale, block_q, block_kv, kv_l
         do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1, keepdims=True
     )
 
-    sub_q, sub_kv = _sub_tiles(block_q, block_kv, nq * nk)
+    band = window_band(window, block_q, nk) if window is not None else 0
+    sub_q, sub_kv = _sub_tiles(block_q, block_kv, 1 if band else nq * nk)
+    q_tile = _band_index(band, False, nq) if band else (lambda j, i: i)
+    kv_tile = _band_index(band, True, nk) if band else (lambda i, j: j)
 
     def kernel(fn):
         return functools.partial(
             fn, sm_scale=sm_scale, causal=causal, block_q=block_q,
-            block_kv=block_kv, sub_q=sub_q, sub_kv=sub_kv, kv_len=kv_len,
+            block_kv=block_kv, sub_q=sub_q, sub_kv=sub_kv, kv_len=None if band else kv_len,
             num_q_blocks=nq, num_kv_blocks=nk,
+            **({"window": window, "band": band} if band else {}),
         )
 
-    q_spec = pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, j, i: (b_, h_, i, 0))
+    q_spec = pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, j, i: (b_, h_, q_tile(j, i), 0))
     kv_spec = pl.BlockSpec((1, 1, block_kv, d), lambda b_, h_, j, i: (b_, h_, j, 0))
-    row_spec = pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, j, i: (b_, h_, i, 0))
+    row_spec = pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, j, i: (b_, h_, q_tile(j, i), 0))
 
     dk, dv = pl.pallas_call(
         kernel(_dkv_kernel),
-        grid=(b, h, nk, nq),
+        grid=(b, h, nk, band or nq),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
         out_specs=[kv_spec, kv_spec],
         out_shape=[
@@ -584,24 +732,24 @@ def _bwd_pallas(q, k, v, out, lse, do, causal, sm_scale, block_q, block_kv, kv_l
         scratch_shapes=[
             pltpu.VMEM((block_kv, d), jnp.float32),
             pltpu.VMEM((block_kv, d), jnp.float32),
-        ] if nq > 1 else [],
+        ] if (band or nq) > 1 else [],
         interpret=interpret,
-        name="flash_bwd_dkv",
+        name="flash_win_bwd_dkv" if band else "flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
 
     q_spec2 = pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, i, j: (b_, h_, i, 0))
-    kv_spec2 = pl.BlockSpec((1, 1, block_kv, d), lambda b_, h_, i, j: (b_, h_, j, 0))
+    kv_spec2 = pl.BlockSpec((1, 1, block_kv, d), lambda b_, h_, i, j: (b_, h_, kv_tile(i, j), 0))
     row_spec2 = pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, i, j: (b_, h_, i, 0))
 
     dq = pl.pallas_call(
         kernel(_dq_kernel),
-        grid=(b, h, nq, nk),
+        grid=(b, h, nq, band or nk),
         in_specs=[q_spec2, kv_spec2, kv_spec2, q_spec2, row_spec2, row_spec2],
         out_specs=q_spec2,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)] if nk > 1 else [],
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)] if (band or nk) > 1 else [],
         interpret=interpret,
-        name="flash_bwd_dq",
+        name="flash_win_bwd_dq" if band else "flash_bwd_dq",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -609,18 +757,18 @@ def _bwd_pallas(q, k, v, out, lse, do, causal, sm_scale, block_q, block_kv, kv_l
 # ----------------------------------------------------------- custom_vjp plumbing
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret):
-    out, _ = _fwd_pallas(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret, window=None):
+    out, _ = _fwd_pallas(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret, window)
     return out
 
 
-def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret):
-    out, lse = _fwd_pallas(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret)
+def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret, window=None):
+    out, lse = _fwd_pallas(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret, window)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, sm_scale, block_q, block_kv, kv_len, interpret, res, do):
+def _flash_bwd(causal, sm_scale, block_q, block_kv, kv_len, interpret, window, res, do):
     q, k, v, out, lse = res
     hq, hkv = q.shape[1], k.shape[1]
     if hq != hkv:
@@ -632,7 +780,7 @@ def _flash_bwd(causal, sm_scale, block_q, block_kv, kv_len, interpret, res, do):
         k_full, v_full = k, v
     dq, dk, dv = _bwd_pallas(
         q, k_full, v_full, out, lse, do, causal, sm_scale, block_q, block_kv,
-        kv_len, interpret,
+        kv_len, interpret, window,
     )
     if groups > 1:
         b, _, skv, d = dk.shape
@@ -650,9 +798,24 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 _IMPLEMENTATIONS = ("xla", "pallas")
 
 
-def _blocks(sq: int, skv: int, block_q: Optional[int], block_kv: Optional[int]):
+# Side of a windowed call's square grid tiles. One chip sweep at S = 8,192,
+# window 2,048, 32 query heads over 4 key-value heads of 128 (PERF.md section
+# 6, PR 33) chose it; with the band's static offsets the sub-tile walk runs
+# inside every tile, so the side only trades grid steps against VMEM.
+_WINDOW_BLOCK = 1024
+
+
+def _blocks(sq: int, skv: int, block_q: Optional[int], block_kv: Optional[int],
+            window: Optional[int] = None):
     """(block_q, block_kv) of the Pallas kernels: the whole sequence up to
-    1,024 a side, resident in VMEM for the sub-tile walk."""
+    1,024 a side, resident in VMEM for the sub-tile walk. A windowed call's
+    tiles are square (its band of tiles then lies at static offsets from the
+    diagonal)."""
+    if window is not None:
+        if block_q != block_kv:
+            raise ValueError("a windowed call takes square blocks: block_q == block_kv")
+        side = min(block_q or _WINDOW_BLOCK, max(sq, 1))
+        return side, side
     return min(block_q or 1024, max(sq, 1)), min(block_kv or 1024, max(skv, 1))
 
 
@@ -675,7 +838,8 @@ def resolve_attention_impl(implementation: Optional[str] = None) -> str:
 
 
 def attention_plan(seq: int, *, causal: bool = True,
-                   implementation: Optional[str] = None) -> dict:
+                   implementation: Optional[str] = None,
+                   window: Optional[int] = None) -> dict:
     """What `flash_attention` runs for one head of a (seq, seq)
     self-attention: the resolved implementation and how far the kernels'
     sub-tile walk engages (no sub-tiles for "xla").
@@ -684,11 +848,12 @@ def attention_plan(seq: int, *, causal: bool = True,
     impl = resolve_attention_impl(implementation)
     visited = masked = total = 0
     if impl != "xla":
-        bq, bkv = _blocks(seq, seq, None, None)
+        bq, bkv = _blocks(seq, seq, None, None, window)
         padded_q, padded_kv = seq + (-seq) % bq, seq + (-seq) % bkv
+        grid_tiles = 1 if window is not None else (padded_q // bq) * (padded_kv // bkv)
         visited, masked, total = attention_subtiles(
             padded_q, padded_kv, causal, seq, bq, bkv,
-            *_sub_tiles(bq, bkv, (padded_q // bq) * (padded_kv // bkv)))
+            *_sub_tiles(bq, bkv, grid_tiles), window)
     return {
         "attention_impl": impl,
         "attn_subtiles_visited": visited,
@@ -737,6 +902,7 @@ def flash_attention(
     v: jax.Array,
     *,
     causal: bool = False,
+    window: Optional[int] = None,
     sm_scale: Optional[float] = None,
     block_q: Optional[int] = None,
     block_kv: Optional[int] = None,
@@ -755,20 +921,31 @@ def flash_attention(
     sub-tiles and skip the dead ones (the comment above `_SUB_TILE`; the
     chip sweep is in PERF.md section 6, PR 26). Explicit `block_q` /
     `block_kv` make a grid of smaller blocks, each its own sub-tile.
+
+    window (causal self-attention only): key j is live for query i iff
+    i - window < j <= i. The same kernels under the names `flash_win_*`:
+    the kv axis of the grid is then the BAND of tiles a q tile's window
+    touches (`window_band`; 3 of 8 at S = 8,192, window 2,048), so a tile
+    outside the window is neither computed nor copied, and since a band
+    tile lies at a static offset from the diagonal the sub-tile walk runs
+    inside each: a sub-tile outside the window is skipped, one inside runs
+    unmasked, one the diagonal or the window's edge crosses builds the mask.
     """
     sq, skv = q.shape[2], k.shape[2]
     implementation = resolve_attention_impl(implementation)
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
+    if window is not None and not (causal and sq == skv and window >= 1):
+        raise ValueError("a window needs causal self-attention (Sq == Skv) and window >= 1")
     if implementation == "xla":
-        return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale)
+        return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale, window=window)
     if causal and sq != skv:
         raise NotImplementedError("causal flash kernel requires Sq == Skv")
     interpret = jax.default_backend() != "tpu"
-    bq, bkv = _blocks(sq, skv, block_q, block_kv)
+    bq, bkv = _blocks(sq, skv, block_q, block_kv, window)
     out = _per_shard(
         lambda q_, k_, v_: _flash(
-            q_, k_, v_, causal, sm_scale, bq, bkv, skv, interpret
+            q_, k_, v_, causal, sm_scale, bq, bkv, skv, interpret, window
         )
     )(_pad_seq(q, 2, bq), _pad_seq(k, 2, bkv), _pad_seq(v, 2, bkv))
     if out.shape[2] != sq:

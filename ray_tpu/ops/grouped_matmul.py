@@ -60,7 +60,13 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array, *,
                    interpret: bool = False) -> jax.Array:
     """lhs (P, K) sorted by group, rhs (G, K, N), group_sizes (G,) int32
     -> (P, N) in lhs's dtype. For "pallas" every size is a positive
-    multiple of `tile_rows` and P is one too."""
+    multiple of `tile_rows` and P is one too.
+
+    A row tile after the last group is never computed, and its copy is not
+    made either (its block index repeats the last used tile's), so a buffer
+    sized for more rows than it holds (a layer that holds a part of the
+    experts, models/moe.py) costs what its rows cost: 0.45 ms a call of
+    36,864 empty slots otherwise (PERF.md section 6, PR 33)."""
     if resolve_gmm_impl(implementation) == "xla":
         return jax.lax.ragged_dot(lhs, rhs, group_sizes.astype(jnp.int32))
     if lhs.shape[0] % tile_rows:
@@ -125,6 +131,12 @@ def _params():
         dimension_semantics=("parallel", "arbitrary"), vmem_limit_bytes=_VMEM_LIMIT)
 
 
+def _row_tile(i, used):
+    """The row tile a grid step reads: its own, or the last used one for the
+    steps after it, whose copy is then skipped."""
+    return jnp.minimum(i, jnp.maximum(used[0] - 1, 0))
+
+
 def _gmm_call(lhs, rhs, tile_group, tiles_used, tile_rows, interpret, transpose_rhs):
     rows, k = lhs.shape
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
@@ -138,7 +150,8 @@ def _gmm_call(lhs, rhs, tile_group, tiles_used, tile_rows, interpret, transpose_
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(n // tn, rows // tile_rows),
-            in_specs=[pl.BlockSpec((tile_rows, k), lambda j, i, tg, used: (i, 0)), rhs_spec],
+            in_specs=[pl.BlockSpec((tile_rows, k), lambda j, i, tg, used: (_row_tile(i, used), 0)),
+                      rhs_spec],
             out_specs=pl.BlockSpec((tile_rows, tn), lambda j, i, tg, used: (i, j)),
         ),
         out_shape=jax.ShapeDtypeStruct((rows, n), lhs.dtype),
@@ -158,8 +171,8 @@ def _drhs_call(lhs, dout, tile_group, tiles_used, groups, tile_rows, interpret, 
             num_scalar_prefetch=2,
             grid=(n // tn, rows // tile_rows),
             in_specs=[
-                pl.BlockSpec((tile_rows, k), lambda j, i, tg, used: (i, 0)),
-                pl.BlockSpec((tile_rows, tn), lambda j, i, tg, used: (i, j)),
+                pl.BlockSpec((tile_rows, k), lambda j, i, tg, used: (_row_tile(i, used), 0)),
+                pl.BlockSpec((tile_rows, tn), lambda j, i, tg, used: (_row_tile(i, used), j)),
             ],
             out_specs=pl.BlockSpec((1, k, tn), lambda j, i, tg, used: (tg[i], 0, j)),
             scratch_shapes=[pltpu.VMEM((k, tn), jnp.float32)],

@@ -257,7 +257,7 @@ def lm_loss(
         logits = jnp.einsum("bse,ev->bsv", hidden, head)
         loss, ntok = cross_entropy_loss(logits, targets, z_loss_coeff=z_loss_coeff)
     objective = loss
-    if routers:
+    if "router_aux_loss" in routers:
         objective = loss + config.router_aux_coeff * routers["router_aux_loss"]
     return objective, {"loss": loss, "num_tokens": ntok, **routers}
 

@@ -145,7 +145,7 @@ class LMTrainer:
         plan = attention_plan(
             seq, causal=self.config.causal, implementation=self.config.attn_impl)
         with jax.sharding.use_abstract_mesh(self.mesh.abstract_mesh):  # as the step is traced
-            plan.update(model_family(self.config).plan(self.config, batch * seq))
+            plan.update(model_family(self.config).plan(self.config, batch, seq))
         plan["loss_chunk"] = self.step_fn.loss_chunk_for(tokens_shape, self.state)
         plan.update(self.step_fn.remat_plan_for(tokens_shape, self.state))
         for key, value in plan.items():

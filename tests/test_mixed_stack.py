@@ -1,0 +1,337 @@
+"""The stack of more than one kind of layer (models/mixed_stack.py) and what
+it asks of the parts it is built from: the runs, the block against the plain
+reference of the family that runs it (benchmark/reference/afmoe_ref.py), the
+expert layer told which experts it holds, the router's forms."""
+
+import dataclasses
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models import MixedStackConfig, model_family, moe
+from ray_tpu.models.mixed_stack import LayerKind, Run, layer_kinds, stack_runs
+from ray_tpu.models.transformer import lm_head_weights
+from ray_tpu.train.lm import lm_loss
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def tiny(**kw) -> MixedStackConfig:
+    """2 dense + 4 expert layers (dS dS eS eF eS eS), 32 experts top-4 of
+    which 8 are held, a window shorter than the sequence, 4 heads of 8 over a
+    model of 64 (so head_dim is not d_model / n_heads), float32."""
+    base = dict(
+        vocab_size=256, d_model=64, n_layers=6, n_heads=4, n_kv_heads=2, d_head=8, d_ff=32,
+        d_ff_dense=96, max_seq=64, pos_emb="rope", norm="rmsnorm", act="swiglu", use_bias=False,
+        tie_embeddings=False, norm_eps=1e-5, dtype=jnp.float32, remat=True,
+        qk_norm_per_head=True, attn_gate=True, sandwich_norm=True, scale_embedding=True,
+        sliding_window=16, global_attn_every=4, n_dense_layers=2, n_experts=32,
+        held_experts=(0, 8), top_k=4, norm_topk_prob=True,
+        route_scale=2.826, router_score="sigmoid", router_select_bias=True,
+        shared_expert_width=32, router_aux_coeff=0.0, frozen_leaves=("router",))
+    return MixedStackConfig(**{**base, **kw})
+
+
+def arch(config):
+    return dict(global_attn_every=config.global_attn_every, num_dense_layers=config.n_dense_layers,
+                sliding_window=config.sliding_window, rope_theta=config.rope_theta,
+                norm_eps=config.norm_eps, top_k=config.top_k, route_scale=config.route_scale,
+                held_experts=config.held_experts, frozen_leaves=config.frozen_leaves)
+
+
+def seeded(config, seed=0):
+    """Parameters with every norm weight off 1, a NON-zero selection bias and
+    weights large enough that the gates and the softmax are not flat."""
+    params = model_family(config).init_params(config, jax.random.PRNGKey(seed))
+
+    def shake(path, w):
+        name = jax.tree_util.keystr(path)
+        key = jax.random.fold_in(jax.random.PRNGKey(seed + 1), len(name) + int(w.size))
+        if "expert_bias" in name:
+            return 0.3 * jax.random.normal(key, w.shape)
+        if "scale" in name:
+            return w + 0.1 * jax.random.normal(key, w.shape)
+        return 3.0 * w
+
+    return jax.tree_util.tree_map_with_path(shake, params)
+
+
+def _said(runs):
+    """`2 x (dS) | eS eF eS eS`: a run as its repeats times its period."""
+    period = lambda run: " ".join(k.code for k in run.kinds)  # noqa: E731
+    return " | ".join(f"{run.repeats} x ({period(run)})" if run.repeats > 1 else period(run)
+                      for run in runs)
+
+
+@pytest.mark.parametrize("depth,dense,every,want", [
+    (6, 2, 4, "2 x (dS) | eS eF eS eS"),
+    (32, 2, 4, "2 x (dS) | 7 x (eS eF eS eS) | eS eF"),
+    (8, 0, 1, "8 x (eF)"),
+    (5, 5, 2, "2 x (dS dF) | dS")],
+    ids=["the-cell", "the-published-depth", "all-alike", "dense-with-a-remainder"])
+def test_layers_group_into_runs_of_a_period(depth, dense, every, want):
+    config = tiny(n_layers=depth, n_dense_layers=dense, global_attn_every=every)
+    kinds = layer_kinds(config)
+    runs = stack_runs(kinds)
+    assert _said(runs) == want
+    # the runs are the stack, in order
+    assert [k for run in runs for _ in range(run.repeats) for k in run.kinds] == kinds
+    assert all(isinstance(run, Run) and isinstance(k, LayerKind) for run in runs for k in run.kinds)
+    # compile time grows with the period: no run's body holds more than the pattern
+    assert max(len(run.kinds) for run in runs) <= every
+
+
+def test_parameters_are_stacked_a_run_and_mirror_their_axes():
+    config = tiny()
+    family = model_family(config)
+    params = jax.eval_shape(lambda: family.init_params(config, jax.random.PRNGKey(0)))
+    axes = family.logical_axes(config)
+    is_axes = lambda x: isinstance(x, tuple) and all(a is None or isinstance(a, str) for a in x)  # noqa: E731
+    flat_axes = jax.tree.leaves(axes, is_leaf=is_axes)
+    flat = jax.tree.leaves(params)
+    assert len(flat) == len(flat_axes) and all(len(a) == x.ndim for a, x in zip(flat_axes, flat))
+    dense, experts = params["runs"]
+    assert dense[0]["w_up"].shape == (2, 64, 96) and "router" not in dense[0]
+    assert experts[1]["we_up"].shape == (1, 8, 64, 32)          # 8 of the 32 experts held
+    assert experts[1]["router"].shape == (1, 64, 32) and experts[1]["expert_bias"].shape == (1, 32)
+    assert dense[0]["wq"].shape == (2, 64, 4, 8) and dense[0]["q_norm_scale"].shape == (2, 8)
+
+
+def test_forward_loss_and_every_gradient_match_the_plain_reference():
+    """Logits, loss and every leaf's gradient of the system against
+    benchmark/reference/afmoe_ref.py, float32, 1e-4: both attention kinds,
+    both MLP kinds, the held share, a seeded non-zero `expert_bias`."""
+    from benchmark.reference import afmoe_ref
+
+    config = tiny()
+    family = model_family(config)
+    params = seeded(config)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 49), 0, config.vocab_size)
+
+    def logits(p, t):
+        hidden, _ = family.forward_hidden(p, t, config)
+        return jnp.einsum("bse,ev->bsv", hidden, lm_head_weights(p, config))
+
+    ours = jax.jit(logits)(params, tokens[:, :-1])
+    theirs = afmoe_ref.forward_logits(params, tokens[:, :-1], **arch(config))
+    np.testing.assert_allclose(np.asarray(ours), np.asarray(theirs), atol=1e-4)
+    (loss, grads) = jax.jit(jax.value_and_grad(lambda p: lm_loss(p, tokens, config)[0]))(params)
+    (ref_loss, ref_grads) = jax.value_and_grad(
+        lambda p: afmoe_ref.objective(p, tokens, **arch(config)))(params)
+    assert abs(float(loss) - float(ref_loss)) < 1e-5
+    for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(grads)[0], jax.tree.leaves(ref_grads)):
+        scale = max(float(jnp.max(jnp.abs(r))), 1e-3)
+        assert float(jnp.max(jnp.abs(g - r))) <= 1e-4 * scale, jax.tree_util.keystr(path)
+        if "expert_bias" in jax.tree_util.keystr(path) or "router" in jax.tree_util.keystr(path):
+            # the bias enters the selection only, and the router is a frozen leaf here
+            assert not np.asarray(g).any() and not np.asarray(r).any()
+
+
+def test_a_frozen_leaf_loses_its_gradient_and_nothing_else_does():
+    """`frozen_leaves` is read where a layer reads its parameters: the named
+    leaf's gradient is zero, every other leaf's is what the unfrozen step
+    computes (the gates still carry their gradient back into the layer's
+    input), and a name no layer has is refused."""
+    frozen, free = tiny(), tiny(frozen_leaves=())
+    params = seeded(frozen)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 49), 0, frozen.vocab_size)
+    grads = {name: jax.jit(jax.grad(lambda p, c=c: lm_loss(p, tokens, c)[0]))(params)
+             for name, c in (("frozen", frozen), ("free", free))}
+    flat = jax.tree_util.tree_flatten_with_path(grads["frozen"])[0]
+    routers = 0
+    for (path, g), f in zip(flat, jax.tree.leaves(grads["free"])):
+        if "'router'" in jax.tree_util.keystr(path):
+            routers += 1
+            assert not np.asarray(g).any() and np.asarray(f).any()
+        else:
+            np.testing.assert_allclose(np.asarray(g), np.asarray(f), rtol=1e-5, atol=1e-8,
+                                       err_msg=jax.tree_util.keystr(path))
+    assert routers == 4
+    with pytest.raises(ValueError, match="no layer has such a leaf"):
+        tiny(frozen_leaves=("rooter",))
+
+
+def _expert_layer(config, key=2):
+    """One expert layer's parameters with ALL the published experts, and
+    normed activations to feed it."""
+    whole = dataclasses.replace(config, held_experts=None, n_layers=1, n_dense_layers=0,
+                                global_attn_every=1)
+    lp = jax.tree.map(lambda w: w[0], seeded(whole, key)["runs"][0][0])
+    h = jax.random.normal(jax.random.PRNGKey(key + 1), (2, 24, config.d_model))
+    return whole, lp, h
+
+
+def _reference_mlp(h, lp, config, held):
+    """The plain reference's expert layer on normed activations: the shared
+    expert and every one of the `held` experts on every token, gated."""
+    from benchmark.reference import afmoe_ref
+
+    with jax.default_matmul_precision("highest"):
+        scores = jax.nn.sigmoid(h @ lp["router"])
+        gates, _ = afmoe_ref._gates(scores, lp["expert_bias"], config.top_k, config.route_scale)
+        return afmoe_ref._swiglu(h, lp["ws_gate"], lp["ws_up"], lp["ws_down"]) + sum(
+            gates[..., e, None] * afmoe_ref._swiglu(h, lp["we_gate"][e], lp["we_up"][e], lp["we_down"][e])
+            for e in range(held))
+
+
+def test_the_shares_of_a_layer_add_up_to_the_uncut_layer():
+    """The routed parts of the four chips' shares of one layer, plus the
+    shared expert once, are the uncut layer's output: every share routes over
+    all 32 experts, normalises the gates over all 4 chosen, and computes the
+    chosen experts it holds."""
+    whole, lp, h = _expert_layer(tiny())
+    uncut, scalars = moe.moe_mlp(h, lp, whole)
+    total, rows = 0.0, 0.0
+    for share in range(4):
+        first = 8 * share
+        config = dataclasses.replace(whole, held_experts=(first, first + 8),
+                                     shared_expert_width=32 if share == 0 else 0)
+        held = dict(lp, **{name: lp[name][first: first + 8] for name in ("we_gate", "we_up", "we_down")})
+        part, part_scalars = moe.moe_mlp(h, held, config)
+        total, rows = total + part, rows + part_scalars["moe_rows_held"]
+        np.testing.assert_array_equal(np.asarray(part_scalars["load"]), np.asarray(scalars["load"]))
+    np.testing.assert_allclose(np.asarray(total), np.asarray(uncut), atol=2e-5)
+    assert float(rows) == 2 * 24 * 4            # every (token, choice) row lies on exactly one chip
+    # and the uncut layer is the reference's
+    np.testing.assert_allclose(np.asarray(uncut), np.asarray(_reference_mlp(h, lp, whole, 32)), atol=2e-5)
+
+
+@pytest.mark.parametrize("sent_here", ["every-choice", "none", "as-routed"])
+def test_no_row_routed_to_a_held_expert_is_dropped(sent_here):
+    """Whatever the routing: a selection bias that sends EVERY token's every
+    choice to the held experts fills the buffer `held_passes_most` times over
+    (2 passes of T k / 2 rows here) and is exact, forward and gradient, against
+    every held expert applied to every token; one that sends none computes
+    the shared expert alone."""
+    config = tiny()
+    whole, lp, h = _expert_layer(config)
+    bias = {"every-choice": jnp.where(jnp.arange(32) < 8, 10.0, 0.0),
+            "none": jnp.where(jnp.arange(32) < 8, -10.0, 0.0), "as-routed": lp["expert_bias"]}[sent_here]
+    held = dict(lp, expert_bias=bias,
+                **{name: lp[name][:8] for name in ("we_gate", "we_up", "we_down")})
+    config = dataclasses.replace(whole, held_experts=(0, 8))
+    tokens = h.shape[0] * h.shape[1]
+    assert moe.held_buffer_rows(config, tokens, 1) == tokens * 4 // 2
+    assert moe.held_passes_most(config, tokens, 1) == 2
+
+    def ours(h, lp):
+        out, scalars = moe.moe_mlp(h, lp, config)
+        return jnp.sum(out * jnp.cos(out)), (out, scalars)
+
+    def theirs(h, lp):
+        out = _reference_mlp(h, lp, config, 8)
+        return jnp.sum(out * jnp.cos(out)), out
+
+    (_, (out, scalars)), grads = jax.jit(jax.value_and_grad(ours, (0, 1), has_aux=True))(h, held)
+    (_, want), want_grads = jax.value_and_grad(theirs, (0, 1), has_aux=True)(h, held)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
+    for g, w in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=1e-4)
+    rows = {"every-choice": tokens * 4, "none": 0}.get(sent_here)
+    if rows is not None:
+        assert float(scalars["moe_rows_held"]) == rows
+        assert float(scalars["moe_passes"]) == (2 if rows else 1)
+    else:
+        assert 0 < float(scalars["moe_rows_held"]) < tokens * 4
+
+
+@pytest.mark.parametrize("sent_here", ["every-choice", "as-routed"])
+def test_held_layer_through_the_grouped_matmul_kernels(monkeypatch, sent_here):
+    """The held layer as a TPU runs it: the three `moe_gmm_*` kernels
+    (interpret mode, 8-row tiles) over a buffer that is mostly empty, whose
+    unused row tiles are neither computed nor copied, one pass
+    or two, against `ragged_dot`: output and every gradient."""
+    from ray_tpu.ops import grouped_matmul as gmm
+
+    whole, lp, h = _expert_layer(tiny())
+    config = dataclasses.replace(whole, held_experts=(0, 8))
+    bias = lp["expert_bias"] if sent_here == "as-routed" else jnp.where(jnp.arange(32) < 8, 10.0, 0.0)
+    held = dict(lp, expert_bias=bias,
+                **{name: lp[name][:8] for name in ("we_gate", "we_up", "we_down")})
+
+    def run(h, lp):
+        out, scalars = moe.moe_mlp(h, lp, config)
+        return jnp.sum(jnp.sin(out)), (out, scalars)
+
+    want = jax.value_and_grad(run, (0, 1), has_aux=True)(h, held)
+    monkeypatch.setattr(moe, "resolve_gmm_impl", lambda implementation=None: "pallas")
+    monkeypatch.setattr(moe, "gmm_tile_rows", lambda implementation=None: 8)
+    real = gmm.grouped_matmul
+    seen = []
+
+    def interpreted(lhs, rhs, sizes, **kw):
+        seen.append(lhs.shape[0])
+        return real(lhs, rhs, sizes, **dict(kw, interpret=True))
+
+    monkeypatch.setattr(moe, "grouped_matmul", interpreted)
+    got = jax.value_and_grad(run, (0, 1), has_aux=True)(h, held)
+    # a pass's buffer: twice the even share and a tile a held expert, not all T k rows
+    assert seen and set(seen) == {moe.held_buffer_rows(config, h.shape[0] * h.shape[1], 8) + 8 * 8}
+    for ours, theirs in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(ours), np.asarray(theirs), atol=2e-5)
+    assert float(got[0][1][1]["moe_passes"]) == (2 if sent_here == "every-choice" else 1)
+
+
+@pytest.mark.parametrize("score", ["softmax", "sigmoid"])
+def test_router_forms_against_a_count_by_hand(score):
+    """Scores softmax or sigmoid; the selection bias moves the choice and not
+    the gate; renormalised; scaled. The renormalisation's epsilon is the one
+    constant every router here has (1e-9): under a float32 sum of chosen
+    scores it changes no bit against the source's 1e-20."""
+    config = moe.MoEConfig(n_experts=4, top_k=2, router_score=score, router_select_bias=True,
+                           norm_topk_prob=True, route_scale=2.0)
+    total = jnp.float32(0.5)
+    assert float(total + jnp.float32(1e-9)) == float(total + jnp.float32(1e-20)) == 0.5
+    scores = jnp.asarray([[0.1, 0.4, 0.3, 0.2]])
+    gates, experts = moe._route(scores, scores + jnp.asarray([0.5, 0.0, 0.0, 0.0]), config)
+    assert experts.tolist() == [[0, 1]]                       # 0.6 and 0.4: the bias chose expert 0
+    np.testing.assert_allclose(np.asarray(gates), [[2.0 * 0.1 / 0.5, 2.0 * 0.4 / 0.5]], rtol=1e-6)
+    plain = dataclasses.replace(config, router_select_bias=False, norm_topk_prob=False, route_scale=1.0)
+    gates, experts = moe._route(scores, None, plain)
+    assert experts.tolist() == [[1, 2]] and np.allclose(np.asarray(gates), [[0.4, 0.3]])
+
+
+def test_the_step_reports_the_stack_and_the_held_rows():
+    """`plan` for the `train.init.step_fn` span, and the scalars every
+    `train.report` carries."""
+    config = tiny()
+    family = model_family(config)
+    plan = family.plan(config, 2, 48)
+    assert plan["layer_kinds"] == "dS dS eS eF eS eS" and plan["attn_window"] == 16
+    assert (plan["moe_router"], plan["moe_experts_held"], plan["moe_experts_routed"],
+            plan["moe_shared_width"]) == ("sigmoid", 8, 32, 32)
+    assert plan["moe_held_buffer_rows"] == 2 * 48 * 4 // 2 and plan["moe_held_passes_most"] == 2
+    assert {"attn_window_subtiles_visited", "attn_window_subtiles_masked",
+            "attn_window_subtiles_total"} <= set(plan)
+    params = seeded(config)
+    tokens = jax.random.randint(jax.random.PRNGKey(3), (2, 49), 0, config.vocab_size)
+    _, scalars = lm_loss(params, tokens, config)
+    assert {"loss", "num_tokens", "moe_load_max_over_mean", "moe_rows_held", "moe_rows_held_share",
+            "moe_passes"} == set(scalars)
+    assert float(scalars["moe_rows_held_share"]) == pytest.approx(
+        100 * float(scalars["moe_rows_held"]) / (2 * 48 * 4))
+    assert 1 <= float(scalars["moe_passes"]) <= 2
+
+
+def test_the_older_families_are_untouched_by_the_new_fields():
+    """With no window, no held experts, softmax scores and no shared expert
+    the dense and MoE families build the parameters they built and take the
+    branches they took (the lowered programs of the three shipped cells are
+    compared with the parent's on the chip: PERF.md section 6, PR 33)."""
+    from ray_tpu.models import get_config
+
+    dense, sparse = get_config("gpt2-tiny"), moe.moe_tiny()
+    for config in (dense, sparse):
+        assert config.d_head is None and config.head_dim == config.d_model // config.n_heads
+        assert not (config.qk_norm_per_head or config.attn_gate or config.sandwich_norm
+                    or config.scale_embedding)
+    assert (sparse.router_score, sparse.router_select_bias, sparse.route_scale,
+            sparse.shared_expert_width, sparse.held_experts) == ("softmax", False, 1.0, 0, None)
+    params = moe.init_params(sparse, jax.random.PRNGKey(0))
+    assert set(params["blocks"]) == {"ln1_scale", "wq", "wk", "wv", "wo", "ln2_scale", "router",
+                                     "we_gate", "we_up", "we_down"}
+    assert params["blocks"]["we_up"].shape[1] == sparse.n_experts == sparse.n_experts_held
+    assert "blocks" in model_family(dense).init_params(dense, jax.random.PRNGKey(0))

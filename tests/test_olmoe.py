@@ -113,7 +113,7 @@ def test_dropless_path_drops_nothing_whatever_the_routing(case):
         logits = logits.at[..., 3].add(-30.0)     # expert 3 is nobody's
     probs = jax.nn.softmax(logits, -1)
     weights = (lp["we_gate"], lp["we_up"], lp["we_down"])
-    out, load = moe._dropless_shard(h, probs, weights, config)
+    out, load, _ = moe._dropless_shard(h, probs, weights, config)
     load = np.asarray(load)
     assert load.sum() == 2 * 32 * config.top_k
     if case == "two-experts-take-every-token":

@@ -218,9 +218,9 @@ def test_kernels_walk_the_counted_subtiles(monkeypatch, s, causal):
     pieces = []
     scores = A._scores
 
-    def recording(q, k, scale, mask_at, causal_, kv_len):
+    def recording(q, k, scale, mask_at, *rest):
         pieces.append((q.shape[0] * k.shape[0], mask_at is not None))
-        return scores(q, k, scale, mask_at, causal_, kv_len)
+        return scores(q, k, scale, mask_at, *rest)
 
     monkeypatch.setattr(A, "_scores", recording)
     q = jnp.zeros((1, 1, s, 64), jnp.float32)
@@ -245,28 +245,26 @@ def test_kernels_walk_the_counted_subtiles(monkeypatch, s, causal):
         assert (visited, masked, total) == (3, 3, 4)
 
 
-@pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("kv_len", [1024, 700, 0])
-@pytest.mark.parametrize("sub", [(256, 256), (128, 256), (512, 128)])
-def test_subtile_ranges_agree(causal, kv_len, sub):
-    """`_q_range` (the dkv kernel's bounds) reaches exactly the set
-    `_kv_range` (forward, dq, the counter) reaches, with the same masked
-    sub-tiles, and that set is the live pairs'."""
-    from ray_tpu.ops.attention import _kv_range, _q_range
+def _tiles_by_runs(runs_of, strips, key):
+    """{(a, c, is_masked)} of the sub-tiles the strips' runs reach."""
+    return {key(strip, index, masked) for strip in range(strips)
+            for lo, hi, masked in runs_of(strip) for index in range(lo, hi)}
+
+
+def _assert_ranges_cover(live_pair, sub, causal, kv_len, window=None):
+    from ray_tpu.ops.attention import _kv_runs, _q_runs
 
     sub_q, sub_kv = sub
-    nq, nk = 1024 // sub_q, 1024 // sub_kv
-    by_rows, by_cols = set(), set()
-    for a in range(nq):
-        full, live = _kv_range(a * sub_q, 0, nk, sub_q, sub_kv, causal, kv_len)
-        by_rows |= {(a, c, c >= full) for c in range(live)}
-    for c in range(nk):
-        first_live, first_full = _q_range(0, c * sub_kv, nq, sub_q, sub_kv, causal, kv_len)
-        by_cols |= {(a, c, a < first_full) for a in range(first_live, nq)}
+    size = live_pair.shape[0]
+    nq, nk = size // sub_q, size // sub_kv
+    by_rows = _tiles_by_runs(
+        lambda a: _kv_runs(a * sub_q, 0, nk, sub_q, sub_kv, causal, kv_len, window),
+        nq, lambda a, c, m: (a, c, m))
+    by_cols = _tiles_by_runs(
+        lambda c: _q_runs(0, c * sub_kv, nq, sub_q, sub_kv, causal, kv_len, window),
+        nk, lambda c, a, m: (a, c, m))
     assert by_rows == by_cols
     # every live pair is in a visited sub-tile, every masked pair in a masked one
-    rows, cols = np.arange(1024)[:, None], np.arange(1024)[None, :]
-    live_pair = (cols < kv_len) & ((cols <= rows) | (not causal))
     for a in range(nq):
         for c in range(nk):
             tile = live_pair[a * sub_q:(a + 1) * sub_q, c * sub_kv:(c + 1) * sub_kv]
@@ -274,6 +272,92 @@ def test_subtile_ranges_agree(causal, kv_len, sub):
             assert bool(tile.any()) == bool(kind)
             if kind:
                 assert kind == {not tile.all()}
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("kv_len", [1024, 700, 0])
+@pytest.mark.parametrize("sub", [(256, 256), (128, 256), (512, 128)])
+def test_subtile_ranges_agree(causal, kv_len, sub):
+    """`_q_range` (the dkv kernel's bounds) reaches exactly the set
+    `_kv_range` (forward, dq, the counter) reaches, with the same masked
+    sub-tiles, and that set is the live pairs'."""
+    rows, cols = np.arange(1024)[:, None], np.arange(1024)[None, :]
+    live_pair = (cols < kv_len) & ((cols <= rows) | (not causal))
+    _assert_ranges_cover(live_pair, sub, causal, kv_len)
+
+
+@pytest.mark.parametrize("window", [1, 100, 256, 300, 512, 1024, 4096])
+@pytest.mark.parametrize("sub", [(256, 256), (128, 256), (512, 128)])
+def test_subtile_ranges_agree_under_a_window(window, sub):
+    """The same with the window's lower edge (key j live iff i - window < j
+    <= i; no kv_len edge, causality implies it)."""
+    rows, cols = np.arange(1024)[:, None], np.arange(1024)[None, :]
+    _assert_ranges_cover((cols <= rows) & (cols > rows - window), sub, True, None, window)
+
+
+@pytest.mark.parametrize("s,window,block", [
+    (48, 64, None), (64, 64, None), (96, 32, 32), (128, 48, 32), (100, 32, 32),
+    (128, 32, 64), (128, 200, 32), (64, 16, None), (128, 1, 32)],
+    ids=["below-window", "at-window", "above-3x3", "edge-inside-a-tile", "no-tile-divides",
+         "two-tiles", "window-over-the-sequence", "one-tile-walked", "window-of-one"])
+def test_windowed_kernels_match_the_masked_reference(monkeypatch, s, window, block):
+    """`flash_attention(window=)` in interpret mode against `mha_reference`
+    with the same mask, forward and all three gradients, GQA: sequences below,
+    at and above the window, a window edge inside a tile, and a length no tile
+    divides. The walk runs inside every band tile (16 x 16 sub-tiles here)."""
+    from ray_tpu.ops import attention as A
+
+    monkeypatch.setattr(A, "_SUB_TILE", (16, 16))
+    keys = jax.random.split(jax.random.PRNGKey(s + window), 4)
+    q, do = (_rand(key, (1, 4, s, 32)) for key in (keys[0], keys[3]))
+    k, v = (_rand(key, (1, 2, s, 32)) for key in keys[1:3])
+
+    def objective(implementation):
+        return lambda q, k, v: jnp.sum(do * flash_attention(
+            q, k, v, causal=True, window=window, implementation=implementation,
+            block_q=block, block_kv=block))
+
+    got = flash_attention(q, k, v, causal=True, window=window, implementation="pallas",
+                          block_q=block, block_kv=block)
+    want = mha_reference(q, k, v, causal=True, window=window)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    # the reference's window is the stated one: key j live iff i - window < j <= i
+    rows, cols = np.arange(s)[:, None], np.arange(s)[None, :]
+    scores = np.einsum("hqd,hkd->hqk", np.asarray(q[0]), np.repeat(np.asarray(k[0]), 2, 0)) / 32 ** 0.5
+    scores = np.where((cols <= rows) & (cols > rows - window), scores, -np.inf)
+    probs = np.exp(scores - scores.max(-1, keepdims=True))
+    by_hand = np.einsum("hqk,hkd->hqd", probs / probs.sum(-1, keepdims=True),
+                        np.repeat(np.asarray(v[0]), 2, 0))
+    np.testing.assert_allclose(np.asarray(want[0]), by_hand, atol=2e-5)
+    for ours, theirs in zip(jax.grad(objective("pallas"), (0, 1, 2))(q, k, v),
+                            jax.grad(objective("xla"), (0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(np.asarray(ours), np.asarray(theirs), atol=5e-5)
+
+
+@pytest.mark.parametrize("s,window,block", [
+    (8192, 2048, 1024),     # a band of 3 tiles a q tile: 21 of the 36 causal tiles
+    (1024, 2048, 1024),     # one tile: the causal walk, 10 / 4 / 16
+    (2048, 256, 1024), (4096, 1000, 512)])
+def test_windowed_subtile_counts(s, window, block):
+    """`attention_subtiles` under a window against a count over the pairs
+    themselves: a sub-tile is visited iff it holds a live pair and masked iff
+    it also holds a dead one."""
+    from ray_tpu.ops.attention import attention_subtiles
+
+    rows, cols = np.arange(s)[:, None], np.arange(s)[None, :]
+    live = ((cols <= rows) & (cols > rows - window)).reshape(s // 256, 256, s // 256, 256)
+    some, every = live.any(axis=(1, 3)), live.all(axis=(1, 3))
+    by_pairs = (int(some.sum()), int((some & ~every).sum()), (s // 256) ** 2)
+    assert attention_subtiles(s, s, True, s, block, block, 256, 256, window) == by_pairs
+
+
+def test_window_needs_causal_self_attention():
+    q = jnp.zeros((1, 1, 64, 32))
+    with pytest.raises(ValueError, match="window"):
+        flash_attention(q, q, q, causal=False, window=16)
+    with pytest.raises(ValueError, match="square"):
+        flash_attention(q, q, q, causal=True, window=16, block_q=32, block_kv=64,
+                        implementation="pallas")
 
 
 def test_rmsnorm_and_layernorm():

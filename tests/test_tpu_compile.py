@@ -85,6 +85,32 @@ def test_flash_attention_fwd_and_grad_compile(as_tpu, shapes):
     assert _kernel_calls(grad.lower(q, k, v).compile()) == 3  # fwd, dkv, dq
 
 
+TRINITY = ((2, 32, 8192, 128), (2, 4, 8192, 128))     # train-trinity-mini-8k, GQA 32 -> 4
+
+
+@pytest.mark.parametrize("seq,window,block", [
+    (8192, 2048, None), (8192, 2048, 512), (1024, 2048, None),
+    (1536, 1000, 512)],
+    ids=["cell-8k-band3", "block512-band5", "one-tile", "edge-inside-a-tile"])
+def test_windowed_flash_attention_fwd_and_grad_compile(as_tpu, seq, window, block):
+    """The `flash_win_*` kernels of a windowed call (a band of tiles at static
+    offsets from the diagonal, the sub-tile walk inside each) at the cell's
+    widths: the forward, and the forward with both backward kernels."""
+    q = _on(as_tpu, (2, 32, seq, 128))
+    k = v = _on(as_tpu, (2, 4, seq, 128))
+
+    def attend(q, k, v):
+        return flash.flash_attention(q, k, v, causal=True, window=window,
+                                     block_q=block, block_kv=block)
+
+    forward = jax.jit(attend).lower(q, k, v).compile()
+    assert _kernel_calls(forward) == 1 and "flash_win_fwd" in forward.as_text()
+    grad = jax.jit(jax.grad(lambda q, k, v: attend(q, k, v).astype(jnp.float32).sum(),
+                            argnums=(0, 1, 2))).lower(q, k, v).compile()
+    assert _kernel_calls(grad) == 3
+    assert all(name in grad.as_text() for name in ("flash_win_bwd_dkv", "flash_win_bwd_dq"))
+
+
 def test_default_rule_is_the_backend_alone(as_tpu, monkeypatch):
     """One kernel family since PR 26: the rule looks at the backend, not
     at the shape, and the walk engages at the cells' sequence length."""
