@@ -49,9 +49,9 @@ class ModelFamily(NamedTuple):
     # `moe_load_max_over_mean`; {} for a dense model). `remat_saved` names what
     # a model with `config.remat` keeps of each block across the forward pass
     forward_hidden: Callable
-    # (config, S, split) -> what one block costs a token and which of its values
-    # a checkpoint may keep (transformer.block_costs); None: a family whose
-    # blocks name none, and are recomputed whole
+    # (config, S, split) -> what the stack's blocks cost a token and which of
+    # their values a checkpoint may keep (transformer.block_costs); None: a
+    # family whose blocks name none, and are recomputed whole
     block_costs: Optional[Callable]
     # (config, batch, seq of a step) -> what the family's own layers resolve to, for
     # callers that report it (LMTrainer's `train.init.step_fn` span)
@@ -65,7 +65,7 @@ def _dense_hidden(params, tokens, config, remat_saved=()):
 # most derived first: a MixedStackConfig is a MoEConfig is a TransformerConfig
 _FAMILIES = (
     (MixedStackConfig, ModelFamily(_mixed.init_params, _mixed.logical_axes, _mixed.forward_hidden,
-                                   None, _mixed.plan)),
+                                   _mixed.block_costs, _mixed.plan)),
     (MoEConfig, ModelFamily(_moe.init_params, _moe.logical_axes, _moe.forward_hidden,
                             None, _moe.moe_plan)),
     (TransformerConfig, ModelFamily(init_params, logical_axes, _dense_hidden,

@@ -16,23 +16,37 @@ run's repeats.
 The block is written once, from the parts the two other families own:
 `transformer.attention_sublayer` (head size, QK-norm a head, rotary or none,
 window, output gate, post-norm: data of the configuration and arguments of
-the call), `transformer.mlp_sublayer`, `moe.moe_mlp`. Every block is
-recomputed whole in the backward pass where `config.remat`.
+the call), `transformer.mlp_sublayer`, `moe.moe_mlp`. Where `config.remat`
+every block is recomputed in the backward pass but for what the step keeps of
+it (`block_costs` names what it may: the attention kernel's output and lse and
+the residual after the output projection in every layer, gate and up in the
+dense ones; an expert layer's MLP is recomputed whole).
 """
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import math
-from typing import Any, Dict, List, NamedTuple, Optional, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ..ops import rope_frequencies
 from ..ops.attention import attention_plan
-from .moe import MoEConfig, load_max_over_mean, moe_mlp, moe_plan
-from .transformer import Params, _norm, attention_sublayer, checkpoint_block, mlp_sublayer
+from .moe import _HELD_BUFFER_SHARES, MoEConfig, load_max_over_mean, moe_mlp, moe_plan
+from .transformer import (
+    Params,
+    StackRun,
+    _norm,
+    attention_costs,
+    attention_sublayer,
+    checkpoint_block,
+    mlp_costs,
+    mlp_sublayer,
+    stack_costs,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -209,7 +223,7 @@ def logical_axes(config: MixedStackConfig) -> Params:
 # -------------------------------------------------------------------- forward
 
 
-def _block(x, lp, config, kind: LayerKind, rope_tables, positions):
+def _block(x, lp, config, kind: LayerKind, rope_tables, positions, remat_saved=()):
     """One layer of either attention kind and either MLP kind on (B, S, E):
     x + norm(attention(norm(x))), then the same around the MLP. -> (x, the
     expert layer's scalars, {} for a dense layer)."""
@@ -221,7 +235,7 @@ def _block(x, lp, config, kind: LayerKind, rope_tables, positions):
     with jax.named_scope("attn.window" if sliding else "attn.full"):
         x = attention_sublayer(
             x, lp, c, rope_tables if sliding else None, positions,
-            window=c.sliding_window if sliding else None)
+            window=c.sliding_window if sliding else None, remat_saved=remat_saved)
     if kind.mlp == "dense":
         return mlp_sublayer(x, lp, c), {}
     out, scalars = moe_mlp(_norm(x, lp["ln2_scale"], None, c.norm, c.norm_eps), lp, c)
@@ -257,7 +271,7 @@ def forward_hidden(
             scalars = []
             for kind, lp in zip(kinds, period_lp):
                 def block_fn(x, lp, kind=kind):
-                    return _block(x, lp, c, kind, rope_tables, positions)
+                    return _block(x, lp, c, kind, rope_tables, positions, remat_saved)
 
                 if c.remat:
                     block_fn = checkpoint_block(block_fn, remat_saved)
@@ -282,6 +296,48 @@ def forward_hidden(
                    moe_rows_held_share=100.0 * rows_held / (b * s * c.top_k),
                    moe_passes=jnp.max(every["moe_passes"]))
     return x, out
+
+
+def _expert_costs(config: MixedStackConfig, split: Callable[[str], int]) -> Dict[str, Any]:
+    """An expert layer's MLP as `transformer.mlp_costs` gives a dense one's,
+    a row (a token) and device at an even routing: the router, the shared
+    expert, the held experts' three grouped matmuls on the rows sent here,
+    and the buffer those rows pass through. It names no candidate: what the
+    buffer or the shared expert's gate and up are worth kept is not known
+    from any record (PERF.md section 7)."""
+    c = config
+    shared, d_ff = c.shared_expert_width // split("ws_up"), c.d_ff // split("we_up")
+    rows_here = c.top_k * c.n_experts_held / c.n_experts   # (token, choice) pairs a token
+    return {
+        "flops": int(2 * c.d_model * (c.n_experts + 3 * shared + 3 * rows_here * d_ff)),
+        # both norms' outputs, the layer's, the residual; the router's float32
+        # scores; the shared expert's gate, up and activation; a buffer row's
+        # input, gate, up, activation and output
+        "width": int(4 * c.d_model + c.n_experts * 4 // jnp.dtype(c.dtype).itemsize + 3 * shared
+                     + min(_HELD_BUFFER_SHARES * rows_here, c.top_k) * (2 * c.d_model + 3 * d_ff)),
+        "candidates": (),
+    }
+
+
+def block_costs(
+    config: MixedStackConfig, seq: int, split: Callable[[str], int] = lambda weight: 1,
+) -> Dict[str, Any]:
+    """`transformer.block_costs` for a stack of mixed layers: its runs as
+    `forward_hidden` walks them (one with repeats is a scan), each with the
+    costs of the kinds of layer it has. A candidate counts the layers of the
+    kinds that write it: the attention output and the residual after it every
+    layer (a windowed layer's scores are cheaper than a full one's), gate and
+    up the dense layers."""
+    c = config
+
+    def kind_costs(kind: LayerKind):
+        return (attention_costs(c, seq, split, c.sliding_window if kind.attention == "sliding" else None),
+                mlp_costs(c, split, c.d_ff_dense) if kind.mlp == "dense" else _expert_costs(c, split))
+
+    return stack_costs([
+        StackRun(run.repeats > 1, ("runs", r), tuple(
+            (n * run.repeats, *kind_costs(kind)) for kind, n in collections.Counter(run.kinds).items()))
+        for r, run in enumerate(stack_runs(layer_kinds(c)))])
 
 
 def plan(config: MixedStackConfig, batch: int, seq: int) -> Dict[str, Any]:

@@ -25,7 +25,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -34,6 +34,7 @@ from jax.ad_checkpoint import checkpoint_name
 from ..ops import (
     apply_rope,
     flash_attention,
+    flash_attention_kept,
     gelu,
     layernorm,
     rmsnorm,
@@ -229,10 +230,12 @@ def attention_sublayer(
     rope_tables: Optional[Tuple[jax.Array, jax.Array]],
     positions: Optional[jax.Array],
     window: Optional[int] = None,
+    remat_saved: Tuple[str, ...] = (),
 ) -> jax.Array:
     """Pre-norm causal self-attention + residual on (B, S, E). What differs
     between the layers of one stack comes as arguments: `rope_tables` (None:
-    this layer encodes no positions) and `window` (None: every causal key)."""
+    this layer encodes no positions) and `window` (None: every causal key).
+    `remat_saved`: what the checkpoint around the block keeps."""
     c = config
     dt = c.dtype
     h = _norm(x, lp["ln1_scale"], lp.get("ln1_bias"), c.norm, c.norm_eps)
@@ -273,7 +276,8 @@ def attention_sublayer(
         cos, sin = rope_tables
         q = apply_rope(q, cos, sin, positions)
         k = apply_rope(k, cos, sin, positions)
-    attn = flash_attention(q, k, v, causal=c.causal, window=window, implementation=c.attn_impl)
+    attend = flash_attention_kept if "attn_lse" in remat_saved else flash_attention
+    attn = attend(q, k, v, causal=c.causal, window=window, implementation=c.attn_impl)
     if c.attn_gate:
         gate = jnp.einsum("bse,ehd->bhsd", h, lp["wg"].astype(dt))
         attn = attn * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(dt)
@@ -313,60 +317,159 @@ def _block(
     config: TransformerConfig,
     rope_tables: Optional[Tuple[jax.Array, jax.Array]],
     positions: Optional[jax.Array],
+    remat_saved: Tuple[str, ...] = (),
 ) -> jax.Array:
     """One transformer block on (B, S, E) activations (training/prefill)."""
-    x = attention_sublayer(x, lp, config, rope_tables, positions)
+    x = attention_sublayer(x, lp, config, rope_tables, positions, remat_saved=remat_saved)
     return mlp_sublayer(x, lp, config)
 
 
 class RematCandidate(NamedTuple):
-    """A value of the block, under its `checkpoint_name`, that a checkpoint
-    policy may keep across the forward pass, and what keeping it is worth,
-    a row (a token) and device."""
+    """Values of a layer, under their `checkpoint_name`s, that a checkpoint
+    policy may keep across the forward pass (all of `names` or none), and
+    what keeping them is worth, a row (a token), layer and device."""
 
-    name: str
-    width: int        # features held
-    flops: int        # forward FLOPs that the backward need not repeat
-    all_reduce: bool  # and a tensor-parallel all-reduce of such a row with them
+    names: Tuple[str, ...]
+    width: int               # features held
+    flops: int               # forward FLOPs that the backward need not repeat
+    worth: int               # the time they take less what keeping moves, in FLOPs at the matmuls' rate
+    all_reduce: bool         # and a tensor-parallel all-reduce of such a row with them
+    layers: Tuple[int, ...]  # the layers that write them, in each run of the stack
+
+
+# What a kept attention output is worth beside a kept matmul output, both
+# from chip runs (PERF.md section 6, PR 34). The flash kernels' share of the
+# chip's peak, by which the scores they spare count for their time beside
+# matmuls that run near it (`flash_fwd_roofline` 52.7 at S = 1,024 and 58.0
+# at 8,192, `flash_win_fwd_roofline` 35.5: ledger, PR 33)
+_FLASH_SHARE_OF_PEAK = {"full": 0.55, "window": 0.355}
+# and FLOPs that take as long as moving one byte to keep it: the kernels
+# write the lse a lane of 128 float32 a row and read it so, and the kept
+# (B, H, S) is reshaped from and to that; the output is copied into the kept
+# stack and out of it (a matmul's is written there as it is computed). On
+# Mistral-7B's shard (S = 1,024, 16 heads of 128) keeping both spared 4.0 ms
+# a step of `flash_fwd` and the step took 0.27 ms MORE: 4.27 ms for 2.42 GB
+_KEPT_KERNEL_FLOPS_PER_BYTE = 350
+
+
+def attention_costs(
+    config: TransformerConfig, seq: int, split: Callable[[str], int], window: Optional[int] = None,
+) -> Dict[str, Any]:
+    """`attention_sublayer`'s part of `block_costs`, a layer: `flops`,
+    `width` and `candidates` as there."""
+    c = config
+    q_width, kv_width = c.n_heads * c.head_dim // split("wq"), c.kv_heads * c.head_dim // split("wk")
+    out_proj = 2 * q_width * c.d_model
+    # the scores' two matmuls over the keys a query sees
+    scores = 4 * q_width * (min(seq, window) if window else seq // 2 if c.causal else seq)
+    # the lse beside the output: 4 bytes a head, in features of the activations' dtype
+    heads, itemsize = c.n_heads // split("wq"), jnp.dtype(c.dtype).itemsize
+    lse_width = -(-4 * heads // itemsize)
+    moved = 2 * (heads * 128 * 4 + q_width * itemsize)
+    return {
+        "flops": (2 * c.d_model * ((2 if c.attn_gate else 1) * q_width + 2 * kv_width)
+                  + scores + out_proj),
+        # the norm's output, the sublayer's (and its own norm's), the residual;
+        # q and the attention output, (the gate and the gated output); k, v
+        "width": ((4 if c.sandwich_norm else 3) * c.d_model
+                  + (4 if c.attn_gate else 2) * q_width + 2 * kv_width),
+        "candidates": (
+            RematCandidate(
+                ("attn_out", "attn_lse"), q_width + lse_width, scores,
+                int(scores / _FLASH_SHARE_OF_PEAK["window" if window else "full"]
+                    - moved * _KEPT_KERNEL_FLOPS_PER_BYTE), False, ()),
+            RematCandidate(("attn_residual",), c.d_model, out_proj, out_proj, split("wq") > 1, ()),
+        ),
+    }
+
+
+def mlp_costs(config: TransformerConfig, split: Callable[[str], int],
+              d_ff: Optional[int] = None) -> Dict[str, Any]:
+    """`mlp_sublayer`'s part of `block_costs`, a layer of width `d_ff`
+    (None: the configuration's)."""
+    c = config
+    wide = ("w_up", "w_gate") if c.act == "swiglu" else ("w_up",)
+    d_ff = (d_ff or c.d_ff) // split("w_up")
+    matmul = 2 * c.d_model * d_ff  # each of up, (gate,) down
+    return {
+        "flops": (len(wide) + 1) * matmul,
+        # the down projection's output only feeds the next block's input,
+        # which is kept; a norm on it reads it again in the backward pass
+        "kept_anyway": 0 if c.sandwich_norm else matmul,
+        # the norm's output, the MLP's (and its own norm's), the residual; up,
+        # (gate,) and the activation
+        "width": (4 if c.sandwich_norm else 3) * c.d_model + (len(wide) + 1) * d_ff,
+        "candidates": tuple(
+            RematCandidate((w.replace("w_", "mlp_"),), d_ff, matmul, matmul, False, ()) for w in wide),
+    }
+
+
+class StackRun(NamedTuple):
+    """Layers in a row that the forward runs as one scan (`scanned`; its
+    stacked gradients and kept values then live as long as the scan's
+    backward) or one after the other, their parameters under `params` of the
+    parameter tree: (layers of a kind, the kind's `attention_costs`, its
+    MLP's costs) a kind."""
+
+    scanned: bool
+    params: Tuple[Any, ...]
+    kinds: Tuple[Tuple[int, Dict[str, Any], Dict[str, Any]], ...]
+
+
+def stack_costs(runs: Sequence[StackRun]) -> Dict[str, Any]:
+    """`block_costs` of a stack given as its runs: FLOPs summed over the
+    stack; a run's layers, whether it is scanned, its widest kind's width and
+    where its parameters are; a candidate once, with the layers of each run
+    that write it and their mean FLOPs and worth."""
+    flops = recomputed = 0
+    merged: Dict[Tuple[str, ...], RematCandidate] = {}
+    for r, run in enumerate(runs):
+        for n, attention, mlp in run.kinds:
+            flops += n * (attention["flops"] + mlp["flops"])
+            recomputed += n * (attention["flops"] + mlp["flops"] - mlp.get("kept_anyway", 0))
+            for c in (*attention["candidates"], *mlp["candidates"]):
+                seen = merged.get(c.names, c._replace(layers=(0,) * len(runs), flops=0, worth=0))
+                if (seen.width, seen.all_reduce) != (c.width, c.all_reduce):
+                    raise ValueError(f"{c.names} differs between the kinds of one stack")
+                before = sum(seen.layers)
+                merged[c.names] = seen._replace(
+                    layers=tuple(m + n * (i == r) for i, m in enumerate(seen.layers)),
+                    flops=(before * seen.flops + n * c.flops) // (before + n),
+                    worth=(before * seen.worth + n * c.worth) // (before + n))
+    return {
+        "flops": flops, "recomputed_flops": recomputed,
+        "runs": tuple({"scanned": run.scanned, "params": run.params,
+                       "layers": sum(n for n, _, _ in run.kinds),
+                       "width": max(a["width"] + m["width"] for _, a, m in run.kinds)}
+                      for run in runs),
+        "candidates": tuple(merged.values()),
+    }
 
 
 def block_costs(
     config: TransformerConfig, seq: int, split: Callable[[str], int] = lambda weight: 1,
 ) -> Dict[str, Any]:
-    """What one `_block` costs a device for a row (a token) of an S-long
-    sequence, for the rule that decides what a recomputing step keeps
-    (train/lm.py): `flops` of its forward pass; `recomputed_flops`, the part
-    a whole-block checkpoint runs again in the backward pass (all but the
-    down projection, whose output only feeds the next block's input, which
-    is kept); `width`, the features of every activation the block writes;
-    `candidates`, the values named in the sublayers that a policy may keep:
-    the MLP's up (and gate) projection, each on its own, and the residual
+    """What the stack's blocks cost a device for a row (a token) of an
+    S-long sequence, for the rule that decides what a recomputing step keeps
+    (train/lm.py): `flops` of their forward pass; `recomputed_flops`, the
+    part a whole-block checkpoint runs again in the backward pass; `runs`,
+    the stack as the backward pass walks it (here one scan: layers, the
+    features of every activation one block writes, where its parameters
+    are); `candidates`, the values named in the sublayers that a policy may
+    keep: the MLP's up (and gate) projection, each on its own; the residual
     stream after the attention output projection (with it the backward needs
     neither that matmul again nor, under tensor parallelism, the all-reduce
-    of its partial sums). The q, k, v projections and the attention output
-    are not named: beside the others they do not fit the one cell that
-    recomputes (PERF.md section 6, PR 30). `split(weight)` is the number of
-    devices that share the output features of that block parameter's matmul
-    (tensor parallelism)."""
-    c = config
-    q_width, kv_width = c.n_heads * c.head_dim // split("wq"), c.kv_heads * c.head_dim // split("wk")
-    wide = ("w_up", "w_gate") if c.act == "swiglu" else ("w_up",)
-    d_ff = c.d_ff // split("w_up")
-    matmul = 2 * c.d_model * d_ff  # each of up, (gate,) down
-    out_proj = 2 * q_width * c.d_model
-    scores = 4 * seq * q_width // (2 if c.causal else 1)
-    flops = 2 * c.d_model * (q_width + 2 * kv_width) + scores + out_proj + (len(wide) + 1) * matmul
-    return {
-        "flops": flops,
-        "recomputed_flops": flops - matmul,
-        # both norms' outputs, the attention's and the MLP's, the two residuals;
-        # q and the attention output; k, v; up, (gate,) and the activation
-        "width": 6 * c.d_model + 2 * q_width + 2 * kv_width + (len(wide) + 1) * d_ff,
-        "candidates": (
-            *(RematCandidate(w.replace("w_", "mlp_"), d_ff, matmul, False) for w in wide),
-            RematCandidate("attn_residual", c.d_model, out_proj, split("wq") > 1),
-        ),
-    }
+    of its partial sums); and the attention kernel's output with its lse
+    (with both the backward does not run the forward kernel again; the q, k,
+    v projections it still does: they are not named). A candidate's `worth`
+    is its FLOPs where a matmul is spared; the kernel's are priced by its
+    time, less what keeping its results moves (`_FLASH_SHARE_OF_PEAK`,
+    `_KEPT_KERNEL_FLOPS_PER_BYTE`): at S = 1,024 that leaves nothing, at
+    8,192 it is the most valuable candidate a byte. `split(weight)` is the
+    number of devices that share the output features of that block
+    parameter's matmul (tensor parallelism)."""
+    return stack_costs([StackRun(True, ("blocks",), (
+        (config.n_layers, attention_costs(config, seq, split), mlp_costs(config, split)),))])
 
 
 def checkpoint_block(block_fn, saved: Tuple[str, ...] = ()):
@@ -403,7 +506,7 @@ def forward_hidden(
         rope_tables = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
 
     def block_fn(carry, lp):
-        return _block(carry, lp, c, rope_tables, positions), None
+        return _block(carry, lp, c, rope_tables, positions, remat_saved), None
 
     if c.remat:
         block_fn = checkpoint_block(block_fn, remat_saved)

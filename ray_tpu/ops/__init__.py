@@ -13,7 +13,7 @@ shape) — a kernel is never tried and swapped for the reference when it
 fails.
 """
 
-from .attention import flash_attention, mha_reference  # noqa: F401
+from .attention import flash_attention, flash_attention_kept, mha_reference  # noqa: F401
 from .ragged_paged_attention import (  # noqa: F401
     ragged_paged_attention,
     ragged_reference_attention,

@@ -23,6 +23,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
@@ -757,19 +758,36 @@ def _bwd_pallas(q, k, v, out, lse, do, causal, sm_scale, block_q, block_kv, kv_l
 # ----------------------------------------------------------- custom_vjp plumbing
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
-def _flash(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret, window=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
+def _flash(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret, window=None,
+           lse_first=False):
     out, _ = _fwd_pallas(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret, window)
     return out
 
 
-def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret, window=None):
+def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret, window=None,
+               lse_first=False):
     out, lse = _fwd_pallas(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret, window)
+    # What a recomputing block (jax.checkpoint) may keep in place of this
+    # call: both or neither, the backward rebuilds the probabilities from the
+    # lse, so the output alone spares nothing. The lse is held as (B, H, S):
+    # a tiled layout pads the kernel's trailing axis of 1 to a lane's 128.
+    # Where nothing is recomputed the two reshapes fold and the backward
+    # reads the kernel's own lse, as it did.
+    out = checkpoint_name(out, "attn_out")
+    lse = checkpoint_name(lse[..., 0], "attn_lse")
+    if lse_first:
+        # the output is there when its lse is there in the shape that is kept:
+        # left to itself the compiler reshapes the lse where it is next read,
+        # in the backward pass, and holds the padded one until then (in a
+        # scanned block the loop's own boundary does this)
+        out, lse = jax.lax.optimization_barrier((out, lse))
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, sm_scale, block_q, block_kv, kv_len, interpret, window, res, do):
+def _flash_bwd(causal, sm_scale, block_q, block_kv, kv_len, interpret, window, lse_first, res, do):
     q, k, v, out, lse = res
+    lse = lse[..., None]   # the row blocks' trailing axis (`_write_out`)
     hq, hkv = q.shape[1], k.shape[1]
     if hq != hkv:
         groups = hq // hkv
@@ -931,6 +949,19 @@ def flash_attention(
     inside each: a sub-tile outside the window is skipped, one inside runs
     unmasked, one the diagonal or the window's edge crosses builds the mask.
     """
+    return _attend(q, k, v, False, causal, window, sm_scale, block_q, block_kv, implementation)
+
+
+def flash_attention_kept(q: jax.Array, k: jax.Array, v: jax.Array, **how) -> jax.Array:
+    """`flash_attention(q, k, v, **how)` for a block whose checkpoint keeps
+    the kernel's output and lse ("attn_out", "attn_lse" of `_flash_fwd`):
+    the same numbers, and the lse in the shape that is kept before anything
+    reads the output (`lse_first` there)."""
+    return _attend(q, k, v, True, **how)
+
+
+def _attend(q, k, v, lse_first, causal=False, window=None, sm_scale=None, block_q=None,
+            block_kv=None, implementation=None):
     sq, skv = q.shape[2], k.shape[2]
     implementation = resolve_attention_impl(implementation)
     if sm_scale is None:
@@ -945,7 +976,7 @@ def flash_attention(
     bq, bkv = _blocks(sq, skv, block_q, block_kv, window)
     out = _per_shard(
         lambda q_, k_, v_: _flash(
-            q_, k_, v_, causal, sm_scale, bq, bkv, skv, interpret, window
+            q_, k_, v_, causal, sm_scale, bq, bkv, skv, interpret, window, lse_first
         )
     )(_pad_seq(q, 2, bq), _pad_seq(k, 2, bkv), _pad_seq(v, 2, bkv))
     if out.shape[2] != sq:

@@ -111,10 +111,27 @@ def auto_loss_chunk(
         hbm_bytes = device_hbm_bytes()
     if not hbm_bytes:
         return 0
-    headroom = max(resident_bytes + step_bytes, _AUTO_CHUNK_HEADROOM_FRACTION * hbm_bytes)
-    room = hbm_bytes - headroom
-    if loss_logits_bytes(batch_per_device, seq, vocab) <= room:
+    if loss_logits_bytes(batch_per_device, seq, vocab) <= _logits_room(
+            hbm_bytes, resident_bytes, step_bytes):
         return 0
+    return chunk_of_a_chunked_head(
+        batch_per_device, seq, vocab, hbm_bytes,
+        resident_bytes=resident_bytes, step_bytes=step_bytes)
+
+
+def _logits_room(hbm_bytes: int, resident_bytes: int, step_bytes: int) -> float:
+    return hbm_bytes - max(resident_bytes + step_bytes, _AUTO_CHUNK_HEADROOM_FRACTION * hbm_bytes)
+
+
+def chunk_of_a_chunked_head(
+    batch_per_device: int, seq: int, vocab: int, hbm_bytes: int, *,
+    resident_bytes: int = 0, step_bytes: int = 0,
+) -> int:
+    """The chunk `auto_loss_chunk` gives a head that is chunked, whoever
+    decided that it is: the largest of `_CHUNK_CANDIDATES` that divides S and
+    whose own logits fit half the logits' room (the smallest that divides S
+    if none does; 0, the dense head, if none divides S)."""
+    room = _logits_room(hbm_bytes, resident_bytes, step_bytes)
     dividing = [chunk for chunk in _CHUNK_CANDIDATES if seq % chunk == 0]
     for chunk in dividing:
         if loss_logits_bytes(batch_per_device, seq, vocab, chunk) <= _CHUNK_ROOM_FRACTION * room:

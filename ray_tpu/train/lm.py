@@ -165,16 +165,23 @@ def create_train_state(
 
 # ------------------------------------------------ what a remat step keeps
 # Constants of `auto_remat_saved` and of the estimate `make_train_step` gives
-# it, set from runs of Mistral-7B's widths on four v5e chips, fsdp=2 x tp=2,
-# 8 layers, 12 x 1,024 rows a device (PERF.md section 6, PR 30):
-# the share of the device that whatever is kept leaves free. The estimate read
-# 2% over the whole-block step's peak (11.38 GB against 11.145) and 2.4% over
-# with everything kept (15.00 against 14.66, 86.7% of the chip).
-_REMAT_FREE_FRACTION = 0.10
+# it, set from two cells' measured peaks on v5e chips of 16.91 GB (PERF.md
+# section 6, PR 30 and PR 34): Mistral-7B's widths on four chips, fsdp=2 x
+# tp=2, 8 scanned layers, 12 x 1,024 rows a device (whole block 11.145 GB,
+# estimate 11.06; gate, up and the residual kept 14.658, estimate 14.68), and
+# one chip's share of Trinity-Mini, 2 scanned and 4 unrolled layers, 2 x 8,192
+# rows (the attention outputs kept 15.670 GB, estimate 15.73).
+# The share of the device that whatever is kept leaves free. The compiler
+# itself works to a ceiling near 93%: a program that needs more is scheduled
+# and rematerialised into it and pays in time (Trinity with the residual kept
+# beside the attention outputs, estimate 15.86 GB: 15.62 GB and 9 ms a step
+# MORE; with everything kept 16.36 GB and 26 ms more).
+_REMAT_FREE_FRACTION = 0.065
 # the backward pass of one block, in copies of the activations it writes:
-# the peak less state, gradients and the scan's carries was 2.29 GB, the
-# block's activations 1.26 GB (and chunking the head did not lower it, PR 29)
-_REMAT_BLOCK_COPIES = 2.0
+# Mistral's peak less state, gradients and the scan's carries was 2.29 GB, the
+# block's activations 1.26 GB (and chunking the head did not lower it, PR 29):
+# 1.82; Trinity's with the attention outputs kept 2.94 of 1.71: 1.72
+_REMAT_BLOCK_COPIES = 1.75
 # FLOPs that take as long as moving one byte through a tensor-parallel
 # all-reduce, which is how a spared all-reduce counts beside spared matmuls:
 # keeping the residual took 23.2 ms off the step beside gate and up (32.0 on
@@ -186,38 +193,61 @@ def auto_remat_saved(
     candidates: Tuple[Any, ...],
     *,
     rows: int,
-    layers: int,
     itemsize: int,
-    whole_block_bytes: float,
+    peak_bytes: Callable[[Tuple[Any, ...]], float],
     hbm_bytes: Optional[int] = None,
 ) -> Tuple[Tuple[Any, ...], int]:
-    """Which of a block's `candidates` (models/transformer.RematCandidate)
+    """Which of its blocks' `candidates` (models/transformer.RematCandidate)
     a step that recomputes its blocks keeps across the forward pass, and the
     bytes a device holds for them (their shapes': on the chip a kept value
-    cost 0.96 to 1.00 of that): one by one in order of the recomputation
-    spared per byte (a spared all-reduce counts as the FLOPs of its time),
-    each one that still fits beside `whole_block_bytes`, the estimate of the
-    step that keeps nothing, with `_REMAT_FREE_FRACTION` of the device left
-    free. `rows` are a device's tokens a step. Nothing live is probed but the
-    device's size, so the same model, mesh and batch always get the same
-    program; an unknown size (CPU) keeps nothing: the whole-block step is the
-    one that fits wherever anything does."""
+    cost 0.96 to 1.00 of that): those worth keeping, one by one in order of
+    their worth per byte (a spared all-reduce counts as the FLOPs of its
+    time), each one with which `peak_bytes(kept)`, the estimate of the step's peak,
+    still leaves `_REMAT_FREE_FRACTION` of the device free. `rows` are a
+    device's tokens a step. Nothing live is probed but the device's size, so
+    the same model, mesh and batch always get the same program; an unknown
+    size (CPU) keeps nothing: the whole-block step is the one that fits
+    wherever anything does."""
     if hbm_bytes is None:
         hbm_bytes = losses.device_hbm_bytes()
     if not hbm_bytes:
         return (), 0
-    room = (1 - _REMAT_FREE_FRACTION) * hbm_bytes - whole_block_bytes
 
-    def spared_per_byte(c) -> float:
-        return c.flops / (c.width * itemsize) + c.all_reduce * _ALL_REDUCE_FLOPS_PER_BYTE
+    def worth_per_byte(c) -> float:
+        return c.worth / (c.width * itemsize) + c.all_reduce * _ALL_REDUCE_FLOPS_PER_BYTE
 
-    kept, kept_bytes = [], 0
-    for c in sorted(candidates, key=spared_per_byte, reverse=True):
-        c_bytes = layers * rows * c.width * itemsize
-        if kept_bytes + c_bytes <= room:
-            kept.append(c)
-            kept_bytes += c_bytes
-    return tuple(kept), kept_bytes
+    kept: Tuple[Any, ...] = ()
+    for c in sorted(candidates, key=worth_per_byte, reverse=True):
+        if worth_per_byte(c) > 0 and peak_bytes(kept + (c,)) <= (1 - _REMAT_FREE_FRACTION) * hbm_bytes:
+            kept += (c,)
+    return kept, sum(sum(c.layers) * rows * c.width * itemsize for c in kept)
+
+
+def step_peak_bytes(
+    kept: Tuple[Any, ...], *, rows: int, itemsize: int, always: float, logits: float,
+    runs: Tuple[Dict[str, Any], ...],
+) -> float:
+    """The estimate of a recomputing step's peak on a device, with `kept`
+    held across the forward pass: the largest of its moments. `always` is
+    there all along (the device's share of the state, the gradients of what
+    is outside the blocks and, accumulating, every gradient). The head's
+    moment adds every kept value, every block's input and the head's
+    `logits`. The backward pass of a run of the stack (`runs`, in the
+    forward's order, each with its `gradients`, its blocks' `inputs`, one
+    block's backward pass `block`, and `scanned`) adds the gradients of the
+    runs behind it, the kept values and inputs of the runs before it, one
+    block's backward pass, and of the run itself: scanned, its stacked
+    gradients beside its kept values and inputs; unrolled, the larger of the
+    two, since a layer's kept values go as its gradients come."""
+    held = [sum(c.layers[r] * rows * c.width * itemsize for c in kept) for r in range(len(runs))]
+    inputs = [run["inputs"] for run in runs]
+    moments = [always + sum(held) + sum(inputs) + logits]
+    for r, run in enumerate(runs):
+        mine = (run["gradients"], held[r] + inputs[r])
+        moments.append(always + sum(held[:r]) + sum(inputs[:r])
+                       + sum(later["gradients"] for later in runs[r + 1:])
+                       + run["block"] + (sum(mine) if run["scanned"] else max(mine)))
+    return max(moments)
 
 
 def _model_split(sharding: NamedSharding, dims: slice) -> int:
@@ -290,8 +320,7 @@ def make_train_step(
     data_shards = mesh.shape.get("dp", 1) * mesh.shape.get("fsdp", 1)
 
     family = model_family(config)
-    chunks: Dict[Tuple[int, ...], int] = {}
-    remat_plans: Dict[Tuple[int, ...], Dict[str, Any]] = {}
+    decided: Dict[Tuple[int, ...], Tuple[int, Dict[str, Any]]] = {}
 
     def device_bytes(tree, shardings) -> int:
         return sum(math.prod(sh.shard_shape(x.shape)) * x.dtype.itemsize
@@ -300,68 +329,99 @@ def make_train_step(
     def device_batch(tokens_shape) -> int:
         return max(tokens_shape[0] // grad_accum // max(data_shards, 1), 1)
 
-    def loss_chunk_for(tokens_shape, state: TrainState) -> int:
-        """The head's form for a (B, S + 1) batch of the whole step: decided
-        the first time the shape is asked for (by a caller or by the step's
-        trace) and kept, so that what is reported is what runs. What a
-        device holds beside the logits is counted from `state`'s shapes
-        and the shardings: its share of the state, and of the gradients."""
-        if loss_chunk is not None:
-            return loss_chunk
+    def model_split(weight: str, dims: slice) -> int:
+        """`_model_split` of the parameter of that name, wherever the family
+        keeps it (a block parameter is (layers, input, *output) in each)."""
+        flat, _ = jax.tree_util.tree_flatten_with_path(state_shardings.params)
+        return _model_split(
+            next(sh for path, sh in flat if getattr(path[-1], "key", None) == weight), dims)
+
+    def head_and_remat_for(tokens_shape, state: TrainState) -> Tuple[int, Dict[str, Any]]:
+        """(the head's form, what the blocks keep) for a (B, S + 1) batch of
+        the whole step: ONE decision, since the dense head's logits and the
+        kept values ask for the same room; made the first time the shape is
+        asked for (by a caller or by the step's trace) and kept, so that what
+        is reported is what runs. The estimate of the step's peak
+        (`step_peak_bytes`) is counted from `state`'s shapes and the
+        shardings. Where the rule leaves a candidate out for want of room
+        while the DENSE head's logits are larger than any block's backward
+        pass, the head is chunked (as `auto_loss_chunk` chunks one whose
+        logits do not fit) and the rule asked again: a step that recomputes
+        for want of memory does not hold all the logits. A `loss_chunk` the
+        caller gave stays."""
         shape = tuple(tokens_shape)
-        if shape not in chunks:
-            chunks[shape] = auto_loss_chunk(
-                device_batch(shape), shape[1] - 1, config.vocab_size,
-                resident_bytes=device_bytes(state, state_shardings),
-                step_bytes=device_bytes(state.params, state_shardings.params),
-            )
-        return chunks[shape]
+        if shape in decided:
+            return decided[shape]
+        batch, seq = device_batch(shape), shape[1] - 1
+        resident = device_bytes(state, state_shardings)
+        gradients = device_bytes(state.params, state_shardings.params)
+        chunk = loss_chunk
+        if chunk is None:
+            chunk = auto_loss_chunk(batch, seq, config.vocab_size,
+                                    resident_bytes=resident, step_bytes=gradients)
+        plan = {"remat": "whole_block" if config.remat else "off", "remat_saved": (),
+                "remat_saved_bytes": 0,
+                "remat_recomputed_flops_share": None if config.remat else 0.0}
+        if config.remat and family.block_costs is not None:
+            hbm_bytes = losses.device_hbm_bytes()
+            rows, itemsize = batch * seq, jnp.dtype(config.dtype).itemsize
+            costs = family.block_costs(
+                config, seq, lambda weight: model_split(weight, slice(2, None)))
+            vocab = config.vocab_size // (
+                model_split("lm_head", slice(1, None)) if "lm_head" in state_shardings.params
+                else model_split("wte", slice(0, 1)))
+
+            def under(tree, path):
+                return functools.reduce(lambda sub, key: sub[key], path, tree)
+
+            runs = tuple({
+                "scanned": run["scanned"],
+                "gradients": device_bytes(under(state.params, run["params"]),
+                                          under(state_shardings.params, run["params"])),
+                "inputs": run["layers"] * rows * config.d_model * itemsize,
+                "block": _REMAT_BLOCK_COPIES * rows * run["width"] * itemsize,
+            } for run in costs["runs"])
+            always = (resident + gradients - sum(run["gradients"] for run in runs)
+                      + (gradients if grad_accum > 1 else 0))   # their accumulator
+
+            def kept_beside(chunk: int):
+                logits = losses.loss_logits_bytes(batch, seq, vocab, chunk)
+                return logits > max(run["block"] for run in runs), auto_remat_saved(
+                    costs["candidates"], rows=rows, itemsize=itemsize, hbm_bytes=hbm_bytes,
+                    peak_bytes=functools.partial(
+                        step_peak_bytes, rows=rows, itemsize=itemsize, always=always,
+                        logits=logits, runs=runs))
+
+            head_is_larger, (kept, kept_bytes) = kept_beside(chunk)
+            if (loss_chunk is None and not chunk and hbm_bytes and head_is_larger
+                    and len(kept) < len(costs["candidates"])):
+                chunk = losses.chunk_of_a_chunked_head(
+                    batch, seq, config.vocab_size, hbm_bytes,
+                    resident_bytes=resident, step_bytes=gradients)
+                _, (kept, kept_bytes) = kept_beside(chunk)
+            plan.update(
+                remat="selective" if kept else "whole_block",
+                remat_saved=tuple(name for c in kept for name in c.names),
+                remat_saved_bytes=kept_bytes,
+                remat_recomputed_flops_share=(
+                    costs["recomputed_flops"] - sum(sum(c.layers) * c.flops for c in kept))
+                / costs["flops"])
+        decided[shape] = chunk, plan
+        return decided[shape]
+
+    def loss_chunk_for(tokens_shape, state: TrainState) -> int:
+        """The head's form for a (B, S + 1) batch of the whole step: 0 = the
+        dense head, else the chunk (`head_and_remat_for`)."""
+        return head_and_remat_for(tokens_shape, state)[0]
 
     def remat_plan_for(tokens_shape, state: TrainState) -> Dict[str, Any]:
         """What the step's blocks keep across the forward pass for a
-        (B, S + 1) batch, decided once a shape and kept, as the head's form
-        is: `remat` (`off`: nothing is recomputed; `whole_block`; `selective`),
-        `remat_saved` (the `checkpoint_name`s kept), `remat_saved_bytes` (a
-        device) and `remat_recomputed_flops_share` (of a block's forward pass,
-        run again in the backward; None for a family that names no
-        candidates). The estimate of the whole-block step counts, a device:
-        its share of the state and of the gradients (from `state`'s shapes
-        and the shardings), the blocks' inputs that the scan carries, and the
-        larger of the head's logits and one block's backward pass."""
-        shape = tuple(tokens_shape)
-        if shape in remat_plans:
-            return remat_plans[shape]
-        plan = remat_plans[shape] = {
-            "remat": "whole_block" if config.remat else "off", "remat_saved": (),
-            "remat_saved_bytes": 0, "remat_recomputed_flops_share": None if config.remat else 0.0}
-        if not config.remat or family.block_costs is None:
-            return plan
-        seq, chunk = shape[1] - 1, loss_chunk_for(shape, state)
-        rows = device_batch(shape) * seq
-        itemsize = jnp.dtype(config.dtype).itemsize
-        blocks = state_shardings.params["blocks"]
-        # a block parameter is (layers, input, *output)
-        costs = family.block_costs(
-            config, seq, lambda weight: _model_split(blocks[weight], slice(2, None)))
-        head = state_shardings.params.get("lm_head")
-        vocab = config.vocab_size // (
-            _model_split(state_shardings.params["wte"], slice(0, 1)) if head is None
-            else _model_split(head, slice(1, None)))
-        logits = losses.loss_logits_bytes(device_batch(shape), seq, vocab, chunk)
-        gradients = device_bytes(state.params, state_shardings.params)
-        kept, kept_bytes = auto_remat_saved(
-            costs["candidates"], rows=rows, layers=config.n_layers, itemsize=itemsize,
-            whole_block_bytes=(
-                device_bytes(state, state_shardings)
-                + gradients * (2 if grad_accum > 1 else 1)   # and their accumulator
-                + config.n_layers * rows * config.d_model * itemsize
-                + max(logits, _REMAT_BLOCK_COPIES * rows * costs["width"] * itemsize)))
-        plan.update(
-            remat="selective" if kept else "whole_block",
-            remat_saved=tuple(c.name for c in kept), remat_saved_bytes=kept_bytes,
-            remat_recomputed_flops_share=(
-                costs["recomputed_flops"] - sum(c.flops for c in kept)) / costs["flops"])
-        return plan
+        (B, S + 1) batch (`head_and_remat_for`): `remat` (`off`: nothing is
+        recomputed; `whole_block`; `selective`), `remat_saved` (the
+        `checkpoint_name`s kept), `remat_saved_bytes` (a device) and
+        `remat_recomputed_flops_share` (of the blocks' forward pass, run again
+        in the backward; None for a family that names no candidates)."""
+        return head_and_remat_for(tokens_shape, state)[1]
 
     def microbatch_grads(loss_fn, params, tokens):
         """(the step's scalars: `loss`, `num_tokens` and the routers', grads

@@ -172,14 +172,32 @@ def test_fused_qkv_and_unroll_match_baseline():
 # ------------------------------------------------- what a recomputing block keeps
 
 REMAT_NAMES = ("mlp_up", "mlp_gate", "attn_residual")
+# the attention kernel's output and its lse: named where the kernels run
+# (`attn_impl="pallas"`, interpreted here), and kept together
+ATTN_OUT = ("attn_out", "attn_lse")
+
+
+def _remat_config(preset):
+    """A preset of the dense family by name; `...-pallas`: through the flash
+    kernels; `mixed-pallas`: the tiny mixed stack of tests/test_mixed_stack.py."""
+    if preset == "mixed-pallas":
+        from tests.test_mixed_stack import tiny
+
+        return tiny(attn_impl="pallas")
+    if preset.endswith("-pallas"):
+        return get_config(preset[:-len("-pallas")]).replace(attn_impl="pallas")
+    return get_config(preset)
 
 
 def _remat_loss_and_grads(preset, remat, saved=()):
+    from ray_tpu.models import model_family
     from ray_tpu.train.lm import lm_loss
 
-    config = get_config(preset).replace(remat=remat)
-    params = init_params(config, jax.random.PRNGKey(0))
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 17), 0, config.vocab_size)
+    config = _remat_config(preset).replace(remat=remat)
+    params = model_family(config).init_params(config, jax.random.PRNGKey(0))
+    # the mixed stack's window is 16: a sequence that leaves it
+    seq = 48 if preset == "mixed-pallas" else 16
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, seq + 1), 0, config.vocab_size)
     loss_fn = jax.jit(jax.value_and_grad(
         lambda p: lm_loss(p, tokens, config, remat_saved=saved)[0]))
     return loss_fn(params)
@@ -194,6 +212,9 @@ def _kept_sets(names):
     *(("llama-tiny", kept) for kept in [None, *_kept_sets(REMAT_NAMES)]),
     # a GELU block has no gate: what the rule can choose there
     *(("gpt2-tiny", kept) for kept in [None, *_kept_sets(("mlp_up", "attn_residual"))]),
+    # through the kernels: the attention output with its lse, alone and beside the rest
+    *(("llama-tiny-pallas", kept) for kept in [None, ATTN_OUT, ATTN_OUT + REMAT_NAMES]),
+    *(("mixed-pallas", kept) for kept in [None, ATTN_OUT, ATTN_OUT + REMAT_NAMES]),
 ], ids=lambda v: v if isinstance(v, str) else "+".join(v) if v else "whole_block")
 def test_a_recomputing_block_gives_the_loss_and_gradients_of_one_that_keeps_everything(
         preset, saved):
@@ -244,3 +265,52 @@ def test_gate_and_up_are_not_multiplied_again_in_the_backward_pass_when_kept():
     assert wide_dots(config, ("mlp_up", "mlp_gate")) == kept_all
     # the residual spares the output projection, not these
     assert wide_dots(config, ("attn_residual",)) == kept_all + 2
+
+
+def _kernel_calls(jaxpr, name) -> int:
+    """`pallas_call`s of that name, in a jaxpr and every jaxpr inside it."""
+    count = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            count += eqn.params["name"] == name
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            count += _kernel_calls(sub, name)
+    return count
+
+
+@pytest.mark.parametrize("preset,kernels", [
+    # one scanned block
+    ("llama-tiny-pallas", {"flash_fwd": 1}),
+    # `dS dS` scanned, `eS eF eS eS` unrolled: 1 + 3 windowed bodies, 1 full
+    ("mixed-pallas", {"flash_win_fwd": 4, "flash_fwd": 1}),
+], ids=["dense", "mixed"])
+def test_the_forward_kernel_is_not_run_again_in_the_backward_pass_when_output_and_lse_are_kept(
+        preset, kernels):
+    """The forward flash kernel in the jaxpr of a step's gradient, a block
+    body: once where nothing is recomputed; twice under a whole-block
+    checkpoint; once again when the policy keeps the output AND the lse;
+    twice when it keeps the output alone (the backward kernels read the lse,
+    and only the forward kernel writes it). The backward kernels run once
+    whatever is kept."""
+    from ray_tpu.models import model_family
+    from ray_tpu.train.lm import lm_loss
+
+    config = _remat_config(preset).replace(remat=True)
+    params = model_family(config).init_params(config, jax.random.PRNGKey(0))
+    tokens = jnp.zeros((2, 49), jnp.int32)
+
+    def calls(config, saved=()):
+        grad = jax.grad(lambda p: lm_loss(p, tokens, config, remat_saved=saved)[0])
+        jaxpr = jax.make_jaxpr(grad)(params).jaxpr
+        return ({name: _kernel_calls(jaxpr, name) for name in kernels},
+                {name: _kernel_calls(jaxpr, name.replace("fwd", "bwd_dq")) for name in kernels})
+
+    once, twice = kernels, {name: 2 * n for name, n in kernels.items()}
+    assert calls(config.replace(remat=False)) == (once, once)
+    assert calls(config) == (twice, once)
+    assert calls(config, ATTN_OUT) == (once, once)
+    assert calls(config, ("attn_out",)) == (twice, once)
+    assert calls(config, ("attn_lse",)) == (twice, once)
+    # the matmul outputs spare no kernel
+    assert calls(config, REMAT_NAMES) == (twice, once)
+    assert calls(config, ATTN_OUT + REMAT_NAMES) == (once, once)

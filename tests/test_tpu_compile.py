@@ -14,6 +14,7 @@ instead of the attached device's.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
 
@@ -240,24 +241,31 @@ def test_moe_layer_under_a_mesh_runs_the_kernels_per_shard(v5e, as_tpu):
     assert compiled.memory_analysis().argument_size_in_bytes < 2 * (3 * e * m * f * 4) // 4
 
 
-@pytest.fixture(scope="module")
-def mistral_cell_step(v5e):
-    """What `train-mistral7b-fsdp2tp2` runs, as shapes on the described 2x2:
-    the state as `create_train_state` builds it, 24 x 1,025 tokens over the
-    data axes."""
+def _cell_step_shapes(cell_config, mesh, tokens_shape):
+    """What a training cell runs, as shapes on a described mesh: (its
+    configuration, the optimizer, the state as `create_train_state` builds
+    it, its shardings, a batch of tokens over the data axes)."""
     from benchmark import model_config
     from ray_tpu.train.lm import abstract_train_state, default_optimizer
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     config = model_config.transformer_config(model_config.load_config(
-        os.path.join(root, "benchmark/configs/mistral-7b-v0.3-train-4chip.json")))
-    mesh = build_mesh(MeshSpec(fsdp=2, tp=2), devices=v5e.devices)
+        os.path.join(root, "benchmark/configs", cell_config + ".json")))
     opt = default_optimizer(3e-4, total_steps=1000)
     state, shardings = abstract_train_state(config, opt, mesh)
     state = jax.tree.map(
         lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh), state, shardings)
     tokens = jax.ShapeDtypeStruct(
-        (24, 1025), jnp.int32, sharding=NamedSharding(mesh, PartitionSpec(("dp", "fsdp"), None)))
+        tokens_shape, jnp.int32, sharding=NamedSharding(mesh, PartitionSpec(("dp", "fsdp"), None)))
+    return config, opt, state, shardings, tokens
+
+
+@pytest.fixture(scope="module")
+def mistral_cell_step(v5e):
+    """`train-mistral7b-fsdp2tp2` on the described 2x2: 24 x 1,025 tokens."""
+    mesh = build_mesh(MeshSpec(fsdp=2, tp=2), devices=v5e.devices)
+    config, opt, state, shardings, tokens = _cell_step_shapes(
+        "mistral-7b-v0.3-train-4chip", mesh, (24, 1025))
     return config, opt, mesh, shardings, state, tokens
 
 
@@ -280,7 +288,11 @@ def test_mistral_cell_step_keeps_what_fits_and_compiles(
     all-reduce less in the text, and no more than 4.2 GiB of temporaries
     over the whole-block program's (3.95 by this compiler's count, which
     read 2.1 GiB over the chip's peak for the whole-block step and 25% over
-    the kept values' own bytes: PERF.md section 6, PR 30)."""
+    the kept values' own bytes: PERF.md section 6, PR 30). The attention
+    kernel's output is a candidate since PR 34 and is not kept here: at
+    S = 1,024 it is worth less than keeping it moves (with it this compiler
+    counts 4.34 GiB over and three kernel calls, the chip 0.41 GB more and
+    no gain: PERF.md section 6, PR 34)."""
     from ray_tpu.ops import losses
     from ray_tpu.train.lm import make_train_step
 
@@ -304,3 +316,51 @@ def test_mistral_cell_step_keeps_what_fits_and_compiles(
         assert plan["remat_saved_bytes"] / GIB == pytest.approx(3.375, abs=0.001)
         assert tflop <= WHOLE_BLOCK_TFLOP - 1.6
         assert all_reduces == 20
+
+
+def _kernels_named(compiled, name) -> int:
+    """Custom calls of the Pallas kernel `name` in the compiled module."""
+    return len(re.findall(rf"^\s*%{name}[.\d]* = .*custom-call\(", compiled.as_text(), re.M))
+
+
+def test_trinity_cell_step_keeps_the_attention_outputs_and_compiles(as_tpu, monkeypatch, v5e):
+    """`train-trinity-mini-8k`'s whole step (2 x 8,193 tokens, the state as
+    `create_train_state` builds it) for one described v5e chip of 15.75 GiB:
+    the rule chunks the head and keeps the attention kernels' outputs, and
+    the compiled step runs each forward flash kernel once a layer (the
+    two scanned dense layers' in the forward loop's body alone, the four
+    unrolled layers' once each) beside its two backward kernels; a
+    whole-block step runs it twice. The kernel's own lse, (B, H, S, 1), is
+    what a tiled layout pads 128 times: the value kept is (B, H, S)."""
+    from ray_tpu.ops import losses
+    from ray_tpu.train.lm import make_train_step
+
+    mesh = build_mesh(MeshSpec(), devices=[as_tpu])
+    config, opt, state, shardings, tokens = _cell_step_shapes(
+        "trinity-mini-train-1chip", mesh, (2, 8193))
+    monkeypatch.setattr(losses, "device_hbm_bytes", lambda: int(15.75 * GIB))
+    step = make_train_step(config, opt, mesh, state_shardings=shardings)
+    plan = step.remat_plan_for(tokens.shape, state)
+    assert (plan["remat"], plan["remat_saved"]) == ("selective", ("attn_out", "attn_lse"))
+    assert step.loss_chunk_for(tokens.shape, state) == 2048
+    compiled = step.lower(state, {"tokens": tokens}).compile()
+    # dS dS scanned (one body forward, one backward), eS eF eS eS unrolled
+    assert _kernels_named(compiled, "flash_win_fwd") == 1 + 3
+    assert _kernels_named(compiled, "flash_fwd") == 1
+    for backward in ("flash_win_bwd_dkv", "flash_win_bwd_dq"):
+        assert _kernels_named(compiled, backward) == 1 + 3
+    text = compiled.as_text()
+    assert "f32[2,32,8192,1]{3,2,1,0:T(8,128)}" in text      # 268 MB where written
+    assert "f32[2,32,8192]{2,1,0:T(8,128)" in text           # 2.1 MB where kept
+    # `lse_first`: in the schedule every unrolled layer's lse is reshaped before the
+    # first backward kernel runs (left alone, where the backward reads it)
+    lines = text[text.index("\nENTRY "):].splitlines()
+    first_backward = next(i for i, line in enumerate(lines) if "%flash_win_bwd_dkv" in line.split("=")[0])
+    lse_outputs = [re.match(r"\s*(%[\w.\-]+) = f32\[2,32,8192,1\]", line).group(1) for line in lines
+                   if re.match(r"\s*%[\w.\-]+ = f32\[2,32,8192,1\].* get-tuple-element\(%flash_(win_)?fwd", line)]
+    assert len(lse_outputs) == 4
+    for name in lse_outputs:
+        reader = next(i for i, line in enumerate(lines) if f"({name})" in line or f"({name}," in line)
+        assert reader < first_backward
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes / GIB == pytest.approx(8.61, abs=0.02)

@@ -302,85 +302,158 @@ def _cell_step(monkeypatch, cell_config, mesh_spec, hbm_bytes, **changed):
 
 
 MISTRAL = ("mistral-7b-v0.3-train-4chip", MeshSpec(fsdp=2, tp=2))
+TRINITY = ("trinity-mini-train-1chip", MeshSpec())
 
 
-EVERY_NAME = ("attn_residual", "mlp_up", "mlp_gate")
+MATMUL_NAMES = ("attn_residual", "mlp_up", "mlp_gate")
+ATTN_OUT = ("attn_out", "attn_lse")   # the kernel's output and its lse: one candidate
 
 
 @pytest.mark.parametrize("cell,mesh_spec,hbm,batch,seq,changed,want", [
-    (*MISTRAL, V5E_HBM, 24, 1024, {}, ("selective", EVERY_NAME)),
-    # nothing as wide as gate or up fits beside two more layers of state
-    (*MISTRAL, V5E_HBM, 24, 1024, {"n_layers": 10}, ("selective", ("attn_residual",))),
-    # no room at all: today's program, not an out-of-memory error
-    (*MISTRAL, V5E_HBM, 24, 1024, {"n_layers": 12}, ("whole_block", ())),
-    (*MISTRAL, V5E_HBM, 48, 1024, {}, ("whole_block", ())),
+    # every matmul output, as since PR 30. Not the attention output, which has room (0.41 GB
+    # a device): at S = 1,024 the kernel's second run costs no more than keeping its
+    # results moves (the chip: 38,334 tokens/s without it, 38,318 with it, PERF.md section 6)
+    (*MISTRAL, V5E_HBM, 24, 1024, {}, ("selective", MATMUL_NAMES, 0)),
+    # beside two more layers of state one value as wide as up fits, not two (before PR 34's
+    # refit, which leaves 6.5% of the chip free where it left 10%: the narrow residual alone)
+    (*MISTRAL, V5E_HBM, 24, 1024, {"n_layers": 10}, ("selective", ("attn_residual", "mlp_up"), 0)),
+    # no room at all: the whole-block program, not an out-of-memory error
+    (*MISTRAL, V5E_HBM, 24, 1024, {"n_layers": 12}, ("whole_block", (), 0)),
+    # twice the batch: the narrow residual (before the refit: nothing); with ten layers nothing
+    (*MISTRAL, V5E_HBM, 48, 1024, {}, ("selective", ("attn_residual",), 0)),
+    (*MISTRAL, V5E_HBM, 48, 1024, {"n_layers": 10}, ("whole_block", (), 512)),
     # a device of unknown size (the CPU): the step that fits wherever anything does
-    (*MISTRAL, 0, 24, 1024, {}, ("whole_block", ())),
-    ("gpt2-small-train-1chip", MeshSpec(), V5E_HBM, 24, 1024, {}, ("off", ())),
-    ("olmoe-1b-7b-train-1chip", MeshSpec(), V5E_HBM, 4, 4096, {}, ("off", ())),
+    (*MISTRAL, 0, 24, 1024, {}, ("whole_block", (), 0)),
+    ("gpt2-small-train-1chip", MeshSpec(), V5E_HBM, 24, 1024, {}, ("off", (), 0)),
+    ("olmoe-1b-7b-train-1chip", MeshSpec(), V5E_HBM, 4, 4096, {}, ("off", (), 2048)),
     # a family that names no candidates is recomputed whole wherever it recomputes
     ("olmoe-1b-7b-train-1chip", MeshSpec(), 4 * V5E_HBM, 4, 4096, {"remat": True},
-     ("whole_block", ())),
+     ("whole_block", (), 0)),
+    # the mixed stack: the attention kernels' outputs, beside the chunked head
+    (*TRINITY, V5E_HBM, 2, 8192, {}, ("selective", ATTN_OUT, 2048)),
+    (*TRINITY, V5E_HBM, 4, 8192, {}, ("whole_block", (), 2048)),
+    (*TRINITY, 0, 2, 8192, {}, ("whole_block", (), 0)),
 ], ids=["mistral-2x2", "mistral-2x2-10-layers", "mistral-2x2-12-layers", "mistral-2x2-batch-48",
-        "mistral-2x2-unknown-size", "gpt2s", "olmoe", "olmoe-with-remat"])
+        "mistral-2x2-batch-48-10-layers", "mistral-2x2-unknown-size", "gpt2s", "olmoe", "olmoe-with-remat",
+        "trinity", "trinity-batch-4", "trinity-unknown-size"])
 def test_remat_rule_on_the_cells_numbers(monkeypatch, cell, mesh_spec, hbm, batch, seq, changed, want):
-    """`make_train_step(...).remat_plan_for(shape, state)` at the shipped
-    cells' widths, meshes and batches, on a v5e's 15.75 GiB: the one cell
-    that recomputes keeps every named value and leaves the stated share of
-    the chip free; with ten layers only the narrow one fits, with twelve or
-    twice the batch nothing does and the whole block is recomputed, as on a
-    device whose size is unknown; the cells without `remat` recompute
-    nothing. The same numbers, the same plan: nothing live is read but the
+    """`make_train_step(...)`'s `remat_plan_for(shape, state)` and
+    `loss_chunk_for(shape, state)` at the shipped cells' widths, meshes and
+    batches, on a v5e's 15.75 GiB. The Mistral cell keeps every named matmul
+    output and leaves the stated share of the chip free; with ten layers or
+    twice the batch fewer fit, with twelve or with both nothing does and the
+    whole block is recomputed, as on a device whose size is unknown. The
+    attention output is worth keeping at S = 8,192, not at 1,024. The Trinity cell
+    keeps the attention kernels' outputs of its mixed stack beside the
+    CHUNKED head (its dense head's logits, 4.1 GB, are larger than any
+    block's backward pass, and the next candidate has no room): the two
+    scanned dense layers' kept values are still held when every gradient
+    exists, which is the moment that binds; twice the batch keeps nothing; an
+    unknown size keeps nothing and the dense head. The cells without `remat`
+    recompute nothing, and their heads are what `auto_loss_chunk` alone
+    says. The same numbers, the same plan: nothing live is read but the
     device's size."""
     from ray_tpu.train import lm
 
     step, state = _cell_step(monkeypatch, cell, mesh_spec, hbm, **changed)
     plan = step.remat_plan_for((batch, seq + 1), state)
-    assert (plan["remat"], plan["remat_saved"]) == want
+    assert (plan["remat"], plan["remat_saved"],
+            step.loss_chunk_for((batch, seq + 1), state)) == want
     assert step.remat_plan_for((batch, seq + 1), state) is plan
     if want[0] == "off":
         assert plan["remat_recomputed_flops_share"] == 0.0 and plan["remat_saved_bytes"] == 0
     if cell == MISTRAL[0] and want[0] == "whole_block":
         # all of the block but the down projection: 319 + 8 of 436 + 8 MFLOP a token
         assert plan["remat_recomputed_flops_share"] == pytest.approx(0.736, abs=0.001)
-    if cell != MISTRAL[0] and want[0] == "whole_block":
+    if cell.startswith("olmoe") and want[0] == "whole_block":
         assert plan["remat_recomputed_flops_share"] is None
-    if want == ("selective", EVERY_NAME):
+    if cell == TRINITY[0] and want[0] == "whole_block":
+        # a norm follows every sublayer's output: nothing of a block is spared
+        assert plan["remat_recomputed_flops_share"] == 1.0
+    if cell == TRINITY[0] and want[0] == "selective":
+        # 16,384 rows x (32 heads of 128 + their lse, 4 B a head) x 2 B x 6 layers
+        assert plan["remat_saved_bytes"] == 16384 * (4096 + 64) * 2 * 6
+        # the scores of five windowed layers and a full one, of the stack's forward
+        assert plan["remat_recomputed_flops_share"] == pytest.approx(0.712, abs=0.001)
+    if want[:2] == ("selective", MATMUL_NAMES):
         # 12,288 rows a device x (its half of gate and of up + the residual) x 2 B x 8 layers
         assert plan["remat_saved_bytes"] == 12288 * (2 * 7168 + 4096) * 2 * 8
         # what is left to recompute: q, k, v and the attention kernel
         assert plan["remat_recomputed_flops_share"] == pytest.approx(0.132, abs=0.001)
         # beside the whole-block step as the chip measured it (11.145 GB; the
-        # estimate reads a little over), the stated share stays free
+        # estimate reads a little under), the stated share stays free
         assert 11.145e9 + plan["remat_saved_bytes"] < (1 - lm._REMAT_FREE_FRACTION) * hbm
-
-
 def test_remat_rule_keeps_in_order_of_recomputation_spared_a_byte():
     """The candidates one by one, not as a pair: with room for one d_ff-wide
     value, one is kept and the residual beside it; a spared all-reduce
-    counts, so under tensor parallelism the residual can come first."""
+    counts, so under tensor parallelism the residual can come first; a
+    candidate is as large as the layers that write it."""
     from ray_tpu.models.transformer import RematCandidate
-    from ray_tpu.train.lm import _ALL_REDUCE_FLOPS_PER_BYTE, auto_remat_saved
+    from ray_tpu.train.lm import _ALL_REDUCE_FLOPS_PER_BYTE, _REMAT_FREE_FRACTION, auto_remat_saved
+
+    def held_bytes(cs):
+        return sum(100 * sum(c.layers) * 2 * c.width for c in cs)
 
     def kept(candidates, room_rows):
-        size = dict(rows=100, layers=2, itemsize=2)
-        got, held = auto_remat_saved(candidates, **size, whole_block_bytes=0,
-                                     hbm_bytes=int(room_rows * 100 * 2 * 2 / 0.9) + 1)
-        assert held == sum(100 * 2 * 2 * c.width for c in got)
-        return tuple(c.name for c in got)
+        got, held = auto_remat_saved(
+            candidates, rows=100, itemsize=2, peak_bytes=held_bytes,
+            hbm_bytes=int(room_rows * 100 * 2 * 2 / (1 - _REMAT_FREE_FRACTION)) + 1)
+        assert held == held_bytes(got)
+        return tuple(name for c in got for name in c.names)
 
-    up, gate = RematCandidate("mlp_up", 64, 8192, False), RematCandidate("mlp_gate", 64, 8192, False)
-    residual = RematCandidate("attn_residual", 16, 512, False)
+    up = RematCandidate(("mlp_up",), 64, 8192, 8192, False, (2,))
+    gate = RematCandidate(("mlp_gate",), 64, 8192, 8192, False, (2,))
+    residual = RematCandidate(("attn_residual",), 16, 512, 512, False, (2,))
     assert kept((up, gate, residual), 64 + 64 + 16) == ("mlp_up", "mlp_gate", "attn_residual")
     assert kept((up, gate, residual), 64 + 16) == ("mlp_up", "attn_residual")
     assert kept((up, gate, residual), 63) == ("attn_residual",)
     assert kept((up, gate, residual), 15) == ()
-    assert auto_remat_saved((up, gate), rows=100, layers=2, itemsize=2, whole_block_bytes=0,
+    assert auto_remat_saved((up, gate), rows=100, itemsize=2, peak_bytes=held_bytes,
                             hbm_bytes=0) == ((), 0)
     # 64 FLOPs a byte against 16 and an all-reduce
     exposed = residual._replace(all_reduce=True)
     assert _ALL_REDUCE_FLOPS_PER_BYTE > 48
     assert kept((up, gate, exposed), 64 + 16) == ("attn_residual", "mlp_up")
+    # two names under one candidate are kept together or not at all, and a
+    # candidate that one layer in two writes takes half the room
+    pair = RematCandidate(("attn_out", "attn_lse"), 32, 8192, 8192, False, (1, 0))
+    assert kept((pair, residual), 16 + 16) == ("attn_out", "attn_lse", "attn_residual")
+    assert kept((pair, residual), 16 + 15) == ("attn_out", "attn_lse")
+    # what costs as much to keep as it spares is left out though it fits
+    assert kept((pair._replace(worth=0), residual), 16 + 16) == ("attn_residual",)
+
+
+def test_step_peak_counts_a_scanned_run_whole_and_an_unrolled_run_by_its_larger_half():
+    """The estimate's moments on a stack of a scanned run then an unrolled
+    one (the Trinity cell's order): a value kept in the scanned run is still
+    held when every gradient exists and adds its bytes; one kept in the
+    unrolled run goes as that run's gradients come, so it is free while it is
+    the smaller of the two; the head's moment counts the logits beside
+    everything kept and no block's gradient."""
+    from ray_tpu.models.transformer import RematCandidate
+    from ray_tpu.train.lm import step_peak_bytes
+
+    scanned = {"scanned": True, "gradients": 300, "inputs": 40, "block": 100}
+    unrolled = {"scanned": False, "gradients": 500, "inputs": 80, "block": 100}
+
+    def peak(kept, logits=0, runs=(scanned, unrolled)):
+        return step_peak_bytes(kept, rows=10, itemsize=2, always=1000.0, logits=logits, runs=runs)
+
+    # the scanned run's backward: the unrolled run's gradients, a block, its own gradients and inputs
+    assert peak(()) == 1000 + 500 + 100 + 300 + 40
+    assert peak((), logits=2000) == 1000 + 40 + 80 + 2000
+    in_unrolled = RematCandidate(("a",), 5, 1, 1, False, (0, 4))     # 4 x 10 x 5 x 2 = 400 B
+    in_scanned = RematCandidate(("b",), 5, 1, 1, False, (2, 0))      # 200 B
+    in_both = RematCandidate(("c",), 5, 1, 1, False, (2, 4))
+    assert peak((in_unrolled,)) == peak(())
+    assert peak((in_scanned,)) == peak(()) + 200
+    assert peak((in_both,)) == peak(()) + 200
+    # past the run's gradients an unrolled run's kept values do count: its own backward
+    # holds the scanned run's inputs, a block, and the larger of 500 and 2 x 400 + 80
+    assert peak((in_unrolled, in_unrolled._replace(names=("d",)))) == max(
+        peak(()), 1000 + 40 + 100 + 880)
+    # one scan alone (the dense family): everything at once
+    assert peak((in_scanned,), logits=150, runs=(scanned,)) == 1000 + 100 + 300 + 40 + 200
 
 
 def test_selective_step_on_a_mesh_gives_one_devices_loss(monkeypatch):

@@ -92,16 +92,23 @@ def test_step_fn_span_names_the_attention_the_step_runs(trainer, monkeypatch):
     trainer._note_step_plan((2, 17))
 
 
-@pytest.mark.parametrize("hbm_bytes,want", [
-    (0, ("whole_block", ())),                       # the CPU: a device of unknown size
-    (10 ** 9, ("selective", ("attn_residual", "mlp_up", "mlp_gate"))),
-], ids=["unknown-device-size", "room-for-every-name"])
-def test_step_fn_span_names_what_a_recomputing_step_keeps(monkeypatch, hbm_bytes, want):
+@pytest.mark.parametrize("hbm_bytes,kernel_move_cost,want", [
+    (0, None, ("whole_block", ())),                 # the CPU: a device of unknown size
+    # at 16 tokens the attention kernel's output is worth less than keeping it moves
+    (10 ** 9, None, ("selective", ("attn_residual", "mlp_up", "mlp_gate"))),
+    # were keeping it free (as it all but is at the cells' 8,192), both its names follow
+    (10 ** 9, 0, ("selective", ("attn_residual", "mlp_up", "mlp_gate", "attn_out", "attn_lse"))),
+], ids=["unknown-device-size", "room-for-every-name", "and-the-attention-output"])
+def test_step_fn_span_names_what_a_recomputing_step_keeps(
+        monkeypatch, hbm_bytes, kernel_move_cost, want):
     """`train.init.step_fn` of a model with `remat`: the four `remat*` keys,
     and what they report is what the step's trace gave the blocks'
     checkpoint."""
     from ray_tpu.models import transformer
     from ray_tpu.ops import losses
+
+    if kernel_move_cost is not None:
+        monkeypatch.setattr(transformer, "_KEPT_KERNEL_FLOPS_PER_BYTE", kernel_move_cost)
 
     traced = []
     checkpoint_block = transformer.checkpoint_block
@@ -116,7 +123,11 @@ def test_step_fn_span_names_what_a_recomputing_step_keeps(monkeypatch, hbm_bytes
     assert traced and set(traced) == {want[1]}
     assert attrs == {**attrs, **trainer.step_fn.remat_plan_for((16, 17), trainer.state)}
     rows, itemsize = 4 * 16, jnp.dtype(config.dtype).itemsize
+    # a device's half of gate and up and the residual; its half of the attention output
+    # with the lse of its 2 heads
     kept_width = (2 * config.d_ff // 2 + config.d_model) if want[1] else 0
+    if "attn_out" in want[1]:
+        kept_width += config.d_model // 2 + 4 * 2 // itemsize
     assert attrs["remat_saved_bytes"] == config.n_layers * rows * itemsize * kept_width
     assert 0 < attrs["remat_recomputed_flops_share"] < (0.3 if want[1] else 0.9)
 
