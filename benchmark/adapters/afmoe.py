@@ -75,6 +75,20 @@ def shapes(conf):
     )
 
 
+def expert_layer(conf):
+    """`model_config.expert_layer`: the routed experts held here of the
+    published ones, the experts a token is sent to, the hidden size and one
+    expert's width."""
+    return {"held": conf["num_experts"], "published": _published(conf, "num_experts"),
+            "per_token": conf["num_experts_per_tok"], "hidden": conf["hidden_size"],
+            "width": conf["moe_intermediate_size"]}
+
+
+def attention_window(conf):
+    """`model_config.attention_window`: the keys a sliding layer's query sees."""
+    return conf["sliding_window"]
+
+
 def train_flops_per_token(conf, seq):
     """Operations a trained token REQUIRES here (model_config.
     train_flops_per_token's docstring): two a weight of every matmul it

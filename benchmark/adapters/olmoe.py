@@ -40,6 +40,15 @@ def shapes(conf):
     )
 
 
+def expert_layer(conf):
+    """`model_config.expert_layer`: every published expert is held; an
+    expert's width is this family's `intermediate_size`. No layer has a
+    window, so there is no `attention_window`."""
+    return {"held": conf["num_experts"], "published": conf["num_experts"],
+            "per_token": conf["num_experts_per_tok"], "hidden": conf["hidden_size"],
+            "width": conf["intermediate_size"]}
+
+
 def _arch(conf):
     return {"top_k": conf["num_experts_per_tok"], "norm_topk_prob": conf["norm_topk_prob"],
             "rope_theta": float(conf["rope_theta"]), "norm_eps": float(conf["rms_norm_eps"])}

@@ -5,8 +5,8 @@ level (as run: a key named in `reduced` carries the reduced value and
 `published` the original), and nested groups for what the benchmark
 sets itself (`engine` or `trainer`, `dtype`, `program`, `sizing`,
 `assumed`, `departures`, and `share` where the chip holds its share of
-each layer). What depends on the model's family is in
-benchmark/adapters/<model_type>.py.
+each layer). What depends on the model's family, the names of its keys
+among it, is in benchmark/adapters/<model_type>.py.
 """
 
 from __future__ import annotations
@@ -14,7 +14,7 @@ from __future__ import annotations
 import importlib
 import json
 import os
-from typing import Any, Dict
+from typing import Any, Dict, Optional
 
 # What `reduced` may name, and nothing else (the model-configs guide, section
 # 4): the depth, or a COUNT of which this chip holds its share. Each is a
@@ -27,27 +27,48 @@ DEPTH_KEYS = {
     "n_layer": "GPT-2's name for the same",
     "num_layers": "the same",
 }
+# A share key names its ROLE, and the floor hangs on the role (`SHARE_ROLES`): the
+# routed experts are counted under five names in the catalog beside the guide, and a
+# synonym without the floor would be a way round it. A closed list in the benchmark's
+# own file: an adapter or a configuration file (both arrive with a `model_config` PR)
+# cannot declare a key a count.
 SHARE_KEYS = {
-    "num_experts": "routed experts held, each a whole expert of the published width; the router keeps its published outputs",
-    "vocab_size": "rows of the embedding and columns of the head held, each of the published hidden size",
+    "num_experts": "experts",
+    "n_routed_experts": "experts",
+    "num_local_experts": "experts",
+    "moe_num_experts": "experts",
+    "moe_num_primary_experts": "experts",
+    "vocab_size": "vocabulary",
 }
-# Every share key has a floor below. Heads held as a share (attention, key-value,
-# linear-attention or state-space heads) are not admitted yet: they want a floor and
-# a rule that keeps query and key-value heads in step, and a `benchmark` PR adds
-# them with both, under the names the configuration that needs them uses.
-# the guide's floors for a share: what is left is still the model
+# role -> why it is a count; its floor is below and in `check_reduced`
+SHARE_ROLES = {
+    "experts": "routed experts held, each a whole expert of the published width; the router keeps its published outputs",
+    "vocabulary": "rows of the embedding and columns of the head held, each of the published hidden size",
+}
+# Not here, so refused by name: every count that is not whole routed experts of the
+# published width (`n_shared_experts`, `num_shared_experts`, `zero_expert_num`, the
+# experts a token is sent to under any name: `num_experts_per_tok`,
+# `num_experts_per_token`, `experts_top_k`, `moe_num_active_primary_experts`, and the
+# expert-group keys), every other vocabulary-like key (`unpadded_vocab_size`, n-gram and
+# embedding-table sizes), and heads of any kind. Heads held as a share (attention,
+# key-value, linear-attention or state-space heads) are not admitted yet: they want a
+# floor and a rule that keeps query and key-value heads in step, and a `benchmark` PR
+# adds them with both, under the names the configuration that needs them uses.
+# The guide's floors for a share, by role: what is left is still the model.
 MIN_EXPERTS_HELD = 8
 MIN_VOCAB_SHARE = 8     # at least 1/8 of the published vocabulary
 
 
 def check_reduced(conf: Dict[str, Any], name: str = "configuration") -> None:
     """Refuse, with the key in the message, a `reduced` that names anything
-    but the depth or a count held as this chip's share (the lists above).
-    A share key needs the file's `share` group, which states over how many
-    chips each layer is divided and how; the published count must be a whole
-    multiple of the held one, at most that many times it."""
+    but the depth or a count held as this chip's share (the lists above), or
+    two keys of one role. A share key needs the file's `share` group, which
+    states over how many chips each layer is divided and how; the published
+    count must be a whole multiple of the held one, at most that many times
+    it; the floor is its role's."""
     published = conf.get("published", {})
     share = conf.get("share")
+    named: Dict[str, str] = {}
     for key in conf["reduced"]:
         if key not in DEPTH_KEYS and key not in SHARE_KEYS:
             raise ValueError(
@@ -58,6 +79,11 @@ def check_reduced(conf: Dict[str, Any], name: str = "configuration") -> None:
                              "another value for it than the one run")
         if key in DEPTH_KEYS:
             continue
+        role = SHARE_KEYS[key]
+        if role in named:
+            raise ValueError(f"{name}: `reduced` names {named[role]!r} and {key!r}, two keys of "
+                             f"the role {role!r}: a file counts them under one")
+        named[role] = key
         if (not isinstance(share, dict) or not isinstance(share.get("chips_sharing_a_layer"), int)
                 or share["chips_sharing_a_layer"] < 2 or not share.get("how")):
             raise ValueError(
@@ -68,9 +94,9 @@ def check_reduced(conf: Dict[str, Any], name: str = "configuration") -> None:
             raise ValueError(
                 f"{name}: {key!r} holds {held} of the published {whole}: the published count must be "
                 f"a whole multiple of the held one, at most chips_sharing_a_layer = {chips} times it")
-        if key == "num_experts" and held < MIN_EXPERTS_HELD:
+        if role == "experts" and held < MIN_EXPERTS_HELD:
             raise ValueError(f"{name}: {key!r} holds {held} routed experts; the floor is {MIN_EXPERTS_HELD}")
-        if key == "vocab_size" and held * MIN_VOCAB_SHARE < whole:
+        if role == "vocabulary" and held * MIN_VOCAB_SHARE < whole:
             raise ValueError(f"{name}: {key!r} holds {held} of {whole} rows; the floor is "
                              f"1/{MIN_VOCAB_SHARE} of the published vocabulary")
 
@@ -109,6 +135,30 @@ def transformer_config(conf: Dict[str, Any]):
 def shape_numbers(conf: Dict[str, Any]) -> Dict[str, Any]:
     """The sizes the cost functions in roofline.py take."""
     return adapter(conf).shapes(conf)
+
+
+def _family_says(conf: Dict[str, Any], what: str) -> Any:
+    """The adapter's own function of that name on the file, None where the
+    family's adapter has no such function."""
+    own = getattr(adapter(conf), what, None)
+    return None if own is None else own(conf)
+
+
+def expert_layer(conf: Dict[str, Any]) -> Optional[Dict[str, int]]:
+    """The sizes of the family's routed expert layer, by the adapter's own
+    `expert_layer(conf)`: `held` experts here of the `published` ones, the
+    experts `per_token`, the `hidden` size and ONE expert's `width`. None
+    where the family's adapter has no such function (it has no expert layer):
+    the readers that price one then have nothing to read. Beside `shapes`
+    and not in it: `shapes` is what `roofline.train_flops_per_token` takes."""
+    return _family_says(conf, "expert_layer")
+
+
+def attention_window(conf: Dict[str, Any]) -> Optional[int]:
+    """The keys a query of a windowed attention layer sees, by the adapter's
+    own `attention_window(conf)`; None where the family has no such function
+    or the configuration no window."""
+    return _family_says(conf, "attention_window")
 
 
 def train_flops_per_token(conf: Dict[str, Any], seq: int) -> float:

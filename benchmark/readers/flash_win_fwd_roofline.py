@@ -2,8 +2,9 @@
 `flash_win_fwd`'s calls in the trace x the required work of one windowed call
 (benchmark/window_cost.py: the live pairs sum_i min(i + 1, W), every q, k, v,
 output and lse row once), over the kernel's device time. A program that runs
-no such kernel (the parent of the PR that brought it, a configuration
-without a window) has nothing to read."""
+no such kernel (the parent of the PR that brought it), or a configuration
+whose family states no window (`model_config.attention_window`), has
+nothing to read."""
 
 from .. import model_config, roofline, window_cost
 from ..trace_reduce import count_of, seconds_of
@@ -12,7 +13,7 @@ PREFIXES = ("flash_win_fwd",)
 
 
 def read(ctx):
-    trace, window = ctx.get("trace"), ctx["conf"].get("sliding_window")
+    trace, window = ctx.get("trace"), model_config.attention_window(ctx["conf"])
     if not trace or not window:
         return None
     kernel_s, calls = seconds_of(trace, PREFIXES), count_of(trace, PREFIXES)

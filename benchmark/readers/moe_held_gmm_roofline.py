@@ -12,9 +12,10 @@ trace covers a segment of the window, not the last report's step. Under one
 row tile a group (`moe_gmm_tile_rows` of the `train.init.step_fn` span)
 there is nothing to measure: the kernels' time is then the held experts'
 weights alone, whatever the rows, and no number is given. A program without
-the counters or without the kernels has nothing to read."""
+the counters or without the kernels, or a family without an expert layer
+(`model_config.expert_layer`), has nothing to read."""
 
-from .. import moe_cost, roofline
+from .. import model_config, moe_cost, roofline
 from ..trace_reduce import count_of, seconds_of
 from .moe_held_rows_off_even import window_reports
 from .program_spans import program_spans
@@ -34,13 +35,14 @@ def read(ctx):
         return None
     kernel_s, calls = seconds_of(trace, PREFIXES), count_of(trace, PREFIXES)
     rows, passes = window_reports(ctx, "moe_rows_held"), window_reports(ctx, "moe_passes")
-    if kernel_s <= 0 or not calls or not rows or not passes or len(rows) != len(passes):
+    experts = model_config.expert_layer(ctx["conf"])
+    if (kernel_s <= 0 or not calls or not rows or not passes or len(rows) != len(passes)
+            or not experts):
         return None
     rows = sum(r / max(p, 1.0) for r, p in zip(rows, passes)) / len(rows)
-    conf = ctx["conf"]
-    if rows < conf["num_experts"] * _tile_rows():
+    if rows < experts["held"] * _tile_rows():
         return None
-    cost = moe_cost.gmm_cost(rows=rows, k=conf["hidden_size"], n=conf["moe_intermediate_size"],
-                             groups=conf["num_experts"])
+    cost = moe_cost.gmm_cost(rows=rows, k=experts["hidden"], n=experts["width"],
+                             groups=experts["held"])
     least = roofline.roofline_seconds(cost, ctx["device"]["kind"])["seconds"]
     return 100.0 * calls * least / kernel_s

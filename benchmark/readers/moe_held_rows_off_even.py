@@ -5,10 +5,12 @@ ended inside the window (each carries the step's own count: rows routed to
 held experts over T k, mean over the expert layers). The share itself has no
 better direction (a router that sends nothing here, or everything, is at
 fault either way, and the rate follows the rows); the distance has: 0 is a
-router that loads the published experts evenly. A program without the span
-record or the counter, or a configuration that holds every expert, has
-nothing to read."""
+router that loads the published experts evenly. Held and published are the
+family's to say (`model_config.expert_layer`). A program without the span
+record or the counter, a family without an expert layer, or a configuration
+that holds every expert, has nothing to read."""
 
+from .. import model_config
 from .program_spans import program_spans
 
 ATTRIBUTE = "moe_rows_held_share"
@@ -26,9 +28,8 @@ def window_reports(ctx, attribute, span="train.report"):
 
 
 def read(ctx):
-    conf = ctx["conf"]
-    published = conf.get("published", {}).get("num_experts")
+    experts = model_config.expert_layer(ctx["conf"])
     shares = window_reports(ctx, ATTRIBUTE)
-    if not shares or not published or published == conf["num_experts"]:
+    if not shares or not experts or experts["published"] == experts["held"]:
         return None
-    return abs(sum(shares) / len(shares) - 100.0 * conf["num_experts"] / published)
+    return abs(sum(shares) / len(shares) - 100.0 * experts["held"] / experts["published"])

@@ -82,7 +82,10 @@ def test_cells_find_their_files(benchmark_json):
 
 
 @pytest.mark.parametrize("key", ["hidden_size", "intermediate_size", "num_experts_per_tok",
-                                 "head_dim", "kv_lora_rank", "moe_intermediate_size"])
+                                 "head_dim", "kv_lora_rank", "moe_intermediate_size",
+                                 "moe_ffn_hidden_size", "moe_num_active_primary_experts",
+                                 "n_shared_experts", "zero_expert_num", "num_attention_heads",
+                                 "sliding_window_size"])
 def test_an_entry_whose_reduced_names_a_width_is_refused(benchmark_json, key):
     """Through BENCHMARK.json's own `reduced`: the shipped OLMoE file, with
     an entry that claims one more key."""

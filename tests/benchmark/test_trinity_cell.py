@@ -196,7 +196,8 @@ def test_held_readers_price_the_rows_the_program_counted(monkeypatch):
     assert moe_held_rows_off_even.read(_ctx(trace)) is None
     assert moe_held_gmm_roofline.read(_ctx(None)) is None
     # a configuration that holds every expert has no share to be off
-    assert moe_held_rows_off_even.read(dict(_ctx(trace), conf={"num_experts": 64})) is None
+    olmoe = load(os.path.join(ROOT, "benchmark", "configs", "olmoe-1b-7b-train-1chip.json"))
+    assert moe_held_rows_off_even.read(dict(_ctx(trace), conf=olmoe)) is None
 
 
 def test_cell_joins_the_shared_metrics_and_not_the_two_that_would_misread(benchmark_json):
