@@ -4,9 +4,14 @@ The dense and MoE families (transformer.py, moe.py) scan one block over a
 stack of identical layers. Here the configuration says, a layer, its
 attention kind ("sliding": a causal window with rotary positions; "full":
 every causal key and no positional encoding) and its MLP kind ("dense": one
-SwiGLU; "experts": a routed expert layer beside a shared expert), by two
-rules on the layer's index: every `global_attn_every`-th layer is full, the
-first `n_dense_layers` are dense. `stack_runs` groups the layers into runs
+SwiGLU; "experts": a routed expert layer, beside a shared expert where the
+configuration has one), by two rules on the layer's index: one layer of every
+`global_attn_every` is full (the last of its period, or the first where
+`global_attn_first`), the first `n_dense_layers` are dense. Which leaves a
+layer has is data too (`_layer_shapes`: the output gate, the QK-norm, the
+norms on a sublayer's output, the selection bias and the shared expert exist
+where the configuration's flags say so), as is the tensor an expert layer's
+router reads (`router_input`). `stack_runs` groups the layers into runs
 of a repeated period of kinds; the forward scans each run over its repeats
 and unrolls the period inside the scan's body, so compile time grows with
 the period and not with the depth, and parameters are stacked a run: run r,
@@ -55,9 +60,28 @@ class MixedStackConfig(MoEConfig):
     the width of a dense layer's MLP."""
 
     sliding_window: int = 2048
-    global_attn_every: int = 4     # layer i is full iff (i + 1) % this == 0
+    global_attn_every: int = 4     # layer i is full iff (i + 1) % this == 0 ...
+    global_attn_first: bool = False  # ... or, the full layer FIRST in its period, iff i % this == 0
     n_dense_layers: int = 0        # the leading layers whose MLP is dense
     d_ff_dense: int = 0
+    # what an expert layer's router reads: "mlp", the normed tensor its
+    # experts read, or "attention", the tensor the layer's ATTENTION sublayer
+    # takes (the layer's input, before that sublayer's norm): the routing is
+    # then decided before attention has run
+    router_input: str = "mlp"
+    # the deviation the embedding's rows start at. Blocks that put no norm on
+    # a sublayer's output add every branch to the stream at its own size: at
+    # 0.02 an untrained attention layer's output (the mean of the values, all
+    # but the same vector for every query) outgrows the token's embedding
+    # within a layer, and every token then chooses the same experts
+    # (POST_NORM_GAIN's finding, for the family that has such norms)
+    embedding_std: float = 0.02
+    # the deviation a router's weights start at. A scale changes no choice of
+    # experts (top-k keeps its order) and sets how sharp the gates over the
+    # chosen are, and with them what a row that changes its LAST expert
+    # between two precisions moves: the last gate, a tenth of the row's weight
+    # at logits of deviation 1, a fiftieth at 3
+    router_std: float = 0.02
     # a layer's leaves that the step reads as constants: they get no gradient
     # and so are not trained. What one chip's share of a layer names when it
     # cannot form a leaf's whole gradient: the router's needs the outputs of
@@ -65,6 +89,8 @@ class MixedStackConfig(MoEConfig):
     frozen_leaves: Tuple[str, ...] = ()
 
     def __post_init__(self):
+        if self.router_input not in ("mlp", "attention"):
+            raise ValueError(f"unknown router input: {self.router_input!r}")
         known = {name for mlp in ("dense", "experts")
                  for name in _layer_shapes(self, LayerKind("full", mlp))}
         if not set(self.frozen_leaves) <= known:
@@ -90,7 +116,8 @@ class Run(NamedTuple):
 
 def layer_kinds(config: MixedStackConfig) -> List[LayerKind]:
     c = config
-    return [LayerKind("full" if (i + 1) % c.global_attn_every == 0 else "sliding",
+    full_at = 0 if c.global_attn_first else c.global_attn_every - 1
+    return [LayerKind("full" if i % c.global_attn_every == full_at else "sliding",
                       "dense" if i < c.n_dense_layers else "experts")
             for i in range(c.n_layers)]
 
@@ -140,56 +167,61 @@ POST_NORM_GAIN = 0.03
 
 def _layer_shapes(config: MixedStackConfig, kind: LayerKind) -> Dict[str, Tuple[Tuple[int, ...], Any, Any]]:
     """name -> (shape, how it is initialised, logical axes) of one layer's
-    leaves. "post": the norm on a sublayer's output."""
+    leaves: those the configuration's flags give it, in one fixed order.
+    "post": the norm on a sublayer's output. "into_residual": a projection
+    into the residual stream, which takes the other families' 1/sqrt(2 L)
+    scale where no such norm follows it (under one the scale is undone)."""
     c = config
     dh, m = c.head_dim, c.d_model
-    shapes = {
-        "ln1_scale": ((m,), "ones", (None,)),
-        "ln1_post_scale": ((m,), "post", (None,)),
-        "ln2_scale": ((m,), "ones", (None,)),
-        "ln2_post_scale": ((m,), "post", (None,)),
-        "wq": ((m, c.n_heads, dh), "normal", ("embed", "heads", "head_dim")),
-        "wk": ((m, c.kv_heads, dh), "normal", ("embed", "kv_heads", "head_dim")),
-        "wv": ((m, c.kv_heads, dh), "normal", ("embed", "kv_heads", "head_dim")),
-        "wg": ((m, c.n_heads, dh), "normal", ("embed", "heads", "head_dim")),
-        "wo": ((c.n_heads, dh, m), "normal", ("heads", "head_dim", "embed")),
-        "q_norm_scale": ((dh,), "ones", (None,)),
-        "k_norm_scale": ((dh,), "ones", (None,)),
-    }
-    if kind.mlp == "dense":
-        shapes.update(
-            w_gate=((m, c.d_ff_dense), "normal", ("embed", "mlp")),
-            w_up=((m, c.d_ff_dense), "normal", ("embed", "mlp")),
-            w_down=((c.d_ff_dense, m), "normal", ("mlp", "embed")))
-        return shapes
+    into_residual = "normal" if c.sandwich_norm else "into_residual"
     held, shared = c.n_experts_held, c.shared_expert_width
-    shapes.update(
+    experts = kind.mlp == "experts"
+    # (name, whether this layer has the leaf, its shape, initialisation and axes)
+    leaves = [
+        ("ln1_scale", True, (m,), "ones", (None,)),
+        ("ln1_post_scale", c.sandwich_norm, (m,), "post", (None,)),
+        ("ln2_scale", True, (m,), "ones", (None,)),
+        ("ln2_post_scale", c.sandwich_norm, (m,), "post", (None,)),
+        ("wq", True, (m, c.n_heads, dh), "normal", ("embed", "heads", "head_dim")),
+        ("wk", True, (m, c.kv_heads, dh), "normal", ("embed", "kv_heads", "head_dim")),
+        ("wv", True, (m, c.kv_heads, dh), "normal", ("embed", "kv_heads", "head_dim")),
+        ("wg", c.attn_gate, (m, c.n_heads, dh), "normal", ("embed", "heads", "head_dim")),
+        ("wo", True, (c.n_heads, dh, m), into_residual, ("heads", "head_dim", "embed")),
+        ("q_norm_scale", c.qk_norm_per_head, (dh,), "ones", (None,)),
+        ("k_norm_scale", c.qk_norm_per_head, (dh,), "ones", (None,)),
+        ("w_gate", not experts, (m, c.d_ff_dense), "normal", ("embed", "mlp")),
+        ("w_up", not experts, (m, c.d_ff_dense), "normal", ("embed", "mlp")),
+        ("w_down", not experts, (c.d_ff_dense, m), into_residual, ("mlp", "embed")),
         # replicated, as moe.logical_axes has it
-        router=((m, c.n_experts), "normal", (None, None)),
-        expert_bias=((c.n_experts,), "zeros", (None,)),
-        we_gate=((held, m, c.d_ff), "normal", ("expert", "embed", "mlp")),
-        we_up=((held, m, c.d_ff), "normal", ("expert", "embed", "mlp")),
-        we_down=((held, c.d_ff, m), "normal", ("expert", "mlp", "embed")),
-        ws_gate=((m, shared), "normal", ("embed", "mlp")),
-        ws_up=((m, shared), "normal", ("embed", "mlp")),
-        ws_down=((shared, m), "normal", ("mlp", "embed")))
-    return shapes
+        ("router", experts, (m, c.n_experts), "router", (None, None)),
+        ("expert_bias", experts and c.router_select_bias, (c.n_experts,), "zeros", (None,)),
+        ("we_gate", experts, (held, m, c.d_ff), "normal", ("expert", "embed", "mlp")),
+        ("we_up", experts, (held, m, c.d_ff), "normal", ("expert", "embed", "mlp")),
+        ("we_down", experts, (held, c.d_ff, m), into_residual, ("expert", "mlp", "embed")),
+        ("ws_gate", experts and shared > 0, (m, shared), "normal", ("embed", "mlp")),
+        ("ws_up", experts and shared > 0, (m, shared), "normal", ("embed", "mlp")),
+        ("ws_down", experts and shared > 0, (shared, m), into_residual, ("mlp", "embed")),
+    ]
+    return {name: (shape, how, axes) for name, has, shape, how, axes in leaves if has}
 
 
 def init_params(config: MixedStackConfig, key: jax.Array) -> Params:
-    """The repo's initialisation (N(0, 0.02), norms 1 but those on a
-    sublayer's output, which start at POST_NORM_GAIN, the selection bias 0),
+    """The repo's initialisation (N(0, 0.02), over sqrt(2 L) for a projection
+    into the residual stream that no norm follows; norms 1 but those on a
+    sublayer's output, which start at POST_NORM_GAIN; the selection bias 0),
     stacked a run: params["runs"][r][p][leaf] has the run's repeats in
     front."""
     c = config
     pd = c.param_dtype
     std = 0.02
+    deviation = {"normal": std, "into_residual": std / math.sqrt(2 * c.n_layers),
+                 "router": c.router_std}
     constant = {"ones": 1.0, "post": POST_NORM_GAIN, "zeros": 0.0}
 
     def leaf(k, shape, how, repeats):
         shape = (repeats, *shape)
-        if how == "normal":
-            return std * jax.random.normal(k, shape, pd)
+        if how in deviation:
+            return deviation[how] * jax.random.normal(k, shape, pd)
         return jnp.full(shape, constant[how], pd)
 
     runs = []
@@ -203,7 +235,7 @@ def init_params(config: MixedStackConfig, key: jax.Array) -> Params:
         runs.append(period)
     k_embed, k_head = jax.random.split(jax.random.fold_in(key, 10**6))
     return {
-        "wte": std * jax.random.normal(k_embed, (c.vocab_size, c.d_model), pd),
+        "wte": c.embedding_std * jax.random.normal(k_embed, (c.vocab_size, c.d_model), pd),
         "runs": runs,
         "lnf_scale": jnp.ones((c.d_model,), pd),
         "lm_head": std * jax.random.normal(k_head, (c.d_model, c.vocab_size), pd),
@@ -225,21 +257,27 @@ def logical_axes(config: MixedStackConfig) -> Params:
 
 def _block(x, lp, config, kind: LayerKind, rope_tables, positions, remat_saved=()):
     """One layer of either attention kind and either MLP kind on (B, S, E):
-    x + norm(attention(norm(x))), then the same around the MLP. -> (x, the
-    expert layer's scalars, {} for a dense layer)."""
+    x + attention(norm(x)), then the same around the MLP, each sublayer's
+    output through a norm of its own where the configuration has them. ->
+    (x, the expert layer's scalars, {} for a dense layer)."""
     c = config
     if c.frozen_leaves:
         lp = {name: jax.lax.stop_gradient(w) if name in c.frozen_leaves else w
               for name, w in lp.items()}
     sliding = kind.attention == "sliding"
+    # the layer's input is kept for the backward pass whatever is recomputed,
+    # so a router that reads it costs no tensor carried past the attention
+    router_input = x if c.router_input == "attention" else None
     with jax.named_scope("attn.window" if sliding else "attn.full"):
         x = attention_sublayer(
             x, lp, c, rope_tables if sliding else None, positions,
             window=c.sliding_window if sliding else None, remat_saved=remat_saved)
     if kind.mlp == "dense":
         return mlp_sublayer(x, lp, c), {}
-    out, scalars = moe_mlp(_norm(x, lp["ln2_scale"], None, c.norm, c.norm_eps), lp, c)
-    out = _norm(out, lp["ln2_post_scale"], None, c.norm, c.norm_eps)
+    out, scalars = moe_mlp(_norm(x, lp["ln2_scale"], None, c.norm, c.norm_eps), lp, c,
+                           router_input=router_input)
+    if c.sandwich_norm:
+        out = _norm(out, lp["ln2_post_scale"], None, c.norm, c.norm_eps)
     scalars.pop("aux")  # this family trains on the cross entropy alone
     return x + out, dict(scalars, load=load_max_over_mean(scalars["load"]))
 
@@ -255,8 +293,10 @@ def forward_hidden(
     """Forward up to the LM head: (B, S) -> ((B, S, E), what the expert layers
     report: `moe_load_max_over_mean` and `moe_passes` of the worst layer,
     `moe_rows_held` and `moe_rows_held_share` (of the T k routed rows) as the
-    mean over the expert layers). The rotary table is built for the
-    sequence at hand, not for `max_seq`."""
+    mean over the expert layers, as is `moe_act_live_share`, the percentage of
+    the held ReGLU experts' hidden units that the ReLU leaves non-zero on the
+    rows sent here). The rotary table is built for the sequence at hand, not
+    for `max_seq`."""
     c = config
     dt = c.dtype
     b, s = tokens.shape
@@ -295,6 +335,9 @@ def forward_hidden(
         out.update(moe_rows_held=rows_held,
                    moe_rows_held_share=100.0 * rows_held / (b * s * c.top_k),
                    moe_passes=jnp.max(every["moe_passes"]))
+    if "moe_act_live_units" in every:
+        out["moe_act_live_share"] = jnp.mean(
+            100.0 * every["moe_act_live_units"] / jnp.maximum(every["moe_rows_held"] * c.d_ff, 1.0))
     return x, out
 
 
@@ -306,7 +349,8 @@ def _expert_costs(config: MixedStackConfig, split: Callable[[str], int]) -> Dict
     buffer or the shared expert's gate and up are worth kept is not known
     from any record (PERF.md section 7)."""
     c = config
-    shared, d_ff = c.shared_expert_width // split("ws_up"), c.d_ff // split("we_up")
+    shared = c.shared_expert_width // split("ws_up") if c.shared_expert_width else 0
+    d_ff = c.d_ff // split("we_up")
     rows_here = c.top_k * c.n_experts_held / c.n_experts   # (token, choice) pairs a token
     return {
         "flops": int(2 * c.d_model * (c.n_experts + 3 * shared + 3 * rows_here * d_ff)),
@@ -336,7 +380,8 @@ def block_costs(
 
     return stack_costs([
         StackRun(run.repeats > 1, ("runs", r), tuple(
-            (n * run.repeats, *kind_costs(kind)) for kind, n in collections.Counter(run.kinds).items()))
+            (n * run.repeats, *kind_costs(kind)) for kind, n in collections.Counter(run.kinds).items()),
+            period=len(run.kinds))
         for r, run in enumerate(stack_runs(layer_kinds(c)))])
 
 
@@ -356,6 +401,7 @@ def plan(config: MixedStackConfig, batch: int, seq: int) -> Dict[str, Any]:
         **{name.replace("attn_subtiles", "attn_window_subtiles"): value
            for name, value in windowed.items() if name.startswith("attn_subtiles")},
         "moe_router": c.router_score,
+        "moe_router_input": c.router_input,
         "moe_experts_routed": c.n_experts,
         "moe_shared_width": c.shared_expert_width,
     }

@@ -68,6 +68,9 @@ class MoEConfig(TransformerConfig):
     route_scale: float = 1.0
     # a dense SwiGLU of this width on every token, added to the routed sum (0: none)
     shared_expert_width: int = 0
+    # a routed expert's gated unit: "swiglu" (silu(gate) * up) or "reglu"
+    # (relu(gate) * up), between the grouped matmuls in either form
+    expert_act: str = "swiglu"
     # the range [first, last) of the published experts this device holds, the
     # others lying on further chips (None: all `n_experts`). The router keeps
     # its `n_experts` outputs and its top-k over all of them, the gates are
@@ -311,6 +314,7 @@ def moe_plan(config: "MoEConfig", batch: int, seq: int) -> Dict[str, Any]:
         impl, tile = {"pallas": "gmm_pallas", "xla": "ragged_dot"}[gmm], gmm_tile_rows(gmm)
     plan = {
         "moe_impl": impl, "moe_experts": config.n_experts, "moe_top_k": config.top_k,
+        "moe_expert_act": config.expert_act,
         "moe_rows_per_step": batch * seq * config.top_k, "moe_gmm_tile_rows": tile,
     }
     if config.held_experts is not None:
@@ -323,6 +327,20 @@ def moe_plan(config: "MoEConfig", batch: int, seq: int) -> Dict[str, Any]:
                     moe_held_buffer_rows=held_buffer_rows(*mine),
                     moe_held_passes_most=held_passes_most(*mine))
     return plan
+
+
+def _reglu(gate, up):
+    return jax.nn.relu(gate) * up
+
+
+_EXPERT_ACTS = {"swiglu": swiglu, "reglu": _reglu}
+
+
+def _expert_act(config: "MoEConfig"):
+    try:
+        return _EXPERT_ACTS[config.expert_act]
+    except KeyError:
+        raise ValueError(f"unknown expert activation: {config.expert_act!r}") from None
 
 
 def _route(scores, select, config):
@@ -355,20 +373,28 @@ def _gshard_experts(h, probs, weights, config):
     expert_in = jnp.einsum("bsec,bsm->ebcm", dispatch.astype(dt), h)
     gate = jnp.einsum("ebcm,emf->ebcf", expert_in, we_gate)
     up = jnp.einsum("ebcm,emf->ebcf", expert_in, we_up)
-    act = swiglu(gate, up)
+    act = _expert_act(c)(gate, up)
     expert_out = jnp.einsum("ebcf,efm->ebcm", act, we_down)
     out = jnp.einsum("ebcm,bsec->bsm", expert_out, combine.astype(dt))
     return out, jnp.sum(dispatch, axis=(0, 1, 3))
 
 
-def _swiglu_groups(expert_in, weights, group_sizes, tile, impl):
-    """The three grouped matmuls of the expert-sorted rows `expert_in`."""
+def _gated_groups(expert_in, weights, group_sizes, tile, impl, config):
+    """The three grouped matmuls of the expert-sorted rows `expert_in`, the
+    configuration's gated unit between them. -> (output, a slot's count of
+    hidden units a ReLU gate leaves non-zero; None where the unit has no
+    dead ones to count)."""
     we_gate, we_up, we_down = weights
 
     def gmm(lhs, w):
         return grouped_matmul(lhs, w, group_sizes, tile_rows=tile, implementation=impl)
 
-    return gmm(swiglu(gmm(expert_in, we_gate), gmm(expert_in, we_up)), we_down)
+    gate = gmm(expert_in, we_gate)
+    out = gmm(_expert_act(config)(gate, gmm(expert_in, we_up)), we_down)
+    live = None
+    if config.expert_act == "reglu":
+        live = jnp.sum(jax.lax.stop_gradient(gate) > 0, axis=-1, dtype=jnp.float32)
+    return out, live
 
 
 def _dropless_shard(h, probs, weights, config, select=None):
@@ -395,7 +421,7 @@ def _dropless_shard(h, probs, weights, config, select=None):
             h.reshape(tokens, m), layout.slot_row // c.top_k,
             layout.row_slot.reshape(tokens, c.top_k))
     with jax.named_scope("moe.experts"):
-        expert_out = _swiglu_groups(expert_in, weights, layout.padded_sizes, tile, impl)
+        expert_out, _ = _gated_groups(expert_in, weights, layout.padded_sizes, tile, impl, c)
     with jax.named_scope("moe.combine"):
         chosen = _take_rows(expert_out, layout.row_slot, layout.slot_row[:, None])
         # gated in float32; one fused pass over the gathered rows
@@ -431,7 +457,9 @@ def held_passes_most(config: MoEConfig, tokens: int, tile: int) -> int:
 def _held_experts(h, gates, experts, weights, config, tile, impl):
     """h (T, M), gates and published expert ids (T, k) -> (sum over each
     token's chosen experts THAT ARE HELD of gate x expert(h), float32 (T, M);
-    {"moe_rows_held", "moe_passes"}).
+    {"moe_rows_held", "moe_passes"} and, for ReGLU experts,
+    "moe_act_live_units": the hidden units the ReLU left non-zero, over the
+    slots that hold a row).
 
     The (token, choice) rows are sorted by expert, those routed to an absent
     expert last; they are never gathered, multiplied or combined. The held
@@ -479,28 +507,37 @@ def _held_experts(h, gates, experts, weights, config, tile, impl):
             slot_token = slot_row // k
             expert_in = jnp.take(h, slot_token, axis=0, mode="fill", fill_value=0)
         with jax.named_scope("moe.experts"):
-            expert_out = _swiglu_groups(expert_in, weights, padded.astype(jnp.int32), tile, impl)
+            expert_out, live = _gated_groups(
+                expert_in, weights, padded.astype(jnp.int32), tile, impl, c)
         with jax.named_scope("moe.combine"):
             slot_gate = jnp.take(flat_gates, slot_row, mode="fill", fill_value=0)
             gated = slot_gate[:, None] * expert_out.astype(jnp.float32)
-            return jnp.zeros((tokens, m), jnp.float32).at[slot_token].add(gated, mode="drop")
+            out = jnp.zeros((tokens, m), jnp.float32).at[slot_token].add(gated, mode="drop")
+        if live is None:
+            return (out,)
+        return out, jnp.sum(jnp.where(slot_row < rows, live, 0.0))
 
-    out = one_pass(h, flat_gates, weights, jnp.int32(0))
+    # (the output, then what the pass counted of itself), summed over the passes
+    total = one_pass(h, flat_gates, weights, jnp.int32(0))
     most = held_passes_most(c, tokens, tile)
     if most > 1:
         later = jax.checkpoint(one_pass)
 
-        def further(out, start):
-            return out + jax.lax.cond(
+        def further(total, start):
+            more = jax.lax.cond(
                 start < rows_held, later,
-                lambda h, g, w, start: jnp.zeros((tokens, m), jnp.float32),
-                h, flat_gates, weights, start), None
+                lambda h, g, w, start: tuple(jnp.zeros(part.shape, part.dtype) for part in total),
+                h, flat_gates, weights, start)
+            return tuple(a + b for a, b in zip(total, more)), None
 
-        out, _ = jax.lax.scan(
-            further, out, jnp.arange(1, most, dtype=jnp.int32) * buffer_rows)
+        total, _ = jax.lax.scan(
+            further, total, jnp.arange(1, most, dtype=jnp.int32) * buffer_rows)
     passes = jnp.maximum(-(-rows_held // buffer_rows), 1)
-    return out, {"moe_rows_held": rows_held.astype(jnp.float32),
-                 "moe_passes": passes.astype(jnp.float32)}
+    report = {"moe_rows_held": rows_held.astype(jnp.float32),
+              "moe_passes": passes.astype(jnp.float32)}
+    if len(total) > 1:
+        report["moe_act_live_units"] = total[1]
+    return total[0], report
 
 
 def _dropless_experts(h, probs, weights, config, mesh, select=None):
@@ -531,7 +568,10 @@ def _dropless_experts(h, probs, weights, config, mesh, select=None):
         return out, sizes, held
 
     tok = P(batch or None, seq, None)
-    held_names = ("moe_rows_held", "moe_passes") if config.held_experts is not None else ()
+    held_names = ()
+    if config.held_experts is not None:
+        held_names = ("moe_rows_held", "moe_passes",
+                      *(("moe_act_live_units",) if config.expert_act == "reglu" else ()))
     return jax.shard_map(
         shard, mesh=mesh,
         in_specs=(tok, tok, (P(None, None, tp), P(None, None, tp), P(None, tp, None)),
@@ -540,19 +580,24 @@ def _dropless_experts(h, probs, weights, config, mesh, select=None):
     )(h, probs, weights, *((select,) if select is not None else ()))
 
 
-def moe_mlp(h: jax.Array, lp: Params, config: MoEConfig) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+def moe_mlp(
+    h: jax.Array, lp: Params, config: MoEConfig, router_input: Optional[jax.Array] = None,
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """The expert layer on normed activations (B, S, M): router, routed
-    experts (those held here) and the shared expert. -> (output, the layer's
-    scalars: `aux` (the load-balancing loss), `load` (rows a published expert;
-    `load_max_over_mean` of them is what is reported) and, where a part of the
-    experts is held, `moe_rows_held` and `moe_passes`)."""
+    experts (those held here) and the shared expert. `router_input` is the
+    (B, S, M) tensor the router reads where that is not the experts' own `h`
+    (an architecture that routes a layer before its attention). -> (output,
+    the layer's scalars: `aux` (the load-balancing loss), `load` (rows a
+    published expert; `load_max_over_mean` of them is what is reported) and,
+    where a part of the experts is held, `moe_rows_held`, `moe_passes` and,
+    for ReGLU experts, `moe_act_live_units`)."""
     c = config
     with jax.named_scope("moe.route"):
         # float32 in earnest: a TPU's default float32 matmul is one bfloat16
         # pass, which rounds the router's weights and flips near-ties
         router_logits = jnp.einsum(
-            "bsm,me->bse", h.astype(jnp.float32), lp["router"].astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST,
+            "bsm,me->bse", (h if router_input is None else router_input).astype(jnp.float32),
+            lp["router"].astype(jnp.float32), precision=jax.lax.Precision.HIGHEST,
         )
         if c.router_score == "softmax":
             probs = jax.nn.softmax(router_logits, axis=-1)

@@ -409,11 +409,13 @@ class StackRun(NamedTuple):
     stacked gradients and kept values then live as long as the scan's
     backward) or one after the other, their parameters under `params` of the
     parameter tree: (layers of a kind, the kind's `attention_costs`, its
-    MLP's costs) a kind."""
+    MLP's costs) a kind. `period`: the layers one iteration of the scan runs
+    (its body unrolls them)."""
 
     scanned: bool
     params: Tuple[Any, ...]
     kinds: Tuple[Tuple[int, Dict[str, Any], Dict[str, Any]], ...]
+    period: int = 1
 
 
 def stack_costs(runs: Sequence[StackRun]) -> Dict[str, Any]:
@@ -438,7 +440,7 @@ def stack_costs(runs: Sequence[StackRun]) -> Dict[str, Any]:
                     worth=(before * seen.worth + n * c.worth) // (before + n))
     return {
         "flops": flops, "recomputed_flops": recomputed,
-        "runs": tuple({"scanned": run.scanned, "params": run.params,
+        "runs": tuple({"scanned": run.scanned, "params": run.params, "period": run.period,
                        "layers": sum(n for n, _, _ in run.kinds),
                        "width": max(a["width"] + m["width"] for _, a, m in run.kinds)}
                       for run in runs),

@@ -335,3 +335,241 @@ def test_the_older_families_are_untouched_by_the_new_fields():
                                      "we_gate", "we_up", "we_down"}
     assert params["blocks"]["we_up"].shape[1] == sparse.n_experts == sparse.n_experts_held
     assert "blocks" in model_family(dense).init_params(dense, jax.random.PRNGKey(0))
+
+
+# ------------------------------------------ an all-expert stack, routed before attention
+
+
+def tiny_all_experts(**kw) -> MixedStackConfig:
+    """8 expert layers (eF eS eS eS, twice), the full layer FIRST in its
+    period, the router on the attention's input, ReGLU experts with no shared
+    one, softmax gates over the chosen, plain pre-norm blocks: 32 experts
+    top-3 of which 8 are held, float32."""
+    base = dict(
+        vocab_size=256, d_model=64, n_layers=8, n_heads=4, n_kv_heads=2, d_head=8, d_ff=32,
+        max_seq=64, pos_emb="rope", norm="rmsnorm", act="swiglu", use_bias=False,
+        tie_embeddings=False, norm_eps=1e-6, rope_theta=1.5e6, dtype=jnp.float32, remat=True,
+        sliding_window=16, global_attn_every=4, global_attn_first=True, n_dense_layers=0,
+        router_input="attention", n_experts=32, held_experts=(0, 8), top_k=3, norm_topk_prob=True,
+        router_score="softmax", expert_act="reglu", router_aux_coeff=0.0, frozen_leaves=("router",))
+    return MixedStackConfig(**{**base, **kw})
+
+
+def arch_all_experts(config):
+    return dict(global_attn_every=config.global_attn_every, sliding_window=config.sliding_window,
+                rope_theta=config.rope_theta, norm_eps=config.norm_eps, top_k=config.top_k,
+                held_experts=config.held_experts, frozen_leaves=config.frozen_leaves)
+
+
+@pytest.mark.parametrize("depth,dense,first,want", [
+    (8, 0, True, "2 x (eF eS eS eS)"),
+    (52, 0, True, "13 x (eF eS eS eS)"),
+    (6, 0, True, "eF eS eS eS eF eS"),
+    (6, 2, False, "2 x (dS) | eS eF eS eS"),
+    (6, 2, True, "dF dS | eS eS eF eS")],
+    ids=["the-cell", "the-published-depth", "a-period-and-a-half", "global-last-as-before",
+         "global-first-behind-dense-layers"])
+def test_the_global_layer_first_in_its_period_is_one_scanned_run(depth, dense, first, want):
+    config = tiny(n_layers=depth, n_dense_layers=dense, global_attn_first=first)
+    kinds = layer_kinds(config)
+    assert _said(stack_runs(kinds)) == want
+    assert [k.attention == "full" for k in kinds] == [
+        i % 4 == (0 if first else 3) for i in range(depth)]
+
+
+def test_a_layer_has_the_leaves_its_flags_give_it_and_no_others():
+    """The plain pre-norm all-expert layer has no output gate, no QK-norm, no
+    norm on a sublayer's output, no selection bias and no shared expert, as
+    leaves and as logical axes alike; the family that has them all keeps its
+    tree, in its order, to the last bit of its initial values (the hash is
+    the parent's, PR 36)."""
+    import hashlib
+
+    plain = tiny_all_experts()
+    family = model_family(plain)
+    params = family.init_params(plain, jax.random.PRNGKey(0))
+    (period,) = params["runs"]
+    assert [list(lp) for lp in period] == [[
+        "ln1_scale", "ln2_scale", "wq", "wk", "wv", "wo", "router", "we_gate", "we_up", "we_down"]] * 4
+    assert jax.tree.structure(family.logical_axes(plain)["runs"], is_leaf=lambda x: isinstance(x, tuple)) \
+        == jax.tree.structure(jax.tree.map(lambda w: (), params["runs"]), is_leaf=lambda x: isinstance(x, tuple))
+    assert period[0]["we_up"].shape == (2, 8, 64, 32) and period[0]["router"].shape == (2, 64, 32)
+    # projections into the residual stream, with no norm behind them: 0.02 / sqrt(2 L)
+    assert float(jnp.std(period[0]["wo"])) == pytest.approx(0.02 / 4, rel=0.05)
+    assert float(jnp.std(period[0]["we_down"])) == pytest.approx(0.02 / 4, rel=0.05)
+    assert float(jnp.std(period[0]["wq"])) == pytest.approx(0.02, rel=0.05)
+    assert float(jnp.std(params["wte"])) == pytest.approx(0.02, rel=0.05)
+    wide = family.init_params(dataclasses.replace(plain, embedding_std=1.0, router_std=0.06),
+                              jax.random.PRNGKey(0))
+    np.testing.assert_allclose(np.asarray(wide["wte"]), np.asarray(params["wte"] / 0.02), rtol=1e-6)
+    # a router's deviation is a scale on the same draw: every choice of experts stays
+    np.testing.assert_allclose(np.asarray(wide["runs"][0][0]["router"]),
+                               np.asarray(3 * period[0]["router"]), rtol=1e-6)
+    assert all(np.array_equal(a, b) for a, b in zip(
+        jax.tree.leaves({k: v for k, v in wide["runs"][0][0].items() if k != "router"}),
+        jax.tree.leaves({k: v for k, v in period[0].items() if k != "router"})))
+    # each flag brings its own leaves
+    for flags, leaves in ((dict(attn_gate=True), {"wg"}),
+                          (dict(qk_norm_per_head=True), {"q_norm_scale", "k_norm_scale"}),
+                          (dict(sandwich_norm=True), {"ln1_post_scale", "ln2_post_scale"}),
+                          (dict(router_select_bias=True), {"expert_bias"}),
+                          (dict(shared_expert_width=32), {"ws_gate", "ws_up", "ws_down"})):
+        more = jax.eval_shape(lambda: family.init_params(
+            dataclasses.replace(plain, **flags), jax.random.PRNGKey(0)))["runs"][0][0]
+        assert set(more) - set(period[0]) == leaves, flags
+    with pytest.raises(ValueError, match="expert_bias"):
+        tiny_all_experts(frozen_leaves=("expert_bias",))
+    with pytest.raises(ValueError, match="router input"):
+        tiny_all_experts(router_input="residual")
+    # the family with every flag set: the parent's tree and the parent's numbers
+    every = tiny()
+    theirs = model_family(every).init_params(every, jax.random.PRNGKey(0))
+    assert list(theirs["runs"][1][0]) == [
+        "ln1_scale", "ln1_post_scale", "ln2_scale", "ln2_post_scale", "wq", "wk", "wv", "wg", "wo",
+        "q_norm_scale", "k_norm_scale", "router", "expert_bias", "we_gate", "we_up", "we_down",
+        "ws_gate", "ws_up", "ws_down"]
+    digest = hashlib.sha256()
+    for path, w in jax.tree_util.tree_flatten_with_path(theirs)[0]:
+        digest.update(jax.tree_util.keystr(path).encode())
+        digest.update(np.asarray(w).tobytes())
+    assert digest.hexdigest() == "3af119b68e65cd573b76a854b7156488501a52cf0b85ef7d9bfa47f1f0250b7f"
+
+
+def _logits_of(config):
+    family = model_family(config)
+
+    def logits(p, t):
+        hidden, _ = family.forward_hidden(p, t, config)
+        return jnp.einsum("bse,ev->bsv", hidden, lm_head_weights(p, config))
+
+    return jax.jit(logits)
+
+
+def test_all_expert_stack_matches_its_plain_reference():
+    """Logits, loss and every leaf's gradient of the system against
+    benchmark/reference/smallthinker_ref.py, float32: both attention kinds,
+    the router on the attention's input, ReGLU, the held share, a frozen
+    router."""
+    from benchmark.reference import smallthinker_ref
+
+    config = tiny_all_experts()
+    params = seeded(config)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 49), 0, config.vocab_size)
+    ours = _logits_of(config)(params, tokens[:, :-1])
+    theirs = smallthinker_ref.forward_logits(params, tokens[:, :-1], **arch_all_experts(config))
+    np.testing.assert_allclose(np.asarray(ours), np.asarray(theirs), atol=1e-4)
+    # the reference's one departure, a block of positions at a time, changes no number
+    blocked = smallthinker_ref.forward_logits(
+        params, tokens[:, :-1], query_block=8, **arch_all_experts(config))
+    np.testing.assert_allclose(np.asarray(blocked), np.asarray(theirs), atol=1e-5)
+    (loss, grads) = jax.jit(jax.value_and_grad(lambda p: lm_loss(p, tokens, config)[0]))(params)
+    (ref_loss, ref_grads) = jax.value_and_grad(lambda p: smallthinker_ref.objective_part(
+        p, tokens, total_tokens=tokens[:, 1:].size, query_block=8, **arch_all_experts(config))[0])(params)
+    assert abs(float(loss) - float(ref_loss)) < 1e-5
+    for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(grads)[0], jax.tree.leaves(ref_grads)):
+        scale = max(float(jnp.max(jnp.abs(r))), 1e-3)
+        assert float(jnp.max(jnp.abs(g - r))) <= 1e-4 * scale, jax.tree_util.keystr(path)
+        if "router" in jax.tree_util.keystr(path):
+            assert not np.asarray(g).any() and not np.asarray(r).any()
+    # every weight through float8_e4m3 fails the same comparison
+    low = jax.tree.map(lambda w: w.astype(jnp.float8_e4m3fn).astype(w.dtype), params)
+    assert float(jnp.max(jnp.abs(_logits_of(config)(low, tokens[:, :-1]) - theirs))) > 1e-2
+
+
+@pytest.mark.parametrize("router_input", ["attention", "mlp"])
+def test_the_router_reads_the_tensor_the_configuration_names(router_input):
+    """Perturbing layer 0's attention weights leaves THAT layer's chosen
+    experts as they were where the router reads the attention's input, and
+    changes them where it reads the MLP's; either way it changes the next
+    layer's."""
+    from benchmark.reference import smallthinker_ref
+
+    config = tiny_all_experts(router_input=router_input, held_experts=None, n_experts=32, n_layers=4)
+    params = seeded(config)
+    tokens = jax.random.randint(jax.random.PRNGKey(5), (2, 48), 0, config.vocab_size)
+
+    def chosen_rows(p):
+        """Rows a published expert, a layer: the block's two sublayers walked by hand (the
+        block folds `load` to its max over mean)."""
+        x = p["wte"][tokens]
+        rope = moe.rope_frequencies(config.head_dim, tokens.shape[1], config.rope_theta)
+        from ray_tpu.models.transformer import _norm, attention_sublayer
+
+        rows = []
+        for kind, lp in zip(layer_kinds(config), smallthinker_ref.layers_of(p)):
+            sliding = kind.attention == "sliding"
+            after = attention_sublayer(x, lp, config, rope if sliding else None, None,
+                                       window=config.sliding_window if sliding else None)
+            m = _norm(after, lp["ln2_scale"], None, config.norm, config.norm_eps)
+            out, scalars = moe.moe_mlp(m, lp, config,
+                                       router_input=x if router_input == "attention" else None)
+            rows.append(np.asarray(scalars["load"]))
+            x = after + out
+        return rows
+
+    before = chosen_rows(params)
+    shaken = jax.tree_util.tree_map_with_path(
+        lambda path, w: w.at[0].multiply(-1.5) if jax.tree_util.keystr(path).endswith("[0]['wv']") else w,
+        params)
+    after = chosen_rows(shaken)
+    same_layer = np.array_equal(before[0], after[0])
+    assert same_layer == (router_input == "attention")
+    assert not np.array_equal(before[1], after[1])
+
+
+def test_the_eight_shares_of_an_all_expert_layer_add_up_to_the_uncut_reference():
+    """Nothing is computed alike on every chip here (no shared expert), so
+    the eight shares' outputs, each routed over all 32 experts by the layer's
+    INPUT and gated by the softmax over all 3 chosen, add up to the uncut
+    layer, which is the plain reference's; every ReGLU unit's count is
+    between none and all."""
+    from benchmark.reference import smallthinker_ref
+
+    config = tiny_all_experts(n_experts=64, held_experts=None, n_layers=1, global_attn_every=1)
+    lp = jax.tree.map(lambda w: w[0], seeded(config, 2)["runs"][0][0])
+    h = jax.random.normal(jax.random.PRNGKey(3), (2, 24, config.d_model))
+    routed_by = jax.random.normal(jax.random.PRNGKey(4), (2, 24, config.d_model))
+    uncut, scalars = moe.moe_mlp(h, lp, config, router_input=routed_by)
+    total, rows, live = 0.0, 0.0, 0.0
+    for share in range(8):
+        first = 8 * share
+        held = dict(lp, **{name: lp[name][first: first + 8] for name in ("we_gate", "we_up", "we_down")})
+        part, part_scalars = moe.moe_mlp(
+            h, held, dataclasses.replace(config, held_experts=(first, first + 8)), router_input=routed_by)
+        total, rows = total + part, rows + part_scalars["moe_rows_held"]
+        live += part_scalars["moe_act_live_units"]
+        np.testing.assert_array_equal(np.asarray(part_scalars["load"]), np.asarray(scalars["load"]))
+    np.testing.assert_allclose(np.asarray(total), np.asarray(uncut), atol=2e-5)
+    assert float(rows) == 2 * 24 * 3            # every (token, choice) row lies on exactly one chip
+    with jax.default_matmul_precision("highest"):
+        gates, chosen = smallthinker_ref._gates(routed_by @ lp["router"], config.top_k)
+        reference = sum(gates[..., e, None] * smallthinker_ref._reglu(
+            h, lp["we_gate"][e], lp["we_up"][e], lp["we_down"][e]) for e in range(64))
+        # the units the ReLU leaves alive on the rows really sent: counted by hand
+        gate = jnp.einsum("bsm,emf->bsef", h, lp["we_gate"])
+        sent = jax.nn.one_hot(chosen, 64).sum(axis=2)                  # (B, S, E)
+        by_hand = float(jnp.sum((gate > 0) * sent[..., None]))
+    np.testing.assert_allclose(np.asarray(uncut), np.asarray(reference), atol=2e-5)
+    assert float(live) == by_hand and 0 < by_hand < 2 * 24 * 3 * config.d_ff
+
+
+def test_the_all_expert_step_reports_its_router_its_unit_and_the_live_share():
+    config = tiny_all_experts()
+    plan = model_family(config).plan(config, 2, 48)
+    assert plan["layer_kinds"] == "eF eS eS eS eF eS eS eS" and plan["attn_window"] == 16
+    assert (plan["moe_router"], plan["moe_router_input"], plan["moe_expert_act"],
+            plan["moe_experts_held"], plan["moe_experts_routed"], plan["moe_shared_width"]) == (
+        "softmax", "attention", "reglu", 8, 32, 0)
+    older = model_family(tiny()).plan(tiny(), 2, 48)
+    assert (older["moe_router_input"], older["moe_expert_act"]) == ("mlp", "swiglu")
+    params = seeded(config)
+    tokens = jax.random.randint(jax.random.PRNGKey(3), (2, 49), 0, config.vocab_size)
+    _, scalars = lm_loss(params, tokens, config)
+    assert {"loss", "num_tokens", "moe_load_max_over_mean", "moe_rows_held", "moe_rows_held_share",
+            "moe_passes", "moe_act_live_share"} == set(scalars)
+    assert 30.0 < float(scalars["moe_act_live_share"]) < 70.0       # a seeded gate is half alive
+    # the rule that decides what a recomputing step keeps sees a period of four layers
+    costs = model_family(config).block_costs(config, 48)
+    assert [(run["scanned"], run["period"], run["layers"]) for run in costs["runs"]] == [(True, 4, 8)]
+    assert [(run["scanned"], run["period"], run["layers"])
+            for run in model_family(tiny()).block_costs(tiny(), 48)["runs"]] == [(True, 1, 2), (False, 4, 4)]

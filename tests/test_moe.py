@@ -145,3 +145,80 @@ def test_moe_training_reduces_loss(model):
         params, opt_state, loss = step(params, opt_state)
         losses.append(float(loss))
     assert losses[-1] < losses[0] * 0.8, (losses[0], losses[-1])
+
+
+def _one_layer(config, key=0):
+    from ray_tpu.models.moe import moe_mlp
+
+    params = init_params(config, jax.random.PRNGKey(key))
+    lp = jax.tree.map(lambda w: 3.0 * w[0], {k: params["blocks"][k] for k in (
+        "router", "we_gate", "we_up", "we_down")})
+    h = jax.random.normal(jax.random.PRNGKey(key + 1), (2, 16, config.d_model))
+    return moe_mlp, lp, h
+
+
+def _dense_experts(h, lp, config, act, router_input=None):
+    """Every expert on every token, gated by the renormalised top-k softmax
+    of the router's logits: the expert layer as plain einsums."""
+    with jax.default_matmul_precision("highest"):
+        logits = (h if router_input is None else router_input) @ lp["router"]
+        top, chosen = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), config.top_k)
+        gates = jnp.einsum("bsk,bske->bse", top / top.sum(-1, keepdims=True),
+                           jax.nn.one_hot(chosen, config.n_experts))
+        hidden = act(jnp.einsum("bsm,emf->bsef", h, lp["we_gate"])) * jnp.einsum(
+            "bsm,emf->bsef", h, lp["we_up"])
+        return jnp.einsum("bse,bsef,efm->bsm", gates, hidden, lp["we_down"])
+
+
+@pytest.mark.parametrize("expert_act,act", [("reglu", jax.nn.relu), ("swiglu", jax.nn.silu)])
+def test_the_gated_unit_through_the_grouped_form_equals_the_dense_einsum(expert_act, act):
+    """`MoEConfig.expert_act` is read between the grouped matmuls: ReGLU
+    (relu(gate) * up) and SwiGLU alike equal every expert on every token,
+    gated, output and gradient."""
+    import dataclasses
+
+    config = dataclasses.replace(moe_tiny(), expert_act=expert_act)
+    moe_mlp, lp, h = _one_layer(config)
+    out, _ = moe_mlp(h, lp, config)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(_dense_experts(h, lp, config, act)), atol=2e-5)
+    ours = jax.grad(lambda lp: jnp.sum(moe_mlp(h, lp, config)[0] ** 2))(lp)
+    theirs = jax.grad(lambda lp: jnp.sum(_dense_experts(h, lp, config, act) ** 2))(lp)
+    for name in ("we_gate", "we_up", "we_down"):
+        np.testing.assert_allclose(np.asarray(ours[name]), np.asarray(theirs[name]), atol=2e-4)
+    with pytest.raises(ValueError, match="unknown expert activation"):
+        moe_mlp(h, lp, dataclasses.replace(config, expert_act="geglu"))
+
+
+def test_relu_gated_experts_in_the_gshard_form_equal_the_grouped_form(model):
+    """Under an `ep` mesh the einsum form reads the same `expert_act` (ample
+    capacity: nothing is dropped)."""
+    import dataclasses
+
+    config, params = model
+    config = dataclasses.replace(config, expert_act="reglu", capacity_factor=8.0)
+    tokens = jax.random.randint(jax.random.PRNGKey(3), (4, 16), 0, config.vocab_size)
+    expected, _ = forward(params, tokens, config)
+    swiglu, _ = forward(params, tokens, dataclasses.replace(config, expert_act="swiglu"))
+    assert float(jnp.max(jnp.abs(expected - swiglu))) > 1e-4
+    mesh = build_mesh(MeshSpec(dp=2, ep=2, tp=2))
+    sharded = shard_tree(params, logical_axes(config), default_rules(), mesh)
+    with jax.set_mesh(mesh):
+        out, _ = jax.jit(lambda p, t: forward(p, t, config))(sharded, tokens)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(expected), atol=1e-4, rtol=1e-4)
+
+
+def test_the_router_may_read_another_tensor_than_the_experts():
+    """`moe_mlp(h, lp, config, router_input=r)`: the choice and the gates
+    come from `r`, the experts compute on `h`; without it the layer traces
+    what it traced."""
+    config = moe_tiny()
+    moe_mlp, lp, h = _one_layer(config)
+    r = jax.random.normal(jax.random.PRNGKey(9), h.shape)
+    out, scalars = moe_mlp(h, lp, config, router_input=r)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(_dense_experts(h, lp, config, jax.nn.silu, router_input=r)), atol=2e-5)
+    own, own_scalars = moe_mlp(h, lp, config)
+    assert not np.array_equal(np.asarray(scalars["load"]), np.asarray(own_scalars["load"]))
+    np.testing.assert_array_equal(np.asarray(moe_mlp(h, lp, config, router_input=h)[0]), np.asarray(own))
+    assert str(jax.make_jaxpr(lambda h: moe_mlp(h, lp, config)[0])(h)) == str(
+        jax.make_jaxpr(lambda h: moe_mlp(h, lp, config, router_input=None)[0])(h))

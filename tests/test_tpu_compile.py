@@ -364,3 +364,32 @@ def test_trinity_cell_step_keeps_the_attention_outputs_and_compiles(as_tpu, monk
         assert reader < first_backward
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes / GIB == pytest.approx(8.61, abs=0.02)
+
+
+def test_smallthinker_cell_step_keeps_what_a_four_layer_iteration_leaves_room_for(as_tpu, monkeypatch, v5e):
+    """`train-smallthinker-16k`'s whole step (1 x 16,385 tokens) for one
+    described v5e chip of 15.75 GiB: one scanned run whose iteration is four
+    layers. Counted as a one-layer iteration the estimate kept everything and
+    the compiler refused the step (17.04 GB with the head chunked); with the
+    other three layers' slices counted the rule keeps the attention kernels'
+    outputs, chunks the head, and the step compiles: each forward flash kernel
+    once a layer of the period in the forward loop's body alone."""
+    from ray_tpu.ops import losses
+    from ray_tpu.train.lm import make_train_step
+
+    mesh = build_mesh(MeshSpec(), devices=[as_tpu])
+    config, opt, state, shardings, tokens = _cell_step_shapes(
+        "smallthinker-21b-a3b-train-1chip", mesh, (1, 16385))
+    monkeypatch.setattr(losses, "device_hbm_bytes", lambda: int(15.75 * GIB))
+    step = make_train_step(config, opt, mesh, state_shardings=shardings)
+    plan = step.remat_plan_for(tokens.shape, state)
+    assert (plan["remat"], plan["remat_saved"]) == ("selective", ("attn_out", "attn_lse"))
+    assert step.loss_chunk_for(tokens.shape, state) == 2048
+    compiled = step.lower(state, {"tokens": tokens}).compile()
+    # eF eS eS eS, scanned twice: one body forward, one backward
+    assert _kernels_named(compiled, "flash_win_fwd") == 3 and _kernels_named(compiled, "flash_fwd") == 1
+    for backward in ("flash_win_bwd_dkv", "flash_win_bwd_dq"):
+        assert _kernels_named(compiled, backward) == 3
+    # 4 layers x 3 projections, forward and recomputed: the expert layer is recomputed whole
+    assert _kernels_named(compiled, "moe_gmm_fwd") == 2 * 4 * 3 * 2
+    assert compiled.memory_analysis().argument_size_in_bytes / GIB == pytest.approx(7.20, abs=0.02)

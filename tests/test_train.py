@@ -433,8 +433,8 @@ def test_step_peak_counts_a_scanned_run_whole_and_an_unrolled_run_by_its_larger_
     from ray_tpu.models.transformer import RematCandidate
     from ray_tpu.train.lm import step_peak_bytes
 
-    scanned = {"scanned": True, "gradients": 300, "inputs": 40, "block": 100}
-    unrolled = {"scanned": False, "gradients": 500, "inputs": 80, "block": 100}
+    scanned = {"scanned": True, "gradients": 300, "inputs": 40, "block": 100, "period": 1, "layers": 2}
+    unrolled = {"scanned": False, "gradients": 500, "inputs": 80, "block": 100, "period": 4, "layers": 4}
 
     def peak(kept, logits=0, runs=(scanned, unrolled)):
         return step_peak_bytes(kept, rows=10, itemsize=2, always=1000.0, logits=logits, runs=runs)
@@ -454,6 +454,25 @@ def test_step_peak_counts_a_scanned_run_whole_and_an_unrolled_run_by_its_larger_
         peak(()), 1000 + 40 + 100 + 880)
     # one scan alone (the dense family): everything at once
     assert peak((in_scanned,), logits=150, runs=(scanned,)) == 1000 + 100 + 300 + 40 + 200
+
+
+def test_step_peak_adds_the_other_layers_of_a_scanned_period():
+    """A scan whose iteration runs `period` layers holds, in its backward
+    pass, that iteration's own slices of gradients, kept values and inputs
+    beside the stacked ones: one layer's are in `block`, the others' are
+    added (the all-expert stack: 2 iterations of 4 layers)."""
+    from ray_tpu.models.transformer import RematCandidate
+    from ray_tpu.train.lm import step_peak_bytes
+
+    run = {"scanned": True, "gradients": 800, "inputs": 160, "block": 100, "period": 4, "layers": 8}
+    kept = RematCandidate(("a",), 5, 1, 1, False, (8,))             # 8 x 10 x 5 x 2 = 800 B
+
+    def peak(kept, run):
+        return step_peak_bytes(kept, rows=10, itemsize=2, always=1000.0, logits=0, runs=(run,))
+
+    assert peak((), dict(run, period=1)) == 1000 + 100 + 800 + 160
+    assert peak((), run) == 1000 + 100 + 960 + 960 * 3 / 8
+    assert peak((kept,), run) == 1000 + 100 + 1760 + 1760 * 3 / 8
 
 
 def test_selective_step_on_a_mesh_gives_one_devices_loss(monkeypatch):
