@@ -13,6 +13,12 @@ query-head groups, no materialized repeat on the forward path).
 
 All shapes are static; padding to block multiples happens in the wrapper and
 is masked inside the kernel, so XLA never sees dynamic shapes.
+
+The grid, by what a call can observe of its own shape: one (S, S) tile a
+head up to S = 1,024, walked in sub-tiles; past it a causal call steps
+through the lower triangle of 1,024-wide tiles alone, a windowed one through
+the band of tiles its window touches, and any other call through a dense
+grid of blocks (the comment above `_SUB_TILE`).
 """
 
 from __future__ import annotations
@@ -100,10 +106,28 @@ def mha_reference(
 # counts with the same two functions.
 #
 # The walk needs the strip's place against the diagonal while tracing, so
-# it engages with one grid tile a head. With more (S > 1,024, explicit
-# small blocks) a grid tile is its own single sub-tile whose offsets are
-# traced scalars: live or not is then decided per grid step
-# (`_walk_strip`), which is the grid-level skip the kernels always had.
+# it engages with one grid tile a head, and wherever a tile's place against
+# the diagonal is static whatever its place in the sequence:
+#
+# A CAUSAL self-attention call past one tile a head (S > 1,024, square
+# tiles, no window: `_live_grid`) has a grid of its LIVE tiles alone, the
+# lower triangle: nq (nq + 1) / 2 steps a head on one flattened axis whose
+# (q tile, kv tile) come from two prefetched tables (`_triangle`), so a tile
+# above the diagonal is neither a step nor a copy. A live tile is of one of
+# two classes, each emitted once (`_triangle_classes`): BELOW the diagonal
+# every pair is live, one whole unmasked piece (one softmax step a tile in
+# the forward); ON it the tile is what a one-tile call's is, walked in
+# sub-tiles or computed whole with a static mask, a kernel
+# (`_DIAGONAL_WALK`). The forward and dQ run a q tile's kv tiles 0..i, the
+# carry initialised at the first and written at the diagonal; dK/dV runs a
+# kv tile's q tiles j..nq - 1 from the diagonal down. `kv_len` is not
+# tested there: causality implies it on every real row, a padded row's
+# output is sliced off and its cotangent is zero.
+#
+# Any other grid of tiles (not causal, explicit blocks that are not square,
+# ring attention's blocks) is dense, and a grid tile is its own single
+# sub-tile whose offsets are traced scalars: live or not is then decided per
+# grid step (`_walk_strip`), and a live tile is computed masked.
 #
 # A WINDOWED call (key j live for query i iff i - window < j <= i) has a
 # third edge, the window's lower one, and a grid of its own: its kv axis is
@@ -121,6 +145,16 @@ def mha_reference(
 # won, or lost by under 1%, in all three kernels at both widths, so the
 # size depends on nothing a call can observe but its block.
 _SUB_TILE = (256, 256)
+
+# Whether a kernel walks a causal grid's DIAGONAL tile in those sub-tiles (10 of
+# 16, 4 of them masked) or computes it whole and masked (`_triangle_classes`).
+# One alternating chip sweep at the three cells' shapes, D = 128 (1 x 28/4 x
+# 16,384, 2 x 32/4 x 8,192, 4 x 16/16 x 4,096; PERF.md section 6, PR 37), ms a
+# call whole / walked: dK/dV 23.99 / 23.31, 15.17 / 14.38, 4.64 / 4.25 and dQ
+# 18.25 / 17.61, 11.69 / 10.96, 3.68 / 3.31, the walk wins at all three; the
+# forward 15.62 / 16.30, 9.69 / 10.46, 2.85 / 3.24, it loses at all three (a
+# softmax step a 256-row strip, as in the windowed forward).
+_DIAGONAL_WALK = {"flash_fwd": False, "flash_bwd_dkv": True, "flash_bwd_dq": True}
 
 
 def _sub_tiles(block_q: int, block_kv: int, grid_tiles: int):
@@ -219,16 +253,20 @@ def window_band(window: int, block: int, tiles: int) -> int:
 
 def attention_subtiles(sq: int, skv: int, causal: bool, kv_len: int,
                        block_q: int, block_kv: int, sub_q: int, sub_kv: int,
-                       window: Optional[int] = None):
+                       window: Optional[int] = None, kernel: str = "flash_bwd_dq"):
     """(visited, masked, total) sub-tiles of one head's (sq, skv) score
     matrix as the kernels walk it: `visited` run their matmuls, `masked`
     of those build the mask, `total` is what a dense walk would visit.
-    Counted with the kernels' own loop bounds."""
+    Counted with the kernels' own loop bounds. The three kernels walk alike
+    but for a causal grid's diagonal tiles (`_DIAGONAL_WALK`), which `kernel`
+    decides: the forward computes them whole."""
     visited = masked = 0
+    total = (sq // sub_q) * (skv // sub_kv)
+    tiles = sq // block_q
     if window is not None:
         # a band of tiles at static offsets from the diagonal (`_band_tiles`)
         band = window_band(window, block_q, skv // block_kv)
-        for i in range(sq // block_q):
+        for i in range(tiles):
             for d in range(min(band, i + 1)):
                 for a in range(block_q // sub_q):
                     first, full_lo, full_hi, live = _kv_range(
@@ -236,8 +274,18 @@ def attention_subtiles(sq: int, skv: int, causal: bool, kv_len: int,
                         sub_q, sub_kv, causal, None, window)
                     visited += live - first
                     masked += (live - first) - (full_hi - full_lo)
-        return visited, masked, (sq // sub_q) * (skv // sub_kv)
-    for i in range(sq // block_q):
+        return visited, masked, total
+    if _live_grid(causal, window, block_q, block_kv, tiles, skv // block_kv):
+        # the lower triangle's tiles (`_triangle_classes`): those below the
+        # diagonal whole and unmasked, the diagonal's as one tile a head is
+        # walked, or whole and masked
+        whole = (block_q // sub_q) * (block_kv // sub_kv)
+        on_visited, on_masked = whole, whole
+        if _DIAGONAL_WALK[kernel]:
+            on_visited, on_masked, _ = attention_subtiles(
+                block_q, block_kv, True, block_kv, block_q, block_kv, sub_q, sub_kv)
+        return (tiles * (tiles - 1) // 2 * whole + tiles * on_visited, tiles * on_masked, total)
+    for i in range(tiles):
         for j in range(skv // block_kv):
             for a in range(block_q // sub_q):
                 _, _, full, live = _kv_range(
@@ -245,9 +293,26 @@ def attention_subtiles(sq: int, skv: int, causal: bool, kv_len: int,
                     sub_q, sub_kv, causal, kv_len)
                 visited += live
                 masked += live - full
-    if sq // block_q * (skv // block_kv) > 1:
+    if tiles * (skv // block_kv) > 1:
         masked = visited  # traced offsets: `_walk_strip` masks every live tile
-    return visited, masked, (sq // sub_q) * (skv // sub_kv)
+    return visited, masked, total
+
+
+def attention_grid_steps(sq: int, skv: int, causal: bool, kv_len: int, block_q: int,
+                         block_kv: int, window: Optional[int] = None):
+    """(steps, live) of one head's (q tile, kv tile) grid: the steps a kernel
+    is run and copies its blocks for, and those of them whose tile holds a
+    live pair. A band and a causal grid step through live tiles alone (but a
+    band's first rows, whose window starts before the sequence)."""
+    nq, nk = sq // block_q, skv // block_kv
+    if window is not None:
+        band = window_band(window, block_q, nk)
+        return nq * band, sum(min(band, i + 1) for i in range(nq))
+    if _live_grid(causal, window, block_q, block_kv, nq, nk):
+        return (nq * (nq + 1) // 2,) * 2
+    live = sum(1 for i in range(nq) for j in range(nk)
+               if j * block_kv < kv_len and (not causal or j * block_kv <= (i + 1) * block_q - 1))
+    return nq * nk, live
 
 
 def _static(*xs) -> bool:
@@ -314,6 +379,46 @@ def _band_tiles(band, step, tile, tiles, toward_diagonal, strips):
             pl.when(here)(run)
 
 
+def _live_grid(causal, window, block_q, block_kv, num_q_blocks, num_kv_blocks) -> bool:
+    """Whether a call's grid holds its live tiles alone, in two classes: causal
+    self-attention with no window, square tiles and more than one a head."""
+    return bool(causal and window is None and block_q == block_kv
+                and num_q_blocks == num_kv_blocks > 1)
+
+
+def _triangle(tiles: int, by_kv: bool):
+    """The steps of a causal grid's one tile axis, as the (q tile, kv tile)
+    tables its index maps and kernels read (scalar prefetch): the lower
+    triangle row by row, kv tiles 0..i of q tile i with the diagonal last
+    (forward, dQ), or `by_kv` column by column, q tiles j..tiles - 1 of kv tile
+    j with the diagonal first (dK/dV)."""
+    pairs = ([(i, j) for j in range(tiles) for i in range(j, tiles)] if by_kv
+             else [(i, j) for i in range(tiles) for j in range(i + 1)])
+    q_tiles, kv_tiles = zip(*pairs)
+    return jnp.asarray(q_tiles, jnp.int32), jnp.asarray(kv_tiles, jnp.int32)
+
+
+def _triangle_tile(q_tiles_ref, kv_tiles_ref):
+    """(i, j) of a causal grid's step, read from `_triangle`'s tables."""
+    step = pl.program_id(2)
+    return q_tiles_ref[step], kv_tiles_ref[step]
+
+
+def _triangle_classes(kernel, i, j, block_q, block_kv, strips):
+    """A causal grid's step: the code of its tile's class. Both classes have
+    STATIC offsets against the diagonal, as a band's tiles have, so `strips`
+    takes them as (q tile, kv tile) of a head: the diagonal tile is (0, 0),
+    walked in sub-tiles where `_DIAGONAL_WALK` says so and else one masked
+    piece; a tile below it is (1, 0) as ONE unmasked piece (a softmax step a
+    256-row strip made the windowed forward a third dearer a tile than the
+    whole one: PERF.md section 6, PR 33 and PR 37). Each is emitted once
+    whatever the length."""
+    whole = (block_q, block_kv)
+    diagonal = _sub_tiles(block_q, block_kv, 1) if _DIAGONAL_WALK[kernel] else whole
+    pl.when(i == j)(functools.partial(strips, 0, 0, *diagonal))
+    pl.when(i != j)(functools.partial(strips, 1, 0, *whole))
+
+
 def _scores(q, k, scale, mask_at, causal, kv_len, window=None):
     """QK^T of one piece in the base-2 log domain. `mask_at` is None for
     a piece of live pairs only, else its (row0, col0)."""
@@ -375,12 +480,7 @@ def _write_out(o_ref, lse_ref, rows, m, l, acc):
 
 
 def _fwd_kernel(
-    q_ref,
-    k_ref,
-    v_ref,
-    o_ref,
-    lse_ref,
-    *scratch,
+    *refs,
     sm_scale: float,
     causal: bool,
     block_q: int,
@@ -392,12 +492,14 @@ def _fwd_kernel(
     num_kv_blocks: int,
     window: Optional[int] = None,
     band: int = 0,
+    triangle: bool = False,
 ):
+    # `triangle` (a causal grid of live tiles): `_triangle`'s tables come first
+    tiles, (q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch) = refs[:2 * triangle], refs[2 * triangle:]
     # `band` > 0 (a windowed call): the kv grid axis is the band's, not the
     # sequence's
     kv_steps = band or num_kv_blocks
-    i, j = _grid_tile(num_q_blocks, kv_steps, 2, 3)
-    n = block_kv // sub_kv
+    i, j = _triangle_tile(*tiles) if triangle else _grid_tile(num_q_blocks, kv_steps, 2, 3)
     # one kv block a row: a strip's softmax is whole and goes straight to
     # the output. More: (m, l, acc) ride in scratch across the kv axis.
     carried = kv_steps > 1
@@ -414,7 +516,7 @@ def _fwd_kernel(
     # per strip of queries: one softmax step over its live kv sub-tiles.
     # Causal: a strip (with a grid of tiles, the whole tile) above the
     # diagonal has none.
-    def strips(i, j):
+    def strips(i, j, sub_q=sub_q, sub_kv=sub_kv):
         for a in range(block_q // sub_q):
             row0 = i * block_q + a * sub_q
             rows = pl.ds(a * sub_q, sub_q)
@@ -443,16 +545,19 @@ def _fwd_kernel(
                 m_scr[rows, :] = jnp.broadcast_to(m, (sub_q, m_scr.shape[1]))
                 l_scr[rows, :] = jnp.broadcast_to(l, (sub_q, l_scr.shape[1]))
 
-            _walk_strip(row0, j * block_kv, n, sub_q, sub_kv, causal, kv_len, "kv",
-                        visit, always=not carried, window=window)
+            _walk_strip(row0, j * block_kv, block_kv // sub_kv, sub_q, sub_kv, causal,
+                        kv_len, "kv", visit, always=not carried, window=window)
 
     if band:
         _band_tiles(band, j, i, num_q_blocks, True, strips)
+    elif triangle:
+        _triangle_classes("flash_fwd", i, j, block_q, block_kv, strips)
     else:
         strips(i, j)
 
     if carried:
-        pl.when(j == kv_steps - 1)(lambda: _write_out(
+        # a row's last kv tile: the diagonal's, or the grid axis's
+        pl.when(j == (i if triangle else kv_steps - 1))(lambda: _write_out(
             o_ref, lse_ref, slice(None), m_scr[:, :1], l_scr[:, :1], acc_scr[...]))
 
 
@@ -466,7 +571,17 @@ def _band_index(band: int, toward_diagonal: bool, tiles: int):
     return lambda j, t: jnp.minimum(j + t, tiles - 1)
 
 
-def _fwd_pallas(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret, window=None):
+def _grid_call(kernel, tables, grid, in_specs, out_specs, scratch_shapes, **how):
+    """`pl.pallas_call` over `grid`; with a causal grid's `tables` as its
+    scalar prefetch, which every index map and the kernel then take too."""
+    spec = dict(grid=grid, in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch_shapes)
+    if tables:
+        spec = {"grid_spec": pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=len(tables), **spec)}
+    return pl.pallas_call(kernel, **spec, **how)
+
+
+def _fwd_pallas(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret, window=None,
+                triangle=False):
     b, hq, sq, d = q.shape
     _, hkv, skv, _ = k.shape
     groups = hq // hkv
@@ -484,42 +599,46 @@ def _fwd_pallas(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret,
         block_kv=block_kv,
         sub_q=sub_q,
         sub_kv=sub_kv,
-        kv_len=None if band else kv_len,
+        kv_len=None if band or triangle else kv_len,
         num_q_blocks=nq,
         num_kv_blocks=nk,
         **({"window": window, "band": band} if band else {}),
+        triangle=triangle,
     )
     kv_steps = band or nk
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(b, hq, nq, kv_steps),
+    if triangle:
+        tables = _triangle(nq, by_kv=False)
+        grid = (b, hq, len(tables[0]))
+        q_at = lambda b_, h, t, qt, kt: (b_, h, qt[t], 0)  # noqa: E731
+        kv_at = lambda b_, h, t, qt, kt: (b_, h // groups, kt[t], 0)  # noqa: E731
+    else:
+        tables = ()
+        grid = (b, hq, nq, kv_steps)
+        q_at = lambda b_, h, i, j: (b_, h, i, 0)  # noqa: E731
+        kv_at = lambda b_, h, i, j: (b_, h // groups, kv_tile(i, j), 0)  # noqa: E731
+    out, lse = _grid_call(
+        kernel, tables, grid,
         in_specs=[
-            pl.BlockSpec((1, 1, block_q, d), lambda b_, h, i, j: (b_, h, i, 0)),
-            pl.BlockSpec(
-                (1, 1, block_kv, d),
-                lambda b_, h, i, j, g=groups: (b_, h // g, kv_tile(i, j), 0)
-            ),
-            pl.BlockSpec(
-                (1, 1, block_kv, d),
-                lambda b_, h, i, j, g=groups: (b_, h // g, kv_tile(i, j), 0)
-            ),
+            pl.BlockSpec((1, 1, block_q, d), q_at),
+            pl.BlockSpec((1, 1, block_kv, d), kv_at),
+            pl.BlockSpec((1, 1, block_kv, d), kv_at),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, d), lambda b_, h, i, j: (b_, h, i, 0)),
-            pl.BlockSpec((1, 1, block_q, 1), lambda b_, h, i, j: (b_, h, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((b, hq, sq, 1), jnp.float32),
+            pl.BlockSpec((1, 1, block_q, d), q_at),
+            pl.BlockSpec((1, 1, block_q, 1), q_at),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ] if kv_steps > 1 else [],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
+            jax.ShapeDtypeStruct((b, hq, sq, 1), jnp.float32),
+        ],
         interpret=interpret,
         name="flash_win_fwd" if band else "flash_fwd",
-    )(q, k, v)
+    )(*tables, q, k, v)
     return out, lse
 
 
@@ -564,16 +683,17 @@ def _accumulate(out_ref, scr, carried, where, value):
 
 
 def _dkv_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-    dk_ref, dv_ref, *scratch,
+    *refs,
     sm_scale, causal, block_q, block_kv, sub_q, sub_kv, kv_len,
-    num_q_blocks, num_kv_blocks, window=None, band=0,
+    num_q_blocks, num_kv_blocks, window=None, band=0, triangle=False,
 ):
+    tiles, (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+            dk_ref, dv_ref, *scratch) = refs[:2 * triangle], refs[2 * triangle:]
     # grid: kv block outer (axis 2), q block inner (axis 3): the sequence's q
-    # blocks, or a windowed call's band of them
+    # blocks, or a windowed call's band of them; a causal grid's one tile
+    # axis runs a kv block's q blocks from the diagonal down
     q_steps = band or num_q_blocks
-    i, j = _grid_tile(q_steps, num_kv_blocks, 3, 2)
-    n = block_q // sub_q
+    i, j = _triangle_tile(*tiles) if triangle else _grid_tile(q_steps, num_kv_blocks, 3, 2)
     carried = q_steps > 1  # dK_j, dV_j summed over q blocks in scratch
     dk_scr, dv_scr = scratch if carried else (None, None)
     if carried:
@@ -581,9 +701,9 @@ def _dkv_kernel(
             dk_scr[...] = jnp.zeros_like(dk_scr)
             dv_scr[...] = jnp.zeros_like(dv_scr)
 
-        pl.when(i == 0)(_init)
+        pl.when(i == (j if triangle else 0))(_init)
 
-    def strips(i, j):
+    def strips(i, j, sub_q=sub_q, sub_kv=sub_kv):
         for c in range(block_kv // sub_kv):
             col0 = j * block_kv + c * sub_kv
             cols = pl.ds(c * sub_kv, sub_kv)
@@ -616,12 +736,14 @@ def _dkv_kernel(
                 _accumulate(dv_ref, dv_scr, carried, cols, dv)
                 _accumulate(dk_ref, dk_scr, carried, cols, dk)
 
-            _walk_strip(i * block_q, col0, n, sub_q, sub_kv, causal, kv_len, "q",
-                        visit, always=not carried, window=window)
+            _walk_strip(i * block_q, col0, block_q // sub_q, sub_q, sub_kv, causal, kv_len,
+                        "q", visit, always=not carried, window=window)
 
     if band:
         # from the diagonal tile down: q tile j + t of kv tile j
         _band_tiles(band, i, j, num_q_blocks, False, strips)
+    elif triangle:
+        _triangle_classes("flash_bwd_dkv", i, j, block_q, block_kv, strips)
     else:
         strips(i, j)
 
@@ -634,15 +756,16 @@ def _dkv_kernel(
 
 
 def _dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-    dq_ref, *scratch,
+    *refs,
     sm_scale, causal, block_q, block_kv, sub_q, sub_kv, kv_len,
-    num_q_blocks, num_kv_blocks, window=None, band=0,
+    num_q_blocks, num_kv_blocks, window=None, band=0, triangle=False,
 ):
-    # grid: q block outer (axis 2), kv block inner (axis 3)
+    tiles, (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+            dq_ref, *scratch) = refs[:2 * triangle], refs[2 * triangle:]
+    # grid: q block outer (axis 2), kv block inner (axis 3); a causal grid's
+    # one tile axis runs a q block's kv blocks up to the diagonal
     kv_steps = band or num_kv_blocks
-    i, j = _grid_tile(num_q_blocks, kv_steps, 2, 3)
-    n = block_kv // sub_kv
+    i, j = _triangle_tile(*tiles) if triangle else _grid_tile(num_q_blocks, kv_steps, 2, 3)
     carried = kv_steps > 1  # dQ_i summed over kv blocks in scratch
     (dq_scr,) = scratch if carried else (None,)
     if carried:
@@ -651,7 +774,7 @@ def _dq_kernel(
 
         pl.when(j == 0)(_init)
 
-    def strips(i, j):
+    def strips(i, j, sub_q=sub_q, sub_kv=sub_kv):
         for a in range(block_q // sub_q):
             row0 = i * block_q + a * sub_q
             rows = pl.ds(a * sub_q, sub_q)
@@ -677,11 +800,13 @@ def _dq_kernel(
                     dq = dq_cur if dq is None else dq + dq_cur
                 _accumulate(dq_ref, dq_scr, carried, rows, dq)
 
-            _walk_strip(row0, j * block_kv, n, sub_q, sub_kv, causal, kv_len, "kv",
-                        visit, always=not carried, window=window)
+            _walk_strip(row0, j * block_kv, block_kv // sub_kv, sub_q, sub_kv, causal,
+                        kv_len, "kv", visit, always=not carried, window=window)
 
     if band:
         _band_tiles(band, j, i, num_q_blocks, True, strips)
+    elif triangle:
+        _triangle_classes("flash_bwd_dq", i, j, block_q, block_kv, strips)
     else:
         strips(i, j)
 
@@ -689,11 +814,11 @@ def _dq_kernel(
         def _finalize():
             dq_ref[0, 0] = dq_scr[...].astype(dq_ref.dtype)
 
-        pl.when(j == kv_steps - 1)(_finalize)
+        pl.when(j == (i if triangle else kv_steps - 1))(_finalize)
 
 
 def _bwd_pallas(q, k, v, out, lse, do, causal, sm_scale, block_q, block_kv, kv_len, interpret,
-                window=None):
+                window=None, triangle=False):
     b, h, sq, d = q.shape
     skv = k.shape[2]
     nq = sq // block_q
@@ -712,62 +837,83 @@ def _bwd_pallas(q, k, v, out, lse, do, causal, sm_scale, block_q, block_kv, kv_l
     def kernel(fn):
         return functools.partial(
             fn, sm_scale=sm_scale, causal=causal, block_q=block_q,
-            block_kv=block_kv, sub_q=sub_q, sub_kv=sub_kv, kv_len=None if band else kv_len,
+            block_kv=block_kv, sub_q=sub_q, sub_kv=sub_kv,
+            kv_len=None if band or triangle else kv_len,
             num_q_blocks=nq, num_kv_blocks=nk,
             **({"window": window, "band": band} if band else {}),
+            triangle=triangle,
         )
 
-    q_spec = pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, j, i: (b_, h_, q_tile(j, i), 0))
-    kv_spec = pl.BlockSpec((1, 1, block_kv, d), lambda b_, h_, j, i: (b_, h_, j, 0))
-    row_spec = pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, j, i: (b_, h_, q_tile(j, i), 0))
+    def specs(q_at, kv_at):
+        q_spec = pl.BlockSpec((1, 1, block_q, d), q_at)
+        kv_spec = pl.BlockSpec((1, 1, block_kv, d), kv_at)
+        row_spec = pl.BlockSpec((1, 1, block_q, 1), q_at)
+        return q_spec, kv_spec, [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec]
 
-    dk, dv = pl.pallas_call(
-        kernel(_dkv_kernel),
-        grid=(b, h, nk, band or nq),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+    if triangle:
+        by_kv, by_q = _triangle(nq, by_kv=True), _triangle(nq, by_kv=False)
+        dkv_grid, dq_grid = (b, h, len(by_kv[0])), (b, h, len(by_q[0]))
+        q_at = q_at2 = lambda b_, h_, t, qt, kt: (b_, h_, qt[t], 0)  # noqa: E731
+        kv_at = kv_at2 = lambda b_, h_, t, qt, kt: (b_, h_, kt[t], 0)  # noqa: E731
+    else:
+        by_kv = by_q = ()
+        dkv_grid, dq_grid = (b, h, nk, band or nq), (b, h, nq, band or nk)
+        q_at = lambda b_, h_, j, i: (b_, h_, q_tile(j, i), 0)  # noqa: E731
+        kv_at = lambda b_, h_, j, i: (b_, h_, j, 0)  # noqa: E731
+        q_at2 = lambda b_, h_, i, j: (b_, h_, i, 0)  # noqa: E731
+        kv_at2 = lambda b_, h_, i, j: (b_, h_, kv_tile(i, j), 0)  # noqa: E731
+
+    _, kv_spec, in_specs = specs(q_at, kv_at)
+    dk, dv = _grid_call(
+        kernel(_dkv_kernel), by_kv, dkv_grid,
+        in_specs=in_specs,
         out_specs=[kv_spec, kv_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
-        ],
         scratch_shapes=[
             pltpu.VMEM((block_kv, d), jnp.float32),
             pltpu.VMEM((block_kv, d), jnp.float32),
         ] if (band or nq) > 1 else [],
+        out_shape=[
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+        ],
         interpret=interpret,
         name="flash_win_bwd_dkv" if band else "flash_bwd_dkv",
-    )(q, k, v, do, lse, delta)
+    )(*by_kv, q, k, v, do, lse, delta)
 
-    q_spec2 = pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, i, j: (b_, h_, i, 0))
-    kv_spec2 = pl.BlockSpec((1, 1, block_kv, d), lambda b_, h_, i, j: (b_, h_, kv_tile(i, j), 0))
-    row_spec2 = pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, i, j: (b_, h_, i, 0))
-
-    dq = pl.pallas_call(
-        kernel(_dq_kernel),
-        grid=(b, h, nq, band or nk),
-        in_specs=[q_spec2, kv_spec2, kv_spec2, q_spec2, row_spec2, row_spec2],
+    q_spec2, _, in_specs2 = specs(q_at2, kv_at2)
+    dq = _grid_call(
+        kernel(_dq_kernel), by_q, dq_grid,
+        in_specs=in_specs2,
         out_specs=q_spec2,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)] if (band or nk) > 1 else [],
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
         name="flash_win_bwd_dq" if band else "flash_bwd_dq",
-    )(q, k, v, do, lse, delta)
+    )(*by_q, q, k, v, do, lse, delta)
     return dq, dk, dv
 
 
 # ----------------------------------------------------------- custom_vjp plumbing
 
 
+def _live_grid_of(q, k, causal, window, block_q, block_kv) -> bool:
+    """`_live_grid` of a padded self-attention call's operands."""
+    return _live_grid(causal, window, block_q, block_kv,
+                      q.shape[2] // block_q, k.shape[2] // block_kv)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
 def _flash(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret, window=None,
            lse_first=False):
-    out, _ = _fwd_pallas(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret, window)
+    out, _ = _fwd_pallas(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret, window,
+                         _live_grid_of(q, k, causal, window, block_q, block_kv))
     return out
 
 
 def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret, window=None,
                lse_first=False):
-    out, lse = _fwd_pallas(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret, window)
+    out, lse = _fwd_pallas(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret, window,
+                           _live_grid_of(q, k, causal, window, block_q, block_kv))
     # What a recomputing block (jax.checkpoint) may keep in place of this
     # call: both or neither, the backward rebuilds the probabilities from the
     # lse, so the output alone spares nothing. The lse is held as (B, H, S):
@@ -798,7 +944,7 @@ def _flash_bwd(causal, sm_scale, block_q, block_kv, kv_len, interpret, window, l
         k_full, v_full = k, v
     dq, dk, dv = _bwd_pallas(
         q, k_full, v_full, out, lse, do, causal, sm_scale, block_q, block_kv,
-        kv_len, interpret, window,
+        kv_len, interpret, window, _live_grid_of(q, k, causal, window, block_q, block_kv),
     )
     if groups > 1:
         b, _, skv, d = dk.shape
@@ -864,19 +1010,24 @@ def attention_plan(seq: int, *, causal: bool = True,
     For callers that report it: LMTrainer's `train.init.step_fn` span,
     chip_smoke.py."""
     impl = resolve_attention_impl(implementation)
-    visited = masked = total = 0
+    visited = masked = total = steps = live = 0
     if impl != "xla":
         bq, bkv = _blocks(seq, seq, None, None, window)
         padded_q, padded_kv = seq + (-seq) % bq, seq + (-seq) % bkv
-        grid_tiles = 1 if window is not None else (padded_q // bq) * (padded_kv // bkv)
+        nq, nk = padded_q // bq, padded_kv // bkv
+        # a band's and a causal grid's tiles lie at static offsets: walked
+        static = window is not None or _live_grid(causal, window, bq, bkv, nq, nk)
         visited, masked, total = attention_subtiles(
             padded_q, padded_kv, causal, seq, bq, bkv,
-            *_sub_tiles(bq, bkv, grid_tiles), window)
+            *_sub_tiles(bq, bkv, 1 if static else nq * nk), window)
+        steps, live = attention_grid_steps(padded_q, padded_kv, causal, seq, bq, bkv, window)
     return {
         "attention_impl": impl,
         "attn_subtiles_visited": visited,
         "attn_subtiles_masked": masked,
         "attn_subtiles_total": total,
+        "attn_grid_steps": steps,
+        "attn_grid_steps_live": live,
     }
 
 
@@ -937,8 +1088,16 @@ def flash_attention(
     ~0.9 us of DMA issue and bookkeeping, which is why smaller grid blocks
     lost every sweep. Inside the resident block the kernels walk 256 x 256
     sub-tiles and skip the dead ones (the comment above `_SUB_TILE`; the
-    chip sweep is in PERF.md section 6, PR 26). Explicit `block_q` /
-    `block_kv` make a grid of smaller blocks, each its own sub-tile.
+    chip sweep is in PERF.md section 6, PR 26).
+
+    Past one tile a head a causal call's grid is the lower triangle of
+    tiles alone (136 steps a head at S = 16,384 where the square has 256):
+    a tile above the diagonal is neither stepped through nor copied, one
+    below it runs whole and unmasked, the diagonal's as a one-tile call's
+    does (PERF.md section 6, PR 37). Explicit square `block_q` / `block_kv`
+    make the same grid of smaller tiles; a call that is not causal, or whose
+    blocks are not square, a dense grid of blocks, each its own masked
+    sub-tile.
 
     window (causal self-attention only): key j is live for query i iff
     i - window < j <= i. The same kernels under the names `flash_win_*`:

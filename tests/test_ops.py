@@ -141,6 +141,54 @@ def test_flash_subtile_walk_matches_reference(case):
                                    atol=1e-3, rtol=1e-3)
 
 
+_CAUSAL_GRID_CASES = {
+    # name: (S, Hq, Hkv, block): 4 x 4 tiles of 512 (10 live), walked in 256s
+    "s2048-b512-gqa4to2": (2048, 4, 2, 512),
+    "s2048-b512-mha2": (2048, 2, 2, 512),
+    # no tile divides it: padded to 2,048, the last tile's rows past 1,900 sliced off
+    "s1900-b512-padded": (1900, 2, 1, 512),
+    # an odd number of tiles a side
+    "s1536-b512-3x3": (1536, 2, 2, 512),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CAUSAL_GRID_CASES))
+def test_causal_grid_matches_reference_and_dense_grid(monkeypatch, case):
+    """The grid of live tiles (`_live_grid`: causal, square tiles, more than
+    one a head) against `mha_reference` and against the values of the dense
+    grid it replaced, forward and all three gradients."""
+    from ray_tpu.ops import attention as A
+
+    s, hq, hkv, block = _CAUSAL_GRID_CASES[case]
+    keys = jax.random.split(jax.random.PRNGKey(37), 4)
+    q, w = (_rand(key, (1, hq, s, 32)) for key in (keys[0], keys[3]))
+    k, v = (_rand(key, (1, hkv, s, 32)) for key in keys[1:3])
+
+    def both(attend):
+        loss = lambda q, k, v: jnp.sum(attend(q, k, v) * w)  # noqa: E731
+        return (attend(q, k, v), *jax.grad(loss, argnums=(0, 1, 2))(q, k, v))
+
+    kernels = lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, causal=True, implementation="pallas", block_q=block, block_kv=block)
+    names = []
+    call = A.pl.pallas_call
+    monkeypatch.setattr(A.pl, "pallas_call", lambda *a, **kw: (
+        names.append((kw["name"], "grid_spec" in kw)), call(*a, **kw))[1])
+    live = both(kernels)
+    assert set(names) == {("flash_fwd", True), ("flash_bwd_dkv", True), ("flash_bwd_dq", True)}
+    names.clear()
+    monkeypatch.setattr(A, "_live_grid", lambda *a: False)
+    dense = both(kernels)
+    assert set(names) == {("flash_fwd", False), ("flash_bwd_dkv", False), ("flash_bwd_dq", False)}
+    reference = both(lambda q, k, v: mha_reference(q, k, v, causal=True))
+    for got, was, want, tol in zip(live, dense, reference, (2e-5, 1e-3, 1e-3, 1e-3)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=tol, rtol=tol)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(was), atol=2e-5, rtol=2e-5)
+    # the forward sums in the dense grid's order: its values are the parent's,
+    # to what the interpreter's own fusions round
+    np.testing.assert_allclose(np.asarray(live[0]), np.asarray(dense[0]), atol=1e-6, rtol=0)
+
+
 def test_flash_rows_no_subtile_reaches():
     """kv_len = 0 visits nothing: o = 0 and lse = -inf, the contract ring
     attention's merge relies on. Padded query rows (beyond kv_len) see
@@ -171,21 +219,60 @@ def test_flash_rows_no_subtile_reaches():
      ((1024, 1024, True, 1024, 1024, 1024, 128, 128), (36, 8, 64)),
      ((1024, 1024, True, 1024, 1024, 1024, 512, 512), (3, 2, 4)),
      ((1024, 1024, False, 1024, 1024, 1024, 256, 256), (16, 0, 16)),
-     # a grid of tiles a head: each its own sub-tile, masked when live
-     ((2048, 2048, True, 2048, 1024, 1024, 1024, 1024), (3, 3, 4)),
+     # a causal grid of tiles a head, each its own sub-tile: the one below the
+     # diagonal unmasked
+     ((2048, 2048, True, 2048, 1024, 1024, 1024, 1024), (3, 2, 4)),
      # one tile, kv_len 700: 3 of 4 kv sub-tiles a strip, the third crossed
      ((1024, 1024, False, 700, 1024, 1024, 256, 256), (12, 4, 16)),
      # rectangular sub-tiles
      ((1024, 1024, True, 1024, 1024, 1024, 512, 256), (6, 4, 8)),
      # explicit small blocks: a grid of single sub-tiles
-     ((256, 256, True, 256, 128, 64, 128, 64), (6, 6, 8))],
+     ((256, 256, True, 256, 128, 64, 128, 64), (6, 6, 8)),
+     # a causal grid: below the diagonal 16 of 16 unmasked a tile, the diagonal
+     # tile walked as one tile a head is (10, 4 of them masked) ...
+     ((16384, 16384, True, 16384, 1024, 1024, 256, 256), (2080, 64, 4096)),
+     ((8192, 8192, True, 8192, 1024, 1024, 256, 256), (528, 32, 1024)),
+     ((4096, 4096, True, 4096, 1024, 1024, 256, 256), (136, 16, 256)),
+     # ... whatever kv_len says (causality implies it on every real row)
+     ((2048, 2048, True, 1536, 1024, 1024, 256, 256), (36, 8, 64)),
+     ((2048, 2048, True, 2048, 512, 512, 256, 256), (36, 8, 64)),
+     # ... but by the forward, which computes it whole and masked
+     ((16384, 16384, True, 16384, 1024, 1024, 256, 256, None, "flash_fwd"), (2176, 256, 4096)),
+     ((4096, 4096, True, 4096, 1024, 1024, 256, 256, None, "flash_bwd_dkv"), (136, 16, 256)),
+     # not causal, or not square: the dense grid, every live tile masked
+     ((2048, 2048, False, 2048, 1024, 1024, 1024, 1024), (4, 4, 4)),
+     ((2048, 2048, True, 2048, 1024, 512, 1024, 512), (6, 6, 8))],
     ids=["causal-256", "causal-128", "causal-512", "full-256", "grid2x2",
-         "kv-edge", "rectangular", "small-blocks"],
+         "kv-edge", "rectangular", "small-blocks", "causal-grid-16k", "causal-grid-8k",
+         "causal-grid-4k", "causal-grid-padded", "causal-grid-block512", "causal-grid-16k-forward",
+         "causal-grid-4k-dkv", "full-grid2x2", "causal-rectangular-grid"],
 )
 def test_attention_subtiles_counts(args, expect):
     from ray_tpu.ops.attention import attention_subtiles
 
     assert attention_subtiles(*args) == expect
+
+
+@pytest.mark.parametrize(
+    "args,expect",
+    [((16384, 16384, True, 16384, 1024, 1024), (136, 136)),
+     ((4096, 4096, True, 4000, 1024, 1024), (10, 10)),
+     ((1024, 1024, True, 1024, 1024, 1024), (1, 1)),
+     # the dense grid steps through its dead tiles: above the diagonal, beyond kv_len
+     ((4096, 4096, True, 4096, 1024, 512), (32, 20)),
+     ((2048, 2048, False, 1000, 1024, 1024), (4, 2)),
+     ((2048, 2048, False, 2048, 1024, 1024), (4, 4)),
+     # a band: a q tile's first steps fall before the sequence
+     ((8192, 8192, True, 8192, 1024, 1024, 2048), (24, 21))],
+    ids=["causal-grid-16k", "causal-grid-padded", "one-tile", "causal-rectangular",
+         "full-kv-edge", "full", "band"],
+)
+def test_attention_grid_steps(args, expect):
+    """The steps a head's grid has and those of them that hold a live pair:
+    equal for a causal grid, whose steps are the lower triangle's tiles."""
+    from ray_tpu.ops.attention import attention_grid_steps
+
+    assert attention_grid_steps(*args) == expect
 
 
 def test_attention_plan_names_what_runs(monkeypatch):
@@ -195,20 +282,33 @@ def test_attention_plan_names_what_runs(monkeypatch):
 
     assert attention_plan(1024) == {
         "attention_impl": "xla", "attn_subtiles_visited": 0,
-        "attn_subtiles_masked": 0, "attn_subtiles_total": 0}
+        "attn_subtiles_masked": 0, "attn_subtiles_total": 0,
+        "attn_grid_steps": 0, "attn_grid_steps_live": 0}
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert attention_plan(1024) == {
         "attention_impl": "pallas", "attn_subtiles_visited": 10,
-        "attn_subtiles_masked": 4, "attn_subtiles_total": 16}
+        "attn_subtiles_masked": 4, "attn_subtiles_total": 16,
+        "attn_grid_steps": 1, "attn_grid_steps_live": 1}
     assert attention_plan(1024, causal=False)["attn_subtiles_visited"] == 16
     assert attention_plan(1024, implementation="xla")["attention_impl"] == "xla"
-    # a grid of tiles: each its own sub-tile, the one above the diagonal skipped
-    assert attention_plan(2048)["attn_subtiles_visited"] == 3
+    # a causal grid of tiles (the three cells past one tile a head): the live
+    # tiles alone are steps, counted in the walk's sub-tiles
+    for seq, visited, masked, steps in [(2048, 36, 8, 3), (4096, 136, 16, 10),
+                                        (8192, 528, 32, 36), (16384, 2080, 64, 136)]:
+        plan = attention_plan(seq)
+        assert (plan["attn_subtiles_visited"], plan["attn_subtiles_masked"],
+                plan["attn_subtiles_total"]) == (visited, masked, (seq // 256) ** 2)
+        assert plan["attn_grid_steps"] == plan["attn_grid_steps_live"] == steps
+    # not causal: the dense grid, every tile live and masked
+    assert attention_plan(2048, causal=False)["attn_subtiles_masked"] == 4
+    banded = attention_plan(8192, window=2048)
+    assert (banded["attn_grid_steps"], banded["attn_grid_steps_live"]) == (24, 21)
 
 
 @pytest.mark.parametrize("s,causal", [(1024, True), (1024, False), (700, True),
-                                      (2048, True)],
-                         ids=["causal", "full", "odd-block", "grid2x2"])
+                                      (2048, True), (3000, True), (2048, False)],
+                         ids=["causal", "full", "odd-block", "grid2x2", "grid3x3-padded",
+                              "full-grid2x2"])
 def test_kernels_walk_the_counted_subtiles(monkeypatch, s, causal):
     """What the three kernels compute while they are traced is what
     `attention_subtiles` counts: every QK^T piece is recorded with its
@@ -228,21 +328,36 @@ def test_kernels_walk_the_counted_subtiles(monkeypatch, s, causal):
         q, q, q, causal=causal, implementation="pallas").sum())(q)
     block = min(1024, s)
     padded = s + (-s) % block
-    tiles = (padded // block) ** 2
-    sub_q, sub_kv = A._sub_tiles(block, block, tiles)
-    visited, masked, total = A.attention_subtiles(
-        padded, padded, causal, s, block, block, sub_q, sub_kv)
-    if tiles == 1:
+    side = padded // block
+    live_grid = A._live_grid(causal, None, block, block, side, side)
+    sub_q, sub_kv = A._sub_tiles(block, block, 1 if live_grid else side ** 2)
+    area = sub_q * sub_kv
+    counts = {kernel: A.attention_subtiles(padded, padded, causal, s, block, block, sub_q,
+                                           sub_kv, kernel=kernel)
+              for kernel in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq")}
+    if side == 1:
         # static walk: each kernel's pieces add up to the count
-        area = sub_q * sub_kv
+        (visited, masked, total), = set(counts.values())
         assert sum(n for n, _ in pieces) == 3 * visited * area
         assert sum(n for n, m in pieces if m) == 3 * masked * area
         assert total == (padded // sub_q) * (padded // sub_kv)
+    elif live_grid:
+        # a causal grid: a kernel holds one body a class, run per grid step.
+        # Below the diagonal one whole unmasked piece; the diagonal tile's
+        # pieces are the count's, a tile
+        below, whole = side * (side - 1) // 2, (block // sub_q) * (block // sub_kv)
+        assert [p for p in pieces if not p[1] and p[0] == block * block] == [(block * block, False)] * 3
+        on_diagonal = sum(n for n, _ in pieces) - 3 * block * block
+        assert on_diagonal * side == sum(v - below * whole for v, _, _ in counts.values()) * area
+        assert sum(n for n, m in pieces if m) * side == sum(m for _, m, _ in counts.values()) * area
+        assert counts["flash_fwd"][:2] != counts["flash_bwd_dq"][:2] == counts["flash_bwd_dkv"][:2]
+        plan_steps = A.attention_grid_steps(padded, padded, causal, s, block, block)
+        assert plan_steps == (side * (side + 1) // 2,) * 2
     else:
-        # a grid of tiles: one masked whole-tile body a kernel, run or not
-        # per grid step
+        # a dense grid of tiles: one masked whole-tile body a kernel, run or
+        # not per grid step
         assert pieces == [(block * block, True)] * 3
-        assert (visited, masked, total) == (3, 3, 4)
+        assert set(counts.values()) == {(4, 4, 4)}
 
 
 def _tiles_by_runs(runs_of, strips, key):

@@ -64,16 +64,26 @@ LLAMA = ((4, 32, 2048, 128), (4, 8, 2048, 128))       # GQA 32 -> 8, 2 x 2 tiles
 RING = ((4, 8, 2048, 128), (4, 8, 2048, 128))         # a ring-attention block
 
 
+TRINITY = ((2, 32, 8192, 128), (2, 4, 8192, 128))     # train-trinity-mini-8k, GQA 32 -> 4
+SMALLTHINKER = ((1, 28, 16384, 128), (1, 4, 16384, 128))  # train-smallthinker-16k, GQA 28 -> 4
+OLMOE = ((4, 16, 4096, 128), (4, 16, 4096, 128))      # train-olmoe-64e-4k
+
+
 @pytest.mark.parametrize(
-    "shapes", [GPT2, MISTRAL, LLAMA],
-    ids=["gpt2-d64-s1024", "mistral-d128-s1024-gqa", "llama-d128-s2048-grid"],
+    "shapes", [GPT2, MISTRAL, LLAMA, SMALLTHINKER, TRINITY, OLMOE],
+    ids=["gpt2-d64-s1024", "mistral-d128-s1024-gqa", "llama-d128-s2048-grid",
+         "smallthinker-d128-s16384-gqa7", "trinity-d128-s8192-gqa8", "olmoe-d128-s4096"],
 )
 def test_flash_attention_fwd_and_grad_compile(as_tpu, shapes):
     """One grid tile a head (the sub-tile walk, both cells' shapes) and a
-    grid of tiles, at the default blocks and the default rule."""
+    causal grid of live tiles in its two classes (2 x 2 tiles, and the three
+    cells' full layers: 136, 36 and 10 steps a head), at the default blocks
+    and the default rule."""
     q_shape, kv_shape = shapes
     q, k, v = _on(as_tpu, q_shape), _on(as_tpu, kv_shape), _on(as_tpu, kv_shape)
-    assert flash.attention_plan(q_shape[2])["attention_impl"] == "pallas"
+    plan = flash.attention_plan(q_shape[2])
+    assert plan["attention_impl"] == "pallas"
+    assert plan["attn_grid_steps"] == plan["attn_grid_steps_live"]
 
     def attend(q, k, v):
         return flash.flash_attention(q, k, v, causal=True)
@@ -81,12 +91,58 @@ def test_flash_attention_fwd_and_grad_compile(as_tpu, shapes):
     def loss(q, k, v):
         return attend(q, k, v).astype(jnp.float32).sum()
 
-    assert _kernel_calls(jax.jit(attend).lower(q, k, v).compile()) == 1
-    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
-    assert _kernel_calls(grad.lower(q, k, v).compile()) == 3  # fwd, dkv, dq
+    forward = jax.jit(attend).lower(q, k, v).compile()
+    assert _kernel_calls(forward) == 1 and "flash_fwd" in forward.as_text()
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, k, v).compile()
+    assert _kernel_calls(grad) == 3  # fwd, dkv, dq
+    assert all(name in grad.as_text() for name in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"))
+    assert "flash_win" not in grad.as_text()
 
 
-TRINITY = ((2, 32, 8192, 128), (2, 4, 8192, 128))     # train-trinity-mini-8k, GQA 32 -> 4
+def _kernel_payloads(lowered_text: str) -> list:
+    """sha256 of each Mosaic kernel of a lowered program, in order: its MLIR
+    printed without debug info (the payload itself carries the checkout's
+    path and the line of every frame above the kernel)."""
+    import base64
+    import hashlib
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    hashes = []
+    for body in re.findall(r'"body": "([A-Za-z0-9+/=]+)"', lowered_text.replace("\\22", '"')):
+        context = mlir.make_ir_context()
+        context.allow_unregistered_dialects = True  # `stable_mosaic`, for a described chip
+        with context:
+            text = ir.Module.parse(base64.b64decode(body)).operation.get_asm(enable_debug_info=False)
+        hashes.append(hashlib.sha256(re.sub(r"loc\([^)]*\)", "", text).encode()).hexdigest()[:16])
+    return hashes
+
+
+# What the kernels of the calls that ISSUE 37's causal grid must NOT reach lowered
+# to at its parent (183a4fe, jax 0.9.0): one tile a head and a windowed band.
+# A change that means to move them records its own.
+_PARENT_KERNELS = {
+    "gpt2-one-tile": (GPT2, None, ["168573c8a395eef5", "c7d28a62531b4dfc", "0d9fd8c88998994e"]),
+    "mistral-one-tile": (MISTRAL, None, ["908c08fb4f6e79d1", "9f20af3abcff34e9", "3861f3c4fd3b362a"]),
+    "trinity-window-2048": (TRINITY, 2048, ["0c31b494380ff759", "355e5c6ff6c53256", "620890a7c0f71e85"]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PARENT_KERNELS))
+def test_one_tile_and_windowed_calls_keep_their_kernels(as_tpu, case):
+    """`train-gpt2s` and `train-mistral7b-fsdp2tp2` (S = 1,024: one tile a
+    head) and the windowed layers run the kernels they ran before the causal
+    grid: forward, dK/dV, dQ lower to the same MLIR."""
+    (q_shape, kv_shape), window, want = _PARENT_KERNELS[case]
+    q, k, v = _on(as_tpu, q_shape), _on(as_tpu, kv_shape), _on(as_tpu, kv_shape)
+    assert not flash._live_grid(True, window, 1024, 1024, q_shape[2] // 1024, q_shape[2] // 1024)
+
+    def loss(q, k, v):
+        return flash.flash_attention(q, k, v, causal=True, window=window).astype(jnp.float32).sum()
+
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, k, v)
+    assert _kernel_payloads(lowered.as_text()) == want
 
 
 @pytest.mark.parametrize("seq,window,block", [
