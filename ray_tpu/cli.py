@@ -380,6 +380,13 @@ def _cmd_profile(args) -> int:
         print(f"  node {node_hex[:12]}: {status}")
         for name in meta.get("artifact_names", ()):
             print(f"    {name}")
+        # a train step's device time by pass (util/profiling.scope_seconds)
+        for program, split in sorted(meta.get("scope_seconds", {}).items()):
+            total = max(split["total_s"], 1e-12)
+            shares = dict(split["by_pass"], unscoped=split["unscoped_s"],
+                          unmatched=split["unmatched_s"])
+            print(f"    {program}: {split['total_s']:.3f} device s: " + ", ".join(
+                f"{name} {100 * s / total:.1f}%" for name, s in shares.items()))
     if args.output:
         from .core.runtime import get_runtime
 

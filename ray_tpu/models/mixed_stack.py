@@ -268,18 +268,19 @@ def _block(x, lp, config, kind: LayerKind, rope_tables, positions, remat_saved=(
     # the layer's input is kept for the backward pass whatever is recomputed,
     # so a router that reads it costs no tensor carried past the attention
     router_input = x if c.router_input == "attention" else None
-    with jax.named_scope("attn.window" if sliding else "attn.full"):
-        x = attention_sublayer(
-            x, lp, c, rope_tables if sliding else None, positions,
-            window=c.sliding_window if sliding else None, remat_saved=remat_saved)
+    x = attention_sublayer(     # the scope `attn.window` or `attn.full`, by the window
+        x, lp, c, rope_tables if sliding else None, positions,
+        window=c.sliding_window if sliding else None, remat_saved=remat_saved)
     if kind.mlp == "dense":
         return mlp_sublayer(x, lp, c), {}
-    out, scalars = moe_mlp(_norm(x, lp["ln2_scale"], None, c.norm, c.norm_eps), lp, c,
-                           router_input=router_input)
-    if c.sandwich_norm:
-        out = _norm(out, lp["ln2_post_scale"], None, c.norm, c.norm_eps)
+    with jax.named_scope("moe"):
+        out, scalars = moe_mlp(_norm(x, lp["ln2_scale"], None, c.norm, c.norm_eps), lp, c,
+                               router_input=router_input)
+        if c.sandwich_norm:
+            out = _norm(out, lp["ln2_post_scale"], None, c.norm, c.norm_eps)
+        x = x + out
     scalars.pop("aux")  # this family trains on the cross entropy alone
-    return x + out, dict(scalars, load=load_max_over_mean(scalars["load"]))
+    return x, dict(scalars, load=load_max_over_mean(scalars["load"]))
 
 
 def forward_hidden(
@@ -300,9 +301,10 @@ def forward_hidden(
     c = config
     dt = c.dtype
     b, s = tokens.shape
-    x = params["wte"].astype(dt)[tokens]
-    if c.scale_embedding:
-        x = x * jnp.asarray(math.sqrt(c.d_model), dt)
+    with jax.named_scope("embed"):
+        x = params["wte"].astype(dt)[tokens]
+        if c.scale_embedding:
+            x = x * jnp.asarray(math.sqrt(c.d_model), dt)
     rope_tables = rope_frequencies(c.head_dim, s, c.rope_theta)
 
     reports: List[Dict[str, jax.Array]] = []
@@ -325,7 +327,8 @@ def forward_hidden(
         else:
             x, scalars = jax.lax.scan(period, x, period_params, unroll=c.scan_unroll)
         reports.extend(scalars)      # under a scan each scalar is (repeats,)
-    x = _norm(x, params["lnf_scale"], None, c.norm, c.norm_eps)
+    with jax.named_scope("head"):
+        x = _norm(x, params["lnf_scale"], None, c.norm, c.norm_eps)
     if not reports:
         return x, {}
     every = {name: jnp.concatenate([jnp.ravel(r[name]) for r in reports]) for name in reports[0]}

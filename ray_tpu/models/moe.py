@@ -641,12 +641,13 @@ def load_max_over_mean(load: jax.Array) -> jax.Array:
 def moe_mlp_sublayer(
     x: jax.Array, lp: Params, config: MoEConfig
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Pre-norm MoE FFN + residual; returns (out, aux_loss, the largest
-    expert's rows over the mean)."""
+    """Pre-norm MoE FFN + residual, the scope `moe`; returns (out, aux_loss,
+    the largest expert's rows over the mean)."""
     c = config
-    h = _norm(x, lp["ln2_scale"], lp.get("ln2_bias"), c.norm, c.norm_eps)
-    out, scalars = moe_mlp(h, lp, c)
-    return x + out, scalars["aux"], load_max_over_mean(scalars["load"])
+    with jax.named_scope("moe"):
+        h = _norm(x, lp["ln2_scale"], lp.get("ln2_bias"), c.norm, c.norm_eps)
+        out, scalars = moe_mlp(h, lp, c)
+        return x + out, scalars["aux"], load_max_over_mean(scalars["load"])
 
 
 # -------------------------------------------------------------------- forward
@@ -668,11 +669,12 @@ def forward_hidden(
     c = config
     dt = c.dtype
     _, s = tokens.shape
-    x = params["wte"].astype(dt)[tokens]
-    if c.pos_emb == "learned":
-        x = x + params["wpe"].astype(dt)[None, :s]
-        rope_tables = None
-    else:
+    with jax.named_scope("embed"):
+        x = params["wte"].astype(dt)[tokens]
+        if c.pos_emb == "learned":
+            x = x + params["wpe"].astype(dt)[None, :s]
+    rope_tables = None
+    if c.pos_emb != "learned":
         rope_tables = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
 
     def block_fn(carry, lp):
@@ -684,7 +686,8 @@ def forward_hidden(
         block_fn = checkpoint_block(block_fn, remat_saved)
     x, (aux_per_layer, load_per_layer) = jax.lax.scan(
         block_fn, x, params["blocks"], unroll=c.scan_unroll)
-    x = _norm(x, params["lnf_scale"], params.get("lnf_bias"), c.norm, c.norm_eps)
+    with jax.named_scope("head"):
+        x = _norm(x, params["lnf_scale"], params.get("lnf_bias"), c.norm, c.norm_eps)
     return x, {
         "router_aux_loss": jnp.sum(aux_per_layer),
         "moe_load_max_over_mean": jnp.max(load_per_layer),

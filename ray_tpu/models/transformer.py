@@ -235,80 +235,93 @@ def attention_sublayer(
     """Pre-norm causal self-attention + residual on (B, S, E). What differs
     between the layers of one stack comes as arguments: `rope_tables` (None:
     this layer encodes no positions) and `window` (None: every causal key).
-    `remat_saved`: what the checkpoint around the block keeps."""
+    `remat_saved`: what the checkpoint around the block keeps. The sublayer
+    is the scope `attn.window` or `attn.full`, by its kind, and inside it
+    `attn.proj`, `attn.kernel` and `attn.out` (util/profiling.STEP_SCOPES)."""
+    with jax.named_scope("attn.full" if window is None else "attn.window"):
+        return _attention_sublayer(x, lp, config, rope_tables, positions, window, remat_saved)
+
+
+def _attention_sublayer(x, lp, config, rope_tables, positions, window, remat_saved):
     c = config
     dt = c.dtype
-    h = _norm(x, lp["ln1_scale"], lp.get("ln1_bias"), c.norm, c.norm_eps)
-    if c.fused_qkv:
-        # one wide matmul beats three narrow ones on the MXU; the concat of
-        # the (static) weights folds into the kernel at compile time
-        wqkv = jnp.concatenate(
-            [
-                lp["wq"].reshape(c.d_model, -1),
-                lp["wk"].reshape(c.d_model, -1),
-                lp["wv"].reshape(c.d_model, -1),
-            ],
-            axis=-1,
-        ).astype(dt)
-        qkv = jnp.einsum("bse,ef->bsf", h, wqkv)
-        nq = c.n_heads * c.head_dim
-        nkv = c.kv_heads * c.head_dim
-        b_, s_, _ = qkv.shape
-        q = qkv[..., :nq].reshape(b_, s_, c.n_heads, c.head_dim).transpose(0, 2, 1, 3)
-        k = qkv[..., nq : nq + nkv].reshape(b_, s_, c.kv_heads, c.head_dim).transpose(0, 2, 1, 3)
-        v = qkv[..., nq + nkv :].reshape(b_, s_, c.kv_heads, c.head_dim).transpose(0, 2, 1, 3)
-    else:
-        q = jnp.einsum("bse,ehd->bhsd", h, lp["wq"].astype(dt))
-        k = jnp.einsum("bse,ehd->bhsd", h, lp["wk"].astype(dt))
-        v = jnp.einsum("bse,ehd->bhsd", h, lp["wv"].astype(dt))
-    if c.use_bias:
-        q = q + lp["bq"].astype(dt)[None, :, None, :]
-        k = k + lp["bk"].astype(dt)[None, :, None, :]
-        v = v + lp["bv"].astype(dt)[None, :, None, :]
-    if c.qk_norm:
-        q = _qk_norm(q, lp["q_norm_scale"], c.norm_eps)
-        k = _qk_norm(k, lp["k_norm_scale"], c.norm_eps)
-    if c.qk_norm_per_head:
-        kw = {} if c.norm_eps is None else {"eps": c.norm_eps}
-        q = rmsnorm(q, lp["q_norm_scale"], **kw)
-        k = rmsnorm(k, lp["k_norm_scale"], **kw)
-    if rope_tables is not None:
-        cos, sin = rope_tables
-        q = apply_rope(q, cos, sin, positions)
-        k = apply_rope(k, cos, sin, positions)
-    attend = flash_attention_kept if "attn_lse" in remat_saved else flash_attention
-    attn = attend(q, k, v, causal=c.causal, window=window, implementation=c.attn_impl)
+    with jax.named_scope("attn.proj"):
+        h = _norm(x, lp["ln1_scale"], lp.get("ln1_bias"), c.norm, c.norm_eps)
+        if c.fused_qkv:
+            # one wide matmul beats three narrow ones on the MXU; the concat of
+            # the (static) weights folds into the kernel at compile time
+            wqkv = jnp.concatenate(
+                [
+                    lp["wq"].reshape(c.d_model, -1),
+                    lp["wk"].reshape(c.d_model, -1),
+                    lp["wv"].reshape(c.d_model, -1),
+                ],
+                axis=-1,
+            ).astype(dt)
+            qkv = jnp.einsum("bse,ef->bsf", h, wqkv)
+            nq = c.n_heads * c.head_dim
+            nkv = c.kv_heads * c.head_dim
+            b_, s_, _ = qkv.shape
+            q = qkv[..., :nq].reshape(b_, s_, c.n_heads, c.head_dim).transpose(0, 2, 1, 3)
+            k = qkv[..., nq : nq + nkv].reshape(b_, s_, c.kv_heads, c.head_dim).transpose(0, 2, 1, 3)
+            v = qkv[..., nq + nkv :].reshape(b_, s_, c.kv_heads, c.head_dim).transpose(0, 2, 1, 3)
+        else:
+            q = jnp.einsum("bse,ehd->bhsd", h, lp["wq"].astype(dt))
+            k = jnp.einsum("bse,ehd->bhsd", h, lp["wk"].astype(dt))
+            v = jnp.einsum("bse,ehd->bhsd", h, lp["wv"].astype(dt))
+        if c.use_bias:
+            q = q + lp["bq"].astype(dt)[None, :, None, :]
+            k = k + lp["bk"].astype(dt)[None, :, None, :]
+            v = v + lp["bv"].astype(dt)[None, :, None, :]
+        if c.qk_norm:
+            q = _qk_norm(q, lp["q_norm_scale"], c.norm_eps)
+            k = _qk_norm(k, lp["k_norm_scale"], c.norm_eps)
+        if c.qk_norm_per_head:
+            kw = {} if c.norm_eps is None else {"eps": c.norm_eps}
+            q = rmsnorm(q, lp["q_norm_scale"], **kw)
+            k = rmsnorm(k, lp["k_norm_scale"], **kw)
+        if rope_tables is not None:
+            cos, sin = rope_tables
+            q = apply_rope(q, cos, sin, positions)
+            k = apply_rope(k, cos, sin, positions)
+    with jax.named_scope("attn.kernel"):
+        attend = flash_attention_kept if "attn_lse" in remat_saved else flash_attention
+        attn = attend(q, k, v, causal=c.causal, window=window, implementation=c.attn_impl)
     if c.attn_gate:
-        gate = jnp.einsum("bse,ehd->bhsd", h, lp["wg"].astype(dt))
-        attn = attn * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(dt)
-    out = jnp.einsum("bhsd,hde->bse", attn, lp["wo"].astype(dt))
-    if c.use_bias:
-        out = out + lp["bo"].astype(dt)
-    if c.sandwich_norm:
-        out = _norm(out, lp["ln1_post_scale"], lp.get("ln1_post_bias"), c.norm, c.norm_eps)
-    return checkpoint_name(x + out, "attn_residual")
+        with jax.named_scope("attn.proj"):
+            gate = jnp.einsum("bse,ehd->bhsd", h, lp["wg"].astype(dt))
+    with jax.named_scope("attn.out"):
+        if c.attn_gate:
+            attn = attn * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(dt)
+        out = jnp.einsum("bhsd,hde->bse", attn, lp["wo"].astype(dt))
+        if c.use_bias:
+            out = out + lp["bo"].astype(dt)
+        if c.sandwich_norm:
+            out = _norm(out, lp["ln1_post_scale"], lp.get("ln1_post_bias"), c.norm, c.norm_eps)
+        return checkpoint_name(x + out, "attn_residual")
 
 
 def mlp_sublayer(x: jax.Array, lp: Params, config: TransformerConfig) -> jax.Array:
-    """Pre-norm dense MLP + residual on (B, S, E)."""
+    """Pre-norm dense MLP + residual on (B, S, E): the scope `mlp`."""
     c = config
     dt = c.dtype
-    h = _norm(x, lp["ln2_scale"], lp.get("ln2_bias"), c.norm, c.norm_eps)
-    up = jnp.einsum("bse,ef->bsf", h, lp["w_up"].astype(dt))
-    if c.use_bias:
-        up = up + lp["b_up"].astype(dt)
-    up = checkpoint_name(up, "mlp_up")
-    if c.act == "swiglu":
-        gate = jnp.einsum("bse,ef->bsf", h, lp["w_gate"].astype(dt))
-        act = swiglu(checkpoint_name(gate, "mlp_gate"), up)
-    else:
-        act = gelu(up)
-    down = jnp.einsum("bsf,fe->bse", act, lp["w_down"].astype(dt))
-    if c.use_bias:
-        down = down + lp["b_down"].astype(dt)
-    if c.sandwich_norm:
-        down = _norm(down, lp["ln2_post_scale"], lp.get("ln2_post_bias"), c.norm, c.norm_eps)
-    return x + down
+    with jax.named_scope("mlp"):
+        h = _norm(x, lp["ln2_scale"], lp.get("ln2_bias"), c.norm, c.norm_eps)
+        up = jnp.einsum("bse,ef->bsf", h, lp["w_up"].astype(dt))
+        if c.use_bias:
+            up = up + lp["b_up"].astype(dt)
+        up = checkpoint_name(up, "mlp_up")
+        if c.act == "swiglu":
+            gate = jnp.einsum("bse,ef->bsf", h, lp["w_gate"].astype(dt))
+            act = swiglu(checkpoint_name(gate, "mlp_gate"), up)
+        else:
+            act = gelu(up)
+        down = jnp.einsum("bsf,fe->bse", act, lp["w_down"].astype(dt))
+        if c.use_bias:
+            down = down + lp["b_down"].astype(dt)
+        if c.sandwich_norm:
+            down = _norm(down, lp["ln2_post_scale"], lp.get("ln2_post_bias"), c.norm, c.norm_eps)
+        return x + down
 
 
 def _block(
@@ -497,14 +510,15 @@ def forward_hidden(
     c = config
     dt = c.dtype
     _, s = tokens.shape
-    x = params["wte"].astype(dt)[tokens]
-    if c.pos_emb == "learned":
-        if positions is None:
-            x = x + params["wpe"].astype(dt)[None, :s]
-        else:
-            x = x + params["wpe"].astype(dt)[positions]
-        rope_tables = None
-    else:
+    with jax.named_scope("embed"):
+        x = params["wte"].astype(dt)[tokens]
+        if c.pos_emb == "learned":
+            if positions is None:
+                x = x + params["wpe"].astype(dt)[None, :s]
+            else:
+                x = x + params["wpe"].astype(dt)[positions]
+    rope_tables = None
+    if c.pos_emb != "learned":
         rope_tables = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
 
     def block_fn(carry, lp):
@@ -514,7 +528,8 @@ def forward_hidden(
         block_fn = checkpoint_block(block_fn, remat_saved)
     x, _ = jax.lax.scan(block_fn, x, params["blocks"], unroll=c.scan_unroll)
 
-    return _norm(x, params["lnf_scale"], params.get("lnf_bias"), c.norm, c.norm_eps)
+    with jax.named_scope("head"):
+        return _norm(x, params["lnf_scale"], params.get("lnf_bias"), c.norm, c.norm_eps)
 
 
 def lm_head_weights(params: Params, config: TransformerConfig) -> jax.Array:

@@ -287,13 +287,14 @@ def lm_loss(
     targets = tokens[:, 1:]
     hidden, routers = model_family(config).forward_hidden(
         params, tokens[:, :-1], config, remat_saved=remat_saved)
-    head = lm_head_weights(params, config)
-    if chunk:
-        loss, ntok = fused_linear_cross_entropy(
-            hidden, head, targets, chunk=chunk, z_loss_coeff=z_loss_coeff)
-    else:
-        logits = jnp.einsum("bse,ev->bsv", hidden, head)
-        loss, ntok = cross_entropy_loss(logits, targets, z_loss_coeff=z_loss_coeff)
+    with jax.named_scope("head"):
+        head = lm_head_weights(params, config)
+        if chunk:
+            loss, ntok = fused_linear_cross_entropy(
+                hidden, head, targets, chunk=chunk, z_loss_coeff=z_loss_coeff)
+        else:
+            logits = jnp.einsum("bse,ev->bsv", hidden, head)
+            loss, ntok = cross_entropy_loss(logits, targets, z_loss_coeff=z_loss_coeff)
     objective = loss
     if "router_aux_loss" in routers:
         objective = loss + config.router_aux_coeff * routers["router_aux_loss"]
@@ -459,8 +460,9 @@ def make_train_step(
         scalars = {k: v if k == "num_tokens" else v * scale for k, v in scalars.items()}
         return scalars, jax.tree.map(lambda g: g * scale, grads)
 
-    # named_scope labels match the train/steplog STEP_PHASES so device
-    # traces (`ray_tpu profile`) line up with the step-phase waterfall
+    # The two scopes split the `device` bucket of train/steplog's waterfall;
+    # with the sublayers' they are util/profiling.STEP_SCOPES, the closed set
+    # by which a device profile's time is read (`profiling.scope_seconds`).
     def step_fn(state: TrainState, batch: Dict[str, jax.Array]):
         tokens = batch["tokens"]
         # the head's form and what the blocks keep, decided for this shape
@@ -473,7 +475,7 @@ def make_train_step(
         with jax.named_scope("steplog.optimizer_update"):
             updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
             new_params = optax.apply_updates(state.params, updates)
-        gnorm = optax.global_norm(grads)
+            gnorm = optax.global_norm(grads)
         new_state = TrainState(
             step=state.step + 1,
             params=new_params,

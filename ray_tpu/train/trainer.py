@@ -111,8 +111,11 @@ class LMTrainer:
                 )
         # cost_analysis() of the compiled step (util/profiling), computed
         # once the first time a report needs it (one extra AOT compile;
-        # disable with profile_cost_accounting=False)
+        # disable with profile_cost_accounting=False). The compiled object
+        # lives from `train.report.cost` to the `train.report.ops` that
+        # follows it, which reads the step's operation table off it
         self._step_cost = None
+        self._step_compiled = None
         # batch shape the step was last traced for: the attention
         # implementation and its sub-tile walk, the expert layer's form and
         # the head's chunk are known with it, and are then written on the
@@ -289,8 +292,13 @@ class LMTrainer:
                             batch, (now - window_t0) / max(window_steps, 1)
                         ))
                         window_t0, window_steps = now, 0
+                    accounted = cost
+                    if self._step_compiled is not None:  # the first report's
+                        with tracing.span("train.report.ops", parent=rctx,
+                                          start=cost.ended) as accounted:
+                            self._register_step_ops(accounted)
                     with tracing.span("train.report.publish", parent=rctx,
-                                      start=cost.ended):
+                                      start=accounted.ended):
                         # after `cost`: what step_cost lowers or builds counts
                         compiled_s = tracing.compile_seconds()
                         metrics["compile_s"] = round(
@@ -373,8 +381,23 @@ class LMTrainer:
         if self._step_cost is None:
             from ..util import profiling
 
-            self._step_cost = profiling.step_cost(self.step_fn, self.state, batch)
+            self._step_compiled = profiling.compile_step(self.step_fn, self.state, batch)
+            self._step_cost = profiling.step_cost(self._step_compiled)
         return self._step_cost
+
+    def _register_step_ops(self, span) -> None:
+        """The step's operation table (`profiling.program_ops`: which scope
+        and which pass each operation of the compiled step belongs to), read
+        off the object `step_cost` compiled, which is dropped here; what the
+        table holds goes on `span`. Like the cost, it never fails a run."""
+        from ..util import profiling
+
+        compiled, self._step_compiled = self._step_compiled, None
+        try:
+            for key, value in profiling.register_program_ops(compiled).items():
+                span.set_attribute(key, value)
+        except profiling.ProfilingError as exc:
+            span.set_attribute("error", str(exc))
 
     def profiling_metrics(self, batch: Dict[str, Any],
                           step_time_s: float) -> Dict[str, Any]:

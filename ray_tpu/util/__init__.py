@@ -46,7 +46,6 @@ from .actor_pool import ActorPool  # noqa: F401
 from .profiling import (  # noqa: F401
     ProfilingError,
     StepCost,
-    annotate,
     capture_local_profile,
     device_peaks,
     device_trace,
@@ -54,7 +53,6 @@ from .profiling import (  # noqa: F401
     roofline,
     start_device_trace,
     start_profiler_server,
-    step_annotation,
     step_cost,
     stop_device_trace,
 )

@@ -6,12 +6,14 @@ first-class subsystem). TPU inversion: the interesting timeline is on
 the DEVICE, and XLA already has a first-class profiler *and* a
 first-class cost model — so this module is three things:
 
-1. The **jax.profiler bridge** (`device_trace`, `start_profiler_server`,
-   `annotate`) with typed errors (`ProfilingError`) instead of raw jax
+1. The **jax.profiler bridge** (`device_trace`, `start_profiler_server`)
+   with typed errors (`ProfilingError`) instead of raw jax
    exceptions, an idempotent profiler server whose port rides the node
    stats snapshot, and `capture_local_profile` — a time-boxed device
    trace plus a host-side sampling profile, collected as bounded
-   artifact bytes the cluster capture RPC ships back to the head.
+   artifact bytes the cluster capture RPC ships back to the head. (Host
+   regions in a profile are `util/tracing.span`s: each is a
+   `jax.profiler.TraceAnnotation` of its name.)
 2. The **cost-model layer**: `step_cost` reads
    ``compiled.cost_analysis()`` FLOPs/bytes off any jitted/compiled
    step, `device_peaks` prices them against the detected chip's peak
@@ -36,6 +38,7 @@ import dataclasses
 import gzip
 import json
 import os
+import re
 import sys
 import threading
 import time
@@ -147,27 +150,6 @@ def profiler_server_port() -> Optional[int]:
     return _profiler_server_port
 
 
-# ----------------------------------------------------------- annotations
-
-def annotate(name: str, **kwargs):
-    """Named host-side region that shows up in device traces
-    (jax.profiler.TraceAnnotation) — use around engine ticks/train steps
-    so runtime phases line up with HLO activity."""
-    import jax
-
-    return jax.profiler.TraceAnnotation(name, **kwargs)
-
-
-@contextlib.contextmanager
-def step_annotation(step: int, name: str = "train") -> Iterator[None]:
-    """StepTraceAnnotation wrapper: marks step boundaries so the profile
-    viewer's per-step breakdown works."""
-    import jax
-
-    with jax.profiler.StepTraceAnnotation(name, step_num=step):
-        yield
-
-
 # ------------------------------------------------------ host-side profiling
 
 
@@ -251,7 +233,11 @@ def capture_local_profile(duration_s: Optional[float] = None, *,
 
     Returns {"meta": {...}, "artifacts": {name: bytes}}. Never raises
     for a degraded capture (no jax, trace busy): the meta records what
-    was skipped and why, so a fan-out over mixed nodes still returns."""
+    was skipped and why, so a fan-out over mixed nodes still returns.
+    Where the device trace holds runs of a program whose operation table
+    this process keeps (`program_ops`: a train step's), the meta's
+    `scope_seconds` has that program's device time by scope and by pass
+    (`scope_seconds`, the join the benchmark's reader calls)."""
     import shutil
     import tempfile
 
@@ -309,6 +295,9 @@ def capture_local_profile(duration_s: Optional[float] = None, *,
                 artifacts.update(_collect_trace_artifacts(
                     logdir, cfg.profile_max_artifact_bytes
                 ))
+                split = _captured_scope_seconds(logdir)
+                if split:
+                    meta["scope_seconds"] = split
             except ProfilingError as exc:
                 meta["device"] = f"error: {exc}"
             shutil.rmtree(logdir, ignore_errors=True)
@@ -556,7 +545,6 @@ class StepCost:
 
     flops: float
     bytes_accessed: float
-    buckets: Dict[str, float]   # the raw analysis entries (numeric only)
     device_kind: str
     n_devices: int
     peak_flops: float           # per device
@@ -570,14 +558,9 @@ class StepCost:
     def total_bytes(self) -> float:
         return self.bytes_accessed * self.n_devices
 
-    def top_buckets(self, k: int = 5) -> List[Tuple[str, float]]:
-        ranked = sorted(self.buckets.items(), key=lambda kv: -abs(kv[1]))
-        return ranked[:k]
 
-
-def compiled_cost(compiled: Any) -> Tuple[float, float, Dict[str, float]]:
-    """Normalize `compiled.cost_analysis()` (a dict) into
-    (flops, bytes_accessed, raw_numeric_buckets)."""
+def compiled_cost(compiled: Any) -> Tuple[float, float]:
+    """`compiled.cost_analysis()` (a dict) -> (flops, bytes_accessed)."""
     try:
         analysis = compiled.cost_analysis()
     except Exception as exc:  # noqa: BLE001 - typed boundary
@@ -586,37 +569,33 @@ def compiled_cost(compiled: Any) -> Tuple[float, float, Dict[str, float]]:
         raise ProfilingError(
             f"cost_analysis returned {type(analysis).__name__}, not a dict"
         )
-    buckets = {
-        k: float(v) for k, v in analysis.items()
-        if isinstance(v, (int, float))
-    }
-    return (
-        float(analysis.get("flops", 0.0)),
-        float(analysis.get("bytes accessed", 0.0)),
-        buckets,
-    )
+    return (float(analysis.get("flops", 0.0)),
+            float(analysis.get("bytes accessed", 0.0)))
+
+
+def compile_step(fn: Any, *args: Any, **kwargs: Any) -> Any:
+    """The executable of a jitted `fn` at the given example arguments, by
+    the AOT path (one extra XLA compile or a fetch from the persistent
+    cache, so callers keep what they read from it and drop it)."""
+    if not hasattr(fn, "lower"):
+        raise ProfilingError(
+            f"step_cost needs a jitted or compiled callable, got "
+            f"{type(fn).__name__}"
+        )
+    device_peaks()  # no peaks for this backend: fail before the compile
+    try:
+        return fn.lower(*args, **kwargs).compile()
+    except Exception as exc:  # noqa: BLE001 - typed boundary
+        raise ProfilingError(f"lower/compile failed: {exc!r}") from exc
 
 
 def step_cost(fn: Any, *args: Any, **kwargs: Any) -> StepCost:
     """FLOPs/bytes of one invocation of a jitted function at the given
     example arguments, priced against the attached chip. `fn` may be a
-    jitted callable (lowered+compiled here via the AOT path — one extra
-    XLA compile, so callers cache the result) or an already-compiled
-    object exposing `cost_analysis()`."""
-    if hasattr(fn, "cost_analysis"):
-        compiled = fn
-    elif hasattr(fn, "lower"):
-        device_peaks()  # no peaks for this backend: fail before the compile
-        try:
-            compiled = fn.lower(*args, **kwargs).compile()
-        except Exception as exc:  # noqa: BLE001 - typed boundary
-            raise ProfilingError(f"lower/compile failed: {exc!r}") from exc
-    else:
-        raise ProfilingError(
-            f"step_cost needs a jitted or compiled callable, got "
-            f"{type(fn).__name__}"
-        )
-    flops, nbytes, buckets = compiled_cost(compiled)
+    jitted callable (`compile_step`s it here, so callers cache the
+    result) or an already-compiled object exposing `cost_analysis()`."""
+    compiled = fn if hasattr(fn, "cost_analysis") else compile_step(fn, *args, **kwargs)
+    flops, nbytes = compiled_cost(compiled)
     if flops <= 0 and nbytes <= 0:
         raise ProfilingError(
             "cost_analysis reported no flops/bytes for this program"
@@ -636,7 +615,6 @@ def step_cost(fn: Any, *args: Any, **kwargs: Any) -> StepCost:
     return StepCost(
         flops=flops,
         bytes_accessed=nbytes,
-        buckets=buckets,
         device_kind=peaks["device_kind"],
         n_devices=n_devices,
         peak_flops=peaks["peak_flops"],
@@ -666,3 +644,294 @@ def roofline(cost: StepCost, step_time_s: float) -> Dict[str, Any]:
         "n_devices": cost.n_devices,
         "device_kind": cost.device_kind,
     }
+
+
+# ------------------------------------------- the step's operations, by scope
+#
+# A compiled train step says of every operation which sublayer and which
+# pass it belongs to: each HLO instruction carries the `jax.named_scope`s
+# and the transforms it was traced under (`metadata={op_name="jit(step)/
+# steplog.fwd_bwd_compute/transpose(jvp(...))/while/body/checkpoint/
+# rematted_computation/moe/moe.combine/..."}`), and a device profile names
+# each operation by that instruction. `program_ops_table` reads the one,
+# `scope_seconds` joins it to the other.
+
+# The ONE closed set of scope names a train step may use (train/lm.py and
+# the models' sublayers; tests/test_step_scopes.py holds every
+# `jax.named_scope` literal there to it). Scopes nest, and an operation
+# belongs to every name on its path: `attn.proj` (norm, q/k/v/gate
+# projections, QK-norm, rotary), `attn.kernel` (the flash call and the
+# layout work around it) and `attn.out` (gate, output projection, norm,
+# residual) lie inside `attn.full` or `attn.window`, the layer's kind; the
+# five `moe.*` inside `moe`, which also holds the expert layer's norms;
+# `head` is the final norm, the logits and the loss.
+STEP_SCOPES: Tuple[str, ...] = (
+    "steplog.fwd_bwd_compute", "steplog.optimizer_update",
+    "embed",
+    "attn.full", "attn.window", "attn.proj", "attn.kernel", "attn.out",
+    "mlp",
+    "moe", "moe.route", "moe.dispatch", "moe.experts", "moe.combine", "moe.shared",
+    "head",
+)
+# the forward and backward pass as a whole: a phase, which places an
+# operation in no sublayer (one that carries nothing else is "unscoped")
+_PHASE_SCOPE = "steplog.fwd_bwd_compute"
+STEP_PASSES: Tuple[str, ...] = ("fwd", "recompute", "bwd", "optimizer", "other")
+
+# (scopes of STEP_SCOPES on the path, pass, index of the computation that
+# holds the instruction: all of a computation's instructions run equally often)
+OpInstance = Tuple[Tuple[str, ...], str, int]
+
+_SCOPE_ON_PATH = re.compile(
+    r"(?<!jit\()(?<![\w.])(" + "|".join(
+        re.escape(name) for name in sorted(STEP_SCOPES, key=len, reverse=True))
+    + r")(?![\w.])")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) [({]")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = (.*)$")
+_OPCODE = re.compile(r" ([a-z][a-z0-9\-]*)\(")
+# a traced operation's path; the partitioner's own names (`op_name="convert.9"`) have no "/"
+_OP_NAME = re.compile(r'op_name="([^"]*/[^"]*)"')
+_INLINED = re.compile(r"(calls|to_apply)=%?([\w.\-]+)")
+_INSTANCE_SUFFIX = re.compile(r"(\.\d+)+$")
+# instructions that are no operation of the device's: a profile never shows one
+_NO_DEVICE_OP = frozenset({"parameter", "constant", "get-tuple-element", "tuple", "bitcast"})
+
+_ops_lock = threading.Lock()
+_program_ops: Dict[str, Dict[str, Tuple[OpInstance, ...]]] = {}
+
+
+def _profile_key(name: str, rest: str) -> Tuple[str, str]:
+    """(the name a reduced profile keys an operation by, its opcode), of an
+    instruction `name = rest`: a kernel (`custom-call`) loses its instance
+    suffix (`flash_fwd.3` -> `flash_fwd`), so that a layer's copies add up."""
+    opcode = _OPCODE.search(" " + rest)
+    opcode = opcode.group(1) if opcode else ""
+    return (_INSTANCE_SUFFIX.sub("", name) if opcode == "custom-call" else name), opcode
+
+
+def op_pass(op_name: str) -> str:
+    """Which pass of the step an operation traced under `op_name` runs in."""
+    if "rematted_computation" in op_name:
+        return "recompute"
+    if "transpose(" in op_name:
+        return "bwd"
+    if "jvp(" in op_name:
+        return "fwd"
+    if "steplog.optimizer_update" in op_name:
+        return "optimizer"
+    return "other"
+
+
+def program_ops_table(text: str) -> Tuple[str, Dict[str, Tuple[OpInstance, ...]]]:
+    """The text of an optimised HLO module -> (the module's name, which is
+    how a profile names the program: `jit_step_under_mesh`; {operation name
+    as a device profile gives it: its instances}). Instructions of every
+    computation that runs as control flow (the entry, while bodies,
+    branches), not the insides of fusions or of reducers: what a profile's
+    "XLA Ops" line shows. A kernel (`custom-call`) is keyed without its
+    instance suffix, as the reduced trace of the benchmark keys it
+    (`_profile_key`), and so may have several instances; every other name
+    has one."""
+    module = re.match(r"HloModule\s+([^\s,]+)", text)
+    if module is None:
+        raise ProfilingError("not the text of an HLO module")
+    computations: List[str] = []
+    inlined = set()
+    fused_paths: Dict[str, str] = {}    # a fusion's computation -> its last instruction's op_name
+    rows: List[Tuple[str, OpInstance]] = []
+    in_fusion = False
+    for line in text.splitlines():
+        if not line.startswith(" "):
+            header = _COMPUTATION.match(line)
+            if header:
+                computations.append(header.group(1))
+                # most of the text, and dropped below through its caller's
+                # `calls=` whatever it is named: the name only spares the parse
+                in_fusion = header.group(1).startswith("fused_computation")
+            continue
+        if in_fusion:
+            if "to_apply=" in line:     # a reducer called from inside a fusion
+                inlined.add(_INLINED.search(line).group(2))
+            path = _OP_NAME.search(line)
+            if path:
+                fused_paths[computations[-1]] = path.group(1)
+            elif "calls=" in line:      # a fusion inside the fusion
+                inner = fused_paths.get(_INLINED.search(line).group(2))
+                if inner:
+                    fused_paths[computations[-1]] = inner
+            continue
+        instruction = _INSTRUCTION.match(line)
+        if instruction is None or not computations:
+            continue
+        name, opcode = _profile_key(*instruction.groups())
+        rest = instruction.group(2)
+        called = _INLINED.search(rest)
+        if called and (called.group(1) == "calls" or opcode != "call"):
+            inlined.add(called.group(2))
+        if opcode in _NO_DEVICE_OP:
+            continue
+        path = _OP_NAME.search(rest)
+        # a fusion the compiler left without metadata (a multi-output one) is
+        # placed by what it fuses
+        path = path.group(1) if path else fused_paths.get(called.group(2), "") if called else ""
+        on_path = set(_SCOPE_ON_PATH.findall(path))
+        rows.append((name, (tuple(s for s in STEP_SCOPES if s in on_path),
+                            op_pass(path), len(computations) - 1)))
+    table: Dict[str, Tuple[OpInstance, ...]] = {}
+    for name, instance in rows:
+        if computations[instance[2]] not in inlined:
+            table[name] = table.get(name, ()) + (instance,)
+    return module.group(1), table
+
+
+def _module_text(compiled: Any) -> str:
+    """The optimised module of a compiled program as text, without the
+    kernels' payloads and the constants (most of `as_text()`'s bytes)."""
+    from jaxlib._jax import HloPrintOptions
+
+    options = HloPrintOptions()
+    for leave_out in ("print_backend_config", "print_large_constants", "print_operand_shape",
+                      "print_result_shape", "print_program_shape", "include_layout_in_shapes",
+                      "print_control_dependencies"):
+        setattr(options, leave_out, False)
+    return compiled.runtime_executable().hlo_modules()[0].to_string(options)
+
+
+def sublayer_scoped(scopes: Tuple[str, ...]) -> bool:
+    """Whether the scopes place an operation: in a sublayer or the optimizer."""
+    return any(s != _PHASE_SCOPE for s in scopes)
+
+
+def register_program_ops(compiled: Any) -> Dict[str, Any]:
+    """Build the operation table of a compiled program and keep it under
+    the program's name (`program_ops`). The compiled object is not kept.
+    -> what the caller's span says of it: `program`, `ops` (instructions
+    in the table), `ops_scoped` (those a scope places) and `text_bytes`."""
+    try:
+        text = _module_text(compiled)
+        program, table = program_ops_table(text)
+    except ProfilingError:
+        raise
+    except Exception as exc:  # noqa: BLE001 - typed boundary
+        raise ProfilingError(f"no operation table for this program: {exc!r}") from exc
+    with _ops_lock:
+        _program_ops[program] = table
+    instances = [i for found in table.values() for i in found]
+    return {"program": program, "ops": len(instances),
+            "ops_scoped": sum(sublayer_scoped(i[0]) for i in instances),
+            "text_bytes": len(text)}
+
+
+def program_ops() -> Dict[str, Dict[str, Tuple[OpInstance, ...]]]:
+    """{program name as a profile prints it: its operation table}, for
+    every program registered in this process."""
+    with _ops_lock:
+        return dict(_program_ops)
+
+
+def scope_seconds(op_seconds: Dict[str, float], op_counts: Dict[str, float],
+                  table: Dict[str, Tuple[OpInstance, ...]]) -> Dict[str, Any]:
+    """Device seconds by operation name (`op_seconds`, with how often each
+    ran, `op_counts`: a reduced profile's) joined to a program's operation
+    table -> {"by_scope_pass": {(scope, pass): seconds}, an operation under
+    several scopes counted under each; "by_pass": {pass: seconds};
+    "unscoped_ops": {name: seconds} of the operations that no scope places
+    in a sublayer or the optimizer; "unmatched_ops": {name: seconds} of
+    those the table does not hold; "total": all of `op_seconds`}. A name
+    with several instances (a kernel) has its seconds split by how often
+    each instance's computation ran, which the counts of that
+    computation's other operations say (a branch never taken ran none);
+    evenly where they say nothing."""
+    runs: Dict[int, float] = {}
+    for name, count in op_counts.items():
+        found = table.get(name, ())
+        if len(found) == 1:
+            runs[found[0][2]] = max(runs.get(found[0][2], 0.0), count)
+    by_scope_pass: Dict[Tuple[str, str], float] = {}
+    by_pass: Dict[str, float] = {}
+    unscoped: Dict[str, float] = {}
+    unmatched: Dict[str, float] = {}
+    for name, seconds in op_seconds.items():
+        found = table.get(name)
+        if not found:
+            unmatched[name] = seconds
+            continue
+        weights = [runs.get(computation, 0.0) for _, _, computation in found]
+        if not sum(weights):
+            weights = [1.0] * len(found)
+        total = sum(weights)
+        for (scopes, pass_, _), weight in zip(found, weights):
+            share = seconds * weight / total
+            by_pass[pass_] = by_pass.get(pass_, 0.0) + share
+            for scope in scopes:
+                by_scope_pass[scope, pass_] = by_scope_pass.get((scope, pass_), 0.0) + share
+            if not sublayer_scoped(scopes):
+                unscoped[name] = unscoped.get(name, 0.0) + share
+    return {"by_scope_pass": by_scope_pass, "by_pass": by_pass, "unscoped_ops": unscoped,
+            "unmatched_ops": unmatched, "total": sum(op_seconds.values())}
+
+
+# what a profile lists as one operation though it only contains others
+_CONTAINERS = frozenset({"while", "conditional", "call"})
+
+
+def profiled_op_seconds(planes: Any, program: str) -> Tuple[Dict[str, float], Dict[str, float]]:
+    """({operation: device seconds}, {operation: times it ran}) inside the
+    runs of `program`, summed over the chips of a profile
+    (`jax.profiler.ProfileData.planes`: a chip is a plane `/device:TPU:<n>`
+    whose line "XLA Modules" has an event a program run, `<program>(<hash>)`,
+    and whose line "XLA Ops" one an operation, named by its HLO text),
+    keyed as `program_ops_table` keys them."""
+    import bisect
+
+    seconds: Dict[str, float] = {}
+    counts: Dict[str, float] = {}
+    for plane in planes:
+        if not plane.name.startswith("/device:TPU:"):
+            continue
+        lines = {line.name: line for line in plane.lines}
+        if "XLA Modules" not in lines or "XLA Ops" not in lines:
+            continue
+        runs = sorted((e.start_ns, e.start_ns + e.duration_ns) for e in lines["XLA Modules"].events
+                      if e.name.split("(")[0] == program)
+        starts = [start for start, _ in runs]
+        for event in lines["XLA Ops"].events:
+            at = bisect.bisect_right(starts, event.start_ns) - 1
+            if at < 0 or event.start_ns >= runs[at][1]:
+                continue
+            name, _, rest = event.name.partition(" = ")
+            name, opcode = _profile_key(name.lstrip("%").strip(), rest)
+            if opcode in _CONTAINERS:
+                continue
+            seconds[name] = seconds.get(name, 0.0) + event.duration_ns / 1e9
+            counts[name] = counts.get(name, 0.0) + 1
+    return seconds, counts
+
+
+def _captured_scope_seconds(logdir: str) -> Dict[str, Dict[str, Any]]:
+    """{program: its device seconds by "scope|pass", by pass, unscoped,
+    unmatched and in all} for every registered program that ran in the
+    device trace under `logdir`; {} where none did (no chip, no table)."""
+    tables = program_ops()
+    paths = [os.path.join(root, name) for root, _dirs, names in os.walk(logdir)
+             for name in names if name.endswith(".xplane.pb")]
+    if not tables or not paths:
+        return {}
+    import jax
+
+    planes = list(jax.profiler.ProfileData.from_file(sorted(paths)[-1]).planes)
+    out: Dict[str, Dict[str, Any]] = {}
+    for program, table in tables.items():
+        seconds, counts = profiled_op_seconds(planes, program)
+        if not seconds:
+            continue
+        split = scope_seconds(seconds, counts, table)
+        out[program] = {
+            "by_scope_pass": {f"{scope}|{pass_}": s
+                              for (scope, pass_), s in sorted(split["by_scope_pass"].items())},
+            "by_pass": split["by_pass"],
+            "unscoped_s": sum(split["unscoped_ops"].values()),
+            "unmatched_s": sum(split["unmatched_ops"].values()),
+            "total_s": split["total"],
+        }
+    return out

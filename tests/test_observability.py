@@ -786,20 +786,21 @@ def test_log_lines_carry_node_and_task_attribution():
 
 def test_device_trace_captures_xla_profile(tmp_path):
     """util.profiling.device_trace writes a TensorBoard-loadable XLA
-    profile for work dispatched inside the block (SURVEY §5 tracing)."""
+    profile for work dispatched inside the block (SURVEY §5 tracing); the
+    host regions in it are `tracing.span`s (each a TraceAnnotation)."""
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.util import annotate, device_trace, step_annotation
+    from ray_tpu.util import device_trace, tracing
 
     logdir = str(tmp_path / "trace")
     f = jax.jit(lambda x: (x @ x).sum())
     x = jnp.ones((128, 128))
     with device_trace(logdir):
-        with annotate("warmup"):
+        with tracing.span("warmup"):
             f(x).block_until_ready()
         for step in range(2):
-            with step_annotation(step):
+            with tracing.span("measured.step", step=step):
                 f(x).block_until_ready()
     import os
 

@@ -147,7 +147,6 @@ def test_step_cost_and_roofline(nominal_cpu_peaks):
     w = jnp.ones((128, 64))
     cost = profiling.step_cost(f, x, w)
     assert cost.flops > 0 and cost.bytes_accessed > 0
-    assert cost.top_buckets(3)[0][0] == "flops"
     roof = profiling.roofline(cost, 0.001)
     assert roof["mfu"] > 0 and roof["hbm_fraction"] > 0
     assert roof["bound"] in ("compute", "memory")

@@ -24,7 +24,7 @@ LEAVES = {
                    "train.step.dispatch", "train.step.sync", "train.report",
                    "train.ckpt_save"},
     "train.report": {"train.report.read", "train.report.cost",
-                     "train.report.publish"},
+                     "train.report.ops", "train.report.publish"},
     "train.init": {"train.init.mesh", "train.init.state", "train.init.step_fn"},
 }
 
