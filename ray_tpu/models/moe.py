@@ -40,6 +40,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..ops import rope_frequencies, swiglu
 from ..ops.grouped_matmul import gmm_tile_rows, grouped_matmul, resolve_gmm_impl
+from ..ops.moe_rows_sum import rows_sum, rows_sum_tile, take_token_rows, token_tile_bounds
 from ..parallel.mesh import DATA_AXES
 from .transformer import (
     Params,
@@ -326,6 +327,10 @@ def moe_plan(config: "MoEConfig", batch: int, seq: int) -> Dict[str, Any]:
         plan.update(moe_experts_held=config.n_experts_held,
                     moe_held_buffer_rows=held_buffer_rows(*mine),
                     moe_held_passes_most=held_passes_most(*mine))
+        if impl != "gshard_dense":  # which refuses held experts
+            # how a token's held rows are added up (`_held_experts`): as the matmuls run
+            plan["moe_held_row_sum"] = {
+                "gmm_pallas": "rows_sum_pallas", "ragged_dot": "scatter_add"}[impl]
     return plan
 
 
@@ -379,7 +384,7 @@ def _gshard_experts(h, probs, weights, config):
     return out, jnp.sum(dispatch, axis=(0, 1, 3))
 
 
-def _gated_groups(expert_in, weights, group_sizes, tile, impl, config):
+def _gated_groups(expert_in, weights, group_sizes, tile, impl, config, interpret=False):
     """The three grouped matmuls of the expert-sorted rows `expert_in`, the
     configuration's gated unit between them. -> (output, a slot's count of
     hidden units a ReLU gate leaves non-zero; None where the unit has no
@@ -387,7 +392,8 @@ def _gated_groups(expert_in, weights, group_sizes, tile, impl, config):
     we_gate, we_up, we_down = weights
 
     def gmm(lhs, w):
-        return grouped_matmul(lhs, w, group_sizes, tile_rows=tile, implementation=impl)
+        return grouped_matmul(lhs, w, group_sizes, tile_rows=tile, implementation=impl,
+                              interpret=interpret)
 
     gate = gmm(expert_in, we_gate)
     out = gmm(_expert_act(config)(gate, gmm(expert_in, we_up)), we_down)
@@ -470,7 +476,14 @@ def _held_experts(h, gates, experts, weights, config, tile, impl):
     always runs; a further pass runs (`lax.cond`) only if rows are left for
     it, and is recomputed in the backward pass, so a pass not taken costs
     neither time nor memory. No routing drops a row: `held_passes_most`
-    passes hold all T k."""
+    passes hold all T k.
+
+    A token's rows are added up as `impl` says, the grouped matmuls' own: under
+    "pallas" by `ops/moe_rows_sum` (the combine, and the transpose of the
+    dispatch's gather: a pass's slots are the kernel's layout as they stand,
+    an expert's rows a group whose tokens ascend), interpreted off a TPU as
+    the attention kernels are; under "xla" by a gather and a scatter-add,
+    which is also what the kernel's tests compare it with."""
     c = config
     tokens, m = h.shape
     k, n = c.top_k, c.n_experts_held
@@ -478,6 +491,8 @@ def _held_experts(h, gates, experts, weights, config, tile, impl):
     first_held = c.held_experts[0]
     buffer_rows = held_buffer_rows(c, tokens, tile)
     slots = buffer_rows + n * tile
+    kernel = impl == "pallas"
+    interpret = kernel and jax.default_backend() != "tpu"
     with jax.named_scope("moe.dispatch"):
         local = experts.reshape(-1).astype(jnp.int32) - first_held
         flat = jnp.where((local >= 0) & (local < n), local, n)      # absent: sorted last
@@ -505,14 +520,23 @@ def _held_experts(h, gates, experts, weights, config, tile, impl):
             # slot -> token: T (out of range: read as a zero row, dropped when written)
             # where the slot holds none
             slot_token = slot_row // k
-            expert_in = jnp.take(h, slot_token, axis=0, mode="fill", fill_value=0)
+            if kernel:
+                bounds = token_tile_bounds(
+                    slot_token, slot_expert, n, tokens, rows_sum_tile(tokens, m))
+                expert_in = take_token_rows(h, slot_token, bounds, interpret=interpret)
+            else:
+                expert_in = jnp.take(h, slot_token, axis=0, mode="fill", fill_value=0)
         with jax.named_scope("moe.experts"):
             expert_out, live = _gated_groups(
-                expert_in, weights, padded.astype(jnp.int32), tile, impl, c)
+                expert_in, weights, padded.astype(jnp.int32), tile, impl, c, interpret)
         with jax.named_scope("moe.combine"):
             slot_gate = jnp.take(flat_gates, slot_row, mode="fill", fill_value=0)
-            gated = slot_gate[:, None] * expert_out.astype(jnp.float32)
-            out = jnp.zeros((tokens, m), jnp.float32).at[slot_token].add(gated, mode="drop")
+            if kernel:
+                out = rows_sum(expert_out, slot_gate, slot_token, bounds, tokens,
+                               interpret=interpret)
+            else:
+                gated = slot_gate[:, None] * expert_out.astype(jnp.float32)
+                out = jnp.zeros((tokens, m), jnp.float32).at[slot_token].add(gated, mode="drop")
         if live is None:
             return (out,)
         return out, jnp.sum(jnp.where(slot_row < rows, live, 0.0))

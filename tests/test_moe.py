@@ -222,3 +222,79 @@ def test_the_router_may_read_another_tensor_than_the_experts():
     np.testing.assert_array_equal(np.asarray(moe_mlp(h, lp, config, router_input=h)[0]), np.asarray(own))
     assert str(jax.make_jaxpr(lambda h: moe_mlp(h, lp, config)[0])(h)) == str(
         jax.make_jaxpr(lambda h: moe_mlp(h, lp, config, router_input=None)[0])(h))
+
+
+# ------------------------------------------- the held layer's row-sum kernel
+
+_ROUTINGS = ("even", "one-expert", "none-held", "zero-and-k")
+
+
+def _held_case(routing, m, dtype, tokens=512, k=4, published=16, held=(4, 6)):
+    """Inputs of `_held_experts` with the routing written by hand: (config,
+    h, gates, experts, the three expert weights)."""
+    config = MoEConfig(d_model=m, d_ff=128, n_experts=published, top_k=k, held_experts=held,
+                       dtype=dtype)
+    rng = np.random.default_rng(m + len(routing))
+    first, last = held
+    absent = np.array([e for e in range(published) if not first <= e < last])
+    if routing == "even":  # every token draws k distinct experts, an eighth of the rows held
+        experts = np.stack([rng.permutation(published)[:k] for _ in range(tokens)])
+    elif routing == "one-expert":  # T k rows of one expert: four passes of the buffer
+        experts = np.full((tokens, k), first + 1)
+    elif routing == "none-held":
+        experts = rng.choice(absent, (tokens, k))
+    else:  # a token holds all k of its rows or none: k slots of a token in one group
+        experts = np.where(rng.random((tokens, 1)) < 0.2, np.full((tokens, k), first),
+                           rng.choice(absent, (tokens, k)))
+    h = jnp.asarray(rng.standard_normal((tokens, m)), dtype)
+    gates = jnp.asarray(rng.random((tokens, k)), jnp.float32)
+    n = last - first
+    weights = tuple(jnp.asarray(0.05 * rng.standard_normal(shape), dtype)
+                    for shape in ((n, m, 128), (n, m, 128), (n, 128, m)))
+    return config, h, gates, jnp.asarray(experts, jnp.int32), weights
+
+
+@pytest.mark.parametrize("m,dtype", [(2560, jnp.bfloat16), (2048, jnp.float32)],
+                         ids=["m2560-bf16", "m2048-f32"])
+@pytest.mark.parametrize("routing", _ROUTINGS)
+def test_held_rows_summed_by_the_kernel_equal_the_scatter_add(routing, m, dtype):
+    """`_held_experts` under "pallas" (the `moe_rows_sum` kernel for the
+    combine, float32 out, and for the transpose of the dispatch's gather, the
+    activations' dtype out; interpret mode here, with the grouped matmuls')
+    against "xla" (`take`, `.at[].add`, `ragged_dot`): the output and the
+    gradients with respect to h, the gates and the three expert weights,
+    through the first pass and the `lax.cond` / `lax.scan` of the later ones.
+
+    Tolerance. The kernel adds a token's at most k products in slot order in
+    float32 where the scatter's order is the compiler's: k float32 roundings
+    of the sum, so float32 cases agree to 1e-5 of the largest value (the two
+    grouped matmuls differ by as much). In bfloat16 the experts' rows and
+    every cotangent are rounded to 8 bits on both sides, and XLA's transpose
+    of the gather adds a token's k rows in bfloat16 where the kernel adds in
+    float32 and rounds once: 2 ** -6 of the largest value."""
+    from ray_tpu.models import moe
+    from ray_tpu.ops.grouped_matmul import gmm_tile_rows
+
+    config, h, gates, experts, weights = _held_case(routing, m, dtype)
+
+    def run(impl):
+        def loss(h, gates, weights):
+            out, report = moe._held_experts(
+                h, gates, experts, weights, config, gmm_tile_rows(impl), impl)
+            return jnp.sum(jnp.sin(out)), (out, report)
+
+        return jax.jit(jax.value_and_grad(loss, (0, 1, 2), has_aux=True))(h, gates, weights)
+
+    ((_, (out, report)), grads), ((_, (want, want_report)), want_grads) = run("pallas"), run("xla")
+    assert out.dtype == jnp.float32 and grads[0].dtype == dtype
+    held = {"even": None, "one-expert": 512 * 4, "none-held": 0, "zero-and-k": None}[routing]
+    if held is not None:
+        assert float(report["moe_rows_held"]) == held
+    assert float(report["moe_passes"]) == float(want_report["moe_passes"]) == (
+        4 if routing == "one-expert" else 1)
+    tolerance = 1e-5 if dtype == jnp.float32 else 2.0 ** -6
+    for ours, theirs in zip(jax.tree.leaves((out, grads)), jax.tree.leaves((want, want_grads))):
+        ours, theirs = np.asarray(ours, np.float32), np.asarray(theirs, np.float32)
+        np.testing.assert_allclose(ours, theirs, atol=tolerance * max(np.abs(theirs).max(), 1e-6))
+    if routing == "none-held":
+        assert not np.asarray(out).any() and not np.asarray(grads[0], np.float32).any()

@@ -61,11 +61,9 @@ def dense():
     return tables[STEP], texts[0], spans
 
 
-@pytest.fixture(scope="module")
-def mixed():
-    """The operation table of a tiny mixed stack's step (dS dS scanned, eS eF
-    unrolled: window and full attention, a dense MLP, held experts with a
-    shared one), compiled and never run."""
+def _lowered_mixed_step():
+    """The tiny mixed stack's step (dS dS scanned, eS eF unrolled), lowered
+    for one device and never run."""
     from test_mixed_stack import tiny
 
     config = tiny(n_layers=4)
@@ -73,8 +71,15 @@ def mixed():
     optimizer = default_optimizer(3e-4, total_steps=10)
     state, shardings = create_train_state(config, optimizer, jax.random.PRNGKey(0), mesh)
     step = make_train_step(config, optimizer, mesh, state_shardings=shardings)
-    compiled = step.lower(state, {"tokens": jnp.zeros((2, 33), jnp.int32)}).compile()
-    program, table = profiling.program_ops_table(profiling._module_text(compiled))
+    return step.lower(state, {"tokens": jnp.zeros((2, 33), jnp.int32)})
+
+
+@pytest.fixture(scope="module")
+def mixed():
+    """The operation table of a tiny mixed stack's step (dS dS scanned, eS eF
+    unrolled: window and full attention, a dense MLP, held experts with a
+    shared one), compiled and never run."""
+    program, table = profiling.program_ops_table(profiling._module_text(_lowered_mixed_step().compile()))
     assert program == STEP
     return table
 
@@ -136,6 +141,30 @@ def test_mixed_step_table_holds_both_attention_kinds_both_mlps_and_the_expert_la
                 assert "moe" in scopes
             if ATTENTION & set(scopes):
                 assert ("attn.window" in scopes) != ("attn.full" in scopes)
+
+
+def test_held_row_sum_kernel_is_one_body_a_signature_under_the_combine_and_the_dispatch(monkeypatch):
+    """The tiny mixed step with the expert layer's kernels on (interpreted
+    here): `moe_rows_sum` is lowered once a signature (float32 rows with
+    gates, float32 rows without) and called from every layer, pass and
+    recomputation, and each call keeps its call site's scopes: the combine's
+    in the forward pass and its recomputation, the dispatch's (the transpose
+    of its gather) in the backward pass, and nowhere else."""
+    from ray_tpu.models import moe
+
+    monkeypatch.setattr(moe, "resolve_gmm_impl", lambda implementation=None: "pallas")
+    monkeypatch.setattr(moe, "gmm_tile_rows", lambda implementation=None: 16)
+    lowered = _lowered_mixed_step()
+    text = lowered.as_text()
+    assert len(re.findall(r"func\.func private @moe_rows_sum\w*\(", text)) == 2
+    # eS eF unrolled, the first pass and the later one, forward, recomputed and backward
+    assert len(re.findall(r"call @moe_rows_sum", text)) == 2 * 2 * 3
+    placed = set()
+    for path in re.findall(r'op_name="([^"]*/moe_rows_sum/[^"]*)"', profiling._module_text(lowered.compile())):
+        placed.add((tuple(sorted(set(profiling._SCOPE_ON_PATH.findall(path)) & {"moe.combine", "moe.dispatch"})),
+                    profiling.op_pass(path)))
+    assert placed == {(("moe.combine",), "fwd"), (("moe.combine",), "recompute"),
+                      (("moe.dispatch",), "bwd")}
 
 
 def test_op_pass_precedence():

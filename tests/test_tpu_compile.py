@@ -263,6 +263,30 @@ def test_moe_grouped_matmul_kernels_compile_at_the_olmoe_cell_shapes(as_tpu, k, 
     assert _kernel_calls(grad.lower(lhs, rhs, sizes).compile()) == 2
 
 
+@pytest.mark.parametrize("weighted,out_dtype", [(True, jnp.float32), (False, jnp.bfloat16)],
+                         ids=["combine-f32", "dispatch-transpose-bf16"])
+@pytest.mark.parametrize("slots,m,held", [(26624, 2560, 8), (36864, 2048, 16)],
+                         ids=["smallthinker", "trinity"])
+def test_moe_rows_sum_compiles_at_the_held_cells_shapes(as_tpu, slots, m, held, weighted, out_dtype):
+    """`train-smallthinker-16k` (a pass's 26,624 slots of 2,560 in 8 groups)
+    and `train-trinity-mini-8k` (36,864 of 2,048 in 16), 16,384 tokens each:
+    the two signatures a step holds, bfloat16 rows with float32 gates summed
+    to float32 (the combine) and bfloat16 rows with none to bfloat16 (the
+    transpose of the dispatch's gather). One kernel call each."""
+    from ray_tpu.ops import moe_rows_sum as rs
+
+    tokens = 16384
+    tiles = tokens // rs.rows_sum_tile(tokens, m)
+
+    def summed(x, weight, token, bounds):
+        return rs.rows_sum(x, weight if weighted else None, token, bounds, tokens, out_dtype=out_dtype)
+
+    compiled = jax.jit(summed).lower(
+        _on(as_tpu, (slots, m)), _on(as_tpu, (slots,), jnp.float32), _on(as_tpu, (slots,), jnp.int32),
+        _on(as_tpu, (held, tiles + 1), jnp.int32)).compile()
+    assert _kernel_calls(compiled) == 1 and "%moe_rows_sum" in compiled.as_text()
+
+
 def test_moe_layer_under_a_mesh_runs_the_kernels_per_shard(v5e, as_tpu):
     """One OLMoE expert layer (64 experts of 1024, top-8) on fsdp=2 x tp=2:
     the layer shard_maps itself over the mesh the weights carry (no context
@@ -379,6 +403,17 @@ def _kernels_named(compiled, name) -> int:
     return len(re.findall(rf"^\s*%{name}[.\d]* = .*custom-call\(", compiled.as_text(), re.M))
 
 
+def _held_row_sums(lowered, compiled, tokens_by_width) -> tuple:
+    """Of a held-expert cell's step: (`moe_rows_sum` bodies in the lowered
+    text: what a warm set-up traces and lowers; its calls in the compiled
+    step; scatters that write `tokens_by_width`: the combine, float32, and
+    the transpose of the dispatch's gather, bfloat16, as XLA has them: 8 of
+    each in either cell's step before the kernel)."""
+    bodies = lowered.as_text().count('kernel_name = "moe_rows_sum"')
+    scatters = re.findall(rf"= (?:f32|bf16)\[{tokens_by_width}\]\S* scatter\(", compiled.as_text())
+    return bodies, _kernels_named(compiled, "moe_rows_sum"), len(scatters)
+
+
 def test_trinity_cell_step_keeps_the_attention_outputs_and_compiles(as_tpu, monkeypatch, v5e):
     """`train-trinity-mini-8k`'s whole step (2 x 8,193 tokens, the state as
     `create_train_state` builds it) for one described v5e chip of 15.75 GiB:
@@ -399,7 +434,12 @@ def test_trinity_cell_step_keeps_the_attention_outputs_and_compiles(as_tpu, monk
     plan = step.remat_plan_for(tokens.shape, state)
     assert (plan["remat"], plan["remat_saved"]) == ("selective", ("attn_out", "attn_lse"))
     assert step.loss_chunk_for(tokens.shape, state) == 2048
-    compiled = step.lower(state, {"tokens": tokens}).compile()
+    lowered = step.lower(state, {"tokens": tokens})
+    compiled = lowered.compile()
+    # one body a signature (float32 with gates, bfloat16 without) however many call it:
+    # 4 layers x (the first pass, the later one) x (combine, its recomputation for the
+    # norm after it, the dispatch's transpose); no scatter adds wide rows into tokens
+    assert _held_row_sums(lowered, compiled, "16384,2048") == (2, 4 * 2 * 3, 0)
     # dS dS scanned (one body forward, one backward), eS eF eS eS unrolled
     assert _kernels_named(compiled, "flash_win_fwd") == 1 + 3
     assert _kernels_named(compiled, "flash_fwd") == 1
@@ -441,11 +481,15 @@ def test_smallthinker_cell_step_keeps_what_a_four_layer_iteration_leaves_room_fo
     plan = step.remat_plan_for(tokens.shape, state)
     assert (plan["remat"], plan["remat_saved"]) == ("selective", ("attn_out", "attn_lse"))
     assert step.loss_chunk_for(tokens.shape, state) == 2048
-    compiled = step.lower(state, {"tokens": tokens}).compile()
+    lowered = step.lower(state, {"tokens": tokens})
+    compiled = lowered.compile()
     # eF eS eS eS, scanned twice: one body forward, one backward
     assert _kernels_named(compiled, "flash_win_fwd") == 3 and _kernels_named(compiled, "flash_fwd") == 1
     for backward in ("flash_win_bwd_dkv", "flash_win_bwd_dq"):
         assert _kernels_named(compiled, backward) == 3
     # 4 layers x 3 projections, forward and recomputed: the expert layer is recomputed whole
     assert _kernels_named(compiled, "moe_gmm_fwd") == 2 * 4 * 3 * 2
+    # `moe_rows_sum`: one body a signature; 4 layers x 2 passes x (the combine, the
+    # dispatch's transpose): nothing in the backward pass reads a recomputed combine
+    assert _held_row_sums(lowered, compiled, "16384,2560") == (2, 4 * 2 * 2, 0)
     assert compiled.memory_analysis().argument_size_in_bytes / GIB == pytest.approx(7.20, abs=0.02)

@@ -99,6 +99,22 @@ def test_step_fn_span_names_the_attention_the_step_runs(trainer, monkeypatch):
     trainer._note_step_plan((2, 17))
 
 
+def test_step_fn_span_names_how_a_held_expert_layer_adds_up_a_tokens_rows(monkeypatch):
+    """`moe_held_row_sum` follows the grouped matmuls' implementation, which
+    is the one thing `_held_experts` asks: a gather and a scatter-add beside
+    `ragged_dot`, the `moe_rows_sum` kernel beside the Pallas matmuls."""
+    from test_mixed_stack import tiny
+
+    trainer = LMTrainer(tiny(n_layers=4), mesh_spec=MeshSpec(dp=2, fsdp=2, tp=2), total_steps=10)
+    trainer._note_step_plan((8, 33))
+    attrs = trainer._step_fn_span.to_dict()["attrs"]
+    assert (attrs["moe_impl"], attrs["moe_held_row_sum"]) == ("ragged_dot", "scatter_add")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    trainer._note_step_plan((8, 33))
+    attrs = trainer._step_fn_span.to_dict()["attrs"]
+    assert (attrs["moe_impl"], attrs["moe_held_row_sum"]) == ("gmm_pallas", "rows_sum_pallas")
+
+
 @pytest.mark.parametrize("hbm_bytes,kernel_move_cost,want", [
     (0, None, ("whole_block", ())),                 # the CPU: a device of unknown size
     # at 16 tokens the attention kernel's output is worth less than keeping it moves
