@@ -36,6 +36,7 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..ops import rope_frequencies, swiglu
@@ -413,12 +414,14 @@ def _dropless_shard(h, probs, weights, config, select=None):
     tokens = b * s
     impl = resolve_gmm_impl()
     tile = gmm_tile_rows(impl)
-    gates, experts = _route(
-        probs.reshape(tokens, c.n_experts),
-        None if select is None else select.reshape(tokens, c.n_experts), c)  # (T, k)
+    with jax.named_scope("moe.select"):
+        gates, experts = _route(
+            probs.reshape(tokens, c.n_experts),
+            None if select is None else select.reshape(tokens, c.n_experts), c)  # (T, k)
     if c.held_experts is not None:
         out, held = _held_experts(h.reshape(tokens, m), gates, experts, weights, c, tile, impl)
-        load = jnp.sum(jax.nn.one_hot(experts, c.n_experts, dtype=jnp.float32), axis=(0, 1))
+        with jax.named_scope("moe.select"):
+            load = jnp.sum(jax.nn.one_hot(experts, c.n_experts, dtype=jnp.float32), axis=(0, 1))
         return out.astype(c.dtype).reshape(b, s, m), load, held
     with jax.named_scope("moe.dispatch"):
         layout = dropless_layout(experts, c.n_experts, tile)
@@ -473,10 +476,18 @@ def _held_experts(h, gates, experts, weights, config, tile, impl):
     pass p takes the sorted rows [p R, (p + 1) R), whose groups are what is
     left of each expert's rows there, so every pass has the layout
     `ops/grouped_matmul` asks for and the same static shapes. The first pass
-    always runs; a further pass runs (`lax.cond`) only if rows are left for
-    it, and is recomputed in the backward pass, so a pass not taken costs
-    neither time nor memory. No routing drops a row: `held_passes_most`
-    passes hold all T k.
+    always runs and its sums go INTO the later ones: a later pass runs
+    (`lax.cond`) only if rows are left for it and adds to what it is handed,
+    one that has none hands it back, and one test stands around all of them,
+    so a step whose rows fit the buffer evaluates one predicate a layer. The
+    backward pass does the same with the first pass's cotangents of `h`, the
+    gates and the weights (`all_passes`, a `custom_vjp`: a later pass is
+    computed again there from its inputs, nothing else is kept for it). So a
+    pass not taken fills nothing and adds nothing, forward or backward (a
+    `cond` that returns the pass's own sums for the caller to add fills a zero
+    of the output's size a pass, a zero cotangent an input and zeros for a
+    checkpointed pass's kept inputs: 8% of a step, PERF.md section 5, PR 42).
+    No routing drops a row: `held_passes_most` passes hold all T k.
 
     A token's rows are added up as `impl` says, the grouped matmuls' own: under
     "pallas" by `ops/moe_rows_sum` (the combine, and the transpose of the
@@ -500,12 +511,11 @@ def _held_experts(h, gates, experts, weights, config, tile, impl):
         sorted_expert = flat[order]
         ends = jnp.searchsorted(
             sorted_expert, jnp.arange(n, dtype=jnp.int32), side="right").astype(jnp.int32)
-        rows_held = ends[-1]
-        slot = jnp.arange(slots, dtype=jnp.int32)
         flat_gates = gates.reshape(-1)
 
-    def one_pass(h, flat_gates, weights, start):
+    def one_pass(h, flat_gates, weights, order, ends, start):
         with jax.named_scope("moe.dispatch"):
+            slot = jnp.arange(slots, dtype=jnp.int32)
             pass_ends = jnp.clip(ends - start, 0, buffer_rows)
             sizes = jnp.diff(pass_ends, prepend=0)
             first_position = start + pass_ends - sizes     # of each expert's rows in this pass
@@ -541,21 +551,58 @@ def _held_experts(h, gates, experts, weights, config, tile, impl):
             return (out,)
         return out, jnp.sum(jnp.where(slot_row < rows, live, 0.0))
 
-    # (the output, then what the pass counted of itself), summed over the passes
-    total = one_pass(h, flat_gates, weights, jnp.int32(0))
-    most = held_passes_most(c, tokens, tile)
-    if most > 1:
-        later = jax.checkpoint(one_pass)
+    later_starts = np.arange(1, held_passes_most(c, tokens, tile), dtype=np.int32) * buffer_rows
 
-        def further(total, start):
-            more = jax.lax.cond(
-                start < rows_held, later,
-                lambda h, g, w, start: tuple(jnp.zeros(part.shape, part.dtype) for part in total),
-                h, flat_gates, weights, start)
-            return tuple(a + b for a, b in zip(total, more)), None
+    def through_later_passes(sums, add_pass, ends):
+        """`sums` through every pass after the first that has rows left for
+        it, `add_pass(sums, start)` each; handed back as it came by a pass
+        that has none, and by one test for all of them where the first pass
+        took every row."""
+        if not later_starts.size:
+            return sums
+        rows_held = ends[-1]
 
-        total, _ = jax.lax.scan(
-            further, total, jnp.arange(1, most, dtype=jnp.int32) * buffer_rows)
+        def later(sums, start):
+            return jax.lax.cond(start < rows_held, add_pass, lambda sums, start: sums,
+                                sums, start), None
+
+        with jax.named_scope("moe.passes"):
+            return jax.lax.cond(
+                rows_held > buffer_rows,
+                lambda sums: jax.lax.scan(later, sums, later_starts)[0], lambda sums: sums, sums)
+
+    def add(sums, more):
+        return jax.tree.map(jnp.add, sums, more)
+
+    # (the output, then what a pass counted of itself), summed over the passes.
+    # Its own rule for the backward pass, because a `cond` differentiated by
+    # JAX's rule answers a pass not taken with a zero for `h`, the gates and
+    # each weight stack, which the caller then adds: here the first pass's
+    # cotangents ARE the sums a later pass adds to, so nothing is filled
+    def all_passes_fwd(h, flat_gates, weights, order, ends):
+        def add_pass(total, start):
+            return add(total, one_pass(h, flat_gates, weights, order, ends, start))
+
+        # the first pass keeps what JAX's own rule keeps of it; a later one its inputs
+        total, first_vjp = jax.vjp(
+            lambda *inputs: one_pass(*inputs, order, ends, jnp.int32(0)), h, flat_gates, weights)
+        kept = (first_vjp, h, flat_gates, weights, order, ends)
+        return through_later_passes(total, add_pass, ends), kept
+
+    def all_passes_bwd(kept, d_total):
+        first_vjp, h, flat_gates, weights, order, ends = kept
+
+        def add_pass(grads, start):  # the pass again, then its transpose
+            _, pass_vjp = jax.vjp(
+                lambda *inputs: one_pass(*inputs, order, ends, start), h, flat_gates, weights)
+            return add(grads, pass_vjp(d_total))
+
+        return (*through_later_passes(first_vjp(d_total), add_pass, ends), None, None)
+
+    all_passes = jax.custom_vjp(lambda *inputs: all_passes_fwd(*inputs)[0])
+    all_passes.defvjp(all_passes_fwd, all_passes_bwd)
+    total = all_passes(h, flat_gates, weights, order, ends)
+    rows_held = ends[-1]
     passes = jnp.maximum(-(-rows_held // buffer_rows), 1)
     report = {"moe_rows_held": rows_held.astype(jnp.float32),
               "moe_passes": passes.astype(jnp.float32)}
@@ -652,8 +699,9 @@ def moe_mlp(
     # E * sum_e f_e P_e: f_e the share of the (token, choice) pairs routed to
     # e (it carries no gradient; under GShard, of those kept), P_e the mean
     # router probability
-    share = load / (probs.shape[0] * probs.shape[1] * c.top_k)
-    aux = c.n_experts * jnp.sum(share * jnp.mean(probs, axis=(0, 1)))
+    with jax.named_scope("moe.select"):
+        share = load / (probs.shape[0] * probs.shape[1] * c.top_k)
+        aux = c.n_experts * jnp.sum(share * jnp.mean(probs, axis=(0, 1)))
     return out, {"aux": aux, "load": load, **held}
 
 

@@ -663,14 +663,19 @@ def roofline(cost: StepCost, step_time_s: float) -> Dict[str, Any]:
 # projections, QK-norm, rotary), `attn.kernel` (the flash call and the
 # layout work around it) and `attn.out` (gate, output projection, norm,
 # residual) lie inside `attn.full` or `attn.window`, the layer's kind; the
-# five `moe.*` inside `moe`, which also holds the expert layer's norms;
+# seven `moe.*` inside `moe`, which also holds the expert layer's norms, its
+# weights' casts and its residual (`moe.select`: top-k, the gates'
+# renormalisation, the load count, the auxiliary loss; `moe.passes`: the
+# control and the sums of a held layer's passes after the first, around
+# those passes' own `moe.dispatch`, `moe.experts` and `moe.combine`);
 # `head` is the final norm, the logits and the loss.
 STEP_SCOPES: Tuple[str, ...] = (
     "steplog.fwd_bwd_compute", "steplog.optimizer_update",
     "embed",
     "attn.full", "attn.window", "attn.proj", "attn.kernel", "attn.out",
     "mlp",
-    "moe", "moe.route", "moe.dispatch", "moe.experts", "moe.combine", "moe.shared",
+    "moe", "moe.route", "moe.select", "moe.dispatch", "moe.experts", "moe.combine", "moe.passes",
+    "moe.shared",
     "head",
 )
 # the forward and backward pass as a whole: a phase, which places an

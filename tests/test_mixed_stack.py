@@ -238,6 +238,91 @@ def test_no_row_routed_to_a_held_expert_is_dropped(sent_here):
         assert 0 < float(scalars["moe_rows_held"]) < tokens * 4
 
 
+def _held_layer(expert_act="swiglu", tokens_here=48):
+    """A held layer of 8 of 64 experts, top-4, so that the buffer of twice the
+    even share (T k / 4 rows) holds every routing in `held_passes_most` = 4
+    passes; the first `tokens_here` tokens send all four choices to held
+    experts, the others none. -> (inputs (h, gates, weights), the layer's
+    value, (output, report) and gradients as a function of the inputs)."""
+    config = moe.MoEConfig(d_model=32, d_ff=16, n_experts=64, top_k=4, held_experts=(0, 8),
+                           expert_act=expert_act, dtype=jnp.float32)
+    tokens, keys = 48, jax.random.split(jax.random.PRNGKey(7), 6)
+    assert moe.held_buffer_rows(config, tokens, 1) == tokens and moe.held_passes_most(config, tokens, 1) == 4
+    h = jax.random.normal(keys[0], (tokens, 32))
+    gates = jax.nn.softmax(jax.random.normal(keys[1], (tokens, 4)))
+    weights = tuple(0.3 * jax.random.normal(key, shape) for key, shape in
+                    zip(keys[2:5], ((8, 32, 16), (8, 32, 16), (8, 16, 32))))
+    held = jax.vmap(lambda key: jax.random.permutation(key, 8)[:4])(jax.random.split(keys[5], tokens))
+    experts = jnp.where(jnp.arange(tokens)[:, None] < tokens_here, held, 8 + 4 * held)
+
+    def layer(h, gates, weights):
+        out, report = moe._held_experts(h, gates, experts, weights, config, 1, "xla")
+        return jnp.sum(out * jnp.cos(out)), (out, report)
+
+    return (h, gates, weights), jax.value_and_grad(layer, (0, 1, 2), has_aux=True)
+
+
+@pytest.mark.parametrize("expert_act", ["swiglu", "reglu"])
+@pytest.mark.parametrize("passes", [1, 2, 4])
+def test_every_pass_taken_adds_what_one_buffer_for_all_rows_computes(monkeypatch, passes, expert_act):
+    """1, 2 and `held_passes_most` passes taken (40, 80 and all 192 rows sent
+    here through a buffer of 48): the output, what the layer counts of itself
+    and the gradients of `h`, the gates and the three weight stacks are those
+    of ONE pass through a buffer that holds every row. With one pass taken
+    they are, bit for bit, those of a layer that has no later pass at all:
+    a pass not taken hands the sums back as they came."""
+    inputs, layer = _held_layer(expert_act, tokens_here={1: 10, 2: 20, 4: 48}[passes])
+    (_, (out, report)), grads = jax.jit(layer)(*inputs)
+    assert float(report["moe_passes"]) == passes
+    assert float(report["moe_rows_held"]) == {1: 40, 2: 80, 4: 192}[passes]
+    with monkeypatch.context() as patch:
+        patch.setattr(moe, "_HELD_BUFFER_SHARES", 64.0)     # one buffer for all T k rows
+        (_, (want, want_report)), want_grads = layer(*inputs)
+    assert float(want_report["moe_passes"]) == 1
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=1e-5, atol=1e-5)
+    for g, w in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads), strict=True):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-5, atol=2e-5)
+    if expert_act == "reglu":
+        assert float(report["moe_act_live_units"]) == float(want_report["moe_act_live_units"]) > 0
+    if passes == 1:
+        monkeypatch.setattr(moe, "held_passes_most", lambda config, tokens, tile: 1)
+        (_, (first, _)), first_grads = jax.jit(layer)(*inputs)
+        for ours, theirs in zip(jax.tree.leaves((out, grads)), jax.tree.leaves((first, first_grads)), strict=True):
+            np.testing.assert_array_equal(np.asarray(ours), np.asarray(theirs))
+
+
+def test_a_pass_not_taken_fills_nothing_and_adds_nothing():
+    """The layer's forward and gradient, as traced: every `cond` of the
+    later passes (one test around all of them and one a pass, forward and
+    backward) hands its operands back untouched in the branch not taken, and
+    outside the branches taken nothing under `moe.passes` makes a value of
+    the output's or of an input's size: no zero is filled, none is added."""
+    inputs, layer = _held_layer()
+    wide = {inputs[0].shape, *(w.shape for w in inputs[2])}     # (tokens, hidden) and the weight stacks
+    conds = []
+
+    def walk(jaxpr, path, taken):
+        for eqn in jaxpr.eqns:
+            here = f"{path}/{eqn.source_info.name_stack}"
+            control = eqn.primitive.name in ("cond", "scan")
+            if "moe.passes" in here and not taken and not control:
+                assert not any(getattr(v.aval, "shape", None) in wide for v in eqn.outvars), (here, eqn.primitive)
+            if eqn.primitive.name == "cond" and "moe.passes" in here:
+                not_taken, later = eqn.params["branches"]
+                conds.append(here)
+                assert not not_taken.jaxpr.eqns and set(not_taken.jaxpr.outvars) <= set(not_taken.jaxpr.invars), here
+                walk(later.jaxpr, here, True)
+                continue
+            for value in eqn.params.values():
+                for sub in value if isinstance(value, (tuple, list)) else (value,):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub, here, taken)
+
+    walk(jax.make_jaxpr(layer)(*inputs).jaxpr, "", False)
+    assert len(conds) == 4 and sum("transpose" in path for path in conds) == 2, conds
+
+
 @pytest.mark.parametrize("sent_here", ["every-choice", "as-routed"])
 def test_held_layer_through_the_grouped_matmul_kernels(monkeypatch, sent_here):
     """The held layer as a TPU runs it: the three `moe_gmm_*` kernels

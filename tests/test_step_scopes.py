@@ -131,7 +131,8 @@ def test_dense_step_table_classifies_a_known_instruction(dense, path, scope, pas
 
 def test_mixed_step_table_holds_both_attention_kinds_both_mlps_and_the_expert_layers_parts(mixed):
     pairs = _scope_passes(mixed)
-    moe = {"moe", "moe.route", "moe.dispatch", "moe.experts", "moe.combine", "moe.shared"}
+    moe = {"moe", "moe.route", "moe.select", "moe.dispatch", "moe.experts", "moe.combine", "moe.passes",
+           "moe.shared"}
     assert {scope for scope, _ in pairs} == set(profiling.STEP_SCOPES)
     for sublayer in {"attn.window", "attn.full", "mlp"} | moe:
         assert {(sublayer, "fwd"), (sublayer, "recompute"), (sublayer, "bwd")} <= pairs

@@ -414,6 +414,17 @@ def _held_row_sums(lowered, compiled, tokens_by_width) -> tuple:
     return bodies, _kernels_named(compiled, "moe_rows_sum"), len(scatters)
 
 
+def _held_passes_not_taken(lowered, compiled, tokens_by_width) -> tuple:
+    """Of a held-expert cell's step: (Mosaic bodies in the lowered text, each
+    traced and lowered in every run's set-up, cache or no cache: a call site
+    more in a layer's body is what cost PR 40 7 s of `setup_s`; zeros of the
+    expert layer's float32 output filled in the compiled step: what a `cond`
+    of the later passes does whose untaken branch does not hand its operands
+    back)."""
+    fills = re.findall(rf"= f32\[{tokens_by_width}\]\S* broadcast\(", compiled.as_text())
+    return _kernel_calls(lowered), len(fills)
+
+
 def test_trinity_cell_step_keeps_the_attention_outputs_and_compiles(as_tpu, monkeypatch, v5e):
     """`train-trinity-mini-8k`'s whole step (2 x 8,193 tokens, the state as
     `create_train_state` builds it) for one described v5e chip of 15.75 GiB:
@@ -440,6 +451,8 @@ def test_trinity_cell_step_keeps_the_attention_outputs_and_compiles(as_tpu, monk
     # 4 layers x (the first pass, the later one) x (combine, its recomputation for the
     # norm after it, the dispatch's transpose); no scatter adds wide rows into tokens
     assert _held_row_sums(lowered, compiled, "16384,2048") == (2, 4 * 2 * 3, 0)
+    bodies, fills = _held_passes_not_taken(lowered, compiled, "16384,2048")
+    assert bodies <= 125 and fills == 0     # PR 41's count of bodies; a pass not taken fills nothing
     # dS dS scanned (one body forward, one backward), eS eF eS eS unrolled
     assert _kernels_named(compiled, "flash_win_fwd") == 1 + 3
     assert _kernels_named(compiled, "flash_fwd") == 1
@@ -492,4 +505,6 @@ def test_smallthinker_cell_step_keeps_what_a_four_layer_iteration_leaves_room_fo
     # `moe_rows_sum`: one body a signature; 4 layers x 2 passes x (the combine, the
     # dispatch's transpose): nothing in the backward pass reads a recomputed combine
     assert _held_row_sums(lowered, compiled, "16384,2560") == (2, 4 * 2 * 2, 0)
+    bodies, fills = _held_passes_not_taken(lowered, compiled, "16384,2560")
+    assert bodies <= 110 and fills == 0     # PR 41's count of bodies; a pass not taken fills nothing
     assert compiled.memory_analysis().argument_size_in_bytes / GIB == pytest.approx(7.20, abs=0.02)
