@@ -402,7 +402,7 @@ def plan(config: MixedStackConfig, batch: int, seq: int) -> Dict[str, Any]:
         "layer_kinds": " ".join(kind.code for kind in layer_kinds(c)),
         "attn_window": c.sliding_window,
         **{name.replace("attn_subtiles", "attn_window_subtiles"): value
-           for name, value in windowed.items() if name.startswith("attn_subtiles")},
+           for name, value in windowed.items() if name.startswith(("attn_subtiles", "attn_window"))},
         "moe_router": c.router_score,
         "moe_router_input": c.router_input,
         "moe_experts_routed": c.n_experts,

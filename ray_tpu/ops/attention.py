@@ -134,11 +134,15 @@ def mha_reference(
 # not the sequence's tiles but the BAND of square tiles one q tile's window
 # touches (`window_band`), the diagonal tile and those below it. A band tile
 # lies at a static offset from the diagonal whatever its place in the
-# sequence, and a pair is live by its row less its column alone, so the walk
-# engages inside every tile at any length (`_band_tiles`): a sub-tile
-# outside the window is skipped, one inside it and below the diagonal runs
-# unmasked, one that either edge crosses builds the mask; a tile outside the
-# band is never a grid step, so it is not copied either.
+# sequence, and a pair is live by its row less its column alone, so which of
+# a tile's pairs are live is known while tracing (`_band_tiles`). A band tile
+# is of one of three classes (`_band_class`): INTERIOR, every pair live, one
+# body for all of them; the DIAGONAL tile and the TRAILING one(s) the window's
+# lower edge crosses, a body each. A kernel computes a class whole, one piece
+# with the static mask of the edge that crosses it (none, interior), or walks
+# it in sub-tiles, where a sub-tile outside the window is skipped, one inside
+# it runs unmasked and one an edge crosses builds the mask (`_BAND_WALK`). A
+# tile outside the band is never a grid step, so it is not copied either.
 
 # (sub_q, sub_kv) of the walk. One chip sweep over {128, 256, 512} a side
 # and kernel, at D = 64 and D = 128 (PERF.md section 6, PR 26): 256 x 256
@@ -155,6 +159,39 @@ _SUB_TILE = (256, 256)
 # forward 15.62 / 16.30, 9.69 / 10.46, 2.85 / 3.24, it loses at all three (a
 # softmax step a 256-row strip, as in the windowed forward).
 _DIAGONAL_WALK = {"flash_fwd": False, "flash_bwd_dkv": True, "flash_bwd_dq": True}
+
+# The same question for each class of a windowed call's band tiles (`_band_class`):
+# True walks the class in those sub-tiles, False computes it as one whole piece
+# (an interior tile unmasked, a diagonal or trailing one with the static mask of
+# the edge that crosses it). One alternating chip sweep of all eight tables a
+# kernel at the two cells' shapes, D = 128 (1 x 28/4 x 16,384, window 4,096: 16
+# diagonal + 42 interior + 12 trailing tiles a head; 2 x 32/4 x 8,192, window
+# 2,048: 8 + 7 + 6; PERF.md section 6, PR 43), ms a call by the host's clock
+# over 5 queued calls, the parent's kernel first, then all walked / interior
+# whole / the chosen table / all whole:
+#   forward  10.33, 10.30 /  9.33 /  8.28 /  8.28 and 7.49, 7.46 / 7.10 / 5.90 / 5.90
+#   dK/dV    13.14, 13.05 / 12.16 / 12.16 / 13.25 and 8.78, 8.76 / 8.40 / 8.40 / 9.68
+#   dQ        8.87,  8.82 /  8.81 /  8.81 /  9.93 and 6.10, 6.09 / 6.08 / 6.08 / 7.27
+# The forward gains from every class it computes whole (a softmax step a tile,
+# not one a 256-row strip: diagonal 0.71 / 0.77, interior 0.97 / 0.36, trailing
+# 0.38 / 0.42 ms a call), each class's gain the same whatever the others do. The
+# backward kernels gain from the interior class alone (dK/dV 0.89 / 0.36; dQ
+# 0.005 / 0.011, a tie, broken for the smaller body) and LOSE on both edges
+# computed whole (dK/dV 0.69 / 0.77 and 0.43 / 0.48, dQ 0.64 / 0.62 and 0.48 /
+# 0.52): they skip the six dead sub-tiles of an edge tile, as on the causal
+# grid's diagonal (`_DIAGONAL_WALK`).
+_BAND_WALK = {
+    "flash_fwd": {"diagonal": False, "interior": False, "trailing": False},
+    "flash_bwd_dkv": {"diagonal": True, "interior": False, "trailing": True},
+    "flash_bwd_dq": {"diagonal": True, "interior": False, "trailing": True},
+}
+
+# The share of a tile's sub-tiles that the walk visits on the tiles that sweep
+# timed (10 of 16: the causal triangle, and its mirror where the window is a
+# multiple of the tile side). A diagonal or trailing tile of which the walk
+# visits less (a window narrower than a tile, the second of two trailing tiles)
+# is no class of the table: it is walked, as every band tile was before.
+_WALK_VISITS_SWEPT = 10 / 16
 
 
 def _sub_tiles(block_q: int, block_kv: int, grid_tiles: int):
@@ -251,6 +288,54 @@ def window_band(window: int, block: int, tiles: int) -> int:
     return min(tiles, -(-(window - 1) // block) + 1)
 
 
+def _band_tile_walk(d: int, block: int, sub_q: int, sub_kv: int, window: int):
+    """(visited, masked) sub-tiles of the band tile d tiles below the diagonal
+    as the walk covers it, by the kernels' own loop bounds."""
+    visited = masked = 0
+    for a in range(block // sub_q):
+        first, full_lo, full_hi, live = _kv_range(
+            d * block + a * sub_q, 0, block // sub_kv, sub_q, sub_kv, True, None, window)
+        visited += live - first
+        masked += (live - first) - (full_hi - full_lo)
+    return visited, masked
+
+
+def _band_class(d: int, band: int, block: int, window: int) -> Optional[str]:
+    """The class of the band tile d tiles below the diagonal, from its offset,
+    the tile side and the window alone: "interior" (every pair live: the
+    code is the same at any such offset), "diagonal" (d == 0), "trailing" (the
+    window's lower edge crosses it), or None: a tile of which the walk visits
+    under `_WALK_VISITS_SWEPT`, and the one tile of a band of 1 (nothing is
+    carried there: a strip's softmax is final, as in a one-tile causal call,
+    whose walk PR 26's sweep chose). None is walked whatever the kernel."""
+    if band == 1:
+        return None
+    if d >= 1 and (d + 1) * block - 1 < window:
+        return "interior"
+    sub_q, sub_kv = _sub_tiles(block, block, 1)
+    visited, _ = _band_tile_walk(d, block, sub_q, sub_kv, window)
+    if visited < _WALK_VISITS_SWEPT * (block // sub_q) * (block // sub_kv):
+        return None
+    return "diagonal" if d == 0 else "trailing"
+
+
+def _band_whole(kernel: str, d: int, band: int, block: int, window: int) -> bool:
+    """Whether `kernel` computes that band tile as one whole piece."""
+    tile_class = _band_class(d, band, block, window)
+    return tile_class is not None and not _BAND_WALK[kernel][tile_class]
+
+
+def _band_tile_subtiles(kernel: str, d: int, band: int, block: int, sub_q: int, sub_kv: int,
+                        window: int):
+    """(visited, masked) sub-tiles of that band tile as `kernel` computes it:
+    the walk's, or whole: all of them, masked if any pair is dead."""
+    visited, masked = _band_tile_walk(d, block, sub_q, sub_kv, window)
+    if _band_whole(kernel, d, band, block, window):
+        whole = (block // sub_q) * (block // sub_kv)
+        return whole, whole * (masked > 0)
+    return visited, masked
+
+
 def attention_subtiles(sq: int, skv: int, causal: bool, kv_len: int,
                        block_q: int, block_kv: int, sub_q: int, sub_kv: int,
                        window: Optional[int] = None, kernel: str = "flash_bwd_dq"):
@@ -258,22 +343,20 @@ def attention_subtiles(sq: int, skv: int, causal: bool, kv_len: int,
     matrix as the kernels walk it: `visited` run their matmuls, `masked`
     of those build the mask, `total` is what a dense walk would visit.
     Counted with the kernels' own loop bounds. The three kernels walk alike
-    but for a causal grid's diagonal tiles (`_DIAGONAL_WALK`), which `kernel`
-    decides: the forward computes them whole."""
+    but for a causal grid's diagonal tiles (`_DIAGONAL_WALK`) and a band's
+    tiles by class (`_BAND_WALK`), which `kernel` decides: a tile computed
+    whole visits all its sub-tiles, and masks all or (interior) none."""
     visited = masked = 0
     total = (sq // sub_q) * (skv // sub_kv)
     tiles = sq // block_q
     if window is not None:
-        # a band of tiles at static offsets from the diagonal (`_band_tiles`)
+        # a band of tiles at static offsets from the diagonal (`_band_tiles`):
+        # the q tiles d .. tiles - 1 have a tile d below the diagonal
         band = window_band(window, block_q, skv // block_kv)
-        for i in range(tiles):
-            for d in range(min(band, i + 1)):
-                for a in range(block_q // sub_q):
-                    first, full_lo, full_hi, live = _kv_range(
-                        d * block_q + a * sub_q, 0, block_kv // sub_kv,
-                        sub_q, sub_kv, causal, None, window)
-                    visited += live - first
-                    masked += (live - first) - (full_hi - full_lo)
+        for d in range(band):
+            on_visited, on_masked = _band_tile_subtiles(kernel, d, band, block_q, sub_q, sub_kv, window)
+            visited += (tiles - d) * on_visited
+            masked += (tiles - d) * on_masked
         return visited, masked, total
     if _live_grid(causal, window, block_q, block_kv, tiles, skv // block_kv):
         # the lower triangle's tiles (`_triangle_classes`): those below the
@@ -357,22 +440,32 @@ def _grid_tile(num_q_blocks, num_kv_blocks, q_axis, kv_axis):
     return i, j
 
 
-def _band_tiles(band, step, tile, tiles, toward_diagonal, strips):
-    """A windowed call's grid step: `strips(d, 0)` for the one tile of the
-    band this step holds, as the q tile d and kv tile 0 of a head, its STATIC
-    offset from the diagonal (a pair is live by its row less its column
-    alone), so that the sub-tile walk engages whatever the length. `tile` is the fixed
-    side's tile (a q tile for the forward and dQ, whose band runs
+def _band_tiles(kernel, band, window, block, step, tile, tiles, toward_diagonal, strips):
+    """A windowed call's grid step: `strips(d, 0, *piece)` for the one tile of
+    the band this step holds, as the q tile d and kv tile 0 of a head, its
+    STATIC offset from the diagonal (a pair is live by its row less its column
+    alone), so that which pairs are live is known here whatever the length.
+    `piece` is the whole tile or the walk's sub-tile, by the tile's class and
+    `_BAND_WALK`; the interior tiles share ONE body, traced at d = 1 (every
+    pair live at any of their offsets), the others have one each. `tile` is
+    the fixed side's tile (a q tile for the forward and dQ, whose band runs
     `toward_diagonal` over kv tiles tile - band + 1 .. tile; a kv tile for
     dK/dV, whose band runs away from it over q tiles tile .. tile + band -
     1), `step` the band's grid axis. A step whose tile falls off the
     sequence (before its start, or after the last of `tiles`) does nothing;
     its index map repeats a neighbour's block, so nothing is copied either."""
+    bodies = {}     # (the offset a body is traced at, its piece) -> when it runs
     for t in range(band):
         d = band - 1 - t if toward_diagonal else t    # tiles below the diagonal
         inside = tile >= d if toward_diagonal else tile + d <= tiles - 1
         here = inside if band == 1 else inside & (step == t)
-        run = functools.partial(strips, d, 0)
+        tile_class = _band_class(d, band, block, window)
+        walked = tile_class is None or _BAND_WALK[kernel][tile_class]
+        body = (1 if tile_class == "interior" else d,
+                _sub_tiles(block, block, 1) if walked else (block, block))
+        bodies[body] = bodies[body] | here if body in bodies else here
+    for (d, piece), here in bodies.items():
+        run = functools.partial(strips, d, 0, *piece)
         if here is True:
             run()
         elif here is not False:
@@ -432,10 +525,15 @@ def _scores(q, k, scale, mask_at, causal, kv_len, window=None):
     mask = None if kv_len is None else col < kv_len
     if causal:
         row = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        upper = col <= row
-        mask = upper if mask is None else mask & upper
-        if window is not None:
-            mask = mask & (col > row - window)
+        # a windowed call's offsets are static: of its two edges a piece
+        # builds the one(s) that cross it (some pair above the diagonal, some
+        # pair at or beyond the window's lower edge)
+        if window is None or col0 + s.shape[1] - 1 > row0:
+            upper = col <= row
+            mask = upper if mask is None else mask & upper
+        if window is not None and col0 <= row0 + s.shape[0] - 1 - window:
+            lower = col > row - window
+            mask = lower if mask is None else mask & lower
     return jnp.where(mask, s, _NEG_INF)
 
 
@@ -549,7 +647,7 @@ def _fwd_kernel(
                         kv_len, "kv", visit, always=not carried, window=window)
 
     if band:
-        _band_tiles(band, j, i, num_q_blocks, True, strips)
+        _band_tiles("flash_fwd", band, window, block_q, j, i, num_q_blocks, True, strips)
     elif triangle:
         _triangle_classes("flash_fwd", i, j, block_q, block_kv, strips)
     else:
@@ -741,7 +839,7 @@ def _dkv_kernel(
 
     if band:
         # from the diagonal tile down: q tile j + t of kv tile j
-        _band_tiles(band, i, j, num_q_blocks, False, strips)
+        _band_tiles("flash_bwd_dkv", band, window, block_q, i, j, num_q_blocks, False, strips)
     elif triangle:
         _triangle_classes("flash_bwd_dkv", i, j, block_q, block_kv, strips)
     else:
@@ -804,7 +902,7 @@ def _dq_kernel(
                         kv_len, "kv", visit, always=not carried, window=window)
 
     if band:
-        _band_tiles(band, j, i, num_q_blocks, True, strips)
+        _band_tiles("flash_bwd_dq", band, window, block_q, j, i, num_q_blocks, True, strips)
     elif triangle:
         _triangle_classes("flash_bwd_dq", i, j, block_q, block_kv, strips)
     else:
@@ -964,8 +1062,8 @@ _IMPLEMENTATIONS = ("xla", "pallas")
 
 # Side of a windowed call's square grid tiles. One chip sweep at S = 8,192,
 # window 2,048, 32 query heads over 4 key-value heads of 128 (PERF.md section
-# 6, PR 33) chose it; with the band's static offsets the sub-tile walk runs
-# inside every tile, so the side only trades grid steps against VMEM.
+# 6, PR 33) chose it; the band's static offsets give every tile its class
+# whatever the side, so the side only trades grid steps against VMEM.
 _WINDOW_BLOCK = 1024
 
 
@@ -1006,11 +1104,12 @@ def attention_plan(seq: int, *, causal: bool = True,
                    window: Optional[int] = None) -> dict:
     """What `flash_attention` runs for one head of a (seq, seq)
     self-attention: the resolved implementation and how far the kernels'
-    sub-tile walk engages (no sub-tiles for "xla").
+    sub-tile walk engages (no sub-tiles for "xla"; the sub-tiles as dQ counts
+    them, `attention_subtiles`).
     For callers that report it: LMTrainer's `train.init.step_fn` span,
     chip_smoke.py."""
     impl = resolve_attention_impl(implementation)
-    visited = masked = total = steps = live = 0
+    visited = masked = total = steps = live = whole = 0
     if impl != "xla":
         bq, bkv = _blocks(seq, seq, None, None, window)
         padded_q, padded_kv = seq + (-seq) % bq, seq + (-seq) % bkv
@@ -1021,6 +1120,9 @@ def attention_plan(seq: int, *, causal: bool = True,
             padded_q, padded_kv, causal, seq, bq, bkv,
             *_sub_tiles(bq, bkv, 1 if static else nq * nk), window)
         steps, live = attention_grid_steps(padded_q, padded_kv, causal, seq, bq, bkv, window)
+        if window is not None:
+            band = window_band(window, bq, nk)
+            whole = sum(nq - d for d in range(band) if _band_whole("flash_fwd", d, band, bq, window))
     return {
         "attention_impl": impl,
         "attn_subtiles_visited": visited,
@@ -1028,6 +1130,9 @@ def attention_plan(seq: int, *, causal: bool = True,
         "attn_subtiles_total": total,
         "attn_grid_steps": steps,
         "attn_grid_steps_live": live,
+        # a windowed call's band tiles a head that the forward computes as one
+        # piece (`_BAND_WALK`), of its `attn_grid_steps_live`
+        **({} if window is None else {"attn_window_tiles_whole": whole}),
     }
 
 
@@ -1104,9 +1209,11 @@ def flash_attention(
     the kv axis of the grid is then the BAND of tiles a q tile's window
     touches (`window_band`; 3 of 8 at S = 8,192, window 2,048), so a tile
     outside the window is neither computed nor copied, and since a band
-    tile lies at a static offset from the diagonal the sub-tile walk runs
-    inside each: a sub-tile outside the window is skipped, one inside runs
-    unmasked, one the diagonal or the window's edge crosses builds the mask.
+    tile lies at a static offset from the diagonal its class is known while
+    tracing: an interior tile (every pair live) is one whole unmasked piece,
+    the diagonal tile and the one the window's lower edge crosses are whole
+    with that edge's static mask or walked in sub-tiles, a kernel
+    (`_BAND_WALK`; PERF.md section 6, PR 43).
     """
     return _attend(q, k, v, False, causal, window, sm_scale, block_q, block_kv, implementation)
 

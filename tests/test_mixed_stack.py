@@ -390,7 +390,7 @@ def test_the_step_reports_the_stack_and_the_held_rows():
             plan["moe_shared_width"]) == ("sigmoid", 8, 32, 32)
     assert plan["moe_held_buffer_rows"] == 2 * 48 * 4 // 2 and plan["moe_held_passes_most"] == 2
     assert {"attn_window_subtiles_visited", "attn_window_subtiles_masked",
-            "attn_window_subtiles_total"} <= set(plan)
+            "attn_window_subtiles_total", "attn_window_tiles_whole"} <= set(plan)
     params = seeded(config)
     tokens = jax.random.randint(jax.random.PRNGKey(3), (2, 49), 0, config.vocab_size)
     _, scalars = lm_loss(params, tokens, config)

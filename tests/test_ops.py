@@ -241,11 +241,24 @@ def test_flash_rows_no_subtile_reaches():
      ((4096, 4096, True, 4096, 1024, 1024, 256, 256, None, "flash_bwd_dkv"), (136, 16, 256)),
      # not causal, or not square: the dense grid, every live tile masked
      ((2048, 2048, False, 2048, 1024, 1024, 1024, 1024), (4, 4, 4)),
-     ((2048, 2048, True, 2048, 1024, 512, 1024, 512), (6, 6, 8))],
+     ((2048, 2048, True, 2048, 1024, 512, 1024, 512), (6, 6, 8)),
+     # a band of 5 at window 4,096 (16 diagonal + 42 interior + 12 trailing tiles
+     # a head): the backward kernels walk the edge tiles (10 of 16, 4 masked) ...
+     ((16384, 16384, True, 16384, 1024, 1024, 256, 256, 4096, "flash_bwd_dkv"),
+      (28 * 10 + 42 * 16, 28 * 4, 4096)),
+     ((16384, 16384, True, 16384, 1024, 1024, 256, 256, 4096, "flash_bwd_dq"), (952, 112, 4096)),
+     # ... the forward computes them whole: 16 visited and 16 masked a tile
+     ((16384, 16384, True, 16384, 1024, 1024, 256, 256, 4096, "flash_fwd"),
+      (70 * 16, 28 * 16, 4096)),
+     # a band of 3 at window 2,048: 8 + 7 + 6
+     ((8192, 8192, True, 8192, 1024, 1024, 256, 256, 2048, "flash_fwd"), (21 * 16, 14 * 16, 1024)),
+     ((8192, 8192, True, 8192, 1024, 1024, 256, 256, 2048, "flash_bwd_dkv"),
+      (14 * 10 + 7 * 16, 14 * 4, 1024))],
     ids=["causal-256", "causal-128", "causal-512", "full-256", "grid2x2",
          "kv-edge", "rectangular", "small-blocks", "causal-grid-16k", "causal-grid-8k",
          "causal-grid-4k", "causal-grid-padded", "causal-grid-block512", "causal-grid-16k-forward",
-         "causal-grid-4k-dkv", "full-grid2x2", "causal-rectangular-grid"],
+         "causal-grid-4k-dkv", "full-grid2x2", "causal-rectangular-grid", "band5-16k-dkv",
+         "band5-16k-dq", "band5-16k-forward", "band3-8k-forward", "band3-8k-dkv"],
 )
 def test_attention_subtiles_counts(args, expect):
     from ray_tpu.ops.attention import attention_subtiles
@@ -303,6 +316,15 @@ def test_attention_plan_names_what_runs(monkeypatch):
     assert attention_plan(2048, causal=False)["attn_subtiles_masked"] == 4
     banded = attention_plan(8192, window=2048)
     assert (banded["attn_grid_steps"], banded["attn_grid_steps_live"]) == (24, 21)
+    # the band's tiles by class: the forward computes all 21 (and all 70 at
+    # SmallThinker's shape) whole; the sub-tiles are dQ's, whose edge tiles are
+    # walked (10 of 16, 4 masked) and whose 7 (42) interior tiles are whole
+    assert banded["attn_window_tiles_whole"] == 21
+    assert (banded["attn_subtiles_visited"], banded["attn_subtiles_masked"]) == (252, 56)
+    wider = attention_plan(16384, window=4096)
+    assert (wider["attn_grid_steps_live"], wider["attn_window_tiles_whole"]) == (70, 70)
+    assert (wider["attn_subtiles_visited"], wider["attn_subtiles_masked"]) == (952, 112)
+    assert "attn_window_tiles_whole" not in attention_plan(8192)
 
 
 @pytest.mark.parametrize("s,causal", [(1024, True), (1024, False), (700, True),
@@ -410,19 +432,44 @@ def test_subtile_ranges_agree_under_a_window(window, sub):
     _assert_ranges_cover((cols <= rows) & (cols > rows - window), sub, True, None, window)
 
 
-@pytest.mark.parametrize("s,window,block", [
-    (48, 64, None), (64, 64, None), (96, 32, 32), (128, 48, 32), (100, 32, 32),
-    (128, 32, 64), (128, 200, 32), (64, 16, None), (128, 1, 32)],
+# Bands with interior tiles, at the chip's geometry a sixteenth the size (tiles
+# of 64 in 4 x 4 sub-tiles of 16): (s, window, block) -> the band's classes
+_BANDS_WITH_INTERIOR_TILES = {
+    "band3-window-2-tiles": ((320, 128, 64), ["diagonal", "interior", "trailing"]),
+    "band4-window-3-tiles": ((448, 192, 64), ["diagonal", "interior", "interior", "trailing"]),
+    # the window's edge inside a tile: two trailing tiles, the walk visits 3 of 16 of the last
+    "band5-edge-inside-a-tile": ((512, 210, 64), ["diagonal", "interior", "interior", "trailing", None]),
+    "band5-window-4-tiles": ((384, 256, 64), ["diagonal", "interior", "interior", "interior", "trailing"]),
+    # every q tile's band starts before the sequence; no tile divides the length
+    "band-longer-than-the-sequence": ((200, 256, 64), ["diagonal", "interior", "interior", "interior"]),
+    # narrower than a tile: the walk visits 7 and 1 of 16, so neither tile is a class
+    "window-under-a-tile": ((256, 16, 64), [None, None]),
+}
+
+
+@pytest.mark.parametrize("s,window,block,classes", [
+    *((*case, "table") for case in [
+        (48, 64, None), (64, 64, None), (96, 32, 32), (128, 48, 32), (100, 32, 32),
+        (128, 32, 64), (128, 200, 32), (64, 16, None), (128, 1, 32)]),
+    *((*case, classes) for case, _ in _BANDS_WITH_INTERIOR_TILES.values()
+      for classes in ("table", "walked", "whole"))],
     ids=["below-window", "at-window", "above-3x3", "edge-inside-a-tile", "no-tile-divides",
-         "two-tiles", "window-over-the-sequence", "one-tile-walked", "window-of-one"])
-def test_windowed_kernels_match_the_masked_reference(monkeypatch, s, window, block):
+         "two-tiles", "window-over-the-sequence", "one-tile-walked", "window-of-one",
+         *(f"{name}-{classes}" for name in _BANDS_WITH_INTERIOR_TILES
+           for classes in ("table", "walked", "whole"))])
+def test_windowed_kernels_match_the_masked_reference(monkeypatch, s, window, block, classes):
     """`flash_attention(window=)` in interpret mode against `mha_reference`
     with the same mask, forward and all three gradients, GQA: sequences below,
     at and above the window, a window edge inside a tile, and a length no tile
-    divides. The walk runs inside every band tile (16 x 16 sub-tiles here)."""
+    divides; bands of 3 to 5 tiles with interior ones, by `_BAND_WALK` as it
+    stands and with every class walked (16 x 16 sub-tiles here) and every
+    class whole in all three kernels."""
     from ray_tpu.ops import attention as A
 
     monkeypatch.setattr(A, "_SUB_TILE", (16, 16))
+    if classes != "table":
+        monkeypatch.setattr(A, "_BAND_WALK", {kernel: dict.fromkeys(by_class, classes == "walked")
+                                              for kernel, by_class in A._BAND_WALK.items()})
     keys = jax.random.split(jax.random.PRNGKey(s + window), 4)
     q, do = (_rand(key, (1, 4, s, 32)) for key in (keys[0], keys[3]))
     k, v = (_rand(key, (1, 2, s, 32)) for key in keys[1:3])
@@ -449,21 +496,95 @@ def test_windowed_kernels_match_the_masked_reference(monkeypatch, s, window, blo
         np.testing.assert_allclose(np.asarray(ours), np.asarray(theirs), atol=5e-5)
 
 
+def test_band_tiles_have_the_class_their_offset_says(monkeypatch):
+    """`_band_class` from the offset, the side and the window alone, against
+    the pairs themselves: interior iff every pair of the tile is live."""
+    from ray_tpu.ops import attention as A
+
+    monkeypatch.setattr(A, "_SUB_TILE", (16, 16))
+    for (s, window, block), want in _BANDS_WITH_INTERIOR_TILES.values():
+        band = A.window_band(window, block, -(-s // block))
+        assert [A._band_class(d, band, block, window) for d in range(band)] == want
+        for d in range(band):
+            rows, cols = d * block + np.arange(block)[:, None], np.arange(block)[None, :]
+            live = (cols <= rows) & (cols > rows - window)
+            assert live.any() and live.all() == (want[d] == "interior")
+    # the two cells: 3 of 5 and 1 of 3 band tiles interior; one tile a head is no class
+    monkeypatch.undo()
+    assert [A._band_class(d, 5, 1024, 4096) for d in range(5)] == [
+        "diagonal", "interior", "interior", "interior", "trailing"]
+    assert [A._band_class(d, 3, 1024, 2048) for d in range(3)] == ["diagonal", "interior", "trailing"]
+    assert A._band_class(0, 1, 1024, 2048) is None
+
+
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"])
 @pytest.mark.parametrize("s,window,block", [
     (8192, 2048, 1024),     # a band of 3 tiles a q tile: 21 of the 36 causal tiles
+    (16384, 4096, 1024),    # a band of 5: 70 tiles a head, 42 of them interior
     (1024, 2048, 1024),     # one tile: the causal walk, 10 / 4 / 16
-    (2048, 256, 1024), (4096, 1000, 512)])
-def test_windowed_subtile_counts(s, window, block):
-    """`attention_subtiles` under a window against a count over the pairs
-    themselves: a sub-tile is visited iff it holds a live pair and masked iff
-    it also holds a dead one."""
-    from ray_tpu.ops.attention import attention_subtiles
+    (2048, 256, 1024), (4096, 1000, 512), (4096, 1500, 1024)])
+def test_windowed_subtile_counts(s, window, block, kernel):
+    """`attention_subtiles` under a window, a kernel, against a count over the
+    pairs themselves: in a tile the kernel walks a sub-tile is visited iff it
+    holds a live pair and masked iff it also holds a dead one; a tile it
+    computes whole (`_BAND_WALK` by `_band_class`) visits all 16 and masks all
+    or, with every pair live, none."""
+    from ray_tpu.ops import attention as A
 
+    tiles, subs = s // block, block // 256
+    band = A.window_band(window, block, tiles)
     rows, cols = np.arange(s)[:, None], np.arange(s)[None, :]
-    live = ((cols <= rows) & (cols > rows - window)).reshape(s // 256, 256, s // 256, 256)
-    some, every = live.any(axis=(1, 3)), live.all(axis=(1, 3))
-    by_pairs = (int(some.sum()), int((some & ~every).sum()), (s // 256) ** 2)
-    assert attention_subtiles(s, s, True, s, block, block, 256, 256, window) == by_pairs
+    live = ((cols <= rows) & (cols > rows - window)).reshape(tiles, subs, 256, tiles, subs, 256)
+    some, every = live.any(axis=(2, 5)), live.all(axis=(2, 5))    # (tile, sub, tile, sub)
+    visited = masked = whole_tiles = 0
+    for i in range(tiles):
+        for d in range(min(band, i + 1)):
+            tile_some, tile_every = some[i, :, i - d], every[i, :, i - d]
+            if A._band_whole(kernel, d, band, block, window):
+                whole_tiles += 1
+                visited += subs * subs
+                masked += 0 if tile_every.all() else subs * subs
+            else:
+                visited += int(tile_some.sum())
+                masked += int((tile_some & ~tile_every).sum())
+    assert A.attention_subtiles(s, s, True, s, block, block, 256, 256, window, kernel) == (
+        visited, masked, (s // 256) ** 2)
+    if kernel == "flash_fwd":
+        plan_whole = sum(tiles - d for d in range(band) if A._band_whole(kernel, d, band, block, window))
+        assert plan_whole == whole_tiles
+
+
+@pytest.mark.parametrize("s,window", [(4096, 2048), (6144, 4096), (4096, 1500), (2048, 256)],
+                         ids=["band3", "band5", "two-trailing-tiles", "under-a-tile"])
+def test_windowed_kernels_trace_the_counted_pieces(monkeypatch, s, window):
+    """What the three windowed kernels hold, as they are traced at the chip's
+    tile and sub-tile: ONE body for the interior tiles, a whole unmasked
+    piece, and one for each other band tile, whose pieces are what
+    `attention_subtiles` counts for that tile under that kernel."""
+    from ray_tpu.ops import attention as A
+
+    pieces = []
+    scores = A._scores
+
+    def recording(q, k, scale, mask_at, *rest):
+        pieces.append((q.shape[0] * k.shape[0], mask_at is not None))
+        return scores(q, k, scale, mask_at, *rest)
+
+    monkeypatch.setattr(A, "_scores", recording)
+    q = jnp.zeros((1, 1, s, 64), jnp.float32)
+    jax.eval_shape(jax.grad(lambda q: flash_attention(
+        q, q, q, causal=True, window=window, implementation="pallas").sum()), q)
+    band, area = A.window_band(window, 1024, s // 1024), 256 * 256
+    live = masked = 0
+    for kernel in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"):
+        bodies = {1 if A._band_class(d, band, 1024, window) == "interior" else d for d in range(band)}
+        for d in bodies:
+            on_visited, on_masked = A._band_tile_subtiles(kernel, d, band, 1024, 256, 256, window)
+            live, masked = live + on_visited * area, masked + on_masked * area
+    assert sum(n for n, _ in pieces) == live
+    assert sum(n for n, m in pieces if m) == masked
+    interior = [d for d in range(band) if A._band_class(d, band, 1024, window) == "interior"]
+    assert pieces.count((1024 * 1024, False)) == (3 if interior else 0)
 
 
 def test_window_needs_causal_self_attention():

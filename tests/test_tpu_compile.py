@@ -120,23 +120,38 @@ def _kernel_payloads(lowered_text: str) -> list:
 
 
 # What the kernels of the calls that ISSUE 37's causal grid must NOT reach lowered
-# to at its parent (183a4fe, jax 0.9.0): one tile a head and a windowed band.
+# to at its parent (183a4fe, jax 0.9.0): one tile a head. ISSUE 43's band classes
+# must not reach those nor the causal grid's: the three cells' full layers as they
+# lowered at ITS parent (b705cbf). The windowed bands' are the ones PR 43 recorded
+# for its own kernels (`_BAND_WALK` as its sweep filled it; at b705cbf the
+# 2,048 band lowered to 0c31b494380ff759, 355e5c6ff6c53256, 620890a7c0f71e85 and
+# the 4,096 one to 74e2b245f31dcdf7, 8878514e319b7661, 17836ec746b727c5).
 # A change that means to move them records its own.
 _PARENT_KERNELS = {
     "gpt2-one-tile": (GPT2, None, ["168573c8a395eef5", "c7d28a62531b4dfc", "0d9fd8c88998994e"]),
     "mistral-one-tile": (MISTRAL, None, ["908c08fb4f6e79d1", "9f20af3abcff34e9", "3861f3c4fd3b362a"]),
-    "trinity-window-2048": (TRINITY, 2048, ["0c31b494380ff759", "355e5c6ff6c53256", "620890a7c0f71e85"]),
+    "olmoe-causal-grid": (OLMOE, None, ["d2faf6bbdfcd3509", "0d8aef9f814896c9", "9b4b8ad6a4c59342"]),
+    "trinity-causal-grid": (TRINITY, None, ["f9110c423f8b7cea", "ea81818b8eb90d2c", "78314226a55998ae"]),
+    "smallthinker-causal-grid": (SMALLTHINKER, None,
+                                 ["cc133df6a3900f80", "9b065628311428f4", "e01ec46875231a9b"]),
+    "trinity-window-2048": (TRINITY, 2048, ["814711fc3c30193f", "1cfe1a04b182bd14", "fc1d872126362468"]),
+    "smallthinker-window-4096": (SMALLTHINKER, 4096,
+                                 ["bd2bb6a8b593727a", "723cb52d86133fdc", "f4ebf95525fd6754"]),
 }
 
 
 @pytest.mark.parametrize("case", sorted(_PARENT_KERNELS))
 def test_one_tile_and_windowed_calls_keep_their_kernels(as_tpu, case):
     """`train-gpt2s` and `train-mistral7b-fsdp2tp2` (S = 1,024: one tile a
-    head) and the windowed layers run the kernels they ran before the causal
-    grid: forward, dK/dV, dQ lower to the same MLIR."""
+    head) run the kernels they ran before the causal grid, and the three
+    other cells' full layers those of that grid as PR 43's parent lowered it:
+    forward, dK/dV, dQ lower to the same MLIR. The windowed layers' hashes are
+    NEW in PR 43, which gave a band's tiles their classes: they hold the two
+    cells' windowed kernels to what that PR measured."""
     (q_shape, kv_shape), window, want = _PARENT_KERNELS[case]
     q, k, v = _on(as_tpu, q_shape), _on(as_tpu, kv_shape), _on(as_tpu, kv_shape)
-    assert not flash._live_grid(True, window, 1024, 1024, q_shape[2] // 1024, q_shape[2] // 1024)
+    tiles = q_shape[2] // 1024
+    assert flash._live_grid(True, window, 1024, 1024, tiles, tiles) == ("causal-grid" in case)
 
     def loss(q, k, v):
         return flash.flash_attention(q, k, v, causal=True, window=window).astype(jnp.float32).sum()
@@ -145,16 +160,20 @@ def test_one_tile_and_windowed_calls_keep_their_kernels(as_tpu, case):
     assert _kernel_payloads(lowered.as_text()) == want
 
 
-@pytest.mark.parametrize("seq,window,block", [
-    (8192, 2048, None), (8192, 2048, 512), (1024, 2048, None),
-    (1536, 1000, 512)],
-    ids=["cell-8k-band3", "block512-band5", "one-tile", "edge-inside-a-tile"])
-def test_windowed_flash_attention_fwd_and_grad_compile(as_tpu, seq, window, block):
+@pytest.mark.parametrize("shapes,seq,window,block", [
+    (TRINITY, 8192, 2048, None), (SMALLTHINKER, 16384, 4096, None), (TRINITY, 8192, 2048, 512),
+    (TRINITY, 1024, 2048, None), (TRINITY, 1536, 1000, 512), (TRINITY, 4096, 1500, None)],
+    ids=["cell-8k-band3", "cell-16k-band5", "block512-band5", "one-tile", "edge-inside-a-tile",
+         "two-trailing-tiles"])
+def test_windowed_flash_attention_fwd_and_grad_compile(as_tpu, shapes, seq, window, block):
     """The `flash_win_*` kernels of a windowed call (a band of tiles at static
-    offsets from the diagonal, the sub-tile walk inside each) at the cell's
-    widths: the forward, and the forward with both backward kernels."""
-    q = _on(as_tpu, (2, 32, seq, 128))
-    k = v = _on(as_tpu, (2, 4, seq, 128))
+    offsets from the diagonal, each computed whole or walked in sub-tiles by
+    its class and `_BAND_WALK`) at both cells' shapes and widths: the forward,
+    and the forward with both backward kernels. A whole 1,024 x 1,024 piece
+    beside a walked body fits the scoped VMEM in all three."""
+    (b, hq, _, d), (_, hkv, _, _) = shapes
+    q = _on(as_tpu, (b, hq, seq, d))
+    k = v = _on(as_tpu, (b, hkv, seq, d))
 
     def attend(q, k, v):
         return flash.flash_attention(q, k, v, causal=True, window=window,
