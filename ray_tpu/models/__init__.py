@@ -56,6 +56,11 @@ class ModelFamily(NamedTuple):
     # (config, batch, seq of a step) -> what the family's own layers resolve to, for
     # callers that report it (LMTrainer's `train.init.step_fn` span)
     plan: Callable
+    # (params, hidden, next_tokens, config, routers, remat_saved=()) -> (the hidden
+    # states of the family's multi-token prediction module for the shared head,
+    # `forward_hidden`'s scalars with the module's block counted in); None: a
+    # family without such a module (train/lm.lm_loss asks where `config.mtp_modules`)
+    mtp_hidden: Optional[Callable] = None
 
 
 def _dense_hidden(params, tokens, config, remat_saved=()):
@@ -65,7 +70,7 @@ def _dense_hidden(params, tokens, config, remat_saved=()):
 # most derived first: a MixedStackConfig is a MoEConfig is a TransformerConfig
 _FAMILIES = (
     (MixedStackConfig, ModelFamily(_mixed.init_params, _mixed.logical_axes, _mixed.forward_hidden,
-                                   _mixed.block_costs, _mixed.plan)),
+                                   _mixed.block_costs, _mixed.plan, _mixed.mtp_hidden)),
     (MoEConfig, ModelFamily(_moe.init_params, _moe.logical_axes, _moe.forward_hidden,
                             None, _moe.moe_plan)),
     (TransformerConfig, ModelFamily(init_params, logical_axes, _dense_hidden,

@@ -3,7 +3,10 @@
 The dense and MoE families (transformer.py, moe.py) scan one block over a
 stack of identical layers. Here the configuration says, a layer, its
 attention kind ("sliding": a causal window with rotary positions; "full":
-every causal key and no positional encoding) and its MLP kind ("dense": one
+every causal key and no positional encoding; "latent", every layer of a
+configuration with `kv_lora_rank`: every causal key, q from a low-rank
+latent, keys and values from one latent all heads share, rotary positions
+on a part of the head) and its MLP kind ("dense": one
 SwiGLU; "experts": a routed expert layer, beside a shared expert where the
 configuration has one), by two rules on the layer's index: one layer of every
 `global_attn_every` is full (the last of its period, or the first where
@@ -24,8 +27,16 @@ window, output gate, post-norm: data of the configuration and arguments of
 the call), `transformer.mlp_sublayer`, `moe.moe_mlp`. Where `config.remat`
 every block is recomputed in the backward pass but for what the step keeps of
 it (`block_costs` names what it may: the attention kernel's output and lse and
-the residual after the output projection in every layer, gate and up in the
-dense ones; an expert layer's MLP is recomputed whole).
+the residual after the output projection in every layer, a latent layer's
+latents, gate and up in the dense ones; an expert layer's MLP is recomputed
+whole).
+
+A configuration with `mtp_modules` has, beside the stack, one multi-token
+prediction module (`params["mtp"]`, `mtp_hidden`): the stack's output and
+the embedding of the NEXT token, each through a norm of its own, joined by
+`eh_proj`, through one more block of the expert-layer kind and a final norm,
+to the shared head, which then predicts the token after next. The objective
+that uses it is train/lm.lm_loss's.
 """
 
 from __future__ import annotations
@@ -87,24 +98,32 @@ class MixedStackConfig(MoEConfig):
     # cannot form a leaf's whole gradient: the router's needs the outputs of
     # all the chosen experts, and the absent ones' lie on other chips
     frozen_leaves: Tuple[str, ...] = ()
+    # multi-token prediction modules after the stack (0 or 1) and the weight
+    # of their loss in the objective: loss + mtp_loss_weight x mtp_loss
+    mtp_modules: int = 0
+    mtp_loss_weight: float = 0.0
 
     def __post_init__(self):
+        super().__post_init__()
+        if self.mtp_modules not in (0, 1):
+            raise ValueError(f"mtp_modules {self.mtp_modules}: one multi-token prediction module "
+                             "is what the program runs")
         if self.router_input not in ("mlp", "attention"):
             raise ValueError(f"unknown router input: {self.router_input!r}")
         known = {name for mlp in ("dense", "experts")
-                 for name in _layer_shapes(self, LayerKind("full", mlp))}
+                 for name in _layer_shapes(self, LayerKind(layer_kinds(self)[0].attention, mlp))}
         if not set(self.frozen_leaves) <= known:
             raise ValueError(f"frozen_leaves {sorted(set(self.frozen_leaves) - known)}: "
                              f"no layer has such a leaf")
 
 
 class LayerKind(NamedTuple):
-    attention: str  # "sliding" | "full"
+    attention: str  # "sliding" | "full" | "latent"
     mlp: str        # "dense" | "experts"
 
     @property
     def code(self) -> str:
-        return ("d" if self.mlp == "dense" else "e") + ("F" if self.attention == "full" else "S")
+        return ("d" if self.mlp == "dense" else "e") + self.attention[0].upper()
 
 
 class Run(NamedTuple):
@@ -117,7 +136,8 @@ class Run(NamedTuple):
 def layer_kinds(config: MixedStackConfig) -> List[LayerKind]:
     c = config
     full_at = 0 if c.global_attn_first else c.global_attn_every - 1
-    return [LayerKind("full" if i % c.global_attn_every == full_at else "sliding",
+    return [LayerKind("latent" if c.latent_attention
+                      else "full" if i % c.global_attn_every == full_at else "sliding",
                       "dense" if i < c.n_dense_layers else "experts")
             for i in range(c.n_layers)]
 
@@ -176,15 +196,25 @@ def _layer_shapes(config: MixedStackConfig, kind: LayerKind) -> Dict[str, Tuple[
     into_residual = "normal" if c.sandwich_norm else "into_residual"
     held, shared = c.n_experts_held, c.shared_expert_width
     experts = kind.mlp == "experts"
+    latent, plain = kind.attention == "latent", kind.attention != "latent"
+    q_rank, kv_rank, rope = c.q_lora_rank, c.kv_lora_rank, c.qk_rope_dim
     # (name, whether this layer has the leaf, its shape, initialisation and axes)
     leaves = [
         ("ln1_scale", True, (m,), "ones", (None,)),
         ("ln1_post_scale", c.sandwich_norm, (m,), "post", (None,)),
         ("ln2_scale", True, (m,), "ones", (None,)),
         ("ln2_post_scale", c.sandwich_norm, (m,), "post", (None,)),
-        ("wq", True, (m, c.n_heads, dh), "normal", ("embed", "heads", "head_dim")),
-        ("wk", True, (m, c.kv_heads, dh), "normal", ("embed", "kv_heads", "head_dim")),
-        ("wv", True, (m, c.kv_heads, dh), "normal", ("embed", "kv_heads", "head_dim")),
+        ("wq", plain, (m, c.n_heads, dh), "normal", ("embed", "heads", "head_dim")),
+        ("wk", plain, (m, c.kv_heads, dh), "normal", ("embed", "kv_heads", "head_dim")),
+        ("wv", plain, (m, c.kv_heads, dh), "normal", ("embed", "kv_heads", "head_dim")),
+        # a latent layer: the down-projections and the latents' norms whole on
+        # every device of a tensor-parallel group, the up-projections over heads
+        ("wq_a", latent, (m, q_rank), "normal", ("embed", None)),
+        ("q_a_norm_scale", latent, (q_rank,), "ones", (None,)),
+        ("wq_b", latent, (q_rank, c.n_heads, dh), "normal", (None, "heads", "head_dim")),
+        ("wkv_a", latent, (m, kv_rank + rope), "normal", ("embed", None)),
+        ("kv_a_norm_scale", latent, (kv_rank,), "ones", (None,)),
+        ("wkv_b", latent, (kv_rank, c.n_heads, 2 * dh - rope), "normal", (None, "heads", "head_dim")),
         ("wg", c.attn_gate, (m, c.n_heads, dh), "normal", ("embed", "heads", "head_dim")),
         ("wo", True, (c.n_heads, dh, m), into_residual, ("heads", "head_dim", "embed")),
         ("q_norm_scale", c.qk_norm_per_head, (dh,), "ones", (None,)),
@@ -203,6 +233,23 @@ def _layer_shapes(config: MixedStackConfig, kind: LayerKind) -> Dict[str, Tuple[
         ("ws_down", experts and shared > 0, (shared, m), into_residual, ("mlp", "embed")),
     ]
     return {name: (shape, how, axes) for name, has, shape, how, axes in leaves if has}
+
+
+def mtp_kind(config: MixedStackConfig) -> LayerKind:
+    """The multi-token prediction module's block: an expert layer with the
+    attention of the stack's last layer."""
+    return LayerKind(layer_kinds(config)[-1].attention, "experts")
+
+
+def _mtp_shapes(config: MixedStackConfig) -> Dict[str, Tuple[Tuple[int, ...], Any, Any]]:
+    """`_layer_shapes` of the module's leaves outside its block: the norms on
+    the next token's embedding and on the stack's output, the projection that
+    joins the two (their concatenation, embedding first, -> the stream), and
+    the norm before the shared head."""
+    m = config.d_model
+    return {"enorm_scale": ((m,), "ones", (None,)), "hnorm_scale": ((m,), "ones", (None,)),
+            "eh_proj": ((2 * m, m), "normal", (None, "embed")),
+            "norm_scale": ((m,), "ones", (None,))}
 
 
 def init_params(config: MixedStackConfig, key: jax.Array) -> Params:
@@ -234,22 +281,36 @@ def init_params(config: MixedStackConfig, key: jax.Array) -> Params:
                            for k, (name, (shape, how, _)) in zip(keys, shapes.items())})
         runs.append(period)
     k_embed, k_head = jax.random.split(jax.random.fold_in(key, 10**6))
-    return {
+    params = {
         "wte": c.embedding_std * jax.random.normal(k_embed, (c.vocab_size, c.d_model), pd),
         "runs": runs,
         "lnf_scale": jnp.ones((c.d_model,), pd),
         "lm_head": std * jax.random.normal(k_head, (c.d_model, c.vocab_size), pd),
     }
+    if c.mtp_modules:
+        # the module's own leaves without a leading axis, its block's stacked as a run of one
+        own, block = _mtp_shapes(c), _layer_shapes(c, mtp_kind(c))
+        keys = iter(jax.random.split(jax.random.fold_in(key, 10**6 + 1), len(own) + len(block)))
+        params["mtp"] = {
+            **{name: leaf(next(keys), shape, how, 1)[0] for name, (shape, how, _) in own.items()},
+            "block": {name: leaf(next(keys), shape, how, 1) for name, (shape, how, _) in block.items()}}
+    return params
 
 
 def logical_axes(config: MixedStackConfig) -> Params:
-    return {
+    def stacked(kind):
+        return {name: ("layers", *axes) for name, (_, _, axes) in _layer_shapes(config, kind).items()}
+
+    axes = {
         "wte": ("vocab", "embed"),
-        "runs": [[{name: ("layers", *axes) for name, (_, _, axes) in _layer_shapes(config, kind).items()}
-                  for kind in run.kinds] for run in stack_runs(layer_kinds(config))],
+        "runs": [[stacked(kind) for kind in run.kinds] for run in stack_runs(layer_kinds(config))],
         "lnf_scale": (None,),
         "lm_head": ("embed", "vocab"),
     }
+    if config.mtp_modules:
+        axes["mtp"] = {**{name: leaf_axes for name, (_, _, leaf_axes) in _mtp_shapes(config).items()},
+                       "block": stacked(mtp_kind(config))}
+    return axes
 
 
 # -------------------------------------------------------------------- forward
@@ -269,7 +330,7 @@ def _block(x, lp, config, kind: LayerKind, rope_tables, positions, remat_saved=(
     # so a router that reads it costs no tensor carried past the attention
     router_input = x if c.router_input == "attention" else None
     x = attention_sublayer(     # the scope `attn.window` or `attn.full`, by the window
-        x, lp, c, rope_tables if sliding else None, positions,
+        x, lp, c, None if kind.attention == "full" else rope_tables, positions,
         window=c.sliding_window if sliding else None, remat_saved=remat_saved)
     if kind.mlp == "dense":
         return mlp_sublayer(x, lp, c), {}
@@ -305,7 +366,7 @@ def forward_hidden(
         x = params["wte"].astype(dt)[tokens]
         if c.scale_embedding:
             x = x * jnp.asarray(math.sqrt(c.d_model), dt)
-    rope_tables = rope_frequencies(c.head_dim, s, c.rope_theta)
+    rope_tables = rope_frequencies(c.rotary_dims, s, c.rope_theta)
 
     reports: List[Dict[str, jax.Array]] = []
     for run, period_params in zip(stack_runs(layer_kinds(c)), params["runs"]):
@@ -329,19 +390,72 @@ def forward_hidden(
         reports.extend(scalars)      # under a scan each scalar is (repeats,)
     with jax.named_scope("head"):
         x = _norm(x, params["lnf_scale"], None, c.norm, c.norm_eps)
+    return x, _expert_layers_report(reports, c, b * s)
+
+
+def _expert_layers_report(reports: List[Dict[str, jax.Array]], config: MixedStackConfig,
+                          tokens: int) -> Dict[str, jax.Array]:
+    """What `forward_hidden` says of its expert layers, from their scalars (a
+    scanned run's are (repeats,) each); {} for none."""
+    c = config
     if not reports:
-        return x, {}
+        return {}
     every = {name: jnp.concatenate([jnp.ravel(r[name]) for r in reports]) for name in reports[0]}
     out = {"moe_load_max_over_mean": jnp.max(every["load"])}
     if "moe_rows_held" in every:
         rows_held = jnp.mean(every["moe_rows_held"])
         out.update(moe_rows_held=rows_held,
-                   moe_rows_held_share=100.0 * rows_held / (b * s * c.top_k),
+                   moe_rows_held_share=100.0 * rows_held / (tokens * c.top_k),
                    moe_passes=jnp.max(every["moe_passes"]))
     if "moe_act_live_units" in every:
         out["moe_act_live_share"] = jnp.mean(
             100.0 * every["moe_act_live_units"] / jnp.maximum(every["moe_rows_held"] * c.d_ff, 1.0))
-    return x, out
+    return out
+
+
+def mtp_hidden(
+    params: Params,
+    hidden: jax.Array,
+    next_tokens: jax.Array,
+    config: MixedStackConfig,
+    routers: Dict[str, jax.Array],
+    *,
+    remat_saved: Tuple[str, ...] = (),
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """The multi-token prediction module on `forward_hidden`'s output (B, S,
+    E) and the tokens that FOLLOW its positions (B, S): [norm_e(embedding of
+    the next token) ; norm_h(hidden)] `eh_proj`, one block of `mtp_kind` (its
+    checkpoint keeps what the stack's blocks keep), a norm: -> ((B, S, E) for
+    the shared head, which then predicts the token after next; `routers`,
+    `forward_hidden`'s report, with this block's expert layer counted as one
+    more of the stack's)."""
+    c = config
+    dt = c.dtype
+    mp = params["mtp"]
+    b, s = next_tokens.shape
+    with jax.named_scope("embed"):
+        embedded = params["wte"].astype(dt)[next_tokens]
+        if c.scale_embedding:
+            embedded = embedded * jnp.asarray(math.sqrt(c.d_model), dt)
+    joined = jnp.concatenate([_norm(embedded, mp["enorm_scale"], None, c.norm, c.norm_eps),
+                              _norm(hidden, mp["hnorm_scale"], None, c.norm, c.norm_eps)], axis=-1)
+    x = jnp.einsum("bsf,fe->bse", joined, mp["eh_proj"].astype(dt))
+    rope_tables = rope_frequencies(c.rotary_dims, s, c.rope_theta)
+
+    def block_fn(x, lp):
+        return _block(x, lp, c, mtp_kind(c), rope_tables, None, remat_saved)
+
+    if c.remat:
+        block_fn = checkpoint_block(block_fn, remat_saved)
+    x, scalars = block_fn(x, jax.tree.map(lambda w: w[0], mp["block"]))
+    x = _norm(x, mp["norm_scale"], None, c.norm, c.norm_eps)
+    # the block's layer beside the stack's: the worst of both, and the means
+    # weighted by the layers they are of
+    mine, layers = _expert_layers_report([scalars], c, b * s), c.n_layers - c.n_dense_layers
+    worst = ("moe_load_max_over_mean", "moe_passes")
+    return x, {name: (jnp.maximum(routers[name], value) if name in worst
+                      else (layers * routers[name] + value) / (layers + 1))
+               for name, value in mine.items()} if layers else mine
 
 
 def _expert_costs(config: MixedStackConfig, split: Callable[[str], int]) -> Dict[str, Any]:
@@ -381,11 +495,15 @@ def block_costs(
         return (attention_costs(c, seq, split, c.sliding_window if kind.attention == "sliding" else None),
                 mlp_costs(c, split, c.d_ff_dense) if kind.mlp == "dense" else _expert_costs(c, split))
 
-    return stack_costs([
+    runs = [
         StackRun(run.repeats > 1, ("runs", r), tuple(
             (n * run.repeats, *kind_costs(kind)) for kind, n in collections.Counter(run.kinds).items()),
             period=len(run.kinds))
-        for r, run in enumerate(stack_runs(layer_kinds(c)))])
+        for r, run in enumerate(stack_runs(layer_kinds(c)))]
+    if c.mtp_modules:
+        # the module's block: one more layer, run after the stack and on its own
+        runs.append(StackRun(False, ("mtp", "block"), ((1, *kind_costs(mtp_kind(c))),)))
+    return stack_costs(runs)
 
 
 def plan(config: MixedStackConfig, batch: int, seq: int) -> Dict[str, Any]:
@@ -393,21 +511,30 @@ def plan(config: MixedStackConfig, batch: int, seq: int) -> Dict[str, Any]:
     for callers that report it (LMTrainer's `train.init.step_fn` span): the
     layers' kinds in order (`dS dS eS eF ...`), the window and the windowed
     kernels' sub-tile walk (the full layers' is `attention_plan`'s, which the
-    trainer writes for every model), the router's form and the expert layer's
-    (`moe.moe_plan`)."""
+    trainer writes for every model) or, of a latent-attention stack, the ranks
+    of its latents, the features of a head that rotate and the head size; the
+    multi-token prediction module and its loss's weight where there is one;
+    the router's form and the expert layer's (`moe.moe_plan`)."""
     c = config
-    windowed = attention_plan(seq, causal=c.causal, implementation=c.attn_impl,
-                              window=c.sliding_window)
-    out = {
-        "layer_kinds": " ".join(kind.code for kind in layer_kinds(c)),
-        "attn_window": c.sliding_window,
-        **{name.replace("attn_subtiles", "attn_window_subtiles"): value
-           for name, value in windowed.items() if name.startswith(("attn_subtiles", "attn_window"))},
+    out = {"layer_kinds": " ".join(kind.code for kind in layer_kinds(c))}
+    if c.latent_attention:
+        out.update(attn_latent_q_rank=c.q_lora_rank, attn_latent_kv_rank=c.kv_lora_rank,
+                   attn_rope_dims=c.rotary_dims, attn_head_dim=c.head_dim)
+    else:
+        windowed = attention_plan(seq, causal=c.causal, implementation=c.attn_impl,
+                                  window=c.sliding_window)
+        out.update({"attn_window": c.sliding_window},
+                   **{name.replace("attn_subtiles", "attn_window_subtiles"): value
+                      for name, value in windowed.items()
+                      if name.startswith(("attn_subtiles", "attn_window"))})
+    if c.mtp_modules:
+        out.update(mtp_modules=c.mtp_modules, mtp_loss_weight=c.mtp_loss_weight)
+    out.update({
         "moe_router": c.router_score,
         "moe_router_input": c.router_input,
         "moe_experts_routed": c.n_experts,
         "moe_shared_width": c.shared_expert_width,
-    }
+    })
     if c.n_dense_layers < c.n_layers:
         out.update(moe_plan(c, batch, seq))
     return out

@@ -84,6 +84,30 @@ class TransformerConfig:
     attn_gate: bool = False
     sandwich_norm: bool = False
     scale_embedding: bool = False
+    # Latent attention (`kv_lora_rank` > 0; the mixed stack's third attention
+    # kind): q comes up from a normed latent of `q_lora_rank`, the keys' part
+    # without positions and the values come up, a head, from ONE normed latent
+    # of `kv_lora_rank` that all heads share, and the last `qk_rope_dim` of a
+    # head's `head_dim` features carry rotary positions: q's own, and for the
+    # keys one head of `qk_rope_dim` projected beside the latent and given to
+    # every head. `v_head_dim` (None: `head_dim`) is stated so that a
+    # configuration whose values are not as wide as its keys is refused by name
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: Optional[int] = None
+
+    def __post_init__(self):
+        if self.latent_attention:
+            if self.v_head_dim not in (None, self.head_dim):
+                raise ValueError(
+                    f"latent attention: v_head_dim {self.v_head_dim} is not the q/k head size "
+                    f"{self.head_dim}; the flash kernels take one head size")
+            if not (self.q_lora_rank > 0 and 0 < self.qk_rope_dim < self.head_dim
+                    and self.qk_rope_dim % 2 == 0 and self.kv_heads == self.n_heads):
+                raise ValueError(
+                    "latent attention: q_lora_rank > 0, an even qk_rope_dim under the head size "
+                    "and as many key-value heads as query heads are what the program runs")
 
     @property
     def kv_heads(self) -> int:
@@ -92,6 +116,16 @@ class TransformerConfig:
     @property
     def head_dim(self) -> int:
         return self.d_head or self.d_model // self.n_heads
+
+    @property
+    def latent_attention(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def rotary_dims(self) -> int:
+        """The features of a head that rotate, which is the width of the
+        rotary table's rows x 2: the whole head, or a latent head's rotary part."""
+        return self.qk_rope_dim if self.latent_attention else self.head_dim
 
     def replace(self, **kw) -> "TransformerConfig":
         return dataclasses.replace(self, **kw)
@@ -104,6 +138,7 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Params:
     """GPT-2-style init: N(0, 0.02), residual-out projections scaled by
     1/sqrt(2L). Block params are stacked on a leading layer axis for scan."""
     c = config
+    _no_latent_attention(c, "transformer.init_params")
     pd = c.param_dtype
     dh = c.head_dim
     keys = jax.random.split(key, 16)
@@ -242,12 +277,48 @@ def attention_sublayer(
         return _attention_sublayer(x, lp, config, rope_tables, positions, window, remat_saved)
 
 
+def _latent_qkv(h, lp, config, rope_tables, positions):
+    """q, k, v (B, H, S, head_dim) of a latent-attention layer from the normed
+    stream h (B, S, E): the scope `attn.latent`. q = [q_nope | rot(q_rope)] a
+    head from the normed q latent; k = [k_nope | rot(k_rope)] with k_nope and v
+    a head from the normed key-value latent and the ONE rotary key head given
+    to all of them. The two normed latents and the rotary key part are named
+    (`attn_latent_*`): a checkpoint that keeps them recomputes the
+    up-projections alone."""
+    c = config
+    dt = c.dtype
+    nope = c.head_dim - c.qk_rope_dim
+    kw = {} if c.norm_eps is None else {"eps": c.norm_eps}
+    cos, sin = rope_tables
+    with jax.named_scope("attn.latent"):
+        q_latent = rmsnorm(jnp.einsum("bse,er->bsr", h, lp["wq_a"].astype(dt)),
+                           lp["q_a_norm_scale"], **kw)
+        q_latent = checkpoint_name(q_latent, "attn_latent_q")
+        kv_a = jnp.einsum("bse,er->bsr", h, lp["wkv_a"].astype(dt))
+        kv_latent = checkpoint_name(
+            rmsnorm(kv_a[..., :c.kv_lora_rank], lp["kv_a_norm_scale"], **kw), "attn_latent_kv")
+        k_rope = checkpoint_name(kv_a[..., c.kv_lora_rank:], "attn_latent_k_rope")
+        q = apply_rope(jnp.einsum("bsr,rhd->bhsd", q_latent, lp["wq_b"].astype(dt)),
+                       cos, sin, positions, rotary_dims=c.qk_rope_dim)
+        # the up-projection's two parts as two matmuls of the split WEIGHT: the
+        # values leave theirs as the kernel takes them
+        wkv_b = lp["wkv_b"].astype(dt)
+        k_nope = jnp.einsum("bsr,rhd->bhsd", kv_latent, wkv_b[..., :nope])
+        v = jnp.einsum("bsr,rhd->bhsd", kv_latent, wkv_b[..., nope:])
+        k_rope = apply_rope(k_rope[:, None], cos, sin, positions)      # one head: (B, 1, S, rope)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rope, (*k_nope.shape[:-1], c.qk_rope_dim))], axis=-1)
+    return q, k, v
+
+
 def _attention_sublayer(x, lp, config, rope_tables, positions, window, remat_saved):
     c = config
     dt = c.dtype
     with jax.named_scope("attn.proj"):
         h = _norm(x, lp["ln1_scale"], lp.get("ln1_bias"), c.norm, c.norm_eps)
-        if c.fused_qkv:
+        if c.latent_attention:
+            q, k, v = _latent_qkv(h, lp, c, rope_tables, positions)
+        elif c.fused_qkv:
             # one wide matmul beats three narrow ones on the MXU; the concat of
             # the (static) weights folds into the kernel at compile time
             wqkv = jnp.concatenate(
@@ -280,7 +351,7 @@ def _attention_sublayer(x, lp, config, rope_tables, positions, window, remat_sav
             kw = {} if c.norm_eps is None else {"eps": c.norm_eps}
             q = rmsnorm(q, lp["q_norm_scale"], **kw)
             k = rmsnorm(k, lp["k_norm_scale"], **kw)
-        if rope_tables is not None:
+        if rope_tables is not None and not c.latent_attention:
             cos, sin = rope_tables
             q = apply_rope(q, cos, sin, positions)
             k = apply_rope(k, cos, sin, positions)
@@ -371,14 +442,13 @@ def attention_costs(
     """`attention_sublayer`'s part of `block_costs`, a layer: `flops`,
     `width` and `candidates` as there."""
     c = config
+    if c.latent_attention:
+        return _latent_attention_costs(c, seq, split)
     q_width, kv_width = c.n_heads * c.head_dim // split("wq"), c.kv_heads * c.head_dim // split("wk")
     out_proj = 2 * q_width * c.d_model
     # the scores' two matmuls over the keys a query sees
     scores = 4 * q_width * (min(seq, window) if window else seq // 2 if c.causal else seq)
-    # the lse beside the output: 4 bytes a head, in features of the activations' dtype
     heads, itemsize = c.n_heads // split("wq"), jnp.dtype(c.dtype).itemsize
-    lse_width = -(-4 * heads // itemsize)
-    moved = 2 * (heads * 128 * 4 + q_width * itemsize)
     return {
         "flops": (2 * c.d_model * ((2 if c.attn_gate else 1) * q_width + 2 * kv_width)
                   + scores + out_proj),
@@ -387,11 +457,48 @@ def attention_costs(
         "width": ((4 if c.sandwich_norm else 3) * c.d_model
                   + (4 if c.attn_gate else 2) * q_width + 2 * kv_width),
         "candidates": (
-            RematCandidate(
-                ("attn_out", "attn_lse"), q_width + lse_width, scores,
-                int(scores / _FLASH_SHARE_OF_PEAK["window" if window else "full"]
-                    - moved * _KEPT_KERNEL_FLOPS_PER_BYTE), False, ()),
+            _kept_kernel_candidate(scores, heads, q_width, itemsize, "window" if window else "full"),
             RematCandidate(("attn_residual",), c.d_model, out_proj, out_proj, split("wq") > 1, ()),
+        ),
+    }
+
+
+def _kept_kernel_candidate(scores: int, heads: int, q_width: int, itemsize: int, kind: str):
+    """The attention kernel's output and lse as a candidate: what `scores`
+    FLOPs take at the kernels' share of the peak, less what keeping moves."""
+    lse_width = -(-4 * heads // itemsize)    # 4 bytes a head, in features of the activations' dtype
+    moved = 2 * (heads * 128 * 4 + q_width * itemsize)
+    return RematCandidate(
+        ("attn_out", "attn_lse"), q_width + lse_width, scores,
+        int(scores / _FLASH_SHARE_OF_PEAK[kind] - moved * _KEPT_KERNEL_FLOPS_PER_BYTE), False, ())
+
+
+def _latent_attention_costs(config: TransformerConfig, seq: int, split: Callable[[str], int]):
+    """`attention_costs` of a latent-attention layer: the five matmuls (the
+    two down-projections whole on every device, the up-projections and the
+    output's over the heads it holds), and beside the other kinds' two
+    candidates the normed latents with the rotary key part: `q_lora_rank +
+    kv_lora_rank + qk_rope_dim` features a token, after which the backward
+    repeats the up-projections alone."""
+    c = config
+    heads, itemsize = c.n_heads // split("wq_b"), jnp.dtype(c.dtype).itemsize
+    q_width, nope = heads * c.head_dim, c.head_dim - c.qk_rope_dim
+    latents = c.q_lora_rank + c.kv_lora_rank + c.qk_rope_dim
+    down = 2 * c.d_model * latents
+    up = 2 * (c.q_lora_rank * q_width + c.kv_lora_rank * heads * (nope + c.head_dim))
+    out_proj = 2 * q_width * c.d_model
+    scores = 4 * q_width * (seq // 2 if c.causal else seq)
+    return {
+        "flops": down + up + scores + out_proj,
+        # the norm's output, the sublayer's, the residual; both latents before
+        # and after their norms; q before and after its rotation, the keys'
+        # part without positions, k, v and the attention output
+        "width": 3 * c.d_model + 2 * latents + 5 * q_width + heads * nope,
+        "candidates": (
+            _kept_kernel_candidate(scores, heads, q_width, itemsize, "full"),
+            RematCandidate(("attn_residual",), c.d_model, out_proj, out_proj, split("wq_b") > 1, ()),
+            RematCandidate(("attn_latent_q", "attn_latent_kv", "attn_latent_k_rope"),
+                           latents, down, down, False, ()),
         ),
     }
 
@@ -563,6 +670,13 @@ def _no_qk_norm(config: TransformerConfig) -> None:
             "forward) only: the cached decode paths do not carry it yet")
 
 
+def _no_latent_attention(config: TransformerConfig, where: str) -> None:
+    if config.latent_attention:
+        raise NotImplementedError(
+            f"latent attention is run by the mixed stack's training forward alone: {where} has "
+            "neither its seven leaves nor a cache of latent rows (the absorbed decode form)")
+
+
 def init_cache(
     config: TransformerConfig, batch: int, max_seq: Optional[int] = None
 ) -> Params:
@@ -606,6 +720,7 @@ def decode_step(
     """
     c = config
     _no_qk_norm(c)
+    _no_latent_attention(c, "the dense cache")
     dt = c.dtype
     b = tokens.shape[0]
     x = params["wte"].astype(dt)[tokens][:, None, :]  # (B, 1, E)
@@ -679,6 +794,7 @@ def prefill(
     lengths (B,) true prompt lengths."""
     c = config
     _no_qk_norm(c)
+    _no_latent_attention(c, "the dense cache")
     dt = c.dtype
     b, s = tokens.shape
     x = params["wte"].astype(dt)[tokens]

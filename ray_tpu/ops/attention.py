@@ -160,6 +160,46 @@ _SUB_TILE = (256, 256)
 # softmax step a 256-row strip, as in the windowed forward).
 _DIAGONAL_WALK = {"flash_fwd": False, "flash_bwd_dkv": True, "flash_bwd_dq": True}
 
+# A head WIDER than 128 features (latent attention's 256): every q, k, v, do, o
+# and accumulator block of a tile is twice a 128-wide head's, and a 1,024 x 1,024
+# tile's backward no longer fits the compiler's 16 MiB of scoped VMEM (dK/dV is
+# refused for the described v5e). The side of a grid tile, the scoped VMEM the
+# kernels ask for (None: the compiler's), the walk's sub-tile and the diagonal
+# tile's walk are therefore chosen again for such a head. One alternating chip
+# sweep at 2 x 20/20 x 8,192 x 256, causal (PERF.md section 6, PR 44), ms a
+# call by the host's clock over 4 queued calls, the median of 5 rounds; a tile
+# side of 512 asks for no VMEM, 1,024 for 64 MiB, 2,048 for 100 MiB; the
+# diagonal tile whole, or walked in sub-tiles of 128 x 128 / 256 x 256 / 512 x 512:
+#              512: whole   128    256   | 1,024: whole   128    256    512  | 2,048: 512 | 256: whole
+#   forward         13.24  13.49  13.49  |        10.03  10.04   9.71   9.62  |       9.60 |      28.34
+#   dK/dV           18.57  18.23  18.52  |        17.81  16.44  16.87  16.93  |      24.78 |      30.77
+#   dQ              14.56  14.70  14.63  |        13.59  12.54  12.59  12.94  |      20.45 |      21.30
+# (512 a side with sub-tiles of 256 x 128 and 128 x 256: within 0.4% of 256 x 256.)
+# The 1,024 tile wins all three kernels (the forward by 27%: 136 steps a head
+# become 36, a quarter of the copies and of the softmax's carried updates), so
+# the scoped VMEM is asked for; 2,048 gains nothing more in the forward and
+# loses a third in the backward. All three kernels WALK the diagonal tile at
+# this width, the forward too (at D = 128 it computes it whole: the masked
+# quarter of a tile is twice as many MXU passes here); 256 x 256 wins or loses
+# by under 2.6% (dK/dV at 128 x 128), so the sub-tile stays the narrow heads'.
+# A call's head size is static, so the choice is one more rule on the shape a
+# call can observe, not an option. The forward then reads 72% of its roofline
+# (6.98 ms of required work a call).
+_WIDE_HEAD = {
+    "tile": 1024, "vmem_limit_bytes": 64 * 1024 * 1024, "sub_tile": (256, 256),
+    "diagonal_walk": {"flash_fwd": True, "flash_bwd_dkv": True, "flash_bwd_dq": True},
+}
+
+
+def _head_choices(head_dim: Optional[int] = None) -> dict:
+    """The grid tile's side, the walk's sub-tile, which kernels walk a causal
+    grid's diagonal tile and the scoped VMEM asked for, by the head size (None:
+    a head of up to 128 features, which every sweep before PR 44 was made at)."""
+    if head_dim is None or head_dim <= 128:
+        return {"tile": 1024, "vmem_limit_bytes": None, "sub_tile": _SUB_TILE,
+                "diagonal_walk": _DIAGONAL_WALK}
+    return _WIDE_HEAD
+
 # The same question for each class of a windowed call's band tiles (`_band_class`):
 # True walks the class in those sub-tiles, False computes it as one whole piece
 # (an interior tile unmasked, a diagonal or trailing one with the static mask of
@@ -194,13 +234,13 @@ _BAND_WALK = {
 _WALK_VISITS_SWEPT = 10 / 16
 
 
-def _sub_tiles(block_q: int, block_kv: int, grid_tiles: int):
+def _sub_tiles(block_q: int, block_kv: int, grid_tiles: int, head_dim: Optional[int] = None):
     """Sub-tile shape of the walk over a (block_q, block_kv) block, one of
     `grid_tiles` a head whose offsets are traced. A block the sub-tile does
     not divide (it is smaller, or an odd length) and a block of a larger grid
     are their own single sub-tile. (A windowed call's band of tiles has
     static offsets against the diagonal, so it passes 1.)"""
-    sub_q, sub_kv = _SUB_TILE
+    sub_q, sub_kv = _head_choices(head_dim)["sub_tile"]
     if grid_tiles > 1:
         return block_q, block_kv
     return (sub_q if block_q % sub_q == 0 else block_q,
@@ -338,14 +378,16 @@ def _band_tile_subtiles(kernel: str, d: int, band: int, block: int, sub_q: int, 
 
 def attention_subtiles(sq: int, skv: int, causal: bool, kv_len: int,
                        block_q: int, block_kv: int, sub_q: int, sub_kv: int,
-                       window: Optional[int] = None, kernel: str = "flash_bwd_dq"):
+                       window: Optional[int] = None, kernel: str = "flash_bwd_dq",
+                       head_dim: Optional[int] = None):
     """(visited, masked, total) sub-tiles of one head's (sq, skv) score
     matrix as the kernels walk it: `visited` run their matmuls, `masked`
     of those build the mask, `total` is what a dense walk would visit.
     Counted with the kernels' own loop bounds. The three kernels walk alike
-    but for a causal grid's diagonal tiles (`_DIAGONAL_WALK`) and a band's
-    tiles by class (`_BAND_WALK`), which `kernel` decides: a tile computed
-    whole visits all its sub-tiles, and masks all or (interior) none."""
+    but for a causal grid's diagonal tiles (`_DIAGONAL_WALK`, or a wide
+    `head_dim`'s table) and a band's tiles by class (`_BAND_WALK`), which
+    `kernel` decides: a tile computed whole visits all its sub-tiles, and masks
+    all or (interior) none."""
     visited = masked = 0
     total = (sq // sub_q) * (skv // sub_kv)
     tiles = sq // block_q
@@ -364,7 +406,7 @@ def attention_subtiles(sq: int, skv: int, causal: bool, kv_len: int,
         # walked, or whole and masked
         whole = (block_q // sub_q) * (block_kv // sub_kv)
         on_visited, on_masked = whole, whole
-        if _DIAGONAL_WALK[kernel]:
+        if _head_choices(head_dim)["diagonal_walk"][kernel]:
             on_visited, on_masked, _ = attention_subtiles(
                 block_q, block_kv, True, block_kv, block_q, block_kv, sub_q, sub_kv)
         return (tiles * (tiles - 1) // 2 * whole + tiles * on_visited, tiles * on_masked, total)
@@ -497,7 +539,7 @@ def _triangle_tile(q_tiles_ref, kv_tiles_ref):
     return q_tiles_ref[step], kv_tiles_ref[step]
 
 
-def _triangle_classes(kernel, i, j, block_q, block_kv, strips):
+def _triangle_classes(kernel, i, j, block_q, block_kv, strips, head_dim):
     """A causal grid's step: the code of its tile's class. Both classes have
     STATIC offsets against the diagonal, as a band's tiles have, so `strips`
     takes them as (q tile, kv tile) of a head: the diagonal tile is (0, 0),
@@ -507,7 +549,8 @@ def _triangle_classes(kernel, i, j, block_q, block_kv, strips):
     whole one: PERF.md section 6, PR 33 and PR 37). Each is emitted once
     whatever the length."""
     whole = (block_q, block_kv)
-    diagonal = _sub_tiles(block_q, block_kv, 1) if _DIAGONAL_WALK[kernel] else whole
+    walked = _head_choices(head_dim)["diagonal_walk"][kernel]
+    diagonal = _sub_tiles(block_q, block_kv, 1, head_dim) if walked else whole
     pl.when(i == j)(functools.partial(strips, 0, 0, *diagonal))
     pl.when(i != j)(functools.partial(strips, 1, 0, *whole))
 
@@ -649,7 +692,7 @@ def _fwd_kernel(
     if band:
         _band_tiles("flash_fwd", band, window, block_q, j, i, num_q_blocks, True, strips)
     elif triangle:
-        _triangle_classes("flash_fwd", i, j, block_q, block_kv, strips)
+        _triangle_classes("flash_fwd", i, j, block_q, block_kv, strips, q_ref.shape[-1])
     else:
         strips(i, j)
 
@@ -669,10 +712,14 @@ def _band_index(band: int, toward_diagonal: bool, tiles: int):
     return lambda j, t: jnp.minimum(j + t, tiles - 1)
 
 
-def _grid_call(kernel, tables, grid, in_specs, out_specs, scratch_shapes, **how):
+def _grid_call(kernel, tables, grid, in_specs, out_specs, scratch_shapes, head_dim, **how):
     """`pl.pallas_call` over `grid`; with a causal grid's `tables` as its
-    scalar prefetch, which every index map and the kernel then take too."""
+    scalar prefetch, which every index map and the kernel then take too; with
+    the scoped VMEM a wide head's choices ask for."""
     spec = dict(grid=grid, in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch_shapes)
+    vmem_limit_bytes = _head_choices(head_dim)["vmem_limit_bytes"]
+    if vmem_limit_bytes:
+        how["compiler_params"] = pltpu.CompilerParams(vmem_limit_bytes=vmem_limit_bytes)
     if tables:
         spec = {"grid_spec": pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=len(tables), **spec)}
     return pl.pallas_call(kernel, **spec, **how)
@@ -686,7 +733,7 @@ def _fwd_pallas(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret,
     nq = sq // block_q
     nk = skv // block_kv
     band = window_band(window, block_q, nk) if window is not None else 0
-    sub_q, sub_kv = _sub_tiles(block_q, block_kv, 1 if band else nq * nk)
+    sub_q, sub_kv = _sub_tiles(block_q, block_kv, 1 if band else nq * nk, d)
     kv_tile = _band_index(band, True, nk) if band else (lambda i, j: j)
 
     kernel = functools.partial(
@@ -730,6 +777,7 @@ def _fwd_pallas(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret,
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ] if kv_steps > 1 else [],
+        head_dim=d,
         out_shape=[
             jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
             jax.ShapeDtypeStruct((b, hq, sq, 1), jnp.float32),
@@ -841,7 +889,7 @@ def _dkv_kernel(
         # from the diagonal tile down: q tile j + t of kv tile j
         _band_tiles("flash_bwd_dkv", band, window, block_q, i, j, num_q_blocks, False, strips)
     elif triangle:
-        _triangle_classes("flash_bwd_dkv", i, j, block_q, block_kv, strips)
+        _triangle_classes("flash_bwd_dkv", i, j, block_q, block_kv, strips, q_ref.shape[-1])
     else:
         strips(i, j)
 
@@ -904,7 +952,7 @@ def _dq_kernel(
     if band:
         _band_tiles("flash_bwd_dq", band, window, block_q, j, i, num_q_blocks, True, strips)
     elif triangle:
-        _triangle_classes("flash_bwd_dq", i, j, block_q, block_kv, strips)
+        _triangle_classes("flash_bwd_dq", i, j, block_q, block_kv, strips, q_ref.shape[-1])
     else:
         strips(i, j)
 
@@ -928,7 +976,7 @@ def _bwd_pallas(q, k, v, out, lse, do, causal, sm_scale, block_q, block_kv, kv_l
     )
 
     band = window_band(window, block_q, nk) if window is not None else 0
-    sub_q, sub_kv = _sub_tiles(block_q, block_kv, 1 if band else nq * nk)
+    sub_q, sub_kv = _sub_tiles(block_q, block_kv, 1 if band else nq * nk, d)
     q_tile = _band_index(band, False, nq) if band else (lambda j, i: i)
     kv_tile = _band_index(band, True, nk) if band else (lambda i, j: j)
 
@@ -970,6 +1018,7 @@ def _bwd_pallas(q, k, v, out, lse, do, causal, sm_scale, block_q, block_kv, kv_l
             pltpu.VMEM((block_kv, d), jnp.float32),
             pltpu.VMEM((block_kv, d), jnp.float32),
         ] if (band or nq) > 1 else [],
+        head_dim=d,
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
@@ -984,6 +1033,7 @@ def _bwd_pallas(q, k, v, out, lse, do, causal, sm_scale, block_q, block_kv, kv_l
         in_specs=in_specs2,
         out_specs=q_spec2,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)] if (band or nk) > 1 else [],
+        head_dim=d,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
         name="flash_win_bwd_dq" if band else "flash_bwd_dq",
@@ -1068,17 +1118,18 @@ _WINDOW_BLOCK = 1024
 
 
 def _blocks(sq: int, skv: int, block_q: Optional[int], block_kv: Optional[int],
-            window: Optional[int] = None):
+            window: Optional[int] = None, head_dim: Optional[int] = None):
     """(block_q, block_kv) of the Pallas kernels: the whole sequence up to
-    1,024 a side, resident in VMEM for the sub-tile walk. A windowed call's
-    tiles are square (its band of tiles then lies at static offsets from the
-    diagonal)."""
+    1,024 a side (a head wider than 128: `_WIDE_HEAD`'s side, the same), resident in
+    VMEM for the sub-tile walk. A windowed call's tiles are square (its band
+    of tiles then lies at static offsets from the diagonal)."""
     if window is not None:
         if block_q != block_kv:
             raise ValueError("a windowed call takes square blocks: block_q == block_kv")
         side = min(block_q or _WINDOW_BLOCK, max(sq, 1))
         return side, side
-    return min(block_q or 1024, max(sq, 1)), min(block_kv or 1024, max(skv, 1))
+    tile = _head_choices(head_dim)["tile"]
+    return min(block_q or tile, max(sq, 1)), min(block_kv or tile, max(skv, 1))
 
 
 def resolve_attention_impl(implementation: Optional[str] = None) -> str:
@@ -1101,24 +1152,29 @@ def resolve_attention_impl(implementation: Optional[str] = None) -> str:
 
 def attention_plan(seq: int, *, causal: bool = True,
                    implementation: Optional[str] = None,
-                   window: Optional[int] = None) -> dict:
+                   window: Optional[int] = None, head_dim: Optional[int] = None) -> dict:
     """What `flash_attention` runs for one head of a (seq, seq)
     self-attention: the resolved implementation and how far the kernels'
     sub-tile walk engages (no sub-tiles for "xla"; the sub-tiles as dQ counts
     them, `attention_subtiles`).
+    With the `head_dim` of the call, also what was chosen by it: the grid
+    tile's side and the sub-tile (`_head_choices`).
     For callers that report it: LMTrainer's `train.init.step_fn` span,
     chip_smoke.py."""
     impl = resolve_attention_impl(implementation)
     visited = masked = total = steps = live = whole = 0
+    chosen = {}
     if impl != "xla":
-        bq, bkv = _blocks(seq, seq, None, None, window)
+        bq, bkv = _blocks(seq, seq, None, None, window, head_dim)
         padded_q, padded_kv = seq + (-seq) % bq, seq + (-seq) % bkv
         nq, nk = padded_q // bq, padded_kv // bkv
         # a band's and a causal grid's tiles lie at static offsets: walked
         static = window is not None or _live_grid(causal, window, bq, bkv, nq, nk)
+        if head_dim is not None:
+            chosen = {"attn_tile": bq, "attn_subtile": "{}x{}".format(*_sub_tiles(bq, bkv, 1, head_dim))}
         visited, masked, total = attention_subtiles(
             padded_q, padded_kv, causal, seq, bq, bkv,
-            *_sub_tiles(bq, bkv, 1 if static else nq * nk), window)
+            *_sub_tiles(bq, bkv, 1 if static else nq * nk, head_dim), window, head_dim=head_dim)
         steps, live = attention_grid_steps(padded_q, padded_kv, causal, seq, bq, bkv, window)
         if window is not None:
             band = window_band(window, bq, nk)
@@ -1133,6 +1189,7 @@ def attention_plan(seq: int, *, causal: bool = True,
         # a windowed call's band tiles a head that the forward computes as one
         # piece (`_BAND_WALK`), of its `attn_grid_steps_live`
         **({} if window is None else {"attn_window_tiles_whole": whole}),
+        **chosen,
     }
 
 
@@ -1239,7 +1296,7 @@ def _attend(q, k, v, lse_first, causal=False, window=None, sm_scale=None, block_
     if causal and sq != skv:
         raise NotImplementedError("causal flash kernel requires Sq == Skv")
     interpret = jax.default_backend() != "tpu"
-    bq, bkv = _blocks(sq, skv, block_q, block_kv, window)
+    bq, bkv = _blocks(sq, skv, block_q, block_kv, window, q.shape[-1])
     out = _per_shard(
         lambda q_, k_, v_: _flash(
             q_, k_, v_, causal, sm_scale, bq, bkv, skv, interpret, window, lse_first
@@ -1294,7 +1351,7 @@ def flash_attention_with_lse(
         out = jnp.einsum("bhqk,bhkd->bhqd", p / l, v.astype(p.dtype))
         return out.astype(q.dtype), m + jnp.log(l)
     interpret = jax.default_backend() != "tpu"
-    bq, bkv = _blocks(sq, skv, block_q, block_kv)
+    bq, bkv = _blocks(sq, skv, block_q, block_kv, head_dim=q.shape[-1])
     out, lse = _fwd_pallas(
         _pad_seq(q, 2, bq), _pad_seq(k, 2, bkv), _pad_seq(v, 2, bkv),
         causal, sm_scale, bq, bkv, skv, interpret,

@@ -58,13 +58,22 @@ def rope_frequencies(
 
 
 def apply_rope(
-    x: jax.Array, cos: jax.Array, sin: jax.Array, positions: jax.Array | None = None
+    x: jax.Array, cos: jax.Array, sin: jax.Array, positions: jax.Array | None = None,
+    *, rotary_dims: int | None = None,
 ) -> jax.Array:
     """Rotary position embedding over the last dim of x (B, H, S, D).
 
     `positions` (B, S) selects rows of the (max_seq, D/2) tables; defaults to
     arange(S). Uses the split-half convention (matches HF Llama).
+    `rotary_dims` (None: the whole head) rotates the LAST `rotary_dims`
+    features of every head alone, with tables of `rotary_dims` / 2 columns,
+    and passes the features before them through: a latent-attention head's
+    [no positions | rotary] layout. A rotary key part that all heads share is
+    a call with H = 1.
     """
+    if rotary_dims is not None and rotary_dims != x.shape[-1]:
+        through, rotary = jnp.split(x, [x.shape[-1] - rotary_dims], axis=-1)
+        return jnp.concatenate([through, apply_rope(rotary, cos, sin, positions)], axis=-1)
     b, _, s, d = x.shape
     if positions is None:
         cos_sel = cos[:s][None, None]  # (1, 1, S, D/2)

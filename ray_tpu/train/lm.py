@@ -279,26 +279,44 @@ def lm_loss(
     """The training objective on a (B, S + 1) batch, for every model
     family: next-token cross entropy (the head chunked by `chunk` rows of
     the sequence, 0 = dense) plus, for a MoE model, `router_aux_coeff`
-    times the routers' load-balancing loss. `remat_saved`: what a model
-    with `config.remat` keeps of each block. Returns (objective, the
-    step's scalars: `loss` = the cross entropy alone, so that a dense and
-    a sparse model's losses mean the same, `num_tokens`, and the
-    routers')."""
+    times the routers' load-balancing loss and, for a model with a multi-token
+    prediction module (`config.mtp_modules`), `mtp_loss_weight` times that
+    module's loss: from the stack's output and the NEXT token's embedding
+    the module predicts the token after next, through the same head in the
+    same form, on all S positions with the last (which has no such target)
+    masked out. `remat_saved`: what a model with `config.remat` keeps of
+    each block. Returns (objective, the step's scalars: `loss` = the
+    next-token cross entropy alone, so that every model's losses mean the
+    same, `num_tokens`, the routers', and `mtp_loss`)."""
     targets = tokens[:, 1:]
-    hidden, routers = model_family(config).forward_hidden(
+    family = model_family(config)
+    hidden, routers = family.forward_hidden(
         params, tokens[:, :-1], config, remat_saved=remat_saved)
-    with jax.named_scope("head"):
-        head = lm_head_weights(params, config)
-        if chunk:
-            loss, ntok = fused_linear_cross_entropy(
-                hidden, head, targets, chunk=chunk, z_loss_coeff=z_loss_coeff)
-        else:
+
+    def head_loss(hidden, targets, mask=None):
+        with jax.named_scope("head"):
+            head = lm_head_weights(params, config)
+            if chunk:
+                return fused_linear_cross_entropy(
+                    hidden, head, targets, mask=mask, chunk=chunk, z_loss_coeff=z_loss_coeff)
             logits = jnp.einsum("bse,ev->bsv", hidden, head)
-            loss, ntok = cross_entropy_loss(logits, targets, z_loss_coeff=z_loss_coeff)
-    objective = loss
+            return cross_entropy_loss(logits, targets, mask=mask, z_loss_coeff=z_loss_coeff)
+
+    loss, ntok = head_loss(hidden, targets)
+    objective, scalars = loss, {}
+    if getattr(config, "mtp_modules", 0):
+        with jax.named_scope("mtp"):
+            mtp_hidden, routers = family.mtp_hidden(
+                params, hidden, targets, config, routers, remat_saved=remat_saved)
+            # position i predicts token i + 2; the last position has none
+            has_target = jnp.broadcast_to(jnp.arange(targets.shape[1]) < targets.shape[1] - 1,
+                                          targets.shape)
+            mtp_loss, _ = head_loss(mtp_hidden, jnp.roll(targets, -1, axis=1), has_target)
+        objective = objective + config.mtp_loss_weight * mtp_loss
+        scalars["mtp_loss"] = mtp_loss
     if "router_aux_loss" in routers:
-        objective = loss + config.router_aux_coeff * routers["router_aux_loss"]
-    return objective, {"loss": loss, "num_tokens": ntok, **routers}
+        objective = objective + config.router_aux_coeff * routers["router_aux_loss"]
+    return objective, {"loss": loss, "num_tokens": ntok, **scalars, **routers}
 
 
 def make_train_step(

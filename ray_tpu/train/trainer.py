@@ -146,7 +146,8 @@ class LMTrainer:
         self._plan_shape = tuple(tokens_shape)
         batch, seq = tokens_shape[0], tokens_shape[1] - 1
         plan = attention_plan(
-            seq, causal=self.config.causal, implementation=self.config.attn_impl)
+            seq, causal=self.config.causal, implementation=self.config.attn_impl,
+            head_dim=self.config.head_dim)
         with jax.sharding.use_abstract_mesh(self.mesh.abstract_mesh):  # as the step is traced
             plan.update(model_family(self.config).plan(self.config, batch, seq))
         plan["loss_chunk"] = self.step_fn.loss_chunk_for(tokens_shape, self.state)

@@ -662,21 +662,27 @@ def roofline(cost: StepCost, step_time_s: float) -> Dict[str, Any]:
 # belongs to every name on its path: `attn.proj` (norm, q/k/v/gate
 # projections, QK-norm, rotary), `attn.kernel` (the flash call and the
 # layout work around it) and `attn.out` (gate, output projection, norm,
-# residual) lie inside `attn.full` or `attn.window`, the layer's kind; the
+# residual) lie inside `attn.full` or `attn.window`, the layer's kind
+# (`attn.latent`, inside `attn.proj`: a latent-attention layer's down- and
+# up-projections, its latents' norms, the rotary slice and the build of q and
+# k); the
 # seven `moe.*` inside `moe`, which also holds the expert layer's norms, its
 # weights' casts and its residual (`moe.select`: top-k, the gates'
 # renormalisation, the load count, the auxiliary loss; `moe.passes`: the
 # control and the sums of a held layer's passes after the first, around
 # those passes' own `moe.dispatch`, `moe.experts` and `moe.combine`);
-# `head` is the final norm, the logits and the loss.
+# `head` is the final norm, the logits and the loss; `mtp` a multi-token
+# prediction module whole (its block's own scopes nest in it, its pass of the
+# head is `mtp` and `head`).
 STEP_SCOPES: Tuple[str, ...] = (
     "steplog.fwd_bwd_compute", "steplog.optimizer_update",
     "embed",
-    "attn.full", "attn.window", "attn.proj", "attn.kernel", "attn.out",
+    "attn.full", "attn.window", "attn.proj", "attn.latent", "attn.kernel", "attn.out",
     "mlp",
     "moe", "moe.route", "moe.select", "moe.dispatch", "moe.experts", "moe.combine", "moe.passes",
     "moe.shared",
     "head",
+    "mtp",
 )
 # the forward and backward pass as a whole: a phase, which places an
 # operation in no sublayer (one that carries nothing else is "unscoped")

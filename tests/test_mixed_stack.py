@@ -177,15 +177,27 @@ def _reference_mlp(h, lp, config, held):
             for e in range(held))
 
 
-def test_the_shares_of_a_layer_add_up_to_the_uncut_layer():
-    """The routed parts of the four chips' shares of one layer, plus the
-    shared expert once, are the uncut layer's output: every share routes over
-    all 32 experts, normalises the gates over all 4 chosen, and computes the
-    chosen experts it holds."""
-    whole, lp, h = _expert_layer(tiny())
+def _glm47flash_family():
+    """Latent attention's family in small (tests/test_latent_attention.py) with
+    its published 64 routed experts: eight shares of 8, top-4, gates x 1.8."""
+    from test_latent_attention import tiny_latent
+
+    return tiny_latent(n_experts=64, mtp_modules=0)
+
+
+@pytest.mark.parametrize("family, shares", [(tiny, 4), (_glm47flash_family, 8)],
+                         ids=["afmoe-4-shares-of-32", "glm4_moe_lite-8-shares-of-64"])
+def test_the_shares_of_a_layer_add_up_to_the_uncut_layer(family, shares):
+    """The routed parts of the chips' shares of one layer (8 experts each),
+    plus the shared expert once, are the uncut layer's output: every share
+    routes over all the published experts with its family's router,
+    normalises the gates over all 4 chosen, and computes the chosen experts it
+    holds."""
+    whole, lp, h = _expert_layer(family())
+    assert whole.n_experts == 8 * shares
     uncut, scalars = moe.moe_mlp(h, lp, whole)
     total, rows = 0.0, 0.0
-    for share in range(4):
+    for share in range(shares):
         first = 8 * share
         config = dataclasses.replace(whole, held_experts=(first, first + 8),
                                      shared_expert_width=32 if share == 0 else 0)
@@ -196,7 +208,8 @@ def test_the_shares_of_a_layer_add_up_to_the_uncut_layer():
     np.testing.assert_allclose(np.asarray(total), np.asarray(uncut), atol=2e-5)
     assert float(rows) == 2 * 24 * 4            # every (token, choice) row lies on exactly one chip
     # and the uncut layer is the reference's
-    np.testing.assert_allclose(np.asarray(uncut), np.asarray(_reference_mlp(h, lp, whole, 32)), atol=2e-5)
+    np.testing.assert_allclose(np.asarray(uncut), np.asarray(_reference_mlp(h, lp, whole, 8 * shares)),
+                               atol=2e-5)
 
 
 @pytest.mark.parametrize("sent_here", ["every-choice", "none", "as-routed"])

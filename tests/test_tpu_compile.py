@@ -67,21 +67,25 @@ RING = ((4, 8, 2048, 128), (4, 8, 2048, 128))         # a ring-attention block
 TRINITY = ((2, 32, 8192, 128), (2, 4, 8192, 128))     # train-trinity-mini-8k, GQA 32 -> 4
 SMALLTHINKER = ((1, 28, 16384, 128), (1, 4, 16384, 128))  # train-smallthinker-16k, GQA 28 -> 4
 OLMOE = ((4, 16, 4096, 128), (4, 16, 4096, 128))      # train-olmoe-64e-4k
+GLM47FLASH = ((2, 20, 8192, 256), (2, 20, 8192, 256))  # train-glm47flash-8k: a wide head, MHA
 
 
 @pytest.mark.parametrize(
-    "shapes", [GPT2, MISTRAL, LLAMA, SMALLTHINKER, TRINITY, OLMOE],
+    "shapes", [GPT2, MISTRAL, LLAMA, SMALLTHINKER, TRINITY, OLMOE, GLM47FLASH],
     ids=["gpt2-d64-s1024", "mistral-d128-s1024-gqa", "llama-d128-s2048-grid",
-         "smallthinker-d128-s16384-gqa7", "trinity-d128-s8192-gqa8", "olmoe-d128-s4096"],
+         "smallthinker-d128-s16384-gqa7", "trinity-d128-s8192-gqa8", "olmoe-d128-s4096",
+         "glm47flash-d256-s8192"],
 )
 def test_flash_attention_fwd_and_grad_compile(as_tpu, shapes):
     """One grid tile a head (the sub-tile walk, both cells' shapes) and a
     causal grid of live tiles in its two classes (2 x 2 tiles, and the three
-    cells' full layers: 136, 36 and 10 steps a head), at the default blocks
-    and the default rule."""
+    cells' full layers: 136, 36 and 10 steps a head; a head of 256 at the
+    1,024-wide tile with the scoped VMEM it asks for: at the compiler's own
+    16 MiB its dK/dV kernel is refused), at the default blocks and the
+    default rule."""
     q_shape, kv_shape = shapes
     q, k, v = _on(as_tpu, q_shape), _on(as_tpu, kv_shape), _on(as_tpu, kv_shape)
-    plan = flash.attention_plan(q_shape[2])
+    plan = flash.attention_plan(q_shape[2], head_dim=q_shape[3])
     assert plan["attention_impl"] == "pallas"
     assert plan["attn_grid_steps"] == plan["attn_grid_steps_live"]
 
@@ -527,3 +531,48 @@ def test_smallthinker_cell_step_keeps_what_a_four_layer_iteration_leaves_room_fo
     bodies, fills = _held_passes_not_taken(lowered, compiled, "16384,2560")
     assert bodies <= 110 and fills == 0     # PR 41's count of bodies; a pass not taken fills nothing
     assert compiled.memory_analysis().argument_size_in_bytes / GIB == pytest.approx(7.20, abs=0.02)
+
+
+def test_glm47flash_cell_step_keeps_the_attention_outputs_and_the_latents_and_compiles(as_tpu, monkeypatch, v5e):
+    """`train-glm47flash-8k`'s whole step (2 x 8,193 tokens) for one described
+    v5e chip of 15.75 GiB: a dense latent-attention layer, four scanned expert
+    layers and the multi-token prediction module's block. The rule keeps the
+    attention kernels' outputs and the latents (after which the backward
+    repeats the up-projections alone) and leaves the head dense; the compiled
+    step runs the forward flash kernel at D = 256 once a body (the dense
+    layer, the scan's body, the module), the module's block and the second
+    pass of the head lie under `mtp`, and the latent projections under
+    `attn.latent`."""
+    from ray_tpu.ops import losses
+    from ray_tpu.train.lm import make_train_step
+    from ray_tpu.util import profiling
+
+    mesh = build_mesh(MeshSpec(), devices=[as_tpu])
+    config, opt, state, shardings, tokens = _cell_step_shapes(
+        "glm-4.7-flash-train-1chip", mesh, (2, 8193))
+    monkeypatch.setattr(losses, "device_hbm_bytes", lambda: int(15.75 * GIB))
+    step = make_train_step(config, opt, mesh, state_shardings=shardings)
+    plan = step.remat_plan_for(tokens.shape, state)
+    assert (plan["remat"], plan["remat_saved"]) == ("selective", (
+        "attn_out", "attn_lse", "attn_latent_q", "attn_latent_kv", "attn_latent_k_rope"))
+    # 6 layers x 16,384 rows x (5,120 + 40 + 768 + 512 + 64) bfloat16 features
+    assert plan["remat_saved_bytes"] == 6 * 16384 * (5120 + 40 + 1344) * 2
+    assert step.loss_chunk_for(tokens.shape, state) == 0
+    lowered = step.lower(state, {"tokens": tokens})
+    compiled = lowered.compile()
+    for kernel in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"):
+        assert _kernels_named(compiled, kernel) == 3
+    assert "flash_win" not in compiled.as_text()
+    # 5 expert layers in 2 bodies (the scan's, the module's) x 3 projections x (the first
+    # pass through the held buffer, the later ones), forward and recomputed
+    assert _kernels_named(compiled, "moe_gmm_fwd") == 2 * 3 * 2 * 2
+    _, table = profiling.program_ops_table(profiling._module_text(compiled))
+    scoped = [scopes for instances in table.values() for scopes, _, _ in instances]
+    assert any("attn.latent" in scopes and "attn.proj" in scopes and "mtp" not in scopes for scopes in scoped)
+    assert any({"mtp", "attn.latent"} <= set(scopes) for scopes in scoped)
+    assert any({"mtp", "head"} <= set(scopes) for scopes in scoped)
+    assert any({"mtp", "moe.experts"} <= set(scopes) for scopes in scoped)
+    assert not any("attn.latent" in scopes and "attn.proj" not in scopes for scopes in scoped)
+    # 706.5 M parameters x 12 bytes of state (the gradients are the step's own)
+    assert compiled.memory_analysis().argument_size_in_bytes / GIB == pytest.approx(7.90, abs=0.02)
+
