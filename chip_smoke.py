@@ -48,7 +48,7 @@ SEED = 0
 _KERNEL_NAME = re.compile(r'kernel_name = "(\w+)"')
 TRAIN_KERNELS = {
     "xla": set(),
-    "pallas": {"flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"},
+    "pallas": {"flash_fwd", "flash_bwd_dkv_dq"},
 }
 RAGGED_KERNEL_NAME = "ragged_paged_attention"
 GIB = float(1 << 30)

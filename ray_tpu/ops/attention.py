@@ -118,8 +118,8 @@ def mha_reference(
 # every pair is live, one whole unmasked piece (one softmax step a tile in
 # the forward); ON it the tile is what a one-tile call's is, walked in
 # sub-tiles or computed whole with a static mask, a kernel
-# (`_DIAGONAL_WALK`). The forward and dQ run a q tile's kv tiles 0..i, the
-# carry initialised at the first and written at the diagonal; dK/dV runs a
+# (`_DIAGONAL_WALK`). The forward runs a q tile's kv tiles 0..i, the carry
+# initialised at the first and written at the diagonal; the backward runs a
 # kv tile's q tiles j..nq - 1 from the diagonal down. `kv_len` is not
 # tested there: causality implies it on every real row, a padded row's
 # output is sliced off and its cotangent is zero.
@@ -147,7 +147,10 @@ def mha_reference(
 # (sub_q, sub_kv) of the walk. One chip sweep over {128, 256, 512} a side
 # and kernel, at D = 64 and D = 128 (PERF.md section 6, PR 26): 256 x 256
 # won, or lost by under 1%, in all three kernels at both widths, so the
-# size depends on nothing a call can observe but its block.
+# size depends on nothing a call can observe but its block. (The one backward
+# kernel on one tile a head, PR 45, 24 x 12 x 1,024 x 64 and 12 x 16 x 1,024 x
+# 128, ms a call: the parent's pair 4.04 and 1.96; 256 x 256 3.47 and 1.59,
+# 128 x 256 3.46 and 1.58, 256 x 512 3.55 and 1.65, 512 x 256 3.66 and 1.74.)
 _SUB_TILE = (256, 256)
 
 # Whether a kernel walks a causal grid's DIAGONAL tile in those sub-tiles (10 of
@@ -158,7 +161,15 @@ _SUB_TILE = (256, 256)
 # 18.25 / 17.61, 11.69 / 10.96, 3.68 / 3.31, the walk wins at all three; the
 # forward 15.62 / 16.30, 9.69 / 10.46, 2.85 / 3.24, it loses at all three (a
 # softmax step a 256-row strip, as in the windowed forward).
-_DIAGONAL_WALK = {"flash_fwd": False, "flash_bwd_dkv": True, "flash_bwd_dq": True}
+# The ONE backward kernel that took those two's place, swept again at the same
+# shapes, alternating with the parent's pair (PERF.md section 6, PR 45; ms a
+# call by the host's clock over 4 queued calls, the median of 5 rounds), the
+# pair / whole / walked: 39.81 / 29.71 / 28.91, 24.29 / 18.78 / 17.87, 6.91 /
+# 5.75 / 5.30: the walk still wins at all three, and the one kernel by 23-27%.
+# (Swept with delta = rowsum(dO O) still an XLA input; computed in the kernel,
+# a second sweep of the chosen table read 28.27, 17.11 and 4.94, the fusion
+# that wrote delta gone: -29%, -30%, -29% against the pair.)
+_DIAGONAL_WALK = {"flash_fwd": False, "flash_bwd_dkv_dq": True}
 
 # A head WIDER than 128 features (latent attention's 256): every q, k, v, do, o
 # and accumulator block of a tile is twice a 128-wide head's, and a 1,024 x 1,024
@@ -182,13 +193,22 @@ _DIAGONAL_WALK = {"flash_fwd": False, "flash_bwd_dkv": True, "flash_bwd_dq": Tru
 # this width, the forward too (at D = 128 it computes it whole: the masked
 # quarter of a tile is twice as many MXU passes here); 256 x 256 wins or loses
 # by under 2.6% (dK/dV at 128 x 128), so the sub-tile stays the narrow heads'.
+# (The one backward kernel since PR 45, at 1,024 and 256 x 256, the parent's
+# dK/dV + dQ / diagonal whole / walked: 28.40 / 22.03 / 20.73 ms a call.)
 # A call's head size is static, so the choice is one more rule on the shape a
 # call can observe, not an option. The forward then reads 72% of its roofline
 # (6.98 ms of required work a call).
 _WIDE_HEAD = {
     "tile": 1024, "vmem_limit_bytes": 64 * 1024 * 1024, "sub_tile": (256, 256),
-    "diagonal_walk": {"flash_fwd": True, "flash_bwd_dkv": True, "flash_bwd_dq": True},
+    "diagonal_walk": {"flash_fwd": True, "flash_bwd_dkv_dq": True},
 }
+
+
+# Scoped VMEM the compiler gives a kernel that asks for none, and what a v5e
+# has: the backward's resident dQ (`_resident_dq_bytes`) is asked for on top of
+# the first (or of a wide head's own), within the second.
+_SCOPED_VMEM_BYTES = 16 * 1024 * 1024
+_VMEM_BYTES = 128 * 1024 * 1024
 
 
 def _head_choices(head_dim: Optional[int] = None) -> dict:
@@ -220,10 +240,15 @@ def _head_choices(head_dim: Optional[int] = None) -> dict:
 # computed whole (dK/dV 0.69 / 0.77 and 0.43 / 0.48, dQ 0.64 / 0.62 and 0.48 /
 # 0.52): they skip the six dead sub-tiles of an edge tile, as on the causal
 # grid's diagonal (`_DIAGONAL_WALK`).
+# The ONE backward kernel (PR 45), all eight tables at the same two shapes,
+# alternating with the parent's pair, ms a call (W walked, o whole; diagonal,
+# interior, trailing):  pair    WWW    WWo    WoW    Woo    oWW    oWo    ooW    ooo
+#   window 4,096 of 16,384  19.74  15.56  16.11  14.71  15.28  16.35  16.88  15.51  16.07
+#   window 2,048 of 8,192   13.27  10.53  11.17  10.21  10.86  11.44  12.06  11.11  11.75
+# the same table wins (WoW): each class moves the call by what it did in dK/dV.
 _BAND_WALK = {
     "flash_fwd": {"diagonal": False, "interior": False, "trailing": False},
-    "flash_bwd_dkv": {"diagonal": True, "interior": False, "trailing": True},
-    "flash_bwd_dq": {"diagonal": True, "interior": False, "trailing": True},
+    "flash_bwd_dkv_dq": {"diagonal": True, "interior": False, "trailing": True},
 }
 
 # The share of a tile's sub-tiles that the walk visits on the tiles that sweep
@@ -378,13 +403,13 @@ def _band_tile_subtiles(kernel: str, d: int, band: int, block: int, sub_q: int, 
 
 def attention_subtiles(sq: int, skv: int, causal: bool, kv_len: int,
                        block_q: int, block_kv: int, sub_q: int, sub_kv: int,
-                       window: Optional[int] = None, kernel: str = "flash_bwd_dq",
+                       window: Optional[int] = None, kernel: str = "flash_bwd_dkv_dq",
                        head_dim: Optional[int] = None):
     """(visited, masked, total) sub-tiles of one head's (sq, skv) score
     matrix as the kernels walk it: `visited` run their matmuls, `masked`
     of those build the mask, `total` is what a dense walk would visit.
-    Counted with the kernels' own loop bounds. The three kernels walk alike
-    but for a causal grid's diagonal tiles (`_DIAGONAL_WALK`, or a wide
+    Counted with the kernels' own loop bounds. The forward and the backward
+    kernel walk alike but for a causal grid's diagonal tiles (`_DIAGONAL_WALK`, or a wide
     `head_dim`'s table) and a band's tiles by class (`_BAND_WALK`), which
     `kernel` decides: a tile computed whole visits all its sub-tiles, and masks
     all or (interior) none."""
@@ -482,6 +507,15 @@ def _grid_tile(num_q_blocks, num_kv_blocks, q_axis, kv_axis):
     return i, j
 
 
+def _when(condition, run):
+    """`run()` under a condition that is static (an axis of one block) or traced."""
+    if isinstance(condition, bool):
+        if condition:
+            run()
+    else:
+        pl.when(condition)(run)
+
+
 def _band_tiles(kernel, band, window, block, step, tile, tiles, toward_diagonal, strips):
     """A windowed call's grid step: `strips(d, 0, *piece)` for the one tile of
     the band this step holds, as the q tile d and kv tile 0 of a head, its
@@ -490,10 +524,10 @@ def _band_tiles(kernel, band, window, block, step, tile, tiles, toward_diagonal,
     `piece` is the whole tile or the walk's sub-tile, by the tile's class and
     `_BAND_WALK`; the interior tiles share ONE body, traced at d = 1 (every
     pair live at any of their offsets), the others have one each. `tile` is
-    the fixed side's tile (a q tile for the forward and dQ, whose band runs
+    the fixed side's tile (a q tile for the forward, whose band runs
     `toward_diagonal` over kv tiles tile - band + 1 .. tile; a kv tile for
-    dK/dV, whose band runs away from it over q tiles tile .. tile + band -
-    1), `step` the band's grid axis. A step whose tile falls off the
+    the backward, whose band runs away from it over q tiles tile .. tile +
+    band - 1), `step` the band's grid axis. A step whose tile falls off the
     sequence (before its start, or after the last of `tiles`) does nothing;
     its index map repeats a neighbour's block, so nothing is copied either."""
     bodies = {}     # (the offset a body is traced at, its piece) -> when it runs
@@ -507,11 +541,7 @@ def _band_tiles(kernel, band, window, block, step, tile, tiles, toward_diagonal,
                 _sub_tiles(block, block, 1) if walked else (block, block))
         bodies[body] = bodies[body] | here if body in bodies else here
     for (d, piece), here in bodies.items():
-        run = functools.partial(strips, d, 0, *piece)
-        if here is True:
-            run()
-        elif here is not False:
-            pl.when(here)(run)
+        _when(here, functools.partial(strips, d, 0, *piece))
 
 
 def _live_grid(causal, window, block_q, block_kv, num_q_blocks, num_kv_blocks) -> bool:
@@ -525,8 +555,8 @@ def _triangle(tiles: int, by_kv: bool):
     """The steps of a causal grid's one tile axis, as the (q tile, kv tile)
     tables its index maps and kernels read (scalar prefetch): the lower
     triangle row by row, kv tiles 0..i of q tile i with the diagonal last
-    (forward, dQ), or `by_kv` column by column, q tiles j..tiles - 1 of kv tile
-    j with the diagonal first (dK/dV)."""
+    (forward), or `by_kv` column by column, q tiles j..tiles - 1 of kv tile
+    j with the diagonal first (backward)."""
     pairs = ([(i, j) for j in range(tiles) for i in range(j, tiles)] if by_kv
              else [(i, j) for i in range(tiles) for j in range(i + 1)])
     q_tiles, kv_tiles = zip(*pairs)
@@ -712,12 +742,16 @@ def _band_index(band: int, toward_diagonal: bool, tiles: int):
     return lambda j, t: jnp.minimum(j + t, tiles - 1)
 
 
-def _grid_call(kernel, tables, grid, in_specs, out_specs, scratch_shapes, head_dim, **how):
+def _grid_call(kernel, tables, grid, in_specs, out_specs, scratch_shapes, head_dim,
+               resident_bytes=0, **how):
     """`pl.pallas_call` over `grid`; with a causal grid's `tables` as its
     scalar prefetch, which every index map and the kernel then take too; with
-    the scoped VMEM a wide head's choices ask for."""
+    the scoped VMEM a wide head's choices ask for, and `resident_bytes` (the
+    backward's dQ of a head) on top of that or of the compiler's own."""
     spec = dict(grid=grid, in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch_shapes)
     vmem_limit_bytes = _head_choices(head_dim)["vmem_limit_bytes"]
+    if resident_bytes:
+        vmem_limit_bytes = (vmem_limit_bytes or _SCOPED_VMEM_BYTES) + resident_bytes
     if vmem_limit_bytes:
         how["compiler_params"] = pltpu.CompilerParams(vmem_limit_bytes=vmem_limit_bytes)
     if tables:
@@ -790,14 +824,43 @@ def _fwd_pallas(q, k, v, causal, sm_scale, block_q, block_kv, kv_len, interpret,
 
 # ------------------------------------------------------------- pallas backward
 #
-# Standard flash backward (Dao et al. alg. 2), two kernels:
-#   dkv kernel: grid kv-outer / q-inner, accumulates dK_j, dV_j across q blocks
-#   dq  kernel: grid q-outer / kv-inner, accumulates dQ_i across kv blocks
-# P is recomputed from (q, k, lse); delta = rowsum(dO * O) is cheap in XLA.
+# Flash backward (Dao et al. alg. 2) as ONE kernel, grid kv-outer / q-inner:
+# a tile's scores, probabilities and dS are built once and feed all three
+# gradients. dK_j and dV_j sum over the q blocks of a kv block in scratch, as
+# a tile's worth of rows. dQ_i gets a term from EVERY kv block, and a TPU
+# kernel has no atomic add: the head's whole dQ is a float32 scratch of
+# (sq, d) that stays in VMEM from the head's first grid step to its last
+# (8 MiB at 16,384 x 128 and at 8,192 x 256 of a v5e's 128: `_resident_dq_bytes`),
+# zeroed at the first, and a q tile's rows leave it in q's dtype at the step
+# that adds their last term (`_write_dq`), through an output block whose index
+# map names that tile. kv tiles reach a q tile in ascending order, so dQ sums
+# as a q-major kernel would.
+# P is recomputed from (q, k, lse), and delta = rowsum(dO * O) from the rows of
+# dO and O a piece holds: as a (B, H, S, 1) float32 input it is laid out a lane
+# of 128 a row, 235 MB a call at 28 x 16,384 that XLA writes and the kernel
+# reads back a tile at a time (one alternating chip sweep at the eight shapes
+# of the tables above, PERF.md section 6, PR 45, ms a call with delta an input /
+# in the kernel: 28.98 / 28.27, 14.72 / 14.07, 17.89 / 17.11, 10.20 / 9.45, D =
+# 256 20.76 / 20.04, 4 x 16 x 4,096 5.34 / 4.94, one tile 3.49 / 3.37 and 1.60 /
+# 1.32; the step's peak falls by that array).
 # GQA is handled in the wrapper (repeat kv, then segment-sum dk/dv) — the
-# kernels always see Hq == Hkv. Inside a grid tile both walk sub-tiles as
-# the forward does: dkv per kv sub-tile over the q sub-blocks from the
-# diagonal down, dq per q sub-block over the kv sub-tiles up to it.
+# kernel always sees Hq == Hkv. Inside a grid tile it walks sub-tiles as the
+# forward does, per kv sub-tile over the q sub-blocks from the diagonal down.
+
+
+def _resident_dq_bytes(sq: int, head_dim: int) -> int:
+    """Bytes of the float32 dQ the backward kernel keeps in VMEM for a head of
+    `sq` (padded) queries. A head it cannot keep beside a tile's blocks (64 k
+    tokens at D = 256, 256 k at 128) is refused here, by name: no model, cell
+    or test of the repo is within a factor of 4 of it."""
+    resident = sq * head_dim * 4
+    tile_bytes = _head_choices(head_dim)["vmem_limit_bytes"] or _SCOPED_VMEM_BYTES
+    if tile_bytes + resident >= _VMEM_BYTES:
+        raise ValueError(
+            f"flash attention's backward keeps a head's float32 dQ in VMEM: {sq} queries x "
+            f"{head_dim} features are {resident} bytes, which with the {tile_bytes} of a tile's "
+            f"blocks is all of the chip's {_VMEM_BYTES} or more; shard the sequence (ring attention)")
+    return resident
 
 
 def _probs(q, k, lse, sm_scale, mask_at, causal, kv_len, window=None):
@@ -806,11 +869,12 @@ def _probs(q, k, lse, sm_scale, mask_at, causal, kv_len, window=None):
     return jnp.exp2(s - lse * _LOG2E)
 
 
-def _ds(p, do, v, delta, sm_scale):
-    """dP = dO V^T ; dS = P * (dP - delta)."""
+def _ds(p, do, v, o, sm_scale):
+    """dP = dO V^T ; dS = P * (dP - delta), delta = rowsum(dO * O)."""
     dp = jax.lax.dot_general(
         do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1, keepdims=True)
     return p * (dp - delta) * sm_scale
 
 
@@ -828,13 +892,13 @@ def _accumulate(out_ref, scr, carried, where, value):
         out_ref[0, 0, where, :] = value.astype(out_ref.dtype)
 
 
-def _dkv_kernel(
+def _bwd_kernel(
     *refs,
     sm_scale, causal, block_q, block_kv, sub_q, sub_kv, kv_len,
     num_q_blocks, num_kv_blocks, window=None, band=0, triangle=False,
 ):
-    tiles, (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-            dk_ref, dv_ref, *scratch) = refs[:2 * triangle], refs[2 * triangle:]
+    tiles, (q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
+            dk_ref, dv_ref, dq_ref, dq_scr, *scratch) = refs[:2 * triangle], refs[2 * triangle:]
     # grid: kv block outer (axis 2), q block inner (axis 3): the sequence's q
     # blocks, or a windowed call's band of them; a causal grid's one tile
     # axis runs a kv block's q blocks from the diagonal down
@@ -848,6 +912,18 @@ def _dkv_kernel(
             dv_scr[...] = jnp.zeros_like(dv_scr)
 
         pl.when(i == (j if triangle else 0))(_init)
+
+    # the head's dQ: zero at the head's first step; `dq_rows` are this step's q
+    # tile's in it (a band's step is the tile's offset below the diagonal tile j)
+    def _init_dq():
+        dq_scr[...] = jnp.zeros_like(dq_scr)
+
+    _when((i == 0) & (j == 0), _init_dq)
+    q_row0 = (j + i if band else i) * block_q
+
+    def dq_rows(first, size):
+        start = q_row0 + first
+        return pl.ds(start if _static(start) else pl.multiple_of(start, sub_q), size)
 
     def strips(i, j, sub_q=sub_q, sub_kv=sub_kv):
         for c in range(block_kv // sub_kv):
@@ -871,12 +947,13 @@ def _dkv_kernel(
                         p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
                         preferred_element_type=jnp.float32,
                     )
-                    ds = _ds(p, do, v, delta_ref[0, 0, rows, :], sm_scale)
+                    ds = _ds(p, do, v, o_ref[0, 0, rows, :], sm_scale).astype(q.dtype)
                     # dK_j += dS^T Q
                     dk_cur = jax.lax.dot_general(
-                        ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32,
-                    )
+                        ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+                    # dQ_i += dS K_j, into the head's rows
+                    dq_scr[dq_rows(lo * sub_q, rows.size), :] += jax.lax.dot_general(
+                        ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
                     dv = dv_cur if dv is None else dv + dv_cur
                     dk = dk_cur if dk is None else dk + dk_cur
                 _accumulate(dv_ref, dv_scr, carried, cols, dv)
@@ -887,9 +964,9 @@ def _dkv_kernel(
 
     if band:
         # from the diagonal tile down: q tile j + t of kv tile j
-        _band_tiles("flash_bwd_dkv", band, window, block_q, i, j, num_q_blocks, False, strips)
+        _band_tiles("flash_bwd_dkv_dq", band, window, block_q, i, j, num_q_blocks, False, strips)
     elif triangle:
-        _triangle_classes("flash_bwd_dkv", i, j, block_q, block_kv, strips, q_ref.shape[-1])
+        _triangle_classes("flash_bwd_dkv_dq", i, j, block_q, block_kv, strips, q_ref.shape[-1])
     else:
         strips(i, j)
 
@@ -900,67 +977,13 @@ def _dkv_kernel(
 
         pl.when(i == q_steps - 1)(_finalize)
 
+    # a q tile's dQ is whole when its last kv tile has added to it: the diagonal
+    # tile, a kv tile's FIRST step on a causal grid and on a band; the last kv
+    # tile of a dense grid. `_bwd_pallas` maps the dQ block to that q tile.
+    def _write_dq():
+        dq_ref[0, 0] = dq_scr[dq_rows(0, block_q), :].astype(dq_ref.dtype)
 
-def _dq_kernel(
-    *refs,
-    sm_scale, causal, block_q, block_kv, sub_q, sub_kv, kv_len,
-    num_q_blocks, num_kv_blocks, window=None, band=0, triangle=False,
-):
-    tiles, (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-            dq_ref, *scratch) = refs[:2 * triangle], refs[2 * triangle:]
-    # grid: q block outer (axis 2), kv block inner (axis 3); a causal grid's
-    # one tile axis runs a q block's kv blocks up to the diagonal
-    kv_steps = band or num_kv_blocks
-    i, j = _triangle_tile(*tiles) if triangle else _grid_tile(num_q_blocks, kv_steps, 2, 3)
-    carried = kv_steps > 1  # dQ_i summed over kv blocks in scratch
-    (dq_scr,) = scratch if carried else (None,)
-    if carried:
-        def _init():
-            dq_scr[...] = jnp.zeros_like(dq_scr)
-
-        pl.when(j == 0)(_init)
-
-    def strips(i, j, sub_q=sub_q, sub_kv=sub_kv):
-        for a in range(block_q // sub_q):
-            row0 = i * block_q + a * sub_q
-            rows = pl.ds(a * sub_q, sub_q)
-
-            def visit(pieces, row0=row0, rows=rows):
-                q = q_ref[0, 0, rows, :]
-                do = do_ref[0, 0, rows, :]
-                lse = lse_ref[0, 0, rows, :]  # (sub_q, 1)
-                delta = delta_ref[0, 0, rows, :]
-                dq = None
-                for lo, hi, masked in pieces:
-                    cols = pl.ds(lo * sub_kv, (hi - lo) * sub_kv)
-                    k = k_ref[0, 0, cols, :]
-                    p = _probs(
-                        q, k, lse, sm_scale,
-                        (row0, j * block_kv + lo * sub_kv) if masked else None,
-                        causal, kv_len, window)
-                    ds = _ds(p, do, v_ref[0, 0, cols, :], delta, sm_scale)
-                    dq_cur = jax.lax.dot_general(
-                        ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32,
-                    )
-                    dq = dq_cur if dq is None else dq + dq_cur
-                _accumulate(dq_ref, dq_scr, carried, rows, dq)
-
-            _walk_strip(row0, j * block_kv, block_kv // sub_kv, sub_q, sub_kv, causal,
-                        kv_len, "kv", visit, always=not carried, window=window)
-
-    if band:
-        _band_tiles("flash_bwd_dq", band, window, block_q, j, i, num_q_blocks, True, strips)
-    elif triangle:
-        _triangle_classes("flash_bwd_dq", i, j, block_q, block_kv, strips, q_ref.shape[-1])
-    else:
-        strips(i, j)
-
-    if carried:
-        def _finalize():
-            dq_ref[0, 0] = dq_scr[...].astype(dq_ref.dtype)
-
-        pl.when(j == (i if triangle else kv_steps - 1))(_finalize)
+    _when((i == j) if triangle else (i == 0) if band else (j == num_kv_blocks - 1), _write_dq)
 
 
 def _bwd_pallas(q, k, v, out, lse, do, causal, sm_scale, block_q, block_kv, kv_len, interpret,
@@ -970,74 +993,55 @@ def _bwd_pallas(q, k, v, out, lse, do, causal, sm_scale, block_q, block_kv, kv_l
     nq = sq // block_q
     nk = skv // block_kv
 
-    # (b, h, sq, 1): the trailing singleton keeps row blocks 2D for Mosaic
-    delta = jnp.sum(
-        do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1, keepdims=True
-    )
-
     band = window_band(window, block_q, nk) if window is not None else 0
     sub_q, sub_kv = _sub_tiles(block_q, block_kv, 1 if band else nq * nk, d)
     q_tile = _band_index(band, False, nq) if band else (lambda j, i: i)
-    kv_tile = _band_index(band, True, nk) if band else (lambda i, j: j)
 
-    def kernel(fn):
-        return functools.partial(
-            fn, sm_scale=sm_scale, causal=causal, block_q=block_q,
-            block_kv=block_kv, sub_q=sub_q, sub_kv=sub_kv,
-            kv_len=None if band or triangle else kv_len,
-            num_q_blocks=nq, num_kv_blocks=nk,
-            **({"window": window, "band": band} if band else {}),
-            triangle=triangle,
-        )
-
-    def specs(q_at, kv_at):
-        q_spec = pl.BlockSpec((1, 1, block_q, d), q_at)
-        kv_spec = pl.BlockSpec((1, 1, block_kv, d), kv_at)
-        row_spec = pl.BlockSpec((1, 1, block_q, 1), q_at)
-        return q_spec, kv_spec, [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec]
-
+    kernel = functools.partial(
+        _bwd_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
+        block_kv=block_kv, sub_q=sub_q, sub_kv=sub_kv,
+        kv_len=None if band or triangle else kv_len,
+        num_q_blocks=nq, num_kv_blocks=nk,
+        **({"window": window, "band": band} if band else {}),
+        triangle=triangle,
+    )
+    # dQ leaves by q tile, at the step that completes it (`_write_dq`): on a
+    # causal grid and a band (square tiles) that step is kv tile j's first and
+    # the q tile is j; on a dense grid the last kv tile completes every q tile
     if triangle:
-        by_kv, by_q = _triangle(nq, by_kv=True), _triangle(nq, by_kv=False)
-        dkv_grid, dq_grid = (b, h, len(by_kv[0])), (b, h, len(by_q[0]))
-        q_at = q_at2 = lambda b_, h_, t, qt, kt: (b_, h_, qt[t], 0)  # noqa: E731
-        kv_at = kv_at2 = lambda b_, h_, t, qt, kt: (b_, h_, kt[t], 0)  # noqa: E731
+        tables = _triangle(nq, by_kv=True)
+        grid = (b, h, len(tables[0]))
+        q_at = lambda b_, h_, t, qt, kt: (b_, h_, qt[t], 0)  # noqa: E731
+        kv_at = dq_at = lambda b_, h_, t, qt, kt: (b_, h_, kt[t], 0)  # noqa: E731
     else:
-        by_kv = by_q = ()
-        dkv_grid, dq_grid = (b, h, nk, band or nq), (b, h, nq, band or nk)
+        tables = ()
+        grid = (b, h, nk, band or nq)
         q_at = lambda b_, h_, j, i: (b_, h_, q_tile(j, i), 0)  # noqa: E731
         kv_at = lambda b_, h_, j, i: (b_, h_, j, 0)  # noqa: E731
-        q_at2 = lambda b_, h_, i, j: (b_, h_, i, 0)  # noqa: E731
-        kv_at2 = lambda b_, h_, i, j: (b_, h_, kv_tile(i, j), 0)  # noqa: E731
-
-    _, kv_spec, in_specs = specs(q_at, kv_at)
-    dk, dv = _grid_call(
-        kernel(_dkv_kernel), by_kv, dkv_grid,
-        in_specs=in_specs,
-        out_specs=[kv_spec, kv_spec],
-        scratch_shapes=[
+        dq_at = kv_at if band else (
+            lambda b_, h_, j, i: (b_, h_, jnp.where(j == nk - 1, i, 0), 0))  # noqa: E731
+    q_spec = pl.BlockSpec((1, 1, block_q, d), q_at)
+    kv_spec = pl.BlockSpec((1, 1, block_kv, d), kv_at)
+    # (b, h, sq, 1): the trailing singleton keeps the lse's row blocks 2D for Mosaic
+    row_spec = pl.BlockSpec((1, 1, block_q, 1), q_at)
+    dk, dv, dq = _grid_call(
+        kernel, tables, grid,
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, q_spec],
+        out_specs=[kv_spec, kv_spec, pl.BlockSpec((1, 1, block_q, d), dq_at)],
+        scratch_shapes=[pltpu.VMEM((sq, d), jnp.float32)] + ([
             pltpu.VMEM((block_kv, d), jnp.float32),
             pltpu.VMEM((block_kv, d), jnp.float32),
-        ] if (band or nq) > 1 else [],
+        ] if (band or nq) > 1 else []),
         head_dim=d,
+        resident_bytes=_resident_dq_bytes(sq, d),
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
         ],
         interpret=interpret,
-        name="flash_win_bwd_dkv" if band else "flash_bwd_dkv",
-    )(*by_kv, q, k, v, do, lse, delta)
-
-    q_spec2, _, in_specs2 = specs(q_at2, kv_at2)
-    dq = _grid_call(
-        kernel(_dq_kernel), by_q, dq_grid,
-        in_specs=in_specs2,
-        out_specs=q_spec2,
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)] if (band or nk) > 1 else [],
-        head_dim=d,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
-        name="flash_win_bwd_dq" if band else "flash_bwd_dq",
-    )(*by_q, q, k, v, do, lse, delta)
+        name="flash_win_bwd_dkv_dq" if band else "flash_bwd_dkv_dq",
+    )(*tables, q, k, v, do, lse, out)
     return dq, dk, dv
 
 
@@ -1154,11 +1158,13 @@ def attention_plan(seq: int, *, causal: bool = True,
                    implementation: Optional[str] = None,
                    window: Optional[int] = None, head_dim: Optional[int] = None) -> dict:
     """What `flash_attention` runs for one head of a (seq, seq)
-    self-attention: the resolved implementation and how far the kernels'
-    sub-tile walk engages (no sub-tiles for "xla"; the sub-tiles as dQ counts
-    them, `attention_subtiles`).
+    self-attention: the resolved implementation, how far the kernels'
+    sub-tile walk engages (no sub-tiles for "xla"; the sub-tiles as the
+    backward kernel counts them, `attention_subtiles`) and how many kernels
+    the backward of a call is (`attn_bwd_kernels`: 1, `flash_bwd_dkv_dq`).
     With the `head_dim` of the call, also what was chosen by it: the grid
-    tile's side and the sub-tile (`_head_choices`).
+    tile's side, the sub-tile (`_head_choices`) and the bytes of a head's
+    float32 dQ that the backward kernel keeps in VMEM (`_resident_dq_bytes`).
     For callers that report it: LMTrainer's `train.init.step_fn` span,
     chip_smoke.py."""
     impl = resolve_attention_impl(implementation)
@@ -1171,7 +1177,8 @@ def attention_plan(seq: int, *, causal: bool = True,
         # a band's and a causal grid's tiles lie at static offsets: walked
         static = window is not None or _live_grid(causal, window, bq, bkv, nq, nk)
         if head_dim is not None:
-            chosen = {"attn_tile": bq, "attn_subtile": "{}x{}".format(*_sub_tiles(bq, bkv, 1, head_dim))}
+            chosen = {"attn_tile": bq, "attn_subtile": "{}x{}".format(*_sub_tiles(bq, bkv, 1, head_dim)),
+                      "attn_bwd_resident_bytes": _resident_dq_bytes(padded_q, head_dim)}
         visited, masked, total = attention_subtiles(
             padded_q, padded_kv, causal, seq, bq, bkv,
             *_sub_tiles(bq, bkv, 1 if static else nq * nk, head_dim), window, head_dim=head_dim)
@@ -1186,6 +1193,7 @@ def attention_plan(seq: int, *, causal: bool = True,
         "attn_subtiles_total": total,
         "attn_grid_steps": steps,
         "attn_grid_steps_live": live,
+        "attn_bwd_kernels": int(impl != "xla"),
         # a windowed call's band tiles a head that the forward computes as one
         # piece (`_BAND_WALK`), of its `attn_grid_steps_live`
         **({} if window is None else {"attn_window_tiles_whole": whole}),
@@ -1271,6 +1279,13 @@ def flash_attention(
     the diagonal tile and the one the window's lower edge crosses are whole
     with that edge's static mask or walked in sub-tiles, a kernel
     (`_BAND_WALK`; PERF.md section 6, PR 43).
+
+    The backward of a call is ONE kernel on any of those grids
+    (`flash_bwd_dkv_dq`; PERF.md section 6, PR 45): a tile's scores,
+    probabilities and dS are built once for dK, dV and dQ, and a head's dQ
+    sums over kv tiles in a float32 scratch of its whole q sequence that
+    stays in VMEM for the head (`_resident_dq_bytes`, which refuses a head
+    that cannot: 64 k tokens at D = 256).
     """
     return _attend(q, k, v, False, causal, window, sm_scale, block_q, block_kv, implementation)
 

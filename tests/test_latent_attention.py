@@ -118,13 +118,17 @@ def test_the_plan_reports_what_a_wide_head_chose():
     assert (wide["attn_tile"], wide["attn_subtile"]) == (1024, "256x256")
     assert wide["attn_grid_steps"] == wide["attn_grid_steps_live"] == 8 * 9 // 2
     narrow = flash.attention_plan(8192, implementation="pallas", head_dim=128)
-    assert narrow == wide        # the same tile and sub-tile; dQ walks the diagonal at both widths
+    # the same tile and sub-tile, and the backward walks the diagonal at both widths; a
+    # head's resident dQ is twice a narrow head's
+    assert narrow == {**wide, "attn_bwd_resident_bytes": 8192 * 128 * 4}
+    assert wide["attn_bwd_resident_bytes"] == 8192 * 256 * 4 and wide["attn_bwd_kernels"] == 1
     # the forward walks the diagonal tile of a wide head and computes a narrow head's whole
     counts = {d: flash.attention_subtiles(8192, 8192, True, 8192, 1024, 1024, 256, 256,
                                           kernel="flash_fwd", head_dim=d) for d in (128, 256)}
     assert counts[256] == (28 * 16 + 8 * 10, 8 * 4, 1024) and counts[128] == (36 * 16, 8 * 16, 1024)
     # without the head size the plan is what it was, key for key
-    assert set(flash.attention_plan(8192, implementation="pallas")) == set(narrow) - {"attn_tile", "attn_subtile"}
+    assert set(flash.attention_plan(8192, implementation="pallas")) == set(narrow) - {
+        "attn_tile", "attn_subtile", "attn_bwd_resident_bytes"}
 
 
 # --------------------------------------------------- the layer, block and model

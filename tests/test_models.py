@@ -303,7 +303,7 @@ def test_the_forward_kernel_is_not_run_again_in_the_backward_pass_when_output_an
         grad = jax.grad(lambda p: lm_loss(p, tokens, config, remat_saved=saved)[0])
         jaxpr = jax.make_jaxpr(grad)(params).jaxpr
         return ({name: _kernel_calls(jaxpr, name) for name in kernels},
-                {name: _kernel_calls(jaxpr, name.replace("fwd", "bwd_dq")) for name in kernels})
+                {name: _kernel_calls(jaxpr, name.replace("fwd", "bwd_dkv_dq")) for name in kernels})
 
     once, twice = kernels, {name: 2 * n for name, n in kernels.items()}
     assert calls(config.replace(remat=False)) == (once, once)

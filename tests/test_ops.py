@@ -175,11 +175,11 @@ def test_causal_grid_matches_reference_and_dense_grid(monkeypatch, case):
     monkeypatch.setattr(A.pl, "pallas_call", lambda *a, **kw: (
         names.append((kw["name"], "grid_spec" in kw)), call(*a, **kw))[1])
     live = both(kernels)
-    assert set(names) == {("flash_fwd", True), ("flash_bwd_dkv", True), ("flash_bwd_dq", True)}
+    assert set(names) == {("flash_fwd", True), ("flash_bwd_dkv_dq", True)}
     names.clear()
     monkeypatch.setattr(A, "_live_grid", lambda *a: False)
     dense = both(kernels)
-    assert set(names) == {("flash_fwd", False), ("flash_bwd_dkv", False), ("flash_bwd_dq", False)}
+    assert set(names) == {("flash_fwd", False), ("flash_bwd_dkv_dq", False)}
     reference = both(lambda q, k, v: mha_reference(q, k, v, causal=True))
     for got, was, want, tol in zip(live, dense, reference, (2e-5, 1e-3, 1e-3, 1e-3)):
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=tol, rtol=tol)
@@ -187,6 +187,69 @@ def test_causal_grid_matches_reference_and_dense_grid(monkeypatch, case):
     # the forward sums in the dense grid's order: its values are the parent's,
     # to what the interpreter's own fusions round
     np.testing.assert_allclose(np.asarray(live[0]), np.asarray(dense[0]), atol=1e-6, rtol=0)
+
+
+# The one backward kernel on each grid it serves: (b, hq, hkv, s, d, causal, window, kv_len)
+_BACKWARD_CASES = {
+    "one-tile-s1024-d64": (1, 2, 2, 1024, 64, True, None, None),
+    "causal-grid-s4096": (1, 1, 1, 4096, 32, True, None, None),
+    "band-window-2048-of-8192": (1, 1, 1, 8192, 32, True, 2048, None),
+    "grouped-32-over-4": (1, 32, 4, 2048, 32, True, None, None),
+    "wide-head-d256": (1, 1, 1, 2048, 256, True, None, None),
+    "dense-full-grid2x2": (1, 2, 1, 2048, 32, False, None, None),
+    "one-tile-kv-len-700": (1, 1, 1, 1024, 64, False, None, 700),
+    "dense-grid-kv-len-700": (1, 1, 1, 2048, 32, False, None, 700),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_BACKWARD_CASES))
+def test_one_backward_kernel_matches_reference(monkeypatch, case):
+    """`flash_bwd_dkv_dq` (a windowed call's `flash_win_bwd_dkv_dq`): ONE
+    `pallas_call` a call of attention gives dQ, dK and dV, a head's dQ summed
+    over kv tiles in a float32 scratch of the whole q sequence, against the
+    gradients of `mha_reference`: one tile a head, the causal grid of live
+    tiles, a band, grouped queries, a head of 256, a dense grid, and a `kv_len`
+    edge inside a tile and between the tiles of a dense grid."""
+    from ray_tpu.ops import attention as A
+
+    b, hq, hkv, s, d, causal, window, kv_len = _BACKWARD_CASES[case]
+    keys = jax.random.split(jax.random.PRNGKey(45), 4)
+    q, do = (_rand(key, (b, hq, s, d)) for key in (keys[0], keys[3]))
+    k, v = (_rand(key, (b, hkv, s, d)) for key in keys[1:3])
+    names = []
+    call = A.pl.pallas_call
+    monkeypatch.setattr(A.pl, "pallas_call", lambda *a, **kw: (names.append(kw["name"]), call(*a, **kw))[1])
+    scale = d ** -0.5
+    if kv_len is None:
+        def ours(q, k, v):
+            return flash_attention(q, k, v, causal=causal, window=window, implementation="pallas")
+        got = jax.vjp(ours, q, k, v)[1](do)
+    else:
+        block = min(s, 1024)
+        out, lse = A._fwd_pallas(q, k, v, causal, scale, block, block, kv_len, True)
+        got = A._bwd_pallas(q, k, v, out, lse, do, causal, scale, block, block, kv_len, True)
+    backward = "flash_win_bwd_dkv_dq" if window else "flash_bwd_dkv_dq"
+    assert sorted(names) == sorted(["flash_win_fwd" if window else "flash_fwd", backward])
+    want = jax.vjp(lambda q, k, v: mha_reference(
+        q, k, v, causal=causal, window=window, kv_len=kv_len), q, k, v)[1](do)
+    for name, g, r in zip("qkv", got, want):
+        assert g.shape == r.shape and g.dtype == r.dtype
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=2e-5 * float(jnp.max(jnp.abs(r))),
+                                   err_msg=f"d{name}")
+
+
+def test_a_head_whose_dq_does_not_fit_is_refused_by_name():
+    """The backward keeps a head's float32 dQ in VMEM beside a tile's blocks:
+    64 k tokens at D = 256 are past the chip's 128 MiB, and say so."""
+    from ray_tpu.ops.attention import _resident_dq_bytes, attention_plan
+
+    assert _resident_dq_bytes(32768, 256) == 32 * 1024 * 1024
+    assert _resident_dq_bytes(131072, 128) == 64 * 1024 * 1024
+    for seq, head_dim in ((65536, 256), (262144, 128)):
+        with pytest.raises(ValueError, match="dQ in VMEM"):
+            _resident_dq_bytes(seq, head_dim)
+        with pytest.raises(ValueError, match="dQ in VMEM"):
+            attention_plan(seq, implementation="pallas", head_dim=head_dim)
 
 
 def test_flash_rows_no_subtile_reaches():
@@ -238,27 +301,28 @@ def test_flash_rows_no_subtile_reaches():
      ((2048, 2048, True, 2048, 512, 512, 256, 256), (36, 8, 64)),
      # ... but by the forward, which computes it whole and masked
      ((16384, 16384, True, 16384, 1024, 1024, 256, 256, None, "flash_fwd"), (2176, 256, 4096)),
-     ((4096, 4096, True, 4096, 1024, 1024, 256, 256, None, "flash_bwd_dkv"), (136, 16, 256)),
+     ((4096, 4096, True, 4096, 1024, 1024, 256, 256, None, "flash_bwd_dkv_dq"), (136, 16, 256)),
      # not causal, or not square: the dense grid, every live tile masked
      ((2048, 2048, False, 2048, 1024, 1024, 1024, 1024), (4, 4, 4)),
      ((2048, 2048, True, 2048, 1024, 512, 1024, 512), (6, 6, 8)),
      # a band of 5 at window 4,096 (16 diagonal + 42 interior + 12 trailing tiles
-     # a head): the backward kernels walk the edge tiles (10 of 16, 4 masked) ...
-     ((16384, 16384, True, 16384, 1024, 1024, 256, 256, 4096, "flash_bwd_dkv"),
+     # a head): the backward kernel walks the edge tiles (10 of 16, 4 masked) ...
+     ((16384, 16384, True, 16384, 1024, 1024, 256, 256, 4096, "flash_bwd_dkv_dq"),
       (28 * 10 + 42 * 16, 28 * 4, 4096)),
-     ((16384, 16384, True, 16384, 1024, 1024, 256, 256, 4096, "flash_bwd_dq"), (952, 112, 4096)),
+     # ... and is the kernel that is counted where none is named
+     ((16384, 16384, True, 16384, 1024, 1024, 256, 256, 4096), (952, 112, 4096)),
      # ... the forward computes them whole: 16 visited and 16 masked a tile
      ((16384, 16384, True, 16384, 1024, 1024, 256, 256, 4096, "flash_fwd"),
       (70 * 16, 28 * 16, 4096)),
      # a band of 3 at window 2,048: 8 + 7 + 6
      ((8192, 8192, True, 8192, 1024, 1024, 256, 256, 2048, "flash_fwd"), (21 * 16, 14 * 16, 1024)),
-     ((8192, 8192, True, 8192, 1024, 1024, 256, 256, 2048, "flash_bwd_dkv"),
+     ((8192, 8192, True, 8192, 1024, 1024, 256, 256, 2048, "flash_bwd_dkv_dq"),
       (14 * 10 + 7 * 16, 14 * 4, 1024))],
     ids=["causal-256", "causal-128", "causal-512", "full-256", "grid2x2",
          "kv-edge", "rectangular", "small-blocks", "causal-grid-16k", "causal-grid-8k",
          "causal-grid-4k", "causal-grid-padded", "causal-grid-block512", "causal-grid-16k-forward",
-         "causal-grid-4k-dkv", "full-grid2x2", "causal-rectangular-grid", "band5-16k-dkv",
-         "band5-16k-dq", "band5-16k-forward", "band3-8k-forward", "band3-8k-dkv"],
+         "causal-grid-4k-backward", "full-grid2x2", "causal-rectangular-grid", "band5-16k-backward",
+         "band5-16k-default", "band5-16k-forward", "band3-8k-forward", "band3-8k-backward"],
 )
 def test_attention_subtiles_counts(args, expect):
     from ray_tpu.ops.attention import attention_subtiles
@@ -296,12 +360,18 @@ def test_attention_plan_names_what_runs(monkeypatch):
     assert attention_plan(1024) == {
         "attention_impl": "xla", "attn_subtiles_visited": 0,
         "attn_subtiles_masked": 0, "attn_subtiles_total": 0,
-        "attn_grid_steps": 0, "attn_grid_steps_live": 0}
+        "attn_grid_steps": 0, "attn_grid_steps_live": 0, "attn_bwd_kernels": 0}
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert attention_plan(1024) == {
         "attention_impl": "pallas", "attn_subtiles_visited": 10,
         "attn_subtiles_masked": 4, "attn_subtiles_total": 16,
-        "attn_grid_steps": 1, "attn_grid_steps_live": 1}
+        "attn_grid_steps": 1, "attn_grid_steps_live": 1, "attn_bwd_kernels": 1}
+    # the one backward kernel keeps a head's float32 dQ in VMEM: 256 KiB at the
+    # two one-tile cells' gpt2 heads, 8 MiB at SmallThinker's and at GLM's
+    assert attention_plan(1024, head_dim=64)["attn_bwd_resident_bytes"] == 256 * 1024
+    assert (attention_plan(16384, head_dim=128)["attn_bwd_resident_bytes"]
+            == attention_plan(8192, head_dim=256)["attn_bwd_resident_bytes"] == 8 * 1024 * 1024)
+    assert attention_plan(3000, head_dim=128)["attn_bwd_resident_bytes"] == 3072 * 128 * 4
     assert attention_plan(1024, causal=False)["attn_subtiles_visited"] == 16
     assert attention_plan(1024, implementation="xla")["attention_impl"] == "xla"
     # a causal grid of tiles (the three cells past one tile a head): the live
@@ -317,8 +387,8 @@ def test_attention_plan_names_what_runs(monkeypatch):
     banded = attention_plan(8192, window=2048)
     assert (banded["attn_grid_steps"], banded["attn_grid_steps_live"]) == (24, 21)
     # the band's tiles by class: the forward computes all 21 (and all 70 at
-    # SmallThinker's shape) whole; the sub-tiles are dQ's, whose edge tiles are
-    # walked (10 of 16, 4 masked) and whose 7 (42) interior tiles are whole
+    # SmallThinker's shape) whole; the sub-tiles are the backward kernel's, whose
+    # edge tiles are walked (10 of 16, 4 masked) and whose 7 (42) interior tiles are whole
     assert banded["attn_window_tiles_whole"] == 21
     assert (banded["attn_subtiles_visited"], banded["attn_subtiles_masked"]) == (252, 56)
     wider = attention_plan(16384, window=4096)
@@ -332,9 +402,10 @@ def test_attention_plan_names_what_runs(monkeypatch):
                          ids=["causal", "full", "odd-block", "grid2x2", "grid3x3-padded",
                               "full-grid2x2"])
 def test_kernels_walk_the_counted_subtiles(monkeypatch, s, causal):
-    """What the three kernels compute while they are traced is what
-    `attention_subtiles` counts: every QK^T piece is recorded with its
-    size in sub-tiles and whether it builds the mask."""
+    """What the two kernels (the forward, and the one backward kernel that
+    builds a tile's scores once for dK, dV and dQ) compute while they are
+    traced is what `attention_subtiles` counts: every QK^T piece is recorded
+    with its size in sub-tiles and whether it builds the mask."""
     from ray_tpu.ops import attention as A
 
     pieces = []
@@ -356,29 +427,29 @@ def test_kernels_walk_the_counted_subtiles(monkeypatch, s, causal):
     area = sub_q * sub_kv
     counts = {kernel: A.attention_subtiles(padded, padded, causal, s, block, block, sub_q,
                                            sub_kv, kernel=kernel)
-              for kernel in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq")}
+              for kernel in ("flash_fwd", "flash_bwd_dkv_dq")}
     if side == 1:
         # static walk: each kernel's pieces add up to the count
         (visited, masked, total), = set(counts.values())
-        assert sum(n for n, _ in pieces) == 3 * visited * area
-        assert sum(n for n, m in pieces if m) == 3 * masked * area
+        assert sum(n for n, _ in pieces) == 2 * visited * area
+        assert sum(n for n, m in pieces if m) == 2 * masked * area
         assert total == (padded // sub_q) * (padded // sub_kv)
     elif live_grid:
         # a causal grid: a kernel holds one body a class, run per grid step.
         # Below the diagonal one whole unmasked piece; the diagonal tile's
         # pieces are the count's, a tile
         below, whole = side * (side - 1) // 2, (block // sub_q) * (block // sub_kv)
-        assert [p for p in pieces if not p[1] and p[0] == block * block] == [(block * block, False)] * 3
-        on_diagonal = sum(n for n, _ in pieces) - 3 * block * block
+        assert [p for p in pieces if not p[1] and p[0] == block * block] == [(block * block, False)] * 2
+        on_diagonal = sum(n for n, _ in pieces) - 2 * block * block
         assert on_diagonal * side == sum(v - below * whole for v, _, _ in counts.values()) * area
         assert sum(n for n, m in pieces if m) * side == sum(m for _, m, _ in counts.values()) * area
-        assert counts["flash_fwd"][:2] != counts["flash_bwd_dq"][:2] == counts["flash_bwd_dkv"][:2]
+        assert counts["flash_fwd"][:2] != counts["flash_bwd_dkv_dq"][:2]
         plan_steps = A.attention_grid_steps(padded, padded, causal, s, block, block)
         assert plan_steps == (side * (side + 1) // 2,) * 2
     else:
         # a dense grid of tiles: one masked whole-tile body a kernel, run or
         # not per grid step
-        assert pieces == [(block * block, True)] * 3
+        assert pieces == [(block * block, True)] * 2
         assert set(counts.values()) == {(4, 4, 4)}
 
 
@@ -415,8 +486,8 @@ def _assert_ranges_cover(live_pair, sub, causal, kv_len, window=None):
 @pytest.mark.parametrize("kv_len", [1024, 700, 0])
 @pytest.mark.parametrize("sub", [(256, 256), (128, 256), (512, 128)])
 def test_subtile_ranges_agree(causal, kv_len, sub):
-    """`_q_range` (the dkv kernel's bounds) reaches exactly the set
-    `_kv_range` (forward, dq, the counter) reaches, with the same masked
+    """`_q_range` (the backward kernel's bounds) reaches exactly the set
+    `_kv_range` (forward, the counter) reaches, with the same masked
     sub-tiles, and that set is the live pairs'."""
     rows, cols = np.arange(1024)[:, None], np.arange(1024)[None, :]
     live_pair = (cols < kv_len) & ((cols <= rows) | (not causal))
@@ -463,7 +534,7 @@ def test_windowed_kernels_match_the_masked_reference(monkeypatch, s, window, blo
     at and above the window, a window edge inside a tile, and a length no tile
     divides; bands of 3 to 5 tiles with interior ones, by `_BAND_WALK` as it
     stands and with every class walked (16 x 16 sub-tiles here) and every
-    class whole in all three kernels."""
+    class whole in both kernels."""
     from ray_tpu.ops import attention as A
 
     monkeypatch.setattr(A, "_SUB_TILE", (16, 16))
@@ -517,12 +588,15 @@ def test_band_tiles_have_the_class_their_offset_says(monkeypatch):
     assert A._band_class(0, 1, 1024, 2048) is None
 
 
-@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"])
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_bwd_dkv_dq"])
 @pytest.mark.parametrize("s,window,block", [
     (8192, 2048, 1024),     # a band of 3 tiles a q tile: 21 of the 36 causal tiles
     (16384, 4096, 1024),    # a band of 5: 70 tiles a head, 42 of them interior
     (1024, 2048, 1024),     # one tile: the causal walk, 10 / 4 / 16
-    (2048, 256, 1024), (4096, 1000, 512), (4096, 1500, 1024)])
+    (2048, 256, 1024), (4096, 1000, 512), (4096, 1500, 1024),
+    (8192, 4096, 1024),     # a band of 5 that most q tiles start before the sequence
+    (4096, 3000, 1024),     # the window's edge inside the band's last tile
+    (3072, 1024, 512)])     # a band of 3 at half the tile side
 def test_windowed_subtile_counts(s, window, block, kernel):
     """`attention_subtiles` under a window, a kernel, against a count over the
     pairs themselves: in a tile the kernel walks a sub-tile is visited iff it
@@ -557,7 +631,7 @@ def test_windowed_subtile_counts(s, window, block, kernel):
 @pytest.mark.parametrize("s,window", [(4096, 2048), (6144, 4096), (4096, 1500), (2048, 256)],
                          ids=["band3", "band5", "two-trailing-tiles", "under-a-tile"])
 def test_windowed_kernels_trace_the_counted_pieces(monkeypatch, s, window):
-    """What the three windowed kernels hold, as they are traced at the chip's
+    """What the two windowed kernels hold, as they are traced at the chip's
     tile and sub-tile: ONE body for the interior tiles, a whole unmasked
     piece, and one for each other band tile, whose pieces are what
     `attention_subtiles` counts for that tile under that kernel."""
@@ -576,7 +650,7 @@ def test_windowed_kernels_trace_the_counted_pieces(monkeypatch, s, window):
         q, q, q, causal=True, window=window, implementation="pallas").sum()), q)
     band, area = A.window_band(window, 1024, s // 1024), 256 * 256
     live = masked = 0
-    for kernel in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"):
+    for kernel in ("flash_fwd", "flash_bwd_dkv_dq"):
         bodies = {1 if A._band_class(d, band, 1024, window) == "interior" else d for d in range(band)}
         for d in bodies:
             on_visited, on_masked = A._band_tile_subtiles(kernel, d, band, 1024, 256, 256, window)
@@ -584,7 +658,7 @@ def test_windowed_kernels_trace_the_counted_pieces(monkeypatch, s, window):
     assert sum(n for n, _ in pieces) == live
     assert sum(n for n, m in pieces if m) == masked
     interior = [d for d in range(band) if A._band_class(d, band, 1024, window) == "interior"]
-    assert pieces.count((1024 * 1024, False)) == (3 if interior else 0)
+    assert pieces.count((1024 * 1024, False)) == (2 if interior else 0)
 
 
 def test_window_needs_causal_self_attention():

@@ -76,18 +76,27 @@ GLM47FLASH = ((2, 20, 8192, 256), (2, 20, 8192, 256))  # train-glm47flash-8k: a 
          "smallthinker-d128-s16384-gqa7", "trinity-d128-s8192-gqa8", "olmoe-d128-s4096",
          "glm47flash-d256-s8192"],
 )
-def test_flash_attention_fwd_and_grad_compile(as_tpu, shapes):
+def test_flash_attention_fwd_and_grad_compile(as_tpu, monkeypatch, shapes):
     """One grid tile a head (the sub-tile walk, both cells' shapes) and a
     causal grid of live tiles in its two classes (2 x 2 tiles, and the three
     cells' full layers: 136, 36 and 10 steps a head; a head of 256 at the
     1,024-wide tile with the scoped VMEM it asks for: at the compiler's own
-    16 MiB its dK/dV kernel is refused), at the default blocks and the
-    default rule."""
+    16 MiB its backward kernel is refused), at the default blocks and the
+    default rule. The backward is ONE kernel, and lowers within the scoped
+    VMEM it asks for: a head's float32 dQ (8 MiB at 16,384 x 128 and at
+    8,192 x 256, the tight ones) on top of the compiler's 16 MiB or a wide
+    head's 64."""
     q_shape, kv_shape = shapes
     q, k, v = _on(as_tpu, q_shape), _on(as_tpu, kv_shape), _on(as_tpu, kv_shape)
     plan = flash.attention_plan(q_shape[2], head_dim=q_shape[3])
     assert plan["attention_impl"] == "pallas"
     assert plan["attn_grid_steps"] == plan["attn_grid_steps_live"]
+    assert plan["attn_bwd_kernels"] == 1
+    asked = {}
+    call = flash.pl.pallas_call
+    monkeypatch.setattr(flash.pl, "pallas_call", lambda *a, **kw: (
+        asked.update({kw["name"]: getattr(kw.get("compiler_params"), "vmem_limit_bytes", None)}),
+        call(*a, **kw))[1])
 
     def attend(q, k, v):
         return flash.flash_attention(q, k, v, causal=True)
@@ -98,9 +107,13 @@ def test_flash_attention_fwd_and_grad_compile(as_tpu, shapes):
     forward = jax.jit(attend).lower(q, k, v).compile()
     assert _kernel_calls(forward) == 1 and "flash_fwd" in forward.as_text()
     grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, k, v).compile()
-    assert _kernel_calls(grad) == 3  # fwd, dkv, dq
-    assert all(name in grad.as_text() for name in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"))
-    assert "flash_win" not in grad.as_text()
+    assert _kernel_calls(grad) == 2  # the forward, the one backward kernel
+    assert all(name in grad.as_text() for name in ("flash_fwd", "flash_bwd_dkv_dq"))
+    assert "flash_win" not in grad.as_text() and "flash_bwd_dq" not in grad.as_text()
+    tile_bytes = (64 if q_shape[3] > 128 else 16) * 2 ** 20
+    assert plan["attn_bwd_resident_bytes"] == q_shape[2] * q_shape[3] * 4
+    assert asked == {"flash_fwd": tile_bytes if q_shape[3] > 128 else None,
+                     "flash_bwd_dkv_dq": tile_bytes + plan["attn_bwd_resident_bytes"]}
 
 
 def _kernel_payloads(lowered_text: str) -> list:
@@ -130,17 +143,21 @@ def _kernel_payloads(lowered_text: str) -> list:
 # for its own kernels (`_BAND_WALK` as its sweep filled it; at b705cbf the
 # 2,048 band lowered to 0c31b494380ff759, 355e5c6ff6c53256, 620890a7c0f71e85 and
 # the 4,096 one to 74e2b245f31dcdf7, 8878514e319b7661, 17836ec746b727c5).
-# A change that means to move them records its own.
+# A change that means to move them records its own: PR 45 made the backward ONE
+# kernel, so each case's second hash is that kernel's as PR 45 measured it (at
+# its parent, a5c1f2d, dK/dV and dQ lowered to c7d28a62531b4dfc + 0d9fd8c88998994e,
+# 9f20af3abcff34e9 + 3861f3c4fd3b362a, 0d8aef9f814896c9 + 9b4b8ad6a4c59342,
+# ea81818b8eb90d2c + 78314226a55998ae, 9b065628311428f4 + e01ec46875231a9b,
+# 1cfe1a04b182bd14 + fc1d872126362468, 723cb52d86133fdc + f4ebf95525fd6754, in
+# the order below); the forward's, the first, are those of PR 37 and PR 43 still.
 _PARENT_KERNELS = {
-    "gpt2-one-tile": (GPT2, None, ["168573c8a395eef5", "c7d28a62531b4dfc", "0d9fd8c88998994e"]),
-    "mistral-one-tile": (MISTRAL, None, ["908c08fb4f6e79d1", "9f20af3abcff34e9", "3861f3c4fd3b362a"]),
-    "olmoe-causal-grid": (OLMOE, None, ["d2faf6bbdfcd3509", "0d8aef9f814896c9", "9b4b8ad6a4c59342"]),
-    "trinity-causal-grid": (TRINITY, None, ["f9110c423f8b7cea", "ea81818b8eb90d2c", "78314226a55998ae"]),
-    "smallthinker-causal-grid": (SMALLTHINKER, None,
-                                 ["cc133df6a3900f80", "9b065628311428f4", "e01ec46875231a9b"]),
-    "trinity-window-2048": (TRINITY, 2048, ["814711fc3c30193f", "1cfe1a04b182bd14", "fc1d872126362468"]),
-    "smallthinker-window-4096": (SMALLTHINKER, 4096,
-                                 ["bd2bb6a8b593727a", "723cb52d86133fdc", "f4ebf95525fd6754"]),
+    "gpt2-one-tile": (GPT2, None, ["168573c8a395eef5", "690b1522b695dc66"]),
+    "mistral-one-tile": (MISTRAL, None, ["908c08fb4f6e79d1", "f9ba20ec880e4bac"]),
+    "olmoe-causal-grid": (OLMOE, None, ["d2faf6bbdfcd3509", "222cc34eb56d05cd"]),
+    "trinity-causal-grid": (TRINITY, None, ["f9110c423f8b7cea", "159f04a64eacc0ba"]),
+    "smallthinker-causal-grid": (SMALLTHINKER, None, ["cc133df6a3900f80", "93842e428174dc08"]),
+    "trinity-window-2048": (TRINITY, 2048, ["814711fc3c30193f", "a173a261c01c7e9e"]),
+    "smallthinker-window-4096": (SMALLTHINKER, 4096, ["bd2bb6a8b593727a", "9323f427c2ca4473"]),
 }
 
 
@@ -149,9 +166,10 @@ def test_one_tile_and_windowed_calls_keep_their_kernels(as_tpu, case):
     """`train-gpt2s` and `train-mistral7b-fsdp2tp2` (S = 1,024: one tile a
     head) run the kernels they ran before the causal grid, and the three
     other cells' full layers those of that grid as PR 43's parent lowered it:
-    forward, dK/dV, dQ lower to the same MLIR. The windowed layers' hashes are
-    NEW in PR 43, which gave a band's tiles their classes: they hold the two
-    cells' windowed kernels to what that PR measured."""
+    the forward lowers to the same MLIR. The windowed layers' forward hashes
+    are PR 43's, which gave a band's tiles their classes, and every backward
+    hash is PR 45's, which made the backward one kernel: they hold the cells'
+    kernels to what those PRs measured."""
     (q_shape, kv_shape), window, want = _PARENT_KERNELS[case]
     q, k, v = _on(as_tpu, q_shape), _on(as_tpu, kv_shape), _on(as_tpu, kv_shape)
     tiles = q_shape[2] // 1024
@@ -173,8 +191,9 @@ def test_windowed_flash_attention_fwd_and_grad_compile(as_tpu, shapes, seq, wind
     """The `flash_win_*` kernels of a windowed call (a band of tiles at static
     offsets from the diagonal, each computed whole or walked in sub-tiles by
     its class and `_BAND_WALK`) at both cells' shapes and widths: the forward,
-    and the forward with both backward kernels. A whole 1,024 x 1,024 piece
-    beside a walked body fits the scoped VMEM in all three."""
+    and the forward with the one backward kernel. A whole 1,024 x 1,024 piece
+    beside a walked body and the head's resident dQ fits the scoped VMEM asked
+    for."""
     (b, hq, _, d), (_, hkv, _, _) = shapes
     q = _on(as_tpu, (b, hq, seq, d))
     k = v = _on(as_tpu, (b, hkv, seq, d))
@@ -187,8 +206,8 @@ def test_windowed_flash_attention_fwd_and_grad_compile(as_tpu, shapes, seq, wind
     assert _kernel_calls(forward) == 1 and "flash_win_fwd" in forward.as_text()
     grad = jax.jit(jax.grad(lambda q, k, v: attend(q, k, v).astype(jnp.float32).sum(),
                             argnums=(0, 1, 2))).lower(q, k, v).compile()
-    assert _kernel_calls(grad) == 3
-    assert all(name in grad.as_text() for name in ("flash_win_bwd_dkv", "flash_win_bwd_dq"))
+    assert _kernel_calls(grad) == 2
+    assert "flash_win_bwd_dkv_dq" in grad.as_text() and "flash_win_bwd_dq" not in grad.as_text()
 
 
 def test_default_rule_is_the_backend_alone(as_tpu, monkeypatch):
@@ -253,7 +272,7 @@ def test_flash_attention_under_a_mesh_is_partitioned_per_shard(v5e, as_tpu):
         return out.astype(jnp.float32).sum()
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv).compile()
-    assert _kernel_calls(compiled) == 3
+    assert _kernel_calls(compiled) == 2     # the forward, the one backward kernel
     # each chip holds a quarter of q: two of four sequences, half the heads
     per_chip = 2 * 16 * 2048 * 128 * 2
     assert compiled.memory_analysis().argument_size_in_bytes < 3 * per_chip
@@ -373,7 +392,8 @@ def mistral_cell_step(v5e):
 
 
 GIB = 2 ** 30
-WHOLE_BLOCK_TEMP_GIB, WHOLE_BLOCK_TFLOP = 6.84, 14.98   # this step since PR 24's rehearsal
+# this step since PR 24's rehearsal; 6.84 GiB of temporaries until PR 45 made the backward ONE kernel
+WHOLE_BLOCK_TEMP_GIB, WHOLE_BLOCK_TFLOP = 6.51, 14.98
 
 
 @pytest.mark.parametrize("hbm_gib,want", [
@@ -384,17 +404,19 @@ def test_mistral_cell_step_keeps_what_fits_and_compiles(
         as_tpu, monkeypatch, mistral_cell_step, hbm_gib, want):
     """The cell's whole step for the described v5e:2x2, dense head. A device
     of unknown size gets the whole-block program (5.63 GiB of arguments and
-    6.84 of temporaries a chip, 14.98 TFLOP with the scanned block counted
+    6.51 of temporaries a chip, 14.98 TFLOP with the scanned block counted
     once, 21 all-reduces). At the chip's 15.75 GiB the rule keeps gate, up
     and the residual after the output projection: two matmuls of 0.72 TFLOP
     and the output projection's 0.2 less in the scanned block, one
-    all-reduce less in the text, and no more than 4.2 GiB of temporaries
-    over the whole-block program's (3.95 by this compiler's count, which
+    all-reduce less in the text, and no more than 4.5 GiB of temporaries
+    over the whole-block program's (4.20 by this compiler's count since PR 45,
+    whose one backward kernel took 0.33 GiB off that program and 0.08 off this
+    one; 3.95 of 6.84 before, which
     read 2.1 GiB over the chip's peak for the whole-block step and 25% over
     the kept values' own bytes: PERF.md section 6, PR 30). The attention
     kernel's output is a candidate since PR 34 and is not kept here: at
     S = 1,024 it is worth less than keeping it moves (with it this compiler
-    counts 4.34 GiB over and three kernel calls, the chip 0.41 GB more and
+    counts 4.34 GiB over and a kernel call less, the chip 0.41 GB more and
     no gain: PERF.md section 6, PR 34)."""
     from ray_tpu.ops import losses
     from ray_tpu.train.lm import make_train_step
@@ -407,7 +429,7 @@ def test_mistral_cell_step_keeps_what_fits_and_compiles(
     compiled = step.lower(state, {"tokens": tokens}).compile()
     memory, tflop = compiled.memory_analysis(), compiled.cost_analysis()["flops"] / 1e12
     all_reduces = compiled.as_text().count(" all-reduce(")
-    assert _kernel_calls(compiled) == 4   # flash_fwd twice (the forward, its recomputation), dkv, dq
+    assert _kernel_calls(compiled) == 3   # flash_fwd twice (the forward, its recomputation), flash_bwd_dkv_dq
     assert memory.argument_size_in_bytes / GIB == pytest.approx(5.63, abs=0.02)
     temp_gib = memory.temp_size_in_bytes / GIB
     if not want[1]:
@@ -415,7 +437,7 @@ def test_mistral_cell_step_keeps_what_fits_and_compiles(
         assert tflop == pytest.approx(WHOLE_BLOCK_TFLOP, abs=0.05)
         assert all_reduces == 21
     else:
-        assert WHOLE_BLOCK_TEMP_GIB + 3.0 < temp_gib <= WHOLE_BLOCK_TEMP_GIB + 4.2
+        assert WHOLE_BLOCK_TEMP_GIB + 3.0 < temp_gib <= WHOLE_BLOCK_TEMP_GIB + 4.5
         assert plan["remat_saved_bytes"] / GIB == pytest.approx(3.375, abs=0.001)
         assert tflop <= WHOLE_BLOCK_TFLOP - 1.6
         assert all_reduces == 20
@@ -454,7 +476,7 @@ def test_trinity_cell_step_keeps_the_attention_outputs_and_compiles(as_tpu, monk
     the rule chunks the head and keeps the attention kernels' outputs, and
     the compiled step runs each forward flash kernel once a layer (the
     two scanned dense layers' in the forward loop's body alone, the four
-    unrolled layers' once each) beside its two backward kernels; a
+    unrolled layers' once each) beside its one backward kernel; a
     whole-block step runs it twice. The kernel's own lse, (B, H, S, 1), is
     what a tiled layout pads 128 times: the value kept is (B, H, S)."""
     from ray_tpu.ops import losses
@@ -475,19 +497,21 @@ def test_trinity_cell_step_keeps_the_attention_outputs_and_compiles(as_tpu, monk
     # norm after it, the dispatch's transpose); no scatter adds wide rows into tokens
     assert _held_row_sums(lowered, compiled, "16384,2048") == (2, 4 * 2 * 3, 0)
     bodies, fills = _held_passes_not_taken(lowered, compiled, "16384,2048")
-    assert bodies <= 125 and fills == 0     # PR 41's count of bodies; a pass not taken fills nothing
+    # PR 45's count of bodies (its parent's 125 less the five dQ kernels); a pass not taken fills nothing
+    assert bodies <= 120 and fills == 0
     # dS dS scanned (one body forward, one backward), eS eF eS eS unrolled
     assert _kernels_named(compiled, "flash_win_fwd") == 1 + 3
     assert _kernels_named(compiled, "flash_fwd") == 1
-    for backward in ("flash_win_bwd_dkv", "flash_win_bwd_dq"):
-        assert _kernels_named(compiled, backward) == 1 + 3
+    assert _kernels_named(compiled, "flash_win_bwd_dkv_dq") == 1 + 3
+    assert _kernels_named(compiled, "flash_bwd_dkv_dq") == 1
     text = compiled.as_text()
+    assert "flash_bwd_dq" not in text and "flash_win_bwd_dq" not in text
     assert "f32[2,32,8192,1]{3,2,1,0:T(8,128)}" in text      # 268 MB where written
     assert "f32[2,32,8192]{2,1,0:T(8,128)" in text           # 2.1 MB where kept
     # `lse_first`: in the schedule every unrolled layer's lse is reshaped before the
     # first backward kernel runs (left alone, where the backward reads it)
     lines = text[text.index("\nENTRY "):].splitlines()
-    first_backward = next(i for i, line in enumerate(lines) if "%flash_win_bwd_dkv" in line.split("=")[0])
+    first_backward = next(i for i, line in enumerate(lines) if "%flash_win_bwd_dkv_dq" in line.split("=")[0])
     lse_outputs = [re.match(r"\s*(%[\w.\-]+) = f32\[2,32,8192,1\]", line).group(1) for line in lines
                    if re.match(r"\s*%[\w.\-]+ = f32\[2,32,8192,1\].* get-tuple-element\(%flash_(win_)?fwd", line)]
     assert len(lse_outputs) == 4
@@ -521,15 +545,17 @@ def test_smallthinker_cell_step_keeps_what_a_four_layer_iteration_leaves_room_fo
     compiled = lowered.compile()
     # eF eS eS eS, scanned twice: one body forward, one backward
     assert _kernels_named(compiled, "flash_win_fwd") == 3 and _kernels_named(compiled, "flash_fwd") == 1
-    for backward in ("flash_win_bwd_dkv", "flash_win_bwd_dq"):
-        assert _kernels_named(compiled, backward) == 3
+    assert _kernels_named(compiled, "flash_win_bwd_dkv_dq") == 3
+    assert _kernels_named(compiled, "flash_bwd_dkv_dq") == 1
+    assert "flash_bwd_dq" not in compiled.as_text() and "flash_win_bwd_dq" not in compiled.as_text()
     # 4 layers x 3 projections, forward and recomputed: the expert layer is recomputed whole
     assert _kernels_named(compiled, "moe_gmm_fwd") == 2 * 4 * 3 * 2
     # `moe_rows_sum`: one body a signature; 4 layers x 2 passes x (the combine, the
     # dispatch's transpose): nothing in the backward pass reads a recomputed combine
     assert _held_row_sums(lowered, compiled, "16384,2560") == (2, 4 * 2 * 2, 0)
     bodies, fills = _held_passes_not_taken(lowered, compiled, "16384,2560")
-    assert bodies <= 110 and fills == 0     # PR 41's count of bodies; a pass not taken fills nothing
+    # PR 45's count of bodies (its parent's 110 less the four dQ kernels); a pass not taken fills nothing
+    assert bodies <= 106 and fills == 0
     assert compiled.memory_analysis().argument_size_in_bytes / GIB == pytest.approx(7.20, abs=0.02)
 
 
@@ -560,9 +586,9 @@ def test_glm47flash_cell_step_keeps_the_attention_outputs_and_the_latents_and_co
     assert step.loss_chunk_for(tokens.shape, state) == 0
     lowered = step.lower(state, {"tokens": tokens})
     compiled = lowered.compile()
-    for kernel in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"):
+    for kernel in ("flash_fwd", "flash_bwd_dkv_dq"):
         assert _kernels_named(compiled, kernel) == 3
-    assert "flash_win" not in compiled.as_text()
+    assert "flash_win" not in compiled.as_text() and "flash_bwd_dq" not in compiled.as_text()
     # 5 expert layers in 2 bodies (the scan's, the module's) x 3 projections x (the first
     # pass through the held buffer, the later ones), forward and recomputed
     assert _kernels_named(compiled, "moe_gmm_fwd") == 2 * 3 * 2 * 2

@@ -78,7 +78,7 @@ def test_step_fn_span_names_the_attention_the_step_runs(trainer, monkeypatch):
     attrs = trainer._step_fn_span.to_dict()["attrs"]
     assert attrs == {"attention_impl": "xla", "attn_subtiles_visited": 0,
                      "attn_subtiles_masked": 0, "attn_subtiles_total": 0,
-                     "attn_grid_steps": 0, "attn_grid_steps_live": 0,
+                     "attn_grid_steps": 0, "attn_grid_steps_live": 0, "attn_bwd_kernels": 0,
                      "loss_chunk": 0,   # 0: the dense head; no MoE key on a dense model
                      # gpt2-tiny keeps every activation: nothing is recomputed
                      "remat": "off", "remat_saved": (), "remat_saved_bytes": 0,
@@ -91,6 +91,9 @@ def test_step_fn_span_names_the_attention_the_step_runs(trainer, monkeypatch):
     assert (attrs["attn_subtiles_visited"], attrs["attn_subtiles_masked"],
             attrs["attn_subtiles_total"]) == (10, 4, 16)
     assert (attrs["attn_grid_steps"], attrs["attn_grid_steps_live"]) == (1, 1)
+    # one backward kernel a call, a head's float32 dQ resident in it
+    assert attrs["attn_bwd_kernels"] == 1
+    assert attrs["attn_bwd_resident_bytes"] == 1024 * trainer.config.head_dim * 4
     # past one tile a head the grid holds the live tiles alone, masked on the diagonal
     trainer._note_step_plan((2, 4097))
     attrs = trainer._step_fn_span.to_dict()["attrs"]
