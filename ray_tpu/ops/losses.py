@@ -58,18 +58,24 @@ def cross_entropy_loss(
 # from the head matmul, their f32 upcast, and the f32 probs tensor the
 # backward softmax materializes
 _DENSE_LOSS_BYTES_PER_LOGIT = 2 + 4 + 4
-# and the chunked path, per logit of ONE chunk: the bf16 logits, and room
-# for a float32 pass over them (the compiled v5e program keeps only the
-# narrow ones: 1.7 bytes a logit by its memory analysis)
-_CHUNKED_LOSS_BYTES_PER_LOGIT = 2 + 4
-# the share of the room beside state and gradients that one chunk's logits
-# may take: the activations live there too and are not counted. Measured on
-# a v5e (PERF.md section 6, PR 28): chunks at 36-37% of the room were the
-# fastest that fit (OLMoE 4 x 4,096: 2,048; gpt2-small at batch 32: 512),
-# and at 73% gpt2-small's 1,024 lost 6% to 512 with the chip 95% full
-_CHUNK_ROOM_FRACTION = 0.5
-_AUTO_CHUNK_HEADROOM_FRACTION = 0.2  # the least kept for params/opt/activations
-_CHUNK_CANDIDATES = (2048, 1024, 512, 256, 128)
+# and the fused head, per logit of ONE chunk, beside what its caller counts
+# as held: the narrow logits and what the chunk's three matmuls keep around
+# them. Fitted to gpt2-small's peaks on a v5e (PERF.md section 6, PR 46):
+# the peak less the estimate of state, gradients and activations, over the
+# chunk's logits, read 2.25 (24 x 512 rows), 2.76 (24 x 1,024, the whole
+# sequence) and 2.42 (32 x 512); under 512 rows a chunk it reads more (3.5
+# at 256), which only ever picks between the two smallest candidates
+_CHUNKED_LOSS_BYTES_PER_LOGIT = 3
+# The share of the device that a step's largest moment leaves free, for this
+# rule and for what a recomputing step keeps (train/lm.auto_remat_saved).
+# The compiler itself works to a ceiling near 93%: a program that needs
+# more is scheduled and rematerialised into it and pays in time (Trinity-Mini
+# with the residual kept beside the attention outputs, estimate 15.86 GB of
+# 16.91: 15.62 GB and 9 ms a step MORE, PR 34; gpt2-small at 32 x 1,024 with
+# the whole sequence as the head's one chunk, estimate 17.8 GB: 94.8% and
+# 5.6% slower than two chunks, PR 46)
+HBM_FREE_FRACTION = 0.065
+_CHUNK_CANDIDATES = (2048, 1024, 512, 256, 128)   # after the whole sequence
 
 
 def auto_loss_chunk(
@@ -81,67 +87,50 @@ def auto_loss_chunk(
     resident_bytes: int = 0,
     step_bytes: int = 0,
 ) -> int:
-    """Pick the fused-linear-CE chunk size (0 = dense) from the logits HBM
-    working-set estimate vs what the device has left.
+    """The chunk of the fused head (`fused_linear_cross_entropy`) for a
+    device of known size; 0, the dense head, for one of unknown size.
 
-    Where the (B_local, S, V) logits fit, the head is dense. (On a v5e,
-    gpt2-small at batch 24, the chunked head reads +2.8% over the dense one
-    with 4.4 GB less memory, PERF.md section 6, PR 28: whether the dense
-    head should stay at all is ROADMAP Design 2's question; this function
-    only says where the logits stop fitting.) The logits have the device
-    less a headroom: `resident_bytes` the caller knows a
-    device holds all along (its share of the train state, counted from the
-    shardings) plus `step_bytes` it will hold during the step (its
-    gradients), and never less than 20% of the device — so a small model
-    crosses over at estimate > 80% of HBM (gpt2-small on a 16G v5e: dense
-    at batch 24, chunked at 32), and a model whose state fills most of the
-    chip (one OLMoE layer: 10 GB of 16.9) chunks though its logits alone
-    would fit. The chunk is then the largest of `_CHUNK_CANDIDATES` that
-    divides S and whose own logits fit half of the same room (the smallest
-    that divides S if none does): each chunk reads and writes the head
-    gradient's float32 accumulator once, so fewer, larger chunks are
-    faster while memory is not short (train-olmoe-64e-4k on a v5e, PR 28:
-    2,048 over 512 +2.1%, peak memory 75.0% -> 76.3%). Nothing live is
-    probed but the device's size, so the same model and batch always get
-    the same program.
+    Where the size is known the head is the fused one, whether or not the
+    dense logits would fit: it holds none of the (B_local, S, V) logits,
+    and a step that fits only just pays for them elsewhere (gpt2-small at
+    24 x 1,024 on a v5e, PR 46: dense 143,573 tokens/s at 94.5% of memory,
+    the compiler rematerialising nine MLP activations a step to stay under
+    its ceiling; chunks of 256 / 512 / 1,024 rows 147,923 / 149,090 /
+    149,395 at 66.3 / 68.2 / 80.2%, nothing rematerialised). The
+    chunk is the largest candidate (the whole sequence first, then those of
+    `_CHUNK_CANDIDATES` that divide S; the smallest if none fits) with which
+    the head's moment leaves `HBM_FREE_FRACTION` of the device free: what
+    the caller counts as held then, `resident_bytes` all along (a device's
+    share of the train state, from the shardings) plus `step_bytes` during
+    the step (its gradients, and the activations the blocks hold when the
+    head runs), plus `_CHUNKED_LOSS_BYTES_PER_LOGIT` a logit of one chunk.
+    Each chunk reads and writes the head gradient's float32 accumulator
+    once, so fewer, larger chunks are faster while memory is not short
+    (OLMoE's one layer at 4 x 4,096, PR 46: 2,048 rows 85,961 tokens/s at
+    76.3%, the whole 4,096 87,462 at 77.9%), and slower once the program no
+    longer fits under the compiler's ceiling (gpt2-small at 32 x 1,024: 256 /
+    512 / 1,024 rows 148,226 / 148,833 / 140,453 at 84.8 / 87.9 / 94.8%).
+    The three answers those readings ask for (1,024, 4,096, 512) are what
+    the one criterion gives. Nothing live is probed but the device's size,
+    so the same model and batch always get the same program.
 
     hbm_bytes None = probe the local device (memory_stats().bytes_limit);
-    an unknown limit (CPU backends) means no HBM cliff to dodge -> dense."""
+    an unknown limit (CPU backends) means no HBM cliff to dodge and no
+    reading to go by -> dense, the plain form the fused head's tests
+    compare with."""
     if hbm_bytes is None:
         hbm_bytes = device_hbm_bytes()
     if not hbm_bytes:
         return 0
-    if loss_logits_bytes(batch_per_device, seq, vocab) <= _logits_room(
-            hbm_bytes, resident_bytes, step_bytes):
-        return 0
-    return chunk_of_a_chunked_head(
-        batch_per_device, seq, vocab, hbm_bytes,
-        resident_bytes=resident_bytes, step_bytes=step_bytes)
-
-
-def _logits_room(hbm_bytes: int, resident_bytes: int, step_bytes: int) -> float:
-    return hbm_bytes - max(resident_bytes + step_bytes, _AUTO_CHUNK_HEADROOM_FRACTION * hbm_bytes)
-
-
-def chunk_of_a_chunked_head(
-    batch_per_device: int, seq: int, vocab: int, hbm_bytes: int, *,
-    resident_bytes: int = 0, step_bytes: int = 0,
-) -> int:
-    """The chunk `auto_loss_chunk` gives a head that is chunked, whoever
-    decided that it is: the largest of `_CHUNK_CANDIDATES` that divides S and
-    whose own logits fit half the logits' room (the smallest that divides S
-    if none does; 0, the dense head, if none divides S)."""
-    room = _logits_room(hbm_bytes, resident_bytes, step_bytes)
-    dividing = [chunk for chunk in _CHUNK_CANDIDATES if seq % chunk == 0]
-    for chunk in dividing:
-        if loss_logits_bytes(batch_per_device, seq, vocab, chunk) <= _CHUNK_ROOM_FRACTION * room:
-            return chunk
-    return dividing[-1] if dividing else 0
+    candidates = [seq] + [chunk for chunk in _CHUNK_CANDIDATES if chunk < seq and seq % chunk == 0]
+    room = (1 - HBM_FREE_FRACTION) * hbm_bytes - resident_bytes - step_bytes
+    return next((chunk for chunk in candidates
+                 if loss_logits_bytes(batch_per_device, seq, vocab, chunk) <= room), candidates[-1])
 
 
 def loss_logits_bytes(batch_per_device: int, seq: int, vocab: int, chunk: int = 0) -> int:
     """The estimate of what the head's logits hold on a device: all of them
-    under the dense loss (chunk 0), one chunk's under the chunked one."""
+    under the dense loss (chunk 0), one chunk's under the fused one."""
     if chunk:
         return batch_per_device * chunk * vocab * _CHUNKED_LOSS_BYTES_PER_LOGIT
     return batch_per_device * seq * vocab * _DENSE_LOSS_BYTES_PER_LOGIT

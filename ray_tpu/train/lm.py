@@ -170,14 +170,10 @@ def create_train_state(
 # tp=2, 8 scanned layers, 12 x 1,024 rows a device (whole block 11.145 GB,
 # estimate 11.06; gate, up and the residual kept 14.658, estimate 14.68), and
 # one chip's share of Trinity-Mini, 2 scanned and 4 unrolled layers, 2 x 8,192
-# rows (the attention outputs kept 15.670 GB, estimate 15.73).
-# The share of the device that whatever is kept leaves free. The compiler
-# itself works to a ceiling near 93%: a program that needs more is scheduled
-# and rematerialised into it and pays in time (Trinity with the residual kept
-# beside the attention outputs, estimate 15.86 GB: 15.62 GB and 9 ms a step
-# MORE; with everything kept 16.36 GB and 26 ms more).
-_REMAT_FREE_FRACTION = 0.065
-# the backward pass of one block, in copies of the activations it writes:
+# rows (the attention outputs kept 15.670 GB, estimate 15.73). The share of
+# the device that whatever is kept leaves free is `losses.HBM_FREE_FRACTION`,
+# which the head's rule shares.
+# The backward pass of one block, in copies of the activations it writes:
 # Mistral's peak less state, gradients and the scan's carries was 2.29 GB, the
 # block's activations 1.26 GB (and chunking the head did not lower it, PR 29):
 # 1.82; Trinity's with the attention outputs kept 2.94 of 1.71: 1.72
@@ -203,7 +199,7 @@ def auto_remat_saved(
     cost 0.96 to 1.00 of that): those worth keeping, one by one in order of
     their worth per byte (a spared all-reduce counts as the FLOPs of its
     time), each one with which `peak_bytes(kept)`, the estimate of the step's peak,
-    still leaves `_REMAT_FREE_FRACTION` of the device free. `rows` are a
+    still leaves `losses.HBM_FREE_FRACTION` of the device free. `rows` are a
     device's tokens a step. Nothing live is probed but the device's size, so
     the same model, mesh and batch always get the same program; an unknown
     size (CPU) keeps nothing: the whole-block step is the one that fits
@@ -218,7 +214,7 @@ def auto_remat_saved(
 
     kept: Tuple[Any, ...] = ()
     for c in sorted(candidates, key=worth_per_byte, reverse=True):
-        if worth_per_byte(c) > 0 and peak_bytes(kept + (c,)) <= (1 - _REMAT_FREE_FRACTION) * hbm_bytes:
+        if worth_per_byte(c) > 0 and peak_bytes(kept + (c,)) <= (1 - losses.HBM_FREE_FRACTION) * hbm_bytes:
             kept += (c,)
     return kept, sum(sum(c.layers) * rows * c.width * itemsize for c in kept)
 
@@ -338,9 +334,13 @@ def make_train_step(
     the peak-memory hog at LM vocab sizes — never materialize, and each
     chunk's logits are built once (its dx and its share of the head's
     gradient are computed in the same scan step: three head matmuls a
-    chunk, as the dense head runs over the whole batch). None (default)
-    auto-selects via ops.losses.auto_loss_chunk (logits HBM estimate vs
-    the device limit); 0 forces the dense path."""
+    chunk, as the dense head runs over the whole batch). None (default):
+    on a device whose size is known the fused head, with the largest chunk
+    (the whole sequence first) that the head's moment has room for
+    (ops.losses.auto_loss_chunk; on a v5e the dense head lost 3.0% to 4.1%
+    to every chunk tried on gpt2-small, PERF.md section 6, PR 46), and the
+    dense head on one of unknown size (the CPU); 0 forces the dense head,
+    the plain form that the fused one's tests compare with."""
     batch_sharding = NamedSharding(mesh, PartitionSpec(DATA_AXES, None))
     metric_sharding = NamedSharding(mesh, PartitionSpec())
     # batch rows per device, for the loss-chunk heuristic
@@ -365,38 +365,48 @@ def make_train_step(
 
     def head_and_remat_for(tokens_shape, state: TrainState) -> Tuple[int, Dict[str, Any]]:
         """(the head's form, what the blocks keep) for a (B, S + 1) batch of
-        the whole step: ONE decision, since the dense head's logits and the
-        kept values ask for the same room; made the first time the shape is
-        asked for (by a caller or by the step's trace) and kept, so that what
-        is reported is what runs. The estimate of the step's peak
-        (`step_peak_bytes`) is counted from `state`'s shapes and the
-        shardings. Where the rule leaves a candidate out for want of room
-        while the DENSE head's logits are larger than any block's backward
-        pass, the head is chunked (as `auto_loss_chunk` chunks one whose
-        logits do not fit) and the rule asked again: a step that recomputes
-        for want of memory does not hold all the logits. A `loss_chunk` the
-        caller gave stays."""
+        the whole step: ONE decision, since the head's logits and the kept
+        values ask for the same room; made the first time the shape is asked
+        for (by a caller or by the step's trace) and kept, so that what is
+        reported is what runs. Everything is counted from `state`'s shapes,
+        the shardings and the device's size. First the head
+        (`auto_loss_chunk`): beside a device's share of the state and the
+        gradients it is told what the blocks hold when the head runs: every
+        activation they write where nothing is recomputed (`block_costs`
+        names a block's width; a family that names none counts none), their
+        inputs where they are. Then, with that head's logits in the
+        estimate of the step's peak (`step_peak_bytes`), what a recomputing
+        step keeps (`auto_remat_saved`). On a v5e the six cells' heads are
+        one chunk each, the whole sequence (PERF.md section 6, PR 46). A
+        `loss_chunk` the caller gave stays."""
         shape = tuple(tokens_shape)
         if shape in decided:
             return decided[shape]
         batch, seq = device_batch(shape), shape[1] - 1
+        rows, itemsize = batch * seq, jnp.dtype(config.dtype).itemsize
         resident = device_bytes(state, state_shardings)
-        gradients = device_bytes(state.params, state_shardings.params)
+        # the gradients and, where they are summed over microbatches, their accumulator
+        gradients = device_bytes(state.params, state_shardings.params) * (2 if grad_accum > 1 else 1)
+        costs = family.block_costs(
+            config, seq, lambda weight: model_split(weight, slice(2, None))
+        ) if family.block_costs else None
+        vocab = config.vocab_size // (
+            model_split("lm_head", slice(1, None)) if "lm_head" in state_shardings.params
+            else model_split("wte", slice(0, 1)))
+        # what the blocks hold when the head runs: their inputs where they are
+        # recomputed (what is kept beside those is decided after the head),
+        # else every activation they write
+        activations = sum(
+            run["layers"] * rows * (config.d_model if config.remat else run["width"]) * itemsize
+            for run in costs["runs"]) if costs else 0
         chunk = loss_chunk
         if chunk is None:
-            chunk = auto_loss_chunk(batch, seq, config.vocab_size,
-                                    resident_bytes=resident, step_bytes=gradients)
+            chunk = auto_loss_chunk(batch, seq, vocab, resident_bytes=resident,
+                                    step_bytes=gradients + activations)
         plan = {"remat": "whole_block" if config.remat else "off", "remat_saved": (),
                 "remat_saved_bytes": 0,
                 "remat_recomputed_flops_share": None if config.remat else 0.0}
-        if config.remat and family.block_costs is not None:
-            hbm_bytes = losses.device_hbm_bytes()
-            rows, itemsize = batch * seq, jnp.dtype(config.dtype).itemsize
-            costs = family.block_costs(
-                config, seq, lambda weight: model_split(weight, slice(2, None)))
-            vocab = config.vocab_size // (
-                model_split("lm_head", slice(1, None)) if "lm_head" in state_shardings.params
-                else model_split("wte", slice(0, 1)))
+        if config.remat and costs:
 
             def under(tree, path):
                 return functools.reduce(lambda sub, key: sub[key], path, tree)
@@ -408,24 +418,12 @@ def make_train_step(
                 "inputs": run["layers"] * rows * config.d_model * itemsize,
                 "block": _REMAT_BLOCK_COPIES * rows * run["width"] * itemsize,
             } for run in costs["runs"])
-            always = (resident + gradients - sum(run["gradients"] for run in runs)
-                      + (gradients if grad_accum > 1 else 0))   # their accumulator
-
-            def kept_beside(chunk: int):
-                logits = losses.loss_logits_bytes(batch, seq, vocab, chunk)
-                return logits > max(run["block"] for run in runs), auto_remat_saved(
-                    costs["candidates"], rows=rows, itemsize=itemsize, hbm_bytes=hbm_bytes,
-                    peak_bytes=functools.partial(
-                        step_peak_bytes, rows=rows, itemsize=itemsize, always=always,
-                        logits=logits, runs=runs))
-
-            head_is_larger, (kept, kept_bytes) = kept_beside(chunk)
-            if (loss_chunk is None and not chunk and hbm_bytes and head_is_larger
-                    and len(kept) < len(costs["candidates"])):
-                chunk = losses.chunk_of_a_chunked_head(
-                    batch, seq, config.vocab_size, hbm_bytes,
-                    resident_bytes=resident, step_bytes=gradients)
-                _, (kept, kept_bytes) = kept_beside(chunk)
+            always = resident + gradients - sum(run["gradients"] for run in runs)
+            kept, kept_bytes = auto_remat_saved(
+                costs["candidates"], rows=rows, itemsize=itemsize,
+                peak_bytes=functools.partial(
+                    step_peak_bytes, rows=rows, itemsize=itemsize, always=always,
+                    logits=losses.loss_logits_bytes(batch, seq, vocab, chunk), runs=runs))
             plan.update(
                 remat="selective" if kept else "whole_block",
                 remat_saved=tuple(name for c in kept for name in c.names),

@@ -100,6 +100,37 @@ def test_the_step_reports_the_module_beside_the_stack():
         100 * float(merged["moe_rows_held"]) / (2 * 32 * 4), rel=1e-6)
 
 
+def test_fused_head_step_with_the_module_is_the_dense_heads_step():
+    """One step of `LMTrainer` with the module in the loss, the fused head
+    (which a device of known size runs since PR 46, this cell with the whole
+    sequence as its one chunk) against the dense one: both passes of the head,
+    the module's with its mask, give the same losses, gradient norm and
+    updated parameters (plain SGD, so that the update is the gradient)."""
+    import optax
+
+    from ray_tpu.parallel import MeshSpec
+    from ray_tpu.train import LMTrainer
+
+    config = tiny_latent()
+    tokens = np.random.default_rng(5).integers(0, config.vocab_size, size=(8, 33)).astype(np.int32)
+
+    def one_step(loss_chunk):
+        trainer = LMTrainer(config, mesh_spec=MeshSpec(dp=8), seed=3, optimizer=optax.sgd(0.1),
+                            loss_chunk=loss_chunk)
+        out = trainer.train(iter([{"tokens": tokens}]), num_steps=1, report_every=1)
+        assert trainer._step_fn_span.to_dict()["attrs"]["loss_chunk"] == loss_chunk
+        return out, trainer.state.params
+
+    dense, dense_params = one_step(0)
+    fused, fused_params = one_step(32)
+    for key in ("loss", "mtp_loss", "grad_norm", "num_tokens"):
+        assert fused[key] == pytest.approx(dense[key], rel=1e-5), key
+    for (path, got), want in zip(jax.tree_util.tree_flatten_with_path(fused_params)[0],
+                                 jax.tree.leaves(dense_params)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-6,
+                                   err_msg=jax.tree_util.keystr(path))
+
+
 # ------------------------------------------------- the recompute plan's prices
 
 

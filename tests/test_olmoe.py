@@ -297,52 +297,59 @@ def test_grouped_matmul_kernels_equal_ragged_dot_through_the_layout(sizes):
 V5E_HBM = int(15.75 * 2 ** 30)
 
 
-@pytest.mark.parametrize("cell,batch,seq,vocab,state_gb,grad_gb,want", [
-    ("train-gpt2s", 24, 1024, 50257, 1.5, 0.5, 0),
-    ("train-mistral7b-fsdp2tp2", 12, 1024, 32768, 6.04, 2.01, 0),
-    ("train-olmoe-64e-4k", 4, 4096, 50304, 7.51, 2.50, 2048),
-    ("gpt2s-at-batch-32", 32, 1024, 50257, 1.5, 0.5, 512),
-    # the largest chunk whose own logits fit half the room the dense ones did not
-    ("a-200k-vocabulary-at-4k", 8, 4096, 200192, 7.5, 2.5, 256),
+@pytest.mark.parametrize("cell,batch,seq,vocab,state_gb,step_gb,want", [
+    # a device's state; its gradients and what its blocks hold when the head runs; readings:
+    # PERF.md section 6, PR 46 (chip). Dense 143,573 tokens/s, the whole sequence 149,395
+    ("train-gpt2s", 24, 1024, 50257, 1.49, 8.65, 1024),
+    # a device's rows and its half of the vocabulary; one chunk read -0.03% against dense (PR 28)
+    ("train-mistral7b-fsdp2tp2", 12, 1024, 16384, 6.04, 2.82, 1024),
+    # 2,048 rows 85,961 tokens/s at 76.3% of the chip, the whole 4,096 87,462 at 77.9%
+    ("train-olmoe-64e-4k", 4, 4096, 50304, 7.51, 2.50, 4096),
+    # the whole sequence would run at the compiler's ceiling: 140,453 against 148,833
+    ("gpt2s-at-batch-32", 32, 1024, 50257, 1.49, 11.37, 512),
+    # the largest chunk whose own logits fit the room beside what is held
+    ("a-200k-vocabulary-at-4k", 8, 4096, 200192, 7.5, 2.5, 1024),
     ("no-chunk-fits-so-the-smallest", 64, 1024, 200192, 10.5, 3.5, 128),
 ])
-def test_head_is_chunked_where_the_logits_do_not_fit_beside_the_state(
-        cell, batch, seq, vocab, state_gb, grad_gb, want):
-    """The shipped cells keep the dense head they ran before; one OLMoE
-    layer's state (10 GB of 16.9 with its gradients) leaves no room for
-    8.2 GB of logits. A chunked head takes the largest chunk that divides
-    S and whose own logits fit half of that room. Nothing live is read: the same
-    numbers, the same form."""
+def test_head_takes_the_largest_chunk_that_fits_beside_what_is_held(
+        cell, batch, seq, vocab, state_gb, step_gb, want):
+    """On a device of known size the head is the fused one (no shipped cell
+    keeps the dense head it ran before PR 46), its chunk the largest
+    candidate, the whole sequence first, whose logits at 3 bytes each leave
+    6.5% of the device free beside what the caller counts as held. Nothing
+    live is read: the same numbers, the same form."""
     from ray_tpu.ops.losses import auto_loss_chunk
 
     assert auto_loss_chunk(batch, seq, vocab, V5E_HBM, resident_bytes=int(state_gb * 1e9),
-                           step_bytes=int(grad_gb * 1e9)) == want
+                           step_bytes=int(step_gb * 1e9)) == want
 
 
 def test_loss_chunk_for_counts_a_devices_share_of_the_state_from_the_shardings(monkeypatch):
     """`make_train_step(...).loss_chunk_for(shape, state)`: what a device
     holds beside the logits is its share of the state and of the gradients,
-    read from the shardings and not from live memory. With room for the
-    logits and HALF the state, one device (the whole state) chunks the head
-    and a device of fsdp=2 x tp=2 (a quarter) does not."""
+    and the logits are its rows' over its share of the vocabulary, read from
+    the shardings and not from live memory. With room for the whole
+    sequence's logits and HALF the state, one device (the whole state) takes
+    the smaller chunk and a device of fsdp=2 x tp=2 (a quarter) the whole
+    sequence."""
     from ray_tpu.ops import losses
     from ray_tpu.train.lm import create_train_state, default_optimizer, make_train_step
 
-    config = tiny_olmoe(top_k=2, max_seq=128)
+    config = tiny_olmoe(top_k=2, max_seq=256)
     opt = default_optimizer(1e-3, total_steps=10)
-    shape = (8, 129)
+    shape = (8, 257)
 
     def chunk_on(spec, room_for_state):
         mesh = build_mesh(spec, devices=jax.devices()[:spec.num_devices])
         state, shardings = create_train_state(config, opt, jax.random.PRNGKey(0), mesh)
         step = make_train_step(config, opt, mesh, state_shardings=shardings)
         whole = sum(x.nbytes for x in jax.tree.leaves((state, state.params)))
-        rows = shape[0] // (spec.dp * spec.fsdp)
-        logits = rows * 128 * config.vocab_size * 10
-        monkeypatch.setattr(losses, "device_hbm_bytes",
-                            lambda: logits + int(room_for_state * whole))
+        logits = losses.loss_logits_bytes(
+            shape[0] // (spec.dp * spec.fsdp), 256, config.vocab_size // spec.tp, 256)
+        monkeypatch.setattr(losses, "device_hbm_bytes", lambda: int(
+            (logits + room_for_state * whole) / (1 - losses.HBM_FREE_FRACTION)))
         return step.loss_chunk_for(shape, state)
 
     assert chunk_on(MeshSpec(), 0.5) == 128
-    assert chunk_on(MeshSpec(), 1.5) == 0
-    assert chunk_on(MeshSpec(fsdp=2, tp=2), 0.5) == 0
+    assert chunk_on(MeshSpec(), 1.5) == 256
+    assert chunk_on(MeshSpec(fsdp=2, tp=2), 0.5) == 256

@@ -768,21 +768,35 @@ def test_flash_kernel_runs_per_shard_under_a_context_mesh():
                                    atol=1e-3, rtol=1e-3)
 
 
-def test_auto_loss_chunk_crossover():
-    """Pins the dense->fused crossover at the measured v5e numbers: batch
-    24 stays dense on a 16G chip, batch 32 (the measured regression) flips
-    to the fused chunked path (1,024, the whole sequence, would take more
-    than half the room the activations share: measured 6% slower than 512
-    with the chip 95% full); unknown HBM (CPU) always dense."""
+V5E_HBM = int(15.75 * 2 ** 30)
+# gpt2-small as `train/lm.head_and_remat_for` counts it: 124.4 M float32 parameters with AdamW's
+# two moments; their gradients and, a row, 12 layers x 18 x 768 bfloat16 activations
+GPT2S_STATE, GPT2S_GRADIENTS, GPT2S_ROW = 1.493e9, 0.498e9, 12 * 18 * 768 * 2
+
+
+@pytest.mark.parametrize("batch,seq,hbm,want", [
+    # the whole sequence as one chunk: 149,395 tokens/s at 80.2% of a v5e against 149,090 (512),
+    # 147,923 (256) and the dense head's 143,573 at 94.5% (chip, PERF.md section 6, PR 46)
+    (24, 1024, V5E_HBM, 1024),
+    # two chunks: 148,833 at 87.9% against 148,226 (256); the whole sequence (estimate 17.8 GB
+    # of 16.9) ran at the compiler's ceiling, 94.8%, and lost 5.6% (same runs)
+    (32, 1024, V5E_HBM, 512),
+    # a sequence that few candidates divide: itself, 256, 128; none fits, so the smallest
+    (32, 1280, V5E_HBM, 128),
+    (8, 1280, V5E_HBM, 1280),
+    # a device of unknown size (the CPU) keeps the dense head
+    (1024, 1024, None, 0),
+    (24, 1024, 0, 0),
+], ids=["gpt2s-24-whole-sequence", "gpt2s-32-two-chunks", "gpt2s-32x1280-smallest", "gpt2s-8x1280-whole",
+        "unknown-size-dense", "size-0-dense"])
+def test_auto_loss_chunk_crossover(batch, seq, hbm, want):
+    """The fused head's chunk at the measured v5e points: no dense head
+    where the device's size is known, the whole sequence where the head's
+    moment has room for it, the next candidate that divides S where not."""
     from ray_tpu.ops.losses import auto_loss_chunk
 
-    v5e = 16 * 1024**3
-    assert auto_loss_chunk(24, 1024, 50257, v5e) == 0
-    assert auto_loss_chunk(32, 1024, 50257, v5e) == 512
-    # seq indivisible by the preferred chunks falls back down the ladder
-    assert auto_loss_chunk(32, 1280, 50257, v5e) in (256, 128, 0)
-    assert auto_loss_chunk(1024, 1024, 50257, None) == 0  # no HBM info
-    assert auto_loss_chunk(24, 1024, 50257, 0) == 0
+    assert auto_loss_chunk(batch, seq, 50257, hbm, resident_bytes=int(GPT2S_STATE),
+                           step_bytes=int(GPT2S_GRADIENTS + batch * seq * GPT2S_ROW)) == want
 
 
 def test_check_kernel_fallbacks_wired():
@@ -855,15 +869,25 @@ def _head_case(dtype=jnp.float32):
 
 
 @pytest.mark.parametrize(
-    "case", ["plain", "mask", "z_loss", "mask_z_loss", "cotangent", "tied", "jit", "bf16"])
+    "case", ["plain", "mask", "z_loss", "mask_z_loss", "cotangent", "tied", "jit", "bf16",
+             "whole_sequence", "whole_sequence_mask_z_loss", "whole_sequence_bf16",
+             "module_mask", "whole_sequence_module_mask"])
 def test_chunked_head_value_and_gradients_match_the_dense_loss(case):
     """`fused_linear_cross_entropy` computes dx and dW in the chunk's
     forward pass (one custom_vjp); the dense `cross_entropy_loss` under
-    plain autodiff is the reference for the value and both gradients."""
+    plain autodiff is the reference for the value and both gradients. Also
+    where the rule sends it since PR 46: the whole sequence as ONE chunk (a
+    scan of one step), and the multi-token prediction module's pass
+    (`train/lm.lm_loss`: the targets rolled by one, the last position, which
+    has no such target, masked out)."""
     from ray_tpu.ops.losses import fused_linear_cross_entropy
 
-    dtype = jnp.bfloat16 if case == "bf16" else jnp.float32
+    dtype = jnp.bfloat16 if "bf16" in case else jnp.float32
     x, head, targets, mask = _head_case(dtype)
+    if "module_mask" in case:
+        targets = jnp.roll(targets, -1, axis=1)
+        mask = jnp.broadcast_to(jnp.arange(targets.shape[1]) < targets.shape[1] - 1, targets.shape)
+    chunk = x.shape[1] if "whole_sequence" in case else 16
     kw = {"mask": mask if "mask" in case else None,
           "z_loss_coeff": 1e-2 if "z_loss" in case else 0.0}
     scale = 3.0 if case == "cotangent" else 1.0
@@ -876,7 +900,7 @@ def test_chunked_head_value_and_gradients_match_the_dense_loss(case):
         return scale * cross_entropy_loss(logits, targets, **kw)[0]
 
     def chunked(x, w):
-        loss, num = fused_linear_cross_entropy(x, weights(w), targets, chunk=16, **kw)
+        loss, num = fused_linear_cross_entropy(x, weights(w), targets, chunk=chunk, **kw)
         return scale * loss, num
 
     want, (want_dx, want_dw) = jax.value_and_grad(dense, argnums=(0, 1))(x, w)
@@ -886,8 +910,8 @@ def test_chunked_head_value_and_gradients_match_the_dense_loss(case):
     assert float(num) == (float(mask.sum()) if "mask" in case else targets.size)
     # float32: the sums run in another order; bfloat16: dx and dW leave the
     # dense path through the same casts, so one ulp (2^-8) of the largest entry
-    tol = {"rtol": 2e-2, "atol": 2e-3} if case == "bf16" else {"rtol": 1e-5, "atol": 1e-6}
-    np.testing.assert_allclose(float(got), float(want), rtol=1e-2 if case == "bf16" else 1e-5)
+    tol = {"rtol": 2e-2, "atol": 2e-3} if "bf16" in case else {"rtol": 1e-5, "atol": 1e-6}
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-2 if "bf16" in case else 1e-5)
     np.testing.assert_allclose(np.asarray(got_dx, np.float32), np.asarray(want_dx, np.float32), **tol)
     np.testing.assert_allclose(np.asarray(got_dw, np.float32), np.asarray(want_dw, np.float32), **tol)
     # the undifferentiated call (evaluation) is the same loss

@@ -402,7 +402,10 @@ WHOLE_BLOCK_TEMP_GIB, WHOLE_BLOCK_TFLOP = 6.51, 14.98
 ], ids=["unknown-device-size", "v5e-15.75GiB"])
 def test_mistral_cell_step_keeps_what_fits_and_compiles(
         as_tpu, monkeypatch, mistral_cell_step, hbm_gib, want):
-    """The cell's whole step for the described v5e:2x2, dense head. A device
+    """The cell's whole step for the described v5e:2x2, with the head the
+    rule gives it: the dense one on a device of unknown size, since PR 46
+    the fused one (a device's 12 x 1,024 rows as one chunk) at the chip's
+    15.75 GiB. A device
     of unknown size gets the whole-block program (5.63 GiB of arguments and
     6.51 of temporaries a chip, 14.98 TFLOP with the scanned block counted
     once, 21 all-reduces). At the chip's 15.75 GiB the rule keeps gate, up
@@ -423,9 +426,10 @@ def test_mistral_cell_step_keeps_what_fits_and_compiles(
 
     config, opt, mesh, shardings, state, tokens = mistral_cell_step
     monkeypatch.setattr(losses, "device_hbm_bytes", lambda: int(hbm_gib * GIB))
-    step = make_train_step(config, opt, mesh, state_shardings=shardings, loss_chunk=0)
+    step = make_train_step(config, opt, mesh, state_shardings=shardings)
     plan = step.remat_plan_for(tokens.shape, state)
     assert (plan["remat"], plan["remat_saved"]) == want
+    assert step.loss_chunk_for(tokens.shape, state) == (1024 if hbm_gib else 0)
     compiled = step.lower(state, {"tokens": tokens}).compile()
     memory, tflop = compiled.memory_analysis(), compiled.cost_analysis()["flops"] / 1e12
     all_reduces = compiled.as_text().count(" all-reduce(")
@@ -473,7 +477,7 @@ def _held_passes_not_taken(lowered, compiled, tokens_by_width) -> tuple:
 def test_trinity_cell_step_keeps_the_attention_outputs_and_compiles(as_tpu, monkeypatch, v5e):
     """`train-trinity-mini-8k`'s whole step (2 x 8,193 tokens, the state as
     `create_train_state` builds it) for one described v5e chip of 15.75 GiB:
-    the rule chunks the head and keeps the attention kernels' outputs, and
+    the rule fuses the head and keeps the attention kernels' outputs, and
     the compiled step runs each forward flash kernel once a layer (the
     two scanned dense layers' in the forward loop's body alone, the four
     unrolled layers' once each) beside its one backward kernel; a
@@ -489,7 +493,9 @@ def test_trinity_cell_step_keeps_the_attention_outputs_and_compiles(as_tpu, monk
     step = make_train_step(config, opt, mesh, state_shardings=shardings)
     plan = step.remat_plan_for(tokens.shape, state)
     assert (plan["remat"], plan["remat_saved"]) == ("selective", ("attn_out", "attn_lse"))
-    assert step.loss_chunk_for(tokens.shape, state) == 2048
+    # the whole sequence as the fused head's one chunk since PR 46 (the chip, one seed: 2,048 rows
+    # 28,873 tokens/s at 89.18% of memory, the whole 8,192 28,902 at 88.56%)
+    assert step.loss_chunk_for(tokens.shape, state) == 8192
     lowered = step.lower(state, {"tokens": tokens})
     compiled = lowered.compile()
     # one body a signature (float32 with gates, bfloat16 without) however many call it:
@@ -528,7 +534,7 @@ def test_smallthinker_cell_step_keeps_what_a_four_layer_iteration_leaves_room_fo
     layers. Counted as a one-layer iteration the estimate kept everything and
     the compiler refused the step (17.04 GB with the head chunked); with the
     other three layers' slices counted the rule keeps the attention kernels'
-    outputs, chunks the head, and the step compiles: each forward flash kernel
+    outputs, fuses the head, and the step compiles: each forward flash kernel
     once a layer of the period in the forward loop's body alone."""
     from ray_tpu.ops import losses
     from ray_tpu.train.lm import make_train_step
@@ -540,7 +546,9 @@ def test_smallthinker_cell_step_keeps_what_a_four_layer_iteration_leaves_room_fo
     step = make_train_step(config, opt, mesh, state_shardings=shardings)
     plan = step.remat_plan_for(tokens.shape, state)
     assert (plan["remat"], plan["remat_saved"]) == ("selective", ("attn_out", "attn_lse"))
-    assert step.loss_chunk_for(tokens.shape, state) == 2048
+    # the whole sequence as the fused head's one chunk since PR 46 (the chip, one seed: 2,048 rows
+    # 25,871 tokens/s at 92.59% of memory, the whole 16,384 25,922 at 92.50%)
+    assert step.loss_chunk_for(tokens.shape, state) == 16384
     lowered = step.lower(state, {"tokens": tokens})
     compiled = lowered.compile()
     # eF eS eS eS, scanned twice: one body forward, one backward
@@ -564,7 +572,7 @@ def test_glm47flash_cell_step_keeps_the_attention_outputs_and_the_latents_and_co
     v5e chip of 15.75 GiB: a dense latent-attention layer, four scanned expert
     layers and the multi-token prediction module's block. The rule keeps the
     attention kernels' outputs and the latents (after which the backward
-    repeats the up-projections alone) and leaves the head dense; the compiled
+    repeats the up-projections alone) beside the fused head; the compiled
     step runs the forward flash kernel at D = 256 once a body (the dense
     layer, the scan's body, the module), the module's block and the second
     pass of the head lie under `mtp`, and the latent projections under
@@ -583,7 +591,10 @@ def test_glm47flash_cell_step_keeps_the_attention_outputs_and_the_latents_and_co
         "attn_out", "attn_lse", "attn_latent_q", "attn_latent_kv", "attn_latent_k_rope"))
     # 6 layers x 16,384 rows x (5,120 + 40 + 768 + 512 + 64) bfloat16 features
     assert plan["remat_saved_bytes"] == 6 * 16384 * (5120 + 40 + 1344) * 2
-    assert step.loss_chunk_for(tokens.shape, state) == 0
+    # both passes of the head, the stack's and the module's, fused with the whole sequence as
+    # the one chunk since PR 46 (the chip, one seed: dense 27,964 tokens/s at 90.28% of
+    # memory, the whole 8,192 27,986 at 89.32%)
+    assert step.loss_chunk_for(tokens.shape, state) == 8192
     lowered = step.lower(state, {"tokens": tokens})
     compiled = lowered.compile()
     for kernel in ("flash_fwd", "flash_bwd_dkv_dq"):

@@ -307,35 +307,46 @@ TRINITY = ("trinity-mini-train-1chip", MeshSpec())
 
 MATMUL_NAMES = ("attn_residual", "mlp_up", "mlp_gate")
 ATTN_OUT = ("attn_out", "attn_lse")   # the kernel's output and its lse: one candidate
+LATENTS = ("attn_latent_q", "attn_latent_kv", "attn_latent_k_rope")   # a latent-attention layer's
 
 
 @pytest.mark.parametrize("cell,mesh_spec,hbm,batch,seq,changed,want", [
     # every matmul output, as since PR 30. Not the attention output, which has room (0.41 GB
     # a device): at S = 1,024 the kernel's second run costs no more than keeping its
-    # results moves (the chip: 38,334 tokens/s without it, 38,318 with it, PERF.md section 6)
-    (*MISTRAL, V5E_HBM, 24, 1024, {}, ("selective", MATMUL_NAMES, 0)),
+    # results moves (the chip: 38,334 tokens/s without it, 38,318 with it, PERF.md section 6).
+    # The head, here and below: the fused one with the device's whole sequence as its one
+    # chunk (12 x 1,024 x 16,384 logits a device; the chip, PR 28: -0.03% against dense)
+    (*MISTRAL, V5E_HBM, 24, 1024, {}, ("selective", MATMUL_NAMES, 1024)),
     # beside two more layers of state one value as wide as up fits, not two (before PR 34's
     # refit, which leaves 6.5% of the chip free where it left 10%: the narrow residual alone)
-    (*MISTRAL, V5E_HBM, 24, 1024, {"n_layers": 10}, ("selective", ("attn_residual", "mlp_up"), 0)),
+    (*MISTRAL, V5E_HBM, 24, 1024, {"n_layers": 10}, ("selective", ("attn_residual", "mlp_up"), 1024)),
     # no room at all: the whole-block program, not an out-of-memory error
-    (*MISTRAL, V5E_HBM, 24, 1024, {"n_layers": 12}, ("whole_block", (), 0)),
+    (*MISTRAL, V5E_HBM, 24, 1024, {"n_layers": 12}, ("whole_block", (), 1024)),
     # twice the batch: the narrow residual (before the refit: nothing); with ten layers nothing
-    (*MISTRAL, V5E_HBM, 48, 1024, {}, ("selective", ("attn_residual",), 0)),
-    (*MISTRAL, V5E_HBM, 48, 1024, {"n_layers": 10}, ("whole_block", (), 512)),
+    (*MISTRAL, V5E_HBM, 48, 1024, {}, ("selective", ("attn_residual",), 1024)),
+    (*MISTRAL, V5E_HBM, 48, 1024, {"n_layers": 10}, ("whole_block", (), 1024)),
     # a device of unknown size (the CPU): the step that fits wherever anything does
     (*MISTRAL, 0, 24, 1024, {}, ("whole_block", (), 0)),
-    ("gpt2-small-train-1chip", MeshSpec(), V5E_HBM, 24, 1024, {}, ("off", (), 0)),
-    ("olmoe-1b-7b-train-1chip", MeshSpec(), V5E_HBM, 4, 4096, {}, ("off", (), 2048)),
+    # the chip, PR 46: dense 143,573 tokens/s at 94.5%, 512 rows 149,090, the whole 1,024 149,395 at 80.2%
+    ("gpt2-small-train-1chip", MeshSpec(), V5E_HBM, 24, 1024, {}, ("off", (), 1024)),
+    # the whole sequence beside 32 rows' activations would run at the compiler's ceiling (140,453
+    # at 94.8% against 148,833 with two chunks at 87.9%)
+    ("gpt2-small-train-1chip", MeshSpec(), V5E_HBM, 32, 1024, {}, ("off", (), 512)),
+    # 2,048 rows 85,961 at 76.3%, the whole 4,096 87,462 at 77.9%
+    ("olmoe-1b-7b-train-1chip", MeshSpec(), V5E_HBM, 4, 4096, {}, ("off", (), 4096)),
     # a family that names no candidates is recomputed whole wherever it recomputes
     ("olmoe-1b-7b-train-1chip", MeshSpec(), 4 * V5E_HBM, 4, 4096, {"remat": True},
-     ("whole_block", (), 0)),
-    # the mixed stack: the attention kernels' outputs, beside the chunked head
-    (*TRINITY, V5E_HBM, 2, 8192, {}, ("selective", ATTN_OUT, 2048)),
-    (*TRINITY, V5E_HBM, 4, 8192, {}, ("whole_block", (), 2048)),
+     ("whole_block", (), 4096)),
+    # the mixed stack: the attention kernels' outputs, beside the fused head
+    (*TRINITY, V5E_HBM, 2, 8192, {}, ("selective", ATTN_OUT, 8192)),
+    (*TRINITY, V5E_HBM, 4, 8192, {}, ("whole_block", (), 8192)),
     (*TRINITY, 0, 2, 8192, {}, ("whole_block", (), 0)),
+    ("smallthinker-21b-a3b-train-1chip", MeshSpec(), V5E_HBM, 1, 16384, {}, ("selective", ATTN_OUT, 16384)),
+    # dense 27,956 tokens/s at 90.3%, the whole 8,192 27,938 at 89.3% (2,048: 27,833, 4,096: 27,709)
+    ("glm-4.7-flash-train-1chip", MeshSpec(), V5E_HBM, 2, 8192, {}, ("selective", ATTN_OUT + LATENTS, 8192)),
 ], ids=["mistral-2x2", "mistral-2x2-10-layers", "mistral-2x2-12-layers", "mistral-2x2-batch-48",
-        "mistral-2x2-batch-48-10-layers", "mistral-2x2-unknown-size", "gpt2s", "olmoe", "olmoe-with-remat",
-        "trinity", "trinity-batch-4", "trinity-unknown-size"])
+        "mistral-2x2-batch-48-10-layers", "mistral-2x2-unknown-size", "gpt2s", "gpt2s-batch-32", "olmoe",
+        "olmoe-with-remat", "trinity", "trinity-batch-4", "trinity-unknown-size", "smallthinker", "glm47flash"])
 def test_remat_rule_on_the_cells_numbers(monkeypatch, cell, mesh_spec, hbm, batch, seq, changed, want):
     """`make_train_step(...)`'s `remat_plan_for(shape, state)` and
     `loss_chunk_for(shape, state)` at the shipped cells' widths, meshes and
@@ -344,16 +355,18 @@ def test_remat_rule_on_the_cells_numbers(monkeypatch, cell, mesh_spec, hbm, batc
     twice the batch fewer fit, with twelve or with both nothing does and the
     whole block is recomputed, as on a device whose size is unknown. The
     attention output is worth keeping at S = 8,192, not at 1,024. The Trinity cell
-    keeps the attention kernels' outputs of its mixed stack beside the
-    CHUNKED head (its dense head's logits, 4.1 GB, are larger than any
-    block's backward pass, and the next candidate has no room): the two
+    keeps the attention kernels' outputs of its mixed stack: the two
     scanned dense layers' kept values are still held when every gradient
     exists, which is the moment that binds; twice the batch keeps nothing; an
-    unknown size keeps nothing and the dense head. The cells without `remat`
-    recompute nothing, and their heads are what `auto_loss_chunk` alone
-    says. The same numbers, the same plan: nothing live is read but the
-    device's size."""
-    from ray_tpu.train import lm
+    unknown size keeps nothing. The cells without `remat` recompute nothing.
+    Every head on a device of known size is the fused one (since PR 46;
+    each changed row carries the chip's reading), its chunk the largest
+    candidate, the whole sequence first, with which the head's moment
+    leaves 6.5% of the chip free: the whole sequence in every shipped
+    cell, two chunks for gpt2-small at 32 rows; an unknown size keeps the
+    dense head. The same numbers, the same plan: nothing live is read but
+    the device's size."""
+    from ray_tpu.ops import losses
 
     step, state = _cell_step(monkeypatch, cell, mesh_spec, hbm, **changed)
     plan = step.remat_plan_for((batch, seq + 1), state)
@@ -382,14 +395,15 @@ def test_remat_rule_on_the_cells_numbers(monkeypatch, cell, mesh_spec, hbm, batc
         assert plan["remat_recomputed_flops_share"] == pytest.approx(0.132, abs=0.001)
         # beside the whole-block step as the chip measured it (11.145 GB; the
         # estimate reads a little under), the stated share stays free
-        assert 11.145e9 + plan["remat_saved_bytes"] < (1 - lm._REMAT_FREE_FRACTION) * hbm
+        assert 11.145e9 + plan["remat_saved_bytes"] < (1 - losses.HBM_FREE_FRACTION) * hbm
 def test_remat_rule_keeps_in_order_of_recomputation_spared_a_byte():
     """The candidates one by one, not as a pair: with room for one d_ff-wide
     value, one is kept and the residual beside it; a spared all-reduce
     counts, so under tensor parallelism the residual can come first; a
     candidate is as large as the layers that write it."""
     from ray_tpu.models.transformer import RematCandidate
-    from ray_tpu.train.lm import _ALL_REDUCE_FLOPS_PER_BYTE, _REMAT_FREE_FRACTION, auto_remat_saved
+    from ray_tpu.ops.losses import HBM_FREE_FRACTION
+    from ray_tpu.train.lm import _ALL_REDUCE_FLOPS_PER_BYTE, auto_remat_saved
 
     def held_bytes(cs):
         return sum(100 * sum(c.layers) * 2 * c.width for c in cs)
@@ -397,7 +411,7 @@ def test_remat_rule_keeps_in_order_of_recomputation_spared_a_byte():
     def kept(candidates, room_rows):
         got, held = auto_remat_saved(
             candidates, rows=100, itemsize=2, peak_bytes=held_bytes,
-            hbm_bytes=int(room_rows * 100 * 2 * 2 / (1 - _REMAT_FREE_FRACTION)) + 1)
+            hbm_bytes=int(room_rows * 100 * 2 * 2 / (1 - HBM_FREE_FRACTION)) + 1)
         assert held == held_bytes(got)
         return tuple(name for c in got for name in c.names)
 
