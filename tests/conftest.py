@@ -8,6 +8,18 @@ how the reference tests multi-node behavior with N raylets on one machine
 
 import os
 
+# WHERE A LONG TEST LIVES. The driver runs this tree with `-n 6 --dist
+# loadfile`: xdist hands out whole FILES, ordered by their number of tests,
+# most first, and gives a worker its next file when it has two tests or fewer
+# left. So a file of few tests starts last, and a long test at the end of a
+# file of many holds the next file back on a busy worker: the wall follows
+# the place of the long tests, not the work. The rule: a test over 25 s (on
+# the driver's loaded machine: ~12 s alone) lives in a file of at most 6
+# tests (`*_long.py` beside its origin, `test_tpu_compile_cells.py` for a
+# cell's compiled step), and such a file takes no more than 450 s. A new
+# model's compile test and reference test go there from the start.
+# `python scripts/suite_schedule.py <junit.xml>` names what breaks it.
+
 # Tests run on the CPU backend whatever the machine holds: the chip is
 # exercised by chip_smoke.py, one process at a time, never by pytest. The
 # variables are set before jax is imported and are inherited by every

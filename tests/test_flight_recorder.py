@@ -280,8 +280,13 @@ def test_serve_slo_attainment_ledger():
     from ray_tpu.util.watchdog import ServeSLOMonitor
 
     # a fresh monitor's first window is everything the process's serve
-    # histograms ever saw: start from none (earlier test files fill them)
+    # histograms ever saw, and every tenant's TTFT samples that an engine of
+    # an earlier test file left undrained (one `ttft_p99:<tenant>` SLO each,
+    # and the report below takes the minimum over all): start from none
+    from ray_tpu.serve import tenancy
+
     registry().clear()
+    tenancy.reset()
     cfg.set(serve_slo_ttft_p99_s=0.05)
     try:
         hist = get_or_create_histogram(

@@ -389,65 +389,6 @@ def test_router_grace_window_keeps_cached_replicas(monkeypatch):
 # --------------------------------------------------------------------------
 
 
-def test_head_restart_restores_surviving_agent():
-    tmp = tempfile.mkdtemp(prefix="ray_tpu_headrestart_")
-    snap = os.path.join(tmp, "gcs.snap")
-    port = _free_port()
-    address = f"127.0.0.1:{port}"
-    head_log = os.path.join(tmp, "head.log")
-    agent_log = os.path.join(tmp, "agent.log")
-
-    head_cmd = [
-        sys.executable, "-m", "ray_tpu", "--no-tpu", "start", "--head",
-        "--port", str(port), "--num-cpus", "1", "--snapshot-path", snap,
-    ]
-    head = _spawn(head_cmd, open(head_log, "w"))
-    agent = None
-    try:
-        _wait_line(head_log, "head up", proc=head)
-        agent = _spawn(
-            [sys.executable, "-m", "ray_tpu", "--no-tpu", "start",
-             "--address", address, "--num-cpus", "2",
-             "--resources", '{"pet": 3}'],
-            open(agent_log, "w"),
-        )
-        _wait_line(agent_log, "joined", proc=agent)
-
-        # observer 1: the agent's resources are visible pre-kill
-        out = subprocess.run(
-            [sys.executable, "-c", _OBSERVER, address, "pet", "3"],
-            env=_ENV, capture_output=True, text=True, timeout=120,
-        )
-        assert "OBSERVER-OK" in out.stdout, out.stdout + out.stderr
-        agent_pid_1 = int(out.stdout.split("OBSERVER-OK")[1].strip())
-        assert agent_pid_1 == agent.pid
-
-        # give the snapshot loop a beat to persist the node table
-        time.sleep(2.0)
-
-        # kill the head hard; the agent keeps running (heartbeats warn)
-        head.send_signal(signal.SIGKILL)
-        head.wait(timeout=30)
-        time.sleep(1.0)
-        assert agent.poll() is None, "agent must survive head death"
-
-        # restart the head from the snapshot, same port
-        head = _spawn(head_cmd + ["--restore"], open(head_log, "a"))
-        _wait_line(head_log, "head up", proc=head)
-
-        # observer 2: the surviving agent (same pid!) re-registered and
-        # still executes work — no agent restart happened
-        out = subprocess.run(
-            [sys.executable, "-c", _OBSERVER, address, "pet", "3"],
-            env=_ENV, capture_output=True, text=True, timeout=120,
-        )
-        assert "OBSERVER-OK" in out.stdout, out.stdout + out.stderr
-        agent_pid_2 = int(out.stdout.split("OBSERVER-OK")[1].strip())
-        assert agent_pid_2 == agent.pid == agent_pid_1
-    finally:
-        _terminate(head, agent)
-
-
 @pytest.mark.slow
 def test_head_restart_reconciles_lost_state():
     """Restore brings back a node that died DURING the outage plus actor

@@ -176,7 +176,7 @@ def test_a_block_matches_the_plain_reference(mlp):
     x = jax.random.normal(jax.random.PRNGKey(5), (2, 48, 64))
     tables = rope_frequencies(config.rotary_dims, 48, config.rope_theta)
     ours, _ = jax.jit(lambda x, lp: _block(x, lp, config, LayerKind("latent", mlp), tables, None))(x, lp)
-    theirs, chosen = ref._layer_fn(mlp == "dense", query_block=16, **arch(config))(x, lp)
+    theirs, chosen = jax.jit(ref._layer_fn(mlp == "dense", query_block=16, **arch(config)))(x, lp)
     assert (chosen is None) == (mlp == "dense")
     np.testing.assert_allclose(np.asarray(ours), np.asarray(theirs), atol=2e-5)
     low = dataclasses.replace(config, dtype=jnp.bfloat16)

@@ -1,5 +1,7 @@
 """Model tests: GPT-2/Llama forward, decode-cache equivalence, sharded run."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -151,7 +153,7 @@ def test_grad_flows(model):
         l, _ = cross_entropy_loss(logits[:, :-1], tokens[:, 1:])
         return l
 
-    g = jax.grad(loss)(params)
+    g = jax.jit(jax.grad(loss))(params)
     norms = [float(jnp.linalg.norm(x)) for x in jax.tree.leaves(g)]
     assert all(np.isfinite(norms))
     assert sum(norms) > 0
@@ -208,20 +210,41 @@ def _kept_sets(names):
             for mask in range(1, 2 ** len(names))]
 
 
-@pytest.mark.parametrize("preset,saved", [
+def _kept_id(v):
+    return v if isinstance(v, str) else "+".join(v) if v else "whole_block"
+
+
+@pytest.fixture(scope="module")
+def keeps_everything():
+    """preset -> (loss, gradients) of the step with `remat` off, computed
+    once a preset: what every kept set of that preset is compared with."""
+    return functools.cache(lambda preset: _remat_loss_and_grads(preset, False))
+
+
+# The `mixed-pallas` cases of this list and of `_KEPT_OUTPUT_CASES` take 20-45 s
+# each: tests/test_models_long.py runs them, from these lists (the rule in
+# tests/conftest.py).
+_REMAT_CASES = [
     *(("llama-tiny", kept) for kept in [None, *_kept_sets(REMAT_NAMES)]),
     # a GELU block has no gate: what the rule can choose there
     *(("gpt2-tiny", kept) for kept in [None, *_kept_sets(("mlp_up", "attn_residual"))]),
     # through the kernels: the attention output with its lse, alone and beside the rest
     *(("llama-tiny-pallas", kept) for kept in [None, ATTN_OUT, ATTN_OUT + REMAT_NAMES]),
     *(("mixed-pallas", kept) for kept in [None, ATTN_OUT, ATTN_OUT + REMAT_NAMES]),
-], ids=lambda v: v if isinstance(v, str) else "+".join(v) if v else "whole_block")
+]
+
+
+def _long(case):
+    return case[0] == "mixed-pallas"
+
+
+@pytest.mark.parametrize("preset,saved", [case for case in _REMAT_CASES if not _long(case)], ids=_kept_id)
 def test_a_recomputing_block_gives_the_loss_and_gradients_of_one_that_keeps_everything(
-        preset, saved):
+        preset, saved, keeps_everything):
     """`remat` off, the whole block recomputed, and every set of names a
     policy can keep: the kept values are the ones the forward computed, so
     the loss and every gradient leaf are those of the step that keeps all."""
-    want_loss, want_grads = _remat_loss_and_grads(preset, False)
+    want_loss, want_grads = keeps_everything(preset)
     loss, grads = _remat_loss_and_grads(preset, True, saved or ())
     assert float(loss) == float(want_loss)
     for (path, want), got in zip(jax.tree_util.tree_flatten_with_path(want_grads)[0],
@@ -278,12 +301,16 @@ def _kernel_calls(jaxpr, name) -> int:
     return count
 
 
-@pytest.mark.parametrize("preset,kernels", [
+_KEPT_OUTPUT_CASES = {
     # one scanned block
-    ("llama-tiny-pallas", {"flash_fwd": 1}),
+    "dense": ("llama-tiny-pallas", {"flash_fwd": 1}),
     # `dS dS` scanned, `eS eF eS eS` unrolled: 1 + 3 windowed bodies, 1 full
-    ("mixed-pallas", {"flash_win_fwd": 4, "flash_fwd": 1}),
-], ids=["dense", "mixed"])
+    "mixed": ("mixed-pallas", {"flash_win_fwd": 4, "flash_fwd": 1}),
+}
+
+
+@pytest.mark.parametrize("preset,kernels", [pytest.param(*case, id=name) for name, case
+                                            in _KEPT_OUTPUT_CASES.items() if not _long(case)])
 def test_the_forward_kernel_is_not_run_again_in_the_backward_pass_when_output_and_lse_are_kept(
         preset, kernels):
     """The forward flash kernel in the jaxpr of a step's gradient, a block

@@ -25,7 +25,7 @@ def forward(params, tokens, config):
     return logits, routers["router_aux_loss"]
 
 
-@pytest.fixture
+@pytest.fixture(scope="module")
 def model():
     config = moe_tiny()
     params = init_params(config, jax.random.PRNGKey(0))
@@ -56,7 +56,7 @@ def test_topk_dispatch_capacity_drops():
 def test_forward_shapes_and_aux(model):
     config, params = model
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, config.vocab_size)
-    logits, aux = forward(params, tokens, config)
+    logits, aux = jax.jit(lambda p, t: forward(p, t, config))(params, tokens)
     assert logits.shape == (2, 16, config.vocab_size)
     assert np.isfinite(np.asarray(logits)).all()
     # balanced-ish routing at init → aux near 1.0 (its minimum is 1)
@@ -83,7 +83,7 @@ def test_param_axes_match(model):
 def test_grad_flows_including_router(model):
     config, params = model
     tokens = jax.random.randint(jax.random.PRNGKey(2), (2, 16), 0, config.vocab_size)
-    grads = jax.grad(lambda p: lm_loss(p, tokens, config)[0])(params)
+    grads = jax.jit(jax.grad(lambda p: lm_loss(p, tokens, config)[0]))(params)
     router_norm = float(jnp.linalg.norm(grads["blocks"]["router"]))
     expert_norm = float(jnp.linalg.norm(grads["blocks"]["we_up"]))
     assert np.isfinite(router_norm) and router_norm > 0
@@ -180,9 +180,10 @@ def test_the_gated_unit_through_the_grouped_form_equals_the_dense_einsum(expert_
     config = dataclasses.replace(moe_tiny(), expert_act=expert_act)
     moe_mlp, lp, h = _one_layer(config)
     out, _ = moe_mlp(h, lp, config)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(_dense_experts(h, lp, config, act)), atol=2e-5)
-    ours = jax.grad(lambda lp: jnp.sum(moe_mlp(h, lp, config)[0] ** 2))(lp)
-    theirs = jax.grad(lambda lp: jnp.sum(_dense_experts(h, lp, config, act) ** 2))(lp)
+    dense = jax.jit(lambda h, lp: _dense_experts(h, lp, config, act))(h, lp)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(dense), atol=2e-5)
+    ours = jax.jit(jax.grad(lambda lp: jnp.sum(moe_mlp(h, lp, config)[0] ** 2)))(lp)
+    theirs = jax.jit(jax.grad(lambda lp: jnp.sum(_dense_experts(h, lp, config, act) ** 2)))(lp)
     for name in ("we_gate", "we_up", "we_down"):
         np.testing.assert_allclose(np.asarray(ours[name]), np.asarray(theirs[name]), atol=2e-4)
     with pytest.raises(ValueError, match="unknown expert activation"):

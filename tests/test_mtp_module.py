@@ -5,6 +5,7 @@ losses, every leaf's gradient; what the step reports of the module; and what
 the selective-recompute plan is told of the stack and the module's block."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -19,19 +20,37 @@ from ray_tpu.train.lm import lm_loss
 from test_latent_attention import arch, seeded, tiny_latent
 
 
+@pytest.fixture(scope="module")
+def by_the_reference():
+    """The tiny model, a batch and what glm4_moe_lite_ref makes of them, each
+    compiled as one program: (config, params, tokens, main logits, the
+    module's logits, (objective, (main loss, module loss)), gradients, the
+    objective as the sum of its row-at-a-time shares)."""
+    from benchmark.reference import glm4_moe_lite_ref as ref
+
+    config = tiny_latent()
+    params = seeded(config)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 49), 0, config.vocab_size)
+    main = jax.jit(functools.partial(ref.forward_logits, **arch(config)))(params, tokens[:, :-1])
+    module = jax.jit(functools.partial(ref.module_logits, **arch(config)))(params, tokens)
+    value, grads = jax.jit(jax.value_and_grad(
+        lambda p: ref.objective(p, tokens, mtp_loss_weight=0.3, **arch(config)), has_aux=True))(params)
+    part = jax.jit(functools.partial(ref.objective_part, total_tokens=2 * 48, mtp_loss_weight=0.3,
+                                     head_rows=16, query_block=16, **arch(config)))
+    share = sum(part(params, tokens[i: i + 1])[0] for i in range(2))
+    return config, params, tokens, main, module, value, grads, share
+
+
 @pytest.mark.parametrize("chunk", [0, 16], ids=["dense-head", "chunked-head"])
-def test_logits_both_losses_and_every_gradient_match_the_plain_reference(chunk):
+def test_logits_both_losses_and_every_gradient_match_the_plain_reference(chunk, by_the_reference):
     """The whole model with its module against glm4_moe_lite_ref, float32:
     the main logits and the module's (1e-4 of logits of size ~1: the sums'
     order through four blocks), the main and the module's loss (1e-5) and
     every leaf's gradient of main + 0.3 x module (1e-4 of the leaf's largest
     entry); bfloat16 compute reads 1e-2 on the logits and fails each."""
-    from benchmark.reference import glm4_moe_lite_ref as ref
-
-    config = tiny_latent()
+    (config, params, tokens, ref_main_logits, ref_module_logits,
+     (ref_objective, (ref_main, ref_module)), ref_grads, share) = by_the_reference
     family = model_family(config)
-    params = seeded(config)
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 49), 0, config.vocab_size)
 
     def logits(p, t):
         hidden, routers = family.forward_hidden(p, t[:, :-1], config)
@@ -40,19 +59,15 @@ def test_logits_both_losses_and_every_gradient_match_the_plain_reference(chunk):
         return jnp.einsum("bse,ev->bsv", hidden, head), jnp.einsum("bse,ev->bsv", module, head)
 
     main, module = jax.jit(logits)(params, tokens)
-    np.testing.assert_allclose(
-        np.asarray(main), np.asarray(ref.forward_logits(params, tokens[:, :-1], **arch(config))), atol=1e-4)
-    np.testing.assert_allclose(
-        np.asarray(module), np.asarray(ref.module_logits(params, tokens, **arch(config))), atol=1e-4)
+    np.testing.assert_allclose(np.asarray(main), np.asarray(ref_main_logits), atol=1e-4)
+    np.testing.assert_allclose(np.asarray(module), np.asarray(ref_module_logits), atol=1e-4)
     low = dataclasses.replace(config, dtype=jnp.bfloat16)
-    rounded, _ = family.forward_hidden(params, tokens[:, :-1], low)
+    rounded, _ = jax.jit(functools.partial(family.forward_hidden, config=low))(params, tokens[:, :-1])
     rounded = jnp.einsum("bse,ev->bsv", rounded.astype(jnp.float32), params["lm_head"])
     assert float(jnp.max(jnp.abs(rounded - main))) > 1e-3
 
     (objective, scalars), grads = jax.jit(jax.value_and_grad(
         lambda p: lm_loss(p, tokens, config, chunk=chunk), has_aux=True))(params)
-    (ref_objective, (ref_main, ref_module)), ref_grads = jax.value_and_grad(
-        lambda p: ref.objective(p, tokens, mtp_loss_weight=0.3, **arch(config)), has_aux=True)(params)
     assert abs(float(scalars["loss"]) - float(ref_main)) < 1e-5
     assert abs(float(scalars["mtp_loss"]) - float(ref_module)) < 1e-5
     assert abs(float(objective) - float(ref_objective)) < 1e-5
@@ -70,8 +85,6 @@ def test_logits_both_losses_and_every_gradient_match_the_plain_reference(chunk):
     # every other leaf, the module's too, gets a gradient (router and bias: the scanned run's, the module's)
     assert moved == len(jax.tree.leaves(grads)) - 2 * 2
     # the row-at-a-time share the first training steps are followed with adds up to the same
-    share = sum(ref.objective_part(params, tokens[i: i + 1], total_tokens=2 * 48, mtp_loss_weight=0.3,
-                                   head_rows=16, query_block=16, **arch(config))[0] for i in range(2))
     assert abs(float(share) - float(ref_objective)) < 1e-5
 
 
@@ -89,8 +102,13 @@ def test_the_step_reports_the_module_beside_the_stack():
     assert "mtp_loss" not in plain_scalars and float(scalars["mtp_loss"]) > 0
     assert float(objective) > float(plain)
     family = model_family(config)
-    hidden, routers = family.forward_hidden(params, tokens[:, :-1], config)
-    _, merged = family.mtp_hidden(params, hidden, tokens[:, 1:], config, routers)
+
+    @jax.jit
+    def reports(p, t):
+        hidden, routers = family.forward_hidden(p, t[:, :-1], config)
+        return routers, family.mtp_hidden(p, hidden, t[:, 1:], config, routers)[1]
+
+    routers, merged = reports(params, tokens)
     assert set(merged) == set(routers) == {"moe_load_max_over_mean", "moe_rows_held",
                                            "moe_rows_held_share", "moe_passes"}
     assert float(merged["moe_load_max_over_mean"]) >= float(routers["moe_load_max_over_mean"])

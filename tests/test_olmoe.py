@@ -18,6 +18,8 @@ BF16_LOGITS_REL_RMS = 0.02. bfloat16 compute (8 bits of mantissa) against
   renormalised (another gate rule) measured 5% to 7%.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -80,14 +82,15 @@ def _seeded(config, seed=0, batch=2, seq=32):
 def test_forward_and_every_gradient_leaf_equal_the_reference(top_k, norm_topk):
     config = tiny_olmoe(top_k, norm_topk_prob=norm_topk)
     params, tokens = _seeded(config, seed=top_k)
-    logits, aux = _forward(params, tokens[:, :-1], config)
-    ref_logits, ref_aux, _ = olmoe_ref.forward(params, tokens[:, :-1], **_arch(config))
+    logits, aux = jax.jit(functools.partial(_forward, config=config))(params, tokens[:, :-1])
+    ref_logits, ref_aux, _ = jax.jit(functools.partial(olmoe_ref.forward, **_arch(config)))(
+        params, tokens[:, :-1])
     assert _rel_rms(logits, ref_logits) <= LOGITS_REL_RMS
     assert float(aux) == pytest.approx(float(ref_aux), rel=1e-5)
 
-    grads = jax.grad(lambda p: lm_loss(p, tokens, config)[0])(params)
-    ref_grads = jax.grad(lambda p: olmoe_ref.objective(
-        p, tokens, router_aux_loss_coef=config.router_aux_coeff, **_arch(config))[0])(params)
+    grads = jax.jit(jax.grad(lambda p: lm_loss(p, tokens, config)[0]))(params)
+    ref_grads = jax.jit(jax.grad(lambda p: olmoe_ref.objective(
+        p, tokens, router_aux_loss_coef=config.router_aux_coeff, **_arch(config))[0]))(params)
     flat, _ = jax.tree_util.tree_flatten_with_path(grads)
     ref_flat = dict(jax.tree_util.tree_flatten_with_path(ref_grads)[0])
     assert len(flat) == len(ref_flat)
@@ -128,8 +131,8 @@ def test_dropless_path_drops_nothing_whatever_the_routing(case):
         for e in range(config.n_experts))
     assert _rel_rms(out, want) <= LOGITS_REL_RMS
     # the gradient reaches a loaded expert's weights and is exactly zero for an empty one's
-    grad = jax.grad(lambda w: jnp.sum(
-        moe._dropless_shard(h, probs, (weights[0], w, weights[2]), config)[0] ** 2))(lp["we_up"])
+    grad = jax.jit(jax.grad(lambda w: jnp.sum(
+        moe._dropless_shard(h, probs, (weights[0], w, weights[2]), config)[0] ** 2)))(lp["we_up"])
     assert float(jnp.abs(grad[int(np.argmax(load))]).max()) > 0
     assert float(jnp.abs(grad[int(np.argmin(load))]).max()) == 0
 
@@ -146,8 +149,9 @@ def test_lmtrainer_first_step_is_the_reference_loss_and_reports_the_routers():
     params0 = jax.tree.map(jnp.copy, trainer.state.params)
     tokens = np.random.default_rng(3).integers(0, 256, size=(8, 33)).astype(np.int32)
     out = trainer.train(iter([{"tokens": tokens}] * 3), num_steps=1, report_every=1)
-    _, parts = olmoe_ref.objective(params0, jnp.asarray(tokens),
-                                   router_aux_loss_coef=config.router_aux_coeff, **_arch(config))
+    _, parts = jax.jit(functools.partial(
+        olmoe_ref.objective, router_aux_loss_coef=config.router_aux_coeff, **_arch(config)))(
+        params0, jnp.asarray(tokens))
     assert out["loss"] == pytest.approx(float(parts["cross_entropy"]), abs=1e-5)
     assert out["router_aux_loss"] == pytest.approx(float(parts["router_aux"]), rel=1e-5)
     assert 1.0 <= out["moe_load_max_over_mean"] <= config.n_experts
@@ -205,7 +209,8 @@ def test_dropless_layer_on_a_mesh_is_the_one_device_layer_per_shard(axes, contex
     config = tiny_olmoe(top_k=2)
     params, tokens = _seeded(config, seed=11, batch=4)
     fn = jax.value_and_grad(lambda p: lm_loss(p, tokens, config), has_aux=True)
-    (want, want_scalars), want_grads = fn(params)
+    # a function of its own: the trace below has to see the sharded weights' mesh
+    (want, want_scalars), want_grads = jax.jit(lambda p: fn(p))(params)
 
     mesh = build_mesh(MeshSpec(**axes), devices=jax.devices()[:int(np.prod(list(axes.values())))])
     sharded = shard_tree(params, moe.logical_axes(config), default_rules(), mesh)
@@ -226,7 +231,7 @@ def test_bfloat16_compute_stays_near_the_reference_and_a_lower_precision_does_no
     params, tokens = _seeded(config, seed=7)
     # experts as loud as the residual stream, so that they are judged
     params["blocks"]["we_down"] = params["blocks"]["we_down"] * 10.0
-    ref_logits = olmoe_ref.forward_logits(params, tokens[:, :-1], **_arch(config))
+    ref_logits = jax.jit(functools.partial(olmoe_ref.forward_logits, **_arch(config)))(params, tokens[:, :-1])
 
     def off(params, config):
         logits, _ = _forward(params, tokens[:, :-1], config)
@@ -249,8 +254,9 @@ def test_norm_eps_on_the_dense_mistral_block_equals_the_reference_at_that_value(
     from ray_tpu.models import forward
 
     logits = forward(params, tokens, config)
-    at_published = transformer_ref.forward_logits(params, tokens, family="mistral", norm_eps=1e-5)
-    at_default = transformer_ref.forward_logits(params, tokens, family="mistral", norm_eps=1e-6)
+    at_published, at_default = (
+        jax.jit(functools.partial(transformer_ref.forward_logits, family="mistral", norm_eps=eps))(params, tokens)
+        for eps in (1e-5, 1e-6))
     assert _rel_rms(logits, at_published) <= LOGITS_REL_RMS
     assert _rel_rms(at_default, at_published) > 10 * LOGITS_REL_RMS
     # and None keeps the old arithmetic

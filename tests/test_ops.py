@@ -32,7 +32,7 @@ def test_flash_forward_matches_reference(causal, gqa):
     q = _rand(kq, (b, hq, s, d))
     k = _rand(kk, (b, hkv, s, d))
     v = _rand(kv, (b, hkv, s, d))
-    ref = mha_reference(q, k, v, causal=causal)
+    ref = jax.jit(lambda q, k, v: mha_reference(q, k, v, causal=causal))(q, k, v)
     out = flash_attention(q, k, v, causal=causal, implementation="pallas",
                           block_q=128, block_kv=128)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
@@ -46,7 +46,7 @@ def test_flash_forward_unpadded_vs_padded():
     q = _rand(kq, (b, h, s, d))
     k = _rand(kk, (b, h, s, d))
     v = _rand(kv, (b, h, s, d))
-    ref = mha_reference(q, k, v, causal=False)
+    ref = jax.jit(lambda q, k, v: mha_reference(q, k, v, causal=False))(q, k, v)
     out = flash_attention(q, k, v, causal=False, implementation="pallas",
                           block_q=128, block_kv=128)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
@@ -69,8 +69,8 @@ def test_flash_backward_matches_reference(causal):
         o = mha_reference(q, k, v, causal=causal)
         return jnp.sum(o * o)
 
-    gp = jax.grad(loss_pallas, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    gp = jax.jit(jax.grad(loss_pallas, argnums=(0, 1, 2)))(q, k, v)
+    gr = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
     for a, b_ in zip(gp, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=1e-3, rtol=1e-3)
 
@@ -89,8 +89,8 @@ def test_flash_backward_gqa():
     def loss_ref(q, k, v):
         return jnp.sum(mha_reference(q, k, v, causal=True) ** 2)
 
-    gp = jax.grad(loss_pallas, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    gp = jax.jit(jax.grad(loss_pallas, argnums=(0, 1, 2)))(q, k, v)
+    gr = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
     for a, b_ in zip(gp, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=1e-3, rtol=1e-3)
 
@@ -132,10 +132,10 @@ def test_flash_subtile_walk_matches_reference(case):
     kernel = lambda q, k, v: flash_attention(  # noqa: E731
         q, k, v, causal=causal, implementation="pallas")
     ref = lambda q, k, v: mha_reference(q, k, v, causal=causal)  # noqa: E731
-    np.testing.assert_allclose(np.asarray(kernel(q, k, v)),
-                               np.asarray(ref(q, k, v)), atol=2e-5, rtol=2e-5)
-    gk = jax.grad(loss(kernel), argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(np.asarray(jax.jit(kernel)(q, k, v)),
+                               np.asarray(jax.jit(ref)(q, k, v)), atol=2e-5, rtol=2e-5)
+    gk = jax.jit(jax.grad(loss(kernel), argnums=(0, 1, 2)))(q, k, v)
+    gr = jax.jit(jax.grad(loss(ref), argnums=(0, 1, 2)))(q, k, v)
     for a, b_ in zip(gk, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    atol=1e-3, rtol=1e-3)
@@ -165,12 +165,16 @@ def test_causal_grid_matches_reference_and_dense_grid(monkeypatch, case):
     k, v = (_rand(key, (1, hkv, s, 32)) for key in keys[1:3])
 
     def both(attend):
+        # functions of their own a call: jit keys its cache on the function, and
+        # the second call of `kernels` has to be traced under the patched grid
         loss = lambda q, k, v: jnp.sum(attend(q, k, v) * w)  # noqa: E731
-        return (attend(q, k, v), *jax.grad(loss, argnums=(0, 1, 2))(q, k, v))
+        out = jax.jit(lambda q, k, v: attend(q, k, v))(q, k, v)
+        forward_alone.append(set(names))
+        return (out, *jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v))
 
     kernels = lambda q, k, v: flash_attention(  # noqa: E731
         q, k, v, causal=True, implementation="pallas", block_q=block, block_kv=block)
-    names = []
+    names, forward_alone = [], []
     call = A.pl.pallas_call
     monkeypatch.setattr(A.pl, "pallas_call", lambda *a, **kw: (
         names.append((kw["name"], "grid_spec" in kw)), call(*a, **kw))[1])
@@ -180,6 +184,7 @@ def test_causal_grid_matches_reference_and_dense_grid(monkeypatch, case):
     monkeypatch.setattr(A, "_live_grid", lambda *a: False)
     dense = both(kernels)
     assert set(names) == {("flash_fwd", False), ("flash_bwd_dkv_dq", False)}
+    assert forward_alone == [{("flash_fwd", True)}, {("flash_fwd", False)}]
     reference = both(lambda q, k, v: mha_reference(q, k, v, causal=True))
     for got, was, want, tol in zip(live, dense, reference, (2e-5, 1e-3, 1e-3, 1e-3)):
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=tol, rtol=tol)
@@ -223,15 +228,15 @@ def test_one_backward_kernel_matches_reference(monkeypatch, case):
     if kv_len is None:
         def ours(q, k, v):
             return flash_attention(q, k, v, causal=causal, window=window, implementation="pallas")
-        got = jax.vjp(ours, q, k, v)[1](do)
+        got = jax.jit(lambda q, k, v, do: jax.vjp(ours, q, k, v)[1](do))(q, k, v, do)
     else:
         block = min(s, 1024)
         out, lse = A._fwd_pallas(q, k, v, causal, scale, block, block, kv_len, True)
         got = A._bwd_pallas(q, k, v, out, lse, do, causal, scale, block, block, kv_len, True)
     backward = "flash_win_bwd_dkv_dq" if window else "flash_bwd_dkv_dq"
     assert sorted(names) == sorted(["flash_win_fwd" if window else "flash_fwd", backward])
-    want = jax.vjp(lambda q, k, v: mha_reference(
-        q, k, v, causal=causal, window=window, kv_len=kv_len), q, k, v)[1](do)
+    want = jax.jit(lambda q, k, v, do: jax.vjp(lambda q, k, v: mha_reference(
+        q, k, v, causal=causal, window=window, kv_len=kv_len), q, k, v)[1](do))(q, k, v, do)
     for name, g, r in zip("qkv", got, want):
         assert g.shape == r.shape and g.dtype == r.dtype
         np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=2e-5 * float(jnp.max(jnp.abs(r))),
@@ -271,7 +276,7 @@ def test_flash_rows_no_subtile_reaches():
     out, lse = _fwd_pallas(q, k, v, True, 0.125, 1024, 1024, 700, True)
     assert np.isfinite(np.asarray(out)).all()
     assert np.isfinite(np.asarray(lse)).all() and (np.asarray(lse) > -1e29).all()
-    ref = mha_reference(q, k, v, causal=True, sm_scale=0.125, kv_len=700)
+    ref = jax.jit(lambda q, k, v: mha_reference(q, k, v, causal=True, sm_scale=0.125, kv_len=700))(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
 
@@ -550,9 +555,9 @@ def test_windowed_kernels_match_the_masked_reference(monkeypatch, s, window, blo
             q, k, v, causal=True, window=window, implementation=implementation,
             block_q=block, block_kv=block))
 
-    got = flash_attention(q, k, v, causal=True, window=window, implementation="pallas",
-                          block_q=block, block_kv=block)
-    want = mha_reference(q, k, v, causal=True, window=window)
+    got = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=window, implementation="pallas", block_q=block, block_kv=block))(q, k, v)
+    want = jax.jit(lambda q, k, v: mha_reference(q, k, v, causal=True, window=window))(q, k, v)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
     # the reference's window is the stated one: key j live iff i - window < j <= i
     rows, cols = np.arange(s)[:, None], np.arange(s)[None, :]
@@ -562,8 +567,8 @@ def test_windowed_kernels_match_the_masked_reference(monkeypatch, s, window, blo
     by_hand = np.einsum("hqk,hkd->hqd", probs / probs.sum(-1, keepdims=True),
                         np.repeat(np.asarray(v[0]), 2, 0))
     np.testing.assert_allclose(np.asarray(want[0]), by_hand, atol=2e-5)
-    for ours, theirs in zip(jax.grad(objective("pallas"), (0, 1, 2))(q, k, v),
-                            jax.grad(objective("xla"), (0, 1, 2))(q, k, v)):
+    for ours, theirs in zip(jax.jit(jax.grad(objective("pallas"), (0, 1, 2)))(q, k, v),
+                            jax.jit(jax.grad(objective("xla"), (0, 1, 2)))(q, k, v)):
         np.testing.assert_allclose(np.asarray(ours), np.asarray(theirs), atol=5e-5)
 
 
@@ -761,7 +766,7 @@ def test_flash_kernel_runs_per_shard_under_a_context_mesh():
                    in_shardings=(spec, spec, spec))
     assert "manual_computation" in step.lower(q, k, v).as_text()
     val, grads = step(q, k, v)
-    ref_val, ref_grads = jax.value_and_grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    ref_val, ref_grads = jax.jit(jax.value_and_grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
     np.testing.assert_allclose(float(val), float(ref_val), rtol=1e-4)
     for a, b_ in zip(grads, ref_grads):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
@@ -850,8 +855,8 @@ def test_fused_linear_cross_entropy_matches_dense():
     np.testing.assert_allclose(
         float(dense(x, head)), float(fused(x, head)), rtol=1e-5
     )
-    gd = jax.grad(dense, argnums=(0, 1))(x, head)
-    gf = jax.grad(fused, argnums=(0, 1))(x, head)
+    gd = jax.jit(jax.grad(dense, argnums=(0, 1)))(x, head)
+    gf = jax.jit(jax.grad(fused, argnums=(0, 1)))(x, head)
     for a, b_ in zip(gd, gf):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=1e-5)
 
@@ -903,7 +908,7 @@ def test_chunked_head_value_and_gradients_match_the_dense_loss(case):
         loss, num = fused_linear_cross_entropy(x, weights(w), targets, chunk=chunk, **kw)
         return scale * loss, num
 
-    want, (want_dx, want_dw) = jax.value_and_grad(dense, argnums=(0, 1))(x, w)
+    want, (want_dx, want_dw) = jax.jit(jax.value_and_grad(dense, argnums=(0, 1)))(x, w)
     grad = jax.value_and_grad(chunked, argnums=(0, 1), has_aux=True)
     (got, num), (got_dx, got_dw) = (jax.jit(grad) if case == "jit" else grad)(x, w)
     assert got_dx.dtype == x.dtype and got_dw.dtype == w.dtype and got_dw.shape == w.shape

@@ -71,8 +71,8 @@ def test_ring_backward_matches_reference(sp_mesh):
         out = mha_reference(q, k, v, causal=True)
         return jnp.sum(out * out)
 
-    gr = jax.grad(loss_ring, argnums=(0, 1, 2))(q, k, v)
-    ge = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    gr = jax.jit(jax.grad(loss_ring, argnums=(0, 1, 2)))(q, k, v)
+    ge = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(gr, ge):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4, rtol=1e-4)
 
@@ -105,7 +105,7 @@ def test_fused_gradients_match_dense(sp_mesh):
     def dense_loss(q, k, v):
         return jnp.sum(mha_reference(q, k, v, causal=True) ** 2)
 
-    g_ring = jax.grad(ring_loss, argnums=(0, 1, 2))(q, k, v)
-    g_dense = jax.grad(dense_loss, argnums=(0, 1, 2))(q, k, v)
+    g_ring = jax.jit(jax.grad(ring_loss, argnums=(0, 1, 2)))(q, k, v)
+    g_dense = jax.jit(jax.grad(dense_loss, argnums=(0, 1, 2)))(q, k, v)
     for gr, gd in zip(g_ring, g_dense):
         np.testing.assert_allclose(np.asarray(gr), np.asarray(gd), atol=1e-4)

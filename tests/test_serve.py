@@ -6,13 +6,13 @@ import time
 import urllib.request
 
 import jax
-import numpy as np
 import pytest
 
 import ray_tpu
 from ray_tpu import serve
-from ray_tpu.models import forward, get_config, init_params
+from ray_tpu.models import get_config, init_params
 from ray_tpu.serve.llm import EngineConfig, LLMEngine, LLMServer, build_llm_app
+from tests.test_paged_engine import _greedy_reference
 
 
 @pytest.fixture(autouse=True)
@@ -122,49 +122,6 @@ def test_autoscaling_up():
 
 
 # ------------------------------------------------------------------ LLM engine
-
-
-def _greedy_reference(config, params, prompt, n):
-    """Greedy decode via repeated full forward — ground truth."""
-    tokens = list(prompt)
-    for _ in range(n):
-        logits = forward(params, np.asarray([tokens], dtype=np.int32), config)
-        tokens.append(int(np.argmax(np.asarray(logits[0, -1]))))
-    return tokens[len(prompt):]
-
-
-def test_engine_greedy_matches_full_forward():
-    config = get_config("llama-tiny")
-    params = init_params(config, jax.random.PRNGKey(0))
-    engine = LLMEngine(config, params, EngineConfig(max_slots=4))
-    try:
-        prompt = [5, 17, 42, 7]
-        got = engine.generate(prompt, max_tokens=8)
-        expected = _greedy_reference(config, params, prompt, 8)
-        assert got == expected, (got, expected)
-    finally:
-        engine.shutdown()
-
-
-def test_engine_continuous_batching_staggered():
-    """Requests arriving mid-flight batch with ongoing ones and all finish
-    correctly (the continuous-batching property)."""
-    config = get_config("gpt2-tiny")
-    params = init_params(config, jax.random.PRNGKey(1))
-    engine = LLMEngine(config, params, EngineConfig(max_slots=4))
-    try:
-        prompts = [[1, 2, 3], [9, 8], [30, 31, 32, 33], [4], [100, 101]]
-        streams = []
-        for i, p in enumerate(prompts):
-            streams.append((p, engine.submit(p, max_tokens=6)))
-            time.sleep(0.02)  # staggered arrivals
-        for p, s in streams:
-            got = s.result(timeout=60)
-            expected = _greedy_reference(config, params, p, 6)
-            assert got == expected, (p, got, expected)
-        assert engine.metrics["prefills"] == 5
-    finally:
-        engine.shutdown()
 
 
 def test_engine_more_requests_than_slots():

@@ -30,8 +30,6 @@ from ray_tpu.serve.llm.speculative import (
 )
 from ray_tpu.util.metrics import registry
 
-from tests.test_paged_engine import _greedy_reference
-
 
 class WrongProposer:
     """Adversarial drill: drafts walk a +1 ring the greedy chain almost
@@ -142,7 +140,7 @@ def test_accept_rejection_sampling_marginal_is_exact():
 
     n = 20000
     toks = np.asarray(
-        jax.vmap(first_token)(jax.random.split(jax.random.PRNGKey(7), n))
+        jax.jit(jax.vmap(first_token))(jax.random.split(jax.random.PRNGKey(7), n))
     )
     emp = np.bincount(toks, minlength=v) / n
     tv = 0.5 * np.abs(emp - target).sum()
@@ -150,63 +148,6 @@ def test_accept_rejection_sampling_marginal_is_exact():
 
 
 # --------------------------------------------------------- engine: exactness
-
-
-def test_spec_ngram_greedy_parity_and_acceptance():
-    """A repetitive prompt lets the n-gram proposer draft real spans:
-    output stays exactly greedy and some drafts are accepted."""
-    config, params, engine = _spec_engine()
-    try:
-        prompt = [5, 17, 42, 7, 5, 17, 42, 7, 5, 17, 42, 7]
-        got = engine.generate(prompt, max_tokens=16)
-        assert got == _greedy_reference(config, params, prompt, 16)
-        m = engine.metrics
-        assert m["spec_proposed"] > 0
-        # one verify launch per round emits >= 1 token: launches/token <= 1
-        assert m["decode_steps"] <= m["decode_tokens"]
-    finally:
-        engine.shutdown()
-
-
-def test_spec_all_reject_parity_with_page_boundary_rollbacks():
-    """Always-wrong drafts: every round rejects at draft 1, speculated
-    pages roll back (across page boundaries), and the output is STILL
-    exactly greedy. Afterwards every page returns to the pool."""
-    config = get_config("llama-tiny")
-    config2, params, engine = _spec_engine(
-        proposer=WrongProposer(config.vocab_size)
-    )
-    try:
-        prompt = [3, 1, 4, 1, 5]
-        # 24 tokens from position 5: crosses pages at 8, 16, 24 (ps=8)
-        got = engine.generate(prompt, max_tokens=24)
-        assert got == _greedy_reference(config2, params, prompt, 24)
-        m = engine.metrics
-        assert m["spec_proposed"] > 0
-        assert m["spec_acceptance_rate"] < 0.25
-        assert m["spec_rollback_pages"] > 0
-        deadline = time.time() + 10
-        total = engine.paged.num_pages - 1  # page 0 reserved
-        while engine.allocator.available < total:
-            assert time.time() < deadline, "speculated pages leaked"
-            time.sleep(0.01)
-    finally:
-        engine.shutdown()
-
-
-def test_spec_staggered_batch_parity():
-    config, params, engine = _spec_engine(model="gpt2-tiny", seed=1)
-    try:
-        prompts = [[1, 2, 3, 1, 2, 3], [9, 8, 9, 8], [30, 31, 30, 31], [4, 4, 4]]
-        streams = []
-        for p in prompts:
-            streams.append((p, engine.submit(p, max_tokens=6)))
-            time.sleep(0.02)
-        for p, s in streams:
-            got = s.result(timeout=60)
-            assert got == _greedy_reference(engine.model_config, params, p, 6)
-    finally:
-        engine.shutdown()
 
 
 def test_spec_replay_acceptance_reduces_launches():

@@ -10,16 +10,13 @@ import re
 import sys
 from types import SimpleNamespace
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from ray_tpu.core.config import cfg
 from ray_tpu.models.configs import llama_tiny
-from ray_tpu.parallel import MeshSpec, build_mesh
+from ray_tpu.parallel import MeshSpec
 from ray_tpu.train import LMTrainer
-from ray_tpu.train.lm import create_train_state, default_optimizer, make_train_step
 from ray_tpu.util import profiling, tracing
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -59,29 +56,6 @@ def dense():
     peaks.undo()
     tracing.tracer().clear()
     return tables[STEP], texts[0], spans
-
-
-def _lowered_mixed_step():
-    """The tiny mixed stack's step (dS dS scanned, eS eF unrolled), lowered
-    for one device and never run."""
-    from test_mixed_stack import tiny
-
-    config = tiny(n_layers=4)
-    mesh = build_mesh(MeshSpec(), devices=jax.devices()[:1])
-    optimizer = default_optimizer(3e-4, total_steps=10)
-    state, shardings = create_train_state(config, optimizer, jax.random.PRNGKey(0), mesh)
-    step = make_train_step(config, optimizer, mesh, state_shardings=shardings)
-    return step.lower(state, {"tokens": jnp.zeros((2, 33), jnp.int32)})
-
-
-@pytest.fixture(scope="module")
-def mixed():
-    """The operation table of a tiny mixed stack's step (dS dS scanned, eS eF
-    unrolled: window and full attention, a dense MLP, held experts with a
-    shared one), compiled and never run."""
-    program, table = profiling.program_ops_table(profiling._module_text(_lowered_mixed_step().compile()))
-    assert program == STEP
-    return table
 
 
 def _scope_passes(table):
@@ -127,46 +101,6 @@ def test_dense_step_table_classifies_a_known_instruction(dense, path, scope, pas
     table, text, _ = dense
     ((scopes, found_pass, _),) = table[_named(text, table, path)]
     assert scope in scopes and found_pass == pass_
-
-
-def test_mixed_step_table_holds_both_attention_kinds_both_mlps_and_the_expert_layers_parts(mixed):
-    pairs = _scope_passes(mixed)
-    moe = {"moe", "moe.route", "moe.select", "moe.dispatch", "moe.experts", "moe.combine", "moe.passes",
-           "moe.shared"}
-    # every scope but the two a latent-attention stack with a prediction module adds (PR 44)
-    assert {scope for scope, _ in pairs} == set(profiling.STEP_SCOPES) - {"attn.latent", "mtp"}
-    for sublayer in {"attn.window", "attn.full", "mlp"} | moe:
-        assert {(sublayer, "fwd"), (sublayer, "recompute"), (sublayer, "bwd")} <= pairs
-    for found in mixed.values():
-        for scopes, _, _ in found:
-            if set(scopes) & (moe - {"moe"}):
-                assert "moe" in scopes
-            if ATTENTION & set(scopes):
-                assert ("attn.window" in scopes) != ("attn.full" in scopes)
-
-
-def test_held_row_sum_kernel_is_one_body_a_signature_under_the_combine_and_the_dispatch(monkeypatch):
-    """The tiny mixed step with the expert layer's kernels on (interpreted
-    here): `moe_rows_sum` is lowered once a signature (float32 rows with
-    gates, float32 rows without) and called from every layer, pass and
-    recomputation, and each call keeps its call site's scopes: the combine's
-    in the forward pass and its recomputation, the dispatch's (the transpose
-    of its gather) in the backward pass, and nowhere else."""
-    from ray_tpu.models import moe
-
-    monkeypatch.setattr(moe, "resolve_gmm_impl", lambda implementation=None: "pallas")
-    monkeypatch.setattr(moe, "gmm_tile_rows", lambda implementation=None: 16)
-    lowered = _lowered_mixed_step()
-    text = lowered.as_text()
-    assert len(re.findall(r"func\.func private @moe_rows_sum\w*\(", text)) == 2
-    # eS eF unrolled, the first pass and the later one, forward, recomputed and backward
-    assert len(re.findall(r"call @moe_rows_sum", text)) == 2 * 2 * 3
-    placed = set()
-    for path in re.findall(r'op_name="([^"]*/moe_rows_sum/[^"]*)"', profiling._module_text(lowered.compile())):
-        placed.add((tuple(sorted(set(profiling._SCOPE_ON_PATH.findall(path)) & {"moe.combine", "moe.dispatch"})),
-                    profiling.op_pass(path)))
-    assert placed == {(("moe.combine",), "fwd"), (("moe.combine",), "recompute"),
-                      (("moe.dispatch",), "bwd")}
 
 
 def test_op_pass_precedence():

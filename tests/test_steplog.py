@@ -245,6 +245,10 @@ def test_straggler_drill_warning_names_rank_and_data_wait():
             train_stall_ewma_alpha=0.3)
     run_name = "skew_drill"
 
+    def stall_seen():
+        return [e for e in events().list(severity="WARNING", source="watchdog", limit=200)
+                if run_name in e["message"] and "STALLED" in e["message"]]
+
     def train_fn(config):
         import time as _t
 
@@ -267,7 +271,14 @@ def test_straggler_drill_warning_names_rank_and_data_wait():
             train.report({"step": step, "_steplog": [rec],
                           "_mono": _t.perf_counter()})
             if slow and step == 10:
-                _t.sleep(1.2)  # the injected stall: EWMA regression
+                # the injected stall (EWMA regression): 1.2 s, and on a host
+                # so loaded that the steps before it were slow too, until
+                # the watchdog has seen it (the gang's workers are threads
+                # of this process, so they read the same event log)
+                _t.sleep(1.2)
+                stalled = _t.monotonic()
+                while not stall_seen() and _t.monotonic() - stalled < 20.0:
+                    _t.sleep(0.05)
             else:
                 _t.sleep(0.03)
 
@@ -287,11 +298,7 @@ def test_straggler_drill_warning_names_rank_and_data_wait():
         deadline = time.monotonic() + 30
         warned = []
         while time.monotonic() < deadline and not warned:
-            warned = [
-                e for e in events().list(severity="WARNING",
-                                         source="watchdog", limit=200)
-                if run_name in e["message"] and "STALLED" in e["message"]
-            ]
+            warned = stall_seen()
             time.sleep(0.02)
         assert warned, "stall watchdog never fired on the slow-input rank"
         msg = warned[0]["message"]
@@ -313,6 +320,9 @@ def test_straggler_drill_warning_names_rank_and_data_wait():
         assert all(r["straggler_rank"] == 1 for r in two_rank)
         assert all(r["dominant_bucket"] == "data_wait" for r in two_rank)
     finally:
+        # a drill that failed leaves its gang running: the next tests of
+        # this file start a runtime of their own and must not find this one
+        t.join(timeout=60)
         ray_tpu.shutdown()
 
 

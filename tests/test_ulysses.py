@@ -56,8 +56,8 @@ def test_ulysses_backward_matches_reference(sp4_mesh):
         out = mha_reference(q, k, v, causal=True)
         return jnp.sum(out * out)
 
-    gu = jax.grad(loss_u, argnums=(0, 1, 2))(q, k, v)
-    ge = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    gu = jax.jit(jax.grad(loss_u, argnums=(0, 1, 2)))(q, k, v)
+    ge = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(gu, ge):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4, rtol=1e-4)
 

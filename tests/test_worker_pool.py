@@ -99,29 +99,34 @@ def test_process_task_runs_in_separate_pid(runtime):
     assert child != os.getpid()
 
 
-def test_process_task_gil_free_parallelism(runtime):
-    """Two CPU-burn tasks across processes finish in ~1x single-task time."""
+def test_process_task_gil_free_parallelism(runtime, tmp_path):
+    """Two CPU-burn tasks run at the same time, each in a process of its
+    own: each starts, waits until the other has started too and only then
+    burns. Were they run one after the other, the first would wait out its
+    deadline (a ratio of wall times said the same on an idle host and
+    answered to the host's load on a busy one)."""
 
-    def burn(n):
+    def burn(n, mine, other, deadline_s=120.0):
+        open(mine, "w").close()
+        waited = time.monotonic()
+        while not os.path.exists(other):
+            if time.monotonic() - waited > deadline_s:
+                return None
+            time.sleep(0.01)
         acc = 0
         for i in range(n):
             acc += i * i
-        return acc
+        return os.getpid(), acc
 
-    n = 2_000_000
-    t0 = time.perf_counter()
-    api.get(api.remote(burn).options(executor="process").remote(n))
-    solo = time.perf_counter() - t0
-
-    t0 = time.perf_counter()
+    marks = [str(tmp_path / "a"), str(tmp_path / "b")]
     refs = [
-        api.remote(burn).options(executor="process").remote(n) for _ in range(2)
+        api.remote(burn).options(executor="process").remote(2_000_000, mine, other)
+        for mine, other in (marks, marks[::-1])
     ]
-    api.get(refs)
-    duo = time.perf_counter() - t0
-    # true parallelism: 2 tasks take well under 2x one task (allow slack
-    # for spawn variance on a loaded CI host)
-    assert duo < solo * 1.7, (solo, duo)
+    done = api.get(refs)
+    assert all(done), "a task never saw the other start: they ran one after the other"
+    (pid_a, acc_a), (pid_b, acc_b) = done
+    assert acc_a == acc_b and len({pid_a, pid_b, os.getpid()}) == 3
 
 
 def test_process_task_error_propagates(runtime):
