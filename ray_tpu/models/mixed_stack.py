@@ -37,6 +37,15 @@ the embedding of the NEXT token, each through a norm of its own, joined by
 `eh_proj`, through one more block of the expert-layer kind and a final norm,
 to the shared head, which then predicts the token after next. The objective
 that uses it is train/lm.lm_loss's.
+
+A layer need not be both sublayers. A configuration with a `layer_pattern`
+(one character a layer) gives each layer ONE mixer behind one norm, x +
+mixer(norm(x)): "M", a Mamba-2 state-space mixer (`_ssm_sublayer`: an
+in-projection to a gate z, the convolved x, B, C and a step a head; a causal
+depthwise convolution; the chunked selective scan of ops/ssd; the gate and a
+norm a group; an out-projection: leaves `ssm_*` of its own); "*", full
+attention without positions; "E", an expert layer. Its kind is then
+`LayerKind("ssm", "none")`, `("full", "none")` or `("none", "experts")`.
 """
 
 from __future__ import annotations
@@ -49,11 +58,12 @@ from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops import rope_frequencies
+from ..ops import rmsnorm, rope_frequencies, ssd
 from ..ops.attention import attention_plan
 from .moe import _HELD_BUFFER_SHARES, MoEConfig, load_max_over_mean, moe_mlp, moe_plan
 from .transformer import (
     Params,
+    RematCandidate,
     StackRun,
     _norm,
     attention_costs,
@@ -102,28 +112,78 @@ class MixedStackConfig(MoEConfig):
     # of their loss in the objective: loss + mtp_loss_weight x mtp_loss
     mtp_modules: int = 0
     mtp_loss_weight: float = 0.0
+    # The layers' kinds as a string, one character a layer, each layer then ONE
+    # sublayer (`PATTERN_KINDS`: "M" a state-space mixer, "*" full attention
+    # without positions, "E" an expert layer); "": the two index rules above,
+    # every layer attention and then an MLP. A pattern may be longer than
+    # `n_layers` (a published one of which the first layers are run)
+    layer_pattern: str = ""
+    # the state-space mixer (Mamba-2): heads of `ssm_head_dim` features with a
+    # state of `ssm_state` a feature, B and C in `ssm_groups` groups of heads, a
+    # causal depthwise convolution of `ssm_conv_kernel` taps over x, B and C,
+    # the scan's chunk, and the range a head's step starts in (log-uniform in
+    # [min, max], no smaller than the floor)
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv_kernel: int = 4
+    ssm_chunk: int = 128
+    ssm_dt_min: float = 0.001
+    ssm_dt_max: float = 0.1
+    ssm_dt_floor: float = 1e-4
+
+    @property
+    def ssm_conv_width(self) -> int:
+        """The features the convolution runs over: x, then B and C a group."""
+        return self.ssm_heads * self.ssm_head_dim + 2 * self.ssm_groups * self.ssm_state
 
     def __post_init__(self):
         super().__post_init__()
+        unknown = set(self.layer_pattern) - set(PATTERN_KINDS)
+        if unknown or (self.layer_pattern and len(self.layer_pattern) < self.n_layers):
+            raise ValueError(f"layer_pattern {self.layer_pattern!r}: at least n_layers = {self.n_layers} "
+                             f"characters of {sorted(PATTERN_KINDS)} are what the program runs")
+        if self.layer_pattern and (self.mtp_modules or self.latent_attention):
+            raise ValueError("layer_pattern: a patterned stack has no latent attention and no "
+                             "multi-token prediction module")
+        if "M" in self.layer_pattern[:self.n_layers] and not (
+                self.ssm_heads > 0 and self.ssm_head_dim > 0 and self.ssm_state > 0
+                and self.ssm_groups > 0 and self.ssm_heads % self.ssm_groups == 0):
+            raise ValueError("layer_pattern has a state-space layer: ssm_heads (a multiple of "
+                             "ssm_groups), ssm_head_dim and ssm_state must be given")
         if self.mtp_modules not in (0, 1):
             raise ValueError(f"mtp_modules {self.mtp_modules}: one multi-token prediction module "
                              "is what the program runs")
         if self.router_input not in ("mlp", "attention"):
             raise ValueError(f"unknown router input: {self.router_input!r}")
-        known = {name for mlp in ("dense", "experts")
-                 for name in _layer_shapes(self, LayerKind(layer_kinds(self)[0].attention, mlp))}
+        kinds = layer_kinds(self)
+        known = {name for kind in (*kinds, *(LayerKind(kinds[0].attention, mlp) for mlp in ("dense", "experts")))
+                 for name in _layer_shapes(self, kind)}
         if not set(self.frozen_leaves) <= known:
             raise ValueError(f"frozen_leaves {sorted(set(self.frozen_leaves) - known)}: "
                              f"no layer has such a leaf")
 
 
 class LayerKind(NamedTuple):
-    attention: str  # "sliding" | "full" | "latent"
-    mlp: str        # "dense" | "experts"
+    """The sublayers a layer has: its mixer first, then its MLP."""
+
+    attention: str  # "sliding" | "full" | "latent" | "ssm" (a state-space mixer) | "none"
+    mlp: str        # "dense" | "experts" | "none"
 
     @property
     def code(self) -> str:
-        return ("d" if self.mlp == "dense" else "e") + self.attention[0].upper()
+        return ({"dense": "d", "experts": "e", "none": "-"}[self.mlp]
+                + {"sliding": "S", "full": "F", "latent": "L", "ssm": "M", "none": "-"}[self.attention])
+
+    @property
+    def sublayers(self) -> int:
+        return (self.attention != "none") + (self.mlp != "none")
+
+
+# a `layer_pattern`'s characters: each a layer of one sublayer
+PATTERN_KINDS = {"M": LayerKind("ssm", "none"), "*": LayerKind("full", "none"),
+                 "E": LayerKind("none", "experts")}
 
 
 class Run(NamedTuple):
@@ -135,6 +195,8 @@ class Run(NamedTuple):
 
 def layer_kinds(config: MixedStackConfig) -> List[LayerKind]:
     c = config
+    if c.layer_pattern:
+        return [PATTERN_KINDS[character] for character in c.layer_pattern[:c.n_layers]]
     full_at = 0 if c.global_attn_first else c.global_attn_every - 1
     return [LayerKind("latent" if c.latent_attention
                       else "full" if i % c.global_attn_every == full_at else "sliding",
@@ -143,25 +205,42 @@ def layer_kinds(config: MixedStackConfig) -> List[LayerKind]:
 
 
 def stack_runs(kinds: List[LayerKind]) -> List[Run]:
-    """The layers as runs: the stack is cut where the MLP kind changes (the
-    parameters' shapes do), and each piece is its shortest period repeated,
-    then what is left over as one more run of a single repeat."""
+    """The layers as runs of a repeated period of whole kinds. The stack is
+    cut where the MLP kind changes between two layers that both have an MLP
+    (the parameters' shapes do). A piece is its shortest period repeated, then
+    what is left over as one more run of a single repeat. A piece that no
+    period tiles is, where it STARTS with a period of two layers or more
+    repeated in a row, that run (the period that covers most layers, the
+    shortest of those that cover as many) and then what follows it by the
+    same rule; else one run of a single repeat (a layer repeated at the start
+    of such a piece stays unrolled, as before PR 48: the shipped trees keep
+    their layout). `MEMEM*EME`: `ME` twice, then `M*EME` once."""
     runs: List[Run] = []
     start = 0
     while start < len(kinds):
-        end = start
-        while end < len(kinds) and kinds[end].mlp == kinds[start].mlp:
+        end = start + 1
+        while end < len(kinds) and ("none" in (kinds[end].mlp, kinds[end - 1].mlp)
+                                    or kinds[end].mlp == kinds[end - 1].mlp):
             end += 1
-        piece = kinds[start:end]
-        # the shortest period that repeats at least twice, else the piece itself
-        period = next((p for p in range(1, len(piece) // 2 + 1)
-                       if all(piece[i] == piece[i % p] for i in range(len(piece) // p * p))),
-                      len(piece))
-        repeats = len(piece) // period
-        runs.append(Run(tuple(piece[:period]), repeats))
-        if len(piece) % period:
-            runs.append(Run(tuple(piece[repeats * period:]), 1))
-        start = end
+        while start < end:
+            piece = kinds[start:end]
+
+            def repeats_of(period):
+                n = 1
+                while piece[n * period:(n + 1) * period] == piece[:period]:
+                    n += 1
+                return n
+
+            halves = range(1, len(piece) // 2 + 1)
+            # the shortest period that tiles the piece, a tail shorter than it aside
+            period = next((p for p in halves if repeats_of(p) == len(piece) // p), None)
+            if period is None:
+                _, period = max(((repeats_of(p) * p, -p) for p in halves[1:] if repeats_of(p) > 1),
+                                default=(0, -len(piece)))
+                period = -period
+            repeats = repeats_of(period)
+            runs.append(Run(tuple(piece[:period]), repeats))
+            start += repeats * period
     return runs
 
 
@@ -190,20 +269,26 @@ def _layer_shapes(config: MixedStackConfig, kind: LayerKind) -> Dict[str, Tuple[
     leaves: those the configuration's flags give it, in one fixed order.
     "post": the norm on a sublayer's output. "into_residual": a projection
     into the residual stream, which takes the other families' 1/sqrt(2 L)
-    scale where no such norm follows it (under one the scale is undone)."""
+    scale (one over the root of the sublayers the stack has) where no such
+    norm follows it (under one the scale is undone). A layer has the norms of
+    the sublayers it has: `ln1_scale` before its mixer, `ln2_scale` before
+    its MLP."""
     c = config
     dh, m = c.head_dim, c.d_model
     into_residual = "normal" if c.sandwich_norm else "into_residual"
     held, shared = c.n_experts_held, c.shared_expert_width
-    experts = kind.mlp == "experts"
-    latent, plain = kind.attention == "latent", kind.attention != "latent"
+    dense, experts = kind.mlp == "dense", kind.mlp == "experts"
+    gated = "we_gate" in c.expert_weights
+    ssm, attention = kind.attention == "ssm", kind.attention not in ("ssm", "none")
+    latent, plain = kind.attention == "latent", attention and kind.attention != "latent"
     q_rank, kv_rank, rope = c.q_lora_rank, c.kv_lora_rank, c.qk_rope_dim
+    heads, inner, conv = c.ssm_heads, c.ssm_heads * c.ssm_head_dim, c.ssm_conv_width
     # (name, whether this layer has the leaf, its shape, initialisation and axes)
     leaves = [
-        ("ln1_scale", True, (m,), "ones", (None,)),
-        ("ln1_post_scale", c.sandwich_norm, (m,), "post", (None,)),
-        ("ln2_scale", True, (m,), "ones", (None,)),
-        ("ln2_post_scale", c.sandwich_norm, (m,), "post", (None,)),
+        ("ln1_scale", attention or ssm, (m,), "ones", (None,)),
+        ("ln1_post_scale", attention and c.sandwich_norm, (m,), "post", (None,)),
+        ("ln2_scale", dense or experts, (m,), "ones", (None,)),
+        ("ln2_post_scale", (dense or experts) and c.sandwich_norm, (m,), "post", (None,)),
         ("wq", plain, (m, c.n_heads, dh), "normal", ("embed", "heads", "head_dim")),
         ("wk", plain, (m, c.kv_heads, dh), "normal", ("embed", "kv_heads", "head_dim")),
         ("wv", plain, (m, c.kv_heads, dh), "normal", ("embed", "kv_heads", "head_dim")),
@@ -215,20 +300,31 @@ def _layer_shapes(config: MixedStackConfig, kind: LayerKind) -> Dict[str, Tuple[
         ("wkv_a", latent, (m, kv_rank + rope), "normal", ("embed", None)),
         ("kv_a_norm_scale", latent, (kv_rank,), "ones", (None,)),
         ("wkv_b", latent, (kv_rank, c.n_heads, 2 * dh - rope), "normal", (None, "heads", "head_dim")),
-        ("wg", c.attn_gate, (m, c.n_heads, dh), "normal", ("embed", "heads", "head_dim")),
-        ("wo", True, (c.n_heads, dh, m), into_residual, ("heads", "head_dim", "embed")),
-        ("q_norm_scale", c.qk_norm_per_head, (dh,), "ones", (None,)),
-        ("k_norm_scale", c.qk_norm_per_head, (dh,), "ones", (None,)),
-        ("w_gate", not experts, (m, c.d_ff_dense), "normal", ("embed", "mlp")),
-        ("w_up", not experts, (m, c.d_ff_dense), "normal", ("embed", "mlp")),
-        ("w_down", not experts, (c.d_ff_dense, m), into_residual, ("mlp", "embed")),
+        ("wg", attention and c.attn_gate, (m, c.n_heads, dh), "normal", ("embed", "heads", "head_dim")),
+        ("wo", attention, (c.n_heads, dh, m), into_residual, ("heads", "head_dim", "embed")),
+        ("q_norm_scale", attention and c.qk_norm_per_head, (dh,), "ones", (None,)),
+        ("k_norm_scale", attention and c.qk_norm_per_head, (dh,), "ones", (None,)),
+        # a state-space mixer: [z | x B C | dt] in one projection, whole on every
+        # device; what is a head's carries the heads' axis (whole here too: no
+        # rule of parallel/sharding names it yet)
+        ("ssm_in", ssm, (m, inner + conv + heads), "normal", ("embed", None)),
+        ("ssm_conv_w", ssm, (conv, c.ssm_conv_kernel), "conv", (None, None)),
+        ("ssm_conv_b", ssm, (conv,), "zeros", (None,)),
+        ("ssm_dt_bias", ssm, (heads,), "ssm_dt_bias", ("ssm_heads",)),
+        ("ssm_a_log", ssm, (heads,), "ssm_a_log", ("ssm_heads",)),
+        ("ssm_d", ssm, (heads,), "ones", ("ssm_heads",)),
+        ("ssm_norm_scale", ssm, (heads, c.ssm_head_dim), "ones", ("ssm_heads", None)),
+        ("ssm_out", ssm, (heads, c.ssm_head_dim, m), into_residual, ("ssm_heads", "head_dim", "embed")),
+        ("w_gate", dense, (m, c.d_ff_dense), "normal", ("embed", "mlp")),
+        ("w_up", dense, (m, c.d_ff_dense), "normal", ("embed", "mlp")),
+        ("w_down", dense, (c.d_ff_dense, m), into_residual, ("mlp", "embed")),
         # replicated, as moe.logical_axes has it
         ("router", experts, (m, c.n_experts), "router", (None, None)),
         ("expert_bias", experts and c.router_select_bias, (c.n_experts,), "zeros", (None,)),
-        ("we_gate", experts, (held, m, c.d_ff), "normal", ("expert", "embed", "mlp")),
+        ("we_gate", experts and gated, (held, m, c.d_ff), "normal", ("expert", "embed", "mlp")),
         ("we_up", experts, (held, m, c.d_ff), "normal", ("expert", "embed", "mlp")),
         ("we_down", experts, (held, c.d_ff, m), into_residual, ("expert", "mlp", "embed")),
-        ("ws_gate", experts and shared > 0, (m, shared), "normal", ("embed", "mlp")),
+        ("ws_gate", experts and gated and shared > 0, (m, shared), "normal", ("embed", "mlp")),
         ("ws_up", experts and shared > 0, (m, shared), "normal", ("embed", "mlp")),
         ("ws_down", experts and shared > 0, (shared, m), into_residual, ("mlp", "embed")),
     ]
@@ -253,15 +349,21 @@ def _mtp_shapes(config: MixedStackConfig) -> Dict[str, Tuple[Tuple[int, ...], An
 
 
 def init_params(config: MixedStackConfig, key: jax.Array) -> Params:
-    """The repo's initialisation (N(0, 0.02), over sqrt(2 L) for a projection
-    into the residual stream that no norm follows; norms 1 but those on a
-    sublayer's output, which start at POST_NORM_GAIN; the selection bias 0),
+    """The repo's initialisation (N(0, 0.02), over the root of the stack's
+    sublayers, sqrt(2 L) where every layer has two, for a projection into the
+    residual stream that no norm follows; norms 1 but those on a sublayer's
+    output, which start at POST_NORM_GAIN; the selection bias 0), and a
+    state-space mixer's own leaves as its family starts them (A = -U(1, 16) a
+    head; the step's bias the inverse softplus of a step drawn log-uniformly
+    in [ssm_dt_min, ssm_dt_max] and no smaller than ssm_dt_floor; D and the
+    gated norm 1; the convolution's taps U(+-1 / sqrt(taps)) and its bias 0),
     stacked a run: params["runs"][r][p][leaf] has the run's repeats in
     front."""
     c = config
     pd = c.param_dtype
     std = 0.02
-    deviation = {"normal": std, "into_residual": std / math.sqrt(2 * c.n_layers),
+    sublayers = sum(kind.sublayers for kind in layer_kinds(c))
+    deviation = {"normal": std, "into_residual": std / math.sqrt(sublayers),
                  "router": c.router_std}
     constant = {"ones": 1.0, "post": POST_NORM_GAIN, "zeros": 0.0}
 
@@ -269,6 +371,15 @@ def init_params(config: MixedStackConfig, key: jax.Array) -> Params:
         shape = (repeats, *shape)
         if how in deviation:
             return deviation[how] * jax.random.normal(k, shape, pd)
+        if how == "conv":
+            bound = 1.0 / math.sqrt(shape[-1])
+            return jax.random.uniform(k, shape, pd, -bound, bound)
+        if how == "ssm_a_log":
+            return jnp.log(jax.random.uniform(k, shape, pd, 1.0, 16.0))
+        if how == "ssm_dt_bias":
+            low, high = math.log(c.ssm_dt_min), math.log(c.ssm_dt_max)
+            step = jnp.maximum(jnp.exp(jax.random.uniform(k, shape, pd, low, high)), c.ssm_dt_floor)
+            return step + jnp.log(-jnp.expm1(-step))       # softplus(bias) = step
         return jnp.full(shape, constant[how], pd)
 
     runs = []
@@ -316,32 +427,76 @@ def logical_axes(config: MixedStackConfig) -> Params:
 # -------------------------------------------------------------------- forward
 
 
+def _ssm_sublayer(x, lp, config):
+    """A Mamba-2 mixer + residual on (B, S, E), the scope `ssm`: [z | xBC |
+    dt] = norm(x) W_in (`ssm.in_proj`); xBC through the causal depthwise
+    convolution and silu (`ssm.conv`); the step softplus(dt + bias) in float32
+    and the selective scan of the heads' x with their group's B and C
+    (`ssm.scan`, ops/ssd.ssd_scan); y silu(z) through an RMS norm over each
+    group's features (`ssm.gate_norm`: the gate first, then the norm); W_out
+    and the residual (`ssm.out_proj`). -> (x, the most negative cumulative
+    log-decay inside a chunk)."""
+    c = config
+    dt = c.dtype
+    b, s, _ = x.shape
+    heads, p, groups, n = c.ssm_heads, c.ssm_head_dim, c.ssm_groups, c.ssm_state
+    inner = heads * p
+    with jax.named_scope("ssm"):
+        with jax.named_scope("ssm.in_proj"):
+            u = _norm(x, lp["ln1_scale"], None, c.norm, c.norm_eps)
+            projected = jnp.einsum("bse,ef->bsf", u, lp["ssm_in"].astype(dt))
+            z, xbc, step = jnp.split(projected, [inner, inner + c.ssm_conv_width], axis=-1)
+        with jax.named_scope("ssm.conv"):
+            xbc = ssd.causal_conv1d(xbc, lp["ssm_conv_w"], lp["ssm_conv_b"])
+        with jax.named_scope("ssm.scan"):
+            step = jax.nn.softplus(step.astype(jnp.float32) + lp["ssm_dt_bias"].astype(jnp.float32))
+            bc = xbc[..., inner:].reshape(b, s, 2, groups, n)
+            y = ssd.ssd_scan(xbc[..., :inner].reshape(b, s, heads, p), step, lp["ssm_a_log"],
+                             bc[:, :, 0], bc[:, :, 1], lp["ssm_d"], chunk=c.ssm_chunk)
+            decay_min = jax.lax.stop_gradient(
+                ssd.log_decay_chunk_min(step, lp["ssm_a_log"], c.ssm_chunk))
+        with jax.named_scope("ssm.gate_norm"):
+            kw = {} if c.norm_eps is None else {"eps": c.norm_eps}
+            gated = y.reshape(b, s, inner).astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+            y = rmsnorm(gated.reshape(b, s, groups, inner // groups),
+                        lp["ssm_norm_scale"].reshape(groups, inner // groups), **kw).astype(dt)
+        with jax.named_scope("ssm.out_proj"):
+            out = jnp.einsum("bshp,hpe->bse", y.reshape(b, s, heads, p), lp["ssm_out"].astype(dt))
+            return x + out, decay_min
+
+
 def _block(x, lp, config, kind: LayerKind, rope_tables, positions, remat_saved=()):
-    """One layer of either attention kind and either MLP kind on (B, S, E):
-    x + attention(norm(x)), then the same around the MLP, each sublayer's
-    output through a norm of its own where the configuration has them. ->
-    (x, the expert layer's scalars, {} for a dense layer)."""
+    """One layer on (B, S, E), the sublayers its kind names: x + mixer(norm(x))
+    (attention of either kind, or a state-space mixer), then the same around
+    the MLP (dense or experts), each sublayer's output through a norm of its
+    own where the configuration has them. -> (x, the layer's scalars: an
+    expert layer's, a state-space mixer's `ssm_log_decay_chunk_min`)."""
     c = config
     if c.frozen_leaves:
         lp = {name: jax.lax.stop_gradient(w) if name in c.frozen_leaves else w
               for name, w in lp.items()}
-    sliding = kind.attention == "sliding"
     # the layer's input is kept for the backward pass whatever is recomputed,
     # so a router that reads it costs no tensor carried past the attention
     router_input = x if c.router_input == "attention" else None
-    x = attention_sublayer(     # the scope `attn.window` or `attn.full`, by the window
-        x, lp, c, None if kind.attention == "full" else rope_tables, positions,
-        window=c.sliding_window if sliding else None, remat_saved=remat_saved)
+    scalars = {}
+    if kind.attention == "ssm":
+        x, scalars["ssm_log_decay_chunk_min"] = _ssm_sublayer(x, lp, c)
+    elif kind.attention != "none":
+        x = attention_sublayer(     # the scope `attn.window` or `attn.full`, by the window
+            x, lp, c, None if kind.attention == "full" else rope_tables, positions,
+            window=c.sliding_window if kind.attention == "sliding" else None, remat_saved=remat_saved)
     if kind.mlp == "dense":
-        return mlp_sublayer(x, lp, c), {}
+        x = mlp_sublayer(x, lp, c)
+    if kind.mlp != "experts":
+        return x, scalars
     with jax.named_scope("moe"):
-        out, scalars = moe_mlp(_norm(x, lp["ln2_scale"], None, c.norm, c.norm_eps), lp, c,
-                               router_input=router_input)
+        out, routed = moe_mlp(_norm(x, lp["ln2_scale"], None, c.norm, c.norm_eps), lp, c,
+                              router_input=router_input)
         if c.sandwich_norm:
             out = _norm(out, lp["ln2_post_scale"], None, c.norm, c.norm_eps)
         x = x + out
-    scalars.pop("aux")  # this family trains on the cross entropy alone
-    return x, dict(scalars, load=load_max_over_mean(scalars["load"]))
+    routed.pop("aux")  # this family trains on the cross entropy alone
+    return x, {**scalars, **routed, "load": load_max_over_mean(routed["load"])}
 
 
 def forward_hidden(
@@ -357,8 +512,9 @@ def forward_hidden(
     `moe_rows_held` and `moe_rows_held_share` (of the T k routed rows) as the
     mean over the expert layers, as is `moe_act_live_share`, the percentage of
     the held ReGLU experts' hidden units that the ReLU leaves non-zero on the
-    rows sent here). The rotary table is built for the sequence at hand, not
-    for `max_seq`."""
+    rows sent here; what the state-space layers report: `ssm_log_decay_chunk_min`
+    of the worst layer). The rotary table is built for the sequence at hand,
+    not for `max_seq`."""
     c = config
     dt = c.dtype
     b, s = tokens.shape
@@ -390,7 +546,11 @@ def forward_hidden(
         reports.extend(scalars)      # under a scan each scalar is (repeats,)
     with jax.named_scope("head"):
         x = _norm(x, params["lnf_scale"], None, c.norm, c.norm_eps)
-    return x, _expert_layers_report(reports, c, b * s)
+    report = _expert_layers_report([r for r in reports if "load" in r], c, b * s)
+    decays = [jnp.ravel(r["ssm_log_decay_chunk_min"]) for r in reports if "ssm_log_decay_chunk_min" in r]
+    if decays:
+        report["ssm_log_decay_chunk_min"] = jnp.min(jnp.concatenate(decays))
+    return x, report
 
 
 def _expert_layers_report(reports: List[Dict[str, jax.Array]], config: MixedStackConfig,
@@ -451,32 +611,78 @@ def mtp_hidden(
     x = _norm(x, mp["norm_scale"], None, c.norm, c.norm_eps)
     # the block's layer beside the stack's: the worst of both, and the means
     # weighted by the layers they are of
-    mine, layers = _expert_layers_report([scalars], c, b * s), c.n_layers - c.n_dense_layers
+    mine, layers = _expert_layers_report([scalars], c, b * s), _expert_layers(c)
     worst = ("moe_load_max_over_mean", "moe_passes")
     return x, {name: (jnp.maximum(routers[name], value) if name in worst
                       else (layers * routers[name] + value) / (layers + 1))
                for name, value in mine.items()} if layers else mine
 
 
+def _expert_layers(config: MixedStackConfig) -> int:
+    return sum(kind.mlp == "experts" for kind in layer_kinds(config))
+
+
 def _expert_costs(config: MixedStackConfig, split: Callable[[str], int]) -> Dict[str, Any]:
     """An expert layer's MLP as `transformer.mlp_costs` gives a dense one's,
     a row (a token) and device at an even routing: the router, the shared
-    expert, the held experts' three grouped matmuls on the rows sent here,
-    and the buffer those rows pass through. It names no candidate: what the
-    buffer or the shared expert's gate and up are worth kept is not known
-    from any record (PERF.md section 7)."""
+    expert, the held experts' grouped matmuls on the rows sent here (three
+    matrices an expert, or the two of one that is not gated), and the buffer
+    those rows pass through. It names no candidate: what the buffer or the
+    shared expert's gate and up are worth kept is not known from any record
+    (PERF.md section 7)."""
     c = config
     shared = c.shared_expert_width // split("ws_up") if c.shared_expert_width else 0
     d_ff = c.d_ff // split("we_up")
+    matrices = len(c.expert_weights)
     rows_here = c.top_k * c.n_experts_held / c.n_experts   # (token, choice) pairs a token
     return {
-        "flops": int(2 * c.d_model * (c.n_experts + 3 * shared + 3 * rows_here * d_ff)),
+        "flops": int(2 * c.d_model * (c.n_experts + matrices * shared + matrices * rows_here * d_ff)),
         # both norms' outputs, the layer's, the residual; the router's float32
-        # scores; the shared expert's gate, up and activation; a buffer row's
-        # input, gate, up, activation and output
-        "width": int(4 * c.d_model + c.n_experts * 4 // jnp.dtype(c.dtype).itemsize + 3 * shared
-                     + min(_HELD_BUFFER_SHARES * rows_here, c.top_k) * (2 * c.d_model + 3 * d_ff)),
+        # scores; the shared expert's (gate,) up and activation; a buffer row's
+        # input, (gate,) up, activation and output
+        "width": int(4 * c.d_model + c.n_experts * 4 // jnp.dtype(c.dtype).itemsize + matrices * shared
+                     + min(_HELD_BUFFER_SHARES * rows_here, c.top_k) * (2 * c.d_model + matrices * d_ff)),
         "candidates": (),
+    }
+
+
+# a layer's absent sublayer, in `block_costs`
+_NO_SUBLAYER: Dict[str, Any] = {"flops": 0, "width": 0, "candidates": ()}
+# The chunked scan's share of the chip's peak, by which the operations it
+# spares count for their time beside matmuls that run near the peak (as
+# transformer._FLASH_SHARE_OF_PEAK does for the attention kernels): from the
+# chip at the `train-nemotron3nano-8k` cell's shapes (PERF.md section 6, PR 48:
+# one forward scan of 2 x 8,192 tokens, 55.8 GFLOP as computed, in 6.57 ms)
+_SSD_SHARE_OF_PEAK = 0.043
+
+
+def _ssm_costs(config: MixedStackConfig) -> Dict[str, Any]:
+    """`_ssm_sublayer`'s part of `block_costs`, a layer and token (whole on
+    every device: its weights are not split). The one candidate is the scan's
+    output WITH the states it keeps a block of chunks (`ssm_scan_out`,
+    `ssm_chunk_states` of ops/ssd): with both the backward pass does not walk
+    the sequence forward again; with one of them alone it must."""
+    c = config
+    heads, p, n, chunk = c.ssm_heads, c.ssm_head_dim, c.ssm_state, c.ssm_chunk
+    inner, conv = heads * p, c.ssm_conv_width
+    itemsize = jnp.dtype(c.dtype).itemsize
+    # as ops/ssd computes it: C B^T and the weighted x inside a chunk, a chunk's
+    # state, the read-out of the state that entered it
+    scan = 2 * chunk * c.ssm_groups * n + 2 * chunk * inner + 4 * inner * n
+    # a float32 state a block of chunks, in features of the activations' dtype a token
+    state = -(-inner * n * 4 // (chunk * ssd.BLOCK_CHUNKS * itemsize))
+    return {
+        "flops": (2 * c.d_model * (inner + conv + heads) + 2 * c.ssm_conv_kernel * conv + scan
+                  + 2 * inner * c.d_model),
+        # the norm's output, the sublayer's, the residual; the projection; the
+        # convolution's output (its float32 sum is fused away); the scan's
+        # operands cut into blocks; its output and the gated, normed one. On
+        # the chip the whole-block step of the cell peaked at 13.87 GB, 2.4 GB
+        # over its state, gradients and the blocks' inputs: 1.75 copies of
+        # 41.9 k features a row, where this counts 42.9 k
+        "width": (3 * c.d_model + (inner + conv + heads) + conv + (inner + conv) + 2 * inner),
+        "candidates": (RematCandidate(("ssm_scan_out", "ssm_chunk_states"), inner + state, scan,
+                                      int(scan / _SSD_SHARE_OF_PEAK), False, ()),),
     }
 
 
@@ -492,8 +698,11 @@ def block_costs(
     c = config
 
     def kind_costs(kind: LayerKind):
-        return (attention_costs(c, seq, split, c.sliding_window if kind.attention == "sliding" else None),
-                mlp_costs(c, split, c.d_ff_dense) if kind.mlp == "dense" else _expert_costs(c, split))
+        mixer = (_ssm_costs(c) if kind.attention == "ssm" else _NO_SUBLAYER if kind.attention == "none"
+                 else attention_costs(c, seq, split, c.sliding_window if kind.attention == "sliding" else None))
+        mlp = (mlp_costs(c, split, c.d_ff_dense) if kind.mlp == "dense"
+               else _expert_costs(c, split) if kind.mlp == "experts" else _NO_SUBLAYER)
+        return mixer, mlp
 
     runs = [
         StackRun(run.repeats > 1, ("runs", r), tuple(
@@ -512,15 +721,21 @@ def plan(config: MixedStackConfig, batch: int, seq: int) -> Dict[str, Any]:
     layers' kinds in order (`dS dS eS eF ...`), the window and the windowed
     kernels' sub-tile walk (the full layers' is `attention_plan`'s, which the
     trainer writes for every model) or, of a latent-attention stack, the ranks
-    of its latents, the features of a head that rotate and the head size; the
+    of its latents, the features of a head that rotate and the head size; a
+    state-space mixer's sizes and its scan's form (`ops/ssd.scan_plan`); the
     multi-token prediction module and its loss's weight where there is one;
     the router's form and the expert layer's (`moe.moe_plan`)."""
     c = config
-    out = {"layer_kinds": " ".join(kind.code for kind in layer_kinds(c))}
+    kinds = layer_kinds(c)
+    out = {"layer_kinds": " ".join(kind.code for kind in kinds)}
+    if any(kind.attention == "ssm" for kind in kinds):
+        out.update(ssm_heads=c.ssm_heads, ssm_head_dim=c.ssm_head_dim, ssm_state=c.ssm_state,
+                   ssm_groups=c.ssm_groups, ssm_conv_kernel=c.ssm_conv_kernel,
+                   **ssd.scan_plan(seq, c.ssm_chunk))
     if c.latent_attention:
         out.update(attn_latent_q_rank=c.q_lora_rank, attn_latent_kv_rank=c.kv_lora_rank,
                    attn_rope_dims=c.rotary_dims, attn_head_dim=c.head_dim)
-    else:
+    elif any(kind.attention == "sliding" for kind in kinds):
         windowed = attention_plan(seq, causal=c.causal, implementation=c.attn_impl,
                                   window=c.sliding_window)
         out.update({"attn_window": c.sliding_window},
@@ -535,6 +750,6 @@ def plan(config: MixedStackConfig, batch: int, seq: int) -> Dict[str, Any]:
         "moe_experts_routed": c.n_experts,
         "moe_shared_width": c.shared_expert_width,
     })
-    if c.n_dense_layers < c.n_layers:
+    if _expert_layers(c):
         out.update(moe_plan(c, batch, seq))
     return out

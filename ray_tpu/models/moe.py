@@ -68,10 +68,13 @@ class MoEConfig(TransformerConfig):
     router_score: str = "softmax"
     router_select_bias: bool = False
     route_scale: float = 1.0
-    # a dense SwiGLU of this width on every token, added to the routed sum (0: none)
+    # a dense expert of this width on every token, added to the routed sum (0:
+    # none): a SwiGLU beside gated experts, relu(up)^2 beside those that are not
     shared_expert_width: int = 0
-    # a routed expert's gated unit: "swiglu" (silu(gate) * up) or "reglu"
-    # (relu(gate) * up), between the grouped matmuls in either form
+    # a routed expert's unit between the grouped matmuls, in either form of the
+    # layer: gated, "swiglu" (silu(gate) * up) or "reglu" (relu(gate) * up),
+    # three matrices an expert; or not gated, "relu2" (relu(up)^2), two: such
+    # an expert has no `we_gate` and its shared expert no `ws_gate`
     expert_act: str = "swiglu"
     # the range [first, last) of the published experts this device holds, the
     # others lying on further chips (None: all `n_experts`). The router keeps
@@ -84,6 +87,12 @@ class MoEConfig(TransformerConfig):
     def n_experts_held(self) -> int:
         first, last = self.held_experts or (0, self.n_experts)
         return last - first
+
+    @property
+    def expert_weights(self) -> Tuple[str, ...]:
+        """The leaves of the routed experts, in the order the layer takes them."""
+        _expert_act(self)
+        return ("we_gate", "we_up", "we_down") if self.expert_act in _GATED_ACTS else ("we_up", "we_down")
 
 
 def mixtral_8x7b() -> MoEConfig:
@@ -171,7 +180,8 @@ def init_params(config: MoEConfig, key: jax.Array) -> Params:
     L, E = c.n_layers, c.n_experts
     blocks["router"] = (std * jax.random.normal(keys[0], (L, c.d_model, E))).astype(pd)
     E = c.n_experts_held
-    blocks["we_gate"] = (std * jax.random.normal(keys[1], (L, E, c.d_model, c.d_ff))).astype(pd)
+    if "we_gate" in c.expert_weights:
+        blocks["we_gate"] = (std * jax.random.normal(keys[1], (L, E, c.d_model, c.d_ff))).astype(pd)
     blocks["we_up"] = (std * jax.random.normal(keys[2], (L, E, c.d_model, c.d_ff))).astype(pd)
     blocks["we_down"] = (res_std * jax.random.normal(keys[3], (L, E, c.d_ff, c.d_model))).astype(pd)
     return base
@@ -185,7 +195,8 @@ def logical_axes(config: MoEConfig) -> Params:
     # replicated: 0.13 M weights a layer, and an embed-sharded router makes
     # GSPMD reshard the float32 activations for its gradient
     blocks["router"] = ("layers", None, None)
-    blocks["we_gate"] = ("layers", "expert", "embed", "mlp")
+    if "we_gate" in config.expert_weights:
+        blocks["we_gate"] = ("layers", "expert", "embed", "mlp")
     blocks["we_up"] = ("layers", "expert", "embed", "mlp")
     blocks["we_down"] = ("layers", "expert", "mlp", "embed")
     return axes
@@ -339,7 +350,12 @@ def _reglu(gate, up):
     return jax.nn.relu(gate) * up
 
 
-_EXPERT_ACTS = {"swiglu": swiglu, "reglu": _reglu}
+def _relu2(up):
+    return jnp.square(jax.nn.relu(up))
+
+
+_GATED_ACTS = {"swiglu": swiglu, "reglu": _reglu}      # (gate, up) -> hidden
+_EXPERT_ACTS = {**_GATED_ACTS, "relu2": _relu2}        # relu2: (up) -> hidden
 
 
 def _expert_act(config: "MoEConfig"):
@@ -347,6 +363,19 @@ def _expert_act(config: "MoEConfig"):
         return _EXPERT_ACTS[config.expert_act]
     except KeyError:
         raise ValueError(f"unknown expert activation: {config.expert_act!r}") from None
+
+
+def _expert(config: "MoEConfig", project, weights, act=None):
+    """One expert unit of either form: `project(w)` is the input through the
+    up (or gate) matrix `w`, and the hidden units go through the last of
+    `weights` by the caller. `act`: another unit than the routed experts'.
+    -> (the hidden units, the gate's projection or None where the unit is
+    not gated)."""
+    act = act or _expert_act(config)
+    if len(weights) == 2:
+        return act(project(weights[0])), None
+    gate = project(weights[0])
+    return act(gate, project(weights[1])), gate
 
 
 def _route(scores, select, config):
@@ -370,34 +399,30 @@ def _route(scores, select, config):
 def _gshard_experts(h, probs, weights, config):
     """(B, S, M) -> expert output (B, S, M), rows per expert (E,)."""
     c, dt = config, config.dtype
-    we_gate, we_up, we_down = weights
     s = h.shape[1]
     capacity = max(1, int(c.capacity_factor * c.top_k * s / c.n_experts))
     dispatch, combine = topk_dispatch(probs, c.top_k, capacity, c.norm_topk_prob)
     # dispatch: (B,S,E,C) x (B,S,M) -> (E,B,C,M); XLA turns the e-sharded
     # contraction into the all-to-all over the ep axis
     expert_in = jnp.einsum("bsec,bsm->ebcm", dispatch.astype(dt), h)
-    gate = jnp.einsum("ebcm,emf->ebcf", expert_in, we_gate)
-    up = jnp.einsum("ebcm,emf->ebcf", expert_in, we_up)
-    act = _expert_act(c)(gate, up)
-    expert_out = jnp.einsum("ebcf,efm->ebcm", act, we_down)
+    act, _ = _expert(c, lambda w: jnp.einsum("ebcm,emf->ebcf", expert_in, w), weights)
+    expert_out = jnp.einsum("ebcf,efm->ebcm", act, weights[-1])
     out = jnp.einsum("ebcm,bsec->bsm", expert_out, combine.astype(dt))
     return out, jnp.sum(dispatch, axis=(0, 1, 3))
 
 
 def _gated_groups(expert_in, weights, group_sizes, tile, impl, config, interpret=False):
-    """The three grouped matmuls of the expert-sorted rows `expert_in`, the
-    configuration's gated unit between them. -> (output, a slot's count of
-    hidden units a ReLU gate leaves non-zero; None where the unit has no
-    dead ones to count)."""
-    we_gate, we_up, we_down = weights
+    """The grouped matmuls of the expert-sorted rows `expert_in` (three of a
+    gated expert, two of one that is not), the configuration's unit between
+    them. -> (output, a slot's count of hidden units a ReLU gate leaves
+    non-zero; None where the unit has no dead ones to count)."""
 
     def gmm(lhs, w):
         return grouped_matmul(lhs, w, group_sizes, tile_rows=tile, implementation=impl,
                               interpret=interpret)
 
-    gate = gmm(expert_in, we_gate)
-    out = gmm(_expert_act(config)(gate, gmm(expert_in, we_up)), we_down)
+    act, gate = _expert(config, lambda w: gmm(expert_in, w), weights)
+    out = gmm(act, weights[-1])
     live = None
     if config.expert_act == "reglu":
         live = jnp.sum(jax.lax.stop_gradient(gate) > 0, axis=-1, dtype=jnp.float32)
@@ -472,7 +497,7 @@ def _held_experts(h, gates, experts, weights, config, tile, impl):
 
     The (token, choice) rows are sorted by expert, those routed to an absent
     expert last; they are never gathered, multiplied or combined. The held
-    rows go through the three grouped matmuls `held_buffer_rows` at a time:
+    rows go through the grouped matmuls `held_buffer_rows` at a time:
     pass p takes the sorted rows [p R, (p + 1) R), whose groups are what is
     left of each expert's rows there, so every pass has the layout
     `ops/grouped_matmul` asks for and the same static shapes. The first pass
@@ -645,7 +670,7 @@ def _dropless_experts(h, probs, weights, config, mesh, select=None):
                       *(("moe_act_live_units",) if config.expert_act == "reglu" else ()))
     return jax.shard_map(
         shard, mesh=mesh,
-        in_specs=(tok, tok, (P(None, None, tp), P(None, None, tp), P(None, tp, None)),
+        in_specs=(tok, tok, (*(P(None, None, tp),) * (len(weights) - 1), P(None, tp, None)),
                   *((tok,) if select is not None else ())),
         out_specs=(tok, P(), {name: P() for name in held_names}), check_vma=False,
     )(h, probs, weights, *((select,) if select is not None else ()))
@@ -679,8 +704,8 @@ def moe_mlp(
         select = None
         if c.router_select_bias:
             select = probs + jax.lax.stop_gradient(lp["expert_bias"].astype(jnp.float32))
-    weights = tuple(lp[name].astype(c.dtype) for name in ("we_gate", "we_up", "we_down"))
-    mesh = _mesh_of(lp["we_gate"])
+    weights = tuple(lp[name].astype(c.dtype) for name in c.expert_weights)
+    mesh = _mesh_of(lp["we_up"])
     if expert_parallel(mesh):
         if select is not None or c.held_experts is not None or c.route_scale != 1.0:
             raise NotImplementedError("the GShard form routes by the plain top-k of the scores")
@@ -690,12 +715,10 @@ def moe_mlp(
         out, load, held = _dropless_experts(h, probs, weights, c, mesh, select)
     if c.shared_expert_width:
         with jax.named_scope("moe.shared"):
-            dt = c.dtype
-            out = out + jnp.einsum(
-                "bsf,fm->bsm",
-                swiglu(jnp.einsum("bsm,mf->bsf", h, lp["ws_gate"].astype(dt)),
-                       jnp.einsum("bsm,mf->bsf", h, lp["ws_up"].astype(dt))),
-                lp["ws_down"].astype(dt))
+            shared = tuple(lp[name.replace("we_", "ws_")].astype(c.dtype) for name in c.expert_weights)
+            act, _ = _expert(c, lambda w: jnp.einsum("bsm,mf->bsf", h, w), shared,
+                             swiglu if len(shared) == 3 else None)
+            out = out + jnp.einsum("bsf,fm->bsm", act, shared[-1])
     # E * sum_e f_e P_e: f_e the share of the (token, choice) pairs routed to
     # e (it carries no gradient; under GShard, of those kept), P_e the mean
     # router probability

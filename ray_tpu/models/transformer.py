@@ -675,6 +675,11 @@ def _no_latent_attention(config: TransformerConfig, where: str) -> None:
         raise NotImplementedError(
             f"latent attention is run by the mixed stack's training forward alone: {where} has "
             "neither its seven leaves nor a cache of latent rows (the absorbed decode form)")
+    if getattr(config, "layer_pattern", ""):
+        raise NotImplementedError(
+            f"a patterned stack (state-space `ssm` layers, one sublayer a layer) is run by the mixed "
+            f"stack's training forward alone: {where} has neither a state-space mixer's leaves nor a "
+            "cache of its convolution's window and its state")
 
 
 def init_cache(

@@ -41,6 +41,7 @@ def default_rules() -> List[Tuple[str, MeshAxis]]:
         ("embed", "fsdp"),         # param hidden dim: ZeRO-3 shard
         ("heads", "tp"),           # attention heads: Megatron split
         ("kv_heads", "tp"),
+        ("ssm_heads", None),       # a state-space mixer's heads: whole on every device so far
         ("head_dim", None),
         ("mlp", "tp"),             # ffn hidden: Megatron split
         ("vocab", "tp"),

@@ -673,11 +673,16 @@ def roofline(cost: StepCost, step_time_s: float) -> Dict[str, Any]:
 # those passes' own `moe.dispatch`, `moe.experts` and `moe.combine`);
 # `head` is the final norm, the logits and the loss; `mtp` a multi-token
 # prediction module whole (its block's own scopes nest in it, its pass of the
-# head is `mtp` and `head`).
+# head is `mtp` and `head`); `ssm` a state-space mixer whole, and inside it
+# `ssm.in_proj` (norm and the one projection), `ssm.conv` (the causal
+# depthwise convolution and its silu), `ssm.scan` (the step's softplus and
+# the chunked selective scan), `ssm.gate_norm` (the gate and the norm a
+# group) and `ssm.out_proj` (the projection and the residual).
 STEP_SCOPES: Tuple[str, ...] = (
     "steplog.fwd_bwd_compute", "steplog.optimizer_update",
     "embed",
     "attn.full", "attn.window", "attn.proj", "attn.latent", "attn.kernel", "attn.out",
+    "ssm", "ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.gate_norm", "ssm.out_proj",
     "mlp",
     "moe", "moe.route", "moe.select", "moe.dispatch", "moe.experts", "moe.combine", "moe.passes",
     "moe.shared",

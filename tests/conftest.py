@@ -15,9 +15,10 @@ import os
 # file of many holds the next file back on a busy worker: the wall follows
 # the place of the long tests, not the work. The rule: a test over 25 s (on
 # the driver's loaded machine: ~12 s alone) lives in a file of at most 6
-# tests (`*_long.py` beside its origin, `test_tpu_compile_cells.py` for a
-# cell's compiled step), and such a file takes no more than 450 s. A new
-# model's compile test and reference test go there from the start.
+# tests (`*_long.py` beside its origin, `test_tpu_compile_cells.py` and,
+# since PR 48, `test_tpu_compile_cells_2.py` for a cell's compiled step),
+# and such a file takes no more than 450 s. A new model's compile test and
+# reference test go there from the start.
 # `python scripts/suite_schedule.py <junit.xml>` names what breaks it.
 
 # Tests run on the CPU backend whatever the machine holds: the chip is

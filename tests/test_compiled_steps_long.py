@@ -81,7 +81,9 @@ def test_mixed_step_table_holds_both_attention_kinds_both_mlps_and_the_expert_la
     moe = {"moe", "moe.route", "moe.select", "moe.dispatch", "moe.experts", "moe.combine", "moe.passes",
            "moe.shared"}
     # every scope but the two a latent-attention stack with a prediction module adds (PR 44)
-    assert {scope for scope, _ in pairs} == set(profiling.STEP_SCOPES) - {"attn.latent", "mtp"}
+    # and a state-space mixer's (PR 48)
+    assert {scope for scope, _ in pairs} == {
+        s for s in profiling.STEP_SCOPES if s not in ("attn.latent", "mtp") and not s.startswith("ssm")}
     for sublayer in {"attn.window", "attn.full", "mlp"} | moe:
         assert {(sublayer, "fwd"), (sublayer, "recompute"), (sublayer, "bwd")} <= pairs
     for found in mixed.values():

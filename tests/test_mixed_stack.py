@@ -597,3 +597,158 @@ def test_the_all_expert_step_reports_its_router_its_unit_and_the_live_share():
     assert [(run["scanned"], run["period"], run["layers"]) for run in costs["runs"]] == [(True, 4, 8)]
     assert [(run["scanned"], run["period"], run["layers"])
             for run in model_family(tiny()).block_costs(tiny(), 48)["runs"]] == [(True, 1, 2), (False, 4, 4)]
+
+
+# ------------------------------------------- layers of ONE sublayer (PR 48)
+
+
+def tiny_pattern(**kw) -> MixedStackConfig:
+    """The nemotron_h family in small: `MEMEM*EME`, every layer one mixer
+    behind one norm: Mamba-2 layers of 8 heads of 8 with a state of 16 in 2
+    groups and a chunk of 16, attention of 4 / 2 heads of 16 without
+    positions, 32 sigmoid-routed squared-ReLU experts (8 held, top-6, gates
+    x 2.5) beside a shared one. The pattern is longer than the depth, as the
+    published one is."""
+    base = dict(
+        vocab_size=256, d_model=64, n_layers=9, layer_pattern="MEMEM*EMEMEM*E", n_heads=4,
+        n_kv_heads=2, d_head=16, ssm_heads=8, ssm_head_dim=8, ssm_state=16, ssm_groups=2, ssm_chunk=16,
+        d_ff=32, max_seq=64, pos_emb="rope", norm="rmsnorm", act="swiglu", use_bias=False,
+        tie_embeddings=False, norm_eps=1e-5, dtype=jnp.float32, remat=True, n_experts=32,
+        held_experts=(0, 8), top_k=6, norm_topk_prob=True, route_scale=2.5, router_score="sigmoid",
+        router_select_bias=True, expert_act="relu2", shared_expert_width=64, router_aux_coeff=0.0,
+        frozen_leaves=("router",), embedding_std=1.0, router_std=0.06)
+    return MixedStackConfig(**{**base, **kw})
+
+
+PUBLISHED_PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
+
+
+@pytest.mark.parametrize("pattern, want", [
+    ("MEMEM*EME", "2 x (-M e-) | -M -F e- -M e-"),
+    ("MEMEM*E", "2 x (-M e-) | -M -F e-"),
+    (PUBLISHED_PATTERN, "5 x (-M e- -M e- -M -F e-) | 3 x (-M e-) | -M -F e- -M e- -M e- -M e- -M e-"),
+    ("MMMM", "4 x (-M)"), ("EM*", "e- -M -F")],
+    ids=["the-cell", "the-first-period", "the-published-52", "all-alike", "nothing-repeats"])
+def test_a_pattern_of_one_sublayer_layers_groups_into_runs_of_whole_kinds(pattern, want):
+    config = tiny_pattern(n_layers=len(pattern), layer_pattern=pattern)
+    kinds = layer_kinds(config)
+    runs = stack_runs(kinds)
+    assert _said(runs) == want
+    assert [k for run in runs for _ in range(run.repeats) for k in run.kinds] == kinds
+    assert all(kind.sublayers == 1 for kind in kinds)
+    codes = {"M": LayerKind("ssm", "none"), "*": LayerKind("full", "none"), "E": LayerKind("none", "experts")}
+    assert kinds == [codes[character] for character in pattern]
+
+
+def test_a_one_sublayer_layer_owns_one_norm_and_its_kinds_leaves_only():
+    config = tiny_pattern()
+    family = model_family(config)
+    params = jax.eval_shape(lambda: family.init_params(config, jax.random.PRNGKey(0)))
+    (mamba, experts), (_, attention, *_rest) = params["runs"]
+    # (eval_shape hands a dict back in the order of its keys)
+    assert set(mamba) == {"ln1_scale", "ssm_in", "ssm_conv_w", "ssm_conv_b", "ssm_dt_bias", "ssm_a_log",
+                          "ssm_d", "ssm_norm_scale", "ssm_out"}
+    assert set(attention) == {"ln1_scale", "wq", "wk", "wv", "wo"}
+    # a non-gated expert: two matrices an expert, two of the shared one
+    assert set(experts) == {"ln2_scale", "router", "expert_bias", "we_up", "we_down", "ws_up", "ws_down"}
+    assert mamba["ssm_in"].shape == (2, 64, 64 + (64 + 2 * 2 * 16) + 8)      # [z | x B C | dt]
+    assert mamba["ssm_conv_w"].shape == (2, 128, 4) and mamba["ssm_out"].shape == (2, 8, 8, 64)
+    assert experts["we_up"].shape == (2, 8, 64, 32) and experts["ws_up"].shape == (2, 64, 64)
+    axes = family.logical_axes(config)["runs"][0][0]
+    assert axes["ssm_out"] == ("layers", "ssm_heads", "head_dim", "embed")
+    assert axes["ssm_a_log"] == ("layers", "ssm_heads")
+    # the Mamba leaves start as the family starts them
+    leaves = family.init_params(config, jax.random.PRNGKey(0))["runs"][0][0]
+    a, step = np.exp(np.asarray(leaves["ssm_a_log"])), np.asarray(jax.nn.softplus(leaves["ssm_dt_bias"]))
+    assert 1.0 <= a.min() and a.max() <= 16.0 and 0.001 * 0.999 <= step.min() and step.max() <= 0.1 * 1.001
+    assert np.all(np.asarray(leaves["ssm_d"]) == 1) and np.all(np.asarray(leaves["ssm_norm_scale"]) == 1)
+    assert np.all(np.asarray(leaves["ssm_conv_b"]) == 0) and np.abs(np.asarray(leaves["ssm_conv_w"])).max() <= 0.5
+    # a projection into the residual stream: 0.02 over the root of the NINE sublayers the stack has
+    assert float(jnp.std(leaves["ssm_out"])) == pytest.approx(0.02 / 3, rel=0.05)
+    said = family.plan(config, 2, 48)
+    assert said["layer_kinds"] == "-M e- -M e- -M -F e- -M e-"
+    assert (said["ssm_heads"], said["ssm_head_dim"], said["ssm_state"], said["ssm_groups"], said["ssm_chunk"],
+            said["ssm_conv_kernel"], said["ssm_scan_impl"], said["moe_expert_act"]) == (
+        8, 8, 16, 2, 16, 4, "xla_chunked", "relu2")
+    assert "attn_window" not in said
+
+
+@pytest.mark.parametrize("change, match", [
+    (dict(layer_pattern="MEME-EMEM"), "layer_pattern"), (dict(layer_pattern="MEM"), "at least n_layers"),
+    (dict(ssm_heads=0), "ssm_heads"), (dict(ssm_groups=3), "ssm_heads"), (dict(mtp_modules=1), "patterned stack")],
+    ids=["a-dense-mlp-layer", "shorter-than-the-depth", "no-heads", "ragged-groups", "with-a-module"])
+def test_what_a_pattern_cannot_run_is_refused_by_name(change, match):
+    with pytest.raises(ValueError, match=match):
+        tiny_pattern(**change)
+
+
+def test_block_costs_price_the_sublayers_a_layer_has():
+    """An `M` layer costs its mixer and no MLP, an `E` layer its experts and
+    no attention (two matrices an expert where it is not gated); the one
+    candidate a state-space layer names is the scan's output WITH its states."""
+    from ray_tpu.models.mixed_stack import _expert_costs, _ssm_costs, block_costs
+
+    config = tiny_pattern()
+    costs = block_costs(config, 48)
+    assert [(run["scanned"], run["layers"], run["period"]) for run in costs["runs"]] == [
+        (True, 4, 2), (False, 5, 5)]
+    by_name = {c.names: c for c in costs["candidates"]}
+    assert by_name["ssm_scan_out", "ssm_chunk_states"].layers == (2, 2)
+    assert by_name["attn_out", "attn_lse"].layers == (0, 1) and by_name["attn_residual",].layers == (0, 1)
+    ssm, experts = _ssm_costs(config), _expert_costs(config, lambda weight: 1)
+    inner, conv = 64, 64 + 2 * 2 * 16
+    assert ssm["flops"] == (2 * 64 * (inner + conv + 8) + 2 * 4 * conv
+                            + 2 * 16 * 2 * 16 + 2 * 16 * inner + 4 * inner * 16 + 2 * inner * 64)
+    assert experts["flops"] == int(2 * 64 * (32 + 2 * 64 + 2 * (6 * 8 / 32) * 32))
+    gated = _expert_costs(dataclasses.replace(config, expert_act="swiglu"), lambda weight: 1)
+    assert gated["flops"] == int(2 * 64 * (32 + 3 * 64 + 3 * (6 * 8 / 32) * 32))
+    # every layer is one sublayer: the stack's FLOPs are the sum of the nine
+    attention = next(c for c in costs["candidates"] if c.names == ("attn_residual",))
+    assert costs["flops"] > 4 * ssm["flops"] + 4 * experts["flops"] + attention.flops
+
+
+def test_the_three_shipped_mixed_stack_cells_keep_their_kinds_runs_and_leaves():
+    """`train-trinity-mini-8k`, `train-smallthinker-16k` and `train-glm47flash-8k`
+    resolve to the kinds, runs and leaf names they had before a layer could
+    be one sublayer (their seeded trees and first losses were compared bit
+    for bit with the parent's when PR 48 was built)."""
+    from benchmark import model_config
+
+    want = {
+        "trinity-mini-train-1chip": ("dS dS eS eF eS eS", "2 x (dS) | eS eF eS eS"),
+        "smallthinker-21b-a3b-train-1chip": ("eF eS eS eS eF eS eS eS", "2 x (eF eS eS eS)"),
+        "glm-4.7-flash-train-1chip": ("dL eL eL eL eL", "dL | 4 x (eL)")}
+    leaves = {
+        "dS": ["ln1_scale", "ln1_post_scale", "ln2_scale", "ln2_post_scale", "wq", "wk", "wv", "wg", "wo",
+               "q_norm_scale", "k_norm_scale", "w_gate", "w_up", "w_down"],
+        "eF": ["ln1_scale", "ln2_scale", "wq", "wk", "wv", "wo", "router", "we_gate", "we_up", "we_down"],
+        "eL": ["ln1_scale", "ln2_scale", "wq_a", "q_a_norm_scale", "wq_b", "wkv_a", "kv_a_norm_scale", "wkv_b",
+               "wo", "router", "expert_bias", "we_gate", "we_up", "we_down", "ws_gate", "ws_up", "ws_down"]}
+    for name, (kinds, runs) in want.items():
+        config = model_config.transformer_config(model_config.load_config(
+            os.path.join(ROOT, "benchmark", "configs", name + ".json")))
+        assert " ".join(k.code for k in layer_kinds(config)) == kinds
+        assert _said(stack_runs(layer_kinds(config))) == runs
+        assert all(kind.sublayers == 2 for kind in layer_kinds(config))
+        shapes = jax.eval_shape(lambda: model_family(config).init_params(config, jax.random.PRNGKey(0)))
+        first = {run.kinds[0].code: sorted(period[0]) for run, period in zip(
+            stack_runs(layer_kinds(config)), shapes["runs"])}
+        for code, names in first.items():
+            if code in leaves:
+                assert names == sorted(leaves[code]), (name, code)
+
+
+def test_serving_refuses_a_patterned_stack_by_name():
+    """Training only: the dense cache's `decode_step` and `prefill` and the
+    paged engine's pool refuse the `ssm` kind by name, as they do the latent
+    kind."""
+    from ray_tpu.models import decode_step, prefill
+    from ray_tpu.serve.llm.paged import PagedConfig, init_paged_cache
+
+    config = tiny_pattern()
+    tokens, lengths = jnp.zeros((1, 4), jnp.int32), jnp.ones((1,), jnp.int32)
+    for refused in (lambda: decode_step({}, {}, tokens[:, 0], lengths, config),
+                    lambda: prefill({}, tokens, lengths, {}, config),
+                    lambda: init_paged_cache(config, PagedConfig(page_size=8, num_pages=4))):
+        with pytest.raises(NotImplementedError, match="state-space"):
+            refused()

@@ -152,7 +152,7 @@ def _one_layer(config, key=0):
 
     params = init_params(config, jax.random.PRNGKey(key))
     lp = jax.tree.map(lambda w: 3.0 * w[0], {k: params["blocks"][k] for k in (
-        "router", "we_gate", "we_up", "we_down")})
+        "router", *config.expert_weights)})
     h = jax.random.normal(jax.random.PRNGKey(key + 1), (2, 16, config.d_model))
     return moe_mlp, lp, h
 
@@ -188,6 +188,43 @@ def test_the_gated_unit_through_the_grouped_form_equals_the_dense_einsum(expert_
         np.testing.assert_allclose(np.asarray(ours[name]), np.asarray(theirs[name]), atol=2e-4)
     with pytest.raises(ValueError, match="unknown expert activation"):
         moe_mlp(h, lp, dataclasses.replace(config, expert_act="geglu"))
+
+
+def test_a_non_gated_expert_has_two_matrices_in_both_forms_and_equals_the_dense_einsum(model):
+    """`expert_act="relu2"`: W_down relu(W_up m)^2, no `we_gate` leaf in the
+    tree or its axes, two grouped matmuls; the grouped form equals every
+    expert on every token, gated, output and gradient, and under an `ep` mesh
+    the einsum form equals the grouped one."""
+    import dataclasses
+
+    config = dataclasses.replace(moe_tiny(), expert_act="relu2")
+    assert config.expert_weights == ("we_up", "we_down")
+    params = init_params(config, jax.random.PRNGKey(0))
+    assert "we_gate" not in params["blocks"] and "we_gate" not in logical_axes(config)["blocks"]
+    moe_mlp, lp, h = _one_layer(config)
+
+    def dense(h, lp):
+        logits = jnp.einsum("bsm,me->bse", h, lp["router"], precision=jax.lax.Precision.HIGHEST)
+        top, chosen = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), config.top_k)
+        gates = jnp.einsum("bsk,bske->bse", top / top.sum(-1, keepdims=True),
+                           jax.nn.one_hot(chosen, config.n_experts))
+        hidden = jnp.square(jax.nn.relu(jnp.einsum("bsm,emf->bsef", h, lp["we_up"])))
+        return jnp.einsum("bse,bsef,efm->bsm", gates, hidden, lp["we_down"])
+
+    out, _ = moe_mlp(h, lp, config)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(jax.jit(dense)(h, lp)), atol=2e-5)
+    ours = jax.jit(jax.grad(lambda lp: jnp.sum(moe_mlp(h, lp, config)[0] ** 2)))(lp)
+    theirs = jax.jit(jax.grad(lambda lp: jnp.sum(dense(h, lp) ** 2)))(lp)
+    for name in ("we_up", "we_down"):
+        np.testing.assert_allclose(np.asarray(ours[name]), np.asarray(theirs[name]), atol=2e-4)
+    config = dataclasses.replace(config, capacity_factor=8.0)
+    tokens = jax.random.randint(jax.random.PRNGKey(3), (4, 16), 0, config.vocab_size)
+    expected, _ = forward(params, tokens, config)
+    mesh = build_mesh(MeshSpec(dp=2, ep=2, tp=2))
+    sharded = shard_tree(params, logical_axes(config), default_rules(), mesh)
+    with jax.set_mesh(mesh):
+        out, _ = jax.jit(lambda p, t: forward(p, t, config))(sharded, tokens)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(expected), atol=1e-4, rtol=1e-4)
 
 
 def test_relu_gated_experts_in_the_gshard_form_equal_the_grouped_form(model):

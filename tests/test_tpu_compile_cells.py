@@ -3,8 +3,9 @@
 What tests/test_tpu_compile.py says of its kernels holds here: nothing runs,
 and a compile that passes is not a chip run. Each test takes 25-130 s, so
 they live apart from the kernel compiles (the rule at the top of
-tests/conftest.py): this file grows by one test a model, and when it would
-pass 6 tests the next PR starts tests/test_tpu_compile_cells_2.py.
+tests/conftest.py): this file grew by one test a model until its five took 380 s of the
+450 a file of long tests may; since PR 48 a new model's goes to
+tests/test_tpu_compile_cells_2.py.
 """
 
 import os
@@ -268,4 +269,3 @@ def test_glm47flash_cell_step_keeps_the_attention_outputs_and_the_latents_and_co
     assert not any("attn.latent" in scopes and "attn.proj" not in scopes for scopes in scoped)
     # 706.5 M parameters x 12 bytes of state (the gradients are the step's own)
     assert compiled.memory_analysis().argument_size_in_bytes / GIB == pytest.approx(7.90, abs=0.02)
-

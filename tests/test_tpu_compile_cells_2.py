@@ -1,0 +1,56 @@
+"""More training cells' whole steps at real widths, compiled for a DESCRIBED
+v5e: what tests/test_tpu_compile_cells.py says of its tests holds here (nothing
+runs; a compile that passes is not a chip run). That file's five tests take
+380 s of the 450 s a file of long tests may (the rule at the top of
+tests/conftest.py), so a new model's compiled step lives here: at most six."""
+
+import jax  # noqa: F401
+import pytest
+
+from ray_tpu.parallel import MeshSpec, build_mesh
+from tests.test_tpu_compile import as_tpu, v5e  # noqa: F401 - fixtures
+from tests.test_tpu_compile_cells import GIB, _cell_step_shapes, _kernels_named
+
+
+def test_nemotron3nano_cell_step_runs_the_scan_under_its_scopes_and_compiles(as_tpu, monkeypatch, v5e):
+    """`train-nemotron3nano-8k`'s whole step (2 x 8,193 tokens) for one described
+    v5e chip of 15.75 GiB: `ME` scanned twice, then `M*EME` unrolled. Each of
+    the 7 layer bodies is one sublayer; the state-space mixer's five scopes
+    lie inside `ssm` in the forward pass, its recomputation and the backward
+    pass; the one attention layer runs the causal D = 128 flash kernels and
+    the expert layers TWO grouped matmuls a pass (a non-gated expert); the
+    fused head takes the whole sequence as its chunk."""
+    from ray_tpu.ops import losses
+    from ray_tpu.train.lm import make_train_step
+    from ray_tpu.util import profiling
+
+    mesh = build_mesh(MeshSpec(), devices=[as_tpu])
+    config, opt, state, shardings, tokens = _cell_step_shapes(
+        "nemotron-3-nano-30b-a3b-train-1chip", mesh, (2, 8193))
+    monkeypatch.setattr(losses, "device_hbm_bytes", lambda: int(15.75 * GIB))
+    step = make_train_step(config, opt, mesh, state_shardings=shardings)
+    plan = step.remat_plan_for(tokens.shape, state)
+    assert plan["remat"] in ("whole_block", "selective")
+    assert set(plan["remat_saved"]) <= {"ssm_scan_out", "ssm_chunk_states", "attn_out", "attn_lse", "attn_residual"}
+    assert step.loss_chunk_for(tokens.shape, state) == 8192
+    compiled = step.lower(state, {"tokens": tokens}).compile()
+    # one attention layer, in the unrolled run: the forward kernel and its recomputation, one backward
+    assert _kernels_named(compiled, "flash_bwd_dkv_dq") == 1 and "flash_win" not in compiled.as_text()
+    # 4 expert layers in 3 bodies (the scan's, two unrolled) x 2 projections x (the first pass
+    # through the held buffer, the later ones), forward and recomputed
+    assert _kernels_named(compiled, "moe_gmm_fwd") == 3 * 2 * 2 * 2
+    _, table = profiling.program_ops_table(profiling._module_text(compiled))
+    pairs = {(scope, pass_) for instances in table.values() for scopes, pass_, _ in instances for scope in scopes}
+    for scope in ("ssm", "ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.gate_norm"):
+        assert {(scope, "fwd"), (scope, "recompute"), (scope, "bwd")} <= pairs, scope
+    # the out-projection's output only feeds the next layer's input, which is kept: not run again
+    assert {("ssm.out_proj", "fwd"), ("ssm.out_proj", "bwd")} <= pairs
+    scoped = [set(scopes) for instances in table.values() for scopes, _, _ in instances]
+    assert not any(scopes & {"ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.gate_norm", "ssm.out_proj"}
+                   and "ssm" not in scopes for scopes in scoped)
+    assert not any({"ssm", "moe"} <= scopes or {"ssm", "attn.full"} <= scopes for scopes in scoped)
+    assert not any("mlp" in scopes or "attn.window" in scopes for scopes in scoped)
+    # 667.0 M parameters x 12 bytes of state (the gradients are the step's own), and the step fits
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes / GIB == pytest.approx(7.45, abs=0.02)
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes) / GIB < 15.75
