@@ -648,20 +648,24 @@ def _expert_costs(config: MixedStackConfig, split: Callable[[str], int]) -> Dict
 
 # a layer's absent sublayer, in `block_costs`
 _NO_SUBLAYER: Dict[str, Any] = {"flops": 0, "width": 0, "candidates": ()}
-# The chunked scan's share of the chip's peak, by which the operations it
-# spares count for their time beside matmuls that run near the peak (as
-# transformer._FLASH_SHARE_OF_PEAK does for the attention kernels): from the
-# chip at the `train-nemotron3nano-8k` cell's shapes (PERF.md section 6, PR 48:
-# one forward scan of 2 x 8,192 tokens, 55.8 GFLOP as computed, in 6.57 ms)
-_SSD_SHARE_OF_PEAK = 0.043
+# The scan's share of the chip's peak, by which the operations it spares count
+# for their time beside matmuls that run near the peak (as
+# transformer._FLASH_SHARE_OF_PEAK does for the attention kernels), by the form
+# that runs (`ops/ssd.resolve_scan_impl`): from the chip at the
+# `train-nemotron3nano-8k` cell's shapes, one forward scan of 2 x 8,192 tokens,
+# 55.8 GFLOP as computed, in 6.57 ms as XLA einsums (PERF.md section 6, PR 48)
+# and in 1.63 ms as `ssd_fwd` with the layouts around it (PR 49)
+_SSD_SHARE_OF_PEAK = {"xla_chunked": 0.043, "pallas": 0.174}
 
 
 def _ssm_costs(config: MixedStackConfig) -> Dict[str, Any]:
     """`_ssm_sublayer`'s part of `block_costs`, a layer and token (whole on
     every device: its weights are not split). The one candidate is the scan's
-    output WITH the states it keeps a block of chunks (`ssm_scan_out`,
-    `ssm_chunk_states` of ops/ssd): with both the backward pass does not walk
-    the sequence forward again; with one of them alone it must."""
+    output WITH the states its backward pass starts from (`ssm_scan_out`,
+    `ssm_chunk_states` of ops/ssd: the kernels keep the state that entered
+    every chunk in the activations' dtype, the XLA form a float32 state a
+    block of chunks): with both the backward pass does not run the scan
+    forward again; with one of them alone it must."""
     c = config
     heads, p, n, chunk = c.ssm_heads, c.ssm_head_dim, c.ssm_state, c.ssm_chunk
     inner, conv = heads * p, c.ssm_conv_width
@@ -669,8 +673,10 @@ def _ssm_costs(config: MixedStackConfig) -> Dict[str, Any]:
     # as ops/ssd computes it: C B^T and the weighted x inside a chunk, a chunk's
     # state, the read-out of the state that entered it
     scan = 2 * chunk * c.ssm_groups * n + 2 * chunk * inner + 4 * inner * n
-    # a float32 state a block of chunks, in features of the activations' dtype a token
-    state = -(-inner * n * 4 // (chunk * ssd.BLOCK_CHUNKS * itemsize))
+    impl = ssd.resolve_scan_impl(chunk=chunk, heads=heads, groups=c.ssm_groups, head_dim=p, state=n)
+    # the kept states, in features of the activations' dtype a token
+    state = (inner * n // chunk if impl == "pallas"
+             else -(-inner * n * 4 // (chunk * ssd.BLOCK_CHUNKS * itemsize)))
     return {
         "flops": (2 * c.d_model * (inner + conv + heads) + 2 * c.ssm_conv_kernel * conv + scan
                   + 2 * inner * c.d_model),
@@ -682,7 +688,7 @@ def _ssm_costs(config: MixedStackConfig) -> Dict[str, Any]:
         # 41.9 k features a row, where this counts 42.9 k
         "width": (3 * c.d_model + (inner + conv + heads) + conv + (inner + conv) + 2 * inner),
         "candidates": (RematCandidate(("ssm_scan_out", "ssm_chunk_states"), inner + state, scan,
-                                      int(scan / _SSD_SHARE_OF_PEAK), False, ()),),
+                                      int(scan / _SSD_SHARE_OF_PEAK[impl]), False, ()),),
     }
 
 
@@ -731,7 +737,8 @@ def plan(config: MixedStackConfig, batch: int, seq: int) -> Dict[str, Any]:
     if any(kind.attention == "ssm" for kind in kinds):
         out.update(ssm_heads=c.ssm_heads, ssm_head_dim=c.ssm_head_dim, ssm_state=c.ssm_state,
                    ssm_groups=c.ssm_groups, ssm_conv_kernel=c.ssm_conv_kernel,
-                   **ssd.scan_plan(seq, c.ssm_chunk))
+                   **ssd.scan_plan(seq, c.ssm_chunk, heads=c.ssm_heads, groups=c.ssm_groups,
+                                   head_dim=c.ssm_head_dim, state=c.ssm_state))
     if c.latent_attention:
         out.update(attn_latent_q_rank=c.q_lora_rank, attn_latent_kv_rank=c.kv_lora_rank,
                    attn_rope_dims=c.rotary_dims, attn_head_dim=c.head_dim)

@@ -24,16 +24,36 @@ sums under the causal mask, never the two factors exp(c_l) exp(-c_s)) and
 the carried state are float32; the products take their operands in the
 dtype x comes in and accumulate in float32.
 
-The chunks are walked `BLOCK_CHUNKS` at a time by a `lax.scan` that carries
-the state from block to block, with a backward pass of its own (`_walk`, a
-`custom_vjp`): differentiated, the walk keeps its inputs and ONE state a
-block (the state that entered it), and the backward pass computes a block
-again from that state before it transposes it, whatever the caller
-recomputes around it. What a block builds and drops is (B, chunks, H, chunk,
-chunk) decays and scores and (B, chunks, H, P, N) states, 0.2 GB at 2 x
-1,024 tokens of 64 heads where the whole 8,192-token sequence at once is 1.6
-GB. The walk's output and the kept states carry the names `ssm_scan_out` and
-`ssm_chunk_states` for a checkpoint policy around the caller.
+Two forms compute it, chosen by a static rule on the backend and the shapes
+(`resolve_scan_impl`; `scan_plan` reports it; no flag, and a form is never
+tried and swapped for the other):
+
+- "pallas", on a TPU at the sizes the kernels tile: two Mosaic kernels under
+  one `custom_vjp`, `ssd_fwd` and `ssd_bwd`, every call named so that a device
+  trace prints it. A grid step computes ONE chunk of one group's heads in
+  VMEM: C B^T once a group, exp(c_l - c_s) under the causal mask, the weighted
+  sum over x, the read-out of the state, the state's update and + d x before
+  the one write of y. The group's float32 state lives in VMEM scratch from a
+  sequence's first chunk to its last; no decays, scores or per-chunk states
+  cross HBM. Differentiated, the forward also writes the state that ENTERED
+  each chunk, narrowed to x's dtype as the read-out takes it ((B, chunks, N,
+  H P): 134 MB a layer of 2 x 8,192 tokens in bfloat16), and the backward
+  walks the chunks in reverse with the state's cotangent in scratch, builds
+  the chunk's scores and decays again in VMEM and writes dx, d(dt),
+  d(log-decay) and dB, dC summed over the group's heads.
+- "xla_chunked", everywhere else and what the kernels are compared with: XLA
+  einsums (`_block`) over `BLOCK_CHUNKS` chunks at a time, walked by a
+  `lax.scan` that carries the state from block to block, with a backward pass
+  of its own (`_walk`, a `custom_vjp`): differentiated, the walk keeps its
+  inputs and ONE float32 state a block (the state that entered it), and the
+  backward pass computes a block again from that state before it transposes
+  it. What a block builds and drops is (B, chunks, H, chunk, chunk) decays and
+  scores and (B, chunks, H, P, N) states, 0.2 GB at 2 x 1,024 tokens of 64
+  heads.
+
+Either form's output and kept states carry the names `ssm_scan_out` and
+`ssm_chunk_states` for a checkpoint policy around the caller: with both kept,
+its backward pass does not run the scan forward a second time.
 
 A sequence that is no multiple of the chunk is refused by name: padding at
 the end would be silent work, and the cell's sequences are multiples.
@@ -41,13 +61,17 @@ the end would be silent work, and the cell's sequences are multiples.
 
 from __future__ import annotations
 
-from typing import Tuple
+import functools
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 F32 = jnp.float32
+_IMPLEMENTATIONS = ("xla_chunked", "pallas")
 
 # Chunks a step of the scan over the sequence computes at once (and a
 # backward step computes again). Set by a sweep on the chip at the
@@ -67,12 +91,69 @@ def causal_conv1d(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
     return jax.nn.silu(out).astype(x.dtype)
 
 
-def scan_plan(seq: int, chunk: int) -> dict:
+# The kernels' tiles: a chunk is the side of the (chunk, chunk) decays a grid
+# step builds in VMEM, and a lane tile of the MXU holds 128 // P heads' features
+_KERNEL_CHUNK = 128
+_LANES = 128
+# the float32 state (and its cotangent) a grid step may hold in VMEM scratch
+_KERNEL_STATE_BYTES = 1024 * 1024
+_VMEM_LIMIT = 32 * 1024 * 1024
+
+
+def _kernels_tile(chunk: int, heads: int, groups: int, head_dim: int, state: int) -> bool:
+    """Whether `ssd_fwd` / `ssd_bwd` tile a scan of these sizes: the published
+    chunk; heads of 64 or 128 features, so that a 128-lane tile holds whole
+    heads; a group's heads and its state whole tiles of lanes; the group's
+    float32 state within `_KERNEL_STATE_BYTES`."""
+    per_group = heads // groups
+    return (chunk == _KERNEL_CHUNK and head_dim in (64, 128)
+            and (per_group * head_dim) % _LANES == 0 and state % _LANES == 0
+            and per_group * head_dim * state * 4 <= _KERNEL_STATE_BYTES)
+
+
+def resolve_scan_impl(implementation: Optional[str] = None, *, chunk: int, heads: int, groups: int,
+                      head_dim: int, state: int) -> str:
+    """The implementation `ssd_scan` runs: "pallas" (the kernels `ssd_fwd` /
+    `ssd_bwd`) or "xla_chunked" (the einsums of `_block`). A static rule on
+    the backend and the shapes, as `ops/attention.resolve_attention_impl` is:
+    with nothing requested, "pallas" on a TPU for the sizes the kernels tile
+    (`_kernels_tile`) and "xla_chunked" elsewhere (off a TPU the kernels only
+    run through the interpreter, for callers that ask). A kernel is never
+    tried and swapped for the other form when it fails; one asked for by name
+    at sizes it does not tile is refused.
+
+    GSPMD cannot partition a Mosaic call: traced under a context mesh of more
+    than one device with nothing around it that made the axes manual (a
+    `shard_map`), the rule keeps the XLA form. No cell does this today: the
+    one configuration with a state-space layer runs on one chip."""
+    if implementation is not None and implementation not in _IMPLEMENTATIONS:
+        raise ValueError(f"unknown scan implementation: {implementation!r}")
+    tiles = _kernels_tile(chunk, heads, groups, head_dim, state)
+    if implementation == "pallas" and not tiles:
+        raise ValueError(
+            f"ssd_scan: the kernels do not tile chunk {chunk}, {heads} heads of {head_dim} in "
+            f"{groups} groups, state {state}")
+    if implementation is not None:
+        return implementation
+    mesh = jax.sharding.get_abstract_mesh()
+    spread = not mesh.empty and not mesh.manual_axes and mesh.size > 1
+    return "pallas" if tiles and not spread and jax.default_backend() == "tpu" else "xla_chunked"
+
+
+def scan_plan(seq: int, chunk: int, *, heads: int, groups: int, head_dim: int, state: int,
+              implementation: Optional[str] = None) -> dict:
     """What `ssd_scan` resolves to for a sequence of `seq`, for callers that
-    report it: the implementation's name, the chunk and the chunks a step of
-    the walk over the sequence computes at once."""
-    return {"ssm_scan_impl": "xla_chunked", "ssm_chunk": chunk,
-            "ssm_scan_block_chunks": _block_chunks(seq // chunk)}
+    report it: the implementation's name, the chunk, the chunks a step over
+    the sequence computes at once (a grid step of the kernels: one), the
+    `pallas_call`s a differentiated scan makes and the float32 state a grid
+    step holds in VMEM (none of either for the XLA form)."""
+    impl = resolve_scan_impl(implementation, chunk=chunk, heads=heads, groups=groups,
+                             head_dim=head_dim, state=state)
+    kernels = impl == "pallas"
+    return {"ssm_scan_impl": impl, "ssm_chunk": chunk,
+            "ssm_scan_block_chunks": 1 if kernels else _block_chunks(seq // chunk),
+            "ssm_scan_kernels": 2 if kernels else 0,
+            "ssm_scan_state_bytes": heads // groups * head_dim * state * 4 if kernels else 0}
 
 
 def _block_chunks(chunks: int) -> int:
@@ -161,22 +242,317 @@ def _walk_bwd(kept, dy):
 _walk.defvjp(_walk_fwd, _walk_bwd)
 
 
+# ------------------------------------------------------------------ kernels
+#
+# `ssd_fwd` and `ssd_bwd`: a grid over (sequence of the batch, group of heads,
+# chunk), the chunks innermost and in order (the backward's reversed), the
+# group's float32 state (its cotangent) in VMEM scratch from a sequence's
+# first chunk to its last. x, y and their cotangents are (B, S, H P) with a
+# group's heads side by side on the lanes, B and C (B, S, G N): blocks of the
+# arrays as they are, no copy into blocks. A (chunk, chunk) matrix has the
+# read position l on the sublanes and the written one s on the lanes, so dt
+# and the cumulative log-decay come a head a row (s on the lanes: (B, S, H)
+# float32 transposed outside, where a head a column would be padded sixteen
+# times in HBM) and the kernels transpose a step's (heads, chunk) rows for
+# the heads' columns (l on the sublanes). What is shared by a group is
+# computed once a step with its heads stacked (C B^T; the read-out C H^T and
+# the state's update Xs^T B at the heads' whole width); a head's own (chunk,
+# chunk) weights meet x a 128-lane tile at a time, 128 // P heads side by
+# side, each keeping its own lanes of the product.
+
+_NN = (((1,), (0,)), ((), ()))      # a b
+_NT = (((1,), (1,)), ((), ()))      # a b^T
+_TN = (((0,), (0,)), ((), ()))      # a^T b
+
+
+def _dot(a, b, dims=_NN):
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=F32)
+
+
+def _chunk_masks(chunk: int, head_dim: int):
+    """(l >= s on a (chunk, chunk) matrix, which of a lane tile's heads a lane is of)."""
+    causal = (jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+              >= jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1))
+    return causal, jax.lax.broadcasted_iota(jnp.int32, (chunk, _LANES), 1) // head_dim
+
+
+def _as_columns(*rows):
+    """(heads, chunk) arrays, a head a row -> (chunk, heads) each, a head a
+    column: stacked on the sublanes, padded to a square and transposed ONCE
+    (a (heads, chunk) transpose of its own costs Mosaic several times a
+    square one's: PERF.md section 6, PR 49)."""
+    heads, chunk = rows[0].shape
+    stacked = jnp.concatenate([*rows, jnp.zeros((chunk - len(rows) * heads, chunk), F32)], axis=0).T
+    return [stacked[:, i * heads:(i + 1) * heads] for i in range(len(rows))]
+
+
+def _sum_over_lanes(matrix):
+    """(chunk, 128) -> its sum over the lanes as a ROW (1, chunk): transposed
+    and summed over the sublanes, which costs less than a lane reduction of
+    every sublane and leaves the sum as dt and the log-decay are stored."""
+    return jnp.sum(matrix.T, axis=0, keepdims=True)
+
+
+def _position_factors(dt_rows, cum_rows):
+    """Of a step's heads at once, a head a row: exp(c_l), by which a position
+    reads the state that entered the chunk, and exp(c_last - c_s) dt_s, by
+    which one writes into the state that leaves it, without dt and with it."""
+    to_last = jnp.exp(cum_rows[:, -1:] - cum_rows)
+    return jnp.exp(cum_rows), to_last, to_last * dt_rows
+
+
+def _fwd_kernel(x_ref, b_ref, c_ref, dt_ref, cum_ref, d_ref, y_ref, *rest, head_dim: int):
+    """A chunk of a group's heads from the state in `state_scr`, which it
+    leaves updated: y, and where `rest` holds a block for it the state that
+    entered the chunk, narrowed as the read-out takes it."""
+    *states_ref, state_scr = rest
+    dtype = x_ref.dtype
+    chunk, width = x_ref.shape[1:]
+    per_tile = _LANES // head_dim
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        state_scr[...] = jnp.zeros_like(state_scr)
+
+    bm, cm = b_ref[0], c_ref[0]
+    dt_rows, cum_rows = dt_ref[0, 0], cum_ref[0, 0]                 # (heads, chunk): s on the lanes
+    out_rows, _, into_rows = _position_factors(dt_rows, cum_rows)
+    cum_cols, out_cols, into_cols = _as_columns(cum_rows, out_rows, into_rows)  # (chunk, heads): l on the sublanes
+    causal, lane_head = _chunk_masks(chunk, head_dim)
+    scores = _dot(cm, bm, _NT)                                       # C_l . B_s
+    entering = state_scr[...].astype(dtype)
+    if states_ref:
+        states_ref[0][0, 0] = entering
+    read = _dot(cm, entering)                                        # C_l . H, every head
+    scaled, whole = [], []
+    for tile in range(width // _LANES):
+        lanes = slice(tile * _LANES, (tile + 1) * _LANES)
+        xt = x_ref[0, :, lanes]
+        xf = xt.astype(F32)
+        inside = out_decay = into_state = jnp.zeros((chunk, _LANES), F32)
+        for j in range(per_tile):
+            h = tile * per_tile + j
+            decay = jnp.exp(jnp.where(causal, cum_cols[:, h:h + 1] - cum_rows[h:h + 1], -jnp.inf))
+            weights = (decay * dt_rows[h:h + 1] * scores).astype(dtype)
+            mine = lane_head == j
+            inside = jnp.where(mine, _dot(weights, xt), inside)
+            out_decay = jnp.where(mine, out_cols[:, h:h + 1], out_decay)
+            into_state = jnp.where(mine, into_cols[:, h:h + 1], into_state)
+        y_ref[0, :, lanes] = (inside + out_decay * read[:, lanes] + d_ref[:, lanes] * xf).astype(dtype)
+        scaled.append((into_state * xf).astype(dtype))
+        whole.append(out_decay[chunk - 1:])                         # exp(the chunk's whole log-decay)
+    own = _dot(bm, jnp.concatenate(scaled, axis=1), _TN)            # the chunk's state, (N, width)
+    state_scr[...] = jnp.concatenate(whole, axis=1) * state_scr[...] + own
+
+
+def _bwd_kernel(x_ref, b_ref, c_ref, dt_ref, cum_ref, d_ref, states_ref, dy_ref,
+                dx_ref, db_ref, dc_ref, ddt_ref, dcum_ref, dd_ref, dstate_scr, *, head_dim: int):
+    """The transpose of `_fwd_kernel`'s chunk, the chunks in reverse: the
+    scores and decays again in VMEM, the cotangent of the state that leaves
+    the chunk in `dstate_scr`, replaced by that of the state that entered it.
+    dB and dC are summed over the group's heads; d's is summed over the
+    sequence's chunks in its block. What reaches dt and the log-decay as the
+    written position s is a sum over a matrix's sublanes, a head a row as
+    they are stored; what reaches them as the read position l is a sum over
+    its lanes (`_sum_over_lanes`)."""
+    dtype = x_ref.dtype
+    chunk, width = x_ref.shape[1:]
+    per_tile = _LANES // head_dim
+    heads = width // head_dim
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dstate_scr[...] = jnp.zeros_like(dstate_scr)
+        dd_ref[...] = jnp.zeros_like(dd_ref)
+
+    bm, cm = b_ref[0], c_ref[0]
+    dt_rows, cum_rows = dt_ref[0, 0], cum_ref[0, 0]
+    out_rows, to_last_rows, into_rows = _position_factors(dt_rows, cum_rows)
+    cum_cols, out_cols, into_cols = _as_columns(cum_rows, out_rows, into_rows)
+    causal, lane_head = _chunk_masks(chunk, head_dim)
+    head_row = jax.lax.broadcasted_iota(jnp.int32, (heads, 1), 0)
+    scores = _dot(cm, bm, _NT)
+    entering = states_ref[0, 0]
+    d_leaving = dstate_scr[...]
+    d_leaving_n = d_leaving.astype(dtype)
+    read = _dot(cm, entering)                                        # (chunk, width)
+    d_scaled = _dot(bm, d_leaving_n)                                 # d of (into_state x), (chunk, width)
+    d_scores = jnp.zeros((chunk, chunk), F32)
+    held = jnp.zeros((heads, 1), F32)
+    scaled, d_reads, whole, down, as_read, d_into = [], [], [], [], [], []
+    for tile in range(width // _LANES):
+        lanes = slice(tile * _LANES, (tile + 1) * _LANES)
+        xt, dyt = x_ref[0, :, lanes], dy_ref[0, :, lanes]
+        xf, dyf = xt.astype(F32), dyt.astype(F32)
+        dx = d_ref[:, lanes] * dyf
+        out_decay = into_state = jnp.zeros((chunk, _LANES), F32)
+        through = []
+        for j in range(per_tile):
+            h = tile * per_tile + j
+            dtr = dt_rows[h:h + 1]
+            decay = jnp.exp(jnp.where(causal, cum_cols[:, h:h + 1] - cum_rows[h:h + 1], -jnp.inf))
+            scored = decay * scores
+            weights = (scored * dtr).astype(dtype)
+            mine = lane_head == j
+            d_weights = _dot(jnp.where(mine, dyt, jnp.zeros_like(dyt)), xt, _NT)
+            dx = jnp.where(mine, dx + _dot(weights, dyt, _TN), dx)
+            d_scores = d_scores + d_weights * (decay * dtr)
+            before_dt = d_weights * scored                          # d of (decay scores dt) / d dt
+            down.append(jnp.sum(before_dt, axis=0, keepdims=True))   # over l: what reached s
+            out_decay = jnp.where(mine, out_cols[:, h:h + 1], out_decay)
+            into_state = jnp.where(mine, into_cols[:, h:h + 1], into_state)
+            through.append((mine, h, before_dt * dtr))
+        d_read = out_decay * dyf                                     # d of C_l . H
+        d_xs = d_scaled[:, lanes]
+        from_state = d_xs * xf                                       # summed over a head's lanes: d of into_state
+        # what reaches c_l: through the decays (a sum over s), the read-out and, as -c_s, the state's update
+        to_cum = d_read * read[:, lanes] - from_state * into_state
+        # d of exp(the whole log-decay) H, a lane: the sum over the state's N of dH' H
+        kept = (jnp.sum(d_leaving[:, lanes] * entering[:, lanes].astype(F32), axis=0, keepdims=True)
+                * out_decay[chunk - 1:])
+        for mine, h, through_decay in through:
+            as_read.append(_sum_over_lanes(through_decay + jnp.where(mine, to_cum, 0.0)))
+            d_into.append(_sum_over_lanes(jnp.where(mine, from_state, 0.0)))
+            held = jnp.where(head_row == h, jnp.sum(jnp.where(mine[:1], kept, 0.0), axis=1, keepdims=True), held)
+        dx_ref[0, :, lanes] = (dx + d_xs * into_state).astype(dtype)
+        dd_ref[0, :, lanes] += jnp.sum(dyf * xf, axis=0, keepdims=True)
+        scaled.append((into_state * xf).astype(dtype))
+        d_reads.append(d_read.astype(dtype))
+        whole.append(out_decay[chunk - 1:])
+    down, as_read, d_into = (jnp.concatenate(rows, axis=0) for rows in (down, as_read, d_into))  # (heads, chunk)
+    ddt_ref[0, 0] = down + d_into * to_last_rows
+    # the chunk's whole log-decay is its last position's: d of exp(c_last - c_s) and of exp(c_last) H
+    d_last = jnp.sum(d_into * into_rows, axis=1, keepdims=True) + held
+    is_last = jax.lax.broadcasted_iota(jnp.int32, (heads, chunk), 1) == chunk - 1
+    dcum_ref[0, 0] = as_read - down * dt_rows + jnp.where(is_last, d_last, 0.0)
+    d_scores_n, d_read = d_scores.astype(dtype), jnp.concatenate(d_reads, axis=1)
+    dc_ref[0] = (_dot(d_scores_n, bm) + _dot(d_read, entering, _NT)).astype(dtype)
+    db_ref[0] = (_dot(d_scores_n, cm, _TN)
+                 + _dot(jnp.concatenate(scaled, axis=1), d_leaving_n, _NT)).astype(dtype)
+    dstate_scr[...] = jnp.concatenate(whole, axis=1) * d_leaving + _dot(cm, d_read, _TN)
+
+
+def _head_rows(t, groups: int):
+    """(B, S, H) -> a head a row, a group's heads together: (B, G, H / G, S)."""
+    bsz, s, h = t.shape
+    return jnp.transpose(t.reshape(bsz, s, groups, h // groups), (0, 2, 3, 1))
+
+
+def _grid(x, sizes, chunk_of):
+    """(the grid over (sequence, group of heads, chunk), and the blocks of a
+    step (b, g, k) whose chunk is `chunk_of(k)`: x's and y's; B's and C's; a
+    head a row; d's; the entering states'; what is summed over a sequence's
+    chunks), for x (B, S, H P). A step holds a whole group's heads, so that C
+    B^T is computed once a group: fewer heads a step lost on the chip (PERF.md
+    section 6, PR 49)."""
+    chunk, groups, head_dim, state = sizes
+    bsz, s, inner = x.shape
+    width = inner // groups
+    return (bsz, groups, s // chunk), (
+        pl.BlockSpec((1, chunk, width), lambda b, g, k: (b, chunk_of(k), g)),
+        pl.BlockSpec((1, chunk, state), lambda b, g, k: (b, chunk_of(k), g)),
+        pl.BlockSpec((1, 1, width // head_dim, chunk), lambda b, g, k: (b, g, 0, chunk_of(k))),
+        pl.BlockSpec((1, width), lambda b, g, k: (0, g)),
+        pl.BlockSpec((1, 1, state, width), lambda b, g, k: (b, chunk_of(k), 0, g)),
+        pl.BlockSpec((1, 1, width), lambda b, g, k: (b, 0, g)))
+
+
+def _params():
+    return pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary"),
+                                vmem_limit_bytes=_VMEM_LIMIT)
+
+
+def _fwd_call(x, dt, cum, b, c, d, sizes, keep_states: bool, interpret: bool):
+    """`ssd_fwd` on x (B, S, H P), dt and cum (B, S, H) float32, b and c (B,
+    S, G N), d (1, H P) float32 -> (y, the state that entered each chunk (B,
+    chunks, N, H P) in x's dtype, or None)."""
+    _, _, head_dim, state = sizes
+    grid, (wide, narrow, rows, skip, states, _) = _grid(x, sizes, lambda k: k)
+    bsz, groups, chunks = grid
+    out = pl.pallas_call(
+        functools.partial(_fwd_kernel, head_dim=head_dim),
+        grid=grid,
+        in_specs=[wide, narrow, narrow, rows, rows, skip],
+        out_specs=[wide, states] if keep_states else [wide],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)]
+        + [jax.ShapeDtypeStruct((bsz, chunks, state, x.shape[2]), x.dtype)] * keep_states,
+        scratch_shapes=[pltpu.VMEM(states.block_shape[2:], F32)],
+        compiler_params=_params(), interpret=interpret, name="ssd_fwd",
+    )(x, b, c, _head_rows(dt, groups), _head_rows(cum, groups), d)
+    return (out[0], out[1]) if keep_states else (out[0], None)
+
+
+def _bwd_call(x, dt, cum, b, c, d, states, dy, sizes, interpret: bool):
+    """`ssd_bwd`: the cotangents of `_fwd_call`'s six arguments from y's."""
+    chunk, _, head_dim, _ = sizes
+    chunks = x.shape[1] // chunk
+    grid, (wide, narrow, rows, skip, kept, summed) = _grid(x, sizes, lambda k: chunks - 1 - k)
+    bsz, groups, _ = grid
+    by_head = jax.ShapeDtypeStruct((bsz, groups, dt.shape[2] // groups, dt.shape[1]), F32)
+    dx, db, dc, ddt, dcum, dd = pl.pallas_call(
+        functools.partial(_bwd_kernel, head_dim=head_dim),
+        grid=grid,
+        in_specs=[wide, narrow, narrow, rows, rows, skip, kept, wide],
+        out_specs=[wide, narrow, narrow, rows, rows, summed],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype), jax.ShapeDtypeStruct(b.shape, b.dtype),
+                   jax.ShapeDtypeStruct(c.shape, c.dtype), by_head, by_head,
+                   jax.ShapeDtypeStruct((bsz, 1, x.shape[2]), F32)],
+        scratch_shapes=[pltpu.VMEM(kept.block_shape[2:], F32)],
+        compiler_params=_params(), interpret=interpret, name="ssd_bwd",
+    )(x, b, c, _head_rows(dt, groups), _head_rows(cum, groups), d, states, dy)
+
+    def of_tokens(t):                   # (B, G, H / G, S) -> (B, S, H)
+        return jnp.transpose(t, (0, 3, 1, 2)).reshape(dt.shape)
+
+    return dx, of_tokens(ddt), of_tokens(dcum), db, dc, jnp.sum(dd, axis=0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _scan_kernels(x, dt, cum, b, c, d, sizes, interpret):
+    return _fwd_call(x, dt, cum, b, c, d, sizes, False, interpret)[0]
+
+
+def _scan_kernels_fwd(x, dt, cum, b, c, d, sizes, interpret):
+    y, states = _fwd_call(x, dt, cum, b, c, d, sizes, True, interpret)
+    # what a checkpoint around the caller may keep: with both, its backward
+    # pass starts from here and does not run `ssd_fwd` a second time
+    y = checkpoint_name(y, "ssm_scan_out")
+    states = checkpoint_name(states, "ssm_chunk_states")
+    return y, (x, dt, cum, b, c, d, states)
+
+
+def _scan_kernels_bwd(sizes, interpret, kept, dy):
+    return _bwd_call(*kept, dy, sizes, interpret)
+
+
+_scan_kernels.defvjp(_scan_kernels_fwd, _scan_kernels_bwd)
+
+
 def ssd_scan(x: jax.Array, dt: jax.Array, a_log: jax.Array, b: jax.Array, c: jax.Array,
-             d: jax.Array, *, chunk: int = 128) -> jax.Array:
+             d: jax.Array, *, chunk: int = 128, implementation: Optional[str] = None) -> jax.Array:
     """The selective scan of the module's docstring in its chunked form. x
     (B, S, H, P); dt (B, S, H), the positive step (float32: after its bias
     and softplus); a_log, d (H,); b, c (B, S, G, N) with H a multiple of G
     -> y (B, S, H, P) in x's dtype. Differentiable in every argument; what
-    the backward pass keeps is the module's docstring's."""
+    the backward pass keeps is the module's docstring's. `implementation`
+    is `resolve_scan_impl`'s, for tests: with nothing asked, the kernels on a
+    TPU at the sizes they tile and the XLA form elsewhere."""
     bsz, s, h, p = x.shape
-    g = b.shape[2]
+    g, n = b.shape[2:]
     if s % chunk:
         raise ValueError(f"ssd_scan: a sequence of {s} is no multiple of the chunk {chunk}")
     if h % g:
         raise ValueError(f"ssd_scan: {h} heads are no multiple of the {g} groups")
-    per_block = _block_chunks(s // chunk)
+    impl = resolve_scan_impl(implementation, chunk=chunk, heads=h, groups=g, head_dim=p, state=n)
     dt = dt.astype(F32)
     da = dt * -jnp.exp(a_log.astype(F32))
+    if impl == "pallas":
+        cum = jnp.cumsum(da.reshape(bsz, s // chunk, chunk, h), axis=2).reshape(bsz, s, h)
+        y = _scan_kernels(x.reshape(bsz, s, h * p), dt, cum, b.reshape(bsz, s, g * n),
+                          c.reshape(bsz, s, g * n), jnp.repeat(d.astype(F32), p)[None],
+                          (chunk, g, p, n), jax.default_backend() != "tpu")
+        return y.reshape(bsz, s, h, p)
+    per_block = _block_chunks(s // chunk)
 
     def cut(t):     # (B, S, ...) -> (blocks, B, per_block, chunk, ...)
         return jnp.moveaxis(t.reshape(bsz, -1, per_block, chunk, *t.shape[2:]), 1, 0)

@@ -104,9 +104,11 @@ def test_a_ragged_sequence_and_ragged_groups_are_refused_by_name():
 
 
 def test_plan_and_the_most_negative_log_decay_of_a_chunk():
-    assert ssd.scan_plan(8192, 128) == {
-        "ssm_scan_impl": "xla_chunked", "ssm_chunk": 128, "ssm_scan_block_chunks": ssd.BLOCK_CHUNKS}
-    assert ssd.scan_plan(48, 16)["ssm_scan_block_chunks"] == 3      # a divisor of the chunks
+    sizes = dict(heads=4, groups=2, head_dim=8, state=16)
+    assert ssd.scan_plan(8192, 128, **sizes) == {       # the CPU's answer: the XLA form, no kernel
+        "ssm_scan_impl": "xla_chunked", "ssm_chunk": 128, "ssm_scan_block_chunks": ssd.BLOCK_CHUNKS,
+        "ssm_scan_kernels": 0, "ssm_scan_state_bytes": 0}
+    assert ssd.scan_plan(48, 16, **sizes)["ssm_scan_block_chunks"] == 3      # a divisor of the chunks
     dt = jnp.full((1, 32, 2), 0.5)
     a_log = jnp.log(jnp.asarray([1.0, 4.0]))
     # 16 positions x 0.5 x -4

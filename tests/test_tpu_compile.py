@@ -327,3 +327,31 @@ def test_moe_rows_sum_compiles_at_the_held_cells_shapes(as_tpu, slots, m, held, 
         _on(as_tpu, (slots, m)), _on(as_tpu, (slots,), jnp.float32), _on(as_tpu, (slots,), jnp.int32),
         _on(as_tpu, (held, tiles + 1), jnp.int32)).compile()
     assert _kernel_calls(compiled) == 1 and "%moe_rows_sum" in compiled.as_text()
+
+
+@pytest.mark.parametrize("batch, seq, heads, head_dim, groups, state", [(2, 8192, 64, 64, 8, 128), (1, 512, 8, 128, 4, 256)],
+                         ids=["nemotron3nano-8-heads-of-64-a-group", "2-heads-of-128-a-group-state-256"])
+def test_ssd_scan_kernels_compile_within_the_scoped_vmem_they_ask_for(as_tpu, batch, seq, heads, head_dim,
+                                                                      groups, state):
+    """`ssd_fwd` and `ssd_bwd` at the `train-nemotron3nano-8k` cell's shapes (a
+    grid step: one chunk of a group's 8 heads, the (128, 512) float32 state
+    in scratch) and at a wider head with a larger state: the rule says
+    "pallas" there, a differentiated scan is the two calls, and Mosaic fits
+    both in the `_VMEM_LIMIT` their calls ask for (a kernel that did not would
+    be refused here as on the chip)."""
+    from ray_tpu.ops import ssd
+
+    sizes = dict(chunk=128, heads=heads, groups=groups, head_dim=head_dim, state=state)
+    assert ssd.resolve_scan_impl(**sizes) == "pallas"
+    assert ssd.scan_plan(seq, 128, heads=heads, groups=groups, head_dim=head_dim, state=state)[
+        "ssm_scan_state_bytes"] == heads // groups * head_dim * state * 4 <= ssd._KERNEL_STATE_BYTES
+    args = (_on(as_tpu, (batch, seq, heads, head_dim)), _on(as_tpu, (batch, seq, heads), jnp.float32),
+            _on(as_tpu, (heads,), jnp.float32), _on(as_tpu, (batch, seq, groups, state)),
+            _on(as_tpu, (batch, seq, groups, state)), _on(as_tpu, (heads,), jnp.float32))
+    forward = jax.jit(lambda *a: ssd.ssd_scan(*a, chunk=128)).lower(*args)
+    # the scoped VMEM the call asks Mosaic for, as the lowered call carries it
+    assert f"\\22size\\22: {ssd._VMEM_LIMIT}}}]" in forward.as_text()
+    assert _kernel_calls(forward.compile()) == 1
+    grad = jax.jit(jax.grad(lambda *a: jnp.sum(ssd.ssd_scan(*a, chunk=128).astype(jnp.float32) ** 2),
+                            argnums=tuple(range(6)))).lower(*args).compile()
+    assert _kernel_calls(grad) == 2

@@ -17,9 +17,10 @@ def test_nemotron3nano_cell_step_runs_the_scan_under_its_scopes_and_compiles(as_
     v5e chip of 15.75 GiB: `ME` scanned twice, then `M*EME` unrolled. Each of
     the 7 layer bodies is one sublayer; the state-space mixer's five scopes
     lie inside `ssm` in the forward pass, its recomputation and the backward
-    pass; the one attention layer runs the causal D = 128 flash kernels and
+    pass, and its scan is the two kernels of ops/ssd; the one attention layer runs the causal D = 128 flash kernels and
     the expert layers TWO grouped matmuls a pass (a non-gated expert); the
     fused head takes the whole sequence as its chunk."""
+    from ray_tpu.models import model_family
     from ray_tpu.ops import losses
     from ray_tpu.train.lm import make_train_step
     from ray_tpu.util import profiling
@@ -33,13 +34,25 @@ def test_nemotron3nano_cell_step_runs_the_scan_under_its_scopes_and_compiles(as_
     assert plan["remat"] in ("whole_block", "selective")
     assert set(plan["remat_saved"]) <= {"ssm_scan_out", "ssm_chunk_states", "attn_out", "attn_lse", "attn_residual"}
     assert step.loss_chunk_for(tokens.shape, state) == 8192
+    said = model_family(config).plan(config, 2, 8192)
+    assert (said["ssm_scan_impl"], said["ssm_scan_kernels"], said["ssm_scan_state_bytes"]) == (
+        "pallas", 2, 8 * 64 * 128 * 4)
     compiled = step.lower(state, {"tokens": tokens}).compile()
     # one attention layer, in the unrolled run: the forward kernel and its recomputation, one backward
     assert _kernels_named(compiled, "flash_bwd_dkv_dq") == 1 and "flash_win" not in compiled.as_text()
     # 4 expert layers in 3 bodies (the scan's, two unrolled) x 2 projections x (the first pass
     # through the held buffer, the later ones), forward and recomputed
     assert _kernels_named(compiled, "moe_gmm_fwd") == 3 * 2 * 2 * 2
+    # 4 state-space layers in 3 bodies (the scan's, two unrolled): the rule keeps the scans' outputs
+    # and the states that entered their chunks, so `ssd_fwd` runs in the forward pass alone (a plan
+    # that kept neither would run it again under `recompute`) and `ssd_bwd` once a body
+    assert {"ssm_scan_out", "ssm_chunk_states"} <= set(plan["remat_saved"])
+    assert _kernels_named(compiled, "ssd_fwd") == 3 and _kernels_named(compiled, "ssd_bwd") == 3
     _, table = profiling.program_ops_table(profiling._module_text(compiled))
+    for kernel, pass_ in (("ssd_fwd", "fwd"), ("ssd_bwd", "bwd")):
+        assert len(table[kernel]) == 3
+        for scopes, found, _ in table[kernel]:
+            assert {"ssm", "ssm.scan"} <= set(scopes) and found == pass_, (kernel, scopes, found)
     pairs = {(scope, pass_) for instances in table.values() for scopes, pass_, _ in instances for scope in scopes}
     for scope in ("ssm", "ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.gate_norm"):
         assert {(scope, "fwd"), (scope, "recompute"), (scope, "bwd")} <= pairs, scope
