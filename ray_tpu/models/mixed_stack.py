@@ -52,13 +52,14 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import math
 from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from ..ops import rmsnorm, rope_frequencies, ssd
+from ..ops import rope_frequencies, ssd
 from ..ops.attention import attention_plan
 from .moe import _HELD_BUFFER_SHARES, MoEConfig, load_max_over_mean, moe_mlp, moe_plan
 from .transformer import (
@@ -427,15 +428,41 @@ def logical_axes(config: MixedStackConfig) -> Params:
 # -------------------------------------------------------------------- forward
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def _gate_xbc_dt(projected, inner: int, conv: int):
+    """[z | xBC | dt], the in-projection's output -> (the WHOLE array for the
+    gate, xBC, dt). `ssd.gated_group_norm` reads z at the first `inner`
+    features of the whole: a slice in front of a kernel is a copy, and with
+    it XLA writes the projection transposed and copies xBC too (PERF.md
+    section 6, PR 50). The transpose joins the three cotangents in ONE
+    concatenate, as `jnp.split`'s does, whatever read the whole."""
+    return projected, projected[..., inner:inner + conv], projected[..., inner + conv:]
+
+
+def _gate_xbc_dt_fwd(projected, inner, conv):
+    return _gate_xbc_dt(projected, inner, conv), None
+
+
+def _gate_xbc_dt_bwd(inner, conv, _, cotangents):
+    whole, xbc, step = cotangents
+    return (jnp.concatenate([whole[..., :inner], whole[..., inner:inner + conv] + xbc,
+                             whole[..., inner + conv:] + step], axis=-1),)
+
+
+_gate_xbc_dt.defvjp(_gate_xbc_dt_fwd, _gate_xbc_dt_bwd)
+
+
 def _ssm_sublayer(x, lp, config):
     """A Mamba-2 mixer + residual on (B, S, E), the scope `ssm`: [z | xBC |
     dt] = norm(x) W_in (`ssm.in_proj`); xBC through the causal depthwise
     convolution and silu (`ssm.conv`); the step softplus(dt + bias) in float32
     and the selective scan of the heads' x with their group's B and C
     (`ssm.scan`, ops/ssd.ssd_scan); y silu(z) through an RMS norm over each
-    group's features (`ssm.gate_norm`: the gate first, then the norm); W_out
-    and the residual (`ssm.out_proj`). -> (x, the most negative cumulative
-    log-decay inside a chunk)."""
+    group's features (`ssm.gate_norm`, ops/ssd.gated_group_norm: the gate
+    first, then the norm; on a TPU two kernels on the flat (B, S, H P) y and
+    the projection as it is, elsewhere ops/layers.rmsnorm on the view by
+    groups); W_out on the flat features and the residual (`ssm.out_proj`).
+    -> (x, the most negative cumulative log-decay inside a chunk)."""
     c = config
     dt = c.dtype
     b, s, _ = x.shape
@@ -445,7 +472,7 @@ def _ssm_sublayer(x, lp, config):
         with jax.named_scope("ssm.in_proj"):
             u = _norm(x, lp["ln1_scale"], None, c.norm, c.norm_eps)
             projected = jnp.einsum("bse,ef->bsf", u, lp["ssm_in"].astype(dt))
-            z, xbc, step = jnp.split(projected, [inner, inner + c.ssm_conv_width], axis=-1)
+            z, xbc, step = _gate_xbc_dt(projected, inner, c.ssm_conv_width)
         with jax.named_scope("ssm.conv"):
             xbc = ssd.causal_conv1d(xbc, lp["ssm_conv_w"], lp["ssm_conv_b"])
         with jax.named_scope("ssm.scan"):
@@ -456,12 +483,10 @@ def _ssm_sublayer(x, lp, config):
             decay_min = jax.lax.stop_gradient(
                 ssd.log_decay_chunk_min(step, lp["ssm_a_log"], c.ssm_chunk))
         with jax.named_scope("ssm.gate_norm"):
-            kw = {} if c.norm_eps is None else {"eps": c.norm_eps}
-            gated = y.reshape(b, s, inner).astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-            y = rmsnorm(gated.reshape(b, s, groups, inner // groups),
-                        lp["ssm_norm_scale"].reshape(groups, inner // groups), **kw).astype(dt)
+            y = ssd.gated_group_norm(y.reshape(b, s, inner), z, lp["ssm_norm_scale"].reshape(inner), groups=groups,
+                                     eps=1e-6 if c.norm_eps is None else c.norm_eps)    # None: rmsnorm's own
         with jax.named_scope("ssm.out_proj"):
-            out = jnp.einsum("bshp,hpe->bse", y.reshape(b, s, heads, p), lp["ssm_out"].astype(dt))
+            out = jnp.einsum("bsf,fe->bse", y, lp["ssm_out"].astype(dt).reshape(inner, -1))
             return x + out, decay_min
 
 
@@ -682,7 +707,9 @@ def _ssm_costs(config: MixedStackConfig) -> Dict[str, Any]:
                   + 2 * inner * c.d_model),
         # the norm's output, the sublayer's, the residual; the projection; the
         # convolution's output (its float32 sum is fused away); the scan's
-        # operands cut into blocks; its output and the gated, normed one. On
+        # operands cut into blocks; its output and the gated, normed one (both
+        # in the activations' dtype: the norm's kernels hold the float32
+        # product in VMEM, and the XLA form's float32 copies are the CPU's). On
         # the chip the whole-block step of the cell peaked at 13.87 GB, 2.4 GB
         # over its state, gradients and the blocks' inputs: 1.75 copies of
         # 41.9 k features a row, where this counts 42.9 k
@@ -738,7 +765,8 @@ def plan(config: MixedStackConfig, batch: int, seq: int) -> Dict[str, Any]:
         out.update(ssm_heads=c.ssm_heads, ssm_head_dim=c.ssm_head_dim, ssm_state=c.ssm_state,
                    ssm_groups=c.ssm_groups, ssm_conv_kernel=c.ssm_conv_kernel,
                    **ssd.scan_plan(seq, c.ssm_chunk, heads=c.ssm_heads, groups=c.ssm_groups,
-                                   head_dim=c.ssm_head_dim, state=c.ssm_state))
+                                   head_dim=c.ssm_head_dim, state=c.ssm_state),
+                   **ssd.gate_norm_plan(batch * seq, c.ssm_heads * c.ssm_head_dim, c.ssm_groups))
     if c.latent_attention:
         out.update(attn_latent_q_rank=c.q_lora_rank, attn_latent_kv_rank=c.kv_lora_rank,
                    attn_rope_dims=c.rotary_dims, attn_head_dim=c.head_dim)

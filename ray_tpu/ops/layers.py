@@ -2,9 +2,14 @@
 
 These are deliberately plain jnp: XLA fuses elementwise chains into the
 surrounding matmuls on TPU, so hand-written Pallas buys nothing here (the
-Pallas budget goes to attention and serving kernels instead). Computation is
-done in float32 and cast back, the standard mixed-precision discipline for
-bf16 training.
+Pallas budget goes to attention and serving kernels instead). The one
+exception is a norm over GROUPS of the lane axis: `rmsnorm` on a view (...,
+groups, width) puts the groups on the sublanes, which on the chip is a
+shuffle of the whole float32 array, so the Mamba-2 mixer's gated group norm
+is two kernels that reduce each group's lanes in place
+(ops/ssd.gated_group_norm; off a TPU it is `rmsnorm` on that view).
+Computation is done in float32 and cast back, the standard mixed-precision
+discipline for bf16 training.
 """
 
 from __future__ import annotations

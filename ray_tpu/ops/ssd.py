@@ -1,5 +1,6 @@
-"""The state-space mixer's core (Mamba-2): a causal depthwise convolution
-and the selective scan in its chunked, state-space-duality form.
+"""The state-space mixer's core (Mamba-2): a causal depthwise convolution,
+the selective scan in its chunked, state-space-duality form, and the gated
+norm over groups of features behind it (`gated_group_norm`, at the end).
 
 The recurrence, a head h of P features with a state of P x N, reading group
 g = h // (H / G) of the G groups that B and C come in:
@@ -24,9 +25,10 @@ sums under the causal mask, never the two factors exp(c_l) exp(-c_s)) and
 the carried state are float32; the products take their operands in the
 dtype x comes in and accumulate in float32.
 
-Two forms compute it, chosen by a static rule on the backend and the shapes
-(`resolve_scan_impl`; `scan_plan` reports it; no flag, and a form is never
-tried and swapped for the other):
+Two forms compute it, chosen by a static rule on the backend, the shapes and
+the context mesh (`resolve_scan_impl`; `scan_plan` reports it; no flag, and a
+form is never tried and swapped for the other; `_resolve` is the one rule,
+the gated norm's too):
 
 - "pallas", on a TPU at the sizes the kernels tile: two Mosaic kernels under
   one `custom_vjp`, `ssd_fwd` and `ssd_bwd`, every call named so that a device
@@ -57,6 +59,13 @@ its backward pass does not run the scan forward a second time.
 
 A sequence that is no multiple of the chunk is refused by name: padding at
 the end would be silent work, and the cell's sequences are multiples.
+
+The gated norm, y silu(z) through an RMS norm over each group's features, has
+the same two forms by the same rule (`resolve_gate_norm_impl`,
+`gate_norm_plan`): "pallas", two kernels under one `custom_vjp`,
+`ssm_gate_norm_fwd` and `ssm_gate_norm_bwd`, a grid step a tile of rows with
+every group a static slice of whole lane tiles; "xla", ops/layers.rmsnorm on
+the view by groups. Its section says why.
 """
 
 from __future__ import annotations
@@ -69,6 +78,8 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .layers import rmsnorm
 
 F32 = jnp.float32
 _IMPLEMENTATIONS = ("xla_chunked", "pallas")
@@ -111,33 +122,41 @@ def _kernels_tile(chunk: int, heads: int, groups: int, head_dim: int, state: int
             and per_group * head_dim * state * 4 <= _KERNEL_STATE_BYTES)
 
 
-def resolve_scan_impl(implementation: Optional[str] = None, *, chunk: int, heads: int, groups: int,
-                      head_dim: int, state: int) -> str:
-    """The implementation `ssd_scan` runs: "pallas" (the kernels `ssd_fwd` /
-    `ssd_bwd`) or "xla_chunked" (the einsums of `_block`). A static rule on
-    the backend and the shapes, as `ops/attention.resolve_attention_impl` is:
-    with nothing requested, "pallas" on a TPU for the sizes the kernels tile
-    (`_kernels_tile`) and "xla_chunked" elsewhere (off a TPU the kernels only
-    run through the interpreter, for callers that ask). A kernel is never
-    tried and swapped for the other form when it fails; one asked for by name
-    at sizes it does not tile is refused.
+def _resolve(implementation: Optional[str], forms: Tuple[str, str], tiles: bool, what: str,
+             refusal: str) -> str:
+    """The one rule of this module's kernels, the scan's and the gated norm's:
+    of `forms` = (the XLA form, "pallas"), the kernels on a TPU at the sizes
+    they tile and the XLA form elsewhere (off a TPU the kernels only run
+    through the interpreter, for callers that ask). A kernel is never tried
+    and swapped for the other form when it fails; one asked for by name at
+    sizes it does not tile is refused.
 
     GSPMD cannot partition a Mosaic call: traced under a context mesh of more
     than one device with nothing around it that made the axes manual (a
     `shard_map`), the rule keeps the XLA form. No cell does this today: the
     one configuration with a state-space layer runs on one chip."""
-    if implementation is not None and implementation not in _IMPLEMENTATIONS:
-        raise ValueError(f"unknown scan implementation: {implementation!r}")
-    tiles = _kernels_tile(chunk, heads, groups, head_dim, state)
+    if implementation is not None and implementation not in forms:
+        raise ValueError(f"unknown {what} implementation: {implementation!r}")
     if implementation == "pallas" and not tiles:
-        raise ValueError(
-            f"ssd_scan: the kernels do not tile chunk {chunk}, {heads} heads of {head_dim} in "
-            f"{groups} groups, state {state}")
+        raise ValueError(refusal)
     if implementation is not None:
         return implementation
     mesh = jax.sharding.get_abstract_mesh()
     spread = not mesh.empty and not mesh.manual_axes and mesh.size > 1
-    return "pallas" if tiles and not spread and jax.default_backend() == "tpu" else "xla_chunked"
+    return "pallas" if tiles and not spread and jax.default_backend() == "tpu" else forms[0]
+
+
+def resolve_scan_impl(implementation: Optional[str] = None, *, chunk: int, heads: int, groups: int,
+                      head_dim: int, state: int) -> str:
+    """The implementation `ssd_scan` runs: "pallas" (the kernels `ssd_fwd` /
+    `ssd_bwd`) or "xla_chunked" (the einsums of `_block`). A static rule on
+    the backend, the shapes and the context mesh (`_resolve`, as
+    `ops/attention.resolve_attention_impl` is): with nothing requested,
+    "pallas" on a TPU for the sizes the kernels tile (`_kernels_tile`) and
+    "xla_chunked" elsewhere."""
+    return _resolve(implementation, _IMPLEMENTATIONS, _kernels_tile(chunk, heads, groups, head_dim, state),
+                    "scan", f"ssd_scan: the kernels do not tile chunk {chunk}, {heads} heads of {head_dim} in "
+                    f"{groups} groups, state {state}")
 
 
 def scan_plan(seq: int, chunk: int, *, heads: int, groups: int, head_dim: int, state: int,
@@ -569,6 +588,181 @@ def log_decay_chunk_min(dt: jax.Array, a_log: jax.Array, chunk: int) -> jax.Arra
     bsz, s, h = dt.shape
     da = dt.astype(F32) * -jnp.exp(a_log.astype(F32))
     return jnp.min(jnp.sum(da.reshape(bsz, s // chunk, chunk, h), axis=2))
+
+
+# ----------------------------------------------------------- the gated norm
+#
+# `ssm_gate_norm_fwd` and `ssm_gate_norm_bwd`: y silu(z) through an RMS norm
+# over each group's features, on the (B S, inner) arrays as they are. A grid
+# step is a tile of rows with ALL groups; a group is a static slice of whole
+# 128-lane tiles (512 lanes at the published sizes) and its sum of squares a
+# lane reduction of that slice, so nothing moves: the view (.., groups, inner
+# // groups) that the XLA form takes puts the groups on the sublanes, on the
+# chip a shuffle of the float32 product, five a layer forward and backward
+# (PERF.md section 6, PR 49 and PR 50). Float32 inside a kernel, the
+# activations' dtype in HBM on both sides; the backward keeps nothing of its
+# own and builds g and the inverse root again from y and z.
+
+_NORM_IMPLEMENTATIONS = ("xla", "pallas")
+# The most rows of (B S, inner) a grid step takes, and the rows of it computed
+# at once: a bfloat16 tile's 16 sublanes, whose float32 values stay in vector
+# registers; a step's rows are a multiple. The kernels move 3 and 5 arrays
+# through VMEM and nothing else binds them: 128 / 256 / 512 rows a step and 8
+# to 64 a strip read the same on the chip at the `train-nemotron3nano-8k`
+# cell's shapes (PERF.md section 6, PR 50); these compile fastest.
+_NORM_ROWS = 256
+_NORM_STRIP = 16
+
+
+def _norm_rows(rows: int) -> int:
+    """The rows a grid step of the norm's kernels takes: the largest divisor
+    of `rows` that is a multiple of `_NORM_STRIP` and at most `_NORM_ROWS`
+    (0: the rows do not tile)."""
+    most = min(_NORM_ROWS, rows) // _NORM_STRIP * _NORM_STRIP
+    return next((n for n in range(most, 0, -_NORM_STRIP) if rows % n == 0), 0)
+
+
+def resolve_gate_norm_impl(implementation: Optional[str] = None, *, rows: int, inner: int, groups: int) -> str:
+    """The implementation `gated_group_norm` runs on `rows` = B S rows, by the
+    scan's rule (`_resolve`): "pallas" (the kernels `ssm_gate_norm_fwd` /
+    `ssm_gate_norm_bwd`) on a TPU where a group's width is a whole number of
+    128-lane tiles and the rows tile (`_norm_rows`), "xla" (ops/layers.rmsnorm
+    on the view by groups) elsewhere."""
+    tiles = inner % groups == 0 and (inner // groups) % _LANES == 0 and _norm_rows(rows) > 0
+    return _resolve(implementation, _NORM_IMPLEMENTATIONS, tiles, "gated norm",
+                    f"gated_group_norm: the kernels do not tile {rows} rows of {inner} features in {groups} groups")
+
+
+def gate_norm_plan(rows: int, inner: int, groups: int) -> dict:
+    """What `gated_group_norm` resolves to for B S = `rows`, for callers that
+    report it: the implementation's name and the rows a grid step of the
+    kernels takes (0 for the XLA form)."""
+    impl = resolve_gate_norm_impl(rows=rows, inner=inner, groups=groups)
+    return {"ssm_gate_norm_impl": impl, "ssm_gate_norm_rows": _norm_rows(rows) if impl == "pallas" else 0}
+
+
+def _strips_of_groups(rows: int, inner: int, groups: int, body):
+    """`body(rows, lanes)` for every strip of `_NORM_STRIP` rows of a tile and
+    every group's lanes in it."""
+    width = inner // groups
+
+    def step(i, carry):
+        at = pl.ds(pl.multiple_of(i * _NORM_STRIP, _NORM_STRIP), _NORM_STRIP)
+        for group in range(groups):
+            body(at, slice(group * width, (group + 1) * width))
+        return carry
+
+    jax.lax.fori_loop(0, rows // _NORM_STRIP, step, 0)
+
+
+def _gated(y_ref, z_ref, at, lanes, eps: float):
+    """Of a strip's rows and a group's lanes, in float32: (y, z, sigmoid(z), g
+    = y silu(z), the inverse root of g's mean square + eps a row)."""
+    y, z = y_ref[at, lanes].astype(F32), z_ref[at, lanes].astype(F32)
+    gate = jax.nn.sigmoid(z)
+    g = y * (z * gate)
+    return y, z, gate, g, jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + eps)
+
+
+def _gate_norm_fwd_kernel(y_ref, z_ref, scale_ref, out_ref, *, groups: int, eps: float):
+    def body(at, lanes):
+        *_, g, inv = _gated(y_ref, z_ref, at, lanes, eps)
+        out_ref[at, lanes] = (g * inv * scale_ref[:, lanes]).astype(out_ref.dtype)
+
+    _strips_of_groups(*y_ref.shape, groups, body)
+
+
+def _gate_norm_bwd_kernel(y_ref, z_ref, scale_ref, dout_ref, dy_ref, dz_ref, dscale_ref, *, groups: int,
+                          eps: float):
+    """The transpose of `_gate_norm_fwd_kernel`'s tile. With n = g inv and out
+    = n scale: dn = dout scale, dg = inv (dn - n mean(dn n)) over the group's
+    lanes, dy = dg silu(z), dz = dg y silu'(z); the scale's cotangent, the
+    sum over rows of dout n, is accumulated over the grid in its block."""
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        dscale_ref[...] = jnp.zeros_like(dscale_ref)
+
+    def body(at, lanes):
+        y, z, gate, g, inv = _gated(y_ref, z_ref, at, lanes, eps)
+        dout = dout_ref[at, lanes].astype(F32)
+        normed = g * inv
+        dn = dout * scale_ref[:, lanes]
+        dg = inv * (dn - normed * jnp.mean(dn * normed, axis=-1, keepdims=True))
+        dy_ref[at, lanes] = (dg * (z * gate)).astype(dy_ref.dtype)
+        dz_ref[at, lanes] = (dg * y * (gate * (1.0 + z * (1.0 - gate)))).astype(dz_ref.dtype)
+        dscale_ref[:, lanes] += jnp.sum(dout * normed, axis=0, keepdims=True)
+
+    _strips_of_groups(*y_ref.shape, groups, body)
+
+
+def _norm_call(y, z, scale, dout, sizes, interpret: bool):
+    """`ssm_gate_norm_fwd` (`dout` None) -> [out], or `ssm_gate_norm_bwd` ->
+    [dy, dz, the scale's cotangent (1, inner) float32], over the row tiles of
+    y, z and dout taken as (B S, inner): z may be wider, its first `inner`
+    features are its block; `scale` (1, inner) float32 is whole every step
+    and its cotangent's block stays in VMEM over the grid."""
+    groups, eps, step_rows = sizes
+    rows, inner = y.shape[0] * y.shape[1], y.shape[2]
+    backward = dout is not None
+    tile = pl.BlockSpec((step_rows, inner), lambda i: (i, 0))
+    whole = pl.BlockSpec((1, inner), lambda i: (0, 0))
+    like = jax.ShapeDtypeStruct((rows, inner), y.dtype)
+    tiled = [t.reshape(rows, t.shape[2]) for t in ((y, z, dout) if backward else (y, z))]
+    # every tile of a step twice (the pipeline's two buffers) and room for a strip's values
+    vmem = 2 * (5 if backward else 3) * step_rows * inner * y.dtype.itemsize + 8 * 1024 * 1024
+    out = pl.pallas_call(
+        functools.partial(_gate_norm_bwd_kernel if backward else _gate_norm_fwd_kernel, groups=groups, eps=eps),
+        grid=(rows // step_rows,),
+        in_specs=[tile, tile, whole, tile] if backward else [tile, tile, whole],
+        out_specs=[tile, tile, whole] if backward else [tile],
+        out_shape=[like, like, jax.ShapeDtypeStruct((1, inner), F32)] if backward else [like],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary" if backward else "parallel",),
+                                             vmem_limit_bytes=vmem),
+        interpret=interpret, name="ssm_gate_norm_bwd" if backward else "ssm_gate_norm_fwd",
+    )(*tiled[:2], scale, *tiled[2:])
+    return [t.reshape(y.shape) for t in out[:2]] + list(out[2:])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _norm_kernels(y, z, scale, sizes, interpret):
+    """y (B, S, inner), z (B, S, inner or more: the gate is its first inner
+    features), scale (1, inner) float32 -> the gated, normed (B, S, inner) in
+    y's dtype; `sizes` = (groups, eps, the rows a step)."""
+    return _norm_call(y, z, scale, None, sizes, interpret)[0]
+
+
+def _norm_kernels_fwd(y, z, scale, sizes, interpret):
+    return _norm_call(y, z, scale, None, sizes, interpret)[0], (y, z, scale)        # nothing kept but the arguments
+
+
+def _norm_kernels_bwd(sizes, interpret, kept, dout):
+    y, z, scale = kept
+    dy, dz, dscale = _norm_call(y, z, scale, dout, sizes, interpret)
+    return dy, jnp.pad(dz, ((0, 0), (0, 0), (0, z.shape[2] - y.shape[2]))), dscale
+
+
+_norm_kernels.defvjp(_norm_kernels_fwd, _norm_kernels_bwd)
+
+
+def gated_group_norm(y: jax.Array, z: jax.Array, scale: jax.Array, *, groups: int, eps: float,
+                     implementation: Optional[str] = None) -> jax.Array:
+    """The Mamba-2 mixer's gated norm: g = y silu(z) in float32 (the gate
+    first), an RMS norm of g over each of the `groups` contiguous segments of
+    inner // groups features (eps inside the root), times scale, in y's
+    dtype. y (B, S, inner), scale (inner,) -> (B, S, inner); z (B, S, inner),
+    or WIDER with the gate its first inner features: the kernels read those
+    columns of the array as it is, where a slice handed to them would be a
+    copy. Differentiable in y, z and scale. `implementation` is
+    `resolve_gate_norm_impl`'s, for tests: with nothing asked, the kernels on
+    a TPU at the sizes they tile and the XLA form elsewhere."""
+    bsz, s, inner = y.shape
+    impl = resolve_gate_norm_impl(implementation, rows=bsz * s, inner=inner, groups=groups)
+    if impl == "pallas":
+        return _norm_kernels(y, z, scale.astype(F32).reshape(1, inner), (groups, eps, _norm_rows(bsz * s)),
+                             jax.default_backend() != "tpu")
+    gated = y.astype(F32) * jax.nn.silu(z[..., :inner].astype(F32))
+    normed = rmsnorm(gated.reshape(bsz, s, groups, inner // groups), scale.reshape(groups, inner // groups), eps=eps)
+    return normed.astype(y.dtype).reshape(bsz, s, inner)
 
 
 def ssd_reference(x, dt, a_log, b, c, d) -> Tuple[jax.Array, jax.Array]:

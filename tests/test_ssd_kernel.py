@@ -1,10 +1,12 @@
-"""ops/ssd.py's kernels (`ssd_fwd`, `ssd_bwd`), interpreted: against the
-recurrence taken one position at a time (`ssd_reference`) AND against the XLA
-chunked form, outputs and every argument's gradient, at the published head
-shapes (P 64, N 128, chunk 128); the rule that chooses the form; what a
-checkpoint around a differentiated scan keeps. The whole file takes under a
-minute alone (the rule at the top of conftest.py): two or three chunks a
-case."""
+"""ops/ssd.py's kernels, interpreted. The scan's (`ssd_fwd`, `ssd_bwd`):
+against the recurrence taken one position at a time (`ssd_reference`) AND
+against the XLA chunked form, outputs and every argument's gradient, at the
+published head shapes (P 64, N 128, chunk 128); what a checkpoint around a
+differentiated scan keeps. The gated norm's (`ssm_gate_norm_fwd`,
+`ssm_gate_norm_bwd`): against the XLA form AND a float32 reference written
+here, outputs and the gradients of y, z and scale. The one rule that chooses
+either's form. The whole file takes about a minute alone (the rule at the
+top of conftest.py): two or three chunks, a few tiles of rows a case."""
 
 import jax
 import jax.numpy as jnp
@@ -23,12 +25,12 @@ def _scan(implementation):
 
 
 def _output_and_gradients(scan, weight, args):
-    """(y, the six arguments' gradients of sum(weight y)), one compilation."""
+    """(y, every argument's gradient of sum(weight y)), one compilation."""
     def objective(*a):
         y = scan(*a)
         return jnp.sum(weight * y.astype(jnp.float32)), y
 
-    grads, y = jax.jit(jax.grad(objective, argnums=range(6), has_aux=True))(*args)
+    grads, y = jax.jit(jax.grad(objective, argnums=range(len(args)), has_aux=True))(*args)
     return y, grads
 
 
@@ -89,35 +91,60 @@ def test_a_fast_head_whose_chunk_decays_past_what_float32_holds():
         assert _scaled_gap(got, ref) < (1e-3 if name == "a_log" else 1e-4), name
 
 
-def test_the_rule_is_the_backend_the_shapes_and_the_mesh(monkeypatch):
-    """Off a TPU the XLA form; on one the kernels at the sizes they tile and
-    the XLA form elsewhere, said by `scan_plan`; never under a context mesh
-    of several devices that nothing made manual; a kernel asked for by name
-    at sizes it does not tile is refused."""
-    cell = dict(chunk=128, heads=64, groups=8, head_dim=64, state=128)
-    tests = dict(chunk=16, heads=8, groups=2, head_dim=8, state=16)
-    assert ssd.resolve_scan_impl(**cell) == "xla_chunked"                       # this backend
-    assert ssd.resolve_scan_impl("pallas", **cell) == "pallas"                  # interpreted, for tests
+SCAN_CELL = dict(chunk=128, heads=64, groups=8, head_dim=64, state=128)
+NORM_CELL = dict(rows=2 * 8192, inner=4096, groups=8)
+RULES = {
+    # resolver, the XLA form's name, the cell's sizes, sizes the kernels do not tile
+    "scan": (ssd.resolve_scan_impl, "xla_chunked", SCAN_CELL,
+             (dict(chunk=16, heads=8, groups=2, head_dim=8, state=16), dict(SCAN_CELL, chunk=64),
+              dict(SCAN_CELL, head_dim=32), dict(SCAN_CELL, state=64), dict(SCAN_CELL, groups=64),
+              dict(SCAN_CELL, heads=512, groups=8))),
+    # a group of 64 or 192 lanes is no whole number of 128-lane tiles; 8,200 rows have no divisor that
+    # is a multiple of a bfloat16 tile's 16 sublanes; 4,096 features are not 3 groups
+    "gate-norm": (ssd.resolve_gate_norm_impl, "xla", NORM_CELL,
+                  (dict(NORM_CELL, inner=512), dict(NORM_CELL, inner=1536), dict(NORM_CELL, rows=8200),
+                   dict(NORM_CELL, groups=3), dict(rows=96, inner=32, groups=2))),
+}
+
+
+@pytest.mark.parametrize("which", sorted(RULES))
+def test_the_rule_is_the_backend_the_shapes_and_the_mesh(monkeypatch, which):
+    """ONE rule for the scan's and the gated norm's kernels (`ssd._resolve`):
+    off a TPU the XLA form; on one the kernels at the sizes they tile and
+    the XLA form elsewhere, said by the plan; never under a context mesh of
+    several devices that nothing made manual; a kernel asked for by name at
+    sizes it does not tile is refused."""
+    resolve, plain, cell, untiled_sizes = RULES[which]
+    assert resolve(**cell) == plain                                 # this backend
+    assert resolve("pallas", **cell) == "pallas"                    # interpreted, for tests
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert ssd.resolve_scan_impl(**cell) == "pallas"
+    assert resolve(**cell) == "pallas"
+    for untiled in untiled_sizes:
+        assert resolve(**untiled) == plain, untiled
+        with pytest.raises(ValueError, match="the kernels do not tile"):
+            resolve("pallas", **untiled)
+    with pytest.raises(ValueError, match=f"unknown {which.replace('-', 'd ')} implementation"):
+        resolve("mosaic", **cell)
+    mesh = jax.make_mesh((2, 4), ("fsdp", "tp"))
+    with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+        assert resolve(**cell) == plain
+    with jax.sharding.use_abstract_mesh(jax.make_mesh((1,), ("fsdp",)).abstract_mesh):
+        assert resolve(**cell) == "pallas"
+
+
+def test_the_plans_say_what_the_rule_chose(monkeypatch):
+    tiny = ssd.scan_plan(48, 16, heads=8, groups=2, head_dim=8, state=16)
+    assert (tiny["ssm_scan_impl"], tiny["ssm_scan_block_chunks"], tiny["ssm_scan_kernels"],
+            tiny["ssm_scan_state_bytes"]) == ("xla_chunked", 3, 0, 0)
+    assert ssd.gate_norm_plan(**NORM_CELL) == {"ssm_gate_norm_impl": "xla", "ssm_gate_norm_rows": 0}
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert ssd.scan_plan(8192, 128, heads=64, groups=8, head_dim=64, state=128) == {
         "ssm_scan_impl": "pallas", "ssm_chunk": 128, "ssm_scan_block_chunks": 1,
         "ssm_scan_kernels": 2, "ssm_scan_state_bytes": 8 * 64 * 128 * 4}
-    for untiled in (tests, dict(cell, chunk=64), dict(cell, head_dim=32), dict(cell, state=64),
-                    dict(cell, groups=64), dict(cell, heads=512, groups=8)):
-        assert ssd.resolve_scan_impl(**untiled) == "xla_chunked", untiled
-        with pytest.raises(ValueError, match="the kernels do not tile"):
-            ssd.resolve_scan_impl("pallas", **untiled)
-    said = ssd.scan_plan(48, 16, heads=8, groups=2, head_dim=8, state=16)
-    assert (said["ssm_scan_impl"], said["ssm_scan_block_chunks"], said["ssm_scan_kernels"],
-            said["ssm_scan_state_bytes"]) == ("xla_chunked", 3, 0, 0)
-    with pytest.raises(ValueError, match="unknown scan implementation"):
-        ssd.resolve_scan_impl("mosaic", **cell)
-    mesh = jax.make_mesh((2, 4), ("fsdp", "tp"))
-    with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
-        assert ssd.resolve_scan_impl(**cell) == "xla_chunked"
-    with jax.sharding.use_abstract_mesh(jax.make_mesh((1,), ("fsdp",)).abstract_mesh):
-        assert ssd.resolve_scan_impl(**cell) == "pallas"
+    assert ssd.gate_norm_plan(**NORM_CELL) == {"ssm_gate_norm_impl": "pallas", "ssm_gate_norm_rows": ssd._NORM_ROWS}
+    # 2 x 200 rows: the largest divisor that is a multiple of 16 sublanes and at most `_NORM_ROWS`
+    assert ssd.gate_norm_plan(400, 4096, 8)["ssm_gate_norm_rows"] == 80
+    assert ssd.gate_norm_plan(8200, 4096, 8) == {"ssm_gate_norm_impl": "xla", "ssm_gate_norm_rows": 0}
 
 
 def _calls(jaxpr, name):
@@ -157,3 +184,108 @@ def test_a_differentiated_scan_is_two_kernels_and_a_checkpoint_may_keep_what_spa
     assert _calls(plain, "ssd_fwd") == 1 and "128,128]" not in str([v.aval for v in plain.outvars])
     np.testing.assert_array_equal(np.asarray(_scan("pallas")(*args)),
                                   np.asarray(jax.vjp(_scan("pallas"), *args)[0]))
+
+
+
+# ----------------------------------------------------------- the gated norm
+
+
+def _norm_reference(y, z, scale, groups, eps):
+    """The gate, then an RMS norm a group, written out in float32."""
+    y, z, scale = (t.astype(jnp.float32) for t in (y, z, scale))
+    gated = y * z / (1.0 + jnp.exp(-z))
+    by_group = gated.reshape(*gated.shape[:2], groups, -1)
+    normed = by_group / jnp.sqrt(jnp.mean(by_group * by_group, axis=-1, keepdims=True) + eps)
+    return normed.reshape(gated.shape) * scale
+
+
+def _norm_inputs(batch, seq, inner, dtype, wide=0):
+    """(y, z, scale, the objective's weights): z `wide` features wider than y where asked."""
+    keys = jax.random.split(jax.random.PRNGKey(50), 4)
+    return (jax.random.normal(keys[0], (batch, seq, inner), dtype),
+            jax.random.normal(keys[1], (batch, seq, inner + wide), dtype),
+            1.0 + 0.2 * jax.random.normal(keys[2], (inner,)), jax.random.normal(keys[3], (batch, seq, inner)))
+
+
+NORM_TOLERANCE = {jnp.float32: 2e-6, jnp.bfloat16: 1e-2}      # bfloat16: one rounding of the output, 2^-8
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("groups, width, wide", [(8, 512, 192), (2, 256, 0)],
+                         ids=["8-groups-of-512-in-the-projection", "2-groups-of-256"])
+def test_norm_kernels_equal_the_xla_form_and_the_reference_outputs_and_gradients(monkeypatch, groups, width, wide, dtype):
+    """2 x 48 rows in three tiles of 32: the scale's cotangent is summed over
+    the grid in its block. At the published 8 groups of 512 z is the
+    in-projection's output as the mixer hands it over, WIDER than y with the
+    gate first: its cotangent is the gate's and zeros."""
+    monkeypatch.setattr(ssd, "_NORM_ROWS", 32)
+    inner = groups * width
+    y, z, scale, weight = _norm_inputs(2, 48, inner, dtype, wide)
+    norm = lambda impl: lambda *a: ssd.gated_group_norm(*a, groups=groups, eps=1e-5, implementation=impl)  # noqa: E731
+    out, ours = _output_and_gradients(norm("pallas"), weight, (y, z, scale))
+    plain, plains = _output_and_gradients(norm("xla"), weight, (y, z, scale))
+    want, theirs = _output_and_gradients(
+        lambda y, z, scale: _norm_reference(y, z[..., :inner], scale, groups, 1e-5), weight,
+        (y.astype(jnp.float32), z.astype(jnp.float32), scale))
+    assert out.dtype == dtype and out.shape == y.shape
+    assert _scaled_gap(out, want) < NORM_TOLERANCE[dtype]
+    assert _scaled_gap(out, plain.astype(jnp.float32)) < NORM_TOLERANCE[dtype]
+    for name, got, plain, ref in zip(("y", "z", "scale"), ours, plains, theirs):
+        assert got.shape == ref.shape and got.dtype == plain.dtype, name
+        assert _scaled_gap(got, ref) < 2 * NORM_TOLERANCE[dtype], name
+        assert _scaled_gap(got, plain.astype(jnp.float32)) < 2 * NORM_TOLERANCE[dtype], name
+    assert not wide or float(jnp.max(jnp.abs(ours[1][..., inner:]))) == 0.0
+
+
+def test_norm_kernels_take_a_row_count_that_is_no_multiple_of_the_tile():
+    """1 x 400 rows under tiles of at most 256: five steps of 80 rows, the
+    largest divisor that is a multiple of 16; 1 x 40 rows have none, and the
+    kernels asked for by name are refused."""
+    y, z, scale, weight = _norm_inputs(1, 400, 512, jnp.float32)
+    assert ssd._norm_rows(400) == 80
+    norm = lambda impl: lambda *a: ssd.gated_group_norm(*a, groups=2, eps=1e-6, implementation=impl)  # noqa: E731
+    out, ours = _output_and_gradients(norm("pallas"), weight, (y, z, scale))
+    plain, plains = _output_and_gradients(norm("xla"), weight, (y, z, scale))
+    assert _scaled_gap(out, plain) < 2e-6
+    for name, got, want in zip(("y", "z", "scale"), ours, plains):
+        assert _scaled_gap(got, want) < 4e-6, name
+    with pytest.raises(ValueError, match="the kernels do not tile 40 rows"):
+        norm("pallas")(y[:, :40], z[:, :40], scale)
+    np.testing.assert_array_equal(np.asarray(norm(None)(y[:, :40], z[:, :40], scale)),
+                                  np.asarray(norm("xla")(y[:, :40], z[:, :40], scale)))
+
+
+def test_norm_kernels_asked_for_by_name_at_a_width_they_do_not_tile_are_refused():
+    """Groups of 64 lanes (the tiny trees') are no whole 128-lane tiles: with
+    nothing asked the XLA form, bit for bit what ops/layers.rmsnorm gives on
+    the view by groups; by name refused, never swapped."""
+    y, z, scale, _ = _norm_inputs(2, 16, 128, jnp.float32)
+    with pytest.raises(ValueError, match="the kernels do not tile 32 rows of 128 features in 2 groups"):
+        ssd.gated_group_norm(y, z, scale, groups=2, eps=1e-5, implementation="pallas")
+    with pytest.raises(ValueError, match="unknown gated norm implementation"):
+        ssd.gated_group_norm(y, z, scale, groups=2, eps=1e-5, implementation="mosaic")
+    from ray_tpu.ops import rmsnorm
+    gated = y * jax.nn.silu(z)
+    want = rmsnorm(gated.reshape(2, 16, 2, 64), scale.reshape(2, 64), eps=1e-5).reshape(2, 16, 128)
+    np.testing.assert_array_equal(np.asarray(ssd.gated_group_norm(y, z, scale, groups=2, eps=1e-5)), np.asarray(want))
+
+
+def test_a_differentiated_norm_is_two_kernels_that_keep_nothing_of_their_own():
+    """`ssm_gate_norm_fwd` once and `ssm_gate_norm_bwd` once; what the
+    backward kernel reads is the forward's three arguments, so a checkpoint
+    runs the forward kernel again only where something reads its OUTPUT (the
+    mixer's out-projection does: its weight gradient)."""
+    y, z, scale, _ = _norm_inputs(1, 32, 512, jnp.bfloat16)
+
+    def norm(*a):
+        return ssd.gated_group_norm(*a, groups=2, eps=1e-5, implementation="pallas").astype(jnp.float32)
+
+    def kernels(fn):
+        jaxpr = jax.make_jaxpr(jax.grad(fn, argnums=(0, 1, 2)))(y, z, scale).jaxpr
+        return _calls(jaxpr, "ssm_gate_norm_fwd"), _calls(jaxpr, "ssm_gate_norm_bwd")
+
+    linear, square = lambda *a: jnp.sum(norm(*a)), lambda *a: jnp.sum(norm(*a) ** 2)       # noqa: E731
+    assert kernels(linear) == kernels(square) == kernels(jax.checkpoint(linear)) == (1, 1)
+    assert kernels(jax.checkpoint(square)) == (2, 1)
+    kept = [str(shape) for shape, _ in jax._src.ad_checkpoint.saved_residuals(linear, y, z, scale)]
+    assert sorted(kept) == ["bfloat16[1,32,512]"] * 2 + ["float32[1,512]"], kept      # y, z and the scale

@@ -13,6 +13,7 @@ Steering is done in this file: code that asks `jax.default_backend()` sees
 instead of the attached device's.
 """
 
+import math
 import os
 import re
 
@@ -355,3 +356,61 @@ def test_ssd_scan_kernels_compile_within_the_scoped_vmem_they_ask_for(as_tpu, ba
     grad = jax.jit(jax.grad(lambda *a: jnp.sum(ssd.ssd_scan(*a, chunk=128).astype(jnp.float32) ** 2),
                             argnums=tuple(range(6)))).lower(*args).compile()
     assert _kernel_calls(grad) == 2
+
+
+@pytest.mark.parametrize("batch, seq, inner, wide, groups", [(2, 8192, 4096, 10304, 8), (1, 400, 512, 512, 2)],
+                         ids=["nemotron3nano-8-groups-of-512-in-the-projection", "2-groups-of-256-tiles-of-80-rows"])
+def test_gate_norm_kernels_compile_within_the_scoped_vmem_they_ask_for(as_tpu, batch, seq, inner, wide, groups):
+    """`ssm_gate_norm_fwd` and `ssm_gate_norm_bwd` at the `train-nemotron3nano-8k`
+    cell's shapes (a grid step: 256 rows of all 8 groups, z read at the first
+    4,096 features of the in-projection's (2, 8,192, 10,304) output) and at
+    one other tiling: the rule says "pallas" there, a differentiated norm is
+    the two calls, and Mosaic fits each in the scoped VMEM its call asks for
+    (every tile twice and 8 MiB: a kernel that did not fit would be refused
+    here as on the chip)."""
+    from ray_tpu.ops import ssd
+
+    rows = ssd._norm_rows(batch * seq)
+    assert ssd.gate_norm_plan(batch * seq, inner, groups) == {"ssm_gate_norm_impl": "pallas", "ssm_gate_norm_rows": rows}
+    args = (_on(as_tpu, (batch, seq, inner)), _on(as_tpu, (batch, seq, wide)), _on(as_tpu, (inner,), jnp.float32))
+    norm = lambda *a: ssd.gated_group_norm(*a, groups=groups, eps=1e-5)     # noqa: E731
+    forward = jax.jit(norm).lower(*args)
+    assert f"\\22size\\22: {2 * 3 * rows * inner * 2 + 8 * 2**20}}}]" in forward.as_text()
+    assert _kernel_calls(forward.compile()) == 1
+    grad = jax.jit(jax.grad(lambda *a: jnp.sum(norm(*a).astype(jnp.float32) ** 2), argnums=(0, 1, 2))).lower(*args)
+    assert f"\\22size\\22: {2 * 5 * rows * inner * 2 + 8 * 2**20}}}]" in grad.as_text()
+    assert _kernel_calls(grad.compile()) == 2
+
+
+def test_the_lowered_mixer_holds_no_float32_copy_of_the_group_view(as_tpu):
+    """One Mamba-2 mixer of the `train-nemotron3nano-8k` cell, forward and
+    gradient, compiled for the described v5e: the gate and the group norm are
+    the two kernels under `ssm.gate_norm`, and NO operation of the program
+    copies, reshapes or transposes a float32 array of the product's size
+    (2 x 8,192 x 4,096 x 4 bytes: the group view's shuffles, five a layer
+    before PR 50). The in-projection's output reaches the kernels as it is
+    written (no slice of z in front of them), and the gate's cotangent joins
+    xBC's and dt's with no pad of its own to the projection's width."""
+    from benchmark import model_config
+    from ray_tpu.models import mixed_stack, model_family
+
+    config = model_config.transformer_config(model_config.load_config(
+        os.path.join(os.path.dirname(__file__), "..", "benchmark", "configs", "nemotron-3-nano-30b-a3b-train-1chip.json")))
+    shapes = jax.eval_shape(lambda: model_family(config).init_params(config, jax.random.PRNGKey(0)))
+    one = SingleDeviceSharding(as_tpu)
+    lp = jax.tree.map(lambda w: jax.ShapeDtypeStruct(w.shape[1:], w.dtype, sharding=one), shapes["runs"][0][0])
+
+    def loss(x, lp):
+        return jnp.sum(mixed_stack._ssm_sublayer(x, lp, config)[0].astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(_on(as_tpu, (2, 8192, config.d_model)), lp).compile()
+    text = compiled.as_text()
+    assert _kernel_calls(compiled) == 4 and "%ssm_gate_norm_fwd" in text and "%ssm_gate_norm_bwd" in text
+    product = 2 * 8192 * 4096
+    moved = [line.strip()[:160] for line in text.splitlines()
+             for found in [re.match(r"\s*(?:ROOT )?%[\w.\-]+ = f32\[([\d,]+)\]\S* (copy|reshape|transpose)\(", line)]
+             if found and math.prod(int(d) for d in found.group(1).split(",")) >= product]
+    assert not moved, moved
+    entry = text[text.index("\nENTRY "):]
+    assert not re.search(r"= bf16\[2,8192,4096\]\S* (slice|copy)\(%fusion", entry)     # z is not cut out of the projection
+    assert not re.search(r"= bf16\[(2,8192|16384),10304\]\S* pad\(", entry)        # nor its cotangent padded to the width

@@ -17,7 +17,7 @@ def test_nemotron3nano_cell_step_runs_the_scan_under_its_scopes_and_compiles(as_
     v5e chip of 15.75 GiB: `ME` scanned twice, then `M*EME` unrolled. Each of
     the 7 layer bodies is one sublayer; the state-space mixer's five scopes
     lie inside `ssm` in the forward pass, its recomputation and the backward
-    pass, and its scan is the two kernels of ops/ssd; the one attention layer runs the causal D = 128 flash kernels and
+    pass, its scan and its gated norm are the two kernels each of ops/ssd; the one attention layer runs the causal D = 128 flash kernels and
     the expert layers TWO grouped matmuls a pass (a non-gated expert); the
     fused head takes the whole sequence as its chunk."""
     from ray_tpu.models import model_family
@@ -37,6 +37,7 @@ def test_nemotron3nano_cell_step_runs_the_scan_under_its_scopes_and_compiles(as_
     said = model_family(config).plan(config, 2, 8192)
     assert (said["ssm_scan_impl"], said["ssm_scan_kernels"], said["ssm_scan_state_bytes"]) == (
         "pallas", 2, 8 * 64 * 128 * 4)
+    assert (said["ssm_gate_norm_impl"], said["ssm_gate_norm_rows"]) == ("pallas", 256)
     compiled = step.lower(state, {"tokens": tokens}).compile()
     # one attention layer, in the unrolled run: the forward kernel and its recomputation, one backward
     assert _kernels_named(compiled, "flash_bwd_dkv_dq") == 1 and "flash_win" not in compiled.as_text()
@@ -53,6 +54,13 @@ def test_nemotron3nano_cell_step_runs_the_scan_under_its_scopes_and_compiles(as_
         assert len(table[kernel]) == 3
         for scopes, found, _ in table[kernel]:
             assert {"ssm", "ssm.scan"} <= set(scopes) and found == pass_, (kernel, scopes, found)
+    # the gated norm's output is not kept: its forward kernel runs again in every body's recomputation
+    # (the out-projection's weight gradient reads it), its backward kernel once a body
+    assert _kernels_named(compiled, "ssm_gate_norm_fwd") == 6 and _kernels_named(compiled, "ssm_gate_norm_bwd") == 3
+    assert sorted(found for _, found, _ in table["ssm_gate_norm_fwd"]) == ["fwd"] * 3 + ["recompute"] * 3
+    for scopes, found, _ in table["ssm_gate_norm_fwd"] + table["ssm_gate_norm_bwd"]:
+        assert {"ssm", "ssm.gate_norm"} <= set(scopes), scopes
+    assert {found for _, found, _ in table["ssm_gate_norm_bwd"]} == {"bwd"}
     pairs = {(scope, pass_) for instances in table.values() for scopes, pass_, _ in instances for scope in scopes}
     for scope in ("ssm", "ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.gate_norm"):
         assert {(scope, "fwd"), (scope, "recompute"), (scope, "bwd")} <= pairs, scope

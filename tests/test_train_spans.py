@@ -336,7 +336,7 @@ def test_a_report_reads_the_device_once(trainer, monkeypatch):
     transfer (under a busy host each further round trip cost the OLMoE
     cell ~10 ms a segment, PERF.md PR 27): no scalar is read on its own."""
     trainer, config, _, _ = trainer
-    cfg.train_step_log = False          # a sampled step syncs on its own
+    cfg.set(train_step_log=False)       # a sampled step syncs on its own (an override: `_clean` resets it)
     fetched = []
     real = jax.device_get
     monkeypatch.setattr(jax, "device_get", lambda x: fetched.append(x) or real(x))
