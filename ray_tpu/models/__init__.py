@@ -74,7 +74,7 @@ _FAMILIES = (
     (MoEConfig, ModelFamily(_moe.init_params, _moe.logical_axes, _moe.forward_hidden,
                             None, _moe.moe_plan)),
     (TransformerConfig, ModelFamily(init_params, logical_axes, _dense_hidden,
-                                    _dense.block_costs, lambda config, batch, seq: {})),
+                                    _dense.block_costs, _dense.plan)),
 )
 
 

@@ -41,6 +41,8 @@ from ..ops import (
     rope_frequencies,
     swiglu,
 )
+from ..ops.attention import attention_plan
+from ..ops.eva import eva_attention, eva_plan
 
 Params = Dict[str, Any]
 
@@ -96,8 +98,42 @@ class TransformerConfig:
     kv_lora_rank: int = 0
     qk_rope_dim: int = 0
     v_head_dim: Optional[int] = None
+    # EVA attention (`eva_window` > 0; ops/eva.py: the EvaByte family): exact
+    # causal attention inside a window of `eva_window` positions, one learned
+    # summary a chunk of `eva_chunk` positions of everything before the window,
+    # one softmax over both; a layer's leaves `eva_mu`, `eva_phi` (heads,
+    # head_dim) are a head's two pooling vectors. 0: softmax attention over
+    # every causal key (or a mixed stack's window)
+    eva_window: int = 0
+    eva_chunk: int = 0
+    # Data of the same family, each False / 1 for the others: an RMSNorm that
+    # multiplies by 1 + scale (scale starts at 0); the residual stream carried
+    # and added in float32 whatever `dtype` the sublayers compute in; and
+    # `pred_heads` next-token heads in the loss, head n a `vocab_size`-column
+    # slice of ONE (d_model, pred_heads x vocab_size) head matrix that
+    # predicts token t + 1 + n from position t (train/lm.lm_loss)
+    norm_unit_offset: bool = False
+    residual_fp32: bool = False
+    pred_heads: int = 1
 
     def __post_init__(self):
+        if self.eva_window or self.eva_chunk:
+            if not (self.eva_chunk > 0 and self.eva_window > 0
+                    and self.eva_window % self.eva_chunk == 0):
+                raise ValueError(f"eva attention: eva_window {self.eva_window} must be a positive "
+                                 f"multiple of eva_chunk {self.eva_chunk}")
+            if not (self.causal and self.kv_heads == self.n_heads and self.pos_emb == "rope"
+                    and not (self.latent_attention or self.qk_norm or self.qk_norm_per_head
+                             or self.attn_gate or self.fused_qkv)):
+                raise ValueError(
+                    "eva attention: causal, rotary positions, as many key-value heads as query "
+                    "heads and none of latent attention, QK-norm, an output gate or a fused "
+                    "projection are what the program runs")
+        if self.pred_heads < 1 or (self.pred_heads > 1 and self.tie_embeddings):
+            raise ValueError(f"pred_heads {self.pred_heads}: at least 1, and more than one head "
+                             "needs an untied head matrix")
+        if self.norm_unit_offset and self.norm != "rmsnorm":
+            raise ValueError("norm_unit_offset is an RMSNorm's (1 + scale)")
         if self.latent_attention:
             if self.v_head_dim not in (None, self.head_dim):
                 raise ValueError(
@@ -120,6 +156,15 @@ class TransformerConfig:
     @property
     def latent_attention(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def eva_attention(self) -> bool:
+        return self.eva_window > 0
+
+    @property
+    def stream_dtype(self) -> Any:
+        """The dtype of the residual stream: what a block takes and returns."""
+        return jnp.float32 if self.residual_fp32 else self.dtype
 
     @property
     def rotary_dims(self) -> int:
@@ -151,13 +196,15 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Params:
         return s * jax.random.normal(k, shape, pd)
 
     L = c.n_layers
+    # a norm's scale where it multiplies by (1 + scale) starts at 0
+    norm_scale = jnp.zeros if c.norm_unit_offset else jnp.ones
     blocks: Params = {
-        "ln1_scale": jnp.ones((L, c.d_model), pd),
+        "ln1_scale": norm_scale((L, c.d_model), pd),
         "wq": normal(keys[0], (L, c.d_model, c.n_heads, dh)),
         "wk": normal(keys[1], (L, c.d_model, c.kv_heads, dh)),
         "wv": normal(keys[2], (L, c.d_model, c.kv_heads, dh)),
         "wo": normal(keys[3], (L, c.n_heads, dh, c.d_model), res_std),
-        "ln2_scale": jnp.ones((L, c.d_model), pd),
+        "ln2_scale": norm_scale((L, c.d_model), pd),
         "w_up": normal(keys[4], (L, c.d_model, c.d_ff)),
         "w_down": normal(keys[5], (L, c.d_ff, c.d_model), res_std),
     }
@@ -166,6 +213,9 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Params:
     if c.qk_norm:
         blocks["q_norm_scale"] = jnp.ones((L, c.n_heads * dh), pd)
         blocks["k_norm_scale"] = jnp.ones((L, c.kv_heads * dh), pd)
+    if c.eva_attention:
+        blocks["eva_mu"] = normal(keys[10], (L, c.n_heads, dh))
+        blocks["eva_phi"] = normal(keys[11], (L, c.n_heads, dh))
     if c.norm == "layernorm":
         blocks["ln1_bias"] = jnp.zeros((L, c.d_model), pd)
         blocks["ln2_bias"] = jnp.zeros((L, c.d_model), pd)
@@ -180,14 +230,14 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Params:
     params: Params = {
         "wte": normal(keys[7], (c.vocab_size, c.d_model)),
         "blocks": blocks,
-        "lnf_scale": jnp.ones((c.d_model,), pd),
+        "lnf_scale": norm_scale((c.d_model,), pd),
     }
     if c.pos_emb == "learned":
         params["wpe"] = normal(keys[8], (c.max_seq, c.d_model), 0.01)
     if c.norm == "layernorm":
         params["lnf_bias"] = jnp.zeros((c.d_model,), pd)
     if not c.tie_embeddings:
-        params["lm_head"] = normal(keys[9], (c.d_model, c.vocab_size))
+        params["lm_head"] = normal(keys[9], (c.d_model, c.pred_heads * c.vocab_size))
     return params
 
 
@@ -209,6 +259,9 @@ def logical_axes(config: TransformerConfig) -> Params:
     if c.qk_norm:
         blocks["q_norm_scale"] = ("layers", None)
         blocks["k_norm_scale"] = ("layers", None)
+    if c.eva_attention:
+        blocks["eva_mu"] = ("layers", "heads", "head_dim")
+        blocks["eva_phi"] = ("layers", "heads", "head_dim")
     if c.norm == "layernorm":
         blocks["ln1_bias"] = ("layers", None)
         blocks["ln2_bias"] = ("layers", None)
@@ -240,12 +293,20 @@ def count_params(params: Params) -> int:
 # -------------------------------------------------------------------- forward
 
 
-def _norm(x, scale, bias, kind, eps=None):
-    """`eps` None keeps each norm's own default (ops/layers)."""
+def _norm(x, scale, bias, kind, eps=None, unit_offset=False):
+    """`eps` None keeps each norm's own default (ops/layers); `unit_offset`:
+    an RMSNorm that multiplies by 1 + scale."""
     kw = {} if eps is None else {"eps": eps}
     if kind == "rmsnorm":
-        return rmsnorm(x, scale, **kw)
+        return rmsnorm(x, 1.0 + scale.astype(jnp.float32) if unit_offset else scale, **kw)
     return layernorm(x, scale, bias, **kw)
+
+
+def _block_norm(x, scale, bias, config):
+    """A sublayer's norm of the stream `x`, in the dtype the sublayer computes
+    in (the stream's own unless it is carried in float32)."""
+    c = config
+    return _norm(x, scale, bias, c.norm, c.norm_eps, c.norm_unit_offset).astype(c.dtype)
 
 
 def _qk_norm(x: jax.Array, scale: jax.Array, eps: Optional[float]) -> jax.Array:
@@ -315,7 +376,7 @@ def _attention_sublayer(x, lp, config, rope_tables, positions, window, remat_sav
     c = config
     dt = c.dtype
     with jax.named_scope("attn.proj"):
-        h = _norm(x, lp["ln1_scale"], lp.get("ln1_bias"), c.norm, c.norm_eps)
+        h = _block_norm(x, lp["ln1_scale"], lp.get("ln1_bias"), c)
         if c.latent_attention:
             q, k, v = _latent_qkv(h, lp, c, rope_tables, positions)
         elif c.fused_qkv:
@@ -356,8 +417,12 @@ def _attention_sublayer(x, lp, config, rope_tables, positions, window, remat_sav
             q = apply_rope(q, cos, sin, positions)
             k = apply_rope(k, cos, sin, positions)
     with jax.named_scope("attn.kernel"):
-        attend = flash_attention_kept if "attn_lse" in remat_saved else flash_attention
-        attn = attend(q, k, v, causal=c.causal, window=window, implementation=c.attn_impl)
+        if c.eva_attention:
+            attn = eva_attention(q, k, v, lp["eva_mu"], lp["eva_phi"], window=c.eva_window,
+                                 chunk=c.eva_chunk, implementation=c.attn_impl)
+        else:
+            attend = flash_attention_kept if "attn_lse" in remat_saved else flash_attention
+            attn = attend(q, k, v, causal=c.causal, window=window, implementation=c.attn_impl)
     if c.attn_gate:
         with jax.named_scope("attn.proj"):
             gate = jnp.einsum("bse,ehd->bhsd", h, lp["wg"].astype(dt))
@@ -377,7 +442,7 @@ def mlp_sublayer(x: jax.Array, lp: Params, config: TransformerConfig) -> jax.Arr
     c = config
     dt = c.dtype
     with jax.named_scope("mlp"):
-        h = _norm(x, lp["ln2_scale"], lp.get("ln2_bias"), c.norm, c.norm_eps)
+        h = _block_norm(x, lp["ln2_scale"], lp.get("ln2_bias"), c)
         up = jnp.einsum("bse,ef->bsf", h, lp["w_up"].astype(dt))
         if c.use_bias:
             up = up + lp["b_up"].astype(dt)
@@ -447,18 +512,25 @@ def attention_costs(
     q_width, kv_width = c.n_heads * c.head_dim // split("wq"), c.kv_heads * c.head_dim // split("wk")
     out_proj = 2 * q_width * c.d_model
     # the scores' two matmuls over the keys a query sees
-    scores = 4 * q_width * (min(seq, window) if window else seq // 2 if c.causal else seq)
+    if c.eva_attention:
+        # half its window, and a summary a chunk of the windows before its own
+        visible = c.eva_window // 2 + (seq - c.eva_window) // (2 * c.eva_chunk)
+    else:
+        visible = min(seq, window) if window else seq // 2 if c.causal else seq
+    scores = 4 * q_width * visible
     heads, itemsize = c.n_heads // split("wq"), jnp.dtype(c.dtype).itemsize
+    # the residual stream's features, in features of the activations' dtype
+    stream = c.d_model * jnp.dtype(c.stream_dtype).itemsize // itemsize
     return {
         "flops": (2 * c.d_model * ((2 if c.attn_gate else 1) * q_width + 2 * kv_width)
                   + scores + out_proj),
         # the norm's output, the sublayer's (and its own norm's), the residual;
         # q and the attention output, (the gate and the gated output); k, v
-        "width": ((4 if c.sandwich_norm else 3) * c.d_model
+        "width": ((3 if c.sandwich_norm else 2) * c.d_model + stream
                   + (4 if c.attn_gate else 2) * q_width + 2 * kv_width),
         "candidates": (
             _kept_kernel_candidate(scores, heads, q_width, itemsize, "window" if window else "full"),
-            RematCandidate(("attn_residual",), c.d_model, out_proj, out_proj, split("wq") > 1, ()),
+            RematCandidate(("attn_residual",), stream, out_proj, out_proj, split("wq") > 1, ()),
         ),
     }
 
@@ -511,6 +583,8 @@ def mlp_costs(config: TransformerConfig, split: Callable[[str], int],
     wide = ("w_up", "w_gate") if c.act == "swiglu" else ("w_up",)
     d_ff = (d_ff or c.d_ff) // split("w_up")
     matmul = 2 * c.d_model * d_ff  # each of up, (gate,) down
+    itemsize = jnp.dtype(c.dtype).itemsize
+    stream = c.d_model * jnp.dtype(c.stream_dtype).itemsize // itemsize
     return {
         "flops": (len(wide) + 1) * matmul,
         # the down projection's output only feeds the next block's input,
@@ -518,7 +592,7 @@ def mlp_costs(config: TransformerConfig, split: Callable[[str], int],
         "kept_anyway": 0 if c.sandwich_norm else matmul,
         # the norm's output, the MLP's (and its own norm's), the residual; up,
         # (gate,) and the activation
-        "width": (4 if c.sandwich_norm else 3) * c.d_model + (len(wide) + 1) * d_ff,
+        "width": (3 if c.sandwich_norm else 2) * c.d_model + stream + (len(wide) + 1) * d_ff,
         "candidates": tuple(
             RematCandidate((w.replace("w_", "mlp_"),), d_ff, matmul, matmul, False, ()) for w in wide),
     }
@@ -594,6 +668,22 @@ def block_costs(
         (config.n_layers, attention_costs(config, seq, split), mlp_costs(config, split)),))])
 
 
+def plan(config: TransformerConfig, batch: int, seq: int) -> Dict[str, Any]:
+    """What the dense family's own layers resolve to for a (batch, seq) step,
+    for callers that report it (LMTrainer's `train.init.step_fn` span): an
+    EVA model's flash calls (a window long, one a window: `attention_plan`
+    of a window, over the caller's of the whole sequence), its windows,
+    chunks and tile counts (ops/eva.eva_plan) and its next-token heads;
+    nothing for the others."""
+    c = config
+    if not c.eva_attention:
+        return {}
+    return dict(attention_plan(c.eva_window, causal=c.causal, implementation=c.attn_impl,
+                               head_dim=c.head_dim),
+                **eva_plan(seq, window=c.eva_window, chunk=c.eva_chunk, head_dim=c.head_dim,
+                           implementation=c.attn_impl), pred_heads=c.pred_heads)
+
+
 def checkpoint_block(block_fn, saved: Tuple[str, ...] = ()):
     """`block_fn` recomputed in the backward pass but for the values whose
     `checkpoint_name` is in `saved` (none: the whole block)."""
@@ -624,6 +714,7 @@ def forward_hidden(
                 x = x + params["wpe"].astype(dt)[None, :s]
             else:
                 x = x + params["wpe"].astype(dt)[positions]
+        x = x.astype(c.stream_dtype)
     rope_tables = None
     if c.pos_emb != "learned":
         rope_tables = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
@@ -636,12 +727,12 @@ def forward_hidden(
     x, _ = jax.lax.scan(block_fn, x, params["blocks"], unroll=c.scan_unroll)
 
     with jax.named_scope("head"):
-        return _norm(x, params["lnf_scale"], params.get("lnf_bias"), c.norm, c.norm_eps)
+        return _block_norm(x, params["lnf_scale"], params.get("lnf_bias"), c)
 
 
 def lm_head_weights(params: Params, config: TransformerConfig) -> jax.Array:
     """(E, V) output projection — tied to wte unless a separate lm_head
-    exists."""
+    exists; (E, pred_heads x V) for a model of several next-token heads."""
     head = params.get("lm_head", None)
     if head is None:
         head = params["wte"].T
@@ -655,7 +746,9 @@ def forward(
     *,
     positions: Optional[jax.Array] = None,
 ) -> jax.Array:
-    """Full-sequence forward (training / prefill): (B, S) → (B, S, V)."""
+    """Full-sequence forward (training / prefill): (B, S) → (B, S, V);
+    (B, S, pred_heads x V) for a model of several next-token heads, head n's
+    logits the n-th V columns."""
     x = forward_hidden(params, tokens, config, positions=positions)
     return jnp.einsum("bse,ev->bsv", x, lm_head_weights(params, config))
 
@@ -680,6 +773,18 @@ def _no_latent_attention(config: TransformerConfig, where: str) -> None:
             f"a patterned stack (state-space `ssm` layers, one sublayer a layer) is run by the mixed "
             f"stack's training forward alone: {where} has neither a state-space mixer's leaves nor a "
             "cache of its convolution's window and its state")
+
+
+def _no_eva(config: TransformerConfig, where: str) -> None:
+    """Refuse, by name, what the EvaByte family adds and the cached paths lack."""
+    if config.eva_attention:
+        raise NotImplementedError(
+            f"eva attention is run by the training forward alone: {where} has no cache of a "
+            "window's keys and values beside one summary pair a chunk of everything before it")
+    if config.pred_heads > 1 or config.norm_unit_offset or config.residual_fp32:
+        raise NotImplementedError(
+            f"several next-token heads, a (1 + scale) norm and a float32 residual stream are run "
+            f"by the training forward alone: {where} reads one head and adds in the compute dtype")
 
 
 def init_cache(
@@ -726,6 +831,7 @@ def decode_step(
     c = config
     _no_qk_norm(c)
     _no_latent_attention(c, "the dense cache")
+    _no_eva(c, "the dense cache")
     dt = c.dtype
     b = tokens.shape[0]
     x = params["wte"].astype(dt)[tokens][:, None, :]  # (B, 1, E)
@@ -800,6 +906,7 @@ def prefill(
     c = config
     _no_qk_norm(c)
     _no_latent_attention(c, "the dense cache")
+    _no_eva(c, "the dense cache")
     dt = c.dtype
     b, s = tokens.shape
     x = params["wte"].astype(dt)[tokens]

@@ -1210,7 +1210,8 @@ def _per_shard(kernel_fn):
     With no context mesh, one device, or inside somebody else's
     shard_map (ring, Ulysses, pipeline, explicit-dp: the axes are already
     manual there) the kernel is called as it is. Axis names are
-    parallel.mesh's (DATA_AXES, "tp")."""
+    parallel.mesh's (DATA_AXES, "tp"). Every operand is a (B, H, ., .) array
+    (q, k, v; ops/eva.py's summaries beside them)."""
     mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty or mesh.manual_axes:
         return kernel_fn
@@ -1219,10 +1220,10 @@ def _per_shard(kernel_fn):
     if not batch and heads is None:
         return kernel_fn
     spec = P(batch or None, heads, None, None)
-    return jax.shard_map(
-        kernel_fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+    return lambda *operands: jax.shard_map(
+        kernel_fn, mesh=mesh, in_specs=(spec,) * len(operands), out_specs=spec,
         check_vma=False,
-    )
+    )(*operands)
 
 
 def _pad_seq(x: jax.Array, axis: int, multiple: int) -> jax.Array:
@@ -1335,11 +1336,14 @@ def flash_attention_with_lse(
 ) -> "tuple[jax.Array, jax.Array]":
     """Like flash_attention but also returns the per-row logsumexp of the
     scaled scores, shape (B, Hq, Sq, 1) float32 — the carry blockwise
-    consumers (ring attention) need to merge partial attentions exactly.
+    consumers need to merge partial attentions exactly: ring attention, and
+    (through `_fwd_pallas` itself) ops/eva.py, whose far kernel goes on from
+    the local part's output and lse.
 
-    FORWARD ONLY: no VJP is registered through the lse output; callers
-    that need gradients wrap their own (ring_attention's custom_vjp
-    recomputes through the einsum reference)."""
+    FORWARD ONLY: no VJP is registered through the lse output; both
+    consumers own theirs (ring_attention's custom_vjp recomputes through
+    the einsum reference, ops/eva.py's hands the MERGED output and lse to
+    `flash_bwd_dkv_dq`)."""
     sq, skv = q.shape[2], k.shape[2]
     implementation = resolve_attention_impl(implementation)
     if sm_scale is None:

@@ -268,3 +268,116 @@ def fused_linear_cross_entropy(
     mask = jnp.ones((b, s), jnp.float32) if mask is None else mask.astype(jnp.float32)
     loss = _chunked_cross_entropy(x, head, targets, mask, chunk, z_loss_coeff)
     return loss, _num_tokens(mask)
+
+
+# ------------------------------------------------- several next-token heads
+
+
+def multihead_targets(tokens: jax.Array, heads: int) -> Tuple[jax.Array, jax.Array]:
+    """(targets, has_target), each (B, S, heads), of a (B, S + 1) batch for
+    `heads` next-token heads: head n at position t is scored against token
+    t + 1 + n, and the last n positions of a sequence have no such target
+    (their target reads 0 and their `has_target` False)."""
+    s = tokens.shape[1] - 1
+    index = jnp.arange(s)[:, None] + 1 + jnp.arange(heads)[None, :]          # (S, heads)
+    has_target = index <= s
+    targets = jnp.where(has_target[None], tokens[:, jnp.minimum(index, s)], 0)
+    return targets, jnp.broadcast_to(has_target[None], targets.shape)
+
+
+def _head_weights(has_target):
+    """(B, S, heads) float32: 1 / (a head's positions that have a target), 0
+    where there is none, so that a weighted sum over positions is that head's
+    mean."""
+    mask = has_target.astype(jnp.float32)
+    return mask / jnp.maximum(jnp.sum(mask, axis=(0, 1)), 1.0)
+
+
+def multihead_cross_entropy(logits: jax.Array, targets: jax.Array,
+                            has_target: jax.Array) -> jax.Array:
+    """(heads,) mean cross entropy of each next-token head: logits (B, S,
+    heads x V), head n's the n-th V columns, in float32; targets and
+    `has_target` (B, S, heads) (`multihead_targets`). The plain form that
+    the fused one's tests compare with."""
+    b, s, heads = targets.shape
+    logits32 = logits.astype(jnp.float32).reshape(b, s, heads, -1)
+    logz = jax.nn.logsumexp(logits32, axis=-1)
+    tgt = jnp.take_along_axis(logits32, targets[..., None], axis=-1)[..., 0]
+    return jnp.sum((logz - tgt) * _head_weights(has_target), axis=(0, 1))
+
+
+def _multihead_chunk(xc, head, tc, wc):
+    """One chunk's float32 logits (b, c, heads, V) from ONE matmul over all
+    the heads' columns, their logsumexp a head and the chunk's share of each
+    head's mean cross entropy."""
+    heads = tc.shape[-1]
+    logits = jnp.einsum("bce,ev->bcv", xc, head, preferred_element_type=jnp.float32)
+    logits = logits.reshape(*logits.shape[:2], heads, -1)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    tgt = jnp.take_along_axis(logits, tc[..., None], axis=-1)[..., 0]
+    return logits, lse, jnp.sum((lse - tgt) * wc, axis=(0, 1))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _chunked_multihead_cross_entropy(x, head, targets, weights, chunk):
+    """(the mean over the heads of their mean cross entropies, each head's
+    own (heads,): reported, not differentiated). The loss alone: one matmul a
+    chunk."""
+    def body(sums, xtw):
+        xc, tc, wc = xtw
+        return sums + _multihead_chunk(xc, head, tc, wc)[2], None
+
+    sums, _ = jax.lax.scan(body, jnp.zeros((targets.shape[-1],)), _chunks(chunk, x, targets, weights))
+    return jnp.mean(sums), sums
+
+
+def _chunked_multihead_cross_entropy_fwd(x, head, targets, weights, chunk):
+    """As `_chunked_cross_entropy_fwd`: while a chunk's logits are live, its
+    share of d(objective)/dx and d(objective)/d(head). d(logits) of head n =
+    (softmax - onehot) x weight / heads: the objective's cotangent is a
+    scalar, and the heads' own losses carry none."""
+    heads, vocab = targets.shape[-1], head.shape[-1] // targets.shape[-1]
+
+    def body(carry, xtw):
+        sums, dw = carry
+        xc, tc, wc = xtw
+        logits, lse, share = _multihead_chunk(xc, head, tc, wc)
+        onehot = tc[..., None] == jnp.arange(vocab, dtype=tc.dtype)
+        dlogits32 = (jnp.exp(logits - lse[..., None]) - onehot) * (wc / heads)[..., None]
+        dlogits = dlogits32.reshape(*dlogits32.shape[:2], -1).astype(
+            jnp.result_type(xc.dtype, head.dtype))
+        dxc = jnp.einsum("bcv,ev->bce", dlogits, head).astype(xc.dtype)
+        dw = dw + jnp.einsum("bce,bcv->ev", xc, dlogits, preferred_element_type=dw.dtype)
+        return (sums + share, dw), dxc
+
+    dw0 = jnp.zeros(head.shape, jnp.promote_types(head.dtype, jnp.float32))
+    (sums, dw), dxs = jax.lax.scan(
+        body, (jnp.zeros((heads,)), dw0), _chunks(chunk, x, targets, weights))
+    dx = dxs.swapaxes(0, 1).reshape(x.shape)
+    return (jnp.mean(sums), sums), (dx, dw.astype(head.dtype))
+
+
+def _chunked_multihead_cross_entropy_bwd(chunk, residuals, cotangents):
+    dx, dw = residuals
+    g, _ = cotangents
+    # the integer targets and the weights take no cotangent
+    return (g * dx).astype(dx.dtype), (g * dw).astype(dw.dtype), None, None
+
+
+_chunked_multihead_cross_entropy.defvjp(
+    _chunked_multihead_cross_entropy_fwd, _chunked_multihead_cross_entropy_bwd)
+
+
+def fused_multihead_cross_entropy(x: jax.Array, head: jax.Array, targets: jax.Array,
+                                  has_target: jax.Array, *, chunk: int) -> Tuple[jax.Array, jax.Array]:
+    """`fused_linear_cross_entropy` for several next-token heads that share
+    ONE head matrix (E, heads x V): a chunk's logits of all the heads are
+    built once, by one matmul, in float32. x (B, S, E); targets and
+    `has_target` (B, S, heads) (`multihead_targets`). Returns (the mean over
+    the heads of each head's mean cross entropy over the positions that have
+    its target, the heads' own means (heads,), which carry no gradient)."""
+    if x.shape[1] % chunk:
+        raise ValueError(f"seq len {x.shape[1]} not divisible by loss chunk {chunk}")
+    objective, per_head = _chunked_multihead_cross_entropy(
+        x, head, targets, _head_weights(has_target), chunk)
+    return objective, jax.lax.stop_gradient(per_head)

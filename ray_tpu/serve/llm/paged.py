@@ -39,7 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...models.transformer import TransformerConfig, _no_latent_attention, _norm
+from ...models.transformer import TransformerConfig, _no_eva, _no_latent_attention, _norm
 from ...ops import apply_rope, rope_frequencies
 from ...ops.ragged_paged_attention import (
     RAGGED_KERNEL,
@@ -85,6 +85,7 @@ def init_paged_cache(
     double-buffer or slice out ~pool/L per layer per step; measured 8x
     decode slowdown at 512 pages.)"""
     _no_latent_attention(model, "the paged engine")
+    _no_eva(model, "the paged engine")
     shape = (
         model.kv_heads,
         model.n_layers * paged.num_pages,

@@ -29,7 +29,13 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from ..models import model_family
 from ..models.transformer import TransformerConfig, lm_head_weights
 from ..ops import cross_entropy_loss, losses
-from ..ops.losses import auto_loss_chunk, fused_linear_cross_entropy
+from ..ops.losses import (
+    auto_loss_chunk,
+    fused_linear_cross_entropy,
+    fused_multihead_cross_entropy,
+    multihead_cross_entropy,
+    multihead_targets,
+)
 from ..parallel.mesh import DATA_AXES
 from ..parallel.sharding import LogicalRules, default_rules, tree_specs
 
@@ -283,11 +289,16 @@ def lm_loss(
     masked out. `remat_saved`: what a model with `config.remat` keeps of
     each block. Returns (objective, the step's scalars: `loss` = the
     next-token cross entropy alone, so that every model's losses mean the
-    same, `num_tokens`, the routers', and `mtp_loss`)."""
+    same, `num_tokens`, the routers', and `mtp_loss`).
+
+    A model of several next-token heads (`config.pred_heads` > 1: the EvaByte
+    family) has another objective (`_multihead_lm_loss`)."""
     targets = tokens[:, 1:]
     family = model_family(config)
     hidden, routers = family.forward_hidden(
         params, tokens[:, :-1], config, remat_saved=remat_saved)
+    if config.pred_heads > 1:
+        return _multihead_lm_loss(params, tokens, hidden, routers, config, chunk, z_loss_coeff)
 
     def head_loss(hidden, targets, mask=None):
         with jax.named_scope("head"):
@@ -313,6 +324,35 @@ def lm_loss(
     if "router_aux_loss" in routers:
         objective = objective + config.router_aux_coeff * routers["router_aux_loss"]
     return objective, {"loss": loss, "num_tokens": ntok, **scalars, **routers}
+
+
+def _multihead_lm_loss(params, tokens, hidden, routers, config, chunk, z_loss_coeff):
+    """`lm_loss` of a model whose ONE head matrix (E, pred_heads x V) is
+    `pred_heads` next-token heads, head n scoring position t against token
+    t + 1 + n: the mean over the heads of each head's mean cross entropy over
+    the positions that have its target, under the scope `head.multibyte`
+    inside `head`, with float32 logits. Scalars: `loss` = the objective (what
+    is trained, and compared with the reference's), `loss_next_byte` = head
+    0's, the number comparable with every other model's loss, and
+    `loss_last_head`."""
+    if z_loss_coeff or getattr(config, "mtp_modules", 0) or "router_aux_loss" in routers:
+        raise NotImplementedError("several next-token heads: no z-loss, no multi-token "
+                                  "prediction module and no router's loss beside them")
+    heads = config.pred_heads
+    with jax.named_scope("head"), jax.named_scope("head.multibyte"):
+        targets, has_target = multihead_targets(tokens, heads)
+        head = lm_head_weights(params, config)
+        if chunk:
+            objective, per_head = fused_multihead_cross_entropy(
+                hidden, head, targets, has_target, chunk=chunk)
+        else:
+            per_head = multihead_cross_entropy(
+                jnp.einsum("bse,ev->bsv", hidden, head, preferred_element_type=jnp.float32),
+                targets, has_target)
+            objective = jnp.mean(per_head)
+    ntok = jnp.asarray(targets.shape[0] * targets.shape[1], jnp.float32)
+    return objective, {"loss": objective, "num_tokens": ntok, "loss_next_byte": per_head[0],
+                       "loss_last_head": per_head[-1], **routers}
 
 
 def make_train_step(
@@ -384,20 +424,24 @@ def make_train_step(
             return decided[shape]
         batch, seq = device_batch(shape), shape[1] - 1
         rows, itemsize = batch * seq, jnp.dtype(config.dtype).itemsize
+        # a block's input is a row of the residual stream, which a family may
+        # carry in another dtype than it computes in
+        stream_bytes = config.d_model * jnp.dtype(config.stream_dtype).itemsize
         resident = device_bytes(state, state_shardings)
         # the gradients and, where they are summed over microbatches, their accumulator
         gradients = device_bytes(state.params, state_shardings.params) * (2 if grad_accum > 1 else 1)
         costs = family.block_costs(
             config, seq, lambda weight: model_split(weight, slice(2, None))
         ) if family.block_costs else None
-        vocab = config.vocab_size // (
+        # the logits a position: every next-token head's
+        vocab = config.vocab_size * config.pred_heads // (
             model_split("lm_head", slice(1, None)) if "lm_head" in state_shardings.params
             else model_split("wte", slice(0, 1)))
         # what the blocks hold when the head runs: their inputs where they are
         # recomputed (what is kept beside those is decided after the head),
         # else every activation they write
         activations = sum(
-            run["layers"] * rows * (config.d_model if config.remat else run["width"]) * itemsize
+            run["layers"] * rows * (stream_bytes if config.remat else run["width"] * itemsize)
             for run in costs["runs"]) if costs else 0
         chunk = loss_chunk
         if chunk is None:
@@ -415,7 +459,7 @@ def make_train_step(
                 "scanned": run["scanned"], "period": run["period"], "layers": run["layers"],
                 "gradients": device_bytes(under(state.params, run["params"]),
                                           under(state_shardings.params, run["params"])),
-                "inputs": run["layers"] * rows * config.d_model * itemsize,
+                "inputs": run["layers"] * rows * stream_bytes,
                 "block": _REMAT_BLOCK_COPIES * rows * run["width"] * itemsize,
             } for run in costs["runs"])
             always = resident + gradients - sum(run["gradients"] for run in runs)
@@ -529,7 +573,9 @@ def make_eval_step(config: TransformerConfig, mesh: Mesh, state_shardings: Any):
         tokens = batch["tokens"]
         with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):  # see make_train_step
             hidden, _ = family.forward_hidden(state.params, tokens[:, :-1], config)
-            logits = jnp.einsum("bse,ev->bsv", hidden, lm_head_weights(state.params, config))
+            # of several next-token heads the first: the next token's
+            head = lm_head_weights(state.params, config)[:, :config.vocab_size]
+            logits = jnp.einsum("bse,ev->bsv", hidden, head)
         loss, ntok = cross_entropy_loss(logits, tokens[:, 1:])
         return {"eval_loss": loss.astype(jnp.float32), "num_tokens": ntok}
 

@@ -682,11 +682,12 @@ STEP_SCOPES: Tuple[str, ...] = (
     "steplog.fwd_bwd_compute", "steplog.optimizer_update",
     "embed",
     "attn.full", "attn.window", "attn.proj", "attn.latent", "attn.kernel", "attn.out",
+    "attn.eva", "attn.eva.pool", "attn.eva.local", "attn.eva.far", "attn.eva.merge",
     "ssm", "ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.gate_norm", "ssm.out_proj",
     "mlp",
     "moe", "moe.route", "moe.select", "moe.dispatch", "moe.experts", "moe.combine", "moe.passes",
     "moe.shared",
-    "head",
+    "head", "head.multibyte",
     "mtp",
 )
 # the forward and backward pass as a whole: a phase, which places an

@@ -414,3 +414,27 @@ def test_the_lowered_mixer_holds_no_float32_copy_of_the_group_view(as_tpu):
     entry = text[text.index("\nENTRY "):]
     assert not re.search(r"= bf16\[2,8192,4096\]\S* (slice|copy)\(%fusion", entry)     # z is not cut out of the projection
     assert not re.search(r"= bf16\[(2,8192|16384),10304\]\S* pad\(", entry)        # nor its cotangent padded to the width
+
+
+def test_eva_attention_kernels_compile_at_the_evabyte_cells_shard(as_tpu):
+    """train-evabyte-fsdp4-32k, one chip's sequence: 1 x 32 x 32,768 x 128,
+    window 2,048, chunk 16. The forward is the causal flash kernel over 512
+    windows-as-heads and `eva_far_fwd`; differentiated, also `flash_bwd_dkv_dq`
+    with the merged output and lse and `eva_far_bwd` (a head's 2,048 summaries
+    resident, their float32 cotangents summed over the 16 windows in place)."""
+    from ray_tpu.ops import eva
+
+    qkv = _on(as_tpu, (1, 32, 32768, 128))
+    vector = _on(as_tpu, (32, 128), jnp.float32)
+
+    def attend(q, k, v, mu, phi):
+        return eva.eva_attention(q, k, v, mu, phi, window=2048, chunk=16)
+
+    forward = jax.jit(attend).lower(qkv, qkv, qkv, vector, vector).compile()
+    assert _kernel_calls(forward) == 2
+    grad = jax.jit(jax.grad(lambda *a: attend(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2, 3, 4))).lower(
+        qkv, qkv, qkv, vector, vector).compile()
+    assert _kernel_calls(grad) == 4
+    for kernel in ("flash_fwd", "flash_bwd_dkv_dq", "eva_far_fwd", "eva_far_bwd"):
+        assert len(re.findall(rf"^\s*%{kernel}[.\d]* = .*custom-call\(", grad.as_text(), re.M)) == 1, kernel
+    assert eva.eva_plan(32768, window=2048, chunk=16, head_dim=128)["eva_impl"] == "pallas"

@@ -75,3 +75,46 @@ def test_nemotron3nano_cell_step_runs_the_scan_under_its_scopes_and_compiles(as_
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes / GIB == pytest.approx(7.45, abs=0.02)
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes) / GIB < 15.75
+
+
+def test_evabyte_cell_step_runs_the_kernels_once_a_shard_and_compiles(as_tpu, monkeypatch, v5e):
+    """`train-evabyte-fsdp4-32k`'s whole step (4 x 32,769 bytes under fsdp=4,
+    one sequence a chip) for the described v5e:2x2 at 15.75 GiB a chip: the
+    scanned block runs the causal flash kernels over windows and the two far
+    kernels inside one shard_map a call, forward, recomputed and backward,
+    under `attn.eva`; the eight-head loss is the fused one, the whole
+    sequence a chunk, under `head.multibyte`; and the step fits a chip."""
+    from ray_tpu.ops import losses
+    from ray_tpu.train.lm import make_train_step
+    from ray_tpu.util import profiling
+
+    mesh = build_mesh(MeshSpec(fsdp=4), devices=v5e.devices)
+    config, opt, state, shardings, tokens = _cell_step_shapes("evabyte-6.5b-train-4chip", mesh, (4, 32769))
+    monkeypatch.setattr(losses, "device_hbm_bytes", lambda: int(15.75 * GIB))
+    step = make_train_step(config, opt, mesh, state_shardings=shardings)
+    plan = step.remat_plan_for(tokens.shape, state)
+    assert plan["remat"] in ("whole_block", "selective")
+    assert set(plan["remat_saved"]) <= {"attn_out", "attn_lse", "attn_residual", "mlp_up", "mlp_gate"}
+    assert step.loss_chunk_for(tokens.shape, state) == 32768
+    compiled = step.lower(state, {"tokens": tokens}).compile()
+    kept = "attn_lse" in plan["remat_saved"]
+    assert _kernels_named(compiled, "flash_bwd_dkv_dq") == 1 and _kernels_named(compiled, "eva_far_bwd") == 1
+    assert _kernels_named(compiled, "flash_fwd") == _kernels_named(compiled, "eva_far_fwd") == (1 if kept else 2)
+    assert "flash_win" not in compiled.as_text()
+    _, table = profiling.program_ops_table(profiling._module_text(compiled))
+    for kernel, scope in (("flash_fwd", "attn.eva.local"), ("eva_far_fwd", "attn.eva.far"),
+                          ("flash_bwd_dkv_dq", "attn.eva.local"), ("eva_far_bwd", "attn.eva.far")):
+        for scopes, found, _ in table[kernel]:
+            assert {"attn.full", "attn.kernel", "attn.eva", scope} <= set(scopes), (kernel, scopes)
+    assert {found for _, found, _ in table["eva_far_bwd"]} == {"bwd"}
+    pairs = {(scope, pass_) for instances in table.values() for scopes, pass_, _ in instances for scope in scopes}
+    assert {("attn.eva.pool", "fwd"), ("attn.eva.pool", "bwd"), ("head.multibyte", "fwd"), ("mlp", "fwd"),
+            ("attn.proj", "fwd"), ("attn.out", "fwd")} <= pairs
+    # 12 bytes a parameter of state over four chips (the gradients are the step's own). This compiler
+    # counts 15.4 GiB of temporaries beside them, 17.7 GiB a chip, for the step that ran on the chips
+    # at a peak of 14,633,370,624 B = 13.63 GiB (my chip run, PR 51: size from the chip, not from the
+    # rehearsal, as PR 30 found on Mistral's step): the band guards the program, not the fit
+    memory = compiled.memory_analysis()
+    parameters = sum(x.size for x in jax.tree.leaves(state.params))
+    assert memory.argument_size_in_bytes / GIB == pytest.approx(12 * parameters / 4 / GIB, abs=0.05)
+    assert memory.temp_size_in_bytes / GIB == pytest.approx(15.4, abs=0.8)
