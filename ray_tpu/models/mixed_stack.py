@@ -431,12 +431,13 @@ def logical_axes(config: MixedStackConfig) -> Params:
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
 def _gate_xbc_dt(projected, inner: int, conv: int):
     """[z | xBC | dt], the in-projection's output -> (the WHOLE array for the
-    gate, xBC, dt). `ssd.gated_group_norm` reads z at the first `inner`
-    features of the whole: a slice in front of a kernel is a copy, and with
-    it XLA writes the projection transposed and copies xBC too (PERF.md
-    section 6, PR 50). The transpose joins the three cotangents in ONE
-    concatenate, as `jnp.split`'s does, whatever read the whole."""
-    return projected, projected[..., inner:inner + conv], projected[..., inner + conv:]
+    gate, the WHOLE array for the convolution, dt). `ssd.gated_group_norm`
+    reads z at the first `inner` features of the whole and
+    `ssd.causal_conv1d` xBC at the `conv` after them: a slice in front of a
+    kernel is a copy, and with it XLA writes the projection transposed
+    (PERF.md section 6, PR 50). The transpose joins the three cotangents in
+    ONE concatenate, as `jnp.split`'s does, whatever read either whole."""
+    return projected, projected, projected[..., inner + conv:]
 
 
 def _gate_xbc_dt_fwd(projected, inner, conv):
@@ -444,9 +445,12 @@ def _gate_xbc_dt_fwd(projected, inner, conv):
 
 
 def _gate_xbc_dt_bwd(inner, conv, _, cotangents):
-    whole, xbc, step = cotangents
-    return (jnp.concatenate([whole[..., :inner], whole[..., inner:inner + conv] + xbc,
-                             whole[..., inner + conv:] + step], axis=-1),)
+    # a slice of each whole's cotangent, not of their sum: a kernel's is a pad
+    # of its columns, and XLA reads a slice of a pad as the columns or as zeros
+    gate, xbc, step = cotangents
+    z, conv_in, rest = (gate[..., at] + xbc[..., at]
+                        for at in (slice(0, inner), slice(inner, inner + conv), slice(inner + conv, None)))
+    return (jnp.concatenate([z, conv_in, rest + step], axis=-1),)
 
 
 _gate_xbc_dt.defvjp(_gate_xbc_dt_fwd, _gate_xbc_dt_bwd)
@@ -455,7 +459,9 @@ _gate_xbc_dt.defvjp(_gate_xbc_dt_fwd, _gate_xbc_dt_bwd)
 def _ssm_sublayer(x, lp, config):
     """A Mamba-2 mixer + residual on (B, S, E), the scope `ssm`: [z | xBC |
     dt] = norm(x) W_in (`ssm.in_proj`); xBC through the causal depthwise
-    convolution and silu (`ssm.conv`); the step softplus(dt + bias) in float32
+    convolution and silu (`ssm.conv`, ops/ssd.causal_conv1d: on a TPU two
+    kernels that read xBC out of the projection as it is and write x, B and
+    C apart); the step softplus(dt + bias) in float32
     and the selective scan of the heads' x with their group's B and C
     (`ssm.scan`, ops/ssd.ssd_scan); y silu(z) through an RMS norm over each
     group's features (`ssm.gate_norm`, ops/ssd.gated_group_norm: the gate
@@ -474,12 +480,13 @@ def _ssm_sublayer(x, lp, config):
             projected = jnp.einsum("bse,ef->bsf", u, lp["ssm_in"].astype(dt))
             z, xbc, step = _gate_xbc_dt(projected, inner, c.ssm_conv_width)
         with jax.named_scope("ssm.conv"):
-            xbc = ssd.causal_conv1d(xbc, lp["ssm_conv_w"], lp["ssm_conv_b"])
+            # x, B and C as an output each: slices of one would be copies in front of the scan's kernels
+            xs, bs, cs = ssd.causal_conv1d(xbc, lp["ssm_conv_w"], lp["ssm_conv_b"], offset=inner,
+                                           splits=(inner, groups * n, groups * n))
         with jax.named_scope("ssm.scan"):
             step = jax.nn.softplus(step.astype(jnp.float32) + lp["ssm_dt_bias"].astype(jnp.float32))
-            bc = xbc[..., inner:].reshape(b, s, 2, groups, n)
-            y = ssd.ssd_scan(xbc[..., :inner].reshape(b, s, heads, p), step, lp["ssm_a_log"],
-                             bc[:, :, 0], bc[:, :, 1], lp["ssm_d"], chunk=c.ssm_chunk)
+            y = ssd.ssd_scan(xs.reshape(b, s, heads, p), step, lp["ssm_a_log"], bs.reshape(b, s, groups, n),
+                             cs.reshape(b, s, groups, n), lp["ssm_d"], chunk=c.ssm_chunk)
             decay_min = jax.lax.stop_gradient(
                 ssd.log_decay_chunk_min(step, lp["ssm_a_log"], c.ssm_chunk))
         with jax.named_scope("ssm.gate_norm"):
@@ -762,11 +769,14 @@ def plan(config: MixedStackConfig, batch: int, seq: int) -> Dict[str, Any]:
     kinds = layer_kinds(c)
     out = {"layer_kinds": " ".join(kind.code for kind in kinds)}
     if any(kind.attention == "ssm" for kind in kinds):
+        inner, group_states = c.ssm_heads * c.ssm_head_dim, c.ssm_groups * c.ssm_state
         out.update(ssm_heads=c.ssm_heads, ssm_head_dim=c.ssm_head_dim, ssm_state=c.ssm_state,
                    ssm_groups=c.ssm_groups, ssm_conv_kernel=c.ssm_conv_kernel,
                    **ssd.scan_plan(seq, c.ssm_chunk, heads=c.ssm_heads, groups=c.ssm_groups,
                                    head_dim=c.ssm_head_dim, state=c.ssm_state),
-                   **ssd.gate_norm_plan(batch * seq, c.ssm_heads * c.ssm_head_dim, c.ssm_groups))
+                   **ssd.gate_norm_plan(batch * seq, inner, c.ssm_groups),
+                   **ssd.conv_plan(seq, c.ssm_conv_width, c.ssm_conv_kernel, inner,
+                                   (inner, group_states, group_states)))
     if c.latent_attention:
         out.update(attn_latent_q_rank=c.q_lora_rank, attn_latent_kv_rank=c.kv_lora_rank,
                    attn_rope_dims=c.rotary_dims, attn_head_dim=c.head_dim)

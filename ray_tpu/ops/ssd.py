@@ -1,6 +1,7 @@
-"""The state-space mixer's core (Mamba-2): a causal depthwise convolution,
-the selective scan in its chunked, state-space-duality form, and the gated
-norm over groups of features behind it (`gated_group_norm`, at the end).
+"""The state-space mixer's core (Mamba-2): a causal depthwise convolution
+(`causal_conv1d`), the selective scan in its chunked, state-space-duality
+form (`ssd_scan`), and the gated norm over groups of features behind it
+(`gated_group_norm`, at the end).
 
 The recurrence, a head h of P features with a state of P x N, reading group
 g = h // (H / G) of the G groups that B and C come in:
@@ -28,7 +29,7 @@ dtype x comes in and accumulate in float32.
 Two forms compute it, chosen by a static rule on the backend, the shapes and
 the context mesh (`resolve_scan_impl`; `scan_plan` reports it; no flag, and a
 form is never tried and swapped for the other; `_resolve` is the one rule,
-the gated norm's too):
+the gated norm's and the convolution's too):
 
 - "pallas", on a TPU at the sizes the kernels tile: two Mosaic kernels under
   one `custom_vjp`, `ssd_fwd` and `ssd_bwd`, every call named so that a device
@@ -66,11 +67,30 @@ the same two forms by the same rule (`resolve_gate_norm_impl`,
 `ssm_gate_norm_fwd` and `ssm_gate_norm_bwd`, a grid step a tile of rows with
 every group a static slice of whole lane tiles; "xla", ops/layers.rmsnorm on
 the view by groups. Its section says why.
+
+So has the convolution in front of the scan, with its bias and silu
+(`causal_conv1d`; `resolve_conv_impl`, `conv_plan`): "pallas", two kernels
+under one `custom_vjp`, `ssm_conv_fwd` and `ssm_conv_bwd`, a grid step a tile
+of rows of ONE sequence with every channel, read out of the in-projection's
+output as it is at a column offset and written as x, B and C, an output each;
+"xla", K shifted slices of a padded array. Its section says how.
+
+What the three pairs of kernels tile, and nothing else (other sizes run the
+XLA forms, by the rule; a kernel asked for by name there is refused by name):
+the scan a chunk of 128, heads of 64 or 128 features, a group's heads and the
+state whole 128-lane tiles, the group's float32 state within 1 MiB; the norm
+groups of whole lane tiles and B S rows with a divisor that is a multiple of
+16; the convolution at most 8,192 channels, they, their column offset and
+every output whole lane tiles, a sequence with a divisor that is a multiple
+of 16, and 2 to 17 taps (a tap reaches one strip of 16 rows back). All of
+them one device, or a `shard_map` around them: under a context mesh of several
+devices that nothing made manual the rule keeps the XLA form.
 """
 
 from __future__ import annotations
 
 import functools
+import itertools
 from typing import Optional, Tuple
 
 import jax
@@ -78,6 +98,8 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.extend.core import Primitive
+from jax.interpreters import mlir
 
 from .layers import rmsnorm
 
@@ -90,16 +112,38 @@ _IMPLEMENTATIONS = ("xla_chunked", "pallas")
 BLOCK_CHUNKS = 8
 
 
-def causal_conv1d(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
-    """silu(b[c] + sum_j w[c, j] x_{t - K + 1 + j}[c]) on x (B, S, C) with w
-    (C, K), b (C,): a causal depthwise convolution with bias, zeros before
-    the sequence. K shifted multiply-adds in float32, one fused pass."""
-    k = w.shape[-1]
+def causal_conv1d(x: jax.Array, w: jax.Array, b: jax.Array, *, offset: int = 0,
+                  splits: Optional[Tuple[int, ...]] = None, implementation: Optional[str] = None):
+    """silu(b[c] + sum_j w[c, j] x_{t - K + 1 + j}[offset + c]) on x (B, S, C
+    or WIDER: the convolution reads the C features from `offset` of the array
+    as it is, where a slice handed to the kernels would be a copy) with w (C,
+    K), b (C,) -> (B, S, C) in x's dtype: a causal depthwise convolution with
+    bias, zeros before the sequence. With `splits`, widths that add up to C,
+    a tuple of arrays of those widths instead (the kernels write each as an
+    output of its own and take each one's cotangent, where slices of one
+    output are copies on both passes). Float32 inside, differentiable in x, w
+    and b. `implementation` is `resolve_conv_impl`'s, for tests: with nothing
+    asked, the kernels of the section "the convolution" on a TPU at the sizes
+    they tile and, elsewhere, K shifted multiply-adds of a padded array that
+    JAX differentiates."""
+    channels, k = w.shape
     s = x.shape[1]
+    widths = (channels,) if splits is None else tuple(splits)
+    if sum(widths) != channels:
+        raise ValueError(f"causal_conv1d: splits {widths} do not add up to the {channels} channels")
+    impl = resolve_conv_impl(implementation, seq=s, channels=channels, taps=k, offset=offset, splits=widths)
+    if impl == "pallas":
+        out = _conv_kernels(x, w.astype(F32).T, b.astype(F32).reshape(1, channels),
+                            (offset, _conv_rows(s), widths), jax.default_backend() != "tpu")
+        return out[0] if splits is None else out
+    x = x[..., offset:offset + channels]
     padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
     w = w.astype(F32)
     out = b.astype(F32) + sum(w[:, j] * padded[:, j:j + s].astype(F32) for j in range(k))
-    return jax.nn.silu(out).astype(x.dtype)
+    out = jax.nn.silu(out).astype(x.dtype)
+    if splits is None:
+        return out
+    return tuple(out[..., end - width:end] for width, end in zip(widths, itertools.accumulate(widths)))
 
 
 # The kernels' tiles: a chunk is the side of the (chunk, chunk) decays a grid
@@ -120,6 +164,11 @@ def _kernels_tile(chunk: int, heads: int, groups: int, head_dim: int, state: int
     return (chunk == _KERNEL_CHUNK and head_dim in (64, 128)
             and (per_group * head_dim) % _LANES == 0 and state % _LANES == 0
             and per_group * head_dim * state * 4 <= _KERNEL_STATE_BYTES)
+
+
+def _largest_tile(rows: int, most: int, strip: int) -> int:
+    """The largest divisor of `rows` that is a multiple of `strip` and at most `most` (0: none)."""
+    return next((n for n in range(min(most, rows) // strip * strip, 0, -strip) if rows % n == 0), 0)
 
 
 def _resolve(implementation: Optional[str], forms: Tuple[str, str], tiles: bool, what: str,
@@ -590,6 +639,320 @@ def log_decay_chunk_min(dt: jax.Array, a_log: jax.Array, chunk: int) -> jax.Arra
     return jnp.min(jnp.sum(da.reshape(bsz, s // chunk, chunk, h), axis=2))
 
 
+# ---------------------------------------------------------- the convolution
+#
+# `ssm_conv_fwd` and `ssm_conv_bwd`: the causal depthwise convolution, its
+# bias and silu on the (B, S, F) array as it is, read at a column offset. A
+# grid step is a tile of rows of ONE sequence with every channel, x in blocks
+# of whole 128-lane tiles that the offset is a multiple of (the same array
+# under one block map a block); the rows before the tile (and, backward, after
+# it) come as further blocks of the same array one strip of `_CONV_STRIP` rows
+# back (on): zeros at a sequence's first (last) tile, never the rows beside
+# them in memory. x, B and C leave as an output each (`splits`). A tap's shift
+# is a roll over the sublanes of a strip's float32 registers with the first
+# rows taken from the strip before, which every strip leaves in VMEM scratch
+# for the next: nothing is padded in HBM. The backward keeps nothing of its
+# own: it builds the pre-activation again from x, d pre = dy silu'(pre), dx[t]
+# = sum_j w[:, j] d pre[t + K - 1 - j], and sums dw and db over the grid in
+# resident blocks.
+
+_CONV_IMPLEMENTATIONS = ("xla", "pallas")
+# The most rows of a sequence a grid step takes, the rows computed at once (a
+# bfloat16 tile's 16 sublanes; a tap reaches at most one strip back) and their
+# float32 sublanes a register, the widest block of columns, the most channels
+# (a step takes them all) and the most lanes computed at once. On the chip at
+# the `train-nemotron3nano-8k` cell's shapes, forward / backward ms a call
+# (PERF.md section 6, PR 52; the bytes alone 0.68 / 0.95, the XLA form 1.28 /
+# 5.99): 256 rows and 512 lanes 0.71 / 1.24 (0.71 / 1.33 with the ONE loop
+# body a kernel that ships, below); 512 rows 0.70 / 1.23; 128 rows
+# 0.83 / 1.35; column tiles of 2,048 a step 0.76 / 1.27; 256 and 128 lanes
+# within 0.1 ms at 1.6 and 2.7 times the bodies; 1,024 lanes 0.73 / 1.38. The
+# lane chunks are a static loop INSIDE the loop over strips: outside it, a
+# chunk's strips in a loop of their own that carried the strip before in
+# registers, the same work took 0.90 / 1.71 (1.85 / 2.60 at 128 lanes): a
+# strip's chain of rolls and the sigmoid is long, Mosaic does not overlap a
+# loop's iterations, and only other chunks' work fills it. What a strip hands
+# the next (its rows, its d pre) goes through VMEM scratch, so the loop has
+# ONE body: the bodies' equations are what set-up pays (`ssm_conv_p` below).
+_CONV_ROWS = 256
+_CONV_STRIP = 16
+_CONV_HALF = 8
+_CONV_COLUMNS = 2048
+_CONV_CHANNELS = 8192
+_CONV_LANES = 512
+
+
+def _conv_rows(seq: int) -> int:
+    """The rows of a sequence a grid step of the convolution's kernels takes:
+    the largest divisor of `seq` that is a multiple of `_CONV_STRIP` and at
+    most `_CONV_ROWS` (0: the sequence does not tile)."""
+    return _largest_tile(seq, _CONV_ROWS, _CONV_STRIP)
+
+
+def _conv_columns(most: int, *widths: int) -> int:
+    """The most columns, whole lane tiles and at most `most`, that every one
+    of `widths` (multiples of a lane tile, or 0) is a multiple of."""
+    return next(n for n in range(most // _LANES * _LANES, 0, -_LANES) if all(width % n == 0 for width in widths))
+
+
+def resolve_conv_impl(implementation: Optional[str] = None, *, seq: int, channels: int, taps: int,
+                      offset: int = 0, splits: Optional[Tuple[int, ...]] = None) -> str:
+    """The implementation `causal_conv1d` runs on sequences of `seq`, by the
+    scan's rule (`_resolve`): "pallas" (the kernels `ssm_conv_fwd` /
+    `ssm_conv_bwd`) on a TPU where the channels, at most `_CONV_CHANNELS`, and
+    every one of the `splits` are whole numbers of 128-lane tiles from a
+    lane-aligned offset, the sequence tiles (`_conv_rows`) and a tap reaches
+    at most one strip back, "xla" (shifted slices of a padded array)
+    elsewhere."""
+    tiles = (all(width % _LANES == 0 for width in (channels, offset, *(splits or ())))
+             and 0 < channels <= _CONV_CHANNELS and _conv_rows(seq) > 0 and 1 < taps <= _CONV_STRIP + 1)
+    cut = f" in {tuple(splits)}" if splits and len(splits) > 1 else ""
+    return _resolve(implementation, _CONV_IMPLEMENTATIONS, tiles, "convolution",
+                    f"causal_conv1d: the kernels do not tile sequences of {seq} with {channels} channels{cut} "
+                    f"at column {offset} under {taps} taps")
+
+
+def conv_plan(seq: int, channels: int, taps: int, offset: int = 0, splits: Optional[Tuple[int, ...]] = None) -> dict:
+    """What `causal_conv1d` resolves to, for callers that report it: the
+    implementation's name and the rows of a sequence a grid step of the
+    kernels takes (0 for the XLA form)."""
+    impl = resolve_conv_impl(seq=seq, channels=channels, taps=taps, offset=offset, splits=splits)
+    return {"ssm_conv_impl": impl, "ssm_conv_rows": _conv_rows(seq) if impl == "pallas" else 0}
+
+
+def _later(before, piece, d: int, row):
+    """An (8, lanes) piece's rows d later: [t] = piece[t - d], the first d the last of the piece before."""
+    return pltpu.roll(jnp.where(row >= _CONV_HALF - d, before, piece), d, 0)
+
+
+def _sooner(piece, after, d: int, row):
+    """An (8, lanes) piece's rows d sooner: [t] = piece[t + d], the last d the first of the piece after."""
+    return pltpu.roll(jnp.where(row < d, after, piece), _CONV_HALF - d, 0)
+
+
+def _halves(strip):
+    """A strip's float32 values as its two (8, lanes) pieces, a register a 128 lanes each."""
+    return strip[:_CONV_HALF], strip[_CONV_HALF:]
+
+
+def _lane_chunks(columns: int, splits: Tuple[int, ...], most: int):
+    """Chunks of at most `most` lanes, each inside one block of `columns` and
+    one of the `splits`: (the block, its lanes, the same lanes of all
+    channels, the split, its lanes)."""
+    lanes = _conv_columns(most, columns, *splits)
+    ends = list(itertools.accumulate(splits))
+    chunks = []
+    for at in range(0, ends[-1], lanes):
+        part = next(k for k, end in enumerate(ends) if at < end)
+        inside = at - (ends[part] - splits[part])
+        chunks.append((at // columns, slice(at % columns, at % columns + lanes), slice(at, at + lanes),
+                       part, slice(inside, inside + lanes)))
+    return chunks
+
+
+def _strip_at(i):
+    return pl.ds(pl.multiple_of(i * _CONV_STRIP, _CONV_STRIP), _CONV_STRIP)
+
+
+def _taps_row(w_ref, dst):
+    """The taps' weights on a chunk's lanes by d, the rows a tap reaches back: w[taps - 1 - d]."""
+    taps = w_ref.shape[0]
+    return [w_ref[taps - 1 - d:taps - d, dst] for d in range(taps)]
+
+
+def _pre_activation(before, strip, weights, bias):
+    """(bias + sum_d weights[d] (the strip d rows later), those shifted strips
+    by d) for a strip (16, lanes) and the strip `before` it, of which the last
+    rows are read."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (_CONV_HALF, strip.shape[1]), 0)
+    strip = strip.astype(F32)
+    (_, last), (low, high) = _halves(before.astype(F32)), _halves(strip)
+    shifted = [strip] + [jnp.concatenate([_later(last, low, d, row), _later(low, high, d, row)], axis=0)
+                         for d in range(1, len(weights))]
+    return bias + sum(weight * of for weight, of in zip(weights, shifted)), shifted
+
+
+def _start_tile(before_refs, before_scr, columns: int):
+    """The strip before a tile's first into `before_scr`, where every strip
+    leaves itself for the next: zeros at a sequence's first tile."""
+    first = pl.program_id(1) == 0
+    for v, before_ref in enumerate(before_refs):
+        before = before_ref[0].astype(F32)
+        before_scr[:, v * columns:(v + 1) * columns] = jnp.where(first, 0.0, before).astype(before_scr.dtype)
+
+
+def _conv_fwd_kernel(*refs, blocks: int, splits: Tuple[int, ...]):
+    x_refs, before_refs = refs[:blocks], refs[blocks:2 * blocks]
+    w_ref, b_ref, *out_refs, before_scr = refs[2 * blocks:]
+    rows, columns = x_refs[0].shape[1:]
+    _start_tile(before_refs, before_scr, columns)
+
+    def step(i, carry):
+        at = _strip_at(i)
+        for v, src, dst, part, lanes in _lane_chunks(columns, splits, _CONV_LANES):
+            strip = x_refs[v][0, at, src]
+            pre, _ = _pre_activation(before_scr[:, dst], strip, _taps_row(w_ref, dst), b_ref[:, dst])
+            before_scr[:, dst] = strip
+            out_refs[part][0, at, lanes] = (pre * jax.nn.sigmoid(pre)).astype(out_refs[part].dtype)
+        return carry
+
+    jax.lax.fori_loop(0, rows // _CONV_STRIP, step, 0)
+
+
+def _conv_bwd_kernel(*refs, blocks: int, splits: Tuple[int, ...]):
+    """The transpose of `_conv_fwd_kernel`'s tile. A strip's d pre reaches the
+    dx of the strip before it: d pre is left in `d_scr` for the next strip,
+    which writes that dx (the first strip writes one over what `d_scr` held,
+    and the second writes it again), and the last strip's dx waits for the d
+    pre of the rows AFTER the tile (zeros at a sequence's last tile), which
+    are not summed into dw and db: the next step's are. dw and db are summed
+    over a tile's strips in `sums_scr`, 8 rows a tap, and over the grid in
+    their resident blocks."""
+    x_refs, before_refs, after_refs = refs[:blocks], refs[blocks:2 * blocks], refs[2 * blocks:3 * blocks]
+    w_ref, b_ref, *rest = refs[3 * blocks:]
+    dy_refs, dy_after_refs = rest[:len(splits)], rest[len(splits):2 * len(splits)]
+    dx_ref, dw_ref, db_ref, before_scr, d_scr, sums_scr = rest[2 * len(splits):]
+    taps, (rows, columns) = w_ref.shape[0], x_refs[0].shape[1:]
+
+    @pl.when((pl.program_id(0) == 0) & (pl.program_id(1) == 0))
+    def _():
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+        db_ref[...] = jnp.zeros_like(db_ref)
+
+    _start_tile(before_refs, before_scr, columns)
+    sums_scr[...] = jnp.zeros_like(sums_scr)
+
+    def d_pre(before, strip, dy, weights, dst):
+        pre, shifted = _pre_activation(before, strip, weights, b_ref[:, dst])
+        gate = jax.nn.sigmoid(pre)
+        return dy.astype(F32) * (gate * (1.0 + pre * (1.0 - gate))), shifted
+
+    def write_dx(at, d_after, weights, dst):
+        """dx of the strip at `at`, whose d pre `d_scr` holds, with the d pre of the strip after it."""
+        row = jax.lax.broadcasted_iota(jnp.int32, (_CONV_HALF, d_after.shape[1]), 0)
+        d_strip = d_scr[:, dst]
+        (low, high), (after, _) = _halves(d_strip), _halves(d_after)
+        sooner = [d_strip] + [jnp.concatenate([_sooner(low, high, d, row), _sooner(high, after, d, row)], axis=0)
+                              for d in range(1, taps)]
+        dx_ref[0, at, dst] = sum(weight * of for weight, of in zip(weights, sooner)).astype(dx_ref.dtype)
+
+    def step(i, carry):
+        at = _strip_at(i)
+        for v, src, dst, part, lanes in _lane_chunks(columns, splits, _CONV_LANES):
+            weights, strip = _taps_row(w_ref, dst), x_refs[v][0, at, src]
+            d_strip, shifted = d_pre(before_scr[:, dst], strip, dy_refs[part][0, at, lanes], weights, dst)
+            before_scr[:, dst] = strip
+            write_dx(_strip_at(jnp.maximum(i - 1, 0)), d_strip, weights, dst)
+            d_scr[:, dst] = d_strip
+            for d, of in enumerate([*shifted, None]):       # the taps' sums, then the bias's
+                low, high = _halves(d_strip if of is None else d_strip * of)
+                sums_scr[d * _CONV_HALF:(d + 1) * _CONV_HALF, dst] += low + high
+        return carry
+
+    jax.lax.fori_loop(0, rows // _CONV_STRIP, step, 0)
+    # once a tile, on lanes as wide as a block and a split allow: the last strip's dx
+    last = pl.program_id(1) == pl.num_programs(1) - 1
+    for v, src, dst, part, lanes in _lane_chunks(columns, splits, _CONV_COLUMNS):
+        weights = _taps_row(w_ref, dst)
+        d_after, _ = d_pre(before_scr[:, dst], after_refs[v][0, :, src], dy_after_refs[part][0, :, lanes], weights, dst)
+        write_dx(pl.ds(rows - _CONV_STRIP, _CONV_STRIP), jnp.where(last, 0.0, d_after), weights, dst)
+    for d in range(taps):
+        dw_ref[taps - 1 - d:taps - d] += jnp.sum(sums_scr[d * _CONV_HALF:(d + 1) * _CONV_HALF], axis=0, keepdims=True)
+    db_ref[...] += jnp.sum(sums_scr[taps * _CONV_HALF:], axis=0, keepdims=True)
+
+
+def _conv_call(x, w, b, *dys, sizes, interpret: bool):
+    """`ssm_conv_fwd` (no `dys`) -> the outputs, (B, S, a split) each, or
+    `ssm_conv_bwd` -> [dx (B, S, C), dw (K, C) float32, db (1, C) float32]
+    from the outputs' cotangents, on x (B, S, F) read at the columns
+    `offset`..`offset` + C, w (K, C) and b (1, C) float32. The grid is
+    (sequence, tile of rows), every channel a step: x comes as C / columns
+    blocks of the one array, and dw's and db's blocks stay in VMEM over the
+    grid."""
+    offset, step_rows, splits = sizes
+    bsz, s, _ = x.shape
+    taps, channels = w.shape
+    backward = bool(dys)
+    columns = _conv_columns(_CONV_COLUMNS, channels, offset)
+    blocks = channels // columns
+    strips, last_strip = step_rows // _CONV_STRIP, s // _CONV_STRIP - 1
+    tile = lambda v: (lambda b, i: (b, i, v))                                                   # noqa: E731
+    before = lambda v: (lambda b, i: (b, jnp.maximum(i * strips - 1, 0), v))                    # noqa: E731
+    after = lambda v: (lambda b, i: (b, jnp.minimum((i + 1) * strips, last_strip), v))          # noqa: E731
+
+    def of_x(rows, index):          # the blocks of x's columns offset..offset + C
+        return [pl.BlockSpec((1, rows, columns), index(offset // columns + v)) for v in range(blocks)]
+
+    def of_parts(rows, index):      # an array a split, each all its columns
+        return [pl.BlockSpec((1, rows, width), index(0)) for width in splits]
+
+    whole = [pl.BlockSpec((taps, channels), lambda b, i: (0, 0)), pl.BlockSpec((1, channels), lambda b, i: (0, 0))]
+    scratch = [pltpu.VMEM((_CONV_STRIP, channels), x.dtype)]
+    if backward:
+        scratch += [pltpu.VMEM((_CONV_STRIP, channels), F32), pltpu.VMEM(((taps + 1) * _CONV_HALF, channels), F32)]
+    # every tile of a step twice (the pipeline's two buffers) and room for the strips' values
+    vmem = 2 * (3 if backward else 2) * step_rows * channels * x.dtype.itemsize + 8 * 1024 * 1024
+    return pl.pallas_call(
+        functools.partial(_conv_bwd_kernel if backward else _conv_fwd_kernel, blocks=blocks, splits=splits),
+        grid=(bsz, s // step_rows),
+        in_specs=(of_x(step_rows, tile) + of_x(_CONV_STRIP, before)
+                  + (of_x(_CONV_STRIP, after) + whole + of_parts(step_rows, tile) + of_parts(_CONV_STRIP, after)
+                     if backward else whole)),
+        out_specs=([pl.BlockSpec((1, step_rows, channels), tile(0))] + whole if backward
+                   else of_parts(step_rows, tile)),
+        out_shape=([jax.ShapeDtypeStruct((bsz, s, channels), x.dtype), jax.ShapeDtypeStruct(w.shape, F32),
+                    jax.ShapeDtypeStruct(b.shape, F32)] if backward
+                   else [jax.ShapeDtypeStruct((bsz, s, width), x.dtype) for width in splits]),
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary" if backward else "parallel",) * 2,
+                                             vmem_limit_bytes=vmem),
+        interpret=interpret, name="ssm_conv_bwd" if backward else "ssm_conv_fwd",
+    )(*([x] * (3 if backward else 2) * blocks), w, b, *(dys * 2))
+
+
+def _conv_shapes(x, w, b, *dys, sizes, interpret):
+    del interpret
+    like = lambda shape, dtype: x.update(shape=shape, dtype=dtype, weak_type=False)     # noqa: E731
+    if dys:
+        return [like((*x.shape[:2], w.shape[1]), x.dtype), like(w.shape, F32), like(b.shape, F32)]
+    return [like((*x.shape[:2], width), x.dtype) for width in sizes[2]]
+
+
+# Every call site enters through ONE primitive whose lowering builds the
+# kernel and is emitted out of line, as ops/moe_rows_sum's is: a program traces
+# a kernel and lowers it to a Mosaic module once a signature and calls that one
+# function from every layer, pass and recomputation. Traced in line, the
+# bodies' ~2,000 equations were built again by every trace of the step (the
+# plan's, the checkpoint's, the transpose's): +27 s of set-up on the chip
+# (PERF.md section 6, PR 52).
+ssm_conv_p = Primitive("ssm_conv")
+ssm_conv_p.multiple_results = True
+ssm_conv_p.def_abstract_eval(_conv_shapes)
+ssm_conv_p.def_impl(lambda *args, **params: jax.jit(functools.partial(ssm_conv_p.bind, **params))(*args))
+mlir.register_lowering(ssm_conv_p, mlir.lower_fun(_conv_call, multiple_results=True), inline=False)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _conv_kernels(x, w, b, sizes, interpret):
+    """x (B, S, F), w (K, C) and b (1, C) float32 -> silu of the convolution
+    of x's columns offset..offset + C, cut into the splits: (B, S, a split)
+    each, in x's dtype; `sizes` = (offset, the rows a step, the splits)."""
+    return tuple(ssm_conv_p.bind(x, w, b, sizes=sizes, interpret=interpret))
+
+
+def _conv_kernels_fwd(x, w, b, sizes, interpret):
+    return _conv_kernels(x, w, b, sizes, interpret), (x, w, b)       # nothing kept but the arguments
+
+
+def _conv_kernels_bwd(sizes, interpret, kept, dys):
+    x, w, b = kept
+    dx, dw, db = ssm_conv_p.bind(x, w, b, *dys, sizes=sizes, interpret=interpret)
+    return jnp.pad(dx, ((0, 0), (0, 0), (sizes[0], x.shape[2] - sizes[0] - dx.shape[2]))), dw, db
+
+
+_conv_kernels.defvjp(_conv_kernels_fwd, _conv_kernels_bwd)
+
+
 # ----------------------------------------------------------- the gated norm
 #
 # `ssm_gate_norm_fwd` and `ssm_gate_norm_bwd`: y silu(z) through an RMS norm
@@ -618,8 +981,7 @@ def _norm_rows(rows: int) -> int:
     """The rows a grid step of the norm's kernels takes: the largest divisor
     of `rows` that is a multiple of `_NORM_STRIP` and at most `_NORM_ROWS`
     (0: the rows do not tile)."""
-    most = min(_NORM_ROWS, rows) // _NORM_STRIP * _NORM_STRIP
-    return next((n for n in range(most, 0, -_NORM_STRIP) if rows % n == 0), 0)
+    return _largest_tile(rows, _NORM_ROWS, _NORM_STRIP)
 
 
 def resolve_gate_norm_impl(implementation: Optional[str] = None, *, rows: int, inner: int, groups: int) -> str:

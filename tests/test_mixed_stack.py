@@ -673,28 +673,33 @@ def test_a_one_sublayer_layer_owns_one_norm_and_its_kinds_leaves_only():
             said["ssm_conv_kernel"], said["ssm_scan_impl"], said["moe_expert_act"]) == (
         8, 8, 16, 2, 16, 4, "xla_chunked", "relu2")
     assert (said["ssm_gate_norm_impl"], said["ssm_gate_norm_rows"]) == ("xla", 0)
+    assert (said["ssm_conv_impl"], said["ssm_conv_rows"]) == ("xla", 0)
     assert "attn_window" not in said
 
 
-def test_the_in_projections_parts_with_the_whole_for_the_gate_differentiate_as_a_split():
-    """`_gate_xbc_dt` hands the gated norm the WHOLE projection (the gate is
-    read at its first features) beside xBC and dt: the values are
-    `jnp.split`'s, and so is the gradient, whatever reads the whole (a reader
-    of the gate alone, as the norm is, and one of every feature)."""
+def test_the_in_projections_parts_with_the_whole_for_the_gate_and_the_convolution_differentiate_as_a_split():
+    """`_gate_xbc_dt` hands the gated norm AND the convolution the WHOLE
+    projection (the gate is read at its first features, xBC at those after
+    them) beside dt: the values are `jnp.split`'s, and so is the gradient,
+    whatever reads either whole (readers of their own columns alone, as the
+    kernels are, and one of every feature)."""
     from ray_tpu.models.mixed_stack import _gate_xbc_dt
 
     projected = jax.random.normal(jax.random.PRNGKey(0), (2, 6, 16 + 24 + 4))
 
-    def through(split, read):
+    def through(split, read_gate, read_xbc):
         def loss(projected):
             z, xbc, step = split(projected)
-            return jnp.sum(jnp.sin(read(z))) + jnp.sum(xbc ** 2) + jnp.sum(jnp.cos(step))
+            return jnp.sum(jnp.sin(read_gate(z))) + jnp.sum(read_xbc(xbc) ** 2) + jnp.sum(jnp.cos(step))
         return jax.value_and_grad(loss)(projected)
 
     parts = lambda t: jnp.split(t, [16, 40], axis=-1)                     # noqa: E731
+    whole = lambda t: t                                                   # noqa: E731
     for got, want in (
-            (through(lambda t: _gate_xbc_dt(t, 16, 24), lambda z: z[..., :16]), through(parts, lambda z: z)),
-            (through(lambda t: _gate_xbc_dt(t, 16, 24), lambda z: z), through(lambda t: (t, *parts(t)[1:]), lambda z: z))):
+            (through(lambda t: _gate_xbc_dt(t, 16, 24), lambda z: z[..., :16], lambda xbc: xbc[..., 16:40]),
+             through(parts, whole, whole)),
+            (through(lambda t: _gate_xbc_dt(t, 16, 24), whole, whole),
+             through(lambda t: (t, t, parts(t)[2]), whole, whole))):
         np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
         np.testing.assert_allclose(np.asarray(got[1]), np.asarray(want[1]), rtol=1e-6, atol=1e-7)
 

@@ -1,7 +1,8 @@
 """The nemotron_h family in small against its plain reference
 (benchmark/reference/nemotron_h_ref.py: the recurrence one position at a
 time): a `MEMEM*EME` stack's loss and every leaf's gradient, and the 16
-shares of one expert layer against the uncut layer. Long tests (the rule at
+shares of one expert layer against the uncut layer; the same stack against
+the mixer that convolved a slice of the projection (PR 52). Long tests (the rule at
 the top of tests/conftest.py): at most six live here."""
 
 import dataclasses
@@ -84,3 +85,38 @@ def test_the_sixteen_shares_of_an_expert_layer_add_up_to_the_uncut_reference():
     assert float(rows) == 2 * 24 * 6
     reference = jax.jit(functools.partial(_reference_layer, config=whole, held=128))(h, lp)
     np.testing.assert_allclose(np.asarray(uncut), np.asarray(reference), atol=2e-5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+def test_on_the_cpu_the_mixer_reads_xbc_out_of_the_whole_and_computes_what_a_slice_through_the_padded_sums_did(
+        monkeypatch, dtype):
+    """Off a TPU `causal_conv1d` is the XLA form it was (PR 48's three lines)
+    on the columns it is handed an offset to, its output cut into x, B and C:
+    the tiny tree's loss and every gradient equal, to the bit, those of the
+    mixer that cut xBC out of the projection first, convolved the slice and
+    split the result (the plan says `xla`)."""
+    from ray_tpu.models import model_family
+    from ray_tpu.ops import ssd
+
+    config = tiny_pattern(dtype=dtype)
+    assert model_family(config).plan(config, 2, 48)["ssm_conv_impl"] == "xla"
+    params = seeded(config)
+    tokens = jax.random.randint(jax.random.PRNGKey(5), (2, 49), 0, config.vocab_size)
+
+    def first_step():       # a new function each time: `jit` keys its cache on the function object
+        return jax.jit(jax.value_and_grad(lambda p: lm_loss(p, tokens, config)[0]))(params)
+
+    loss, grads = first_step()
+
+    def of_the_slice(x, w, b, *, offset=0, splits, implementation=None):
+        x = x[..., offset:offset + w.shape[0]]
+        padded = jnp.pad(x, ((0, 0), (w.shape[1] - 1, 0), (0, 0)))
+        taps = sum(w[:, j].astype(jnp.float32) * padded[:, j:j + x.shape[1]].astype(jnp.float32) for j in range(w.shape[1]))
+        return jnp.split(jax.nn.silu(b.astype(jnp.float32) + taps).astype(x.dtype), np.cumsum(splits)[:-1], axis=-1)
+
+    monkeypatch.setattr(ssd, "causal_conv1d", of_the_slice)
+    want_loss, want = first_step()
+    np.testing.assert_array_equal(np.asarray(loss), np.asarray(want_loss))
+    for got, ref in zip(jax.tree.leaves(grads), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(got.astype(jnp.float32)), np.asarray(ref.astype(jnp.float32)))
+    assert float(jnp.max(jnp.abs(grads["runs"][0][0]["ssm_conv_w"]))) > 0

@@ -382,15 +382,49 @@ def test_gate_norm_kernels_compile_within_the_scoped_vmem_they_ask_for(as_tpu, b
     assert _kernel_calls(grad.compile()) == 2
 
 
+@pytest.mark.parametrize("batch, seq, wide, offset, channels, splits", [
+    (2, 8192, 10304, 4096, 6144, (4096, 1024, 1024)), (1, 400, 384, 128, 256, None)],
+    ids=["nemotron3nano-x-b-c-from-column-4096-of-the-projection", "tiles-of-80-rows-two-blocks-of-128"])
+def test_conv_kernels_compile_within_the_scoped_vmem_they_ask_for(as_tpu, batch, seq, wide, offset, channels, splits):
+    """`ssm_conv_fwd` and `ssm_conv_bwd` at the `train-nemotron3nano-8k` cell's
+    shapes (a grid step: 256 rows of a sequence with all 6,144 channels, read
+    as three blocks of 2,048 columns from column 4,096 of the in-projection's
+    (2, 8,192, 10,304) output, with the strip of 16 rows before and, backward,
+    after; x, B and C an output and a cotangent each) and at one other tiling: the rule says "pallas" there, a
+    differentiated convolution is the two calls, and Mosaic fits each in the
+    scoped VMEM its call asks for (every tile twice and 8 MiB)."""
+    from ray_tpu.ops import ssd
+
+    rows = ssd._conv_rows(seq)
+    assert ssd.conv_plan(seq, channels, 4, offset, splits) == {"ssm_conv_impl": "pallas", "ssm_conv_rows": rows}
+    args = (_on(as_tpu, (batch, seq, wide)), _on(as_tpu, (channels, 4), jnp.float32), _on(as_tpu, (channels,), jnp.float32))
+
+    def conv(*a):
+        out = ssd.causal_conv1d(*a, offset=offset, splits=splits)
+        return out if splits is None else jnp.concatenate([part * (1 + i) for i, part in enumerate(out)], axis=-1)
+
+    forward = jax.jit(conv).lower(*args)
+    assert f"\\22size\\22: {2 * 2 * rows * channels * 2 + 8 * 2**20}}}]" in forward.as_text()
+    assert _kernel_calls(forward.compile()) == 1
+    grad = jax.jit(jax.grad(lambda *a: jnp.sum(conv(*a).astype(jnp.float32) ** 2), argnums=(0, 1, 2))).lower(*args)
+    assert f"\\22size\\22: {2 * 3 * rows * channels * 2 + 8 * 2**20}}}]" in grad.as_text()
+    compiled = grad.compile()
+    assert _kernel_calls(compiled) == 2
+    for kernel in ("ssm_conv_fwd", "ssm_conv_bwd"):
+        assert len(re.findall(rf"^\s*%\w*{kernel}[\w.]* = .*custom-call\(", compiled.as_text(), re.M)) == 1, kernel
+
+
 def test_the_lowered_mixer_holds_no_float32_copy_of_the_group_view(as_tpu):
     """One Mamba-2 mixer of the `train-nemotron3nano-8k` cell, forward and
-    gradient, compiled for the described v5e: the gate and the group norm are
-    the two kernels under `ssm.gate_norm`, and NO operation of the program
+    gradient, compiled for the described v5e: the convolution is the two
+    kernels under `ssm.conv` and the gate and the group norm the two under
+    `ssm.gate_norm`, and NO operation of the program
     copies, reshapes or transposes a float32 array of the product's size
     (2 x 8,192 x 4,096 x 4 bytes: the group view's shuffles, five a layer
     before PR 50). The in-projection's output reaches the kernels as it is
-    written (no slice of z in front of them), and the gate's cotangent joins
-    xBC's and dt's with no pad of its own to the projection's width."""
+    written (no slice of z or of xBC in front of them, no copy of the
+    projection or of the convolution's output), and the gate's and xBC's
+    cotangents join dt's with no pad of their own to the projection's width."""
     from benchmark import model_config
     from ray_tpu.models import mixed_stack, model_family
 
@@ -405,7 +439,8 @@ def test_the_lowered_mixer_holds_no_float32_copy_of_the_group_view(as_tpu):
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(_on(as_tpu, (2, 8192, config.d_model)), lp).compile()
     text = compiled.as_text()
-    assert _kernel_calls(compiled) == 4 and "%ssm_gate_norm_fwd" in text and "%ssm_gate_norm_bwd" in text
+    assert _kernel_calls(compiled) == 6 and "%ssm_gate_norm_fwd" in text and "%ssm_gate_norm_bwd" in text
+    assert "%ssm_conv_fwd" in text and "%ssm_conv_bwd" in text
     product = 2 * 8192 * 4096
     moved = [line.strip()[:160] for line in text.splitlines()
              for found in [re.match(r"\s*(?:ROOT )?%[\w.\-]+ = f32\[([\d,]+)\]\S* (copy|reshape|transpose)\(", line)]
@@ -413,6 +448,7 @@ def test_the_lowered_mixer_holds_no_float32_copy_of_the_group_view(as_tpu):
     assert not moved, moved
     entry = text[text.index("\nENTRY "):]
     assert not re.search(r"= bf16\[2,8192,4096\]\S* (slice|copy)\(%fusion", entry)     # z is not cut out of the projection
+    assert not re.search(r"= bf16\[2,8192,(6144|10304)\]\S* (slice|copy)\(", entry)     # nor xBC, nor either copied
     assert not re.search(r"= bf16\[(2,8192|16384),10304\]\S* pad\(", entry)        # nor its cotangent padded to the width
 
 

@@ -4,6 +4,8 @@ runs; a compile that passes is not a chip run). That file's five tests take
 380 s of the 450 s a file of long tests may (the rule at the top of
 tests/conftest.py), so a new model's compiled step lives here: at most six."""
 
+import re
+
 import jax  # noqa: F401
 import pytest
 
@@ -17,7 +19,9 @@ def test_nemotron3nano_cell_step_runs_the_scan_under_its_scopes_and_compiles(as_
     v5e chip of 15.75 GiB: `ME` scanned twice, then `M*EME` unrolled. Each of
     the 7 layer bodies is one sublayer; the state-space mixer's five scopes
     lie inside `ssm` in the forward pass, its recomputation and the backward
-    pass, its scan and its gated norm are the two kernels each of ops/ssd; the one attention layer runs the causal D = 128 flash kernels and
+    pass, its convolution, its scan and its gated norm are the two kernels
+    each of ops/ssd, the convolution's reading xBC out of the in-projection's
+    output as it is; the one attention layer runs the causal D = 128 flash kernels and
     the expert layers TWO grouped matmuls a pass (a non-gated expert); the
     fused head takes the whole sequence as its chunk."""
     from ray_tpu.models import model_family
@@ -38,6 +42,7 @@ def test_nemotron3nano_cell_step_runs_the_scan_under_its_scopes_and_compiles(as_
     assert (said["ssm_scan_impl"], said["ssm_scan_kernels"], said["ssm_scan_state_bytes"]) == (
         "pallas", 2, 8 * 64 * 128 * 4)
     assert (said["ssm_gate_norm_impl"], said["ssm_gate_norm_rows"]) == ("pallas", 256)
+    assert (said["ssm_conv_impl"], said["ssm_conv_rows"]) == ("pallas", 256)
     compiled = step.lower(state, {"tokens": tokens}).compile()
     # one attention layer, in the unrolled run: the forward kernel and its recomputation, one backward
     assert _kernels_named(compiled, "flash_bwd_dkv_dq") == 1 and "flash_win" not in compiled.as_text()
@@ -61,6 +66,17 @@ def test_nemotron3nano_cell_step_runs_the_scan_under_its_scopes_and_compiles(as_
     for scopes, found, _ in table["ssm_gate_norm_fwd"] + table["ssm_gate_norm_bwd"]:
         assert {"ssm", "ssm.gate_norm"} <= set(scopes), scopes
     assert {found for _, found, _ in table["ssm_gate_norm_bwd"]} == {"bwd"}
+    # the convolution keeps nothing but its arguments: forward and recomputed in every body, one backward
+    assert _kernels_named(compiled, "ssm_conv_fwd") == 6 and _kernels_named(compiled, "ssm_conv_bwd") == 3
+    assert sorted(found for _, found, _ in table["ssm_conv_fwd"]) == ["fwd"] * 3 + ["recompute"] * 3
+    for scopes, found, _ in table["ssm_conv_fwd"] + table["ssm_conv_bwd"]:
+        assert {"ssm", "ssm.conv"} <= set(scopes), scopes
+    assert {found for _, found, _ in table["ssm_conv_bwd"]} == {"bwd"}
+    # xBC reaches the kernels in the projection as the matmul wrote it, and their output the scan's
+    # slices: no copy of a (2, 8,192, 6,144) array, and of a (2, 8,192, 10,304) one at most the one the
+    # parent has too (the scanned run's forward projection, written S-minor: PERF.md row 49 (h))
+    copies = re.findall(r"= bf16\[(?:2,8192|16384),(?:6144|10304)\]\S* copy\([^\n]*", compiled.as_text())
+    assert len(copies) <= 1 and all("10304]" in copy and "/ssm.in_proj/" in copy for copy in copies), copies
     pairs = {(scope, pass_) for instances in table.values() for scopes, pass_, _ in instances for scope in scopes}
     for scope in ("ssm", "ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.gate_norm"):
         assert {(scope, "fwd"), (scope, "recompute"), (scope, "bwd")} <= pairs, scope
