@@ -352,6 +352,21 @@ def _cmd_job(args) -> int:
     raise SystemExit(f"unknown job command {args.job_cmd!r}")
 
 
+def _collective_lines(rows) -> list:
+    """A profile record's `collective_seconds` rows of one program (util/
+    profiling.collective_seconds: by kind, mesh axes, scope and pass) as one
+    line a (kind, axes), most device time first."""
+    moved: dict = {}
+    for row in rows:
+        calls, nbytes, seconds = moved.get((row["kind"], row["axes"]), (0.0, 0.0, 0.0))
+        moved[row["kind"], row["axes"]] = (
+            calls + row["calls"], nbytes + row["bytes"], seconds + row["seconds"])
+    return [f"{kind} over {axes or '(unplaced)'}: {calls:.0f} calls, "
+            f"{nbytes / max(calls, 1) / 1e6:.2f} MB a call, {1e3 * seconds:.1f} ms, "
+            f"{nbytes / max(seconds, 1e-12) / 1e9:.1f} GB/s"
+            for (kind, axes), (calls, nbytes, seconds) in sorted(moved.items(), key=lambda kv: -kv[1][2])]
+
+
 def _cmd_profile(args) -> int:
     """Coordinated cluster profile capture (reference: per-worker
     profiling behind `ray timeline`/the dashboard profiler buttons):
@@ -387,6 +402,8 @@ def _cmd_profile(args) -> int:
                           unmatched=split["unmatched_s"])
             print(f"    {program}: {split['total_s']:.3f} device s: " + ", ".join(
                 f"{name} {100 * s / total:.1f}%" for name, s in shares.items()))
+            for line in _collective_lines(meta.get("collective_seconds", {}).get(program, ())):
+                print("      " + line)
     if args.output:
         from .core.runtime import get_runtime
 

@@ -388,14 +388,16 @@ class LMTrainer:
 
     def _register_step_ops(self, span) -> None:
         """The step's operation table (`profiling.program_ops`: which scope
-        and which pass each operation of the compiled step belongs to), read
-        off the object `step_cost` compiled, which is dropped here; what the
-        table holds goes on `span`. Like the cost, it never fails a run."""
+        and which pass each operation of the compiled step belongs to) and
+        its collectives (`profiling.program_collectives`: kind, the axes of
+        this trainer's mesh, bytes), read off the object `step_cost`
+        compiled, which is dropped here; what they hold goes on `span`.
+        Like the cost, it never fails a run."""
         from ..util import profiling
 
         compiled, self._step_compiled = self._step_compiled, None
         try:
-            for key, value in profiling.register_program_ops(compiled).items():
+            for key, value in profiling.register_program_ops(compiled, self.mesh).items():
                 span.set_attribute(key, value)
         except profiling.ProfilingError as exc:
             span.set_attribute("error", str(exc))

@@ -37,13 +37,14 @@ import contextlib
 import dataclasses
 import gzip
 import json
+import math
 import os
 import re
 import sys
 import threading
 import time
 from collections import OrderedDict
-from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
+from typing import Any, Callable, Dict, Iterator, List, NamedTuple, Optional, Tuple
 
 from ..core.exceptions import ProfilingError
 
@@ -237,7 +238,9 @@ def capture_local_profile(duration_s: Optional[float] = None, *,
     Where the device trace holds runs of a program whose operation table
     this process keeps (`program_ops`: a train step's), the meta's
     `scope_seconds` has that program's device time by scope and by pass
-    (`scope_seconds`, the join the benchmark's reader calls)."""
+    (`scope_seconds`, the join the benchmark's reader calls) and, where the
+    program runs collectives, its `collective_seconds` their calls, bytes
+    and device time by kind, mesh axis, scope and pass (`collective_seconds`)."""
     import shutil
     import tempfile
 
@@ -295,9 +298,11 @@ def capture_local_profile(duration_s: Optional[float] = None, *,
                 artifacts.update(_collect_trace_artifacts(
                     logdir, cfg.profile_max_artifact_bytes
                 ))
-                split = _captured_scope_seconds(logdir)
+                split, moved = _captured_splits(logdir)
                 if split:
                     meta["scope_seconds"] = split
+                if moved:
+                    meta["collective_seconds"] = moved
             except ProfilingError as exc:
                 meta["device"] = f"error: {exc}"
             shutil.rmtree(logdir, ignore_errors=True)
@@ -749,6 +754,12 @@ def program_ops_table(text: str) -> Tuple[str, Dict[str, Tuple[OpInstance, ...]]
     instance suffix, as the reduced trace of the benchmark keys it
     (`_profile_key`), and so may have several instances; every other name
     has one."""
+    return _walk_module(text)[:2]
+
+
+def _walk_module(text: str) -> Tuple[str, Dict[str, Tuple[OpInstance, ...]], List["_FoundCollective"]]:
+    """`program_ops_table`'s walk -> (the module's name, the table, the
+    collectives among the table's operations as the text spells them)."""
     module = re.match(r"HloModule\s+([^\s,]+)", text)
     if module is None:
         raise ProfilingError("not the text of an HLO module")
@@ -756,6 +767,10 @@ def program_ops_table(text: str) -> Tuple[str, Dict[str, Tuple[OpInstance, ...]]
     inlined = set()
     fused_paths: Dict[str, str] = {}    # a fusion's computation -> its last instruction's op_name
     rows: List[Tuple[str, OpInstance]] = []
+    # a one-chip step's text (up to 11 MB) spells no group: nothing below looks for a collective
+    may_hold = "replica_groups=" in text or "source_target_pairs=" in text
+    held: Dict[str, _FoundCollective] = {}      # a computation -> the collective inside it
+    found: List[Tuple[int, _FoundCollective]] = []
     in_fusion = False
     for line in text.splitlines():
         if not line.startswith(" "):
@@ -776,6 +791,8 @@ def program_ops_table(text: str) -> Tuple[str, Dict[str, Tuple[OpInstance, ...]]
                 inner = fused_paths.get(_INLINED.search(line).group(2))
                 if inner:
                     fused_paths[computations[-1]] = inner
+            if may_hold:
+                _collective_on(line, computations[-1], held)
             continue
         instruction = _INSTRUCTION.match(line)
         if instruction is None or not computations:
@@ -787,6 +804,10 @@ def program_ops_table(text: str) -> Tuple[str, Dict[str, Tuple[OpInstance, ...]]
             inlined.add(called.group(2))
         if opcode in _NO_DEVICE_OP:
             continue
+        if may_hold:
+            inside = _collective_on(line, computations[-1], held)
+            if inside:
+                found.append((len(computations) - 1, inside))
         path = _OP_NAME.search(rest)
         # a fusion the compiler left without metadata (a multi-output one) is
         # placed by what it fuses
@@ -798,20 +819,31 @@ def program_ops_table(text: str) -> Tuple[str, Dict[str, Tuple[OpInstance, ...]]
     for name, instance in rows:
         if computations[instance[2]] not in inlined:
             table[name] = table.get(name, ()) + (instance,)
-    return module.group(1), table
+    return module.group(1), table, [c for at, c in found if computations[at] not in inlined]
+
+
+def _print_module(compiled: Any, leave_out: Tuple[str, ...]) -> str:
+    from jaxlib._jax import HloPrintOptions
+
+    options = HloPrintOptions()
+    for option in ("print_backend_config", "print_large_constants", "print_operand_shape",
+                   "print_program_shape", "include_layout_in_shapes", "print_control_dependencies") + leave_out:
+        setattr(options, option, False)
+    return compiled.runtime_executable().hlo_modules()[0].to_string(options)
 
 
 def _module_text(compiled: Any) -> str:
     """The optimised module of a compiled program as text, without the
     kernels' payloads and the constants (most of `as_text()`'s bytes)."""
-    from jaxlib._jax import HloPrintOptions
+    return _print_module(compiled, ("print_result_shape",))
 
-    options = HloPrintOptions()
-    for leave_out in ("print_backend_config", "print_large_constants", "print_operand_shape",
-                      "print_result_shape", "print_program_shape", "include_layout_in_shapes",
-                      "print_control_dependencies"):
-        setattr(options, leave_out, False)
-    return compiled.runtime_executable().hlo_modules()[0].to_string(options)
+
+def _module_shapes_text(compiled: Any) -> str:
+    """The same module with every instruction's result shape and nothing
+    of where it came from: what a collective's bytes are read off. Printed
+    only for a module that holds a collective (Mistral's four-chip step:
+    0.34 MB beside `_module_text`'s 0.52)."""
+    return _print_module(compiled, ("print_metadata", "print_operand_names"))
 
 
 def sublayer_scoped(scopes: Tuple[str, ...]) -> bool:
@@ -819,24 +851,39 @@ def sublayer_scoped(scopes: Tuple[str, ...]) -> bool:
     return any(s != _PHASE_SCOPE for s in scopes)
 
 
-def register_program_ops(compiled: Any) -> Dict[str, Any]:
+def register_program_ops(compiled: Any, mesh: Any = None) -> Dict[str, Any]:
     """Build the operation table of a compiled program and keep it under
-    the program's name (`program_ops`). The compiled object is not kept.
-    -> what the caller's span says of it: `program`, `ops` (instructions
-    in the table), `ops_scoped` (those a scope places) and `text_bytes`."""
+    the program's name (`program_ops`), and beside it what the module says
+    of its collectives (`program_collectives`; their axes are those of
+    `mesh`, the `jax.sharding.Mesh` the program runs under). The compiled
+    object is not kept. -> what the caller's span says of it: `program`,
+    `ops` (instructions in the table), `ops_scoped` (those a scope places),
+    `text_bytes`, `collectives` (a start / done pair counted once; 0 on a
+    one-chip step), `collective_axes` (the mesh axes they run along,
+    `fsdp,tp`) and `collectives_unplaced` (those whose groups match no set
+    of axes)."""
+    mesh_axes = tuple(mesh.shape.items()) if mesh is not None else ()
     try:
         text = _module_text(compiled)
-        program, table = program_ops_table(text)
+        program, table, spelled = _walk_module(text)
+        collectives = _collective_records(
+            spelled, _collective_shapes(_module_shapes_text(compiled)), mesh_axes) if spelled else {}
     except ProfilingError:
         raise
     except Exception as exc:  # noqa: BLE001 - typed boundary
         raise ProfilingError(f"no operation table for this program: {exc!r}") from exc
     with _ops_lock:
         _program_ops[program] = table
+    with _collectives_lock:
+        _program_collectives[program] = collectives
     instances = [i for found in table.values() for i in found]
+    whole = [record for record in collectives.values() if not record.completes]
+    along = {axis for record in whole for axis in record.axes}
     return {"program": program, "ops": len(instances),
             "ops_scoped": sum(sublayer_scoped(i[0]) for i in instances),
-            "text_bytes": len(text)}
+            "text_bytes": len(text), "collectives": len(whole),
+            "collective_axes": ",".join(name for name, _ in mesh_axes if name in along),
+            "collectives_unplaced": sum(not record.axes for record in whole)}
 
 
 def program_ops() -> Dict[str, Dict[str, Tuple[OpInstance, ...]]]:
@@ -888,6 +935,281 @@ def scope_seconds(op_seconds: Dict[str, float], op_counts: Dict[str, float],
             "unmatched_ops": unmatched, "total": sum(op_seconds.values())}
 
 
+# ------------------------------------------ the step's collectives, by axis
+#
+# A name like `all-reduce.102` hides what decides a collective's cost: its
+# kind, between which devices it runs (which MESH AXIS) and how many bytes
+# it moves. The optimised module says all three (`replica_groups=...`, the
+# opcode, the result's shape); `_walk_module` finds the instructions,
+# `program_collectives_table` reads them, `collective_seconds` joins a
+# profile's seconds to them and to the operation table's scopes and passes.
+
+COLLECTIVE_KINDS: Tuple[str, ...] = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
+                                     "collective-permute", "collective-broadcast")
+
+
+class Collective(NamedTuple):
+    """What the module says of one operation that is, wraps or fuses a
+    collective. An asynchronous one is ONE collective in several operations
+    (`-start` / `-done`, `async-start` / `async-done` around a computation
+    that holds one, or the TPU compiler's fusions `async-collective-start` /
+    `async-collective-done`): the `done` names its `start` in `completes`,
+    and so does a compute fusion the collective is carried through between
+    the two (`under`: its device time is the compute's, not the
+    collective's); all carry the collective's groups, axes and bytes."""
+
+    kind: str                               # one of COLLECTIVE_KINDS
+    half: str                               # "" (synchronous), "start", "done" or "under"
+    completes: str                          # a done's or an under's start (its name in the registry), else ""
+    groups: Tuple[Tuple[int, ...], ...]     # device groups by partition id; a permute's (source, target)s
+    axes: Tuple[str, ...]                   # the mesh axes the groups run along; () = unplaced
+    bytes: int                              # the full array on one chip: the larger of operand and result
+
+
+class _FoundCollective(NamedTuple):
+    name: str       # the operation as a profile names it (the wrapper's or the fusion's, where one holds it)
+    kind: str
+    half: str
+    inner: str      # the collective instruction itself, whose result's shape says the bytes
+    spelled: str    # the text behind its opcode: `replica_groups=` or `source_target_pairs=`
+    operand: str    # the operation's first operand: a done's start
+
+
+_KINDS = "|".join(COLLECTIVE_KINDS)
+_MAY_BE_COLLECTIVE = re.compile(r" (?:all-|collective-|reduce-scatter)[a-z\-]*\(")     # spares most lines the next
+_COLLECTIVE_OP = re.compile(
+    r"^\s+(?:ROOT )?%?([\w.\-]+) = (?:\S.*? )?(" + _KINDS + r")(?:-(start|done))?\(%?([\w.\-]*)(.*)$")
+_HOLDER = re.compile(
+    r"^\s+(?:ROOT )?%?([\w.\-]+) = (?:\S.*? )?(fusion|async-start|async-update|async-done)"
+    r"\(%?([\w.\-]*).*?calls=%?([\w.\-]+)")
+_ASYNC_HALF = {"async-start": "start", "async-update": "done", "async-done": "done"}
+_COLLECTIVE_CALL = re.compile(r" (?:" + _KINDS + r")(?:-start|-done)?\(")
+_ARRAY = re.compile(r"\b([a-z]+\d*[a-z0-9]*)\[([\d,]*)\]")
+_GROUP_LIST = re.compile(r"(?:replica_groups|source_target_pairs)=\{((?:\{[\d,]*\},?)*)\}")
+_CHANNEL = re.compile(r"channel_id=(\d+)")
+_GROUP_IOTA = re.compile(r"replica_groups=\[([\d,]+)\]<=\[([\d,]+)\](?:T\(([\d,]+)\))?")
+
+_collectives_lock = threading.Lock()
+_program_collectives: Dict[str, Dict[str, Collective]] = {}
+
+
+def _collective_on(line: str, computation: str,
+                   held: Dict[str, _FoundCollective]) -> Optional[_FoundCollective]:
+    """The collective an instruction's line is, or holds through the
+    computation it `calls=` (a fusion, an async wrapper), or None; `held`
+    keeps the first one of each computation for its callers. The TPU
+    compiler's own asynchronous form is a fusion whose computation holds the
+    collective and a custom call `AsyncCollectiveStart` / `AsyncCollectiveDone`,
+    which makes the computation's collective that half."""
+    inside = None
+    own = _COLLECTIVE_OP.match(line) if _MAY_BE_COLLECTIVE.search(line) else None
+    if own:
+        name, kind, half, operand, spelled = own.groups()
+        inside = _FoundCollective(name, kind, half or "", name, spelled, operand)
+    elif "AsyncCollectiveStart" in line and computation in held:
+        held[computation] = held[computation]._replace(half="start")
+    elif "AsyncCollectiveDone" in line and computation in held:
+        held[computation] = held[computation]._replace(half="done")
+    elif "calls=" in line:
+        holder = _HOLDER.match(line)
+        if holder and holder.group(4) in held:
+            inner = held[holder.group(4)]
+            inside = inner._replace(name=holder.group(1), operand=holder.group(3),
+                                    half=_ASYNC_HALF.get(holder.group(2), inner.half))
+    if inside:
+        held.setdefault(computation, inside)
+    return inside
+
+
+def _numbers(text: Optional[str]) -> List[int]:
+    return [int(n) for n in (text or "").split(",") if n]
+
+
+def collective_groups(spelled: str, devices: int) -> Tuple[Tuple[int, ...], ...]:
+    """The device groups of a collective as its instruction spells them:
+    `replica_groups={{0,1},{2,3}}`, `={}` (all `devices` in one), the iota
+    forms `[2,2]<=[4]` and `[2,2]<=[2,2]T(1,0)` (the ids 0..n-1 reshaped,
+    transposed, and cut into rows), or a permute's
+    `source_target_pairs={{0,1},{1,0}}`. () where it spells none."""
+    import itertools
+
+    iota = _GROUP_IOTA.search(spelled)
+    if iota:
+        dims, reshape, perm = (_numbers(part) for part in iota.groups())
+        perm = perm or list(range(len(reshape)))
+        strides = [1] * len(reshape)
+        for k in range(len(reshape) - 2, -1, -1):
+            strides[k] = strides[k + 1] * reshape[k + 1]
+        flat = [sum(i * strides[p] for i, p in zip(index, perm))
+                for index in itertools.product(*(range(reshape[p]) for p in perm))]
+        return tuple(tuple(flat[at:at + dims[-1]]) for at in range(0, len(flat), dims[-1]))
+    listed = _GROUP_LIST.search(spelled)
+    if listed is None:
+        return ()
+    groups = tuple(tuple(_numbers(group)) for group in re.findall(r"\{([\d,]*)\}", listed.group(1)))
+    return groups or ((tuple(range(devices)),) if devices else ())
+
+
+def group_axes(groups: Tuple[Tuple[int, ...], ...], mesh_axes: Tuple[Tuple[str, int], ...],
+               pairs: bool = False) -> Tuple[str, ...]:
+    """The axes of a mesh (`tuple(mesh.shape.items())`) that device groups
+    run along. A partition id is a position in the executable's device
+    assignment, which for a step jitted under a mesh is the mesh's devices
+    row-major, so id i has the coordinates of position i. Groups run along
+    the axes whose coordinate varies inside a group, if the groups are
+    exactly the mesh cut along the other axes; () where they are not (or
+    name a device the mesh has not). A permute's `pairs` run along every
+    axis on which some source and its target differ."""
+    sizes = [size for _, size in mesh_axes]
+    devices = math.prod(sizes)
+
+    def coordinates(device: int) -> Tuple[int, ...]:
+        out = []
+        for size in reversed(sizes):
+            out.append(device % size)
+            device //= size
+        return tuple(reversed(out))
+
+    if not groups or any(not 0 <= d < devices for group in groups for d in group):
+        return ()
+    if pairs:
+        varying = {k for source, target in groups
+                   for k, (a, b) in enumerate(zip(coordinates(source), coordinates(target))) if a != b}
+    else:
+        varying = {k for k in range(len(sizes)) if len({coordinates(d)[k] for d in groups[0]}) > 1}
+        cut: Dict[Tuple[int, ...], set] = {}
+        for device in range(devices):
+            at = coordinates(device)
+            cut.setdefault(tuple(c for k, c in enumerate(at) if k not in varying), set()).add(device)
+        if {frozenset(group) for group in groups} != {frozenset(group) for group in cut.values()}:
+            return ()
+    return tuple(name for k, (name, _) in enumerate(mesh_axes) if k in varying)
+
+
+def _array_bytes(shape: str) -> List[int]:
+    """Bytes of each array a printed shape holds (`bf16[12,1024,4096]`, a
+    tuple's `(f32[4096], f32[])`; a width in bits is the first number of the
+    element type's name, `pred` a byte, `token[]` none)."""
+    out = []
+    for dtype, dims in _ARRAY.findall(shape):
+        bits = re.search(r"\d+", dtype)
+        if bits is None and dtype != "pred":
+            continue
+        out.append(math.prod(_numbers(dims)) * (int(bits.group()) if bits else 8) // 8)
+    return out
+
+
+def _collective_records(found: List[_FoundCollective], shapes: Dict[str, str],
+                        mesh_axes: Tuple[Tuple[str, int], ...]) -> Dict[str, Collective]:
+    """{operation name: record} of the collectives the walk found; `shapes`
+    is {collective instruction: its printed result shape}. A done finds its
+    start by its operand, or, like a fusion that carries an asynchronous
+    collective under its compute, by the channel the pieces share."""
+    devices = math.prod(size for _, size in mesh_axes)
+    records: Dict[str, Collective] = {}
+    started: Dict[str, str] = {}        # channel id -> the start on it
+    placed: Dict[Tuple[Any, ...], Tuple[str, ...]] = {}     # a step spells a handful of groups, each many times
+    for c in sorted(found, key=lambda c: c.half != "start"):     # starts first, else as the text has them
+        channel = _CHANNEL.search(c.spelled)
+        channel = channel.group(1) if channel else ""
+        arrays = _array_bytes(shapes.get(c.inner, ""))
+        named = c.half == "done" and c.operand in records and records[c.operand].half == "start"
+        start = c.operand if named else started.get(channel, "")
+        if start and c.half != "start":
+            half = "done" if c.half == "done" else "under"
+            size = records[start].bytes
+            if half == "done":     # the pair's bytes are its result's, which the done says plainly
+                size = sum(arrays) or size
+                records[start] = records[start]._replace(bytes=size)
+            records[c.name] = records[start]._replace(half=half, completes=start, bytes=size)
+            continue
+        pairs = c.kind == "collective-permute"
+        groups = collective_groups(c.spelled, devices)
+        # a start's own shape is (operand, result, ...): the larger of the two
+        size = max(arrays, default=0) if c.half == "start" and c.inner == c.name else sum(arrays)
+        if c.kind == "reduce-scatter" and groups:
+            size *= len(groups[0])  # its operand is the full array
+        if (groups, pairs) not in placed:
+            placed[groups, pairs] = group_axes(groups, mesh_axes, pairs)
+        records[c.name] = Collective(c.kind, c.half, "", groups, placed[groups, pairs], size)
+        if c.half == "start" and channel:
+            started[channel] = c.name
+    return records
+
+
+def program_collectives_table(text: str, mesh_axes: Tuple[Tuple[str, int], ...] = (),
+                              shapes_text: Optional[str] = None) -> Dict[str, Collective]:
+    """The text of an optimised HLO module -> {operation name as a device
+    profile gives it: `Collective`} for every operation of
+    `program_ops_table`'s that is a collective, one half of an asynchronous
+    one, or a fusion or async wrapper that holds one. `mesh_axes` is
+    `tuple(mesh.shape.items())` of the mesh the step runs under; the bytes
+    need result shapes, which `shapes_text` (the same module printed with
+    them; default: `text`) has."""
+    found = _walk_module(text)[2]
+    return _collective_records(found, _collective_shapes(text if shapes_text is None else shapes_text),
+                               tuple(mesh_axes))
+
+
+def _collective_shapes(text: str) -> Dict[str, str]:
+    """{collective instruction: its result shape as printed} of a module's
+    text that has shapes (`name = shape opcode(`)."""
+    shapes = {}
+    for call in _COLLECTIVE_CALL.finditer(text):
+        name, _, shape = text[text.rfind("\n", 0, call.start()) + 1:call.start()].partition(" = ")
+        shapes[name.split()[-1].lstrip("%")] = shape
+    return shapes
+
+
+def program_collectives() -> Dict[str, Dict[str, Collective]]:
+    """{program name as a profile prints it: {operation name: `Collective`}}
+    for every program registered in this process ({} for a one-chip step)."""
+    with _collectives_lock:
+        return dict(_program_collectives)
+
+
+def collective_seconds(op_seconds: Dict[str, float], op_counts: Dict[str, float],
+                       table: Dict[str, Tuple[OpInstance, ...]],
+                       collectives: Dict[str, Collective]) -> List[Dict[str, Any]]:
+    """A reduced profile's device seconds and runs by operation name joined
+    to a program's collectives and, for the same names, its operation
+    table's scopes and pass -> one row a (kind, axes, scopes, pass), most
+    seconds first: `calls`, `bytes` (all calls'), `bytes_per_call`,
+    `seconds` (on the profile's "XLA Ops" line: a synchronous collective
+    whole, an asynchronous one's start and its wait in the done),
+    `gbytes_per_s` (bytes over seconds) and `under_seconds` (the compute
+    fusions an asynchronous collective is carried through: no part of
+    `seconds`). The operations of an asynchronous collective are one
+    collective: seconds summed, calls counted once. `scopes` are the
+    sublayer scopes on the operation's path (() for the partitioner's own
+    or an operation the table does not hold, whose pass is `other`).
+    Collectives that never ran in the profile give no row."""
+    parts: Dict[str, List[str]] = {}
+    for name, record in collectives.items():
+        parts.setdefault(record.completes or name, []).append(name)
+    rows: Dict[Tuple[Any, ...], Dict[str, Any]] = {}
+    for first, names in parts.items():
+        own = [name for name in names if collectives[name].half != "under"]
+        seconds = sum(op_seconds.get(name, 0.0) for name in own)
+        calls = max((op_counts.get(name, 0.0) for name in own), default=0.0)
+        if not seconds and not calls:
+            continue
+        record = collectives[first]
+        scopes, pass_, _ = (table.get(first) or (((), "other", 0),))[0]
+        scopes = tuple(s for s in scopes if s != _PHASE_SCOPE)
+        row = rows.setdefault((record.kind, record.axes, scopes, pass_), {
+            "kind": record.kind, "axes": record.axes, "scopes": scopes, "pass": pass_,
+            "calls": 0.0, "bytes": 0.0, "seconds": 0.0, "under_seconds": 0.0})
+        row["calls"] += calls
+        row["bytes"] += record.bytes * calls
+        row["seconds"] += seconds
+        row["under_seconds"] += sum(op_seconds.get(name, 0.0) for name in names if name not in own)
+    for row in rows.values():
+        row["bytes_per_call"] = row["bytes"] / row["calls"] if row["calls"] else 0.0
+        row["gbytes_per_s"] = row["bytes"] / row["seconds"] / 1e9 if row["seconds"] else 0.0
+    return sorted(rows.values(), key=lambda row: -row["seconds"])
+
+
 # what a profile lists as one operation though it only contains others
 _CONTAINERS = frozenset({"while", "conditional", "call"})
 
@@ -925,25 +1247,28 @@ def profiled_op_seconds(planes: Any, program: str) -> Tuple[Dict[str, float], Di
     return seconds, counts
 
 
-def _captured_scope_seconds(logdir: str) -> Dict[str, Dict[str, Any]]:
-    """{program: its device seconds by "scope|pass", by pass, unscoped,
-    unmatched and in all} for every registered program that ran in the
-    device trace under `logdir`; {} where none did (no chip, no table)."""
-    tables = program_ops()
+def _captured_splits(logdir: str) -> Tuple[Dict[str, Dict[str, Any]], Dict[str, List[Dict[str, Any]]]]:
+    """({program: its device seconds by "scope|pass", by pass, unscoped,
+    unmatched and in all}, {program: `collective_seconds`' rows, axes and
+    scopes as `fsdp,tp` and `attn.full/attn.proj`, where it ran any}) for
+    every registered program that ran in the device trace under `logdir`;
+    ({}, {}) where none did (no chip, no table)."""
+    tables, collectives = program_ops(), program_collectives()
     paths = [os.path.join(root, name) for root, _dirs, names in os.walk(logdir)
              for name in names if name.endswith(".xplane.pb")]
     if not tables or not paths:
-        return {}
+        return {}, {}
     import jax
 
     planes = list(jax.profiler.ProfileData.from_file(sorted(paths)[-1]).planes)
-    out: Dict[str, Dict[str, Any]] = {}
+    scopes: Dict[str, Dict[str, Any]] = {}
+    moved: Dict[str, List[Dict[str, Any]]] = {}
     for program, table in tables.items():
         seconds, counts = profiled_op_seconds(planes, program)
         if not seconds:
             continue
         split = scope_seconds(seconds, counts, table)
-        out[program] = {
+        scopes[program] = {
             "by_scope_pass": {f"{scope}|{pass_}": s
                               for (scope, pass_), s in sorted(split["by_scope_pass"].items())},
             "by_pass": split["by_pass"],
@@ -951,4 +1276,8 @@ def _captured_scope_seconds(logdir: str) -> Dict[str, Dict[str, Any]]:
             "unmatched_s": sum(split["unmatched_ops"].values()),
             "total_s": split["total"],
         }
-    return out
+        rows = collective_seconds(seconds, counts, table, collectives.get(program, {}))
+        if rows:
+            moved[program] = [dict(row, axes=",".join(row["axes"]), scopes="/".join(row["scopes"]))
+                              for row in rows]
+    return scopes, moved

@@ -330,6 +330,9 @@ def test_first_report_builds_the_table_under_a_span_of_its_own(dense):
     for before, after in zip(leaves, leaves[1:]):     # one clock reading a boundary
         assert after["start_mono"] == before["end_mono"]
     instances = [i for found in table.values() for i in found]
+    # what the module says of its collectives rides the same span (tests/test_step_collectives.py)
+    said = {key: ops["attrs"].pop(key) for key in ("collectives", "collective_axes", "collectives_unplaced")}
+    assert said["collectives"] > 0 and said["collective_axes"] == "dp,fsdp,tp"
     assert ops["attrs"] == {
         "program": STEP, "ops": len(instances),
         "ops_scoped": sum(profiling.sublayer_scoped(i[0]) for i in instances),

@@ -102,6 +102,35 @@ def test_mistral_cell_step_keeps_what_fits_and_compiles(
         assert plan["remat_saved_bytes"] / GIB == pytest.approx(3.375, abs=0.001)
         assert tflop <= WHOLE_BLOCK_TFLOP - 1.6
         assert all_reduces == 20
+        _mistral_step_says_its_collectives(compiled, mesh)
+
+
+def _mistral_step_says_its_collectives(compiled, mesh):
+    """What the TPU compiler emits for the cell's step, as the program's
+    registry reads it (PR 53; `profiling.program_collectives_table`): every
+    collective placed on the 2x2, the activations' all-reduces along `tp`
+    (the MLP's output among them: the model says so), the weights' gathers
+    and the gradients' all-reduces along `fsdp`, 13 of the gathers in the
+    compiler's own asynchronous form (fusions `async-collective-start` /
+    `-done` with the matmul fusion that carries the all-gather between them)."""
+    from collections import Counter
+
+    from ray_tpu.util import profiling
+
+    text = profiling._module_text(compiled)
+    table = profiling.program_ops_table(text)[1]
+    found = profiling.program_collectives_table(
+        text, tuple(mesh.shape.items()), profiling._module_shapes_text(compiled))
+    assert set(found) <= set(table) and all(record.axes and record.bytes for record in found.values())
+    whole = Counter((record.kind, record.half, record.axes) for record in found.values() if not record.completes)
+    assert whole["all-gather", "start", ("fsdp",)] == 13 and whole["all-reduce", "", ("tp",)] == 8
+    assert whole["all-reduce", "", ("fsdp",)] == 10 and sum(whole.values()) == 41
+    carried = Counter(record.half for name, record in found.items() if name.startswith(("async-collective", "fusion.")))
+    assert carried["start"] == carried["done"] == 13 and carried["under"] >= 13
+    activation = 12 * 1024 * 4096 * 2
+    mlp_out = [record for name, record in found.items()
+               if record.kind == "all-reduce" and table[name][0][:2] == (("steplog.fwd_bwd_compute", "mlp"), "fwd")]
+    assert [(record.axes, record.bytes) for record in mlp_out] == [(("tp",), activation)]
 
 
 def _kernels_named(compiled, name) -> int:

@@ -123,6 +123,17 @@ def test_evabyte_cell_step_runs_the_kernels_once_a_shard_and_compiles(as_tpu, mo
         for scopes, found, _ in table[kernel]:
             assert {"attn.full", "attn.kernel", "attn.eva", scope} <= set(scopes), (kernel, scopes)
     assert {found for _, found, _ in table["eva_far_bwd"]} == {"bwd"}
+    # what the TPU compiler emits between the four chips, as the program's registry reads it (PR 53):
+    # all of it along `fsdp`; the MLP's and the projections' gathers cut into rings of
+    # `collective-permute-start` / `-done` (a windowed einsum), 9 weights' gathers in its own
+    # asynchronous form (`async-collective-start` / `-done` fusions)
+    moved = profiling.program_collectives_table(
+        profiling._module_text(compiled), tuple(mesh.shape.items()), profiling._module_shapes_text(compiled))
+    assert set(moved) <= set(table) and {record.axes for record in moved.values()} == {("fsdp",)}
+    whole = [record for record in moved.values() if not record.completes]
+    assert len(whole) == 73 and all(record.bytes for record in whole)
+    assert sum(record.kind == "collective-permute" and record.half == "start" for record in whole) == 58
+    assert sum(record.kind == "all-gather" and record.half == "start" for record in whole) == 9
     pairs = {(scope, pass_) for instances in table.values() for scopes, pass_, _ in instances for scope in scopes}
     assert {("attn.eva.pool", "fwd"), ("attn.eva.pool", "bwd"), ("head.multibyte", "fwd"), ("mlp", "fwd"),
             ("attn.proj", "fwd"), ("attn.out", "fwd")} <= pairs
