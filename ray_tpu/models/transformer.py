@@ -9,6 +9,16 @@ first-class and TPU-shaped:
 - parameters are a plain pytree with a parallel tree of *logical axis names*
   (ray_tpu.parallel.sharding) — DP/FSDP/TP/SP/EP is a rule-table change,
   never a model change;
+- ONE activation is constrained, and only where a step is traced under a
+  context mesh with `tp` > 1 (ray_tpu.parallel.sequence_parallel): the
+  residual stream between sublayers holds its sequence axis over `tp`
+  (`constrain_stream` at the embedding's output and after each sublayer's
+  residual add; whole sequences again on the final norm's output, for the
+  head), the projections that read it gather it a piece at a time under
+  their matmuls (`column_parallel`: `wq/wk/wv`, `w_up/w_gate`) and those that
+  write it scatter their partial sums the same way (`row_parallel`: `wo`,
+  `w_down`). With no mesh, a manual one or `tp` = 1 the three are the plain
+  einsums and the lowered program is what it was;
 - layers are stacked on a leading axis and executed with `lax.scan`, so
   compile time is O(1) in depth and remat is one `jax.checkpoint`;
 - attention dispatches to the Pallas flash kernel on TPU (ray_tpu.ops);
@@ -43,6 +53,7 @@ from ..ops import (
 )
 from ..ops.attention import attention_plan
 from ..ops.eva import eva_attention, eva_plan
+from ..parallel.sequence_parallel import column_parallel, constrain_stream, row_parallel
 
 Params = Dict[str, Any]
 
@@ -378,8 +389,9 @@ def _attention_sublayer(x, lp, config, rope_tables, positions, window, remat_sav
     with jax.named_scope("attn.proj"):
         h = _block_norm(x, lp["ln1_scale"], lp.get("ln1_bias"), c)
         if c.latent_attention:
-            q, k, v = _latent_qkv(h, lp, c, rope_tables, positions)
+            q, k, v = _latent_qkv(constrain_stream(h, whole=True), lp, c, rope_tables, positions)
         elif c.fused_qkv:
+            h = constrain_stream(h, whole=True)
             # one wide matmul beats three narrow ones on the MXU; the concat of
             # the (static) weights folds into the kernel at compile time
             wqkv = jnp.concatenate(
@@ -398,9 +410,8 @@ def _attention_sublayer(x, lp, config, rope_tables, positions, window, remat_sav
             k = qkv[..., nq : nq + nkv].reshape(b_, s_, c.kv_heads, c.head_dim).transpose(0, 2, 1, 3)
             v = qkv[..., nq + nkv :].reshape(b_, s_, c.kv_heads, c.head_dim).transpose(0, 2, 1, 3)
         else:
-            q = jnp.einsum("bse,ehd->bhsd", h, lp["wq"].astype(dt))
-            k = jnp.einsum("bse,ehd->bhsd", h, lp["wk"].astype(dt))
-            v = jnp.einsum("bse,ehd->bhsd", h, lp["wv"].astype(dt))
+            q, k, v = column_parallel(
+                h, *(("bse,ehd->bhsd", lp[w]) for w in ("wq", "wk", "wv")))
         if c.use_bias:
             q = q + lp["bq"].astype(dt)[None, :, None, :]
             k = k + lp["bk"].astype(dt)[None, :, None, :]
@@ -425,16 +436,16 @@ def _attention_sublayer(x, lp, config, rope_tables, positions, window, remat_sav
             attn = attend(q, k, v, causal=c.causal, window=window, implementation=c.attn_impl)
     if c.attn_gate:
         with jax.named_scope("attn.proj"):
-            gate = jnp.einsum("bse,ehd->bhsd", h, lp["wg"].astype(dt))
+            (gate,) = column_parallel(h, ("bse,ehd->bhsd", lp["wg"]))
     with jax.named_scope("attn.out"):
         if c.attn_gate:
             attn = attn * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(dt)
-        out = jnp.einsum("bhsd,hde->bse", attn, lp["wo"].astype(dt))
+        out = row_parallel("bhsd,hde->bse", attn, lp["wo"])
         if c.use_bias:
             out = out + lp["bo"].astype(dt)
         if c.sandwich_norm:
             out = _norm(out, lp["ln1_post_scale"], lp.get("ln1_post_bias"), c.norm, c.norm_eps)
-        return checkpoint_name(x + out, "attn_residual")
+        return checkpoint_name(constrain_stream(x + out), "attn_residual")
 
 
 def mlp_sublayer(x: jax.Array, lp: Params, config: TransformerConfig) -> jax.Array:
@@ -443,21 +454,22 @@ def mlp_sublayer(x: jax.Array, lp: Params, config: TransformerConfig) -> jax.Arr
     dt = c.dtype
     with jax.named_scope("mlp"):
         h = _block_norm(x, lp["ln2_scale"], lp.get("ln2_bias"), c)
-        up = jnp.einsum("bse,ef->bsf", h, lp["w_up"].astype(dt))
-        if c.use_bias:
-            up = up + lp["b_up"].astype(dt)
-        up = checkpoint_name(up, "mlp_up")
-        if c.act == "swiglu":
-            gate = jnp.einsum("bse,ef->bsf", h, lp["w_gate"].astype(dt))
-            act = swiglu(checkpoint_name(gate, "mlp_gate"), up)
-        else:
-            act = gelu(up)
-        down = jnp.einsum("bsf,fe->bse", act, lp["w_down"].astype(dt))
+        # up and, where there is one, gate: one gather of h for both. Row-wise work alone reads
+        # them before the down projection, so each stays the tuple of pieces the gather delivers
+        # (one piece, the whole, wherever the stream is not sharded)
+        wide = ("w_up", "w_gate") if c.act == "swiglu" else ("w_up",)
+        acts = []
+        for up, *gate in zip(*column_parallel(h, *(("bse,ef->bsf", lp[w]) for w in wide), ordered=False)):
+            if c.use_bias:
+                up = up + lp["b_up"].astype(dt)
+            up = checkpoint_name(up, "mlp_up")
+            acts.append(swiglu(checkpoint_name(gate[0], "mlp_gate"), up) if gate else gelu(up))
+        down = row_parallel("bsf,fe->bse", acts, lp["w_down"], ordered=False)
         if c.use_bias:
             down = down + lp["b_down"].astype(dt)
         if c.sandwich_norm:
             down = _norm(down, lp["ln2_post_scale"], lp.get("ln2_post_bias"), c.norm, c.norm_eps)
-        return x + down
+        return constrain_stream(x + down)
 
 
 def _block(
@@ -482,7 +494,10 @@ class RematCandidate(NamedTuple):
     width: int               # features held
     flops: int               # forward FLOPs that the backward need not repeat
     worth: int               # the time they take less what keeping moves, in FLOPs at the matmuls' rate
-    all_reduce: bool         # and a tensor-parallel all-reduce of such a row with them
+    # and with them a sum of such a row's partial results over `tp`: an all-reduce of the row where
+    # every `tp` device holds the whole stream, a reduce-scatter into the kept part of it where the
+    # stream's sequence lies over `tp` (half the bytes on the link for half the bytes kept at tp = 2)
+    tp_sum: bool
     layers: Tuple[int, ...]  # the layers that write them, in each run of the stack
 
 
@@ -519,14 +534,16 @@ def attention_costs(
         visible = min(seq, window) if window else seq // 2 if c.causal else seq
     scores = 4 * q_width * visible
     heads, itemsize = c.n_heads // split("wq"), jnp.dtype(c.dtype).itemsize
-    # the residual stream's features, in features of the activations' dtype
-    stream = c.d_model * jnp.dtype(c.stream_dtype).itemsize // itemsize
+    # a row of the residual stream on this device, in features of the activations' dtype:
+    # its share where the sequence lies over `tp`
+    stream = c.d_model * jnp.dtype(c.stream_dtype).itemsize // itemsize // split("stream")
     return {
         "flops": (2 * c.d_model * ((2 if c.attn_gate else 1) * q_width + 2 * kv_width)
                   + scores + out_proj),
-        # the norm's output, the sublayer's (and its own norm's), the residual;
-        # q and the attention output, (the gate and the gated output); k, v
-        "width": ((3 if c.sandwich_norm else 2) * c.d_model + stream
+        # the norm's output (gathered whole for the projections), the sublayer's
+        # (and its own norm's) and the residual as the stream is held; q and the
+        # attention output, (the gate and the gated output); k, v
+        "width": (c.d_model + (2 if c.sandwich_norm else 1) * c.d_model // split("stream") + stream
                   + (4 if c.attn_gate else 2) * q_width + 2 * kv_width),
         "candidates": (
             _kept_kernel_candidate(scores, heads, q_width, itemsize, "window" if window else "full"),
@@ -565,10 +582,12 @@ def _latent_attention_costs(config: TransformerConfig, seq: int, split: Callable
         # the norm's output, the sublayer's, the residual; both latents before
         # and after their norms; q before and after its rotation, the keys'
         # part without positions, k, v and the attention output
-        "width": 3 * c.d_model + 2 * latents + 5 * q_width + heads * nope,
+        "width": (c.d_model + 2 * c.d_model // split("stream") + 2 * latents + 5 * q_width
+                  + heads * nope),
         "candidates": (
             _kept_kernel_candidate(scores, heads, q_width, itemsize, "full"),
-            RematCandidate(("attn_residual",), c.d_model, out_proj, out_proj, split("wq_b") > 1, ()),
+            RematCandidate(("attn_residual",), c.d_model // split("stream"), out_proj, out_proj,
+                           split("wq_b") > 1, ()),
             RematCandidate(("attn_latent_q", "attn_latent_kv", "attn_latent_k_rope"),
                            latents, down, down, False, ()),
         ),
@@ -584,15 +603,18 @@ def mlp_costs(config: TransformerConfig, split: Callable[[str], int],
     d_ff = (d_ff or c.d_ff) // split("w_up")
     matmul = 2 * c.d_model * d_ff  # each of up, (gate,) down
     itemsize = jnp.dtype(c.dtype).itemsize
-    stream = c.d_model * jnp.dtype(c.stream_dtype).itemsize // itemsize
+    stream = c.d_model * jnp.dtype(c.stream_dtype).itemsize // itemsize // split("stream")
     return {
         "flops": (len(wide) + 1) * matmul,
         # the down projection's output only feeds the next block's input,
         # which is kept; a norm on it reads it again in the backward pass
         "kept_anyway": 0 if c.sandwich_norm else matmul,
-        # the norm's output, the MLP's (and its own norm's), the residual; up,
-        # (gate,) and the activation
-        "width": (3 if c.sandwich_norm else 2) * c.d_model + stream + (len(wide) + 1) * d_ff,
+        # the devices that share a sequence of what the sublayer hands on
+        "stream_split": split("stream"),
+        # the norm's output (gathered whole), the MLP's (and its own norm's)
+        # and the residual as the stream is held; up, (gate,) and the activation
+        "width": (c.d_model + (2 if c.sandwich_norm else 1) * c.d_model // split("stream") + stream
+                  + (len(wide) + 1) * d_ff),
         "candidates": tuple(
             RematCandidate((w.replace("w_", "mlp_"),), d_ff, matmul, matmul, False, ()) for w in wide),
     }
@@ -625,7 +647,7 @@ def stack_costs(runs: Sequence[StackRun]) -> Dict[str, Any]:
             recomputed += n * (attention["flops"] + mlp["flops"] - mlp.get("kept_anyway", 0))
             for c in (*attention["candidates"], *mlp["candidates"]):
                 seen = merged.get(c.names, c._replace(layers=(0,) * len(runs), flops=0, worth=0))
-                if (seen.width, seen.all_reduce) != (c.width, c.all_reduce):
+                if (seen.width, seen.tp_sum) != (c.width, c.tp_sum):
                     raise ValueError(f"{c.names} differs between the kinds of one stack")
                 before = sum(seen.layers)
                 merged[c.names] = seen._replace(
@@ -636,6 +658,8 @@ def stack_costs(runs: Sequence[StackRun]) -> Dict[str, Any]:
         "flops": flops, "recomputed_flops": recomputed,
         "runs": tuple({"scanned": run.scanned, "params": run.params, "period": run.period,
                        "layers": sum(n for n, _, _ in run.kinds),
+                       # a block's input is held as its layers' last sublayer hands the stream on
+                       "stream_split": min(m.get("stream_split", 1) for _, _, m in run.kinds),
                        "width": max(a["width"] + m["width"] for _, a, m in run.kinds)}
                       for run in runs),
         "candidates": tuple(merged.values()),
@@ -654,8 +678,9 @@ def block_costs(
     are); `candidates`, the values named in the sublayers that a policy may
     keep: the MLP's up (and gate) projection, each on its own; the residual
     stream after the attention output projection (with it the backward needs
-    neither that matmul again nor, under tensor parallelism, the all-reduce
-    of its partial sums); and the attention kernel's output with its lse
+    neither that matmul again nor, under tensor parallelism, the sum of its
+    partial results over `tp`: `RematCandidate.tp_sum`); and the attention
+    kernel's output with its lse
     (with both the backward does not run the forward kernel again; the q, k,
     v projections it still does: they are not named). A candidate's `worth`
     is its FLOPs where a matmul is spared; the kernel's are priced by its
@@ -663,7 +688,10 @@ def block_costs(
     `_KEPT_KERNEL_FLOPS_PER_BYTE`): at S = 1,024 that leaves nothing, at
     8,192 it is the most valuable candidate a byte. `split(weight)` is the
     number of devices that share the output features of that block
-    parameter's matmul (tensor parallelism)."""
+    parameter's matmul (tensor parallelism), and `split("stream")` the
+    devices that share one sequence of the residual stream between sublayers
+    (parallel/sequence_parallel.stream_shards): the stream's rows, a kept
+    `attn_residual` and a block's input are that share a device."""
     return stack_costs([StackRun(True, ("blocks",), (
         (config.n_layers, attention_costs(config, seq, split), mlp_costs(config, split)),))])
 
@@ -714,7 +742,9 @@ def forward_hidden(
                 x = x + params["wpe"].astype(dt)[None, :s]
             else:
                 x = x + params["wpe"].astype(dt)[positions]
-        x = x.astype(c.stream_dtype)
+        # whole sequences first, as the lookup in a table split over `tp` leaves them
+        # (one all-reduce); its device's rows of them are then a slice
+        x = constrain_stream(constrain_stream(x.astype(c.stream_dtype), whole=True))
     rope_tables = None
     if c.pos_emb != "learned":
         rope_tables = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
@@ -727,7 +757,7 @@ def forward_hidden(
     x, _ = jax.lax.scan(block_fn, x, params["blocks"], unroll=c.scan_unroll)
 
     with jax.named_scope("head"):
-        return _block_norm(x, params["lnf_scale"], params.get("lnf_bias"), c)
+        return constrain_stream(_block_norm(x, params["lnf_scale"], params.get("lnf_bias"), c), whole=True)
 
 
 def lm_head_weights(params: Params, config: TransformerConfig) -> jax.Array:

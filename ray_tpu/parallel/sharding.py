@@ -12,6 +12,15 @@ Two rule systems compose:
   logical-axis tuples (the common path for models built in this repo)
 - path-regex rules: [(r".*attn/wq", P("fsdp", "tp")), ...] applied to
   parameter tree paths (escape hatch for imported/foreign pytrees)
+
+The rules place PARAMETERS. Activations follow from them by propagation, but
+for one: on a mesh with `tp` > 1 the residual stream between sublayers is
+constrained to P(("dp", "fsdp"), "tp", None), its sequence axis over `tp`
+(parallel/sequence_parallel.py: `constrain_stream`, called by
+models/transformer.py at the embedding's output and after each sublayer's
+residual add; the matmuls on either side of it are written out there as rings
+over `tp`). What decides is the context mesh's `tp` size as the step is
+traced, and nothing else: no rule, flag or configuration key.
 """
 
 from __future__ import annotations

@@ -37,6 +37,7 @@ from ..ops.losses import (
     multihead_targets,
 )
 from ..parallel.mesh import DATA_AXES
+from ..parallel.sequence_parallel import stream_shards
 from ..parallel.sharding import LogicalRules, default_rules, tree_specs
 
 
@@ -187,7 +188,9 @@ _REMAT_BLOCK_COPIES = 1.75
 # FLOPs that take as long as moving one byte through a tensor-parallel
 # all-reduce, which is how a spared all-reduce counts beside spared matmuls:
 # keeping the residual took 23.2 ms off the step beside gate and up (32.0 on
-# its own), 8.8 of them the output projection's: 14.4 ms for 805 MB
+# its own), 8.8 of them the output projection's: 14.4 ms for 805 MB. Where the
+# stream's sequence lies over `tp` (PR 54) the spared sum is a reduce-scatter
+# of half the bytes into half the bytes kept: the same price a byte kept
 _ALL_REDUCE_FLOPS_PER_BYTE = 3600
 
 
@@ -203,7 +206,7 @@ def auto_remat_saved(
     a step that recomputes its blocks keeps across the forward pass, and the
     bytes a device holds for them (their shapes': on the chip a kept value
     cost 0.96 to 1.00 of that): those worth keeping, one by one in order of
-    their worth per byte (a spared all-reduce counts as the FLOPs of its
+    their worth per byte (a spared sum over `tp` counts as the FLOPs of its
     time), each one with which `peak_bytes(kept)`, the estimate of the step's peak,
     still leaves `losses.HBM_FREE_FRACTION` of the device free. `rows` are a
     device's tokens a step. Nothing live is probed but the device's size, so
@@ -216,7 +219,7 @@ def auto_remat_saved(
         return (), 0
 
     def worth_per_byte(c) -> float:
-        return c.worth / (c.width * itemsize) + c.all_reduce * _ALL_REDUCE_FLOPS_PER_BYTE
+        return c.worth / (c.width * itemsize) + c.tp_sum * _ALL_REDUCE_FLOPS_PER_BYTE
 
     kept: Tuple[Any, ...] = ()
     for c in sorted(candidates, key=worth_per_byte, reverse=True):
@@ -430,8 +433,12 @@ def make_train_step(
         resident = device_bytes(state, state_shardings)
         # the gradients and, where they are summed over microbatches, their accumulator
         gradients = device_bytes(state.params, state_shardings.params) * (2 if grad_accum > 1 else 1)
+        # a block's matmuls by their weights' shardings; the stream between
+        # sublayers by the mesh, as the models constrain it when they are traced
+        stream_split = stream_shards(mesh.abstract_mesh, shape[0] // grad_accum, seq)
         costs = family.block_costs(
-            config, seq, lambda weight: model_split(weight, slice(2, None))
+            config, seq, lambda weight: (stream_split if weight == "stream"
+                                         else model_split(weight, slice(2, None)))
         ) if family.block_costs else None
         # the logits a position: every next-token head's
         vocab = config.vocab_size * config.pred_heads // (
@@ -441,7 +448,8 @@ def make_train_step(
         # recomputed (what is kept beside those is decided after the head),
         # else every activation they write
         activations = sum(
-            run["layers"] * rows * (stream_bytes if config.remat else run["width"] * itemsize)
+            run["layers"] * rows * (stream_bytes // run["stream_split"] if config.remat
+                                    else run["width"] * itemsize)
             for run in costs["runs"]) if costs else 0
         chunk = loss_chunk
         if chunk is None:
@@ -459,7 +467,7 @@ def make_train_step(
                 "scanned": run["scanned"], "period": run["period"], "layers": run["layers"],
                 "gradients": device_bytes(under(state.params, run["params"]),
                                           under(state_shardings.params, run["params"])),
-                "inputs": run["layers"] * rows * stream_bytes,
+                "inputs": run["layers"] * rows * stream_bytes // run["stream_split"],
                 "block": _REMAT_BLOCK_COPIES * rows * run["width"] * itemsize,
             } for run in costs["runs"])
             always = resident + gradients - sum(run["gradients"] for run in runs)

@@ -163,11 +163,11 @@ def test_the_plan_prices_the_five_matmuls_and_names_the_latents():
     assert names == [("attn_out", "attn_lse"), ("attn_residual",),
                      ("attn_latent_q", "attn_latent_kv", "attn_latent_k_rope")]
     kept = costs["candidates"][2]
-    assert (kept.width, kept.flops, kept.worth, kept.all_reduce) == (latents, down, down, False)
+    assert (kept.width, kept.flops, kept.worth, kept.tp_sum) == (latents, down, down, False)
     # under tp = 2 the up-projections, the scores and the output projection halve; the down-projections do not
     halved = attention_costs(config, 32, lambda weight: 2 if weight == "wq_b" else 1)
     assert halved["flops"] == down + (up + scores + out_proj) // 2
-    assert halved["candidates"][1].all_reduce and halved["candidates"][2].width == latents
+    assert halved["candidates"][1].tp_sum and halved["candidates"][2].width == latents
     # the module's block is one more run of the stack, unrolled, with its parameters' place
     whole = block_costs(config, 32)
     assert [(run["params"], run["layers"], run["scanned"]) for run in whole["runs"]] == [

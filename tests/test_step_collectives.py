@@ -320,10 +320,12 @@ def test_a_step_on_four_devices_says_its_collectives_and_both_axes(monkeypatch):
     assert set(found) <= set(table)
     axes = {record.axes for record in found.values()}
     assert {("fsdp",), ("tp",)} <= axes and all(record.bytes > 0 for record in found.values())
-    # the model says which: the MLP's output is summed over `tp`, its weights' gradients over `fsdp`
+    # the model says which: the MLP's partial sums reach their rows by permutes over `tp` (an all-reduce
+    # over `tp` until PR 54 laid the stream's sequences over it), the projections' gradients are summed over `fsdp`
     where = {(record.kind, record.axes, scope, table[name][0][1]) for name, record in found.items()
              for scope in table[name][0][0]}
-    assert {("all-reduce", ("tp",), "mlp", "fwd"), ("all-reduce", ("fsdp",), "mlp", "bwd")} <= where
+    assert {("collective-permute", ("tp",), "mlp", "fwd"), ("all-reduce", ("fsdp",), "attn.proj", "bwd")} <= where
+    assert ("all-reduce", ("tp",), "mlp", "fwd") not in where
     rows = profiling.collective_seconds(dict.fromkeys(found, 1e-3), dict.fromkeys(found, 1.0), table, found)
     assert sum(row["calls"] for row in rows) == attrs["collectives"]
 

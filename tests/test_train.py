@@ -320,11 +320,16 @@ LATENTS = ("attn_latent_q", "attn_latent_kv", "attn_latent_k_rope")   # a latent
     # beside two more layers of state one value as wide as up fits, not two (before PR 34's
     # refit, which leaves 6.5% of the chip free where it left 10%: the narrow residual alone)
     (*MISTRAL, V5E_HBM, 24, 1024, {"n_layers": 10}, ("selective", ("attn_residual", "mlp_up"), 1024)),
-    # no room at all: the whole-block program, not an out-of-memory error
-    (*MISTRAL, V5E_HBM, 24, 1024, {"n_layers": 12}, ("whole_block", (), 1024)),
-    # twice the batch: the narrow residual (before the refit: nothing); with ten layers nothing
+    # twelve layers: nothing before PR 54 (the whole-block program, not an out-of-memory error);
+    # since then the blocks' inputs and the residual are half the rows a device (their sequences
+    # lie over `tp`) and the narrow residual fits; with fourteen nothing does
+    (*MISTRAL, V5E_HBM, 24, 1024, {"n_layers": 12}, ("selective", ("attn_residual",), 1024)),
+    (*MISTRAL, V5E_HBM, 24, 1024, {"n_layers": 14}, ("whole_block", (), 1024)),
+    # twice the batch: the narrow residual (before the refit: nothing); with ten layers the same
+    # since PR 54, with twelve nothing
     (*MISTRAL, V5E_HBM, 48, 1024, {}, ("selective", ("attn_residual",), 1024)),
-    (*MISTRAL, V5E_HBM, 48, 1024, {"n_layers": 10}, ("whole_block", (), 1024)),
+    (*MISTRAL, V5E_HBM, 48, 1024, {"n_layers": 10}, ("selective", ("attn_residual",), 1024)),
+    (*MISTRAL, V5E_HBM, 48, 1024, {"n_layers": 12}, ("whole_block", (), 1024)),
     # a device of unknown size (the CPU): the step that fits wherever anything does
     (*MISTRAL, 0, 24, 1024, {}, ("whole_block", (), 0)),
     # the chip, PR 46: dense 143,573 tokens/s at 94.5%, 512 rows 149,090, the whole 1,024 149,395 at 80.2%
@@ -344,16 +349,19 @@ LATENTS = ("attn_latent_q", "attn_latent_kv", "attn_latent_k_rope")   # a latent
     ("smallthinker-21b-a3b-train-1chip", MeshSpec(), V5E_HBM, 1, 16384, {}, ("selective", ATTN_OUT, 16384)),
     # dense 27,956 tokens/s at 90.3%, the whole 8,192 27,938 at 89.3% (2,048: 27,833, 4,096: 27,709)
     ("glm-4.7-flash-train-1chip", MeshSpec(), V5E_HBM, 2, 8192, {}, ("selective", ATTN_OUT + LATENTS, 8192)),
-], ids=["mistral-2x2", "mistral-2x2-10-layers", "mistral-2x2-12-layers", "mistral-2x2-batch-48",
-        "mistral-2x2-batch-48-10-layers", "mistral-2x2-unknown-size", "gpt2s", "gpt2s-batch-32", "olmoe",
+], ids=["mistral-2x2", "mistral-2x2-10-layers", "mistral-2x2-12-layers", "mistral-2x2-14-layers",
+        "mistral-2x2-batch-48", "mistral-2x2-batch-48-10-layers", "mistral-2x2-batch-48-12-layers",
+        "mistral-2x2-unknown-size", "gpt2s", "gpt2s-batch-32", "olmoe",
         "olmoe-with-remat", "trinity", "trinity-batch-4", "trinity-unknown-size", "smallthinker", "glm47flash"])
 def test_remat_rule_on_the_cells_numbers(monkeypatch, cell, mesh_spec, hbm, batch, seq, changed, want):
     """`make_train_step(...)`'s `remat_plan_for(shape, state)` and
     `loss_chunk_for(shape, state)` at the shipped cells' widths, meshes and
     batches, on a v5e's 15.75 GiB. The Mistral cell keeps every named matmul
     output and leaves the stated share of the chip free; with ten layers or
-    twice the batch fewer fit, with twelve or with both nothing does and the
-    whole block is recomputed, as on a device whose size is unknown. The
+    twice the batch fewer fit, with twelve or with both the narrow residual
+    alone (half its rows a device since PR 54), with fourteen, or twelve at
+    twice the batch, nothing does and the whole block is recomputed, as on a
+    device whose size is unknown. The
     attention output is worth keeping at S = 8,192, not at 1,024. The Trinity cell
     keeps the attention kernels' outputs of its mixed stack: the two
     scanned dense layers' kept values are still held when every gradient
@@ -389,8 +397,9 @@ def test_remat_rule_on_the_cells_numbers(monkeypatch, cell, mesh_spec, hbm, batc
         # the scores of five windowed layers and a full one, of the stack's forward
         assert plan["remat_recomputed_flops_share"] == pytest.approx(0.712, abs=0.001)
     if want[:2] == ("selective", MATMUL_NAMES):
-        # 12,288 rows a device x (its half of gate and of up + the residual) x 2 B x 8 layers
-        assert plan["remat_saved_bytes"] == 12288 * (2 * 7168 + 4096) * 2 * 8
+        # 12,288 rows a device x (its half of gate and of up + its half of the residual's
+        # sequences, which lie over `tp` since PR 54) x 2 B x 8 layers
+        assert plan["remat_saved_bytes"] == 12288 * (2 * 7168 + 4096 // 2) * 2 * 8
         # what is left to recompute: q, k, v and the attention kernel
         assert plan["remat_recomputed_flops_share"] == pytest.approx(0.132, abs=0.001)
         # beside the whole-block step as the chip measured it (11.145 GB; the
@@ -425,7 +434,7 @@ def test_remat_rule_keeps_in_order_of_recomputation_spared_a_byte():
     assert auto_remat_saved((up, gate), rows=100, itemsize=2, peak_bytes=held_bytes,
                             hbm_bytes=0) == ((), 0)
     # 64 FLOPs a byte against 16 and an all-reduce
-    exposed = residual._replace(all_reduce=True)
+    exposed = residual._replace(tp_sum=True)
     assert _ALL_REDUCE_FLOPS_PER_BYTE > 48
     assert kept((up, gate, exposed), 64 + 16) == ("attn_residual", "mlp_up")
     # two names under one candidate are kept together or not at all, and a
@@ -493,7 +502,8 @@ def test_selective_step_on_a_mesh_gives_one_devices_loss(monkeypatch):
     """fsdp=2 x tp=2 on virtual devices, the rule given room for every
     candidate: the step that keeps them reports what one device's
     whole-block step reports, and its plan counts a device's share (half
-    the rows, half of gate and up, the residual whole)."""
+    the rows, half of gate and up and, its sequences lying over `tp` since
+    PR 54, half of the residual)."""
     from ray_tpu.ops import losses
 
     config = get_config("llama-tiny").replace(remat=True)
@@ -516,6 +526,6 @@ def test_selective_step_on_a_mesh_gives_one_devices_loss(monkeypatch):
         "mlp_up", "mlp_gate", "attn_residual"}
     rows, layers, itemsize = 4 * 16, config.n_layers, jnp.dtype(config.dtype).itemsize
     assert plan["remat_saved_bytes"] == layers * rows * itemsize * (
-        2 * config.d_ff // 2 + config.d_model)
+        2 * config.d_ff // 2 + config.d_model // 2)
     assert sharded["loss"] == pytest.approx(whole["loss"], rel=2e-3)
     assert sharded["grad_norm"] == pytest.approx(whole["grad_norm"], rel=2e-2)

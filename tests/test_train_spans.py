@@ -149,9 +149,9 @@ def test_step_fn_span_names_what_a_recomputing_step_keeps(
     assert traced and set(traced) == {want[1]}
     assert attrs == {**attrs, **trainer.step_fn.remat_plan_for((16, 17), trainer.state)}
     rows, itemsize = 4 * 16, jnp.dtype(config.dtype).itemsize
-    # a device's half of gate and up and the residual; its half of the attention output
-    # with the lse of its 2 heads
-    kept_width = (2 * config.d_ff // 2 + config.d_model) if want[1] else 0
+    # a device's half of gate and up and (its sequences lie over `tp` since PR 54) of the
+    # residual; its half of the attention output with the lse of its 2 heads
+    kept_width = (2 * config.d_ff // 2 + config.d_model // 2) if want[1] else 0
     if "attn_out" in want[1]:
         kept_width += config.d_model // 2 + 4 * 2 // itemsize
     assert attrs["remat_saved_bytes"] == config.n_layers * rows * itemsize * kept_width
