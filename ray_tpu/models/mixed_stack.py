@@ -46,6 +46,16 @@ depthwise convolution; the chunked selective scan of ops/ssd; the gate and a
 norm a group; an out-projection: leaves `ssm_*` of its own); "*", full
 attention without positions; "E", an expert layer. Its kind is then
 `LayerKind("ssm", "none")`, `("full", "none")` or `("none", "experts")`.
+
+A configuration with `kda_heads` mixes a fifth kind of mixer into the index
+rules: every layer that is not the one of its period of `global_attn_every`
+is "kda", Kimi Delta Attention (`_kda_sublayer`: q, k, v and a decay a channel
+from one projection, a causal depthwise convolution and silu on q, k and v,
+L2-normalised q and k, the chunked delta rule of ops/kda, an RMS norm a head
+under ONE sigmoid gate a head, an out-projection: leaves `kda_*` of its own;
+no positions), and the period's one layer is "latent" where the configuration
+has `kv_lora_rank`, else "full". `first_layer` is the published index of the
+first layer that is run: the two index rules count from it.
 """
 
 from __future__ import annotations
@@ -59,8 +69,9 @@ from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops import rope_frequencies, ssd
+from ..ops import kda, rope_frequencies, ssd
 from ..ops.attention import attention_plan
+from ..ops.layers import rmsnorm
 from .moe import _HELD_BUFFER_SHARES, MoEConfig, load_max_over_mean, moe_mlp, moe_plan
 from .transformer import (
     Params,
@@ -133,6 +144,22 @@ class MixedStackConfig(MoEConfig):
     ssm_dt_min: float = 0.001
     ssm_dt_max: float = 0.1
     ssm_dt_floor: float = 1e-4
+    # the published index of the first layer that is run (a chip's stage of a
+    # pipeline): layer i of the stack is layer `first_layer` + i of the two
+    # index rules (`global_attn_every`, `n_dense_layers`)
+    first_layer: int = 0
+    # Kimi Delta Attention (`kda_heads` > 0): the layers that are not their
+    # period's one are "kda", heads of `kda_head_dim` key and value features
+    # with a state of kda_head_dim^2 a head, a causal depthwise convolution of
+    # `kda_conv_kernel` taps over q, k and v, the delta rule's chunk, and the
+    # gate's lower bound: a channel's log-decay a position is
+    # `kda_gate_lower_bound` x sigmoid(exp(A_log) (f + dt_bias)). A_log and
+    # dt_bias start as a state-space mixer's do (`ssm_dt_*`)
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv_kernel: int = 4
+    kda_chunk: int = kda.CHUNK
+    kda_gate_lower_bound: float = -5.0
 
     @property
     def ssm_conv_width(self) -> int:
@@ -145,9 +172,16 @@ class MixedStackConfig(MoEConfig):
         if unknown or (self.layer_pattern and len(self.layer_pattern) < self.n_layers):
             raise ValueError(f"layer_pattern {self.layer_pattern!r}: at least n_layers = {self.n_layers} "
                              f"characters of {sorted(PATTERN_KINDS)} are what the program runs")
-        if self.layer_pattern and (self.mtp_modules or self.latent_attention):
-            raise ValueError("layer_pattern: a patterned stack has no latent attention and no "
-                             "multi-token prediction module")
+        if self.layer_pattern and (self.mtp_modules or self.latent_attention or self.kda_heads
+                                   or self.first_layer):
+            raise ValueError("layer_pattern: a patterned stack has no latent attention, no delta-rule "
+                             "mixer, no multi-token prediction module and starts at its first layer")
+        if self.kda_heads and not (self.kda_head_dim > 0 and self.kda_gate_lower_bound < 0
+                                   and not self.mtp_modules):
+            raise ValueError("kda_heads: kda_head_dim, a negative kda_gate_lower_bound and no multi-token "
+                             "prediction module are what the program runs")
+        if self.first_layer < 0:
+            raise ValueError(f"first_layer {self.first_layer}: a published layer's index")
         if "M" in self.layer_pattern[:self.n_layers] and not (
                 self.ssm_heads > 0 and self.ssm_head_dim > 0 and self.ssm_state > 0
                 and self.ssm_groups > 0 and self.ssm_heads % self.ssm_groups == 0):
@@ -169,13 +203,14 @@ class MixedStackConfig(MoEConfig):
 class LayerKind(NamedTuple):
     """The sublayers a layer has: its mixer first, then its MLP."""
 
-    attention: str  # "sliding" | "full" | "latent" | "ssm" (a state-space mixer) | "none"
+    attention: str  # "sliding" | "full" | "latent" | "ssm" (a state-space mixer) | "kda" (a delta-rule one) | "none"
     mlp: str        # "dense" | "experts" | "none"
 
     @property
     def code(self) -> str:
         return ({"dense": "d", "experts": "e", "none": "-"}[self.mlp]
-                + {"sliding": "S", "full": "F", "latent": "L", "ssm": "M", "none": "-"}[self.attention])
+                + {"sliding": "S", "full": "F", "latent": "L", "ssm": "M", "kda": "K",
+                   "none": "-"}[self.attention])
 
     @property
     def sublayers(self) -> int:
@@ -199,10 +234,12 @@ def layer_kinds(config: MixedStackConfig) -> List[LayerKind]:
     if c.layer_pattern:
         return [PATTERN_KINDS[character] for character in c.layer_pattern[:c.n_layers]]
     full_at = 0 if c.global_attn_first else c.global_attn_every - 1
-    return [LayerKind("latent" if c.latent_attention
-                      else "full" if i % c.global_attn_every == full_at else "sliding",
+    # the period's one layer, and the others
+    one = "latent" if c.latent_attention else "full"
+    others = "kda" if c.kda_heads else one if c.latent_attention else "sliding"
+    return [LayerKind(one if i % c.global_attn_every == full_at else others,
                       "dense" if i < c.n_dense_layers else "experts")
-            for i in range(c.n_layers)]
+            for i in range(c.first_layer, c.first_layer + c.n_layers)]
 
 
 def stack_runs(kinds: List[LayerKind]) -> List[Run]:
@@ -280,29 +317,34 @@ def _layer_shapes(config: MixedStackConfig, kind: LayerKind) -> Dict[str, Tuple[
     held, shared = c.n_experts_held, c.shared_expert_width
     dense, experts = kind.mlp == "dense", kind.mlp == "experts"
     gated = "we_gate" in c.expert_weights
-    ssm, attention = kind.attention == "ssm", kind.attention not in ("ssm", "none")
+    ssm, delta = kind.attention == "ssm", kind.attention == "kda"
+    attention = kind.attention not in ("ssm", "kda", "none")
     latent, plain = kind.attention == "latent", attention and kind.attention != "latent"
-    q_rank, kv_rank, rope = c.q_lora_rank, c.kv_lora_rank, c.qk_rope_dim
+    q_rank, kv_rank, rope, dv = c.q_lora_rank, c.kv_lora_rank, c.qk_rope_dim, c.value_dim
     heads, inner, conv = c.ssm_heads, c.ssm_heads * c.ssm_head_dim, c.ssm_conv_width
+    kda_inner = c.kda_heads * c.kda_head_dim
+    gate = ((m, c.n_heads), ("embed", "heads")) if c.attn_gate_per_head else (
+        (m, c.n_heads, dh), ("embed", "heads", "head_dim"))
     # (name, whether this layer has the leaf, its shape, initialisation and axes)
     leaves = [
-        ("ln1_scale", attention or ssm, (m,), "ones", (None,)),
+        ("ln1_scale", attention or ssm or delta, (m,), "ones", (None,)),
         ("ln1_post_scale", attention and c.sandwich_norm, (m,), "post", (None,)),
         ("ln2_scale", dense or experts, (m,), "ones", (None,)),
         ("ln2_post_scale", (dense or experts) and c.sandwich_norm, (m,), "post", (None,)),
-        ("wq", plain, (m, c.n_heads, dh), "normal", ("embed", "heads", "head_dim")),
+        # a latent layer with no q latent has q's ONE projection, as a plain layer has
+        ("wq", plain or (latent and not q_rank), (m, c.n_heads, dh), "normal", ("embed", "heads", "head_dim")),
         ("wk", plain, (m, c.kv_heads, dh), "normal", ("embed", "kv_heads", "head_dim")),
         ("wv", plain, (m, c.kv_heads, dh), "normal", ("embed", "kv_heads", "head_dim")),
         # a latent layer: the down-projections and the latents' norms whole on
         # every device of a tensor-parallel group, the up-projections over heads
-        ("wq_a", latent, (m, q_rank), "normal", ("embed", None)),
-        ("q_a_norm_scale", latent, (q_rank,), "ones", (None,)),
-        ("wq_b", latent, (q_rank, c.n_heads, dh), "normal", (None, "heads", "head_dim")),
+        ("wq_a", latent and q_rank > 0, (m, q_rank), "normal", ("embed", None)),
+        ("q_a_norm_scale", latent and q_rank > 0, (q_rank,), "ones", (None,)),
+        ("wq_b", latent and q_rank > 0, (q_rank, c.n_heads, dh), "normal", (None, "heads", "head_dim")),
         ("wkv_a", latent, (m, kv_rank + rope), "normal", ("embed", None)),
         ("kv_a_norm_scale", latent, (kv_rank,), "ones", (None,)),
-        ("wkv_b", latent, (kv_rank, c.n_heads, 2 * dh - rope), "normal", (None, "heads", "head_dim")),
-        ("wg", attention and c.attn_gate, (m, c.n_heads, dh), "normal", ("embed", "heads", "head_dim")),
-        ("wo", attention, (c.n_heads, dh, m), into_residual, ("heads", "head_dim", "embed")),
+        ("wkv_b", latent, (kv_rank, c.n_heads, dh - rope + dv), "normal", (None, "heads", "head_dim")),
+        ("wg", attention and c.attn_gate, gate[0], "normal", gate[1]),
+        ("wo", attention, (c.n_heads, dv, m), into_residual, ("heads", "head_dim", "embed")),
         ("q_norm_scale", attention and c.qk_norm_per_head, (dh,), "ones", (None,)),
         ("k_norm_scale", attention and c.qk_norm_per_head, (dh,), "ones", (None,)),
         # a state-space mixer: [z | x B C | dt] in one projection, whole on every
@@ -316,6 +358,16 @@ def _layer_shapes(config: MixedStackConfig, kind: LayerKind) -> Dict[str, Tuple[
         ("ssm_d", ssm, (heads,), "ones", ("ssm_heads",)),
         ("ssm_norm_scale", ssm, (heads, c.ssm_head_dim), "ones", ("ssm_heads", None)),
         ("ssm_out", ssm, (heads, c.ssm_head_dim, m), into_residual, ("ssm_heads", "head_dim", "embed")),
+        # a delta-rule mixer: [q | k | v | f] in one projection and [beta | gate], one
+        # logit a head each, in another (64 columns beside 16,384 would leave the wide one
+        # no whole number of lane tiles), whole on every device as a state-space mixer's
+        ("kda_in", delta, (m, 4 * kda_inner), "normal", ("embed", None)),
+        ("kda_bg", delta, (m, 2 * c.kda_heads), "normal", ("embed", None)),
+        ("kda_conv_w", delta, (3 * kda_inner, c.kda_conv_kernel), "conv", (None, None)),
+        ("kda_a_log", delta, (c.kda_heads,), "ssm_a_log", ("ssm_heads",)),
+        ("kda_dt_bias", delta, (kda_inner,), "ssm_dt_bias", (None,)),
+        ("kda_norm_scale", delta, (c.kda_head_dim,), "ones", (None,)),
+        ("kda_out", delta, (c.kda_heads, c.kda_head_dim, m), into_residual, ("ssm_heads", "head_dim", "embed")),
         ("w_gate", dense, (m, c.d_ff_dense), "normal", ("embed", "mlp")),
         ("w_up", dense, (m, c.d_ff_dense), "normal", ("embed", "mlp")),
         ("w_down", dense, (c.d_ff_dense, m), into_residual, ("mlp", "embed")),
@@ -497,12 +549,66 @@ def _ssm_sublayer(x, lp, config):
             return x + out, decay_min
 
 
+# inside the root of q's and k's L2 norms
+_KDA_L2_EPS = 1e-6
+
+
+def _kda_sublayer(x, lp, config):
+    """A Kimi-Delta-Attention mixer + residual on (B, S, E), the scope `kda`:
+    [q | k | v | f] = norm(x) W_in and [beta | gate] = norm(x) W_bg, one logit
+    a head each (`kda.in_proj`); q, k and v through a causal depthwise
+    convolution and silu (`kda.conv`, ops/ssd.causal_conv1d with no bias: the
+    state-space mixer's, kernels and rule); q and k L2-normalised a head, q
+    over sqrt(head_dim); the log-decay a channel, `kda_gate_lower_bound` x
+    sigmoid(exp(A_log) (f + dt_bias)) in float32, beta = sigmoid; the chunked
+    delta rule (`kda.chunk`, ops/kda.kda_chunk); an RMS norm over each head's
+    output times sigmoid of the head's ONE gate logit (`kda.gate_norm`); W_out
+    and the residual (`kda.out_proj`). No positions. -> (x, the most negative
+    cumulative log-decay inside a chunk)."""
+    c = config
+    dt = c.dtype
+    b, s, _ = x.shape
+    heads, d = c.kda_heads, c.kda_head_dim
+    inner, by_head = heads * d, (b, s, heads, d)
+    with jax.named_scope("kda"):
+        with jax.named_scope("kda.in_proj"):
+            u = _norm(x, lp["ln1_scale"], None, c.norm, c.norm_eps)
+            projected = jnp.einsum("bse,ef->bsf", u, lp["kda_in"].astype(dt))
+            beta_gate = jnp.einsum("bse,ef->bsf", u, lp["kda_bg"].astype(dt)).astype(jnp.float32)
+            # [q | k | v | f]: the WHOLE array for q's and k's convolution and for v's (two calls: the
+            # kernels take at most 8,192 channels a step), and f; `_gate_xbc_dt`'s reasons
+            for_qk, for_v, f = _gate_xbc_dt(projected, 2 * inner, inner)
+        with jax.named_scope("kda.conv"):
+            taps = lp["kda_conv_w"]
+            q, k = ssd.causal_conv1d(for_qk, taps[:2 * inner], jnp.zeros((2 * inner,), jnp.float32),
+                                     splits=(inner, inner))
+            v = ssd.causal_conv1d(for_v, taps[2 * inner:], jnp.zeros((inner,), jnp.float32), offset=2 * inner)
+        with jax.named_scope("kda.chunk"):
+            def unit(t):    # a head's features over their L2 norm, in float32
+                t = t.reshape(by_head).astype(jnp.float32)
+                return t * jax.lax.rsqrt(jnp.sum(jnp.square(t), axis=-1, keepdims=True) + _KDA_L2_EPS)
+
+            rate = jnp.exp(lp["kda_a_log"].astype(jnp.float32))[:, None]
+            log_decay = c.kda_gate_lower_bound * jax.nn.sigmoid(
+                rate * (f.astype(jnp.float32) + lp["kda_dt_bias"].astype(jnp.float32)).reshape(by_head))
+            out = kda.kda_chunk((unit(q) * d ** -0.5).astype(dt), unit(k).astype(dt), v.reshape(by_head),
+                                log_decay, jax.nn.sigmoid(beta_gate[..., :heads]), chunk=c.kda_chunk)
+            decay_min = jax.lax.stop_gradient(kda.log_decay_chunk_min(log_decay, c.kda_chunk))
+        with jax.named_scope("kda.gate_norm"):
+            out = rmsnorm(out.astype(jnp.float32), lp["kda_norm_scale"],
+                          eps=1e-6 if c.norm_eps is None else c.norm_eps)
+            out = (out * jax.nn.sigmoid(beta_gate[..., heads:])[..., None]).astype(dt)
+        with jax.named_scope("kda.out_proj"):
+            return x + jnp.einsum("bshd,hde->bse", out, lp["kda_out"].astype(dt)), decay_min
+
+
 def _block(x, lp, config, kind: LayerKind, rope_tables, positions, remat_saved=()):
     """One layer on (B, S, E), the sublayers its kind names: x + mixer(norm(x))
-    (attention of either kind, or a state-space mixer), then the same around
-    the MLP (dense or experts), each sublayer's output through a norm of its
-    own where the configuration has them. -> (x, the layer's scalars: an
-    expert layer's, a state-space mixer's `ssm_log_decay_chunk_min`)."""
+    (attention of either kind, a state-space mixer or a delta-rule one), then
+    the same around the MLP (dense or experts), each sublayer's output through
+    a norm of its own where the configuration has them. -> (x, the layer's
+    scalars: an expert layer's, a state-space mixer's
+    `ssm_log_decay_chunk_min`, a delta-rule mixer's `kda_log_decay_chunk_min`)."""
     c = config
     if c.frozen_leaves:
         lp = {name: jax.lax.stop_gradient(w) if name in c.frozen_leaves else w
@@ -513,6 +619,8 @@ def _block(x, lp, config, kind: LayerKind, rope_tables, positions, remat_saved=(
     scalars = {}
     if kind.attention == "ssm":
         x, scalars["ssm_log_decay_chunk_min"] = _ssm_sublayer(x, lp, c)
+    elif kind.attention == "kda":
+        x, scalars["kda_log_decay_chunk_min"] = _kda_sublayer(x, lp, c)
     elif kind.attention != "none":
         x = attention_sublayer(     # the scope `attn.window` or `attn.full`, by the window
             x, lp, c, None if kind.attention == "full" else rope_tables, positions,
@@ -545,8 +653,8 @@ def forward_hidden(
     mean over the expert layers, as is `moe_act_live_share`, the percentage of
     the held ReGLU experts' hidden units that the ReLU leaves non-zero on the
     rows sent here; what the state-space layers report: `ssm_log_decay_chunk_min`
-    of the worst layer). The rotary table is built for the sequence at hand,
-    not for `max_seq`."""
+    of the worst layer, and the delta-rule layers `kda_log_decay_chunk_min`).
+    The rotary table is built for the sequence at hand, not for `max_seq`."""
     c = config
     dt = c.dtype
     b, s = tokens.shape
@@ -579,9 +687,10 @@ def forward_hidden(
     with jax.named_scope("head"):
         x = _norm(x, params["lnf_scale"], None, c.norm, c.norm_eps)
     report = _expert_layers_report([r for r in reports if "load" in r], c, b * s)
-    decays = [jnp.ravel(r["ssm_log_decay_chunk_min"]) for r in reports if "ssm_log_decay_chunk_min" in r]
-    if decays:
-        report["ssm_log_decay_chunk_min"] = jnp.min(jnp.concatenate(decays))
+    for name in ("ssm_log_decay_chunk_min", "kda_log_decay_chunk_min"):
+        decays = [jnp.ravel(r[name]) for r in reports if name in r]
+        if decays:
+            report[name] = jnp.min(jnp.concatenate(decays))
     return x, report
 
 
@@ -592,7 +701,9 @@ def _expert_layers_report(reports: List[Dict[str, jax.Array]], config: MixedStac
     c = config
     if not reports:
         return {}
-    every = {name: jnp.concatenate([jnp.ravel(r[name]) for r in reports]) for name in reports[0]}
+    # a layer's scalars may hold its mixer's too, which not every expert layer has
+    every = {name: jnp.concatenate([jnp.ravel(r[name]) for r in reports])
+             for name in reports[0] if all(name in r for r in reports)}
     out = {"moe_load_max_over_mean": jnp.max(every["load"])}
     if "moe_rows_held" in every:
         rows_held = jnp.mean(every["moe_rows_held"])
@@ -726,6 +837,42 @@ def _ssm_costs(config: MixedStackConfig) -> Dict[str, Any]:
     }
 
 
+# `_SSD_SHARE_OF_PEAK` for the delta rule's chunked form, the XLA einsums of
+# ops/kda (no kernels yet): from the chip at the `train-ling3flash-4k` cell's
+# shapes, one forward of 4,096 tokens, 31.1 GFLOP as `_kda_costs` counts it,
+# in 8.02 ms alone and 8.13 ms a layer in the step (PERF.md section 6, PR 55)
+_KDA_SHARE_OF_PEAK = {"xla_chunked": 0.02}
+
+
+def _kda_costs(config: MixedStackConfig) -> Dict[str, Any]:
+    """`_kda_sublayer`'s part of `block_costs`, a layer and token (whole on
+    every device). The one candidate is the chunked rule's output WITH the
+    float32 states that entered its chunks (`kda_chunk_out`,
+    `kda_chunk_states` of ops/kda): with both the backward pass does not run
+    the rule forward again."""
+    c = config
+    heads, d, chunk = c.kda_heads, c.kda_head_dim, c.kda_chunk
+    inner = heads * d
+    itemsize = jnp.dtype(c.dtype).itemsize
+    # as ops/kda computes it, a token and head: the two pairwise decays and the
+    # products with the inverse (chunk x D each), the inverse's merges, and
+    # five products with a D x D state or its update
+    rule = heads * (2 * chunk * (4 * d + chunk) + 10 * d * d)
+    state = -(-inner * d * 4 // (chunk * itemsize))     # the kept float32 states, in features a token
+    impl = kda.resolve_kda_impl()
+    return {
+        "flops": (2 * c.d_model * (4 * inner + 2 * heads) + 2 * c.kda_conv_kernel * 3 * inner + rule
+                  + 2 * inner * c.d_model),
+        # the norm's output, the sublayer's, the residual; the projection; the
+        # convolved q, k, v; the normalised q and k; the float32 log-decay; the
+        # chunks' states; the rule's output and the gated, normed one
+        "width": (3 * c.d_model + 4 * inner + 3 * inner + 2 * inner + inner * 4 // itemsize + state
+                  + 2 * inner),
+        "candidates": (RematCandidate(("kda_chunk_out", "kda_chunk_states"), inner + state, rule,
+                                      int(rule / _KDA_SHARE_OF_PEAK[impl]), False, ()),),
+    }
+
+
 def block_costs(
     config: MixedStackConfig, seq: int, split: Callable[[str], int] = lambda weight: 1,
 ) -> Dict[str, Any]:
@@ -738,7 +885,8 @@ def block_costs(
     c = config
 
     def kind_costs(kind: LayerKind):
-        mixer = (_ssm_costs(c) if kind.attention == "ssm" else _NO_SUBLAYER if kind.attention == "none"
+        mixer = (_ssm_costs(c) if kind.attention == "ssm" else _kda_costs(c) if kind.attention == "kda"
+                 else _NO_SUBLAYER if kind.attention == "none"
                  else attention_costs(c, seq, split, c.sliding_window if kind.attention == "sliding" else None))
         mlp = (mlp_costs(c, split, c.d_ff_dense) if kind.mlp == "dense"
                else _expert_costs(c, split) if kind.mlp == "experts" else _NO_SUBLAYER)
@@ -777,9 +925,18 @@ def plan(config: MixedStackConfig, batch: int, seq: int) -> Dict[str, Any]:
                    **ssd.gate_norm_plan(batch * seq, inner, c.ssm_groups),
                    **ssd.conv_plan(seq, c.ssm_conv_width, c.ssm_conv_kernel, inner,
                                    (inner, group_states, group_states)))
+    if any(kind.attention == "kda" for kind in kinds):
+        inner = c.kda_heads * c.kda_head_dim
+        convs = [ssd.conv_plan(seq, *sizes)["ssm_conv_impl"] for sizes in (
+            (2 * inner, c.kda_conv_kernel, 0, (inner, inner)), (inner, c.kda_conv_kernel, 2 * inner))]
+        out.update(kda_heads=c.kda_heads, kda_head_dim=c.kda_head_dim, kda_conv_kernel=c.kda_conv_kernel,
+                   kda_gate_lower_bound=c.kda_gate_lower_bound, kda_conv_impl="+".join(sorted(set(convs))),
+                   **kda.kda_plan(c.kda_chunk))
     if c.latent_attention:
         out.update(attn_latent_q_rank=c.q_lora_rank, attn_latent_kv_rank=c.kv_lora_rank,
                    attn_rope_dims=c.rotary_dims, attn_head_dim=c.head_dim)
+        if c.value_dim != c.head_dim:
+            out.update(attn_latent_v_dim=c.value_dim, attn_kernel_head_dim=c.kernel_head_dim)
     elif any(kind.attention == "sliding" for kind in kinds):
         windowed = attention_plan(seq, causal=c.causal, implementation=c.attn_impl,
                                   window=c.sliding_window)

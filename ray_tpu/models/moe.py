@@ -68,6 +68,12 @@ class MoEConfig(TransformerConfig):
     router_score: str = "softmax"
     router_select_bias: bool = False
     route_scale: float = 1.0
+    # group-limited routing: the experts in `route_groups` groups of neighbours,
+    # of which the `route_groups_kept` best (a group's score the sum of its two
+    # largest selection scores) are the only ones the top-k is taken from
+    # (1, 1: no limit)
+    route_groups: int = 1
+    route_groups_kept: int = 1
     # a dense expert of this width on every token, added to the routed sum (0:
     # none): a SwiGLU beside gated experts, relu(up)^2 beside those that are not
     shared_expert_width: int = 0
@@ -328,6 +334,7 @@ def moe_plan(config: "MoEConfig", batch: int, seq: int) -> Dict[str, Any]:
     plan = {
         "moe_impl": impl, "moe_experts": config.n_experts, "moe_top_k": config.top_k,
         "moe_expert_act": config.expert_act,
+        "moe_route_groups": config.route_groups, "moe_route_groups_kept": config.route_groups_kept,
         "moe_rows_per_step": batch * seq * config.top_k, "moe_gmm_tile_rows": tile,
     }
     if config.held_experts is not None:
@@ -394,6 +401,22 @@ def _route(scores, select, config):
     if c.route_scale != 1.0:
         gates = gates * c.route_scale
     return gates, experts
+
+
+def _group_limited(select, config):
+    """The selection scores (..., E) with every expert outside the
+    `route_groups_kept` best of the `route_groups` groups at -inf: a group's
+    score is the sum of its two largest selection scores."""
+    c = config
+    groups, kept, size = c.route_groups, c.route_groups_kept, c.n_experts // c.route_groups
+    if c.n_experts % groups or not 0 < kept <= groups or size < 2 or kept * size < c.top_k:
+        raise ValueError(f"group-limited routing: {c.n_experts} experts in {groups} groups, {kept} kept, "
+                         f"top-{c.top_k}: whole groups of two experts or more, and the kept ones hold the top-k")
+    grouped = select.reshape(*select.shape[:-1], groups, size)
+    score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)                          # (..., groups)
+    _, best = jax.lax.top_k(score, kept)
+    keep = jnp.any(best[..., :, None] == jnp.arange(groups), axis=-2)              # (..., groups)
+    return jnp.where(keep[..., None], grouped, -jnp.inf).reshape(select.shape)
 
 
 def _gshard_experts(h, probs, weights, config):
@@ -704,6 +727,8 @@ def moe_mlp(
         select = None
         if c.router_select_bias:
             select = probs + jax.lax.stop_gradient(lp["expert_bias"].astype(jnp.float32))
+        if c.route_groups > 1:
+            select = _group_limited(probs if select is None else select, c)
     weights = tuple(lp[name].astype(c.dtype) for name in c.expert_weights)
     mesh = _mesh_of(lp["we_up"])
     if expert_parallel(mesh):

@@ -103,12 +103,17 @@ class TransformerConfig:
     # of `kv_lora_rank` that all heads share, and the last `qk_rope_dim` of a
     # head's `head_dim` features carry rotary positions: q's own, and for the
     # keys one head of `qk_rope_dim` projected beside the latent and given to
-    # every head. `v_head_dim` (None: `head_dim`) is stated so that a
-    # configuration whose values are not as wide as its keys is refused by name
+    # every head. `q_lora_rank` 0: no q latent, q comes from the normed stream
+    # in one projection (`wq`). `v_head_dim` (None: `head_dim`): values narrower
+    # than the keys; the kernels take one head size, so q, k and v are padded
+    # with zeros to `kernel_head_dim`, which changes no score and no output
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_rope_dim: int = 0
     v_head_dim: Optional[int] = None
+    # the output gate (`attn_gate`) as ONE logit a head, `wg` (d_model, heads),
+    # and not one a feature
+    attn_gate_per_head: bool = False
     # EVA attention (`eva_window` > 0; ops/eva.py: the EvaByte family): exact
     # causal attention inside a window of `eva_window` positions, one learned
     # summary a chunk of `eva_chunk` positions of everything before the window,
@@ -145,16 +150,21 @@ class TransformerConfig:
                              "needs an untied head matrix")
         if self.norm_unit_offset and self.norm != "rmsnorm":
             raise ValueError("norm_unit_offset is an RMSNorm's (1 + scale)")
+        if self.attn_gate_per_head and not self.attn_gate:
+            raise ValueError("attn_gate_per_head is a form of attn_gate, which is off")
         if self.latent_attention:
-            if self.v_head_dim not in (None, self.head_dim):
+            if not 0 < self.value_dim <= self.head_dim:
                 raise ValueError(
-                    f"latent attention: v_head_dim {self.v_head_dim} is not the q/k head size "
-                    f"{self.head_dim}; the flash kernels take one head size")
-            if not (self.q_lora_rank > 0 and 0 < self.qk_rope_dim < self.head_dim
+                    f"latent attention: v_head_dim {self.v_head_dim} is not in (0, the q/k head size "
+                    f"{self.head_dim}]: values are padded up to the keys' width, never cut")
+            if not (self.q_lora_rank >= 0 and 0 < self.qk_rope_dim < self.head_dim
                     and self.qk_rope_dim % 2 == 0 and self.kv_heads == self.n_heads):
                 raise ValueError(
-                    "latent attention: q_lora_rank > 0, an even qk_rope_dim under the head size "
+                    "latent attention: q_lora_rank >= 0, an even qk_rope_dim under the head size "
                     "and as many key-value heads as query heads are what the program runs")
+        elif self.v_head_dim not in (None, self.head_dim):
+            raise ValueError(f"v_head_dim {self.v_head_dim}: values narrower than the keys are a "
+                             "latent-attention layer's")
 
     @property
     def kv_heads(self) -> int:
@@ -167,6 +177,18 @@ class TransformerConfig:
     @property
     def latent_attention(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def value_dim(self) -> int:
+        """The features of a head's values (and of the attention output a head)."""
+        return self.head_dim if self.v_head_dim is None else self.v_head_dim
+
+    @property
+    def kernel_head_dim(self) -> int:
+        """The one head size the attention kernels are handed: `head_dim`, or
+        where the values are narrower than the keys the next multiple of 128
+        lanes, to which q, k and v are padded with zeros."""
+        return self.head_dim if self.value_dim == self.head_dim else -(-self.head_dim // 128) * 128
 
     @property
     def eva_attention(self) -> bool:
@@ -350,28 +372,31 @@ def attention_sublayer(
 
 
 def _latent_qkv(h, lp, config, rope_tables, positions):
-    """q, k, v (B, H, S, head_dim) of a latent-attention layer from the normed
-    stream h (B, S, E): the scope `attn.latent`. q = [q_nope | rot(q_rope)] a
-    head from the normed q latent; k = [k_nope | rot(k_rope)] with k_nope and v
-    a head from the normed key-value latent and the ONE rotary key head given
-    to all of them. The two normed latents and the rotary key part are named
-    (`attn_latent_*`): a checkpoint that keeps them recomputes the
-    up-projections alone."""
+    """q, k (B, H, S, head_dim) and v (B, H, S, value_dim) of a latent-attention
+    layer from the normed stream h (B, S, E): the scope `attn.latent`. q =
+    [q_nope | rot(q_rope)] a head from the normed q latent (or, with no q
+    latent, from h in the one projection `wq`); k = [k_nope | rot(k_rope)] with
+    k_nope and v a head from the normed key-value latent and the ONE rotary
+    key head given to all of them. The two normed latents and the rotary key
+    part are named (`attn_latent_*`): a checkpoint that keeps them recomputes
+    the up-projections alone."""
     c = config
     dt = c.dtype
     nope = c.head_dim - c.qk_rope_dim
     kw = {} if c.norm_eps is None else {"eps": c.norm_eps}
     cos, sin = rope_tables
     with jax.named_scope("attn.latent"):
-        q_latent = rmsnorm(jnp.einsum("bse,er->bsr", h, lp["wq_a"].astype(dt)),
-                           lp["q_a_norm_scale"], **kw)
-        q_latent = checkpoint_name(q_latent, "attn_latent_q")
+        if c.q_lora_rank:
+            q_latent = rmsnorm(jnp.einsum("bse,er->bsr", h, lp["wq_a"].astype(dt)),
+                               lp["q_a_norm_scale"], **kw)
+            q_latent = checkpoint_name(q_latent, "attn_latent_q")
         kv_a = jnp.einsum("bse,er->bsr", h, lp["wkv_a"].astype(dt))
         kv_latent = checkpoint_name(
             rmsnorm(kv_a[..., :c.kv_lora_rank], lp["kv_a_norm_scale"], **kw), "attn_latent_kv")
         k_rope = checkpoint_name(kv_a[..., c.kv_lora_rank:], "attn_latent_k_rope")
-        q = apply_rope(jnp.einsum("bsr,rhd->bhsd", q_latent, lp["wq_b"].astype(dt)),
-                       cos, sin, positions, rotary_dims=c.qk_rope_dim)
+        q = (jnp.einsum("bsr,rhd->bhsd", q_latent, lp["wq_b"].astype(dt)) if c.q_lora_rank
+             else jnp.einsum("bse,ehd->bhsd", h, lp["wq"].astype(dt)))
+        q = apply_rope(q, cos, sin, positions, rotary_dims=c.qk_rope_dim)
         # the up-projection's two parts as two matmuls of the split WEIGHT: the
         # values leave theirs as the kernel takes them
         wkv_b = lp["wkv_b"].astype(dt)
@@ -433,10 +458,21 @@ def _attention_sublayer(x, lp, config, rope_tables, positions, window, remat_sav
                                  chunk=c.eva_chunk, implementation=c.attn_impl)
         else:
             attend = flash_attention_kept if "attn_lse" in remat_saved else flash_attention
-            attn = attend(q, k, v, causal=c.causal, window=window, implementation=c.attn_impl)
+            how = {}
+            if c.kernel_head_dim != c.head_dim:
+                # values narrower than the keys: one head size for the kernels, by zeros
+                # that change no score (the scale stays the keys' own) and no output
+                q, k, v = (jnp.pad(t, ((0, 0),) * 3 + ((0, c.kernel_head_dim - t.shape[-1]),))
+                           for t in (q, k, v))
+                how["sm_scale"] = 1.0 / math.sqrt(c.head_dim)
+            attn = attend(q, k, v, causal=c.causal, window=window, implementation=c.attn_impl, **how)
+            attn = attn[..., :c.value_dim]
     if c.attn_gate:
         with jax.named_scope("attn.proj"):
-            (gate,) = column_parallel(h, ("bse,ehd->bhsd", lp["wg"]))
+            if c.attn_gate_per_head:    # one logit a head
+                gate = jnp.einsum("bse,eh->bhs", h, lp["wg"].astype(dt))[..., None]
+            else:
+                (gate,) = column_parallel(h, ("bse,ehd->bhsd", lp["wg"]))
     with jax.named_scope("attn.out"):
         if c.attn_gate:
             attn = attn * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(dt)
@@ -565,31 +601,37 @@ def _kept_kernel_candidate(scores: int, heads: int, q_width: int, itemsize: int,
 def _latent_attention_costs(config: TransformerConfig, seq: int, split: Callable[[str], int]):
     """`attention_costs` of a latent-attention layer: the five matmuls (the
     two down-projections whole on every device, the up-projections and the
-    output's over the heads it holds), and beside the other kinds' two
-    candidates the normed latents with the rotary key part: `q_lora_rank +
-    kv_lora_rank + qk_rope_dim` features a token, after which the backward
-    repeats the up-projections alone."""
+    output's over the heads it holds; with no q latent, q's one projection),
+    and beside the other kinds' two candidates the normed latents with the
+    rotary key part: `q_lora_rank + kv_lora_rank + qk_rope_dim` features a
+    token, after which the backward repeats the up-projections alone. The
+    kernels' operands and output are `kernel_head_dim` wide a head (the head
+    size, or values narrower than the keys padded up)."""
     c = config
-    heads, itemsize = c.n_heads // split("wq_b"), jnp.dtype(c.dtype).itemsize
+    q_weight = "wq_b" if c.q_lora_rank else "wq"
+    heads, itemsize = c.n_heads // split(q_weight), jnp.dtype(c.dtype).itemsize
     q_width, nope = heads * c.head_dim, c.head_dim - c.qk_rope_dim
+    kernel_width, out_width = heads * c.kernel_head_dim, heads * c.value_dim
     latents = c.q_lora_rank + c.kv_lora_rank + c.qk_rope_dim
     down = 2 * c.d_model * latents
-    up = 2 * (c.q_lora_rank * q_width + c.kv_lora_rank * heads * (nope + c.head_dim))
-    out_proj = 2 * q_width * c.d_model
-    scores = 4 * q_width * (seq // 2 if c.causal else seq)
+    up = 2 * ((c.q_lora_rank or c.d_model) * q_width + c.kv_lora_rank * heads * (nope + c.value_dim))
+    out_proj = 2 * out_width * c.d_model
+    scores = 2 * (q_width + out_width) * (seq // 2 if c.causal else seq)
+    gate = 2 * c.d_model * (heads if c.attn_gate_per_head else out_width) if c.attn_gate else 0
+    named = (("attn_latent_q",) if c.q_lora_rank else ()) + ("attn_latent_kv", "attn_latent_k_rope")
     return {
-        "flops": down + up + scores + out_proj,
+        "flops": down + up + scores + gate + out_proj,
         # the norm's output, the sublayer's, the residual; both latents before
         # and after their norms; q before and after its rotation, the keys'
-        # part without positions, k, v and the attention output
-        "width": (c.d_model + 2 * c.d_model // split("stream") + 2 * latents + 5 * q_width
-                  + heads * nope),
+        # part without positions, k, v and the attention output (the last four
+        # as the kernels take them)
+        "width": (c.d_model + 2 * c.d_model // split("stream") + 2 * latents + q_width
+                  + 4 * kernel_width + heads * nope),
         "candidates": (
-            _kept_kernel_candidate(scores, heads, q_width, itemsize, "full"),
+            _kept_kernel_candidate(scores, heads, kernel_width, itemsize, "full"),
             RematCandidate(("attn_residual",), c.d_model // split("stream"), out_proj, out_proj,
-                           split("wq_b") > 1, ()),
-            RematCandidate(("attn_latent_q", "attn_latent_kv", "attn_latent_k_rope"),
-                           latents, down, down, False, ()),
+                           split(q_weight) > 1, ()),
+            RematCandidate(named, latents, down, down, False, ()),
         ),
     }
 

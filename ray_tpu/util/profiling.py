@@ -682,13 +682,18 @@ def roofline(cost: StepCost, step_time_s: float) -> Dict[str, Any]:
 # `ssm.in_proj` (norm and the one projection), `ssm.conv` (the causal
 # depthwise convolution and its silu), `ssm.scan` (the step's softplus and
 # the chunked selective scan), `ssm.gate_norm` (the gate and the norm a
-# group) and `ssm.out_proj` (the projection and the residual).
+# group) and `ssm.out_proj` (the projection and the residual); `kda` a
+# delta-rule mixer whole, and inside it `kda.in_proj` (norm and the two
+# projections), `kda.conv` (the convolutions of q, k and v and their silu),
+# `kda.chunk` (the L2 norms, the gate's log-decay, beta and the chunked delta
+# rule), `kda.gate_norm` (the norm a head and its gate) and `kda.out_proj`.
 STEP_SCOPES: Tuple[str, ...] = (
     "steplog.fwd_bwd_compute", "steplog.optimizer_update",
     "embed",
     "attn.full", "attn.window", "attn.proj", "attn.latent", "attn.kernel", "attn.out",
     "attn.eva", "attn.eva.pool", "attn.eva.local", "attn.eva.far", "attn.eva.merge",
     "ssm", "ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.gate_norm", "ssm.out_proj",
+    "kda", "kda.in_proj", "kda.conv", "kda.chunk", "kda.gate_norm", "kda.out_proj",
     "mlp",
     "moe", "moe.route", "moe.select", "moe.dispatch", "moe.experts", "moe.combine", "moe.passes",
     "moe.shared",

@@ -1,0 +1,84 @@
+"""ops/kda.py: the chunked delta rule with a decay a channel against the
+recurrence one position at a time, values and gradients, float32 on the CPU."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.ops import kda
+
+
+def _inputs(seed, b=2, s=128, h=2, d_k=16, d_v=8, gate=None, dtype=jnp.float32, same_keys=False):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    q = jax.random.normal(keys[0], (b, s, h, d_k))
+    k = jax.random.normal(keys[1], (b, 1 if same_keys else s, h, d_k))
+    k = jnp.broadcast_to(k, (b, s, h, d_k))
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) / np.sqrt(d_k)
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = jax.random.normal(keys[2], (b, s, h, d_v))
+    if gate is None:        # log-decays over the whole range of the bounded gate
+        a = -5.0 * jax.nn.sigmoid(3.0 * jax.random.normal(keys[3], (b, s, h, d_k)))
+    else:
+        a = jnp.full((b, s, h, d_k), gate)
+    beta = jax.nn.sigmoid(2.0 * jax.random.normal(keys[4], (b, s, h)))
+    return q.astype(dtype), k.astype(dtype), v.astype(dtype), a, beta
+
+
+def _weighted(fn, weights):
+    return lambda *inputs: jnp.sum(fn(*inputs) * weights)
+
+
+@pytest.mark.parametrize("case", ["mixed_gates", "at_the_bound", "no_decay", "same_keys"])
+def test_chunked_matches_the_recurrence_in_values_and_gradients(case):
+    how = {"mixed_gates": {}, "at_the_bound": {"gate": -5.0}, "no_decay": {"gate": 0.0},
+           "same_keys": {"same_keys": True, "gate": -0.01}}[case]
+    inputs = _inputs(3, **how)
+    weights = jax.random.normal(jax.random.PRNGKey(9), (*inputs[0].shape[:3], inputs[2].shape[-1]))
+    chunked = jax.jit(lambda *t: kda.kda_chunk(*t, chunk=64))
+    plain = jax.jit(lambda *t: kda.kda_reference(*t)[0])
+    got, want = chunked(*inputs), plain(*inputs)
+    assert bool(jnp.all(jnp.isfinite(got)))
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+    grads = jax.jit(jax.grad(_weighted(lambda *t: kda.kda_chunk(*t, chunk=64), weights), argnums=range(5)))(*inputs)
+    wanted = jax.jit(jax.grad(_weighted(lambda *t: kda.kda_reference(*t)[0], weights), argnums=range(5)))(*inputs)
+    for name, g, w in zip("q k v a beta".split(), grads, wanted):
+        assert bool(jnp.all(jnp.isfinite(g))), name
+        scale = float(jnp.max(jnp.abs(w))) + 1e-6
+        np.testing.assert_allclose(g / scale, w / scale, atol=3e-4, err_msg=name)
+
+
+def test_a_whole_chunk_at_the_bound_underflows_and_stays_finite():
+    # -5 a position is -320 over a chunk: e^{-A} would be e^{320}
+    q, k, v, a, beta = _inputs(5, s=192, gate=-5.0)
+    assert float(kda.log_decay_chunk_min(a, 64)) == pytest.approx(-320.0)
+    out, grads = jax.jit(jax.value_and_grad(
+        lambda *t: jnp.sum(jnp.square(kda.kda_chunk(*t, chunk=64))), argnums=range(5)))(q, k, v, a, beta)
+    assert np.isfinite(float(out)) and all(bool(jnp.all(jnp.isfinite(g))) for g in grads)
+
+
+def test_a_chunk_that_is_one_sub_block_and_half_precision_inputs():
+    inputs = _inputs(7, s=64, d_k=8, d_v=8)
+    np.testing.assert_allclose(kda.kda_chunk(*inputs, chunk=8), kda.kda_reference(*inputs)[0],
+                               rtol=2e-4, atol=2e-5)
+    half = _inputs(7, s=64, dtype=jnp.bfloat16)
+    out = kda.kda_chunk(*half, chunk=32)
+    assert out.dtype == jnp.bfloat16
+    np.testing.assert_allclose(out.astype(jnp.float32), kda.kda_reference(*half)[0], atol=0.05)
+
+
+def test_the_unit_lower_inverse_is_exact_where_a_series_would_cancel():
+    lower = jnp.tril(jnp.ones((3, 64, 64)), -1)       # every key the same, beta 1, no decay
+    inverse = kda._unit_lower_inverse(lower, 16)
+    np.testing.assert_allclose(inverse @ (jnp.eye(64) + lower), jnp.broadcast_to(jnp.eye(64), (3, 64, 64)),
+                               atol=1e-5)
+
+
+def test_refusals_and_the_plan():
+    q, k, v, a, beta = _inputs(1, s=96)
+    with pytest.raises(ValueError, match="no multiple of the chunk"):
+        kda.kda_chunk(q, k, v, a, beta, chunk=64)
+    with pytest.raises(ValueError, match="unknown kda implementation"):
+        kda.kda_chunk(q, k, v, a, beta, chunk=32, implementation="pallas")
+    assert kda.kda_plan() == {"kda_impl": "xla_chunked", "kda_chunk": 64, "kda_subchunk": 16}
+    assert kda.kda_plan(24)["kda_subchunk"] == 24

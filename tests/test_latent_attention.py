@@ -214,8 +214,9 @@ def test_a_latent_layer_under_tp_2_matches_one_device():
 
 
 def test_what_is_not_run_is_refused_by_name():
-    with pytest.raises(ValueError, match="v_head_dim 12"):
-        tiny_latent(v_head_dim=12)
+    with pytest.raises(ValueError, match="v_head_dim 20"):      # wider than the keys; narrower ones are padded (PR 55)
+        tiny_latent(v_head_dim=20)
+    assert tiny_latent(v_head_dim=12).kernel_head_dim == 128 and tiny_latent().kernel_head_dim == 16
     with pytest.raises(ValueError, match="qk_rope_dim"):
         tiny_latent(qk_rope_dim=16)
     with pytest.raises(ValueError, match="one multi-token prediction module"):

@@ -145,3 +145,55 @@ def test_evabyte_cell_step_runs_the_kernels_once_a_shard_and_compiles(as_tpu, mo
     parameters = sum(x.size for x in jax.tree.leaves(state.params))
     assert memory.argument_size_in_bytes / GIB == pytest.approx(12 * parameters / 4 / GIB, abs=0.05)
     assert memory.temp_size_in_bytes / GIB == pytest.approx(15.4, abs=0.8)
+
+
+def test_ling3flash_cell_step_runs_the_delta_rule_under_its_scopes_and_compiles(as_tpu, monkeypatch, v5e):
+    """`train-ling3flash-4k`'s whole step (1 x 4,097 tokens) for one described
+    v5e chip of 15.75 GiB: `dK` then `eK eK eK eL eK eK`, all seven unrolled.
+    The six delta-rule mixers' five scopes lie inside `kda`; their convolutions
+    are ops/ssd's kernels (q with k in one call, v in another, read out of the
+    in-projection as it is); the one latent layer runs the causal flash kernels
+    at 256 (192-wide keys and 128-wide values padded with zeros); the expert
+    layers the grouped matmuls of a held layer; the fused head takes the whole
+    sequence as its chunk."""
+    from ray_tpu.models import model_family
+    from ray_tpu.ops import losses
+    from ray_tpu.train.lm import make_train_step
+    from ray_tpu.util import profiling
+
+    mesh = build_mesh(MeshSpec(), devices=[as_tpu])
+    config, opt, state, shardings, tokens = _cell_step_shapes(
+        "ling-3.0-flash-train-1chip", mesh, (1, 4097))
+    monkeypatch.setattr(losses, "device_hbm_bytes", lambda: int(15.75 * GIB))
+    step = make_train_step(config, opt, mesh, state_shardings=shardings)
+    plan = step.remat_plan_for(tokens.shape, state)
+    assert plan["remat"] in ("whole_block", "selective")
+    assert set(plan["remat_saved"]) <= {"kda_chunk_out", "kda_chunk_states", "attn_out", "attn_lse",
+                                        "attn_residual", "attn_latent_kv", "attn_latent_k_rope",
+                                        "mlp_up", "mlp_gate"}
+    assert step.loss_chunk_for(tokens.shape, state) == 4096
+    said = model_family(config).plan(config, 1, 4096)
+    assert said["layer_kinds"] == "dK eK eK eK eL eK eK"
+    assert (said["kda_impl"], said["kda_chunk"], said["kda_subchunk"], said["kda_conv_impl"]) == (
+        "xla_chunked", 64, 16, "pallas")
+    assert (said["kda_heads"], said["kda_head_dim"], said["kda_gate_lower_bound"]) == (32, 128, -5.0)
+    assert (said["attn_latent_q_rank"], said["attn_latent_v_dim"], said["attn_kernel_head_dim"]) == (0, 128, 256)
+    assert (said["moe_route_groups"], said["moe_route_groups_kept"], said["moe_experts_held"]) == (8, 4, 8)
+    compiled = step.lower(state, {"tokens": tokens}).compile()
+    # one latent layer: the forward kernel (and its recomputation unless its output is kept), one backward
+    assert _kernels_named(compiled, "flash_bwd_dkv_dq") == 1 and "flash_win" not in compiled.as_text()
+    # six mixers x two convolutions (q with k, v), forward and recomputed; one backward each
+    assert _kernels_named(compiled, "ssm_conv_fwd") == 6 * 2 * 2 and _kernels_named(compiled, "ssm_conv_bwd") == 6 * 2
+    assert _kernels_named(compiled, "moe_gmm_fwd") > 0
+    _, table = profiling.program_ops_table(profiling._module_text(compiled))
+    for scopes, _, _ in table["ssm_conv_fwd"] + table["ssm_conv_bwd"]:
+        assert {"kda", "kda.conv"} <= set(scopes), scopes
+    pairs = {(scope, pass_) for instances in table.values() for scopes, pass_, _ in instances for scope in scopes}
+    for scope in ("kda", "kda.in_proj", "kda.conv", "kda.chunk", "kda.gate_norm", "kda.out_proj",
+                  "attn.full", "attn.latent", "moe", "mlp", "head"):
+        assert {(scope, "fwd"), (scope, "bwd")} <= pairs, scope
+    memory = compiled.memory_analysis()
+    total = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    print("ling3flash step: arguments", memory.argument_size_in_bytes / GIB, "temporaries",
+          memory.temp_size_in_bytes / GIB, "plan", plan)
+    assert total < 15.75 * GIB
