@@ -592,7 +592,8 @@ def _kda_sublayer(x, lp, config):
             log_decay = c.kda_gate_lower_bound * jax.nn.sigmoid(
                 rate * (f.astype(jnp.float32) + lp["kda_dt_bias"].astype(jnp.float32)).reshape(by_head))
             out = kda.kda_chunk((unit(q) * d ** -0.5).astype(dt), unit(k).astype(dt), v.reshape(by_head),
-                                log_decay, jax.nn.sigmoid(beta_gate[..., :heads]), chunk=c.kda_chunk)
+                                log_decay, jax.nn.sigmoid(beta_gate[..., :heads]), chunk=c.kda_chunk,
+                                lower_bound=c.kda_gate_lower_bound)
             decay_min = jax.lax.stop_gradient(kda.log_decay_chunk_min(log_decay, c.kda_chunk))
         with jax.named_scope("kda.gate_norm"):
             out = rmsnorm(out.astype(jnp.float32), lp["kda_norm_scale"],
@@ -837,11 +838,14 @@ def _ssm_costs(config: MixedStackConfig) -> Dict[str, Any]:
     }
 
 
-# `_SSD_SHARE_OF_PEAK` for the delta rule's chunked form, the XLA einsums of
-# ops/kda (no kernels yet): from the chip at the `train-ling3flash-4k` cell's
-# shapes, one forward of 4,096 tokens, 31.1 GFLOP as `_kda_costs` counts it,
-# in 8.02 ms alone and 8.13 ms a layer in the step (PERF.md section 6, PR 55)
-_KDA_SHARE_OF_PEAK = {"xla_chunked": 0.02}
+# `_SSD_SHARE_OF_PEAK` for the delta rule's chunked form, by the form that
+# runs (`ops/kda.resolve_kda_impl`): from the chip at the `train-ling3flash-4k`
+# cell's shapes, one forward of 4,096 tokens, 31.1 GFLOP as `_kda_costs` counts
+# it. The XLA einsums: 8.02 ms alone and 8.13 ms a layer in the step (PERF.md
+# section 6, PR 55). The kernel `kda_fwd`, which is what keeping both names
+# spares: 1.86 ms a layer in the step, writing the states (PERF.md section 5
+# and 6, PR 56)
+_KDA_SHARE_OF_PEAK = {"xla_chunked": 0.02, "pallas": 0.085}
 
 
 def _kda_costs(config: MixedStackConfig) -> Dict[str, Any]:
@@ -859,7 +863,7 @@ def _kda_costs(config: MixedStackConfig) -> Dict[str, Any]:
     # five products with a D x D state or its update
     rule = heads * (2 * chunk * (4 * d + chunk) + 10 * d * d)
     state = -(-inner * d * 4 // (chunk * itemsize))     # the kept float32 states, in features a token
-    impl = kda.resolve_kda_impl()
+    impl = kda.resolve_kda_impl(chunk=chunk, d_k=d, d_v=d, lower_bound=c.kda_gate_lower_bound)
     return {
         "flops": (2 * c.d_model * (4 * inner + 2 * heads) + 2 * c.kda_conv_kernel * 3 * inner + rule
                   + 2 * inner * c.d_model),
@@ -931,7 +935,8 @@ def plan(config: MixedStackConfig, batch: int, seq: int) -> Dict[str, Any]:
             (2 * inner, c.kda_conv_kernel, 0, (inner, inner)), (inner, c.kda_conv_kernel, 2 * inner))]
         out.update(kda_heads=c.kda_heads, kda_head_dim=c.kda_head_dim, kda_conv_kernel=c.kda_conv_kernel,
                    kda_gate_lower_bound=c.kda_gate_lower_bound, kda_conv_impl="+".join(sorted(set(convs))),
-                   **kda.kda_plan(c.kda_chunk))
+                   **kda.kda_plan(c.kda_chunk, heads=c.kda_heads, d_k=c.kda_head_dim, d_v=c.kda_head_dim,
+                                  lower_bound=c.kda_gate_lower_bound))
     if c.latent_attention:
         out.update(attn_latent_q_rank=c.q_lora_rank, attn_latent_kv_rank=c.kv_lora_rank,
                    attn_rope_dims=c.rotary_dims, attn_head_dim=c.head_dim)

@@ -51,43 +51,130 @@ and the carried state are float32.
 Differentiated, the op carries its own backward pass (`custom_vjp`): it keeps
 q, k, v, a, beta, the state that entered each chunk and the output (named
 `kda_chunk_states` and `kda_chunk_out` for a checkpoint policy around the
-caller), builds the chunks' parts again, walks the chunks in reverse with the
-state's cotangent (two small matmuls a step, as forward) and transposes the
-parts of every chunk at once. ONE form today, XLA einsums everywhere
-("xla_chunked"; `kda_plan` reports it): kernels follow ops/ssd's `_resolve`
-rule when they are written.
+caller: with both kept, its backward pass does not run the rule forward a
+second time), builds the chunks' parts again, walks the chunks in reverse with
+the state's cotangent and transposes the parts.
+
+Two forms compute it, chosen by ops/ssd's one static rule on the backend, the
+shapes and the context mesh (`resolve_kda_impl` through `ssd._resolve`;
+`kda_plan` reports it; no flag, and a form is never tried and swapped for the
+other):
+
+- "pallas", on a TPU at the sizes the kernels tile: two Mosaic kernels under
+  one `custom_vjp`, `kda_fwd` and `kda_bwd`, bound through ONE primitive
+  lowered out of line (`kda_p`). A grid step is ONE chunk of a block of heads
+  (`_KERNEL_HEADS`: at most 8, the sweep's winner): it builds the chunk's parts
+  in VMEM (the cumulative log-decay as a triangular matmul, the two pairwise
+  matrices, T, W, U'), then does one step of the walk against the block's
+  float32 states, which live in VMEM scratch from a sequence's first chunk to
+  its last. It reads q, k, v, a as (B, S, H D), a head one 128-lane tile, and
+  writes o and, differentiated, the float32 state that entered the chunk (134
+  MB a layer of 4,096 tokens of 32 heads, what the XLA form keeps). The
+  backward is ONE kernel, the same walk reversed with the states' cotangents
+  in scratch: it builds the parts again from the arguments, transposes the
+  chunk's step and its parts, takes a's reverse cumulative sum as a
+  triangular matmul and writes dq, dk, dv, da, dbeta; it keeps nothing of its
+  own. Every array of a step carries the block's heads side by side, so the
+  long chain of dependent steps of one head (cumulative sum, exponentials,
+  sub-blocks, 15 eliminations, two merges, T's products, the state) is every
+  head's at once: a head after a head, the same work took 1.7 times as long.
+  THE DIAGONAL SUB-BLOCKS ARE FACTORED there, (x_i e^{A_i - R + 40}) . (k_j
+  e^{R - A_j - 40}) with R the sub-block's own start, both factors float32
+  and the product at "highest" precision: the pairwise form's (16, 16, 128)
+  exponentials need a lane reduction a pair, which the vector unit pays 256
+  times a head and chunk, where the factored form is one (2 C, D) x (D, C)
+  product a head. Every term carries float32's relative error, as the
+  pairwise form's does; the exponents A_i - R lie in [-80, 0] under the gate's
+  bound and are centred (+-40), so that neither factor times a small feature
+  leaves float32's normal numbers (e^{-80} q_d does: 0.004 of the output at
+  the bound, measured). So the kernels count on the gate's lower bound:
+  `kda_chunk` takes it (`lower_bound`, the configuration's
+  `kda_gate_lower_bound`), and bound x 16 under -87 does not tile. Everything
+  else is float32 or bfloat16 exactly where the XLA form is.
+- "xla_chunked", everywhere else (every CPU run) and what the kernels are
+  compared with: the einsums above, the diagonal sub-blocks pairwise.
+
+What the kernels tile, and nothing else (other sizes run the XLA form, by the
+rule; a kernel asked for by name there is refused by name): key and value
+heads of 128 features, the chunk of 64 with its sub-block of 16, a gate whose
+lower bound x 16 stays over -87, one device or a `shard_map` around them.
 
 A sequence that is no multiple of the chunk is refused by name.
 """
 
 from __future__ import annotations
 
+import functools
+import math
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.extend.core import Primitive
+from jax.interpreters import mlir
+
+from . import ssd
 
 F32 = jnp.float32
-_IMPLEMENTATIONS = ("xla_chunked",)
+_IMPLEMENTATIONS = ("xla_chunked", "pallas")
 CHUNK = 64
 # the sub-block inside which decays are formed pairwise and, across which,
 # relative to the sub-block's start: `SUBCHUNK` x the gate's lower bound (5)
 # stays inside float32's exponent (80 < 88)
 SUBCHUNK = 16
+LOWER_BOUND = -5.0
 _HIGHEST = jax.lax.Precision.HIGHEST
+# What the kernels tile: a head's key and value features one 128-lane tile, and
+# at most this many heads a grid step (the sweep on the chip: PERF.md section 6,
+# PR 56). The kernels' diagonal sub-blocks hold e^{R - A_j} over a sub-block, so
+# the gate's lower bound x `SUBCHUNK` has to stay inside float32's exponent.
+_LANES = ssd._LANES
+_KERNEL_HEADS = 8
+_KERNEL_EXPONENT = -87.0
+_KERNEL_SHIFT = -LOWER_BOUND * SUBCHUNK / 2
+_KERNEL_UP = math.exp(_KERNEL_SHIFT)
+_VMEM_LIMIT = 64 * 1024 * 1024
 
 
-def resolve_kda_impl(implementation: Optional[str] = None) -> str:
-    """The implementation `kda_chunk` runs: "xla_chunked", the one there is."""
-    if implementation not in (None, *_IMPLEMENTATIONS):
-        raise ValueError(f"unknown kda implementation: {implementation!r}")
-    return _IMPLEMENTATIONS[0]
+def _kernels_tile(chunk: int, d_k: int, d_v: int, lower_bound: float) -> bool:
+    return (chunk == CHUNK and d_k == _LANES and d_v == _LANES
+            and lower_bound <= 0 and lower_bound * SUBCHUNK >= _KERNEL_EXPONENT)
 
 
-def kda_plan(chunk: int = CHUNK, implementation: Optional[str] = None) -> dict:
-    """What `kda_chunk` resolves to, for callers that report it."""
-    return {"kda_impl": resolve_kda_impl(implementation), "kda_chunk": chunk, "kda_subchunk": _subchunk(chunk)}
+def _heads_per_step(heads: int) -> int:
+    """The heads a grid step of the kernels takes: the largest divisor of `heads` at most `_KERNEL_HEADS`."""
+    return next(n for n in range(min(_KERNEL_HEADS, heads), 0, -1) if heads % n == 0)
+
+
+def resolve_kda_impl(implementation: Optional[str] = None, *, chunk: int = CHUNK, d_k: int = 0, d_v: int = 0,
+                     lower_bound: float = LOWER_BOUND) -> str:
+    """The implementation `kda_chunk` runs: "pallas" (the kernels `kda_fwd` /
+    `kda_bwd`) or "xla_chunked" (the einsums of `_chunked`), by ops/ssd's one
+    rule (`ssd._resolve`): with nothing asked, "pallas" on a TPU, on one
+    device or inside a `shard_map`, at the sizes the kernels tile (a chunk of
+    64, key and value heads of 128 features, a gate whose lower bound x the
+    sub-block of 16 stays over -87) and "xla_chunked" elsewhere; a kernel
+    asked for by name where it does not tile is refused by name."""
+    return ssd._resolve(implementation, _IMPLEMENTATIONS, _kernels_tile(chunk, d_k, d_v, lower_bound), "kda",
+                        f"kda_chunk: the kernels do not tile a chunk of {chunk}, key heads of {d_k} and value "
+                        f"heads of {d_v} under a gate whose lower bound is {lower_bound} a position")
+
+
+def kda_plan(chunk: int = CHUNK, implementation: Optional[str] = None, *, heads: int = 0, d_k: int = 0,
+             d_v: int = 0, lower_bound: float = LOWER_BOUND) -> dict:
+    """What `kda_chunk` resolves to for `heads` heads of `d_k` key and `d_v`
+    value features, for callers that report it: the implementation's name, the
+    chunk and its sub-block, the `pallas_call`s a differentiated rule makes,
+    the heads a grid step takes and the float32 state a grid step holds in
+    VMEM scratch (none of the three for the XLA form)."""
+    impl = resolve_kda_impl(implementation, chunk=chunk, d_k=d_k, d_v=d_v, lower_bound=lower_bound)
+    per_step = _heads_per_step(heads) if impl == "pallas" else 0
+    return {"kda_impl": impl, "kda_chunk": chunk, "kda_subchunk": _subchunk(chunk),
+            "kda_kernels": 2 if impl == "pallas" else 0, "kda_heads_per_step": per_step,
+            "kda_state_bytes": per_step * d_k * d_v * 4}
 
 
 def _subchunk(chunk: int) -> int:
@@ -249,17 +336,325 @@ def _chunked_bwd(kept, d_out):
 _chunked.defvjp(_chunked_fwd, _chunked_bwd)
 
 
+# ------------------------------------------------------------------ kernels
+#
+# `kda_fwd` and `kda_bwd`: a grid over (sequence of the batch, block of heads,
+# chunk), the chunks innermost and in order (the backward's reversed), the
+# block's float32 states (their cotangents) in VMEM scratch from a sequence's
+# first chunk to its last, TRANSPOSED (D_v, D_k): the decay of a state is a
+# channel of its keys, a row (1, D_k) over the lanes as the cumulative
+# log-decay's last position is. q, k, v, a, o and their cotangents are (B, S,
+# H D) as the mixer has them, a head one 128-lane tile; beta comes (B, H /
+# heads a step, S, heads a step), a head a column. A step builds in VMEM what
+# `_chunk_parts` builds and does one step of `_states` and `_read_out`;
+# nothing of a chunk but o (and, differentiated, the state that entered it)
+# reaches HBM.
+#
+# The pairwise decays inside a diagonal sub-block are FACTORED relative to the
+# sub-block's own start R, (x_i e^{A_i - R}) . (k_j e^{R - A_j}), with both
+# factors float32 and the product at "highest" precision: every term carries
+# float32's relative error, as the exact pairwise form's does; A_i - R lies in
+# [-80, 0] under the gate's bound (`_kernels_tile`) and the factors are centred
+# on its middle (`_KERNEL_SHIFT`), e^{+-40} at most. One (2 C, D) x (D, C)
+# product a head gives all four sub-blocks of both matrices (what it computes
+# outside them is finite and masked). Before the sub-block the factors are
+# `_pairwise_decays`' own, in the activations' dtype. The 16 x 16 inverses
+# are 15 eliminations of a column on the vector unit (forward substitution,
+# row for row what `_unit_lower_inverse` does), every sub-block of the chunk
+# at once in a block-diagonal (C, C); the two merges are T - T (L between the
+# halves) T, exact for a block-diagonal T.
+
+_NN, _NT, _TN = ssd._NN, ssd._NT, ssd._TN        # a b, a b^T, a^T b
+
+
+def _dots(a, b, dims=_NN, precision=None):
+    """A product a head, back to back (Mosaic pipelines them), of operands
+    (heads, ., .) or (., .) for one that every head shares -> (heads, ., .)."""
+    heads = a.shape[0] if a.ndim == 3 else b.shape[0]
+    of = lambda t, h: t[h] if t.ndim == 3 else t      # noqa: E731
+    return jnp.stack([jax.lax.dot_general(of(a, h), of(b, h), dims, precision=precision,
+                                          preferred_element_type=F32) for h in range(heads)])
+
+
+def _stack(pieces):
+    return jnp.concatenate(pieces, axis=1)
+
+
+def _kernel_parts(q, k, v, a, beta):
+    """A chunk's parts in VMEM, a step's heads side by side in every array so
+    that one head's long chain of dependent steps is every head's: q, k, v
+    (heads, C, D) in the activations' dtype, a (heads, C, D) and beta (heads,
+    C, 1) float32 -> a dict of float32 arrays, `_chunk_parts`' and what the
+    backward reads of how they were made."""
+    dtype = q.dtype
+    heads, c, d = a.shape
+    sub, shift = SUBCHUNK, SUBCHUNK.bit_length() - 1
+    row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    same = (row >> shift) == (col >> shift)
+    qf, kf = q.astype(F32), k.astype(F32)
+    tri = (row >= col).astype(F32)
+    cum = _dots(tri, a, precision=_HIGHEST)
+    starts = [jnp.zeros((heads, 1, d), F32)] + [cum[:, lo - 1:lo] for lo in range(sub, c, sub)]
+    over_sub = lambda rows: _stack([jnp.broadcast_to(r, (heads, sub, d)) for r in rows])      # noqa: E731
+    rel = cum - over_sub(starts)
+    # rel is in [-80, 0] under the gate's bound: the factors of a diagonal sub-block are centred on its
+    # middle, e^{rel + 40} and e^{-rel - 40}, so that neither times a small feature leaves float32's normals
+    e_in, down = jnp.exp(rel), jnp.exp(-rel - _KERNEL_SHIFT)
+    up = e_in * _KERNEL_UP
+    kin, qin = kf * e_in, qf * e_in
+    both, kdown = _stack([kf * up, qf * up]), kf * down
+    diagonal = _dots(both, kdown, _NT, _HIGHEST)                      # (2 C, C)
+    position = jax.lax.broadcasted_iota(jnp.int32, (c, d), 0)
+    zero = jnp.zeros((heads, sub, c), F32)
+    rows_k, rows_q, before = [zero], [zero], []
+    for p in range(1, c // sub):
+        lo = p * sub
+        decay = jnp.exp(jnp.where(position < lo, starts[p] - cum, -jnp.inf))
+        keyed = kf * decay
+        here = _stack([kin[:, lo:lo + sub], qin[:, lo:lo + sub]]).astype(dtype)
+        m = _dots(here, keyed.astype(dtype), _NT)                       # (2 sub, C), 0 from the sub-block on
+        rows_k.append(m[:, :sub])
+        rows_q.append(m[:, sub:])
+        before.append((lo, decay, keyed, here))
+    keys = jnp.where(same, jnp.where(row > col, diagonal[:, :c], 0.0), _stack(rows_k))
+    queries = jnp.where(same, jnp.where(row >= col, diagonal[:, c:], 0.0), _stack(rows_q))
+    lower = beta * keys
+    # the diagonal sub-blocks' inverses, block-diagonal in (C, C)
+    inverse = jnp.broadcast_to((row == col).astype(F32), (heads, c, c))
+    for j in range(sub - 1):
+        column = _stack([lower[:, lo:lo + sub, lo + j:lo + j + 1] for lo in range(0, c, sub)])
+        pivot = _stack([jnp.broadcast_to(inverse[:, lo + j:lo + j + 1], (heads, sub, c)) for lo in range(0, c, sub)])
+        inverse = inverse - column * pivot
+    size = sub
+    while size < c:
+        level = size.bit_length() - 1
+        between = jnp.where((((row >> level) & 1) == 1) & ((col >> level) == (row >> level) - 1), lower, 0.0)
+        inverse = inverse - _dots(_dots(inverse, between, precision=_HIGHEST), inverse, precision=_HIGHEST)
+        size *= 2
+    e_all = e_in * over_sub([jnp.exp(start) for start in starts])
+    last = cum[:, c - 1:]
+    to_last = jnp.exp(last - cum)
+    unweighted = jnp.concatenate([kf * e_all, v.astype(F32)], axis=2)  # (C, 2 D)
+    solved = _dots(inverse, beta * unweighted, precision=_HIGHEST)
+    return dict(kf=kf, e_in=e_in, up=up, down=down, kin=kin, qin=qin, kdown=kdown, both=both, before=before,
+                keys=keys, queries=queries, inverse=inverse, e_all=e_all, to_last=to_last, leaving=jnp.exp(last),
+                unweighted=unweighted, solved=solved, w=solved[:, :, :d], u_own=solved[:, :, d:],
+                decayed_k=kf * to_last, decayed_q=qf * e_all, tri=tri, row=row, col=col, same=same,
+                position=position)
+
+
+def _by_head(ref, heads: int):
+    """A block (1, C, heads x 128) -> (heads, C, 128)."""
+    return jnp.stack([ref[0, :, h * _LANES:(h + 1) * _LANES] for h in range(heads)])
+
+
+def _columns(ref, heads: int):
+    """beta's block (1, 1, C, heads) -> (heads, C, 1)."""
+    return jnp.stack([ref[0, 0, :, h:h + 1] for h in range(heads)])
+
+
+def _write(ref, value):
+    """(heads, C, 128) into a block (1, C, heads x 128)."""
+    for h in range(value.shape[0]):
+        ref[0, :, h * _LANES:(h + 1) * _LANES] = value[h].astype(ref.dtype)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, a_ref, beta_ref, o_ref, *rest):
+    """A chunk of a block of heads from the states in `state_scr`, which it
+    leaves updated: o, and where `rest` holds a block for them the states
+    that entered the chunk."""
+    *states_ref, state_scr = rest
+    dtype = q_ref.dtype
+    heads = state_scr.shape[0]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        state_scr[...] = jnp.zeros_like(state_scr)
+
+    parts = _kernel_parts(_by_head(q_ref, heads), _by_head(k_ref, heads), _by_head(v_ref, heads),
+                          _by_head(a_ref, heads), _columns(beta_ref, heads))
+    state = state_scr[...]
+    if states_ref:
+        states_ref[0][0, 0] = state
+    narrow = state.astype(dtype)
+    u = (parts["u_own"] - _dots(parts["w"].astype(dtype), narrow, _NT)).astype(dtype)
+    out = _dots(parts["decayed_q"].astype(dtype), narrow, _NT) + _dots(parts["queries"].astype(dtype), u)
+    _write(o_ref, out)
+    state_scr[...] = parts["leaving"] * state + _dots(u, parts["decayed_k"].astype(dtype), _TN)
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, a_ref, beta_ref, states_ref, do_ref,
+                dq_ref, dk_ref, dv_ref, da_ref, dbeta_ref, dstate_scr):
+    """The transpose of `_fwd_kernel`'s chunk, the chunks in reverse: the
+    parts again in VMEM, the cotangent of the state that leaves the chunk in
+    `dstate_scr`, replaced by that of the state that entered it. The sub-block
+    starts R are constants of the factoring (the decays do not depend on
+    them), so nothing reaches a through them."""
+    dtype = q_ref.dtype
+    heads = dstate_scr.shape[0]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dstate_scr[...] = jnp.zeros_like(dstate_scr)
+
+    beta = _columns(beta_ref, heads)
+    p = _kernel_parts(_by_head(q_ref, heads), _by_head(k_ref, heads), _by_head(v_ref, heads),
+                      _by_head(a_ref, heads), beta)
+    _, c, d = p["kf"].shape
+    row, col, same = p["row"], p["col"], p["same"]
+    state, d_after = states_ref[0, 0], dstate_scr[...]                   # (heads, D_v, D_k)
+    narrow, d_after_n, d_out = state.astype(dtype), d_after.astype(dtype), _by_head(do_ref, heads)
+    w_n, decayed_k_n = p["w"].astype(dtype), p["decayed_k"].astype(dtype)
+    decayed_q_n, queries_n = p["decayed_q"].astype(dtype), p["queries"].astype(dtype)
+    u = (p["u_own"] - _dots(w_n, narrow, _NT)).astype(dtype)
+    # o = (Q e^A) S + queries u;  S' = e^{A_last} S + (K e^{A_last - A})^T u;  u = U' - W S
+    d_u = _dots(queries_n, d_out, _TN) + _dots(decayed_k_n, d_after_n, _NT)
+    d_u_n = d_u.astype(dtype)
+    d_queries = jnp.where(row >= col, _dots(d_out, u, _NT), 0.0)
+    d_decayed_q = _dots(d_out, narrow)
+    d_w = -_dots(d_u_n, narrow)
+    d_decayed_k = _dots(u, d_after_n)
+    d_leaving = jnp.sum(state * d_after, axis=1, keepdims=True)         # (heads, 1, D_k)
+    dstate_scr[...] = p["leaving"] * d_after + _dots(d_out, decayed_q_n, _TN) - _dots(d_u_n, w_n, _TN)
+    # [W | U'] = T (beta [K e^A | V]),  T = (I + beta keys)^-1
+    d_weighted = _dots(p["inverse"], jnp.concatenate([d_w, d_u], axis=2), _TN, _HIGHEST)
+    d_lower = jnp.where(row > col, -_dots(d_weighted, p["solved"], _NT, _HIGHEST), 0.0)
+    d_beta = (jnp.sum(d_weighted * p["unweighted"], axis=2, keepdims=True)
+              + jnp.sum(d_lower * p["keys"], axis=2, keepdims=True))
+    d_keyed_all, d_v = beta * d_weighted[:, :, :d], beta * d_weighted[:, :, d:]
+    d_keys = beta * d_lower
+    # the pairwise decays: the diagonal sub-blocks, then the rows before each sub-block
+    d_diagonal = _stack([jnp.where(same, d_keys, 0.0), jnp.where(same, d_queries, 0.0)])   # (2 C, C)
+    d_both = _dots(d_diagonal, p["kdown"], precision=_HIGHEST)          # (2 C, D): d of [K e^up; Q e^up]
+    d_kdown = _dots(d_diagonal, p["both"], _TN, _HIGHEST)               # (C, D)
+    zero = jnp.zeros((heads, SUBCHUNK, d), F32)
+    rows_k, rows_q = [zero], [zero]
+    d_k_before = d_cum_before = jnp.zeros((heads, c, d), F32)
+    for lo, decay, keyed, here in p["before"]:
+        d_m = _stack([d_keys[:, lo:lo + SUBCHUNK], d_queries[:, lo:lo + SUBCHUNK]]).astype(dtype)   # (2 sub, C)
+        d_here = _dots(d_m, keyed.astype(dtype))                         # (2 sub, D)
+        d_keyed = _dots(d_m, here, _TN)                                  # (C, D)
+        rows_k.append(d_here[:, :SUBCHUNK])
+        rows_q.append(d_here[:, SUBCHUNK:])
+        d_k_before = d_k_before + d_keyed * decay
+        d_cum_before = d_cum_before + d_keyed * keyed
+    d_kin, d_qin = _stack(rows_k), _stack(rows_q)
+    d_up = d_both * p["both"]
+    dk = (d_both[:, :c] * p["up"] + d_kdown * p["down"] + d_kin * p["e_in"] + d_k_before
+          + d_keyed_all * p["e_all"] + d_decayed_k * p["to_last"])
+    dq = d_both[:, c:] * p["up"] + d_qin * p["e_in"] + d_decayed_q * p["e_all"]
+    to_last = d_decayed_k * p["decayed_k"]
+    d_cum = (d_up[:, :c] + d_up[:, c:] - d_kdown * p["kdown"] + d_kin * p["kin"] + d_qin * p["qin"] - d_cum_before
+             + d_keyed_all * p["unweighted"][:, :, :d] + d_decayed_q * p["decayed_q"] - to_last)
+    d_last = jnp.sum(to_last, axis=1, keepdims=True) + d_leaving * p["leaving"]
+    d_cum = d_cum + jnp.where(p["position"] == c - 1, d_last, 0.0)
+    _write(dq_ref, dq)
+    _write(dk_ref, dk)
+    _write(dv_ref, d_v)
+    _write(da_ref, _dots(p["tri"], d_cum, _TN, _HIGHEST))               # the reverse cumulative sum
+    head_lane = jax.lax.broadcasted_iota(jnp.int32, dbeta_ref.shape[2:], 1)
+    d_beta_all = jnp.zeros(dbeta_ref.shape[2:], F32)
+    for h in range(heads):
+        d_beta_all = jnp.where(head_lane == h, d_beta[h], d_beta_all)
+    dbeta_ref[0, 0] = d_beta_all
+
+
+def _kda_call(q, k, v, a, beta, *kept, heads: int, keep_states: bool, interpret: bool):
+    """`kda_fwd` (no `kept`) -> [o (B, S, H D)] and, with `keep_states`, the
+    float32 states that entered the chunks, transposed (B, chunks, H, D_v,
+    D_k); or `kda_bwd` on `kept` = (those states, o's cotangent) -> [dq, dk,
+    dv, da, dbeta]. q, k, v (B, S, H D), a (B, S, H D) float32, beta (B, H /
+    heads, S, heads) float32, `heads` the heads a grid step takes."""
+    bsz, s, inner = q.shape
+    chunks, width = s // CHUNK, heads * _LANES
+    backward = bool(kept)
+    of = (lambda n: chunks - 1 - n) if backward else (lambda n: n)
+    wide = pl.BlockSpec((1, CHUNK, width), lambda b, g, n: (b, of(n), g))
+    column = pl.BlockSpec((1, 1, CHUNK, heads), lambda b, g, n: (b, g, of(n), 0))
+    states = pl.BlockSpec((1, 1, heads, _LANES, _LANES), lambda b, g, n: (b, of(n), g, 0, 0))
+    states_shape = jax.ShapeDtypeStruct((bsz, chunks, inner // _LANES, _LANES, _LANES), F32)
+    if backward:
+        in_specs, out_specs = [wide] * 4 + [column, states, wide], [wide] * 4 + [column]
+        out_shape = [jax.ShapeDtypeStruct(t.shape, t.dtype) for t in (q, k, v, a, beta)]
+    else:
+        in_specs, out_specs = [wide] * 4 + [column], [wide] + [states] * keep_states
+        out_shape = [jax.ShapeDtypeStruct(v.shape, v.dtype)] + [states_shape] * keep_states
+    return pl.pallas_call(
+        _bwd_kernel if backward else _fwd_kernel,
+        grid=(bsz, inner // width, chunks), in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((heads, _LANES, _LANES), F32)],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary"),
+                                             vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret, name="kda_bwd" if backward else "kda_fwd",
+    )(q, k, v, a, beta, *kept)
+
+
+def _kda_shapes(q, k, v, a, beta, *kept, heads, keep_states, interpret):
+    del heads, interpret
+    if kept:
+        return [t.update(weak_type=False) for t in (q, k, v, a, beta)]
+    bsz, s, inner = q.shape
+    states = a.update(shape=(bsz, s // CHUNK, inner // _LANES, _LANES, _LANES), weak_type=False)
+    return [v.update(weak_type=False)] + [states] * keep_states
+
+
+# Every call site enters through ONE primitive whose lowering builds the kernel
+# and is emitted out of line, as ops/ssd's `ssm_conv_p` is: a program lowers a
+# kernel once a signature and calls that one function from every layer.
+kda_p = Primitive("kda")
+kda_p.multiple_results = True
+kda_p.def_abstract_eval(_kda_shapes)
+kda_p.def_impl(lambda *args, **params: jax.jit(functools.partial(kda_p.bind, **params))(*args))
+mlir.register_lowering(kda_p, mlir.lower_fun(_kda_call, multiple_results=True), inline=False)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _kernels(q, k, v, a, beta, heads, interpret):
+    return kda_p.bind(q, k, v, a, beta, heads=heads, keep_states=False, interpret=interpret)[0]
+
+
+def _kernels_fwd(q, k, v, a, beta, heads, interpret):
+    out, states = kda_p.bind(q, k, v, a, beta, heads=heads, keep_states=True, interpret=interpret)
+    # what a checkpoint around the caller may keep: with both, its backward
+    # pass starts from here and does not run `kda_fwd` a second time
+    out = checkpoint_name(out, "kda_chunk_out")
+    states = checkpoint_name(states, "kda_chunk_states")
+    return out, (q, k, v, a, beta, states)
+
+
+def _kernels_bwd(heads, interpret, kept, d_out):
+    return tuple(kda_p.bind(*kept, d_out, heads=heads, keep_states=False, interpret=interpret))
+
+
+_kernels.defvjp(_kernels_fwd, _kernels_bwd)
+
+
 def kda_chunk(q: jax.Array, k: jax.Array, v: jax.Array, a: jax.Array, beta: jax.Array, *,
-              chunk: int = CHUNK, implementation: Optional[str] = None) -> jax.Array:
+              chunk: int = CHUNK, implementation: Optional[str] = None,
+              lower_bound: float = LOWER_BOUND) -> jax.Array:
     """The delta rule with a decay a channel on q, k (B, S, H, D_k), v (B, S,
     H, D_v), the log-decay a (B, S, H, D_k) <= 0 and beta (B, S, H) -> o (B,
     S, H, D_v) in v's dtype, from a zero state, in chunks of `chunk`
     positions (the module's docstring). a and beta are taken in float32;
-    differentiable in all five."""
-    resolve_kda_impl(implementation)
-    b, s, h, _ = q.shape
+    differentiable in all five. `lower_bound` is the least log-decay a
+    position that the caller's gate gives (the kernels' diagonal sub-blocks
+    count on it); `implementation` is `resolve_kda_impl`'s, for tests."""
+    b, s, h, d_k = q.shape
+    d_v = v.shape[-1]
+    impl = resolve_kda_impl(implementation, chunk=chunk, d_k=d_k, d_v=d_v, lower_bound=lower_bound)
     if s % chunk:
         raise ValueError(f"kda_chunk: a sequence of {s} is no multiple of the chunk {chunk}")
+    if impl == "pallas":
+        per_step = _heads_per_step(h)
+
+        def columns(t):     # (B, S, H) -> a head a column of its block: (B, H / per_step, S, per_step)
+            return jnp.transpose(t.reshape(b, s, h // per_step, per_step), (0, 2, 1, 3))
+
+        flat = (b, s, h * d_k)
+        out = _kernels(q.reshape(flat), k.reshape(flat), v.reshape(flat), a.astype(F32).reshape(flat),
+                       columns(beta.astype(F32)), per_step, jax.default_backend() != "tpu")
+        return out.reshape(b, s, h, d_v)
 
     def cut(t):     # (B, S, H, ...) -> (N, B, H, C, ...)
         t = t.reshape(b, s // chunk, chunk, *t.shape[2:])

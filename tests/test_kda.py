@@ -79,6 +79,9 @@ def test_refusals_and_the_plan():
     with pytest.raises(ValueError, match="no multiple of the chunk"):
         kda.kda_chunk(q, k, v, a, beta, chunk=64)
     with pytest.raises(ValueError, match="unknown kda implementation"):
+        kda.kda_chunk(q, k, v, a, beta, chunk=32, implementation="mosaic")
+    with pytest.raises(ValueError, match="the kernels do not tile a chunk of 32, key heads of 16"):
         kda.kda_chunk(q, k, v, a, beta, chunk=32, implementation="pallas")
-    assert kda.kda_plan() == {"kda_impl": "xla_chunked", "kda_chunk": 64, "kda_subchunk": 16}
+    assert kda.kda_plan() == {"kda_impl": "xla_chunked", "kda_chunk": 64, "kda_subchunk": 16, "kda_kernels": 0,
+                              "kda_heads_per_step": 0, "kda_state_bytes": 0}
     assert kda.kda_plan(24)["kda_subchunk"] == 24

@@ -169,11 +169,13 @@ def test_the_step_reports_the_delta_rule_the_latent_layer_and_the_groups(stack):
     said = model_family(config).plan(config, 2, 128)
     assert said["layer_kinds"] == "dK eK eK eK eL eK eK"
     assert {name: said[name] for name in (
-        "kda_heads", "kda_head_dim", "kda_chunk", "kda_subchunk", "kda_impl", "kda_conv_impl",
+        "kda_heads", "kda_head_dim", "kda_chunk", "kda_subchunk", "kda_impl", "kda_kernels",
+        "kda_heads_per_step", "kda_state_bytes", "kda_conv_impl",
         "kda_gate_lower_bound", "attn_latent_v_dim", "attn_latent_q_rank", "moe_route_groups",
         "moe_route_groups_kept")} == {
         "kda_heads": 4, "kda_head_dim": 16, "kda_chunk": 32, "kda_subchunk": 16, "kda_impl": "xla_chunked",
-        "kda_conv_impl": "xla", "kda_gate_lower_bound": -5.0, "attn_latent_v_dim": 16,
+        "kda_kernels": 0, "kda_heads_per_step": 0, "kda_state_bytes": 0, "kda_conv_impl": "xla",
+        "kda_gate_lower_bound": -5.0, "attn_latent_v_dim": 16,
         "attn_latent_q_rank": 0, "moe_route_groups": 4, "moe_route_groups_kept": 2}
     costs = model_family(config).block_costs(config, 128)
     named = {name for candidate in costs["candidates"] for name in candidate.names}

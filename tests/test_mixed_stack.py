@@ -757,6 +757,26 @@ def test_the_scan_candidate_is_priced_by_the_form_that_runs(monkeypatch, backend
         ("ssm_scan_out", "ssm_chunk_states"), width, scan, int(scan / share))
 
 
+@pytest.mark.parametrize("backend, bound, share", [("cpu", -5.0, 0.02), ("tpu", -5.0, 0.085), ("tpu", -6.0, 0.02)],
+                         ids=["xla-chunked", "kernels", "a-gate-the-kernels-do-not-tile"])
+def test_the_delta_rule_candidate_is_priced_by_the_form_that_runs(monkeypatch, backend, bound, share):
+    """At the published sizes (32 heads of 128, chunk 64) in bfloat16 either
+    form keeps the output and the float32 state that entered every chunk
+    (128 x 128 x 4 B a head over 64 tokens, 32 KB a token: 16,384 features of
+    two bytes beside the output's 4,096), and the rule they spare counts at the measured share of
+    the peak of the form that runs (`ops/kda.resolve_kda_impl`)."""
+    from test_ling3flash_model import tiny_ling
+
+    from ray_tpu.models.mixed_stack import _kda_costs
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    config = tiny_ling(kda_heads=32, kda_head_dim=128, kda_chunk=64, kda_gate_lower_bound=bound, dtype=jnp.bfloat16)
+    (candidate,) = _kda_costs(config)["candidates"]
+    rule = 32 * (2 * 64 * (4 * 128 + 64) + 10 * 128 * 128)
+    assert (candidate.names, candidate.width, candidate.flops, candidate.worth) == (
+        ("kda_chunk_out", "kda_chunk_states"), 4096 + 16384, rule, int(rule / share))
+
+
 def test_the_three_shipped_mixed_stack_cells_keep_their_kinds_runs_and_leaves():
     """`train-trinity-mini-8k`, `train-smallthinker-16k` and `train-glm47flash-8k`
     resolve to the kinds, runs and leaf names they had before a layer could

@@ -414,6 +414,35 @@ def test_conv_kernels_compile_within_the_scoped_vmem_they_ask_for(as_tpu, batch,
         assert len(re.findall(rf"^\s*%\w*{kernel}[\w.]* = .*custom-call\(", compiled.as_text(), re.M)) == 1, kernel
 
 
+@pytest.mark.parametrize("batch, seq, heads", [(1, 4096, 32), (2, 128, 6)],
+                         ids=["ling3flash-32-heads-8-a-step", "6-heads-a-step-two-chunks"])
+def test_kda_kernels_compile_within_the_scoped_vmem_they_ask_for(as_tpu, batch, seq, heads):
+    """`kda_fwd` and `kda_bwd` at the `train-ling3flash-4k` cell's shapes (a
+    grid step: one chunk of 64 positions of 8 heads of 128, their (8, 128, 128)
+    float32 states in scratch) and at another block of heads: the rule says
+    "pallas" there, a differentiated rule is the two calls, each named, and
+    Mosaic fits both in the `_VMEM_LIMIT` their calls ask for (a kernel that
+    did not would be refused here as on the chip)."""
+    from ray_tpu.ops import kda
+
+    plan = kda.kda_plan(64, heads=heads, d_k=128, d_v=128)
+    per_step = min(heads, 8)
+    assert (plan["kda_impl"], plan["kda_kernels"], plan["kda_heads_per_step"], plan["kda_state_bytes"]) == (
+        "pallas", 2, per_step, per_step * 128 * 128 * 4)
+    by_head = (batch, seq, heads, 128)
+    args = (_on(as_tpu, by_head), _on(as_tpu, by_head), _on(as_tpu, by_head), _on(as_tpu, by_head, jnp.float32),
+            _on(as_tpu, (batch, seq, heads), jnp.float32))
+    forward = jax.jit(lambda *a: kda.kda_chunk(*a)).lower(*args)
+    # the scoped VMEM the call asks Mosaic for, as the lowered call carries it
+    assert f"\\22size\\22: {kda._VMEM_LIMIT}}}]" in forward.as_text()
+    assert _kernel_calls(forward.compile()) == 1
+    compiled = jax.jit(jax.grad(lambda *a: jnp.sum(kda.kda_chunk(*a).astype(jnp.float32) ** 2),
+                                argnums=tuple(range(5)))).lower(*args).compile()
+    assert _kernel_calls(compiled) == 2
+    for kernel in ("kda_fwd", "kda_bwd"):
+        assert len(re.findall(rf"^\s*%\w*{kernel}[\w.]* = .*custom-call\(", compiled.as_text(), re.M)) == 1, kernel
+
+
 def test_the_lowered_mixer_holds_no_float32_copy_of_the_group_view(as_tpu):
     """One Mamba-2 mixer of the `train-nemotron3nano-8k` cell, forward and
     gradient, compiled for the described v5e: the convolution is the two

@@ -175,7 +175,8 @@ def test_ling3flash_cell_step_runs_the_delta_rule_under_its_scopes_and_compiles(
     said = model_family(config).plan(config, 1, 4096)
     assert said["layer_kinds"] == "dK eK eK eK eL eK eK"
     assert (said["kda_impl"], said["kda_chunk"], said["kda_subchunk"], said["kda_conv_impl"]) == (
-        "xla_chunked", 64, 16, "pallas")
+        "pallas", 64, 16, "pallas")
+    assert (said["kda_kernels"], said["kda_heads_per_step"], said["kda_state_bytes"]) == (2, 8, 8 * 128 * 128 * 4)
     assert (said["kda_heads"], said["kda_head_dim"], said["kda_gate_lower_bound"]) == (32, 128, -5.0)
     assert (said["attn_latent_q_rank"], said["attn_latent_v_dim"], said["attn_kernel_head_dim"]) == (0, 128, 256)
     assert (said["moe_route_groups"], said["moe_route_groups_kept"], said["moe_experts_held"]) == (8, 4, 8)
@@ -188,6 +189,13 @@ def test_ling3flash_cell_step_runs_the_delta_rule_under_its_scopes_and_compiles(
     _, table = profiling.program_ops_table(profiling._module_text(compiled))
     for scopes, _, _ in table["ssm_conv_fwd"] + table["ssm_conv_bwd"]:
         assert {"kda", "kda.conv"} <= set(scopes), scopes
+    # the delta rule is its two kernels (PR 56): one forward a mixer, which keeps the states that entered
+    # the chunks, none run again (the plan keeps `kda_chunk_out` and `kda_chunk_states`), one backward
+    assert set(plan["remat_saved"]) >= {"kda_chunk_out", "kda_chunk_states"}
+    assert sorted(found for _, found, _ in table["kda_fwd"]) == ["fwd"] * 6
+    assert sorted(found for _, found, _ in table["kda_bwd"]) == ["bwd"] * 6
+    for scopes, _, _ in table["kda_fwd"] + table["kda_bwd"]:
+        assert {"kda", "kda.chunk"} <= set(scopes), scopes
     pairs = {(scope, pass_) for instances in table.values() for scopes, pass_, _ in instances for scope in scopes}
     for scope in ("kda", "kda.in_proj", "kda.conv", "kda.chunk", "kda.gate_norm", "kda.out_proj",
                   "attn.full", "attn.latent", "moe", "mlp", "head"):
