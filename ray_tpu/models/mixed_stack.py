@@ -28,8 +28,9 @@ the call), `transformer.mlp_sublayer`, `moe.moe_mlp`. Where `config.remat`
 every block is recomputed in the backward pass but for what the step keeps of
 it (`block_costs` names what it may: the attention kernel's output and lse and
 the residual after the output projection in every layer, a latent layer's
-latents, gate and up in the dense ones; an expert layer's MLP is recomputed
-whole).
+latents, gate and up in the dense ones, a state-space or delta-rule mixer's
+in-projection and its scan's or rule's output with the chunks' states; an
+expert layer's MLP is recomputed whole).
 
 A configuration with `mtp_modules` has, beside the stack, one multi-token
 prediction module (`params["mtp"]`, `mtp_hidden`): the stack's output and
@@ -68,6 +69,7 @@ from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import kda, rope_frequencies, ssd
 from ..ops.attention import attention_plan
@@ -529,7 +531,7 @@ def _ssm_sublayer(x, lp, config):
     with jax.named_scope("ssm"):
         with jax.named_scope("ssm.in_proj"):
             u = _norm(x, lp["ln1_scale"], None, c.norm, c.norm_eps)
-            projected = jnp.einsum("bse,ef->bsf", u, lp["ssm_in"].astype(dt))
+            projected = checkpoint_name(jnp.einsum("bse,ef->bsf", u, lp["ssm_in"].astype(dt)), "ssm_in_proj")
             z, xbc, step = _gate_xbc_dt(projected, inner, c.ssm_conv_width)
         with jax.named_scope("ssm.conv"):
             # x, B and C as an output each: slices of one would be copies in front of the scan's kernels
@@ -573,7 +575,7 @@ def _kda_sublayer(x, lp, config):
     with jax.named_scope("kda"):
         with jax.named_scope("kda.in_proj"):
             u = _norm(x, lp["ln1_scale"], None, c.norm, c.norm_eps)
-            projected = jnp.einsum("bse,ef->bsf", u, lp["kda_in"].astype(dt))
+            projected = checkpoint_name(jnp.einsum("bse,ef->bsf", u, lp["kda_in"].astype(dt)), "kda_in_proj")
             beta_gate = jnp.einsum("bse,ef->bsf", u, lp["kda_bg"].astype(dt)).astype(jnp.float32)
             # [q | k | v | f]: the WHOLE array for q's and k's convolution and for v's (two calls: the
             # kernels take at most 8,192 channels a step), and f; `_gate_xbc_dt`'s reasons
@@ -804,12 +806,22 @@ _SSD_SHARE_OF_PEAK = {"xla_chunked": 0.043, "pallas": 0.174}
 
 def _ssm_costs(config: MixedStackConfig) -> Dict[str, Any]:
     """`_ssm_sublayer`'s part of `block_costs`, a layer and token (whole on
-    every device: its weights are not split). The one candidate is the scan's
+    every device: its weights are not split). Two candidates. The scan's
     output WITH the states its backward pass starts from (`ssm_scan_out`,
     `ssm_chunk_states` of ops/ssd: the kernels keep the state that entered
     every chunk in the activations' dtype, the XLA form a float32 state a
     block of chunks): with both the backward pass does not run the scan
-    forward again; with one of them alone it must."""
+    forward again; with one of them alone it must. And the in-projection's
+    output (`ssm_in_proj`: z, xBC and dt are one matmul's), worth its matmul
+    as a dense layer's gate and up are: the identity that `jax.checkpoint`
+    puts on a kept value fuses into the matmul's output, and the scanned
+    run's kept projection is written feature-minor straight into its stack
+    (on the `train-nemotron3nano-8k` cell 22.0 ms of a 392.6 ms step for 1.35
+    GB, the 21.2 the four recomputed projections took and the copy of PERF.md
+    row 49 (h)). The convolution's and the gated norm's outputs are NOT named:
+    a Pallas kernel writes them, so keeping one is a whole copy that nothing
+    fuses, and with the stack's writes the step took 3.3 and 1.1 ms MORE for
+    0.81 and 0.54 GB (PERF.md section 6, PR 57)."""
     c = config
     heads, p, n, chunk = c.ssm_heads, c.ssm_head_dim, c.ssm_state, c.ssm_chunk
     inner, conv = heads * p, c.ssm_conv_width
@@ -821,20 +833,29 @@ def _ssm_costs(config: MixedStackConfig) -> Dict[str, Any]:
     # the kept states, in features of the activations' dtype a token
     state = (inner * n // chunk if impl == "pallas"
              else -(-inner * n * 4 // (chunk * ssd.BLOCK_CHUNKS * itemsize)))
+    projected = inner + conv + heads    # [z | xBC | dt], one matmul's output
+    in_proj = 2 * c.d_model * projected
     return {
-        "flops": (2 * c.d_model * (inner + conv + heads) + 2 * c.ssm_conv_kernel * conv + scan
-                  + 2 * inner * c.d_model),
+        "flops": in_proj + 2 * c.ssm_conv_kernel * conv + scan + 2 * inner * c.d_model,
         # the norm's output, the sublayer's, the residual; the projection; the
         # convolution's output (its float32 sum is fused away); the scan's
         # operands cut into blocks; its output and the gated, normed one (both
         # in the activations' dtype: the norm's kernels hold the float32
         # product in VMEM, and the XLA form's float32 copies are the CPU's). On
-        # the chip the whole-block step of the cell peaked at 13.87 GB, 2.4 GB
-        # over its state, gradients and the blocks' inputs: 1.75 copies of
-        # 41.9 k features a row, where this counts 42.9 k
-        "width": (3 * c.d_model + (inner + conv + heads) + conv + (inner + conv) + 2 * inner),
-        "candidates": (RematCandidate(("ssm_scan_out", "ssm_chunk_states"), inner + state, scan,
-                                      int(scan / _SSD_SHARE_OF_PEAK[impl]), False, ()),),
+        # the chip the whole-block step of the cell peaked at 13.87 GB when
+        # every part was XLA's (PR 48: 1.75 copies of 41.9 k features a row,
+        # where this counts 42.9 k). Since the three pairs of kernels it peaks
+        # at 12.89 GB where `step_peak_bytes` says 13.85, and with the plan the
+        # rule makes now (the projection kept too, 2.65 GB in all) at 15.00 GB
+        # = 88.7% where it says 15.37: high by 0.96 and 0.37 GB, inside the
+        # ceiling's 1.10 GB of margin and on its safe side (PERF.md section 6,
+        # PR 57), so the count stands
+        "width": 3 * c.d_model + projected + conv + (inner + conv) + 2 * inner,
+        "candidates": (
+            RematCandidate(("ssm_scan_out", "ssm_chunk_states"), inner + state, scan,
+                           int(scan / _SSD_SHARE_OF_PEAK[impl]), False, ()),
+            RematCandidate(("ssm_in_proj",), projected, in_proj, in_proj, False, ()),
+        ),
     }
 
 
@@ -850,10 +871,17 @@ _KDA_SHARE_OF_PEAK = {"xla_chunked": 0.02, "pallas": 0.085}
 
 def _kda_costs(config: MixedStackConfig) -> Dict[str, Any]:
     """`_kda_sublayer`'s part of `block_costs`, a layer and token (whole on
-    every device). The one candidate is the chunked rule's output WITH the
+    every device). Two candidates: the chunked rule's output WITH the
     float32 states that entered its chunks (`kda_chunk_out`,
-    `kda_chunk_states` of ops/kda): with both the backward pass does not run
-    the rule forward again."""
+    `kda_chunk_states` of ops/kda: with both the backward pass does not run
+    the rule forward again), and the in-projection's output (`kda_in_proj`:
+    q, k, v and f, one matmul's; `_ssm_costs` says why it is worth its matmul:
+    on the `train-ling3flash-4k` cell 12.8 ms of a 273.2 ms step for 0.81 GB).
+    The small beta and gate projection beside it is NOT named: kept, its
+    bfloat16 rounding is forced where XLA's fusion carries the matmul's
+    float32 into the sigmoids, and the cell's first loss moved by 1e-4.
+    Nor are the convolved q, k and v: their copies cost the step 1.1 ms more
+    than the convolutions' second run takes (PERF.md section 6, PR 57)."""
     c = config
     heads, d, chunk = c.kda_heads, c.kda_head_dim, c.kda_chunk
     inner = heads * d
@@ -864,16 +892,29 @@ def _kda_costs(config: MixedStackConfig) -> Dict[str, Any]:
     rule = heads * (2 * chunk * (4 * d + chunk) + 10 * d * d)
     state = -(-inner * d * 4 // (chunk * itemsize))     # the kept float32 states, in features a token
     impl = kda.resolve_kda_impl(chunk=chunk, d_k=d, d_v=d, lower_bound=c.kda_gate_lower_bound)
+    projected = 4 * inner               # [q | k | v | f], one matmul's output
+    in_proj = 2 * c.d_model * projected
     return {
-        "flops": (2 * c.d_model * (4 * inner + 2 * heads) + 2 * c.kda_conv_kernel * 3 * inner + rule
+        "flops": (in_proj + 2 * c.d_model * 2 * heads + 2 * c.kda_conv_kernel * 3 * inner + rule
                   + 2 * inner * c.d_model),
         # the norm's output, the sublayer's, the residual; the projection; the
         # convolved q, k, v; the normalised q and k; the float32 log-decay; the
-        # chunks' states; the rule's output and the gated, normed one
-        "width": (3 * c.d_model + 4 * inner + 3 * inner + 2 * inner + inner * 4 // itemsize + state
+        # chunks' states; the rule's output and the gated, normed one. On the
+        # chip the cell's whole-block step peaks at 13.24 GB and the plan the
+        # rule makes (everything named kept, 2.01 GB) at 13.42 GB = 79.4%, where
+        # `step_peak_bytes` says 14.64 and 14.66: high by 1.2-1.4 GB, more than
+        # any width here accounts for (run 0's whole block term is 1.48 GB).
+        # What it over-counts is the moment, not a width: it holds every
+        # unrolled layer's gradients beside the LAST block's backward pass,
+        # where on the chip a layer's gradients come as its activations go
+        # (PERF.md section 7). The safe side, and it changes no decision here
+        "width": (3 * c.d_model + projected + 3 * inner + 2 * inner + inner * 4 // itemsize + state
                   + 2 * inner),
-        "candidates": (RematCandidate(("kda_chunk_out", "kda_chunk_states"), inner + state, rule,
-                                      int(rule / _KDA_SHARE_OF_PEAK[impl]), False, ()),),
+        "candidates": (
+            RematCandidate(("kda_chunk_out", "kda_chunk_states"), inner + state, rule,
+                           int(rule / _KDA_SHARE_OF_PEAK[impl]), False, ()),
+            RematCandidate(("kda_in_proj",), projected, in_proj, in_proj, False, ()),
+        ),
     }
 
 

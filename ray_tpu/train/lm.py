@@ -456,7 +456,7 @@ def make_train_step(
             chunk = auto_loss_chunk(batch, seq, vocab, resident_bytes=resident,
                                     step_bytes=gradients + activations)
         plan = {"remat": "whole_block" if config.remat else "off", "remat_saved": (),
-                "remat_saved_bytes": 0,
+                "remat_saved_bytes": 0, "remat_saved_by_run": (), "remat_saved_bytes_by_run": (),
                 "remat_recomputed_flops_share": None if config.remat else 0.0}
         if config.remat and costs:
 
@@ -480,6 +480,10 @@ def make_train_step(
                 remat="selective" if kept else "whole_block",
                 remat_saved=tuple(name for c in kept for name in c.names),
                 remat_saved_bytes=kept_bytes,
+                remat_saved_by_run=tuple(
+                    tuple(name for c in kept if c.layers[r] for name in c.names) for r in range(len(runs))),
+                remat_saved_bytes_by_run=tuple(
+                    sum(c.layers[r] * rows * c.width * itemsize for c in kept) for r in range(len(runs))),
                 remat_recomputed_flops_share=(
                     costs["recomputed_flops"] - sum(sum(c.layers) * c.flops for c in kept))
                 / costs["flops"])
@@ -495,9 +499,12 @@ def make_train_step(
         """What the step's blocks keep across the forward pass for a
         (B, S + 1) batch (`head_and_remat_for`): `remat` (`off`: nothing is
         recomputed; `whole_block`; `selective`), `remat_saved` (the
-        `checkpoint_name`s kept), `remat_saved_bytes` (a device) and
-        `remat_recomputed_flops_share` (of the blocks' forward pass, run again
-        in the backward; None for a family that names no candidates)."""
+        `checkpoint_name`s kept), `remat_saved_bytes` (a device), both again a
+        run of the stack in the forward's order (`remat_saved_by_run`: the
+        names that run's layers write; `remat_saved_bytes_by_run`; no runs
+        where nothing is recomputed) and `remat_recomputed_flops_share` (of the
+        blocks' forward pass, run again in the backward; None for a family
+        that names no candidates)."""
         return head_and_remat_for(tokens_shape, state)[1]
 
     def microbatch_grads(loss_fn, params, tokens):

@@ -715,8 +715,9 @@ def test_what_a_pattern_cannot_run_is_refused_by_name(change, match):
 
 def test_block_costs_price_the_sublayers_a_layer_has():
     """An `M` layer costs its mixer and no MLP, an `E` layer its experts and
-    no attention (two matrices an expert where it is not gated); the one
-    candidate a state-space layer names is the scan's output WITH its states."""
+    no attention (two matrices an expert where it is not gated); a
+    state-space layer names the scan's output WITH its states as one candidate
+    and, since PR 57, the in-projection's output as another."""
     from ray_tpu.models.mixed_stack import _expert_costs, _ssm_costs, block_costs
 
     config = tiny_pattern()
@@ -725,6 +726,7 @@ def test_block_costs_price_the_sublayers_a_layer_has():
         (True, 4, 2), (False, 5, 5)]
     by_name = {c.names: c for c in costs["candidates"]}
     assert by_name["ssm_scan_out", "ssm_chunk_states"].layers == (2, 2)
+    assert (by_name["ssm_in_proj",].layers, by_name["ssm_in_proj",].width) == ((2, 2), 64 + (64 + 2 * 2 * 16) + 8)
     assert by_name["attn_out", "attn_lse"].layers == (0, 1) and by_name["attn_residual",].layers == (0, 1)
     ssm, experts = _ssm_costs(config), _expert_costs(config, lambda weight: 1)
     inner, conv = 64, 64 + 2 * 2 * 16
@@ -751,7 +753,7 @@ def test_the_scan_candidate_is_priced_by_the_form_that_runs(monkeypatch, backend
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     config = tiny_pattern(ssm_heads=64, ssm_head_dim=64, ssm_state=128, ssm_groups=8, ssm_chunk=128,
                           dtype=jnp.bfloat16)
-    (candidate,) = _ssm_costs(config)["candidates"]
+    candidate = _ssm_costs(config)["candidates"][0]
     scan = 2 * 128 * 8 * 128 + 2 * 128 * 4096 + 4 * 4096 * 128
     assert (candidate.names, candidate.width, candidate.flops, candidate.worth) == (
         ("ssm_scan_out", "ssm_chunk_states"), width, scan, int(scan / share))
@@ -771,7 +773,7 @@ def test_the_delta_rule_candidate_is_priced_by_the_form_that_runs(monkeypatch, b
 
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     config = tiny_ling(kda_heads=32, kda_head_dim=128, kda_chunk=64, kda_gate_lower_bound=bound, dtype=jnp.bfloat16)
-    (candidate,) = _kda_costs(config)["candidates"]
+    candidate = _kda_costs(config)["candidates"][0]
     rule = 32 * (2 * 64 * (4 * 128 + 64) + 10 * 128 * 128)
     assert (candidate.names, candidate.width, candidate.flops, candidate.worth) == (
         ("kda_chunk_out", "kda_chunk_states"), 4096 + 16384, rule, int(rule / share))

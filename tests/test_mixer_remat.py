@@ -1,0 +1,143 @@
+"""What a recomputing step may keep of a state-space mixer and of a
+delta-rule mixer beside its scan's (its rule's) output: the in-projection's
+output (models/mixed_stack: `ssm_in_proj`, `kda_in_proj`). Kept, it changes no
+loss and no gradient; at the two cells' published widths `block_costs` lists
+it and the rule (train/lm.auto_remat_saved) takes it on a v5e beside what it
+kept before. (The convolution's and the gated norm's outputs lost on the chip
+and are not named: PERF.md section 6, PR 57.)"""
+
+import functools
+import os
+
+import jax
+import numpy as np
+import pytest
+
+from ray_tpu.models import model_family
+from ray_tpu.ops import losses
+from ray_tpu.parallel import MeshSpec, build_mesh
+from ray_tpu.train.lm import abstract_train_state, default_optimizer, lm_loss, make_train_step
+
+from test_ling3flash_model import gated, tiny_ling  # noqa: E402
+from test_mixed_stack import seeded, tiny_pattern  # noqa: E402
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# the usable memory of the chip the cells run on (benchmark/configs/*-train-1chip.json)
+V5E_BYTES = 16_909_336_064
+
+NEW_NAMES = ("ssm_in_proj", "kda_in_proj")
+
+
+def _ssm_stack():
+    """`2 x (-M e-) | -M`: a state-space mixer in a scanned run and in an unrolled one."""
+    config = tiny_pattern(layer_pattern="MEMEM", n_layers=5)
+    return config, seeded(config, 4), ("ssm_in_proj", "ssm_scan_out", "ssm_chunk_states")
+
+
+def _kda_stack():
+    """`2 x (dK) | eK`: a delta-rule mixer in a scanned run and in an unrolled one."""
+    config = tiny_ling(first_layer=0, n_layers=3)
+    return config, gated(config, 3), ("kda_in_proj", "kda_chunk_out", "kda_chunk_states")
+
+
+@pytest.mark.parametrize("stack", [_ssm_stack, _kda_stack], ids=["state-space", "delta-rule"])
+def test_keeping_a_mixers_named_values_changes_neither_loss_nor_gradients(stack):
+    """The in-projection's output kept beside the scan's (the rule's) output
+    and states, in a scanned run and in an unrolled one: the loss and every
+    gradient of the whole-block step, and the projection is gone from the
+    backward pass's recomputation (one `dot_general` a layer body: the
+    scan's, the unrolled layer's)."""
+    config, params, names = stack()
+    assert [run["scanned"] for run in model_family(config).block_costs(config, 64)["runs"]] == [True, False]
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 65), 0, config.vocab_size)
+    loss = functools.partial(lm_loss, config=config)
+
+    def value_and_gradients(saved):
+        return jax.jit(jax.value_and_grad(lambda p: loss(p, tokens, remat_saved=saved)[0]))
+
+    whole, kept = value_and_gradients(()), value_and_gradients(names)
+    (whole_loss, whole_grads), (kept_loss, kept_grads) = whole(params), kept(params)
+    assert float(whole_loss) == float(kept_loss)
+    for a, b in zip(jax.tree.leaves(whole_grads), jax.tree.leaves(kept_grads)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-5)
+    # beside what the rule kept before (the scan's or the rule's own einsums, off the CPU's backward pass)
+    before = value_and_gradients(tuple(name for name in names if name not in NEW_NAMES))
+    matmuls = [fn.lower(params).as_text().count("dot_general") for fn in (before, kept)]
+    assert matmuls[0] - matmuls[1] == 2, matmuls
+
+
+CELLS = {
+    # (the configuration's file, a step's batch and sequence, the new candidate's features a row, what
+    # the rule kept before it existed)
+    "nemotron3nano": ("nemotron-3-nano-30b-a3b-train-1chip", 2, 8192, {"ssm_in_proj": 10304},
+                      {"attn_out", "attn_lse", "attn_residual", "ssm_scan_out", "ssm_chunk_states"}),
+    "ling3flash": ("ling-3.0-flash-train-1chip", 1, 4096, {"kda_in_proj": 16384},
+                   {"attn_residual", "attn_out", "attn_lse", "mlp_up", "mlp_gate", "attn_latent_kv",
+                    "attn_latent_k_rope", "kda_chunk_out", "kda_chunk_states"}),
+}
+
+
+def _cell_plan(monkeypatch, cell, hbm_bytes):
+    """(`block_costs` of a cell's configuration as a TPU runs it, the plan its
+    step makes on a device of `hbm_bytes`, the estimates the rule was given):
+    shapes alone, nothing is allocated."""
+    from benchmark import model_config
+    from ray_tpu.train import lm
+
+    file, batch, seq, _, _ = CELLS[cell]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")     # the kernels' forms, and their kept states
+    monkeypatch.setattr(losses, "device_hbm_bytes", lambda: hbm_bytes)
+    estimates = []
+    step_peak_bytes = lm.step_peak_bytes
+    monkeypatch.setattr(lm, "step_peak_bytes", lambda kept, **sizes: (
+        estimates.append((frozenset(c.names[0] for c in kept), step_peak_bytes(kept, **sizes))), estimates[-1][1])[1])
+    config = model_config.transformer_config(model_config.load_config(
+        os.path.join(ROOT, "benchmark", "configs", file + ".json")))
+    mesh = build_mesh(MeshSpec(), devices=jax.devices()[:1])
+    opt = default_optimizer(3e-4, total_steps=1000)
+    state, shardings = abstract_train_state(config, opt, mesh)
+    step = make_train_step(config, opt, mesh, state_shardings=shardings)
+    return (model_family(config).block_costs(config, seq), step.remat_plan_for((batch, seq + 1), state),
+            dict(estimates))
+
+
+@pytest.mark.parametrize("cell", list(CELLS))
+def test_the_cells_widths_list_the_new_candidates_and_a_v5e_keeps_the_in_projection(monkeypatch, cell):
+    """At the published widths the in-projection's output is one candidate
+    (z, xBC and dt, or q, k, v and f, come out of one matmul), written by
+    every mixer layer of both runs and worth its matmul, as gate and up are:
+    the chip fuses the identity that `jax.checkpoint` puts on a kept value
+    into the matmul's output. On the cell's chip the rule keeps it, everything
+    it kept before, and its estimate of the step stays under the ceiling; the
+    bytes a run holds add up to the whole."""
+    _, batch, seq, widths, before = CELLS[cell]
+    costs, plan, estimates = _cell_plan(monkeypatch, cell, V5E_BYTES)
+    by_name = {c.names[0]: c for c in costs["candidates"]}
+    mixers = by_name["ssm_scan_out" if cell == "nemotron3nano" else "kda_chunk_out"].layers
+    assert sum(mixers) in (4, 6)
+    for name, width in widths.items():
+        assert (by_name[name].names, by_name[name].width, by_name[name].layers) == ((name,), width, mixers)
+        assert not by_name[name].tp_sum
+    (projection,) = widths
+    d_model = 2688 if cell == "nemotron3nano" else 2560
+    assert by_name[projection].flops == by_name[projection].worth == 2 * d_model * widths[projection]
+    kept = set(plan["remat_saved"])
+    assert plan["remat"] == "selective" and projection in kept and before <= kept
+    assert kept <= before | set(widths)
+    assert estimates[frozenset(kept & set(by_name))] <= (1 - losses.HBM_FREE_FRACTION) * V5E_BYTES
+    assert max(estimates.values()) > min(estimates.values())
+    # a run's names and bytes: what a trace of the cell says of the plan that ran
+    assert len(plan["remat_saved_by_run"]) == len(plan["remat_saved_bytes_by_run"]) == len(costs["runs"])
+    assert sum(plan["remat_saved_bytes_by_run"]) == plan["remat_saved_bytes"]
+    assert {name for names in plan["remat_saved_by_run"] for name in names} == kept
+    assert all(projection in names for names in plan["remat_saved_by_run"])
+    rows = batch * seq
+    assert plan["remat_saved_bytes"] >= sum(mixers) * rows * widths[projection] * 2
+
+
+@pytest.mark.parametrize("cell", list(CELLS))
+def test_a_device_of_unknown_size_keeps_nothing_of_a_mixer(monkeypatch, cell):
+    costs, plan, estimates = _cell_plan(monkeypatch, cell, 0)
+    assert (plan["remat"], plan["remat_saved"], plan["remat_saved_bytes"]) == ("whole_block", (), 0)
+    assert (plan["remat_saved_by_run"], plan["remat_saved_bytes_by_run"]) == (((), ()), (0, 0))
+    assert not estimates and plan["remat_recomputed_flops_share"] > 0.8

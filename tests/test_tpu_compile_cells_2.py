@@ -35,8 +35,14 @@ def test_nemotron3nano_cell_step_runs_the_scan_under_its_scopes_and_compiles(as_
     monkeypatch.setattr(losses, "device_hbm_bytes", lambda: int(15.75 * GIB))
     step = make_train_step(config, opt, mesh, state_shardings=shardings)
     plan = step.remat_plan_for(tokens.shape, state)
-    assert plan["remat"] in ("whole_block", "selective")
-    assert set(plan["remat_saved"]) <= {"ssm_scan_out", "ssm_chunk_states", "attn_out", "attn_lse", "attn_residual"}
+    # what the rule kept before PR 57, and the four state-space layers' in-projections beside it
+    assert plan["remat"] == "selective" and set(plan["remat_saved"]) == {
+        "ssm_scan_out", "ssm_chunk_states", "attn_out", "attn_lse", "attn_residual", "ssm_in_proj"}
+    assert plan["remat_saved_by_run"] == (
+        ("ssm_in_proj", "ssm_scan_out", "ssm_chunk_states"),
+        ("attn_out", "attn_lse", "attn_residual", "ssm_in_proj", "ssm_scan_out", "ssm_chunk_states"))
+    assert plan["remat_saved_bytes_by_run"] == (2 * 16384 * (10304 + 8192) * 2,
+                                                16384 * ((4096 + 64) + 2688 + 2 * (10304 + 8192)) * 2)
     assert step.loss_chunk_for(tokens.shape, state) == 8192
     said = model_family(config).plan(config, 2, 8192)
     assert (said["ssm_scan_impl"], said["ssm_scan_kernels"], said["ssm_scan_state_bytes"]) == (
@@ -73,10 +79,15 @@ def test_nemotron3nano_cell_step_runs_the_scan_under_its_scopes_and_compiles(as_
         assert {"ssm", "ssm.conv"} <= set(scopes), scopes
     assert {found for _, found, _ in table["ssm_conv_bwd"]} == {"bwd"}
     # xBC reaches the kernels in the projection as the matmul wrote it, and their output the scan's
-    # slices: no copy of a (2, 8,192, 6,144) array, and of a (2, 8,192, 10,304) one at most the one the
-    # parent has too (the scanned run's forward projection, written S-minor: PERF.md row 49 (h))
-    copies = re.findall(r"= bf16\[(?:2,8192|16384),(?:6144|10304)\]\S* copy\([^\n]*", compiled.as_text())
-    assert len(copies) <= 1 and all("10304]" in copy and "/ssm.in_proj/" in copy for copy in copies), copies
+    # slices: no copy of a (2, 8,192, 6,144) array, nor of a (2, 8,192, 10,304) one (until PR 57 the
+    # scanned run's forward projection was written S-minor and copied: PERF.md row 49 (h); kept, it is
+    # written feature-minor into its stack, the identity `jax.checkpoint` puts on it fused into the
+    # matmul's output: one matmul a body forward, none under `recompute`)
+    text = compiled.as_text()
+    copies = re.findall(r"= bf16\[(?:2,8192|16384),(?:6144|10304)\]\S* copy\([^\n]*", text)
+    assert not copies, copies
+    assert "/ssm.in_proj/bse,ef->bsf/dot_general" in text
+    assert "rematted_computation/ssm/ssm.in_proj/bse,ef->bsf/dot_general" not in text
     pairs = {(scope, pass_) for instances in table.values() for scopes, pass_, _ in instances for scope in scopes}
     for scope in ("ssm", "ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.gate_norm"):
         assert {(scope, "fwd"), (scope, "recompute"), (scope, "bwd")} <= pairs, scope
@@ -87,10 +98,14 @@ def test_nemotron3nano_cell_step_runs_the_scan_under_its_scopes_and_compiles(as_
                    and "ssm" not in scopes for scopes in scoped)
     assert not any({"ssm", "moe"} <= scopes or {"ssm", "attn.full"} <= scopes for scopes in scoped)
     assert not any("mlp" in scopes or "attn.window" in scopes for scopes in scoped)
-    # 667.0 M parameters x 12 bytes of state (the gradients are the step's own), and the step fits
+    # 667.0 M parameters x 12 bytes of state (the gradients are the step's own). This compiler counts
+    # 7.30 GiB of temporaries beside them for the parent's plan, 14.75 GiB in all, for the step that ran
+    # on the chip at 13,691,970,560 B = 12.75 GiB, and 1.26 GiB more with the projections kept, for the
+    # step that ran at 15,000,355,328 B = 13.97 GiB = 88.7% of the chip (my chip runs, PR 57): it reads
+    # 2.0 GiB over the chip on both, so the band guards the program, and the chip's reading the fit
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes / GIB == pytest.approx(7.45, abs=0.02)
-    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes) / GIB < 15.75
+    assert memory.temp_size_in_bytes / GIB == pytest.approx(7.30 + 1.26, abs=0.25)
 
 
 def test_evabyte_cell_step_runs_the_kernels_once_a_shard_and_compiles(as_tpu, monkeypatch, v5e):
@@ -167,10 +182,13 @@ def test_ling3flash_cell_step_runs_the_delta_rule_under_its_scopes_and_compiles(
     monkeypatch.setattr(losses, "device_hbm_bytes", lambda: int(15.75 * GIB))
     step = make_train_step(config, opt, mesh, state_shardings=shardings)
     plan = step.remat_plan_for(tokens.shape, state)
-    assert plan["remat"] in ("whole_block", "selective")
-    assert set(plan["remat_saved"]) <= {"kda_chunk_out", "kda_chunk_states", "attn_out", "attn_lse",
-                                        "attn_residual", "attn_latent_kv", "attn_latent_k_rope",
-                                        "mlp_up", "mlp_gate"}
+    # everything the stack names: what the rule kept before PR 57, and the six mixers' in-projections
+    assert plan["remat"] == "selective" and set(plan["remat_saved"]) == {
+        "kda_chunk_out", "kda_chunk_states", "attn_out", "attn_lse", "attn_residual", "attn_latent_kv",
+        "attn_latent_k_rope", "mlp_up", "mlp_gate", "kda_in_proj"}
+    assert [set(names) & {"kda_in_proj", "mlp_up", "attn_out"} for names in plan["remat_saved_by_run"]] == [
+        {"kda_in_proj", "mlp_up"}, {"kda_in_proj", "attn_out"}]
+    assert plan["remat_saved_bytes_by_run"][1] == 4096 * (5 * (16384 + 20480) + (8192 + 64) + 2560 + 576) * 2
     assert step.loss_chunk_for(tokens.shape, state) == 4096
     said = model_family(config).plan(config, 1, 4096)
     assert said["layer_kinds"] == "dK eK eK eK eL eK eK"
@@ -196,6 +214,11 @@ def test_ling3flash_cell_step_runs_the_delta_rule_under_its_scopes_and_compiles(
     assert sorted(found for _, found, _ in table["kda_bwd"]) == ["bwd"] * 6
     for scopes, _, _ in table["kda_fwd"] + table["kda_bwd"]:
         assert {"kda", "kda.chunk"} <= set(scopes), scopes
+    # nor is the in-projection (PR 57: `kda_in_proj` is kept); the small beta and gate projection is
+    text = compiled.as_text()
+    assert "rematted_computation/kda/kda.in_proj/bse,ef->bsf/dot_general" in text
+    assert not re.search(
+        r"bf16\[(?:1,)?4096,16384\][^\n]*rematted_computation/kda/kda.in_proj/bse,ef->bsf/dot_general", text)
     pairs = {(scope, pass_) for instances in table.values() for scopes, pass_, _ in instances for scope in scopes}
     for scope in ("kda", "kda.in_proj", "kda.conv", "kda.chunk", "kda.gate_norm", "kda.out_proj",
                   "attn.full", "attn.latent", "moe", "mlp", "head"):
@@ -204,4 +227,5 @@ def test_ling3flash_cell_step_runs_the_delta_rule_under_its_scopes_and_compiles(
     total = memory.argument_size_in_bytes + memory.temp_size_in_bytes
     print("ling3flash step: arguments", memory.argument_size_in_bytes / GIB, "temporaries",
           memory.temp_size_in_bytes / GIB, "plan", plan)
-    assert total < 15.75 * GIB
+    # the chip ran the parent's plan at 13,217,902,080 B and this one at 12.5 GiB (my chip runs, PR 57)
+    assert total < (1 - losses.HBM_FREE_FRACTION) * 15.75 * GIB

@@ -82,6 +82,7 @@ def test_step_fn_span_names_the_attention_the_step_runs(trainer, monkeypatch):
                      "loss_chunk": 0,   # 0: the dense head; no MoE key on a dense model
                      # gpt2-tiny keeps every activation: nothing is recomputed
                      "remat": "off", "remat_saved": (), "remat_saved_bytes": 0,
+                     "remat_saved_by_run": (), "remat_saved_bytes_by_run": (),
                      "remat_recomputed_flops_share": 0.0}
     # on the chip, at the cells' sequence length
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -127,9 +128,9 @@ def test_step_fn_span_names_how_a_held_expert_layer_adds_up_a_tokens_rows(monkey
 ], ids=["unknown-device-size", "room-for-every-name", "and-the-attention-output"])
 def test_step_fn_span_names_what_a_recomputing_step_keeps(
         monkeypatch, hbm_bytes, kernel_move_cost, want):
-    """`train.init.step_fn` of a model with `remat`: the four `remat*` keys,
-    and what they report is what the step's trace gave the blocks'
-    checkpoint."""
+    """`train.init.step_fn` of a model with `remat`: the six `remat*` keys
+    (what is kept, as a whole and a run of the stack: here one scan), and
+    what they report is what the step's trace gave the blocks' checkpoint."""
     from ray_tpu.models import transformer
     from ray_tpu.ops import losses
 
@@ -155,6 +156,8 @@ def test_step_fn_span_names_what_a_recomputing_step_keeps(
     if "attn_out" in want[1]:
         kept_width += config.d_model // 2 + 4 * 2 // itemsize
     assert attrs["remat_saved_bytes"] == config.n_layers * rows * itemsize * kept_width
+    assert (attrs["remat_saved_by_run"], attrs["remat_saved_bytes_by_run"]) == (
+        (want[1],), (attrs["remat_saved_bytes"],))
     assert 0 < attrs["remat_recomputed_flops_share"] < (0.3 if want[1] else 0.9)
 
 
