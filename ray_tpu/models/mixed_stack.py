@@ -560,43 +560,39 @@ def _kda_sublayer(x, lp, config):
     [q | k | v | f] = norm(x) W_in and [beta | gate] = norm(x) W_bg, one logit
     a head each (`kda.in_proj`); q, k and v through a causal depthwise
     convolution and silu (`kda.conv`, ops/ssd.causal_conv1d with no bias: the
-    state-space mixer's, kernels and rule); q and k L2-normalised a head, q
-    over sqrt(head_dim); the log-decay a channel, `kda_gate_lower_bound` x
-    sigmoid(exp(A_log) (f + dt_bias)) in float32, beta = sigmoid; the chunked
-    delta rule (`kda.chunk`, ops/kda.kda_chunk); an RMS norm over each head's
-    output times sigmoid of the head's ONE gate logit (`kda.gate_norm`); W_out
-    and the residual (`kda.out_proj`). No positions. -> (x, the most negative
+    state-space mixer's, kernels and rule); the chunked delta rule on those
+    as they are, flat (B, S, H D), with f, beta's logits, `A_log` and the
+    gate's bias (`kda.chunk`, ops/kda.kda_rule, which makes of them what the
+    recurrence takes, on a TPU in its kernels' VMEM and in `jnp` elsewhere: q
+    and k L2-normalised a head, q over sqrt(head_dim); the log-decay a
+    channel, `kda_gate_lower_bound` x sigmoid(exp(A_log) (f + dt_bias)) in
+    float32; beta = sigmoid); an RMS norm over each head's output times
+    sigmoid of the head's ONE gate logit (`kda.gate_norm`); W_out and the
+    residual (`kda.out_proj`). No positions. -> (x, the most negative
     cumulative log-decay inside a chunk)."""
     c = config
     dt = c.dtype
-    b, s, _ = x.shape
-    heads, d = c.kda_heads, c.kda_head_dim
-    inner, by_head = heads * d, (b, s, heads, d)
+    heads = c.kda_heads
+    inner = heads * c.kda_head_dim
     with jax.named_scope("kda"):
         with jax.named_scope("kda.in_proj"):
             u = _norm(x, lp["ln1_scale"], None, c.norm, c.norm_eps)
             projected = checkpoint_name(jnp.einsum("bse,ef->bsf", u, lp["kda_in"].astype(dt)), "kda_in_proj")
-            beta_gate = jnp.einsum("bse,ef->bsf", u, lp["kda_bg"].astype(dt)).astype(jnp.float32)
+            # the matmul's float32 accumulator is what the sigmoids take: XLA's fusion carried it there before it
+            # was asked to, and beta's logits are a kernel's operand, which is written as the program says
+            beta_gate = jnp.einsum("bse,ef->bsf", u, lp["kda_bg"].astype(dt), preferred_element_type=jnp.float32)
             # [q | k | v | f]: the WHOLE array for q's and k's convolution and for v's (two calls: the
             # kernels take at most 8,192 channels a step), and f; `_gate_xbc_dt`'s reasons
             for_qk, for_v, f = _gate_xbc_dt(projected, 2 * inner, inner)
+            beta = beta_gate[..., :heads]
         with jax.named_scope("kda.conv"):
             taps = lp["kda_conv_w"]
             q, k = ssd.causal_conv1d(for_qk, taps[:2 * inner], jnp.zeros((2 * inner,), jnp.float32),
                                      splits=(inner, inner))
             v = ssd.causal_conv1d(for_v, taps[2 * inner:], jnp.zeros((inner,), jnp.float32), offset=2 * inner)
         with jax.named_scope("kda.chunk"):
-            def unit(t):    # a head's features over their L2 norm, in float32
-                t = t.reshape(by_head).astype(jnp.float32)
-                return t * jax.lax.rsqrt(jnp.sum(jnp.square(t), axis=-1, keepdims=True) + _KDA_L2_EPS)
-
-            rate = jnp.exp(lp["kda_a_log"].astype(jnp.float32))[:, None]
-            log_decay = c.kda_gate_lower_bound * jax.nn.sigmoid(
-                rate * (f.astype(jnp.float32) + lp["kda_dt_bias"].astype(jnp.float32)).reshape(by_head))
-            out = kda.kda_chunk((unit(q) * d ** -0.5).astype(dt), unit(k).astype(dt), v.reshape(by_head),
-                                log_decay, jax.nn.sigmoid(beta_gate[..., :heads]), chunk=c.kda_chunk,
-                                lower_bound=c.kda_gate_lower_bound)
-            decay_min = jax.lax.stop_gradient(kda.log_decay_chunk_min(log_decay, c.kda_chunk))
+            out, decay_min = kda.kda_rule(q, k, v, f, beta, lp["kda_a_log"], lp["kda_dt_bias"], eps=_KDA_L2_EPS,
+                                          chunk=c.kda_chunk, lower_bound=c.kda_gate_lower_bound)
         with jax.named_scope("kda.gate_norm"):
             out = rmsnorm(out.astype(jnp.float32), lp["kda_norm_scale"],
                           eps=1e-6 if c.norm_eps is None else c.norm_eps)
@@ -878,8 +874,10 @@ def _kda_costs(config: MixedStackConfig) -> Dict[str, Any]:
     q, k, v and f, one matmul's; `_ssm_costs` says why it is worth its matmul:
     on the `train-ling3flash-4k` cell 12.8 ms of a 273.2 ms step for 0.81 GB).
     The small beta and gate projection beside it is NOT named: kept, its
-    bfloat16 rounding is forced where XLA's fusion carries the matmul's
-    float32 into the sigmoids, and the cell's first loss moved by 1e-4.
+    bfloat16 rounding was forced where XLA's fusion carried the matmul's
+    float32 into the sigmoids, and the cell's first loss moved by 1e-4
+    (since PR 58 the program asks for that float32 itself); recomputing it
+    is 7 us a layer.
     Nor are the convolved q, k and v: their copies cost the step 1.1 ms more
     than the convolutions' second run takes (PERF.md section 6, PR 57)."""
     c = config
@@ -898,18 +896,21 @@ def _kda_costs(config: MixedStackConfig) -> Dict[str, Any]:
         "flops": (in_proj + 2 * c.d_model * 2 * heads + 2 * c.kda_conv_kernel * 3 * inner + rule
                   + 2 * inner * c.d_model),
         # the norm's output, the sublayer's, the residual; the projection; the
-        # convolved q, k, v; the normalised q and k; the float32 log-decay; the
-        # chunks' states; the rule's output and the gated, normed one. On the
-        # chip the cell's whole-block step peaks at 13.24 GB and the plan the
+        # convolved q, k, v; the normalised q and k and the float32 log-decay
+        # (the XLA form's arrays: the kernels make them in VMEM); the chunks'
+        # states; the rule's output and the gated, normed one. On the
+        # chip the cell's whole-block step peaked at 13.24 GB and the plan the
         # rule makes (everything named kept, 2.01 GB) at 13.42 GB = 79.4%, where
-        # `step_peak_bytes` says 14.64 and 14.66: high by 1.2-1.4 GB, more than
-        # any width here accounts for (run 0's whole block term is 1.48 GB).
+        # `step_peak_bytes` said 14.64 and 14.66 (PR 57; with the kernels making
+        # their own arguments the plan peaks at 13.34 GB and it says 14.42): high
+        # by 1.1-1.4 GB, more than any width here accounts for (run 0's whole
+        # block term is 1.48 GB).
         # What it over-counts is the moment, not a width: it holds every
         # unrolled layer's gradients beside the LAST block's backward pass,
         # where on the chip a layer's gradients come as its activations go
         # (PERF.md section 7). The safe side, and it changes no decision here
-        "width": (3 * c.d_model + projected + 3 * inner + 2 * inner + inner * 4 // itemsize + state
-                  + 2 * inner),
+        "width": (3 * c.d_model + projected + 3 * inner
+                  + (0 if impl == "pallas" else 2 * inner + inner * 4 // itemsize) + state + 2 * inner),
         "candidates": (
             RematCandidate(("kda_chunk_out", "kda_chunk_states"), inner + state, rule,
                            int(rule / _KDA_SHARE_OF_PEAK[impl]), False, ()),

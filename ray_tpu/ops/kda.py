@@ -1,5 +1,6 @@
 """Kimi Delta Attention's core (arXiv:2510.26692): the delta rule with a decay
-a channel, in its chunked form (`kda_chunk`).
+a channel, in its chunked form, from a mixer's arguments as the mixer has them
+(`kda_rule`) or from the recurrence's own (`kda_chunk`, the XLA form).
 
 The recurrence, a head of D_k key and D_v value features with a state S of
 D_k x D_v, position by position (`kda_reference`):
@@ -48,17 +49,25 @@ until one block is the chunk. It, and the two products with T, are float32 at
 comes in and accumulate in float32; a, its cumulative sums, every exponential
 and the carried state are float32.
 
+WHAT THE MIXER HAS is not what the recurrence takes. `kda_rule` reads q, k, v
+as the causal convolution writes them and the gate's input f, flat (B, S, H D)
+in the activations' dtype, beta's logits (B, S, H), `A_log` a head and the
+gate's bias a channel, and makes of them (`rule_arguments`): q and k a head
+over their L2 norm, q over sqrt(D), rounded to the activations' dtype; the
+log-decay a = `lower_bound` x sigmoid(exp(A_log) (f + bias)) in float32; beta
+= sigmoid. -> (o, the most negative cumulative log-decay a chunk reaches).
+
 Differentiated, the op carries its own backward pass (`custom_vjp`): it keeps
-q, k, v, a, beta, the state that entered each chunk and the output (named
+its arguments, the state that entered each chunk and the output (named
 `kda_chunk_states` and `kda_chunk_out` for a checkpoint policy around the
 caller: with both kept, its backward pass does not run the rule forward a
 second time), builds the chunks' parts again, walks the chunks in reverse with
 the state's cotangent and transposes the parts.
 
-Two forms compute it, chosen by ops/ssd's one static rule on the backend, the
-shapes and the context mesh (`resolve_kda_impl` through `ssd._resolve`;
-`kda_plan` reports it; no flag, and a form is never tried and swapped for the
-other):
+Two forms compute `kda_rule`, chosen by ops/ssd's one static rule on the
+backend, the shapes and the context mesh (`resolve_kda_impl` through
+`ssd._resolve`; `kda_plan` reports it; no flag, and a form is never tried and
+swapped for the other). The form that tiles is the form that fuses:
 
 - "pallas", on a TPU at the sizes the kernels tile: two Mosaic kernels under
   one `custom_vjp`, `kda_fwd` and `kda_bwd`, bound through ONE primitive
@@ -67,13 +76,24 @@ other):
   in VMEM (the cumulative log-decay as a triangular matmul, the two pairwise
   matrices, T, W, U'), then does one step of the walk against the block's
   float32 states, which live in VMEM scratch from a sequence's first chunk to
-  its last. It reads q, k, v, a as (B, S, H D), a head one 128-lane tile, and
-  writes o and, differentiated, the float32 state that entered the chunk (134
-  MB a layer of 4,096 tokens of 32 heads, what the XLA form keeps). The
-  backward is ONE kernel, the same walk reversed with the states' cotangents
-  in scratch: it builds the parts again from the arguments, transposes the
-  chunk's step and its parts, takes a's reverse cumulative sum as a
-  triangular matmul and writes dq, dk, dv, da, dbeta; it keeps nothing of its
+  its last. It reads the MIXER'S arguments, q, k, v and f as (B, S, H D), a
+  head one 128-lane tile, beta's logits a (64, H) block, exp(A_log) and the
+  bias a (1, H D) row each, and makes the recurrence's in VMEM first
+  (`_kernel_arguments`: two lane reductions and a sigmoid a head block, each
+  rounding where `rule_arguments` has it, the float32 log-decay never
+  written: around the kernels XLA took as long as the kernels, PERF.md
+  section 6, PR 58). It writes o, the least cumulative log-decay a channel at
+  a chunk's end (a (1, H D) block that stays over a sequence's chunks) and,
+  differentiated, the float32 state that entered the chunk (134 MB a layer of
+  4,096 tokens of 32 heads, what the XLA form keeps). The backward is ONE
+  kernel, the same walk reversed with the states' cotangents in scratch: it
+  makes the arguments and builds the parts again, transposes the chunk's
+  step and its parts, takes a's reverse cumulative sum as a triangular
+  matmul, goes on in float32 through the gate's, beta's and the norms'
+  derivatives and writes the cotangents of the mixer's q, k, v, f (one
+  rounding each) and of beta's logits; the two gate parameters' gradients
+  are summed in float32, a channel, over a sequence's chunks in two blocks
+  that stay, and finished in XLA from those few KB. It keeps nothing of its
   own. Every array of a step carries the block's heads side by side, so the
   long chain of dependent steps of one head (cumulative sum, exponentials,
   sub-blocks, 15 eliminations, two merges, T's products, the state) is every
@@ -87,12 +107,13 @@ other):
   pairwise form's does; the exponents A_i - R lie in [-80, 0] under the gate's
   bound and are centred (+-40), so that neither factor times a small feature
   leaves float32's normal numbers (e^{-80} q_d does: 0.004 of the output at
-  the bound, measured). So the kernels count on the gate's lower bound:
-  `kda_chunk` takes it (`lower_bound`, the configuration's
-  `kda_gate_lower_bound`), and bound x 16 under -87 does not tile. Everything
+  the bound, measured). So the kernels count on the gate's lower bound, which
+  they apply themselves (`lower_bound`, the configuration's
+  `kda_gate_lower_bound`): bound x 16 under -87 does not tile. Everything
   else is float32 or bfloat16 exactly where the XLA form is.
 - "xla_chunked", everywhere else (every CPU run) and what the kernels are
-  compared with: the einsums above, the diagonal sub-blocks pairwise.
+  compared with: `rule_arguments` in `jnp`, then `kda_chunk`, the einsums
+  above, the diagonal sub-blocks pairwise.
 
 What the kernels tile, and nothing else (other sizes run the XLA form, by the
 rule; a kernel asked for by name there is refused by name): key and value
@@ -151,7 +172,7 @@ def _heads_per_step(heads: int) -> int:
 
 def resolve_kda_impl(implementation: Optional[str] = None, *, chunk: int = CHUNK, d_k: int = 0, d_v: int = 0,
                      lower_bound: float = LOWER_BOUND) -> str:
-    """The implementation `kda_chunk` runs: "pallas" (the kernels `kda_fwd` /
+    """The implementation `kda_rule` runs: "pallas" (the kernels `kda_fwd` /
     `kda_bwd`) or "xla_chunked" (the einsums of `_chunked`), by ops/ssd's one
     rule (`ssd._resolve`): with nothing asked, "pallas" on a TPU, on one
     device or inside a `shard_map`, at the sizes the kernels tile (a chunk of
@@ -159,22 +180,25 @@ def resolve_kda_impl(implementation: Optional[str] = None, *, chunk: int = CHUNK
     sub-block of 16 stays over -87) and "xla_chunked" elsewhere; a kernel
     asked for by name where it does not tile is refused by name."""
     return ssd._resolve(implementation, _IMPLEMENTATIONS, _kernels_tile(chunk, d_k, d_v, lower_bound), "kda",
-                        f"kda_chunk: the kernels do not tile a chunk of {chunk}, key heads of {d_k} and value "
+                        f"kda_rule: the kernels do not tile a chunk of {chunk}, key heads of {d_k} and value "
                         f"heads of {d_v} under a gate whose lower bound is {lower_bound} a position")
 
 
 def kda_plan(chunk: int = CHUNK, implementation: Optional[str] = None, *, heads: int = 0, d_k: int = 0,
              d_v: int = 0, lower_bound: float = LOWER_BOUND) -> dict:
-    """What `kda_chunk` resolves to for `heads` heads of `d_k` key and `d_v`
+    """What `kda_rule` resolves to for `heads` heads of `d_k` key and `d_v`
     value features, for callers that report it: the implementation's name, the
     chunk and its sub-block, the `pallas_call`s a differentiated rule makes,
     the heads a grid step takes and the float32 state a grid step holds in
-    VMEM scratch (none of the three for the XLA form)."""
+    VMEM scratch (none of the three for the XLA form), and which form makes
+    the recurrence's arguments (the L2 norms, the gate's log-decay, beta) from
+    the mixer's, `kda_prologue`: "kernel" (in VMEM, `_kernel_arguments`) or
+    "xla" (`rule_arguments`)."""
     impl = resolve_kda_impl(implementation, chunk=chunk, d_k=d_k, d_v=d_v, lower_bound=lower_bound)
     per_step = _heads_per_step(heads) if impl == "pallas" else 0
     return {"kda_impl": impl, "kda_chunk": chunk, "kda_subchunk": _subchunk(chunk),
             "kda_kernels": 2 if impl == "pallas" else 0, "kda_heads_per_step": per_step,
-            "kda_state_bytes": per_step * d_k * d_v * 4}
+            "kda_state_bytes": per_step * d_k * d_v * 4, "kda_prologue": "kernel" if impl == "pallas" else "xla"}
 
 
 def _subchunk(chunk: int) -> int:
@@ -343,12 +367,13 @@ _chunked.defvjp(_chunked_fwd, _chunked_bwd)
 # block's float32 states (their cotangents) in VMEM scratch from a sequence's
 # first chunk to its last, TRANSPOSED (D_v, D_k): the decay of a state is a
 # channel of its keys, a row (1, D_k) over the lanes as the cumulative
-# log-decay's last position is. q, k, v, a, o and their cotangents are (B, S,
-# H D) as the mixer has them, a head one 128-lane tile; beta comes (B, H /
-# heads a step, S, heads a step), a head a column. A step builds in VMEM what
-# `_chunk_parts` builds and does one step of `_states` and `_read_out`;
-# nothing of a chunk but o (and, differentiated, the state that entered it)
-# reaches HBM.
+# log-decay's last position is. q, k, v, f, o and their cotangents are (B, S,
+# H D) as the mixer has them, a head one 128-lane tile; beta's logits come
+# (B, S, H), every head's to every step (their cotangent goes (B, H / heads a
+# step, S, heads a step), a head a column). A step makes in VMEM what
+# `rule_arguments` makes and what `_chunk_parts` builds of it, and does one
+# step of `_states` and `_read_out`; nothing of a chunk but o (and,
+# differentiated, the state that entered it) reaches HBM.
 #
 # The pairwise decays inside a diagonal sub-block are FACTORED relative to the
 # sub-block's own start R, (x_i e^{A_i - R}) . (k_j e^{R - A_j}), with both
@@ -438,31 +463,60 @@ def _kernel_parts(q, k, v, a, beta):
     unweighted = jnp.concatenate([kf * e_all, v.astype(F32)], axis=2)  # (C, 2 D)
     solved = _dots(inverse, beta * unweighted, precision=_HIGHEST)
     return dict(kf=kf, e_in=e_in, up=up, down=down, kin=kin, qin=qin, kdown=kdown, both=both, before=before,
-                keys=keys, queries=queries, inverse=inverse, e_all=e_all, to_last=to_last, leaving=jnp.exp(last),
+                keys=keys, queries=queries, inverse=inverse, e_all=e_all, to_last=to_last, last=last,
+                leaving=jnp.exp(last),
                 unweighted=unweighted, solved=solved, w=solved[:, :, :d], u_own=solved[:, :, d:],
                 decayed_k=kf * to_last, decayed_q=qf * e_all, tri=tri, row=row, col=col, same=same,
                 position=position)
 
 
 def _by_head(ref, heads: int):
-    """A block (1, C, heads x 128) -> (heads, C, 128)."""
+    """A block (1, C, heads x 128) -> (heads, C, 128); a channel's number, C = 1, as well."""
     return jnp.stack([ref[0, :, h * _LANES:(h + 1) * _LANES] for h in range(heads)])
 
 
-def _columns(ref, heads: int):
-    """beta's block (1, 1, C, heads) -> (heads, C, 1)."""
-    return jnp.stack([ref[0, 0, :, h:h + 1] for h in range(heads)])
+def _beta_columns(ref, heads: int):
+    """beta of the step's heads, a column each (heads, C, 1), from the block
+    (1, C, H) of every head's logit: the sigmoid, then the block's heads
+    picked out of the lanes by a mask (the block of heads is a grid index)."""
+    every = jax.nn.sigmoid(ref[0])
+    lane = jax.lax.broadcasted_iota(jnp.int32, every.shape, 1) - pl.program_id(1) * heads
+    return jnp.stack([jnp.sum(jnp.where(lane == h, every, 0.0), axis=1, keepdims=True) for h in range(heads)])
 
 
 def _write(ref, value):
-    """(heads, C, 128) into a block (1, C, heads x 128)."""
+    """(heads, C, 128) into a block (1, C, heads x 128); C = 1 as well."""
     for h in range(value.shape[0]):
         ref[0, :, h * _LANES:(h + 1) * _LANES] = value[h].astype(ref.dtype)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, a_ref, beta_ref, o_ref, *rest):
+def _kernel_arguments(q_ref, k_ref, f_ref, rate_ref, bias_ref, heads: int, lower_bound: float, eps: float):
+    """What the recurrence takes, made in VMEM from what the mixer has (the
+    XLA form's `rule_arguments`, rounded where it rounds): q and k (heads, C,
+    D) over their L2 norm a head, q over sqrt(D), in the activations' dtype;
+    the log-decay `lower_bound` x sigmoid(rate (f + bias)) in float32, never
+    rounded. Beside them what their transposes read: the float32 unit vectors,
+    the inverse norms (heads, C, 1), the sigmoid and f + bias."""
+    dtype = q_ref.dtype
+    units = {}
+    for name, ref in (("q", q_ref), ("k", k_ref)):
+        t = _by_head(ref, heads).astype(F32)
+        inverse_norm = jax.lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True) + eps)
+        units[name] = (t * inverse_norm, inverse_norm)
+    scale = _LANES ** -0.5
+    rate = _by_head(rate_ref, heads)
+    shifted = _by_head(f_ref, heads).astype(F32) + _by_head(bias_ref, heads)
+    gate = jax.nn.sigmoid(rate * shifted)
+    return dict(q=(units["q"][0] * scale).astype(dtype), k=units["k"][0].astype(dtype), a=lower_bound * gate,
+                units=units, scale=scale, rate=rate, shifted=shifted, gate=gate)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, f_ref, beta_ref, rate_ref, bias_ref, o_ref, least_ref, *rest,
+                lower_bound: float, eps: float):
     """A chunk of a block of heads from the states in `state_scr`, which it
-    leaves updated: o, and where `rest` holds a block for them the states
+    leaves updated: o, the least cumulative log-decay a channel that any chunk
+    of the sequence's reached at its last position (the block stays over a
+    sequence's chunks), and where `rest` holds a block for them the states
     that entered the chunk."""
     *states_ref, state_scr = rest
     dtype = q_ref.dtype
@@ -471,9 +525,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, a_ref, beta_ref, o_ref, *rest):
     @pl.when(pl.program_id(2) == 0)
     def _():
         state_scr[...] = jnp.zeros_like(state_scr)
+        least_ref[...] = jnp.zeros_like(least_ref)
 
-    parts = _kernel_parts(_by_head(q_ref, heads), _by_head(k_ref, heads), _by_head(v_ref, heads),
-                          _by_head(a_ref, heads), _columns(beta_ref, heads))
+    made = _kernel_arguments(q_ref, k_ref, f_ref, rate_ref, bias_ref, heads, lower_bound, eps)
+    parts = _kernel_parts(made["q"], made["k"], _by_head(v_ref, heads), made["a"], _beta_columns(beta_ref, heads))
+    _write(least_ref, jnp.minimum(_by_head(least_ref, heads), parts["last"]))
     state = state_scr[...]
     if states_ref:
         states_ref[0][0, 0] = state
@@ -484,23 +540,32 @@ def _fwd_kernel(q_ref, k_ref, v_ref, a_ref, beta_ref, o_ref, *rest):
     state_scr[...] = parts["leaving"] * state + _dots(u, parts["decayed_k"].astype(dtype), _TN)
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, a_ref, beta_ref, states_ref, do_ref,
-                dq_ref, dk_ref, dv_ref, da_ref, dbeta_ref, dstate_scr):
+def _bwd_kernel(q_ref, k_ref, v_ref, f_ref, beta_ref, rate_ref, bias_ref, states_ref, do_ref,
+                dq_ref, dk_ref, dv_ref, df_ref, dbeta_ref, dgate_ref, dgate_shifted_ref, dstate_scr, *,
+                lower_bound: float, eps: float):
     """The transpose of `_fwd_kernel`'s chunk, the chunks in reverse: the
-    parts again in VMEM, the cotangent of the state that leaves the chunk in
-    `dstate_scr`, replaced by that of the state that entered it. The sub-block
-    starts R are constants of the factoring (the decays do not depend on
-    them), so nothing reaches a through them."""
+    arguments and the parts again in VMEM, the cotangent of the state that
+    leaves the chunk in `dstate_scr`, replaced by that of the state that
+    entered it; then the float32 cotangents of the recurrence's arguments
+    through the norms', the gate's and beta's sigmoid's derivatives, written
+    as those of the mixer's (one rounding each). The sums over the sequence
+    that the gate's two parameters' gradients are made of stay float32: the
+    cotangent d of rate (f + bias) and d (f + bias), a channel, added up over
+    the chunks in blocks that stay. The sub-block starts R are constants of
+    the factoring (the decays do not depend on them), so nothing reaches a
+    through them."""
     dtype = q_ref.dtype
     heads = dstate_scr.shape[0]
 
     @pl.when(pl.program_id(2) == 0)
     def _():
         dstate_scr[...] = jnp.zeros_like(dstate_scr)
+        dgate_ref[...] = jnp.zeros_like(dgate_ref)
+        dgate_shifted_ref[...] = jnp.zeros_like(dgate_shifted_ref)
 
-    beta = _columns(beta_ref, heads)
-    p = _kernel_parts(_by_head(q_ref, heads), _by_head(k_ref, heads), _by_head(v_ref, heads),
-                      _by_head(a_ref, heads), beta)
+    made = _kernel_arguments(q_ref, k_ref, f_ref, rate_ref, bias_ref, heads, lower_bound, eps)
+    beta = _beta_columns(beta_ref, heads)
+    p = _kernel_parts(made["q"], made["k"], _by_head(v_ref, heads), made["a"], beta)
     _, c, d = p["kf"].shape
     row, col, same = p["row"], p["col"], p["same"]
     state, d_after = states_ref[0, 0], dstate_scr[...]                   # (heads, D_v, D_k)
@@ -543,60 +608,80 @@ def _bwd_kernel(q_ref, k_ref, v_ref, a_ref, beta_ref, states_ref, do_ref,
     d_up = d_both * p["both"]
     dk = (d_both[:, :c] * p["up"] + d_kdown * p["down"] + d_kin * p["e_in"] + d_k_before
           + d_keyed_all * p["e_all"] + d_decayed_k * p["to_last"])
-    dq = d_both[:, c:] * p["up"] + d_qin * p["e_in"] + d_decayed_q * p["e_all"]
+    dq = (d_both[:, c:] * p["up"] + d_qin * p["e_in"] + d_decayed_q * p["e_all"]) * made["scale"]
     to_last = d_decayed_k * p["decayed_k"]
     d_cum = (d_up[:, :c] + d_up[:, c:] - d_kdown * p["kdown"] + d_kin * p["kin"] + d_qin * p["qin"] - d_cum_before
              + d_keyed_all * p["unweighted"][:, :, :d] + d_decayed_q * p["decayed_q"] - to_last)
     d_last = jnp.sum(to_last, axis=1, keepdims=True) + d_leaving * p["leaving"]
     d_cum = d_cum + jnp.where(p["position"] == c - 1, d_last, 0.0)
-    _write(dq_ref, dq)
-    _write(dk_ref, dk)
+    # a unit vector t / |t|: its cotangent less its part along the vector, over |t|
+    for ref, name, d_unit in ((dq_ref, "q", dq), (dk_ref, "k", dk)):
+        unit, inverse_norm = made["units"][name]
+        _write(ref, inverse_norm * (d_unit - unit * jnp.sum(d_unit * unit, axis=-1, keepdims=True)))
     _write(dv_ref, d_v)
-    _write(da_ref, _dots(p["tri"], d_cum, _TN, _HIGHEST))               # the reverse cumulative sum
+    d_a = _dots(p["tri"], d_cum, _TN, _HIGHEST)                           # the reverse cumulative sum
+    d_pre = d_a * (lower_bound * made["gate"] * (1.0 - made["gate"]))
+    _write(df_ref, d_pre * made["rate"])
+    for ref, summed in ((dgate_ref, d_pre), (dgate_shifted_ref, d_pre * made["shifted"])):
+        _write(ref, _by_head(ref, heads) + jnp.sum(summed, axis=1, keepdims=True))
+    d_logit = d_beta * beta * (1.0 - beta)
     head_lane = jax.lax.broadcasted_iota(jnp.int32, dbeta_ref.shape[2:], 1)
     d_beta_all = jnp.zeros(dbeta_ref.shape[2:], F32)
     for h in range(heads):
-        d_beta_all = jnp.where(head_lane == h, d_beta[h], d_beta_all)
+        d_beta_all = jnp.where(head_lane == h, d_logit[h], d_beta_all)
     dbeta_ref[0, 0] = d_beta_all
 
 
-def _kda_call(q, k, v, a, beta, *kept, heads: int, keep_states: bool, interpret: bool):
-    """`kda_fwd` (no `kept`) -> [o (B, S, H D)] and, with `keep_states`, the
-    float32 states that entered the chunks, transposed (B, chunks, H, D_v,
+def _kda_call(q, k, v, f, beta, rate, bias, *kept, heads: int, keep_states: bool, lower_bound: float, eps: float,
+              interpret: bool):
+    """`kda_fwd` (no `kept`) -> [o (B, S, H D), the least cumulative log-decay
+    a channel at a chunk's end (B, 1, H D) float32] and, with `keep_states`,
+    the float32 states that entered the chunks, transposed (B, chunks, H, D_v,
     D_k); or `kda_bwd` on `kept` = (those states, o's cotangent) -> [dq, dk,
-    dv, da, dbeta]. q, k, v (B, S, H D), a (B, S, H D) float32, beta (B, H /
-    heads, S, heads) float32, `heads` the heads a grid step takes."""
+    dv, df, beta's logits' cotangent (B, H / heads, S, heads) float32, and the
+    sums over a sequence of the cotangent of rate (f + bias) and of it times f
+    + bias, a channel (B, 1, H D) float32 each]. q, k, v, f (B, S, H D) as
+    the mixer has them, beta's logits (B, S, H) float32, `rate` and `bias` (1,
+    1, H D) float32 a channel, `heads` the heads a grid step takes."""
     bsz, s, inner = q.shape
     chunks, width = s // CHUNK, heads * _LANES
     backward = bool(kept)
     of = (lambda n: chunks - 1 - n) if backward else (lambda n: n)
     wide = pl.BlockSpec((1, CHUNK, width), lambda b, g, n: (b, of(n), g))
+    logits = pl.BlockSpec((1, CHUNK, beta.shape[-1]), lambda b, g, n: (b, of(n), 0))
+    channel = pl.BlockSpec((1, 1, width), lambda b, g, n: (0, 0, g))
+    summed = pl.BlockSpec((1, 1, width), lambda b, g, n: (b, 0, g))         # stays over a sequence's chunks
     column = pl.BlockSpec((1, 1, CHUNK, heads), lambda b, g, n: (b, g, of(n), 0))
     states = pl.BlockSpec((1, 1, heads, _LANES, _LANES), lambda b, g, n: (b, of(n), g, 0, 0))
     states_shape = jax.ShapeDtypeStruct((bsz, chunks, inner // _LANES, _LANES, _LANES), F32)
+    summed_shape = jax.ShapeDtypeStruct((bsz, 1, inner), F32)
+    in_specs = [wide] * 4 + [logits, channel, channel]
     if backward:
-        in_specs, out_specs = [wide] * 4 + [column, states, wide], [wide] * 4 + [column]
-        out_shape = [jax.ShapeDtypeStruct(t.shape, t.dtype) for t in (q, k, v, a, beta)]
+        in_specs, out_specs = in_specs + [states, wide], [wide] * 4 + [column, summed, summed]
+        out_shape = [jax.ShapeDtypeStruct(t.shape, t.dtype) for t in (q, k, v, f)] + [
+            jax.ShapeDtypeStruct((bsz, inner // width, s, heads), F32), summed_shape, summed_shape]
     else:
-        in_specs, out_specs = [wide] * 4 + [column], [wide] + [states] * keep_states
-        out_shape = [jax.ShapeDtypeStruct(v.shape, v.dtype)] + [states_shape] * keep_states
+        out_specs = [wide, summed] + [states] * keep_states
+        out_shape = [jax.ShapeDtypeStruct(v.shape, v.dtype), summed_shape] + [states_shape] * keep_states
     return pl.pallas_call(
-        _bwd_kernel if backward else _fwd_kernel,
+        functools.partial(_bwd_kernel if backward else _fwd_kernel, lower_bound=lower_bound, eps=eps),
         grid=(bsz, inner // width, chunks), in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((heads, _LANES, _LANES), F32)],
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary"),
                                              vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret, name="kda_bwd" if backward else "kda_fwd",
-    )(q, k, v, a, beta, *kept)
+    )(q, k, v, f, beta, rate, bias, *kept)
 
 
-def _kda_shapes(q, k, v, a, beta, *kept, heads, keep_states, interpret):
-    del heads, interpret
-    if kept:
-        return [t.update(weak_type=False) for t in (q, k, v, a, beta)]
+def _kda_shapes(q, k, v, f, beta, rate, bias, *kept, heads, keep_states, lower_bound, eps, interpret):
+    del lower_bound, eps, interpret
     bsz, s, inner = q.shape
-    states = a.update(shape=(bsz, s // CHUNK, inner // _LANES, _LANES, _LANES), weak_type=False)
-    return [v.update(weak_type=False)] + [states] * keep_states
+    summed = rate.update(shape=(bsz, 1, inner), weak_type=False)
+    if kept:
+        d_beta = rate.update(shape=(bsz, inner // (heads * _LANES), s, heads), weak_type=False)
+        return [t.update(weak_type=False) for t in (q, k, v, f)] + [d_beta, summed, summed]
+    states = rate.update(shape=(bsz, s // CHUNK, inner // _LANES, _LANES, _LANES), weak_type=False)
+    return [v.update(weak_type=False), summed] + [states] * keep_states
 
 
 # Every call site enters through ONE primitive whose lowering builds the kernel
@@ -609,52 +694,112 @@ kda_p.def_impl(lambda *args, **params: jax.jit(functools.partial(kda_p.bind, **p
 mlir.register_lowering(kda_p, mlir.lower_fun(_kda_call, multiple_results=True), inline=False)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _kernels(q, k, v, a, beta, heads, interpret):
-    return kda_p.bind(q, k, v, a, beta, heads=heads, keep_states=False, interpret=interpret)[0]
+def _channel_rows(a_log, dt_bias, d: int):
+    """(exp(A_log) a head over its `d` channels, the gate's bias), float32 (1, 1, H d) each: the kernels' operands."""
+    return jnp.repeat(jnp.exp(a_log.astype(F32)), d).reshape(1, 1, -1), dt_bias.astype(F32).reshape(1, 1, -1)
 
 
-def _kernels_fwd(q, k, v, a, beta, heads, interpret):
-    out, states = kda_p.bind(q, k, v, a, beta, heads=heads, keep_states=True, interpret=interpret)
+def _bind(q, k, v, f, beta, a_log, dt_bias, *kept, keep_states: bool, static):
+    """The primitive on the mixer's arguments (and `kda_bwd`'s `kept`); `static`: its other parameters, as pairs."""
+    rows = _channel_rows(a_log, dt_bias, q.shape[-1] // a_log.shape[0])
+    return kda_p.bind(q, k, v, f, beta, *rows, *kept, keep_states=keep_states, **dict(static))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def _kernels(q, k, v, f, beta, a_log, dt_bias, static):
+    """-> (o (B, S, H D), the least cumulative log-decay a channel at a chunk's end (B, 1, H D))."""
+    return tuple(_bind(q, k, v, f, beta, a_log, dt_bias, keep_states=False, static=static))
+
+
+def _kernels_fwd(*arguments_and_static):
+    *arguments, static = arguments_and_static
+    out, least, states = _bind(*arguments, keep_states=True, static=static)
     # what a checkpoint around the caller may keep: with both, its backward
     # pass starts from here and does not run `kda_fwd` a second time
     out = checkpoint_name(out, "kda_chunk_out")
     states = checkpoint_name(states, "kda_chunk_states")
-    return out, (q, k, v, a, beta, states)
+    return (out, least), (*arguments, states)
 
 
-def _kernels_bwd(heads, interpret, kept, d_out):
-    return tuple(kda_p.bind(*kept, d_out, heads=heads, keep_states=False, interpret=interpret))
+def _kernels_bwd(static, kept, cotangents):
+    *arguments, states = kept
+    a_log, dt_bias = arguments[5:]
+    b, s, _ = arguments[0].shape
+    *d_arguments, d_beta, d_gate, d_gate_shifted = _bind(*arguments, states, cotangents[0], keep_states=False,
+                                                         static=static)
+    # rate (f + bias) with rate = exp(A_log) a head: the two parameters' gradients from the kernel's float32
+    # sums a channel, over the sequences here
+    rate = jnp.exp(a_log.astype(F32))
+    d_bias = jnp.sum(d_gate, axis=(0, 1)).reshape(a_log.shape[0], -1) * rate[:, None]
+    d_a_log = jnp.sum(jnp.sum(d_gate_shifted, axis=(0, 1)).reshape(a_log.shape[0], -1), axis=1) * rate
+    return (*d_arguments, jnp.transpose(d_beta, (0, 2, 1, 3)).reshape(b, s, -1),
+            d_a_log.astype(a_log.dtype), d_bias.reshape(dt_bias.shape).astype(dt_bias.dtype))
 
 
 _kernels.defvjp(_kernels_fwd, _kernels_bwd)
 
 
+def rule_arguments(q: jax.Array, k: jax.Array, v: jax.Array, f: jax.Array, beta: jax.Array, a_log: jax.Array,
+                   dt_bias: jax.Array, *, lower_bound: float, eps: float):
+    """What the recurrence takes from what the mixer has, in `jnp` (the
+    kernels make the same in VMEM, `_kernel_arguments`): q, k, v and the
+    gate's input f flat (B, S, H D), beta's logits (B, S, H), `a_log` (H,),
+    `dt_bias` (H D,) -> q and k a head over their L2 norm (`eps` inside the
+    root), q over sqrt(D), in their dtype (B, S, H, D); v (B, S, H, D); the
+    log-decay a = `lower_bound` x sigmoid(exp(a_log) (f + dt_bias)) float32
+    (B, S, H, D); beta = sigmoid, float32."""
+    b, s, inner = q.shape
+    heads = a_log.shape[0]
+    by_head = (b, s, heads, inner // heads)
+
+    def unit(t):    # a head's features over their L2 norm, in float32
+        t = t.reshape(by_head).astype(F32)
+        return t * jax.lax.rsqrt(jnp.sum(jnp.square(t), axis=-1, keepdims=True) + eps)
+
+    rate = jnp.exp(a_log.astype(F32))[:, None]
+    log_decay = lower_bound * jax.nn.sigmoid(rate * (f.astype(F32) + dt_bias.astype(F32)).reshape(by_head))
+    return ((unit(q) * by_head[-1] ** -0.5).astype(q.dtype), unit(k).astype(k.dtype),
+            v.reshape(b, s, heads, -1), log_decay, jax.nn.sigmoid(beta.astype(F32)))
+
+
+def kda_rule(q: jax.Array, k: jax.Array, v: jax.Array, f: jax.Array, beta: jax.Array, a_log: jax.Array,
+             dt_bias: jax.Array, *, eps: float, chunk: int = CHUNK, lower_bound: float = LOWER_BOUND,
+             implementation: Optional[str] = None) -> Tuple[jax.Array, jax.Array]:
+    """The delta rule from the mixer's arguments as the mixer has them
+    (`rule_arguments` says which and what is made of them): -> (o (B, S, H,
+    D) in v's dtype, the most negative cumulative log-decay any channel
+    reaches inside a chunk, a scalar with no gradient). Differentiable in all
+    seven. `lower_bound` is the gate's (the kernels' diagonal sub-blocks count
+    on it); `implementation` is `resolve_kda_impl`'s, for tests: the kernels
+    make the recurrence's arguments in VMEM, the XLA form in `jnp`
+    (`rule_arguments`, then `kda_chunk`)."""
+    b, s, inner = q.shape
+    heads = a_log.shape[0]
+    d = inner // heads
+    impl = resolve_kda_impl(implementation, chunk=chunk, d_k=d, d_v=v.shape[-1] // heads, lower_bound=lower_bound)
+    if s % chunk:
+        raise ValueError(f"kda_rule: a sequence of {s} is no multiple of the chunk {chunk}")
+    if impl == "pallas":
+        static = dict(heads=_heads_per_step(heads), lower_bound=float(lower_bound), eps=float(eps),
+                      interpret=jax.default_backend() != "tpu")
+        out, least = _kernels(q, k, v, f, beta.astype(F32), a_log, dt_bias, tuple(sorted(static.items())))
+        return out.reshape(b, s, heads, d), jax.lax.stop_gradient(jnp.min(least))
+    q, k, v, log_decay, beta = rule_arguments(q, k, v, f, beta, a_log, dt_bias, lower_bound=lower_bound, eps=eps)
+    return kda_chunk(q, k, v, log_decay, beta, chunk=chunk), jax.lax.stop_gradient(
+        log_decay_chunk_min(log_decay, chunk))
+
+
 def kda_chunk(q: jax.Array, k: jax.Array, v: jax.Array, a: jax.Array, beta: jax.Array, *,
-              chunk: int = CHUNK, implementation: Optional[str] = None,
-              lower_bound: float = LOWER_BOUND) -> jax.Array:
-    """The delta rule with a decay a channel on q, k (B, S, H, D_k), v (B, S,
-    H, D_v), the log-decay a (B, S, H, D_k) <= 0 and beta (B, S, H) -> o (B,
-    S, H, D_v) in v's dtype, from a zero state, in chunks of `chunk`
-    positions (the module's docstring). a and beta are taken in float32;
-    differentiable in all five. `lower_bound` is the least log-decay a
-    position that the caller's gate gives (the kernels' diagonal sub-blocks
-    count on it); `implementation` is `resolve_kda_impl`'s, for tests."""
-    b, s, h, d_k = q.shape
-    d_v = v.shape[-1]
-    impl = resolve_kda_impl(implementation, chunk=chunk, d_k=d_k, d_v=d_v, lower_bound=lower_bound)
+              chunk: int = CHUNK) -> jax.Array:
+    """The XLA form on the recurrence's own arguments, what `kda_reference`
+    is compared with: the delta rule with a decay a channel on q, k (B, S, H,
+    D_k), v (B, S, H, D_v), the log-decay a (B, S, H, D_k) <= 0 and beta (B,
+    S, H) -> o (B, S, H, D_v) in v's dtype, from a zero state, in chunks of
+    `chunk` positions (the module's docstring). a and beta are taken in
+    float32; differentiable in all five."""
+    b, s, h, _ = q.shape
     if s % chunk:
         raise ValueError(f"kda_chunk: a sequence of {s} is no multiple of the chunk {chunk}")
-    if impl == "pallas":
-        per_step = _heads_per_step(h)
-
-        def columns(t):     # (B, S, H) -> a head a column of its block: (B, H / per_step, S, per_step)
-            return jnp.transpose(t.reshape(b, s, h // per_step, per_step), (0, 2, 1, 3))
-
-        flat = (b, s, h * d_k)
-        out = _kernels(q.reshape(flat), k.reshape(flat), v.reshape(flat), a.astype(F32).reshape(flat),
-                       columns(beta.astype(F32)), per_step, jax.default_backend() != "tpu")
-        return out.reshape(b, s, h, d_v)
 
     def cut(t):     # (B, S, H, ...) -> (N, B, H, C, ...)
         t = t.reshape(b, s // chunk, chunk, *t.shape[2:])

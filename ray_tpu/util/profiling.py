@@ -685,8 +685,13 @@ def roofline(cost: StepCost, step_time_s: float) -> Dict[str, Any]:
 # group) and `ssm.out_proj` (the projection and the residual); `kda` a
 # delta-rule mixer whole, and inside it `kda.in_proj` (norm and the two
 # projections), `kda.conv` (the convolutions of q, k and v and their silu),
-# `kda.chunk` (the L2 norms, the gate's log-decay, beta and the chunked delta
-# rule), `kda.gate_norm` (the norm a head and its gate) and `kda.out_proj`.
+# `kda.chunk` (ops/kda.kda_rule: the chunked delta rule from q, k, v, the
+# gate's input and beta's logits as the mixer has them; on a TPU its two
+# kernels, which make the L2 norms, the log-decay and beta in VMEM, the copy
+# of what a checkpoint keeps of them, and the few small operations that
+# prepare their rows and finish the gate's two gradients; in the XLA form
+# all of that as operations), `kda.gate_norm` (the norm a head and its gate)
+# and `kda.out_proj`.
 STEP_SCOPES: Tuple[str, ...] = (
     "steplog.fwd_bwd_compute", "steplog.optimizer_update",
     "embed",

@@ -25,6 +25,28 @@ def _inputs(seed, b=2, s=128, h=2, d_k=16, d_v=8, gate=None, dtype=jnp.float32, 
     return q.astype(dtype), k.astype(dtype), v.astype(dtype), a, beta
 
 
+def _raw(seed, b=1, s=128, h=2, d_k=128, d_v=128, f=None, dtype=jnp.float32, same_keys=False, zero_query_head=False):
+    """The mixer's own arguments for `kda.kda_rule`: q, k, v and the gate's input f flat (B, S, H D), none of
+    them normalised, beta's logits (B, S, H), `A_log` (H,) and the gate's bias (H D_k,). With `f` a number the
+    gate is that input everywhere under a rate of 1 and no bias: 5 sigmoid(f) is the decay a position."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 7)
+    q = 3.0 * jax.random.normal(keys[0], (b, s, h, d_k))
+    if zero_query_head:     # the head's L2 norm is the epsilon's alone
+        q = q.at[:, :, 0].set(0.0)
+    k = jnp.broadcast_to(2.0 * jax.random.normal(keys[1], (b, 1 if same_keys else s, h, d_k)), (b, s, h, d_k))
+    v = jax.random.normal(keys[2], (b, s, h * d_v))
+    if f is None:           # log-decays over the whole range of the bounded gate, a rate a head, a bias a channel
+        gate = 3.0 * jax.random.normal(keys[3], (b, s, h * d_k))
+        a_log = jnp.log(jax.random.uniform(keys[5], (h,), minval=1.0, maxval=4.0))
+        bias = 0.5 * jax.random.normal(keys[6], (h * d_k,))
+    else:
+        gate, a_log, bias = jnp.full((b, s, h * d_k), f), jnp.zeros((h,)), jnp.zeros((h * d_k,))
+    beta = 2.0 * jax.random.normal(keys[4], (b, s, h))
+    flat = (b, s, h * d_k)
+    return (q.reshape(flat).astype(dtype), k.reshape(flat).astype(dtype), v.astype(dtype), gate.astype(dtype), beta,
+            a_log, bias)
+
+
 def _weighted(fn, weights):
     return lambda *inputs: jnp.sum(fn(*inputs) * weights)
 
@@ -75,13 +97,15 @@ def test_the_unit_lower_inverse_is_exact_where_a_series_would_cancel():
 
 
 def test_refusals_and_the_plan():
-    q, k, v, a, beta = _inputs(1, s=96)
-    with pytest.raises(ValueError, match="no multiple of the chunk"):
-        kda.kda_chunk(q, k, v, a, beta, chunk=64)
+    with pytest.raises(ValueError, match="kda_chunk: a sequence of 96 is no multiple of the chunk"):
+        kda.kda_chunk(*_inputs(1, s=96), chunk=64)
+    raw = _raw(1, s=96, d_k=16, d_v=8)
+    with pytest.raises(ValueError, match="kda_rule: a sequence of 96 is no multiple of the chunk"):
+        kda.kda_rule(*raw, eps=1e-6, chunk=64)
     with pytest.raises(ValueError, match="unknown kda implementation"):
-        kda.kda_chunk(q, k, v, a, beta, chunk=32, implementation="mosaic")
+        kda.kda_rule(*raw, eps=1e-6, chunk=32, implementation="mosaic")
     with pytest.raises(ValueError, match="the kernels do not tile a chunk of 32, key heads of 16"):
-        kda.kda_chunk(q, k, v, a, beta, chunk=32, implementation="pallas")
+        kda.kda_rule(*raw, eps=1e-6, chunk=32, implementation="pallas")
     assert kda.kda_plan() == {"kda_impl": "xla_chunked", "kda_chunk": 64, "kda_subchunk": 16, "kda_kernels": 0,
-                              "kda_heads_per_step": 0, "kda_state_bytes": 0}
+                              "kda_heads_per_step": 0, "kda_state_bytes": 0, "kda_prologue": "xla"}
     assert kda.kda_plan(24)["kda_subchunk"] == 24

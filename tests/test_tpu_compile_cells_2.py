@@ -195,6 +195,7 @@ def test_ling3flash_cell_step_runs_the_delta_rule_under_its_scopes_and_compiles(
     assert (said["kda_impl"], said["kda_chunk"], said["kda_subchunk"], said["kda_conv_impl"]) == (
         "pallas", 64, 16, "pallas")
     assert (said["kda_kernels"], said["kda_heads_per_step"], said["kda_state_bytes"]) == (2, 8, 8 * 128 * 128 * 4)
+    assert said["kda_prologue"] == "kernel"     # the kernels make the recurrence's arguments of the mixer's (PR 58)
     assert (said["kda_heads"], said["kda_head_dim"], said["kda_gate_lower_bound"]) == (32, 128, -5.0)
     assert (said["attn_latent_q_rank"], said["attn_latent_v_dim"], said["attn_kernel_head_dim"]) == (0, 128, 256)
     assert (said["moe_route_groups"], said["moe_route_groups_kept"], said["moe_experts_held"]) == (8, 4, 8)
@@ -214,8 +215,18 @@ def test_ling3flash_cell_step_runs_the_delta_rule_under_its_scopes_and_compiles(
     assert sorted(found for _, found, _ in table["kda_bwd"]) == ["bwd"] * 6
     for scopes, _, _ in table["kda_fwd"] + table["kda_bwd"]:
         assert {"kda", "kda.chunk"} <= set(scopes), scopes
-    # nor is the in-projection (PR 57: `kda_in_proj` is kept); the small beta and gate projection is
+    # and the scope `kda.chunk` is the kernels and little else (PR 58: they read the convolution's q and k, the
+    # gate's input and beta's logits as the mixer has them): nothing of it runs again, XLA writes no float32
+    # log-decay there (nor any float32 array of that size), and what it still does (the output's view a head
+    # and its cotangent's way back, the two rows a channel, beta's cotangent, the two parameters' gradients
+    # from the kernel's sums) is a fifth of the 395 operations it took around the kernels before
+    under_chunk = [(name, found) for name, instances in table.items() for scopes, found, _ in instances
+                   if "kda.chunk" in scopes]
+    assert not [name for name, found in under_chunk if found == "recompute"], under_chunk
+    assert 12 < len(under_chunk) <= 79, len(under_chunk)
     text = compiled.as_text()
+    assert not re.search(r"= f32\[(?:1,)?4096,(?:32,128|4096)\]\S* [^\n]*/kda\.chunk/", text)
+    # nor is the in-projection (PR 57: `kda_in_proj` is kept); the small beta and gate projection is
     assert "rematted_computation/kda/kda.in_proj/bse,ef->bsf/dot_general" in text
     assert not re.search(
         r"bf16\[(?:1,)?4096,16384\][^\n]*rematted_computation/kda/kda.in_proj/bse,ef->bsf/dot_general", text)
