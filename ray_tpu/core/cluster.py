@@ -76,7 +76,7 @@ from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
 from .exceptions import ActorDiedError
-from .gcs import EVENT_NS, PREEMPT_CHANNEL, REQLOG_NS, STEPLOG_NS
+from .gcs import PREEMPT_CHANNEL
 from .gcs_service import PG_NS, GcsClient
 from .ids import ActorID, NodeID, ObjectID
 from .object_transfer import ObjectTransferServer, fetch_object, push_object
@@ -92,17 +92,6 @@ from .worker_pool import WorkerCrashedError
 
 logger = logging.getLogger(__name__)
 
-
-def _loaded_steplog():
-    """The training-forensics recorder IFF the train package is already
-    loaded in this process. A process that never imported the train
-    stack has no step marks to federate, and importing
-    `ray_tpu.train.steplog` here would execute the train package init
-    (jax/flax/optax) inside a lightweight cluster agent's stats thread
-    — seconds of import stalling the very loop the head heartbeats on."""
-    import sys
-
-    return sys.modules.get("ray_tpu.train.steplog")
 
 PROTO_NS = "_protocol"   # GCS KV: "version" -> wire-protocol generation
 NODE_NS = "_nodes"       # GCS KV: node_id hex -> node info dict
@@ -580,15 +569,9 @@ class ClusterContext:
         # piggyback can republish without a read-modify-write race)
         self._info: Dict[str, Any] = {}  # guarded-by: _lock
         self._last_stats_ts = 0.0
-        # flight-recorder federation cursor: last local event seq shipped
-        # into the GCS _events table (watch-loop thread only)
-        self._events_cursor = 0
-        # request-forensics cursor: last local reqlog mark seq shipped
-        # into the GCS _requests table (watch-loop thread only)
-        self._reqlog_cursor = 0
-        # training-forensics cursor: last local steplog mark seq shipped
-        # into the GCS _steps table (watch-loop thread only)
-        self._steplog_cursor = 0
+        # federation cursors by plane name (util/markring's registry):
+        # the last local mark seq shipped into the plane's GCS table
+        self._cursors: Dict[str, int] = {}
         # head fault tolerance: after the head reconnects (possibly a
         # RESTARTED process whose liveness views start empty), suppress
         # death-by-absence declarations until this monotonic deadline —
@@ -752,119 +735,66 @@ class ClusterContext:
             self._info["federation_lag"] = self._federation_lag()
             info = dict(self._info)
         self.gcs.kv_put(self.node_id.hex(), info, namespace=NODE_NS)
-        self._federate_events()
-        self._federate_requests()
-        self._federate_steps()
+        for plane in self._federated_planes():
+            self._federate(plane)
+
+    @staticmethod
+    def _federated_planes():
+        """The mark planes this process has loaded (util/markring's
+        registry: a plane registers when its module is imported, so an
+        agent that never imported a package ships nothing for it and
+        pays no import here). The flight recorder is every node's."""
+        from ..util import events  # noqa: F401 - registers "events"
+        from ..util.markring import federated_planes
+
+        return federated_planes()
 
     def _federation_lag(self) -> Dict[str, int]:
-        """How many local flight-recorder events / reqlog marks / steplog
-        marks have not yet shipped to the head. Grows for the duration of
-        a head outage (the cursors only advance after a successful put)
-        and drains to ~0 after reconnect — `ray_tpu status` surfaces it
-        per node as the buffered-federation depth."""
-        from ..serve import reqlog
-        from ..util.events import events
+        """How many local marks of each enabled plane (flight-recorder
+        events, request marks, step marks) have not yet shipped to the
+        head. Grows for the duration of a head outage (a cursor only
+        advances after a successful put) and drains to ~0 after
+        reconnect — `ray_tpu status` surfaces it per node as the
+        buffered-federation depth."""
+        return {
+            plane.name: max(0, plane.ring().stats()["seq"]
+                            - self._cursors.get(plane.name, 0))
+            for plane in self._federated_planes() if plane.enabled()
+        }
 
-        lag = {"events": max(0, events().stats()["seq"] - self._events_cursor)}
-        if reqlog.enabled():
-            lag["requests"] = max(
-                0, reqlog.log().stats()["seq"] - self._reqlog_cursor)
-        steplog = _loaded_steplog()
-        if steplog is not None and steplog.enabled():
-            lag["steps"] = max(
-                0, steplog.log().stats()["seq"] - self._steplog_cursor)
-        return lag
+    def _federate(self, plane, *, batch: Optional[int] = None,
+                  cap: Optional[int] = None) -> None:
+        """Ship this node's new marks of one plane into the plane's GCS
+        table (same cadence + failure envelope as the stats piggyback),
+        so the head answers for the whole cluster. Each node owns its
+        key, so the read-modify-write is single-writer; the cursor walks
+        oldest-first and never skips — a burst just drains over several
+        periods of `batch` marks (markring.FEDERATE_BATCH), and the
+        table keeps a node's newest `cap` (markring.TABLE_CAP)."""
+        from ..util.markring import FEDERATE_BATCH, TABLE_CAP
 
-    def _federate_events(self) -> None:
-        """Ship this node's new flight-recorder events into the GCS
-        `_events` table (same cadence + failure envelope as the stats
-        piggyback). Each node owns its key, so the read-modify-write is
-        single-writer; the cursor walks oldest-first and never skips —
-        a burst just drains over several periods."""
-        from ..util.events import events
-        from .config import cfg
-
-        batch = events().since(self._events_cursor,
-                               max_n=cfg.events_federate_batch)
-        if not batch:
+        if not plane.enabled():
+            return
+        cursor = self._cursors.get(plane.name, 0)
+        marks = plane.ring().since(cursor, batch or FEDERATE_BATCH)
+        if not marks:
             return
         my_hex = self.node_id.hex()
-        tail = self.gcs.kv_get(my_hex, namespace=EVENT_NS) or []
+        tail = self.gcs.kv_get(my_hex, namespace=plane.namespace) or []
         # reconnect-flush dedup: the cursor only advances after a
         # successful put, so a put that landed at the head but whose
         # reply was lost to an outage gets re-shipped — drop by seq
-        shipped = {e.get("seq") for e in tail}
-        fresh = [e for e in batch if e["seq"] not in shipped]
-        if fresh:
-            tail.extend(
-                e if e.get("node") else dict(e, node=my_hex) for e in fresh
-            )
-            cap = cfg.events_table_cap
-            if len(tail) > cap:
-                del tail[: len(tail) - cap]
-            self.gcs.kv_put(my_hex, tail, namespace=EVENT_NS)
-        self._events_cursor = batch[-1]["seq"]
-
-    def _federate_requests(self) -> None:
-        """Ship this node's new request-forensics marks into the GCS
-        `_requests` table (same single-writer key + oldest-first cursor
-        walk as the flight recorder), so the head can answer
-        `state.request_timeline(id)` for a request whose router hop and
-        engine hop ran on different nodes."""
-        from ..serve import reqlog
-        from .config import cfg
-
-        if not reqlog.enabled():
-            return
-        batch = reqlog.log().since(self._reqlog_cursor,
-                                   max_n=cfg.reqlog_federate_batch)
-        if not batch:
-            return
-        my_hex = self.node_id.hex()
-        tail = self.gcs.kv_get(my_hex, namespace=REQLOG_NS) or []
-        # same reconnect-flush dedup as _federate_events
         shipped = {m.get("seq") for m in tail}
-        fresh = [m for m in batch if m["seq"] not in shipped]
+        fresh = [m for m in marks if m["seq"] not in shipped]
         if fresh:
             tail.extend(
                 m if m.get("node") else dict(m, node=my_hex) for m in fresh
             )
-            cap = cfg.reqlog_table_cap
+            cap = cap or TABLE_CAP
             if len(tail) > cap:
                 del tail[: len(tail) - cap]
-            self.gcs.kv_put(my_hex, tail, namespace=REQLOG_NS)
-        self._reqlog_cursor = batch[-1]["seq"]
-
-    def _federate_steps(self) -> None:
-        """Ship this node's new training-forensics step marks into the
-        GCS `_steps` table (same single-writer key + oldest-first cursor
-        walk as the flight recorder), so the head can answer
-        `state.step_timeline(run)` across every rank of a multihost gang
-        and the skew matrix can compare hosts that never share a
-        process."""
-        from .config import cfg
-
-        steplog = _loaded_steplog()
-        if steplog is None or not steplog.enabled():
-            return
-        batch = steplog.log().since(self._steplog_cursor,
-                                    max_n=cfg.steplog_federate_batch)
-        if not batch:
-            return
-        my_hex = self.node_id.hex()
-        tail = self.gcs.kv_get(my_hex, namespace=STEPLOG_NS) or []
-        # same reconnect-flush dedup as _federate_events
-        shipped = {m.get("seq") for m in tail}
-        fresh = [m for m in batch if m["seq"] not in shipped]
-        if fresh:
-            tail.extend(
-                m if m.get("node") else dict(m, node=my_hex) for m in fresh
-            )
-            cap = cfg.steplog_table_cap
-            if len(tail) > cap:
-                del tail[: len(tail) - cap]
-            self.gcs.kv_put(my_hex, tail, namespace=STEPLOG_NS)
-        self._steplog_cursor = batch[-1]["seq"]
+            self.gcs.kv_put(my_hex, tail, namespace=plane.namespace)
+        self._cursors[plane.name] = marks[-1]["seq"]
 
     def _watch_loop(self) -> None:
         from .config import cfg

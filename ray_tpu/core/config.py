@@ -267,19 +267,6 @@ define_flag("serve_request_log", True,
             "the ledger behind state.request_timeline / `ray_tpu "
             "request` / dashboard /api/requests (False = recorder off; "
             "request ids still thread through).")
-define_flag("serve_request_log_marks", 4096,
-            "Per-process ring capacity for request phase marks; the "
-            "oldest mark is evicted first.")
-define_flag("serve_request_log_requests", 1024,
-            "Per-process cap on request SUMMARIES the recorder indexes "
-            "(oldest request evicted first).")
-define_flag("reqlog_federate_batch", 256,
-            "Max request marks a node ships into the GCS _requests "
-            "table per stats-piggyback period (cursor walk, never "
-            "skips).")
-define_flag("reqlog_table_cap", 2000,
-            "Per-node cap on request marks retained in the GCS "
-            "_requests table (the cluster-wide queryable tail).")
 
 # training forensics plane (train/steplog.py)
 define_flag("train_step_log", True,
@@ -291,18 +278,6 @@ define_flag("step_log_sample_every", 32,
             "Sample every Nth training step for the step-phase "
             "decomposition; only sampled steps pay a block_until_ready, "
             "every other step stays fully async (0 = never sample).")
-define_flag("train_step_log_marks", 4096,
-            "Per-process ring capacity for step phase marks; the "
-            "oldest mark is evicted first.")
-define_flag("train_step_log_steps", 1024,
-            "Per-process cap on step SUMMARIES the recorder indexes "
-            "(oldest sampled step evicted first).")
-define_flag("steplog_federate_batch", 256,
-            "Max step marks a node ships into the GCS _steps table "
-            "per stats-piggyback period (cursor walk, never skips).")
-define_flag("steplog_table_cap", 2000,
-            "Per-node cap on step marks retained in the GCS _steps "
-            "table (the cluster-wide queryable tail).")
 
 # flight recorder (durable events + federation + goodput accounting)
 define_flag("events_dir", "",
@@ -315,13 +290,6 @@ define_flag("events_segment_bytes", 1 << 20,
 define_flag("events_segments_keep", 8,
             "Rotated event segments retained per node before the oldest "
             "is pruned.")
-define_flag("events_federate_batch", 256,
-            "Max events a node ships into the GCS _events table per "
-            "stats-piggyback period (the cursor never skips; a burst "
-            "just takes more periods to drain).")
-define_flag("events_table_cap", 2000,
-            "Per-node cap on events retained in the GCS _events table "
-            "(the cluster-wide queryable tail).")
 
 # profiling plane (coordinated capture + cost accounting)
 define_flag("profile_default_duration_s", 2.0,

@@ -25,10 +25,17 @@ decomposition can rely on phase names instead of parsing messages.
 from __future__ import annotations
 
 import threading
-import time
 import uuid
-from collections import OrderedDict, deque
+from collections import OrderedDict
 from typing import Any, Dict, List, Optional
+
+from ..core.gcs import REQLOG_NS
+from ..util.markring import (
+    INDEX_ENTRIES,
+    RING_MARKS,
+    MarkRing,
+    register_federated,
+)
 
 # ----------------------------------------------------------- phase registry
 #
@@ -90,23 +97,16 @@ def new_request_id() -> str:
     return "req-" + uuid.uuid4().hex[:16]
 
 
-def _default_node() -> Optional[str]:
-    from ..util import logs
-
-    return logs._node_hex
-
-
 class RequestLog:
     """Per-process request recorder: a bounded mark ring plus a bounded
     per-request summary index (OrderedDict, oldest-evicted-first)."""
 
-    def __init__(self, mark_capacity: int = 4096,
-                 request_capacity: int = 1024):
-        self._marks: "deque[Dict[str, Any]]" = deque(maxlen=mark_capacity)
+    def __init__(self, mark_capacity: int = RING_MARKS,
+                 request_capacity: int = INDEX_ENTRIES):
+        self.ring = MarkRing(mark_capacity, on_append=self._index_locked)
+        self._lock = self.ring.lock  # the index shares it
         self._requests: "OrderedDict[str, Dict[str, Any]]" = OrderedDict()
         self._request_capacity = request_capacity
-        self._lock = threading.Lock()
-        self._seq = 0
 
     def mark(self, request_id: str, phase: str,
              node: Optional[str] = None,
@@ -114,26 +114,21 @@ class RequestLog:
              **attrs: Any) -> Dict[str, Any]:
         """Record one typed phase mark. `phase` is a registered PHASES
         name (the raylint request-phase rule enforces this statically —
-        at runtime unknown phases are still recorded)."""
-        if node is None:
-            node = _default_node()
-        with self._lock:
-            self._seq += 1
-            rec: Dict[str, Any] = {
-                "seq": self._seq,
-                "rid": request_id,
-                "phase": phase,
-                "ts": time.time(),
-                "mono": time.perf_counter(),
-                "node": node,
-            }
-            if tenant is not None:
-                rec["tenant"] = tenant
-            if attrs:
-                rec["attrs"] = attrs
-            self._marks.append(rec)
-            self._index_locked(rec)
-        return rec
+        at runtime unknown phases are still recorded). The ring stamps
+        seq, both clocks and the default node."""
+        rec: Dict[str, Any] = {
+            "seq": 0,
+            "rid": request_id,
+            "phase": phase,
+            "ts": None,
+            "mono": None,
+            "node": node,
+        }
+        if tenant is not None:
+            rec["tenant"] = tenant
+        if attrs:
+            rec["attrs"] = attrs
+        return self.ring.append(rec)
 
     def _index_locked(self, rec: Dict[str, Any]) -> None:
         rid = rec["rid"]
@@ -177,8 +172,7 @@ class RequestLog:
 
     def timeline(self, request_id: str) -> List[Dict[str, Any]]:
         """Every buffered mark of one request, oldest first."""
-        with self._lock:
-            return [m for m in self._marks if m["rid"] == request_id]
+        return [m for m in self.ring.since() if m["rid"] == request_id]
 
     def requests(self, tenant: Optional[str] = None,
                  slow_only: bool = False,
@@ -201,22 +195,22 @@ class RequestLog:
         return out[-limit:]
 
     def since(self, seq: int, max_n: int = 1000) -> List[Dict[str, Any]]:
-        """The OLDEST max_n marks with seq greater than `seq` — the
-        federation cursor walk (same contract as EventLog.since)."""
-        with self._lock:
-            return [m for m in self._marks if m["seq"] > seq][:max_n]
+        """The OLDEST max_n marks with seq greater than `seq`
+        (MarkRing.since: the federation cursor's walk)."""
+        return self.ring.since(seq, max_n)
 
     def stats(self) -> Dict[str, Any]:
         with self._lock:
+            ring = self.ring.stats()
             return {
-                "seq": self._seq,
-                "buffered_marks": len(self._marks),
+                "seq": ring["seq"],
+                "buffered_marks": ring["buffered"],
                 "indexed_requests": len(self._requests),
             }
 
     def clear(self) -> None:
         with self._lock:
-            self._marks.clear()
+            self.ring.clear()
             self._requests.clear()
 
 
@@ -230,12 +224,7 @@ def log() -> RequestLog:
     global _reqlog
     with _reqlog_lock:
         if _reqlog is None:
-            from ..core.config import cfg
-
-            _reqlog = RequestLog(
-                mark_capacity=cfg.serve_request_log_marks,
-                request_capacity=cfg.serve_request_log_requests,
-            )
+            _reqlog = RequestLog()
         return _reqlog
 
 
@@ -252,6 +241,9 @@ def mark(request_id: Optional[str], phase: str,
     if request_id is None or not enabled():
         return
     log().mark(request_id, phase, tenant=tenant, **attrs)
+
+
+register_federated("requests", REQLOG_NS, lambda: log().ring, enabled)
 
 
 # ------------------------------------------------------- derived views

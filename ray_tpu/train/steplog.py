@@ -32,8 +32,17 @@ from __future__ import annotations
 
 import threading
 import time
-from collections import OrderedDict, deque
+from collections import OrderedDict
 from typing import Any, Dict, List, Optional, Tuple
+
+from ..core.gcs import STEPLOG_NS
+from ..util.markring import (
+    INDEX_ENTRIES,
+    RING_MARKS,
+    MarkRing,
+    _default_node,
+    register_federated,
+)
 
 # ----------------------------------------------------------- phase registry
 #
@@ -70,12 +79,6 @@ def step_phases() -> Dict[str, str]:
     return dict(STEP_PHASES)
 
 
-def _default_node() -> Optional[str]:
-    from ..util import logs
-
-    return logs._node_hex
-
-
 def _phase_order(buckets: Dict[str, Any]) -> List[str]:
     """Registered phases first (registration order), then any extras."""
     out = [p for p in STEP_PHASES if p in buckets]
@@ -91,15 +94,14 @@ class StepLog:
     (returns None) — that is what makes controller-side ``ingest`` safe
     when an in-process gang shares this very ring with its trainer."""
 
-    def __init__(self, mark_capacity: int = 4096,
-                 step_capacity: int = 1024):
-        self._marks: "deque[Dict[str, Any]]" = deque(maxlen=mark_capacity)
+    def __init__(self, mark_capacity: int = RING_MARKS,
+                 step_capacity: int = INDEX_ENTRIES):
+        self.ring = MarkRing(mark_capacity, on_append=self._index_locked)
+        self._lock = self.ring.lock  # the index shares it
         self._steps: "OrderedDict[Tuple[str, int, int], Dict[str, Any]]" = (
             OrderedDict()
         )
         self._step_capacity = step_capacity
-        self._lock = threading.Lock()
-        self._seq = 0
 
     def mark(self, phase: str, dur_s: Any, *,
              run: str, rank: int, step: int,
@@ -110,31 +112,27 @@ class StepLog:
         is a registered STEP_PHASES name (the raylint step-phase rule
         enforces this statically — at runtime unknown phases are still
         recorded). Returns None when this (run, rank, step, phase) was
-        already marked."""
-        if node is None:
-            node = _default_node()
-        with self._lock:
-            sid = (str(run), int(rank), int(step))
+        already marked. The ring stamps seq, the clocks the caller gave
+        none for and the default node."""
+        sid = (str(run), int(rank), int(step))
+        rec: Dict[str, Any] = {
+            "seq": 0,
+            "run": sid[0],
+            "rank": sid[1],
+            "step": sid[2],
+            "phase": phase,
+            "dur_s": dur_s,
+            "ts": ts,
+            "mono": None,
+            "node": node,
+        }
+        if attrs:
+            rec["attrs"] = attrs
+        with self._lock:  # the duplicate check and the append are one
             summary = self._steps.get(sid)
             if summary is not None and phase in summary["buckets"]:
                 return None
-            self._seq += 1
-            rec: Dict[str, Any] = {
-                "seq": self._seq,
-                "run": sid[0],
-                "rank": sid[1],
-                "step": sid[2],
-                "phase": phase,
-                "dur_s": dur_s,
-                "ts": time.time() if ts is None else ts,
-                "mono": time.perf_counter(),
-                "node": node,
-            }
-            if attrs:
-                rec["attrs"] = attrs
-            self._marks.append(rec)
-            self._index_locked(rec)
-        return rec
+            return self.ring.append(rec)
 
     def _index_locked(self, rec: Dict[str, Any]) -> None:
         sid = (rec["run"], rec["rank"], rec["step"])
@@ -206,11 +204,10 @@ class StepLog:
                  ) -> List[Dict[str, Any]]:
         """Every buffered mark of one run (optionally one rank),
         oldest first."""
-        with self._lock:
-            return [
-                m for m in self._marks
-                if m["run"] == run and (rank is None or m["rank"] == rank)
-            ]
+        return [
+            m for m in self.ring.since()
+            if m["run"] == run and (rank is None or m["rank"] == rank)
+        ]
 
     def steps(self, run: Optional[str] = None,
               limit: int = 200) -> List[Dict[str, Any]]:
@@ -224,22 +221,22 @@ class StepLog:
         return out[-limit:]
 
     def since(self, seq: int, max_n: int = 1000) -> List[Dict[str, Any]]:
-        """The OLDEST max_n marks with seq greater than `seq` — the
-        federation cursor walk (same contract as EventLog.since)."""
-        with self._lock:
-            return [m for m in self._marks if m["seq"] > seq][:max_n]
+        """The OLDEST max_n marks with seq greater than `seq`
+        (MarkRing.since: the federation cursor's walk)."""
+        return self.ring.since(seq, max_n)
 
     def stats(self) -> Dict[str, Any]:
         with self._lock:
+            ring = self.ring.stats()
             return {
-                "seq": self._seq,
-                "buffered_marks": len(self._marks),
+                "seq": ring["seq"],
+                "buffered_marks": ring["buffered"],
                 "indexed_steps": len(self._steps),
             }
 
     def clear(self) -> None:
         with self._lock:
-            self._marks.clear()
+            self.ring.clear()
             self._steps.clear()
 
 
@@ -253,12 +250,7 @@ def log() -> StepLog:
     global _steplog
     with _steplog_lock:
         if _steplog is None:
-            from ..core.config import cfg
-
-            _steplog = StepLog(
-                mark_capacity=cfg.train_step_log_marks,
-                step_capacity=cfg.train_step_log_steps,
-            )
+            _steplog = StepLog()
         return _steplog
 
 
@@ -283,6 +275,32 @@ def mark(phase: str, dur_s: Any, *,
         return
     slog = log()
     slog.mark(phase, dur_s, run=run, rank=rank, step=step, **attrs)
+
+
+def record_step(run: str, rank: int, step: int,
+                buckets: Dict[str, float], wall_s: float) -> Dict[str, Any]:
+    """Hand one SAMPLED step over whole: `buckets` are the measured
+    phases' durations in mark order and `wall_s` the step's; the seal is
+    what they leave of it, so the recorded buckets sum EXACTLY to the
+    recorded wall_s. One mark a bucket, written together (nothing when
+    the recorder is off). Returns the step's record as the gang report
+    carries it under `_steplog` and `StepLog.ingest` reads it."""
+    buckets = dict(buckets)
+    buckets[SEAL_PHASE] = wall_s - sum(buckets.values())
+    if buckets[SEAL_PHASE] < 0.0:  # float rounding: wall is then the sum
+        buckets[SEAL_PHASE] = 0.0
+        wall_s = sum(buckets.values())
+    ids = {"run": run, "rank": rank, "step": step, "node": _default_node()}
+    if enabled():
+        slog = log()
+        with slog.ring.lock:
+            for phase, dur_s in buckets.items():
+                seal = {"wall_s": wall_s} if phase == SEAL_PHASE else {}
+                slog.mark(phase, dur_s, **ids, **seal)
+    return dict(ids, ts=time.time(), wall_s=wall_s, buckets=buckets)
+
+
+register_federated("steps", STEPLOG_NS, lambda: log().ring, enabled)
 
 
 # ------------------------------------------------------- derived views

@@ -324,11 +324,15 @@ class LMTrainer:
                     window_ckpt_save += ckpt_s
                 step.end()
                 if sampled:
-                    pending_steps.append(self._mark_sampled_step(
+                    # the step's span durations as the typed steplog buckets:
+                    # the fused XLA program is one opaque interval, `device`
+                    # (dispatch and the wait for the state)
+                    pending_steps.append(steplog.record_step(
                         run_name, rank, int(self.state.step),
-                        data_wait=wait.duration_s, h2d=h2d.duration_s,
-                        device=device_s, report=report_s, ckpt=ckpt_s,
-                        wall=step.duration_s,
+                        {"data_wait": wait.duration_s, "h2d": h2d.duration_s,
+                         "device": device_s, "ckpt_save": ckpt_s,
+                         "report": report_s},
+                        step.duration_s,
                     ))
                     del pending_steps[:-64]  # bounded if reports never drain
             if pending_steps and session is not None:
@@ -346,35 +350,6 @@ class LMTrainer:
             raise
         loop.end(steps=steps)
         return last_metrics
-
-    def _mark_sampled_step(self, run: str, rank: int, step: int, *,
-                           data_wait: float, h2d: float, device: float,
-                           report: float, ckpt: float,
-                           wall: float) -> Dict[str, Any]:
-        """Write one SAMPLED step's span durations as the typed steplog
-        buckets. The fused XLA program is one opaque interval, `device`
-        (dispatch and the wait for the state). `other` is the step
-        span's duration less every bucket, so the recorded buckets sum
-        EXACTLY to wall_s — the invariant the tests enforce."""
-        from . import steplog
-
-        buckets = {
-            "data_wait": data_wait, "h2d": h2d, "device": device,
-            "ckpt_save": ckpt, "report": report,
-        }
-        buckets["other"] = wall - sum(buckets.values())
-        if buckets["other"] < 0.0:  # float rounding: wall is then the sum
-            buckets["other"] = 0.0
-            wall = sum(buckets.values())
-        ids = {"run": run, "rank": rank, "step": step}
-        steplog.mark("data_wait", data_wait, **ids)
-        steplog.mark("h2d", h2d, **ids)
-        steplog.mark("device", device, **ids)
-        steplog.mark("ckpt_save", ckpt, **ids)
-        steplog.mark("report", report, **ids)
-        steplog.mark("other", buckets["other"], wall_s=wall, **ids)
-        return dict(ids, node=steplog._default_node(), ts=time.time(),
-                    wall_s=wall, buckets=buckets)
 
     def step_cost(self, batch: Dict[str, Any]):
         """cost_analysis() of the compiled train step at this batch's

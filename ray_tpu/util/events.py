@@ -28,9 +28,10 @@ from __future__ import annotations
 import json
 import os
 import threading
-import time
-from collections import deque
 from typing import Any, Dict, List, Optional
+
+from ..core.gcs import EVENT_NS
+from .markring import MarkRing, register_federated
 
 SEVERITIES = ("DEBUG", "INFO", "WARNING", "ERROR")
 
@@ -138,25 +139,16 @@ def event_kinds() -> Dict[str, str]:
     return dict(EVENT_KINDS)
 
 
-def _default_node() -> Optional[str]:
-    """Attribute events to this process's node (util/logs sets it at
-    runtime init) unless the emitter names a more specific one."""
-    from . import logs
-
-    return logs._node_hex
-
-
 class EventLog:
-    """Per-process event recorder: ring buffer + optional JSONL sink +
+    """Per-process event recorder: a mark ring + optional JSONL sink +
     optional bounded durable segment directory."""
 
     def __init__(self, capacity: int = 10_000,
                  sink_path: Optional[str] = None):
-        self._buf: "deque[Dict[str, Any]]" = deque(maxlen=capacity)
-        self._lock = threading.Lock()
+        self.ring = MarkRing(capacity, on_append=self._write_locked)
+        self._lock = self.ring.lock  # the sink and segments share it
         self._sink_path = sink_path
         self._sink_file = None  # cached handle: no per-event open()
-        self._seq = 0
         # durable bounded segments (flight-recorder disk arm)
         self._seg_dir: Optional[str] = None
         self._seg_file = None
@@ -181,45 +173,42 @@ class EventLog:
         EVENT_KINDS name (the raylint event-kinds rule enforces this
         statically — at runtime unknown kinds are still recorded);
         `node` attributes the event to a node id hex (defaults to this
-        process's node)."""
-        severity = normalize_severity(severity)
-        if node is None:
-            node = _default_node()
-        with self._lock:
-            self._seq += 1
-            event = {
-                "seq": self._seq,
-                "ts": time.time(),
-                "mono": time.monotonic(),
-                "severity": severity,
-                "kind": kind or "",
-                "source": source,
-                "node": node,
-                "message": message,
-                **({"extra": extra} if extra else {}),
-            }
-            self._buf.append(event)
-            # write under the lock: concurrent emitters on one handle
-            # would otherwise interleave partial JSONL lines
-            line = None
-            try:
-                f = self._sink_handle()
-                if f is not None:
-                    line = json.dumps(event, default=str)
-                    f.write(line + "\n")
-                    f.flush()
-            except (OSError, ValueError, TypeError):
-                # a full disk must not take the runtime down; drop the
-                # handle so a later emit can retry a fresh open
-                self._close_sink_locked()
-            try:
-                self._segment_write_locked(
-                    line if line is not None
-                    else json.dumps(event, default=str)
-                )
-            except (OSError, ValueError, TypeError):
-                self._close_segment_locked()
-        return event
+        process's node). The ring stamps seq, both clocks and that
+        default."""
+        return self.ring.append({
+            "seq": 0,
+            "ts": None,
+            "mono": None,
+            "severity": normalize_severity(severity),
+            "kind": kind or "",
+            "source": source,
+            "node": node,
+            "message": message,
+            **({"extra": extra} if extra else {}),
+        })
+
+    def _write_locked(self, event: Dict[str, Any]) -> None:
+        """The ring's on_append. Written under the ring's lock:
+        concurrent emitters on one handle would otherwise interleave
+        partial JSONL lines."""
+        line = None
+        try:
+            f = self._sink_handle()
+            if f is not None:
+                line = json.dumps(event, default=str)
+                f.write(line + "\n")
+                f.flush()
+        except (OSError, ValueError, TypeError):
+            # a full disk must not take the runtime down; drop the
+            # handle so a later emit can retry a fresh open
+            self._close_sink_locked()
+        try:
+            self._segment_write_locked(
+                line if line is not None
+                else json.dumps(event, default=str)
+            )
+        except (OSError, ValueError, TypeError):
+            self._close_segment_locked()
 
     def _close_sink_locked(self) -> None:
         if self._sink_file is not None:
@@ -309,35 +298,27 @@ class EventLog:
         """Filtered event tail (oldest first). `severity` matching is
         case-insensitive; `node` matches on hex prefix."""
         sev = normalize_severity(severity) if severity is not None else None
-        with self._lock:
-            out = [
-                e for e in self._buf
-                if e["seq"] > since_seq
-                and e["ts"] >= since_ts
-                and (sev is None or e["severity"] == sev)
-                and (source is None or e["source"] == source)
-                and (kind is None or e.get("kind") == kind)
-                and (node is None or str(e.get("node") or "").startswith(node))
-            ]
+        out = [
+            e for e in self.ring.since(since_seq)
+            if e["ts"] >= since_ts
+            and (sev is None or e["severity"] == sev)
+            and (source is None or e["source"] == source)
+            and (kind is None or e.get("kind") == kind)
+            and (node is None or str(e.get("node") or "").startswith(node))
+        ]
         return out[-limit:]
 
     def since(self, seq: int, max_n: int = 1000) -> List[Dict[str, Any]]:
-        """The OLDEST max_n events with seq greater than `seq` — the
-        federation cursor walk (never skips events the way a tail-limit
-        would; a slow shipper just takes more periods to catch up)."""
-        with self._lock:
-            return [e for e in self._buf if e["seq"] > seq][:max_n]
+        """The OLDEST max_n events with seq greater than `seq`
+        (MarkRing.since: the federation cursor's walk)."""
+        return self.ring.since(seq, max_n)
 
     def stats(self) -> Dict[str, Any]:
         """Flight-recorder health for the node stats snapshot
         (core/stats.py): total events emitted, ring occupancy, and
         whether the durable segment arm is on."""
         with self._lock:
-            return {
-                "seq": self._seq,
-                "buffered": len(self._buf),
-                "segments_dir": self._seg_dir,
-            }
+            return {**self.ring.stats(), "segments_dir": self._seg_dir}
 
     def set_sink(self, path: Optional[str]) -> None:
         with self._lock:
@@ -350,8 +331,7 @@ class EventLog:
                     self._sink_file = None  # emit retries lazily
 
     def clear(self) -> None:
-        with self._lock:
-            self._buf.clear()
+        self.ring.clear()
 
 
 def _segment_index(name: str) -> Optional[int]:
@@ -406,3 +386,6 @@ def emit(severity: str, source: str, message: str, kind: str = "",
          node: Optional[str] = None, **extra: Any) -> None:
     """Module-level convenience used by runtime components."""
     events().emit(severity, source, message, kind=kind, node=node, **extra)
+
+
+register_federated("events", EVENT_NS, lambda: events().ring)

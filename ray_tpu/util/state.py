@@ -12,6 +12,7 @@ import warnings
 from typing import Any, Dict, List, Optional
 
 from ..core import runtime as _rt
+from ..core.gcs import EVENT_NS, REQLOG_NS, STEPLOG_NS
 
 
 def _runtime():
@@ -138,7 +139,10 @@ def head_summary() -> Optional[Dict[str, Any]]:
     if ctx is not None:
         lag = {}
         for info in ctx.nodes():
-            depth = info.get("federation_lag")
+            # a plane with nothing buffered reads as a plane the node
+            # never loaded (core/cluster._federation_lag reports neither)
+            depth = {k: v for k, v in
+                     (info.get("federation_lag") or {}).items() if v}
             if depth:
                 lag[info["node_id"]] = depth
         if lag:
@@ -563,6 +567,46 @@ def list_events(limit: int = 500, severity: Optional[str] = None,
     return _events().list(limit=limit, severity=severity, source=source)
 
 
+def _federated_tail(namespace: str) -> List[Dict[str, Any]]:
+    """Every mark in one federated GCS table (core/cluster.py ships each
+    node's tail of a registered plane under the node's key): through
+    the cluster's GCS client, or the in-process store where there is no
+    cluster. Empty before init and while the head does not answer."""
+    if not _rt.is_initialized():
+        return []
+    runtime = _rt.get_runtime()
+    ctx = getattr(runtime, "cluster", None)
+    out: List[Dict[str, Any]] = []
+    try:
+        if ctx is not None:
+            for key in ctx.gcs.kv_keys(namespace=namespace):
+                out.extend(ctx.gcs.kv_get(key, namespace=namespace) or [])
+        else:
+            kv = runtime.gcs.kv
+            for key in kv.keys(namespace=namespace):
+                out.extend(kv.get(key, namespace=namespace) or [])
+    except Exception:  # noqa: BLE001 - the local ring still answers
+        pass
+    return out
+
+
+def _node_seq(m: Dict[str, Any]) -> Any:
+    return (m.get("node"), m.get("seq"))
+
+
+def _cluster_marks(local: List[Dict[str, Any]], namespace: str,
+                   key=_node_seq) -> List[Dict[str, Any]]:
+    """One plane's marks as this process sees the cluster: its own ring
+    (`local`) merged with the plane's federated table, a local mark
+    winning over the table's copy of the same `key`, sorted by wall
+    time."""
+    merged = {key(m): m for m in local}
+    for m in _federated_tail(namespace):
+        merged.setdefault(key(m), m)
+    return sorted(merged.values(),
+                  key=lambda m: (m.get("ts", 0.0), m.get("seq", 0)))
+
+
 def events(limit: int = 1000, *, kind: Optional[str] = None,
            node: Optional[str] = None, since: float = 0.0,
            severity: Optional[str] = None,
@@ -577,36 +621,15 @@ def events(limit: int = 1000, *, kind: Optional[str] = None,
     from .events import events as _events
     from .events import normalize_severity
 
-    merged: Dict[Any, Dict[str, Any]] = {}
-    for e in _events().list(limit=10_000):
-        merged[(e.get("node"), e["seq"])] = e
-    if _rt.is_initialized():
-        from ..core.gcs import EVENT_NS
-
-        runtime = _rt.get_runtime()
-        ctx = getattr(runtime, "cluster", None)
-        try:
-            if ctx is not None:
-                for key in ctx.gcs.kv_keys(namespace=EVENT_NS):
-                    for e in ctx.gcs.kv_get(key, namespace=EVENT_NS) or []:
-                        merged.setdefault((e.get("node"), e.get("seq")), e)
-            else:
-                kv = runtime.gcs.kv
-                for key in kv.keys(namespace=EVENT_NS):
-                    for e in kv.get(key, namespace=EVENT_NS) or []:
-                        merged.setdefault((e.get("node"), e.get("seq")), e)
-        except Exception:  # noqa: BLE001 - the local ring still answers
-            pass
     sev = normalize_severity(severity) if severity is not None else None
     out = [
-        e for e in merged.values()
+        e for e in _cluster_marks(_events().list(limit=10_000), EVENT_NS)
         if e.get("ts", 0.0) >= since
         and (kind is None or e.get("kind") == kind)
         and (node is None or str(e.get("node") or "").startswith(node))
         and (sev is None or e.get("severity") == sev)
         and (source is None or e.get("source") == source)
     ]
-    out.sort(key=lambda e: (e.get("ts", 0.0), e.get("seq", 0)))
     return out[-limit:] if limit else out
 
 
@@ -618,29 +641,7 @@ def _federated_request_marks() -> List[Dict[str, Any]]:
     wall time."""
     from ..serve import reqlog
 
-    merged: Dict[Any, Dict[str, Any]] = {}
-    for m in reqlog.log().since(0, max_n=1_000_000):
-        merged[(m.get("node"), m.get("seq"))] = m
-    if _rt.is_initialized():
-        from ..core.gcs import REQLOG_NS
-
-        runtime = _rt.get_runtime()
-        ctx = getattr(runtime, "cluster", None)
-        try:
-            if ctx is not None:
-                for key in ctx.gcs.kv_keys(namespace=REQLOG_NS):
-                    for m in ctx.gcs.kv_get(key, namespace=REQLOG_NS) or []:
-                        merged.setdefault((m.get("node"), m.get("seq")), m)
-            else:
-                kv = runtime.gcs.kv
-                for key in kv.keys(namespace=REQLOG_NS):
-                    for m in kv.get(key, namespace=REQLOG_NS) or []:
-                        merged.setdefault((m.get("node"), m.get("seq")), m)
-        except Exception:  # noqa: BLE001 - the local ring still answers
-            pass
-    out = list(merged.values())
-    out.sort(key=lambda m: (m.get("ts", 0.0), m.get("seq", 0)))
-    return out
+    return _cluster_marks(reqlog.log().since(0, max_n=1_000_000), REQLOG_NS)
 
 
 def request_timeline(request_id: str) -> List[Dict[str, Any]]:
@@ -699,29 +700,8 @@ def _federated_step_marks() -> List[Dict[str, Any]]:
     def _key(m: Dict[str, Any]) -> Any:
         return (m.get("run"), m.get("rank"), m.get("step"), m.get("phase"))
 
-    merged: Dict[Any, Dict[str, Any]] = {}
-    for m in steplog.log().since(0, max_n=1_000_000):
-        merged[_key(m)] = m
-    if _rt.is_initialized():
-        from ..core.gcs import STEPLOG_NS
-
-        runtime = _rt.get_runtime()
-        ctx = getattr(runtime, "cluster", None)
-        try:
-            if ctx is not None:
-                for key in ctx.gcs.kv_keys(namespace=STEPLOG_NS):
-                    for m in ctx.gcs.kv_get(key, namespace=STEPLOG_NS) or []:
-                        merged.setdefault(_key(m), m)
-            else:
-                kv = runtime.gcs.kv
-                for key in kv.keys(namespace=STEPLOG_NS):
-                    for m in kv.get(key, namespace=STEPLOG_NS) or []:
-                        merged.setdefault(_key(m), m)
-        except Exception:  # noqa: BLE001 - the local ring still answers
-            pass
-    out = list(merged.values())
-    out.sort(key=lambda m: (m.get("ts", 0.0), m.get("seq", 0)))
-    return out
+    return _cluster_marks(steplog.log().since(0, max_n=1_000_000),
+                          STEPLOG_NS, key=_key)
 
 
 def step_timeline(run: str, rank: Optional[int] = None) -> List[Dict[str, Any]]:

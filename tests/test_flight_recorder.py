@@ -185,23 +185,81 @@ def test_events_federate_into_gcs_table_and_state_query():
         ray_tpu.shutdown()
 
 
-def test_events_table_is_bounded():
-    from ray_tpu.core.config import cfg
-    from ray_tpu.core.gcs import EVENT_NS
+def _burst(name, i):
+    from ray_tpu.serve import reqlog
+    from ray_tpu.train import steplog
 
+    if name == "events":
+        events().emit("INFO", "test", f"burst {i}", kind="node.discovered")
+    elif name == "requests":
+        reqlog.mark(f"req-burst-{i}", "engine.submitted")
+    else:
+        steplog.mark("data_wait", 0.01, run="burst", rank=0, step=i)
+
+
+@pytest.mark.parametrize("name, newest", [
+    ("events", ("message", "burst 79")),
+    ("requests", ("rid", "req-burst-79")),
+    ("steps", ("step", 79)),
+])
+def test_federated_table_is_bounded(name, newest):
+    """One shipper for every registered plane (core/cluster._federate):
+    a node's table keeps its newest `cap` marks whatever the burst."""
+    from ray_tpu.serve import reqlog
+    from ray_tpu.train import steplog
+    from ray_tpu.util.markring import federated_planes
+
+    reqlog.log().clear()
+    steplog.log().clear()
+    plane = {p.name: p for p in federated_planes()}[name]
     rt = ray_tpu.init(num_cpus=1, head=True, detect_accelerators=False)
-    cfg.set(events_table_cap=20, events_federate_batch=500)
     try:
         ctx = rt.cluster
         for i in range(80):
-            events().emit("INFO", "test", f"burst {i}", kind="node.discovered")
-        ctx._last_stats_ts = 0.0
-        ctx._report_stats()
-        tail = ctx.gcs.kv_get(ctx.node_id.hex(), namespace=EVENT_NS)
+            _burst(name, i)
+        ctx._federate(plane, cap=20, batch=500)
+        tail = ctx.gcs.kv_get(ctx.node_id.hex(), namespace=plane.namespace)
         assert len(tail) <= 20
-        assert tail[-1]["message"] == "burst 79"  # newest survive
+        key, value = newest
+        assert tail[-1][key] == value  # newest survive
+        assert ctx._federation_lag()[name] == 0
     finally:
-        cfg.reset()
+        ray_tpu.shutdown()
+        reqlog.log().clear()
+        steplog.log().clear()
+
+
+def test_a_plane_a_node_never_loaded_reads_as_one_with_nothing_buffered():
+    """`_federation_lag` has a key for each plane the node's process
+    registered (an agent that never imported ray_tpu.serve reports no
+    `requests`, one that never imported ray_tpu.train no `steps`); the
+    head's summary and `ray_tpu status` say the same for a missing key
+    as for a 0."""
+    from ray_tpu.util import state
+
+    rt = ray_tpu.init(num_cpus=1, head=True, detect_accelerators=False)
+    try:
+        ctx = rt.cluster
+        assert "events" in ctx._federation_lag()
+
+        def status_with(lag):
+            with ctx._lock:
+                ctx._info["federation_lag"] = lag
+                info = dict(ctx._info)
+            from ray_tpu.core.cluster import NODE_NS
+
+            ctx.gcs.kv_put(ctx.node_id.hex(), info, namespace=NODE_NS)
+            return (state.head_summary().get("federation_lag"),
+                    [line for line in state.status_report().splitlines()
+                     if "buffered federation" in line])
+
+        assert status_with({"events": 0}) \
+            == status_with({"events": 0, "requests": 0, "steps": 0}) \
+            == (None, [])
+        lag, lines = status_with({"events": 3, "requests": 0})
+        assert lag == {ctx.node_id.hex(): {"events": 3}}
+        assert len(lines) == 1 and lines[0].endswith("events=3")
+    finally:
         ray_tpu.shutdown()
 
 

@@ -221,6 +221,63 @@ def test_event_sink_cached_handle(tmp_path):
     assert "five" not in open(other).read()
 
 
+# ----------------------------------------------------------------- mark ring
+#
+# What the three planes (util/events, serve/reqlog, train/steplog) and
+# the federation cursor of core/cluster.py rely on.
+
+
+def test_mark_ring_since_is_oldest_first_and_never_skips():
+    from ray_tpu.util.markring import MarkRing
+
+    ring = MarkRing(capacity=64)
+    for i in range(10):
+        ring.append({"i": i})
+    assert [m["seq"] for m in ring.since()] == list(range(1, 11))
+    # a cursor walked in batches of three sees every mark exactly once
+    cursor, seen = 0, []
+    while batch := ring.since(cursor, 3):
+        assert len(batch) <= 3
+        seen.extend(m["i"] for m in batch)
+        cursor = batch[-1]["seq"]
+    assert seen == list(range(10))
+    assert ring.since(cursor) == []
+
+
+def test_mark_ring_stamps_what_a_record_lacks_and_tells_its_plane():
+    import time
+
+    from ray_tpu.util.markring import MarkRing
+
+    indexed = []
+    ring = MarkRing(on_append=indexed.append)
+    before = time.time()
+    rec = ring.append({"seq": 0, "ts": None, "mono": None, "node": None})
+    assert rec["seq"] == 1 and before <= rec["ts"] <= time.time()
+    assert 0 < rec["mono"] <= time.monotonic()
+    assert list(rec) == ["seq", "ts", "mono", "node"]  # the plane's order
+    given = ring.append({"ts": 12.5, "mono": 0.5, "node": "abcd"})
+    assert (given["ts"], given["mono"], given["node"]) == (12.5, 0.5, "abcd")
+    assert given["seq"] == 2
+    assert indexed == [rec, given]  # on_append saw each, after its stamps
+
+
+def test_mark_ring_seq_survives_clear_and_a_full_ring_evicts_the_oldest():
+    from ray_tpu.util.markring import MarkRing
+
+    ring = MarkRing(capacity=4)
+    for i in range(6):
+        ring.append({"i": i})
+    assert ring.stats() == {"seq": 6, "buffered": 4}
+    assert [m["i"] for m in ring.since()] == [2, 3, 4, 5]
+    # a cursor behind the eviction resumes at the oldest mark still held
+    assert ring.since(1, 2)[0]["seq"] == 3
+    ring.clear()
+    assert ring.stats() == {"seq": 6, "buffered": 0}
+    assert ring.append({"i": 6})["seq"] == 7  # a cursor at 6 stays valid
+    assert [m["i"] for m in ring.since(6)] == [6]
+
+
 # ------------------------------------------------------------------- tracing
 
 

@@ -635,15 +635,83 @@ def test_step_phase_exempts_steplog_module_and_other_marks(tmp_path):
 
 def test_step_phase_production_call_sites_are_typed():
     """Production evidence: the REAL tree passes the rule, the trainer's
-    decomposition marks every registered phase, and the schema the rule
-    keys on exists."""
-    from ray_tpu.train.steplog import STEP_PHASES
+    decomposition names every registered phase (the measured ones as the
+    literal keys it hands `steplog.record_step`, which adds the seal),
+    and the schema the rule keys on exists."""
+    from ray_tpu.train.steplog import SEAL_PHASE, STEP_PHASES
 
     trainer_src = (REPO / "ray_tpu" / "train" / "trainer.py").read_text()
+    call = trainer_src[trainer_src.index("steplog.record_step("):]
+    call = call[:call.index("step.duration_s")]
     for phase in STEP_PHASES:
-        assert f'steplog.mark("{phase}"' in trainer_src, phase
+        assert (phase == SEAL_PHASE) != (f'"{phase}":' in call), phase
     result = run(Project(REPO), rules=["step-phase"])
     assert result.findings == [], [f.location for f in result.findings]
+
+
+# ------------------------------------------------------------------- layering
+
+
+def _names_of_upper_packages(tree) -> list:
+    """(line, name) for each mention of the train or the serve package
+    in an import statement of a module under ray_tpu/core/, or as a
+    string handed to a call or a subscript (`sys.modules.get(...)`,
+    `sys.modules[...]`, `importlib.import_module(...)`)."""
+    import ast
+    import re
+
+    upper = re.compile(r"^(ray_tpu\.)?(train|serve)(\.|$)")
+    found = []
+    for node in ast.walk(tree):
+        names = []
+        if isinstance(node, ast.Import):
+            names = [alias.name for alias in node.names]
+        elif isinstance(node, ast.ImportFrom):
+            if node.level == 0:
+                names = [node.module]
+            elif node.level == 2:  # `..` of ray_tpu/core/x.py is ray_tpu
+                names = ([node.module] if node.module
+                         else [alias.name for alias in node.names])
+        elif isinstance(node, ast.Call):
+            names = [arg.value for arg in node.args
+                     if isinstance(arg, ast.Constant)
+                     and isinstance(arg.value, str)
+                     and arg.value.startswith("ray_tpu.")]
+        elif (isinstance(node, ast.Subscript)
+              and isinstance(node.slice, ast.Constant)
+              and isinstance(node.slice.value, str)
+              and node.slice.value.startswith("ray_tpu.")):
+            names = [node.slice.value]
+        found.extend((node.lineno, n) for n in names if upper.match(n))
+    return found
+
+
+def test_core_names_neither_the_train_nor_the_serve_package():
+    """Imports point down. A recorder of an upper package that core has
+    to ship registers itself with util/markring; core/cluster.py once
+    reached `train.steplog` through `sys.modules` and imported
+    `serve.reqlog` to do it."""
+    import ast
+
+    caught = _names_of_upper_packages(ast.parse(textwrap.dedent("""
+        import sys
+        import ray_tpu.train.steplog
+        from ray_tpu.serve import reqlog
+        from ..serve import reqlog
+        from .. import train
+        from . import gcs
+        from ..util import events
+        steplog = sys.modules.get("ray_tpu.train.steplog")
+        reqlog = sys.modules["ray_tpu.serve.reqlog"]
+        node = sys.modules.get("ray_tpu.util.logs")
+    """)))
+    assert [line for line, _ in caught] == [3, 4, 5, 6, 9, 10], caught
+    offenders = {}
+    for path in sorted((REPO / "ray_tpu" / "core").rglob("*.py")):
+        found = _names_of_upper_packages(ast.parse(path.read_text()))
+        if found:
+            offenders[str(path.relative_to(REPO))] = found
+    assert offenders == {}
 
 
 # ---------------------------------------------------------- gcs-durable-mutations

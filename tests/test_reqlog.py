@@ -497,22 +497,3 @@ def test_request_marks_federate_and_state_queries():
     finally:
         cfg.reset()
         ray_tpu.shutdown()
-
-
-def test_reqlog_table_is_bounded():
-    from ray_tpu.core.gcs import REQLOG_NS
-
-    rt = ray_tpu.init(num_cpus=1, head=True, detect_accelerators=False)
-    cfg.set(reqlog_table_cap=20, reqlog_federate_batch=500)
-    try:
-        ctx = rt.cluster
-        for i in range(80):
-            reqlog.mark(f"req-burst-{i}", "engine.submitted")
-        ctx._last_stats_ts = 0.0
-        ctx._report_stats()
-        tail = ctx.gcs.kv_get(ctx.node_id.hex(), namespace=REQLOG_NS)
-        assert len(tail) <= 20
-        assert tail[-1]["rid"] == "req-burst-79"  # newest survive
-    finally:
-        cfg.reset()
-        ray_tpu.shutdown()

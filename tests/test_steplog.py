@@ -144,6 +144,63 @@ def test_module_mark_is_noop_when_disabled_and_registry_idempotent():
     assert steplog.SEAL_PHASE in steplog.STEP_PHASES
 
 
+@pytest.mark.parametrize("wall", [0.5, 0.25], ids=["remainder", "rounded"])
+def test_record_step_is_the_six_marks_and_the_reports_payload(wall):
+    """The trainer hands a sampled step over in one call: the ring holds
+    what six `mark` calls leave (the measured buckets in order, then the
+    seal with the wall), the buckets sum to wall_s EXACTLY, and what
+    comes back is the record a report carries under `_steplog`. A wall
+    under the buckets' sum (float rounding) becomes the sum."""
+    measured = {"data_wait": 0.125, "h2d": 0.0625, "device": 0.25,
+                "ckpt_save": 0.0, "report": 0.03125}
+    before = time.time()
+    payload = steplog.record_step("rs-run", 3, 7, measured, wall)
+
+    other = max(wall - sum(measured.values()), 0.0)
+    want_wall = sum(measured.values()) + other
+    reference = steplog.StepLog()
+    ids = {"run": "rs-run", "rank": 3, "step": 7}
+    for phase, dur in measured.items():
+        reference.mark(phase, dur, **ids)
+    reference.mark("other", other, wall_s=want_wall, **ids)
+
+    def shape(marks):
+        # seq counts on through the fixture's clear(): relative
+        return [(m["seq"] - marks[0]["seq"], m["phase"], m["dur_s"],
+                 m.get("attrs")) for m in marks]
+
+    marks = steplog.log().timeline("rs-run")
+    assert shape(marks) == shape(reference.timeline("rs-run"))
+    assert [m["phase"] for m in marks] == list(steplog.STEP_PHASES)
+    assert [set(m) for m in marks] == [set(m) for m in
+                                       reference.timeline("rs-run")]
+    (summary,), (ref_summary,) = steplog.log().steps(), reference.steps()
+    assert {k: v for k, v in summary.items() if k != "ts"} \
+        == {k: v for k, v in ref_summary.items() if k != "ts"}
+    assert summary["sealed"] and summary["wall_s"] == want_wall
+    assert sum(summary["buckets"].values()) == summary["wall_s"]  # exact
+
+    assert list(payload) == ["run", "rank", "step", "node", "ts",
+                             "wall_s", "buckets"]
+    assert (payload["run"], payload["rank"], payload["step"]) \
+        == ("rs-run", 3, 7)
+    assert payload["node"] == summary["node"]
+    assert before <= payload["ts"] <= time.time()
+    assert payload["wall_s"] == want_wall
+    assert payload["buckets"] == summary["buckets"]
+    assert list(payload["buckets"]) == list(steplog.STEP_PHASES)
+    # a controller that shares the ring ingests nothing twice; another
+    # one rebuilds the same step from the payload alone
+    assert steplog.log().ingest([payload]) == []
+    assert steplog.StepLog().ingest([payload]) == [payload]
+    # the recorder off: the payload still rides the report, no mark lands
+    cfg.set(train_step_log=False)
+    seq = steplog.log().stats()["seq"]
+    dark = steplog.record_step("rs-dark", 0, 1, measured, wall)
+    assert steplog.log().stats()["seq"] == seq
+    assert dark["buckets"] == payload["buckets"]
+
+
 # ------------------------------------------------- trainer instrumentation
 
 
@@ -379,25 +436,6 @@ def test_step_marks_federate_and_state_queries():
         ctx._report_stats()
         assert not any(m["run"] == "dark-fed" for m in
                        ctx.gcs.kv_get(my_hex, namespace=STEPLOG_NS))
-    finally:
-        cfg.reset()
-        ray_tpu.shutdown()
-
-
-def test_steplog_table_is_bounded():
-    from ray_tpu.core.gcs import STEPLOG_NS
-
-    rt = ray_tpu.init(num_cpus=1, head=True, detect_accelerators=False)
-    cfg.set(steplog_table_cap=20, steplog_federate_batch=500)
-    try:
-        ctx = rt.cluster
-        for i in range(80):
-            steplog.mark("data_wait", 0.01, run="burst", rank=0, step=i)
-        ctx._last_stats_ts = 0.0
-        ctx._report_stats()
-        tail = ctx.gcs.kv_get(ctx.node_id.hex(), namespace=STEPLOG_NS)
-        assert len(tail) <= 20
-        assert tail[-1]["step"] == 79  # newest survive
     finally:
         cfg.reset()
         ray_tpu.shutdown()
