@@ -74,7 +74,7 @@ from jax.ad_checkpoint import checkpoint_name
 from ..ops import kda, rope_frequencies, ssd
 from ..ops.attention import attention_plan
 from ..ops.layers import rmsnorm
-from .moe import _HELD_BUFFER_SHARES, MoEConfig, load_max_over_mean, moe_mlp, moe_plan
+from .moe import _HELD_BUFFER_SHARES, ROUTING, MoEConfig, load_max_over_mean, moe_mlp, moe_plan
 from .transformer import (
     Params,
     RematCandidate,
@@ -596,9 +596,11 @@ def _kda_sublayer(x, lp, config):
         with jax.named_scope("kda.gate_norm"):
             out = rmsnorm(out.astype(jnp.float32), lp["kda_norm_scale"],
                           eps=1e-6 if c.norm_eps is None else c.norm_eps)
-            out = (out * jax.nn.sigmoid(beta_gate[..., heads:])[..., None]).astype(dt)
+            out = checkpoint_name((out * jax.nn.sigmoid(beta_gate[..., heads:])[..., None]).astype(dt),
+                                  "kda_gate_norm_out")
         with jax.named_scope("kda.out_proj"):
-            return x + jnp.einsum("bshd,hde->bse", out, lp["kda_out"].astype(dt)), decay_min
+            out = jnp.einsum("bshd,hde->bse", out, lp["kda_out"].astype(dt))
+            return checkpoint_name(x + out, "kda_residual"), decay_min
 
 
 def _block(x, lp, config, kind: LayerKind, rope_tables, positions, remat_saved=()):
@@ -764,27 +766,67 @@ def _expert_layers(config: MixedStackConfig) -> int:
     return sum(kind.mlp == "experts" for kind in layer_kinds(config))
 
 
+# What a kept routing (`moe.ROUTING`) is worth beside a kept matmul output, from
+# forced plans on the chip (PERF.md section 6, PR 60). The router's float32
+# matmul at `Precision.HIGHEST` is six bfloat16 passes (`moe.route` recomputed,
+# 2.0 ms a step of `train-ling3flash-4k` over 6 layers of 4,096 tokens: 16
+# MFLOP a token at the chip's peak, where six passes count 15.7)
+_ROUTER_MATMUL_PASSES = 6
+# and FLOPs that take as long as selecting over ONE of a token's scores and
+# sorting its choices: the group limit's two top-k's, the masked top-k, the
+# gates' gather and the rows' sort are no arithmetic a matmul's rate measures.
+# Recomputed, `moe.select` 4.0 + the dispatch's sort 1.5 ms a step on that cell
+# (512 scores a token: 96 k a score; kept, the step is 8.2 ms shorter); on
+# `train-trinity-mini-8k` and `train-glm47flash-8k` (128 and 64 scores, no
+# groups) `moe.select` recomputed is 5.1 and 3.4 ms a step: 120 k and 128 k
+_ROUTING_SELECT_FLOPS_PER_SCORE = 100_000
+
+
 def _expert_costs(config: MixedStackConfig, split: Callable[[str], int]) -> Dict[str, Any]:
     """An expert layer's MLP as `transformer.mlp_costs` gives a dense one's,
     a row (a token) and device at an even routing: the router, the shared
     expert, the held experts' grouped matmuls on the rows sent here (three
     matrices an expert, or the two of one that is not gated), and the buffer
-    those rows pass through. It names no candidate: what the buffer or the
-    shared expert's gate and up are worth kept is not known from any record
-    (PERF.md section 7)."""
+    those rows pass through. Its candidates (PERF.md section 6, PR 60: each
+    forced alone on the chip beside what `train-ling3flash-4k` kept before,
+    a step of 234.9 ms). The routing, ONE name for all of it (`moe.ROUTING`:
+    the router's float32 logits, the chosen experts and their scores and, of
+    held experts, the sorted rows' order, 4 bytes each; the held experts'
+    ends are a few integers a layer): with it the backward pass neither runs
+    the router's matmul again nor the group limit, the top-k, the gather or
+    the sort; 8.2 ms for 53 MB, the first loss the same to the bit. And the
+    shared expert's gate and up (up alone where it is not gated), a matmul's
+    output each and worth it as a dense layer's are (`transformer.mlp_costs`):
+    0.6 ms for 75 MB there. NOT named: the held buffer (the gathered rows and
+    the experts' gate and up). Forced, it took 1.35 ms off that step alone and
+    0.9 ms beside the others for 100 MB by its shapes and 146 MB on the chip:
+    a Pallas kernel writes each of the three, so every kept one is a copy,
+    and most of the buffer's second pass stays (the slots' tables, the down
+    projection's operand). Its width is not a function of what `block_costs`
+    is given either (the grouped matmuls' tile pads every held expert's rows:
+    half of that cell's slots), and on the cells with 36,864-row buffers it
+    is 0.2-0.3 GB a layer."""
     c = config
     shared = c.shared_expert_width // split("ws_up") if c.shared_expert_width else 0
     d_ff = c.d_ff // split("we_up")
     matrices = len(c.expert_weights)
     rows_here = c.top_k * c.n_experts_held / c.n_experts   # (token, choice) pairs a token
+    itemsize = jnp.dtype(c.dtype).itemsize
+    router, shared_matmul = 2 * c.d_model * c.n_experts, 2 * c.d_model * shared
+    # float32 logits; the experts, their scores and (held: the sorted rows' order) 4 bytes a choice
+    routing = 4 * (c.n_experts + (2 if c.held_experts is None else 3) * c.top_k) // itemsize
     return {
-        "flops": int(2 * c.d_model * (c.n_experts + matrices * shared + matrices * rows_here * d_ff)),
+        "flops": int(router + matrices * shared_matmul + 2 * c.d_model * matrices * rows_here * d_ff),
         # both norms' outputs, the layer's, the residual; the router's float32
         # scores; the shared expert's (gate,) up and activation; a buffer row's
         # input, (gate,) up, activation and output
-        "width": int(4 * c.d_model + c.n_experts * 4 // jnp.dtype(c.dtype).itemsize + matrices * shared
+        "width": int(4 * c.d_model + c.n_experts * 4 // itemsize + matrices * shared
                      + min(_HELD_BUFFER_SHARES * rows_here, c.top_k) * (2 * c.d_model + matrices * d_ff)),
-        "candidates": (),
+        "candidates": (
+            RematCandidate((ROUTING,), routing, router,
+                           _ROUTER_MATMUL_PASSES * router + _ROUTING_SELECT_FLOPS_PER_SCORE * c.n_experts, False, ()),
+            *(RematCandidate((name.replace("we_", "moe_shared_"),), shared, shared_matmul, shared_matmul, False, ())
+              for name in c.expert_weights[:-1] if shared)),
     }
 
 
@@ -863,17 +905,37 @@ def _ssm_costs(config: MixedStackConfig) -> Dict[str, Any]:
 # spares: 1.86 ms a layer in the step, writing the states (PERF.md section 5
 # and 6, PR 56)
 _KDA_SHARE_OF_PEAK = {"xla_chunked": 0.02, "pallas": 0.085}
+# FLOPs that take as long as making a feature of the gated norm's output again:
+# memory traffic and the view a head's relayout, not arithmetic. Kept, the
+# `train-ling3flash-4k` step is 6.9 ms shorter (5.5 beside `kda_residual`) over
+# 6 layers of 4,096 tokens x 4,096 features (PERF.md section 6, PR 60)
+_KDA_GATE_NORM_FLOPS_PER_FEATURE = 10_000
 
 
-def _kda_costs(config: MixedStackConfig) -> Dict[str, Any]:
+def _kda_costs(config: MixedStackConfig, mlp_follows: bool) -> Dict[str, Any]:
     """`_kda_sublayer`'s part of `block_costs`, a layer and token (whole on
-    every device). Two candidates: the chunked rule's output WITH the
+    every device). Four candidates: the chunked rule's output WITH the
     float32 states that entered its chunks (`kda_chunk_out`,
     `kda_chunk_states` of ops/kda: with both the backward pass does not run
-    the rule forward again), and the in-projection's output (`kda_in_proj`:
+    the rule forward again); the in-projection's output (`kda_in_proj`:
     q, k, v and f, one matmul's; `_ssm_costs` says why it is worth its matmul:
-    on the `train-ling3flash-4k` cell 12.8 ms of a 273.2 ms step for 0.81 GB).
-    The small beta and gate projection beside it is NOT named: kept, its
+    on the `train-ling3flash-4k` cell 12.8 ms of a 273.2 ms step for 0.81 GB);
+    and since PR 60, each forced alone on that cell's chip beside what it kept
+    before, a step of 234.9 ms (PERF.md section 6, PR 60): the gated norm's
+    output as the out-projection reads it (`kda_gate_norm_out`: XLA's fusion
+    writes it, so the kept value costs no copy; 6.9 ms for 0.20 GB, 5.5
+    beside the next, which is what `_KDA_GATE_NORM_FLOPS_PER_FEATURE` counts),
+    and, where an MLP or an expert layer follows the mixer (`mlp_follows`),
+    the stream after the out-projection (`kda_residual`, an attention
+    layer's `attn_residual`: worth that matmul; 5.1 ms for 0.13 GB). With
+    both, the recomputed pass of a delta-rule block still runs the two
+    norms' statistics, the small projection, the convolutions and what the
+    gated norm's backward reads of the norm itself (2.9 ms a step; a
+    backward pass of the norm's own that keeps its three arguments and makes
+    those values itself took them out of the recomputed pass and put 3.0 ms
+    into the backward one, the step 0.6 ms longer: not shipped).
+    The small beta and gate projection beside the in-projection is NOT
+    named: kept, its
     bfloat16 rounding was forced where XLA's fusion carried the matmul's
     float32 into the sigmoids, and the cell's first loss moved by 1e-4
     (since PR 58 the program asks for that float32 itself); recomputing it
@@ -891,10 +953,11 @@ def _kda_costs(config: MixedStackConfig) -> Dict[str, Any]:
     state = -(-inner * d * 4 // (chunk * itemsize))     # the kept float32 states, in features a token
     impl = kda.resolve_kda_impl(chunk=chunk, d_k=d, d_v=d, lower_bound=c.kda_gate_lower_bound)
     projected = 4 * inner               # [q | k | v | f], one matmul's output
-    in_proj = 2 * c.d_model * projected
+    in_proj, out_proj = 2 * c.d_model * projected, 2 * inner * c.d_model
+    gate_norm = 8 * inner               # a square, a sum, the root's, the scale's and the gate's products
     return {
         "flops": (in_proj + 2 * c.d_model * 2 * heads + 2 * c.kda_conv_kernel * 3 * inner + rule
-                  + 2 * inner * c.d_model),
+                  + gate_norm + out_proj),
         # the norm's output, the sublayer's, the residual; the projection; the
         # convolved q, k, v; the normalised q and k and the float32 log-decay
         # (the XLA form's arrays: the kernels make them in VMEM); the chunks'
@@ -915,6 +978,9 @@ def _kda_costs(config: MixedStackConfig) -> Dict[str, Any]:
             RematCandidate(("kda_chunk_out", "kda_chunk_states"), inner + state, rule,
                            int(rule / _KDA_SHARE_OF_PEAK[impl]), False, ()),
             RematCandidate(("kda_in_proj",), projected, in_proj, in_proj, False, ()),
+            RematCandidate(("kda_gate_norm_out",), inner, gate_norm, _KDA_GATE_NORM_FLOPS_PER_FEATURE * inner,
+                           False, ()),
+            *((RematCandidate(("kda_residual",), c.d_model, out_proj, out_proj, False, ()),) if mlp_follows else ()),
         ),
     }
 
@@ -927,11 +993,13 @@ def block_costs(
     costs of the kinds of layer it has. A candidate counts the layers of the
     kinds that write it: the attention output and the residual after it every
     layer (a windowed layer's scores are cheaper than a full one's), gate and
-    up the dense layers."""
+    up the dense layers, the routing and the shared expert's projections the
+    expert layers (the multi-token prediction module's block is one more)."""
     c = config
 
     def kind_costs(kind: LayerKind):
-        mixer = (_ssm_costs(c) if kind.attention == "ssm" else _kda_costs(c) if kind.attention == "kda"
+        mixer = (_ssm_costs(c) if kind.attention == "ssm"
+                 else _kda_costs(c, kind.mlp != "none") if kind.attention == "kda"
                  else _NO_SUBLAYER if kind.attention == "none"
                  else attention_costs(c, seq, split, c.sliding_window if kind.attention == "sliding" else None))
         mlp = (mlp_costs(c, split, c.d_ff_dense) if kind.mlp == "dense"

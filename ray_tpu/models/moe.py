@@ -31,12 +31,14 @@ probability (1 at perfect balance).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ..ops import rope_frequencies, swiglu
@@ -372,30 +374,68 @@ def _expert_act(config: "MoEConfig"):
         raise ValueError(f"unknown expert activation: {config.expert_act!r}") from None
 
 
-def _expert(config: "MoEConfig", project, weights, act=None):
-    """One expert unit of either form: `project(w)` is the input through the
-    up (or gate) matrix `w`, and the hidden units go through the last of
-    `weights` by the caller. `act`: another unit than the routed experts'.
-    -> (the hidden units, the gate's projection or None where the unit is
-    not gated)."""
-    act = act or _expert_act(config)
+def _expert(config: "MoEConfig", project, weights):
+    """One routed expert unit of either form: `project(w)` is the input
+    through the up (or gate) matrix `w`, and the hidden units go through the
+    last of `weights` by the caller. -> (the hidden units, the gate's
+    projection or None where the unit is not gated)."""
+    act = _expert_act(config)
     if len(weights) == 2:
         return act(project(weights[0])), None
     gate = project(weights[0])
     return act(gate, project(weights[1])), gate
 
 
+# What the layer's backward pass reads of its routing, under ONE `checkpoint_name`
+# (a step that recomputes its blocks keeps all of it or none:
+# models/mixed_stack._expert_costs): the router's float32 logits, the chosen
+# experts, their scores and, of a layer that holds a part of the experts, the
+# order of its sorted rows with the ends of each expert's. Every value the
+# backward pass takes of the routing is read off those, so with them kept the
+# pass neither multiplies by the router again nor selects nor sorts
+ROUTING = "moe_routing"
+
+
+@functools.partial(jax.custom_jvp, nondiff_argnums=(1,))
+def _top_k_named(scores, k: int):
+    """`jax.lax.top_k` of (T, E) scores with the indices under `ROUTING`, and
+    JAX's own derivative for the values (the tangent gathered at the indices,
+    the same gather) reading the NAMED indices: its rule reads the top-k's
+    own output, which no name reaches, so a recomputing step would select
+    again whatever it kept. The lowered program is the plain call's. (Taking
+    the indices alone and gathering the values with `take_along_axis`, as the
+    select-bias form has to, is one more gather forward: 1.3 ms a layer at 8
+    of 64 scores of 16,384 tokens, PERF.md section 6, PR 60.)"""
+    gates, experts = jax.lax.top_k(scores, k)
+    return gates, checkpoint_name(experts, ROUTING)
+
+
+@_top_k_named.defjvp
+def _top_k_named_jvp(k, primals, tangents):
+    # the plain lines again, not a call of `_top_k_named`: a checkpoint's policy does not see into a nested call
+    gates, experts = jax.lax.top_k(*primals, k)
+    experts = checkpoint_name(experts, ROUTING)
+    d_gates = jax.lax.gather(
+        tangents[0], experts.reshape(*experts.shape, 1),
+        jax.lax.GatherDimensionNumbers(offset_dims=(), collapsed_slice_dims=(1,), start_index_map=(1,),
+                                       operand_batching_dims=(0,), start_indices_batching_dims=(0,)),
+        slice_sizes=(1, 1))
+    return (gates, experts), (d_gates, np.zeros(experts.shape, jax.dtypes.float0))
+
+
 def _route(scores, select, config):
     """scores (T, E) -> (gates (T, k), the chosen experts (T, k)): the k
     largest of `select` (the scores plus the selection bias; None: of the
     scores themselves), gated by their scores, renormalised and scaled as the
-    configuration says."""
+    configuration says; the experts and their scores under `ROUTING`."""
     c = config
     if select is None:
-        gates, experts = jax.lax.top_k(scores, c.top_k)
+        gates, experts = _top_k_named(scores, c.top_k)
     else:
         _, experts = jax.lax.top_k(select, c.top_k)
+        experts = checkpoint_name(experts, ROUTING)
         gates = jnp.take_along_axis(scores, experts, axis=-1)
+    gates = checkpoint_name(gates, ROUTING)
     if c.norm_topk_prob:
         gates = gates / (jnp.sum(gates, -1, keepdims=True) + 1e-9)
     if c.route_scale != 1.0:
@@ -555,10 +595,11 @@ def _held_experts(h, gates, experts, weights, config, tile, impl):
     with jax.named_scope("moe.dispatch"):
         local = experts.reshape(-1).astype(jnp.int32) - first_held
         flat = jnp.where((local >= 0) & (local < n), local, n)      # absent: sorted last
-        order = jnp.argsort(flat, stable=True).astype(jnp.int32)     # sorted position -> row
+        # sorted position -> row; both arguments of `all_passes`, whose backward pass keeps them
+        order = checkpoint_name(jnp.argsort(flat, stable=True).astype(jnp.int32), ROUTING)
         sorted_expert = flat[order]
-        ends = jnp.searchsorted(
-            sorted_expert, jnp.arange(n, dtype=jnp.int32), side="right").astype(jnp.int32)
+        ends = checkpoint_name(jnp.searchsorted(
+            sorted_expert, jnp.arange(n, dtype=jnp.int32), side="right").astype(jnp.int32), ROUTING)
         flat_gates = gates.reshape(-1)
 
     def one_pass(h, flat_gates, weights, order, ends, start):
@@ -714,10 +755,10 @@ def moe_mlp(
     with jax.named_scope("moe.route"):
         # float32 in earnest: a TPU's default float32 matmul is one bfloat16
         # pass, which rounds the router's weights and flips near-ties
-        router_logits = jnp.einsum(
+        router_logits = checkpoint_name(jnp.einsum(
             "bsm,me->bse", (h if router_input is None else router_input).astype(jnp.float32),
             lp["router"].astype(jnp.float32), precision=jax.lax.Precision.HIGHEST,
-        )
+        ), ROUTING)
         if c.router_score == "softmax":
             probs = jax.nn.softmax(router_logits, axis=-1)
         elif c.router_score == "sigmoid":
@@ -741,8 +782,10 @@ def moe_mlp(
     if c.shared_expert_width:
         with jax.named_scope("moe.shared"):
             shared = tuple(lp[name.replace("we_", "ws_")].astype(c.dtype) for name in c.expert_weights)
-            act, _ = _expert(c, lambda w: jnp.einsum("bsm,mf->bsf", h, w), shared,
-                             swiglu if len(shared) == 3 else None)
+            # (gate,) up: a matmul's output each, under `moe_shared_gate` / `moe_shared_up`
+            wide = [checkpoint_name(jnp.einsum("bsm,mf->bsf", h, w), name.replace("we_", "moe_shared_"))
+                    for name, w in zip(c.expert_weights, shared[:-1])]
+            act = swiglu(*wide) if len(wide) == 2 else _expert_act(c)(*wide)
             out = out + jnp.einsum("bsf,fm->bsm", act, shared[-1])
     # E * sum_e f_e P_e: f_e the share of the (token, choice) pairs routed to
     # e (it carries no gradient; under GShard, of those kept), P_e the mean

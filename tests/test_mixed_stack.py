@@ -773,10 +773,14 @@ def test_the_delta_rule_candidate_is_priced_by_the_form_that_runs(monkeypatch, b
 
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     config = tiny_ling(kda_heads=32, kda_head_dim=128, kda_chunk=64, kda_gate_lower_bound=bound, dtype=jnp.bfloat16)
-    candidate = _kda_costs(config)["candidates"][0]
+    candidate = _kda_costs(config, True)["candidates"][0]
     rule = 32 * (2 * 64 * (4 * 128 + 64) + 10 * 128 * 128)
     assert (candidate.names, candidate.width, candidate.flops, candidate.worth) == (
         ("kda_chunk_out", "kda_chunk_states"), 4096 + 16384, rule, int(rule / share))
+    # the stream after the out-projection is a candidate only where a sublayer follows the mixer (PR 60)
+    assert [[c.names[0] for c in _kda_costs(config, follows)["candidates"]] for follows in (True, False)] == [
+        ["kda_chunk_out", "kda_in_proj", "kda_gate_norm_out", "kda_residual"],
+        ["kda_chunk_out", "kda_in_proj", "kda_gate_norm_out"]]
 
 
 def test_the_three_shipped_mixed_stack_cells_keep_their_kinds_runs_and_leaves():

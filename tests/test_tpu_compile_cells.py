@@ -193,7 +193,12 @@ def test_trinity_cell_step_keeps_the_attention_outputs_and_compiles(as_tpu, monk
     monkeypatch.setattr(losses, "device_hbm_bytes", lambda: int(15.75 * GIB))
     step = make_train_step(config, opt, mesh, state_shardings=shardings)
     plan = step.remat_plan_for(tokens.shape, state)
-    assert (plan["remat"], plan["remat_saved"]) == ("selective", ("attn_out", "attn_lse"))
+    # since PR 60 also the four expert layers' routing (40 MB) and their shared expert's gate and up (0.27
+    # GB): the unrolled run's kept values go as its gradients come, so the estimate's largest moment, the
+    # scanned dense run's, does not move (15.73 GB; the chip 88.56 -> 88.86% of its memory)
+    assert (plan["remat"], plan["remat_saved"]) == ("selective", (
+        "moe_routing", "attn_out", "attn_lse", "moe_shared_gate", "moe_shared_up"))
+    assert plan["remat_saved_by_run"] == (("attn_out", "attn_lse"), plan["remat_saved"])
     # the whole sequence as the fused head's one chunk since PR 46 (the chip, one seed: 2,048 rows
     # 28,873 tokens/s at 89.18% of memory, the whole 8,192 28,902 at 88.56%)
     assert step.loss_chunk_for(tokens.shape, state) == 8192
@@ -246,7 +251,8 @@ def test_smallthinker_cell_step_keeps_what_a_four_layer_iteration_leaves_room_fo
     monkeypatch.setattr(losses, "device_hbm_bytes", lambda: int(15.75 * GIB))
     step = make_train_step(config, opt, mesh, state_shardings=shardings)
     plan = step.remat_plan_for(tokens.shape, state)
-    assert (plan["remat"], plan["remat_saved"]) == ("selective", ("attn_out", "attn_lse"))
+    # since PR 60 the eight expert layers' routing beside them (43 MB: the chip 92.50 -> 92.75% of its memory)
+    assert (plan["remat"], plan["remat_saved"]) == ("selective", ("moe_routing", "attn_out", "attn_lse"))
     # the whole sequence as the fused head's one chunk since PR 46 (the chip, one seed: 2,048 rows
     # 25,871 tokens/s at 92.59% of memory, the whole 16,384 25,922 at 92.50%)
     assert step.loss_chunk_for(tokens.shape, state) == 16384
@@ -257,7 +263,7 @@ def test_smallthinker_cell_step_keeps_what_a_four_layer_iteration_leaves_room_fo
     assert _kernels_named(compiled, "flash_win_bwd_dkv_dq") == 3
     assert _kernels_named(compiled, "flash_bwd_dkv_dq") == 1
     assert "flash_bwd_dq" not in compiled.as_text() and "flash_win_bwd_dq" not in compiled.as_text()
-    # 4 layers x 3 projections, forward and recomputed: the expert layer is recomputed whole
+    # 4 layers x 3 projections, forward and recomputed: the held buffer is recomputed whole (the routing is kept)
     assert _kernels_named(compiled, "moe_gmm_fwd") == 2 * 4 * 3 * 2
     # `moe_rows_sum`: one body a signature; 4 layers x 2 passes x (the combine, the
     # dispatch's transpose): nothing in the backward pass reads a recomputed combine
@@ -288,10 +294,14 @@ def test_glm47flash_cell_step_keeps_the_attention_outputs_and_the_latents_and_co
     monkeypatch.setattr(losses, "device_hbm_bytes", lambda: int(15.75 * GIB))
     step = make_train_step(config, opt, mesh, state_shardings=shardings)
     plan = step.remat_plan_for(tokens.shape, state)
+    # since PR 60 the five expert layers' routing (the module's block is one) and their shared expert's gate
+    # where the latents were: the routing's 25 MB come first by worth a byte and leave the latents' 0.264 GB
+    # 12 MB over the ceiling, the gate's 0.252 GB, worth the same a byte, 7 MB under it (on the chip the step
+    # is 585.2 -> 577.7 ms with it, at 89.3% of its memory either way: PERF.md section 6, PR 60)
     assert (plan["remat"], plan["remat_saved"]) == ("selective", (
-        "attn_out", "attn_lse", "attn_latent_q", "attn_latent_kv", "attn_latent_k_rope"))
-    # 6 layers x 16,384 rows x (5,120 + 40 + 768 + 512 + 64) bfloat16 features
-    assert plan["remat_saved_bytes"] == 6 * 16384 * (5120 + 40 + 1344) * 2
+        "moe_routing", "attn_out", "attn_lse", "moe_shared_gate"))
+    # 6 layers x 16,384 rows x (5,120 + 40) bfloat16 features, 5 x 16,384 x (152 + 1,536)
+    assert plan["remat_saved_bytes"] == (6 * (5120 + 40) + 5 * (152 + 1536)) * 16384 * 2
     # both passes of the head, the stack's and the module's, fused with the whole sequence as
     # the one chunk since PR 46 (the chip, one seed: dense 27,964 tokens/s at 90.28% of
     # memory, the whole 8,192 27,986 at 89.32%)

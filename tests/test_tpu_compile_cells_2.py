@@ -35,14 +35,18 @@ def test_nemotron3nano_cell_step_runs_the_scan_under_its_scopes_and_compiles(as_
     monkeypatch.setattr(losses, "device_hbm_bytes", lambda: int(15.75 * GIB))
     step = make_train_step(config, opt, mesh, state_shardings=shardings)
     plan = step.remat_plan_for(tokens.shape, state)
-    # what the rule kept before PR 57, and the four state-space layers' in-projections beside it
+    # what the rule kept before PR 57, the four state-space layers' in-projections beside it and, since PR
+    # 60, the four expert layers' routing and their shared expert's up (it is not gated)
     assert plan["remat"] == "selective" and set(plan["remat_saved"]) == {
-        "ssm_scan_out", "ssm_chunk_states", "attn_out", "attn_lse", "attn_residual", "ssm_in_proj"}
+        "ssm_scan_out", "ssm_chunk_states", "attn_out", "attn_lse", "attn_residual", "ssm_in_proj",
+        "moe_routing", "moe_shared_up"}
     assert plan["remat_saved_by_run"] == (
-        ("ssm_in_proj", "ssm_scan_out", "ssm_chunk_states"),
-        ("attn_out", "attn_lse", "attn_residual", "ssm_in_proj", "ssm_scan_out", "ssm_chunk_states"))
-    assert plan["remat_saved_bytes_by_run"] == (2 * 16384 * (10304 + 8192) * 2,
-                                                16384 * ((4096 + 64) + 2688 + 2 * (10304 + 8192)) * 2)
+        ("moe_routing", "ssm_in_proj", "moe_shared_up", "ssm_scan_out", "ssm_chunk_states"),
+        ("moe_routing", "attn_out", "attn_lse", "attn_residual", "ssm_in_proj", "moe_shared_up", "ssm_scan_out",
+         "ssm_chunk_states"))
+    assert plan["remat_saved_bytes_by_run"] == (
+        2 * 16384 * (10304 + 8192 + 292 + 3712) * 2,
+        16384 * ((4096 + 64) + 2688 + 2 * (10304 + 8192 + 292 + 3712)) * 2)
     assert step.loss_chunk_for(tokens.shape, state) == 8192
     said = model_family(config).plan(config, 2, 8192)
     assert (said["ssm_scan_impl"], said["ssm_scan_kernels"], said["ssm_scan_state_bytes"]) == (
@@ -102,10 +106,12 @@ def test_nemotron3nano_cell_step_runs_the_scan_under_its_scopes_and_compiles(as_
     # 7.30 GiB of temporaries beside them for the parent's plan, 14.75 GiB in all, for the step that ran
     # on the chip at 13,691,970,560 B = 12.75 GiB, and 1.26 GiB more with the projections kept, for the
     # step that ran at 15,000,355,328 B = 13.97 GiB = 88.7% of the chip (my chip runs, PR 57): it reads
-    # 2.0 GiB over the chip on both, so the band guards the program, and the chip's reading the fit
+    # 2.0 GiB over the chip on both, so the band guards the program, and the chip's reading the fit.
+    # Since PR 60 0.80 GiB more with the routing and the shared expert's up kept (0.49 GiB by their
+    # shapes), for the step that ran at 15,348,006,912 B = 14.29 GiB = 90.8% (my chip run, PR 60): 2.5 over
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes / GIB == pytest.approx(7.45, abs=0.02)
-    assert memory.temp_size_in_bytes / GIB == pytest.approx(7.30 + 1.26, abs=0.25)
+    assert memory.temp_size_in_bytes / GIB == pytest.approx(7.30 + 1.26 + 0.80, abs=0.25)
 
 
 def test_evabyte_cell_step_runs_the_kernels_once_a_shard_and_compiles(as_tpu, monkeypatch, v5e):
@@ -182,13 +188,18 @@ def test_ling3flash_cell_step_runs_the_delta_rule_under_its_scopes_and_compiles(
     monkeypatch.setattr(losses, "device_hbm_bytes", lambda: int(15.75 * GIB))
     step = make_train_step(config, opt, mesh, state_shardings=shardings)
     plan = step.remat_plan_for(tokens.shape, state)
-    # everything the stack names: what the rule kept before PR 57, and the six mixers' in-projections
+    # everything the stack names: what the rule kept before PR 57, the six mixers' in-projections and, since
+    # PR 60, the six expert layers' routing and shared gate and up, the mixers' gated norm's output and the
+    # stream after their out-projection
     assert plan["remat"] == "selective" and set(plan["remat_saved"]) == {
         "kda_chunk_out", "kda_chunk_states", "attn_out", "attn_lse", "attn_residual", "attn_latent_kv",
-        "attn_latent_k_rope", "mlp_up", "mlp_gate", "kda_in_proj"}
-    assert [set(names) & {"kda_in_proj", "mlp_up", "attn_out"} for names in plan["remat_saved_by_run"]] == [
-        {"kda_in_proj", "mlp_up"}, {"kda_in_proj", "attn_out"}]
-    assert plan["remat_saved_bytes_by_run"][1] == 4096 * (5 * (16384 + 20480) + (8192 + 64) + 2560 + 576) * 2
+        "attn_latent_k_rope", "mlp_up", "mlp_gate", "kda_in_proj", "moe_routing", "moe_shared_gate",
+        "moe_shared_up", "kda_gate_norm_out", "kda_residual"}
+    assert [set(names) & {"kda_in_proj", "mlp_up", "attn_out", "moe_routing", "kda_residual"}
+            for names in plan["remat_saved_by_run"]] == [
+        {"kda_in_proj", "mlp_up", "kda_residual"}, {"kda_in_proj", "attn_out", "moe_routing", "kda_residual"}]
+    assert plan["remat_saved_bytes_by_run"][1] == 4096 * (
+        5 * (16384 + 20480 + 4096 + 2560) + (8192 + 64) + 2560 + 576 + 6 * (1072 + 2 * 768)) * 2
     assert step.loss_chunk_for(tokens.shape, state) == 4096
     said = model_family(config).plan(config, 1, 4096)
     assert said["layer_kinds"] == "dK eK eK eK eL eK eK"
@@ -230,6 +241,13 @@ def test_ling3flash_cell_step_runs_the_delta_rule_under_its_scopes_and_compiles(
     assert "rematted_computation/kda/kda.in_proj/bse,ef->bsf/dot_general" in text
     assert not re.search(
         r"bf16\[(?:1,)?4096,16384\][^\n]*rematted_computation/kda/kda.in_proj/bse,ef->bsf/dot_general", text)
+    # nor, since PR 60, the out-projection (`kda_residual`), the router's matmul, the group limit's and the
+    # choice's top-k's and the rows' sort (`moe_routing`), the shared expert's gate and up
+    for again in (r"kda\.out_proj/bshd,hde->bse/dot_general", r"moe\.route/bsm,me->bse/dot_general",
+                  r"moe\.shared/bsm,mf->bsf/dot_general", r"moe\.(?:route|select)/[^\n\"]*top_k",
+                  r"moe\.dispatch/[^\n\"]*sort"):
+        assert re.search(r"jvp\([^\n\"]*" + again, text), again          # the forward pass runs it
+        assert not re.search(r"rematted_computation/[^\n\"]*" + again, text), again
     pairs = {(scope, pass_) for instances in table.values() for scopes, pass_, _ in instances for scope in scopes}
     for scope in ("kda", "kda.in_proj", "kda.conv", "kda.chunk", "kda.gate_norm", "kda.out_proj",
                   "attn.full", "attn.latent", "moe", "mlp", "head"):
