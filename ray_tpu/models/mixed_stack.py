@@ -57,6 +57,17 @@ under ONE sigmoid gate a head, an out-projection: leaves `kda_*` of its own;
 no positions), and the period's one layer is "latent" where the configuration
 has `kv_lora_rank`, else "full". `first_layer` is the published index of the
 first layer that is run: the two index rules count from it.
+
+A configuration with `mixer_kinds` is told its mixers' kinds as data, one a
+layer that is run (a published `layer_types` list that no index rule gives),
+and only its MLPs' kinds by `n_dense_layers` and `first_layer`. The sixth kind
+of mixer is such a list's: "sconv", a gated short convolution (`_sconv_sublayer`:
+[B | C | X] from one projection, C * conv(B * X) with `sconv_taps` causal
+depthwise taps a channel, no bias and no activation, ops/short_conv; an
+out-projection: leaves `sconv_*` of its own; no positions, no state beyond
+`sconv_taps` - 1 rows). Where `attn_full_rope`, a "full" layer has rotary
+positions over the whole head (after its QK-norm). Where `tie_embeddings`, the
+tree has no `lm_head` and the head reads `wte` (transformer.lm_head_weights).
 """
 
 from __future__ import annotations
@@ -71,7 +82,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from ..ops import kda, rope_frequencies, ssd
+from ..ops import kda, rope_frequencies, short_conv, ssd
 from ..ops.attention import attention_plan
 from ..ops.layers import rmsnorm
 from .moe import _HELD_BUFFER_SHARES, ROUTING, MoEConfig, load_max_over_mean, moe_mlp, moe_plan
@@ -162,6 +173,14 @@ class MixedStackConfig(MoEConfig):
     kda_conv_kernel: int = 4
     kda_chunk: int = kda.CHUNK
     kda_gate_lower_bound: float = -5.0
+    # The mixers' kinds as data, one of `MIXER_KINDS` a layer that is run ((): the
+    # index rules or the pattern above): a published list that no rule on the
+    # index gives. The MLPs' kinds stay `n_dense_layers`' and `first_layer`'s
+    mixer_kinds: Tuple[str, ...] = ()
+    # a "full" layer with rotary positions over the whole head (False: it encodes none)
+    attn_full_rope: bool = False
+    # the gated short convolution's causal depthwise taps a channel (an "sconv" mixer)
+    sconv_taps: int = 3
 
     @property
     def ssm_conv_width(self) -> int:
@@ -184,6 +203,15 @@ class MixedStackConfig(MoEConfig):
                              "prediction module are what the program runs")
         if self.first_layer < 0:
             raise ValueError(f"first_layer {self.first_layer}: a published layer's index")
+        if self.mixer_kinds and (len(self.mixer_kinds) != self.n_layers or set(self.mixer_kinds) - set(MIXER_KINDS)):
+            raise ValueError(f"mixer_kinds {self.mixer_kinds!r}: one of {MIXER_KINDS} for each of the "
+                             f"n_layers = {self.n_layers} layers is what the program runs")
+        if self.mixer_kinds and (self.layer_pattern or self.kda_heads or self.latent_attention
+                                 or self.mtp_modules):
+            raise ValueError("mixer_kinds: a stack told its mixers' kinds has no pattern, no delta-rule "
+                             "mixer, no latent attention and no multi-token prediction module")
+        if "sconv" in self.mixer_kinds and self.sconv_taps < 2:
+            raise ValueError(f"sconv_taps {self.sconv_taps}: a short convolution has two taps or more")
         if "M" in self.layer_pattern[:self.n_layers] and not (
                 self.ssm_heads > 0 and self.ssm_head_dim > 0 and self.ssm_state > 0
                 and self.ssm_groups > 0 and self.ssm_heads % self.ssm_groups == 0):
@@ -205,13 +233,15 @@ class MixedStackConfig(MoEConfig):
 class LayerKind(NamedTuple):
     """The sublayers a layer has: its mixer first, then its MLP."""
 
-    attention: str  # "sliding" | "full" | "latent" | "ssm" (a state-space mixer) | "kda" (a delta-rule one) | "none"
+    # "sliding" | "full" | "latent" | "ssm" (a state-space mixer) | "kda" (a delta-rule one)
+    # | "sconv" (a gated short convolution) | "none"
+    attention: str
     mlp: str        # "dense" | "experts" | "none"
 
     @property
     def code(self) -> str:
         return ({"dense": "d", "experts": "e", "none": "-"}[self.mlp]
-                + {"sliding": "S", "full": "F", "latent": "L", "ssm": "M", "kda": "K",
+                + {"sliding": "S", "full": "F", "latent": "L", "ssm": "M", "kda": "K", "sconv": "C",
                    "none": "-"}[self.attention])
 
     @property
@@ -219,6 +249,8 @@ class LayerKind(NamedTuple):
         return (self.attention != "none") + (self.mlp != "none")
 
 
+# what a `mixer_kinds` list may name
+MIXER_KINDS = ("sliding", "full", "sconv")
 # a `layer_pattern`'s characters: each a layer of one sublayer
 PATTERN_KINDS = {"M": LayerKind("ssm", "none"), "*": LayerKind("full", "none"),
                  "E": LayerKind("none", "experts")}
@@ -235,6 +267,9 @@ def layer_kinds(config: MixedStackConfig) -> List[LayerKind]:
     c = config
     if c.layer_pattern:
         return [PATTERN_KINDS[character] for character in c.layer_pattern[:c.n_layers]]
+    if c.mixer_kinds:
+        return [LayerKind(mixer, "dense" if c.first_layer + i < c.n_dense_layers else "experts")
+                for i, mixer in enumerate(c.mixer_kinds)]
     full_at = 0 if c.global_attn_first else c.global_attn_every - 1
     # the period's one layer, and the others
     one = "latent" if c.latent_attention else "full"
@@ -319,8 +354,8 @@ def _layer_shapes(config: MixedStackConfig, kind: LayerKind) -> Dict[str, Tuple[
     held, shared = c.n_experts_held, c.shared_expert_width
     dense, experts = kind.mlp == "dense", kind.mlp == "experts"
     gated = "we_gate" in c.expert_weights
-    ssm, delta = kind.attention == "ssm", kind.attention == "kda"
-    attention = kind.attention not in ("ssm", "kda", "none")
+    ssm, delta, sconv = kind.attention == "ssm", kind.attention == "kda", kind.attention == "sconv"
+    attention = kind.attention not in ("ssm", "kda", "sconv", "none")
     latent, plain = kind.attention == "latent", attention and kind.attention != "latent"
     q_rank, kv_rank, rope, dv = c.q_lora_rank, c.kv_lora_rank, c.qk_rope_dim, c.value_dim
     heads, inner, conv = c.ssm_heads, c.ssm_heads * c.ssm_head_dim, c.ssm_conv_width
@@ -329,7 +364,7 @@ def _layer_shapes(config: MixedStackConfig, kind: LayerKind) -> Dict[str, Tuple[
         (m, c.n_heads, dh), ("embed", "heads", "head_dim"))
     # (name, whether this layer has the leaf, its shape, initialisation and axes)
     leaves = [
-        ("ln1_scale", attention or ssm or delta, (m,), "ones", (None,)),
+        ("ln1_scale", attention or ssm or delta or sconv, (m,), "ones", (None,)),
         ("ln1_post_scale", attention and c.sandwich_norm, (m,), "post", (None,)),
         ("ln2_scale", dense or experts, (m,), "ones", (None,)),
         ("ln2_post_scale", (dense or experts) and c.sandwich_norm, (m,), "post", (None,)),
@@ -370,6 +405,10 @@ def _layer_shapes(config: MixedStackConfig, kind: LayerKind) -> Dict[str, Tuple[
         ("kda_dt_bias", delta, (kda_inner,), "ssm_dt_bias", (None,)),
         ("kda_norm_scale", delta, (c.kda_head_dim,), "ones", (None,)),
         ("kda_out", delta, (c.kda_heads, c.kda_head_dim, m), into_residual, ("ssm_heads", "head_dim", "embed")),
+        # a gated short convolution: [B | C | X] in one projection, whole on every device
+        ("sconv_in", sconv, (m, 3 * m), "normal", ("embed", None)),
+        ("sconv_w", sconv, (m, c.sconv_taps), "conv", (None, None)),
+        ("sconv_out", sconv, (m, m), into_residual, (None, "embed")),
         ("w_gate", dense, (m, c.d_ff_dense), "normal", ("embed", "mlp")),
         ("w_up", dense, (m, c.d_ff_dense), "normal", ("embed", "mlp")),
         ("w_down", dense, (c.d_ff_dense, m), into_residual, ("mlp", "embed")),
@@ -451,8 +490,9 @@ def init_params(config: MixedStackConfig, key: jax.Array) -> Params:
         "wte": c.embedding_std * jax.random.normal(k_embed, (c.vocab_size, c.d_model), pd),
         "runs": runs,
         "lnf_scale": jnp.ones((c.d_model,), pd),
-        "lm_head": std * jax.random.normal(k_head, (c.d_model, c.vocab_size), pd),
     }
+    if not c.tie_embeddings:
+        params["lm_head"] = std * jax.random.normal(k_head, (c.d_model, c.vocab_size), pd)
     if c.mtp_modules:
         # the module's own leaves without a leading axis, its block's stacked as a run of one
         own, block = _mtp_shapes(c), _layer_shapes(c, mtp_kind(c))
@@ -471,8 +511,9 @@ def logical_axes(config: MixedStackConfig) -> Params:
         "wte": ("vocab", "embed"),
         "runs": [[stacked(kind) for kind in run.kinds] for run in stack_runs(layer_kinds(config))],
         "lnf_scale": (None,),
-        "lm_head": ("embed", "vocab"),
     }
+    if not config.tie_embeddings:
+        axes["lm_head"] = ("embed", "vocab")
     if config.mtp_modules:
         axes["mtp"] = {**{name: leaf_axes for name, (_, _, leaf_axes) in _mtp_shapes(config).items()},
                        "block": stacked(mtp_kind(config))}
@@ -603,9 +644,30 @@ def _kda_sublayer(x, lp, config):
             return checkpoint_name(x + out, "kda_residual"), decay_min
 
 
+def _sconv_sublayer(x, lp, config):
+    """A gated short-convolution mixer + residual on (B, S, E), the scope
+    `sconv`: [B | C | X] = norm(x) W_in (`sconv.in_proj`); C * conv(B * X)
+    on the projection as it is, `sconv_taps` causal depthwise taps a channel
+    with no bias and no activation (`sconv.conv`, ops/short_conv.
+    gated_short_conv: both gates and the taps); W_out and the residual
+    (`sconv.out_proj`). No positions."""
+    c = config
+    dt = c.dtype
+    with jax.named_scope("sconv"):
+        with jax.named_scope("sconv.in_proj"):
+            u = _norm(x, lp["ln1_scale"], None, c.norm, c.norm_eps)
+            bcx = checkpoint_name(jnp.einsum("bse,ef->bsf", u, lp["sconv_in"].astype(dt)), "sconv_in_proj")
+        with jax.named_scope("sconv.conv"):
+            y = checkpoint_name(short_conv.gated_short_conv(bcx, lp["sconv_w"]), "sconv_conv_out")
+        with jax.named_scope("sconv.out_proj"):
+            out = jnp.einsum("bsf,fe->bse", y, lp["sconv_out"].astype(dt))
+            return checkpoint_name(x + out, "sconv_residual")
+
+
 def _block(x, lp, config, kind: LayerKind, rope_tables, positions, remat_saved=()):
     """One layer on (B, S, E), the sublayers its kind names: x + mixer(norm(x))
-    (attention of either kind, a state-space mixer or a delta-rule one), then
+    (attention of either kind, a state-space mixer, a delta-rule one or a gated
+    short convolution), then
     the same around the MLP (dense or experts), each sublayer's output through
     a norm of its own where the configuration has them. -> (x, the layer's
     scalars: an expert layer's, a state-space mixer's
@@ -622,9 +684,11 @@ def _block(x, lp, config, kind: LayerKind, rope_tables, positions, remat_saved=(
         x, scalars["ssm_log_decay_chunk_min"] = _ssm_sublayer(x, lp, c)
     elif kind.attention == "kda":
         x, scalars["kda_log_decay_chunk_min"] = _kda_sublayer(x, lp, c)
+    elif kind.attention == "sconv":
+        x = _sconv_sublayer(x, lp, c)
     elif kind.attention != "none":
         x = attention_sublayer(     # the scope `attn.window` or `attn.full`, by the window
-            x, lp, c, None if kind.attention == "full" else rope_tables, positions,
+            x, lp, c, None if kind.attention == "full" and not c.attn_full_rope else rope_tables, positions,
             window=c.sliding_window if kind.attention == "sliding" else None, remat_saved=remat_saved)
     if kind.mlp == "dense":
         x = mlp_sublayer(x, lp, c)
@@ -985,6 +1049,40 @@ def _kda_costs(config: MixedStackConfig, mlp_follows: bool) -> Dict[str, Any]:
     }
 
 
+# FLOPs that take as long as making a feature of the gated convolution's output
+# again: a memory-bound pass that reads the projection's three thirds and writes
+# one (8 bytes a feature in bfloat16; the chip's peaks are 240 FLOPs to a byte).
+# From the peaks, not from a forced plan
+_SCONV_FLOPS_PER_FEATURE = 2_000
+
+
+def _sconv_costs(config: MixedStackConfig, mlp_follows: bool) -> Dict[str, Any]:
+    """`_sconv_sublayer`'s part of `block_costs`, a layer and token (whole on
+    every device). Three candidates: the in-projection's output (`sconv_in_proj`:
+    B, C and X are one matmul's, worth that matmul as a state-space mixer's
+    is); the op's output as the out-projection reads it (`sconv_conv_out`: an
+    XLA fusion writes it, so the kept value is no copy; worth the memory-bound
+    pass that makes it again); and, where an MLP or an expert layer follows
+    the mixer, the stream after the out-projection (`sconv_residual`, an
+    attention layer's `attn_residual`: worth that matmul)."""
+    c = config
+    m = c.d_model
+    itemsize = jnp.dtype(c.dtype).itemsize
+    in_proj, out_proj = 2 * m * 3 * m, 2 * m * m
+    conv = (2 * c.sconv_taps + 1) * m       # two gates, a multiplication a tap, the taps' sum
+    return {
+        "flops": in_proj + conv + out_proj,
+        # the norm's output, the sublayer's, the residual; the projection; the
+        # float32 product B * X padded by the taps' reach; the op's output
+        "width": 3 * m + 3 * m + m * 4 // itemsize + m,
+        "candidates": (
+            RematCandidate(("sconv_in_proj",), 3 * m, in_proj, in_proj, False, ()),
+            RematCandidate(("sconv_conv_out",), m, conv, _SCONV_FLOPS_PER_FEATURE * m, False, ()),
+            *((RematCandidate(("sconv_residual",), m, out_proj, out_proj, False, ()),) if mlp_follows else ()),
+        ),
+    }
+
+
 def block_costs(
     config: MixedStackConfig, seq: int, split: Callable[[str], int] = lambda weight: 1,
 ) -> Dict[str, Any]:
@@ -1000,6 +1098,7 @@ def block_costs(
     def kind_costs(kind: LayerKind):
         mixer = (_ssm_costs(c) if kind.attention == "ssm"
                  else _kda_costs(c, kind.mlp != "none") if kind.attention == "kda"
+                 else _sconv_costs(c, kind.mlp != "none") if kind.attention == "sconv"
                  else _NO_SUBLAYER if kind.attention == "none"
                  else attention_costs(c, seq, split, c.sliding_window if kind.attention == "sliding" else None))
         mlp = (mlp_costs(c, split, c.d_ff_dense) if kind.mlp == "dense"
@@ -1047,6 +1146,13 @@ def plan(config: MixedStackConfig, batch: int, seq: int) -> Dict[str, Any]:
                    kda_gate_lower_bound=c.kda_gate_lower_bound, kda_conv_impl="+".join(sorted(set(convs))),
                    **kda.kda_plan(c.kda_chunk, heads=c.kda_heads, d_k=c.kda_head_dim, d_v=c.kda_head_dim,
                                   lower_bound=c.kda_gate_lower_bound))
+    if any(kind.attention == "sconv" for kind in kinds):
+        out.update(sconv_channels=c.d_model, sconv_taps=c.sconv_taps,
+                   **short_conv.sconv_plan(seq, c.d_model, c.sconv_taps))
+    if c.attn_full_rope:
+        out["attn_full_rope"] = True
+    if c.tie_embeddings:
+        out["tie_embeddings"] = True
     if c.latent_attention:
         out.update(attn_latent_q_rank=c.q_lora_rank, attn_latent_kv_rank=c.kv_lora_rank,
                    attn_rope_dims=c.rotary_dims, attn_head_dim=c.head_dim)

@@ -70,6 +70,9 @@ class MoEConfig(TransformerConfig):
     router_score: str = "softmax"
     router_select_bias: bool = False
     route_scale: float = 1.0
+    # what is added to the sum of the chosen scores before the gates are
+    # renormalised over it (`norm_topk_prob`)
+    route_norm_eps: float = 1e-9
     # group-limited routing: the experts in `route_groups` groups of neighbours,
     # of which the `route_groups_kept` best (a group's score the sum of its two
     # largest selection scores) are the only ones the top-k is taken from
@@ -437,7 +440,7 @@ def _route(scores, select, config):
         gates = jnp.take_along_axis(scores, experts, axis=-1)
     gates = checkpoint_name(gates, ROUTING)
     if c.norm_topk_prob:
-        gates = gates / (jnp.sum(gates, -1, keepdims=True) + 1e-9)
+        gates = gates / (jnp.sum(gates, -1, keepdims=True) + c.route_norm_eps)
     if c.route_scale != 1.0:
         gates = gates * c.route_scale
     return gates, experts

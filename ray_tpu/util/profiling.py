@@ -699,6 +699,7 @@ STEP_SCOPES: Tuple[str, ...] = (
     "attn.eva", "attn.eva.pool", "attn.eva.local", "attn.eva.far", "attn.eva.merge",
     "ssm", "ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.gate_norm", "ssm.out_proj",
     "kda", "kda.in_proj", "kda.conv", "kda.chunk", "kda.gate_norm", "kda.out_proj",
+    "sconv", "sconv.in_proj", "sconv.conv", "sconv.out_proj",
     "mlp",
     "moe", "moe.route", "moe.select", "moe.dispatch", "moe.experts", "moe.combine", "moe.passes",
     "moe.shared",

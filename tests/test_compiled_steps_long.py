@@ -81,11 +81,11 @@ def test_mixed_step_table_holds_both_attention_kinds_both_mlps_and_the_expert_la
     moe = {"moe", "moe.route", "moe.select", "moe.dispatch", "moe.experts", "moe.combine", "moe.passes",
            "moe.shared"}
     # every scope but the two a latent-attention stack with a prediction module adds (PR 44),
-    # a state-space mixer's (PR 48), the dense family's EVA attention and next-byte heads (PR 51) and a
-    # delta-rule mixer's (PR 55)
+    # a state-space mixer's (PR 48), the dense family's EVA attention and next-byte heads (PR 51), a
+    # delta-rule mixer's (PR 55) and a gated short convolution's (PR 61)
     assert {scope for scope, _ in pairs} == {
         s for s in profiling.STEP_SCOPES if s not in ("attn.latent", "mtp", "head.multibyte")
-        and not s.startswith(("ssm", "kda", "attn.eva"))}
+        and not s.startswith(("ssm", "kda", "sconv", "attn.eva"))}
     for sublayer in {"attn.window", "attn.full", "mlp"} | moe:
         assert {(sublayer, "fwd"), (sublayer, "recompute"), (sublayer, "bwd")} <= pairs
     for found in mixed.values():

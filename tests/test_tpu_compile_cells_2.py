@@ -258,3 +258,48 @@ def test_ling3flash_cell_step_runs_the_delta_rule_under_its_scopes_and_compiles(
           memory.temp_size_in_bytes / GIB, "plan", plan)
     # the chip ran the parent's plan at 13,217,902,080 B and this one at 12.5 GiB (my chip runs, PR 57)
     assert total < (1 - losses.HBM_FREE_FRACTION) * 15.75 * GIB
+
+
+def test_lfm2moe_cell_step_runs_the_short_convolution_under_its_scopes_and_compiles(as_tpu, monkeypatch, v5e):
+    """`train-lfm2moe-8k`'s whole step (2 x 8,193 tokens) for one described v5e
+    chip of 15.75 GiB: `dC` then `eF eC eC eC`, all five unrolled. The four
+    short-convolution mixers' three scopes lie inside `sconv` in the forward
+    pass and the backward; the one attention layer runs the causal flash
+    kernels at D = 64 past one tile a head, rotary positions in front of them;
+    the expert layers the grouped matmuls of a held layer; the fused head reads
+    the tied matrix and takes the whole sequence as its chunk."""
+    from ray_tpu.models import model_family
+    from ray_tpu.ops import losses
+    from ray_tpu.train.lm import make_train_step
+    from ray_tpu.util import profiling
+
+    mesh = build_mesh(MeshSpec(), devices=[as_tpu])
+    config, opt, state, shardings, tokens = _cell_step_shapes(
+        "lfm2-8b-a1b-train-1chip", mesh, (2, 8193))
+    assert "lm_head" not in state.params and "lm_head" not in shardings.params
+    monkeypatch.setattr(losses, "device_hbm_bytes", lambda: int(15.75 * GIB))
+    step = make_train_step(config, opt, mesh, state_shardings=shardings)
+    plan = step.remat_plan_for(tokens.shape, state)
+    print("lfm2moe plan", plan)
+    assert plan["remat"] == "selective" and {"sconv_in_proj", "sconv_conv_out", "sconv_residual"} <= set(
+        plan["remat_saved"])
+    assert step.loss_chunk_for(tokens.shape, state) == 8192
+    said = model_family(config).plan(config, 2, 8192)
+    assert said["layer_kinds"] == "dC eF eC eC eC"
+    assert (said["sconv_channels"], said["sconv_taps"], said["sconv_impl"], said["sconv_rows"]) == (2048, 3, "xla", 0)
+    assert said["attn_full_rope"] is True and said["tie_embeddings"] is True
+    assert (said["moe_experts_routed"], said["moe_experts_held"], said["moe_top_k"]) == (32, 8, 4)
+    compiled = step.lower(state, {"tokens": tokens}).compile()
+    assert _kernels_named(compiled, "flash_bwd_dkv_dq") == 1 and "flash_win" not in compiled.as_text()
+    assert _kernels_named(compiled, "moe_gmm_fwd") > 0
+    _, table = profiling.program_ops_table(profiling._module_text(compiled))
+    pairs = {(scope, found) for instances in table.values() for scopes, found, _ in instances for scope in scopes}
+    for scope in ("sconv", "sconv.in_proj", "sconv.conv", "sconv.out_proj", "attn.full", "moe", "mlp", "head"):
+        assert {(scope, "fwd"), (scope, "bwd")} <= pairs, scope
+    memory = compiled.memory_analysis()
+    total = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    print("lfm2moe step: arguments", memory.argument_size_in_bytes / GIB, "temporaries",
+          memory.temp_size_in_bytes / GIB)
+    parameters = sum(x.size for x in jax.tree.leaves(state.params))
+    assert memory.argument_size_in_bytes / GIB == pytest.approx(12 * parameters / GIB, abs=0.05)
+    assert total < (1 - losses.HBM_FREE_FRACTION) * 15.75 * GIB
