@@ -441,6 +441,9 @@ def _attention_sublayer(x, lp, config, rope_tables, positions, window, remat_sav
             q = q + lp["bq"].astype(dt)[None, :, None, :]
             k = k + lp["bk"].astype(dt)[None, :, None, :]
             v = v + lp["bv"].astype(dt)[None, :, None, :]
+        if c.qk_norm or c.qk_norm_per_head:
+            # what a QK-norm's backward pass reads: q and k as the matmuls wrote them
+            q, k = checkpoint_name(q, "attn_q_proj"), checkpoint_name(k, "attn_k_proj")
         if c.qk_norm:
             q = _qk_norm(q, lp["q_norm_scale"], c.norm_eps)
             k = _qk_norm(k, lp["k_norm_scale"], c.norm_eps)
@@ -452,6 +455,9 @@ def _attention_sublayer(x, lp, config, rope_tables, positions, window, remat_sav
             cos, sin = rope_tables
             q = apply_rope(q, cos, sin, positions)
             k = apply_rope(k, cos, sin, positions)
+        # the kernel's operands as it takes them, after the bias, the norms and the rotation: with
+        # the three (and a gate's logits) kept the backward pass repeats no projection of the normed stream
+        q, k, v = (checkpoint_name(t, name) for t, name in ((q, "attn_q"), (k, "attn_k"), (v, "attn_v")))
     with jax.named_scope("attn.kernel"):
         if c.eva_attention:
             attn = eva_attention(q, k, v, lp["eva_mu"], lp["eva_phi"], window=c.eva_window,
@@ -473,6 +479,7 @@ def _attention_sublayer(x, lp, config, rope_tables, positions, window, remat_sav
                 gate = jnp.einsum("bse,eh->bhs", h, lp["wg"].astype(dt))[..., None]
             else:
                 (gate,) = column_parallel(h, ("bse,ehd->bhsd", lp["wg"]))
+            gate = checkpoint_name(gate, "attn_gate")
     with jax.named_scope("attn.out"):
         if c.attn_gate:
             attn = attn * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(dt)
@@ -556,7 +563,15 @@ def attention_costs(
     config: TransformerConfig, seq: int, split: Callable[[str], int], window: Optional[int] = None,
 ) -> Dict[str, Any]:
     """`attention_sublayer`'s part of `block_costs`, a layer: `flops`,
-    `width` and `candidates` as there."""
+    `width` and `candidates` as there, and `candidates_last`, which
+    `stack_costs` lists after every sublayer's `candidates`: the kernel's
+    operands as it takes them, q, k and v after the bias, the norms and the
+    rotation (`attn_q`, `attn_k`, `attn_v`) with, of a gated layer, the gate's
+    logits (`attn_gate`) and, of one with a QK-norm, q and k as the matmuls
+    wrote them, which the norm's backward pass reads (`attn_q_proj`,
+    `attn_k_proj`), all or none: kept, the backward pass repeats no matmul of
+    the normed stream, no norm a head and no rotation (a latent-attention
+    layer keeps its latents instead and offers none of these)."""
     c = config
     if c.latent_attention:
         return _latent_attention_costs(c, seq, split)
@@ -573,6 +588,12 @@ def attention_costs(
     # a row of the residual stream on this device, in features of the activations' dtype:
     # its share where the sequence lies over `tp`
     stream = c.d_model * jnp.dtype(c.stream_dtype).itemsize // itemsize // split("stream")
+    # q, k, v and a gate's logits, one matmul's output each; under a QK-norm q and k twice, as
+    # its backward pass reads them and as the kernel does
+    projected = q_width + 2 * kv_width + ((heads if c.attn_gate_per_head else q_width) if c.attn_gate else 0)
+    qk_normed = c.qk_norm or c.qk_norm_per_head
+    operands = ("attn_q", "attn_k", "attn_v", *(("attn_gate",) if c.attn_gate else ()),
+                *(("attn_q_proj", "attn_k_proj") if qk_normed else ()))
     return {
         "flops": (2 * c.d_model * ((2 if c.attn_gate else 1) * q_width + 2 * kv_width)
                   + scores + out_proj),
@@ -584,6 +605,19 @@ def attention_costs(
         "candidates": (
             _kept_kernel_candidate(scores, heads, q_width, itemsize, "window" if window else "full"),
             RematCandidate(("attn_residual",), stream, out_proj, out_proj, split("wq") > 1, ()),
+        ),
+        # Worth their matmuls alone, as `mlp_up` is: a matmul's output is worth `d_model` FLOPs a
+        # byte whichever matmul wrote it, and listed last these are added to what a step kept
+        # before them, never swapped for it. The row-wise work they spare with the matmuls (the
+        # norms a head, the rotation, the `bse -> bhsd` layout: 4 of the 5.1 ms recomputed on
+        # `train-lfm2moe-8k`, PERF.md section 6, PR 62) is NOT priced: priced, they would stand
+        # over every other matmul's output and push one out on a full chip, and where there is
+        # room the rule takes them as they are. (Under a QK-norm the same FLOPs buy more bytes, q
+        # and k twice, and the candidate stands behind the matmuls' outputs by its worth alone.)
+        # No sum over `tp` is spared: the ring's permutes are not one.
+        "candidates_last": (
+            RematCandidate(operands, projected + (q_width + kv_width if qk_normed else 0),
+                           2 * c.d_model * projected, 2 * c.d_model * projected, False, ()),
         ),
     }
 
@@ -683,11 +717,13 @@ def stack_costs(runs: Sequence[StackRun]) -> Dict[str, Any]:
     that write it and their mean FLOPs and worth."""
     flops = recomputed = 0
     merged: Dict[Tuple[str, ...], RematCandidate] = {}
+    last = set()    # offered as a kind's `candidates_last`
     for r, run in enumerate(runs):
         for n, attention, mlp in run.kinds:
             flops += n * (attention["flops"] + mlp["flops"])
             recomputed += n * (attention["flops"] + mlp["flops"] - mlp.get("kept_anyway", 0))
-            for c in (*attention["candidates"], *mlp["candidates"]):
+            last.update(c.names for c in attention.get("candidates_last", ()))
+            for c in (*attention["candidates"], *mlp["candidates"], *attention.get("candidates_last", ())):
                 seen = merged.get(c.names, c._replace(layers=(0,) * len(runs), flops=0, worth=0))
                 if (seen.width, seen.tp_sum) != (c.width, c.tp_sum):
                     raise ValueError(f"{c.names} differs between the kinds of one stack")
@@ -704,7 +740,9 @@ def stack_costs(runs: Sequence[StackRun]) -> Dict[str, Any]:
                        "stream_split": min(m.get("stream_split", 1) for _, _, m in run.kinds),
                        "width": max(a["width"] + m["width"] for _, a, m in run.kinds)}
                       for run in runs),
-        "candidates": tuple(merged.values()),
+        # in the order the kinds give them, a mixer's before its MLP's, and every kind's `candidates_last`
+        # behind those: the rule's sort is stable, so at equal worth a byte the first listed is tried first
+        "candidates": tuple(sorted(merged.values(), key=lambda c: c.names in last)),
     }
 
 
@@ -721,10 +759,12 @@ def block_costs(
     keep: the MLP's up (and gate) projection, each on its own; the residual
     stream after the attention output projection (with it the backward needs
     neither that matmul again nor, under tensor parallelism, the sum of its
-    partial results over `tp`: `RematCandidate.tp_sum`); and the attention
-    kernel's output with its lse
-    (with both the backward does not run the forward kernel again; the q, k,
-    v projections it still does: they are not named). A candidate's `worth`
+    partial results over `tp`: `RematCandidate.tp_sum`); the attention
+    kernel's output with its lse (with both the backward does not run the
+    forward kernel again); and, last, the kernel's operands q, k and v as it
+    takes them (with a gate's logits and a QK-norm's inputs: with all of them
+    the backward repeats no projection of the normed stream, no norm a head
+    and no rotation; `attention_costs`). A candidate's `worth`
     is its FLOPs where a matmul is spared; the kernel's are priced by its
     time, less what keeping its results moves (`_FLASH_SHARE_OF_PEAK`,
     `_KEPT_KERNEL_FLOPS_PER_BYTE`): at S = 1,024 that leaves nothing, at

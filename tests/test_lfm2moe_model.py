@@ -133,8 +133,11 @@ def test_the_rule_may_keep_the_projection_the_ops_output_and_the_residual():
     config = tiny_lfm2()
     costs = model_family(config).block_costs(config, 128)
     by_name = {c.names: c for c in costs["candidates"]}
+    # and, since PR 62, the QK-normed attention layer's operands with the norm's inputs, listed last
+    operands = ("attn_q", "attn_k", "attn_v", "attn_q_proj", "attn_k_proj")
     assert {("sconv_in_proj",), ("sconv_conv_out",), ("sconv_residual",), ("attn_out", "attn_lse"),
-            ("attn_residual",), ("mlp_up",), ("mlp_gate",), (moe.ROUTING,)} == set(by_name)
+            ("attn_residual",), ("mlp_up",), ("mlp_gate",), (moe.ROUTING,), operands} == set(by_name)
+    assert costs["candidates"][-1].names == operands and by_name[operands].layers == (0, 1)
     m = 64
     assert (by_name["sconv_in_proj",].width, by_name["sconv_in_proj",].flops) == (3 * m, 2 * m * 3 * m)
     assert (by_name["sconv_conv_out",].width, by_name["sconv_conv_out",].flops) == (m, 7 * m)

@@ -103,7 +103,10 @@ EXPERT_CELLS = {
                      {"attn_out", "attn_lse", "moe_routing"}),
     "nemotron3nano": ("nemotron-3-nano-30b-a3b-train-1chip", 2, 8192,
                       {"moe_routing": ((4 * 128 + 12 * 6) // 2, (2, 2)), "moe_shared_up": (3712, (2, 2))},
-                      CELLS["nemotron3nano"][4] | {"ssm_in_proj", "moe_routing", "moe_shared_up"}),
+                      # and, since PR 62, its one attention layer's q, k and v (tests/test_attn_remat.py): it
+                      # stands in the unrolled run, whose kept values go as its gradients come
+                      CELLS["nemotron3nano"][4] | {"ssm_in_proj", "moe_routing", "moe_shared_up",
+                                                   "attn_q", "attn_k", "attn_v"}),
 }
 
 

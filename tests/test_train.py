@@ -306,6 +306,7 @@ TRINITY = ("trinity-mini-train-1chip", MeshSpec())
 
 
 MATMUL_NAMES = ("attn_residual", "mlp_up", "mlp_gate")
+QKV = ("attn_q", "attn_k", "attn_v")    # the attention kernel's operands: one candidate, listed last (PR 62)
 ATTN_OUT = ("attn_out", "attn_lse")   # the kernel's output and its lse: one candidate
 LATENTS = ("attn_latent_q", "attn_latent_kv", "attn_latent_k_rope")   # a latent-attention layer's
 ROUTING = ("moe_routing",)              # an expert layer's: logits, experts, scores, the sorted rows' order (PR 60)
@@ -317,18 +318,21 @@ ROUTING = ("moe_routing",)              # an expert layer's: logits, experts, sc
     # results moves (the chip: 38,334 tokens/s without it, 38,318 with it, PERF.md section 6).
     # The head, here and below: the fused one with the device's whole sequence as its one
     # chunk (12 x 1,024 x 16,384 logits a device; the chip, PR 28: -0.03% against dense)
-    (*MISTRAL, V5E_HBM, 24, 1024, {}, ("selective", MATMUL_NAMES, 1024)),
+    # Since PR 62 q, k and v behind them: 3,072 features a row where up and gate are 7,168 each, tried
+    # last and ADDED to what fits before them (the chip: PERF.md section 6, PR 62)
+    (*MISTRAL, V5E_HBM, 24, 1024, {}, ("selective", MATMUL_NAMES + QKV, 1024)),
     # beside two more layers of state one value as wide as up fits, not two (before PR 34's
-    # refit, which leaves 6.5% of the chip free where it left 10%: the narrow residual alone)
-    (*MISTRAL, V5E_HBM, 24, 1024, {"n_layers": 10}, ("selective", ("attn_residual", "mlp_up"), 1024)),
+    # refit, which leaves 6.5% of the chip free where it left 10%: the narrow residual alone);
+    # q, k and v, less than half as wide, still do
+    (*MISTRAL, V5E_HBM, 24, 1024, {"n_layers": 10}, ("selective", ("attn_residual", "mlp_up") + QKV, 1024)),
     # twelve layers: nothing before PR 54 (the whole-block program, not an out-of-memory error);
     # since then the blocks' inputs and the residual are half the rows a device (their sequences
-    # lie over `tp`) and the narrow residual fits; with fourteen nothing does
-    (*MISTRAL, V5E_HBM, 24, 1024, {"n_layers": 12}, ("selective", ("attn_residual",), 1024)),
+    # lie over `tp`) and the narrow residual fits, and q, k and v beside it; with fourteen nothing does
+    (*MISTRAL, V5E_HBM, 24, 1024, {"n_layers": 12}, ("selective", ("attn_residual",) + QKV, 1024)),
     (*MISTRAL, V5E_HBM, 24, 1024, {"n_layers": 14}, ("whole_block", (), 1024)),
-    # twice the batch: the narrow residual (before the refit: nothing); with ten layers the same
-    # since PR 54, with twelve nothing
-    (*MISTRAL, V5E_HBM, 48, 1024, {}, ("selective", ("attn_residual",), 1024)),
+    # twice the batch: the narrow residual (before the refit: nothing) and q, k and v; with ten layers
+    # the residual alone since PR 54, with twelve nothing
+    (*MISTRAL, V5E_HBM, 48, 1024, {}, ("selective", ("attn_residual",) + QKV, 1024)),
     (*MISTRAL, V5E_HBM, 48, 1024, {"n_layers": 10}, ("selective", ("attn_residual",), 1024)),
     (*MISTRAL, V5E_HBM, 48, 1024, {"n_layers": 12}, ("whole_block", (), 1024)),
     # a device of unknown size (the CPU): the step that fits wherever anything does
@@ -403,12 +407,12 @@ def test_remat_rule_on_the_cells_numbers(monkeypatch, cell, mesh_spec, hbm, batc
         # all but the scores of five windowed layers and a full one and, since PR 60 (0.712 before), the four
         # expert layers' router and shared gate and up, of the stack's forward
         assert plan["remat_recomputed_flops_share"] == pytest.approx(0.668, abs=0.001)
-    if want[:2] == ("selective", MATMUL_NAMES):
+    if want[:2] == ("selective", MATMUL_NAMES + QKV):
         # 12,288 rows a device x (its half of gate and of up + its half of the residual's
-        # sequences, which lie over `tp` since PR 54) x 2 B x 8 layers
-        assert plan["remat_saved_bytes"] == 12288 * (2 * 7168 + 4096 // 2) * 2 * 8
-        # what is left to recompute: q, k, v and the attention kernel
-        assert plan["remat_recomputed_flops_share"] == pytest.approx(0.132, abs=0.001)
+        # sequences, which lie over `tp` since PR 54 + its 16 + 4 + 4 heads of q, k and v) x 2 B x 8 layers
+        assert plan["remat_saved_bytes"] == 12288 * (2 * 7168 + 4096 // 2 + 3072) * 2 * 8
+        # what is left to recompute: the attention kernel (0.132 with q, k and v, before PR 62)
+        assert plan["remat_recomputed_flops_share"] == pytest.approx(0.019, abs=0.001)
         # beside the whole-block step as the chip measured it (11.145 GB; the
         # estimate reads a little under), the stated share stays free
         assert 11.145e9 + plan["remat_saved_bytes"] < (1 - losses.HBM_FREE_FRACTION) * hbm
@@ -530,9 +534,10 @@ def test_selective_step_on_a_mesh_gives_one_devices_loss(monkeypatch):
     plan, sharded = first_step(MeshSpec(fsdp=2, tp=2), 10 ** 9)
     assert whole_plan["remat"] == "whole_block"
     assert plan["remat"] == "selective" and set(plan["remat_saved"]) == {
-        "mlp_up", "mlp_gate", "attn_residual"}
+        "mlp_up", "mlp_gate", "attn_residual", *QKV}
     rows, layers, itemsize = 4 * 16, config.n_layers, jnp.dtype(config.dtype).itemsize
+    # and its 2 + 1 + 1 heads of 16 of q, k and v
     assert plan["remat_saved_bytes"] == layers * rows * itemsize * (
-        2 * config.d_ff // 2 + config.d_model // 2)
+        2 * config.d_ff // 2 + config.d_model // 2 + 4 * 16)
     assert sharded["loss"] == pytest.approx(whole["loss"], rel=2e-3)
     assert sharded["grad_norm"] == pytest.approx(whole["grad_norm"], rel=2e-2)

@@ -122,9 +122,10 @@ def test_step_fn_span_names_how_a_held_expert_layer_adds_up_a_tokens_rows(monkey
 @pytest.mark.parametrize("hbm_bytes,kernel_move_cost,want", [
     (0, None, ("whole_block", ())),                 # the CPU: a device of unknown size
     # at 16 tokens the attention kernel's output is worth less than keeping it moves
-    (10 ** 9, None, ("selective", ("attn_residual", "mlp_up", "mlp_gate"))),
+    (10 ** 9, None, ("selective", ("attn_residual", "mlp_up", "mlp_gate", "attn_q", "attn_k", "attn_v"))),
     # were keeping it free (as it all but is at the cells' 8,192), both its names follow
-    (10 ** 9, 0, ("selective", ("attn_residual", "mlp_up", "mlp_gate", "attn_out", "attn_lse"))),
+    (10 ** 9, 0, ("selective", ("attn_residual", "mlp_up", "mlp_gate", "attn_q", "attn_k", "attn_v",
+                                "attn_out", "attn_lse"))),
 ], ids=["unknown-device-size", "room-for-every-name", "and-the-attention-output"])
 def test_step_fn_span_names_what_a_recomputing_step_keeps(
         monkeypatch, hbm_bytes, kernel_move_cost, want):
@@ -150,15 +151,17 @@ def test_step_fn_span_names_what_a_recomputing_step_keeps(
     assert traced and set(traced) == {want[1]}
     assert attrs == {**attrs, **trainer.step_fn.remat_plan_for((16, 17), trainer.state)}
     rows, itemsize = 4 * 16, jnp.dtype(config.dtype).itemsize
-    # a device's half of gate and up and (its sequences lie over `tp` since PR 54) of the
-    # residual; its half of the attention output with the lse of its 2 heads
-    kept_width = (2 * config.d_ff // 2 + config.d_model // 2) if want[1] else 0
+    # a device's half of gate and up, (its sequences lie over `tp` since PR 54) of the residual
+    # and of q, k and v (2 + 1 + 1 heads of 16); its half of the attention output with the lse of its 2 heads
+    kept_width = (2 * config.d_ff // 2 + config.d_model // 2 + 4 * 16) if want[1] else 0
     if "attn_out" in want[1]:
         kept_width += config.d_model // 2 + 4 * 2 // itemsize
     assert attrs["remat_saved_bytes"] == config.n_layers * rows * itemsize * kept_width
     assert (attrs["remat_saved_by_run"], attrs["remat_saved_bytes_by_run"]) == (
         (want[1],), (attrs["remat_saved_bytes"],))
-    assert 0 < attrs["remat_recomputed_flops_share"] < (0.3 if want[1] else 0.9)
+    # with the kernel's output and its operands both kept, no FLOP the costs count is run again
+    assert ("attn_out" not in want[1]) == (attrs["remat_recomputed_flops_share"] > 0)
+    assert attrs["remat_recomputed_flops_share"] < (0.3 if want[1] else 0.9)
 
 
 def test_span_tree_of_a_three_step_train_call(trainer):
