@@ -84,7 +84,6 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import kda, rope_frequencies, short_conv, ssd
 from ..ops.attention import attention_plan
-from ..ops.layers import rmsnorm
 from .moe import _HELD_BUFFER_SHARES, ROUTING, MoEConfig, load_max_over_mean, moe_mlp, moe_plan
 from .transformer import (
     Params,
@@ -602,15 +601,18 @@ def _kda_sublayer(x, lp, config):
     a head each (`kda.in_proj`); q, k and v through a causal depthwise
     convolution and silu (`kda.conv`, ops/ssd.causal_conv1d with no bias: the
     state-space mixer's, kernels and rule); the chunked delta rule on those
-    as they are, flat (B, S, H D), with f, beta's logits, `A_log` and the
-    gate's bias (`kda.chunk`, ops/kda.kda_rule, which makes of them what the
-    recurrence takes, on a TPU in its kernels' VMEM and in `jnp` elsewhere: q
-    and k L2-normalised a head, q over sqrt(head_dim); the log-decay a
-    channel, `kda_gate_lower_bound` x sigmoid(exp(A_log) (f + dt_bias)) in
-    float32; beta = sigmoid); an RMS norm over each head's output times
-    sigmoid of the head's ONE gate logit (`kda.gate_norm`); W_out and the
-    residual (`kda.out_proj`). No positions. -> (x, the most negative
-    cumulative log-decay inside a chunk)."""
+    as they are, flat (B, S, H D), with f, the two logits a head as the small
+    matmul wrote them, `A_log`, the gate's bias and the norm's scale
+    (`kda.chunk`, ops/kda.kda_rule, which makes of them what the recurrence
+    takes, on a TPU in its kernels' VMEM and in `jnp` elsewhere: q and k
+    L2-normalised a head, q over sqrt(head_dim); the log-decay a channel,
+    `kda_gate_lower_bound` x sigmoid(exp(A_log) (f + dt_bias)) in float32;
+    beta = sigmoid; and which returns the rule's output through an RMS norm
+    over each head's features times sigmoid of the head's ONE gate logit,
+    flat: on a TPU `kda_fwd`'s last lines and `kda_bwd`'s first, elsewhere
+    operations under `kda.gate_norm` inside `kda.chunk`); W_out on the flat
+    features and the residual (`kda.out_proj`). No positions. -> (x, the most
+    negative cumulative log-decay inside a chunk)."""
     c = config
     dt = c.dtype
     heads = c.kda_heads
@@ -620,27 +622,24 @@ def _kda_sublayer(x, lp, config):
             u = _norm(x, lp["ln1_scale"], None, c.norm, c.norm_eps)
             projected = checkpoint_name(jnp.einsum("bse,ef->bsf", u, lp["kda_in"].astype(dt)), "kda_in_proj")
             # the matmul's float32 accumulator is what the sigmoids take: XLA's fusion carried it there before it
-            # was asked to, and beta's logits are a kernel's operand, which is written as the program says
+            # was asked to, and the logits are a kernel's operand, which is written as the program says (WHOLE:
+            # the kernels read beta's lanes and the gate's; a slice in front of a kernel is a copy)
             beta_gate = jnp.einsum("bse,ef->bsf", u, lp["kda_bg"].astype(dt), preferred_element_type=jnp.float32)
             # [q | k | v | f]: the WHOLE array for q's and k's convolution and for v's (two calls: the
             # kernels take at most 8,192 channels a step), and f; `_gate_xbc_dt`'s reasons
             for_qk, for_v, f = _gate_xbc_dt(projected, 2 * inner, inner)
-            beta = beta_gate[..., :heads]
         with jax.named_scope("kda.conv"):
             taps = lp["kda_conv_w"]
             q, k = ssd.causal_conv1d(for_qk, taps[:2 * inner], jnp.zeros((2 * inner,), jnp.float32),
                                      splits=(inner, inner))
             v = ssd.causal_conv1d(for_v, taps[2 * inner:], jnp.zeros((inner,), jnp.float32), offset=2 * inner)
         with jax.named_scope("kda.chunk"):
-            out, decay_min = kda.kda_rule(q, k, v, f, beta, lp["kda_a_log"], lp["kda_dt_bias"], eps=_KDA_L2_EPS,
+            out, decay_min = kda.kda_rule(q, k, v, f, beta_gate, lp["kda_a_log"], lp["kda_dt_bias"],
+                                          lp["kda_norm_scale"], eps=_KDA_L2_EPS,
+                                          norm_eps=1e-6 if c.norm_eps is None else c.norm_eps,
                                           chunk=c.kda_chunk, lower_bound=c.kda_gate_lower_bound)
-        with jax.named_scope("kda.gate_norm"):
-            out = rmsnorm(out.astype(jnp.float32), lp["kda_norm_scale"],
-                          eps=1e-6 if c.norm_eps is None else c.norm_eps)
-            out = checkpoint_name((out * jax.nn.sigmoid(beta_gate[..., heads:])[..., None]).astype(dt),
-                                  "kda_gate_norm_out")
         with jax.named_scope("kda.out_proj"):
-            out = jnp.einsum("bshd,hde->bse", out, lp["kda_out"].astype(dt))
+            out = jnp.einsum("bsf,fe->bse", out, lp["kda_out"].astype(dt).reshape(inner, -1))
             return checkpoint_name(x + out, "kda_residual"), decay_min
 
 
@@ -969,35 +968,27 @@ def _ssm_costs(config: MixedStackConfig) -> Dict[str, Any]:
 # spares: 1.86 ms a layer in the step, writing the states (PERF.md section 5
 # and 6, PR 56)
 _KDA_SHARE_OF_PEAK = {"xla_chunked": 0.02, "pallas": 0.085}
-# FLOPs that take as long as making a feature of the gated norm's output again:
-# memory traffic and the view a head's relayout, not arithmetic. Kept, the
-# `train-ling3flash-4k` step is 6.9 ms shorter (5.5 beside `kda_residual`) over
-# 6 layers of 4,096 tokens x 4,096 features (PERF.md section 6, PR 60)
-_KDA_GATE_NORM_FLOPS_PER_FEATURE = 10_000
 
 
 def _kda_costs(config: MixedStackConfig, mlp_follows: bool) -> Dict[str, Any]:
     """`_kda_sublayer`'s part of `block_costs`, a layer and token (whole on
-    every device). Four candidates: the chunked rule's output WITH the
-    float32 states that entered its chunks (`kda_chunk_out`,
-    `kda_chunk_states` of ops/kda: with both the backward pass does not run
-    the rule forward again); the in-projection's output (`kda_in_proj`:
-    q, k, v and f, one matmul's; `_ssm_costs` says why it is worth its matmul:
-    on the `train-ling3flash-4k` cell 12.8 ms of a 273.2 ms step for 0.81 GB);
-    and since PR 60, each forced alone on that cell's chip beside what it kept
-    before, a step of 234.9 ms (PERF.md section 6, PR 60): the gated norm's
-    output as the out-projection reads it (`kda_gate_norm_out`: XLA's fusion
-    writes it, so the kept value costs no copy; 6.9 ms for 0.20 GB, 5.5
-    beside the next, which is what `_KDA_GATE_NORM_FLOPS_PER_FEATURE` counts),
-    and, where an MLP or an expert layer follows the mixer (`mlp_follows`),
-    the stream after the out-projection (`kda_residual`, an attention
-    layer's `attn_residual`: worth that matmul; 5.1 ms for 0.13 GB). With
-    both, the recomputed pass of a delta-rule block still runs the two
-    norms' statistics, the small projection, the convolutions and what the
-    gated norm's backward reads of the norm itself (2.9 ms a step; a
-    backward pass of the norm's own that keeps its three arguments and makes
-    those values itself took them out of the recomputed pass and put 3.0 ms
-    into the backward one, the step 0.6 ms longer: not shipped).
+    every device). Three candidates: the rule's output, gated and normed as
+    the out-projection reads it, WITH the float32 states that entered its
+    chunks and o as the norm's transpose reads it (`kda_chunk_out`,
+    `kda_chunk_states`, `kda_chunk_o` of ops/kda: with the three the backward
+    pass does not run the rule forward again; since PR 63 the norm a head
+    under the head's gate is the rule's own last step, in the kernels where
+    o already is, so what this candidate spares holds the norm's arithmetic
+    too and the norm's output has no candidate of its own; the bytes are
+    what the two candidates held together); the in-projection's output
+    (`kda_in_proj`: q, k, v and f, one matmul's; `_ssm_costs` says why it is
+    worth its matmul: on the `train-ling3flash-4k` cell 12.8 ms of a 273.2 ms
+    step for 0.81 GB); and, where an MLP or an expert layer follows the mixer
+    (`mlp_follows`), the stream after the out-projection (`kda_residual`, an
+    attention layer's `attn_residual`: worth that matmul; 5.1 ms for 0.13 GB
+    forced alone on that cell's chip, PERF.md section 6, PR 60). With all
+    kept, the recomputed pass of a delta-rule block still runs the input
+    norm's statistics, the small projection and the convolutions.
     The small beta and gate projection beside the in-projection is NOT
     named: kept, its
     bfloat16 rounding was forced where XLA's fusion carried the matmul's
@@ -1039,11 +1030,9 @@ def _kda_costs(config: MixedStackConfig, mlp_follows: bool) -> Dict[str, Any]:
         "width": (3 * c.d_model + projected + 3 * inner
                   + (0 if impl == "pallas" else 2 * inner + inner * 4 // itemsize) + state + 2 * inner),
         "candidates": (
-            RematCandidate(("kda_chunk_out", "kda_chunk_states"), inner + state, rule,
-                           int(rule / _KDA_SHARE_OF_PEAK[impl]), False, ()),
+            RematCandidate(("kda_chunk_out", "kda_chunk_states", "kda_chunk_o"), 2 * inner + state,
+                           rule + gate_norm, int(rule / _KDA_SHARE_OF_PEAK[impl]), False, ()),
             RematCandidate(("kda_in_proj",), projected, in_proj, in_proj, False, ()),
-            RematCandidate(("kda_gate_norm_out",), inner, gate_norm, _KDA_GATE_NORM_FLOPS_PER_FEATURE * inner,
-                           False, ()),
             *((RematCandidate(("kda_residual",), c.d_model, out_proj, out_proj, False, ()),) if mlp_follows else ()),
         ),
     }

@@ -1,6 +1,7 @@
 """Kimi Delta Attention's core (arXiv:2510.26692): the delta rule with a decay
 a channel, in its chunked form, from a mixer's arguments as the mixer has them
-(`kda_rule`) or from the recurrence's own (`kda_chunk`, the XLA form).
+and through the norm a head that follows it (`kda_rule`) or from the
+recurrence's own (`kda_chunk`, the XLA form).
 
 The recurrence, a head of D_k key and D_v value features with a state S of
 D_k x D_v, position by position (`kda_reference`):
@@ -55,13 +56,20 @@ in the activations' dtype, beta's logits (B, S, H), `A_log` a head and the
 gate's bias a channel, and makes of them (`rule_arguments`): q and k a head
 over their L2 norm, q over sqrt(D), rounded to the activations' dtype; the
 log-decay a = `lower_bound` x sigmoid(exp(A_log) (f + bias)) in float32; beta
-= sigmoid. -> (o, the most negative cumulative log-decay a chunk reaches).
+= sigmoid. WHAT THE MIXER READS is not o either: beside beta's logits
+`kda_rule` takes the norm's gate logits, a head's ONE (the two as the one
+small matmul writes them, (B, S, 2 H) float32, [beta | gate]), the norm's
+scale (D_v,) and its eps, and returns y = o over its root mean square a head
+x scale x sigmoid(gate), float32 throughout and rounded once, flat (B, S, H
+D): what the out-projection reads (`gated_head_norm` is the same in `jnp`).
+-> (y, the most negative cumulative log-decay a chunk reaches).
 
 Differentiated, the op carries its own backward pass (`custom_vjp`): it keeps
-its arguments, the state that entered each chunk and the output (named
-`kda_chunk_states` and `kda_chunk_out` for a checkpoint policy around the
-caller: with both kept, its backward pass does not run the rule forward a
-second time), builds the chunks' parts again, walks the chunks in reverse with
+its arguments, the state that entered each chunk and o in the activations'
+dtype (what the norm's transpose reads), and its caller y (named
+`kda_chunk_states`, `kda_chunk_o` and `kda_chunk_out` for a checkpoint policy
+around the caller: with the three kept, its backward pass does not run the
+rule forward a second time), builds the chunks' parts again, walks the chunks in reverse with
 the state's cotangent and transposes the parts.
 
 Two forms compute `kda_rule`, chosen by ops/ssd's one static rule on the
@@ -82,17 +90,31 @@ swapped for the other). The form that tiles is the form that fuses:
   (`_kernel_arguments`: two lane reductions and a sigmoid a head block, each
   rounding where `rule_arguments` has it, the float32 log-decay never
   written: around the kernels XLA took as long as the kernels, PERF.md
-  section 6, PR 58). It writes o, the least cumulative log-decay a channel at
+  section 6, PR 58). It norms o where o is, the block's heads' (64, 128)
+  float32 tiles side by side in VMEM: one more lane reduction a head beside
+  the prologue's two, the scale's row, the sigmoid of the block's heads' gate
+  logits picked out of the logits' lanes as beta's are (`_logit_columns`,
+  `_kernel_gated_norm`: XLA's norm on the view a head, (.., 4,096) -> (..,
+  32, 128), took thirteen times its bytes over three passes, PERF.md section
+  6, PR 63). It writes y, the least cumulative log-decay a channel at
   a chunk's end (a (1, H D) block that stays over a sequence's chunks) and,
   differentiated, the float32 state that entered the chunk (134 MB a layer of
-  4,096 tokens of 32 heads, what the XLA form keeps). The backward is ONE
+  4,096 tokens of 32 heads, what the XLA form keeps) and o rounded to the
+  activations' dtype (33.5 MB a layer: forming o again in the backward from
+  the parts it holds, two products a head, kept 0.20 GB fewer on the
+  `train-ling3flash-4k` cell and its step was 0.78 ms longer, PERF.md section
+  6, PR 63). The backward is ONE
   kernel, the same walk reversed with the states' cotangents in scratch: it
-  makes the arguments and builds the parts again, transposes the chunk's
+  makes the arguments and builds the parts again, reads o, makes the norm's
+  statistics of it
+  and turns y's cotangent into o's, the gate logits' and the scale's;
+  transposes the chunk's
   step and its parts, takes a's reverse cumulative sum as a triangular
   matmul, goes on in float32 through the gate's, beta's and the norms'
   derivatives and writes the cotangents of the mixer's q, k, v, f (one
-  rounding each) and of beta's logits; the two gate parameters' gradients
-  are summed in float32, a channel, over a sequence's chunks in two blocks
+  rounding each) and of the two logits (one block, handed back as ONE (B, S,
+  2 H) cotangent); the two gate parameters' gradients and the norm's scale's
+  are summed in float32, a channel, over a sequence's chunks in three blocks
   that stay, and finished in XLA from those few KB. It keeps nothing of its
   own. Every array of a step carries the block's heads side by side, so the
   long chain of dependent steps of one head (cumulative sum, exponentials,
@@ -113,7 +135,9 @@ swapped for the other). The form that tiles is the form that fuses:
   else is float32 or bfloat16 exactly where the XLA form is.
 - "xla_chunked", everywhere else (every CPU run) and what the kernels are
   compared with: `rule_arguments` in `jnp`, then `kda_chunk`, the einsums
-  above, the diagonal sub-blocks pairwise.
+  above, the diagonal sub-blocks pairwise, then `gated_head_norm` on o as
+  `kda_chunk` rounds it (one rounding more than the kernels' forward), under
+  the scope `kda.gate_norm`; its o carries the kernels' name for it.
 
 What the kernels tile, and nothing else (other sizes run the XLA form, by the
 rule; a kernel asked for by name there is refused by name): key and value
@@ -138,6 +162,7 @@ from jax.extend.core import Primitive
 from jax.interpreters import mlir
 
 from . import ssd
+from .layers import rmsnorm
 
 F32 = jnp.float32
 _IMPLEMENTATIONS = ("xla_chunked", "pallas")
@@ -193,12 +218,15 @@ def kda_plan(chunk: int = CHUNK, implementation: Optional[str] = None, *, heads:
     VMEM scratch (none of the three for the XLA form), and which form makes
     the recurrence's arguments (the L2 norms, the gate's log-decay, beta) from
     the mixer's, `kda_prologue`: "kernel" (in VMEM, `_kernel_arguments`) or
-    "xla" (`rule_arguments`)."""
+    "xla" (`rule_arguments`), and which norms o a head under the head's gate,
+    `kda_epilogue`: "kernel" (`kda_fwd` where it has o, its transpose at
+    `kda_bwd`'s head: `_kernel_gated_norm`) or "xla" (`gated_head_norm`)."""
     impl = resolve_kda_impl(implementation, chunk=chunk, d_k=d_k, d_v=d_v, lower_bound=lower_bound)
     per_step = _heads_per_step(heads) if impl == "pallas" else 0
+    fused = "kernel" if impl == "pallas" else "xla"
     return {"kda_impl": impl, "kda_chunk": chunk, "kda_subchunk": _subchunk(chunk),
             "kda_kernels": 2 if impl == "pallas" else 0, "kda_heads_per_step": per_step,
-            "kda_state_bytes": per_step * d_k * d_v * 4, "kda_prologue": "kernel" if impl == "pallas" else "xla"}
+            "kda_state_bytes": per_step * d_k * d_v * 4, "kda_prologue": fused, "kda_epilogue": fused}
 
 
 def _subchunk(chunk: int) -> int:
@@ -323,7 +351,8 @@ def _chunked_fwd(q, k, v, a, beta):
     w, u_own, decayed_k, leaving, decayed_q, queries = _chunk_parts(q, k, v, a, beta, _subchunk(q.shape[-2]))
     states = checkpoint_name(_states(w, u_own, decayed_k, leaving, dtype), "kda_chunk_states")
     out = _read_out(decayed_q, queries, states, _corrections(w, u_own, states, dtype), dtype)
-    out = checkpoint_name(out.astype(v.dtype), "kda_chunk_out")
+    # what the norm after this reads, forward and backward (`kda_rule`): the kernels' second kept output
+    out = checkpoint_name(out.astype(v.dtype), "kda_chunk_o")
     return out, (q, k, v, a, beta, states)
 
 
@@ -475,13 +504,24 @@ def _by_head(ref, heads: int):
     return jnp.stack([ref[0, :, h * _LANES:(h + 1) * _LANES] for h in range(heads)])
 
 
-def _beta_columns(ref, heads: int):
-    """beta of the step's heads, a column each (heads, C, 1), from the block
-    (1, C, H) of every head's logit: the sigmoid, then the block's heads
+def _logit_columns(ref, heads: int):
+    """(beta, the norm's gate) of the step's heads, a column each (heads, C,
+    1), from the block (1, C, 2 H) of every head's two logits as the small
+    matmul wrote them, [beta | gate]: the sigmoid, then the block's heads
     picked out of the lanes by a mask (the block of heads is a grid index)."""
     every = jax.nn.sigmoid(ref[0])
     lane = jax.lax.broadcasted_iota(jnp.int32, every.shape, 1) - pl.program_id(1) * heads
-    return jnp.stack([jnp.sum(jnp.where(lane == h, every, 0.0), axis=1, keepdims=True) for h in range(heads)])
+    return tuple(jnp.stack([jnp.sum(jnp.where(lane == first + h, every, 0.0), axis=1, keepdims=True)
+                            for h in range(heads)]) for first in (0, every.shape[1] // 2))
+
+
+def _kernel_gated_norm(out, norm_eps: float):
+    """o (heads, C, D) float32 as a step has it -> (o over its root mean
+    square a head and position, the inverse root (heads, C, 1)): y is the
+    first times the scale's row times the gate's column (`gated_head_norm`'s
+    lines, on the float32 o where that reads its rounding)."""
+    inverse = jax.lax.rsqrt(jnp.mean(out * out, axis=-1, keepdims=True) + norm_eps)
+    return out * inverse, inverse
 
 
 def _write(ref, value):
@@ -511,14 +551,16 @@ def _kernel_arguments(q_ref, k_ref, f_ref, rate_ref, bias_ref, heads: int, lower
                 units=units, scale=scale, rate=rate, shifted=shifted, gate=gate)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, f_ref, beta_ref, rate_ref, bias_ref, o_ref, least_ref, *rest,
-                lower_bound: float, eps: float):
+def _fwd_kernel(q_ref, k_ref, v_ref, f_ref, logits_ref, rate_ref, bias_ref, scale_ref, y_ref, least_ref, *rest,
+                lower_bound: float, eps: float, norm_eps: float):
     """A chunk of a block of heads from the states in `state_scr`, which it
-    leaves updated: o, the least cumulative log-decay a channel that any chunk
-    of the sequence's reached at its last position (the block stays over a
-    sequence's chunks), and where `rest` holds a block for them the states
-    that entered the chunk."""
-    *states_ref, state_scr = rest
+    leaves updated: y, the float32 o over its root mean square a head times
+    the norm's scale and the sigmoid of the head's gate logit, rounded once;
+    the least cumulative log-decay a channel that any chunk of the
+    sequence's reached at its last position (the block stays over a
+    sequence's chunks); and where `rest` holds blocks for them the states
+    that entered the chunk and o itself, rounded."""
+    *kept_refs, state_scr = rest
     dtype = q_ref.dtype
     heads = state_scr.shape[0]
 
@@ -528,30 +570,39 @@ def _fwd_kernel(q_ref, k_ref, v_ref, f_ref, beta_ref, rate_ref, bias_ref, o_ref,
         least_ref[...] = jnp.zeros_like(least_ref)
 
     made = _kernel_arguments(q_ref, k_ref, f_ref, rate_ref, bias_ref, heads, lower_bound, eps)
-    parts = _kernel_parts(made["q"], made["k"], _by_head(v_ref, heads), made["a"], _beta_columns(beta_ref, heads))
+    beta, gate = _logit_columns(logits_ref, heads)
+    parts = _kernel_parts(made["q"], made["k"], _by_head(v_ref, heads), made["a"], beta)
     _write(least_ref, jnp.minimum(_by_head(least_ref, heads), parts["last"]))
     state = state_scr[...]
-    if states_ref:
-        states_ref[0][0, 0] = state
+    if kept_refs:
+        kept_refs[0][0, 0] = state
     narrow = state.astype(dtype)
     u = (parts["u_own"] - _dots(parts["w"].astype(dtype), narrow, _NT)).astype(dtype)
     out = _dots(parts["decayed_q"].astype(dtype), narrow, _NT) + _dots(parts["queries"].astype(dtype), u)
-    _write(o_ref, out)
+    _write(y_ref, _kernel_gated_norm(out, norm_eps)[0] * _by_head(scale_ref, heads) * gate)
+    if kept_refs:
+        _write(kept_refs[1], out)
     state_scr[...] = parts["leaving"] * state + _dots(u, parts["decayed_k"].astype(dtype), _TN)
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, f_ref, beta_ref, rate_ref, bias_ref, states_ref, do_ref,
-                dq_ref, dk_ref, dv_ref, df_ref, dbeta_ref, dgate_ref, dgate_shifted_ref, dstate_scr, *,
-                lower_bound: float, eps: float):
+def _bwd_kernel(q_ref, k_ref, v_ref, f_ref, logits_ref, rate_ref, bias_ref, scale_ref, states_ref, o_ref, dy_ref,
+                dq_ref, dk_ref, dv_ref, df_ref, dlogits_ref, dgate_ref, dgate_shifted_ref, dscale_ref, dstate_scr, *,
+                lower_bound: float, eps: float, norm_eps: float):
     """The transpose of `_fwd_kernel`'s chunk, the chunks in reverse: the
-    arguments and the parts again in VMEM, the cotangent of the state that
+    arguments and the parts again in VMEM, then the norm's statistics of the
+    o that `kda_fwd` kept
+    and y's cotangent through the scale, the gate and the norm into o's, in
+    float32, rounded where the norm's transpose in XLA rounded it; the
+    cotangent of the state that
     leaves the chunk in `dstate_scr`, replaced by that of the state that
     entered it; then the float32 cotangents of the recurrence's arguments
     through the norms', the gate's and beta's sigmoid's derivatives, written
     as those of the mixer's (one rounding each). The sums over the sequence
     that the gate's two parameters' gradients are made of stay float32: the
     cotangent d of rate (f + bias) and d (f + bias), a channel, added up over
-    the chunks in blocks that stay. The sub-block starts R are constants of
+    the chunks in blocks that stay, and so is the norm's scale's gradient a
+    head. The two logits' cotangents leave as ONE block, the step's heads'
+    beta's columns then their gates'. The sub-block starts R are constants of
     the factoring (the decays do not depend on them), so nothing reaches a
     through them."""
     dtype = q_ref.dtype
@@ -562,17 +613,29 @@ def _bwd_kernel(q_ref, k_ref, v_ref, f_ref, beta_ref, rate_ref, bias_ref, states
         dstate_scr[...] = jnp.zeros_like(dstate_scr)
         dgate_ref[...] = jnp.zeros_like(dgate_ref)
         dgate_shifted_ref[...] = jnp.zeros_like(dgate_shifted_ref)
+        dscale_ref[...] = jnp.zeros_like(dscale_ref)
 
     made = _kernel_arguments(q_ref, k_ref, f_ref, rate_ref, bias_ref, heads, lower_bound, eps)
-    beta = _beta_columns(beta_ref, heads)
+    beta, gate = _logit_columns(logits_ref, heads)
     p = _kernel_parts(made["q"], made["k"], _by_head(v_ref, heads), made["a"], beta)
     _, c, d = p["kf"].shape
     row, col, same = p["row"], p["col"], p["same"]
     state, d_after = states_ref[0, 0], dstate_scr[...]                   # (heads, D_v, D_k)
-    narrow, d_after_n, d_out = state.astype(dtype), d_after.astype(dtype), _by_head(do_ref, heads)
+    narrow, d_after_n = state.astype(dtype), d_after.astype(dtype)
     w_n, decayed_k_n = p["w"].astype(dtype), p["decayed_k"].astype(dtype)
     decayed_q_n, queries_n = p["decayed_q"].astype(dtype), p["queries"].astype(dtype)
     u = (p["u_own"] - _dots(w_n, narrow, _NT)).astype(dtype)
+    # y = o / rms(o) * scale * gate: y's cotangent into the scale's, the gate's logit's and o's
+    normed, inverse_rms = _kernel_gated_norm(_by_head(o_ref, heads).astype(F32), norm_eps)
+    scale_row = _by_head(scale_ref, heads)
+    d_y = _by_head(dy_ref, heads).astype(F32)
+    along = d_y * normed
+    _write(dscale_ref, _by_head(dscale_ref, heads) + jnp.sum(along * gate, axis=1, keepdims=True))
+    d_gate = jnp.sum(along * scale_row, axis=-1, keepdims=True)          # (heads, C, 1): the gate's own cotangent
+    d_gate_logit = d_gate * gate * (1.0 - gate)
+    # o over its root mean square: the cotangent less its part along the normed o (a head's mean of d_y scale
+    # gate normed, which is the gate's cotangent times the gate over D), over the root
+    d_out = (inverse_rms * gate * (d_y * scale_row - normed * (d_gate * (1.0 / d)))).astype(dtype)
     # o = (Q e^A) S + queries u;  S' = e^{A_last} S + (K e^{A_last - A})^T u;  u = U' - W S
     d_u = _dots(queries_n, d_out, _TN) + _dots(decayed_k_n, d_after_n, _NT)
     d_u_n = d_u.astype(dtype)
@@ -625,63 +688,69 @@ def _bwd_kernel(q_ref, k_ref, v_ref, f_ref, beta_ref, rate_ref, bias_ref, states
     for ref, summed in ((dgate_ref, d_pre), (dgate_shifted_ref, d_pre * made["shifted"])):
         _write(ref, _by_head(ref, heads) + jnp.sum(summed, axis=1, keepdims=True))
     d_logit = d_beta * beta * (1.0 - beta)
-    head_lane = jax.lax.broadcasted_iota(jnp.int32, dbeta_ref.shape[2:], 1)
-    d_beta_all = jnp.zeros(dbeta_ref.shape[2:], F32)
+    head_lane = jax.lax.broadcasted_iota(jnp.int32, dlogits_ref.shape[2:], 1)
+    d_logits = jnp.zeros(dlogits_ref.shape[2:], F32)
     for h in range(heads):
-        d_beta_all = jnp.where(head_lane == h, d_logit[h], d_beta_all)
-    dbeta_ref[0, 0] = d_beta_all
+        d_logits = jnp.where(head_lane == h, d_logit[h], jnp.where(head_lane == heads + h, d_gate_logit[h], d_logits))
+    dlogits_ref[0, 0] = d_logits
 
 
-def _kda_call(q, k, v, f, beta, rate, bias, *kept, heads: int, keep_states: bool, lower_bound: float, eps: float,
-              interpret: bool):
-    """`kda_fwd` (no `kept`) -> [o (B, S, H D), the least cumulative log-decay
+def _kda_call(q, k, v, f, logits, rate, bias, scale, *kept, heads: int, keep_states: bool, lower_bound: float,
+              eps: float, norm_eps: float, interpret: bool):
+    """`kda_fwd` (no `kept`) -> [y (B, S, H D), the least cumulative log-decay
     a channel at a chunk's end (B, 1, H D) float32] and, with `keep_states`,
     the float32 states that entered the chunks, transposed (B, chunks, H, D_v,
-    D_k); or `kda_bwd` on `kept` = (those states, o's cotangent) -> [dq, dk,
-    dv, df, beta's logits' cotangent (B, H / heads, S, heads) float32, and the
-    sums over a sequence of the cotangent of rate (f + bias) and of it times f
-    + bias, a channel (B, 1, H D) float32 each]. q, k, v, f (B, S, H D) as
-    the mixer has them, beta's logits (B, S, H) float32, `rate` and `bias` (1,
-    1, H D) float32 a channel, `heads` the heads a grid step takes."""
+    D_k), and o (B, S, H D); or `kda_bwd` on `kept` = (those states, o, y's
+    cotangent) -> [dq, dk,
+    dv, df, the logits' cotangent (B, H / heads, S, 2 heads) float32 (a
+    step's heads' beta's, then their gates'), the sums over a sequence of the
+    cotangent of rate (f + bias) and of it times f + bias, a channel, and of
+    the norm's scale's gradient, a head's features (B, 1, H D) float32 each].
+    q, k, v, f (B, S, H D) as the mixer has them, the logits [beta | gate] (B,
+    S, 2 H) float32, `rate`, `bias` and the norm's `scale` a head over (1, 1,
+    H D) float32 a channel, `heads` the heads a grid step takes."""
     bsz, s, inner = q.shape
     chunks, width = s // CHUNK, heads * _LANES
     backward = bool(kept)
     of = (lambda n: chunks - 1 - n) if backward else (lambda n: n)
     wide = pl.BlockSpec((1, CHUNK, width), lambda b, g, n: (b, of(n), g))
-    logits = pl.BlockSpec((1, CHUNK, beta.shape[-1]), lambda b, g, n: (b, of(n), 0))
+    both = pl.BlockSpec((1, CHUNK, logits.shape[-1]), lambda b, g, n: (b, of(n), 0))
     channel = pl.BlockSpec((1, 1, width), lambda b, g, n: (0, 0, g))
     summed = pl.BlockSpec((1, 1, width), lambda b, g, n: (b, 0, g))         # stays over a sequence's chunks
-    column = pl.BlockSpec((1, 1, CHUNK, heads), lambda b, g, n: (b, g, of(n), 0))
+    column = pl.BlockSpec((1, 1, CHUNK, 2 * heads), lambda b, g, n: (b, g, of(n), 0))
     states = pl.BlockSpec((1, 1, heads, _LANES, _LANES), lambda b, g, n: (b, of(n), g, 0, 0))
     states_shape = jax.ShapeDtypeStruct((bsz, chunks, inner // _LANES, _LANES, _LANES), F32)
     summed_shape = jax.ShapeDtypeStruct((bsz, 1, inner), F32)
-    in_specs = [wide] * 4 + [logits, channel, channel]
+    in_specs = [wide] * 4 + [both, channel, channel, channel]
     if backward:
-        in_specs, out_specs = in_specs + [states, wide], [wide] * 4 + [column, summed, summed]
+        in_specs, out_specs = in_specs + [states, wide, wide], [wide] * 4 + [column] + [summed] * 3
         out_shape = [jax.ShapeDtypeStruct(t.shape, t.dtype) for t in (q, k, v, f)] + [
-            jax.ShapeDtypeStruct((bsz, inner // width, s, heads), F32), summed_shape, summed_shape]
+            jax.ShapeDtypeStruct((bsz, inner // width, s, 2 * heads), F32)] + [summed_shape] * 3
     else:
-        out_specs = [wide, summed] + [states] * keep_states
-        out_shape = [jax.ShapeDtypeStruct(v.shape, v.dtype), summed_shape] + [states_shape] * keep_states
+        out = jax.ShapeDtypeStruct(v.shape, v.dtype)
+        out_specs = [wide, summed] + [states, wide] * keep_states
+        out_shape = [out, summed_shape] + [states_shape, out] * keep_states
     return pl.pallas_call(
-        functools.partial(_bwd_kernel if backward else _fwd_kernel, lower_bound=lower_bound, eps=eps),
+        functools.partial(_bwd_kernel if backward else _fwd_kernel, lower_bound=lower_bound, eps=eps,
+                          norm_eps=norm_eps),
         grid=(bsz, inner // width, chunks), in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((heads, _LANES, _LANES), F32)],
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary"),
                                              vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret, name="kda_bwd" if backward else "kda_fwd",
-    )(q, k, v, f, beta, rate, bias, *kept)
+    )(q, k, v, f, logits, rate, bias, scale, *kept)
 
 
-def _kda_shapes(q, k, v, f, beta, rate, bias, *kept, heads, keep_states, lower_bound, eps, interpret):
-    del lower_bound, eps, interpret
+def _kda_shapes(q, k, v, f, logits, rate, bias, scale, *kept, heads, keep_states, lower_bound, eps, norm_eps,
+                interpret):
+    del lower_bound, eps, norm_eps, interpret
     bsz, s, inner = q.shape
     summed = rate.update(shape=(bsz, 1, inner), weak_type=False)
     if kept:
-        d_beta = rate.update(shape=(bsz, inner // (heads * _LANES), s, heads), weak_type=False)
-        return [t.update(weak_type=False) for t in (q, k, v, f)] + [d_beta, summed, summed]
+        d_logits = rate.update(shape=(bsz, inner // (heads * _LANES), s, 2 * heads), weak_type=False)
+        return [t.update(weak_type=False) for t in (q, k, v, f)] + [d_logits] + [summed] * 3
     states = rate.update(shape=(bsz, s // CHUNK, inner // _LANES, _LANES, _LANES), weak_type=False)
-    return [v.update(weak_type=False), summed] + [states] * keep_states
+    return [v.update(weak_type=False), summed] + [states, v.update(weak_type=False)] * keep_states
 
 
 # Every call site enters through ONE primitive whose lowering builds the kernel
@@ -694,46 +763,54 @@ kda_p.def_impl(lambda *args, **params: jax.jit(functools.partial(kda_p.bind, **p
 mlir.register_lowering(kda_p, mlir.lower_fun(_kda_call, multiple_results=True), inline=False)
 
 
-def _channel_rows(a_log, dt_bias, d: int):
-    """(exp(A_log) a head over its `d` channels, the gate's bias), float32 (1, 1, H d) each: the kernels' operands."""
-    return jnp.repeat(jnp.exp(a_log.astype(F32)), d).reshape(1, 1, -1), dt_bias.astype(F32).reshape(1, 1, -1)
+def _channel_rows(a_log, dt_bias, norm_scale):
+    """(exp(A_log) a head over its channels, the gate's bias, the norm's scale a head over), float32 (1, 1, H D)
+    each: the kernels' operands."""
+    heads = a_log.shape[0]
+    return (jnp.repeat(jnp.exp(a_log.astype(F32)), dt_bias.shape[0] // heads).reshape(1, 1, -1),
+            dt_bias.astype(F32).reshape(1, 1, -1), jnp.tile(norm_scale.astype(F32), heads).reshape(1, 1, -1))
 
 
-def _bind(q, k, v, f, beta, a_log, dt_bias, *kept, keep_states: bool, static):
+def _bind(q, k, v, f, logits, a_log, dt_bias, norm_scale, *kept, keep_states: bool, static):
     """The primitive on the mixer's arguments (and `kda_bwd`'s `kept`); `static`: its other parameters, as pairs."""
-    rows = _channel_rows(a_log, dt_bias, q.shape[-1] // a_log.shape[0])
-    return kda_p.bind(q, k, v, f, beta, *rows, *kept, keep_states=keep_states, **dict(static))
+    return kda_p.bind(q, k, v, f, logits, *_channel_rows(a_log, dt_bias, norm_scale), *kept,
+                      keep_states=keep_states, **dict(static))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
-def _kernels(q, k, v, f, beta, a_log, dt_bias, static):
-    """-> (o (B, S, H D), the least cumulative log-decay a channel at a chunk's end (B, 1, H D))."""
-    return tuple(_bind(q, k, v, f, beta, a_log, dt_bias, keep_states=False, static=static))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
+def _kernels(q, k, v, f, logits, a_log, dt_bias, norm_scale, static):
+    """-> (y (B, S, H D), the least cumulative log-decay a channel at a chunk's end (B, 1, H D))."""
+    return tuple(_bind(q, k, v, f, logits, a_log, dt_bias, norm_scale, keep_states=False, static=static))
 
 
 def _kernels_fwd(*arguments_and_static):
     *arguments, static = arguments_and_static
-    out, least, states = _bind(*arguments, keep_states=True, static=static)
-    # what a checkpoint around the caller may keep: with both, its backward
-    # pass starts from here and does not run `kda_fwd` a second time
+    out, least, states, o = _bind(*arguments, keep_states=True, static=static)
+    # what a checkpoint around the caller may keep: with the three, its
+    # backward pass starts from here and does not run `kda_fwd` a second time
+    # (y is what the caller's next matmul reads, o what `kda_bwd`'s norm does)
     out = checkpoint_name(out, "kda_chunk_out")
     states = checkpoint_name(states, "kda_chunk_states")
-    return (out, least), (*arguments, states)
+    return (out, least), (*arguments, states, checkpoint_name(o, "kda_chunk_o"))
 
 
 def _kernels_bwd(static, kept, cotangents):
-    *arguments, states = kept
-    a_log, dt_bias = arguments[5:]
+    *arguments, states, o = kept
+    a_log, dt_bias, norm_scale = arguments[5:]
     b, s, _ = arguments[0].shape
-    *d_arguments, d_beta, d_gate, d_gate_shifted = _bind(*arguments, states, cotangents[0], keep_states=False,
-                                                         static=static)
+    heads = a_log.shape[0]
+    *d_arguments, d_logits, d_gate, d_gate_shifted, d_scale = _bind(*arguments, states, o, cotangents[0],
+                                                                    keep_states=False, static=static)
     # rate (f + bias) with rate = exp(A_log) a head: the two parameters' gradients from the kernel's float32
-    # sums a channel, over the sequences here
+    # sums a channel, over the sequences here; the norm's scale's over the sequences and the heads
     rate = jnp.exp(a_log.astype(F32))
-    d_bias = jnp.sum(d_gate, axis=(0, 1)).reshape(a_log.shape[0], -1) * rate[:, None]
-    d_a_log = jnp.sum(jnp.sum(d_gate_shifted, axis=(0, 1)).reshape(a_log.shape[0], -1), axis=1) * rate
-    return (*d_arguments, jnp.transpose(d_beta, (0, 2, 1, 3)).reshape(b, s, -1),
-            d_a_log.astype(a_log.dtype), d_bias.reshape(dt_bias.shape).astype(dt_bias.dtype))
+    d_bias = jnp.sum(d_gate, axis=(0, 1)).reshape(heads, -1) * rate[:, None]
+    d_a_log = jnp.sum(jnp.sum(d_gate_shifted, axis=(0, 1)).reshape(heads, -1), axis=1) * rate
+    d_scale = jnp.sum(jnp.sum(d_scale, axis=(0, 1)).reshape(heads, -1), axis=0)
+    # (B, blocks, S, [beta | gate] x heads a step) -> (B, S, [beta | gate], H)
+    d_logits = jnp.transpose(d_logits.reshape(b, -1, s, 2, d_logits.shape[-1] // 2), (0, 2, 3, 1, 4))
+    return (*d_arguments, d_logits.reshape(b, s, 2 * heads), d_a_log.astype(a_log.dtype),
+            d_bias.reshape(dt_bias.shape).astype(dt_bias.dtype), d_scale.astype(norm_scale.dtype))
 
 
 _kernels.defvjp(_kernels_fwd, _kernels_bwd)
@@ -762,17 +839,35 @@ def rule_arguments(q: jax.Array, k: jax.Array, v: jax.Array, f: jax.Array, beta:
             v.reshape(b, s, heads, -1), log_decay, jax.nn.sigmoid(beta.astype(F32)))
 
 
-def kda_rule(q: jax.Array, k: jax.Array, v: jax.Array, f: jax.Array, beta: jax.Array, a_log: jax.Array,
-             dt_bias: jax.Array, *, eps: float, chunk: int = CHUNK, lower_bound: float = LOWER_BOUND,
-             implementation: Optional[str] = None) -> Tuple[jax.Array, jax.Array]:
+def gated_head_norm(out: jax.Array, gate: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
+    """The norm after the rule, in `jnp`: o (B, S, H, D) over its root mean
+    square a head (float32, `eps` inside the root) times `scale` (D,) times
+    the sigmoid of the head's ONE gate logit (B, S, H) float32, rounded once
+    to o's dtype -> y flat (B, S, H D). The kernels do the same where o
+    already is (`_kernel_gated_norm`)."""
+    b, s, heads, d = out.shape
+    y = rmsnorm(out.astype(F32), scale, eps=eps) * jax.nn.sigmoid(gate.astype(F32))[..., None]
+    return y.astype(out.dtype).reshape(b, s, heads * d)
+
+
+def kda_rule(q: jax.Array, k: jax.Array, v: jax.Array, f: jax.Array, beta_gate: jax.Array, a_log: jax.Array,
+             dt_bias: jax.Array, norm_scale: jax.Array, *, eps: float, norm_eps: float, chunk: int = CHUNK,
+             lower_bound: float = LOWER_BOUND, implementation: Optional[str] = None) -> Tuple[jax.Array, jax.Array]:
     """The delta rule from the mixer's arguments as the mixer has them
-    (`rule_arguments` says which and what is made of them): -> (o (B, S, H,
-    D) in v's dtype, the most negative cumulative log-decay any channel
-    reaches inside a chunk, a scalar with no gradient). Differentiable in all
-    seven. `lower_bound` is the gate's (the kernels' diagonal sub-blocks count
-    on it); `implementation` is `resolve_kda_impl`'s, for tests: the kernels
-    make the recurrence's arguments in VMEM, the XLA form in `jnp`
-    (`rule_arguments`, then `kda_chunk`)."""
+    (`rule_arguments` says which and what is made of them; `beta_gate` (B, S,
+    2 H) holds beta's logits then the norm's gates', as one small matmul
+    writes them), and the norm of its output a head under the head's gate
+    (`gated_head_norm`; `norm_scale` a head's D features, `norm_eps` inside
+    its root): -> (y (B, S, H D) in v's dtype, what the out-projection
+    reads, named `kda_chunk_out` (o, which the norm's transpose reads,
+    `kda_chunk_o`); the most negative cumulative log-decay any
+    channel reaches inside a chunk, a scalar with no gradient).
+    Differentiable in all eight. `lower_bound` is the gate's (the kernels'
+    diagonal sub-blocks count on it); `implementation` is
+    `resolve_kda_impl`'s, for tests: the kernels make the recurrence's
+    arguments in VMEM and norm the float32 o there, the XLA form does both
+    in `jnp` (`rule_arguments`, `kda_chunk`, then `gated_head_norm` on the
+    rounded o under the scope `kda.gate_norm`)."""
     b, s, inner = q.shape
     heads = a_log.shape[0]
     d = inner // heads
@@ -781,12 +876,16 @@ def kda_rule(q: jax.Array, k: jax.Array, v: jax.Array, f: jax.Array, beta: jax.A
         raise ValueError(f"kda_rule: a sequence of {s} is no multiple of the chunk {chunk}")
     if impl == "pallas":
         static = dict(heads=_heads_per_step(heads), lower_bound=float(lower_bound), eps=float(eps),
-                      interpret=jax.default_backend() != "tpu")
-        out, least = _kernels(q, k, v, f, beta.astype(F32), a_log, dt_bias, tuple(sorted(static.items())))
-        return out.reshape(b, s, heads, d), jax.lax.stop_gradient(jnp.min(least))
-    q, k, v, log_decay, beta = rule_arguments(q, k, v, f, beta, a_log, dt_bias, lower_bound=lower_bound, eps=eps)
-    return kda_chunk(q, k, v, log_decay, beta, chunk=chunk), jax.lax.stop_gradient(
-        log_decay_chunk_min(log_decay, chunk))
+                      norm_eps=float(norm_eps), interpret=jax.default_backend() != "tpu")
+        out, least = _kernels(q, k, v, f, beta_gate.astype(F32), a_log, dt_bias, norm_scale,
+                              tuple(sorted(static.items())))
+        return out, jax.lax.stop_gradient(jnp.min(least))
+    q, k, v, log_decay, beta = rule_arguments(q, k, v, f, beta_gate[..., :heads], a_log, dt_bias,
+                                              lower_bound=lower_bound, eps=eps)
+    out = kda_chunk(q, k, v, log_decay, beta, chunk=chunk)
+    with jax.named_scope("kda.gate_norm"):
+        out = checkpoint_name(gated_head_norm(out, beta_gate[..., heads:], norm_scale, norm_eps), "kda_chunk_out")
+    return out, jax.lax.stop_gradient(log_decay_chunk_min(log_decay, chunk))
 
 
 def kda_chunk(q: jax.Array, k: jax.Array, v: jax.Array, a: jax.Array, beta: jax.Array, *,

@@ -686,12 +686,14 @@ def roofline(cost: StepCost, step_time_s: float) -> Dict[str, Any]:
 # delta-rule mixer whole, and inside it `kda.in_proj` (norm and the two
 # projections), `kda.conv` (the convolutions of q, k and v and their silu),
 # `kda.chunk` (ops/kda.kda_rule: the chunked delta rule from q, k, v, the
-# gate's input and beta's logits as the mixer has them; on a TPU its two
-# kernels, which make the L2 norms, the log-decay and beta in VMEM, the copy
-# of what a checkpoint keeps of them, and the few small operations that
-# prepare their rows and finish the gate's two gradients; in the XLA form
-# all of that as operations), `kda.gate_norm` (the norm a head and its gate)
-# and `kda.out_proj`.
+# gate's input and the two logits a head as the mixer has them, through the
+# norm a head under the head's gate; on a TPU its two kernels, which make the
+# L2 norms, the log-decay and beta in VMEM and norm o where they have it, the
+# copy of what a checkpoint keeps of them, and the few small operations that
+# prepare their rows and finish the three parameters' gradients; in the XLA
+# form all of that as operations), `kda.gate_norm` (INSIDE `kda.chunk`, in the
+# XLA form alone: the norm a head and its gate as operations; a program whose
+# kernels do the norm holds nothing under it) and `kda.out_proj`.
 STEP_SCOPES: Tuple[str, ...] = (
     "steplog.fwd_bwd_compute", "steplog.optimizer_update",
     "embed",

@@ -170,19 +170,21 @@ def test_the_step_reports_the_delta_rule_the_latent_layer_and_the_groups(stack):
     assert said["layer_kinds"] == "dK eK eK eK eL eK eK"
     assert {name: said[name] for name in (
         "kda_heads", "kda_head_dim", "kda_chunk", "kda_subchunk", "kda_impl", "kda_kernels",
-        "kda_heads_per_step", "kda_state_bytes", "kda_conv_impl",
+        "kda_heads_per_step", "kda_state_bytes", "kda_prologue", "kda_epilogue", "kda_conv_impl",
         "kda_gate_lower_bound", "attn_latent_v_dim", "attn_latent_q_rank", "moe_route_groups",
         "moe_route_groups_kept")} == {
         "kda_heads": 4, "kda_head_dim": 16, "kda_chunk": 32, "kda_subchunk": 16, "kda_impl": "xla_chunked",
-        "kda_kernels": 0, "kda_heads_per_step": 0, "kda_state_bytes": 0, "kda_conv_impl": "xla",
+        "kda_kernels": 0, "kda_heads_per_step": 0, "kda_state_bytes": 0, "kda_prologue": "xla",
+        "kda_epilogue": "xla", "kda_conv_impl": "xla",
         "kda_gate_lower_bound": -5.0, "attn_latent_v_dim": 16,
         "attn_latent_q_rank": 0, "moe_route_groups": 4, "moe_route_groups_kept": 2}
     costs = model_family(config).block_costs(config, 128)
     named = {name for candidate in costs["candidates"] for name in candidate.names}
-    assert {"kda_chunk_out", "kda_chunk_states", "attn_out", "attn_lse", "attn_latent_kv",
+    assert {"kda_chunk_out", "kda_chunk_states", "kda_chunk_o", "attn_out", "attn_lse", "attn_latent_kv",
             "attn_latent_k_rope", "mlp_up", "mlp_gate"} <= named and "attn_latent_q" not in named
-    (rule,) = [c for c in costs["candidates"] if c.names == ("kda_chunk_out", "kda_chunk_states")]
-    assert rule.layers == (1, 5) and rule.width == 64 + 64 * 16 * 4 // (32 * 4)
+    (rule,) = [c for c in costs["candidates"] if c.names == ("kda_chunk_out", "kda_chunk_states", "kda_chunk_o")]
+    # y as the out-projection reads it, o as the norm's transpose does, and the chunks' float32 states
+    assert rule.layers == (1, 5) and rule.width == 2 * 64 + 64 * 16 * 4 // (32 * 4)
     # an older latent stack says nothing new but the groups it does not have
     from test_latent_attention import tiny_latent
 
@@ -196,7 +198,7 @@ def test_keeping_the_rules_output_and_states_changes_no_gradient(stack):
     loss = functools.partial(lm_loss, config=config)
     whole = jax.jit(jax.grad(lambda p: loss(p, tokens)[0]))(params)
     kept = jax.jit(jax.grad(lambda p: loss(p, tokens, remat_saved=(
-        "kda_chunk_out", "kda_chunk_states", "attn_out", "attn_lse"))[0]))(params)
+        "kda_chunk_out", "kda_chunk_states", "kda_chunk_o", "attn_out", "attn_lse"))[0]))(params)
     for a, b in zip(jax.tree.leaves(whole), jax.tree.leaves(kept)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
 

@@ -763,10 +763,13 @@ def test_the_scan_candidate_is_priced_by_the_form_that_runs(monkeypatch, backend
                          ids=["xla-chunked", "kernels", "a-gate-the-kernels-do-not-tile"])
 def test_the_delta_rule_candidate_is_priced_by_the_form_that_runs(monkeypatch, backend, bound, share):
     """At the published sizes (32 heads of 128, chunk 64) in bfloat16 either
-    form keeps the output and the float32 state that entered every chunk
-    (128 x 128 x 4 B a head over 64 tokens, 32 KB a token: 16,384 features of
-    two bytes beside the output's 4,096), and the rule they spare counts at the measured share of
-    the peak of the form that runs (`ops/kda.resolve_kda_impl`)."""
+    form keeps the gated, normed output, the rule's own o that the norm's
+    transpose reads and the float32 state that entered
+    every chunk (128 x 128 x 4 B a head over 64 tokens, 32 KB a token: 16,384
+    features of two bytes beside the two outputs' 4,096 each), and
+    the rule they spare, with the norm's arithmetic since PR 63, counts at the
+    measured share of the peak of the form that runs
+    (`ops/kda.resolve_kda_impl`)."""
     from test_ling3flash_model import tiny_ling
 
     from ray_tpu.models.mixed_stack import _kda_costs
@@ -776,11 +779,10 @@ def test_the_delta_rule_candidate_is_priced_by_the_form_that_runs(monkeypatch, b
     candidate = _kda_costs(config, True)["candidates"][0]
     rule = 32 * (2 * 64 * (4 * 128 + 64) + 10 * 128 * 128)
     assert (candidate.names, candidate.width, candidate.flops, candidate.worth) == (
-        ("kda_chunk_out", "kda_chunk_states"), 4096 + 16384, rule, int(rule / share))
+        ("kda_chunk_out", "kda_chunk_states", "kda_chunk_o"), 2 * 4096 + 16384, rule + 8 * 4096, int(rule / share))
     # the stream after the out-projection is a candidate only where a sublayer follows the mixer (PR 60)
     assert [[c.names[0] for c in _kda_costs(config, follows)["candidates"]] for follows in (True, False)] == [
-        ["kda_chunk_out", "kda_in_proj", "kda_gate_norm_out", "kda_residual"],
-        ["kda_chunk_out", "kda_in_proj", "kda_gate_norm_out"]]
+        ["kda_chunk_out", "kda_in_proj", "kda_residual"], ["kda_chunk_out", "kda_in_proj"]]
 
 
 def test_the_three_shipped_mixed_stack_cells_keep_their_kinds_runs_and_leaves():

@@ -3,8 +3,9 @@ delta-rule mixer beside its scan's (its rule's) output: the in-projection's
 output (models/mixed_stack: `ssm_in_proj`, `kda_in_proj`). Kept, it changes no
 loss and no gradient; at the two cells' published widths `block_costs` lists
 it and the rule (train/lm.auto_remat_saved) takes it on a v5e beside what it
-kept before. (The convolution's and the gated norm's outputs lost on the chip
-and are not named: PERF.md section 6, PR 57.) Since PR 60 also what it may
+kept before. (The convolution's output lost on the chip and is not named:
+PERF.md section 6, PR 57; the gated norm's is the rule's own output since
+PR 63, `kda_chunk_out`, beside the o its transpose reads, `kda_chunk_o`.) Since PR 60 also what it may
 keep of an expert layer and of the delta-rule mixer's tail, at the five
 expert cells' published widths (tests/test_moe_remat.py holds the values
 themselves on a tiny stack)."""
@@ -40,7 +41,7 @@ def _ssm_stack():
 def _kda_stack():
     """`2 x (dK) | eK`: a delta-rule mixer in a scanned run and in an unrolled one."""
     config = tiny_ling(first_layer=0, n_layers=3)
-    return config, gated(config, 3), ("kda_in_proj", "kda_chunk_out", "kda_chunk_states")
+    return config, gated(config, 3), ("kda_in_proj", "kda_chunk_out", "kda_chunk_states", "kda_chunk_o")
 
 
 @pytest.mark.parametrize("stack", [_ssm_stack, _kda_stack], ids=["state-space", "delta-rule"])
@@ -76,7 +77,7 @@ CELLS = {
                       {"attn_out", "attn_lse", "attn_residual", "ssm_scan_out", "ssm_chunk_states"}),
     "ling3flash": ("ling-3.0-flash-train-1chip", 1, 4096, {"kda_in_proj": 16384},
                    {"attn_residual", "attn_out", "attn_lse", "mlp_up", "mlp_gate", "attn_latent_kv",
-                    "attn_latent_k_rope", "kda_chunk_out", "kda_chunk_states"}),
+                    "attn_latent_k_rope", "kda_chunk_out", "kda_chunk_states", "kda_chunk_o"}),
 }
 # PR 60's candidates at the five expert cells' widths: (the configuration's file, a step's batch and sequence,
 # {first name: (features a row, the layers that write it a run of the stack)}, what the rule keeps on a v5e).
@@ -86,10 +87,9 @@ CELLS = {
 EXPERT_CELLS = {
     "ling3flash": ("ling-3.0-flash-train-1chip", 1, 4096,
                    {"moe_routing": ((4 * 512 + 12 * 8) // 2, (0, 6)), "moe_shared_gate": (768, (0, 6)),
-                    "moe_shared_up": (768, (0, 6)), "kda_residual": (2560, (1, 5)),
-                    "kda_gate_norm_out": (4096, (1, 5))},
+                    "moe_shared_up": (768, (0, 6)), "kda_residual": (2560, (1, 5))},
                    CELLS["ling3flash"][4] | {"kda_in_proj", "moe_routing", "moe_shared_gate", "moe_shared_up",
-                                             "kda_residual", "kda_gate_norm_out"}),
+                                             "kda_residual"}),
     "glm47flash": ("glm-4.7-flash-train-1chip", 2, 8192,
                    {"moe_routing": ((4 * 64 + 12 * 4) // 2, (0, 4, 1)), "moe_shared_gate": (1536, (0, 4, 1)),
                     "moe_shared_up": (1536, (0, 4, 1))},
@@ -197,8 +197,8 @@ def test_the_expert_cells_widths_list_the_routing_the_shared_expert_and_the_mixe
     for name, (width, layers) in named.items():
         assert (by_name[name].names, by_name[name].width, by_name[name].layers, by_name[name].tp_sum) == (
             (name,), width, layers, False)
-    new = {"moe_routing", "moe_shared_gate", "moe_shared_up", "kda_residual", "kda_gate_norm_out"}
-    assert new & set(by_name) == set(named)
+    new = {"moe_routing", "moe_shared_gate", "moe_shared_up", "kda_residual"}
+    assert new & set(by_name) == set(named) and "kda_gate_norm_out" not in by_name
     # a run's expert layers, as the forward walks the stack, then the module's block
     runs = mixed_stack.stack_runs(mixed_stack.layer_kinds(config))
     experts = tuple(run.repeats * sum(kind.mlp == "experts" for kind in run.kinds) for run in runs)
@@ -212,7 +212,12 @@ def test_the_expert_cells_widths_list_the_routing_the_shared_expert_and_the_mixe
         assert by_name[name].flops == by_name[name].worth == 2 * config.d_model * config.shared_expert_width
     if "kda_residual" in named:
         mixers = tuple(run.repeats * sum(kind.attention == "kda" for kind in run.kinds) for run in runs)
-        assert by_name["kda_residual"].layers == by_name["kda_gate_norm_out"].layers == mixers
+        assert by_name["kda_residual"].layers == by_name["kda_chunk_out"].layers == mixers
+        # the kernels' y as the out-projection reads it and their o as the norm's transpose does (PR 63: what
+        # the rule's and the norm's candidates held, as one) with the chunks' float32 states, 128 x 128 x 4 B a
+        # head over 64 tokens
+        assert by_name["kda_chunk_out"].names == ("kda_chunk_out", "kda_chunk_states", "kda_chunk_o")
+        assert by_name["kda_chunk_out"].width == 2 * 4096 + 32 * 128 * 128 * 4 // (64 * 2)
         assert by_name["kda_residual"].worth == 2 * 4096 * config.d_model
     kept = set(plan["remat_saved"])
     assert plan["remat"] == "selective" and kept == want

@@ -2,8 +2,11 @@
 mixer's tail (PR 60): the routing under ONE name (`moe.ROUTING`: the router's
 float32 logits, the chosen experts, their scores, the sorted rows' order and
 each held expert's end), the shared expert's gate and up, the stream after
-the mixer's out-projection (`kda_residual`) and the gated norm's output
-(`kda_gate_norm_out`). Kept, a group changes no loss and no gradient, and
+the mixer's out-projection (`kda_residual`) and, since PR 63 made the norm a
+head under the head's gate the rule's own last step, the rule's output as
+the out-projection reads it with the chunks' states and o (`kda_chunk_out`,
+`kda_chunk_states`, `kda_chunk_o`: there is no `kda_gate_norm_out` any more). Kept, a group
+changes no loss and no gradient, and
 what it stands for is gone from the backward pass's recomputation. (What
 `block_costs` lists at the five cells' published widths and what the rule
 keeps of it on a v5e: tests/test_mixer_remat.py. Six cases of ~20 s: the file
@@ -24,9 +27,10 @@ from ray_tpu.train.lm import lm_loss
 from test_ling3flash_model import gated, tiny_ling  # noqa: E402
 
 # what the rule could keep of this stack before PR 60
-BEFORE = ("kda_in_proj", "kda_chunk_out", "kda_chunk_states", "attn_out", "attn_lse", "attn_residual",
+BEFORE = ("kda_in_proj", "kda_chunk_out", "kda_chunk_states", "kda_chunk_o", "attn_out", "attn_lse", "attn_residual",
           "attn_latent_kv", "attn_latent_k_rope")
 SHARED = ("moe_shared_gate", "moe_shared_up")
+RULE = ("kda_chunk_out", "kda_chunk_states", "kda_chunk_o")
 # (the names kept, the routing, the operations of the lowered step that the backward pass loses with them: a
 # layer BODY counts once, the scan's `eK eL` and the unrolled `eK`: three expert layers, two delta-rule mixers)
 GROUPS = {
@@ -37,10 +41,10 @@ GROUPS = {
     "routing-second-pass": ((moe.ROUTING,), "every-choice", {"dot_general": 3, "top_k": 9, "sort": 3}),
     "shared-expert": (SHARED, "as-routed", {"dot_general": 6}),
     "kda-residual": (("kda_residual",), "as-routed", {"dot_general": 2}),
-    # the product with the gate alone: the norm's own values are what the gate's derivative reads
-    "kda-gate-norm": (("kda_gate_norm_out",), "as-routed", {"multiply": 2}),
-    "all": ((moe.ROUTING, *SHARED, "kda_residual", "kda_gate_norm_out"), "every-choice",
-            {"dot_general": 11, "top_k": 9, "sort": 3}),
+    # the rule's gated, normed output with the states that entered its chunks and o, beside all else BEFORE had:
+    # the walk over the chunks and every decay of the two mixer bodies leave the recomputed pass
+    "kda-rule-and-norm": (RULE, "as-routed", {"while": 2, "exponential": 16}),
+    "all": ((moe.ROUTING, *SHARED, "kda_residual"), "every-choice", {"dot_general": 11, "top_k": 9, "sort": 3}),
 }
 
 
@@ -74,7 +78,9 @@ def test_keeping_a_group_changes_neither_loss_nor_gradients_and_spares_its_recom
     def value_and_gradients(saved):
         return jax.jit(jax.value_and_grad(lambda p: loss(p, tokens, remat_saved=saved), has_aux=True))
 
-    before, kept = value_and_gradients(BEFORE), value_and_gradients(BEFORE + names)
+    # a group that BEFORE holds already is kept against BEFORE without it
+    without = tuple(name for name in BEFORE if name not in names)
+    before, kept = value_and_gradients(without), value_and_gradients(without + names)
     ((before_loss, scalars), before_grads), ((kept_loss, _), kept_grads) = before(params), kept(params)
     assert float(scalars["moe_passes"]) == (2 if routing == "every-choice" else 1)
     assert float(before_loss) == float(kept_loss)

@@ -5,7 +5,9 @@ parameter tree (every leaf's path, shape and dtype), its logical axes and the
 plan the trainer reports, against tests/data/shipped_stacks.json, which was
 recorded on PR 61's PARENT commit by the function below (the new cell's own
 configuration is held by tests/benchmark/test_lfm2moe_cell.py). A change that
-means to move one records it again and says so."""
+means to move one records it again and says so: PR 63 added the key
+`kda_epilogue` ("xla" off a TPU) to `train-ling3flash-4k`'s plan, beside the
+`kda_prologue` it reports, and nothing else of the record moved."""
 
 import hashlib
 import json

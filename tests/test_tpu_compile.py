@@ -430,17 +430,18 @@ def test_kda_kernels_compile_within_the_scoped_vmem_they_ask_for(as_tpu, batch, 
     assert (plan["kda_impl"], plan["kda_kernels"], plan["kda_heads_per_step"], plan["kda_state_bytes"]) == (
         "pallas", 2, per_step, per_step * 128 * 128 * 4)
     flat = (batch, seq, heads * 128)
-    # the mixer's own arguments: q, k, v and the gate's input flat, beta's logits, `A_log` a head, the bias a channel
+    # the mixer's own arguments: q, k, v and the gate's input flat, the two logits a head [beta | gate], `A_log` a
+    # head, the bias a channel, the norm's scale a head's features
     args = (_on(as_tpu, flat), _on(as_tpu, flat), _on(as_tpu, flat), _on(as_tpu, flat),
-            _on(as_tpu, (batch, seq, heads), jnp.float32), _on(as_tpu, (heads,), jnp.float32),
-            _on(as_tpu, (heads * 128,), jnp.float32))
-    rule = lambda *a: kda.kda_rule(*a, eps=1e-6)[0]       # noqa: E731
+            _on(as_tpu, (batch, seq, 2 * heads), jnp.float32), _on(as_tpu, (heads,), jnp.float32),
+            _on(as_tpu, (heads * 128,), jnp.float32), _on(as_tpu, (128,), jnp.float32))
+    rule = lambda *a: kda.kda_rule(*a, eps=1e-6, norm_eps=1e-6)[0]       # noqa: E731
     forward = jax.jit(rule).lower(*args)
     # the scoped VMEM the call asks Mosaic for, as the lowered call carries it
     assert f"\\22size\\22: {kda._VMEM_LIMIT}}}]" in forward.as_text()
     assert _kernel_calls(forward.compile()) == 1
     compiled = jax.jit(jax.grad(lambda *a: jnp.sum(rule(*a).astype(jnp.float32) ** 2),
-                                argnums=tuple(range(7)))).lower(*args).compile()
+                                argnums=tuple(range(8)))).lower(*args).compile()
     assert _kernel_calls(compiled) == 2
     for kernel in ("kda_fwd", "kda_bwd"):
         assert len(re.findall(rf"^\s*%\w*{kernel}[\w.]* = .*custom-call\(", compiled.as_text(), re.M)) == 1, kernel
