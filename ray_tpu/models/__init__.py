@@ -49,9 +49,9 @@ class ModelFamily(NamedTuple):
     # `moe_load_max_over_mean`; {} for a dense model). `remat_saved` names what
     # a model with `config.remat` keeps of each block across the forward pass
     forward_hidden: Callable
-    # (config, S, split) -> what the stack's blocks cost a token and which of
-    # their values a checkpoint may keep (transformer.block_costs); None: a
-    # family whose blocks name none, and are recomputed whole
+    # (config, S, split, a device's tokens a step) -> what the stack's blocks cost a
+    # token and which of their values a checkpoint may keep (transformer.block_costs);
+    # None: a family whose blocks name none, and are recomputed whole
     block_costs: Optional[Callable]
     # (config, batch, seq of a step) -> what the family's own layers resolve to, for
     # callers that report it (LMTrainer's `train.init.step_fn` span)

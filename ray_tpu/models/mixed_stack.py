@@ -29,8 +29,9 @@ every block is recomputed in the backward pass but for what the step keeps of
 it (`block_costs` names what it may: the attention kernel's output and lse and
 the residual after the output projection in every layer, a latent layer's
 latents, gate and up in the dense ones, a state-space or delta-rule mixer's
-in-projection and its scan's or rule's output with the chunks' states; an
-expert layer's MLP is recomputed whole).
+in-projection and its scan's or rule's output with the chunks' states; of an
+expert layer its routing, the shared expert's gate and up and the held
+experts' buffer as the first pass wrote it).
 
 A configuration with `mtp_modules` has, beside the stack, one multi-token
 prediction module (`params["mtp"]`, `mtp_hidden`): the stack's output and
@@ -84,8 +85,19 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import kda, rope_frequencies, short_conv, ssd
 from ..ops.attention import attention_plan
-from .moe import _HELD_BUFFER_SHARES, ROUTING, MoEConfig, load_max_over_mean, moe_mlp, moe_plan
+from ..ops.grouped_matmul import gmm_tile_rows
+from .moe import (
+    _HELD_BUFFER_SHARES,
+    ROUTING,
+    MoEConfig,
+    held_buffer_names,
+    held_buffer_rows,
+    load_max_over_mean,
+    moe_mlp,
+    moe_plan,
+)
 from .transformer import (
+    _KEPT_KERNEL_FLOPS_PER_BYTE,
     Params,
     RematCandidate,
     StackRun,
@@ -843,9 +855,13 @@ _ROUTER_MATMUL_PASSES = 6
 # `train-trinity-mini-8k` and `train-glm47flash-8k` (128 and 64 scores, no
 # groups) `moe.select` recomputed is 5.1 and 3.4 ms a step: 120 k and 128 k
 _ROUTING_SELECT_FLOPS_PER_SCORE = 100_000
+# The held experts' grouped matmuls' share of the chip's peak, by which the
+# FLOPs a kept buffer spares count for their time (`moe_held_gmm_roofline`
+# 77.4 on `train-lfm2moe-8k`: ledger, PR 63)
+_HELD_GMM_SHARE_OF_PEAK = 0.77
 
 
-def _expert_costs(config: MixedStackConfig, split: Callable[[str], int]) -> Dict[str, Any]:
+def _expert_costs(config: MixedStackConfig, split: Callable[[str], int], tokens: int) -> Dict[str, Any]:
     """An expert layer's MLP as `transformer.mlp_costs` gives a dense one's,
     a row (a token) and device at an even routing: the router, the shared
     expert, the held experts' grouped matmuls on the rows sent here (three
@@ -860,15 +876,41 @@ def _expert_costs(config: MixedStackConfig, split: Callable[[str], int]) -> Dict
     the sort; 8.2 ms for 53 MB, the first loss the same to the bit. And the
     shared expert's gate and up (up alone where it is not gated), a matmul's
     output each and worth it as a dense layer's are (`transformer.mlp_costs`):
-    0.6 ms for 75 MB there. NOT named: the held buffer (the gathered rows and
-    the experts' gate and up). Forced, it took 1.35 ms off that step alone and
-    0.9 ms beside the others for 100 MB by its shapes and 146 MB on the chip:
-    a Pallas kernel writes each of the three, so every kept one is a copy,
-    and most of the buffer's second pass stays (the slots' tables, the down
-    projection's operand). Its width is not a function of what `block_costs`
-    is given either (the grouped matmuls' tile pads every held expert's rows:
-    half of that cell's slots), and on the cells with 36,864-row buffers it
-    is 0.2-0.3 GB a layer."""
+    0.6 ms for 75 MB there. And, of a layer that holds a part of the experts,
+    the buffer its first pass wrote (`moe.held_buffer_names`: the gathered
+    rows, the experts' gate and up, the down projection's output and the
+    slots' tables; a later pass is computed again whatever is kept). Forced on
+    the chip of `train-lfm2moe-8k`, each part with the tables beside what the
+    cell kept (PERF.md section 6, PR 67; the refused PR 66's builder made
+    the sweep: a step of 271.0 ms, 34,816 slots of which 32,768 hold rows at
+    twice the even share, four layers): the tables alone 1.4 ms for 2.4 MB,
+    with the gathered rows 4.3 for 0.57 GB, with gate and up 6.3 for 1.00 GB,
+    with the output 4.8 for 0.57 GB, gate, up and the output 7.8, the rows,
+    gate and up 7.4, ALL 10.3 ms for 2.14 GB (the peak 70.3 -> 78.5% of the
+    chip): every part pays beside every other, so they are ONE candidate.
+    With it the backward pass runs none of the layer's 12
+    forward grouped matmuls again (9.6 ms), no gather, search or table, and
+    what the recomputed pass keeps of the layer is the norm, the weights'
+    casts and the activation (an elementwise pass from gate and up); a Pallas
+    kernel writes gate, up and the output, so each kept one is a copy in the
+    forward pass (4.3 ms of the 14.6 spared). Its `width` is the slots', the
+    grouped matmuls' tile a held expert included, over the device's `tokens`;
+    its `worth` the experts' matmuls at an even routing at the kernels' share
+    of the peak less a copy in and out of every byte a kernel wrote
+    (`transformer._KEPT_KERNEL_FLOPS_PER_BYTE`). The gather, the tables and
+    the combine it spares with them are NOT priced (as the row-wise work of
+    an attention layer's operands is not, `transformer.attention_costs`):
+    priced, the candidate would stand before the attention kernel's output on
+    the cells whose chip is full and push it out. So the worth is low, and
+    right in its sign where the matmuls are: on `train-ling3flash-4k` 3,072
+    slots, two thirds of them the tiles' padding, stand for 512 rows (~64 an
+    expert), the copies of 6.1 KB a token in and out outweigh 1.5 MFLOP of
+    matmuls that take microseconds, the worth is negative and the rule never
+    tries it (forced there by PR 60, the rows, gate and up: 0.9 ms of a 215 ms
+    step for 146 MB; by PR 66's builder, all of it: 1.7 ms of 202.3 for 0.22
+    GB, what the formula leaves unpriced and a third of what that cell's line
+    resolves); on the cells with 36,864-row buffers it is 0.2-0.45 GB a layer,
+    positive, and refused for room."""
     c = config
     shared = c.shared_expert_width // split("ws_up") if c.shared_expert_width else 0
     d_ff = c.d_ff // split("we_up")
@@ -878,8 +920,20 @@ def _expert_costs(config: MixedStackConfig, split: Callable[[str], int]) -> Dict
     router, shared_matmul = 2 * c.d_model * c.n_experts, 2 * c.d_model * shared
     # float32 logits; the experts, their scores and (held: the sorted rows' order) 4 bytes a choice
     routing = 4 * (c.n_experts + (2 if c.held_experts is None else 3) * c.top_k) // itemsize
+    experts_matmuls = 2 * c.d_model * matrices * rows_here * d_ff
+    buffer = ()
+    if c.held_experts is not None:
+        # the first pass's slots (moe._held_experts), the grouped matmuls' tile a held expert among them, over
+        # this device's tokens: a slot's gathered row and output, its (gate and) up, its row and gate 4 bytes each
+        tile = gmm_tile_rows()
+        slots = held_buffer_rows(c, tokens, tile) + c.n_experts_held * tile
+        written = c.d_model + (matrices - 1) * d_ff         # by a kernel: each kept one is copied in and out
+        buffer = (RematCandidate(
+            held_buffer_names(c), -(-slots * (c.d_model + written + 8 // itemsize) // tokens), int(experts_matmuls),
+            int(experts_matmuls / _HELD_GMM_SHARE_OF_PEAK
+                - 2 * slots * written * itemsize / tokens * _KEPT_KERNEL_FLOPS_PER_BYTE), False, ()),)
     return {
-        "flops": int(router + matrices * shared_matmul + 2 * c.d_model * matrices * rows_here * d_ff),
+        "flops": int(router + matrices * shared_matmul + experts_matmuls),
         # both norms' outputs, the layer's, the residual; the router's float32
         # scores; the shared expert's (gate,) up and activation; a buffer row's
         # input, (gate,) up, activation and output
@@ -889,7 +943,8 @@ def _expert_costs(config: MixedStackConfig, split: Callable[[str], int]) -> Dict
             RematCandidate((ROUTING,), routing, router,
                            _ROUTER_MATMUL_PASSES * router + _ROUTING_SELECT_FLOPS_PER_SCORE * c.n_experts, False, ()),
             *(RematCandidate((name.replace("we_", "moe_shared_"),), shared, shared_matmul, shared_matmul, False, ())
-              for name in c.expert_weights[:-1] if shared)),
+              for name in c.expert_weights[:-1] if shared),
+            *buffer),
     }
 
 
@@ -1074,14 +1129,17 @@ def _sconv_costs(config: MixedStackConfig, mlp_follows: bool) -> Dict[str, Any]:
 
 def block_costs(
     config: MixedStackConfig, seq: int, split: Callable[[str], int] = lambda weight: 1,
+    tokens: Optional[int] = None,
 ) -> Dict[str, Any]:
     """`transformer.block_costs` for a stack of mixed layers: its runs as
     `forward_hidden` walks them (one with repeats is a scan), each with the
     costs of the kinds of layer it has. A candidate counts the layers of the
     kinds that write it: the attention output and the residual after it every
     layer (a windowed layer's scores are cheaper than a full one's), gate and
-    up the dense layers, the routing and the shared expert's projections the
-    expert layers (the multi-token prediction module's block is one more)."""
+    up the dense layers, the routing, the shared expert's projections and the
+    held experts' buffer the expert layers (the multi-token prediction
+    module's block is one more). `tokens`: a device's tokens a step, which a
+    held layer's buffer is sized from (None: one sequence's)."""
     c = config
 
     def kind_costs(kind: LayerKind):
@@ -1091,7 +1149,7 @@ def block_costs(
                  else _NO_SUBLAYER if kind.attention == "none"
                  else attention_costs(c, seq, split, c.sliding_window if kind.attention == "sliding" else None))
         mlp = (mlp_costs(c, split, c.d_ff_dense) if kind.mlp == "dense"
-               else _expert_costs(c, split) if kind.mlp == "experts" else _NO_SUBLAYER)
+               else _expert_costs(c, split, tokens or seq) if kind.mlp == "experts" else _NO_SUBLAYER)
         return mixer, mlp
 
     runs = [

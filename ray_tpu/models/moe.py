@@ -42,7 +42,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ..ops import rope_frequencies, swiglu
-from ..ops.grouped_matmul import gmm_tile_rows, grouped_matmul, resolve_gmm_impl
+from ..ops.grouped_matmul import TILES, gmm_tile_rows, grouped_matmul, resolve_gmm_impl
 from ..ops.moe_rows_sum import rows_sum, rows_sum_tile, take_token_rows, token_tile_bounds
 from ..parallel.mesh import DATA_AXES
 from .transformer import (
@@ -378,15 +378,16 @@ def _expert_act(config: "MoEConfig"):
 
 
 def _expert(config: "MoEConfig", project, weights):
-    """One routed expert unit of either form: `project(w)` is the input
-    through the up (or gate) matrix `w`, and the hidden units go through the
-    last of `weights` by the caller. -> (the hidden units, the gate's
-    projection or None where the unit is not gated)."""
+    """One routed expert unit of either form: `project(w, name)` is the input
+    through the up (or gate) matrix `w`, `name` the buffer's name for that
+    output, and the hidden units go through the last of `weights` by the
+    caller. -> (the hidden units, the gate's projection or None where the unit
+    is not gated)."""
     act = _expert_act(config)
     if len(weights) == 2:
-        return act(project(weights[0])), None
-    gate = project(weights[0])
-    return act(gate, project(weights[1])), gate
+        return act(project(weights[0], BUFFER_UP)), None
+    gate = project(weights[0], BUFFER_GATE)
+    return act(gate, project(weights[1], BUFFER_UP)), gate
 
 
 # What the layer's backward pass reads of its routing, under ONE `checkpoint_name`
@@ -397,6 +398,26 @@ def _expert(config: "MoEConfig", project, weights):
 # backward pass takes of the routing is read off those, so with them kept the
 # pass neither multiplies by the router again nor selects nor sorts
 ROUTING = "moe_routing"
+# What the backward pass reads of the FIRST pass through a held layer's buffer
+# (`_held_experts`; a later pass is computed again from its inputs, whatever is
+# kept), each under its own `checkpoint_name` and all of them ONE candidate of
+# a recomputing step (models/mixed_stack._expert_costs): the gathered rows
+# (the grouped matmuls' left operand for d(weights)), the gate's and the up
+# projection's outputs (the activation is an elementwise pass from them), the
+# down projection's output (which the gates' cotangent reads) and the slots'
+# tables, a few 4-byte words a slot: each slot's row and float32 gate, each
+# expert's padded size, the row-sum kernel's bounds (and the grouped matmuls'
+# own, `grouped_matmul.TILES`). With all of them kept the backward pass runs no
+# grouped matmul of the forward again, gathers no row and rebuilds no table
+BUFFER_IN, BUFFER_GATE, BUFFER_UP, BUFFER_OUT, BUFFER_SLOTS = (
+    "moe_buffer_in", "moe_buffer_gate", "moe_buffer_up", "moe_buffer_out", "moe_buffer_slots")
+
+
+def held_buffer_names(config: "MoEConfig") -> Tuple[str, ...]:
+    """The names a held layer of this configuration writes: no gate's where
+    the expert's unit is not gated."""
+    gate = (BUFFER_GATE,) if "we_gate" in config.expert_weights else ()
+    return (BUFFER_IN, *gate, BUFFER_UP, BUFFER_OUT, BUFFER_SLOTS, TILES)
 
 
 @functools.partial(jax.custom_jvp, nondiff_argnums=(1,))
@@ -471,7 +492,7 @@ def _gshard_experts(h, probs, weights, config):
     # dispatch: (B,S,E,C) x (B,S,M) -> (E,B,C,M); XLA turns the e-sharded
     # contraction into the all-to-all over the ep axis
     expert_in = jnp.einsum("bsec,bsm->ebcm", dispatch.astype(dt), h)
-    act, _ = _expert(c, lambda w: jnp.einsum("ebcm,emf->ebcf", expert_in, w), weights)
+    act, _ = _expert(c, lambda w, _: jnp.einsum("ebcm,emf->ebcf", expert_in, w), weights)
     expert_out = jnp.einsum("ebcf,efm->ebcm", act, weights[-1])
     out = jnp.einsum("ebcm,bsec->bsm", expert_out, combine.astype(dt))
     return out, jnp.sum(dispatch, axis=(0, 1, 3))
@@ -479,16 +500,17 @@ def _gshard_experts(h, probs, weights, config):
 
 def _gated_groups(expert_in, weights, group_sizes, tile, impl, config, interpret=False):
     """The grouped matmuls of the expert-sorted rows `expert_in` (three of a
-    gated expert, two of one that is not), the configuration's unit between
-    them. -> (output, a slot's count of hidden units a ReLU gate leaves
-    non-zero; None where the unit has no dead ones to count)."""
+    gated expert, two of one that is not), each one's output under its name
+    of the buffer, and the configuration's unit between them. -> (output, a
+    slot's count of hidden units a ReLU gate leaves non-zero; None where the
+    unit has no dead ones to count)."""
 
-    def gmm(lhs, w):
-        return grouped_matmul(lhs, w, group_sizes, tile_rows=tile, implementation=impl,
-                              interpret=interpret)
+    def gmm(lhs, w, name):
+        return checkpoint_name(grouped_matmul(
+            lhs, w, group_sizes, tile_rows=tile, implementation=impl, interpret=interpret), name)
 
-    act, gate = _expert(config, lambda w: gmm(expert_in, w), weights)
-    out = gmm(act, weights[-1])
+    act, gate = _expert(config, lambda w, name: gmm(expert_in, w, name), weights)
+    out = gmm(act, weights[-1], BUFFER_OUT)
     live = None
     if config.expert_act == "reglu":
         live = jnp.sum(jax.lax.stop_gradient(gate) > 0, axis=-1, dtype=jnp.float32)
@@ -573,7 +595,9 @@ def _held_experts(h, gates, experts, weights, config, tile, impl):
     so a step whose rows fit the buffer evaluates one predicate a layer. The
     backward pass does the same with the first pass's cotangents of `h`, the
     gates and the weights (`all_passes`, a `custom_vjp`: a later pass is
-    computed again there from its inputs, nothing else is kept for it). So a
+    computed again there from its inputs, nothing else is kept for it; what
+    the FIRST pass writes and its transpose reads carries the buffer's names,
+    `held_buffer_names`, for a checkpoint around the layer to keep). So a
     pass not taken fills nothing and adds nothing, forward or backward (a
     `cond` that returns the pass's own sums for the caller to add fills a zero
     of the output's size a pass, a zero cotangent an input and zeros for a
@@ -616,23 +640,25 @@ def _held_experts(h, gates, experts, weights, config, tile, impl):
             padded_starts = padded_ends - padded
             slot_expert = jnp.minimum(jnp.searchsorted(padded_ends, slot, side="right"), n - 1)
             rank = slot - padded_starts[slot_expert]
-            slot_row = jnp.where(
+            slot_row = checkpoint_name(jnp.where(
                 rank < sizes[slot_expert],
-                order[jnp.clip(first_position[slot_expert] + rank, 0, rows - 1)], rows)
+                order[jnp.clip(first_position[slot_expert] + rank, 0, rows - 1)], rows), BUFFER_SLOTS)
+            padded = checkpoint_name(padded.astype(jnp.int32), BUFFER_SLOTS)
             # slot -> token: T (out of range: read as a zero row, dropped when written)
             # where the slot holds none
             slot_token = slot_row // k
             if kernel:
-                bounds = token_tile_bounds(
-                    slot_token, slot_expert, n, tokens, rows_sum_tile(tokens, m))
+                bounds = checkpoint_name(token_tile_bounds(
+                    slot_token, slot_expert, n, tokens, rows_sum_tile(tokens, m)), BUFFER_SLOTS)
                 expert_in = take_token_rows(h, slot_token, bounds, interpret=interpret)
             else:
                 expert_in = jnp.take(h, slot_token, axis=0, mode="fill", fill_value=0)
+            expert_in = checkpoint_name(expert_in, BUFFER_IN)
         with jax.named_scope("moe.experts"):
-            expert_out, live = _gated_groups(
-                expert_in, weights, padded.astype(jnp.int32), tile, impl, c, interpret)
+            expert_out, live = _gated_groups(expert_in, weights, padded, tile, impl, c, interpret)
         with jax.named_scope("moe.combine"):
-            slot_gate = jnp.take(flat_gates, slot_row, mode="fill", fill_value=0)
+            slot_gate = checkpoint_name(
+                jnp.take(flat_gates, slot_row, mode="fill", fill_value=0), BUFFER_SLOTS)
             if kernel:
                 out = rows_sum(expert_out, slot_gate, slot_token, bounds, tokens,
                                interpret=interpret)

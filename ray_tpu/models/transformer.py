@@ -748,6 +748,7 @@ def stack_costs(runs: Sequence[StackRun]) -> Dict[str, Any]:
 
 def block_costs(
     config: TransformerConfig, seq: int, split: Callable[[str], int] = lambda weight: 1,
+    tokens: Optional[int] = None,
 ) -> Dict[str, Any]:
     """What the stack's blocks cost a device for a row (a token) of an
     S-long sequence, for the rule that decides what a recomputing step keeps
@@ -773,7 +774,10 @@ def block_costs(
     parameter's matmul (tensor parallelism), and `split("stream")` the
     devices that share one sequence of the residual stream between sublayers
     (parallel/sequence_parallel.stream_shards): the stream's rows, a kept
-    `attn_residual` and a block's input are that share a device."""
+    `attn_residual` and a block's input are that share a device. `tokens`, a
+    device's tokens a step, is every family's argument and sizes nothing here
+    (mixed_stack.block_costs: a held expert layer's buffer)."""
+    del tokens
     return stack_costs([StackRun(True, ("blocks",), (
         (config.n_layers, attention_costs(config, seq, split), mlp_costs(config, split)),))])
 

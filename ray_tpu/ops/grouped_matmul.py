@@ -29,10 +29,14 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _IMPLEMENTATIONS = ("xla", "pallas")
+# the `checkpoint_name` of the kernels' two prefetched tables (each row tile's group, the tiles that hold
+# rows): a few words that all three matmuls of a layer and their transposes read, found by a search
+TILES = "moe_gmm_tiles"
 # rows of one kernel tile: a multiple of the v5e MXU's 128 that keeps the
 # zero rows a group is padded with (half a tile on average) a few percent
 PALLAS_TILE_ROWS = 256
@@ -75,9 +79,9 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array, *,
     tile_starts = jnp.arange(lhs.shape[0] // tile_rows, dtype=jnp.int32) * tile_rows
     # the group of each row tile; tiles after the last group read the last
     # group's matrix and are not computed
-    tile_group = jnp.minimum(
-        jnp.searchsorted(ends, tile_starts, side="right"), rhs.shape[0] - 1).astype(jnp.int32)
-    tiles_used = (ends[-1:] // tile_rows).astype(jnp.int32)
+    tile_group = checkpoint_name(jnp.minimum(
+        jnp.searchsorted(ends, tile_starts, side="right"), rhs.shape[0] - 1).astype(jnp.int32), TILES)
+    tiles_used = checkpoint_name((ends[-1:] // tile_rows).astype(jnp.int32), TILES)
     return _gmm_pallas(lhs, rhs, tile_group, tiles_used, tile_rows, interpret)
 
 

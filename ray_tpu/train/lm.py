@@ -438,7 +438,7 @@ def make_train_step(
         stream_split = stream_shards(mesh.abstract_mesh, shape[0] // grad_accum, seq)
         costs = family.block_costs(
             config, seq, lambda weight: (stream_split if weight == "stream"
-                                         else model_split(weight, slice(2, None)))
+                                         else model_split(weight, slice(2, None))), rows,
         ) if family.block_costs else None
         # the logits a position: every next-token head's
         vocab = config.vocab_size * config.pred_heads // (

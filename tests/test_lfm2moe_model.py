@@ -135,8 +135,11 @@ def test_the_rule_may_keep_the_projection_the_ops_output_and_the_residual():
     by_name = {c.names: c for c in costs["candidates"]}
     # and, since PR 62, the QK-normed attention layer's operands with the norm's inputs, listed last
     operands = ("attn_q", "attn_k", "attn_v", "attn_q_proj", "attn_k_proj")
+    # and, since PR 67, the held experts' buffer as its first pass wrote it
+    buffer = moe.held_buffer_names(config)
     assert {("sconv_in_proj",), ("sconv_conv_out",), ("sconv_residual",), ("attn_out", "attn_lse"),
-            ("attn_residual",), ("mlp_up",), ("mlp_gate",), (moe.ROUTING,), operands} == set(by_name)
+            ("attn_residual",), ("mlp_up",), ("mlp_gate",), (moe.ROUTING,), buffer, operands} == set(by_name)
+    assert by_name[buffer].layers == by_name[moe.ROUTING,].layers == (0, 4)
     assert costs["candidates"][-1].names == operands and by_name[operands].layers == (0, 1)
     m = 64
     assert (by_name["sconv_in_proj",].width, by_name["sconv_in_proj",].flops) == (3 * m, 2 * m * 3 * m)

@@ -728,13 +728,20 @@ def test_block_costs_price_the_sublayers_a_layer_has():
     assert by_name["ssm_scan_out", "ssm_chunk_states"].layers == (2, 2)
     assert (by_name["ssm_in_proj",].layers, by_name["ssm_in_proj",].width) == ((2, 2), 64 + (64 + 2 * 2 * 16) + 8)
     assert by_name["attn_out", "attn_lse"].layers == (0, 1) and by_name["attn_residual",].layers == (0, 1)
-    ssm, experts = _ssm_costs(config), _expert_costs(config, lambda weight: 1)
+    ssm, experts = _ssm_costs(config), _expert_costs(config, lambda weight: 1, 48)
     inner, conv = 64, 64 + 2 * 2 * 16
     assert ssm["flops"] == (2 * 64 * (inner + conv + 8) + 2 * 4 * conv
                             + 2 * 16 * 2 * 16 + 2 * 16 * inner + 4 * inner * 16 + 2 * inner * 64)
     assert experts["flops"] == int(2 * 64 * (32 + 2 * 64 + 2 * (6 * 8 / 32) * 32))
-    gated = _expert_costs(dataclasses.replace(config, expert_act="swiglu"), lambda weight: 1)
+    gated = _expert_costs(dataclasses.replace(config, expert_act="swiglu"), lambda weight: 1, 48)
     assert gated["flops"] == int(2 * 64 * (32 + 3 * 64 + 3 * (6 * 8 / 32) * 32))
+    # the held experts' buffer (PR 67): of 48 tokens top-6, twice the even share of 8 held of 32 is 144 rows and a
+    # one-row tile an expert off a TPU: a slot's input and output, up (and gate) and two float32 words
+    for costs_of, names, matmuls in ((experts, ("in", "up", "out", "slots"), 2), (gated, ("in", "gate", "up", "out", "slots"), 3)):
+        buffer = costs_of["candidates"][-1]
+        assert buffer.names == (*("moe_buffer_" + name for name in names), "moe_gmm_tiles")
+        assert buffer.width == -(-(144 + 8) * (2 * 64 + (matmuls - 1) * 32 + 2) // 48)
+        assert buffer.flops == int(2 * 64 * matmuls * (6 * 8 / 32) * 32)
     # every layer is one sublayer: the stack's FLOPs are the sum of the nine
     attention = next(c for c in costs["candidates"] if c.names == ("attn_residual",))
     assert costs["flops"] > 4 * ssm["flops"] + 4 * experts["flops"] + attention.flops

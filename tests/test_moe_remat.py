@@ -9,8 +9,9 @@ the out-projection reads it with the chunks' states and o (`kda_chunk_out`,
 changes no loss and no gradient, and
 what it stands for is gone from the backward pass's recomputation. (What
 `block_costs` lists at the five cells' published widths and what the rule
-keeps of it on a v5e: tests/test_mixer_remat.py. Six cases of ~20 s: the file
-holds nothing else, tests/conftest.py's rule.)"""
+keeps of it on a v5e: tests/test_mixer_remat.py. Since PR 67 the held layer's
+buffer is two more cases. Eight cases of ~20 s: the file holds nothing else,
+tests/conftest.py's rule.)"""
 
 import collections
 import functools
@@ -31,6 +32,8 @@ BEFORE = ("kda_in_proj", "kda_chunk_out", "kda_chunk_states", "kda_chunk_o", "at
           "attn_latent_kv", "attn_latent_k_rope")
 SHARED = ("moe_shared_gate", "moe_shared_up")
 RULE = ("kda_chunk_out", "kda_chunk_states", "kda_chunk_o")
+# the held layer's buffer (PR 67; tests/test_moe_buffer_remat.py holds the layer alone and the rule's choice)
+BUFFER = ("moe_buffer_in", "moe_buffer_gate", "moe_buffer_up", "moe_buffer_out", "moe_buffer_slots", "moe_gmm_tiles")
 # (the names kept, the routing, the operations of the lowered step that the backward pass loses with them: a
 # layer BODY counts once, the scan's `eK eL` and the unrolled `eK`: three expert layers, two delta-rule mixers)
 GROUPS = {
@@ -45,6 +48,10 @@ GROUPS = {
     # the walk over the chunks and every decay of the two mixer bodies leave the recomputed pass
     "kda-rule-and-norm": (RULE, "as-routed", {"while": 2, "exponential": 16}),
     "all": ((moe.ROUTING, *SHARED, "kda_residual"), "every-choice", {"dot_general": 11, "top_k": 9, "sort": 3}),
+    # the first pass's three grouped matmuls a layer body (`ragged_dot` here) and the search for a slot's expert;
+    # a routing that takes a second pass keeps the first's and computes the second again, as before
+    "buffer": (BUFFER, "as-routed", {"dot_general": 9, "while": 3}),
+    "buffer-second-pass": (BUFFER, "every-choice", {"dot_general": 9, "while": 3}),
 }
 
 

@@ -220,7 +220,9 @@ def test_ling3flash_cell_step_runs_the_delta_rule_under_its_scopes_and_compiles(
     assert _kernels_named(compiled, "flash_bwd_dkv_dq") == 1 and "flash_win" not in compiled.as_text()
     # six mixers x two convolutions (q with k, v), forward and recomputed; one backward each
     assert _kernels_named(compiled, "ssm_conv_fwd") == 6 * 2 * 2 and _kernels_named(compiled, "ssm_conv_bwd") == 6 * 2
+    # three forward grouped matmuls a layer, first pass and later ones, and none in a recomputed pass
     assert _kernels_named(compiled, "moe_gmm_fwd") > 0
+    assert "rematted_computation/moe/moe.experts" not in compiled.as_text()
     _, table = profiling.program_ops_table(profiling._module_text(compiled))
     for scopes, _, _ in table["ssm_conv_fwd"] + table["ssm_conv_bwd"]:
         assert {"kda", "kda.conv"} <= set(scopes), scopes
@@ -295,11 +297,15 @@ def test_lfm2moe_cell_step_runs_the_short_convolution_under_its_scopes_and_compi
     plan = step.remat_plan_for(tokens.shape, state)
     print("lfm2moe plan", plan)
     # everything the stack names, since PR 62 the one attention layer's q, k and v as the kernel takes them
-    # and, its QK-norm's inputs, q and k as the matmuls wrote them (16,384 rows x 5,632 features: 0.185 GB)
+    # and, its QK-norm's inputs, q and k as the matmuls wrote them (16,384 rows x 5,632 features: 0.185 GB); since
+    # PR 67 the four held expert layers' buffers: 34,816 slots of a gathered row, gate, up, an output and two words
     assert plan["remat"] == "selective" and set(plan["remat_saved"]) == {
         "sconv_in_proj", "sconv_conv_out", "sconv_residual", "mlp_up", "mlp_gate", "moe_routing", "attn_out",
-        "attn_lse", "attn_residual", "attn_q", "attn_k", "attn_v", "attn_q_proj", "attn_k_proj"}
-    assert plan["remat_saved_bytes"] == 1_959_788_544 + 16384 * (2048 + 2 * 512 + 2048 + 512) * 2
+        "attn_lse", "attn_residual", "attn_q", "attn_k", "attn_v", "attn_q_proj", "attn_k_proj",
+        "moe_buffer_in", "moe_buffer_gate", "moe_buffer_up", "moe_buffer_out", "moe_buffer_slots", "moe_gmm_tiles"}
+    buffer = -(-34816 * (2 * 2048 + 2 * 1792 + 4) // 16384)
+    assert plan["remat_saved_bytes"] == (
+        1_959_788_544 + 16384 * (2048 + 2 * 512 + 2048 + 512) * 2 + 4 * 16384 * buffer * 2)
     assert step.loss_chunk_for(tokens.shape, state) == 8192
     said = model_family(config).plan(config, 2, 8192)
     assert said["layer_kinds"] == "dC eF eC eC eC"
@@ -308,7 +314,9 @@ def test_lfm2moe_cell_step_runs_the_short_convolution_under_its_scopes_and_compi
     assert (said["moe_experts_routed"], said["moe_experts_held"], said["moe_top_k"]) == (32, 8, 4)
     compiled = step.lower(state, {"tokens": tokens}).compile()
     assert _kernels_named(compiled, "flash_bwd_dkv_dq") == 1 and "flash_win" not in compiled.as_text()
+    # three forward grouped matmuls a layer, first pass and later ones, and none in a recomputed pass
     assert _kernels_named(compiled, "moe_gmm_fwd") > 0
+    assert "rematted_computation/moe/moe.experts" not in compiled.as_text()
     # the attention layer's recomputed pass: the input norm and the QK-norm's statistics, no matmul
     text = compiled.as_text()
     assert "/attn.full/attn.proj/bse,ehd->bhsd/dot_general" in text
